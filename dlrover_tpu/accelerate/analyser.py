@@ -9,8 +9,7 @@ so infeasible strategies are rejected before any compilation.
 from dataclasses import dataclass
 from typing import Dict
 
-import jax
-
+from dlrover_tpu.common import device
 from dlrover_tpu.models.config import ModelConfig
 from dlrover_tpu.accelerate.strategy import AccelerationPlan
 
@@ -49,32 +48,6 @@ class AnalysisResult:
     flops_per_token: float
     fits: bool
     hbm_bytes: float
-
-
-def device_hbm_bytes() -> float:
-    try:
-        dev = jax.devices()[0]
-        stats = dev.memory_stats() or {}
-        limit = stats.get("bytes_limit")
-        if limit:
-            return float(limit)
-    except Exception:  # noqa: BLE001
-        pass
-    kind = ""
-    try:
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:  # noqa: BLE001
-        pass
-    for key, gb in (
-        ("v5p", 95),
-        ("v5 lite", 16),
-        ("v5e", 16),
-        ("v6", 32),
-        ("v4", 32),
-    ):
-        if key in kind:
-            return gb * 1e9
-    return 16e9
 
 
 def analyse(
@@ -148,7 +121,7 @@ def analyse(
         # buffers do not shrink with pp
         act_b += 2 * tokens * cfg.d_model * 4
 
-    hbm = hbm_bytes or device_hbm_bytes()
+    hbm = hbm_bytes or device.device_memory_bytes()
     total = (param_b + opt_b + grad_b + act_b) * 1.15  # fragmentation slack
     return AnalysisResult(
         num_params=n,

@@ -13,12 +13,10 @@ score (free), XLA compiled cost (cheap), or measured dry runs (exact).
 import itertools
 from typing import List, Optional, Tuple
 
+from dlrover_tpu.common import device
 from dlrover_tpu.common.log import get_logger
 from dlrover_tpu.models.config import ModelConfig
-from dlrover_tpu.accelerate.analyser import (
-    analyse,
-    device_hbm_bytes,
-)
+from dlrover_tpu.accelerate.analyser import analyse
 from dlrover_tpu.accelerate.dry_runner import dry_run
 from dlrover_tpu.accelerate.strategy import (
     AccelerationPlan,
@@ -279,7 +277,7 @@ def search_strategy(
             f"unknown search mode {mode!r}: expected "
             "heuristic | cost | measure | bo"
         )
-    hbm = device_hbm_bytes()
+    hbm = device.device_memory_bytes()
     batch_per_chip = max(1, global_batch // n_devices)
     feasible: List[Tuple[float, Strategy, AccelerationPlan]] = []
     # the analytic feasibility filter is cheap — consider the (near-)

@@ -11,13 +11,13 @@ import os
 import signal
 import subprocess
 import sys
-import tempfile
 import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
+from dlrover_tpu.common import compile_cache
 from dlrover_tpu.common.constants import (
     DefaultValues,
     GraftEnv,
@@ -54,10 +54,10 @@ class ElasticLaunchConfig:
     exclude_straggler: bool = False
     node_unit: int = 1
     coordinator_port: int = 7010
-    # persistent XLA compile-cache dir for workers ("" = the private
-    # per-user default under /tmp); same-shape restarts deserialize the
-    # cached executable instead of recompiling — the dominant term in
-    # the <60 s re-mesh recovery budget at real model sizes
+    # persistent XLA compile-cache dir for workers, used when
+    # JAX_COMPILATION_CACHE_DIR is not set ("" = the fixed in-checkout
+    # default, common/compile_cache.py); same-shape restarts deserialize
+    # the cached executable instead of recompiling
     compile_cache_dir: str = ""
     entrypoint: List[str] = field(default_factory=list)
     env: Dict[str, str] = field(default_factory=dict)
@@ -130,53 +130,6 @@ class WorkerProcess:
         except subprocess.TimeoutExpired:
             self._proc.kill()
             self._proc.wait()
-
-
-_compile_cache_memo: List[Optional[str]] = []
-
-
-def _compile_cache_dir() -> Optional[str]:
-    """Private per-user compile-cache dir, or None if one can't be had.
-
-    The path under /tmp is predictable, so it MUST be a real directory
-    (lstat — a pre-created symlink would redirect the cache to an
-    attacker-chosen location) owned by us with no group/other access:
-    another local user able to write it could poison serialized XLA
-    executables that workers deserialize on restart. On any mismatch
-    fall back to a per-job mkdtemp (cross-job persistence is lost,
-    safety is not) — memoized so every elastic restart of this agent
-    reuses ONE dir and the within-job cache keeps working.
-    """
-    if _compile_cache_memo:
-        return _compile_cache_memo[0]
-    path = os.path.join(
-        tempfile.gettempdir(), f"dlrover_tpu_jit_cache_{os.getuid()}"
-    )
-    result: Optional[str]
-    try:
-        os.makedirs(path, mode=0o700, exist_ok=True)
-        st = os.lstat(path)
-        import stat as stat_mod
-
-        if (
-            not stat_mod.S_ISDIR(st.st_mode)
-            or st.st_uid != os.getuid()
-            or (st.st_mode & 0o077)
-        ):
-            logger.warning(
-                "compile cache dir %s is not a private directory we "
-                "own; using a per-job dir instead",
-                path,
-            )
-            result = tempfile.mkdtemp(prefix="dlrover_tpu_jit_cache_")
-        else:
-            result = path
-    except OSError:
-        # transient (ENOSPC, perms mid-cleanup): do NOT memoize — let the
-        # next restart retry rather than losing the cache for the job
-        return None
-    _compile_cache_memo.append(result)
-    return result
 
 
 class ElasticTrainingAgent:
@@ -272,22 +225,16 @@ class ElasticTrainingAgent:
                 if p
             ),
         }
-        if self.config.compile_cache_dir:
-            # job-config override (--compile-cache-dir / operator spec):
-            # e.g. a shared NFS path so every host of the job — and its
-            # relaunched replacements on FRESH hosts — hit one cache
-            env["JAX_COMPILATION_CACHE_DIR"] = self.config.compile_cache_dir
-            env["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "1"
-        elif "JAX_COMPILATION_CACHE_DIR" not in os.environ:
-            # persistent XLA compile cache across worker restarts: the
-            # re-mesh hard part (SURVEY §7) — a restarted worker whose
-            # mesh shape was compiled before (same world, or a prior
-            # round at the new world size) skips the multi-minute
-            # recompile, which dominates the <60s recovery budget
-            cache_dir = _compile_cache_dir()
-            if cache_dir:
-                env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
-                env["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "1"
+        if compile_cache.ENV not in os.environ:
+            # persistent XLA compile cache across worker restarts: a
+            # restarted worker whose mesh shape was compiled before
+            # (same world, or a prior round at the new world size)
+            # deserializes the step instead of recompiling it, which
+            # dominates the recovery budget. The environment's directory
+            # is inherited, never overridden.
+            env[compile_cache.ENV] = compile_cache.compile_cache_dir(
+                self.config.compile_cache_dir
+            )
         env.update(self.config.env)
         return env
 
@@ -333,7 +280,6 @@ class ElasticTrainingAgent:
             slice_index = 0
         self.client.register_node(
             local_chips=self.config.local_chips,
-            tpu_type=_local_tpu_type(),
             slice_id=os.environ.get("DLROVER_TPU_SLICE_ID", slice_raw),
             slice_index=slice_index,
         )
@@ -482,12 +428,3 @@ class ElasticTrainingAgent:
                     self._ckpt_saver.save_shm_to_storage()
                 except Exception:  # noqa: BLE001
                     logger.exception("emergency checkpoint persist failed")
-
-
-def _local_tpu_type() -> str:
-    try:
-        import jax
-
-        return jax.devices()[0].device_kind
-    except Exception:  # noqa: BLE001
-        return "unknown"

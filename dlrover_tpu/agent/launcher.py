@@ -11,7 +11,9 @@ Usage:
 """
 
 import argparse
+import json
 import os
+import subprocess
 import sys
 import threading
 import time
@@ -22,10 +24,6 @@ from dlrover_tpu.common.log import get_logger
 from dlrover_tpu.agent.agent import ElasticLaunchConfig, ElasticTrainingAgent
 from dlrover_tpu.agent.master_client import MasterClient
 from dlrover_tpu.agent.monitor import ResourceMonitor
-from dlrover_tpu.agent.node_check import (
-    run_comm_perf_test,
-    run_node_check,
-)
 
 logger = get_logger(__name__)
 
@@ -42,7 +40,8 @@ def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
         "--nproc",
         type=int,
         default=0,
-        help="local chip count (0 = autodetect via jax)",
+        help="local chip count (0 = the DLROVER_TPU_LOCAL_CHIPS "
+        "environment, else 1; the agent never opens the device to count)",
     )
     p.add_argument("--master-addr", default="", help="job master host:port")
     p.add_argument("--max-restarts", type=int, default=3)
@@ -70,9 +69,9 @@ def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
         "--compile-cache-dir",
         default="",
         help="persistent XLA compile-cache dir for workers (e.g. a "
-        "job-shared NFS path); default: a private per-user dir under "
-        "/tmp — restarts with an already-seen mesh shape skip the "
-        "recompile",
+        "job-shared NFS path), used when JAX_COMPILATION_CACHE_DIR is "
+        "not set; default: a fixed directory inside the checkout — "
+        "restarts with an already-seen mesh shape skip the recompile",
     )
     p.add_argument("--monitor-interval", type=float, default=2.0)
     p.add_argument("entrypoint", nargs=argparse.REMAINDER)
@@ -89,13 +88,38 @@ def _parse_nnodes(spec: str):
     return int(spec), int(spec)
 
 
-def _detect_local_chips() -> int:
-    try:
-        import jax
+def _local_chips(args: argparse.Namespace) -> int:
+    """Chips on this host, as told: ``--nproc``, else the environment,
+    else one. Counting them through jax would take them from the worker;
+    the worker reports what it finds (agent/monitor.py)."""
+    return args.nproc or int(os.environ.get(GraftEnv.LOCAL_CHIPS, "1"))
 
-        return len(jax.local_devices())
-    except Exception:  # noqa: BLE001
-        return 1
+
+def _device_child(mode: str, timeout_s: float = 600.0) -> dict:
+    """Run one ``agent.node_check`` mode in a child process and return
+    the JSON object it prints. A chip belongs to one process at a time:
+    the agent never initialises a jax backend itself, and the child has
+    exited — and released the chip — before the worker starts."""
+    import dlrover_tpu
+
+    pkg_parent = os.path.dirname(os.path.dirname(dlrover_tpu.__file__))
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in (pkg_parent, env.get("PYTHONPATH", "")) if p
+    )
+    proc = subprocess.run(
+        [sys.executable, "-m", "dlrover_tpu.agent.node_check", mode],
+        env=env,
+        capture_output=True,
+        text=True,
+        timeout=timeout_s,
+    )
+    sys.stderr.write(proc.stderr)
+    if proc.returncode != 0:
+        raise RuntimeError(
+            f"node_check {mode} child exited rc={proc.returncode}"
+        )
+    return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
 def _launch_local_master(num_workers: int, max_workers: int, node_unit: int):
@@ -130,8 +154,10 @@ def _run_network_check(client: MasterClient, config: ElasticLaunchConfig):
             timeout_s=config.rdzv_timeout_s,
         )
         handler.next_rendezvous()
-        ok, elapsed = run_node_check()
-        client.report_network_check_result(elapsed, ok)
+        result = _device_child("check")
+        client.report_network_check_result(
+            result["elapsed_s"], result["ok"]
+        )
         time.sleep(1.0)
     status = client.get_network_check_status()
     if not status.normal:
@@ -155,6 +181,22 @@ def _run_network_check(client: MasterClient, config: ElasticLaunchConfig):
             sys.exit(3)
 
 
+def _run_comm_perf_test():
+    """Allreduce bandwidth sweep before the worker starts. A diagnostic:
+    its figures are logged, and a child that fails or runs out of time
+    is logged too — it never stops the launch."""
+    try:
+        gbps = _device_child("comm-perf")["gbps"]
+    except (OSError, RuntimeError, subprocess.SubprocessError, ValueError,
+            LookupError):
+        logger.warning("comm perf test failed", exc_info=True)
+        return
+    logger.info(
+        "comm perf, GB/s by allreduce size in bf16 elements: %s",
+        gbps or "skipped — fewer than 2 devices",
+    )
+
+
 def run(args: argparse.Namespace) -> int:
     min_nodes, max_nodes = _parse_nnodes(args.nnodes)
     node_id = (
@@ -168,7 +210,7 @@ def run(args: argparse.Namespace) -> int:
         from dlrover_tpu.observability.tracing import configure_tracer
 
         configure_tracer("agent")
-    local_chips = args.nproc or _detect_local_chips()
+    local_chips = _local_chips(args)
 
     master = None
     master_addr = args.master_addr or os.environ.get(GraftEnv.MASTER_ADDR, "")
@@ -211,10 +253,7 @@ def run(args: argparse.Namespace) -> int:
         if config.network_check:
             _run_network_check(client, config)
         if config.comm_perf_test:
-            try:
-                run_comm_perf_test()
-            except Exception:  # noqa: BLE001 — diagnostic, never fatal
-                logger.warning("comm perf test failed", exc_info=True)
+            _run_comm_perf_test()
         agent = ElasticTrainingAgent(config, client)
         try:
             from dlrover_tpu.checkpoint.saver import AsyncCheckpointSaver

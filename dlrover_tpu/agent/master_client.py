@@ -10,6 +10,7 @@ import socket
 import time
 from typing import Dict, List, Optional, Tuple
 
+from dlrover_tpu.agent import monitor
 from dlrover_tpu.common import messages as msgs
 from dlrover_tpu.common.comm import MasterTransportClient
 from dlrover_tpu.common.constants import GraftEnv, RendezvousName
@@ -29,6 +30,7 @@ class MasterClient:
         )
         self.node_id = node_id
         self.node_rank = node_rank
+        self._next_device_report = 0.0  # monotonic; see report_global_step
 
     # ---- node lifecycle --------------------------------------------------
 
@@ -349,6 +351,13 @@ class MasterClient:
     # ---- telemetry -------------------------------------------------------
 
     def report_global_step(self, step: int, worker_num: int = 0) -> bool:
+        # the step heartbeat is the one report every worker makes, and
+        # the worker is the process that holds the chips: what they are
+        # and how full rides along on an interval
+        now = time.monotonic()
+        if now >= self._next_device_report and monitor.holds_devices():
+            self._next_device_report = now + monitor.DEVICE_REPORT_INTERVAL_S
+            monitor.report_device_stats(self)
         return self._t.report(
             msgs.GlobalStepRecord(
                 global_step=step,

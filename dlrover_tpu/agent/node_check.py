@@ -8,6 +8,9 @@ chip plus a psum across all local chips (and across hosts when
 jax.distributed is up) — exercising HBM, MXU, and ICI.
 """
 
+import argparse
+import json
+import sys
 import time
 from typing import Tuple
 
@@ -130,3 +133,27 @@ def run_node_check(mock_error: bool = False) -> Tuple[bool, float]:
     except Exception:  # noqa: BLE001
         logger.exception("node check failed")
         return False, 0.0
+
+
+def main(argv=None) -> int:
+    """``python -m dlrover_tpu.agent.node_check check|comm-perf``: the
+    device half of the launcher's pre-flight, run as a child so the
+    agent never holds the chip. Prints one JSON object as its last
+    stdout line."""
+    p = argparse.ArgumentParser(prog="dlrover-tpu-node-check")
+    p.add_argument("mode", choices=("check", "comm-perf"))
+    args = p.parse_args(argv)
+    if args.mode == "check":
+        ok, elapsed = run_node_check()
+        print(json.dumps({"ok": ok, "elapsed_s": elapsed}), flush=True)
+    else:
+        bandwidth = run_comm_perf_test()
+        print(
+            json.dumps({"gbps": {str(k): v for k, v in bandwidth.items()}}),
+            flush=True,
+        )
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -178,6 +178,12 @@ class ResourceStats:
     # high-watermark of HBM in use across all local devices since
     # process start (jax memory_stats peak_bytes_in_use, summed)
     hbm_peak_mb: float = 0.0
+    # set only on the worker's report, which rides its step heartbeat
+    # (agent/monitor.py report_device_stats): the process that holds the
+    # chips says what they are and how full; the agent's host-only
+    # reports leave these and the HBM fields empty
+    tpu_type: str = ""
+    local_chips: int = 0
 
 
 @message

@@ -10,6 +10,7 @@ never loses the staged bytes.
 
 import json
 import os
+import shutil
 import socket
 import socketserver
 import threading
@@ -83,6 +84,17 @@ def create_shared_memory(name: str, size: int) -> shared_memory.SharedMemory:
         old.unlink()
     except FileNotFoundError:
         pass
+    # POSIX shm is tmpfs: creating a segment larger than the mount has
+    # room for succeeds, and the write that crosses the limit is a
+    # SIGBUS. Say it in a sentence first.
+    if os.path.isdir("/dev/shm"):
+        free = shutil.disk_usage("/dev/shm").free
+        if size > free:
+            raise RuntimeError(
+                f"shared-memory segment {name!r} needs {size / 1e9:.2f} GB "
+                f"but /dev/shm has {free / 1e9:.2f} GB free; enlarge it "
+                "(or free stale dlrover_tpu_* segments) to stage this state"
+            )
     shm = shared_memory.SharedMemory(name=name, create=True, size=size)
     try:
         resource_tracker.unregister(shm._name, "shared_memory")  # noqa: SLF001

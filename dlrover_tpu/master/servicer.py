@@ -155,6 +155,14 @@ class MasterServicer:
         return True
 
     def _report_resource(self, m: msgs.ResourceStats) -> bool:
+        if m.tpu_type and self.job_manager:
+            # the worker's report: the process that holds the chips says
+            # what they are. The agent's reports are host-only and carry
+            # no device half — HBM figures come from workers alone.
+            node = self.job_manager.get_node(m.node_id)
+            if node is not None:
+                node.config_resource.tpu_type = m.tpu_type
+                node.config_resource.tpu_chips = m.local_chips
         if self.telemetry_hub is not None and self.telemetry_hub.enabled:
             # diagnosis (and any other consumer) subscribes to the bus;
             # the servicer only translates wire → record
@@ -165,6 +173,8 @@ class MasterServicer:
                     mem_mb=m.used_memory_mb,
                     hbm_mb=m.hbm_used_mb,
                     hbm_peak_mb=m.hbm_peak_mb,
+                    tpu_type=m.tpu_type,
+                    local_chips=m.local_chips,
                 )
             )
         elif self.diagnosis_manager:

@@ -27,6 +27,7 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_policies as cp
 from jax.sharding import PartitionSpec as P
 
+from dlrover_tpu.common import device
 from dlrover_tpu.models.config import ModelConfig
 from dlrover_tpu.ops import pallas_norm, pallas_paged, quant
 from dlrover_tpu.ops.attention import _repeat_kv, mha_reference
@@ -175,9 +176,7 @@ def _embed_lookup_hostile(mesh, table_shape, tokens_shape) -> bool:
         or s % mesh.shape.get("sp", 1)
     ):
         return False
-    from dlrover_tpu.common import jax_compat
-
-    return not jax_compat.manual_axis_names()
+    return not shd.manual_axis_names()
 
 
 def _vocab_parallel_embed(table: jax.Array, tokens: jax.Array, mesh):
@@ -198,8 +197,6 @@ def _vocab_parallel_embed(table: jax.Array, tokens: jax.Array, mesh):
     (atorch/modules/distributed_modules/layers.py) does the same
     masked-lookup + all-reduce with torch collectives.
     """
-    from dlrover_tpu.common.jax_compat import shard_map
-
     def body(rank, tbl, tok):
         vs = tbl.shape[0]
         # tp rank from a tp-sharded iota input, not lax.axis_index:
@@ -212,7 +209,7 @@ def _vocab_parallel_embed(table: jax.Array, tokens: jax.Array, mesh):
         x = jnp.where(inb[..., None], x, jnp.zeros([], x.dtype))
         return jax.lax.psum(x, "tp")
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P("tp"), P("tp", None), P(("dp", "fsdp"), "sp")),
@@ -507,6 +504,18 @@ def _layer_body(
     return x, aux
 
 
+def _offload_names_policy(*names):
+    """Checkpoint policy saving ``names`` to pinned host memory;
+    everything unnamed is recomputed in backward, exactly like
+    ``save_only_these_names(*names)`` — only the residency differs."""
+    return cp.save_and_offload_only_these_names(
+        names_which_can_be_saved=[],
+        names_which_can_be_offloaded=list(names),
+        offload_src="device",
+        offload_dst="pinned_host",
+    )
+
+
 def run_trunk(
     x: jax.Array,          # [B, S, D] embedded inputs
     layers: Params,        # stacked per-layer params (leading axis L)
@@ -597,11 +606,9 @@ def run_trunk(
         # checkpoint, auto/opt_lib/selective_offloading_checkpoint.py) —
         # activation memory ~frees the O(L·B·S·D) attention outputs at
         # the cost of host DMA traffic in backward
-        from dlrover_tpu.common import jax_compat
-
         body = jax.checkpoint(
             body,
-            policy=jax_compat.offload_names_policy(
+            policy=_offload_names_policy(
                 "attn_out", "flash_out", "flash_lse"
             ),
         )
@@ -612,11 +619,9 @@ def run_trunk(
         # 16 GiB chip) but full remat's ~30% recompute is too slow.
         # Backward pays host DMA instead of matmul+kernel re-runs; the
         # DMA overlaps the MLP recompute it replaced.
-        from dlrover_tpu.common import jax_compat
-
         body = jax.checkpoint(
             body,
-            policy=jax_compat.offload_names_policy(
+            policy=_offload_names_policy(
                 "attn_out", "flash_out", "flash_lse",
                 "q_proj", "k_proj", "v_proj",
             ),
@@ -833,7 +838,7 @@ def forward(
             # flash (pallas) on real accelerators; the kernel's
             # interpret path is far slower than plain jnp on CPU
             attn_impl = (
-                "reference" if jax.default_backend() == "cpu" else "flash"
+                "reference" if device.on_cpu() else "flash"
             )
 
     if cfg.prefix_lm and prefix_len is None:
@@ -1339,7 +1344,7 @@ def _chunk_cached_attention(q, ck, cv, positions, cfg: ModelConfig, scale):
     if hkv != h:
         ck = _repeat_kv(ck, h // hkv)
         cv = _repeat_kv(cv, h // hkv)
-    if jax.default_backend() == "cpu":
+    if device.on_cpu():
         # mirror mha_reference's CPU-vs-MXU precision split exactly
         logits = jnp.einsum(
             "bqhd,bkhd->bhqk",

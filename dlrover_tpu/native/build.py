@@ -1,7 +1,8 @@
 """Build + load the native library.
 
 JIT-compiles the C++ sources with g++ on first import and caches the .so
-next to the sources, keyed by a hash of their contents — the same
+next to the sources, keyed by a hash of their contents and of the host
+CPU's feature flags (the build uses ``-march=native``) — the same
 compile-on-demand approach as the reference's op_builder
 (atorch/atorch/ops/op_builder/builder.py), minus the CUDA toolchain.
 """
@@ -9,6 +10,7 @@ compile-on-demand approach as the reference's op_builder
 import ctypes
 import hashlib
 import os
+import platform
 import subprocess
 import tempfile
 import threading
@@ -21,8 +23,23 @@ _lock = threading.Lock()
 _lib = None
 
 
+def _host_cpu_flags() -> bytes:
+    """What ``-march=native`` resolves to on this host: the CPU's
+    feature flags. Part of the artifact key, because the tree — built
+    artifacts included — gets copied between machines, and a library
+    compiled for one CPU must not be loaded on another."""
+    try:
+        with open("/proc/cpuinfo", "rb") as f:
+            for line in f:
+                if line.startswith((b"flags", b"Features")):
+                    return line
+    except OSError:
+        pass
+    return platform.machine().encode()
+
+
 def _source_hash(files=None) -> str:
-    h = hashlib.sha256()
+    h = hashlib.sha256(_host_cpu_flags())
     for rel in (_SOURCES + _HEADERS if files is None else files):
         with open(os.path.join(_SRC_DIR, rel), "rb") as f:
             h.update(f.read())

@@ -117,7 +117,7 @@ class RuntimeKernelTimer:
     def profiled_call(self, step: int, fn, *args, n_steps: int = 1, **kwargs):
         """Run ``fn``; when the cadence hits, run it under a trace and
         refresh the breakdown. Tracing failures degrade to an untimed
-        call (the relay/backend may not support device tracing).
+        call (the backend may not support device tracing).
 
         ``n_steps``: how many train steps ``fn`` executes as one device
         program (the trainer's fused ``block_k`` path). The breakdown

@@ -197,13 +197,19 @@ class StragglerRecord:
 
 @telemetry_record
 class ResourceRecord:
-    """Per-node host/HBM usage as reported by the agent monitor."""
+    """Per-node host usage, plus the device half where the reporter
+    holds the chips: a worker's report names them (``tpu_type``,
+    ``local_chips``) and its HBM figures are readings; the agent's
+    host-only report leaves all four empty, which says nothing about
+    HBM — least of all that it is free."""
 
     node_id: int = -1
     cpu_percent: float = 0.0
     mem_mb: float = 0.0
     hbm_mb: float = 0.0
     hbm_peak_mb: float = 0.0
+    tpu_type: str = ""
+    local_chips: int = 0
     ts: float = 0.0
 
 
@@ -561,7 +567,10 @@ class MetricsSink:
 
     def emit(self, record) -> None:
         tname = type(record).__name__
-        for gauge, attr in _GAUGE_MAP.get(tname, ()):
+        gauges = _GAUGE_MAP.get(tname, ())
+        if tname == "ResourceRecord" and not record.local_chips:
+            gauges = ()  # host-only report: no HBM reading to project
+        for gauge, attr in gauges:
             self._c.set_gauge(gauge, float(getattr(record, attr)))
         counter = _COUNTER_MAP.get(tname)
         if counter:

@@ -20,6 +20,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from dlrover_tpu.common import device
+
 
 def _repeat_kv(k: jax.Array, n_rep: int) -> jax.Array:
     if n_rep == 1:
@@ -55,7 +57,7 @@ def mha_reference(
         k = _repeat_kv(k, h // hkv)
         v = _repeat_kv(v, h // hkv)
     scale = softmax_scale if softmax_scale is not None else d ** -0.5
-    if jax.default_backend() == "cpu":
+    if device.on_cpu():
         # explicit f32 upcast rather than preferred_element_type:
         # XLA:CPU's thunk runtime cannot execute a BF16xBF16=F32 dot
         # when a `name` barrier (remat checkpoint tags upstream) keeps
@@ -125,8 +127,9 @@ def mha(
     if impl == "flash":
         use_flash = True
     elif impl == "auto":
-        on_tpu = jax.default_backend() not in ("cpu", "gpu")
-        use_flash = on_tpu and q.shape[1] >= 1024 and segment_ids is None
+        use_flash = (
+            device.on_tpu() and q.shape[1] >= 1024 and segment_ids is None
+        )
     if use_flash:
         from dlrover_tpu.ops.pallas_attention import flash_attention
 

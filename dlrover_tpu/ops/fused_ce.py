@@ -29,6 +29,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from dlrover_tpu.common import device
+
 _NEG_INF = float("-inf")
 
 
@@ -54,12 +56,26 @@ def _mm_f32(subscripts, a, b):
     fusing the converts, so upcast the operands explicitly — the
     fallback path's extra precision is free there.
     """
-    if jax.default_backend() == "cpu":
+    if device.on_cpu():
         return jnp.einsum(
             subscripts, a.astype(jnp.float32), b.astype(jnp.float32)
         )
     return jnp.einsum(
         subscripts, a, b, preferred_element_type=jnp.float32
+    )
+
+
+def _vary_like(carry, *operands):
+    """A fresh scan carry, marked varying over the manual mesh axes its
+    operands vary over. Inside a shard_map region (the ZeRO step's
+    dp-manual update) constants are unvarying while everything computed
+    from the per-rank batch varies, and scan requires the carry's input
+    and output types to agree."""
+    axes = frozenset().union(*(jax.typeof(o).vma for o in operands))
+    if not axes:
+        return carry
+    return jax.tree.map(
+        lambda a: lax.pcast(a, tuple(sorted(axes)), to="varying"), carry
     )
 
 
@@ -109,6 +125,7 @@ def _fused_fwd(x, w, targets, scale, block_v):
         jnp.full((b, s), _NEG_INF, jnp.float32),
         jnp.zeros((b, s), jnp.int32),
     )
+    init = _vary_like(init, x, w, targets)
 
     def step(carry, i):
         m, se, tgt, av, ai = carry
@@ -171,6 +188,7 @@ def _fused_bwd(scale, block_v, res, cots):
         jnp.zeros(x.shape, jnp.float32),
         jnp.zeros((d, nc * block_v), jnp.float32),
     )
+    init = _vary_like(init, x, w, targets, logz, g_logz, g_tgt)
     (dx, dwp), _ = lax.scan(step, init, jnp.arange(nc))
     d_targets = np.zeros(targets.shape, dtype=jax.dtypes.float0)
     return dx.astype(x.dtype), dwp[:, :v].astype(w.dtype), d_targets

@@ -46,7 +46,8 @@ try:  # pltpu only resolves on TPU builds of jaxlib
 except ImportError:  # pragma: no cover
     pltpu = None
 
-from dlrover_tpu.ops.pallas_attention import _on_tpu
+from dlrover_tpu.common import device
+from dlrover_tpu.ops.pallas_attention import _out_struct
 
 # test hook: run every kernel in pallas interpret mode (CPU-executable).
 # Seeded from the environment so a whole pytest run can flip it without
@@ -71,7 +72,7 @@ def kernels_available(interpret=None) -> bool:
     """True when the Pallas path would actually run (real TPU or
     interpret mode) — what ``cfg.fused_norm=None`` (auto) keys off."""
     interpret = INTERPRET if interpret is None else interpret
-    return pltpu is not None and (_on_tpu() or interpret)
+    return pltpu is not None and (device.on_tpu() or interpret)
 
 
 def _fit_rows(n: int, dp: int, dtype) -> int:
@@ -144,7 +145,7 @@ def _bwd_kernel(*refs, kind, eps, d, has_bias, has_res):
         gx = g32 * s32
         dot = jnp.sum(gx * h32, axis=-1, keepdims=True) / d
         dx = r * gx - (r * r * r) * dot * h32
-        ds_ref[...] = jnp.sum(g32 * h32 * r, axis=0, keepdims=True)
+        ds_ref[0] = jnp.sum(g32 * h32 * r, axis=0, keepdims=True)
     else:
         mean = jnp.sum(h32, axis=-1, keepdims=True) / d
         ex2 = jnp.sum(h32 * h32, axis=-1, keepdims=True) / d
@@ -155,9 +156,9 @@ def _bwd_kernel(*refs, kind, eps, d, has_bias, has_res):
         m1 = jnp.sum(gx, axis=-1, keepdims=True) / d
         m2 = jnp.sum(gx * xhat, axis=-1, keepdims=True) / d
         dx = r * (gx - m1 - xhat * m2)
-        ds_ref[...] = jnp.sum(g32 * xhat, axis=0, keepdims=True)
+        ds_ref[0] = jnp.sum(g32 * xhat, axis=0, keepdims=True)
         if has_bias:
-            db_ref[...] = jnp.sum(g32, axis=0, keepdims=True)
+            db_ref[0] = jnp.sum(g32, axis=0, keepdims=True)
     if has_res:
         # the summed stream's own downstream cotangent folds in here so
         # backward too is one visit per row block
@@ -192,10 +193,10 @@ def _call_fwd(kind, eps, dims, interpret, x, scale, bias, res):
         in_specs.append(row_spec)
         inputs.append(res)
     out_specs = [row_spec]
-    out_shape = [jax.ShapeDtypeStruct((n, dp), x.dtype)]
+    out_shape = [_out_struct((n, dp), x.dtype, x)]
     if has_res:
         out_specs.append(row_spec)
-        out_shape.append(jax.ShapeDtypeStruct((n, dp), x.dtype))
+        out_shape.append(_out_struct((n, dp), x.dtype, x))
     outs = pl.pallas_call(
         functools.partial(
             _fwd_kernel, kind=kind, eps=eps, d=d,
@@ -239,7 +240,11 @@ def _norm_call_bwd(kind, eps, dims, interpret, saved, g):
     grid = n // bn
     row_spec = pl.BlockSpec((bn, dp), lambda i: (i, 0))
     vec_spec = pl.BlockSpec((1, dp), lambda i: (0, 0))
-    part_spec = pl.BlockSpec((1, dp), lambda i: (i, 0))
+    # per-program partials live in a [grid, 1, dp] array so the block's
+    # last two dims equal the array's: Mosaic refuses a (1, dp) block
+    # over a (grid, dp) array (sublane dim must be 8-divisible or full)
+    part_spec = pl.BlockSpec((1, 1, dp), lambda i: (i, 0, 0))
+    part_shape = _out_struct((grid, 1, dp), jnp.float32, h)
     in_specs = [row_spec, row_spec, vec_spec]
     inputs = [gout, h, scale]
     if has_res:
@@ -247,12 +252,12 @@ def _norm_call_bwd(kind, eps, dims, interpret, saved, g):
         inputs.append(gh)
     out_specs = [row_spec, part_spec]
     out_shape = [
-        jax.ShapeDtypeStruct((n, dp), h.dtype),
-        jax.ShapeDtypeStruct((grid, dp), jnp.float32),
+        _out_struct((n, dp), h.dtype, h),
+        part_shape,
     ]
     if has_bias:
         out_specs.append(part_spec)
-        out_shape.append(jax.ShapeDtypeStruct((grid, dp), jnp.float32))
+        out_shape.append(part_shape)
     outs = pl.pallas_call(
         functools.partial(
             _bwd_kernel, kind=kind, eps=eps, d=d,
@@ -266,9 +271,9 @@ def _norm_call_bwd(kind, eps, dims, interpret, saved, g):
         interpret=interpret,
     )(*inputs)
     dx = outs[0]
-    dscale = outs[1].sum(axis=0, keepdims=True).astype(scale.dtype)
+    dscale = outs[1].sum(axis=0).astype(scale.dtype)
     dbias = (
-        outs[2].sum(axis=0, keepdims=True).astype(bias.dtype)
+        outs[2].sum(axis=0).astype(bias.dtype)
         if has_bias
         else None
     )
@@ -337,7 +342,7 @@ def norm(
     if kind == "rmsnorm":
         bias = None
     d = x.shape[-1]
-    if not (pltpu is not None and (_on_tpu() or interpret)):
+    if not (pltpu is not None and (device.on_tpu() or interpret)):
         return _reference(x, scale, bias, kind, eps, residual)
     n = math.prod(x.shape[:-1])
     dp = (d + 127) // 128 * 128

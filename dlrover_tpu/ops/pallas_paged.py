@@ -50,7 +50,7 @@ except ImportError:  # pragma: no cover
 
 from dlrover_tpu.ops import quant
 from dlrover_tpu.ops.attention import _repeat_kv
-from dlrover_tpu.ops.pallas_attention import _on_tpu
+from dlrover_tpu.common import device
 
 NEG_INF = -1e30
 
@@ -66,7 +66,7 @@ def kernels_available(interpret=None) -> bool:
     off. Everywhere else ``paged_attention`` silently runs the jnp
     reference, which is still a paged (pages-held-only) gather."""
     interpret = INTERPRET if interpret is None else interpret
-    return pltpu is not None and (_on_tpu() or interpret)
+    return pltpu is not None and (device.on_tpu() or interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -273,7 +273,7 @@ def paged_attention_reference(
     if hkv != h:
         k = _repeat_kv(k, h // hkv)
         v = _repeat_kv(v, h // hkv)
-    if jax.default_backend() == "cpu":
+    if device.on_cpu():
         logits = jnp.einsum(
             "bqhd,bkhd->bhqk",
             q.astype(jnp.float32),
@@ -300,8 +300,9 @@ def paged_attention_reference(
 def _paged_kernel(
     # scalar prefetch (SMEM)
     tab_ref,            # [B, W] int32 block tables
-    pos_ref,            # [B, C] int32 query positions
+    span_ref,           # [B, 2] int32 (first, last) query position
     # VMEM blocks
+    rowpos_ref,         # [1, n_q, 1] int32 query position of each row
     q_ref,              # [1, C, H, D]
     *refs,
     page_size,
@@ -333,10 +334,10 @@ def _paged_kernel(
     """
     if verify:
         if int8:
-            (kq_ref, ks_ref, vq_ref, vs_ref, ink_ref, inv_ref,
+            (kq_ref, ks_ref, vq_ref, vs_ref, ink_ref, inv_ref, inpos_ref,
              o_ref, m_scr, l_scr, acc_scr) = refs
         else:
-            (k_ref, v_ref, ink_ref, inv_ref,
+            (k_ref, v_ref, ink_ref, inv_ref, inpos_ref,
              o_ref, m_scr, l_scr, acc_scr) = refs
     elif int8:
         kq_ref, ks_ref, vq_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = refs
@@ -354,13 +355,13 @@ def _paged_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # query positions for this slot, expanded to rows (c, g) — element
-    # reads so SMEM access stays scalar on real hardware
-    pos_rows = jnp.stack(
-        [pos_ref[b, r // groups] for r in range(n_q)]
-    )  # [n_q] int32
-    max_pos = pos_ref[b, c - 1]
-    min_pos = pos_ref[b, 0]
+    # query positions for this slot, one per (c, g) row. A VMEM column,
+    # not a vector stacked from SMEM scalars: Mosaic refuses to
+    # concatenate more than a tile's worth of scalars, which is every
+    # prefill chunk of a realistic width (C >= 256)
+    pos_rows = rowpos_ref[0]  # [n_q, 1] int32
+    min_pos = span_ref[b, 0]
+    max_pos = span_ref[b, 1]
 
     def _fold_block(k, v, allowed):
         """Advance the running (max, sum, acc) state by one key block
@@ -419,15 +420,16 @@ def _paged_kernel(
     @pl.when(page_ok)
     def _fold():
         if int8:
-            # in-register dequant against the per-block f32 scales;
+            # in-register dequant against the per-head f32 scales (the
+            # scale block IS the head, so no cross-lane reshape);
             # round-trip through the compute dtype so values match what
             # kv_decode_rows hands the reference path
-            ks = ks_ref[0]  # [ps, n_blocks] f32
+            ks = ks_ref[0]  # [ps, hkv] f32
             vs = vs_ref[0]
             k = (kq_ref[0].astype(jnp.float32) * ks[..., None])
             v = (vq_ref[0].astype(jnp.float32) * vs[..., None])
-            k = k.reshape(page_size, hkv, d).astype(out_dtype)
-            v = v.reshape(page_size, hkv, d).astype(out_dtype)
+            k = k.astype(out_dtype)  # [ps, hkv, d]
+            v = v.astype(out_dtype)
         else:
             k = k_ref[0]  # [ps, hkv, d]
             v = v_ref[0]
@@ -435,26 +437,22 @@ def _paged_kernel(
             jax.lax.broadcasted_iota(jnp.int32, (n_q, page_size), 1)
             + j * page_size
         )
-        allowed = kpos <= pos_rows[:, None]
+        allowed = kpos <= pos_rows
         if verify:
             allowed = jnp.logical_and(allowed, kpos < min_pos)
         if window:
-            allowed = jnp.logical_and(
-                allowed, kpos > pos_rows[:, None] - window
-            )
+            allowed = jnp.logical_and(allowed, kpos > pos_rows - window)
         _fold_block(k, v, allowed)
 
     if verify:
 
         @pl.when(j == nj - 1)
         def _fold_inflight():
-            kpos_in = jnp.stack(
-                [pos_ref[b, i] for i in range(c)]
-            )  # [C] int32 — the chunk positions themselves
-            allowed = kpos_in[None, :] <= pos_rows[:, None]  # [n_q, C]
+            kpos_in = inpos_ref[0]  # [1, C] — the chunk positions
+            allowed = kpos_in <= pos_rows  # [n_q, C]
             if window:
                 allowed = jnp.logical_and(
-                    allowed, kpos_in[None, :] > pos_rows[:, None] - window
+                    allowed, kpos_in > pos_rows - window
                 )
             _fold_block(ink_ref[0], inv_ref[0], allowed)
 
@@ -467,6 +465,33 @@ def _paged_kernel(
             o_ref[0, :, kh * groups:(kh + 1) * groups, :] = out.astype(
                 o_ref.dtype
             )
+
+
+# Mosaic's scoped-VMEM default on a v5e; decode and verify stay inside it
+_SCOPED_VMEM_DEFAULT = 16 * 1024 * 1024
+
+
+def _vmem_limit(c, h, hkv, n_q, d, itemsize, verify):
+    """``vmem_limit_bytes`` for one call: None (the compiler's default)
+    while the call's blocks and scratch fit it, else their bytes plus
+    half again for the compiler's own temporaries. The q/out blocks and
+    the per-head (m, l, acc) scratch grow with C·H·D: compiled for a
+    v5e at 25 heads x 64 in bf16, C=256 fits the default, C=512 needs
+    32-40 MiB (this asks for 53) and C=1024 80-96 MiB (106). A chip
+    that cannot give what is asked refuses at compile time, in words."""
+
+    def pad(n, m):
+        return -(-n // m) * m
+
+    lanes = pad(d, 128)
+    rows = 32 // itemsize  # sublanes a tile holds: 8 of f32, 16 of bf16
+    scratch = hkv * pad(n_q, 8) * (128 + 128 + lanes) * 4
+    blocks = 2 * c * pad(h, rows) * lanes * itemsize  # q and out
+    if verify:
+        blocks += 2 * c * pad(hkv, rows) * lanes * itemsize
+    blocks += pad(n_q, 8) * 128 * 4  # row positions
+    need = int(1.5 * (scratch + 2 * blocks))  # blocks are double-buffered
+    return need if need > _SCOPED_VMEM_DEFAULT else None
 
 
 def _paged_call(q, pools, tables, positions, *, scale, window, kv_heads,
@@ -495,6 +520,11 @@ def _paged_call(q, pools, tables, positions, *, scale, window, kv_heads,
     # clamp the table read in every index map so it stays in bounds
     jw = w - 1
     q_spec = pl.BlockSpec((1, c, h, d), lambda i, j, tab, pos: (i, 0, 0, 0))
+    # (c, g)-major row positions as a VMEM column, and the slot's
+    # (first, last) position as the only per-query SMEM scalars
+    row_pos = jnp.repeat(positions, groups, axis=1)[..., None]
+    rowpos_spec = pl.BlockSpec((1, n_q, 1), lambda i, j, tab, pos: (i, 0, 0))
+    span = jnp.stack([positions[:, 0], positions[:, -1]], axis=1)
     if mode == "bf16":
         pool_args = (pools["k"], pools["v"])
         pool_specs = [
@@ -508,6 +538,11 @@ def _paged_call(q, pools, tables, positions, *, scale, window, kv_heads,
         ]
     else:
         nb, blk = pools["k_q"].shape[-2:]
+        if (nb, blk) != (hkv, d):
+            raise ValueError(
+                f"int8 pools with {nb} scale blocks of {blk} per row: the "
+                f"fused kernel needs one block per kv head ({hkv} x {d})"
+            )
         pool_args = (pools["k_q"], pools["k_scale"],
                      pools["v_q"], pools["v_scale"])
         qspec = pl.BlockSpec(
@@ -529,17 +564,17 @@ def _paged_call(q, pools, tables, positions, *, scale, window, kv_heads,
     if verify:
         if extra_k is None or extra_v is None:
             raise ValueError("verify variant needs extra_k/extra_v rows")
-        extra_args = (extra_k, extra_v)
+        extra_args = (extra_k, extra_v, positions[:, None, :])
         extra_specs = [
             pl.BlockSpec((1, c, hkv, d),
                          lambda i, j, tab, pos: (i, 0, 0, 0))
             for _ in range(2)
-        ]
+        ] + [pl.BlockSpec((1, 1, c), lambda i, j, tab, pos: (i, 0, 0))]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, w + 1) if verify else (b, w),
-        in_specs=[q_spec] + pool_specs + extra_specs,
+        in_specs=[rowpos_spec, q_spec] + pool_specs + extra_specs,
         out_specs=pl.BlockSpec((1, c, h, d),
                                lambda i, j, tab, pos: (i, 0, 0, 0)),
         scratch_shapes=[
@@ -552,7 +587,10 @@ def _paged_call(q, pools, tables, positions, *, scale, window, kv_heads,
         None
         if interpret
         else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(
+                c, h, hkv, n_q, d, q.dtype.itemsize, verify
+            ),
         )
     )
     out = pl.pallas_call(
@@ -561,7 +599,7 @@ def _paged_call(q, pools, tables, positions, *, scale, window, kv_heads,
         out_shape=jax.ShapeDtypeStruct((b, c, h, d), q.dtype),
         compiler_params=compiler_params,
         interpret=interpret,
-    )(tables, positions, q, *pool_args, *extra_args)
+    )(tables, span, row_pos, q, *pool_args, *extra_args)
     return out
 
 
@@ -596,7 +634,7 @@ def paged_attention(
     rejected draft row leaves no trace in page storage.
     """
     interpret = INTERPRET if interpret is None else interpret
-    if pltpu is None or not (_on_tpu() or interpret):
+    if pltpu is None or not (device.on_tpu() or interpret):
         return paged_attention_reference(
             q, pools, block_tables, positions, scale=scale, window=window,
             kv_heads=kv_heads, max_pages=max_pages, variant=variant,

@@ -23,9 +23,8 @@ from typing import Dict, Optional, Sequence
 import jax
 import numpy as np
 from jax.experimental import mesh_utils
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
-from dlrover_tpu.common.jax_compat import mesh_axis_types_kwargs
 from dlrover_tpu.common.log import get_logger
 
 logger = get_logger(__name__)
@@ -141,7 +140,7 @@ def build_mesh(
     mesh = Mesh(
         dev_array,
         MESH_AXES,
-        **mesh_axis_types_kwargs(len(MESH_AXES)),
+        axis_types=(AxisType.Auto,) * len(MESH_AXES),
     )
     logger.info("built mesh %s over %d devices", sizes, len(devices))
     return mesh
@@ -152,7 +151,7 @@ def single_device_mesh(device: Optional[jax.Device] = None) -> Mesh:
     return Mesh(
         np.asarray([device]).reshape((1,) * len(MESH_AXES)),
         MESH_AXES,
-        **mesh_axis_types_kwargs(len(MESH_AXES)),
+        axis_types=(AxisType.Auto,) * len(MESH_AXES),
     )
 
 

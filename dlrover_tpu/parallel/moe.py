@@ -282,7 +282,6 @@ def moe_block(
 
 
 def _moe_block_alltoall(x, moe, cfg, mesh, rng, fp8=None):
-    from dlrover_tpu.common.jax_compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     ep = mesh.shape["ep"]
@@ -335,7 +334,7 @@ def _moe_block_alltoall(x, moe, cfg, mesh, rng, fp8=None):
         }
         return out, aux
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -473,7 +472,6 @@ def _moe_block_ragged(x, moe, cfg, mesh=None, rng=None):
     if mesh.shape.get("ep", 1) > 1:
         return _moe_block_ragged_a2a(x, moe, cfg, mesh, rng)
 
-    from dlrover_tpu.common.jax_compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     token_axes = ("dp", "fsdp")
@@ -504,7 +502,7 @@ def _moe_block_ragged(x, moe, cfg, mesh=None, rng=None):
         )
         return out.reshape(bl, sl, d), aux
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -543,7 +541,6 @@ def _moe_block_ragged_a2a(x, moe, cfg, mesh, rng):
     Layout: tokens sharded over (dp, fsdp, ep); experts sharded over ep
     (each rank owns E/ep experts, all its FFN weights local).
     """
-    from dlrover_tpu.common.jax_compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     ep = mesh.shape["ep"]
@@ -672,7 +669,7 @@ def _moe_block_ragged_a2a(x, moe, cfg, mesh, rng):
         )
         return out.reshape(bl, sl, d).astype(xl.dtype), aux
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(

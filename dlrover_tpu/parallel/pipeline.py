@@ -126,16 +126,6 @@ def pipeline_apply(
     pp = mesh.shape[axis]
     if pp == 1:
         raise ValueError("pipeline_apply requires a pp axis > 1")
-    from dlrover_tpu.common import jax_compat
-
-    if not jax_compat.PARTIAL_MANUAL_PIPELINE:
-        # fail in Python rather than let the 0.4.x SPMD partitioner
-        # CHECK-abort the whole process mid-compile
-        raise NotImplementedError(
-            "pipeline parallelism needs a jax whose partitioner supports "
-            "manual subgroups (jax >= 0.5); this install would abort "
-            "during compilation"
-        )
     v = max(1, int(interleave))
     b_global = x.shape[0]
     m = num_microbatches or pp
@@ -242,11 +232,8 @@ def pipeline_apply(
             jnp.zeros(xs.shape[1:], bdt),
             jnp.zeros(xs.shape, jnp.float32),
         )
-        if hasattr(jax.lax, "pcast"):
-            # newer jax tracks varying-manual-axes types; mark the carry
-            # as varying over pp up front (older jax has no vma typing
-            # and needs no cast)
-            init = jax.lax.pcast(init, (axis,), to="varying")
+        # the carry varies over pp from the first tick on
+        init = jax.lax.pcast(init, (axis,), to="varying")
         (_, outs), _ = jax.lax.scan(
             step, init, jnp.arange(m * v + pp - 1)
         )
@@ -256,10 +243,8 @@ def pipeline_apply(
         outs = jax.lax.psum(outs, axis)
         return outs.swapaxes(0, 1).reshape(x_all.shape)
 
-    from dlrover_tpu.common.jax_compat import shard_map
-
     layer_specs = jax.tree.map(lambda _: P(axis), layers)
-    out = shard_map(
+    out = jax.shard_map(
         local,
         mesh=mesh,
         axis_names={axis},

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from dlrover_tpu.common.jax_compat import shard_map
+from dlrover_tpu.common import device
 
 from dlrover_tpu.ops.attention import _repeat_kv, mha_reference
 
@@ -120,7 +120,7 @@ def ulysses_attention(
     if prefix_len is not None:
         args = args + (prefix_len,)
         in_specs = in_specs + (P(("dp", "fsdp")),)
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=in_specs,
@@ -392,7 +392,7 @@ def ring_attention(
         bq = pa._fit_block(sq, block_q)
         bk = pa._fit_block(k.shape[1], block_k)
         use_flash = (
-            pa.pltpu is not None and pa._on_tpu() and bq and bk
+            pa.pltpu is not None and device.on_tpu() and bq and bk
         )
         if not use_flash:
             # the jnp block path needs matched heads; the flash kernel
@@ -450,7 +450,7 @@ def ring_attention(
     if prefix_len is not None:
         args = args + (prefix_len,)
         in_specs = in_specs + (P(("dp", "fsdp")),)
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=in_specs,

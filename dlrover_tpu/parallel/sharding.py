@@ -18,9 +18,7 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from dlrover_tpu.common import jax_compat
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 MeshAxes = Union[None, str, Tuple[str, ...]]
 
@@ -110,12 +108,23 @@ def constrain(x, mesh: Mesh, *logical_axes: Optional[str], rules=None):
         return x
     rules = rules_for_mesh(mesh, rules)
     spec = logical_to_mesh_axes(logical_axes, rules)
-    manual = jax_compat.manual_axis_names()
+    manual = manual_axis_names()
     if manual:
         am = jax.sharding.get_abstract_mesh()
         spec = P(*[_drop_axes(entry, set(manual)) for entry in spec])
         return jax.lax.with_sharding_constraint(x, NamedSharding(am, spec))
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+
+
+def manual_axis_names() -> frozenset:
+    """Mesh axes currently under manual control (inside a ``shard_map``);
+    empty at top level."""
+    am = jax.sharding.get_abstract_mesh()
+    return frozenset(
+        name
+        for name, t in zip(am.axis_names, am.axis_types)
+        if t == AxisType.Manual
+    )
 
 
 def _drop_axes(entry: MeshAxes, names: set) -> MeshAxes:
@@ -232,11 +241,10 @@ class CommConfig:
 # ---------------------------------------------------------------------------
 
 # Trace-time marker for "model code is being traced inside the
-# update-sharding shard_map". jax 0.4.x cannot tell us we are inside a
-# manual region (jax_compat.manual_axis_names() is pinned empty there),
-# so the train step raises this flag around the shard_map body trace:
-# `constrain` turns into a no-op and the tied-embedding head read routes
-# through the cotangent-splitting alias below.
+# update-sharding shard_map": the train step raises this flag around the
+# shard_map body trace, `constrain` turns into a no-op and the
+# tied-embedding head read routes through the cotangent-splitting alias
+# below.
 _REGION = threading.local()
 
 

@@ -106,6 +106,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from dlrover_tpu.common import device
 from dlrover_tpu.models import decoder, generate
 from dlrover_tpu.observability.tracing import get_tracer
 from dlrover_tpu.ops import pallas_paged, quant
@@ -353,7 +354,7 @@ class ServingEngine:
             )
 
         # buffer donation is a no-op (with a warning) on the CPU backend
-        donate = (1,) if jax.default_backend() != "cpu" else ()
+        donate = () if device.on_cpu() else (1,)
 
         if paged:
 

@@ -11,13 +11,13 @@ Two storage modes share one geometry:
 - ``bf16`` — reference mode: pages hold the model compute dtype
   verbatim, so a gather reproduces a contiguous ``decoder.init_kv_cache``
   buffer bitwise (the parity baseline).
-- ``int8`` — pages hold int8 payloads + per-block f32 scales using the
-  same EQuARX-style max/127 block encode as the gradient wire
-  (``ops/quant.py`` ``kv_encode_rows``), dequantized per-page INSIDE the
-  jitted decode step. A token row of ``kv_heads*head_dim`` bf16 elements
-  (2 bytes each) becomes ``row`` int8 bytes + ``row/kv_block`` f32
-  scales — ≥1.7× resident-bytes reduction at every real shape (1.94× at
-  the tiny row=128, 1.97× at llama rows).
+- ``int8`` — pages hold int8 payloads + one f32 scale per (token, kv
+  head) using the same EQuARX-style max/127 block encode as the
+  gradient wire (``ops/quant.py`` ``kv_encode_rows``), dequantized
+  per-page INSIDE the jitted decode step. A token row of
+  ``kv_heads*head_dim`` bf16 elements (2 bytes each) becomes ``row``
+  int8 bytes + ``kv_heads`` f32 scales — a 2d/(d+4) resident-bytes
+  reduction at head_dim d (1.88× at 64, 1.94× at 128).
 
 Physical page 0 is the TRASH page: never allocated, the write target
 for masked-out lanes (inactive slots, prefill-chunk padding). Gathers
@@ -94,7 +94,6 @@ def make_geometry(
     if mode not in ("bf16", "int8"):
         raise ValueError(f"mode must be bf16|int8, got {mode}")
     max_pages = -(-max_len // page_size)
-    row = cfg.kv_heads * cfg.head_dim
     return PageGeometry(
         n_layers=cfg.n_layer,
         kv_heads=cfg.kv_heads,
@@ -104,7 +103,10 @@ def make_geometry(
         max_pages_per_slot=max_pages,
         mode=mode,
         dtype=str(cfg.dtype),
-        kv_block=quant.kv_block_size(row),
+        # one scale per (token, kv head): the paged kernel dequantizes a
+        # [page, heads, head_dim] tile against [page, heads] scales with
+        # no cross-lane reshape, at any head count (25 x 64 included)
+        kv_block=cfg.head_dim,
     )
 
 

@@ -23,6 +23,7 @@ import contextlib
 import threading
 import time
 
+from dlrover_tpu.common import compile_cache
 from dlrover_tpu.observability.tracing import get_tracer
 from dlrover_tpu.serving.engine import ServingEngine
 from dlrover_tpu.serving.scheduler import (
@@ -90,6 +91,8 @@ class GenerationServer:
     def start(self) -> "GenerationServer":
         if self.alive:
             return self
+        # a replica coming back finds the steps it compiled before
+        compile_cache.enable_compile_cache()
         self._stop_evt.clear()
         self._thread = threading.Thread(
             target=self._loop, name=f"serving-{self.replica}", daemon=True

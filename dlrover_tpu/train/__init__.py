@@ -18,5 +18,6 @@ from dlrover_tpu.train.train_step import (  # noqa: F401
     TrainStepBuilder,
     batch_sharding,
     init_train_state,
+    restore_or_init_train_state,
     state_shardings,
 )

@@ -522,12 +522,8 @@ def streamed_offload_adamw(
     """
     from dlrover_tpu.ops.quant import adamw_direction, adamw_moments
 
-    from dlrover_tpu.common import jax_compat
-
-    # None on jax builds without jax.memory: device_put(x, None) is then
-    # a no-op placement, which matches the CPU-backend aliasing note above
-    _host = jax_compat.HOST_MEMORY
-    _dev = jax_compat.DEVICE_MEMORY
+    _host = jax.memory.Space.Host
+    _dev = jax.memory.Space.Device
 
     def _lr(step):
         return learning_rate(step) if callable(learning_rate) else learning_rate

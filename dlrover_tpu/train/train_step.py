@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dlrover_tpu.common import jax_compat
+from dlrover_tpu.common import device
 from dlrover_tpu.models import decoder
 from dlrover_tpu.models.config import ModelConfig
 from dlrover_tpu.observability import sentinels as snt
@@ -38,8 +38,8 @@ TrainState = Dict[str, Any]  # {"params", "opt_state", "step"}
 # custom op and no separate optimizer implementation needed. (On the CPU
 # backend the Host space aliases device memory — a harmless no-op that
 # keeps the same code path testable on the virtual mesh.)
-_HOST = jax_compat.HOST_MEMORY
-_DEVICE = jax_compat.DEVICE_MEMORY
+_HOST = jax.memory.Space.Host
+_DEVICE = jax.memory.Space.Device
 
 
 def _to_memory_kind(tree, kind):
@@ -360,7 +360,7 @@ def abstract_train_state(
             "compiler-chosen shardings the AOT path cannot reproduce"
         )
     rep = NamedSharding(mesh, P())
-    if offload_opt_state and jax.default_backend() != "cpu":
+    if offload_opt_state and not device.on_cpu():
         opt_sh = _opt_state_host_shardings(
             opt_abs, params_abs, param_shardings, mesh
         )
@@ -433,10 +433,28 @@ def init_train_state(
     ``resolve_update_sharding``); pass the SAME comm the step builder
     resolved (``TrainStepBuilder.comm_resolved``) so state layout and
     step agree.
+
+    The leaves carry the shardings of ``state_shardings`` as that spells
+    them (``out_shardings``, not whatever the compiler derives: it drops
+    mesh axes of size one, so ``P('tp', 'fsdp')`` comes back as ``P()``
+    from a one-chip mesh). The jitted step lowers its arguments'
+    shardings into the program text, and the text is the persistent
+    compile cache's key: an initialised state, the abstract template
+    and a state restored into the template must all spell alike, or a
+    worker restarted after a crash recompiles the step it compiled
+    before.
     """
     param_shardings = shd.shardings_for_tree(
         mesh, decoder.logical_axes(cfg), rules
     )
+    try:
+        out_sh = state_shardings(
+            cfg, mesh, optimizer, rules, offload_opt_state, comm
+        )
+    except NotImplementedError:
+        # low-bit optimizer state: its innards keep the compiler's
+        # shardings (see abstract_train_state), and so does the rest
+        out_sh = None
     us_active, _, plan = resolve_update_sharding(
         cfg, mesh, optimizer, comm, offload_opt_state=offload_opt_state
     )
@@ -466,7 +484,7 @@ def init_train_state(
                 state["fp8"] = decoder.init_fp8_states(cfg)
             return state
 
-        return jax.jit(f_us)(rng)
+        return jax.jit(f_us, out_shardings=out_sh)(rng)
     # optimizer-state leaves (Adam moments etc.) mirror param shapes and
     # must be born with the SAME shardings — otherwise every step starts
     # by involuntarily resharding the moments (XLA's "involuntary full
@@ -505,8 +523,8 @@ def init_train_state(
             state["fp8"] = decoder.init_fp8_states(cfg)
         return state
 
-    if not (offload_opt_state and jax.default_backend() != "cpu"):
-        return jax.jit(f)(rng)
+    if not (offload_opt_state and not device.on_cpu()):
+        return jax.jit(f, out_shardings=out_sh)(rng)
 
     # offload: the moments must be BORN in host memory — a post-jit
     # transfer would still hit the fully-resident HBM peak, which is
@@ -524,20 +542,77 @@ def init_train_state(
         # materialize HBM-resident (the point of offloading)
         return optimizer.init(params)
 
-    params = jax.jit(f_params)(rng)
+    params = jax.jit(f_params, out_shardings=param_shardings)(rng)
     opt_shape = jax.eval_shape(f_opt, params)
-    out_sh = _opt_state_host_shardings(
+    opt_sh = _opt_state_host_shardings(
         opt_shape, params, param_shardings, mesh
     )
-    opt_state = jax.jit(f_opt, out_shardings=out_sh)(params)
+    opt_state = jax.jit(f_opt, out_shardings=opt_sh)(params)
+    rep = NamedSharding(mesh, P())
     state = {
         "params": params,
         "opt_state": opt_state,
-        "step": jnp.zeros([], jnp.int32),
+        "step": jax.device_put(jnp.zeros([], jnp.int32), rep),
     }
     if cfg.fp8 and mesh.shape.get("pp", 1) == 1:
-        state["fp8"] = jax.jit(lambda: decoder.init_fp8_states(cfg))()
+        state["fp8"] = jax.jit(
+            lambda: decoder.init_fp8_states(cfg), out_shardings=rep
+        )()
     return state
+
+
+def restore_or_init_train_state(
+    checkpointer,
+    rng: jax.Array,
+    cfg: ModelConfig,
+    mesh: Mesh,
+    optimizer: optax.GradientTransformation,
+    rules=None,
+    offload_opt_state: bool = False,
+    comm: Optional[shd.CommConfig] = None,
+    step: Optional[int] = None,
+) -> Tuple[TrainState, bool]:
+    """The state a (re)started worker trains from, and whether it came
+    out of a checkpoint: ``checkpointer``'s newest (or ``step``'s)
+    checkpoint restored into the abstract template, else a fresh
+    ``init_train_state``.
+
+    Restore comes BEFORE init, never after: an initialised state beside
+    the restored one is the train state twice, and a recipe sized to
+    the chip (GPT-2 XL with bf16 AdamW is 9.5 GB of a v5e's 16) does
+    not have the room. Either way the leaves spell their shardings as
+    ``state_shardings`` does, so the step compiled before a crash is
+    found again in the compile cache after it.
+    """
+    def init():
+        return init_train_state(
+            rng, cfg, mesh, optimizer, rules, offload_opt_state, comm
+        )
+
+    state = None
+    try:
+        template = abstract_train_state(
+            cfg, mesh, optimizer, rules, offload_opt_state, comm
+        )
+    except NotImplementedError:
+        # a low-bit optimizer state has no abstract template (its
+        # shardings are the compiler's to choose): this one alone
+        # restores beside a fresh state and needs the room for both
+        state = init()
+        template = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=x.sharding
+            ),
+            state,
+        )
+    restored = checkpointer.load_checkpoint(
+        template,
+        shardings=jax.tree.map(lambda a: a.sharding, template),
+        step=step,
+    )
+    if restored is not None:
+        return restored, True
+    return (init() if state is None else state), False
 
 
 class TrainStepBuilder:
@@ -603,26 +678,6 @@ class TrainStepBuilder:
             if self.update_sharding and len(self._plan.mesh_axes) > 1
             else None
         )
-        if (
-            offload_opt_state
-            and _HOST is None
-            and jax.default_backend() != "cpu"
-        ):
-            raise RuntimeError(
-                "offload_opt_state needs the jax.memory.Space API; "
-                "this jax build has no host memory space"
-            )
-        if cfg.remat in ("offload_attn", "save_qkv_offload"):
-            from dlrover_tpu.common import jax_compat
-
-            if not jax_compat.supports_activation_offload():
-                # fail at builder construction, not deep in the remat
-                # trace of the first step
-                raise RuntimeError(
-                    f"remat={cfg.remat!r} needs checkpoint_policies."
-                    "save_and_offload_only_these_names, which this jax "
-                    "build lacks; use save_qkv or full instead"
-                )
         # switch-gating jitter needs a per-step rng; only the built-in
         # loss_fn accepts one (a custom loss_fn owns its rng handling)
         self._needs_rng = (
@@ -811,7 +866,18 @@ class TrainStepBuilder:
         else:
             batch_spec = P("dp")
 
+        def vary(tree):
+            # params enter the region replicated (unvarying over dp).
+            # Differentiating an unvarying input makes jax all-reduce
+            # its cotangent; marking it varying keeps each rank's
+            # gradient local, for exchange() to reduce-scatter. Fresh
+            # scan carries need the same mark to match their outputs.
+            return jax.tree.map(
+                lambda x: jax.lax.pcast(x, ("dp",), to="varying"), tree
+            )
+
         def local_grads(params, f8, mb):
+            params, f8 = vary(params), vary(f8)
             mask = mb.get("mask")
             if mask is None:
                 mask = jnp.ones_like(mb["targets"], dtype=jnp.float32)
@@ -839,7 +905,7 @@ class TrainStepBuilder:
 
             nf8 = None
             if tie:
-                z = jnp.zeros(plan.shapes[0], jnp.float32)
+                z = vary(jnp.zeros(plan.shapes[0], jnp.float32))
                 if f8 is not None:
                     (loss, metrics), (g, gz, nf8) = jax.value_and_grad(
                         lf, argnums=(0, 1, 2), has_aux=True
@@ -925,7 +991,7 @@ class TrainStepBuilder:
                     None if f8 is None else jax.tree.map(jnp.zeros_like, f8),
                 )
                 (g_acc, gz_acc, loss_acc, nf8), _ = jax.lax.scan(
-                    micro, init, batch
+                    micro, vary(init), batch
                 )
                 shards = exchange(g_acc, gz_acc)
                 loc = {"loss": loss_acc}
@@ -956,7 +1022,7 @@ class TrainStepBuilder:
                 )
                 (shards, loss_acc, nf8), _ = jax.lax.scan(
                     micro,
-                    (zeros, jnp.zeros([], jnp.float32), f8_zero),
+                    vary((zeros, jnp.zeros([], jnp.float32), f8_zero)),
                     batch,
                 )
                 loc = {"loss": loss_acc}
@@ -1009,7 +1075,7 @@ class TrainStepBuilder:
             # partial-manual: dp is manual (the explicit psum_scatter /
             # psum collectives), fsdp/tp stay with the auto partitioner
             sm_kwargs["axis_names"] = {"dp"}
-        metrics, grads_flat, new_fp8 = jax_compat.shard_map(
+        metrics, grads_flat, new_fp8 = jax.shard_map(
             region,
             mesh=mesh,
             in_specs=(P(), P(), batch_spec),
@@ -1053,11 +1119,14 @@ class TrainStepBuilder:
                 fp_shard + u, "dp", axis=1, tiled=True
             )
 
-        new_flat = jax_compat.shard_map(
+        new_flat = jax.shard_map(
             apply_region,
             mesh=mesh,
             in_specs=(P(), P(None, "dp")),
             out_specs=P(),
+            # the tiled all_gather IS replicated over dp, but the public
+            # collective types its result as varying
+            check_vma=False,
         )(flat_params["flat"], updates["flat"])
         params = shd.unpack_flat(new_flat, state["params"], plan)
         if zoo:

@@ -38,6 +38,7 @@ from dlrover_tpu.train.train_step import (
     batch_sharding,
     build_eval_step,
     init_train_state,
+    restore_or_init_train_state,
 )
 
 logger = get_logger(__name__)
@@ -300,30 +301,49 @@ class Trainer:
         return self._ckpt
 
     def _init_state(self):
-        if self._init_state_fn is not None:
-            self.state = self._init_state_fn(
-                jax.random.key(self.args.seed)
+        args = self.args
+        if (
+            args.resume
+            and not args.resume_partial
+            and self._init_state_fn is None
+        ):
+            # restore BEFORE init: a fresh state beside the restored one
+            # is the train state twice in HBM
+            self.state, resumed = restore_or_init_train_state(
+                self.checkpointer,
+                jax.random.key(args.seed),
+                self.cfg,
+                self.mesh,
+                self.optimizer,
+                comm=self._builder.comm_resolved,
+                step=args.resume_from_step,
             )
+            if resumed:
+                logger.info("resumed from step %d", int(self.state["step"]))
+            return
+        if self._init_state_fn is not None:
+            self.state = self._init_state_fn(jax.random.key(args.seed))
         else:
             self.state = init_train_state(
-                jax.random.key(self.args.seed),
+                jax.random.key(args.seed),
                 self.cfg,
                 self.mesh,
                 self.optimizer,
                 comm=self._builder.comm_resolved,
             )
-        if not self.args.resume:
+        if not args.resume:
             return
         from dlrover_tpu.checkpoint.checkpointer import state_template
 
-        # partial restore needs the LIVE state (missing leaves keep its
-        # fresh values); the exact-match path uses the abstract template
+        # a caller's own init function gives no abstract template, and
+        # a partial restore needs the LIVE state (missing leaves keep
+        # its fresh values): these two restore beside the fresh state
         restored = self.checkpointer.load_checkpoint(
-            self.state if self.args.resume_partial
+            self.state if args.resume_partial
             else state_template(self.state),
             shardings=jax.tree.map(lambda x: x.sharding, self.state),
-            step=self.args.resume_from_step,
-            partial=self.args.resume_partial,
+            step=args.resume_from_step,
+            partial=args.resume_partial,
         )
         if restored is not None:
             self.state = restored

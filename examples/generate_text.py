@@ -31,23 +31,18 @@ def main():
     args = p.parse_args()
 
     from dlrover_tpu.checkpoint import Checkpointer
-    from dlrover_tpu.checkpoint.checkpointer import state_template
     from dlrover_tpu.models import generate, get_config
     from dlrover_tpu.parallel import MeshConfig, build_mesh
-    from dlrover_tpu.train import init_train_state, make_optimizer
+    from dlrover_tpu.train import make_optimizer, restore_or_init_train_state
 
     cfg = get_config(args.model)
     mesh = build_mesh(MeshConfig(dp=-1))
     opt = make_optimizer(learning_rate=1e-3)
-    state = init_train_state(jax.random.key(0), cfg, mesh, opt)
-
     ckpt = Checkpointer(args.ckpt_dir, use_agent=False)
-    restored = ckpt.load_checkpoint(
-        state_template(state),
-        shardings=jax.tree.map(lambda x: x.sharding, state),
+    state, restored = restore_or_init_train_state(
+        ckpt, jax.random.key(0), cfg, mesh, opt
     )
-    if restored is not None:
-        state = restored
+    if restored:
         print(f"[generate] restored step {int(state['step'])}")
     else:
         print("[generate] no checkpoint found; sampling from init")

@@ -40,8 +40,8 @@ from dlrover_tpu.parallel import sharding as shd
 from dlrover_tpu.train import (
     TrainStepBuilder,
     batch_sharding,
-    init_train_state,
     make_optimizer,
+    restore_or_init_train_state,
     state_shardings,
 )
 from dlrover_tpu.train.data_utils import form_global_batch, iter_shards_spmd
@@ -256,20 +256,16 @@ def main():
             data_replicas_fn=lambda: ctx["mesh"].shape["dp"],
         )
         run_step = trainer.step
-        state = init_train_state(
-            jax.random.key(0), cfg, mesh, opt,
-            comm=ctx["builder"].comm_resolved,
-        )
     else:
         run_step = build_step(1)
-        state = init_train_state(jax.random.key(0), cfg, mesh, opt)
     ckpt = Checkpointer(args.ckpt_dir, master_client=client)
-    restored = ckpt.load_checkpoint(
-        state_template(state),
-        shardings=jax.tree.map(lambda x: x.sharding, state),
+    # restore first, initialise only if nothing answers: a fresh state
+    # beside a restored one is the train state twice in HBM
+    state, resumed = restore_or_init_train_state(
+        ckpt, jax.random.key(0), cfg, mesh, opt,
+        comm=ctx["builder"].comm_resolved,
     )
-    if restored is not None:
-        state = restored
+    if resumed:
         print(f"[worker] resumed from step {int(state['step'])}", flush=True)
     # SPMD: one shard = one GLOBAL step (batch rows × processes); rank 0
     # fetches from the master and broadcasts so all processes stay in

@@ -226,7 +226,7 @@ def test_device_context_probe():
     """Capability probe (atorch device_context.py:10 analog): coherent
     facts on the test platform, cached, and consistent with the
     analyser's HBM sizing."""
-    from dlrover_tpu.accelerate.analyser import device_hbm_bytes
+    from dlrover_tpu.common.device import device_memory_bytes
     from dlrover_tpu.accelerate.device_context import (
         detect_device_context,
         fp8_supported,
@@ -235,7 +235,7 @@ def test_device_context_probe():
     ctx = detect_device_context()
     assert ctx.platform == "cpu" and not ctx.on_tpu
     assert ctx.n_devices == 8  # the virtual test mesh
-    assert ctx.hbm_bytes == device_hbm_bytes()  # single source of truth
+    assert ctx.hbm_bytes == device_memory_bytes()  # single source of truth
     assert not ctx.supports_fp8 and not fp8_supported()
     assert detect_device_context() is ctx  # lru-cached singleton
 

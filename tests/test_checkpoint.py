@@ -201,6 +201,51 @@ def test_checkpointer_memory_then_load(tmp_path):
     )
 
 
+def test_restored_state_lowers_the_step_as_an_initialised_one_does(tmp_path):
+    """A worker restarted after a crash must find the step it compiled
+    before in the persistent cache, and the cache's key is the lowered
+    program's text: the initialised state, the abstract template and a
+    state restored into the template have to lower alike. The compiler
+    spells a sharding over size-one mesh axes as ``P()``, the rules
+    spell it ``P('tp', 'fsdp')`` — restore also comes before init, so
+    nothing is ever held twice."""
+    from dlrover_tpu.models import get_config
+    from dlrover_tpu.parallel.mesh import single_device_mesh
+    from dlrover_tpu.train import (
+        TrainStepBuilder,
+        make_optimizer,
+        restore_or_init_train_state,
+    )
+    from dlrover_tpu.train.train_step import abstract_train_state
+
+    cfg = get_config(
+        "tiny", n_layer=2, d_model=64, d_ff=128, n_head=2, vocab_size=256,
+        max_seq=32,
+    )
+    mesh = single_device_mesh()
+    opt = make_optimizer(learning_rate=1e-3)
+    ckpt = Checkpointer(str(tmp_path / "ckpt"), use_agent=False)
+
+    def start():
+        return restore_or_init_train_state(
+            ckpt, jax.random.key(0), cfg, mesh, opt
+        )
+
+    fresh, resumed = start()
+    assert not resumed
+    assert ckpt.save_checkpoint(7, fresh, StorageType.MEMORY)
+    restored, resumed = start()
+    assert resumed and int(restored["step"]) == int(fresh["step"])
+    tok = jax.ShapeDtypeStruct((2, 32), jnp.int32)
+    batch = {"tokens": tok, "targets": tok}
+    step = TrainStepBuilder(cfg, mesh, opt).build()
+    texts = {
+        step.lower(state, batch).as_text()
+        for state in (fresh, restored, abstract_train_state(cfg, mesh, opt))
+    }
+    assert len(texts) == 1
+
+
 def test_agent_saver_flow(tmp_path):
     """Worker stages via shm IPC; agent daemon persists + commits."""
     from dlrover_tpu.checkpoint.saver import AsyncCheckpointSaver

@@ -275,11 +275,15 @@ def test_pallas_paged_importers_are_interpret_units_or_slow():
     kernel unit files (``test_pallas*``) are serving integration tests:
     they drive jitted decode loops over page pools, which belongs in
     the slow tier. The interpret-mode unit files stay in tier-1 — they
-    are the cheap CPU-executable coverage of the kernel bodies."""
+    are the cheap CPU-executable coverage of the kernel bodies — and so
+    does ``test_tpu_compile.py``, which compiles the kernels for a
+    described chip and runs nothing."""
     rogue = []
     for path in sorted(_TESTS.glob("*.py")):
         if path.name.startswith("test_pallas"):
             continue  # interpret-mode kernel unit files
+        if path.name == "test_tpu_compile.py":
+            continue  # compile-only kernel unit file
         tree = ast.parse(path.read_text(), filename=str(path))
         if not _imports_pallas_paged(tree) or _module_slow_marked(tree):
             continue

@@ -62,6 +62,39 @@ def test_event_callback_registry_fires_hooks(master):
     assert master.job_manager.get_node(0).status == NodeStatus.FAILED
 
 
+def test_step_heartbeat_carries_the_workers_device_report(master):
+    """The worker holds the chips, so it — through the step heartbeat
+    every worker already sends — is the master's only source of device
+    kind, chip count and HBM use; the agent's host-only reports in
+    between must not read as an empty HBM."""
+    import jax
+
+    from dlrover_tpu.agent.monitor import ResourceMonitor, holds_devices
+
+    devices = jax.local_devices()  # this process is a worker: backend up
+    assert holds_devices()
+    records = []
+    master.telemetry_hub.subscribe(records.append, ("ResourceRecord",))
+    client = MasterClient(master.addr, node_id=0)
+    client.register_node(local_chips=1)  # what the agent was told
+    assert client.report_global_step(1)
+    assert client.report_global_step(2)  # inside the interval: no second
+    assert ResourceMonitor(client).report_once()  # the agent's, host-only
+    deadline = time.time() + 5
+    while len(records) < 2 and time.time() < deadline:
+        time.sleep(0.05)
+    worker, agent = records
+    assert worker.tpu_type == devices[0].device_kind
+    assert worker.local_chips == len(devices)
+    assert (agent.tpu_type, agent.local_chips, agent.hbm_mb) == ("", 0, 0.0)
+    res = master.job_manager.get_node(0).config_resource
+    assert (res.tpu_type, res.tpu_chips) == (
+        devices[0].device_kind, len(devices),
+    )
+    # only the worker's reading reached the HBM gauges
+    assert master.metric_collector.gauges["hbm_used_mb"] == worker.hbm_mb
+
+
 def test_task_reschedule_callback_requeues_shards(master):
     """A dead node's in-flight shard goes back to the queue through the
     registry's TaskRescheduleCallback (no inline master plumbing)."""

@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from dlrover_tpu.common import device
 from dlrover_tpu.ops.attention import mha_reference
 from dlrover_tpu.parallel import MeshConfig, build_mesh
 from dlrover_tpu.parallel.sequence import ring_attention, ulysses_attention
@@ -198,7 +199,7 @@ def test_ring_window_flash_path(monkeypatch):
     if pa.pltpu is None:
         pytest.skip("pallas TPU module unavailable")
     monkeypatch.setattr(pa, "INTERPRET", True)
-    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
     mesh = build_mesh(MeshConfig(sp=4, dp=2))
     b, s, h, d = 2, 1024, 2, 32  # 256-wide ring blocks
     ks = jax.random.split(jax.random.key(13), 3)
@@ -238,7 +239,7 @@ def test_ring_window_flash_path_gqa(monkeypatch):
     if pa.pltpu is None:
         pytest.skip("pallas TPU module unavailable")
     monkeypatch.setattr(pa, "INTERPRET", True)
-    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
     mesh = build_mesh(MeshConfig(sp=4, dp=2))
     b, s, hq, hkv, d = 2, 1024, 4, 2, 32
     ks = jax.random.split(jax.random.key(21), 3)
@@ -298,7 +299,7 @@ def test_ring_prefix_flash_path(monkeypatch):
     if pa.pltpu is None:
         pytest.skip("pallas TPU module unavailable")
     monkeypatch.setattr(pa, "INTERPRET", True)
-    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
     mesh = build_mesh(MeshConfig(sp=2, dp=4))
     b, s, h, d = 4, 512, 4, 32
     ks = jax.random.split(jax.random.key(7), 3)
@@ -349,7 +350,7 @@ def test_ring_attention_flash_path_matches_reference(monkeypatch):
     if pa.pltpu is None:
         pytest.skip("pallas TPU module unavailable: flash path untestable")
     monkeypatch.setattr(pa, "INTERPRET", True)
-    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
     # _fit_block needs 128-multiples: S=512 over sp=2 → 256-local blocks
     mesh = build_mesh(MeshConfig(sp=2, dp=4))
     b, s, h, d = 4, 512, 4, 32

@@ -427,14 +427,17 @@ def test_partial_gather_bitwise_equals_sliced_full(mode):
 
 
 def test_resident_bytes_reduction_vs_bf16():
-    for d_model, n_head in ((32, 4), (64, 4), (128, 8)):
+    # one f32 scale per (token, kv head): 2d / (d + 4) at head_dim d —
+    # 1.88x at GPT-2 XL's 64-wide heads, 1.94x at 128
+    for d_model, n_head in ((1600, 25), (256, 4), (2048, 16)):
         cfg = _cfg(d_model=d_model, n_head=n_head)
         g8 = kvc.make_geometry(
             cfg, n_slots=2, max_len=32, page_size=8, mode="int8"
         )
+        assert g8.kv_block == cfg.head_dim
         g16 = g8._replace(mode="bf16")
         ratio = kvc.resident_bytes(g16) / kvc.resident_bytes(g8)
-        assert ratio >= 1.7, (d_model, ratio)
+        assert ratio >= 1.85, (d_model, ratio)
 
 
 def test_decode_traffic_model_asymptotics():

@@ -201,7 +201,15 @@ def test_metrics_sink_projects_gauges_and_counters():
     ))
     assert c.gauges["overlap_drift_us"] == 30.0
     assert c.gauges["overlap_drift_frac"] == pytest.approx(0.3)
-    sink.emit(telemetry.ResourceRecord(hbm_mb=100.0, hbm_peak_mb=140.0))
+    sink.emit(telemetry.ResourceRecord(
+        hbm_mb=100.0, hbm_peak_mb=140.0, tpu_type="TPU v5 lite",
+        local_chips=4,
+    ))
+    assert c.gauges["hbm_peak_mb"] == 140.0
+    # the agent's host-only report has no device half: it must not
+    # read as "HBM is empty"
+    sink.emit(telemetry.ResourceRecord(cpu_percent=50.0, mem_mb=1e3))
+    assert c.gauges["hbm_used_mb"] == 100.0
     assert c.gauges["hbm_peak_mb"] == 140.0
 
 

@@ -37,7 +37,6 @@ import optax
 import pytest
 
 from bench import collective_stats
-from dlrover_tpu.common import jax_compat
 from dlrover_tpu.models.config import get_config
 from dlrover_tpu.parallel import sharding as shd
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
@@ -377,7 +376,7 @@ def test_packplan_roundtrip_sharded_leaves(seed):
     # in_specs may only name the manual axes ({"dp"}); the fsdp
     # shardings ride along on the values through the auto partitioner
     f = jax.jit(
-        jax_compat.shard_map(
+        jax.shard_map(
             region,
             mesh=mesh,
             in_specs=({k: P("dp") for k in tree},),
