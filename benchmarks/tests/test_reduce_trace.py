@@ -1,0 +1,143 @@
+"""The trace reduction on a hand-built trace with known answers: busy
+union, idle share, self time under nesting, Pallas share, exposed
+collective time, gap attribution, and that host planes never count as
+device time."""
+
+import pytest
+
+from benchmarks.lib import trace
+
+US = 1000.0  # the trace is in nanoseconds; the test thinks in microseconds
+
+KERNEL = (
+    "%closed_call.3 = (bf16[8,2,128,64]{3,2,1,0:T(8,128)(2,1)}) "
+    "custom-call(bf16[8,2,128,64]{3,2,1,0} %q), "
+    'custom_call_target="tpu_custom_call"'
+)
+FUSION = (
+    "%fusion.7 = bf16[8,128,256]{2,1,0:T(8,128)(2,1)S(1)} "
+    "fusion(bf16[8,128,256]{2,1,0} %x), kind=kOutput"
+)
+WHILE = "%while.1 = (s32[]{:T(128)}, bf16[8,128]{1,0}) while((s32[]) %t)"
+AR_START = "%all-reduce-start.2 = f32[1000]{0} all-reduce-start(f32[1000]{0} %g)"
+AR_DONE = "%all-reduce-done.2 = f32[1000]{0} all-reduce-done(f32[1000]{0} %s)"
+
+
+def _ev(name, start_us, dur_us):
+    return (name, start_us * US, dur_us * US)
+
+
+def _device(n, shift_us=0.0):
+    # window 0..1000 us. while 100..700 holds fusion 100..300, kernel
+    # 300..500, fusion 500..650, the all-reduce's start op 650..660 and
+    # fusion 660..700; the all-reduce is in flight 650..900 and its
+    # done op waits 800..900; a last fusion 900..950. Busy: 100..700, 800..950 = 750 us. Idle gaps: 0..100,
+    # 700..800, 950..1000.
+    ops = [
+        _ev(WHILE, 100 + shift_us, 600),
+        _ev(FUSION, 100 + shift_us, 200),
+        _ev(KERNEL, 300 + shift_us, 200),
+        _ev(FUSION, 500 + shift_us, 150),
+        _ev(AR_START, 650 + shift_us, 10),
+        _ev(FUSION, 660 + shift_us, 40),
+        _ev(AR_DONE, 800 + shift_us, 100),
+        _ev(FUSION, 900 + shift_us, 50),
+    ]
+    return {
+        "name": f"/device:TPU:{n}",
+        "lines": [
+            {"name": "Steps", "events": [_ev("0", 100, 850)]},
+            {"name": "XLA Modules", "events": [_ev("jit_step", 100, 850)]},
+            {"name": "XLA Ops", "events": ops},
+            {"name": "Async XLA Ops",
+             "events": [_ev(AR_START, 650 + shift_us, 250),
+                        _ev("%copy-start.1 = (f32[1]{0}) copy-start(f32[1] %a)",
+                            0, 1000)]},
+        ],
+    }
+
+
+HOST = {
+    "name": "/host:CPU",
+    "lines": [{
+        "name": "python3",
+        "events": [
+            _ev("bench.traced_window", 0, 1000),
+            _ev("bench.input", 0, 90),
+            _ev("bench.dispatch", 90, 20),
+            _ev("bench.readback", 110, 890),
+            # a host event as long as the window: never device time
+            _ev("$array.py:297 __float__", 0, 1000),
+        ],
+    }],
+}
+
+
+def test_parse_op():
+    assert trace.parse_op(FUSION) == ("fusion.7", "fusion", "bf16[8,128,256]")
+    assert trace.parse_op(KERNEL)[1] == "custom-call"
+    assert trace.parse_op(WHILE)[:2] == ("while.1", "while")
+    assert trace.parse_op("all-gather.4") == ("all-gather.4", "all-gather", "")
+    assert trace.is_pallas(KERNEL) and not trace.is_pallas(FUSION)
+    assert trace.is_collective(AR_START) and trace.is_collective(AR_DONE)
+    assert not trace.is_collective(FUSION)
+    assert not trace.is_collective("%copy-start.1 = (f32[1]{0}) copy-start(f32[1] %a)")
+
+
+def test_interval_arithmetic():
+    u = trace.union([(0, 10), (5, 20), (30, 40), (40, 45), (50, 50)])
+    assert u == [(0, 20), (30, 45)]
+    assert trace.measure(u) == 35
+    assert trace.subtract([(0, 100)], u) == [(20, 30), (45, 100)]
+    assert trace.subtract(u, [(10, 35)]) == [(0, 10), (35, 45)]
+    assert trace.subtract(u, []) == u
+
+
+def test_one_device_known_answers():
+    r = trace.reduce([HOST, _device(0)], window_span="bench.traced_window")
+    assert r["devices"] == 1
+    assert r["window_s"] == pytest.approx(1000e-6)
+    assert r["busy_s"] == pytest.approx(750e-6)
+    # the kernel's own 200 us of 750 busy; the while adds nothing: its
+    # 600 us are all its children's
+    assert r["pallas_s"] == pytest.approx(200e-6)
+    ops = dict(r["device_ops"])
+    assert ops["fusion.7 fusion bf16[8,128,256]"] == pytest.approx(440e-6)
+    assert ops.get(trace.label(WHILE), 0.0) == pytest.approx(0.0, abs=1e-12)
+    # in flight 650..900; compute (leaf, not collective) covers
+    # 660..700 of it; 650..660 is the start op itself, a collective.
+    # Exposed: 650..660 and 700..900.
+    assert r["collective_s"] == pytest.approx(250e-6)
+    assert r["collective_exposed_s"] == pytest.approx(210e-6)
+    gaps = dict(r["idle_gaps"])
+    # a gap goes whole to the host span that covers most of it: 0..100
+    # to input (90 of it; dispatch has 10), 700..800 and 950..1000 to
+    # readback
+    assert gaps["bench.input"] == pytest.approx(100e-6)
+    assert "bench.dispatch" not in gaps
+    assert gaps["bench.readback"] == pytest.approx(150e-6)
+    assert sum(gaps.values()) == pytest.approx(250e-6)
+
+
+def test_devices_are_averaged_and_host_is_not_device_time():
+    # the second device runs 50 us later: busy 150..750 and 850..1000,
+    # 750 us as well, all inside the window
+    r = trace.reduce(
+        [HOST, _device(0), _device(1, shift_us=50)],
+        window_span="bench.traced_window",
+    )
+    assert r["devices"] == 2
+    assert r["busy_s"] == pytest.approx(750e-6)
+    assert [d["plane"] for d in r["per_device"]] == [
+        "/device:TPU:0", "/device:TPU:1"
+    ]
+    # no window span: first op start to last op end on each device
+    r = trace.reduce([_device(0)])
+    assert r["window_s"] == pytest.approx(850e-6)
+    assert r["busy_s"] == pytest.approx(750e-6)
+
+
+def test_no_device_plane_gives_nothing():
+    assert trace.reduce([HOST], window_span="bench.traced_window") is None
+    empty = {"name": "/device:TPU:0", "lines": [{"name": "XLA Ops", "events": []}]}
+    assert trace.reduce([HOST, empty]) is None
