@@ -19,7 +19,8 @@ agent's async persist is a raw copy.
 import dataclasses
 import json
 import math
-from typing import Any, Callable, Dict, List, Optional, Tuple
+import time
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
@@ -154,6 +155,7 @@ def write_pack(
     entries: List[LeafEntry],
     extra: Dict = None,
     header: Optional[bytes] = None,
+    phases: Optional[Dict[str, float]] = None,
 ) -> int:
     """Write header + all shard payloads into ``buf``; returns bytes used.
 
@@ -161,7 +163,16 @@ def write_pack(
     consumed — overlapping DMA with serialization. Pass the ``header``
     already computed for sizing to avoid re-serializing the (potentially
     large) leaf manifest under the checkpoint lock.
+
+    The two things this does are timed apart, summed over the leaves:
+    ``shm_copy`` (copying a shard that is on the host into ``buf``) and
+    ``d2h_wait`` (all the rest: starting the copies and waiting for each
+    shard to arrive). Each becomes one span, ``ckpt.d2h_wait`` then
+    ``ckpt.shm_copy`` laid end to end from the start, and their seconds
+    are added to ``phases``.
     """
+    clock = time.monotonic
+    t_start = clock()
     if header is None:
         header = header_bytes(step, entries, extra)
     n = len(header)
@@ -170,24 +181,40 @@ def write_pack(
     start = payload_start(header)
 
     leaves = [leaf for _, leaf in jax.tree_util.tree_flatten_with_path(state)[0]]
-    with get_tracer().span("ckpt.write_pack", step=step, leaves=len(leaves)):
-        # kick off async D2H for everything first
-        for leaf in leaves:
-            if hasattr(leaf, "copy_to_host_async"):
-                leaf.copy_to_host_async()
-        used = start
-        for leaf, entry in zip(leaves, entries):
-            shards = _replica0_shards(leaf)
-            for shard, sentry in zip(shards, entry.shards):
-                data = np.asarray(shard.data)
-                raw = np.ascontiguousarray(data).view(np.uint8).reshape(-1)
-                lo = start + sentry.offset
-                hi = lo + sentry.nbytes
-                # direct buffer-protocol assignment: .tobytes() would copy
-                # through an intermediate bytes object (measured ~9x slower
-                # for large shards — this is the staging hot loop)
-                buf[lo:hi] = raw
-                used = max(used, hi)
+    # kick off async D2H for everything first
+    for leaf in leaves:
+        if hasattr(leaf, "copy_to_host_async"):
+            leaf.copy_to_host_async()
+    used = start
+    copy_s = 0.0
+    for leaf, entry in zip(leaves, entries):
+        shards = _replica0_shards(leaf)
+        for shard, sentry in zip(shards, entry.shards):
+            data = np.asarray(shard.data)  # waits for the shard
+            t0 = clock()
+            raw = np.ascontiguousarray(data).view(np.uint8).reshape(-1)
+            lo = start + sentry.offset
+            hi = lo + sentry.nbytes
+            # direct buffer-protocol assignment: .tobytes() would copy
+            # through an intermediate bytes object (measured ~9x slower
+            # for large shards — this is the staging hot loop)
+            buf[lo:hi] = raw
+            copy_s += clock() - t0
+            used = max(used, hi)
+    wait_s = clock() - t_start - copy_s
+    tracer = get_tracer()
+    nbytes = used - start
+    tracer.complete_span(
+        "ckpt.d2h_wait", t_start, dur_s=wait_s, step=step, nbytes=nbytes,
+        leaves=len(leaves),
+    )
+    tracer.complete_span(
+        "ckpt.shm_copy", t_start + wait_s, dur_s=copy_s, step=step,
+        nbytes=nbytes,
+    )
+    if phases is not None:
+        phases["d2h_wait"] = phases.get("d2h_wait", 0.0) + wait_s
+        phases["shm_copy"] = phases.get("shm_copy", 0.0) + copy_s
     return used
 
 
@@ -320,6 +347,7 @@ def restore_tree(
     pack_index: PackIndex,
     shardings: Any = None,
     partial: bool = False,
+    phases: Optional[Dict[str, float]] = None,
 ) -> Any:
     """Build a pytree of (sharded) jax arrays matching ``target``'s structure.
 
@@ -333,6 +361,12 @@ def restore_tree(
     The target must then carry CONCRETE arrays (the freshly initialized
     live state, not a ShapeDtypeStruct template) so there is a value to
     keep; an abstract target with a missing leaf still raises.
+
+    Returns once the arrays are on the device. Three phases, each summed
+    over the leaves, are child spans of ``ckpt.restore_tree`` and are
+    added to ``phases``: ``read`` (assembling each slice from the pack
+    on the host), ``h2d`` (handing it to the device) and ``device_wait``
+    (the wait, at the end, for the copies to land).
     """
     leaves_with_path, treedef = jax.tree_util.tree_flatten_with_path(target)
     shard_leaves = (
@@ -346,8 +380,35 @@ def restore_tree(
         leaves=len(leaves_with_path),
         resharded=shardings is not None,
     )
+    try:
+        return _restore_leaves(
+            leaves_with_path, treedef, shard_leaves, pack_index, partial,
+            phases, restore_span,
+        )
+    except BaseException:
+        # a mismatch records nothing: only completed restores land on
+        # the timeline
+        restore_span.cancel()
+        raise
+
+
+def _restore_leaves(
+    leaves_with_path, treedef, shard_leaves, pack_index, partial, phases,
+    restore_span,
+):
     out = []
     kept = []
+    clock = time.monotonic
+    t_start = clock()
+    read_s = 0.0
+
+    def read(path, index, dtype):
+        nonlocal read_s
+        t0 = clock()
+        got = pack_index.read_slice(path, index).astype(dtype, copy=False)
+        read_s += clock() - t0
+        return got
+
     for (path, leaf), sharding in zip(leaves_with_path, shard_leaves):
         pstr = _path_str(path)
         if pstr not in pack_index._meta:
@@ -394,19 +455,15 @@ def restore_tree(
         # heap a step or two after an in-place resume. Alignment of
         # np.empty is luck-of-the-malloc, so the crash is flaky.
         if sharding is None:
-            full = pack_index.read_slice(
-                pstr, tuple(slice(0, d) for d in gshape)
-            )
-            # astype copy=False: a no-op when the pack already matches
-            # the target dtype; jnp.array then makes the owned copy
-            out.append(jax.numpy.array(full.astype(dtype, copy=False)))
+            # astype copy=False (in read): a no-op when the pack already
+            # matches the target dtype; jnp.array makes the owned copy
+            full = read(pstr, tuple(slice(0, d) for d in gshape), dtype)
+            out.append(jax.numpy.array(full))
         else:
             arr = jax.make_array_from_callback(
                 gshape,
                 sharding,
-                lambda idx, p=pstr, dt=dtype: pack_index.read_slice(
-                    p, idx
-                ).astype(dt, copy=False),
+                lambda idx, p=pstr, dt=dtype: read(p, idx, dt),
             )
             # device-to-device copy off the aliased callback shards;
             # jit keeps the sharding and works on multi-host globals
@@ -421,7 +478,18 @@ def restore_tree(
             len(kept),
             kept[0],
         )
-    # mismatch raises above leave the span un-ended, which records
-    # nothing — only completed restores land on the timeline
+    t_issued = clock()
+    out = jax.block_until_ready(out)
+    h2d_s = t_issued - t_start - read_s
+    wait_s = clock() - t_issued
+    tracer = get_tracer()
+    tracer.complete_span("ckpt.restore_read", t_start, dur_s=read_s)
+    tracer.complete_span("ckpt.restore_h2d", t_start + read_s, dur_s=h2d_s)
+    tracer.complete_span("ckpt.restore_device_wait", t_issued, dur_s=wait_s)
+    if phases is not None:
+        for name, seconds in (
+            ("read", read_s), ("h2d", h2d_s), ("device_wait", wait_s),
+        ):
+            phases[name] = phases.get(name, 0.0) + seconds
     restore_span.end(kept=len(kept))
     return jax.tree_util.tree_unflatten(treedef, out)

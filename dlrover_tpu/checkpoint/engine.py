@@ -8,10 +8,11 @@ thread in standalone mode), so a worker crash after staging never loses the
 checkpoint — the agent still holds the bytes.
 """
 
+import contextlib
 import os
 import threading
 import time
-from typing import Any, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 
@@ -30,6 +31,21 @@ from dlrover_tpu.observability import telemetry
 from dlrover_tpu.observability.tracing import get_tracer
 
 logger = get_logger(__name__)
+
+
+@contextlib.contextmanager
+def _phase(phases: Dict[str, float], name: str, **args):
+    """One phase of a save or a restore: a child span ``ckpt.<name>``
+    of whatever span is open, and its seconds added to ``phases``
+    (kept with tracing off too, for the ``CheckpointRecord``)."""
+    t0 = time.perf_counter()
+    with get_tracer().span("ckpt." + name, **args):
+        try:
+            yield
+        finally:
+            phases[name] = (
+                phases.get(name, 0.0) + time.perf_counter() - t0
+            )
 
 
 def shm_name(process_index: Optional[int] = None) -> str:
@@ -73,30 +89,89 @@ class CheckpointEngine:
     # ---- save ------------------------------------------------------------
 
     def save_to_memory(self, step: int, state: Any) -> bool:
-        """Stage ``state`` into shared memory. Returns False if skipped."""
-        t0 = time.perf_counter()
-        entries, payload = core.plan_pack(state)
-        header = core.header_bytes(step, entries, {"dir": self.ckpt_dir})
-        total = core.pack_size(header, payload)
+        """Stage ``state`` into shared memory. Returns False if skipped.
 
-        if not self._acquire(blocking=False):
+        The stall is one span, ``ckpt.save_memory``, whose children are
+        its phases: ``ckpt.plan``, ``ckpt.lock_wait``, ``ckpt.shm_alloc``
+        and, from ``core.write_pack``, ``ckpt.d2h_wait`` and
+        ``ckpt.shm_copy``. The same seconds go onto the
+        ``CheckpointRecord`` and the log line. A save that is skipped or
+        raises records no ``ckpt.save_memory``."""
+        t0 = time.perf_counter()
+        phases: Dict[str, float] = {}
+        stage_span = get_tracer().span("ckpt.save_memory", step=step)
+        try:
+            staged = self._stage(step, state, phases)
+        except BaseException:
+            stage_span.cancel()
+            raise
+        if staged is None:
             # saver busy persisting the previous step: skip this save
             # (reference: engine.py:53 check_all_rank_ready skip path)
+            stage_span.cancel()
             logger.warning("step %d: saver busy, skipping memory save", step)
             return False
-        stage_span = get_tracer().span(
-            "ckpt.save_memory", step=step, nbytes=total
+        total, meta = staged
+        stage_span.end(nbytes=total)
+        stall_s = time.perf_counter() - t0
+        hub = telemetry.get_hub()
+        if hub.enabled:
+            hub.publish(
+                telemetry.CheckpointRecord(
+                    kind="save_memory",
+                    step=step,
+                    seconds=stall_s,
+                    nbytes=total,
+                    tier="memory",
+                    phases=telemetry.format_phases(phases),
+                )
+            )
+        if self._replica is not None:
+            # stream the fresh pack to ring peers off the critical path
+            # (reference: replica.py backup hooked at engine.py:328)
+            self._replica.backup_async(meta, shm_lock=self._lock)
+        if self._client is not None:
+            try:
+                self._client.report_ckpt_step(step)
+            except Exception:  # noqa: BLE001
+                logger.warning("ckpt step report failed", exc_info=True)
+        logger.info(
+            "staged step %d to shm in %.3fs (%.1f MB): %s",
+            step,
+            stall_s,
+            total / 1e6,
+            " ".join(f"{k} {v:.3f}s" for k, v in phases.items()),
         )
+        return True
+
+    def _stage(
+        self, step: int, state: Any, phases: Dict[str, float]
+    ) -> Optional[Tuple[int, Dict]]:
+        """The phases of a memory save; the pack's bytes and the meta
+        entry it published, or None when the saver holds the lock."""
+        with _phase(phases, "plan", step=step):
+            entries, payload = core.plan_pack(state)
+            header = core.header_bytes(
+                step, entries, {"dir": self.ckpt_dir}
+            )
+            total = core.pack_size(header, payload)
+        with _phase(phases, "lock_wait", step=step):
+            locked = self._acquire(blocking=False)
+        if not locked:
+            return None
         try:
             if self._shm is None or self._shm.size < total:
-                name = shm_name()
-                self._shm = create_shared_memory(name, _round_up(total))
+                with _phase(phases, "shm_alloc", step=step, nbytes=total):
+                    self._shm = create_shared_memory(
+                        shm_name(), _round_up(total)
+                    )
             used = core.write_pack(
                 memoryview(self._shm.buf),
                 step,
                 state,
                 entries,
                 header=header,
+                phases=phases,
             )
             meta = {
                 "step": step,
@@ -114,34 +189,7 @@ class CheckpointEngine:
             self._local_step = step
         finally:
             self._release()
-            stage_span.end()
-        hub = telemetry.get_hub()
-        if hub.enabled:
-            hub.publish(
-                telemetry.CheckpointRecord(
-                    kind="save_memory",
-                    step=step,
-                    seconds=stage_span.dur_us / 1e6,
-                    nbytes=total,
-                    tier="memory",
-                )
-            )
-        if self._replica is not None:
-            # stream the fresh pack to ring peers off the critical path
-            # (reference: replica.py backup hooked at engine.py:328)
-            self._replica.backup_async(meta, shm_lock=self._lock)
-        if self._client is not None:
-            try:
-                self._client.report_ckpt_step(step)
-            except Exception:  # noqa: BLE001
-                logger.warning("ckpt step report failed", exc_info=True)
-        logger.info(
-            "staged step %d to shm in %.3fs (%.1f MB)",
-            step,
-            time.perf_counter() - t0,
-            total / 1e6,
-        )
-        return True
+        return total, meta
 
     def save_to_storage(self, step: int, state: Any) -> bool:
         """Stage + trigger async persist."""
@@ -242,11 +290,15 @@ class CheckpointEngine:
         # "failover." prefix: restore is a phase of the recovery timeline,
         # so the drill's phase extraction picks it up with the rest
         span = get_tracer().span("failover.restore")
+        # seconds by phase, over the tiers tried: restore_map (attach or
+        # mmap the pack, parse its header), then core.restore_tree's
+        # read, h2d and device_wait
+        phases: Dict[str, float] = {}
         with span:
             tier = "none"
             try:
                 state = self._load_from_memory(
-                    target, shardings, step, partial
+                    target, shardings, step, partial, phases
                 )
                 if state is not None:
                     tier = "memory"
@@ -256,7 +308,7 @@ class CheckpointEngine:
             if state is None:
                 try:
                     state = self._load_from_replica(
-                        target, shardings, step, partial
+                        target, shardings, step, partial, phases
                     )
                     if state is not None:
                         tier = "replica"
@@ -265,17 +317,19 @@ class CheckpointEngine:
                     state = None
             if state is None:
                 state = self.load_from_storage(
-                    target, shardings, step, partial
+                    target, shardings, step, partial, phases
                 )
                 if state is not None:
                     tier = "storage"
             span.args["tier"] = tier
             if state is None and mismatch is not None:
                 raise mismatch
-        self._publish_restore(tier, span.end())
+        self._publish_restore(tier, span.end(), phases)
         return state
 
-    def _publish_restore(self, tier: str, seconds: float):
+    def _publish_restore(
+        self, tier: str, seconds: float, phases: Dict[str, float]
+    ):
         hub = telemetry.get_hub()
         if hub.enabled:
             hub.publish(
@@ -285,10 +339,14 @@ class CheckpointEngine:
                     seconds=seconds,
                     ok=tier != "none",
                     tier=tier,
+                    phases=telemetry.format_phases(phases),
                 )
             )
 
-    def _load_from_memory(self, target, shardings, step, partial=False):
+    def _load_from_memory(
+        self, target, shardings, step, partial=False, phases=None
+    ):
+        phases = {} if phases is None else phases
         try:
             meta = self._meta.get("latest")
             if not meta:
@@ -305,14 +363,17 @@ class CheckpointEngine:
                         min_step,
                     )
                     return None
-            shm = attach_shared_memory(meta["shm"])
+            with _phase(phases, "restore_map", nbytes=meta["used"]):
+                shm = attach_shared_memory(meta["shm"])
             idx = core.PackIndex()
             try:
-                idx.add_pack(memoryview(shm.buf)[: meta["used"]])
-                state = core.restore_tree(target, idx, shardings, partial=partial)
+                with _phase(phases, "restore_map", nbytes=meta["used"]):
+                    idx.add_pack(memoryview(shm.buf)[: meta["used"]])
+                # returns once everything is on the device
+                state = core.restore_tree(
+                    target, idx, shardings, partial=partial, phases=phases
+                )
                 step = idx.step
-                # restore_tree copied everything to device
-                state = jax.block_until_ready(state)
             finally:
                 # release the views on every path so the segment can
                 # close without 'exported pointers exist' GC noise
@@ -331,7 +392,9 @@ class CheckpointEngine:
             logger.warning("memory restore failed", exc_info=True)
             return None
 
-    def _load_from_replica(self, target, shardings, step, partial=False):
+    def _load_from_replica(
+        self, target, shardings, step, partial=False, phases=None
+    ):
         """Local shm lost (host replaced): pull our pack from a ring peer.
 
         Reference: engine.py:349 _restore_memory_from_replica.
@@ -360,7 +423,8 @@ class CheckpointEngine:
                     idx = core.PackIndex()
                     idx.add_pack(memoryview(pack))
                     state = core.restore_tree(
-                        target, idx, shardings, partial=partial
+                        target, idx, shardings, partial=partial,
+                        phases=phases,
                     )
                 except core.RestoreMismatchError:
                     raise  # tree-contract violation: load() decides the fate
@@ -385,7 +449,10 @@ class CheckpointEngine:
             logger.warning("replica restore failed", exc_info=True)
             return None
 
-    def load_from_storage(self, target, shardings=None, step=None, partial=False):
+    def load_from_storage(
+        self, target, shardings=None, step=None, partial=False, phases=None
+    ):
+        phases = {} if phases is None else phases
         from dlrover_tpu.checkpoint.storage import read_tracker
 
         step = step if step is not None else read_tracker(
@@ -402,10 +469,13 @@ class CheckpointEngine:
         ]
         if not packs:
             return None
-        for name in packs:
-            mv = self._storage.mmap(os.path.join(step_dir, name))
-            idx.add_pack(mv)
-        state = core.restore_tree(target, idx, shardings, partial=partial)
+        with _phase(phases, "restore_map"):
+            for name in packs:
+                mv = self._storage.mmap(os.path.join(step_dir, name))
+                idx.add_pack(mv)
+        state = core.restore_tree(
+            target, idx, shardings, partial=partial, phases=phases
+        )
         logger.info("restored step %d from %s", step, step_dir)
         return state
 

@@ -73,7 +73,11 @@ class ElasticTrainer:
             effective,
         )
         self._replicas = replicas
-        self._step_fn = self._build_step(self.grad_accum)
+        try:
+            self._step_fn = self._build_step(self.grad_accum)
+        except BaseException:
+            replan_span.cancel()
+            raise
         seconds = replan_span.end(grad_accum=self.grad_accum)
         hub = telemetry.get_hub()
         if hub.enabled:
@@ -144,7 +148,11 @@ class ElasticTrainer:
             1, math.ceil(self.global_batch_size / per_step)
         )
         self._replicas = replicas
-        self._step_fn = self._build_step(self.grad_accum)
+        try:
+            self._step_fn = self._build_step(self.grad_accum)
+        except BaseException:
+            span.cancel()
+            raise
         seconds = span.end(grad_accum=self.grad_accum)
         hub = telemetry.get_hub()
         if hub.enabled:

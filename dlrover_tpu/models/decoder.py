@@ -463,44 +463,47 @@ def _layer_body(
     rope=None,
 ):
     ln1, ln2 = layer["ln1"], layer["ln2"]
-    h = _norm_block(x, ln1, cfg)
-    attn = _attention_block(
-        h, layer, cfg, mesh, positions, attn_fn, fp8=fp8, rope=rope
-    )
-    if tag_attn_out:
-        # non-flash attention tags no flash_out/flash_lse, so save_attn
-        # would otherwise pin nothing and recompute O(S²) attention
-        attn = _tag_residual(attn, "attn_out", cfg)
+    with jax.named_scope("attn"):
+        h = _norm_block(x, ln1, cfg)
+        attn = _attention_block(
+            h, layer, cfg, mesh, positions, attn_fn, fp8=fp8, rope=rope
+        )
+        if tag_attn_out:
+            # non-flash attention tags no flash_out/flash_lse, so
+            # save_attn would otherwise pin nothing and recompute O(S²)
+            # attention
+            attn = _tag_residual(attn, "attn_out", cfg)
     aux = {
         "moe_lb_loss": jnp.zeros([], jnp.float32),
         "moe_z_loss": jnp.zeros([], jnp.float32),
     }
-    if cfg.parallel_residual:
-        # GPTNeoX-style: both branches read the LAYER INPUT —
-        # x + attn(ln1 x) + mlp(ln2 x); the attn and mlp matmul chains
-        # have no data dependence, so XLA can overlap them
-        h2 = _norm_block(x, ln2, cfg)
-    else:
-        # fused path: the residual add rides in the norm kernel —
-        # x + attn is written once, from the same VMEM visit that
-        # computes the statistics
-        h2, x = _norm_block(x, ln2, cfg, residual=attn)
-    if cfg.n_experts > 0:
-        from dlrover_tpu.parallel.moe import moe_block
+    with jax.named_scope("mlp"):
+        if cfg.parallel_residual:
+            # GPTNeoX-style: both branches read the LAYER INPUT —
+            # x + attn(ln1 x) + mlp(ln2 x); the attn and mlp matmul
+            # chains have no data dependence, so XLA can overlap them
+            h2 = _norm_block(x, ln2, cfg)
+        else:
+            # fused path: the residual add rides in the norm kernel —
+            # x + attn is written once, from the same VMEM visit that
+            # computes the statistics
+            h2, x = _norm_block(x, ln2, cfg, residual=attn)
+        if cfg.n_experts > 0:
+            from dlrover_tpu.parallel.moe import moe_block
 
-        # fp8 reaches the experts as stateless current scaling (the
-        # dense/all-to-all paths; ragged stays bf16 — see moe.py);
-        # delayed-scaling state dicts cover only the attention
-        # projections in MoE layers (init_fp8_states)
-        mlp_out, aux = moe_block(
-            h2, layer["moe"], cfg, mesh, rng=rng, return_aux=True,
-            fp8=fp8,
-        )
-    else:
-        mlp_out = _mlp_block(h2, layer, cfg, mesh, fp8=fp8)
-    x = x + attn + mlp_out if cfg.parallel_residual else x + mlp_out
-    if mesh is not None:
-        x = shd.constrain(x, mesh, "batch", "seq", None)
+            # fp8 reaches the experts as stateless current scaling (the
+            # dense/all-to-all paths; ragged stays bf16 — see moe.py);
+            # delayed-scaling state dicts cover only the attention
+            # projections in MoE layers (init_fp8_states)
+            mlp_out, aux = moe_block(
+                h2, layer["moe"], cfg, mesh, rng=rng, return_aux=True,
+                fp8=fp8,
+            )
+        else:
+            mlp_out = _mlp_block(h2, layer, cfg, mesh, fp8=fp8)
+        x = x + attn + mlp_out if cfg.parallel_residual else x + mlp_out
+        if mesh is not None:
+            x = shd.constrain(x, mesh, "batch", "seq", None)
     return x, aux
 
 
@@ -812,20 +815,25 @@ def forward(
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
 
-    if _embed_lookup_hostile(
-        mesh, params["embed"]["tokens"].shape, tokens.shape
-    ):
-        x = _vocab_parallel_embed(
-            params["embed"]["tokens"], tokens, mesh
-        ).astype(dt)
-    else:
-        x = jnp.take(params["embed"]["tokens"], tokens, axis=0).astype(dt)
-    if cfg.pos == "learned":
-        x = x + jnp.take(
-            params["pos_embed"]["table"], positions, axis=0
-        ).astype(dt)
-    if mesh is not None:
-        x = shd.constrain(x, mesh, "batch", "seq", None)
+    # named scopes mark the step's layers in every HLO op_name, so a
+    # device trace can be split by layer (observability/runtime_timer)
+    with jax.named_scope("embed"):
+        if _embed_lookup_hostile(
+            mesh, params["embed"]["tokens"].shape, tokens.shape
+        ):
+            x = _vocab_parallel_embed(
+                params["embed"]["tokens"], tokens, mesh
+            ).astype(dt)
+        else:
+            x = jnp.take(
+                params["embed"]["tokens"], tokens, axis=0
+            ).astype(dt)
+        if cfg.pos == "learned":
+            x = x + jnp.take(
+                params["pos_embed"]["table"], positions, axis=0
+            ).astype(dt)
+        if mesh is not None:
+            x = shd.constrain(x, mesh, "batch", "seq", None)
 
     if attn_impl == "auto":
         if mesh is not None and mesh.shape.get("sp", 1) > 1:
@@ -919,16 +927,17 @@ def forward(
         fp8_layers=fp8_states,
     )
 
-    fn = params["final_norm"]
-    x = _norm_block(x, fn, cfg)
-    if features_only:
-        return (x, aux) if return_aux else x
-    w_out, head_scale = head_weight_scale(params, cfg)
-    logits = jnp.einsum(
-        "bsd,dv->bsv", x, w_out.astype(dt), preferred_element_type=jnp.float32
-    )
-    if head_scale != 1.0:
-        logits = logits * head_scale
+    with jax.named_scope("head_loss"):
+        x = _norm_block(x, params["final_norm"], cfg)
+        if features_only:
+            return (x, aux) if return_aux else x
+        w_out, head_scale = head_weight_scale(params, cfg)
+        logits = jnp.einsum(
+            "bsd,dv->bsv", x, w_out.astype(dt),
+            preferred_element_type=jnp.float32,
+        )
+        if head_scale != 1.0:
+            logits = logits * head_scale
     return (logits, aux) if return_aux else logits
 
 
@@ -972,13 +981,10 @@ def loss_fn(
     ``denom`` overrides the loss normalizer (default: this batch's mask
     sum). The update-sharding step passes the psum'd GLOBAL token count
     so per-rank cotangents match the data-parallel program exactly."""
-    targets = batch["targets"]
     use_fused = cfg.fused_ce and not (
         mesh is not None and mesh.shape.get("tp", 1) > 1
     )
     if use_fused:
-        from dlrover_tpu.ops.fused_ce import fused_linear_ce
-
         feats, moe_aux = forward(
             params,
             batch["tokens"],
@@ -990,13 +996,6 @@ def loss_fn(
             features_only=True,
             prefix_len=batch.get("prefix_len"),
             fp8_states=fp8_states,
-        )
-        w_out, head_scale = head_weight_scale(params, cfg)
-        bv = min(
-            cfg.ce_block_v, (cfg.vocab_size + 127) // 128 * 128
-        )
-        logz, tgt_logit, amax = fused_linear_ce(
-            feats, w_out, targets, head_scale, bv
         )
     else:
         logits, moe_aux = forward(
@@ -1010,6 +1009,32 @@ def loss_fn(
             prefix_len=batch.get("prefix_len"),
             fp8_states=fp8_states,
         )
+    with jax.named_scope("head_loss"):
+        return _loss_from_head(
+            params, batch, cfg, z_loss, denom, moe_aux,
+            feats=feats if use_fused else None,
+            logits=None if use_fused else logits,
+        )
+
+
+def _loss_from_head(
+    params, batch, cfg: ModelConfig, z_loss, denom, moe_aux,
+    feats=None, logits=None,
+):
+    """The head and the loss of ``loss_fn``: fused linear
+    cross-entropy over ``feats``, or plain log-softmax over ``logits``."""
+    targets = batch["targets"]
+    if feats is not None:
+        from dlrover_tpu.ops.fused_ce import fused_linear_ce
+
+        w_out, head_scale = head_weight_scale(params, cfg)
+        bv = min(
+            cfg.ce_block_v, (cfg.vocab_size + 127) // 128 * 128
+        )
+        logz, tgt_logit, amax = fused_linear_ce(
+            feats, w_out, targets, head_scale, bv
+        )
+    else:
         logz = jax.nn.logsumexp(logits, axis=-1)
         tgt_logit = jnp.take_along_axis(
             logits, targets[..., None], axis=-1

@@ -1,5 +1,5 @@
-"""Worker-side observability: profiler, kernel census, loss-spike, numerics,
-and the unified telemetry bus + trace spans joining them.
+"""Worker-side observability: step timer, sampled device profile, loss-spike,
+numerics, and the unified telemetry bus + trace spans joining them.
 
 TPU-native analog of the reference's xpu_timer (atorch/dev/xpu_timer —
 LD_PRELOAD CUDA hook timing GEMMs clustered by B/M/N/K and NCCL collectives,
@@ -7,9 +7,10 @@ exported via Prometheus) and of atorch/atorch/utils/{prof.py AProfiler,
 loss_spike_utils.py, numberic_checker.py}.
 
 On TPU there is nothing to LD_PRELOAD: every kernel is compiled by XLA from
-a traced program, so the census comes from the compiled HLO itself
-(exact, ahead of time) and step timing comes from host wall-clock around
-the dispatched step plus the XLA profiler for deep dives.
+a traced program, so step timing comes from host wall-clock around the
+dispatched step, and what the device did inside it from a sampled XLA
+profiler trace that the program reduces itself (``runtime_timer``), by
+kernel name and by phase of the step.
 
 The point tools publish into one stream: producers emit typed records
 onto the :class:`~dlrover_tpu.observability.telemetry.TelemetryHub` and
@@ -29,16 +30,9 @@ from dlrover_tpu.observability.numeric import (
     check_finite,
     sanitize_grads,
 )
-from dlrover_tpu.observability.profiler import (
-    KernelCensus,
-    StepTimer,
-    WorkerMetrics,
-    profile_compiled,
-    xla_trace,
-)
+from dlrover_tpu.observability.profiler import StepTimer
 from dlrover_tpu.observability.telemetry import (
     CheckpointRecord,
-    CollectiveRecord,
     ElasticEvent,
     JsonlSink,
     KernelSample,
@@ -63,17 +57,16 @@ from dlrover_tpu.observability.tracing import (
     Tracer,
     configure_tracer,
     get_tracer,
+    counters,
     merge_trace_dir,
     reset_tracer,
+    self_seconds,
+    set_counter,
     span_intervals,
 )
 
 __all__ = [
-    "KernelCensus",
     "StepTimer",
-    "WorkerMetrics",
-    "profile_compiled",
-    "xla_trace",
     "LossSpikeDetector",
     "NumericChecker",
     "GradSanitizer",
@@ -90,7 +83,6 @@ __all__ = [
     "MetricsSink",
     "MasterSink",
     "StepRecord",
-    "CollectiveRecord",
     "CheckpointRecord",
     "ElasticEvent",
     "NumericEvent",
@@ -111,4 +103,8 @@ __all__ = [
     "reset_tracer",
     "merge_trace_dir",
     "span_intervals",
+    "self_seconds",
+    # counter table
+    "set_counter",
+    "counters",
 ]

@@ -1,166 +1,17 @@
-"""Kernel census + step timing + Prometheus export.
+"""Host wall-clock step timing.
 
-Reference behaviors re-created TPU-first:
-
-- xpu_timer (atorch/dev/xpu_timer/nvidia/hook.cc, common/manager.h): hooks
-  CUDA to time GEMM launches clustered by (B, M, N, K) and NCCL collectives,
-  exported as Prometheus gauges. Here the equivalent information is read
-  from the *compiled HLO*: every dot/convolution/collective the chip will
-  run, with exact shapes, FLOPs and bytes — no interception layer needed
-  because XLA compiles the whole step ahead of time.
-- AProfiler (atorch/atorch/utils/prof.py:38): per-module FLOPs/params/
-  duration. Here ``profile_compiled`` returns FLOPs, bytes accessed and
-  peak HBM from XLA's own cost/memory analysis.
+``StepTimer`` is the Trainer's per-step clock (throughput, percentiles,
+MFU gauges). What the device did inside a step is the runtime timer's
+job (``runtime_timer.py``: a sampled device trace reduced by kernel and
+by phase); counts live in ``tracing.py``'s counter table.
 """
 
 import contextlib
-import re
-import threading
 import time
 from collections import deque
-from dataclasses import dataclass, field
-from typing import Any, Deque, Dict, List, Optional, Tuple
+from typing import Deque, Optional
 
 import jax
-
-# HLO ops we census, mapped to a short kind label.
-_COLLECTIVES = (
-    "all-reduce",
-    "all-gather",
-    "reduce-scatter",
-    "all-to-all",
-    "collective-permute",
-)
-
-_SHAPE_RE = re.compile(r"([a-z0-9]+)\[([0-9,]*)\]")
-
-
-def _parse_shape(text: str) -> Tuple[str, Tuple[int, ...]]:
-    m = _SHAPE_RE.search(text)
-    if not m:
-        return ("?", ())
-    dims = tuple(int(d) for d in m.group(2).split(",") if d)
-    return (m.group(1), dims)
-
-
-@dataclass
-class KernelRecord:
-    """One censused HLO op cluster (cf. xpu_timer's GEMM buckets)."""
-
-    kind: str  # "dot" | "convolution" | one of _COLLECTIVES
-    dtype: str
-    shape: Tuple[int, ...]  # result shape = the (B,)M,N of the GEMM bucket
-    count: int = 0
-
-
-class KernelCensus:
-    """Census of dots/convs/collectives in a compiled step function.
-
-    xpu_timer discovers GEMMs at runtime by intercepting launches; on TPU
-    the compiled HLO is the ground truth, so the census is exact and free.
-
-    Usage::
-
-        compiled = jax.jit(step).lower(state, batch).compile()
-        census = KernelCensus.from_compiled(compiled)
-        census.matmuls        # clustered dot records
-        census.collectives    # all-reduce/all-gather/... records
-        census.flops          # XLA cost-analysis total
-    """
-
-    def __init__(self, records: List[KernelRecord], cost: Dict[str, Any]):
-        self.records = records
-        self.cost = cost
-
-    @property
-    def matmuls(self) -> List[KernelRecord]:
-        return [r for r in self.records if r.kind in ("dot", "convolution")]
-
-    @property
-    def collectives(self) -> List[KernelRecord]:
-        return [r for r in self.records if r.kind in _COLLECTIVES]
-
-    @property
-    def flops(self) -> float:
-        return float(self.cost.get("flops", 0.0))
-
-    @property
-    def bytes_accessed(self) -> float:
-        return float(self.cost.get("bytes accessed", 0.0))
-
-    @classmethod
-    def from_compiled(cls, compiled) -> "KernelCensus":
-        buckets: Dict[Tuple[str, str, Tuple[int, ...]], KernelRecord] = {}
-        for module in compiled.as_text().splitlines():
-            line = module.strip()
-            # HLO instruction lines look like:  %name = bf16[8,1024]{...} dot(...)
-            m = re.match(r"%?[\w.\-]+ = (\S+) ([\w\-]+)\(", line)
-            if not m:
-                continue
-            shape_text, op = m.group(1), m.group(2)
-            # TPU backends emit async pairs (all-reduce-start/-done);
-            # count the -start and skip the -done so pairs aren't doubled
-            if op.endswith("-done"):
-                continue
-            if op.endswith("-start"):
-                op = op[: -len("-start")]
-            if op == "dot" or op == "convolution" or op in _COLLECTIVES:
-                dtype, shape = _parse_shape(shape_text)
-                key = (op, dtype, shape)
-                rec = buckets.get(key)
-                if rec is None:
-                    buckets[key] = KernelRecord(op, dtype, shape, 1)
-                else:
-                    rec.count += 1
-        try:
-            cost = compiled.cost_analysis() or {}
-            if isinstance(cost, (list, tuple)):  # older jax returns [dict]
-                cost = cost[0] if cost else {}
-        except Exception:  # cost analysis is best-effort on some backends
-            cost = {}
-        return cls(list(buckets.values()), dict(cost))
-
-    def summary(self) -> Dict[str, Any]:
-        return {
-            "num_matmul_buckets": len(self.matmuls),
-            "num_collective_buckets": len(self.collectives),
-            "flops": self.flops,
-            "bytes_accessed": self.bytes_accessed,
-        }
-
-
-def profile_compiled(fn, *args, **kwargs) -> Dict[str, Any]:
-    """AProfiler-style one-shot profile of a jittable function.
-
-    Returns flops, bytes accessed, peak HBM (when the backend reports it),
-    and the kernel census — all from compilation, without running a step.
-    """
-    compiled = jax.jit(fn).lower(*args, **kwargs).compile()
-    census = KernelCensus.from_compiled(compiled)
-    out = census.summary()
-    try:
-        mem = compiled.memory_analysis()
-        out["output_bytes"] = getattr(mem, "output_size_in_bytes", None)
-        out["temp_bytes"] = getattr(mem, "temp_size_in_bytes", None)
-        out["argument_bytes"] = getattr(mem, "argument_size_in_bytes", None)
-    except Exception:
-        pass
-    out["census"] = census
-    return out
-
-
-@contextlib.contextmanager
-def xla_trace(logdir: str):
-    """Capture an XLA/Perfetto trace for the enclosed steps.
-
-    TPU replacement for xpu_timer's timeline dump: the XLA profiler already
-    records every kernel + ICI collective with device timestamps.
-    """
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
 
 
 class StepTimer:
@@ -236,59 +87,3 @@ class StepTimer:
         if not (self.flops_per_step and self.peak_flops and self.mean_s):
             return 0.0
         return self.flops_per_step / self.mean_s / self.peak_flops
-
-
-class WorkerMetrics:
-    """Worker-local counters/gauges with a Prometheus text surface.
-
-    Duck-types the collector interface of
-    ``dlrover_tpu.master.job_metrics.MetricsHTTPServer`` so a worker can
-    expose its own scrape endpoint (xpu_timer exposes per-host brpc/bvar;
-    here it is the same tiny HTTP server the master uses).
-    """
-
-    def __init__(self, prefix: str = "dlrover_tpu_worker"):
-        self._prefix = prefix
-        self._lock = threading.Lock()
-        self._counters: Dict[str, float] = {}
-        self._gauges: Dict[str, float] = {}
-
-    def inc(self, name: str, delta: float = 1.0):
-        with self._lock:
-            self._counters[name] = self._counters.get(name, 0.0) + delta
-
-    def set_gauge(self, name: str, value: float):
-        with self._lock:
-            self._gauges[name] = float(value)
-
-    def observe_timer(self, timer: StepTimer):
-        self.set_gauge("step_time_mean_s", timer.mean_s)
-        self.set_gauge("step_time_p99_s", timer.percentile(99))
-        self.set_gauge("steps_per_second", timer.steps_per_s)
-        if timer.mfu:
-            self.set_gauge("mfu", timer.mfu)
-
-    def observe_census(self, census: KernelCensus):
-        self.set_gauge("hlo_flops_per_step", census.flops)
-        self.set_gauge("hlo_bytes_per_step", census.bytes_accessed)
-        self.set_gauge("hlo_matmul_buckets", len(census.matmuls))
-        self.set_gauge("hlo_collective_buckets", len(census.collectives))
-
-    def prometheus_text(self) -> str:
-        with self._lock:
-            lines = []
-            for name, v in sorted(self._counters.items()):
-                lines.append(f"# TYPE {self._prefix}_{name} counter")
-                lines.append(f"{self._prefix}_{name} {v}")
-            for name, v in sorted(self._gauges.items()):
-                lines.append(f"# TYPE {self._prefix}_{name} gauge")
-                lines.append(f"{self._prefix}_{name} {v}")
-            return "\n".join(lines) + "\n"
-
-    def to_json(self) -> str:
-        import json
-
-        with self._lock:
-            return json.dumps(
-                {"counters": dict(self._counters), "gauges": dict(self._gauges)}
-            )

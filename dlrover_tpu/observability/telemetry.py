@@ -1,13 +1,12 @@
 """Unified telemetry bus: typed records, pluggable sinks, one stream.
 
 The observability layer grew as disconnected point tools (StepTimer,
-KernelCensus, the runtime sampler, loss-spike/numeric checks,
-GoodputTracker) with nothing consuming them at runtime.  This module
+the runtime sampler, loss-spike/numeric checks, GoodputTracker) with nothing consuming them at runtime.  This module
 is the substrate that joins them: producers publish small, typed,
 JSON-serializable records into a :class:`TelemetryHub`; consumers
-(JSONL flight-recorder files, the Prometheus surfaces in
-``profiler.WorkerMetrics`` / ``master/job_metrics.py``, master
-reporting over the wire, the diagnosis manager) attach as sinks.
+(JSONL flight-recorder files, the Prometheus surface in
+``master/job_metrics.py``, master reporting over the wire, the
+diagnosis manager) attach as sinks.
 
 Contracts:
 
@@ -88,18 +87,6 @@ class StepRecord:
 
 
 @telemetry_record
-class CollectiveRecord:
-    """One collective class's wire traffic (planned or measured)."""
-
-    op: str = ""
-    bytes: int = 0
-    wire_dtype: str = ""
-    wire_us: float = 0.0
-    exposed_us: float = 0.0
-    ts: float = 0.0
-
-
-@telemetry_record
 class CheckpointRecord:
     """One save/restore action at any tier of the checkpoint stack."""
 
@@ -109,6 +96,11 @@ class CheckpointRecord:
     nbytes: int = 0
     ok: bool = True
     tier: str = ""  # memory | replica | storage
+    # seconds by phase of this action, "plan=0.012,d2h_wait=31.4,..."
+    # (``format_phases`` / ``parse_phases``): plan, lock_wait,
+    # shm_alloc, d2h_wait, shm_copy for a memory save; restore_map,
+    # read, h2d, device_wait for a restore; "" where there is one phase
+    phases: str = ""
     ts: float = 0.0
 
 
@@ -173,7 +165,7 @@ class PlanRecord:
 @telemetry_record
 class OverlapDriftRecord:
     """Planned exposed-collective µs vs measured (from the sampled
-    ``xla_trace``) — the signal ``config_tuner``/``brain`` consume."""
+    device trace) — the signal ``config_tuner``/``brain`` consume."""
 
     step: int = -1
     planned_exposed_us: float = 0.0
@@ -450,8 +442,7 @@ class JsonlSink:
 
 
 # gauge/counter mappings per record type for any collector duck-typing
-# inc(name)/set_gauge(name, value) — WorkerMetrics on the worker,
-# JobMetricCollector on the master.
+# inc(name)/set_gauge(name, value) — JobMetricCollector on the master.
 _GAUGE_MAP: Dict[str, List[Tuple[str, str]]] = {
     "StepRecord": [
         ("telemetry_step_time_s", "step_time_s"),
@@ -558,8 +549,8 @@ class MetricsSink:
     """Project records onto a Prometheus-style collector.
 
     ``collector`` is duck-typed: anything with ``inc(name)`` and
-    ``set_gauge(name, value)`` (``profiler.WorkerMetrics`` worker-side,
-    ``master.job_metrics.JobMetricCollector`` master-side).
+    ``set_gauge(name, value)``
+    (``master.job_metrics.JobMetricCollector``).
     """
 
     def __init__(self, collector):
@@ -760,12 +751,27 @@ def plan_record_from_overlap(
     )
 
 
-_COLLECTIVE_MARKERS = (
+def format_phases(phases: Dict[str, float]) -> str:
+    """Seconds by phase as a record's scalar field."""
+    return ",".join(f"{k}={v:.6f}" for k, v in phases.items())
+
+
+def parse_phases(text: str) -> Dict[str, float]:
+    return {
+        k: float(v)
+        for k, _, v in (part.partition("=") for part in text.split(",") if part)
+    }
+
+
+# HLO opcodes that are collectives (their async ``-start``/``-done``
+# halves begin the same way); runtime_timer reads the same tuple
+COLLECTIVE_MARKERS = (
     "all-reduce",
     "all-gather",
     "reduce-scatter",
     "all-to-all",
     "collective-permute",
+    "collective-broadcast",
 )
 
 
@@ -775,7 +781,7 @@ def measured_collective_us(breakdown: List) -> float:
     total = 0.0
     for op in breakdown:
         name = op.name.lower()
-        if any(m in name for m in _COLLECTIVE_MARKERS):
+        if any(m in name for m in COLLECTIVE_MARKERS):
             total += op.total_us
     return total
 

@@ -1,8 +1,7 @@
 """Cross-process trace spans: a flight recorder from train step to failover.
 
 Reference: the Chrome trace-event format (``ph``/``ts``/``dur`` in µs)
-that Perfetto and ``chrome://tracing`` load directly — the same format
-``runtime_timer.parse_perfetto_dir`` already consumes from XLA.
+that Perfetto and ``chrome://tracing`` load directly.
 
 Design constraints this module pins down:
 
@@ -19,6 +18,22 @@ Design constraints this module pins down:
   ``NullTracer`` unless tracing was configured (explicitly or via
   ``DLROVER_TPU_TRACE_DIR``); its ``span()`` hands back a shared
   no-op span object, so a disabled hot path allocates nothing.
+* **One clock with the device.**  In a process that has imported jax,
+  every span of an enabled tracer is also a
+  ``jax.profiler.TraceAnnotation`` of the same name: outside a profiler
+  session that is a flag test, inside one the span lands on the host
+  plane of the same ``.xplane.pb`` as the device's operations, so an
+  idle gap on the device can be put down to what the program was doing
+  (``runtime_timer.reduce_planes``).  The agent and the master never
+  import jax, and this module never imports it for them.
+* **A tree, not a list.**  Each span has an ``id`` and the ``parent``
+  that was open on its thread when it began; ``self_seconds`` gives a
+  span's duration minus the part its children cover.
+
+Beside the spans sits a process-wide **counter table**
+(``set_counter`` / ``counters``), always on, for values taken at
+boundaries that happen at most once a trace of the step — so it costs
+the step loop nothing. A counter is added with the metric that reads it.
 
 Producers stream one JSON event per line into
 ``$DLROVER_TPU_TRACE_DIR/trace-{role}-{pid}.jsonl`` (append-only, one
@@ -28,8 +43,10 @@ zips the per-process files into a single time-sorted timeline.
 
 import glob
 import io
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -67,15 +84,35 @@ def _correlation_from_env() -> Dict[str, object]:
     return args
 
 
+_span_ids = itertools.count(1)
+# the few small arguments a span carries into the profiler's trace
+_ANNOTATED_ARGS = ("step", "rid", "nbytes")
+
+
 class Span:
     """One open interval; close with ``end()`` or use as a context manager."""
 
-    __slots__ = ("name", "args", "_tracer", "_t0_mono", "_ts_us", "dur_us")
+    __slots__ = (
+        "name", "args", "id", "parent", "dur_us",
+        "_tracer", "_t0_mono", "_ts_us", "_annotation", "_scoped",
+    )
 
-    def __init__(self, tracer: "Tracer", name: str, args: Dict):
+    def __init__(
+        self, tracer: "Tracer", name: str, args: Dict,
+        scoped: bool = True, step: Optional[int] = None,
+    ):
         self._tracer = tracer
         self.name = name
         self.args = args
+        self.id = next(_span_ids)
+        stack = tracer._open_spans()
+        self.parent = stack[-1].id if stack else 0
+        # ``begin()`` spans may overlap and end on another thread: they
+        # have a parent but never become one
+        self._scoped = scoped
+        if scoped:
+            stack.append(self)
+        self._annotation = _annotate(name, args, step)
         self._t0_mono = time.monotonic()
         self._ts_us = tracer._now_us()
         self.dur_us = -1.0  # open
@@ -84,11 +121,28 @@ class Span:
         """Close the span; returns the duration in seconds."""
         if self.dur_us >= 0:  # double-end is a no-op
             return self.dur_us / 1e6
-        self.dur_us = (time.monotonic() - self._t0_mono) * 1e6
+        self._close()
         if extra:
             self.args.update(extra)
         self._tracer._emit_complete(self)
         return self.dur_us / 1e6
+
+    def cancel(self) -> None:
+        """Close the span without recording it (work that raised before
+        it was done: only completed work lands on the timeline)."""
+        if self.dur_us < 0:
+            self._close()
+
+    def _close(self) -> None:
+        self.dur_us = (time.monotonic() - self._t0_mono) * 1e6
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+        if self._scoped:
+            stack = self._tracer._open_spans()
+            if stack and stack[-1] is self:
+                stack.pop()
+            elif self in stack:
+                stack.remove(self)
 
     def __enter__(self) -> "Span":
         return self
@@ -100,12 +154,31 @@ class Span:
         return False
 
 
+def _annotate(name: str, args: Dict, step: Optional[int]):
+    """The span's twin on the profiler's clock, entered; None in a
+    process that has not imported jax (the agent, the master)."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    if profiler is None:
+        return None
+    if step is not None:
+        annotation = profiler.StepTraceAnnotation(name, step_num=step)
+    else:
+        annotation = profiler.TraceAnnotation(
+            name, **{k: args[k] for k in _ANNOTATED_ARGS if k in args}
+        )
+    annotation.__enter__()
+    return annotation
+
+
 class _NullSpan:
     """Shared, stateless stand-in handed out by ``NullTracer``."""
 
     __slots__ = ()
     name = ""
     dur_us = 0.0
+    id = 0
+    parent = 0
 
     @property
     def args(self) -> Dict:
@@ -116,6 +189,9 @@ class _NullSpan:
 
     def end(self, **extra) -> float:
         return 0.0
+
+    def cancel(self) -> None:
+        pass
 
     def __enter__(self):
         return self
@@ -151,6 +227,7 @@ class Tracer:
         self._mono0 = time.monotonic()
         self._events: deque = deque(maxlen=capacity)
         self._lock = threading.Lock()
+        self._tls = threading.local()
         self._common = _correlation_from_env()
         self._common["role"] = role
         self._file: Optional[io.TextIOWrapper] = None
@@ -171,34 +248,58 @@ class Tracer:
         immune to wall-clock steps within one process."""
         return (self._wall0 + (time.monotonic() - self._mono0)) * 1e6
 
+    def _open_spans(self) -> List[Span]:
+        """This thread's stack of open scoped spans."""
+        stack = getattr(self._tls, "stack", None)
+        if stack is None:
+            stack = self._tls.stack = []
+        return stack
+
     # ---- span API -------------------------------------------------------
 
     def span(self, name: str, **args) -> Span:
-        """Open a span; close via ``with`` or explicit ``end()``."""
+        """Open a span; close via ``with`` or explicit ``end()``. Spans
+        opened while it is open on this thread are its children."""
         return Span(self, name, args)
 
+    def step_span(self, name: str, step: int, **args) -> Span:
+        """A span around one train step's dispatch: in the profiler's
+        trace it is a ``StepTraceAnnotation`` carrying the step number."""
+        args["step"] = step
+        return Span(self, name, args, step=step)
+
     def begin(self, name: str, **args) -> Span:
-        """Explicit-lifetime alias of :meth:`span`."""
-        return Span(self, name, args)
+        """Explicit-lifetime span: may overlap others and end on another
+        thread, so it takes a parent but never becomes one."""
+        return Span(self, name, args, scoped=False)
 
     def end(self, span: Span, **extra) -> float:
         return span.end(**extra)
 
-    def complete_span(self, name: str, t0_mono: float, **args) -> float:
+    def complete_span(
+        self, name: str, t0_mono: float, dur_s: Optional[float] = None,
+        **args,
+    ) -> float:
         """Emit a complete ("X") event back-dated to a monotonic start.
 
         For intervals whose start was stamped before a span could be
         opened — e.g. queue wait, measured from ``Request.submit_t``
         (taken on the submitting user thread) to admission (on the
-        engine loop thread). Returns the duration in seconds."""
-        now = time.monotonic()
-        dur_s = max(0.0, now - t0_mono)
+        engine loop thread) — and, with ``dur_s``, for time accumulated
+        over many short pieces (a save's device→host waits over its
+        leaves), laid out as one interval from ``t0_mono``. Returns the
+        duration in seconds."""
+        if dur_s is None:
+            dur_s = max(0.0, time.monotonic() - t0_mono)
+        stack = self._open_spans()
         self._record(
             {
                 "name": name,
                 "ph": "X",
-                "ts": self._now_us() - dur_s * 1e6,
+                "ts": (self._wall0 + (t0_mono - self._mono0)) * 1e6,
                 "dur": dur_s * 1e6,
+                "id": next(_span_ids),
+                "parent": stack[-1].id if stack else 0,
                 "args": args,
             }
         )
@@ -231,6 +332,8 @@ class Tracer:
                 "ph": "X",
                 "ts": span._ts_us,
                 "dur": span.dur_us,
+                "id": span.id,
+                "parent": span.parent,
                 "args": span.args,
             }
         )
@@ -284,10 +387,16 @@ class NullTracer:
 
     begin = span
 
+    def step_span(self, name: str, step: int, **args) -> _NullSpan:
+        return _NULL_SPAN
+
     def end(self, span, **extra) -> float:
         return 0.0
 
-    def complete_span(self, name: str, t0_mono: float, **args) -> float:
+    def complete_span(
+        self, name: str, t0_mono: float, dur_s: Optional[float] = None,
+        **args,
+    ) -> float:
         return 0.0
 
     def instant(self, name: str, **args) -> None:
@@ -394,8 +503,8 @@ def span_intervals(
     events: List[Dict], prefix: str = ""
 ) -> List[Dict]:
     """Complete-phase ("X") spans as ``{name, start_s, dur_s, role,
-    args}`` with seconds-since-epoch starts — the shape the drill's
-    phase-attribution code consumes."""
+    id, parent, args}`` with seconds-since-epoch starts — the shape the
+    drill's phase-attribution code consumes."""
     out = []
     for ev in events:
         if ev.get("ph") != "X":
@@ -410,7 +519,51 @@ def span_intervals(
                 "start_s": ev.get("ts", 0.0) / 1e6,
                 "dur_s": ev.get("dur", 0.0) / 1e6,
                 "role": args.get("role", ""),
+                "id": ev.get("id", 0),
+                "parent": ev.get("parent", 0),
                 "args": args,
             }
         )
     return out
+
+
+def self_seconds(intervals: List[Dict]) -> Dict[int, float]:
+    """Self time of every span of one process, by span id: its duration
+    minus the part of it that its child spans cover (children that
+    overlap each other, on other threads, are covered once)."""
+    children: Dict[int, List] = {}
+    for iv in intervals:
+        children.setdefault(iv["parent"], []).append(iv)
+    out: Dict[int, float] = {}
+    for iv in intervals:
+        lo, hi = iv["start_s"], iv["start_s"] + iv["dur_s"]
+        covered, reach = 0.0, lo
+        for c in sorted(
+            children.get(iv["id"], ()), key=lambda c: c["start_s"]
+        ):
+            s = max(c["start_s"], reach)
+            e = min(c["start_s"] + c["dur_s"], hi)
+            if e > s:
+                covered += e - s
+                reach = e
+        out[iv["id"]] = iv["dur_s"] - covered
+    return out
+
+
+# ---- counters ---------------------------------------------------------------
+
+_counters: Dict[str, float] = {}
+_counters_lock = threading.Lock()
+
+
+def set_counter(name: str, value: float) -> None:
+    """Set a counter to a value (idempotent: what a step moves, what a
+    plan holds — setting it twice must not double it)."""
+    with _counters_lock:
+        _counters[name] = value
+
+
+def counters() -> Dict[str, float]:
+    """A copy of this process's counter table."""
+    with _counters_lock:
+        return dict(_counters)

@@ -775,6 +775,7 @@ def _pallas_backward(q, k, v, out, lse, g, causal, scale,
             ],
             compiler_params=compiler_params,
             interpret=interpret,
+            name="flash_bwd_dq_packed",
         )(qt4, kt4, vt4, dot4, lse84, delta84, *extra)
 
         nq4 = sq // block_q
@@ -814,6 +815,7 @@ def _pallas_backward(q, k, v, out, lse, g, causal, scale,
             ],
             compiler_params=compiler_params,
             interpret=interpret,
+            name="flash_bwd_dkv_packed",
         )(qt4, kt4, vt4, dot4, lse84, delta84, *extra)
         dq = dq.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
         dk = dk.reshape(b, h, sk, d).transpose(0, 2, 1, 3)
@@ -832,6 +834,7 @@ def _pallas_backward(q, k, v, out, lse, g, causal, scale,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=compiler_params,
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qt, kt, vt, dot, lse8, delta8, *extra)
 
     # dkv grid swaps the roles: k-blocks outer, q-blocks inner; q blocks
@@ -874,6 +877,7 @@ def _pallas_backward(q, k, v, out, lse, g, causal, scale,
         ],
         compiler_params=compiler_params,
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qt, kt, vt, dot, lse8, delta8, *extra)
 
     dq = dq.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
@@ -1049,6 +1053,7 @@ def _flash_fwd(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_fwd_packed" if pack > 1 else "flash_fwd",
     )(*inputs)
     out = out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
     lse = (

@@ -208,6 +208,7 @@ def _call_fwd(kind, eps, dims, interpret, x, scale, bias, res):
         out_shape=out_shape,
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
+        name="norm_fwd",
     )(*inputs)
     if has_res:
         return outs[0], outs[1]
@@ -269,6 +270,7 @@ def _norm_call_bwd(kind, eps, dims, interpret, saved, g):
         out_shape=out_shape,
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
+        name="norm_bwd",
     )(*inputs)
     dx = outs[0]
     dscale = outs[1].sum(axis=0).astype(scale.dtype)

@@ -599,6 +599,7 @@ def _paged_call(q, pools, tables, positions, *, scale, window, kv_heads,
         out_shape=jax.ShapeDtypeStruct((b, c, h, d), q.dtype),
         compiler_params=compiler_params,
         interpret=interpret,
+        name="paged_attention",
     )(tables, span, row_pos, q, *pool_args, *extra_args)
     return out
 

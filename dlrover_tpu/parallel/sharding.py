@@ -418,14 +418,15 @@ def pack_flat(tree, plan: PackPlan, n_buckets: Optional[int] = None):
     """
     leaves = jax.tree.leaves(tree)
     nb = plan.n_buckets if n_buckets is None else n_buckets
-    flat = jnp.zeros((nb * plan.bucket_elems,), jnp.float32)
-    off = 0
-    for leaf in leaves:
-        flat = jax.lax.dynamic_update_slice(
-            flat, leaf.reshape(-1).astype(jnp.float32), (off,)
-        )
-        off += int(leaf.size)
-    return flat.reshape(nb, plan.bucket_elems)
+    with jax.named_scope("zero.pack"):
+        flat = jnp.zeros((nb * plan.bucket_elems,), jnp.float32)
+        off = 0
+        for leaf in leaves:
+            flat = jax.lax.dynamic_update_slice(
+                flat, leaf.reshape(-1).astype(jnp.float32), (off,)
+            )
+            off += int(leaf.size)
+        return flat.reshape(nb, plan.bucket_elems)
 
 
 def pack_buckets(tree, plan: PackPlan):
@@ -439,7 +440,11 @@ def pack_buckets(tree, plan: PackPlan):
     behind the end of backward). This is what lets XLA's latency-hiding
     scheduler issue early buckets while the backward tail computes.
     """
-    leaves = jax.tree.leaves(tree)
+    with jax.named_scope("zero.pack"):
+        return _pack_buckets(jax.tree.leaves(tree), plan)
+
+
+def _pack_buckets(leaves, plan: PackPlan):
     e = plan.bucket_elems
     rows = []
     for i in range(plan.n_buckets):
@@ -537,15 +542,33 @@ def exchange_buckets(
         if issue_order == "reverse"
         else range(plan.n_buckets)
     )
-    shards: List = [None] * plan.n_buckets
-    for i in order:
-        shards[i] = _exchange_bucket(rows[i], axis, wire, plan.dp)
-    if tie_extra is not None and plan.tie_size:
-        extra = pack_flat(
-            [tie_extra], plan, n_buckets=plan.n_tie_buckets
-        )
-        for i in range(plan.n_tie_buckets):
-            shards[i] = shards[i] + _exchange_bucket(
-                extra[i], axis, wire, plan.dp
+    tied = tie_extra is not None and bool(plan.tie_size)
+    with jax.named_scope("zero.exchange"):
+        shards: List = [None] * plan.n_buckets
+        for i in order:
+            shards[i] = _exchange_bucket(rows[i], axis, wire, plan.dp)
+        if tied:
+            extra = pack_flat(
+                [tie_extra], plan, n_buckets=plan.n_tie_buckets
             )
-    return jnp.stack(shards)
+            for i in range(plan.n_tie_buckets):
+                shards[i] = shards[i] + _exchange_bucket(
+                    extra[i], axis, wire, plan.dp
+                )
+        return jnp.stack(shards)
+
+
+_WIRE_BYTES = {"float32": 4, "bfloat16": 2, "int8": 1}
+
+
+def exchange_payload_bytes(plan: PackPlan, wire: str, tied: bool) -> int:
+    """Bytes of the gradient stream one rank hands to one
+    ``exchange_buckets`` call: every bucket at the wire's width, the
+    tied head's buckets included (int8: its f32 block scales too)."""
+    n = plan.n_buckets + (plan.n_tie_buckets if tied else 0)
+    nbytes = n * plan.bucket_elems * _WIRE_BYTES[wire]
+    if wire == "int8":
+        from dlrover_tpu.ops.quant import BLOCK
+
+        nbytes += n * plan.bucket_elems // BLOCK * 4
+    return nbytes

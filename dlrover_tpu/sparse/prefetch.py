@@ -133,6 +133,9 @@ class LookaheadPrefetcher:
             self.batches += 1
             self.keys_promoted += promoted
             with self._mu:
+                # the drained buffer is done with: left full it would
+                # keep drain() from ever seeing the prefetcher idle
+                self._buffers[self._fill ^ 1].clear()
                 self._busy = False
 
     def start(self) -> "LookaheadPrefetcher":

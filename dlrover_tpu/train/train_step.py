@@ -25,6 +25,7 @@ from dlrover_tpu.common import device
 from dlrover_tpu.models import decoder
 from dlrover_tpu.models.config import ModelConfig
 from dlrover_tpu.observability import sentinels as snt
+from dlrover_tpu.observability.tracing import set_counter
 from dlrover_tpu.parallel import sharding as shd
 
 logger = logging.getLogger(__name__)
@@ -1100,9 +1101,11 @@ class TrainStepBuilder:
             flat_params["flat"] = jax.lax.with_sharding_constraint(
                 flat_params["flat"], flat_sh
             )
-        updates, new_opt = self._flat_opt.update(
-            {"flat": grads_flat}, state["opt_state"], flat_params
-        )
+        with jax.named_scope("zero.update"):
+            updates, new_opt = self._flat_opt.update(
+                {"flat": grads_flat}, state["opt_state"], flat_params
+            )
+
         def apply_region(fp, u):
             # per-rank `p + u` BEFORE the all-gather. Done in auto mode
             # the partitioner is free to gather `u` first, which splits
@@ -1119,16 +1122,29 @@ class TrainStepBuilder:
                 fp_shard + u, "dp", axis=1, tiled=True
             )
 
-        new_flat = jax.shard_map(
-            apply_region,
-            mesh=mesh,
-            in_specs=(P(), P(None, "dp")),
-            out_specs=P(),
-            # the tiled all_gather IS replicated over dp, but the public
-            # collective types its result as varying
-            check_vma=False,
-        )(flat_params["flat"], updates["flat"])
-        params = shd.unpack_flat(new_flat, state["params"], plan)
+        with jax.named_scope("zero.gather"):
+            new_flat = jax.shard_map(
+                apply_region,
+                mesh=mesh,
+                in_specs=(P(), P(None, "dp")),
+                out_specs=P(),
+                # the tiled all_gather IS replicated over dp, but the
+                # public collective types its result as varying
+                check_vma=False,
+            )(flat_params["flat"], updates["flat"])
+            params = shd.unpack_flat(new_flat, state["params"], plan)
+        # what one rank moves for ZeRO in a step. Trace time, once per
+        # compile, and values: a retrace cannot double them. zero2
+        # exchanges every microbatch, zero1 once; the whole f32
+        # parameter stream is gathered back
+        set_counter(
+            "zero.exchange_bytes",
+            (1 if defer else a)
+            * shd.exchange_payload_bytes(
+                plan, wire, tie and bool(plan.tie_size)
+            ),
+        )
+        set_counter("zero.gather_bytes", plan.padded * 4)
         if zoo:
             # the region suppressed the model's internal constraints;
             # re-pin the unpacked params to their rule shardings so the
@@ -1200,10 +1216,11 @@ class TrainStepBuilder:
             # stream the moments HBM-ward only for the update; the jitted
             # step's output shardings put the new state back on host
             opt_state = _to_memory_kind(opt_state, _DEVICE)
-        updates, new_opt = self.optimizer.update(
-            grads, opt_state, state["params"]
-        )
-        params = optax.apply_updates(state["params"], updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = self.optimizer.update(
+                grads, opt_state, state["params"]
+            )
+            params = optax.apply_updates(state["params"], updates)
         if self.offload_opt_state:
             new_opt = _to_memory_kind(new_opt, _HOST)
         metrics = dict(metrics)

@@ -477,27 +477,34 @@ class Trainer:
         last_evaled = -1
         t_log = time.perf_counter()
         for step in range(start + 1, args.max_steps + 1):
+            # the four spans a device idle gap can be put down to; with
+            # tracing off each is the shared null span
+            tracer = get_tracer()
             try:
                 # single-process: already device-placed by the
                 # prefetch_to_device wrap in __init__; multi-host
                 # batches arrive global via form_global_batch
-                batch = next(self.train_iter)
+                with tracer.span("train.input_wait", step=step):
+                    batch = next(self.train_iter)
             except StopIteration:
                 logger.info("data exhausted at step %d", step - 1)
                 break
             self.timer.start()
-            if self.runtime_timer is not None:
-                self.state, metrics = self.runtime_timer.profiled_call(
-                    step, self._step_fn, self.state, batch
-                )
-            else:
-                self.state, metrics = self._step_fn(self.state, batch)
-            self.timer.stop(outputs=metrics["loss"])
-            # ONE device→host transfer per step, sentinels or not — the
-            # sentinel scalars ride the same readback as the loss
-            # (dispatch-guard-pinned in tests/test_sentinels.py)
-            host = jax.device_get(metrics)
+            with tracer.step_span("train.step", step):
+                if self.runtime_timer is not None:
+                    self.state, metrics = self.runtime_timer.profiled_call(
+                        step, self._step_fn, self.state, batch
+                    )
+                else:
+                    self.state, metrics = self._step_fn(self.state, batch)
+            with tracer.span("train.readback", step=step):
+                self.timer.stop(outputs=metrics["loss"])
+                # ONE device→host transfer per step, sentinels or not —
+                # the sentinel scalars ride the same readback as the
+                # loss (dispatch-guard-pinned in tests/test_sentinels.py)
+                host = jax.device_get(metrics)
             loss = float(host["loss"])
+            hooks_span = tracer.span("train.hooks", step=step)
             self._emit_step_telemetry(step, loss, self.timer.last_s, batch)
             if self.runtime_timer is not None:
                 self._emit_kernel_telemetry(step)
@@ -587,6 +594,7 @@ class Trainer:
                         "on_eval", self, step, eval_metrics, control
                     )
             control.reset_step_flags()
+            hooks_span.end()
             if control.should_stop:
                 logger.info("training stopped by callback at step %d", step)
                 break
@@ -644,7 +652,8 @@ class Trainer:
             return out
 
         def drain(first, k, metrics, t0):
-            host = jax.device_get(metrics)  # previous block: finished
+            with get_tracer().span("train.readback", step=first):
+                host = jax.device_get(metrics)  # previous block: finished
             self.timer.record(time.perf_counter() - t0, n_steps=k)
             per_step_s = self.timer.last_s
             losses = np.asarray(host["loss"]).reshape(-1)
@@ -699,19 +708,25 @@ class Trainer:
             and not control.should_stop
             and not exhausted
         ):
+            tracer = get_tracer()
             batches = []
-            for _ in range(self._next_block_k(step)):
-                try:
-                    batches.append(next(self.train_iter))
-                except StopIteration:
-                    exhausted = True
-                    break
+            with tracer.span("train.input_wait", step=step + 1):
+                for _ in range(self._next_block_k(step)):
+                    try:
+                        batches.append(next(self.train_iter))
+                    except StopIteration:
+                        exhausted = True
+                        break
+                if batches:
+                    block = jax.tree.map(
+                        lambda *xs: jnp.stack(xs), *batches
+                    )
             if not batches:
                 logger.info("data exhausted at step %d", step)
                 break
             k = len(batches)
-            block = jax.tree.map(lambda *xs: jnp.stack(xs), *batches)
             t0 = time.perf_counter()
+            step_span = tracer.step_span("train.step", step + 1, block=k)
             if self.runtime_timer is not None:
                 # profile when a sampled step falls inside this block
                 sample = next(
@@ -745,6 +760,8 @@ class Trainer:
                     self.state, metrics = self._block_fn(self.state, block)
             else:
                 self.state, metrics = self._block_fn(self.state, block)
+            step_span.end()
+            hooks_span = tracer.span("train.hooks", step=step + k)
             if pending is not None:
                 drain(*pending)
             pending = (step + 1, k, metrics, t0)
@@ -789,6 +806,7 @@ class Trainer:
                         "on_eval", self, step, eval_metrics, control
                     )
             control.reset_step_flags()
+            hooks_span.end()
         if pending is not None:
             drain(*pending)
         # flags raised by the FINAL drain still get their boundary

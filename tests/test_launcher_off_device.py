@@ -8,6 +8,8 @@ import subprocess
 import sys
 import time
 
+import pytest
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 _AGENT_SCRIPT = """
@@ -52,18 +54,31 @@ def _env(**extra):
     return env
 
 
-def test_agent_never_initialises_a_backend(tmp_path):
+@pytest.mark.parametrize("tracing", ["off", "on"])
+def test_agent_never_initialises_a_backend(tmp_path, tracing):
+    """With tracing on the agent's spans are mirrored into the profiler's
+    annotations (its packages import jax): still no backend comes up."""
+    extra = {}
+    if tracing == "on":
+        extra["DLROVER_TPU_TRACE_DIR"] = str(tmp_path / "trace")
     proc = subprocess.run(
         [sys.executable, "-c", _AGENT_SCRIPT],
         env=_env(
             DLROVER_TPU_LOCAL_CHIPS="2",
-            DLROVER_TPU_RUN_ID=f"offdev{os.getpid()}",
+            DLROVER_TPU_RUN_ID=f"offdev{os.getpid()}{tracing}",
             DLROVER_TPU_SOCK_DIR=str(tmp_path),
+            **extra,
         ),
         cwd=REPO, capture_output=True, text=True, timeout=120,
     )
     assert proc.returncode == 0, proc.stderr[-3000:]
     assert "agent stayed off the device" in proc.stdout
+    if tracing == "on":
+        assert any(
+            name.startswith("trace-") for name in os.listdir(extra[
+                "DLROVER_TPU_TRACE_DIR"
+            ])
+        )
 
 
 def test_chip_smoke_refuses_the_cpu():

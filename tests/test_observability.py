@@ -278,66 +278,65 @@ def test_runtime_timer_in_trainer(tmp_path):
 # ---------------------------------------------------------------------------
 
 
-def _write_trace(root, events, sub="plugins/profile/run1"):
-    import gzip
-    import os
+def _traced_matmuls(logdir):
+    """A real (CPU-backend) trace of two jitted matmuls, as the planes
+    the program's reducer works on."""
+    import jax
+    import jax.numpy as jnp
 
-    d = os.path.join(str(root), sub)
-    os.makedirs(d, exist_ok=True)
-    path = os.path.join(d, "perfetto_trace.json.gz")
-    with gzip.open(path, "wt") as fh:
-        json.dump({"traceEvents": events}, fh)
-    return path
+    from dlrover_tpu.observability import runtime_timer as rt
 
-
-def test_parse_perfetto_canned_fixture(tmp_path):
-    """Canned perfetto payload: aggregation, ordering, fraction
-    normalization, noise filtering, and top_k truncation — without a
-    live profiler run."""
-    from dlrover_tpu.observability.runtime_timer import parse_perfetto_dir
-
-    assert parse_perfetto_dir(str(tmp_path)) == []  # no trace yet
-    _write_trace(
-        tmp_path,
-        [
-            {"ph": "X", "name": "fusion.1", "dur": 100.0},
-            {"ph": "X", "name": "fusion.1", "dur": 50.0},
-            {"ph": "X", "name": "dot.2", "dur": 300.0},
-            # noise: python frames, runtime threads, non-complete events
-            {"ph": "X", "name": "$py_frame", "dur": 999.0},
-            {"ph": "X", "name": "jit/fn/call", "dur": 999.0},
-            {"ph": "X", "name": "PjitFunction(step)", "dur": 999.0},
-            {"ph": "X", "name": "Thread 12", "dur": 999.0},
-            {"ph": "M", "name": "dot.2", "dur": 999.0},
-            {"ph": "X", "name": "", "dur": 999.0},
-        ],
-    )
-    bd = parse_perfetto_dir(str(tmp_path))
-    assert [o.name for o in bd] == ["dot.2", "fusion.1"]
-    assert bd[0].total_us == 300.0 and bd[0].count == 1
-    assert bd[1].total_us == 150.0 and bd[1].count == 2
-    assert bd[0].fraction == pytest.approx(300.0 / 450.0)
-    assert sum(o.fraction for o in bd) == pytest.approx(1.0)
-    top = parse_perfetto_dir(str(tmp_path), top_k=1)
-    assert [o.name for o in top] == ["dot.2"]
+    f = jax.jit(lambda a: jnp.tanh(a @ a) @ a)
+    x = jnp.ones((128, 128))
+    f(x)
+    with jax.profiler.trace(str(logdir)):
+        with jax.profiler.TraceAnnotation(rt.SAMPLE_SPAN):
+            jax.block_until_ready(f(x))
+    return rt.load_planes(rt.find_xplane(str(logdir)))
 
 
-def test_parse_perfetto_picks_newest_trace(tmp_path):
-    import os
-    import time as _time
+def test_reducer_on_a_real_trace_counts_executed_ops_only(tmp_path):
+    """The successor of the perfetto parser's canned fixture: off the
+    chip, device time is the events the runtime marks as executed HLO
+    operations — never python frames, runtime threads or annotations."""
+    from dlrover_tpu.observability import runtime_timer as rt
 
-    from dlrover_tpu.observability.runtime_timer import parse_perfetto_dir
+    planes = _traced_matmuls(tmp_path)
+    profile = rt.reduce_planes(planes)
+    names = [o.name for o in profile.by_op]
+    assert any(n.startswith("dot") for n in names), names
+    assert not any("$" in n or "/" in n or " " in n for n in names)
+    assert rt.SAMPLE_SPAN not in names
+    assert sum(o.fraction for o in profile.by_op) == pytest.approx(1.0)
+    assert names == [
+        o.name for o in sorted(profile.by_op, key=lambda o: -o.total_us)
+    ]
+    # busy cannot pass the sampled window, however many host threads ran
+    assert 0 < profile.busy_s <= profile.window_s
+    host_total = sum(
+        d for p in planes if not rt.DEVICE_PLANE.match(p["name"])
+        for line in p["lines"] for _n, _s, d in line["events"]
+    ) / 1e9
+    assert profile.busy_s < host_total
 
-    old = _write_trace(
-        tmp_path, [{"ph": "X", "name": "old_op", "dur": 1.0}], sub="a"
-    )
-    new = _write_trace(
-        tmp_path, [{"ph": "X", "name": "new_op", "dur": 1.0}], sub="b"
-    )
-    now = _time.time()
-    os.utime(old, (now - 60, now - 60))
-    os.utime(new, (now, now))
-    assert [o.name for o in parse_perfetto_dir(str(tmp_path))] == ["new_op"]
+
+def test_timer_breakdown_is_the_top_k_of_the_profile(tmp_path):
+    import jax
+    import jax.numpy as jnp
+
+    from dlrover_tpu.observability.runtime_timer import RuntimeKernelTimer
+
+    f = jax.jit(lambda a: jnp.tanh(a @ a) @ a)
+    x = jnp.ones((128, 128))
+    f(x)
+    timer = RuntimeKernelTimer(interval_steps=1, top_k=1)
+    timer.profiled_call(1, f, x)
+    assert len(timer.profile.by_op) > 1
+    assert timer.breakdown == timer.profile.by_op[:1]
+    assert timer.summary() == {
+        timer.breakdown[0].name: timer.breakdown[0].total_us
+    }
+    assert timer.sample_wall_s > 0
 
 
 def test_runtime_timer_forced_one_shot(tmp_path):
@@ -354,8 +353,14 @@ def test_runtime_timer_forced_one_shot(tmp_path):
     timer.force_next()
     assert timer.should_sample(7)
 
-    out = timer.profiled_call(7, lambda a, b: a + b, 2, 3, n_steps=4)
-    assert out == 5
+    import jax
+    import jax.numpy as jnp
+
+    add = jax.jit(lambda a, b: a + b)
+    out = timer.profiled_call(
+        7, add, jnp.full((8,), 2.0), jnp.full((8,), 3.0), n_steps=4
+    )
+    assert float(out[0]) == 5.0
     assert timer.sampled_at == 7
     # a 4-step fused block is labeled as such, never as one step
     assert timer.sampled_block_k == 4

@@ -6,6 +6,7 @@ tests in tfplus/py_ut, on the TPU-native surface.
 """
 
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -913,6 +914,12 @@ class TestLookaheadPrefetcher:
         )
         pf.start()
         try:
+            # drain() is true of a worker that has not run yet: wait for
+            # its first batch, or a loaded host stops it before it peeks
+            deadline = time.monotonic() + 30.0
+            while pf.batches == 0 and time.monotonic() < deadline:
+                pf.notify()
+                time.sleep(0.001)
             for _ in range(5):  # the same head peeked repeatedly
                 pf.notify()
                 assert pf.drain(timeout=30.0)

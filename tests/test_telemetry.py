@@ -346,7 +346,8 @@ def test_merge_trace_dir_sorts_and_tolerates_torn_lines(tmp_path):
     iv = tracing.span_intervals(evs, prefix="failover.")
     assert iv == [{
         "name": "failover.x", "start_s": 1.0, "dur_s": 0.5,
-        "role": "agent", "args": {"role": "agent"},
+        "role": "agent", "id": 0, "parent": 0,  # a recording from before ids
+        "args": {"role": "agent"},
     }]
 
 

@@ -33,13 +33,17 @@ F32 = jnp.float32
 
 
 @pytest.fixture(scope="module")
-def chip():
+def topo():
     try:
-        topo = topologies.get_topology_desc(
+        return topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2"
         )
     except Exception as exc:  # noqa: BLE001 — no TPU compiler installed
         pytest.skip(f"cannot describe a v5e topology here: {exc}")
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -150,3 +154,124 @@ def test_kernel_compiles_for_v5e(chip, case):
     fn, args = build(struct)
     compiled = jax.jit(fn).lower(*args).compile()
     assert compiled.as_text().count("tpu_custom_call") == n_kernels
+
+
+# ---- the whole train step: kernel names and phase scopes ------------------
+# What a device trace shows for an operation is its HLO instruction's
+# name, and what the program's reducer (observability/runtime_timer.py)
+# knows of its place in the step is its ``op_name`` metadata. Both are
+# decided by the chip's compiler, so both are pinned here, at the
+# benchmark's three recipes cut to two layers.
+
+STEP_CASES = {
+    # GPT-2 XL widths: head size 64, so the head-packed kernels
+    "gpt2-like": dict(
+        model="gpt2-1.5b",
+        overrides=dict(n_layer=2, max_seq=1024, remat="full",
+                       param_dtype="bfloat16"),
+        optimizer=dict(state_dtype="bfloat16"), comm=None, chips=1,
+        batch=(8, 1024),
+        kernels={"flash_fwd_packed", "flash_bwd_dq_packed",
+                 "flash_bwd_dkv_packed", "norm_fwd", "norm_bwd"},
+        scopes={"embed", "attn", "mlp", "head_loss", "optimizer"},
+    ),
+    # Mistral widths: head size 128, GQA 32/8, the window live
+    "mistral-like": dict(
+        model="mistral-7b",
+        overrides=dict(n_layer=2, max_seq=2048, attn_window=1024,
+                       remat="full", param_dtype="bfloat16"),
+        optimizer=dict(state_dtype="bfloat16"), comm=None, chips=1,
+        batch=(1, 2048),
+        kernels={"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+                 "norm_fwd", "norm_bwd"},
+        scopes={"embed", "attn", "mlp", "head_loss", "optimizer"},
+    ),
+    # the dp=4 ZeRO-1 recipe: f32 parameters, tied head
+    "zero1-dp4": dict(
+        model="gpt2-1.5b",
+        overrides=dict(n_layer=2, max_seq=1024, remat="full",
+                       param_dtype="float32"),
+        optimizer={}, comm=dict(update_sharding="zero1"), chips=4,
+        batch=(32, 1024),
+        kernels={"flash_fwd_packed", "flash_bwd_dq_packed",
+                 "flash_bwd_dkv_packed", "norm_fwd", "norm_bwd"},
+        scopes={"embed", "attn", "mlp", "head_loss", "zero.pack",
+                "zero.exchange", "zero.update", "zero.gather"},
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(STEP_CASES))
+def test_step_names_its_kernels_and_phases(topo, case):
+    import re
+
+    from dlrover_tpu.observability import runtime_timer, tracing
+    from dlrover_tpu.parallel import MeshConfig, build_mesh
+    from dlrover_tpu.parallel import sharding as shd
+    from dlrover_tpu.train import (
+        TrainStepBuilder, batch_sharding, make_optimizer,
+    )
+    from dlrover_tpu.train.train_step import abstract_train_state
+
+    spec = STEP_CASES[case]
+    cfg = get_config(spec["model"], **spec["overrides"])
+    mesh = build_mesh(
+        MeshConfig(dp=-1), devices=list(topo.devices[: spec["chips"]])
+    )
+    opt = make_optimizer(
+        learning_rate=1e-4, warmup_steps=10, decay_steps=1000,
+        **spec["optimizer"],
+    )
+    comm = shd.CommConfig(**spec["comm"]) if spec["comm"] else None
+    builder = TrainStepBuilder(cfg, mesh, opt, comm=comm)
+    assert bool(builder.update_sharding) == bool(comm), (
+        builder.update_sharding_reason
+    )
+    state = abstract_train_state(
+        cfg, mesh, opt, comm=builder.comm_resolved
+    )
+    batch = {
+        k: jax.ShapeDtypeStruct(
+            spec["batch"], jnp.int32, sharding=batch_sharding(mesh)
+        )
+        for k in ("tokens", "targets")
+    }
+    tracing._counters.clear()
+    text = builder.build().lower(state, batch).compile().as_text()
+
+    # every Pallas kernel's instruction is named after the kernel
+    kernel_lines = [
+        line for line in text.splitlines() if "tpu_custom_call" in line
+    ]
+    named = set()
+    for line in kernel_lines:
+        m = re.match(r"\s*(?:ROOT )?%([\w\-]+?)(?:\.\d+)? = ", line)
+        assert m and m.group(1) in spec["kernels"], line[:160]
+        named.add(m.group(1))
+    assert named == spec["kernels"]
+
+    # and every part of the step shows in the op_names the reducer reads
+    op_names = runtime_timer.op_names_from_hlo(text)
+    scopes = {runtime_timer.scope_of(n) for n in op_names.values()}
+    assert spec["scopes"] <= scopes, spec["scopes"] - scopes
+    phases = {
+        runtime_timer.phase_of("%" + i + " = x", n)
+        for i, n in op_names.items()
+    }
+    wanted = {"forward", "recompute", "backward", "optimizer"}
+    if comm:
+        wanted.add("exchange")
+        plan = builder._plan
+        assert tracing.counters()["zero.exchange_bytes"] == (
+            (plan.n_buckets + plan.n_tie_buckets) * plan.bucket_elems * 4
+        )
+    assert wanted <= phases, wanted - phases
+    for line in kernel_lines:
+        name = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", line).group(1)
+        kernel, phase = name.split(".")[0], runtime_timer.phase_of(
+            line, op_names[name]
+        )
+        if kernel.startswith("flash_bwd") or kernel == "norm_bwd":
+            assert phase == "backward", (name, op_names[name])
+        else:
+            assert phase in ("forward", "recompute"), (name, op_names[name])
