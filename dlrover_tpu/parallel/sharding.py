@@ -299,6 +299,9 @@ def tied_head_table(table: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
+_LANES = 128  # minor tile width of a TPU array of two or more dimensions
+
+
 def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
@@ -403,71 +406,117 @@ def build_pack_plan(
     )
 
 
+def _bucket_row(flats, plan: PackPlan, i: int):
+    """Row ``i`` of the stream, ``[bucket_elems]``, from the 1-D views
+    ``flats`` of the leading leaves: only the leaf slices that overlap
+    it, zero-padded past the last leaf."""
+    e = plan.bucket_elems
+    lo, hi = i * e, (i + 1) * e
+    pieces = [
+        flat[max(lo, off) - off : min(hi, off + size) - off]
+        for off, size, flat in zip(plan.offsets, plan.sizes, flats)
+        if off < hi and off + size > lo
+    ]
+    pad = e - sum(p.shape[0] for p in pieces)
+    if pad:
+        pieces.append(jnp.zeros((pad,), jnp.float32))
+    return pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces)
+
+
+def _flat_views(tree):
+    return [
+        leaf.reshape(-1).astype(jnp.float32) for leaf in jax.tree.leaves(tree)
+    ]
+
+
+def pack_buckets(tree, plan: PackPlan, n_buckets: Optional[int] = None):
+    """Pytree → list of ``n_buckets`` independent ``[bucket_elems]`` rows
+    of the zero-padded flat stream (the tree's leaves in order).
+
+    Each row is built from ONLY the leaf slices overlapping its flat
+    range — so a bucket's reduce-scatter depends on just the gradients
+    inside it, not on every leaf, which is what lets XLA's
+    latency-hiding scheduler issue early buckets while the backward
+    tail computes. A tree of fewer leaves than the plan (the tied
+    head's cotangent alone, ``n_buckets=plan.n_tie_buckets``) packs
+    against the plan's leading offsets.
+    """
+    nb = plan.n_buckets if n_buckets is None else n_buckets
+    with jax.named_scope("zero.pack"):
+        flats = _flat_views(tree)
+        return [_bucket_row(flats, plan, i) for i in range(nb)]
+
+
 def pack_flat(tree, plan: PackPlan, n_buckets: Optional[int] = None):
     """Pytree → ``[n_buckets, bucket_elems]`` f32 stream (zero-padded).
 
-    The flat buffer is built with ``dynamic_update_slice`` writes into a
-    zeros buffer rather than one ``concatenate`` + ``pad``. Both of the
-    obvious spellings miscompile on jax 0.4.x when the leaves carry
-    model-axis (fsdp/tp) shardings: a ``concatenate`` whose operands mix
-    auto-axis-sharded leaves with fresh zeros comes back with its values
-    scaled by the size of an unrelated mesh axis, and ``jnp.pad``
-    check-fails the SPMD partitioner inside a partial-manual region
-    (hlo_sharding_util ``IsManualSubgroup``). The slice writes lower
-    cleanly in both auto and manual contexts.
+    For set-up and tests. The step never holds the whole stream in this
+    shape: on the TPU a 2-D array is tiled (8, 128) and a 1-D one by
+    1024, so going between ``[n_buckets, bucket_elems]`` and the 1-D
+    stream the leaves are cut from is a copy the compiler makes one
+    4 MiB row per loop trip, at a twentieth of the memory's rate. The
+    step packs each rank's shard from the leaves (``pack_shard``) and
+    gathers the stream in its own order (``gather_stream``).
     """
-    leaves = jax.tree.leaves(tree)
-    nb = plan.n_buckets if n_buckets is None else n_buckets
-    with jax.named_scope("zero.pack"):
-        flat = jnp.zeros((nb * plan.bucket_elems,), jnp.float32)
-        off = 0
-        for leaf in leaves:
-            flat = jax.lax.dynamic_update_slice(
-                flat, leaf.reshape(-1).astype(jnp.float32), (off,)
-            )
-            off += int(leaf.size)
-        return flat.reshape(nb, plan.bucket_elems)
+    return jnp.stack(pack_buckets(tree, plan, n_buckets))
 
 
-def pack_buckets(tree, plan: PackPlan):
-    """Pytree → list of ``n_buckets`` independent ``[bucket_elems]`` rows.
+def pack_shard(tree, plan: PackPlan, idx):
+    """Rank ``idx``'s ``[n_buckets, bucket_elems/dp]`` of the stream:
+    ``pack_flat(tree, plan)[:, idx*q:(idx+1)*q]`` without the stream.
 
-    Same values as ``pack_flat(tree, plan)``'s rows, but each row is
-    built from ONLY the leaf slices overlapping its flat range — so a
-    bucket's reduce-scatter depends on just the gradients inside it,
-    not on every leaf (``pack_flat``'s single flat buffer makes each
-    bucket data-dependent on ALL grads, which pins every collective
-    behind the end of backward). This is what lets XLA's latency-hiding
-    scheduler issue early buckets while the backward tail computes.
+    Called inside the dp-manual region with ``idx = axis_index("dp")``.
+    A row that lies inside one leaf is one slice of that leaf's 1-D
+    view; the few that straddle leaves (about one a leaf) or hold the
+    zero tail are built whole and sliced.
     """
+    e, q = plan.bucket_elems, plan.bucket_elems // plan.dp
+    # unsigned: no wrap-around test of a negative start, three scalar
+    # operations a row, in the compiled step
+    idx = jnp.asarray(idx, jnp.uint32)
     with jax.named_scope("zero.pack"):
-        return _pack_buckets(jax.tree.leaves(tree), plan)
-
-
-def _pack_buckets(leaves, plan: PackPlan):
-    e = plan.bucket_elems
-    rows = []
-    for i in range(plan.n_buckets):
-        lo, hi = i * e, (i + 1) * e
-        # slice writes into zeros, not concatenate + pad — see pack_flat
-        # for why both miscompile on sharded leaves under jax 0.4.x
-        row = jnp.zeros((e,), jnp.float32)
-        pos = 0
-        for off, size, leaf in zip(plan.offsets, plan.sizes, leaves):
-            if off + size <= lo or off >= hi:
-                continue
-            a = max(lo, off) - off
-            b = min(hi, off + size) - off
-            row = jax.lax.dynamic_update_slice(
-                row, leaf.reshape(-1)[a:b].astype(jnp.float32), (pos,)
+        flats = _flat_views(tree)
+        rows = []
+        for i in range(plan.n_buckets):
+            lo = i * e
+            flat, base = next(
+                (
+                    (flat, lo - off)
+                    for off, size, flat in zip(plan.offsets, plan.sizes, flats)
+                    if off <= lo and lo + e <= off + size
+                ),
+                (None, 0),
             )
-            pos += b - a
-        rows.append(row)
-    return rows
+            if flat is None:
+                flat = _bucket_row(flats, plan, i)
+            rows.append(
+                jax.lax.dynamic_slice(flat, (base + idx * q,), (q,))
+            )
+        return jnp.stack(rows)
+
+
+def gather_stream(shard, axis: str = "dp"):
+    """All-gather the ranks' ``[n_buckets, bucket_elems/dp]`` shards
+    into the whole stream, as ``[n_buckets, bucket_elems/128, 128]``.
+
+    That shape, tiled (8, 128) like every array of two or more
+    dimensions, lies in memory in the stream's own order, so
+    ``unpack_flat``'s 1-D view of it is free. Gathered as
+    ``[n_buckets, bucket_elems]`` the same bytes interleave eight rows
+    a tile, and the 1-D view is a row-by-row copy of the whole stream.
+    Only the rank's own shard is relaid here. (``bucket_elems/dp`` is a
+    multiple of the quantisation block, itself a multiple of 128.)
+    """
+    n, q = shard.shape
+    return jax.lax.all_gather(
+        shard.reshape(n, q // _LANES, _LANES), axis, axis=1, tiled=True
+    )
 
 
 def unpack_flat(flat, like, plan: PackPlan):
-    """Inverse of ``pack_flat``: flat stream → pytree shaped like ``like``."""
+    """Inverse of ``pack_flat``: the stream, in any shape whose
+    row-major order is the stream's, → pytree shaped like ``like``.
+    The step hands it ``gather_stream``'s result, never a 2-D array."""
     stream = flat.reshape(-1)
     leaves = jax.tree.leaves(like)
     out = [
@@ -548,7 +597,7 @@ def exchange_buckets(
         for i in order:
             shards[i] = _exchange_bucket(rows[i], axis, wire, plan.dp)
         if tied:
-            extra = pack_flat(
+            extra = pack_buckets(
                 [tie_extra], plan, n_buckets=plan.n_tie_buckets
             )
             for i in range(plan.n_tie_buckets):

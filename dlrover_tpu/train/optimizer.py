@@ -407,8 +407,8 @@ def flat_factored_adamw(
     def _repack(leaves, dtype):
         # slice writes into zeros, not concatenate + pad: on jax 0.4.x a
         # concatenate mixing auto-axis-sharded operands with fresh zeros
-        # comes back scaled by an unrelated mesh-axis size (see
-        # parallel.sharding.pack_flat)
+        # came back scaled by an unrelated mesh-axis size (ROADMAP D4:
+        # parallel.sharding's rows dropped this spelling under 0.9)
         flat = jnp.zeros((plan.padded,), dtype)
         off = 0
         for l in leaves:

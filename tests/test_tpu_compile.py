@@ -198,14 +198,27 @@ STEP_CASES = {
         scopes={"embed", "attn", "mlp", "head_loss", "zero.pack",
                 "zero.exchange", "zero.update", "zero.gather"},
     ),
+    # the same under ZeRO-2, two microbatches: an exchange (and the tied
+    # head's buckets) inside the accumulation scan. Layout guard only.
+    "zero2-dp4": dict(
+        model="gpt2-1.5b",
+        overrides=dict(n_layer=2, max_seq=1024, remat="full",
+                       param_dtype="float32"),
+        optimizer={}, comm=dict(update_sharding="zero2"), chips=4,
+        grad_accum=2, batch=(32, 1024),
+    ),
 }
 
 
-@pytest.mark.parametrize("case", sorted(STEP_CASES))
-def test_step_names_its_kernels_and_phases(topo, case):
-    import re
+_STEP_TEXT = {}
 
-    from dlrover_tpu.observability import runtime_timer, tracing
+
+def _compiled_step(topo, case):
+    """(builder, compiled text, counters set while tracing) of one of
+    STEP_CASES, compiled for the described chips once a session."""
+    if case in _STEP_TEXT:
+        return _STEP_TEXT[case]
+    from dlrover_tpu.observability import tracing
     from dlrover_tpu.parallel import MeshConfig, build_mesh
     from dlrover_tpu.parallel import sharding as shd
     from dlrover_tpu.train import (
@@ -223,7 +236,9 @@ def test_step_names_its_kernels_and_phases(topo, case):
         **spec["optimizer"],
     )
     comm = shd.CommConfig(**spec["comm"]) if spec["comm"] else None
-    builder = TrainStepBuilder(cfg, mesh, opt, comm=comm)
+    builder = TrainStepBuilder(
+        cfg, mesh, opt, comm=comm, grad_accum=spec.get("grad_accum", 1)
+    )
     assert bool(builder.update_sharding) == bool(comm), (
         builder.update_sharding_reason
     )
@@ -238,6 +253,21 @@ def test_step_names_its_kernels_and_phases(topo, case):
     }
     tracing._counters.clear()
     text = builder.build().lower(state, batch).compile().as_text()
+    _STEP_TEXT[case] = builder, text, dict(tracing.counters())
+    return _STEP_TEXT[case]
+
+
+@pytest.mark.parametrize(
+    "case", sorted(c for c in STEP_CASES if "scopes" in STEP_CASES[c])
+)
+def test_step_names_its_kernels_and_phases(topo, case):
+    import re
+
+    from dlrover_tpu.observability import runtime_timer
+
+    spec = STEP_CASES[case]
+    builder, text, counters = _compiled_step(topo, case)
+    comm = spec["comm"]
 
     # every Pallas kernel's instruction is named after the kernel
     kernel_lines = [
@@ -262,7 +292,7 @@ def test_step_names_its_kernels_and_phases(topo, case):
     if comm:
         wanted.add("exchange")
         plan = builder._plan
-        assert tracing.counters()["zero.exchange_bytes"] == (
+        assert counters["zero.exchange_bytes"] == (
             (plan.n_buckets + plan.n_tie_buckets) * plan.bucket_elems * 4
         )
     assert wanted <= phases, wanted - phases
@@ -275,3 +305,29 @@ def test_step_names_its_kernels_and_phases(topo, case):
             assert phase == "backward", (name, op_names[name])
         else:
             assert phase in ("forward", "recompute"), (name, op_names[name])
+
+
+@pytest.mark.parametrize("case", ["zero1-dp4", "zero2-dp4"])
+def test_zero_step_has_no_stream_relayout_loop(topo, case):
+    """A 2-D array is tiled (8, 128) on the chip and a 1-D one by 1024,
+    so a reshape between the 1-D parameter stream and
+    ``[n_buckets, bucket_elems]`` compiles to a ``while`` that copies
+    one 4 MiB row per trip at a twentieth of the memory's rate (17% of
+    the dp=4 step before PR 26). Its signature is a ``while`` that
+    carries the whole stream as a 1-D f32 array."""
+    import re
+
+    builder, text, _ = _compiled_step(topo, case)
+    plan = builder._plan
+    assert plan.tie_size and plan.n_buckets > 100
+    streams = {plan.padded, plan.n_tie_buckets * plan.bucket_elems}
+    whiles = [ln for ln in text.splitlines() if " while(" in ln]
+    assert whiles  # the layer scans: the text is the step's
+    for ln in whiles:
+        carried = ln.split(" while(")[0]
+        hit = [
+            n for n in re.findall(r"f32\[(\d+)\]", carried)
+            if int(n) in streams
+        ]
+        assert not hit, ln[:200]
+    assert "tpu_custom_call" in text

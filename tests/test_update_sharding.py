@@ -240,17 +240,73 @@ def test_builder_falls_back_not_fails():
 # ---------------------------------------------------------------------------
 
 
-def test_pack_unpack_roundtrip():
-    cfg = tiny_cfg(tie_embeddings=False)
+@pytest.mark.parametrize("tied", [False, True], ids=["untied", "tied"])
+@pytest.mark.parametrize(
+    # 0.008 MB rounds up to 4096-element buckets, most rows inside one
+    # leaf; 0.05 MB to 14336, most rows straddling leaves
+    "bucket_mb", [0.008, 0.05], ids=["rows-in-leaves", "rows-straddle"]
+)
+def test_stream_values_and_coordinates(tied, bucket_mb):
+    """Every spelling of the stream holds the same values at the same
+    coordinates as a plain concatenation of the leaves, bit for bit:
+    the whole stream, each rank's shard packed alone, the tied head's
+    own buckets, and back again through the gather."""
+    cfg = tiny_cfg(tie_embeddings=tied)
     mesh = dp_mesh()
-    b = TrainStepBuilder(cfg, mesh, optax.adamw(1e-3), comm=comm_cfg())
+    b = TrainStepBuilder(
+        cfg, mesh, optax.adamw(1e-3), comm=comm_cfg(bucket_mb=bucket_mb)
+    )
     plan = b._plan
+    nb, e, q = plan.n_buckets, plan.bucket_elems, plan.bucket_elems // DP
     state = init_train_state(jax.random.key(0), cfg, mesh, optax.adamw(1e-3))
-    flat = shd.pack_flat(state["params"], plan)
-    assert flat.shape == (plan.n_buckets, plan.bucket_elems)
-    back = shd.unpack_flat(flat, state["params"], plan)
-    for x, y in zip(jax.tree.leaves(state["params"]), jax.tree.leaves(back)):
-        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    params = state["params"]
+    leaves = [np.asarray(x).reshape(-1) for x in jax.tree.leaves(params)]
+    want = np.zeros(plan.padded, np.float32)
+    want[: plan.total] = np.concatenate(leaves)
+    want = want.reshape(nb, e)
+    inside = sum(
+        any(o <= i * e and (i + 1) * e <= o + n
+            for o, n in zip(plan.offsets, plan.sizes))
+        for i in range(nb)
+    )
+    assert 0 < inside < nb  # both kinds of row are here
+
+    flat = shd.pack_flat(params, plan)
+    np.testing.assert_array_equal(np.asarray(flat), want)
+    np.testing.assert_array_equal(
+        np.stack([np.asarray(r) for r in shd.pack_buckets(params, plan)]),
+        want,
+    )
+    shard = jax.jit(lambda p, i: shd.pack_shard(p, plan, i))
+    for r in range(DP):
+        np.testing.assert_array_equal(
+            np.asarray(shard(params, jnp.int32(r))),
+            want[:, r * q : (r + 1) * q],
+        )
+    if tied:
+        rows = shd.pack_buckets([params["embed"]["tokens"]], plan,
+                                plan.n_tie_buckets)
+        tie = np.zeros(plan.n_tie_buckets * e, np.float32)
+        tie[: plan.tie_size] = leaves[0]
+        np.testing.assert_array_equal(
+            np.stack([np.asarray(r) for r in rows]),
+            tie.reshape(plan.n_tie_buckets, e),
+        )
+
+    gathered = jax.shard_map(
+        lambda s: shd.gather_stream(s, "dp"),
+        mesh=mesh,
+        in_specs=jax.sharding.PartitionSpec(None, "dp"),
+        out_specs=jax.sharding.PartitionSpec(),
+        check_vma=False,
+    )(flat)
+    assert gathered.shape == (nb, e // 128, 128)
+    np.testing.assert_array_equal(np.asarray(gathered).reshape(nb, e), want)
+    for stream in (flat, gathered):
+        back = shd.unpack_flat(stream, params, plan)
+        for x, y in zip(jax.tree.leaves(params), jax.tree.leaves(back)):
+            assert x.shape == y.shape and x.dtype == y.dtype
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
 # ---------------------------------------------------------------------------
