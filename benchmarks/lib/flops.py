@@ -12,6 +12,10 @@ Convention (fixed here so that no later change can move it):
   the learned position table, not norm scales or biases: a gather and
   an elementwise scale are not matrix multiplications. A tied head
   counts once, as the head. The 6 is forward (2) plus backward (4).
+- A routed layer (``n_experts`` > 0) counts what a token meets: the
+  ``expert_top_k`` experts it is sent to and the router's
+  ``d_model x n_experts`` matrix, not the experts it never visits.
+  Sort, gather and scatter multiply nothing.
 - The attention term is the score and the value matmul, forward and
   backward (2 x 2 x 3 = 12 per query-key pair and channel), over the
   keys a query really attends to: ``mean_span`` is the mean number of
@@ -27,7 +31,9 @@ This reads LOWER than ``ModelConfig.flops_per_token`` in the program
 
 Sizes come from the configuration file's ``sizes`` group, in the
 program's own vocabulary (``n_layer``, ``d_model``, ``n_head``,
-``n_kv_head``, ``d_ff``, ``vocab_size``, ``act``, ``attn_window``).
+``n_kv_head``, ``d_ff``, ``vocab_size``, ``act``, ``attn_window``,
+and for a routed model ``n_experts`` and ``expert_top_k``; absent or 0
+is a dense MLP).
 """
 
 
@@ -45,6 +51,9 @@ def multiplied_params(sizes: dict) -> int:
     kv = sizes.get("n_kv_head") or sizes["n_head"]
     attn = 2 * d * sizes["n_head"] * head_dim + 2 * d * kv * head_dim
     mlp = (3 if sizes["act"] == "swiglu" else 2) * d * sizes["d_ff"]
+    n_experts = sizes.get("n_experts") or 0
+    if n_experts:
+        mlp = sizes["expert_top_k"] * mlp + d * n_experts
     head = d * sizes["vocab_size"]
     return sizes["n_layer"] * (attn + mlp) + head
 

@@ -25,6 +25,10 @@ Definitions (all per device, then averaged over the devices used):
   nested in it, so that a ``while`` is not counted on top of its body.
 - Pallas share: self time of the operations that are Pallas kernels
   (``is_pallas``) over busy.
+- by name: every operation's self time and number of calls under its
+  ``label`` (short name, opcode, kernel mark, output shape). The self
+  times of a device add up to its busy time; ``device_ops`` is the
+  first device's table cut to the largest few.
 - exposed collective time: measure of (union of collective intervals)
   minus (union of every other leaf operation's interval): the time a
   collective runs and no compute does. A collective's interval is its
@@ -219,10 +223,11 @@ def reduce(planes, window_span=None, top=10):
         comp = union(
             (s, e) for n, s, e, _x, _l in leaves if not is_collective(n)
         )
-        by_name, pallas_ns = {}, 0.0
+        by_name, pallas_ns = {}, 0.0  # label -> [self ns, calls]
         for name, _s, _e, self_ns, _leaf in timed:
-            key = label(name)
-            by_name[key] = by_name.get(key, 0.0) + self_ns
+            row = by_name.setdefault(label(name), [0.0, 0])
+            row[0] += self_ns
+            row[1] += 1
             if is_pallas(name):
                 pallas_ns += self_ns
         gaps = subtract([(t0, t1)], busy)
@@ -233,7 +238,7 @@ def reduce(planes, window_span=None, top=10):
             "pallas_s": pallas_ns / 1e9,
             "collective_s": measure(coll) / 1e9,
             "collective_exposed_s": measure(subtract(coll, comp)) / 1e9,
-            "by_name": by_name,
+            "by_name": {k: [ns / 1e9, n] for k, (ns, n) in by_name.items()},
             "gaps": gaps,
         })
     # the breakdown names the first device's operations and gaps; the
@@ -254,12 +259,13 @@ def reduce(planes, window_span=None, top=10):
         "devices": len(per_device),
         **totals,
         "device_ops": _ranked(
-            {k: v / 1e9 for k, v in first["by_name"].items()}, top
+            {k: v[0] for k, v in first["by_name"].items()}, top
         ),
         "idle_gaps": _ranked(gap_by_span, top),
+        # with each device's whole table of operations, ``by_name``:
+        # label -> [self seconds, calls] inside the window
         "per_device": [
-            {k: v for k, v in d.items() if k not in ("by_name", "gaps")}
-            for d in per_device
+            {k: v for k, v in d.items() if k != "gaps"} for d in per_device
         ],
     }
 

@@ -29,10 +29,16 @@ from benchmarks.lib.spans import Spans
 from benchmarks.lib.watch import CompileWatch, hbm, synthetic_batch
 
 # The comparison that decides ``correct``, with the reason for each
-# tolerance. Kernel path: the program's flash attention, fused norms and
-# fused cross-entropy, bf16 activations (and bf16 weights in the
-# one-chip cells). Reference: the configuration's plain float32 forward
-# under matmul precision "highest", same weights, same tokens.
+# tolerance. A configuration names its kind (``"check": {"kind": ...}``;
+# absent: ``dense``). ``dense`` holds the program's free-running logits
+# to the reference's. ``routed`` (``benchmarks/lib/routed.py``) holds
+# them to a reference sent to the experts the program chose, holds those
+# choices to a regret limit, and the free-running logits are recorded
+# only; the tolerances below are the same for both. Kernel path: the
+# program's flash attention, fused norms and fused cross-entropy, bf16
+# activations (and bf16 weights in the one-chip cells). Reference: the
+# configuration's plain float32 forward under matmul precision
+# "highest", same weights, same tokens.
 #
 # Logits, max |difference| over max |reference|. An extreme value over
 # 8192 x vocabulary elements: 1.2e-2..1.9e-2 on a v5e in all three
@@ -158,13 +164,14 @@ def run(ctx):
         hbm=hbm(devices),
     )
 
-    warm = []
+    warm, step_metrics = [], []  # the latter: the program's own, per step
     for i in range(traffic["warmup_steps"]):
         batch = place(WARMUP_INDEX + i)
         t0 = time.perf_counter()
         state, metrics = compiled(state, batch)
         loss = float(metrics["loss"])
         warm.append({"loss": loss, "s": time.perf_counter() - t0})
+        step_metrics.append(metrics)
     say(event="warmup", steps=warm)
 
     checks = _check_outputs(ctx, cfg, mesh, state, batch0, devices[0])
@@ -224,7 +231,7 @@ def run(ctx):
     reduced = None
     if ctx["trace"]:
         reduced = _traced_window(
-            ctx, spans, compiled, state, place, attempted
+            ctx, spans, compiled, state, place, attempted, step_metrics
         )
 
     tokens = gb * seq
@@ -252,6 +259,21 @@ def run(ctx):
         "chips": cell["chips"],
         "peaks": peaks,
         "trace": reduced,
+        # the program's step metrics of the warm-up and the traced steps
+        # (the window reads back its loss and nothing else)
+        "step_metrics": _by_name(step_metrics),
+    }
+
+
+def _by_name(step_metrics):
+    """name -> one float per step, for the scalar metrics of the
+    program's step."""
+    import numpy as np
+
+    return {
+        name: [float(m[name]) for m in step_metrics]
+        for name in (step_metrics[0] if step_metrics else ())
+        if np.ndim(step_metrics[0][name]) == 0
     }
 
 
@@ -279,9 +301,12 @@ def _check_outputs(ctx, cfg, mesh, state, batch0, first):
     and each share runs on the first device alone (on four chips the
     parameters are copied there once). Every share goes through the
     step's own forward (``decoder.loss_fn``: flash attention, fused
-    norms, fused cross-entropy), and their mean is what the first timed
-    step's loss is held to. The first share also goes through
-    ``decoder.forward`` for logits and through the reference."""
+    norms, fused cross-entropy), and the mean of their
+    ``metrics["loss"]`` is what the first timed step's ``metrics["loss"]``
+    is held to: like with like, whatever else the objective adds. The
+    first share also goes through ``decoder.forward`` for logits and
+    through the reference, whose mean cross-entropy is held against the
+    program's."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -289,6 +314,9 @@ def _check_outputs(ctx, cfg, mesh, state, batch0, first):
     from dlrover_tpu.models import decoder
 
     config, traffic, sizes = ctx["config"], ctx["traffic"], ctx["config"]["sizes"]
+    kind = config.get("check", {}).get("kind", "dense")
+    if kind not in ("dense", "routed"):
+        raise ValueError(f"no comparison of kind {kind!r}")
     reference = importlib.import_module(
         "benchmarks.references." + config["reference"]
     )
@@ -306,7 +334,7 @@ def _check_outputs(ctx, cfg, mesh, state, batch0, first):
 
     @jax.jit
     def kernel_loss(params, batch):
-        return decoder.loss_fn(params, batch, cfg=cfg)[0]
+        return decoder.loss_fn(params, batch, cfg=cfg)[1]["loss"]
 
     @jax.jit
     def kernel_logits(params, batch):
@@ -328,32 +356,58 @@ def _check_outputs(ctx, cfg, mesh, state, batch0, first):
     t0 = time.perf_counter()
     batches = [share(i) for i in range(shares)]
     kernel_losses = [float(kernel_loss(params, b)) for b in batches]
-    logits = kernel_logits(params, batches[0])
+    if kind == "routed":
+        from benchmarks.lib import routed
+
+        logits, choices = routed.program_logits_and_choices(
+            params, batches[0]["tokens"], cfg
+        )
+        program = routed.program_losses(params, batches[0], cfg)
+        ce_loss = program.get("ce_loss", program["loss"])
+    else:
+        logits = kernel_logits(params, batches[0])
+        ce_loss = kernel_losses[0]  # a dense objective is its cross-entropy
     ref_loss, logit_err, logit_rms = (
         float(x) for x in against_reference(params, batches[0], logits)
     )
-    del logits, params
-    loss_err = abs(kernel_losses[0] - ref_loss) / abs(ref_loss)
-    ctx["say"](
+    loss_err = abs(ce_loss - ref_loss) / abs(ref_loss)
+    record = dict(
         event="reference", kernel_losses=kernel_losses, ref_loss=ref_loss,
         logit_err=logit_err, logit_rms=logit_rms, loss_err=loss_err,
         rows=rows, shares=shares,
-        wall_s=time.perf_counter() - t0,
     )
-    return {
-        "kernel_loss": sum(kernel_losses) / len(kernel_losses),
-        "results": [
+    if kind == "routed":
+        # free-running logits are recorded; what is judged of that
+        # reference is the loss, which never saw the program's choices
+        results, forced = routed.compare(
+            reference, params, batches[0], sizes,
+            traffic["check"]["q_block"], logits, choices, program,
+            (LOGIT_TOL, LOGIT_RMS_TOL, LOSS_TOL),
+        )
+        results.append((
+            "loss_vs_free_reference", loss_err <= routed.FREE_LOSS_TOL,
+            loss_err,
+        ))
+        record.update(kind=kind, program_losses=program, **forced)
+    else:
+        results = [
             ("logits_vs_reference", logit_err <= LOGIT_TOL, logit_err),
             ("logits_rms_vs_reference", logit_rms <= LOGIT_RMS_TOL, logit_rms),
             ("loss_vs_reference", loss_err <= LOSS_TOL, loss_err),
-        ],
+        ]
+    del logits, params
+    ctx["say"](**record, wall_s=time.perf_counter() - t0)
+    return {
+        "kernel_loss": sum(kernel_losses) / len(kernel_losses),
+        "results": results,
     }
 
 
-def _traced_window(ctx, spans, compiled, state, place, index):
+def _traced_window(ctx, spans, compiled, state, place, index, step_metrics):
     """A few more steps under the profiler, in a window of their own,
     reduced by the benchmark's own code. The trace is written inside
-    the checkout and removed once it is reduced."""
+    the checkout and removed once it is reduced. Each step's metrics
+    are appended to ``step_metrics`` as they are, on the device."""
     import jax
 
     out = os.path.join(
@@ -372,6 +426,7 @@ def _traced_window(ctx, spans, compiled, state, place, index):
                         state, metrics = compiled(state, batch)
                     with spans.span("readback"):
                         float(metrics["loss"])
+                    step_metrics.append(metrics)
         finally:
             jax.profiler.stop_trace()
         t0 = time.perf_counter()
@@ -379,7 +434,11 @@ def _traced_window(ctx, spans, compiled, state, place, index):
         reduced = tracelib.reduce(planes, window_span="bench.traced_window")
         ctx["say"](
             event="trace", parse_s=time.perf_counter() - t0,
-            planes=[p["name"] for p in planes], reduced=reduced,
+            planes=[p["name"] for p in planes],
+            reduced=reduced and dict(reduced, per_device=[
+                {k: v for k, v in d.items() if k != "by_name"}
+                for d in reduced["per_device"]
+            ]),
         )
         return reduced
     finally:

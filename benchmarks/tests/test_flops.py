@@ -102,3 +102,46 @@ def test_peak_table():
     for unknown in ("cpu", "TPU v7x", "NVIDIA H100"):
         with pytest.raises(KeyError):
             peaks.chip_peaks(unknown)
+
+
+# OLMoE-1B-7B's widths (allenai/OLMoE-1B-7B-0125-Instruct) at 3 layers:
+# attention 4 x 2048^2 = 16.777 M; one expert 3 x 2048 x 1024 = 6.291 M,
+# eight of 64 per token; router 2048 x 64 = 0.131 M; head 2048 x 50304
+OLMOE = {
+    "n_layer": 3, "d_model": 2048, "n_head": 16, "n_kv_head": 16,
+    "d_ff": 1024, "vocab_size": 50304, "act": "swiglu", "attn_window": 0,
+}
+
+
+def test_routed_layers_count_what_a_token_meets():
+    routed = dict(OLMOE, n_experts=64, expert_top_k=8)
+    by_hand = 3 * (16_777_216 + 8 * 6_291_456 + 131_072) + 2048 * 50304
+    assert flops.multiplied_params(routed) == by_hand
+    attention = 12 * 3 * 2048 * 2048.5
+    need = flops.required_flops_per_token(routed, 4096)
+    assert need == pytest.approx(6 * by_hand + attention)
+    assert round(need / 1e9, 3) == 1.979
+    # without the keys, or with no experts, a layer's MLP is one dense
+    # block of d_ff: the count of PR 24
+    for dense in (OLMOE, dict(OLMOE, n_experts=0, expert_top_k=8)):
+        assert flops.multiplied_params(dense) == (
+            3 * (16_777_216 + 6_291_456) + 2048 * 50304
+        )
+        assert round(
+            flops.required_flops_per_token(dense, 4096) / 1e9, 3
+        ) == 1.184
+
+
+# the committed configurations, to the last bit: what
+# ``required_flops_per_token`` gives on the parent of PR 28 (commit 625dd4b)
+PARENT = {
+    "gpt2-xl": (1024, 9802598400.0),
+    "mistral-7b-l6": (8192, 9544212480.0),
+    "gpt2-xl-zero1-dp4": (1024, 5142758400.0),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PARENT))
+def test_dense_counts_did_not_move(name):
+    seq, parent = PARENT[name]
+    assert flops.required_flops_per_token(_sizes(name), seq) == parent

@@ -141,3 +141,31 @@ def test_no_device_plane_gives_nothing():
     assert trace.reduce([HOST], window_span="bench.traced_window") is None
     empty = {"name": "/device:TPU:0", "lines": [{"name": "XLA Ops", "events": []}]}
     assert trace.reduce([HOST, empty]) is None
+
+
+def test_by_name_table_is_whole_and_counts_calls():
+    r = trace.reduce(
+        [HOST, _device(0), _device(1, shift_us=50)],
+        window_span="bench.traced_window", top=2,
+    )
+    assert len(r["device_ops"]) == 2  # the printed cut; the table is whole
+    for dev in r["per_device"]:
+        table = dev["by_name"]
+        assert table["fusion.7 fusion bf16[8,128,256]"] == [
+            pytest.approx(440e-6), 4
+        ]
+        assert table[trace.label(KERNEL)] == [pytest.approx(200e-6), 1]
+        assert table[trace.label(WHILE)] == [pytest.approx(0.0, abs=1e-12), 1]
+        assert table[trace.label(AR_START)][1] == 1
+        assert table[trace.label(AR_DONE)] == [pytest.approx(100e-6), 1]
+        assert len(table) == 5
+        # operations on one line nest or follow each other, so their
+        # self times add up to the busy time
+        assert sum(s for s, _n in table.values()) == pytest.approx(
+            dev["busy_s"]
+        )
+        assert sum(n for _s, n in table.values()) == 8
+    assert dict(r["device_ops"]) == {
+        k: v[0] for k, v in r["per_device"][0]["by_name"].items()
+        if v[0] >= 200e-6
+    }
