@@ -52,6 +52,10 @@ class ModelConfig:
     # across 2 heads per program; 1 disables)
     attn_head_pack: int = 0
     rope_theta: float = 10000.0
+    # RMS/LayerNorm (cfg.norm) over the WHOLE q and k projections
+    # (n_head·head_dim / kv_heads·head_dim wide, one scale each), before
+    # the head split and rope — OLMoE's q_norm/k_norm
+    qk_norm: bool = False
     tie_embeddings: bool = True
     # numerics
     dtype: str = "bfloat16"          # activation/compute dtype
@@ -89,6 +93,10 @@ class ModelConfig:
     # stays ragged; tokens past the bound drop. ep (the worst case)
     # guarantees droplessness at ep× wire cost.
     moe_a2a_bound: float = 2.0
+    # top-k combine weights divided by their sum (Mixtral) or the raw
+    # softmax probabilities at the chosen experts (OLMoE's
+    # norm_topk_prob=false). Switch gating is always raw.
+    moe_renorm_topk: bool = True
     # pipeline microbatches when the mesh has pp > 1 (0 → one per stage)
     pp_microbatches: int = 0
     # interleaved (circular) pipeline: v layer chunks per stage cut the
@@ -187,6 +195,11 @@ class ModelConfig:
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_head
+
+    @property
+    def routed_top_k(self) -> int:
+        """Experts a token is sent to: switch gating is top-1."""
+        return 1 if self.moe_gating == "switch" else self.expert_top_k
 
     def num_params(self) -> int:
         """Approximate parameter count (dense part)."""
@@ -384,6 +397,23 @@ CONFIGS = {
         moe_aux_coef=0.01,
         moe_z_coef=0.001,
         moe_alltoall=True,  # ep>1 meshes must not replicate expert acts
+    ),
+    # many narrow experts: OLMoE-1B-7B (arXiv 2409.02060) — 64 SwiGLU
+    # experts of width 1024, top-8 with raw softmax weights, dropless,
+    # data parallel with every expert on every device; MHA with
+    # whole-projection QK-norm
+    "olmoe-1b-7b": replace(
+        _llama(
+            "olmoe-1b-7b", 16, 16, 2048, 1024, max_seq=4096, n_kv_head=16
+        ),
+        vocab_size=50304,
+        qk_norm=True,
+        n_experts=64,
+        expert_top_k=8,
+        moe_impl="ragged",
+        moe_renorm_topk=False,
+        moe_aux_coef=0.01,
+        moe_z_coef=0.001,
     ),
 }
 

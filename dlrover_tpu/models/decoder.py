@@ -85,6 +85,10 @@ def init(rng: jax.Array, cfg: ModelConfig) -> Params:
             "w_up": stack(keys[6], (d, f), d),
             "w_down": stack(keys[7], (f, d), f),
         }
+    if cfg.qk_norm:
+        attn = params["layers"]["attn"]
+        attn["q_norm"] = {"scale": jnp.ones((L, nh * hd), pdt)}
+        attn["k_norm"] = {"scale": jnp.ones((L, nkv * hd), pdt)}
     if cfg.norm == "layernorm":
         params["layers"]["ln1"]["bias"] = jnp.zeros((L, d), pdt)
         params["layers"]["ln2"]["bias"] = jnp.zeros((L, d), pdt)
@@ -131,6 +135,9 @@ def logical_axes(cfg: ModelConfig) -> Params:
             "w_up": ("layers", "embed", "mlp"),
             "w_down": ("layers", "mlp", "embed"),
         }
+    if cfg.qk_norm:
+        ax["layers"]["attn"]["q_norm"] = {"scale": ("layers", "norm")}
+        ax["layers"]["attn"]["k_norm"] = {"scale": ("layers", "norm")}
     if cfg.norm == "layernorm":
         ax["layers"]["ln1"]["bias"] = ("layers", "norm")
         ax["layers"]["ln2"]["bias"] = ("layers", "norm")
@@ -331,20 +338,31 @@ def _project_qkv(
 
     ``rope``: precomputed (cos, sin) tables from ``_rope_tables`` —
     the trunk/prefill/decode loops build them once and pass them to
-    every layer; None recomputes here (external callers, pp bodies)."""
+    every layer; None recomputes here (external callers, pp bodies).
+
+    ``cfg.qk_norm``: q and k are normed over their WHOLE projection
+    (all heads at once; ``attn.q_norm`` / ``attn.k_norm``, scale only)
+    before the head split, the remat tags and rope, whichever GEMM made
+    them. The statistic spans the heads axis, which ``tp`` shards:
+    ``forward`` refuses such a mesh rather than hand the norm kernel
+    one shard of the heads."""
     b, s, _ = x.shape
     nh, nkv, hd = cfg.n_head, cfg.kv_heads, cfg.head_dim
+    attn = layer["attn"]
     if fp8 is not None:
-        q = _fp8_gemm(x, layer["attn"]["wq"].astype(x.dtype), fp8, "wq")
-        k = _fp8_gemm(x, layer["attn"]["wk"].astype(x.dtype), fp8, "wk")
-        v = _fp8_gemm(x, layer["attn"]["wv"].astype(x.dtype), fp8, "wv")
-        q = q.reshape(b, s, nh, hd)
-        k = k.reshape(b, s, nkv, hd)
-        v = v.reshape(b, s, nkv, hd)
+        q = _fp8_gemm(x, attn["wq"].astype(x.dtype), fp8, "wq")
+        k = _fp8_gemm(x, attn["wk"].astype(x.dtype), fp8, "wk")
+        v = _fp8_gemm(x, attn["wv"].astype(x.dtype), fp8, "wv")
     else:
-        q = (x @ layer["attn"]["wq"].astype(x.dtype)).reshape(b, s, nh, hd)
-        k = (x @ layer["attn"]["wk"].astype(x.dtype)).reshape(b, s, nkv, hd)
-        v = (x @ layer["attn"]["wv"].astype(x.dtype)).reshape(b, s, nkv, hd)
+        q = x @ attn["wq"].astype(x.dtype)
+        k = x @ attn["wk"].astype(x.dtype)
+        v = x @ attn["wv"].astype(x.dtype)
+    if cfg.qk_norm:
+        q = _norm_block(q, attn["q_norm"], cfg)
+        k = _norm_block(k, attn["k_norm"], cfg)
+    q = q.reshape(b, s, nh, hd)
+    k = k.reshape(b, s, nkv, hd)
+    v = v.reshape(b, s, nkv, hd)
     # names for the selective remat policies (save_qkv / save_dots):
     # identity outside jax.checkpoint, so the cache paths are
     # unaffected. Tagged BEFORE rope: backward re-runs only the cheap
@@ -745,7 +763,11 @@ def run_trunk(
             x, auxs = jax.lax.scan(
                 scan_fn, x, (layers, jnp.arange(n_layers))
             )
+        # the expert ids are per layer, not a sum: stacked [L, B, S, k]
+        choices = auxs.pop("moe_choices", None)
         aux = jax.tree.map(lambda a: a.sum(), auxs)
+        if choices is not None:
+            aux["moe_choices"] = choices
     return x, aux
 
 
@@ -802,7 +824,9 @@ def forward(
     """tokens:[B,S] int32 → logits:[B,S,vocab] float32.
 
     ``return_aux=True`` additionally returns per-model MoE router losses
-    summed over layers ({moe_lb_loss, moe_z_loss}); ``rng`` enables
+    summed over layers ({moe_lb_loss, moe_z_loss}) and, for a routed
+    model, ``moe_choices``: the expert ids every token was sent to,
+    int32 [n_layer, B, S, k] (not under pp); ``rng`` enables
     switch-gating jitter during training. ``features_only=True`` returns
     the final-norm hidden states [B,S,D] instead of logits (value/reward
     heads attach here). ``prefix_len`` [B] int32 (prefix-LM configs):
@@ -848,6 +872,13 @@ def forward(
             attn_impl = (
                 "reference" if device.on_cpu() else "flash"
             )
+
+    if cfg.qk_norm and mesh is not None and mesh.shape.get("tp", 1) > 1:
+        raise ValueError(
+            "qk_norm takes its statistic over all heads of the q and k "
+            "projections, and tp shards the heads axis: run this model "
+            "with tp=1 (dp/fsdp/sp/ep meshes are fine)"
+        )
 
     if cfg.prefix_lm and prefix_len is None:
         # a GLM-family model silently training fully-causal is the worst
@@ -1060,6 +1091,10 @@ def _loss_from_head(
         loss = loss + lb + rz
         metrics["moe_lb_loss"] = lb
         metrics["moe_z_loss"] = rz
+    if "moe_max_load" in moe_aux:
+        # rows of the fullest expert over the mean rows an expert gets,
+        # mean over layers (run_trunk summed them)
+        metrics["moe_max_load"] = moe_aux["moe_max_load"] / cfg.n_layer
     acc = (amax == targets).astype(jnp.float32) * mask
     metrics["accuracy"] = acc.sum() / denom
     return loss, metrics

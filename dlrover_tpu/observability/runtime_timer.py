@@ -58,11 +58,19 @@ DEVICE_PLANE = re.compile(r"^/device:(TPU|CPU):\d+$")
 OPS_LINE = "XLA Ops"
 PHASES = ("forward", "recompute", "backward", "optimizer", "exchange", "other")
 # the step's named scopes (models/decoder.py, train/train_step.py,
-# parallel/sharding.py); jax renders a scope inside the transforms
-# around it, ``transpose(jvp(embed))``, so delimiters are / ( )
+# parallel/sharding.py, and inside ``mlp`` parallel/moe.py's
+# ``moe.route|sort|experts|combine``); jax renders a scope inside the
+# transforms around it, ``transpose(jvp(embed))``, so delimiters are / ( )
 _SCOPE = re.compile(
-    r"[/(](embed|attn|mlp|head_loss|optimizer|zero\.[a-z]+)(?=[/)]|$)"
+    r"[/(](embed|attn|mlp|head_loss|optimizer|zero\.[a-z]+|moe\.[a-z]+)"
+    r"(?=[/)]|$)"
 )
+# A kernel the compiler itself puts in place of a primitive keeps no
+# name stack: its whole ``op_name`` is the kernel's name
+# (``lax.ragged_dot`` → ``ragged-dot-none``, ``ragged-dot-metadata``).
+# Its scope is known by that name; whether a call is forward,
+# recomputed or backward is not, and its phase reads ``other``.
+_RAGGED_DOT = "ragged-dot"
 # host events that are the program's own spans (tracing.py call sites)
 SPAN_PREFIXES = ("train.", "ckpt.", "serving.", "failover.", "brain.")
 # the annotations profiled_call opens inside its profiler session
@@ -78,6 +86,7 @@ class OpTime:
     count: int
     fraction: float = 0.0
     phase: str = ""
+    scope: str = ""
 
 
 @dataclass
@@ -92,6 +101,8 @@ class DeviceProfile:
     pallas_s: float = 0.0
     by_op: List[OpTime] = field(default_factory=list)
     by_phase: Dict[str, float] = field(default_factory=dict)  # seconds
+    # seconds by innermost named scope of the step ("" = outside them)
+    by_scope: Dict[str, float] = field(default_factory=dict)
     gaps: List[Tuple[str, float]] = field(default_factory=list)
 
     @property
@@ -214,7 +225,9 @@ def is_collective(event_name: str) -> bool:
 def scope_of(op_name: str) -> str:
     """The innermost named scope of the step in an ``op_name``, or ""."""
     found = _SCOPE.findall(op_name)
-    return found[-1] if found else ""
+    if found:
+        return found[-1]
+    return "moe.experts" if op_name.startswith(_RAGGED_DOT) else ""
 
 
 def phase_of(event_name: str, op_name: str) -> str:
@@ -354,14 +367,19 @@ def reduce_planes(
         for name, _s, _e, self_ns in timed:
             short = short_name(name)
             op_name = op_names.get(short, "")
-            phase = phase_of(name, op_name)
+            phase, scope = phase_of(name, op_name), scope_of(op_name)
             row = by_op.get(short)
             if row is None:
-                row = by_op[short] = OpTime(short, 0.0, 0, phase=phase)
+                row = by_op[short] = OpTime(
+                    short, 0.0, 0, phase=phase, scope=scope
+                )
             row.total_us += self_ns / 1e3
             row.count += 1
             profile.by_phase[phase] = (
                 profile.by_phase.get(phase, 0.0) + self_ns / 1e9
+            )
+            profile.by_scope[scope] = (
+                profile.by_scope.get(scope, 0.0) + self_ns / 1e9
             )
         total_us = sum(o.total_us for o in by_op.values()) or 1.0
         for row in by_op.values():

@@ -50,20 +50,27 @@ def top_k_gating(
 ):
     """Token-choice top-k routing with per-sequence capacity.
 
-    gate_logits: [B, S, E] → (dispatch [B,S,E,C] bool, combine [B,S,E,C]).
-    Tokens overflowing an expert's capacity are dropped (standard GShard
-    behavior; the residual connection carries them through).
+    gate_logits: [B, S, E] → (dispatch [B,S,E,C] bool, combine [B,S,E,C],
+    probs [B,S,E]). Tokens overflowing an expert's capacity are dropped
+    (standard GShard behavior; the residual connection carries them
+    through).
 
     ``renormalize``: rescale combine weights to sum to 1 over kept
     choices (Mixtral-style). MUST be False for k=1: renormalizing a
     single choice yields the constant 1.0, which has zero derivative
     w.r.t. the router logits — the router would never train.
     """
+    return _capacity_gating(gate_logits, k, capacity, renormalize)[:3]
+
+
+def _capacity_gating(gate_logits, k: int, capacity: int, renormalize: bool):
+    """``top_k_gating`` plus the top-k expert ids [B,S,k] as chosen,
+    before any drop."""
     b, s, e = gate_logits.shape
     probs = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
-    # raw per-choice weights from the shared rule; the capacity-kept
-    # masking below is this path's only divergence from _topk_weights
-    # (renormalization must run over KEPT choices, after drops)
+    # the choice is _topk_weights' (top-k of the probabilities); the
+    # weights are not: renormalization here runs over KEPT choices,
+    # after drops
     gate_vals, gate_idx = jax.lax.top_k(probs, k)  # [B,S,k]
     # one-hot expert assignment per choice: [B, S, k, E]
     assign = jax.nn.one_hot(gate_idx, e, dtype=jnp.float32)
@@ -87,7 +94,7 @@ def top_k_gating(
         weights = gate_vals * kept
     dispatch = jnp.einsum("bske,bskc->bsec", assign, slot)
     combine = jnp.einsum("bsk,bske,bskc->bsec", weights, assign, slot)
-    return dispatch, combine, probs
+    return dispatch, combine, probs, gate_idx
 
 
 def switch_gating(
@@ -140,11 +147,14 @@ def _jitter(gate_logits, jitter_eps, rng):
 
 
 def _topk_weights(probs, k: int, renormalize: bool):
-    """Top-k choice + combine-weight rule — THE router weight rule,
-    shared by the capacity paths (via top_k_gating) and the ragged path
-    (via _route) so the lowerings cannot drift apart.
+    """Top-k choice + combine weights of the dropless (ragged) path:
+    the k largest probabilities, divided by their sum when
+    ``renormalize``. The capacity path (``_capacity_gating``) makes the
+    same choice but renormalizes over the choices it KEPT. Both take
+    the flag from ``_renormalize(cfg)``, so a model's rule holds in
+    every lowering.
 
-    ``renormalize`` MUST be False for k=1: renormalizing a single choice
+    ``renormalize`` is ignored for k=1: renormalizing a single choice
     yields the constant 1.0, which has zero derivative w.r.t. the router
     logits — the router would never train. Raw router probability
     (Switch: y = p_i(x)·E_i(x)) keeps it differentiable."""
@@ -158,37 +168,55 @@ def _topk_weights(probs, k: int, renormalize: bool):
     return weights, gate_idx
 
 
+def _renormalize(cfg) -> bool:
+    """Whether the model's combine weights are divided by their sum:
+    ``cfg.moe_renorm_topk`` (Mixtral yes, OLMoE no); Switch never."""
+    return cfg.moe_renorm_topk and cfg.moe_gating != "switch"
+
+
+def _router_logits(x, moe, cfg, rng):
+    """Router logits [B,S,E] in float32 straight from the matmul (the
+    operands stay in the compute dtype): a bf16 result would round the
+    logits before the softmax and move near-tied top-k choices. Switch
+    jitter applied."""
+    gate_logits = jnp.matmul(
+        x, moe["w_gate"].astype(x.dtype),
+        preferred_element_type=jnp.float32,
+    )
+    if cfg.moe_gating == "switch":
+        gate_logits = _jitter(gate_logits, cfg.moe_jitter, rng)
+    return gate_logits
+
+
 def _route(x, moe, cfg, rng):
     """Shared router entry for the ragged path: logits (+switch jitter)
     → probs, combine weights, expert choices."""
-    k = 1 if cfg.moe_gating == "switch" else cfg.expert_top_k
-    gate_logits = x @ moe["w_gate"].astype(x.dtype)
-    if cfg.moe_gating == "switch":
-        gate_logits = _jitter(gate_logits, cfg.moe_jitter, rng)
-    probs = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
-    weights, gate_idx = _topk_weights(
-        probs, k, renormalize=cfg.moe_gating != "switch"
-    )
+    with jax.named_scope("moe.route"):
+        gate_logits = _router_logits(x, moe, cfg, rng)
+        probs = jax.nn.softmax(gate_logits, axis=-1)
+        weights, gate_idx = _topk_weights(
+            probs, cfg.routed_top_k, _renormalize(cfg)
+        )
     return gate_logits, probs, weights, gate_idx
 
 
 def _gate(x, moe, cfg, rng):
-    b, s, d = x.shape
-    e = cfg.n_experts
-    k = 1 if cfg.moe_gating == "switch" else cfg.expert_top_k
-    capacity = max(1, int(cfg.capacity_factor * s * k / e))
-    gate_logits = x @ moe["w_gate"].astype(x.dtype)
-    if cfg.moe_gating == "switch":
-        dispatch, combine, probs = switch_gating(
-            gate_logits, capacity, cfg.moe_jitter, rng
-        )
-    else:
-        dispatch, combine, probs = top_k_gating(gate_logits, k, capacity)
+    """Router entry of the capacity paths → (dispatch, combine, probs,
+    gate_logits, gate_idx [B,S,k] before drops)."""
+    k = cfg.routed_top_k
+    capacity = max(
+        1, int(cfg.capacity_factor * x.shape[1] * k / cfg.n_experts)
+    )
+    gate_logits = _router_logits(x, moe, cfg, rng)
+    dispatch, combine, probs, gate_idx = _capacity_gating(
+        gate_logits, k, capacity, _renormalize(cfg)
+    )
     return (
         dispatch.astype(x.dtype),
         combine.astype(x.dtype),
         probs,
         gate_logits,
+        gate_idx,
     )
 
 
@@ -231,6 +259,12 @@ def moe_block(
 ):
     """x: [B,S,D] → [B,S,D]. Expert FFN sharded over the ``ep`` axis.
 
+    ``return_aux`` adds the router's side: ``moe_lb_loss`` and
+    ``moe_z_loss`` (before their coefficients), ``moe_choices`` (the
+    top-k expert ids of every token, int32 [B,S,k]; before drops on the
+    capacity paths) and, on the ragged paths, ``moe_max_load`` (rows of
+    the fullest expert over the mean rows an expert gets).
+
     Three dispatch lowerings:
     - dense einsum (default): capacity-based one-hot dispatch/combine
       einsums + sharding constraints; XLA inserts the expert
@@ -262,10 +296,11 @@ def moe_block(
         out, aux = _moe_block_alltoall(x, moe, cfg, mesh, rng, fp8=fp8)
         return (out, aux) if return_aux else out
 
-    dispatch, combine, probs, gate_logits = _gate(x, moe, cfg, rng)
+    dispatch, combine, probs, gate_logits, gate_idx = _gate(x, moe, cfg, rng)
     aux = {
         "moe_lb_loss": load_balancing_loss(probs, dispatch),
         "moe_z_loss": router_z_loss(gate_logits),
+        "moe_choices": gate_idx,
     }
     # [E, B, C, D]: this einsum is the all-to-all when x is dp-sharded and
     # expert tensors are ep-sharded.
@@ -298,7 +333,9 @@ def _moe_block_alltoall(x, moe, cfg, mesh, rng, fp8=None):
             "w_gate_proj": w_gp,
             "w_down": w_down,
         }
-        dispatch, combine, probs, gate_logits = _gate(xl, local, cfg, rng)
+        dispatch, combine, probs, gate_logits, gate_idx = _gate(
+            xl, local, cfg, rng
+        )
         expert_in = jnp.einsum("bsec,bsd->ebcd", dispatch, xl)  # [E,b,C,D]
         # exchange: every rank sends each expert-owner its slice of tokens
         expert_in = jax.lax.all_to_all(
@@ -332,9 +369,9 @@ def _moe_block_alltoall(x, moe, cfg, mesh, rng, fp8=None):
                 router_z_loss(gate_logits), axis_name=batch_axes
             ),
         }
-        return out, aux
+        return out, aux, gate_idx
 
-    out, aux = jax.shard_map(
+    out, aux, choices = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -344,7 +381,7 @@ def _moe_block_alltoall(x, moe, cfg, mesh, rng, fp8=None):
             P("ep", None, None),
             P("ep", None, None),
         ),
-        out_specs=(P(batch_axes, None, None), P()),
+        out_specs=(P(batch_axes, None, None), P(), P(batch_axes, None, None)),
         check_vma=False,
     )(
         x,
@@ -353,6 +390,7 @@ def _moe_block_alltoall(x, moe, cfg, mesh, rng, fp8=None):
         moe["w_gate_proj"].astype(x.dtype),
         moe["w_down"].astype(x.dtype),
     )
+    aux["moe_choices"] = choices
     return out, aux
 
 
@@ -369,34 +407,47 @@ def _sort_by_expert(xt, gate_idx, e):
     Returns (flat_idx [t·k], order [t·k], token_of [t·k],
     sorted_in [t·k, D], counts [E])."""
     t, k = gate_idx.shape
-    flat_idx = gate_idx.reshape(t * k)
-    order = jnp.argsort(flat_idx)
-    token_of = order // k
-    sorted_in = jnp.take(xt, token_of, axis=0)
-    counts = jnp.bincount(flat_idx, length=e).astype(jnp.int32)
+    with jax.named_scope("moe.sort"):
+        flat_idx = gate_idx.reshape(t * k)
+        order = jnp.argsort(flat_idx)
+        token_of = order // k
+        sorted_in = jnp.take(xt, token_of, axis=0)
+        counts = jnp.bincount(flat_idx, length=e).astype(jnp.int32)
     return flat_idx, order, token_of, sorted_in, counts
+
+
+def _ragged_experts(rows, w_up, w_gate_proj, w_down, group_sizes):
+    """The SwiGLU experts over expert-sorted ``rows`` [N, D] as three
+    ragged matmuls (``lax.ragged_dot``: rhs [E, ·, ·], group_sizes = the
+    rows each expert actually got — the MXU only sees routed tokens)."""
+    with jax.named_scope("moe.experts"):
+        up = jax.lax.ragged_dot(rows, w_up, group_sizes)
+        gate_p = jax.lax.ragged_dot(rows, w_gate_proj, group_sizes)
+        return jax.lax.ragged_dot(
+            jax.nn.silu(gate_p) * up, w_down, group_sizes
+        )
 
 
 def _combine_weighted(out_per_choice, weights, order, token_of, t, d, dtype):
     """Weighted scatter-add of per-(token, choice) expert outputs back
     to token order — the combine tail both ragged lowerings share
     (f32 accumulation; weights applied in sorted order)."""
-    w_sorted = jnp.take(weights.reshape(-1), order)[:, None]
-    out = jnp.zeros((t, d), jnp.float32)
-    out = out.at[token_of].add(
-        out_per_choice.astype(jnp.float32) * w_sorted
-    )
-    return out.astype(dtype)
+    with jax.named_scope("moe.combine"):
+        w_sorted = jnp.take(weights.reshape(-1), order)[:, None]
+        out = jnp.zeros((t, d), jnp.float32)
+        out = out.at[token_of].add(
+            out_per_choice.astype(jnp.float32) * w_sorted
+        )
+        return out.astype(dtype)
 
 
 def _ragged_ffn(xl, moe_local, gate_idx, weights, dtype):
     """Grouped-GEMM expert FFN over one rank's token slice.
 
     xl: [T, D] tokens, gate_idx/weights: [T, k] routing. Sorts the (token,
-    choice) pairs by expert, runs the three projections as ragged matmuls
-    (``lax.ragged_dot``: rhs [E, ·, ·], group_sizes = actual per-expert
-    token counts — the MXU only sees the routed tokens), and scatter-adds
-    the weighted expert outputs back. No capacity, no drops.
+    choice) pairs by expert, runs the experts over them
+    (``_ragged_experts``), and scatter-adds the weighted expert outputs
+    back. No capacity, no drops.
     Returns (out [T, D], group_sizes [E] int32).
     """
     t, d = xl.shape
@@ -404,16 +455,12 @@ def _ragged_ffn(xl, moe_local, gate_idx, weights, dtype):
     _, order, token_of, sorted_in, group_sizes = _sort_by_expert(
         xl, gate_idx, e
     )
-
-    up = jax.lax.ragged_dot(
-        sorted_in, moe_local["w_up"].astype(dtype), group_sizes
-    )
-    gate_p = jax.lax.ragged_dot(
-        sorted_in, moe_local["w_gate_proj"].astype(dtype), group_sizes
-    )
-    h = jax.nn.silu(gate_p) * up
-    out_sorted = jax.lax.ragged_dot(
-        h, moe_local["w_down"].astype(dtype), group_sizes
+    out_sorted = _ragged_experts(
+        sorted_in,
+        moe_local["w_up"].astype(dtype),
+        moe_local["w_gate_proj"].astype(dtype),
+        moe_local["w_down"].astype(dtype),
+        group_sizes,
     )  # [T·k, D]
     out = _combine_weighted(
         out_sorted, weights, order, token_of, t, d, dtype
@@ -427,7 +474,10 @@ def _ragged_aux(gate_logits, probs, group_sizes, pmean_axes=None):
     lb loss: E · Σ_e f_e·p_e with f_e = fraction of (token, choice) slots
     routed to e — the dropless analog of GShard's dispatch fraction.
     Global statistics: fractions are pmean'd over token-sharding axes
-    BEFORE the product (see _moe_block_alltoall note on bias)."""
+    BEFORE the product (see _moe_block_alltoall note on bias).
+
+    ``moe_max_load``: rows of the fullest expert over the mean rows an
+    expert gets (1 = balanced, E/k = every token picks the same k)."""
     total = jnp.maximum(group_sizes.sum(), 1).astype(jnp.float32)
     frac_tokens = group_sizes.astype(jnp.float32) / total
     frac_probs = probs.astype(jnp.float32).mean(axis=(0, 1))
@@ -440,6 +490,7 @@ def _ragged_aux(gate_logits, probs, group_sizes, pmean_axes=None):
     return {
         "moe_lb_loss": e * jnp.sum(frac_tokens * frac_probs),
         "moe_z_loss": z,
+        "moe_max_load": e * jnp.max(frac_tokens),
     }
 
 
@@ -467,6 +518,7 @@ def _moe_block_ragged(x, moe, cfg, mesh=None, rng=None):
             x.dtype,
         )
         aux = _ragged_aux(gate_logits, probs, group_sizes)
+        aux["moe_choices"] = gate_idx
         return out.reshape(b, s, d), aux
 
     if mesh.shape.get("ep", 1) > 1:
@@ -500,9 +552,9 @@ def _moe_block_ragged(x, moe, cfg, mesh=None, rng=None):
             gate_logits, probs, group_sizes,
             pmean_axes=token_axes + ("sp",),
         )
-        return out.reshape(bl, sl, d), aux
+        return out.reshape(bl, sl, d), aux, gate_idx
 
-    return jax.shard_map(
+    out, aux, choices = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -512,7 +564,9 @@ def _moe_block_ragged(x, moe, cfg, mesh=None, rng=None):
             P(None, None, "tp"),
             P(None, "tp", None),
         ),
-        out_specs=(P(token_axes, "sp", None), P()),
+        out_specs=(
+            P(token_axes, "sp", None), P(), P(token_axes, "sp", None)
+        ),
         check_vma=False,
     )(
         x,
@@ -521,6 +575,8 @@ def _moe_block_ragged(x, moe, cfg, mesh=None, rng=None):
         moe["w_gate_proj"].astype(x.dtype),
         moe["w_down"].astype(x.dtype),
     )
+    aux["moe_choices"] = choices
+    return out, aux
 
 
 def _moe_block_ragged_a2a(x, moe, cfg, mesh, rng):
@@ -628,10 +684,9 @@ def _moe_block_ragged_a2a(x, moe, cfg, mesh, rng):
         group_sizes = sent_mine.sum(0)                     # [e_local]
 
         # ---- ragged expert FFN ------------------------------------------
-        up = jax.lax.ragged_dot(compact, w_up, group_sizes)
-        gp = jax.lax.ragged_dot(compact, w_gp, group_sizes)
-        h = jax.nn.silu(gp) * up
-        out_sorted = jax.lax.ragged_dot(h, w_down, group_sizes)
+        out_sorted = _ragged_experts(
+            compact, w_up, w_gp, w_down, group_sizes
+        )
         # zero the sentinel tail so the return path carries no garbage
         n_real = group_sizes.sum()
         out_sorted = jnp.where(
@@ -667,9 +722,9 @@ def _moe_block_ragged_a2a(x, moe, cfg, mesh, rng):
         aux["moe_dropped_frac"] = jax.lax.pmean(
             dropped.astype(jnp.float32) / (t * k), axis_name=token_axes
         )
-        return out.reshape(bl, sl, d).astype(xl.dtype), aux
+        return out.reshape(bl, sl, d).astype(xl.dtype), aux, gate_idx
 
-    return jax.shard_map(
+    out, aux, choices = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -680,7 +735,9 @@ def _moe_block_ragged_a2a(x, moe, cfg, mesh, rng):
             P("ep", None, None),
             P("ep", None, None),
         ),
-        out_specs=(P(token_axes, None, None), P()),
+        out_specs=(
+            P(token_axes, None, None), P(), P(token_axes, None, None)
+        ),
         check_vma=False,
     )(
         jnp.arange(ep, dtype=jnp.int32),
@@ -690,3 +747,5 @@ def _moe_block_ragged_a2a(x, moe, cfg, mesh, rng):
         moe["w_gate_proj"].astype(x.dtype),
         moe["w_down"].astype(x.dtype),
     )
+    aux["moe_choices"] = choices
+    return out, aux

@@ -1180,6 +1180,16 @@ class TrainStepBuilder:
         return new_state, metrics
 
     def step_fn(self, state: TrainState, batch) -> Tuple[TrainState, Dict]:
+        cfg = self.cfg
+        if cfg.n_experts > 0 and "tokens" in batch:
+            # what the routed layers of one step handle. Trace time,
+            # values: a retrace sets the same numbers again
+            set_counter("moe.experts", cfg.n_experts)
+            set_counter("moe.top_k", cfg.routed_top_k)
+            set_counter(
+                "moe.rows_per_step",
+                batch["tokens"].size * cfg.routed_top_k * cfg.n_layer,
+            )
         if self.update_sharding:
             return self._sharded_step_fn(state, batch)
         batch = jax.tree.map(

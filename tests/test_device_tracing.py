@@ -100,6 +100,11 @@ REDUCER_CASES = {
         "forward": 20.0, "recompute": 30.0, "backward": 5.0,
         "optimizer": 10.0, "exchange": 10.0, "other": 5.0,
     },
+    # the innermost named scope of each operation; the while and the
+    # all-reduce carry none
+    "scopes": lambda p: {k: _ms(v) for k, v in p.by_scope.items()} == {
+        "attn": 50.0, "head_loss": 5.0, "optimizer": 10.0, "": 15.0,
+    } and {o.name: o.scope for o in p.by_op}["flash_fwd.1"] == "attn",
     "platform-is-the-planes": lambda p: (p.platform, p.devices) == ("TPU", 1),
     # 0..5 under the dispatch span; 65..70, 80..85, 95..100 under the wait
     "gap-named-by-host-span": lambda p: [
@@ -152,6 +157,30 @@ ENTRY %main.1 (a: f32[8]) -> f32[8] {
     assert rt.scope_of("jit(s)/zero.exchange/zero.pack/dus") == "zero.pack"
     assert rt.phase_of("%dus.7 = f32[4] dynamic-update-slice(%a)",
                        "jit(s)/zero.pack/dynamic_update_slice") == "exchange"
+
+
+SCOPE_CASES = {
+    # the routed layer's scopes sit inside ``mlp``: the innermost counts
+    "moe.sort": "jit(s)/jvp()/while/body/closed_call/mlp/moe.sort/argsort",
+    "moe.combine": (
+        "jit(s)/transpose(jvp())/while/body/closed_call/checkpoint/mlp/"
+        "moe.combine/scatter-add"
+    ),
+    "moe.route": "jit(s)/jvp(mlp)/moe.route/top_k",
+    # lax.ragged_dot's kernel has no name stack: known by its own name
+    "moe.experts": "ragged-dot-none",
+    "mlp": "jit(s)/jvp()/while/body/closed_call/mlp/add",
+    "": "jit(s)/moex.sort/moe.Sort/add",  # not the routed layer's
+}
+
+
+@pytest.mark.parametrize("scope", sorted(SCOPE_CASES))
+def test_scope_of_the_routed_layer(scope):
+    assert rt.scope_of(SCOPE_CASES[scope]) == scope
+    if scope == "moe.experts":
+        # which pass a name-stack-less kernel belongs to cannot be told
+        assert rt.phase_of("%ragged-dot-none.3 = bf16[8] custom-call(%a)",
+                           SCOPE_CASES[scope]) == "other"
 
 
 def test_find_xplane_picks_the_newest(tmp_path):

@@ -1,5 +1,7 @@
 """Model + sharded train step tests on the 8-device CPU mesh."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -456,3 +458,155 @@ def test_layernorm_model_forward_matches_two_pass_family():
     finally:
         decoder._norm = orig
     np.testing.assert_allclose(loss_new, loss_old, rtol=1e-5)
+
+
+# ---- whole-projection QK-norm (OLMoE) ---------------------------------------
+
+
+def _qk_norm_cfg(**kw):
+    kw.setdefault("dtype", "float32")
+    return get_config(
+        "tiny", n_layer=2, d_model=64, d_ff=128, n_head=4, n_kv_head=2,
+        vocab_size=128, max_seq=32, qk_norm=True, **kw,
+    )
+
+
+def _with_random_norm_scales(params, rng):
+    """``init`` sets every norm scale to one, where a norm that forgot
+    its scale would pass: draw the QK-norm scales around one."""
+    attn = params["layers"]["attn"]
+    for i, name in enumerate(("q_norm", "k_norm")):
+        scale = attn[name]["scale"]
+        attn[name]["scale"] = 1.0 + 0.3 * jax.random.normal(
+            jax.random.fold_in(rng, i), scale.shape, scale.dtype
+        )
+    return params
+
+
+@pytest.mark.parametrize("kind", ["rmsnorm", "layernorm"])
+def test_qk_norm_matches_hand_computation(kind):
+    """q and k are normed over ALL heads at once (one statistic per
+    token over n_head·head_dim, resp. kv_heads·head_dim), with their own
+    scales, before the split into heads and before rope; v is not."""
+    cfg = _qk_norm_cfg(norm=kind)
+    params = _with_random_norm_scales(
+        decoder.init(jax.random.key(0), cfg), jax.random.key(5)
+    )
+    attn = params["layers"]["attn"]
+    assert attn["q_norm"]["scale"].shape == (2, 64)
+    assert attn["k_norm"]["scale"].shape == (2, 32)
+    assert set(attn["q_norm"]) == {"scale"}  # scale only, also layernorm
+    layer = jax.tree.map(lambda w: w[1], params["layers"])
+    x = jax.random.normal(jax.random.key(1), (2, 8, 64))
+    pos = jnp.broadcast_to(jnp.arange(8), (2, 8))
+    q, k, v = decoder._project_qkv(x, layer, cfg, pos)
+
+    def norm(y, scale):
+        if kind == "layernorm":
+            y = y - y.mean(-1, keepdims=True)
+            return y / jnp.sqrt((y * y).mean(-1, keepdims=True) + 1e-5) * scale
+        return y / jnp.sqrt((y * y).mean(-1, keepdims=True) + 1e-6) * scale
+
+    def rope(y):  # rotate-half, lane i pairs with lane i + 8
+        inv = cfg.rope_theta ** (-jnp.arange(0, 16, 2) / 16)
+        ang = jnp.arange(8)[:, None] * inv[None]
+        cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
+        y1, y2 = y[..., :8], y[..., 8:]
+        return jnp.concatenate([y1 * cos - y2 * sin, y2 * cos + y1 * sin], -1)
+
+    a = layer["attn"]
+    want_q = rope(norm(x @ a["wq"], a["q_norm"]["scale"]).reshape(2, 8, 4, 16))
+    want_k = rope(norm(x @ a["wk"], a["k_norm"]["scale"]).reshape(2, 8, 2, 16))
+    np.testing.assert_allclose(q, want_q, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(k, want_k, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(
+        v, (x @ a["wv"]).reshape(2, 8, 2, 16), rtol=1e-6, atol=1e-6
+    )
+    # a per-head norm is another function
+    per_head = rope(
+        norm((x @ a["wq"]).reshape(2, 8, 4, 16),
+             a["q_norm"]["scale"].reshape(4, 16))
+    )
+    assert float(jnp.max(jnp.abs(per_head - q))) > 1e-2
+    assert decoder.logical_axes(cfg)["layers"]["attn"]["q_norm"] == {
+        "scale": ("layers", "norm")
+    }
+
+
+def test_qk_norm_refuses_a_tp_mesh(mesh):
+    cfg = _qk_norm_cfg()
+    params = decoder.init(jax.random.key(0), cfg)
+    with pytest.raises(ValueError, match="tp shards the heads axis"):
+        decoder.forward(params, jnp.zeros((8, 16), jnp.int32), cfg, mesh=mesh)
+
+
+def test_qk_norm_prefill_and_decode_match_forward():
+    """A tiny routed model with QK-norm: the prompt through ``prefill``
+    and the rest token by token through ``decode_step`` give the logits
+    of the full forward — the cache paths make q and k in the same one
+    place."""
+    cfg = get_config(
+        "olmoe-1b-7b", n_layer=2, d_model=64, d_ff=32, n_head=4,
+        n_kv_head=4, vocab_size=128, max_seq=32, n_experts=8,
+        expert_top_k=2, dtype="float32",
+    )
+    params = _with_random_norm_scales(
+        decoder.init(jax.random.key(0), cfg), jax.random.key(5)
+    )
+    toks = jax.random.randint(jax.random.key(1), (2, 12), 0, 128)
+    full = decoder.forward(params, toks, cfg)
+    logits, cache = decoder.prefill(params, toks[:, :8], cfg, max_len=16)
+    np.testing.assert_allclose(logits, full[:, :8], rtol=2e-4, atol=2e-4)
+    for pos in range(8, 12):
+        step, cache = decoder.decode_step(
+            params, toks[:, pos], cache, jnp.int32(pos), cfg, prefilled=True
+        )
+        np.testing.assert_allclose(
+            step, full[:, pos], rtol=2e-4, atol=2e-4
+        )
+    # the norm is live in what was compared
+    no_norm = decoder.forward(
+        params, toks, dataclasses.replace(cfg, qk_norm=False)
+    )
+    assert float(jnp.max(jnp.abs(no_norm - full))) > 1e-2
+
+
+def test_qk_norm_tree_round_trips_checkpoint_and_pack_plan(tmp_path):
+    """What walks the parameter tree takes the two new leaves as they
+    come: a checkpoint saved and restored, and ZeRO's pack plan (every
+    leaf in the stream, packed and unpacked bit for bit)."""
+    from dlrover_tpu.checkpoint import Checkpointer, StorageType
+    from dlrover_tpu.checkpoint.checkpointer import state_template
+
+    cfg = _qk_norm_cfg()
+    dp = build_mesh(MeshConfig(dp=-1))
+    opt = make_optimizer(learning_rate=1e-3)
+    builder = TrainStepBuilder(
+        cfg, dp, opt, comm=shd.CommConfig(update_sharding=True, bucket_mb=0.05)
+    )
+    assert builder.update_sharding, builder.update_sharding_reason
+    state = init_train_state(
+        jax.random.key(0), cfg, dp, opt, comm=builder.comm_resolved
+    )
+    params = _with_random_norm_scales(state["params"], jax.random.key(5))
+    paths = {
+        jax.tree_util.keystr(p)
+        for p, _ in jax.tree_util.tree_leaves_with_path(params)
+    }
+    assert "['layers']['attn']['q_norm']['scale']" in paths
+    assert "['layers']['attn']['k_norm']['scale']" in paths
+
+    plan = builder._plan
+    assert plan.total == sum(x.size for x in jax.tree.leaves(params))
+    back = shd.unpack_flat(shd.pack_flat(params, plan), params, plan)
+    for x, y in zip(jax.tree.leaves(params), jax.tree.leaves(back)):
+        assert x.shape == y.shape and x.dtype == y.dtype
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+    ckpt = Checkpointer(str(tmp_path / "ckpt"), use_agent=False)
+    saved = {"params": params, "step": jnp.asarray(3)}
+    assert ckpt.save_checkpoint(3, saved, StorageType.DISK)
+    ckpt.wait_for_persist()
+    out = ckpt.load_checkpoint(state_template(saved))
+    for x, y in zip(jax.tree.leaves(saved), jax.tree.leaves(out)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))

@@ -380,3 +380,229 @@ def test_train_step_threads_jitter_rng(ep_mesh):
     s2, m2 = step(s1, batch)  # same params (lr=0), different step counter
     # with 90% jitter the router losses differ between steps
     assert float(m1["moe_lb_loss"]) != float(m2["moe_lb_loss"])
+
+
+# ---- the router's hand-overs: choices, weight rule, float32 logits, load ----
+
+
+def _tap_route(monkeypatch):
+    """Record what ``_route`` returns while a lowering is traced
+    eagerly (no jit: the recorded arrays are concrete)."""
+    from dlrover_tpu.parallel import moe as moe_mod
+
+    seen = []
+    route = moe_mod._route
+
+    def tapped(*args):
+        out = route(*args)
+        seen.append(out)
+        return out
+
+    monkeypatch.setattr(moe_mod, "_route", tapped)
+    return seen
+
+
+CHOICE_LOWERINGS = {
+    "ragged": dict(moe_impl="ragged"),
+    "ragged-shard_map": dict(moe_impl="ragged"),
+    # capacity high enough that nothing drops: layer 2 then sees the
+    # hidden states the dropless lowering gives it
+    "capacity": dict(moe_impl="dense", capacity_factor=64.0),
+}
+
+
+@pytest.mark.parametrize("lowering", sorted(CHOICE_LOWERINGS))
+def test_moe_choices_are_the_routers_ids(lowering, monkeypatch):
+    """``forward(..., return_aux=True)[1]["moe_choices"]``: int32
+    [L, B, S, k], stacked per layer (not summed), and the ids the
+    router chose — ``_route``'s for the ragged lowerings, the top-k of
+    the probabilities (before drops) for the capacity lowering."""
+    cfg = _moe_cfg(
+        n_experts=8, expert_top_k=2, dtype="float32",
+        **CHOICE_LOWERINGS[lowering],
+    )
+    mesh = (
+        build_mesh(MeshConfig(dp=2), devices=jax.devices()[:2])
+        if lowering == "ragged-shard_map" else None
+    )
+    params = decoder.init(jax.random.key(0), cfg)
+    toks = jax.random.randint(jax.random.key(1), (4, 32), 0, 128)
+    seen = _tap_route(monkeypatch)
+    if mesh is None:
+        fwd = lambda: decoder.forward(  # noqa: E731
+            params, toks, cfg, return_aux=True
+        )
+    else:
+        fwd = jax.jit(lambda: decoder.forward(
+            params, toks, cfg, mesh=mesh, return_aux=True
+        ))
+    with jax.disable_jit(mesh is None):
+        logits, aux = fwd()
+    choices = np.asarray(aux["moe_choices"])
+    assert choices.dtype == np.int32
+    assert choices.shape == (cfg.n_layer, 4, 32, 2)
+    assert set(aux) >= {"moe_lb_loss", "moe_z_loss"}
+    assert np.ndim(aux["moe_lb_loss"]) == 0  # still summed over layers
+    if lowering == "ragged":
+        assert len(seen) == cfg.n_layer
+        for layer, out in enumerate(seen):
+            np.testing.assert_array_equal(choices[layer], np.asarray(out[3]))
+            assert out[0].dtype == jnp.float32  # router logits
+    else:
+        # same weights, same tokens, float32: every lowering routes alike
+        ref = decoder.forward(
+            params, toks, dataclasses.replace(cfg, moe_impl="ragged"),
+            return_aux=True,
+        )[1]["moe_choices"]
+        np.testing.assert_array_equal(choices, np.asarray(ref))
+    assert ((choices >= 0) & (choices < 8)).all()
+    assert (choices[..., 0] != choices[..., 1]).all()
+
+
+@pytest.mark.parametrize("impl", ["ragged", "dense"])
+def test_raw_and_renormalised_combine_weights(impl):
+    """``moe_renorm_topk`` is the model's rule in both lowerings: true
+    divides the k weights by their sum, false leaves the softmax
+    probabilities at the chosen experts (OLMoE)."""
+    from dlrover_tpu.parallel import moe as moe_mod
+
+    cfg = _moe_cfg(
+        n_experts=8, expert_top_k=2, moe_impl=impl, capacity_factor=64.0
+    )
+    raw_cfg = dataclasses.replace(cfg, moe_renorm_topk=False)
+    moe = jax.tree.map(lambda x: x[0], init_moe_params(jax.random.key(0), cfg))
+    x = jax.random.normal(jax.random.key(1), (2, 16, cfg.d_model))
+    probs = jax.nn.softmax(x @ moe["w_gate"], -1)
+    top_p, top_i = jax.lax.top_k(probs, 2)
+
+    def weights(c):
+        if impl == "ragged":
+            _, _, w, idx = moe_mod._route(x, moe, c, None)
+        else:
+            _, combine, _, _, idx = moe_mod._gate(x, moe, c, None)
+            per_expert = combine.sum(-1)  # [B,S,E]: nothing dropped
+            w = jnp.take_along_axis(per_expert, idx, -1)
+        np.testing.assert_array_equal(np.asarray(idx), np.asarray(top_i))
+        return np.asarray(w, np.float32)
+
+    np.testing.assert_allclose(weights(raw_cfg), top_p, rtol=1e-5)
+    assert (weights(raw_cfg).sum(-1) < 0.999).all()
+    np.testing.assert_allclose(
+        weights(cfg), top_p / top_p.sum(-1, keepdims=True), rtol=1e-5
+    )
+    # and the block's output follows the rule
+    out_raw = moe_block(x, moe, raw_cfg, None)
+    out_norm = moe_block(x, moe, cfg, None)
+    assert float(jnp.max(jnp.abs(out_raw - out_norm))) > 1e-3
+
+
+@pytest.mark.parametrize("impl", ["ragged", "dense"])
+def test_router_logits_are_float32_from_bf16_activations(impl):
+    from dlrover_tpu.parallel import moe as moe_mod
+
+    cfg = _moe_cfg(n_experts=8, expert_top_k=2, moe_impl=impl)
+    moe = jax.tree.map(lambda x: x[0], init_moe_params(jax.random.key(0), cfg))
+    x = jax.random.normal(jax.random.key(1), (2, 16, cfg.d_model), jnp.bfloat16)
+    if impl == "ragged":
+        logits = moe_mod._route(x, moe, cfg, None)[0]
+    else:
+        logits = moe_mod._gate(x, moe, cfg, None)[3]
+    assert logits.dtype == jnp.float32
+    # not a bf16 result cast up: bf16 keeps 8 bits of a logit
+    exact = x.astype(jnp.float32) @ moe["w_gate"].astype(
+        jnp.bfloat16
+    ).astype(jnp.float32)
+    assert float(jnp.max(jnp.abs(logits - exact))) < 1e-5
+    rounded = logits.astype(jnp.bfloat16).astype(jnp.float32)
+    assert float(jnp.max(jnp.abs(rounded - logits))) > 1e-4
+
+
+@pytest.mark.parametrize("routing", ["balanced", "collapsed"])
+def test_moe_max_load_metric(routing):
+    """Rows of the fullest expert over the mean rows an expert gets:
+    1.0 when every expert gets the same rows, E/k when every token
+    picks the same k experts. ``loss_fn`` reports the mean over layers."""
+    cfg = _moe_cfg(
+        n_experts=8, expert_top_k=2, moe_impl="ragged", dtype="float32"
+    )
+    moe = jax.tree.map(lambda x: x[0], init_moe_params(jax.random.key(0), cfg))
+    d = cfg.d_model
+    if routing == "balanced":
+        # token i points at experts (2i, 2i+1) mod 8: 32 tokens, 8 rows each
+        ids = (2 * jnp.arange(32)[:, None] + jnp.arange(2)) % 8
+        want = 1.0
+    else:
+        ids = jnp.broadcast_to(jnp.array([3, 5]), (32, 2))
+        want = 8 / 2
+    x = jnp.zeros((32, d)).at[jnp.arange(32)[:, None], ids].set(1.0)
+    moe["w_gate"] = 10.0 * jnp.eye(d, 8)
+    _, aux = moe_block(x[None], moe, cfg, None, return_aux=True)
+    np.testing.assert_array_equal(
+        np.sort(np.asarray(aux["moe_choices"][0]), -1), np.sort(ids, -1)
+    )
+    assert aux["moe_max_load"].dtype == jnp.float32
+    np.testing.assert_allclose(float(aux["moe_max_load"]), want, rtol=1e-6)
+
+
+def test_loss_fn_reports_mean_max_load():
+    cfg = _moe_cfg(n_experts=4, moe_impl="ragged")
+    params = decoder.init(jax.random.key(0), cfg)
+    toks = jax.random.randint(jax.random.key(1), (4, 32), 0, 128)
+    _, metrics = decoder.loss_fn(params, {"tokens": toks, "targets": toks}, cfg)
+    assert "moe_choices" not in metrics
+    assert 1.0 <= float(metrics["moe_max_load"]) <= 4 / 2
+
+
+def _step_text(cfg):
+    """The jitted train step's compiled text for ``cfg`` on one CPU
+    device: its computations, without the source-location tables in
+    front of them and the metadata of each operation."""
+    import re
+
+    from dlrover_tpu.train import TrainStepBuilder, make_optimizer
+    from dlrover_tpu.train.train_step import abstract_train_state
+
+    mesh = build_mesh(MeshConfig(dp=1), devices=jax.devices()[:1])
+    opt = make_optimizer(learning_rate=1e-3)
+    builder = TrainStepBuilder(cfg, mesh, opt)
+    state = abstract_train_state(cfg, mesh, opt)
+    batch = {
+        k: jax.ShapeDtypeStruct((2, 32), jnp.int32)
+        for k in ("tokens", "targets")
+    }
+    text = builder.build().lower(state, batch).compile().as_text()
+    head, _, computations = text.partition("\nStackFrames\n")
+    assert computations, head[:200]
+    computations = computations[computations.index("\n\n"):]
+    return re.sub(r", metadata=\{[^}]*\}", "", computations)
+
+
+@pytest.mark.parametrize("model", ["dense", "ragged", "capacity"])
+def test_train_step_text_unchanged_by_the_choices_hook(model, monkeypatch):
+    """The train step never asks for ``moe_choices``: its compiled text
+    is the same with the hook as with the key dropped at the source. A
+    dense model's step does not reach the routed block at all."""
+    from dlrover_tpu.parallel import moe as moe_mod
+
+    if model == "dense":
+        cfg = get_config(
+            "tiny", n_layer=2, d_model=32, d_ff=64, n_head=4,
+            vocab_size=128, max_seq=32,
+        )
+    else:
+        cfg = _moe_cfg(
+            n_experts=4, moe_aux_coef=0.01, moe_z_coef=0.001,
+            moe_impl="ragged" if model == "ragged" else "dense",
+        )
+    with_hook = _step_text(cfg)
+    block = moe_mod.moe_block
+
+    def without_hook(*args, **kw):
+        assert model != "dense", "a dense step reached the routed block"
+        out, aux = block(*args, **kw)
+        return out, {k: v for k, v in aux.items() if k != "moe_choices"}
+
+    monkeypatch.setattr(moe_mod, "moe_block", without_hook)
+    assert _step_text(cfg) == with_hook
+    assert (model == "dense") == ("top-k" not in with_hook.lower()
+                                  and "topk" not in with_hook.lower())

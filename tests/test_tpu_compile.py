@@ -186,6 +186,20 @@ STEP_CASES = {
                  "norm_fwd", "norm_bwd"},
         scopes={"embed", "attn", "mlp", "head_loss", "optimizer"},
     ),
+    # OLMoE's published widths, one layer of 16: 64 experts of width
+    # 1024 top-8 through ``lax.ragged_dot`` (the compiler's own grouped
+    # matmul and its tile-table kernel), QK-norm as two more norm calls
+    "olmoe-like": dict(
+        model="olmoe-1b-7b",
+        overrides=dict(n_layer=1, remat="full", param_dtype="bfloat16"),
+        optimizer=dict(state_dtype="bfloat16"), comm=None, chips=1,
+        batch=(2, 4096),
+        kernels={"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+                 "norm_fwd", "norm_bwd", "ragged-dot-none",
+                 "ragged-dot-metadata"},
+        scopes={"embed", "attn", "mlp", "head_loss", "optimizer",
+                "moe.route", "moe.sort", "moe.experts", "moe.combine"},
+    ),
     # the dp=4 ZeRO-1 recipe: f32 parameters, tied head
     "zero1-dp4": dict(
         model="gpt2-1.5b",
@@ -296,11 +310,24 @@ def test_step_names_its_kernels_and_phases(topo, case):
             (plan.n_buckets + plan.n_tie_buckets) * plan.bucket_elems * 4
         )
     assert wanted <= phases, wanted - phases
+    if spec["model"] == "olmoe-1b-7b":
+        # the routed layer's counters, and its grouped matmuls under
+        # their scope (by the kernel's name: it has no name stack)
+        assert counters["moe.experts"] == 64 and counters["moe.top_k"] == 8
+        assert counters["moe.rows_per_step"] == 2 * 4096 * 8 * 1
+        grouped = [
+            name for name, op_name in op_names.items()
+            if name.startswith("ragged-dot-none")
+            and runtime_timer.scope_of(op_name) == "moe.experts"
+        ]
+        assert len(grouped) == 12  # a layer: 3 forward, 3 recomputed, 6 back
     for line in kernel_lines:
         name = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", line).group(1)
         kernel, phase = name.split(".")[0], runtime_timer.phase_of(
             line, op_names[name]
         )
+        if kernel.startswith("ragged-dot"):
+            continue  # the compiler's kernels run in every phase
         if kernel.startswith("flash_bwd") or kernel == "norm_bwd":
             assert phase == "backward", (name, op_names[name])
         else:
