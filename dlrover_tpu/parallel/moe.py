@@ -8,6 +8,7 @@ emit the all-to-alls on ICI — no hand-written collectives, and the expert
 FFN is a single batched matmul on the MXU (the grouped-GEMM equivalent).
 """
 
+import functools
 from typing import Dict
 
 import jax
@@ -399,21 +400,64 @@ def _moe_block_alltoall(x, moe, cfg, mesh, rng, fp8=None):
 # ---------------------------------------------------------------------------
 
 
+# Token order and expert order (docs/performance.md): ``order`` is a
+# permutation of the t·k (token, choice) pairs and ``inv`` its inverse,
+# so rows move between the two orders by GATHER in both directions of
+# the derivative, and rows meet only in a dense sum over the k axis of a
+# [t, k, d] view. jax's own transpose of a gather is a scatter-add, which
+# the TPU serialises when indices repeat (every token row is hit k
+# times): a fourteenth of the memory's rate at OLMoE's 65,536 rows. Hence
+# the two hand-written derivatives below.
+
+
+def _rows(x, idx):
+    """``x[idx]`` along axis 0 for indices that are in range by
+    construction (a permutation, or one folded by ``// k``): without the
+    promise every gather is followed by a select over its whole output
+    that fills out-of-range rows."""
+    return x.at[idx].get(mode="promise_in_bounds")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _dispatch(k, xt, token_of, inv):
+    """Token rows [t, d] → expert order [t·k, d] (``token_of`` =
+    ``order // k``)."""
+    return _rows(xt, token_of)
+
+
+def _dispatch_fwd(k, xt, token_of, inv):
+    return _dispatch(k, xt, token_of, inv), inv
+
+
+def _dispatch_bwd(k, inv, g):
+    # the k rows of a token, back in token order, summed in float32
+    with jax.named_scope("moe.sort"):
+        d_xt = _rows(g, inv).reshape(-1, k, g.shape[-1])
+        d_xt = d_xt.sum(axis=1, dtype=jnp.float32).astype(g.dtype)
+    return d_xt, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
 def _sort_by_expert(xt, gate_idx, e):
     """Stable-sort prologue shared by both ragged lowerings: (token,
     choice) pairs ordered by expert. STABILITY is load-bearing — the
     a2a pack/unpack indexing assumes per-expert token order survives.
 
-    Returns (flat_idx [t·k], order [t·k], token_of [t·k],
-    sorted_in [t·k, D], counts [E])."""
+    Returns (flat_idx [t·k], order [t·k], inv [t·k] with
+    ``inv[order] = arange``, sorted_in [t·k, D], counts [E])."""
     t, k = gate_idx.shape
     with jax.named_scope("moe.sort"):
         flat_idx = gate_idx.reshape(t * k)
         order = jnp.argsort(flat_idx)
-        token_of = order // k
-        sorted_in = jnp.take(xt, token_of, axis=0)
-        counts = jnp.bincount(flat_idx, length=e).astype(jnp.int32)
-    return flat_idx, order, token_of, sorted_in, counts
+        inv = jnp.argsort(order)
+        sorted_in = _dispatch(k, xt, order // k, inv)
+        counts = jnp.sum(
+            flat_idx[:, None] == jnp.arange(e, dtype=flat_idx.dtype),
+            axis=0, dtype=jnp.int32,
+        )
+    return flat_idx, order, inv, sorted_in, counts
 
 
 def _ragged_experts(rows, w_up, w_gate_proj, w_down, group_sizes):
@@ -428,17 +472,40 @@ def _ragged_experts(rows, w_up, w_gate_proj, w_down, group_sizes):
         )
 
 
-def _combine_weighted(out_per_choice, weights, order, token_of, t, d, dtype):
-    """Weighted scatter-add of per-(token, choice) expert outputs back
-    to token order — the combine tail both ragged lowerings share
-    (f32 accumulation; weights applied in sorted order)."""
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _combine_weighted(out_per_choice, weights, order, inv, dtype):
+    """Per-(token, choice) expert outputs [t·k, D] in expert order back
+    to token order, weighted by ``weights`` [t, k] — the combine tail
+    both ragged lowerings share. The k rows of a token are gathered
+    (``inv``) and contracted with its weights in float32."""
+    t, k = weights.shape
     with jax.named_scope("moe.combine"):
-        w_sorted = jnp.take(weights.reshape(-1), order)[:, None]
-        out = jnp.zeros((t, d), jnp.float32)
-        out = out.at[token_of].add(
-            out_per_choice.astype(jnp.float32) * w_sorted
-        )
-        return out.astype(dtype)
+        picked = _rows(out_per_choice, inv).reshape(t, k, -1)
+        return jnp.einsum(
+            "tkd,tk->td", picked, weights,
+            preferred_element_type=jnp.float32,
+        ).astype(dtype)
+
+
+def _combine_fwd(out_per_choice, weights, order, inv, dtype):
+    out = _combine_weighted(out_per_choice, weights, order, inv, dtype)
+    return out, (out_per_choice, weights, order, inv)
+
+
+def _combine_bwd(dtype, res, g):
+    # in expert order, so that d_out feeds the grouped matmul's transpose
+    # as it is; only the t·k scalars of d_weights are permuted
+    out_per_choice, weights, order, inv = res
+    with jax.named_scope("moe.combine"):
+        g_sorted = _rows(g, order // weights.shape[1]).astype(jnp.float32)
+        w_sorted = _rows(weights.reshape(-1), order)
+        d_out = (g_sorted * w_sorted[:, None]).astype(out_per_choice.dtype)
+        d_w = (out_per_choice.astype(jnp.float32) * g_sorted).sum(-1)
+        d_weights = _rows(d_w, inv).reshape(weights.shape)
+    return d_out, d_weights.astype(weights.dtype), None, None
+
+
+_combine_weighted.defvjp(_combine_fwd, _combine_bwd)
 
 
 def _ragged_ffn(xl, moe_local, gate_idx, weights, dtype):
@@ -446,15 +513,12 @@ def _ragged_ffn(xl, moe_local, gate_idx, weights, dtype):
 
     xl: [T, D] tokens, gate_idx/weights: [T, k] routing. Sorts the (token,
     choice) pairs by expert, runs the experts over them
-    (``_ragged_experts``), and scatter-adds the weighted expert outputs
-    back. No capacity, no drops.
+    (``_ragged_experts``), and sums each token's k weighted expert
+    outputs (``_combine_weighted``). No capacity, no drops.
     Returns (out [T, D], group_sizes [E] int32).
     """
-    t, d = xl.shape
     e = moe_local["w_up"].shape[0]
-    _, order, token_of, sorted_in, group_sizes = _sort_by_expert(
-        xl, gate_idx, e
-    )
+    _, order, inv, sorted_in, group_sizes = _sort_by_expert(xl, gate_idx, e)
     out_sorted = _ragged_experts(
         sorted_in,
         moe_local["w_up"].astype(dtype),
@@ -462,9 +526,7 @@ def _ragged_ffn(xl, moe_local, gate_idx, weights, dtype):
         moe_local["w_down"].astype(dtype),
         group_sizes,
     )  # [T·k, D]
-    out = _combine_weighted(
-        out_sorted, weights, order, token_of, t, d, dtype
-    )
+    out = _combine_weighted(out_sorted, weights, order, inv, dtype)
     return out, group_sizes
 
 
@@ -619,7 +681,7 @@ def _moe_block_ragged_a2a(x, moe, cfg, mesh, rng):
         k = gate_idx.shape[-1]
         t = bl * sl
         cap = max(1, int(cfg.moe_a2a_bound * t * k / ep))
-        flat_idx, order, token_of, sorted_in, counts = _sort_by_expert(
+        flat_idx, order, inv, sorted_in, counts = _sort_by_expert(
             xl.reshape(t, d), gate_idx.reshape(t, k), e
         )
 
@@ -694,8 +756,9 @@ def _moe_block_ragged_a2a(x, moe, cfg, mesh, rng):
         )
 
         # ---- return path: unsort, a2a back, unpack ----------------------
-        inv = jnp.argsort(perm)
-        back = jnp.take(out_sorted, inv, axis=0).reshape(ep, cap, d)
+        back = jnp.take(
+            out_sorted, jnp.argsort(perm), axis=0
+        ).reshape(ep, cap, d)
         ret = jax.lax.all_to_all(
             back, "ep", split_axis=0, concat_axis=0, tiled=True
         )                                                  # [ep(dest), cap, D]
@@ -711,7 +774,7 @@ def _moe_block_ragged_a2a(x, moe, cfg, mesh, rng):
         ]
         out_per_choice = jnp.where(kept[:, None], gathered, 0.0)
         out = _combine_weighted(
-            out_per_choice, weights, order, token_of, t, d, jnp.float32
+            out_per_choice, weights.reshape(t, k), order, inv, jnp.float32
         )
 
         # ---- aux: global stats ------------------------------------------

@@ -553,6 +553,115 @@ def test_loss_fn_reports_mean_max_load():
     assert 1.0 <= float(metrics["moe_max_load"]) <= 4 / 2
 
 
+# ---- token order and expert order: gathers against the scatter-add oracle ----
+
+
+def _scatter_add_oracle(xt, out_sorted, weights, gate_idx, dtype):
+    """Dispatch and combine as they were before the inverse permutation:
+    a gather whose derivative jax writes as a scatter-add of rows, and a
+    weighted scatter-add of the expert outputs. Kept here as the plain
+    formulation the program's is held to. → (sorted_in, out)."""
+    t, k = gate_idx.shape
+    order = jnp.argsort(gate_idx.reshape(t * k))
+    token_of = order // k
+    sorted_in = jnp.take(xt, token_of, axis=0)
+    w_sorted = jnp.take(weights.reshape(-1), order)[:, None]
+    out = jnp.zeros((t, out_sorted.shape[-1]), jnp.float32)
+    out = out.at[token_of].add(out_sorted.astype(jnp.float32) * w_sorted)
+    return sorted_in, out.astype(dtype)
+
+
+def _program_dispatch_combine(xt, out_sorted, weights, gate_idx, dtype, e):
+    from dlrover_tpu.parallel import moe as moe_mod
+
+    _, order, inv, sorted_in, _ = moe_mod._sort_by_expert(xt, gate_idx, e)
+    out = moe_mod._combine_weighted(out_sorted, weights, order, inv, dtype)
+    return sorted_in, out
+
+
+def _bf16_ulp(ref):
+    """Spacing of bfloat16 (8 significant bits) at each value of ``ref``."""
+    mag = np.maximum(np.abs(ref), np.finfo(np.float32).tiny)
+    return 2.0 ** (np.floor(np.log2(mag)) - 7)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("routing", ["balanced", "collapsed"])
+@pytest.mark.parametrize("k", [1, 2, 8])
+def test_dispatch_and_combine_match_the_scatter_add_oracle(k, routing, dtype):
+    """``_sort_by_expert`` and ``_combine_weighted`` move rows by gather
+    in both directions of the derivative (``inv``) and sum a token's k
+    rows densely. Outputs and the gradients with respect to tokens,
+    expert outputs and router weights are the scatter-add formulation's:
+    to 1e-6 in float32; in bfloat16 within two ulps of the float32
+    oracle and no further from it than the scatter-add is."""
+    from dlrover_tpu.parallel import moe as moe_mod
+
+    t, e, d = 48, 16, 32
+    dt = jnp.dtype(dtype)
+    if routing == "balanced":
+        gate_idx = (k * jnp.arange(t)[:, None] + jnp.arange(k)) % e
+    else:  # every token the same k experts; the other experts get no row
+        gate_idx = jnp.broadcast_to(jnp.arange(3, 3 + k), (t, k))
+    gate_idx = gate_idx.astype(jnp.int32)
+    keys = jax.random.split(jax.random.key(k), 5)
+    xt = jax.random.normal(keys[0], (t, d)).astype(dt)
+    out_sorted = jax.random.normal(keys[1], (t * k, d)).astype(dt)
+    weights = jax.nn.softmax(jax.random.normal(keys[2], (t, k)), -1)
+    cot_sorted = jax.random.normal(keys[3], (t * k, d)).astype(dt)
+    cot_out = jax.random.normal(keys[4], (t, d)).astype(dt)
+
+    flat_idx, order, inv, _, counts = moe_mod._sort_by_expert(
+        xt, gate_idx, e
+    )
+    np.testing.assert_array_equal(inv[order], np.arange(t * k))
+    np.testing.assert_array_equal(counts, np.bincount(flat_idx, minlength=e))
+    assert counts.dtype == jnp.int32
+    # stable: an expert's rows keep their token order
+    same_expert = np.diff(flat_idx[order]) == 0
+    assert (np.diff(order)[same_expert] > 0).all()
+
+    def run(fn, cast):
+        def both(xt, out_sorted, weights):
+            sorted_in, out = fn(xt, out_sorted, weights)
+            loss = (
+                sorted_in.astype(jnp.float32) * cot_sorted.astype(jnp.float32)
+            ).sum() + (
+                out.astype(jnp.float32) * cot_out.astype(jnp.float32)
+            ).sum()
+            return loss, (sorted_in, out)
+
+        args = [a.astype(cast) for a in (xt, out_sorted)] + [weights]
+        (_, outs), grads = jax.value_and_grad(
+            both, argnums=(0, 1, 2), has_aux=True
+        )(*args)
+        assert [g.dtype for g in grads] == [cast, cast, jnp.float32]
+        return [np.asarray(a, np.float32) for a in (*outs, *grads)]
+
+    new = run(
+        lambda *a: _program_dispatch_combine(*a, gate_idx, dt, e), dt
+    )
+    old = run(lambda *a: _scatter_add_oracle(*a, gate_idx, dt), dt)
+    ref = run(
+        lambda *a: _scatter_add_oracle(*a, gate_idx, jnp.float32),
+        jnp.float32,
+    )
+    names = ("sorted_in", "out", "d_tokens", "d_expert_out", "d_weights")
+    for name, got, was, want in zip(names, new, old, ref):
+        scale = np.abs(want).max()
+        err = np.abs(got - want)
+        if dtype == "float32" or name == "d_weights":
+            # router weights and their gradient are float32 in both
+            tol = 1e-6 if dtype == "float32" else 1e-5
+            assert err.max() <= tol * scale, (name, err.max() / scale)
+        else:
+            # a sum that cancels is held to the ulp of its terms' scale
+            ulp = _bf16_ulp(np.maximum(np.abs(want), 2.0**-6 * scale))
+            assert (err <= 2 * ulp).all(), (name, (err / ulp).max())
+            worst_was = np.abs(was - want).max()
+            assert err.max() <= 1.001 * worst_was + 1e-6 * scale, name
+
+
 def _step_text(cfg):
     """The jitted train step's compiled text for ``cfg`` on one CPU
     device: its computations, without the source-location tables in

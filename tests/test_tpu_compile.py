@@ -358,3 +358,32 @@ def test_zero_step_has_no_stream_relayout_loop(topo, case):
         ]
         assert not hit, ln[:200]
     assert "tpu_custom_call" in text
+
+
+def test_routed_layer_scatters_no_rows(topo):
+    """Between token order and expert order rows move by gather in both
+    directions of the derivative (``parallel/moe.py``'s ``inv``). A
+    gather left to jax's transpose comes back as a scatter-add of
+    floating-point rows under a ``moe.*`` scope, which the chip
+    serialises where indices repeat: 14 ms each a step at OLMoE's 65,536
+    rows before PR 30. Integer scatters (a count, a permutation) are
+    allowed there; the embedding's gradient is a row scatter outside."""
+    import re
+
+    from dlrover_tpu.observability import runtime_timer
+
+    _, text, _ = _compiled_step(topo, "olmoe-like")
+    op_names = runtime_timer.op_names_from_hlo(text)
+    scatters = []  # (dtype, shape, scope, line) of every scatter
+    for line in text.splitlines():
+        m = re.match(
+            r"\s*(?:ROOT )?%([\w.\-]+) = (\w+)\[([\d,]*)\]\S* scatter\(", line
+        )
+        if m:
+            scope = runtime_timer.scope_of(op_names.get(m.group(1), ""))
+            scatters.append((m.group(2), m.group(3).split(","), scope, line))
+    # the text is the step's, and the pattern finds its scatters
+    assert any(scope == "embed" for _, _, scope, _ in scatters)
+    for dtype, shape, scope, line in scatters:
+        if scope.startswith("moe.") and len(shape) > 1:
+            assert dtype[0] in "su", line[:200]
