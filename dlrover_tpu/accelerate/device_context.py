@@ -62,8 +62,7 @@ class KernelCapabilities:
 
     Every entry derives from the one probe (``common.device``) plus
     the interpret-mode test hook. Consumers: ``decoder`` (fused norm
-    auto), ``ops.fp8._resolve_native`` (native vs bf16-upcast dots),
-    and ``bench.check_kernels`` (which kernel numerics gates to run).
+    auto) and ``ops.fp8._resolve_native`` (native vs bf16-upcast dots).
 
     ``fp8_native`` means the quantized operands feed the MXU directly;
     False still runs the fp8 recipe with bf16-upcast of the SAME

@@ -423,10 +423,10 @@ def _algo_hot_ps(svc: BrainService, stats: Dict) -> ResourcePlan:
 # Auto-tuner: cold-start planning + live refinement (ROADMAP item 2).
 #
 # This module must stay importable on a bare host (no jax): the memory
-# model and the bandwidth/bucket model are small local replicas of the
-# analyser/bench formulas, calibrated against the measured flagship
-# shape (llama-1.4b b1×s8192 → save_qkv on a 16 GB chip, matching the
-# hand-tuned bench config), instead of imports of jax-heavy modules.
+# model is a small local replica of analyser.py's formulas, calibrated
+# against the flagship shape (llama-1.4b b1×s8192 → save_qkv on a 16 GB
+# chip), instead of an import of jax-heavy modules; the bandwidth and
+# bucket model below is the repository's only one.
 # ---------------------------------------------------------------------------
 
 # cheapest-first remat ladder: each step trades more recompute for a
@@ -455,7 +455,9 @@ _ACT_SCALE = {
 # analyser.py's tables, replicated so the planner stays jax-free
 _OPT_SLOTS = {"adamw": 2, "adam": 2, "agd": 3, "sgd": 1, "lion": 1}
 _DTYPE_BYTES = {"float32": 4, "bfloat16": 2, "float16": 2, "int8": 1}
-# bench.py's ICI bandwidth table (GB/s per link direction)
+# ICI bandwidth assumed per chip (GB/s). Not the benchmark's peak table
+# (benchmarks/lib/peaks.py: v5e 200 GB/s) and not a measurement (the
+# dp=4 cell: 83 GB/s of payload): ROADMAP D13.
 _ICI_GBPS = {
     "v4": 300.0,
     "v5 lite": 400.0,
@@ -488,10 +490,12 @@ def _device_hbm_bytes(device_kind: str = "") -> float:
 
 def _suggest_bucket_mb(total_grad_bytes, device_kind="", launch_us=5.0,
                        grad_accum=1, update_mode=""):
-    """Faithful replica of ``bench.suggest_bucket_mb`` (bench is an
-    entry script, not a library the brain may import): smallest bucket
-    whose wire time dominates launch latency, but ≥ 4 buckets in
-    flight, clamped to [1, 64] MB."""
+    """Bucket size for the ZeRO gradient exchange: the smallest bucket
+    whose wire time dominates its launch latency (≥ 4 × ``launch_us``),
+    but ≥ 4 buckets in flight so the first issue under the tail of
+    backward, clamped to [1, 64] MB. ZeRO-2 exchanges once per
+    microbatch, so its launch cost recurs ``grad_accum`` times a step
+    and the floor scales with it; ZeRO-1 exchanges once a step."""
     gbps = _ici_gbps(device_kind)
     passes = grad_accum if (update_mode == "zero2" and grad_accum > 1) else 1
     min_bytes = 4.0 * launch_us * passes * gbps * 1e3
@@ -512,9 +516,9 @@ def estimate_hbm_bytes(
     """Peak-HBM estimate for one chip running ``cfg`` at this shape.
 
     Model states = params f32 + optimizer slots at ``state_dtype``;
-    gradients are donated/transient (no persistent term — the bench's
-    measured steady state, not analyser.py's conservative worst case,
-    which rejects the flagship shape at every remat). The logits term
+    gradients are donated/transient (no persistent term — the steady
+    state, not analyser.py's conservative worst case, which rejects the
+    flagship shape at every remat). The logits term
     honors fused CE: with ``cfg.fused_ce`` only one ``ce_block_v``-wide
     f32 chunk is ever live. ×1.05 slack for fragmentation/workspace.
     """
@@ -538,7 +542,7 @@ class ColdStartPlanner:
 
     Picks the largest per-chip batch whose cheapest-fitting remat
     policy stays under the HBM budget, then derives the comm knobs from
-    the same bandwidth model the bench plans with: bucket size from
+    the bandwidth model above: bucket size from
     ``_suggest_bucket_mb``, f32 wire inside a slice (bitwise-safe
     default) with an int8 override across DCN, ZeRO mode from the mesh
     (zero2 when the exchange amortizes over grad accumulation)."""
@@ -672,7 +676,7 @@ class BrainTuner:
     * fp8 amax saturation (``AnomalyRecord(kind="fp8_saturation")``) →
       ascend the wire-dtype ladder int8 → bfloat16 → float32 (the DCN
       override first when one is set — the narrow wire lives there);
-    * OOM (the bench failure classifier's verdict, via
+    * OOM (a failure classifier's verdict, via
       :meth:`on_failure` or an ``AnomalyRecord(kind="oom")``) →
       descend :data:`REMAT_LADDER`; past ``full``, halve the batch;
     * serving (``ServingRecord``): accept-rate EWMA high/low →
@@ -803,7 +807,7 @@ class BrainTuner:
         )
 
     def on_failure(self, kind: str, detail: str = "") -> Optional["TuningPlan"]:
-        """Feed a bench-classifier verdict (oom | compile_error |
+        """Feed a failure classifier's verdict (oom | compile_error |
         timeout | error); OOM descends the remat ladder, then the
         batch."""
         if kind != "oom":

@@ -40,8 +40,7 @@ def on_cpu() -> bool:
 
 def require_tpu() -> DeviceInfo:
     """``device_info()`` for a run that was asked for the chip (the chip
-    smoke, the bench's measured paths): anything else is an error, not
-    a slower place to run."""
+    smoke): anything else is an error, not a slower place to run."""
     info = device_info()
     if info.platform != "tpu":
         raise RuntimeError(

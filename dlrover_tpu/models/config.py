@@ -212,18 +212,32 @@ class ModelConfig:
         return L * per_layer + embed + pos + d
 
     def flops_per_token(self, seq_len: int) -> float:
-        """Training FLOPs/token ≈ 6·N + attention term (fwd+bwd).
+        """FLOPs a training step requires per token, forward and
+        backward, counted as the benchmark counts them
+        (``benchmarks/lib/flops.py``): 6 × the parameters that are
+        multiplied, plus 12 · L · (heads × head_dim) × the mean number
+        of keys a query sees.
 
-        A sliding window caps each query's attention span, so windowed
-        configs do O(S·window) attention work, not O(S²)."""
-        n = self.num_params()
-        span = (
-            min(seq_len, self.attn_window)
-            if self.attn_window
-            else seq_len
-        )
-        attn_flops = 12 * self.n_layer * self.d_model * span
-        return 6.0 * n + attn_flops
+        Multiplied: every projection and MLP matrix of every layer and
+        the output head once, tied or not; not the embedding gather, the
+        learned positions or the norm scales. A routed layer counts the
+        ``routed_top_k`` experts a token meets and the router, not the
+        experts it never visits. Under the causal mask query i sees
+        min(i + 1, window or seq_len) keys; without it, all of them.
+        Recomputation does not count."""
+        d = self.d_model
+        d_attn = self.n_head * self.head_dim
+        attn = 2 * d * d_attn + 2 * d * self.kv_heads * self.head_dim
+        mlp = (3 if self.act == "swiglu" else 2) * d * self.d_ff
+        if self.n_experts:
+            mlp = self.routed_top_k * mlp + d * self.n_experts
+        multiplied = self.n_layer * (attn + mlp) + d * self.vocab_size
+        if self.causal:
+            w = min(self.attn_window or seq_len, seq_len)
+            span = (w * (w + 1) / 2 + (seq_len - w) * w) / seq_len
+        else:
+            span = seq_len
+        return 6.0 * multiplied + 12.0 * self.n_layer * d_attn * span
 
 
 def mup_base_config(cfg: "ModelConfig") -> "ModelConfig":

@@ -602,7 +602,7 @@ def run_trunk(
         # save_qkv plus ONE of the two swiglu projections: ~half the
         # extra footprint of save_dots for half its recompute savings —
         # the largest policy that still fits 1.4B training on a 16 GiB
-        # chip (see bench.py)
+        # chip
         body = jax.checkpoint(
             body,
             policy=cp.save_only_these_names(

@@ -203,6 +203,54 @@ def op_names_from_hlo(hlo_text: str) -> Dict[str, str]:
     return names
 
 
+# bytes per element of the dtypes collectives carry
+_HLO_DTYPE_BYTES = {
+    "f64": 8, "f32": 4, "bf16": 2, "f16": 2,
+    "s32": 4, "u32": 4, "s8": 1, "u8": 1, "pred": 1,
+}
+_SHAPE = re.compile(r"([a-z0-9]+)\[([0-9,]*)\]")
+
+
+def collective_stats(hlo_text: str) -> dict:
+    """The collectives of a compiled module's text
+    (``compiled.as_text()``): ``{"counts": {kind: n}, "bytes_by_dtype":
+    {dtype: B}, "bytes_by_op": {kind: B}}``. Bytes are those of each
+    instruction's RESULT, every member of a tuple result summed; a dtype
+    outside ``_HLO_DTYPE_BYTES`` adds none. Counted: a line whose
+    right-hand side calls ``<kind>(`` for a kind in
+    ``COLLECTIVE_MARKERS`` — so an asynchronous ``<kind>-start`` /
+    ``-done`` pair is not. A replicated update coming back (a
+    full-gradient all-reduce) or a changed wire dtype shows here."""
+    counts = dict.fromkeys(COLLECTIVE_MARKERS, 0)
+    bytes_by_dtype: Dict[str, int] = {}
+    bytes_by_op: Dict[str, int] = {}
+    for line in hlo_text.splitlines():
+        _lhs, sep, rhs = line.partition(" = ")
+        if not sep:
+            continue
+        for kind in COLLECTIVE_MARKERS:
+            at = rhs.find(kind + "(")
+            if at >= 0:
+                break
+        else:
+            continue
+        counts[kind] += 1
+        for dtype, dims in _SHAPE.findall(rhs[:at]):
+            if dtype not in _HLO_DTYPE_BYTES:
+                continue
+            size = _HLO_DTYPE_BYTES[dtype]
+            for d in dims.split(","):
+                if d:
+                    size *= int(d)
+            bytes_by_dtype[dtype] = bytes_by_dtype.get(dtype, 0) + size
+            bytes_by_op[kind] = bytes_by_op.get(kind, 0) + size
+    return {
+        "counts": {kind: n for kind, n in counts.items() if n},
+        "bytes_by_dtype": bytes_by_dtype,
+        "bytes_by_op": bytes_by_op,
+    }
+
+
 def short_name(event_name: str) -> str:
     """``%fusion.3 = bf16[8,128]{...} fusion(...)`` → ``fusion.3``; a
     bare name is its own."""

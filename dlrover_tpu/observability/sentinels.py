@@ -42,10 +42,10 @@ cannot skew them.  Norm-based sentinels (``sent_update_ratio``,
 ``grad_norm``) reduce in a different order on the flat stream and are
 tolerance-pinned instead.
 
-Cost model (measured by ``bench.py``'s ``sentinel_overhead_frac``): each
-sentinel is one fused elementwise map + reduction over data the step
-already touches, so XLA folds them into existing HBM passes; the lead
-llama shape pays <1% step time (acceptance-pinned).
+Cost: each sentinel is one fused elementwise map + reduction over data
+the step already touches, so XLA can fold them into existing HBM passes.
+What they add to a step on the chip is not measured: no benchmark cell
+turns them on.
 """
 
 from typing import Dict, Optional

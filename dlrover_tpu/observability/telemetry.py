@@ -147,8 +147,9 @@ class KernelSample:
 
 @telemetry_record
 class PlanRecord:
-    """Bench/accelerate compile-time planning numbers, surfaced at
-    runtime so tuners can compare plan vs reality."""
+    """Planning numbers for a run, published before it starts so that
+    tuners can compare plan with reality. Nothing in the repository
+    produces one at present (ROADMAP D16)."""
 
     config: str = ""
     suggested_bucket_mb: float = 0.0
@@ -156,7 +157,7 @@ class PlanRecord:
     planned_hidden_us: float = 0.0
     assumed_ici_gbps: float = 0.0
     update_sharding_reason: str = ""
-    # measured mean step wall time at the bench shape — the watchdog's
+    # mean step wall time expected at this shape — the watchdog's
     # baseline for step_time_regression (0 = no plan available)
     planned_step_time_s: float = 0.0
     ts: float = 0.0
@@ -342,8 +343,7 @@ class ScaleDecisionRecord:
     detached); ``signal`` names the gate that drove it (slo_breach |
     ttft_regression | out_of_pages | queue_depth | shed_storm | clear |
     planned), with ``value`` the measured reading against ``target``.
-    ``reaction_s`` is the breach-edge → decision-applied latency (the
-    control-loop half of the bench's breach → p99-restored headline);
+    ``reaction_s`` is the breach-edge → decision-applied latency;
     ``version`` is the master's serving-scale directive version (0 when
     the scaler versioned locally). ``replica`` names the joiner
     (scale-out) or the drained victim (scale-in). Recordings that
@@ -729,26 +729,6 @@ def reset_hub() -> None:
 
 
 # ---- producers' helpers ---------------------------------------------------
-
-
-def plan_record_from_overlap(
-    config_name: str,
-    overlap: Optional[Dict],
-    suggested_bucket_mb: float = 0.0,
-    update_sharding_reason: str = "",
-    planned_step_time_s: float = 0.0,
-) -> PlanRecord:
-    """Build a :class:`PlanRecord` from ``bench.overlap_report`` output."""
-    overlap = overlap or {}
-    return PlanRecord(
-        config=config_name,
-        suggested_bucket_mb=float(suggested_bucket_mb or 0.0),
-        planned_exposed_us=float(overlap.get("exposed_us_total", 0.0)),
-        planned_hidden_us=float(overlap.get("hidden_us_total", 0.0)),
-        assumed_ici_gbps=float(overlap.get("assumed_ici_gbps", 0.0)),
-        update_sharding_reason=update_sharding_reason or "",
-        planned_step_time_s=float(planned_step_time_s or 0.0),
-    )
 
 
 def format_phases(phases: Dict[str, float]) -> str:

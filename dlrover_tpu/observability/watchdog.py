@@ -72,7 +72,7 @@ class WatchdogConfig:
     # fraction of fp8 amax histories saturating in one step before the
     # delayed-scaling state is declared stale
     fp8_sat_threshold: float = 0.5
-    # measured step time beyond factor × the bench plan's
+    # measured step time beyond factor × the plan's
     # planned_step_time_s ⇒ step_time_regression
     step_time_factor: float = 1.5
     # skip the first steps of a run/recompile before judging step time
@@ -262,7 +262,7 @@ class Watchdog:
         plan: Optional[Dict] = None,
     ) -> str:
         """Persist the reserved capture: the sampled runtime breakdown,
-        the collective-time diff vs the bench plan, and the anomaly that
+        the collective-time diff vs the plan, and the anomaly that
         triggered it. ``block`` labels a fused K-step capture. Returns
         the written path ("" when nothing was pending)."""
         if not self._pending_capture:

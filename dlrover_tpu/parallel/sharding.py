@@ -573,8 +573,7 @@ def exchange_buckets(
     collectives from the LAST bucket down: backward produces gradients
     roughly output-to-input, and the canonical flat order starts with
     the embedding table — whose gradient lands last — so reverse
-    issue order matches gradient availability (the overlap-report
-    heuristic in bench.py measures what this buys). Values are
+    issue order matches gradient availability. Values are
     order-independent (each bucket is an independent collective), so
     the f32 wire stays bitwise whatever the order. ``tie_extra`` (the
     split-off tied-head cotangent, ``[tie_size]``) rides its own

@@ -806,7 +806,7 @@ class ServingEngine:
         return worked
 
     def drain(self, timeout: float = 120.0) -> None:
-        """Step until queue and slots are empty (tests / bench)."""
+        """Step until queue and slots are empty (tests)."""
         deadline = time.monotonic() + timeout
         while self.scheduler.queue_depth() or self.active_slots():
             self.step()

@@ -129,7 +129,7 @@ def init_pools(geom: PageGeometry) -> Dict[str, jax.Array]:
 
 
 def resident_bytes(geom: PageGeometry) -> int:
-    """Resident KV pool bytes at this geometry — the bench memory stat."""
+    """Resident KV pool bytes at this geometry."""
     g = geom
     rows = g.n_layers * g.n_pages * g.page_size
     if g.mode == "bf16":
@@ -148,8 +148,8 @@ def stored_row_bytes(geom: PageGeometry) -> int:
 def decode_traffic_bytes(
     geom: PageGeometry, pages_held: int, n_slots: int, paged: bool
 ) -> int:
-    """KV HBM bytes one decode step touches under each kernel — the
-    bench's per-token traffic model (``bench.py serve``).
+    """KV HBM bytes one decode step touches under each kernel, from
+    the geometry alone (a model of the traffic, not a measurement).
 
     - ``paged``: every layer reads only the ``pages_held`` pages the
       whole batch holds and writes one row per slot::

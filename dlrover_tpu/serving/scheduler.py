@@ -467,7 +467,7 @@ class Scheduler:
             return self._hists["e2e"].summary()
 
     def latency_summary(self) -> dict:
-        """Flat per-phase percentile summary (the bench/record shape)."""
+        """Flat per-phase percentile summary (the record's shape)."""
         h = self.histograms()
         out = h["e2e"].summary()
         out.update(

@@ -66,7 +66,7 @@ def tier_model_tables(model, cold_dir: str, *, flush_every: int = 256,
                       codec: str = "f32") -> List:
     """Wrap every KvTable in ``model.coll`` with a TieredTable over a
     FileColdStore under ``cold_dir/<table>`` — the one-call setup for
-    tiered serving (bench + drills). Returns the TieredTables."""
+    tiered serving (tests and drills). Returns the TieredTables."""
     import os
 
     from dlrover_tpu.sparse.tiered import FileColdStore, TieredTable

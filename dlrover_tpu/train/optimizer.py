@@ -861,7 +861,7 @@ def make_optimizer(
     if name == "adamw" and state_dtype in ("mixed8", "mixed4"):
         # bf16 momentum + int8/int4 blockwise variance: frees ~75% of
         # nu's HBM with Adafactor-grade variance fidelity; cheaper per
-        # step than bf16 nu (less optimizer bandwidth). The bench's
+        # step than bf16 nu (less optimizer bandwidth). The
         # save_qkv_gate remat tier exists because of this headroom.
         from dlrover_tpu.ops.quant import mixed_adamw
 

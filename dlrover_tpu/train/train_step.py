@@ -174,9 +174,9 @@ def _probe_flat_optimizer(
 # fallback reasons already logged, keyed (reason, config name): the
 # resolver runs on every trace (builder init, abstract/init state, AOT
 # prewarm), and re-warning the same fallback each time buries real
-# warnings. The chosen reason also rides the bench/MULTICHIP records
-# (TrainStepBuilder.update_sharding_reason), which is where a fallback
-# should be noticed.
+# warnings. The chosen reason also rides the dryrun's MULTICHIP-STATS
+# lines (TrainStepBuilder.update_sharding_reason), which is where a
+# fallback should be noticed.
 _LOGGED_FALLBACKS: set = set()
 
 # pack-plan cache: the resolver runs at least three times per job

@@ -269,11 +269,11 @@ class Trainer:
         self.callbacks = CallbackList(callbacks)
         if self.spike_detector is not None:
             self.callbacks.add(LossSpikeCallback(self.spike_detector))
-        # planned exposed-collective µs from the compile-time overlap
-        # report (bench sets this); compared against the measured
-        # runtime-trace collective time → OverlapDriftRecord
+        # planned exposed-collective µs, set by a caller that has a plan;
+        # compared against the measured runtime-trace collective time →
+        # OverlapDriftRecord. 0 = pure measurement.
         self.planned_exposed_us = 0.0
-        # bench-measured step time for this shape (PlanRecord.
+        # expected step time for this shape (PlanRecord.
         # planned_step_time_s); the watchdog's step_time_regression
         # baseline. 0 = no plan, drift detection off.
         self.planned_step_time_s = 0.0

@@ -52,9 +52,8 @@ class FakeClock:
 
 def test_cold_start_plan_reproduces_hand_tuned_flagship():
     """The acceptance bar: from ONLY the model shape + a 16 GiB chip,
-    the planner lands on the hand-tuned bench recipe for the flagship
-    long-context row (llama-1.4b, b1 x s8192, save_qkv — bench.py
-    _ATTEMPTS[0]), i.e. cold_start_mfu_frac == 1.0 by construction."""
+    the planner lands on the hand-tuned recipe for the flagship
+    long-context row (llama-1.4b, b1 x s8192, save_qkv)."""
     cfg = get_config("llama-1.4b", max_seq=8192)
     plan = brain.ColdStartPlanner().plan(
         cfg, n_devices=1, seq=8192, hbm_bytes=16e9
@@ -109,6 +108,33 @@ def test_estimate_hbm_is_calibrated_to_the_attempt_ladder():
     budget = 16e9 * 0.92
     assert brain.estimate_hbm_bytes(cfg, 1, 8192, "save_qkv") <= budget
     assert brain.estimate_hbm_bytes(cfg, 1, 8192, "save_qkv_gate") > budget
+
+
+def test_bucket_suggestion_scales_with_zero2_accumulation():
+    """ZeRO-2 pays the gradient exchange once per microbatch, so the
+    launch cost recurs ``grad_accum`` times a step and the smallest
+    bucket worth its launch grows with it; ZeRO-1 defers to one exchange
+    a step and takes the answer of no accumulation. At least four
+    buckets stay in flight, inside [1, 64] MB."""
+    grad_bytes = 4e9
+    mb1 = brain._suggest_bucket_mb(grad_bytes, launch_us=10.0)
+    mb2 = brain._suggest_bucket_mb(
+        grad_bytes, launch_us=10.0, grad_accum=4, update_mode="zero2"
+    )
+    assert 1.0 < mb1 and mb2 < 64.0
+    assert mb2 == pytest.approx(4 * mb1, rel=1e-3)
+    assert brain._suggest_bucket_mb(
+        grad_bytes, launch_us=10.0, grad_accum=4, update_mode="zero1"
+    ) == mb1
+    # a slow launch is clamped at 64 MB, a small gradient at a quarter of
+    # itself, a tiny one at 1 MB
+    assert brain._suggest_bucket_mb(
+        grad_bytes, launch_us=100.0, grad_accum=4, update_mode="zero2"
+    ) == 64.0
+    assert brain._suggest_bucket_mb(
+        16 * 2**20, launch_us=10.0, grad_accum=4, update_mode="zero2"
+    ) == 4.0
+    assert brain._suggest_bucket_mb(2**20) == 1.0
 
 
 def test_tuning_plan_round_trips_and_replays_old_lines():

@@ -159,6 +159,103 @@ ENTRY %main.1 (a: f32[8]) -> f32[8] {
                        "jit(s)/zero.pack/dynamic_update_slice") == "exchange"
 
 
+_RS = "channel_id=1, replica_groups={{0,1,2,3}}, use_global_device_ids=true"
+COLLECTIVE_CASES = {
+    "reduce-scatter": (
+        "  %reduce-scatter.3 = f32[4,128]{1,0} reduce-scatter(f32[16,128]{1,0} "
+        f"%p), {_RS}, dimensions={{0}}, to_apply=%add",
+        {"reduce-scatter": 1}, {"f32": 2048}, {"reduce-scatter": 2048},
+    ),
+    "all-reduce": (
+        "  %all-reduce.1 = bf16[8,1024,1600]{2,1,0:T(8,128)(2,1)} all-reduce("
+        f"bf16[8,1024,1600]{{2,1,0}} %g), {_RS}, to_apply=%add.1",
+        {"all-reduce": 1}, {"bf16": 26214400}, {"all-reduce": 26214400},
+    ),
+    # the loss: a scalar has no dimension and one element
+    "all-reduce of a scalar": (
+        f"  ROOT %all-reduce.9 = f32[] all-reduce(f32[] %l), {_RS}, to_apply=%add",
+        {"all-reduce": 1}, {"f32": 4}, {"all-reduce": 4},
+    ),
+    "all-gather": (
+        "  %all-gather.4 = f32[782,8192,128]{2,1,0} all-gather(f32[782,2048,128]"
+        f"{{2,1,0}} %shard), {_RS}, dimensions={{1}}",
+        {"all-gather": 1}, {"f32": 3279945728}, {"all-gather": 3279945728},
+    ),
+    "all-to-all": (
+        f"  %all-to-all.2 = s8[4,256]{{1,0}} all-to-all(s8[4,256]{{1,0}} %q), {_RS}",
+        {"all-to-all": 1}, {"s8": 1024}, {"all-to-all": 1024},
+    ),
+    "collective-permute": (
+        "  %collective-permute.2 = bf16[2,64]{1,0} collective-permute(bf16[2,64]"
+        "{1,0} %x), channel_id=3, source_target_pairs={{0,1},{1,0}}",
+        {"collective-permute": 1}, {"bf16": 256}, {"collective-permute": 256},
+    ),
+    # the dp=4 cell's bucket collectives run combined: one instruction,
+    # a tuple result, every member's bytes summed under its own dtype
+    "tuple all-reduce": (
+        "  %all-reduce.5 = (f32[8192,128]{1,0}, f32[4096,128]{1,0}, bf16[16]{0})"
+        " all-reduce(f32[8192,128]{1,0} %a, f32[4096,128]{1,0} %b, bf16[16]{0} "
+        f"%c), {_RS}, to_apply=%add",
+        {"all-reduce": 1}, {"f32": 6291456, "bf16": 32}, {"all-reduce": 6291488},
+    ),
+    # counted, but a dtype outside the byte table adds no bytes
+    "unknown dtype": (
+        "  %all-gather.7 = f8e4m3fn[1024]{0} all-gather(f8e4m3fn[256]{0} %w), "
+        f"{_RS}, dimensions={{0}}",
+        {"all-gather": 1}, {}, {},
+    ),
+    "collective-broadcast": (
+        "  %collective-broadcast.1 = u32[2]{0} collective-broadcast(u32[2]{0} "
+        "%k), channel_id=4, replica_groups={{0,1}}",
+        {"collective-broadcast": 1}, {"u32": 8}, {"collective-broadcast": 8},
+    ),
+    # a collective as an OPERAND, a line that is no instruction, a
+    # computation's header
+    "no collective": (
+        "  %fusion.1 = f32[8]{0} fusion(f32[8]{0} %all-reduce.1), kind=kLoop\n"
+        "  all-reduce(f32[8]{0} %p)\n"
+        "%all-reduce.clone (a: f32[8]) -> f32[8] {",
+        {}, {}, {},
+    ),
+    # the asynchronous pair is neither ``all-reduce(`` nor counted twice:
+    # the compiled steps these tests and the dryruns read hold the
+    # synchronous spelling
+    "async pair": (
+        "  %all-reduce-start.1 = f32[8]{0} all-reduce-start(f32[8]{0} %p), "
+        f"{_RS}, to_apply=%add\n"
+        "  %all-reduce-done.1 = f32[8]{0} all-reduce-done(f32[8]{0} "
+        "%all-reduce-start.1)\n"
+        "  %all-gather-start.2 = (f32[2]{0}, f32[8]{0}) all-gather-start(f32[2]"
+        f"{{0}} %s), {_RS}, dimensions={{0}}",
+        {}, {}, {},
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(COLLECTIVE_CASES))
+def test_collective_stats(case):
+    text, counts, by_dtype, by_op = COLLECTIVE_CASES[case]
+    assert rt.collective_stats(text) == {
+        "counts": counts, "bytes_by_dtype": by_dtype, "bytes_by_op": by_op,
+    }
+
+
+def test_collective_stats_sums_over_a_module():
+    text = "\n".join(COLLECTIVE_CASES[c][0] for c in sorted(COLLECTIVE_CASES))
+    stats = rt.collective_stats("HloModule jit_step\n\n" + text + "\n}\n")
+    assert stats["counts"] == {
+        "all-reduce": 3, "all-gather": 2, "reduce-scatter": 1,
+        "all-to-all": 1, "collective-permute": 1, "collective-broadcast": 1,
+    }
+    assert stats["bytes_by_op"]["all-reduce"] == 26214400 + 4 + 6291488
+    assert stats["bytes_by_dtype"]["f32"] == (
+        2048 + 4 + 3279945728 + 6291456
+    )
+    assert sum(stats["bytes_by_op"].values()) == sum(
+        stats["bytes_by_dtype"].values()
+    )
+
+
 SCOPE_CASES = {
     # the routed layer's scopes sit inside ``mlp``: the innermost counts
     "moe.sort": "jit(s)/jvp()/while/body/closed_call/mlp/moe.sort/argsort",

@@ -145,9 +145,7 @@ def compiled_fp8_sharded():
 
 
 def test_hlo_quantizes_and_reduce_scatters(compiled_fp8_sharded):
-    # function-local: bench is the benchmark entry script (see
-    # test_marker_lint's bench-import rule)
-    from bench import collective_stats
+    from dlrover_tpu.observability.runtime_timer import collective_stats
 
     _, _, _, lowered_text, compiled = compiled_fp8_sharded
     low = lowered_text.lower()

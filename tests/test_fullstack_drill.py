@@ -477,7 +477,7 @@ def test_fullstack_elasticity_drill(monkeypatch, tmp_path):
         # one time-sorted JSONL of every process's spans; the worker-kill
         # failover must decompose into detect → (persist) → rendezvous →
         # restore → first-step, with all three roles on the timeline
-        trace_out = os.path.join(REPO, "DRILL_r08_trace.jsonl")
+        trace_out = str(tmp_path / "drill_trace.jsonl")
         events = merge_trace_dir(trace_dir, out_path=trace_out)
         phases, win = _failover_phases(
             events, t_kill_worker, t_kill_worker + recovery_worker_s
@@ -517,11 +517,7 @@ def test_fullstack_elasticity_drill(monkeypatch, tmp_path):
             "trace_events": len(events),
             "trace_path": os.path.basename(trace_out),
         }
-        out_path = os.environ.get(
-            "DLROVER_TPU_DRILL_ARTIFACT",
-            os.path.join(REPO, "DRILL_r08.json"),
-        )
-        with open(out_path, "w") as f:
+        with open(tmp_path / "drill.json", "w") as f:
             json.dump(artifact, f, indent=1)
         print(f"\n[drill] {json.dumps(artifact)}")
     finally:
@@ -668,36 +664,20 @@ def test_live_reshard_eviction_drill(tmp_path):
         ]
         assert not disk_restores, disk_restores
 
-        # ---- artifact: append the eviction stage ----------------------
-        out_path = os.environ.get(
-            "DLROVER_TPU_DRILL_ARTIFACT",
-            os.path.join(REPO, "DRILL_r08.json"),
-        )
-        try:
-            with open(out_path) as f:
-                artifact = json.load(f)
-        except (OSError, ValueError):
-            # test-order independence: a minimal shell when the main
-            # drill has not written the artifact yet
-            artifact = {
-                "drill": "test_fullstack_elasticity_drill",
-                "failures": [],
-                "recovery_budget_s": RECOVERY_BUDGET_S,
-            }
-        artifact.setdefault("failures", [])
-        artifact["failures"] = [
-            f
-            for f in artifact["failures"]
-            if f.get("kind") != "host_eviction_live_reshard"
-        ] + [
-            {
-                "kind": "host_eviction_live_reshard",
-                "recovery_s": round(float(summary["recovery_s"]), 2),
-                "phases": phase_s,
-                "restore_tier": "live",
-            }
-        ]
-        with open(out_path, "w") as f:
+        # ---- artifact: the eviction stage -----------------------------
+        artifact = {
+            "drill": "test_live_reshard_eviction_drill",
+            "failures": [
+                {
+                    "kind": "host_eviction_live_reshard",
+                    "recovery_s": round(float(summary["recovery_s"]), 2),
+                    "phases": phase_s,
+                    "restore_tier": "live",
+                }
+            ],
+            "recovery_budget_s": RECOVERY_BUDGET_S,
+        }
+        with open(tmp_path / "drill.json", "w") as f:
             json.dump(artifact, f, indent=1)
         print(f"\n[drill] {json.dumps(artifact['failures'][-1])}")
     finally:

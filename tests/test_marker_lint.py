@@ -90,38 +90,6 @@ def _module_slow_marked(tree) -> bool:
     return False
 
 
-def test_bench_imports_are_slow_or_local():
-    """Module-level ``import bench`` is reserved for slow-marked files.
-
-    ``bench`` is the benchmark ENTRY SCRIPT, not a library: importing
-    it at module scope runs its argv/env setup and heavyweight imports
-    during tier-1 COLLECTION, for every test in the file — even when
-    the only consumer is one HLO-guard test. Files whose whole module
-    is ``pytestmark = pytest.mark.slow`` may import it at top level
-    (they never collect into tier-1's budget); everyone else imports
-    it inside the test function that needs it.
-    """
-    rogue = []
-    for path in sorted(_TESTS.glob("*.py")):
-        tree = ast.parse(path.read_text(), filename=str(path))
-        if _module_slow_marked(tree):
-            continue
-        for node in tree.body:  # module level only, by design
-            if isinstance(node, ast.Import):
-                names = [a.name for a in node.names]
-            elif isinstance(node, ast.ImportFrom):
-                names = [node.module or ""]
-            else:
-                continue
-            if any(n == "bench" or n.startswith("bench.") for n in names):
-                rogue.append(f"{path.name}:{node.lineno}")
-    assert not rogue, (
-        "module-level bench import in non-slow test files (move the "
-        "import inside the test, or mark the whole module slow):\n"
-        + "\n".join(rogue)
-    )
-
-
 def _test_functions(tree):
     """Top-level (incl. class-nested) test functions with their decorator
     lists."""
@@ -308,9 +276,6 @@ def test_pallas_paged_importers_are_interpret_units_or_slow():
 # updating the ledger is a hard failure; deleting/renaming the test
 # fails the existence check so the ledger can't rot silently.
 _SLOW_LEDGER = [
-    "test_bench_smoke.py::test_bench_single_tiny_emits_schema",
-    "test_bench_smoke.py::test_bench_single_block_k_mode",
-    "test_bench_smoke.py::test_bench_single_save_qkv_offload_recipe",
     "test_fused_block.py::test_blockwise_cadences_match_stepwise[5]",
     "test_fused_block.py::test_blockwise_cadences_match_stepwise[8]",
     "test_fused_block.py::test_blockwise_cadences_match_stepwise[13]",
@@ -502,7 +467,6 @@ _SLOW_LEDGER = [
     # prefetcher, cold-store and partition-property units in the same
     # files stay tier-1.
     "test_sparse_serving.py::test_ps_reshard_drill_mid_traffic",
-    "test_bench_smoke.py::test_bench_sparse_serve_mode_emits_schema",
 ]
 
 

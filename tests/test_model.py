@@ -1,6 +1,8 @@
 """Model + sharded train step tests on the 8-device CPU mesh."""
 
 import dataclasses
+import json
+import pathlib
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +38,46 @@ def test_forward_shapes():
     )
     assert logits.shape == (2, 16, cfg.vocab_size)
     assert logits.dtype == jnp.float32
+
+
+# the benchmark's configurations (read only) and the sequence length of
+# the cell that runs each
+BENCHMARK_CONFIGS = {
+    "gpt2-xl": 1024,
+    "gpt2-xl-zero1-dp4": 1024,
+    "mistral-7b-l6": 8192,
+    "olmoe-1b-7b-1chip": 4096,
+}
+
+
+@pytest.mark.parametrize("name", sorted(BENCHMARK_CONFIGS))
+def test_flops_per_token_is_the_benchmarks_count(name):
+    """One FLOP count: the MFU the trainer reports to the master and the
+    benchmark's ``train_step.mfu`` divide the same numerator — multiplied
+    parameters only, the head once, the causal and windowed mean span,
+    ``routed_top_k`` experts and the router for a routed layer."""
+    from benchmarks.lib.flops import required_flops_per_token
+
+    configs = pathlib.Path(__file__).parent.parent / "benchmarks" / "configs"
+    assert sorted(p.stem for p in configs.glob("*.json")) == sorted(
+        BENCHMARK_CONFIGS
+    ), "a configuration without a case here"
+    config = json.loads((configs / f"{name}.json").read_text())
+    cfg = get_config(
+        config["program"]["model"], **config["program"]["overrides"]
+    )
+    for seq in (BENCHMARK_CONFIGS[name], 2 * BENCHMARK_CONFIGS[name], 512):
+        assert cfg.flops_per_token(seq) == pytest.approx(
+            required_flops_per_token(config["sizes"], seq), rel=1e-9
+        )
+    # bidirectional attention sees every key, a causal one half on average
+    both_ways = dataclasses.replace(cfg, causal=False, attn_window=0)
+    causal = dataclasses.replace(cfg, attn_window=0)
+    assert both_ways.flops_per_token(1024) - causal.flops_per_token(
+        1024
+    ) == pytest.approx(
+        12.0 * cfg.n_layer * cfg.n_head * cfg.head_dim * (1024 - 512.5)
+    )
 
 
 @pytest.mark.slow  # tier-1 budget: three full model inits (~58s); the

@@ -467,3 +467,68 @@ def test_ps_reshard_drill_mid_traffic(two_servers):
     srv.stop()
     demb.close()
     model.dense_params = None  # model.close() would close demb twice
+
+
+@pytest.mark.slow  # a serving loop (test_marker_lint's serving rule)
+def test_tiered_serving_prefetch_moves_rows_not_values(tmp_path):
+    """The recommendation replica over tiered tables whose rows all start
+    in the cold tier, the same requests twice: lookahead prefetch off
+    (every row faults on the request path), then on (the prefetcher
+    promotes the queue's head while the loop is parked). The scores are
+    bit-equal: tiers move rows, never values."""
+    from dlrover_tpu.serving.sparse_engine import (
+        SparseServingServer,
+        merged_tier_snapshot,
+        tier_model_tables,
+    )
+    from dlrover_tpu.sparse.tiered import TierStats
+
+    cfg = DeepFMConfig(n_fields=4, n_dense=3, emb_dim=8, mlp_dims=(16,))
+    rng = np.random.default_rng(5)
+    n = 24
+    cat = rng.integers(0, 500, size=(n, cfg.n_fields)).astype(np.int64)
+    dense = rng.normal(size=(n, cfg.n_dense)).astype(np.float32)
+    labels = (rng.random(n) < 0.3).astype(np.float32)
+    model = DeepFM(cfg, optimizer=GroupAdam(lr=5e-3), dense_lr=5e-3)
+    try:
+        tiered = tier_model_tables(model, str(tmp_path))
+        model.train_step(cat, dense, labels)  # creates every row served
+
+        def serve(prefetch):
+            for t in tiered:
+                t.demote_before_timestamp(2**60)
+                t.stats = TierStats()
+            assert all(t.hot_size == 0 for t in tiered)
+            srv = SparseServingServer(
+                model, cfg, replica=f"rec-pf{int(prefetch)}",
+                prefetch=prefetch, prefetch_lookahead=n, max_batch=1,
+            ).start()
+            try:
+                with srv.paused():
+                    reqs = [srv.submit(cat[i], dense[i]) for i in range(n)]
+                    if prefetch:
+                        pf = srv.prefetcher
+                        deadline = time.monotonic() + 60.0
+                        while (
+                            pf.keys_promoted == 0
+                            and time.monotonic() < deadline
+                        ):
+                            pf.notify()
+                            time.sleep(0.001)
+                        assert pf.drain(timeout=60.0)
+                scores = [r.future.result(timeout=60)[0] for r in reqs]
+            finally:
+                srv.stop()
+            return np.array(scores, np.float32), merged_tier_snapshot(tiered)
+
+        scores_off, off = serve(False)
+        scores_on, on = serve(True)
+    finally:
+        model.close()
+    np.testing.assert_array_equal(scores_on, scores_off)
+    assert off["cold_faults"] > 0 and off["prefetched"] == 0
+    assert off["prefetch_coverage"] == 0.0
+    # the whole queue fitted the lookahead window and was promoted before
+    # the loop served its first request
+    assert on["prefetched"] > 0 and on["cold_faults"] == 0
+    assert on["hot_rows"] == off["hot_rows"] > 0

@@ -330,9 +330,7 @@ def compiled_sharded():
 
 
 def test_hlo_has_rs_and_ag(compiled_sharded):
-    # function-local: bench is the slow-suite module (see
-    # test_marker_lint's bench-import rule)
-    from bench import collective_stats
+    from dlrover_tpu.observability.runtime_timer import collective_stats
 
     _, _, _, _, compiled = compiled_sharded
     stats = collective_stats(compiled.as_text())

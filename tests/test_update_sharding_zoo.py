@@ -36,8 +36,8 @@ import numpy as np
 import optax
 import pytest
 
-from bench import collective_stats
 from dlrover_tpu.models.config import get_config
+from dlrover_tpu.observability.runtime_timer import collective_stats
 from dlrover_tpu.parallel import sharding as shd
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.train.optimizer import (
