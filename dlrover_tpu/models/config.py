@@ -46,10 +46,10 @@ class ModelConfig:
     # (less grid overhead); _fit_block caps them to the actual sequence.
     attn_block_q: int = 1024
     attn_block_k: int = 1024
-    # heads per flash-kernel program (narrow-head packing; 0 = auto:
-    # 128 // head_dim when head_dim < 128 and the layout is MHA, so
-    # gpt2-family d=64 shapes amortize mask/iota work and grid overhead
-    # across 2 heads per program; 1 disables)
+    # narrow-head packing in the flash kernels (0 = auto: when head_dim
+    # < 128 and the layout is MHA, 128 // head_dim heads share a 128-lane
+    # slab of the projections' [B, S, H·D] arrays — gpt2-family d=64: two
+    # heads a program, no relayout around the kernels; 1 disables)
     attn_head_pack: int = 0
     rope_theta: float = 10000.0
     # RMS/LayerNorm (cfg.norm) over the WHOLE q and k projections

@@ -25,19 +25,29 @@ Both paths support GLM-style prefix-LM masking (per-batch prefix scalar in
 SMEM) and GQA (K/V shared across head groups via BlockSpec index maps, no
 materialized repeats).
 
-Narrow-head packing (``head_pack``): heads narrower than the 128-lane MXU
-quantum (gpt2's head_dim=64) pack ``128 // head_dim`` heads into ONE grid
-program along a leading block axis ([pack, block, d] tiles). The per-head
-matmuls are unrolled inside the program with their m/l/acc/lse bookkeeping
-kept per-head, so numerics are identical to the unpacked kernels. What the
-packing buys is NOT more MXU lanes per matmul — the 128-lane quantum makes
-a d=64 contraction cost the same executed MXU passes packed or not — it is
-everything around the matmuls: the causal/prefix/window mask and its iotas
-are computed once per program and shared by all packed heads (VPU work that
-otherwise rivals the d<128 matmul cost), there are pack× fewer grid
-programs/epilogues, and K/V tiles DMA in pack-head batches. Heads that
-don't divide evenly are zero-padded at the jnp level (a zeroed q/k/v head
-yields out=0 and a finite lse, sliced off after); GQA keeps the unpacked
+Narrow-head packing (``head_pack``): heads narrower than the 128-lane
+quantum (gpt2's head_dim=64) share a **slab**: 128 columns —
+``128 // head_dim`` heads side by side — of the projection's own
+``[B, S, H·D]`` array, of which ``[B, S, H, D]`` is a free view. The packed
+kernels run on grid (batch, slab, q block, k block) and take the
+``[block, 128]`` tile at column block ``slab`` as it lies, and write the
+output, dq, dk and dv the same way: the BlockSpec index map does what a
+transpose to ``[B, H, S, D]`` would, so nothing is padded, transposed or
+sliced in memory between the projection matmuls and the kernels. Inside a
+program the heads are kept apart by zeroing one operand outside head p's
+lanes (a 32-bit AND, ``_and_lanes``): ``q @ k_pᵀ`` contracts 128 lanes of
+which ``d`` are live — the MXU passes of a ``d``-deep contraction, the
+128-lane quantum makes them cost the same — and ``p_p @ v_p`` lands on
+head p's lanes of ONE ``[block_q, 128]`` accumulator, so every store is
+lane-dense. Softmax statistics, lse and delta stay per head. What packing
+buys is everything around the matmuls: no relayout pass over q, k, v, out
+or their cotangents, the causal/prefix/window mask and its iotas computed
+once per program and shared by the slab's heads, pack× fewer grid
+programs. A head count that does not fill its last slab (gpt2-1.5b's 25 ×
+64 = 12½ slabs) is handled in the kernel: the half slab reads past column
+H·D, where the block holds nothing specified; those lanes are in no head's
+mask and are ANDed to zero in the operands used whole, and the part of an
+output block beyond the array is written nowhere. GQA keeps the unpacked
 path (every GQA config here runs full-width d=128 heads anyway).
 """
 
@@ -57,6 +67,7 @@ except ImportError:  # pragma: no cover
     pltpu = None
 
 from dlrover_tpu.common import device
+from dlrover_tpu.observability.tracing import set_counter
 
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
@@ -194,22 +205,27 @@ def _p_and_ds(s, do, v, lse_col, delta_col, scale):
     return p, ds
 
 
-def _fwd_head_step(s, v, m_prev, l_prev, acc_prev):
-    """One head's online-softmax update from masked scores ``s`` — the
-    math shared verbatim by the unpacked and head-packed forward
-    kernels. Returns (m_new [bq,1], l_new [bq,1], acc_new [bq,d])."""
+def _softmax_step(s, m_prev, l_prev):
+    """One head's online-softmax statistics from masked scores ``s`` —
+    the math shared verbatim by the unpacked and head-packed forward
+    kernels. Returns (m_new [bq,1], l_new [bq,1], alpha [bq,1] — the
+    factor the old accumulator shrinks by — and p [bq,bk] f32)."""
     m_cur = jnp.max(s, axis=1, keepdims=True)
     m_new = jnp.maximum(m_prev, m_cur)
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.exp(s - m_new)
     l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-    acc_new = acc_prev * alpha + jax.lax.dot_general(
+    return m_new, l_new, alpha, p
+
+
+def _pv(p, v):
+    """p @ v in v's dtype with f32 accumulation."""
+    return jax.lax.dot_general(
         p.astype(v.dtype),
         v,
         (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    return m_new, l_new, acc_new
 
 
 def _fwd_kernel(
@@ -259,10 +275,10 @@ def _fwd_kernel(
             q_ref[0], k_ref[0], scale, q_start, k_start,
             block_q, block_k, causal, has_prefix, pref, window=window,
         )
-        m_new, l_new, acc_new = _fwd_head_step(
-            s, v_ref[0], m_scratch[:, :1], l_scratch[:, :1], acc_scratch[:]
-        )
-        acc_scratch[:] = acc_new
+        v, m_prev, l_prev = v_ref[0], m_scratch[:, :1], l_scratch[:, :1]
+        acc_prev = acc_scratch[:]
+        m_new, l_new, alpha, p = _softmax_step(s, m_prev, l_prev)
+        acc_scratch[:] = acc_prev * alpha + _pv(p, v)
         m_scratch[:] = jnp.broadcast_to(m_new, m_scratch.shape)
         l_scratch[:] = jnp.broadcast_to(l_new, l_scratch.shape)
 
@@ -277,17 +293,60 @@ def _fwd_kernel(
         )
 
 
+LANES = 128  # a slab: the lane width of one vector register / MXU tile
+
+
+def _and_lanes(x, bits):
+    """``x`` [rows, 128] with the lanes whose ``bits`` [1, 128] uint32
+    are 0 set to zero, as a 32-bit AND on the raw words: exact, NaN in
+    a dropped lane does not survive (a multiply by 0 would keep it), and
+    for bf16 one word holds two ROWS of the same lane, so the mask needs
+    no 16-bit select (the v5e's vector unit has none)."""
+    return pltpu.bitcast(pltpu.bitcast(x, jnp.uint32) & bits, x.dtype)
+
+
+def _slab_lanes(slab, heads, d, pack):
+    """Lane masks of one slab — ``pack`` heads of width ``d`` side by
+    side in 128 lanes of the projection's ``[B, S, H·D]`` array.
+    Returns (per-head uint32 masks [1, 128], mask of the real lanes or
+    None). With ``heads % pack != 0`` the last slab reaches
+    past column H·D: what the block holds there is unspecified, those
+    lanes are in no head's mask, and ``real`` cleans the operands that
+    are used whole."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    ones, zero = jnp.uint32(0xFFFFFFFF), jnp.uint32(0)
+    real = lane < (heads - slab * pack) * d if heads % pack else None
+    head_bits = []
+    for p in range(pack):
+        keep = jnp.logical_and(lane >= p * d, lane < (p + 1) * d)
+        if real is not None:
+            keep = jnp.logical_and(keep, real)
+        head_bits.append(jnp.where(keep, ones, zero))
+    real_bits = None if real is None else jnp.where(real, ones, zero)
+    return head_bits, real_bits
+
+
+def _spread_heads(cols, d):
+    """Per-head columns (``pack`` arrays [rows, 1]) → [rows, 128] with
+    head p's value on head p's lanes."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    out = cols[-1]
+    for p in range(len(cols) - 2, -1, -1):
+        out = jnp.where(lane < (p + 1) * d, cols[p], out)
+    return out
+
+
 def _fwd_kernel_packed(
-    q_ref,  # [1, pack, block_q, d]
-    k_ref,  # [1, pack, block_k, d]
-    v_ref,  # [1, pack, block_k, d]
+    q_ref,  # [1, block_q, 128]: one slab of [B, S, H·D]
+    k_ref,  # [1, block_k, 128]
+    v_ref,  # [1, block_k, 128]
     prefix_ref,  # [B, 1] int32 in SMEM (None w/o prefix)
     offs_ref,  # [1, 2] int32 in SMEM (None w/o offsets)
-    o_ref,  # [1, pack, block_q, d]
+    o_ref,  # [1, block_q, 128]
     lse_ref,  # [1, pack, block_q, 8] f32
     m_scratch,  # [pack, block_q, 128] f32
     l_scratch,  # [pack, block_q, 128] f32
-    acc_scratch,  # [pack, block_q, d] f32
+    acc_scratch,  # [block_q, 128] f32: every head on its own lanes
     *,
     causal: bool,
     scale: float,
@@ -295,22 +354,24 @@ def _fwd_kernel_packed(
     block_k: int,
     has_prefix: bool,
     has_offsets: bool = False,
-    n_head: int = 1,  # grid-dim-0 entries per batch = h // pack
+    heads: int = 2,  # real heads H (the last slab may hold fewer)
     window: int = 0,
     pack: int = 2,
 ):
-    """Head-packed forward: ``pack`` heads of the same batch share one
-    grid program. The per-head online softmax is unrolled with m/l/acc
-    kept per-head, so the results are identical to the unpacked kernel;
-    the mask (the VPU-side cost that rivals a d<128 matmul) is computed
-    ONCE and shared — that, the pack× fewer programs, and the batched
-    K/V DMA are the whole point of packing."""
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
-    pref = (
-        prefix_ref[pl.program_id(0) // n_head, 0] if has_prefix else None
-    )
+    """Head-packed forward on grid (batch, slab, q block, k block): the
+    ``pack`` heads of a slab share one program and one mask. Head p's
+    scores are ``q @ k_pᵀ`` with ``k_p`` the key tile zeroed outside
+    head p's lanes — a 128-deep contraction of which ``d`` lanes are
+    live, the MXU passes of a ``d``-deep one — and ``p_p @ v_p`` lands
+    on head p's lanes of the one [block_q, 128] accumulator, so the
+    output is stored lane-dense. The online softmax is per head, as in
+    the unpacked kernel."""
+    slab = pl.program_id(1)
+    qi = pl.program_id(2)
+    ki = pl.program_id(3)
+    nk = pl.num_programs(3)
+    d = LANES // pack
+    pref = prefix_ref[pl.program_id(0), 0] if has_prefix else None
 
     @pl.when(ki == 0)
     def _init():
@@ -328,29 +389,40 @@ def _fwd_kernel_packed(
             q_start, k_start, block_q, block_k, causal, has_prefix,
             pref, window=window,
         )
+        head_bits, real_bits = _slab_lanes(slab, heads, d, pack)
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]
+        if real_bits is not None:
+            q = _and_lanes(q, real_bits)
+        alphas, pv = [], None
         for p in range(pack):
             s = _masked_scores(
-                q_ref[0, p], k_ref[0, p], scale, q_start, k_start,
+                q, _and_lanes(k, head_bits[p]), scale, q_start, k_start,
                 block_q, block_k, causal, has_prefix, pref,
                 window=window, allowed=allowed,
             )
-            m_new, l_new, acc_new = _fwd_head_step(
-                s, v_ref[0, p],
-                m_scratch[p, :, :1], l_scratch[p, :, :1], acc_scratch[p],
+            m_new, l_new, alpha, pr = _softmax_step(
+                s, m_scratch[p, :, :1], l_scratch[p, :, :1]
             )
-            acc_scratch[p] = acc_new
+            pv_p = _pv(pr, _and_lanes(v, head_bits[p]))
             m_scratch[p] = jnp.broadcast_to(m_new, m_scratch.shape[1:])
             l_scratch[p] = jnp.broadcast_to(l_new, l_scratch.shape[1:])
+            alphas.append(alpha)
+            pv = pv_p if pv is None else pv + pv_p
+        acc_scratch[:] = acc_scratch[:] * _spread_heads(alphas, d) + pv
 
     @pl.when(ki == nk - 1)
     def _finish():
+        ls = []
         for p in range(pack):
             l = l_scratch[p, :, :1]
             l = jnp.where(l == 0.0, 1.0, l)
-            o_ref[0, p] = (acc_scratch[p] / l).astype(o_ref.dtype)
             lse_ref[0, p] = jnp.broadcast_to(
                 m_scratch[p, :, :1] + jnp.log(l), lse_ref.shape[2:]
             )
+            ls.append(l)
+        o_ref[0] = (acc_scratch[:] / _spread_heads(ls, d)).astype(
+            o_ref.dtype
+        )
 
 
 def _insert_none_args(kernel, idxs):
@@ -484,10 +556,11 @@ def _bwd_dkv_kernel(
 
 
 def _bwd_dq_kernel_packed(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, prefix_ref,
-    offs_ref,
-    dq_ref,
-    acc_scratch,  # [pack, block_q, d] f32
+    q_ref, k_ref, v_ref, do_ref,  # [1, block, 128] slabs of [B, S, H·D]
+    lse_ref, delta_ref,  # [1, pack, block_q, 8] f32
+    prefix_ref, offs_ref,
+    dq_ref,  # [1, block_q, 128]
+    acc_scratch,  # [block_q, 128] f32
     *,
     causal: bool,
     scale: float,
@@ -495,19 +568,21 @@ def _bwd_dq_kernel_packed(
     block_k: int,
     has_prefix: bool,
     has_offsets: bool = False,
-    n_head: int = 1,
+    heads: int = 2,
     window: int = 0,
     pack: int = 2,
 ):
-    """Head-packed dq pass: q/k/v/do/lse/delta blocks carry a leading
-    ``pack`` head axis; the recomputed-p backward is unrolled per head
-    under ONE shared mask (see _fwd_kernel_packed)."""
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
-    pref = (
-        prefix_ref[pl.program_id(0) // n_head, 0] if has_prefix else None
-    )
+    """Head-packed dq pass on grid (batch, slab, q block, k block): the
+    recomputed-p backward per head under ONE shared mask, heads kept
+    apart by zeroing k and v outside head p's lanes (see
+    _fwd_kernel_packed); ``ds_p @ k_p`` is then nonzero on head p's
+    lanes only and the heads' dq sum into one lane-dense tile."""
+    slab = pl.program_id(1)
+    qi = pl.program_id(2)
+    ki = pl.program_id(3)
+    nk = pl.num_programs(3)
+    d = LANES // pack
+    pref = prefix_ref[pl.program_id(0), 0] if has_prefix else None
 
     @pl.when(ki == 0)
     def _init():
@@ -523,34 +598,41 @@ def _bwd_dq_kernel_packed(
             q_start, k_start, block_q, block_k, causal, has_prefix,
             pref, window=window,
         )
+        head_bits, real_bits = _slab_lanes(slab, heads, d, pack)
+        q, do, k, v = q_ref[0], do_ref[0], k_ref[0], v_ref[0]
+        if real_bits is not None:
+            q, do = _and_lanes(q, real_bits), _and_lanes(do, real_bits)
+        dq = None
         for p in range(pack):
-            k = k_ref[0, p]
+            k_p = _and_lanes(k, head_bits[p])
             s = _masked_scores(
-                q_ref[0, p], k, scale, q_start, k_start,
+                q, k_p, scale, q_start, k_start,
                 block_q, block_k, causal, has_prefix, pref,
                 window=window, allowed=allowed,
             )
             _, ds = _p_and_ds(
-                s, do_ref[0, p], v_ref[0, p],
+                s, do, _and_lanes(v, head_bits[p]),
                 lse_ref[0, p][:, :1], delta_ref[0, p][:, :1], scale,
             )
-            acc_scratch[p] = acc_scratch[p] + jax.lax.dot_general(
-                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            dq_p = jax.lax.dot_general(
+                ds.astype(k.dtype), k_p, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
+            dq = dq_p if dq is None else dq + dq_p
+        acc_scratch[:] = acc_scratch[:] + dq
 
     @pl.when(ki == nk - 1)
     def _finish():
-        for p in range(pack):
-            dq_ref[0, p] = acc_scratch[p].astype(dq_ref.dtype)
+        dq_ref[0] = acc_scratch[:].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel_packed(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, prefix_ref,
-    offs_ref,
-    dk_ref, dv_ref,
-    dk_scratch,  # [pack, block_k, d] f32
-    dv_scratch,  # [pack, block_k, d] f32
+    q_ref, k_ref, v_ref, do_ref,  # [1, block, 128] slabs of [B, S, H·D]
+    lse_ref, delta_ref,  # [1, pack, block_q, 8] f32
+    prefix_ref, offs_ref,
+    dk_ref, dv_ref,  # [1, block_k, 128]
+    dk_scratch,  # [block_k, 128] f32
+    dv_scratch,  # [block_k, 128] f32
     *,
     causal: bool,
     scale: float,
@@ -558,18 +640,20 @@ def _bwd_dkv_kernel_packed(
     block_k: int,
     has_prefix: bool,
     has_offsets: bool = False,
-    n_head: int = 1,
+    heads: int = 2,
     window: int = 0,
     pack: int = 2,
 ):
-    """Head-packed dk/dv pass (q-blocks innermost), unrolled per head
-    under one shared mask."""
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
-    nq = pl.num_programs(2)
-    pref = (
-        prefix_ref[pl.program_id(0) // n_head, 0] if has_prefix else None
-    )
+    """Head-packed dk/dv pass on grid (batch, slab, k block, q block),
+    q-blocks innermost: here q and dO are the operands zeroed outside
+    head p's lanes, so ``pᵀ @ dO_p`` and ``dsᵀ @ q_p`` land on head
+    p's lanes of the dv and dk tiles."""
+    slab = pl.program_id(1)
+    ki = pl.program_id(2)
+    qi = pl.program_id(3)
+    nq = pl.num_programs(3)
+    d = LANES // pack
+    pref = prefix_ref[pl.program_id(0), 0] if has_prefix else None
 
     @pl.when(qi == 0)
     def _init():
@@ -586,32 +670,133 @@ def _bwd_dkv_kernel_packed(
             q_start, k_start, block_q, block_k, causal, has_prefix,
             pref, window=window,
         )
+        head_bits, real_bits = _slab_lanes(slab, heads, d, pack)
+        q, do, k, v = q_ref[0], do_ref[0], k_ref[0], v_ref[0]
+        if real_bits is not None:
+            k, v = _and_lanes(k, real_bits), _and_lanes(v, real_bits)
+        dk = dv = None
         for p in range(pack):
-            q = q_ref[0, p]
-            do = do_ref[0, p]
+            q_p = _and_lanes(q, head_bits[p])
+            do_p = _and_lanes(do, head_bits[p])
             s = _masked_scores(
-                q, k_ref[0, p], scale, q_start, k_start,
+                q_p, k, scale, q_start, k_start,
                 block_q, block_k, causal, has_prefix, pref,
                 window=window, allowed=allowed,
             )
             pr, ds = _p_and_ds(
-                s, do, v_ref[0, p],
+                s, do_p, v,
                 lse_ref[0, p][:, :1], delta_ref[0, p][:, :1], scale,
             )
-            dv_scratch[p] = dv_scratch[p] + jax.lax.dot_general(
-                pr.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            dv_p = jax.lax.dot_general(
+                pr.astype(do.dtype), do_p, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
-            dk_scratch[p] = dk_scratch[p] + jax.lax.dot_general(
-                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            dk_p = jax.lax.dot_general(
+                ds.astype(q.dtype), q_p, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
+            dv = dv_p if dv is None else dv + dv_p
+            dk = dk_p if dk is None else dk + dk_p
+        dv_scratch[:] = dv_scratch[:] + dv
+        dk_scratch[:] = dk_scratch[:] + dk
 
     @pl.when(qi == nq - 1)
     def _finish():
-        for p in range(pack):
-            dk_ref[0, p] = dk_scratch[p].astype(dk_ref.dtype)
-            dv_ref[0, p] = dv_scratch[p].astype(dv_ref.dtype)
+        dk_ref[0] = dk_scratch[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scratch[:].astype(dv_ref.dtype)
+
+
+def _optional_smem(kernel, prefix, offsets, batch, at):
+    """The optional scalar operands every kernel takes in SMEM: the
+    per-batch prefix-LM lengths and the (q, k) global offsets. Returns
+    (arrays, specs, kernel) with None spliced into the kernel's
+    ``prefix_ref`` / ``offs_ref`` slots (positions ``at``, ``at + 1``)
+    for whichever is absent."""
+    arrays, none_idxs = [], []
+    if prefix is not None:
+        # the whole [B,1] scalar table lives in SMEM; the kernel indexes
+        # its batch row from the grid (Mosaic rejects sub-8 sublane
+        # blocking, so no per-step BlockSpec windowing here)
+        arrays.append(prefix.astype(jnp.int32).reshape(batch, 1))
+    else:
+        none_idxs.append(at)
+    if offsets is not None:
+        arrays.append(offsets.astype(jnp.int32).reshape(1, 2))
+    else:
+        none_idxs.append(at + 1)
+    specs = [pl.BlockSpec(memory_space=pltpu.SMEM)] * len(arrays)
+    if none_idxs:
+        kernel = _insert_none_args(kernel, none_idxs)
+    return arrays, specs, kernel
+
+
+def _clamped_block_maps(causal_clamp, block_q, block_k, n_q_blocks, window):
+    """(k block of grid step (i, j), q block of grid step (j, i)) with
+    the run gate's dead blocks clamped onto a live neighbour: a
+    compute-skipped block still costs its DMA under a naive index map,
+    while re-addressing the SAME block is not refetched. Off
+    (identity) with a prefix, which can make above-diagonal blocks
+    live, and with traced global offsets, where the diagonal's grid
+    position is unknown at trace time."""
+
+    def k_block(i, j):
+        if causal_clamp:
+            j = jnp.minimum(j, _last_visible_k_block(i, block_q, block_k))
+            if window:
+                j = jnp.maximum(
+                    j, _first_window_k_block(i, block_q, block_k, window)
+                )
+        return j
+
+    def q_block(j, i):
+        if causal_clamp:
+            i = jnp.maximum(
+                i, _first_visible_q_block(j, n_q_blocks, block_q, block_k)
+            )
+            if window:
+                i = jnp.minimum(
+                    i,
+                    _last_window_q_block(
+                        j, n_q_blocks, block_q, block_k, window
+                    ),
+                )
+        return i
+
+    return k_block, q_block
+
+
+def _slab_view(x):
+    """``[B, S, H, D]`` as the projection's own ``[B, S, H·D]`` (free)."""
+    b, s, h, d = x.shape
+    return x.reshape(b, s, h * d)
+
+
+def _slab_count(h, hkv, d, pack):
+    """128-lane slabs across ``H·D`` columns; the last may not be full."""
+    assert h == hkv and pack * d == LANES, (
+        "head packing needs MHA heads that fill a 128-lane slab"
+    )
+    return -(-h // pack)
+
+
+def _grid_params(interpret, n_grid):
+    """Every grid here carries its accumulator over the last dimension
+    and nothing over the others."""
+    if interpret:
+        return None
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",) * (n_grid - 1) + ("arbitrary",),
+    )
+
+
+def _slab_rows(x, b, h, n_slabs, pack, s):
+    """Per-row f32 statistics [B, H, S] → the [B·slabs, pack, S, 8]
+    tiles the packed kernels read column 0 of (heads past H: zeros)."""
+    x = jnp.pad(x, ((0, 0), (0, n_slabs * pack - h), (0, 0)))
+    return jnp.broadcast_to(
+        x.reshape(b * n_slabs, pack, s)[..., None],
+        (b * n_slabs, pack, s, 8),
+    )
 
 
 def _pallas_backward(q, k, v, out, lse, g, causal, scale,
@@ -626,21 +811,109 @@ def _pallas_backward(q, k, v, out, lse, g, causal, scale,
     cotangent) folds into the per-row delta — ∂lse/∂s_j = p_j, so it
     enters ds as an additive term and the kernels need no change.
 
-    ``head_pack`` > 1 runs the head-packed kernel variants (MHA only;
-    h must divide by the pack — the jnp wrapper pads heads first).
+    ``head_pack`` > 1 runs the head-packed kernels on 128-lane slabs of
+    the ``[B, S, H·D]`` view (MHA with ``head_pack · D == 128`` only).
     """
     interpret = INTERPRET if interpret is None else interpret
     b, sq, h, d = q.shape
     _, sk, hkv, _ = k.shape
     groups = h // hkv
     pack = max(int(head_pack), 1)
-    if pack > 1:
-        assert h == hkv and h % pack == 0, (
-            "head packing needs MHA with heads divisible by the pack"
-        )
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
     assert sq % block_q == 0 and sk % block_k == 0
+    nq, nk = sq // block_q, sk // block_k
+
+    # per-row softmax residual of the backward, [B, H, S]
+    delta = jnp.sum(
+        g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
+    ).transpose(0, 2, 1)
+    if g_lse is not None:
+        # total ds = p·(dp − delta + g_lse): subtract here once
+        delta = delta - g_lse.astype(jnp.float32)
+
+    common = dict(
+        causal=causal,
+        scale=scale,
+        block_q=block_q,
+        block_k=block_k,
+        has_prefix=prefix is not None,
+        has_offsets=offsets is not None,
+        window=window,
+    )
+    k_block, q_block = _clamped_block_maps(
+        causal and prefix is None and offsets is None,
+        block_q, block_k, nq, window,
+    )
+
+    if pack > 1:
+        # the kernels read q, k, v, dO and write dq, dk, dv as column
+        # slabs of the projections' own [B, S, H·D] arrays: the index
+        # map does what a transpose to [B, H, S, D] did. MHA only, so
+        # no GQA index sharing or group-sum.
+        n_slabs = _slab_count(h, hkv, d, pack)
+        operands = (
+            *(_slab_view(x) for x in (q, k, v, g.astype(q.dtype))),
+            _slab_rows(lse, b, h, n_slabs, pack, sq),
+            _slab_rows(delta, b, h, n_slabs, pack, sq),
+        )
+        common_p = dict(common, heads=h, pack=pack)
+
+        def spec(rows, block):  # block index (batch, slab, step i, step j)
+            return pl.BlockSpec(
+                (1, rows, LANES), lambda b_, s_, i, j: (b_, block(i, j), s_)
+            )
+
+        def row8_spec(block):
+            return pl.BlockSpec(
+                (1, pack, block_q, 8),
+                lambda b_, s_, i, j: (b_ * n_slabs + s_, 0, block(i, j), 0),
+            )
+
+        def slab_struct(s_len, dtype):
+            return _out_struct((b, s_len, h * d), dtype, q)
+
+        first = lambda i, j: i  # noqa: E731 — this grid step's own block
+        extra, extra_specs, kernel = _optional_smem(
+            functools.partial(_bwd_dq_kernel_packed, **common_p),
+            prefix, offsets, b, at=6,
+        )
+        dq = pl.pallas_call(
+            kernel,
+            grid=(b, n_slabs, nq, nk),
+            in_specs=[spec(block_q, first), spec(block_k, k_block),
+                      spec(block_k, k_block), spec(block_q, first),
+                      row8_spec(first), row8_spec(first), *extra_specs],
+            out_specs=spec(block_q, first),
+            out_shape=slab_struct(sq, q.dtype),
+            scratch_shapes=[pltpu.VMEM((block_q, LANES), jnp.float32)],
+            compiler_params=_grid_params(interpret, 4),
+            interpret=interpret,
+            name="flash_bwd_dq_packed",
+        )(*operands, *extra)
+
+        extra, extra_specs, kernel = _optional_smem(
+            functools.partial(_bwd_dkv_kernel_packed, **common_p),
+            prefix, offsets, b, at=6,
+        )
+        dk, dv = pl.pallas_call(
+            kernel,
+            grid=(b, n_slabs, nk, nq),
+            in_specs=[spec(block_q, q_block), spec(block_k, first),
+                      spec(block_k, first), spec(block_q, q_block),
+                      row8_spec(q_block), row8_spec(q_block),
+                      *extra_specs],
+            out_specs=[spec(block_k, first), spec(block_k, first)],
+            out_shape=[slab_struct(sk, k.dtype), slab_struct(sk, v.dtype)],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, LANES), jnp.float32),
+                pltpu.VMEM((block_k, LANES), jnp.float32),
+            ],
+            compiler_params=_grid_params(interpret, 4),
+            interpret=interpret,
+            name="flash_bwd_dkv_packed",
+        )(*operands, *extra)
+        return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
     dot = g.transpose(0, 2, 1, 3).reshape(b * h, sq, d).astype(q.dtype)
@@ -650,220 +923,62 @@ def _pallas_backward(q, k, v, out, lse, g, causal, scale,
     # VMEM alternative would trade for an 'arbitrary' grid dim.
     kt = k.transpose(0, 2, 1, 3).reshape(b * hkv, sk, d)
     vt = v.transpose(0, 2, 1, 3).reshape(b * hkv, sk, d)
-    # per-row softmax residuals, broadcast to the 8-lane tile the kernels
-    # read column 0 of
-    delta = jnp.sum(
-        g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
-    )  # [B, S, H]
-    delta_bh = delta.transpose(0, 2, 1).reshape(b * h, sq)
-    if g_lse is not None:
-        # total ds = p·(dp − delta + g_lse): subtract here once
-        delta_bh = delta_bh - g_lse.reshape(b * h, sq).astype(jnp.float32)
+    # the residuals broadcast to the 8-lane tile the kernels read
+    # column 0 of
     delta8 = jnp.broadcast_to(
-        delta_bh[..., None], (b * h, sq, 8)
+        delta.reshape(b * h, sq)[..., None], (b * h, sq, 8)
     )
     lse8 = jnp.broadcast_to(
         lse.reshape(b * h, sq)[..., None], (b * h, sq, 8)
     )
+    # grid-dim-0 entries per batch: the prefix SMEM row index is
+    # program_id(0) // n_head
+    common["n_head"] = h
 
-    has_prefix = prefix is not None
-    has_offsets = offsets is not None
-    extra = ()
-    extra_specs = []
-    smem_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
-    none_idxs = []
-    if has_prefix:
-        extra += (prefix.astype(jnp.int32).reshape(b, 1),)
-        extra_specs.append(smem_spec)
-    else:
-        none_idxs.append(6)
-    if has_offsets:
-        extra += (offsets.astype(jnp.int32).reshape(1, 2),)
-        extra_specs.append(smem_spec)
-    else:
-        none_idxs.append(7)
-    wrap = (
-        functools.partial(_insert_none_args, idxs=none_idxs)
-        if none_idxs
-        else (lambda kern: kern)
-    )
-
-    common = dict(
-        causal=causal,
-        scale=scale,
-        block_q=block_q,
-        block_k=block_k,
-        has_prefix=has_prefix,
-        has_offsets=has_offsets,
-        # grid-dim-0 entries per batch (the prefix SMEM row index is
-        # program_id(0) // n_head): h unpacked, h/pack packed
-        n_head=h // pack,
-        window=window,
-    )
-    # with traced global offsets the diagonal's grid position is unknown
-    # at trace time — the run gate still compute-skips, but the DMA index
-    # clamp below must not assume a block-local diagonal
-    causal_clamp = causal and prefix is None and not has_offsets
-
-    # dq grid (g, q-block i, k-block j): above-diagonal (and, windowed,
-    # below-window) k blocks are compute-skipped; clamp their index so
-    # pallas re-addresses (and skips refetching) the previous block
-    # instead of DMAing dead data
-    def k_idx(g_, i, j):
-        if causal_clamp:
-            j = jnp.minimum(
-                j, _last_visible_k_block(i, block_q, block_k)
-            )
-            if window:
-                j = jnp.maximum(
-                    j, _first_window_k_block(i, block_q, block_k, window)
-                )
-        return (g_ // groups, j, 0)
-
+    # dq grid (g, q-block i, k-block j)
     q_spec = pl.BlockSpec((1, block_q, d), lambda g_, i, j: (g_, i, 0))
     row8_spec = pl.BlockSpec((1, block_q, 8), lambda g_, i, j: (g_, i, 0))
+
+    def k_idx(g_, i, j):
+        j = k_block(i, j)
+        return (g_ // groups, j, 0)
+
     k_spec = pl.BlockSpec((1, block_k, d), k_idx)
-    compiler_params = (
-        None
-        if interpret
-        else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        )
+    extra, extra_specs, kernel = _optional_smem(
+        functools.partial(_bwd_dq_kernel, **common), prefix, offsets, b,
+        at=6,
     )
-
-    if pack > 1:
-        # head-packed variants: same grids with dim 0 shrunk pack×, all
-        # q/k/v/do/lse/delta blocks carrying a leading pack axis. groups
-        # == 1 here (MHA only), so no GQA index sharing or group-sum.
-        gp = b * h // pack
-        qt4 = qt.reshape(gp, pack, sq, d)
-        kt4 = kt.reshape(gp, pack, sk, d)
-        vt4 = vt.reshape(gp, pack, sk, d)
-        dot4 = dot.reshape(gp, pack, sq, d)
-        delta84 = delta8.reshape(gp, pack, sq, 8)
-        lse84 = lse8.reshape(gp, pack, sq, 8)
-        common_p = dict(common, pack=pack)
-
-        def k_idx4(g_, i, j):
-            if causal_clamp:
-                j = jnp.minimum(
-                    j, _last_visible_k_block(i, block_q, block_k)
-                )
-                if window:
-                    j = jnp.maximum(
-                        j,
-                        _first_window_k_block(i, block_q, block_k, window),
-                    )
-            return (g_, 0, j, 0)
-
-        q_spec4 = pl.BlockSpec(
-            (1, pack, block_q, d), lambda g_, i, j: (g_, 0, i, 0)
-        )
-        row8_spec4 = pl.BlockSpec(
-            (1, pack, block_q, 8), lambda g_, i, j: (g_, 0, i, 0)
-        )
-        k_spec4 = pl.BlockSpec((1, pack, block_k, d), k_idx4)
-        dq = pl.pallas_call(
-            wrap(functools.partial(_bwd_dq_kernel_packed, **common_p)),
-            grid=(gp, sq // block_q, sk // block_k),
-            in_specs=[q_spec4, k_spec4, k_spec4, q_spec4, row8_spec4,
-                      row8_spec4, *extra_specs],
-            out_specs=q_spec4,
-            out_shape=_out_struct((gp, pack, sq, d), q.dtype, q),
-            scratch_shapes=[
-                pltpu.VMEM((pack, block_q, d), jnp.float32)
-            ],
-            compiler_params=compiler_params,
-            interpret=interpret,
-            name="flash_bwd_dq_packed",
-        )(qt4, kt4, vt4, dot4, lse84, delta84, *extra)
-
-        nq4 = sq // block_q
-
-        def q_idx4(g_, j, i):
-            if causal_clamp:
-                i = jnp.maximum(
-                    i, _first_visible_q_block(j, nq4, block_q, block_k)
-                )
-                if window:
-                    i = jnp.minimum(
-                        i,
-                        _last_window_q_block(
-                            j, nq4, block_q, block_k, window
-                        ),
-                    )
-            return (g_, 0, i, 0)
-
-        qkv_spec4 = pl.BlockSpec((1, pack, block_q, d), q_idx4)
-        row8_spec42 = pl.BlockSpec((1, pack, block_q, 8), q_idx4)
-        kv_spec4 = pl.BlockSpec(
-            (1, pack, block_k, d), lambda g_, j, i: (g_, 0, j, 0)
-        )
-        dk, dv = pl.pallas_call(
-            wrap(functools.partial(_bwd_dkv_kernel_packed, **common_p)),
-            grid=(gp, sk // block_k, sq // block_q),
-            in_specs=[qkv_spec4, kv_spec4, kv_spec4, qkv_spec4,
-                      row8_spec42, row8_spec42, *extra_specs],
-            out_specs=[kv_spec4, kv_spec4],
-            out_shape=[
-                _out_struct((gp, pack, sk, d), k.dtype, q),
-                _out_struct((gp, pack, sk, d), v.dtype, q),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((pack, block_k, d), jnp.float32),
-                pltpu.VMEM((pack, block_k, d), jnp.float32),
-            ],
-            compiler_params=compiler_params,
-            interpret=interpret,
-            name="flash_bwd_dkv_packed",
-        )(qt4, kt4, vt4, dot4, lse84, delta84, *extra)
-        dq = dq.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
-        dk = dk.reshape(b, h, sk, d).transpose(0, 2, 1, 3)
-        dv = dv.reshape(b, h, sk, d).transpose(0, 2, 1, 3)
-        return (
-            dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
-        )
-
     dq = pl.pallas_call(
-        wrap(functools.partial(_bwd_dq_kernel, **common)),
-        grid=(b * h, sq // block_q, sk // block_k),
+        kernel,
+        grid=(b * h, nq, nk),
         in_specs=[q_spec, k_spec, k_spec, q_spec, row8_spec, row8_spec,
                   *extra_specs],
         out_specs=q_spec,
         out_shape=_out_struct((b * h, sq, d), q.dtype, q),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=compiler_params,
+        compiler_params=_grid_params(interpret, 3),
         interpret=interpret,
         name="flash_bwd_dq",
     )(qt, kt, vt, dot, lse8, delta8, *extra)
 
-    # dkv grid swaps the roles: k-blocks outer, q-blocks inner; q blocks
-    # entirely above the diagonal contribute nothing — clamp their index
-    nq = sq // block_q
-
-    def q_idx(g_, j, i):
-        if causal_clamp:
-            i = jnp.maximum(
-                i, _first_visible_q_block(j, nq, block_q, block_k)
-            )
-            if window:
-                i = jnp.minimum(
-                    i,
-                    _last_window_q_block(
-                        j, nq, block_q, block_k, window
-                    ),
-                )
-        return (g_, i, 0)
-
-    qkv_spec = pl.BlockSpec((1, block_q, d), q_idx)
-    row8_spec2 = pl.BlockSpec((1, block_q, 8), q_idx)
+    # dkv grid swaps the roles: k-blocks outer, q-blocks inner
+    qkv_spec = pl.BlockSpec(
+        (1, block_q, d), lambda g_, j, i: (g_, q_block(j, i), 0)
+    )
+    row8_spec2 = pl.BlockSpec(
+        (1, block_q, 8), lambda g_, j, i: (g_, q_block(j, i), 0)
+    )
     kv_in_spec = pl.BlockSpec(
         (1, block_k, d), lambda g_, j, i: (g_ // groups, j, 0)
     )
     kv_spec = pl.BlockSpec((1, block_k, d), lambda g_, j, i: (g_, j, 0))
+    extra, extra_specs, kernel = _optional_smem(
+        functools.partial(_bwd_dkv_kernel, **common), prefix, offsets, b,
+        at=6,
+    )
     dk, dv = pl.pallas_call(
-        wrap(functools.partial(_bwd_dkv_kernel, **common)),
-        grid=(b * h, sk // block_k, sq // block_q),
+        kernel,
+        grid=(b * h, nk, nq),
         in_specs=[qkv_spec, kv_in_spec, kv_in_spec, qkv_spec, row8_spec2,
                   row8_spec2, *extra_specs],
         out_specs=[kv_spec, kv_spec],
@@ -875,7 +990,7 @@ def _pallas_backward(q, k, v, out, lse, g, causal, scale,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=compiler_params,
+        compiler_params=_grid_params(interpret, 3),
         interpret=interpret,
         name="flash_bwd_dkv",
     )(qt, kt, vt, dot, lse8, delta8, *extra)
@@ -902,129 +1017,93 @@ def _flash_fwd(
     prefix: Optional[jax.Array] = None,  # [B] int32 prefix-LM lengths
     window: int = 0,  # sliding window (causal only; 0 = unlimited)
     offsets: Optional[jax.Array] = None,  # [2] int32 global (q_off, k_off)
-    head_pack: int = 1,  # heads per grid program (MHA only; h % pack == 0)
-) -> jax.Array:
+    head_pack: int = 1,  # heads per 128-lane slab (MHA, pack · D == 128)
+):
+    """(out [B, S, H, D], lse [B, H, S] f32)."""
     interpret = INTERPRET if interpret is None else interpret
     b, sq, h, d = q.shape
     _, sk, hkv, _ = k.shape
     assert h % hkv == 0
     groups = h // hkv
     pack = max(int(head_pack), 1)
-    if pack > 1:
-        assert h == hkv and h % pack == 0, (
-            "head packing needs MHA with heads divisible by the pack"
-        )
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
     assert sq % block_q == 0 and sk % block_k == 0, (
         "sequence must be padded to the block size"
     )
-
-    # layout: [B, H, S, D] so the matmul dims are the minor two. K/V stay
-    # at hkv heads — GQA sharing happens in the BlockSpec index_map
-    # (g // groups), never as a materialized jnp.repeat in HBM.
-    # Packed: [B·H/pack, pack, S, D] — pack heads ride one grid program.
-    if pack > 1:
-        qt = q.transpose(0, 2, 1, 3).reshape(b * h // pack, pack, sq, d)
-        kt = k.transpose(0, 2, 1, 3).reshape(b * h // pack, pack, sk, d)
-        vt = v.transpose(0, 2, 1, 3).reshape(b * h // pack, pack, sk, d)
-        grid = (b * h // pack, sq // block_q, sk // block_k)
-    else:
-        qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-        kt = k.transpose(0, 2, 1, 3).reshape(b * hkv, sk, d)
-        vt = v.transpose(0, 2, 1, 3).reshape(b * hkv, sk, d)
-        grid = (b * h, sq // block_q, sk // block_k)
-    kernel = functools.partial(
-        _fwd_kernel_packed if pack > 1 else _fwd_kernel,
+    nq, nk = sq // block_q, sk // block_k
+    common = dict(
         causal=causal,
         scale=scale,
         block_q=block_q,
         block_k=block_k,
         has_prefix=prefix is not None,
         has_offsets=offsets is not None,
-        n_head=h // pack,
         window=window,
-        **({"pack": pack} if pack > 1 else {}),
     )
-    inputs = (qt, kt, vt)
-    prefix_specs = []
-    none_idxs = []
-    smem_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
-    if prefix is not None:
-        # the whole [B,1] scalar table lives in SMEM; the kernel indexes
-        # its batch row from grid dim 0 (Mosaic rejects sub-8 sublane
-        # blocking, so no per-step BlockSpec windowing here)
-        inputs += (prefix.astype(jnp.int32).reshape(b, 1),)
-        prefix_specs.append(smem_spec)
-    else:
-        none_idxs.append(3)
-    if offsets is not None:
-        inputs += (offsets.astype(jnp.int32).reshape(1, 2),)
-        prefix_specs.append(smem_spec)
-    else:
-        none_idxs.append(4)
-    kernel_fn = (
-        _insert_none_args(kernel, none_idxs) if none_idxs else kernel
+    k_block, _ = _clamped_block_maps(
+        causal and prefix is None and offsets is None,
+        block_q, block_k, nq, window,
     )
-    if causal and prefix is None and offsets is None:
-        # above-diagonal (and, with a sliding window, below-window)
-        # blocks are compute-skipped by the run gate, but a naive index
-        # map still DMAs them; clamping j re-addresses the SAME block,
-        # which pallas does not refetch — saves the dead K/V traffic.
-        # (A prefix can make above-diagonal blocks live, so no clamp.)
-        def _kv_j(i, j):
-            j = jnp.minimum(j, _last_visible_k_block(i, block_q, block_k))
-            if window:
-                j = jnp.maximum(
-                    j, _first_window_k_block(i, block_q, block_k, window)
-                )
-            return j
-    else:
-        def _kv_j(i, j):
-            return j
 
     if pack > 1:
-        in_specs = [
-            pl.BlockSpec(
-                (1, pack, block_q, d), lambda g, i, j: (g, 0, i, 0)
-            ),
-            pl.BlockSpec(
-                (1, pack, block_k, d),
-                lambda g, i, j: (g, 0, _kv_j(i, j), 0),
-            ),
-            pl.BlockSpec(
-                (1, pack, block_k, d),
-                lambda g, i, j: (g, 0, _kv_j(i, j), 0),
-            ),
-        ]
+        # [B, S, H, D] is a free view of the projection's [B, S, H·D]:
+        # grid program (batch, slab, i, j) takes the 128 columns of slab
+        # — ``pack`` heads side by side — as they lie, and writes the
+        # output the same way; nothing is transposed, padded or sliced
+        # in memory. With H % pack != 0 the last slab is half outside
+        # the array: read unspecified (the kernel zeroes those lanes),
+        # written nowhere.
+        n_slabs = _slab_count(h, hkv, d, pack)
+        kernel = functools.partial(
+            _fwd_kernel_packed, **common, heads=h, pack=pack
+        )
+        inputs = tuple(_slab_view(x) for x in (q, k, v))
+        grid = (b, n_slabs, nq, nk)
+        q_spec = pl.BlockSpec(
+            (1, block_q, LANES), lambda b_, s_, i, j: (b_, i, s_)
+        )
+        kv_spec = pl.BlockSpec(
+            (1, block_k, LANES),
+            lambda b_, s_, i, j: (b_, k_block(i, j), s_),
+        )
+        in_specs = [q_spec, kv_spec, kv_spec]
         out_specs = [
+            q_spec,
             pl.BlockSpec(
-                (1, pack, block_q, d), lambda g, i, j: (g, 0, i, 0)
-            ),
-            pl.BlockSpec(
-                (1, pack, block_q, 8), lambda g, i, j: (g, 0, i, 0)
+                (1, pack, block_q, 8),
+                lambda b_, s_, i, j: (b_ * n_slabs + s_, 0, i, 0),
             ),
         ]
         out_shape = [
-            _out_struct((b * h // pack, pack, sq, d), q.dtype, q),
-            _out_struct((b * h // pack, pack, sq, 8), jnp.float32, q),
+            _out_struct((b, sq, h * d), q.dtype, q),
+            _out_struct((b * n_slabs, pack, sq, 8), jnp.float32, q),
         ]
         scratch_shapes = [
             pltpu.VMEM((pack, block_q, 128), jnp.float32),
             pltpu.VMEM((pack, block_q, 128), jnp.float32),
-            pltpu.VMEM((pack, block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, LANES), jnp.float32),
         ]
     else:
+        # layout: [B·H, S, D] so the matmul dims are the minor two. K/V
+        # stay at hkv heads — GQA sharing happens in the BlockSpec
+        # index_map (g // groups), never as a materialized jnp.repeat
+        # in HBM.
+        kernel = functools.partial(_fwd_kernel, **common, n_head=h)
+        inputs = (
+            q.transpose(0, 2, 1, 3).reshape(b * h, sq, d),
+            k.transpose(0, 2, 1, 3).reshape(b * hkv, sk, d),
+            v.transpose(0, 2, 1, 3).reshape(b * hkv, sk, d),
+        )
+        grid = (b * h, nq, nk)
+        kv_spec = pl.BlockSpec(
+            (1, block_k, d),
+            lambda g, i, j: (g // groups, k_block(i, j), 0),
+        )
         in_specs = [
             pl.BlockSpec((1, block_q, d), lambda g, i, j: (g, i, 0)),
-            pl.BlockSpec(
-                (1, block_k, d),
-                lambda g, i, j: (g // groups, _kv_j(i, j), 0),
-            ),
-            pl.BlockSpec(
-                (1, block_k, d),
-                lambda g, i, j: (g // groups, _kv_j(i, j), 0),
-            ),
+            kv_spec,
+            kv_spec,
         ]
         out_specs = [
             pl.BlockSpec((1, block_q, d), lambda g, i, j: (g, i, 0)),
@@ -1040,28 +1119,27 @@ def _flash_fwd(
             pltpu.VMEM((block_q, d), jnp.float32),
         ]
 
+    extra, extra_specs, kernel = _optional_smem(
+        kernel, prefix, offsets, b, at=3
+    )
     out, lse = pl.pallas_call(
-        kernel_fn,
+        kernel,
         grid=grid,
-        in_specs=[*in_specs, *prefix_specs],
+        in_specs=[*in_specs, *extra_specs],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch_shapes,
-        compiler_params=None
-        if interpret
-        else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=_grid_params(interpret, len(grid)),
         interpret=interpret,
         name="flash_fwd_packed" if pack > 1 else "flash_fwd",
-    )(*inputs)
-    out = out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
-    lse = (
-        lse[..., 0].reshape(b, h, sq)
-        if pack > 1
-        else lse[:, :, 0].reshape(b, h, sq)
-    )  # [B, H, S]
-    return out, lse
+    )(*inputs, *extra)
+    if pack > 1:
+        out = out.reshape(b, sq, h, d)
+        lse = lse[..., 0].reshape(b, n_slabs * pack, sq)[:, :h]
+    else:
+        out = out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
+        lse = lse[:, :, 0].reshape(b, h, sq)
+    return out, lse  # lse [B, H, S]
 
 
 def _bwd_chunk(sk: int, block_k: int) -> int:
@@ -1303,7 +1381,7 @@ def flash_attention(
     block_k: int = DEFAULT_BLOCK_K,
     prefix_len: Optional[jax.Array] = None,  # [B] int32: prefix-LM
     window: int = 0,  # sliding window (causal only; 0 = unlimited)
-    head_pack: int = 0,  # heads per kernel program (0 = auto)
+    head_pack: int = 0,  # 0 = auto (128 // D heads a slab), 1 = unpacked
 ) -> jax.Array:
     """Flash attention; falls back to the jnp path off-TPU.
 
@@ -1312,13 +1390,12 @@ def flash_attention(
     visible to every query — GLM-style bidirectional-prefix attention.
     ``window`` (causal only) limits each query to the last ``window``
     positions — Mistral-style sliding-window attention.
-    ``head_pack`` packs that many narrow heads into one kernel program
-    (module docstring, "narrow-head packing"): 0 picks 128 // D when
-    D < 128 divides the lane width and the layout is MHA, 1 disables.
-    Head counts that don't divide the pack are zero-padded (a zero
-    q/k/v head yields zero out and zero grads, so the slice is exact);
-    GQA always runs unpacked — packing would replicate kv DMA per
-    group and the kernels keep the simple grid//groups indexing.
+    ``head_pack`` (module docstring, "narrow-head packing"): 0 runs
+    heads with D < 128 dividing 128, in an MHA layout, on 128-lane slabs
+    of ``128 // D`` heads; 1 keeps them on the unpacked kernels. A slab
+    is the lane width, so the pack is never another number. GQA always
+    runs unpacked — packing would replicate kv DMA per group and the
+    kernels keep the simple grid//groups indexing.
     """
     scale = softmax_scale if softmax_scale is not None else q.shape[-1] ** -0.5
     sq, sk = q.shape[1], k.shape[1]
@@ -1346,20 +1423,12 @@ def flash_attention(
             q, k, v, causal=causal, softmax_scale=scale,
             prefix_len=prefix_len, window=window,
         )
-    if head_pack == 0:
-        pack = 128 // d if (d < 128 and 128 % d == 0 and h == hkv) else 1
-    else:
-        pack = head_pack
-        if h != hkv or d * pack > 128 or 128 % d != 0:
-            pack = 1  # demote: GQA or pack overflows the lane width
-    if pack > 1 and h % pack:
-        pad = -h % pack
-        zpad = [(0, 0), (0, 0), (0, pad), (0, 0)]
-        out = _flash_attention(
-            jnp.pad(q, zpad), jnp.pad(k, zpad), jnp.pad(v, zpad),
-            prefix_len, None, causal, scale, bq, bk, window, pack,
-        )
-        return out[:, :, :h]
+    # a slab is 128 lanes of the projection's array, so the pack is
+    # never a choice: 128 // D heads, or the unpacked kernels
+    pack = 1
+    if head_pack != 1 and d < LANES and LANES % d == 0 and h == hkv:
+        pack = LANES // d
+    set_counter("attn.heads_per_slab", pack)
     return _flash_attention(
         q, k, v, prefix_len, None, causal, scale, bq, bk, window, pack
     )

@@ -659,110 +659,151 @@ def test_factored_adamw_trains_tiny_model():
 
 
 # -- narrow-head packing (pallas_attention head_pack) -----------------------
+# The packed kernels read 128-lane slabs of the projections' [B, S, H·D]
+# arrays: 128 // D heads side by side. Interpret mode pads what a block
+# reads beyond an array's bounds with NaN, so a case whose last slab is
+# not full (25 x 64, 5 x 32) also shows that nothing out there reaches a
+# result.
+
+SLAB_CASES = {
+    # GPT-2 XL's head count: 12½ slabs, the half slab in the kernel
+    "25x64-causal": dict(h=25, d=64),
+    "26x64-causal": dict(h=26, d=64),
+    "4x32-causal": dict(h=4, d=32),  # four heads a slab
+    "5x32-causal": dict(h=5, d=32),  # ... and one head in the last
+    "4x64-dense": dict(h=4, d=64, causal=False),
+    "4x32-dense": dict(h=4, d=32, causal=False),
+    "25x64-prefix": dict(h=25, d=64, prefix=(17, 100)),
+    "5x64-window": dict(h=5, d=64, window=48),
+    "1x64-causal": dict(h=1, d=64),  # the array narrower than a slab
+    "5x64-two-blocks": dict(h=5, d=64, s=256),  # the carried statistics
+}
 
 
-@pytest.mark.parametrize("causal", [True, False])
-@pytest.mark.parametrize("d,pack", [(64, 2), (32, 4)])
-def test_flash_fwd_packed_matches_unpacked(causal, d, pack):
-    """Packed forward is the SAME online-softmax math per head, so it
-    must be bitwise-identical to the unpacked kernel (and close to the
-    reference)."""
+@pytest.mark.parametrize("case", sorted(SLAB_CASES))
+def test_flash_slab_kernels_match_reference(monkeypatch, case):
+    """Forward and all three gradients of the packed path against
+    ``mha_reference``, through the public entry (head_pack=0: auto)."""
+    from dlrover_tpu.observability import tracing
     from dlrover_tpu.ops import pallas_attention as pa
 
-    q, k, v = _qkv(jax.random.key(20), s=256, h=pack, d=d)
-    scale = d ** -0.5
-    out_p, lse_p = pa._flash_fwd(
-        q, k, v, causal, scale, block_q=128, block_k=128,
-        interpret=True, head_pack=pack,
-    )
-    out_u, lse_u = pa._flash_fwd(
-        q, k, v, causal, scale, block_q=128, block_k=128, interpret=True
-    )
-    np.testing.assert_array_equal(np.asarray(out_p), np.asarray(out_u))
-    np.testing.assert_array_equal(np.asarray(lse_p), np.asarray(lse_u))
-    ref = mha_reference(q, k, v, causal=causal, softmax_scale=scale)
-    np.testing.assert_allclose(
-        np.asarray(out_p), np.asarray(ref), rtol=2e-3, atol=2e-3
-    )
-
-
-def test_flash_fwd_packed_prefix():
-    """Prefix-LM masking under packing: the SMEM prefix ref is indexed
-    by grid entry (h // pack per batch), a different stride than the
-    unpacked kernel's."""
-    from dlrover_tpu.ops import pallas_attention as pa
-
-    q, k, v = _qkv(jax.random.key(21), s=256, h=4, d=64)
-    scale = 64 ** -0.5
-    pref = jnp.array([17, 100], jnp.int32)
-    out_p, _ = pa._flash_fwd(
-        q, k, v, True, scale, block_q=128, block_k=128, prefix=pref,
-        interpret=True, head_pack=2,
-    )
-    ref = mha_reference(
-        q, k, v, causal=True, softmax_scale=scale, prefix_len=pref
-    )
-    np.testing.assert_allclose(
-        np.asarray(out_p), np.asarray(ref), rtol=2e-3, atol=2e-3
-    )
-
-
-@pytest.mark.parametrize("causal", [True, False])
-@pytest.mark.parametrize("d,pack", [(64, 2), (32, 4)])
-def test_pallas_backward_packed_matches_reference(causal, d, pack):
-    from dlrover_tpu.ops import pallas_attention as pa
-
-    q, k, v = _qkv(jax.random.key(22), s=256, h=pack, d=d)
-    scale = d ** -0.5
-    out, lse = pa._flash_fwd(
-        q, k, v, causal, scale, block_q=128, block_k=128, interpret=True
-    )
-    g = jax.random.normal(jax.random.key(23), out.shape)
-    dq, dk, dv = pa._pallas_backward(
-        q, k, v, out, lse, g, causal, scale, 128, 128, interpret=True,
-        head_pack=pack,
-    )
-    # bitwise vs the unpacked kernel: same math, different grid layout
-    uq, uk, uv = pa._pallas_backward(
-        q, k, v, out, lse, g, causal, scale, 128, 128, interpret=True
-    )
-    for a, u in zip((dq, dk, dv), (uq, uk, uv)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(u))
-    ref = lambda q, k, v: jnp.vdot(  # noqa: E731
-        mha_reference(q, k, v, causal=causal, softmax_scale=scale), g
-    )
-    rq, rk, rv = jax.grad(ref, argnums=(0, 1, 2))(q, k, v)
-    for a, r in zip((dq, dk, dv), (rq, rk, rv)):
-        np.testing.assert_allclose(
-            np.asarray(a), np.asarray(r), rtol=2e-3, atol=2e-3
-        )
-
-
-@pytest.mark.parametrize("h", [5, 4])
-def test_flash_attention_autopack_end_to_end(monkeypatch, h):
-    """Public flash_attention with head_pack=0 (auto) at d=64: packs 2
-    heads per program, zero-padding the odd h=5 (gpt2-1.5b has 25);
-    fwd AND grads must match the reference, including the pad slice."""
-    from dlrover_tpu.ops import pallas_attention as pa
-
+    spec = dict(SLAB_CASES[case])
+    h, d, s_len = spec.pop("h"), spec.pop("d"), spec.pop("s", 128)
+    causal = spec.pop("causal", True)
+    prefix = spec.pop("prefix", None)
+    kw = dict(spec)
+    if prefix is not None:
+        kw["prefix_len"] = jnp.array(prefix, jnp.int32)
     monkeypatch.setattr(pa, "INTERPRET", True)
-    q, k, v = _qkv(jax.random.key(24), s=128, h=h, d=64)
-    scale = 64 ** -0.5
+    q, k, v = _qkv(jax.random.key(20), s=s_len, h=h, d=d)
+    scale = d ** -0.5
     g = jax.random.normal(jax.random.key(25), q.shape)
     f = lambda q, k, v: jnp.vdot(  # noqa: E731
-        pa.flash_attention(q, k, v, causal=True, block_q=128,
-                           block_k=128), g
+        pa.flash_attention(q, k, v, causal=causal, block_q=128,
+                           block_k=128, **kw), g
     )
     fr = lambda q, k, v: jnp.vdot(  # noqa: E731
-        mha_reference(q, k, v, causal=True, softmax_scale=scale), g
+        mha_reference(q, k, v, causal=causal, softmax_scale=scale, **kw),
+        g,
     )
     (lo, go) = jax.value_and_grad(f, argnums=(0, 1, 2))(q, k, v)
+    assert tracing.counters()["attn.heads_per_slab"] == 128 // d
     (lr, gr) = jax.value_and_grad(fr, argnums=(0, 1, 2))(q, k, v)
     np.testing.assert_allclose(float(lo), float(lr), rtol=2e-3)
     for a, r in zip(go, gr):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(r), rtol=2e-3, atol=2e-3
         )
+
+
+def test_flash_half_slab_equals_a_zero_head(monkeypatch):
+    """25 heads are the first 25 of a 26-head call whose last head is
+    zero, bit for bit, forward and gradients: the half slab's unreal
+    head is made zero inside the kernel, whatever lies beyond column
+    H·D (NaN here: the interpreter's padding)."""
+    from dlrover_tpu.ops import pallas_attention as pa
+
+    monkeypatch.setattr(pa, "INTERPRET", True)
+    q, k, v = _qkv(jax.random.key(26), b=1, s=128, h=25, d=64)
+    g = jax.random.normal(jax.random.key(27), q.shape)
+    zpad = [(0, 0), (0, 0), (0, 1), (0, 0)]
+
+    def run(q, k, v, g):
+        f = lambda q, k, v: jnp.vdot(  # noqa: E731
+            pa.flash_attention(q, k, v, causal=True, block_q=128,
+                               block_k=128).astype(jnp.float32), g
+        )
+        out = pa.flash_attention(q, k, v, causal=True, block_q=128,
+                                 block_k=128)
+        return (out, *jax.grad(f, argnums=(0, 1, 2))(q, k, v))
+
+    odd = run(q, k, v, g)
+    even = run(*(jnp.pad(x, zpad) for x in (q, k, v, g)))
+    for a, e in zip(odd, even):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_array_equal(
+            np.asarray(a), np.asarray(e[:, :, :25])
+        )
+        assert not np.asarray(e[:, :, 25:]).any()
+
+
+@pytest.mark.parametrize("h", [5, 4])
+def test_flash_packed_lse_contract(h):
+    """``flash_attention_with_lse`` keeps its [B, H, S] lse (ring and
+    Ulysses attention merge on it) on the slab path, equal to the
+    unpacked kernel's to rounding, and its cotangent reaches q, k, v."""
+    from dlrover_tpu.ops import pallas_attention as pa
+
+    q, k, v = _qkv(jax.random.key(28), s=256, h=h, d=64)
+    scale = 64 ** -0.5
+
+    def run(pack):
+        return pa._flash_fwd(
+            q, k, v, True, scale, 128, 128, interpret=True, head_pack=pack
+        )
+
+    (out_p, lse_p), (out_u, lse_u) = run(2), run(1)
+    assert lse_p.shape == (2, h, 256) and lse_p.dtype == jnp.float32
+    np.testing.assert_allclose(
+        np.asarray(lse_p), np.asarray(lse_u), rtol=1e-5, atol=1e-5
+    )
+    np.testing.assert_allclose(
+        np.asarray(out_p), np.asarray(out_u), rtol=1e-5, atol=1e-5
+    )
+    g = jax.random.normal(jax.random.key(29), out_p.shape)
+    g_lse = jax.random.normal(jax.random.key(30), lse_p.shape)
+    grads = [
+        pa._pallas_backward(
+            q, k, v, out_u, lse_u, g, True, scale, 128, 128,
+            interpret=True, g_lse=g_lse, head_pack=pack,
+        )
+        for pack in (2, 1)
+    ]
+    for a, u in zip(*grads):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(u), rtol=2e-4, atol=2e-4
+        )
+
+
+def test_flash_head_pack_one_runs_unpacked(monkeypatch):
+    """``head_pack=1`` (``attn_head_pack`` 1) keeps narrow heads on the
+    unpacked kernels; the counter says which path a trace took."""
+    from dlrover_tpu.observability import tracing
+    from dlrover_tpu.ops import pallas_attention as pa
+
+    monkeypatch.setattr(pa, "INTERPRET", True)
+    q, k, v = _qkv(jax.random.key(31), s=128, h=3, d=64)
+    outs = {}
+    for head_pack in (1, 0):
+        outs[head_pack] = pa.flash_attention(
+            q, k, v, causal=True, block_q=128, block_k=128,
+            head_pack=head_pack,
+        )
+        want = 1 if head_pack == 1 else 2
+        assert tracing.counters()["attn.heads_per_slab"] == want
+    np.testing.assert_allclose(
+        np.asarray(outs[0]), np.asarray(outs[1]), rtol=1e-5, atol=1e-5
+    )
 
 
 def test_flash_attention_gqa_demotes_head_pack(monkeypatch):

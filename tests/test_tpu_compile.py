@@ -156,6 +156,42 @@ def test_kernel_compiles_for_v5e(chip, case):
     assert compiled.as_text().count("tpu_custom_call") == n_kernels
 
 
+@pytest.mark.parametrize(
+    "case,kernels",
+    [
+        ("flash-bwd-25x64-packed",
+         {"flash_fwd_packed", "flash_bwd_dq_packed", "flash_bwd_dkv_packed"}),
+        ("flash-bwd-16x128", {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
+    ],
+)
+def test_flash_layout_around_the_kernels(chip, case, kernels):
+    """Head size 64 runs the three packed kernels on slabs of the
+    projections' own ``[8, 1024, 1600]`` arrays: no array with the heads
+    padded to 26 and moved in front of the sequence (``[8,26,1024,64]``,
+    ``[104,2,1024,64]``) exists around them — before PR 32 seven relayout
+    passes a tensor did, 80-95 ms of GPT-2 XL's step. Head size 128 keeps
+    the unpacked kernels."""
+    import re
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    fn, args = CASES[case][0](struct)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    named = set()
+    for line in text.splitlines():
+        if "tpu_custom_call" in line:
+            m = re.match(
+                r"\s*(?:ROOT )?%\w*?(flash_(?:fwd|bwd_dq|bwd_dkv)"
+                r"(?:_packed)?)[_.\d]* = ", line
+            )
+            assert m, line[:160]
+            named.add(m.group(1))
+    assert named == kernels
+    for shape in ("[8,26,1024,64]", "[104,2,1024,64]"):
+        assert shape not in text
+
+
 # ---- the whole train step: kernel names and phase scopes ------------------
 # What a device trace shows for an operation is its HLO instruction's
 # name, and what the program's reducer (observability/runtime_timer.py)
@@ -310,6 +346,12 @@ def test_step_names_its_kernels_and_phases(topo, case):
             (plan.n_buckets + plan.n_tie_buckets) * plan.bucket_elems * 4
         )
     assert wanted <= phases, wanted - phases
+    # which attention kernels a trace took, and that the packed ones
+    # keep the projections' layout in the whole step too
+    packed = "flash_fwd_packed" in spec["kernels"]
+    assert counters["attn.heads_per_slab"] == (2 if packed else 1)
+    if packed:
+        assert "[8,26,1024,64]" not in text and "[104,2,1024,64]" not in text
     if spec["model"] == "olmoe-1b-7b":
         # the routed layer's counters, and its grouped matmuls under
         # their scope (by the kernel's name: it has no name stack)
