@@ -31,9 +31,16 @@ against the free-running reference (its own top-k), at
 ``FREE_LOSS_TOL``.
 
 From the program this takes ``decoder.forward(..., return_aux=True)``:
-the logits, and ``aux["moe_choices"]``, int32 ``[n_layer, B, S, k]``,
-the expert ids each token was sent to, one row per layer. The ids and
-nothing else reach the reference.
+the logits, and ``aux["moe_choices"]``, int32 ``[L, B, S, k]``, the
+expert ids each token was sent to: one row per ROUTED layer in trunk
+order (a dense layer has none; an extra prediction module's routed
+layer comes last). The ids and nothing else reach the reference.
+
+The objective's other terms are the reference's to name. Every scalar
+the teacher-forced reference returns beside ``router_logits`` (a router
+loss, an extra prediction module's cross-entropy) is a term the
+program's step metrics MUST report under the same name: a term the
+program does not report fails the run, it is not skipped.
 """
 
 import numpy as np
@@ -62,17 +69,28 @@ from benchmarks.lib.device import Refused
 # the limit and still fails a wrong expert, whose regret is a few gaps.
 REGRET_TOL = 0.15
 
-# the program's step metrics that a routed reference computes too, each
-# held to ROUTER_LOSS_TOL relative when both sides carry it. They come
-# from the program's bf16 router logits: over the same 21 seeds
-# moe_lb_loss read 1.2e-5..1.6e-4 and moe_z_loss 2.5e-6..5.7e-4, ten
-# times the cross-entropy's error, and 8-bit router logits read no
-# worse (2.7e-5..2.3e-4): this is no check of precision. It is there
-# for a term that is dropped, scaled or shared out wrongly (a
-# coefficient 1% off reads 1e-2; a share over tokens for one over
+# A term of the objective that the routed reference computes too (every
+# scalar it returns beside ``router_logits``, under the name of the
+# program's step metric) is held to ROUTER_LOSS_TOL relative, unless
+# the reference module lists it in ``CROSS_ENTROPY_TERMS``: a
+# teacher-forced cross-entropy, such as an extra prediction module's,
+# averages its rounding over the tokens as the main loss does and is
+# held to the dense LOSS_TOL, ten times tighter. PROVISIONAL: no program
+# has reported such a term yet, so LOSS_TOL for it stands on the main
+# loss's readings and on none of its own. The PR that brings the first
+# configuration whose reference lists a name there brings, on the chip,
+# the term's error over a dozen seeds and the smallest that a control
+# (the module's targets shifted by one more place, its weight 1% off)
+# reads, and PERF.md section 7 says so; where LOSS_TOL does not lie
+# between the two with room, that is a ``benchmark`` issue before the
+# cell. The router losses come from the program's bf16 router logits:
+# over the same 21 seeds moe_lb_loss read 1.2e-5..1.6e-4 and moe_z_loss
+# 2.5e-6..5.7e-4, ten times the cross-entropy's error, and 8-bit router
+# logits read no worse (2.7e-5..2.3e-4): this is no check of precision.
+# It is there for a term that is dropped, scaled or shared out wrongly
+# (a coefficient 1% off reads 1e-2; a share over tokens for one over
 # (token, choice) pairs reads 1), set at three and a half times the
 # largest seen.
-ROUTER_LOSSES = ("moe_lb_loss", "moe_z_loss")
 ROUTER_LOSS_TOL = 2e-3
 
 # The program's mean cross-entropy against the FREE-RUNNING reference,
@@ -87,10 +105,12 @@ FREE_LOSS_TOL = 5e-4
 
 
 def program_losses(params, batch, cfg):
-    """The forward-only program's step metrics that this comparison
-    reads, as floats: ``loss`` (what the step reports), ``ce_loss`` (the
+    """The forward-only program's scalar step metrics, as floats: among
+    them ``loss`` (what the step reports), ``ce_loss`` (the
     cross-entropy alone, where the program reports it apart from an
-    objective that adds router terms) and the router losses."""
+    objective that adds other terms) and whichever of the objective's
+    terms it reports. ``compare`` looks up the names the reference
+    carries; a term that is not here fails there."""
     import jax
 
     from dlrover_tpu.models import decoder
@@ -98,8 +118,7 @@ def program_losses(params, batch, cfg):
     @jax.jit
     def losses(params, batch):
         metrics = decoder.loss_fn(params, batch, cfg=cfg)[1]
-        wanted = ("loss", "ce_loss") + ROUTER_LOSSES
-        return {k: metrics[k] for k in wanted if k in metrics}
+        return {k: v for k, v in metrics.items() if v.ndim == 0}
 
     return {k: float(v) for k, v in losses(params, batch).items()}
 
@@ -173,18 +192,19 @@ def compare(reference, params, batch, sizes, q_block, logits, choices,
     ``program`` is ``program_losses`` of the same share.
     ``reference.loss_and_logits_routed(params, batch, sizes, q_block,
     choices)`` returns ``(mean cross-entropy, logits, routed)`` where
-    ``routed["router_logits"]`` is float32 [L, B, S, E] and the other
-    entries are the router losses under the names of the program's step
-    metrics (``ROUTER_LOSSES``), coefficient included.
+    ``routed["router_logits"]`` is float32 [L, B, S, E] (one row per
+    routed layer, E the router's width, what the top-k is taken over)
+    and every other entry is a scalar term of the objective under the
+    name of the program's step metric, coefficient included.
 
-    Returns (results, record): ``results`` as ``(name, ok, value)`` for
-    the checks, ``record`` for the ``BENCH reference`` line."""
+    Returns (results, record): ``results`` as ``(name, ok, value,
+    limit)`` for the checks, ``record`` for the ``BENCH reference`` line."""
     import jax
     import jax.numpy as jnp
 
     logit_tol, logit_rms_tol, loss_tol = tolerances
     faults = choice_faults(choices, sizes["n_experts"])
-    results = [("choices_valid", faults == 0, faults)]
+    results = [("choices_valid", faults == 0, faults, 0)]
     if faults:
         # ids that name no expert cannot be forced on the reference
         return results, {"choice_faults": faults}
@@ -213,7 +233,7 @@ def compare(reference, params, batch, sizes, q_block, logits, choices,
             "tokens_moved": jnp.mean(jnp.any(regret > 0, axis=0)),
             "gap_median": jnp.median(stats["gap"]),
             "moved_by_layer": stats["moved"],
-            "router_losses": routed,
+            "objective_terms": routed,
         }
 
     got = jax.tree.map(np.asarray, against_forced(params, batch, logits, choices))
@@ -221,20 +241,25 @@ def compare(reference, params, batch, sizes, q_block, logits, choices,
     loss_err = abs(ce - float(got["ref_loss"])) / abs(float(got["ref_loss"]))
     regret_max = float(got["regret_max"])
     results += [
-        ("routing_regret", regret_max <= REGRET_TOL, regret_max),
+        ("routing_regret", regret_max <= REGRET_TOL, regret_max, REGRET_TOL),
         ("logits_vs_reference", float(got["logit_err"]) <= logit_tol,
-         float(got["logit_err"])),
+         float(got["logit_err"]), logit_tol),
         ("logits_rms_vs_reference", float(got["logit_rms"]) <= logit_rms_tol,
-         float(got["logit_rms"])),
-        ("loss_vs_reference", loss_err <= loss_tol, loss_err),
+         float(got["logit_rms"]), logit_rms_tol),
+        ("loss_vs_reference", loss_err <= loss_tol, loss_err, loss_tol),
     ]
-    for name in ROUTER_LOSSES:
-        if name in program and name in got["router_losses"]:
-            want = float(got["router_losses"][name])
-            err = abs(program[name] - want) / abs(want)
-            results.append(
-                (name + "_vs_reference", err <= ROUTER_LOSS_TOL, err)
-            )
+    cross_entropies = getattr(reference, "CROSS_ENTROPY_TERMS", ())
+    for name, want in got["objective_terms"].items():
+        tol = loss_tol if name in cross_entropies else ROUTER_LOSS_TOL
+        if name not in program:
+            results.append((
+                name + "_vs_reference", False,
+                "not among the program's step metrics", tol,
+            ))
+            continue
+        want = float(want)
+        err = abs(program[name] - want) / (abs(want) or 1.0)
+        results.append((name + "_vs_reference", err <= tol, err, tol))
     record = {
         "forced_ref_loss": float(got["ref_loss"]),
         "forced_loss_err": loss_err,
@@ -247,5 +272,8 @@ def compare(reference, params, batch, sizes, q_block, logits, choices,
         "gap_median": float(got["gap_median"]),
         "moved_by_layer": got["moved_by_layer"].tolist(),
         "regret_tol": REGRET_TOL,
+        "reference_terms": {
+            k: float(v) for k, v in got["objective_terms"].items()
+        },
     }
     return results, record

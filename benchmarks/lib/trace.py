@@ -29,6 +29,24 @@ Definitions (all per device, then averaged over the devices used):
   ``label`` (short name, opcode, kernel mark, output shape). The self
   times of a device add up to its busy time; ``device_ops`` is the
   first device's table cut to the largest few.
+- op names: the TPU's trace carries no name stack per event, the
+  compiled module's text does. ``op_names(compiled.as_text())`` maps an
+  instruction to its ``op_name`` metadata, the path of jax transforms
+  and of the program's ``jax.named_scope``s it was traced under
+  (``jit(step_fn)/transpose(jvp())/while/body/closed_call/checkpoint/
+  mlp/moe.combine/gather``); handed to ``reduce`` with the module's own
+  name (``module_name``) it comes back per device as ``op_names``:
+  label -> {that path: self seconds}, the label's time split by the
+  path of the instruction each event came from, for the events whose
+  instruction has one. Only an event that ran inside an execution of
+  that module (the device's line ``XLA Modules``) is looked up: an
+  instruction name says nothing about another program's ``fusion.3``.
+  ``scope_seconds`` sums a device's rows under given scopes. A kernel
+  the compiler itself puts in place of a primitive
+  (``ragged-dot-none``) keeps no path: its ``op_name`` is the kernel's
+  own name. A fusion the compiler left without metadata takes the
+  FIRST ``op_name`` inside the computation it calls, whichever of its
+  instructions takes the time.
 - exposed collective time: measure of (union of collective intervals)
   minus (union of every other leaf operation's interval): the time a
   collective runs and no compute does. A collective's interval is its
@@ -37,12 +55,14 @@ Definitions (all per device, then averaged over the devices used):
   one, its start-to-done span on the line ``Async XLA Ops``.
 """
 
+import bisect
 import glob
 import os
 import re
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"  # one event per execution: ``<module>(<id>)``
 ASYNC_LINE = "Async XLA Ops"  # start-to-done spans of asynchronous ops
 COLLECTIVES = (
     "all-reduce", "all-gather", "reduce-scatter", "all-to-all",
@@ -95,6 +115,75 @@ def parse_op(name):
         return short, short.split(".")[0], ""
     shape = re.sub(r"\{[^{}]*\}", "", rest[: m.start(1)]).strip()
     return short, m.group(1), shape
+
+
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r"calls=%?([\w.\-]+)")
+
+
+def op_names(hlo_text):
+    """Instruction name -> ``op_name`` metadata, from a compiled
+    module's text (``compiled.as_text()``). An instruction the compiler
+    left without metadata (a fusion it made of several) takes the first
+    ``op_name`` inside the computation it calls; one that has neither is
+    left out."""
+    names, first_inside, calls = {}, {}, []
+    computation = ""
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if not m:
+            c = _COMPUTATION.match(line)
+            if c:
+                computation = c.group(1)
+            continue
+        found = _OP_NAME.search(line)
+        if found:
+            names[m.group(1)] = found.group(1)
+            first_inside.setdefault(computation, found.group(1))
+            continue
+        called = _CALLS.search(line)
+        if called:
+            calls.append((m.group(1), called.group(1)))
+    for instruction, called in calls:
+        if called in first_inside:
+            names[instruction] = first_inside[called]
+    return names
+
+
+_PATH = re.compile(r"[/;()]")
+
+
+def has_scope(op_name, scope):
+    """Whether ``scope`` (a ``jax.named_scope`` of the program, or a
+    transform jax names: ``jvp``, ``transpose``, ``checkpoint``,
+    ``rematted_computation``) is a component of the path ``op_name``.
+    Components end at ``/``, at the brackets of a transform
+    (``transpose(jvp(mlp))``) and at the ``;`` between the paths of
+    instructions the compiler merged."""
+    return scope in _PATH.split(op_name)
+
+
+def module_name(hlo_text):
+    """``jit_step_fn`` of ``HloModule jit_step_fn, is_scheduled=...``."""
+    m = re.match(r"\s*HloModule ([\w.\-]+)", hlo_text)
+    return m.group(1) if m else None
+
+
+def scope_seconds(device, scopes):
+    """{label: self seconds} of one device's rows (an entry of
+    ``per_device``) under an ``op_name`` that has one of ``scopes`` as a
+    path component; empty where ``reduce`` was given no ``op_names``."""
+    out = {}
+    for label, paths in (device.get("op_names") or {}).items():
+        seconds = sum(
+            s for path, s in paths.items()
+            if any(has_scope(path, scope) for scope in scopes)
+        )
+        if seconds:
+            out[label] = seconds
+    return out
 
 
 def label(name, width=96):
@@ -195,7 +284,24 @@ def _line(plane, name):
     return []
 
 
-def reduce(planes, window_span=None, top=10):
+def _within(intervals):
+    """Whether a time lies in one of the sorted disjoint ``intervals``."""
+    starts = [s for s, _e in intervals]
+
+    def within(t):
+        i = bisect.bisect_right(starts, t) - 1
+        return i >= 0 and t < intervals[i][1]
+
+    return within
+
+
+def reduce(planes, window_span=None, top=10, op_names=None, module=None):
+    """``op_names``: instruction -> ``op_name`` of the traced program
+    (``op_names(compiled.as_text())``) and ``module`` its name
+    (``module_name``), or None. Where a device has the line
+    ``XLA Modules``, only the events inside an execution of ``module``
+    are looked up; a trace without that line has one program's events."""
+    op_names = op_names or {}
     devices = [p for p in planes if DEVICE_PLANE.match(p["name"]) and _line(p, OPS_LINE)]
     if not devices:
         return None
@@ -223,13 +329,23 @@ def reduce(planes, window_span=None, top=10):
         comp = union(
             (s, e) for n, s, e, _x, _l in leaves if not is_collective(n)
         )
+        executions = clip(_line(plane, MODULES_LINE), t0, t1)
+        ours = _within(union(
+            (s, e) for n, s, e in executions if n.split("(")[0] == module
+        )) if executions and module else (lambda t: True)
         by_name, pallas_ns = {}, 0.0  # label -> [self ns, calls]
-        for name, _s, _e, self_ns, _leaf in timed:
-            row = by_name.setdefault(label(name), [0.0, 0])
+        paths = {}  # label -> {op_name of the event's instruction: self ns}
+        for name, start, _e, self_ns, _leaf in timed:
+            key = label(name)
+            row = by_name.setdefault(key, [0.0, 0])
             row[0] += self_ns
             row[1] += 1
             if is_pallas(name):
                 pallas_ns += self_ns
+            path = op_names.get(parse_op(name)[0]) if ours(start) else None
+            if path is not None:
+                split = paths.setdefault(key, {})
+                split[path] = split.get(path, 0.0) + self_ns
         gaps = subtract([(t0, t1)], busy)
         per_device.append({
             "plane": plane["name"],
@@ -239,6 +355,11 @@ def reduce(planes, window_span=None, top=10):
             "collective_s": measure(coll) / 1e9,
             "collective_exposed_s": measure(subtract(coll, comp)) / 1e9,
             "by_name": {k: [ns / 1e9, n] for k, (ns, n) in by_name.items()},
+            "op_names": {
+                k: {path: ns / 1e9 for path, ns in split.items()}
+                for k, split in paths.items()
+            },
+            "modules": sorted({n.split("(")[0] for n, _s, _e in executions}),
             "gaps": gaps,
         })
     # the breakdown names the first device's operations and gaps; the
@@ -263,7 +384,9 @@ def reduce(planes, window_span=None, top=10):
         ),
         "idle_gaps": _ranked(gap_by_span, top),
         # with each device's whole table of operations, ``by_name``:
-        # label -> [self seconds, calls] inside the window
+        # label -> [self seconds, calls] inside the window,
+        # ``op_names``: label -> {``op_name``: self seconds} and
+        # ``modules``: the programs that ran in the window
         "per_device": [
             {k: v for k, v in d.items() if k != "gaps"} for d in per_device
         ],

@@ -62,7 +62,9 @@ def metrics_of(entries, cell_name):
 
 def read_layer_metric(name, run):
     """``benchmarks/layer_metrics/<name>.py``'s ``read(run)``; a reader
-    that finds nothing to read returns None and the metric is left out."""
+    that finds nothing to read returns None and the metric is left out.
+    One that is listed for its cells alone and finds there nothing of
+    what it is for may raise: the run then fails and prints no result."""
     path = os.path.join(HERE, "layer_metrics", name + ".py")
     spec = importlib.util.spec_from_file_location(
         "benchmarks.layer_metrics." + name.replace(".", "_"), path
@@ -106,6 +108,7 @@ def main(argv=None):
         return 2
 
     metrics = {}
+    run["say"] = say  # a reader may put what it summed on a BENCH line
     if args.trace:
         for m in metrics_of(manifest["per_layer"], cell["name"]):
             value = read_layer_metric(m["name"], run)
@@ -130,6 +133,14 @@ def main(argv=None):
         }
     sys.stdout.flush()
     print(json.dumps(result), flush=True)
+    # each number compared beside its limit: the last lines of standard
+    # error, which is what is kept of a run that is not correct
+    for name, ok, value, limit in run["checks"]:
+        print(
+            f"check {name}: {value!r} against {limit!r}:"
+            f" {'ok' if ok else 'NOT OK'}", file=sys.stderr,
+        )
+    sys.stderr.flush()
     return 0
 
 

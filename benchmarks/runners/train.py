@@ -24,6 +24,7 @@ import shutil
 import time
 
 from benchmarks.lib import device as devlib
+from benchmarks.lib import flops as flopslib
 from benchmarks.lib import trace as tracelib
 from benchmarks.lib.spans import Spans
 from benchmarks.lib.watch import CompileWatch, hbm, synthetic_batch
@@ -131,6 +132,8 @@ def run(ctx):
     step = builder.build()
     gb, seq = traffic["global_batch"], traffic["seq"]
     bsh = batch_sharding(mesh)
+    # by the reference module's ``required_terms`` where it has one
+    required_flops = flopslib.resolve(config, seq)
 
     def place(index):
         with spans.span("input"):
@@ -217,15 +220,15 @@ def run(ctx):
         abs(losses[0] - checks["kernel_loss"]) / abs(checks["kernel_loss"])
         if losses else float("inf")
     )
-    checks["results"].append(
-        ("first_step_loss", step_err <= STEP_LOSS_TOL, step_err)
-    )
-    checks["results"].append(
-        ("no_compile_in_window", compiles_in_window == 0, compiles_in_window)
-    )
-    checks["results"].append(("no_failed_step", failed == 0, failed))
-    for name, ok, value in checks["results"]:
-        say(event="check", name=name, ok=bool(ok), value=value)
+    checks["results"] += [
+        ("first_step_loss", step_err <= STEP_LOSS_TOL, step_err,
+         STEP_LOSS_TOL),
+        ("no_compile_in_window", compiles_in_window == 0,
+         compiles_in_window, 0),
+        ("no_failed_step", failed == 0, failed, 0),
+    ]
+    for name, ok, value, limit in checks["results"]:
+        say(event="check", name=name, ok=bool(ok), value=value, limit=limit)
 
     memory = hbm(devices)  # before the profiler, which resets the peaks
     reduced = None
@@ -241,7 +244,9 @@ def run(ctx):
             busy_s=reduced["busy_s"], window_s=reduced["window_s"]
         )
     return {
-        "correct": all(ok for _n, ok, _v in checks["results"]),
+        "correct": all(ok for _n, ok, _v, _l in checks["results"]),
+        # (name, ok, value, limit): ``run.py`` ends standard error with them
+        "checks": checks["results"],
         "attempted": attempted,
         "failed": failed,
         "device": device_record,
@@ -256,6 +261,7 @@ def run(ctx):
         "setup_compile_s": set_up[2],
         "sizes": sizes,
         "seq": seq,
+        "required_flops_per_token": required_flops,
         "chips": cell["chips"],
         "peaks": peaks,
         "trace": reduced,
@@ -386,14 +392,20 @@ def _check_outputs(ctx, cfg, mesh, state, batch0, first):
         )
         results.append((
             "loss_vs_free_reference", loss_err <= routed.FREE_LOSS_TOL,
-            loss_err,
+            loss_err, routed.FREE_LOSS_TOL,
         ))
-        record.update(kind=kind, program_losses=program, **forced)
+        compared = ("loss", "ce_loss", *forced.get("reference_terms", ()))
+        record.update(
+            kind=kind, **forced,
+            program_losses={k: program[k] for k in compared if k in program},
+        )
     else:
         results = [
-            ("logits_vs_reference", logit_err <= LOGIT_TOL, logit_err),
-            ("logits_rms_vs_reference", logit_rms <= LOGIT_RMS_TOL, logit_rms),
-            ("loss_vs_reference", loss_err <= LOSS_TOL, loss_err),
+            ("logits_vs_reference", logit_err <= LOGIT_TOL, logit_err,
+             LOGIT_TOL),
+            ("logits_rms_vs_reference", logit_rms <= LOGIT_RMS_TOL,
+             logit_rms, LOGIT_RMS_TOL),
+            ("loss_vs_reference", loss_err <= LOSS_TOL, loss_err, LOSS_TOL),
         ]
     del logits, params
     ctx["say"](**record, wall_s=time.perf_counter() - t0)
@@ -429,14 +441,31 @@ def _traced_window(ctx, spans, compiled, state, place, index, step_metrics):
                     step_metrics.append(metrics)
         finally:
             jax.profiler.stop_trace()
+        # the trace has no name stack per event; the compiled text has
+        t0 = time.perf_counter()
+        text = compiled.as_text()
+        op_names = tracelib.op_names(text)
+        op_names_s = time.perf_counter() - t0
         t0 = time.perf_counter()
         planes = tracelib.load_xplane(tracelib.find_xplane(out))
-        reduced = tracelib.reduce(planes, window_span="bench.traced_window")
+        module = tracelib.module_name(text)
+        reduced = tracelib.reduce(
+            planes, window_span="bench.traced_window", op_names=op_names,
+            module=module,
+        )
         ctx["say"](
             event="trace", parse_s=time.perf_counter() - t0,
+            op_names_s=op_names_s, compiled_text_bytes=len(text),
+            op_names=len(op_names), module=module,
+            # per device: self seconds that found a path, of busy_s
+            named_s=reduced and [
+                sum(sum(split.values()) for split in d["op_names"].values())
+                for d in reduced["per_device"]
+            ],
             planes=[p["name"] for p in planes],
             reduced=reduced and dict(reduced, per_device=[
-                {k: v for k, v in d.items() if k != "by_name"}
+                {k: v for k, v in d.items()
+                 if k not in ("by_name", "op_names")}
                 for d in reduced["per_device"]
             ]),
         )

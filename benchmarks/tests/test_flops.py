@@ -1,8 +1,13 @@
-"""The required-FLOPs function against hand arithmetic for the three
-configurations, and the peak table raising on a chip it does not know."""
+"""The required-FLOPs convention against hand arithmetic: the committed
+configurations through the old call and through the runner's
+resolution, an architecture whose layers are not all alike through a
+``required_terms`` of its own, and the peak table raising on a chip it
+does not know."""
 
 import json
 import os
+import sys
+import types
 
 import pytest
 
@@ -132,16 +137,133 @@ def test_routed_layers_count_what_a_token_meets():
         ) == 1.184
 
 
-# the committed configurations, to the last bit: what
-# ``required_flops_per_token`` gives on the parent of PR 28 (commit 625dd4b)
+# the four configurations committed before PR 33, to the last bit: what
+# ``required_flops_per_token`` gives on the parent of PR 28 (commit
+# 625dd4b) and, for OLMoE, on the parent of PR 33 (commit e8a7159). A
+# configuration added later is not listed here: it brings its hand
+# arithmetic in a test file of its own
 PARENT = {
     "gpt2-xl": (1024, 9802598400.0),
     "mistral-7b-l6": (8192, 9544212480.0),
     "gpt2-xl-zero1-dp4": (1024, 5142758400.0),
+    "olmoe-1b-7b-1chip": (4096, 1979486208.0),
 }
 
 
+@pytest.mark.parametrize("how", ["old_call", "resolved"])
 @pytest.mark.parametrize("name", sorted(PARENT))
-def test_dense_counts_did_not_move(name):
+def test_committed_counts_did_not_move(name, how):
+    """No committed reference module defines ``required_terms``: the
+    runner's resolution and the old call both give the parent's count."""
     seq, parent = PARENT[name]
-    assert flops.required_flops_per_token(_sizes(name), seq) == parent
+    if how == "old_call":
+        assert flops.required_flops_per_token(_sizes(name), seq) == parent
+    else:
+        assert flops.resolve(_config(name), seq) == parent
+
+
+# ---- an architecture whose layers are not all alike ----------------------
+# GLM-4.7-Flash's published widths (zai-org/GLM-4.7-Flash config.json,
+# ``glm4_moe_lite``), as data: no configuration file. Hidden 2048; latent
+# attention, 20 heads: q through rank 768 to 20 x (192 + 64) channels, k
+# and v through rank 512 (+ 64 shared rope channels) to 20 x (192 + 256),
+# out 20 x 256 -> 2048; one leading dense SwiGLU layer of width 10240,
+# then routed layers of 64 SwiGLU experts of width 1536, top-4, beside one
+# shared expert of the same width; one multi-token-prediction module (a
+# 2 x 2048 -> 2048 projection, one routed block, the head once more).
+# The cut of ISSUE 33: 1 dense + 8 routed layers + the module, this chip
+# holding 8 of the 64 experts and 1/8 of the vocabulary (19,360 rows),
+# sequences of 8192.
+GLM = {
+    "d_model": 2048, "n_head": 20, "q_lora_rank": 768, "kv_lora_rank": 512,
+    "qk_nope_head_dim": 192, "qk_rope_head_dim": 64, "v_head_dim": 256,
+    "n_dense_layer": 1, "d_ff": 10240, "n_routed_layer": 8,
+    "d_expert": 1536, "n_experts": 64, "n_experts_held": 8,
+    "expert_top_k": 4, "n_shared_experts": 1, "n_mtp_module": 1,
+    "vocab_size": 19360, "act": "swiglu", "attn_window": 0,
+}
+
+
+def glm_required_terms(sizes, seq):
+    """What a ``references/<module>.py`` for this architecture would
+    define, by the clauses of ``lib/flops.py``'s convention."""
+    d, h = sizes["d_model"], sizes["n_head"]
+    qk = sizes["qk_nope_head_dim"] + sizes["qk_rope_head_dim"]
+    attn = (
+        d * sizes["q_lora_rank"] + sizes["q_lora_rank"] * h * qk
+        + d * (sizes["kv_lora_rank"] + sizes["qk_rope_head_dim"])
+        + sizes["kv_lora_rank"]
+        * h * (sizes["qk_nope_head_dim"] + sizes["v_head_dim"])
+        + h * sizes["v_head_dim"] * d
+    )
+    expert = 3 * d * sizes["d_expert"]
+    met = (  # routed experts a token meets on this chip, and the shared
+        sizes["expert_top_k"] * sizes["n_experts_held"] / sizes["n_experts"]
+        + sizes["n_shared_experts"]
+    )
+    routed = attn + d * sizes["n_experts"] + met * expert
+    dense = attn + 3 * d * sizes["d_ff"]
+    head = d * sizes["vocab_size"]
+    mtp = sizes["n_mtp_module"] * (2 * d * d + routed + head)
+    attn_layers = (
+        sizes["n_dense_layer"] + sizes["n_routed_layer"]
+        + sizes["n_mtp_module"]
+    )
+    return {
+        "multiplied_params": int(
+            sizes["n_dense_layer"] * dense
+            + sizes["n_routed_layer"] * routed + mtp + head
+        ),
+        "attention_pair_channels": (
+            attn_layers * h * (qk + sizes["v_head_dim"]) / 2
+            * flops.mean_span(seq, sizes["attn_window"])
+        ),
+    }
+
+
+def test_layers_that_differ_are_counted_kind_by_kind(monkeypatch):
+    # by hand, per layer: attention 1,572,864 + 3,932,160 + 1,179,648
+    # + 4,587,520 + 10,485,760 = 21,757,952; dense MLP 62,914,560; one
+    # expert 9,437,184; router 131,072. A routed layer on this chip:
+    # attention + router + (4 x 8 / 64 + 1) experts = 36,044,800. The
+    # module: 8,388,608 + one routed layer + the head.
+    terms = glm_required_terms(GLM, 8192)
+    by_hand = (
+        (21_757_952 + 62_914_560) + 8 * 36_044_800
+        + (8_388_608 + 36_044_800 + 2048 * 19360) + 2048 * 19360
+    )
+    assert by_hand == 496_762_880
+    assert terms["multiplied_params"] == by_hand
+    assert terms["attention_pair_channels"] == 10 * 5120 * 4096.5
+    assert round(flops.flops_of(terms) / 1e9, 3) == 5.497
+    # through the runner's resolution: a reference module that defines
+    # ``required_terms`` is counted by it
+    module = types.SimpleNamespace(required_terms=glm_required_terms)
+    monkeypatch.setitem(sys.modules, "benchmarks.references.glm_test", module)
+    config = {"reference": "glm_test", "sizes": GLM}
+    assert flops.resolve(config, 8192) == 6.0 * by_hand + 12.0 * (
+        10 * 5120 * 4096.5
+    )
+    # THE DEFECT ISSUE 33 REPAIRS, pinned: the built-in count on the same
+    # model in the program's vocabulary (``intermediate_size`` 10240 is
+    # ``d_ff``; 9 layers) takes head_dim 2048 // 20 = 102 for 256 and
+    # four experts of the DENSE width in every layer: 2.84 times
+    builtin = {
+        "n_layer": 9, "d_model": 2048, "n_head": 20, "n_kv_head": 20,
+        "d_ff": 10240, "vocab_size": 19360, "act": "swiglu",
+        "attn_window": 0, "n_experts": 64, "expert_top_k": 4,
+    }
+    wrong = flops.required_flops_per_token(builtin, 8192)
+    assert round(wrong / 1e9, 2) == 15.64
+    assert round(wrong / flops.flops_of(terms), 2) == 2.84
+
+
+@pytest.mark.parametrize("terms", [
+    {"multiplied_params": 10},
+    {"multiplied_params": 10, "attention_pair_channels": 1.0, "extra": 1},
+    {"multiplied_params": 0, "attention_pair_channels": 1.0},
+    {"multiplied_params": 10, "attention_pair_channels": float("nan")},
+])
+def test_terms_outside_the_convention_are_refused(terms):
+    with pytest.raises(ValueError):
+        flops.flops_of(terms)

@@ -47,7 +47,8 @@ def _device(n, shift_us=0.0):
         "name": f"/device:TPU:{n}",
         "lines": [
             {"name": "Steps", "events": [_ev("0", 100, 850)]},
-            {"name": "XLA Modules", "events": [_ev("jit_step", 100, 850)]},
+            {"name": "XLA Modules",
+             "events": [_ev("jit_step", 100 + shift_us, 850)]},
             {"name": "XLA Ops", "events": ops},
             {"name": "Async XLA Ops",
              "events": [_ev(AR_START, 650 + shift_us, 250),
@@ -168,4 +169,179 @@ def test_by_name_table_is_whole_and_counts_calls():
     assert dict(r["device_ops"]) == {
         k: v[0] for k, v in r["per_device"][0]["by_name"].items()
         if v[0] >= 200e-6
+    }
+
+
+# ---- op names: the compiled text's name stack, by instruction ------------
+# A module text written by hand, in the compiler's form. ``fusion.7`` has
+# metadata of its own; ``fusion.8`` has none and takes the first
+# ``op_name`` inside the computation it calls; ``fusion.9`` calls a
+# computation without any and gets none; the kernel the compiler puts in
+# place of a primitive keeps no path, only its own name.
+MODULE = """\
+HloModule jit_step_fn, is_scheduled=true
+
+%fused_computation.7 (p.0: bf16[8,128,256]) -> bf16[8,128,256] {
+  %p.0 = bf16[8,128,256]{2,1,0} parameter(0)
+  ROOT %mul.1 = bf16[8,128,256]{2,1,0} multiply(%p.0, %p.0), metadata={op_name="jit(step_fn)/jvp()/while/body/mlp/mul"}
+}
+
+%fused_computation.8 (p.1: bf16[8,128,256]) -> bf16[8,128,256] {
+  %p.1 = bf16[8,128,256]{2,1,0} parameter(0)
+  %gather.3 = bf16[8,128,256]{2,1,0} gather(%p.1), metadata={op_name="jit(step_fn)/transpose(jvp())/while/body/closed_call/checkpoint/mlp/moe.combine/gather" source_file="moe.py" source_line=499}
+  ROOT %add.2 = bf16[8,128,256]{2,1,0} add(%gather.3, %p.1), metadata={op_name="jit(step_fn)/transpose(jvp())/while/body/closed_call/checkpoint/mlp/add"}
+}
+
+%fused_computation.9 (p.2: bf16[8,128,256]) -> bf16[8,128,256] {
+  %p.2 = bf16[8,128,256]{2,1,0} parameter(0)
+  ROOT %copy.4 = bf16[8,128,256]{2,1,0} copy(%p.2)
+}
+
+ENTRY %main.1 (x: bf16[8,128,256]) -> bf16[8,128,256] {
+  %x = bf16[8,128,256]{2,1,0} parameter(0)
+  %fusion.7 = bf16[8,128,256]{2,1,0:T(8,128)(2,1)S(1)} fusion(%x), kind=kOutput, calls=%fused_computation.7, metadata={op_name="jit(step_fn)/transpose(jvp())/while/body/closed_call/checkpoint/rematted_computation/mlp/moe.sort/gather"}
+  %fusion.8 = bf16[8,128,256]{2,1,0} fusion(%fusion.7), kind=kLoop, calls=%fused_computation.8
+  %fusion.9 = bf16[8,128,256]{2,1,0} fusion(%fusion.8), kind=kLoop, calls=%fused_computation.9
+  %closed_call.3 = (bf16[8,2,128,64]{3,2,1,0}) custom-call(%fusion.9), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-none"}
+  ROOT %while.1 = bf16[8,128,256]{2,1,0} copy(%fusion.9)
+}
+"""
+SORT = (
+    "jit(step_fn)/transpose(jvp())/while/body/closed_call/checkpoint/"
+    "rematted_computation/mlp/moe.sort/gather"
+)
+COMBINE = (
+    "jit(step_fn)/transpose(jvp())/while/body/closed_call/checkpoint/mlp/"
+    "moe.combine/gather"
+)
+
+
+def test_op_names_from_a_module_text():
+    names = trace.op_names(MODULE)
+    # metadata on the instruction wins over what it calls
+    assert names["fusion.7"] == SORT
+    # none on the instruction: the first inside the called computation
+    assert names["fusion.8"] == COMBINE
+    # on neither: left out, not guessed
+    assert "fusion.9" not in names and "while.1" not in names
+    assert names["closed_call.3"] == "ragged-dot-none"
+    assert names["mul.1"] == "jit(step_fn)/jvp()/while/body/mlp/mul"
+
+
+def test_scopes_are_path_components():
+    assert trace.has_scope(SORT, "moe.sort") and trace.has_scope(SORT, "mlp")
+    assert trace.has_scope(SORT, "rematted_computation")
+    assert trace.has_scope(SORT, "transpose") and trace.has_scope(SORT, "jvp")
+    assert not trace.has_scope(SORT, "moe") and not trace.has_scope(SORT, "sort")
+    assert not trace.has_scope(COMBINE, "rematted_computation")
+    # older jax renders a scope inside the transforms around it, and the
+    # compiler joins the paths of instructions it merged with ";"
+    assert trace.has_scope("jit(step)/transpose(jvp(attn))/dot", "attn")
+    assert trace.has_scope("a/mlp/reshape;checkpoint/mlp/moe.combine/r", "moe.combine")
+    assert not trace.has_scope("ragged-dot-none", "moe.experts")
+
+
+def test_reduce_carries_op_names_by_label():
+    names = trace.op_names(MODULE)
+    assert trace.module_name(MODULE) == "jit_step_fn"
+    r = trace.reduce(
+        [HOST, _device(0), _device(1, shift_us=50)],
+        window_span="bench.traced_window", op_names=names, module="jit_step",
+    )
+    for dev in r["per_device"]:
+        assert dev["modules"] == ["jit_step"]
+        assert dev["op_names"] == {
+            trace.label(FUSION): {SORT: pytest.approx(440e-6)},
+            trace.label(KERNEL): {"ragged-dot-none": pytest.approx(200e-6)},
+        }
+        assert set(dev["op_names"]) <= set(dev["by_name"])
+        assert trace.scope_seconds(dev, ("moe.sort", "moe.combine")) == {
+            trace.label(FUSION): pytest.approx(440e-6)
+        }
+        assert trace.scope_seconds(dev, ("attn",)) == {}
+    # the printed breakdown and the totals do not move with it
+    plain = trace.reduce(
+        [HOST, _device(0), _device(1, shift_us=50)],
+        window_span="bench.traced_window",
+    )
+    for key in ("busy_s", "pallas_s", "device_ops", "idle_gaps"):
+        assert r[key] == plain[key]
+    assert [d["by_name"] for d in r["per_device"]] == [
+        d["by_name"] for d in plain["per_device"]
+    ]
+    # without the compiled text every device's table is empty, and a
+    # reader of scopes finds nothing to read
+    assert all(d["op_names"] == {} for d in plain["per_device"])
+    assert trace.scope_seconds(plain["per_device"][0], ("moe.sort",)) == {}
+
+
+def _two_programs():
+    # the step runs 100..600 us, another program 600..950 us, and the
+    # other's ``fusion.7`` is no instruction of the step's text
+    dev = _device(0)
+    for line in dev["lines"]:
+        if line["name"] == "XLA Modules":
+            line["events"] = [
+                _ev("jit_step(17)", 100, 500), _ev("jit_other(18)", 600, 350),
+            ]
+    return dev
+
+
+def test_only_the_modules_own_events_are_looked_up():
+    names = trace.op_names(MODULE)
+    dev = trace.reduce(
+        [HOST, _two_programs()], window_span="bench.traced_window",
+        op_names=names, module="jit_step",
+    )["per_device"][0]
+    assert dev["modules"] == ["jit_other", "jit_step"]
+    # the fusions that start at 100 and 500 us are the step's (200 and
+    # 150 us); those at 660 and 900 us ran inside the other program
+    assert dev["op_names"][trace.label(FUSION)] == {
+        SORT: pytest.approx(350e-6)
+    }
+    assert dev["by_name"][trace.label(FUSION)][0] == pytest.approx(440e-6)
+    # a module that never ran in the window names nothing
+    none = trace.reduce(
+        [HOST, _two_programs()], window_span="bench.traced_window",
+        op_names=names, module="jit_step_fn",
+    )["per_device"][0]
+    assert none["op_names"] == {}
+    # a trace without the line is one program's: everything is looked up
+    bare = _device(0)
+    bare["lines"] = [x for x in bare["lines"] if x["name"] != "XLA Modules"]
+    whole = trace.reduce(
+        [HOST, bare], window_span="bench.traced_window",
+        op_names=names, module="jit_step_fn",
+    )["per_device"][0]
+    assert whole["modules"] == []
+    assert whole["op_names"][trace.label(FUSION)] == {
+        SORT: pytest.approx(440e-6)
+    }
+
+
+def test_a_label_two_instructions_share_is_split_by_path():
+    # a label starts with the instruction's name and is cut at 96
+    # characters: two names that differ only after that share a label,
+    # and each event's time goes under its own instruction's path
+    long = "fusion_" + "x" * 96
+    a = FUSION.replace("fusion.7", long + ".1")
+    b = FUSION.replace("fusion.7", long + ".2")
+    assert trace.label(a) == trace.label(b)
+    attn = "jit(step_fn)/jvp()/attn/dot_general"
+    dev = {
+        "name": "/device:TPU:0",
+        "lines": [{"name": "XLA Ops", "events": [
+            _ev(a, 100, 200), _ev(b, 300, 100), _ev(a, 400, 50),
+        ]}],
+    }
+    r = trace.reduce(
+        [HOST, dev], window_span="bench.traced_window",
+        op_names={long + ".1": SORT, long + ".2": attn},
+    )["per_device"][0]
+    assert r["by_name"] == {trace.label(a): [pytest.approx(350e-6), 3]}
+    assert r["op_names"] == {trace.label(a): {
+        SORT: pytest.approx(250e-6), attn: pytest.approx(100e-6),
+    }}
+    assert trace.scope_seconds(r, ("moe.sort",)) == {
+        trace.label(a): pytest.approx(250e-6)
     }

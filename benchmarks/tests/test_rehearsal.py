@@ -144,8 +144,9 @@ def _run_patched(monkeypatch, capsys, config, trace, chips=1, choices=None,
         "--workload", cell["name"], "--seed", str(seed),
         "--seconds", "0.5", "--trace", str(trace),
     ])
-    lines = capsys.readouterr().out.strip().splitlines()
-    return rc, cell, manifest, lines
+    captured = capsys.readouterr()
+    sys.stderr.write(captured.err)  # left for the caller to read
+    return rc, cell, manifest, captured.out.strip().splitlines()
 
 
 def _events(lines):
@@ -211,6 +212,12 @@ def test_end_to_end_line(monkeypatch, capsys, config, chips):
     assert events["reference"]["shares"] == chips
     assert events["compiled"]["update_sharding"] == (chips > 1)
     assert events["window"]["compiles_in_window"] == 0
+    # standard error ends with each number compared beside its limit
+    last = capsys.readouterr().err.strip().splitlines()[-len(checks):]
+    assert last == [
+        f"check {name}: {c['value']!r} against {c['limit']!r}: ok"
+        for name, c in checks.items()
+    ]
 
 
 def test_traced_line_reports_what_it_can_read(monkeypatch, capsys):
@@ -257,6 +264,43 @@ def test_routed_configuration_is_correct(monkeypatch, capsys):
     assert set(ref["program_losses"]) == {"loss", "moe_lb_loss", "moe_z_loss"}
 
 
+# the objective's terms: whatever scalar the teacher-forced reference
+# carries, the program's step metrics have to report under that name
+TERM_CASES = {
+    # an extra prediction module's cross-entropy, sound on both sides
+    "sound": (dict(), True, lambda v: v <= 2e-4),
+    # the reference carries it, the program does not report it
+    "missing": (dict(factor=None), False, lambda v: isinstance(v, str)),
+    # 0.1% off and not listed as a cross-entropy: held at ROUTER_LOSS_TOL,
+    # which passes it. Why the class exists: listed, it fails
+    # (``defects.ce_term_off``)
+    "off_unlisted": (
+        dict(factor=1.001, cross_entropy=False), True,
+        lambda v: 2e-4 < v <= 2e-3,
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TERM_CASES))
+def test_objective_terms_the_reference_carries(monkeypatch, capsys, case):
+    kwargs, ok, value_is = TERM_CASES[case]
+    defects.extra_prediction(monkeypatch.setattr, **kwargs)
+    rc, _cell, _manifest, lines = _run_patched(
+        monkeypatch, capsys, TINY_MOE, 0, seed=ROUTED_SEED
+    )
+    assert rc == 0
+    checks, _events_ = _events(lines)
+    at = ROUTED_CHECKS.index("moe_z_loss_vs_reference") + 1
+    assert list(checks) == (
+        ROUTED_CHECKS[:at] + ["mtp_loss_vs_reference"] + ROUTED_CHECKS[at:]
+    )
+    term = checks["mtp_loss_vs_reference"]
+    assert term["ok"] is ok and value_is(term["value"]), term
+    assert json.loads(lines[-1])["correct"] is ok
+    others = {n: c for n, c in checks.items() if n != "mtp_loss_vs_reference"}
+    assert all(c["ok"] for c in others.values()), others
+
+
 @pytest.mark.parametrize("defect", sorted(defects.INJECT))
 def test_routed_comparison_catches(monkeypatch, capsys, defect):
     defects.INJECT[defect](monkeypatch.setattr)
@@ -299,6 +343,7 @@ def test_choices_that_name_no_experts_fail(monkeypatch, capsys, how):
     assert json.loads(lines[-1])["correct"] is False
     assert checks["choices_valid"] == {
         "event": "check", "name": "choices_valid", "ok": False, "value": 1,
+        "limit": 0,
     }
     assert "logits_vs_reference" not in checks  # nothing to force
 
