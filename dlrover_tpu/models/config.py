@@ -97,6 +97,46 @@ class ModelConfig:
     # softmax probabilities at the chosen experts (OLMoE's
     # norm_topk_prob=false). Switch gating is always raw.
     moe_renorm_topk: bool = True
+    # what the top-k is taken over and the combine weights are made of:
+    # softmax over the router's logits, or each logit's sigmoid
+    # (DeepSeek-V3's ``noaux_tc`` family; its selection bias is a buffer
+    # without gradient, zero at initialisation, and is not modelled)
+    moe_score: str = "softmax"       # softmax | sigmoid
+    # multiplies the combine weights after renormalisation
+    routed_scaling_factor: float = 1.0
+    # width of one expert's SwiGLU (0 = d_ff). With n_dense_layer > 0
+    # d_ff is the dense prefix's width and this the experts'
+    d_expert: int = 0
+    # experts every token meets beside the routed ones, as ONE SwiGLU of
+    # width n_shared_experts · d_expert (0 = none)
+    n_shared_experts: int = 0
+    # expert parallelism's share on this device: the router stays
+    # n_experts wide and a token's k choices and weights are over all of
+    # them, but only experts [expert_offset, expert_offset +
+    # n_experts_held) live here and add to the layer's output
+    # (0 = every expert is held). Ragged lowering only.
+    n_experts_held: int = 0
+    expert_offset: int = 0
+    # leading layers with a dense MLP of d_ff in a routed model
+    # (``first_k_dense_replace``); the other n_layer - n_dense_layer are
+    # routed. params holds each kind stacked by itself
+    n_dense_layer: int = 0
+    # latent attention (MLA; 0 ranks = plain q/k/v projections): q
+    # through rank q_lora_rank to n_head × (qk_nope + qk_rope) channels,
+    # k and v through rank kv_lora_rank (+ qk_rope rope channels all
+    # heads share) to n_head × (qk_nope ‖ v_head_dim). Training runs the
+    # expanded form: MHA at head size qk_nope + qk_rope = v_head_dim
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # multi-token prediction (DeepSeek-V3 §2.2): one extra routed block
+    # on [norm(emb(t_{i+1})) ‖ norm(h_i)] predicting t_{i+2} through the
+    # shared embedding and head; its cross-entropy enters the objective
+    # times mtp_loss_coef. Training path only
+    n_mtp_module: int = 0
+    mtp_loss_coef: float = 0.3
     # pipeline microbatches when the mesh has pp > 1 (0 → one per stage)
     pp_microbatches: int = 0
     # interleaved (circular) pipeline: v layer chunks per stage cut the
@@ -151,6 +191,67 @@ class ModelConfig:
                 f"moe_gating must be 'topk' or 'switch', got "
                 f"{self.moe_gating!r}"
             )
+        if self.moe_score not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"moe_score must be 'softmax' or 'sigmoid', got "
+                f"{self.moe_score!r}"
+            )
+        if self.n_experts_held:
+            if self.moe_impl != "ragged":
+                raise ValueError(
+                    "n_experts_held needs moe_impl='ragged': the capacity "
+                    "lowerings hold every expert"
+                )
+            if not (
+                0 <= self.expert_offset
+                and self.expert_offset + self.n_experts_held
+                <= self.n_experts
+            ):
+                raise ValueError(
+                    f"experts [{self.expert_offset}, {self.expert_offset} "
+                    f"+ {self.n_experts_held}) are not among "
+                    f"{self.n_experts}"
+                )
+        if self.n_dense_layer and not (
+            self.n_experts and self.n_dense_layer < self.n_layer
+        ):
+            raise ValueError(
+                "n_dense_layer is the dense prefix of a routed model: it "
+                "needs n_experts > 0 and a routed layer after it"
+            )
+        if self.latent_attention:
+            ranks = (
+                self.q_lora_rank, self.kv_lora_rank,
+                self.qk_nope_head_dim, self.qk_rope_head_dim,
+            )
+            if not all(r > 0 for r in ranks) or self.qk_rope_head_dim % 2:
+                raise ValueError(
+                    "latent attention needs q_lora_rank, kv_lora_rank, "
+                    "qk_nope_head_dim and an even qk_rope_head_dim"
+                )
+            if self.v_head_dim != (
+                self.qk_nope_head_dim + self.qk_rope_head_dim
+            ):
+                raise ValueError(
+                    "latent attention runs expanded, as MHA at one head "
+                    "size: v_head_dim must equal qk_nope_head_dim + "
+                    f"qk_rope_head_dim, got {self.v_head_dim} against "
+                    f"{self.qk_nope_head_dim} + {self.qk_rope_head_dim}"
+                )
+            if (
+                self.pos != "rope"
+                or self.qk_norm
+                or self.kv_heads != self.n_head
+            ):
+                raise ValueError(
+                    "latent attention is rope on its own channels, MHA, "
+                    "no qk_norm"
+                )
+        if self.n_mtp_module not in (0, 1):
+            raise ValueError(
+                "one multi-token-prediction module is built; "
+                f"n_mtp_module={self.n_mtp_module}"
+            )
         if self.remat not in (
             "none", "full", "dots_saveable", "save_attn", "save_qkv",
             "save_qkv_gate", "save_dots", "offload_attn",
@@ -193,8 +294,49 @@ class ModelConfig:
         return self.n_kv_head or self.n_head
 
     @property
+    def latent_attention(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
     def head_dim(self) -> int:
+        if self.latent_attention:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.d_model // self.n_head
+
+    @property
+    def rope_dim(self) -> int:
+        """Channels of a head that rope turns."""
+        if self.latent_attention:
+            return self.qk_rope_head_dim
+        return self.head_dim
+
+    @property
+    def expert_width(self) -> int:
+        return self.d_expert or self.d_ff
+
+    @property
+    def experts_here(self) -> int:
+        """Routed experts whose weights this device holds."""
+        return self.n_experts_held or self.n_experts
+
+    @property
+    def n_routed_layer(self) -> int:
+        """Routed layers of the trunk (the prediction module's block is
+        not among them)."""
+        return self.n_layer - self.n_dense_layer if self.n_experts else 0
+
+    @property
+    def train_only(self) -> str:
+        """Why the cache, paged, pipeline and generate paths cannot run
+        this model ("" where they can): they are written for one stack
+        of plain-attention layers."""
+        if self.latent_attention:
+            return "latent attention (no latent cache is built)"
+        if self.n_dense_layer:
+            return "a trunk whose layers differ"
+        if self.n_mtp_module:
+            return "a prediction module"
+        return ""
 
     @property
     def routed_top_k(self) -> int:
@@ -202,14 +344,38 @@ class ModelConfig:
         return 1 if self.moe_gating == "switch" else self.expert_top_k
 
     def num_params(self) -> int:
-        """Approximate parameter count (dense part)."""
+        """Approximate parameter count. A routed layer of a model of one
+        kind is counted as one MLP of ``d_ff`` (the dense part); a model
+        with a dense prefix counts what this device holds: the prefix at
+        ``d_ff``, each routed block's held and shared experts and
+        router, and the prediction module."""
         d, f, v, L = self.d_model, self.d_ff, self.vocab_size, self.n_layer
-        attn = d * d + 2 * d * self.kv_heads * self.head_dim + d * d
-        mlp = (3 if self.act == "swiglu" else 2) * d * f
-        per_layer = attn + mlp + 2 * d
+        if self.latent_attention:
+            attn = (
+                d * self.q_lora_rank
+                + self.q_lora_rank * self.n_head * self.head_dim
+                + d * (self.kv_lora_rank + self.qk_rope_head_dim)
+                + self.kv_lora_rank * self.n_head
+                * (self.qk_nope_head_dim + self.v_head_dim)
+                + self.n_head * self.v_head_dim * d
+                + self.q_lora_rank + self.kv_lora_rank
+            )
+        else:
+            attn = d * d + 2 * d * self.kv_heads * self.head_dim + d * d
+        gated = 3 if self.act == "swiglu" else 2
+        mlp = gated * d * f
         embed = v * d * (1 if self.tie_embeddings else 2)
         pos = self.max_seq * d if self.pos == "learned" else 0
-        return L * per_layer + embed + pos + d
+        if not self.n_dense_layer:
+            return L * (attn + mlp + 2 * d) + embed + pos + d
+        routed = attn + 2 * d + d * self.n_experts + (
+            self.experts_here + self.n_shared_experts
+        ) * gated * d * self.expert_width
+        mtp = self.n_mtp_module * (2 * d * d + routed + 3 * d)
+        return (
+            self.n_dense_layer * (attn + mlp + 2 * d)
+            + self.n_routed_layer * routed + mtp + embed + pos + d
+        )
 
     def flops_per_token(self, seq_len: int) -> float:
         """FLOPs a training step requires per token, forward and
@@ -224,20 +390,50 @@ class ModelConfig:
         ``routed_top_k`` experts a token meets and the router, not the
         experts it never visits. Under the causal mask query i sees
         min(i + 1, window or seq_len) keys; without it, all of them.
-        Recomputation does not count."""
+        A device that holds h of E experts counts k · h / E of them, a
+        shared expert whole, a leading dense layer at ``d_ff``, latent
+        attention's five projections, and a prediction module's
+        projection, block and the head once more. Recomputation does
+        not count."""
         d = self.d_model
         d_attn = self.n_head * self.head_dim
-        attn = 2 * d * d_attn + 2 * d * self.kv_heads * self.head_dim
-        mlp = (3 if self.act == "swiglu" else 2) * d * self.d_ff
+        if self.latent_attention:
+            attn = (
+                d * self.q_lora_rank + self.q_lora_rank * d_attn
+                + d * (self.kv_lora_rank + self.qk_rope_head_dim)
+                + self.kv_lora_rank * self.n_head
+                * (self.qk_nope_head_dim + self.v_head_dim)
+                + self.n_head * self.v_head_dim * d
+            )
+        else:
+            attn = 2 * d * d_attn + 2 * d * self.kv_heads * self.head_dim
+        gated = 3 if self.act == "swiglu" else 2
+        head = d * self.vocab_size
+        routed = 0
         if self.n_experts:
-            mlp = self.routed_top_k * mlp + d * self.n_experts
-        multiplied = self.n_layer * (attn + mlp) + d * self.vocab_size
+            # the experts a token meets HERE: its k choices' balanced
+            # share of the experts held, and the shared ones whole
+            met = (
+                self.routed_top_k * self.experts_here / self.n_experts
+                + self.n_shared_experts
+            )
+            routed = attn + met * gated * d * self.expert_width + (
+                d * self.n_experts
+            )
+        dense = attn + gated * d * self.d_ff
+        multiplied = (
+            (self.n_layer - self.n_routed_layer) * dense
+            + self.n_routed_layer * routed
+            + self.n_mtp_module * (2 * d * d + routed + head)
+            + head
+        )
         if self.causal:
             w = min(self.attn_window or seq_len, seq_len)
             span = (w * (w + 1) / 2 + (seq_len - w) * w) / seq_len
         else:
             span = seq_len
-        return 6.0 * multiplied + 12.0 * self.n_layer * d_attn * span
+        attn_layers = self.n_layer + self.n_mtp_module
+        return 6.0 * multiplied + 12.0 * attn_layers * d_attn * span
 
 
 def mup_base_config(cfg: "ModelConfig") -> "ModelConfig":
@@ -428,6 +624,34 @@ CONFIGS = {
         moe_renorm_topk=False,
         moe_aux_coef=0.01,
         moe_z_coef=0.001,
+    ),
+    # latent attention, a dense prefix, sigmoid-scored experts beside a
+    # shared one, one prediction module: GLM-4.7-Flash (``glm4_moe_lite``,
+    # huggingface.co/zai-org/GLM-4.7-Flash config.json) — 20 MLA heads
+    # (ranks 768 / 512, 192 + 64 score and 256 value channels), one
+    # SwiGLU layer of 10240 then 46 of 64 experts of width 1536, top-4
+    # renormalised × 1.8, no router loss term
+    "glm-4.7-flash": replace(
+        _llama(
+            "glm-4.7-flash", 47, 20, 2048, 10240, max_seq=202752,
+            n_kv_head=20,
+        ),
+        vocab_size=154880,
+        rope_theta=1e6,
+        q_lora_rank=768,
+        kv_lora_rank=512,
+        qk_nope_head_dim=192,
+        qk_rope_head_dim=64,
+        v_head_dim=256,
+        n_dense_layer=1,
+        n_experts=64,
+        expert_top_k=4,
+        d_expert=1536,
+        n_shared_experts=1,
+        moe_impl="ragged",
+        moe_score="sigmoid",
+        routed_scaling_factor=1.8,
+        n_mtp_module=1,
     ),
 }
 

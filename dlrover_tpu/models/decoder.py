@@ -46,52 +46,98 @@ def _dense_init(key, shape, in_axis_size, dtype):
     return (jax.random.normal(key, shape) * scale).astype(dtype)
 
 
-def init(rng: jax.Array, cfg: ModelConfig) -> Params:
-    """Initialise parameters; per-layer tensors stacked on axis 0."""
+def _init_layers(keys, cfg: ModelConfig, lead, routed: bool) -> Params:
+    """One kind of layer, its tensors stacked on the leading axes
+    ``lead`` (``()`` = one layer): attention, the two norms and either a
+    dense MLP of ``d_ff`` or the routed block — never both."""
     pdt = jnp.dtype(cfg.param_dtype)
-    d, f, v = cfg.d_model, cfg.d_ff, cfg.vocab_size
-    hd, nh, nkv, L = cfg.head_dim, cfg.n_head, cfg.kv_heads, cfg.n_layer
-    keys = jax.random.split(rng, 16)
+    d, f = cfg.d_model, cfg.d_ff
+    hd, nh, nkv = cfg.head_dim, cfg.n_head, cfg.kv_heads
+    lead = tuple(lead)
 
     def stack(key, shape, fan_in):
         # one RNG draw for all layers: tiny init graph, fast remote compile
         scale = 1.0 / np.sqrt(fan_in)
-        return (jax.random.normal(key, (L,) + shape) * scale).astype(pdt)
+        return (jax.random.normal(key, lead + shape) * scale).astype(pdt)
 
-    params: Params = {
-        "embed": {
-            "tokens": (jax.random.normal(keys[0], (v, d)) * 0.02).astype(pdt)
-        },
-        "layers": {
-            "attn": {
-                "wq": stack(keys[1], (d, nh * hd), d),
-                "wk": stack(keys[2], (d, nkv * hd), d),
-                "wv": stack(keys[3], (d, nkv * hd), d),
-                "wo": stack(keys[4], (nh * hd, d), nh * hd),
-            },
-            "ln1": {"scale": jnp.ones((L, d), pdt)},
-            "ln2": {"scale": jnp.ones((L, d), pdt)},
-        },
-        "final_norm": {"scale": jnp.ones((d,), pdt)},
+    def ones(*shape):
+        return jnp.ones(lead + shape, pdt)
+
+    if cfg.latent_attention:
+        rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+        nope, rd, vd = (
+            cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        )
+        attn = {
+            "wq_a": stack(keys[1], (d, rq), d),
+            "q_a_norm": {"scale": ones(rq)},
+            "wq_b": stack(keys[11], (rq, nh * (nope + rd)), rq),
+            # [c_kv ‖ k_r]: the latent and the rope channels all heads share
+            "wkv_a": stack(keys[2], (d, rkv + rd), d),
+            "kv_a_norm": {"scale": ones(rkv)},
+            # per head [k_nope ‖ v]
+            "wkv_b": stack(keys[3], (rkv, nh * (nope + vd)), rkv),
+            "wo": stack(keys[4], (nh * vd, d), nh * vd),
+        }
+    else:
+        attn = {
+            "wq": stack(keys[1], (d, nh * hd), d),
+            "wk": stack(keys[2], (d, nkv * hd), d),
+            "wv": stack(keys[3], (d, nkv * hd), d),
+            "wo": stack(keys[4], (nh * hd, d), nh * hd),
+        }
+    layers: Params = {
+        "attn": attn,
+        "ln1": {"scale": ones(d)},
+        "ln2": {"scale": ones(d)},
     }
-    if cfg.act == "swiglu":
-        params["layers"]["mlp"] = {
+    if routed:
+        from dlrover_tpu.parallel.moe import init_moe_params
+
+        layers["moe"] = init_moe_params(keys[10], cfg, lead)
+    elif cfg.act == "swiglu":
+        layers["mlp"] = {
             "w_gate": stack(keys[5], (d, f), d),
             "w_up": stack(keys[6], (d, f), d),
             "w_down": stack(keys[7], (f, d), f),
         }
     else:
-        params["layers"]["mlp"] = {
+        layers["mlp"] = {
             "w_up": stack(keys[6], (d, f), d),
             "w_down": stack(keys[7], (f, d), f),
         }
     if cfg.qk_norm:
-        attn = params["layers"]["attn"]
-        attn["q_norm"] = {"scale": jnp.ones((L, nh * hd), pdt)}
-        attn["k_norm"] = {"scale": jnp.ones((L, nkv * hd), pdt)}
+        attn["q_norm"] = {"scale": ones(nh * hd)}
+        attn["k_norm"] = {"scale": ones(nkv * hd)}
     if cfg.norm == "layernorm":
-        params["layers"]["ln1"]["bias"] = jnp.zeros((L, d), pdt)
-        params["layers"]["ln2"]["bias"] = jnp.zeros((L, d), pdt)
+        layers["ln1"]["bias"] = jnp.zeros(lead + (d,), pdt)
+        layers["ln2"]["bias"] = jnp.zeros(lead + (d,), pdt)
+    return layers
+
+
+def init(rng: jax.Array, cfg: ModelConfig) -> Params:
+    """Initialise parameters; per-layer tensors stacked on axis 0, each
+    kind of layer by itself: ``layers`` (every layer of a model of one
+    kind; the routed layers of a model with a dense prefix) and
+    ``dense_layers`` (that prefix). A routed layer has no dense ``mlp``."""
+    pdt = jnp.dtype(cfg.param_dtype)
+    d, v = cfg.d_model, cfg.vocab_size
+    keys = jax.random.split(rng, 16)
+    n_dense = cfg.n_dense_layer
+    params: Params = {
+        "embed": {
+            "tokens": (jax.random.normal(keys[0], (v, d)) * 0.02).astype(pdt)
+        },
+        "layers": _init_layers(
+            keys, cfg, (cfg.n_layer - n_dense,), routed=cfg.n_experts > 0
+        ),
+        "final_norm": {"scale": jnp.ones((d,), pdt)},
+    }
+    if n_dense:
+        params["dense_layers"] = _init_layers(
+            jax.random.split(keys[12], 16), cfg, (n_dense,), routed=False
+        )
+    if cfg.norm == "layernorm":
         params["final_norm"]["bias"] = jnp.zeros((d,), pdt)
     if cfg.pos == "learned":
         params["pos_embed"] = {
@@ -101,55 +147,98 @@ def init(rng: jax.Array, cfg: ModelConfig) -> Params:
         }
     if not cfg.tie_embeddings:
         params["lm_head"] = {"w": _dense_init(keys[9], (d, v), d, pdt)}
-    if cfg.n_experts > 0:
-        from dlrover_tpu.parallel.moe import init_moe_params
-
-        params["layers"]["moe"] = init_moe_params(keys[10], cfg)
+    if cfg.n_mtp_module:
+        mk = jax.random.split(keys[13], 17)
+        norm = {"scale": jnp.ones((d,), pdt)}
+        if cfg.norm == "layernorm":
+            norm["bias"] = jnp.zeros((d,), pdt)
+        params["mtp"] = {
+            "enorm": dict(norm),
+            "hnorm": dict(norm),
+            # [norm(emb(t_{i+1})) ‖ norm(h_i)] -> d
+            "eh_proj": _dense_init(mk[16], (2 * d, d), 2 * d, pdt),
+            "block": _init_layers(mk, cfg, (), routed=cfg.n_experts > 0),
+            "norm": dict(norm),
+        }
     return params
+
+
+def _layer_axes(cfg: ModelConfig, lead, routed: bool) -> Params:
+    """Logical axes of ``_init_layers``' tree."""
+    lead = tuple(lead)
+    if cfg.latent_attention:
+        attn = {
+            "wq_a": lead + ("embed", None),
+            "q_a_norm": {"scale": lead + ("norm",)},
+            "wq_b": lead + (None, "heads"),
+            "wkv_a": lead + ("embed", None),
+            "kv_a_norm": {"scale": lead + ("norm",)},
+            "wkv_b": lead + (None, "heads"),
+            "wo": lead + ("heads", "embed"),
+        }
+    else:
+        attn = {
+            "wq": lead + ("embed", "heads"),
+            "wk": lead + ("embed", "kv"),
+            "wv": lead + ("embed", "kv"),
+            "wo": lead + ("heads", "embed"),
+        }
+    ax: Params = {
+        "attn": attn,
+        "ln1": {"scale": lead + ("norm",)},
+        "ln2": {"scale": lead + ("norm",)},
+    }
+    if routed:
+        from dlrover_tpu.parallel.moe import moe_logical_axes
+
+        ax["moe"] = moe_logical_axes(cfg, lead)
+    elif cfg.act == "swiglu":
+        ax["mlp"] = {
+            "w_gate": lead + ("embed", "mlp"),
+            "w_up": lead + ("embed", "mlp"),
+            "w_down": lead + ("mlp", "embed"),
+        }
+    else:
+        ax["mlp"] = {
+            "w_up": lead + ("embed", "mlp"),
+            "w_down": lead + ("mlp", "embed"),
+        }
+    if cfg.qk_norm:
+        attn["q_norm"] = {"scale": lead + ("norm",)}
+        attn["k_norm"] = {"scale": lead + ("norm",)}
+    if cfg.norm == "layernorm":
+        ax["ln1"]["bias"] = lead + ("norm",)
+        ax["ln2"]["bias"] = lead + ("norm",)
+    return ax
 
 
 def logical_axes(cfg: ModelConfig) -> Params:
     """Pytree of logical-axis tuples, same structure as ``init``'s output."""
+    routed = cfg.n_experts > 0
     ax: Params = {
         "embed": {"tokens": ("vocab", "embed")},
-        "layers": {
-            "attn": {
-                "wq": ("layers", "embed", "heads"),
-                "wk": ("layers", "embed", "kv"),
-                "wv": ("layers", "embed", "kv"),
-                "wo": ("layers", "heads", "embed"),
-            },
-            "ln1": {"scale": ("layers", "norm")},
-            "ln2": {"scale": ("layers", "norm")},
-        },
+        "layers": _layer_axes(cfg, ("layers",), routed),
         "final_norm": {"scale": ("norm",)},
     }
-    if cfg.act == "swiglu":
-        ax["layers"]["mlp"] = {
-            "w_gate": ("layers", "embed", "mlp"),
-            "w_up": ("layers", "embed", "mlp"),
-            "w_down": ("layers", "mlp", "embed"),
-        }
-    else:
-        ax["layers"]["mlp"] = {
-            "w_up": ("layers", "embed", "mlp"),
-            "w_down": ("layers", "mlp", "embed"),
-        }
-    if cfg.qk_norm:
-        ax["layers"]["attn"]["q_norm"] = {"scale": ("layers", "norm")}
-        ax["layers"]["attn"]["k_norm"] = {"scale": ("layers", "norm")}
+    if cfg.n_dense_layer:
+        ax["dense_layers"] = _layer_axes(cfg, ("layers",), routed=False)
     if cfg.norm == "layernorm":
-        ax["layers"]["ln1"]["bias"] = ("layers", "norm")
-        ax["layers"]["ln2"]["bias"] = ("layers", "norm")
         ax["final_norm"]["bias"] = ("norm",)
     if cfg.pos == "learned":
         ax["pos_embed"] = {"table": ("seq", "embed")}
     if not cfg.tie_embeddings:
         ax["lm_head"] = {"w": ("embed", "vocab")}
-    if cfg.n_experts > 0:
-        from dlrover_tpu.parallel.moe import moe_logical_axes
-
-        ax["layers"]["moe"] = moe_logical_axes(cfg)
+    if cfg.n_mtp_module:
+        norm = {"scale": ("norm",)}
+        if cfg.norm == "layernorm":
+            norm["bias"] = ("norm",)
+        ax["mtp"] = {
+            "enorm": dict(norm),
+            "hnorm": dict(norm),
+            "eh_proj": (None, "embed"),
+            "block": _layer_axes(cfg, (), routed),
+            "norm": dict(norm),
+        }
     return ax
 
 
@@ -228,6 +317,14 @@ def _vocab_parallel_embed(table: jax.Array, tokens: jax.Array, mesh):
 # ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
+
+
+def _embed_tokens(params: Params, tokens, mesh, dt):
+    """Rows of the token table for ``tokens`` [B, S]."""
+    table = params["embed"]["tokens"]
+    if _embed_lookup_hostile(mesh, table.shape, tokens.shape):
+        return _vocab_parallel_embed(table, tokens, mesh).astype(dt)
+    return jnp.take(table, tokens, axis=0).astype(dt)
 
 
 def _norm(x, scale, bias, kind: str):
@@ -400,13 +497,60 @@ def _cache_layer_tail(x, attn_out, layer, cfg: ModelConfig):
     return x + attn_out + mlp_out if cfg.parallel_residual else x + mlp_out
 
 
+def _latent_qkv(x, attn, cfg: ModelConfig, positions, rope=None):
+    """Latent attention's q, k, v in the EXPANDED form, [B, S, H, D] each
+    with D = qk_nope + qk_rope = v_head_dim, so that what follows is MHA
+    through the flash kernels:
+
+        c_q = norm(x W_dq);  q_h = c_q W_uq  -> [q_nope,h ‖ q_rope,h]
+        [c_kv ‖ k_r] = x W_dkv;  c_kv = norm(c_kv)
+        [k_nope,h ‖ v_h] = c_kv W_ukv
+        q_h = [q_nope,h ‖ rope(q_rope,h)];  k_h = [k_nope,h ‖ rope(k_r)]
+
+    ``k_r`` is one set of rope channels that every head shares. The
+    up-projection's weight is cut into its k and v columns (4.6 M
+    parameters) rather than its output (tokens × H × 448 activations).
+    Everything here is the scope ``attn.latent``: what of the block is
+    neither a flash call nor W_o. The weight-absorbed form, which never
+    expands k and v, belongs to a latent cache and is not built."""
+    b, s, _ = x.shape
+    nh, rkv = cfg.n_head, cfg.kv_lora_rank
+    nope, rd, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    dt = x.dtype
+    with jax.named_scope("attn.latent"):
+        c_q = _norm_block(x @ attn["wq_a"].astype(dt), attn["q_a_norm"], cfg)
+        q = (c_q @ attn["wq_b"].astype(dt)).reshape(b, s, nh, nope + rd)
+        kv = x @ attn["wkv_a"].astype(dt)
+        c_kv = _norm_block(kv[..., :rkv], attn["kv_a_norm"], cfg)
+        k_r = kv[..., rkv:].reshape(b, s, 1, rd)
+        w_kv = attn["wkv_b"].astype(dt).reshape(rkv, nh, nope + vd)
+        k_nope = jnp.einsum("bsr,rhc->bshc", c_kv, w_kv[..., :nope])
+        v = jnp.einsum("bsr,rhc->bshc", c_kv, w_kv[..., nope:])
+        q = _tag_residual(q, "q_proj", cfg)
+        k_nope = _tag_residual(k_nope, "k_proj", cfg)
+        v = _tag_residual(v, "v_proj", cfg)
+        if rope is None:
+            rope = _rope_tables(positions, rd, cfg.rope_theta)
+        q = jnp.concatenate(
+            [q[..., :nope], _rope(q[..., nope:], rope)], axis=-1
+        )
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(_rope(k_r, rope), (b, s, nh, rd))],
+            axis=-1,
+        )
+    return q, k, v
+
+
 def _attention_block(
     x, layer, cfg: ModelConfig, mesh, positions, attn_fn, fp8=None,
     rope=None,
 ):
     b, s, d = x.shape
     nh, hd = cfg.n_head, cfg.head_dim
-    q, k, v = _project_qkv(x, layer, cfg, positions, fp8=fp8, rope=rope)
+    if cfg.latent_attention:
+        q, k, v = _latent_qkv(x, layer["attn"], cfg, positions, rope=rope)
+    else:
+        q, k, v = _project_qkv(x, layer, cfg, positions, fp8=fp8, rope=rope)
     if mesh is not None:
         q = shd.constrain(q, mesh, "batch", "seq", "heads", None)
         k = shd.constrain(k, mesh, "batch", "seq", "kv", None)
@@ -506,7 +650,7 @@ def _layer_body(
             # x + attn is written once, from the same VMEM visit that
             # computes the statistics
             h2, x = _norm_block(x, ln2, cfg, residual=attn)
-        if cfg.n_experts > 0:
+        if "moe" in layer:
             from dlrover_tpu.parallel.moe import moe_block
 
             # fp8 reaches the experts as stateless current scaling (the
@@ -537,29 +681,10 @@ def _offload_names_policy(*names):
     )
 
 
-def run_trunk(
-    x: jax.Array,          # [B, S, D] embedded inputs
-    layers: Params,        # stacked per-layer params (leading axis L)
-    positions: jax.Array,  # [B, S]
-    cfg: ModelConfig,
-    mesh=None,
-    attn_fn=None,
-    rng: Optional[jax.Array] = None,
-    tag_attn_out: bool = False,
-    fp8_layers=None,
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Run the stacked transformer layers: remat policy, pp pipelining,
-    MoE aux-loss accumulation. Shared by the decoder and the ViT trunk
-    (models/vision.py) so policies stay in one place.
-
-    ``fp8_layers``: stacked per-layer fp8 delayed-scaling states
-    (init_fp8_states; leading axis L) — scanned alongside the layer
-    params — or the string "current" for stateless current scaling
-    (the only sound fp8 mode under pp; see the pp guard below). Dense
-    layers only (MoE experts stay bf16).
-
-    Returns (hidden states [B,S,D] — pre-final-norm, aux losses).
-    """
+def _remat_body(cfg: ModelConfig, mesh, attn_fn, tag_attn_out, fp8_layers):
+    """``_layer_body`` bound to the model and wrapped in the configured
+    rematerialisation policy: what every layer of the trunk, of either
+    kind, and the prediction module's block run through."""
     body = functools.partial(
         _layer_body,
         cfg=cfg,
@@ -648,6 +773,50 @@ def run_trunk(
             ),
         )
 
+    return body
+
+
+def _train_only_guard(cfg: ModelConfig, fn: str):
+    """The cache, paged, pipeline and generate paths scan ONE stack of
+    plain-attention layers; a model they would run wrongly is refused
+    by name."""
+    if cfg.train_only:
+        raise ValueError(
+            f"{fn} cannot run {cfg.name}: {cfg.train_only}. Only the "
+            "training path (forward, loss_fn, the train step) does"
+        )
+
+
+def run_trunk(
+    x: jax.Array,          # [B, S, D] embedded inputs
+    layers: Params,        # stacked per-layer params (leading axis L)
+    positions: jax.Array,  # [B, S]
+    cfg: ModelConfig,
+    mesh=None,
+    attn_fn=None,
+    rng: Optional[jax.Array] = None,
+    tag_attn_out: bool = False,
+    fp8_layers=None,
+    dense_layers: Optional[Params] = None,
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """Run the stacked transformer layers: remat policy, pp pipelining,
+    MoE aux-loss accumulation. Shared by the decoder and the ViT trunk
+    (models/vision.py) so policies stay in one place.
+
+    ``dense_layers``: the dense prefix of a routed model, stacked by
+    itself; it runs first, then the scan over ``layers``, both through
+    the same ``_layer_body`` under the same remat.
+
+    ``fp8_layers``: stacked per-layer fp8 delayed-scaling states
+    (init_fp8_states; leading axis L) — scanned alongside the layer
+    params — or the string "current" for stateless current scaling
+    (the only sound fp8 mode under pp; see the pp guard below). Dense
+    layers only (MoE experts stay bf16).
+
+    Returns (hidden states [B,S,D] — pre-final-norm, aux losses).
+    """
+    body = _remat_body(cfg, mesh, attn_fn, tag_attn_out, fp8_layers)
+
     zero_aux = {
         "moe_lb_loss": jnp.zeros([], jnp.float32),
         "moe_z_loss": jnp.zeros([], jnp.float32),
@@ -669,6 +838,7 @@ def run_trunk(
     if pp > 1:
         from dlrover_tpu.parallel.pipeline import pipeline_apply
 
+        _train_only_guard(cfg, "the pipeline")
         # router aux losses are not collected across pipeline stages
         # (fp8="current" rides inside the body partial when set)
         aux = zero_aux
@@ -712,63 +882,79 @@ def run_trunk(
         # a call-time kwarg (tracers through jax.checkpoint, like rng)
         # so the remat-wrapped body needn't close over them.
         rope = (
-            _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+            _rope_tables(positions, cfg.rope_dim, cfg.rope_theta)
             if cfg.pos == "rope"
             else None
         )
-        if fp8_layers is not None and fp8_layers != "current":
-
-            def scan_fn8(carry, inp):
-                layer, fp8, idx = inp
-                r = (
-                    jax.random.fold_in(rng, idx)
-                    if rng is not None
-                    else None
-                )
-                out, aux = body(
-                    carry, layer, positions, rng=r, fp8=fp8, rope=rope
-                )
-                return out, aux
-
-            x, auxs = jax.lax.scan(
-                scan_fn8, x, (layers, fp8_layers, jnp.arange(n_layers))
+        first = 0
+        if dense_layers is not None:
+            # the prefix's aux is zeros: a dense layer routes nothing
+            first = jax.tree.leaves(dense_layers)[0].shape[0]
+            x, _ = _run_stack(
+                body, x, dense_layers, positions, rng, rope, 0, first
             )
-        elif shd.unroll_layer_scans():
-            # hybrid-mesh update-sharding region: the stacked layer
-            # params are auto-axis-sharded (fsdp/tp) and the 0.4.x
-            # partitioner check-fails on a scan over them inside a
-            # partial-manual region — unroll the layer loop instead
-            aux_list = []
-            for i in range(n_layers):
-                layer = jax.tree.map(lambda t: t[i], layers)
-                r = jax.random.fold_in(rng, i) if rng is not None else None
-                x, a_i = body(x, layer, positions, rng=r, rope=rope)
-                aux_list.append(a_i)
-            auxs = jax.tree.map(
-                lambda *ls: jnp.stack(ls), *aux_list
-            )
-        else:
-            # fp8="current" (when set) is baked into the body partial
-
-            def scan_fn(carry, inp):
-                layer, idx = inp
-                r = (
-                    jax.random.fold_in(rng, idx)
-                    if rng is not None
-                    else None
-                )
-                out, aux = body(carry, layer, positions, rng=r, rope=rope)
-                return out, aux
-
-            x, auxs = jax.lax.scan(
-                scan_fn, x, (layers, jnp.arange(n_layers))
-            )
+        x, auxs = _run_stack(
+            body, x, layers, positions, rng, rope, first, n_layers,
+            fp8_layers=fp8_layers,
+        )
         # the expert ids are per layer, not a sum: stacked [L, B, S, k]
         choices = auxs.pop("moe_choices", None)
         aux = jax.tree.map(lambda a: a.sum(), auxs)
         if choices is not None:
             aux["moe_choices"] = choices
     return x, aux
+
+
+def _run_stack(body, x, layers, positions, rng, rope, first, n_layers,
+               fp8_layers=None):
+    """``body`` over one stack of ``n_layers`` equal layers, the
+    ``first``-th of the trunk onward (the index folds into ``rng``).
+    Returns (x, every layer's aux stacked on axis 0)."""
+    index = jnp.arange(first, first + n_layers)
+    if fp8_layers is not None and fp8_layers != "current":
+
+        def scan_fn8(carry, inp):
+            layer, fp8, idx = inp
+            r = (
+                jax.random.fold_in(rng, idx)
+                if rng is not None
+                else None
+            )
+            out, aux = body(
+                carry, layer, positions, rng=r, fp8=fp8, rope=rope
+            )
+            return out, aux
+
+        return jax.lax.scan(scan_fn8, x, (layers, fp8_layers, index))
+    if shd.unroll_layer_scans():
+        # hybrid-mesh update-sharding region: the stacked layer
+        # params are auto-axis-sharded (fsdp/tp) and the 0.4.x
+        # partitioner check-fails on a scan over them inside a
+        # partial-manual region — unroll the layer loop instead
+        aux_list = []
+        for i in range(n_layers):
+            layer = jax.tree.map(lambda t: t[i], layers)
+            r = (
+                jax.random.fold_in(rng, first + i)
+                if rng is not None
+                else None
+            )
+            x, a_i = body(x, layer, positions, rng=r, rope=rope)
+            aux_list.append(a_i)
+        return x, jax.tree.map(lambda *ls: jnp.stack(ls), *aux_list)
+
+    # fp8="current" (when set) is baked into the body partial
+    def scan_fn(carry, inp):
+        layer, idx = inp
+        r = (
+            jax.random.fold_in(rng, idx)
+            if rng is not None
+            else None
+        )
+        out, aux = body(carry, layer, positions, rng=r, rope=rope)
+        return out, aux
+
+    return jax.lax.scan(scan_fn, x, (layers, index))
 
 
 def init_fp8_states(cfg: ModelConfig):
@@ -842,16 +1028,7 @@ def forward(
     # named scopes mark the step's layers in every HLO op_name, so a
     # device trace can be split by layer (observability/runtime_timer)
     with jax.named_scope("embed"):
-        if _embed_lookup_hostile(
-            mesh, params["embed"]["tokens"].shape, tokens.shape
-        ):
-            x = _vocab_parallel_embed(
-                params["embed"]["tokens"], tokens, mesh
-            ).astype(dt)
-        else:
-            x = jnp.take(
-                params["embed"]["tokens"], tokens, axis=0
-            ).astype(dt)
+        x = _embed_tokens(params, tokens, mesh, dt)
         if cfg.pos == "learned":
             x = x + jnp.take(
                 params["pos_embed"]["table"], positions, axis=0
@@ -878,6 +1055,12 @@ def forward(
             "qk_norm takes its statistic over all heads of the q and k "
             "projections, and tp shards the heads axis: run this model "
             "with tp=1 (dp/fsdp/sp/ep meshes are fine)"
+        )
+
+    if fp8_states is not None and cfg.train_only:
+        raise ValueError(
+            f"fp8 states are stacked for one kind of plain-attention "
+            f"layer; {cfg.name} has {cfg.train_only}"
         )
 
     if cfg.prefix_lm and prefix_len is None:
@@ -956,7 +1139,14 @@ def forward(
         rng=rng,
         tag_attn_out=(attn_impl != "flash"),
         fp8_layers=fp8_states,
+        dense_layers=params.get("dense_layers"),
     )
+    if cfg.n_mtp_module and return_aux:
+        # the module reads the trunk's output BEFORE the final norm
+        aux = _mtp_module(
+            params, x, tokens, positions, cfg, mesh, attn_fn, rng,
+            attn_impl != "flash", aux,
+        )
 
     with jax.named_scope("head_loss"):
         x = _norm_block(x, params["final_norm"], cfg)
@@ -970,6 +1160,60 @@ def forward(
         if head_scale != 1.0:
             logits = logits * head_scale
     return (logits, aux) if return_aux else logits
+
+
+def next_tokens(tokens: jax.Array) -> jax.Array:
+    """``tokens`` [B, S] one place on: position i holds t_{i+1}. The last
+    position has no successor among the tokens and repeats
+    ``tokens[:, -1]``; whoever uses it masks that position out."""
+    return jnp.concatenate([tokens[:, 1:], tokens[:, -1:]], axis=1)
+
+
+def _mtp_module(
+    params, h, tokens, positions, cfg: ModelConfig, mesh, attn_fn, rng,
+    tag_attn_out, aux,
+):
+    """The multi-token-prediction module (DeepSeek-V3 §2.2; the layout
+    of ``glm4_moe_lite``'s ``num_nextn_predict_layers`` weights):
+
+        h'_i = W_eh [norm(emb(t_{i+1})) ‖ norm(h_i)]
+        one block of the trunk's routed kind
+
+    with ``h`` the trunk's output before the final norm, the SHARED
+    token table, and no position table added (the block has rope). The
+    block's output goes through the module's own norm
+    (``shared_head.norm``) and the shared head in ``_loss_from_head``,
+    against t_{i+2}. Returns ``aux`` with ``mtp_features`` [B, S, D]
+    added, the block's router terms summed in and its expert ids as the
+    last row of ``moe_choices``."""
+    m = params["mtp"]
+    with jax.named_scope("mtp"):
+        dt = h.dtype
+        e = _embed_tokens(params, next_tokens(tokens), mesh, dt)
+        z = jnp.concatenate(
+            [_norm_block(e, m["enorm"], cfg), _norm_block(h, m["hnorm"], cfg)],
+            axis=-1,
+        ) @ m["eh_proj"].astype(dt)
+        if mesh is not None:
+            z = shd.constrain(z, mesh, "batch", "seq", None)
+        body = _remat_body(cfg, mesh, attn_fn, tag_attn_out, None)
+        rope = (
+            _rope_tables(positions, cfg.rope_dim, cfg.rope_theta)
+            if cfg.pos == "rope"
+            else None
+        )
+        r = jax.random.fold_in(rng, cfg.n_layer) if rng is not None else None
+        z, block_aux = body(z, m["block"], positions, rng=r, rope=rope)
+    aux = dict(aux)
+    choices = block_aux.pop("moe_choices", None)
+    for name, value in block_aux.items():
+        aux[name] = aux[name] + value
+    if choices is not None:
+        aux["moe_choices"] = jnp.concatenate(
+            [aux["moe_choices"], choices[None]], axis=0
+        )
+    aux["mtp_features"] = z
+    return aux
 
 
 def head_weight_scale(params: Params, cfg: ModelConfig):
@@ -1048,13 +1292,10 @@ def loss_fn(
         )
 
 
-def _loss_from_head(
-    params, batch, cfg: ModelConfig, z_loss, denom, moe_aux,
-    feats=None, logits=None,
-):
-    """The head and the loss of ``loss_fn``: fused linear
-    cross-entropy over ``feats``, or plain log-softmax over ``logits``."""
-    targets = batch["targets"]
+def _token_nll(params, cfg: ModelConfig, targets, feats=None, logits=None):
+    """(log-partition, target logit, argmax) per position through the
+    output head: fused linear cross-entropy over ``feats``, or plain
+    log-softmax over ``logits``."""
     if feats is not None:
         from dlrover_tpu.ops.fused_ce import fused_linear_ce
 
@@ -1062,15 +1303,22 @@ def _loss_from_head(
         bv = min(
             cfg.ce_block_v, (cfg.vocab_size + 127) // 128 * 128
         )
-        logz, tgt_logit, amax = fused_linear_ce(
-            feats, w_out, targets, head_scale, bv
-        )
-    else:
-        logz = jax.nn.logsumexp(logits, axis=-1)
-        tgt_logit = jnp.take_along_axis(
-            logits, targets[..., None], axis=-1
-        )[..., 0]
-        amax = jnp.argmax(logits, -1)
+        return fused_linear_ce(feats, w_out, targets, head_scale, bv)
+    logz = jax.nn.logsumexp(logits, axis=-1)
+    tgt_logit = jnp.take_along_axis(
+        logits, targets[..., None], axis=-1
+    )[..., 0]
+    return logz, tgt_logit, jnp.argmax(logits, -1)
+
+
+def _loss_from_head(
+    params, batch, cfg: ModelConfig, z_loss, denom, moe_aux,
+    feats=None, logits=None,
+):
+    """The head and the loss of ``loss_fn``: fused linear
+    cross-entropy over ``feats``, or plain log-softmax over ``logits``."""
+    targets = batch["targets"]
+    logz, tgt_logit, amax = _token_nll(params, cfg, targets, feats, logits)
 
     mask = batch.get("mask")
     if mask is None:
@@ -1091,10 +1339,40 @@ def _loss_from_head(
         loss = loss + lb + rz
         metrics["moe_lb_loss"] = lb
         metrics["moe_z_loss"] = rz
+    # run_trunk (and the prediction module) summed these over the
+    # routed blocks; reported as the mean over them
+    blocks = cfg.n_routed_layer + cfg.n_mtp_module
     if "moe_max_load" in moe_aux:
-        # rows of the fullest expert over the mean rows an expert gets,
-        # mean over layers (run_trunk summed them)
-        metrics["moe_max_load"] = moe_aux["moe_max_load"] / cfg.n_layer
+        # rows of the fullest expert over the mean rows an expert gets
+        metrics["moe_max_load"] = moe_aux["moe_max_load"] / blocks
+    if "moe_held_rows" in moe_aux:
+        # rows the experts held here received
+        metrics["moe_held_rows"] = moe_aux["moe_held_rows"] / blocks
+    if "mtp_features" in moe_aux:
+        with jax.named_scope("mtp"):
+            # the module at position i predicts t_{i+2}: the targets one
+            # place on. The last position has none and is masked out
+            mtp_mask = next_tokens(mask).at[:, -1].set(0.0)
+            m_feats = _norm_block(
+                moe_aux["mtp_features"], params["mtp"]["norm"], cfg
+            )
+            m_logits = None
+            if feats is None:
+                w_out, head_scale = head_weight_scale(params, cfg)
+                m_logits = head_scale * jnp.einsum(
+                    "bsd,dv->bsv", m_feats, w_out.astype(m_feats.dtype),
+                    preferred_element_type=jnp.float32,
+                )
+                m_feats = None
+            m_logz, m_tgt, _ = _token_nll(
+                params, cfg, next_tokens(targets), m_feats, m_logits
+            )
+            mtp = cfg.mtp_loss_coef * (
+                ((m_logz - m_tgt) * mtp_mask).sum()
+                / jnp.maximum(mtp_mask.sum(), 1.0)
+            )
+        loss = loss + mtp
+        metrics["mtp_loss"] = mtp
     acc = (amax == targets).astype(jnp.float32) * mask
     metrics["accuracy"] = acc.sum() / denom
     return loss, metrics
@@ -1113,6 +1391,7 @@ def init_kv_cache(
     ``dtype`` defaults to the model compute dtype; the serving tier
     passes an explicit dtype when it gathers reference bf16 buffers
     next to its int8 page pools."""
+    _train_only_guard(cfg, "init_kv_cache")
     dt = jnp.dtype(cfg.dtype if dtype is None else dtype)
     shape = (cfg.n_layer, batch, max_len, cfg.kv_heads, cfg.head_dim)
     return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
@@ -1175,6 +1454,7 @@ def prefill(
 
     Returns (logits [B, P, V] f32, cache with positions [0, P) filled).
     """
+    _train_only_guard(cfg, "prefill")
     if not cfg.causal:
         raise ValueError("prefill requires a causal model")
     if cfg.prefix_lm and prefix_len is None:
@@ -1274,6 +1554,7 @@ def decode_step(
     (a tail query sees all prefix keys AND earlier tail keys — both are
     ≤ pos).
     """
+    _train_only_guard(cfg, "decode_step")
     if not cfg.causal:
         raise ValueError(
             "decode_step requires a causal model; encoder (bidirectional) "
@@ -1448,6 +1729,7 @@ def prefill_chunk(
     Returns (logits [B, C, V] f32, updated cache). Causal-only:
     prefix-LM prompts need the bidirectional masking of ``prefill``.
     """
+    _train_only_guard(cfg, "prefill_chunk")
     if not cfg.causal:
         raise ValueError("prefill_chunk requires a causal model")
     if cfg.prefix_lm:
@@ -1523,6 +1805,7 @@ def prefill_chunk(
 
 
 def _paged_guards(cfg: ModelConfig, fn: str):
+    _train_only_guard(cfg, fn)
     if not cfg.causal:
         raise ValueError(f"{fn} requires a causal model")
     if cfg.prefix_lm:

@@ -138,6 +138,7 @@ def sample(
     full-prefix forward), so near-tie draws can diverge — that is
     float noise, not a cache bug.
     """
+    decoder._train_only_guard(cfg, "sample")
     if not cfg.causal:
         # bidirectional (encoder) models have no autoregressive factorization:
         # the full-prefix path would silently condition on the pad filler

@@ -15,8 +15,9 @@ Backward: FlashAttention-2-style pallas kernels via custom_vjp — a dq pass
 (k-blocks innermost, dq carried in VMEM scratch) and a dk/dv pass (q-blocks
 innermost), both recomputing p from the saved lse; tiles capped by head
 width (BWD_BLOCK=512 for head_dim 64, BWD_BLOCK_WIDE=1024 for head_dim
-≥128 — both measured on v5e; the backward holds ~4 [bq,bk] f32
-transients at whichever cap applies). The ring-attention variant's lse cotangent folds into the
+128, BWD_BLOCK_256 = 1024 × 512 for head_dim 256, where 1024 × 1024
+does not fit VMEM — all measured on v5e; the backward holds ~4 [bq,bk]
+f32 transients at whichever cap applies). The ring-attention variant's lse cotangent folds into the
 per-row delta before the kernels, so the SAME kernels serve it. A
 jnp-level chunked recompute remains as the off-TPU / untileable-shape
 fallback.
@@ -90,7 +91,22 @@ INTERPRET = os.environ.get(
 # capped separately from the forward (see _bwd_rule)
 USE_PALLAS_BWD = True
 BWD_BLOCK = 512        # measured best for head_dim 64 (v5e)
-BWD_BLOCK_WIDE = 1024  # measured best for head_dim >= 128 (v5e)
+BWD_BLOCK_WIDE = 1024  # measured best for head_dim 128 (v5e)
+# head_dim >= 256 (latent attention expanded): (q rows, k rows). 1024 x
+# 1024 needs 17.3 MB of the kernel's 16 MB of VMEM (Mosaic refuses it:
+# tests/test_tpu_compile.py). Of those that fit, swept on a v5e at 2 x
+# 8192 x 20 heads (PR 34; the two backward kernels, ms): 1024 x 512
+# 32.7, 512 x 1024 33.1, 512 x 512 33.4, 1024 x 256 35.7, 256 x 1024
+# 36.1, 256 x 512 38.3, 512 x 256 39.2
+BWD_BLOCK_256 = (1024, 512)
+
+
+def _bwd_caps(head_dim: int):
+    """Largest backward tile (q rows, k rows) for a head width."""
+    if head_dim >= 256:
+        return BWD_BLOCK_256
+    cap = BWD_BLOCK_WIDE if head_dim >= 128 else BWD_BLOCK
+    return cap, cap
 
 
 def _last_visible_k_block(i, block_q, block_k):
@@ -1330,9 +1346,9 @@ def _bwd_rule_lse(causal, scale, block_q, block_k, window, head_pack,
     q, k, v, prefix, offsets, out, lse = residuals
     g_out, g_lse = cot
     # wider heads keep the MXU busier per tile, so bigger tiles win
-    bwd_cap = BWD_BLOCK_WIDE if q.shape[-1] >= 128 else BWD_BLOCK
-    bq = _fit_block(q.shape[1], min(block_q, bwd_cap))
-    bk = _fit_block(k.shape[1], min(block_k, bwd_cap))
+    cap_q, cap_k = _bwd_caps(q.shape[-1])
+    bq = _fit_block(q.shape[1], min(block_q, cap_q))
+    bk = _fit_block(k.shape[1], min(block_k, cap_k))
     if (
         USE_PALLAS_BWD
         and pltpu is not None

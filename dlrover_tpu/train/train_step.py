@@ -1188,8 +1188,14 @@ class TrainStepBuilder:
             set_counter("moe.top_k", cfg.routed_top_k)
             set_counter(
                 "moe.rows_per_step",
-                batch["tokens"].size * cfg.routed_top_k * cfg.n_layer,
+                batch["tokens"].size * cfg.routed_top_k
+                * (cfg.n_routed_layer + cfg.n_mtp_module),
             )
+            set_counter("moe.experts_held", cfg.experts_here)
+        if cfg.latent_attention:
+            set_counter("attn.latent_rank", cfg.kv_lora_rank)
+        if cfg.n_mtp_module:
+            set_counter("mtp.depth", cfg.n_mtp_module)
         if self.update_sharding:
             return self._sharded_step_fn(state, batch)
         batch = jax.tree.map(

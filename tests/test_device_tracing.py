@@ -268,12 +268,26 @@ SCOPE_CASES = {
     "moe.experts": "ragged-dot-none",
     "mlp": "jit(s)/jvp()/while/body/closed_call/mlp/add",
     "": "jit(s)/moex.sort/moe.Sort/add",  # not the routed layer's
+    # latent attention's own work sits inside ``attn``; its flash call
+    # and output projection stay with ``attn``
+    "attn.latent": (
+        "jit(s)/transpose(jvp())/while/body/closed_call/checkpoint/attn/"
+        "attn.latent/concatenate"
+    ),
+    "attn": "jit(s)/jvp()/while/body/closed_call/attn/flash_fwd/pallas_call",
+    "moe.shared": "jit(s)/jvp()/while/body/closed_call/mlp/moe.shared/dot",
+    # the prediction module: its projection, head and loss; its block
+    # keeps the block's names
+    "mtp": "jit(s)/jvp(head_loss)/mtp/reduce_sum",
 }
+MODULE_BLOCK = "jit(s)/jvp(mtp)/checkpoint/attn/attn.latent/dot_general"
 
 
 @pytest.mark.parametrize("scope", sorted(SCOPE_CASES))
 def test_scope_of_the_routed_layer(scope):
     assert rt.scope_of(SCOPE_CASES[scope]) == scope
+    if scope == "mtp":
+        assert rt.scope_of(MODULE_BLOCK) == "attn.latent"
     if scope == "moe.experts":
         # which pass a name-stack-less kernel belongs to cannot be told
         assert rt.phase_of("%ragged-dot-none.3 = bf16[8] custom-call(%a)",

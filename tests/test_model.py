@@ -47,6 +47,7 @@ BENCHMARK_CONFIGS = {
     "gpt2-xl-zero1-dp4": 1024,
     "mistral-7b-l6": 8192,
     "olmoe-1b-7b-1chip": 4096,
+    "glm-4.7-flash-ep8-1chip": 8192,
 }
 
 
@@ -55,8 +56,11 @@ def test_flops_per_token_is_the_benchmarks_count(name):
     """One FLOP count: the MFU the trainer reports to the master and the
     benchmark's ``train_step.mfu`` divide the same numerator — multiplied
     parameters only, the head once, the causal and windowed mean span,
-    ``routed_top_k`` experts and the router for a routed layer."""
-    from benchmarks.lib.flops import required_flops_per_token
+    ``routed_top_k`` experts and the router for a routed layer; and for
+    an architecture whose layers differ, the count its reference module
+    states (``flops.resolve``): latent attention's projections, the dense
+    prefix, the held and shared experts, the prediction module."""
+    from benchmarks.lib.flops import resolve
 
     configs = pathlib.Path(__file__).parent.parent / "benchmarks" / "configs"
     assert sorted(p.stem for p in configs.glob("*.json")) == sorted(
@@ -68,7 +72,7 @@ def test_flops_per_token_is_the_benchmarks_count(name):
     )
     for seq in (BENCHMARK_CONFIGS[name], 2 * BENCHMARK_CONFIGS[name], 512):
         assert cfg.flops_per_token(seq) == pytest.approx(
-            required_flops_per_token(config["sizes"], seq), rel=1e-9
+            resolve(config, seq), rel=1e-9
         )
     # bidirectional attention sees every key, a causal one half on average
     both_ways = dataclasses.replace(cfg, causal=False, attn_window=0)
@@ -76,7 +80,8 @@ def test_flops_per_token_is_the_benchmarks_count(name):
     assert both_ways.flops_per_token(1024) - causal.flops_per_token(
         1024
     ) == pytest.approx(
-        12.0 * cfg.n_layer * cfg.n_head * cfg.head_dim * (1024 - 512.5)
+        12.0 * (cfg.n_layer + cfg.n_mtp_module) * cfg.n_head * cfg.head_dim
+        * (1024 - 512.5)
     )
 
 

@@ -130,6 +130,12 @@ CASES = {
     "flash-bwd-25x64-packed": (_flash(25, 64, grad=True), 3),
     "flash-fwd-16x128": (_flash(16, 128, grad=False), 1),
     "flash-bwd-16x128": (_flash(16, 128, grad=True), 3),
+    # latent attention expanded (GLM-4.7-Flash): 20 heads of 256
+    "flash-fwd-20x256": (_flash(20, 256, grad=False), 1),
+    "flash-bwd-20x256": (_flash(20, 256, grad=True), 3),
+    # its two rank norms
+    "norm-bwd-d768": (_norm(768, grad=True, residual=False), 1),
+    "norm-bwd-d512": (_norm(512, grad=True, residual=False), 1),
     "norm-fwd-d1600": (_norm(1600, grad=False, residual=False), 1),
     "norm-bwd-d1600": (_norm(1600, grad=True, residual=False), 1),
     "norm-residual-bwd-d1600": (_norm(1600, grad=True, residual=True), 2),
@@ -236,6 +242,24 @@ STEP_CASES = {
         scopes={"embed", "attn", "mlp", "head_loss", "optimizer",
                 "moe.route", "moe.sort", "moe.experts", "moe.combine"},
     ),
+    # GLM-4.7-Flash's published widths, 1 dense + 1 routed layer + the
+    # prediction module, 8 of 64 experts held: latent attention through
+    # the unpacked flash kernels at head size 256 (whose backward tile
+    # is cut to fit VMEM), the rank norms as norm calls, the shared
+    # expert and the module under scopes of their own
+    "glm-like": dict(
+        model="glm-4.7-flash",
+        overrides=dict(n_layer=2, n_experts_held=8, vocab_size=19360,
+                       max_seq=8192, remat="full", param_dtype="bfloat16"),
+        optimizer=dict(state_dtype="bfloat16"), comm=None, chips=1,
+        batch=(2, 8192),
+        kernels={"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+                 "norm_fwd", "norm_bwd", "ragged-dot-none",
+                 "ragged-dot-metadata"},
+        scopes={"embed", "attn", "attn.latent", "mlp", "head_loss", "mtp",
+                "optimizer", "moe.route", "moe.sort", "moe.experts",
+                "moe.combine", "moe.shared"},
+    ),
     # the dp=4 ZeRO-1 recipe: f32 parameters, tied head
     "zero1-dp4": dict(
         model="gpt2-1.5b",
@@ -261,6 +285,7 @@ STEP_CASES = {
 
 
 _STEP_TEXT = {}
+_STEP_MEMORY = {}  # case -> the compiled step's memory_analysis()
 
 
 def _compiled_step(topo, case):
@@ -302,8 +327,9 @@ def _compiled_step(topo, case):
         for k in ("tokens", "targets")
     }
     tracing._counters.clear()
-    text = builder.build().lower(state, batch).compile().as_text()
-    _STEP_TEXT[case] = builder, text, dict(tracing.counters())
+    compiled = builder.build().lower(state, batch).compile()
+    _STEP_MEMORY[case] = compiled.memory_analysis()
+    _STEP_TEXT[case] = builder, compiled.as_text(), dict(tracing.counters())
     return _STEP_TEXT[case]
 
 
@@ -363,6 +389,18 @@ def test_step_names_its_kernels_and_phases(topo, case):
             and runtime_timer.scope_of(op_name) == "moe.experts"
         ]
         assert len(grouped) == 12  # a layer: 3 forward, 3 recomputed, 6 back
+    if spec["model"] == "glm-4.7-flash":
+        assert counters["moe.experts"] == 64 and counters["moe.top_k"] == 4
+        assert counters["moe.experts_held"] == 8
+        assert counters["attn.latent_rank"] == 512
+        assert counters["mtp.depth"] == 1
+        # one routed layer and the module's block
+        assert counters["moe.rows_per_step"] == 2 * 8192 * 4 * 2
+        # the flash kernels run at head size 256, all three layers
+        flash = [ln for ln in kernel_lines if "%flash_" in ln]
+        assert len(flash) == 3 * 4 and all(
+            "bf16[40,8192,256]" in ln for ln in flash
+        )
     for line in kernel_lines:
         name = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", line).group(1)
         kernel, phase = name.split(".")[0], runtime_timer.phase_of(
@@ -429,3 +467,59 @@ def test_routed_layer_scatters_no_rows(topo):
     for dtype, shape, scope, line in scatters:
         if scope.startswith("moe.") and len(shape) > 1:
             assert dtype[0] in "su", line[:200]
+
+
+def test_backward_tile_of_1024_is_refused_at_head_size_256(chip, monkeypatch):
+    """Why ``BWD_BLOCK_256`` is not ``BWD_BLOCK_WIDE``: at 256 channels a
+    1024 x 1024 backward tile needs 17.3 MB of the 16 MB of VMEM a kernel
+    may use, and Mosaic refuses it (interpret mode takes it). The model's
+    own forward blocks (``attn_block_q/k`` 1024) compile with the cut."""
+    q = jax.ShapeDtypeStruct((2, 8192, 20, 256), BF16, sharding=chip)
+
+    def loss(q, k, v):
+        return pallas_attention.flash_attention(
+            q, k, v, causal=True, block_q=1024, block_k=1024
+        ).astype(F32).sum()
+
+    def compiled():
+        # a fresh function each time: the tile is read while tracing
+        fn = jax.grad(lambda q, k, v: loss(q, k, v), argnums=(0, 1, 2))
+        return jax.jit(fn).lower(q, q, q).compile()
+
+    assert compiled().as_text().count("tpu_custom_call") == 3
+    monkeypatch.setattr(pallas_attention, "BWD_BLOCK_256", (1024, 1024))
+    with pytest.raises(Exception, match="vmem"):
+        compiled()
+
+
+def test_glm_cell_fits_the_chip_at_its_depth(topo):
+    """The benchmark's GLM-4.7-Flash configuration as it is run (1 dense
+    + 8 routed layers + the module, 2 x 8192 tokens): the step the
+    chip's compiler lays out needs under the 16.9 GB the runtime gives
+    and over 12 GB, so the cell fills the chip (15.30 GB by this count;
+    16.52 at one more routed layer, 17.74 at two more; the chip itself
+    reads 13.09 at this depth)."""
+    import json
+    import pathlib
+
+    path = pathlib.Path(__file__).parent.parent / "benchmarks" / "configs"
+    config = json.loads((path / "glm-4.7-flash-ep8-1chip.json").read_text())
+    STEP_CASES["glm-cell"] = dict(
+        model=config["program"]["model"],
+        overrides=config["program"]["overrides"],
+        optimizer=dict(state_dtype="bfloat16"), comm=None, chips=1,
+        batch=(2, 8192),
+    )
+    try:
+        builder, _, _ = _compiled_step(topo, "glm-cell")
+    finally:
+        del STEP_CASES["glm-cell"]
+    stats = _STEP_MEMORY["glm-cell"]
+    need = (
+        stats.argument_size_in_bytes + stats.output_size_in_bytes
+        - stats.alias_size_in_bytes + stats.temp_size_in_bytes
+    )
+    assert 12e9 < need < 16.9e9, need
+    assert stats.argument_size_in_bytes == pytest.approx(
+        6 * 1_133_834_752, rel=1e-3  # bf16 parameters and two moments
+    )
