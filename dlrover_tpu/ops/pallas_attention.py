@@ -17,10 +17,26 @@ innermost), both recomputing p from the saved lse; tiles capped by head
 width (BWD_BLOCK=512 for head_dim 64, BWD_BLOCK_WIDE=1024 for head_dim
 128, BWD_BLOCK_256 = 1024 × 512 for head_dim 256, where 1024 × 1024
 does not fit VMEM — all measured on v5e; the backward holds ~4 [bq,bk]
-f32 transients at whichever cap applies). The ring-attention variant's lse cotangent folds into the
-per-row delta before the kernels, so the SAME kernels serve it. A
-jnp-level chunked recompute remains as the off-TPU / untileable-shape
-fallback.
+f32 transients at whichever cap applies). A jnp-level chunked recompute
+remains as the off-TPU / untileable-shape fallback.
+
+The per-row statistics keep one format from the kernel that makes them
+to the kernels that read them — f32 tiles ``[B·slabs, S, 8]``, head p of
+a slab in lane p of its row's tile (unpacked: ``[B·H, S, 8]``, a slab is
+one head) — and XLA makes no pass over them. ``lse`` is the forward
+kernel's output as it wrote it: the residual (``flash_lse``) and what
+both backward kernels read through the same BlockSpec. ``delta`` = Σ_d dO·out is made
+in the dq kernel, once a q block at its first key step, from the dO
+block it holds and the matching ``out`` block (f32 products of the
+stored values, f32 row sum per head), and is its second output, which
+the dk/dv kernel reads. Only a caller that asked for lse
+(``flash_attention_with_lse``: ring attention's softmax merge) gets the
+``[B, H, S]`` view (``_stat_rows``), and only its lse cotangent comes in
+from XLA (``_stat_tiles``) — into the dq kernel, which subtracts it from
+delta, so the SAME kernels serve the ring. The price of the format: a
+tile array is 128 lanes wide in memory (54 MB a layer at GPT-2 XL for
+0.85 MB of numbers), so a remat policy that saves ``flash_lse`` saves
+that.
 
 Both paths support GLM-style prefix-LM masking (per-batch prefix scalar in
 SMEM) and GQA (K/V shared across head groups via BlockSpec index maps, no
@@ -40,7 +56,8 @@ lanes (a 32-bit AND, ``_and_lanes``): ``q @ k_pᵀ`` contracts 128 lanes of
 which ``d`` are live — the MXU passes of a ``d``-deep contraction, the
 128-lane quantum makes them cost the same — and ``p_p @ v_p`` lands on
 head p's lanes of ONE ``[block_q, 128]`` accumulator, so every store is
-lane-dense. Softmax statistics, lse and delta stay per head. What packing
+lane-dense. Softmax statistics, lse and delta stay per head (delta: the
+row sum of dO·out over the head's own lanes). What packing
 buys is everything around the matmuls: no relayout pass over q, k, v, out
 or their cotangents, the causal/prefix/window mask and its iotas computed
 once per program and shared by the slab's heads, pack× fewer grid
@@ -310,6 +327,7 @@ def _fwd_kernel(
 
 
 LANES = 128  # a slab: the lane width of one vector register / MXU tile
+STAT_LANES = 8  # a row-statistics tile: head p of a slab in lane p
 
 
 def _and_lanes(x, bits):
@@ -342,14 +360,29 @@ def _slab_lanes(slab, heads, d, pack):
     return head_bits, real_bits
 
 
-def _spread_heads(cols, d):
-    """Per-head columns (``pack`` arrays [rows, 1]) → [rows, 128] with
-    head p's value on head p's lanes."""
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+def _spread_heads(cols, d, lanes=LANES):
+    """Per-head columns (``pack`` arrays [rows, 1]) → [rows, lanes] with
+    head p's value on head p's ``d`` lanes (the last head's on the rest).
+    ``d=1, lanes=STAT_LANES``: a row-statistics tile."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
     out = cols[-1]
     for p in range(len(cols) - 2, -1, -1):
         out = jnp.where(lane < (p + 1) * d, cols[p], out)
     return out
+
+
+def _delta_cols(do, out, head_bits=(None,)):
+    """delta = Σ_d dO·out per row, a [rows, 1] f32 column for each entry
+    of ``head_bits``: f32 products of the stored values, summed over the
+    lanes that entry keeps (one head of a slab; None: all of them)."""
+    prod = do.astype(jnp.float32) * out.astype(jnp.float32)
+    return [
+        jnp.sum(
+            prod if bits is None else _and_lanes(prod, bits),
+            axis=1, keepdims=True,
+        )
+        for bits in head_bits
+    ]
 
 
 def _fwd_kernel_packed(
@@ -359,7 +392,7 @@ def _fwd_kernel_packed(
     prefix_ref,  # [B, 1] int32 in SMEM (None w/o prefix)
     offs_ref,  # [1, 2] int32 in SMEM (None w/o offsets)
     o_ref,  # [1, block_q, 128]
-    lse_ref,  # [1, pack, block_q, 8] f32
+    lse_ref,  # [1, block_q, 8] f32: head p in lane p
     m_scratch,  # [pack, block_q, 128] f32
     l_scratch,  # [pack, block_q, 128] f32
     acc_scratch,  # [block_q, 128] f32: every head on its own lanes
@@ -428,14 +461,13 @@ def _fwd_kernel_packed(
 
     @pl.when(ki == nk - 1)
     def _finish():
-        ls = []
+        ls, lses = [], []
         for p in range(pack):
             l = l_scratch[p, :, :1]
             l = jnp.where(l == 0.0, 1.0, l)
-            lse_ref[0, p] = jnp.broadcast_to(
-                m_scratch[p, :, :1] + jnp.log(l), lse_ref.shape[2:]
-            )
+            lses.append(m_scratch[p, :, :1] + jnp.log(l))
             ls.append(l)
+        lse_ref[0] = _spread_heads(lses, 1, STAT_LANES)
         o_ref[0] = (acc_scratch[:] / _spread_heads(ls, d)).astype(
             o_ref.dtype
         )
@@ -457,9 +489,12 @@ def _insert_none_args(kernel, idxs):
 
 
 def _bwd_dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, prefix_ref,
-    offs_ref,
+    q_ref, k_ref, v_ref, do_ref, out_ref,
+    lse_ref,  # [1, block_q, 8] f32, as the forward wrote it
+    glse_ref,  # the lse cotangent in the same tiles (None: ring only)
+    prefix_ref, offs_ref,
     dq_ref,
+    delta_ref,  # [1, block_q, 8] f32: written here, read by the dkv pass
     acc_scratch,  # [block_q, d] f32
     *,
     causal: bool,
@@ -473,7 +508,10 @@ def _bwd_dq_kernel(
 ):
     """dq = Σ_k ds @ K with ds = p·(dp − delta)·scale, p recomputed from
     the saved lse — FlashAttention-2 backward, k-blocks innermost so dq
-    stays resident in VMEM scratch."""
+    stays resident in VMEM scratch. ``delta`` = Σ_d dO·out (less the lse
+    cotangent) is made here, once a q block at its first key step, from
+    the dO block the pass holds anyway, and leaves as a second output in
+    lse's tile format."""
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -484,6 +522,11 @@ def _bwd_dq_kernel(
     @pl.when(ki == 0)
     def _init():
         acc_scratch[:] = jnp.zeros_like(acc_scratch)
+        (delta,) = _delta_cols(do_ref[0], out_ref[0])
+        if glse_ref is not None:
+            # total ds = p·(dp − delta + g_lse): subtract here once
+            delta = delta - glse_ref[0][:, :1]
+        delta_ref[0] = jnp.broadcast_to(delta, delta_ref.shape[1:])
 
     q_start = qi * block_q + (offs_ref[0, 0] if has_offsets else 0)
     k_start = ki * block_k + (offs_ref[0, 1] if has_offsets else 0)
@@ -572,10 +615,12 @@ def _bwd_dkv_kernel(
 
 
 def _bwd_dq_kernel_packed(
-    q_ref, k_ref, v_ref, do_ref,  # [1, block, 128] slabs of [B, S, H·D]
-    lse_ref, delta_ref,  # [1, pack, block_q, 8] f32
+    q_ref, k_ref, v_ref, do_ref, out_ref,  # [1, block, 128] slabs
+    lse_ref,  # [1, block_q, 8] f32, as the forward wrote it
+    glse_ref,  # the lse cotangent in the same tiles (None: ring only)
     prefix_ref, offs_ref,
     dq_ref,  # [1, block_q, 128]
+    delta_ref,  # [1, block_q, 8] f32: written here, head p in lane p
     acc_scratch,  # [block_q, 128] f32
     *,
     causal: bool,
@@ -592,7 +637,9 @@ def _bwd_dq_kernel_packed(
     recomputed-p backward per head under ONE shared mask, heads kept
     apart by zeroing k and v outside head p's lanes (see
     _fwd_kernel_packed); ``ds_p @ k_p`` is then nonzero on head p's
-    lanes only and the heads' dq sum into one lane-dense tile."""
+    lanes only and the heads' dq sum into one lane-dense tile. Each
+    head's ``delta`` is the row sum of dO·out over its own lanes (see
+    _bwd_dq_kernel); lanes past H·D are in no head's sum."""
     slab = pl.program_id(1)
     qi = pl.program_id(2)
     ki = pl.program_id(3)
@@ -603,6 +650,14 @@ def _bwd_dq_kernel_packed(
     @pl.when(ki == 0)
     def _init():
         acc_scratch[:] = jnp.zeros_like(acc_scratch)
+        head_bits, _ = _slab_lanes(slab, heads, d, pack)
+        deltas = _delta_cols(do_ref[0], out_ref[0], head_bits)
+        if glse_ref is not None:
+            deltas = [
+                delta - glse_ref[0][:, p:p + 1]
+                for p, delta in enumerate(deltas)
+            ]
+        delta_ref[0] = _spread_heads(deltas, 1, STAT_LANES)
 
     q_start = qi * block_q + (offs_ref[0, 0] if has_offsets else 0)
     k_start = ki * block_k + (offs_ref[0, 1] if has_offsets else 0)
@@ -628,7 +683,7 @@ def _bwd_dq_kernel_packed(
             )
             _, ds = _p_and_ds(
                 s, do, _and_lanes(v, head_bits[p]),
-                lse_ref[0, p][:, :1], delta_ref[0, p][:, :1], scale,
+                lse_ref[0][:, p:p + 1], delta_ref[0][:, p:p + 1], scale,
             )
             dq_p = jax.lax.dot_general(
                 ds.astype(k.dtype), k_p, (((1,), (0,)), ((), ())),
@@ -644,7 +699,7 @@ def _bwd_dq_kernel_packed(
 
 def _bwd_dkv_kernel_packed(
     q_ref, k_ref, v_ref, do_ref,  # [1, block, 128] slabs of [B, S, H·D]
-    lse_ref, delta_ref,  # [1, pack, block_q, 8] f32
+    lse_ref, delta_ref,  # [1, block_q, 8] f32: head p in lane p
     prefix_ref, offs_ref,
     dk_ref, dv_ref,  # [1, block_k, 128]
     dk_scratch,  # [block_k, 128] f32
@@ -701,7 +756,7 @@ def _bwd_dkv_kernel_packed(
             )
             pr, ds = _p_and_ds(
                 s, do_p, v,
-                lse_ref[0, p][:, :1], delta_ref[0, p][:, :1], scale,
+                lse_ref[0][:, p:p + 1], delta_ref[0][:, p:p + 1], scale,
             )
             dv_p = jax.lax.dot_general(
                 pr.astype(do.dtype), do_p, (((0,), (0,)), ((), ())),
@@ -805,13 +860,24 @@ def _grid_params(interpret, n_grid):
     )
 
 
-def _slab_rows(x, b, h, n_slabs, pack, s):
-    """Per-row f32 statistics [B, H, S] → the [B·slabs, pack, S, 8]
-    tiles the packed kernels read column 0 of (heads past H: zeros)."""
-    x = jnp.pad(x, ((0, 0), (0, n_slabs * pack - h), (0, 0)))
-    return jnp.broadcast_to(
-        x.reshape(b * n_slabs, pack, s)[..., None],
-        (b * n_slabs, pack, s, 8),
+def _stat_rows(tiles, b, h, pack):
+    """``[B, H, S]`` from per-row statistics in the kernels' tiles,
+    ``[B·slabs, S, 8]`` with head p of a slab in lane p (unpacked: a slab
+    is one head): for a caller that asked for lse, and the jnp fallback."""
+    s, pack = tiles.shape[1], max(int(pack), 1)
+    heads = tiles[..., :pack].reshape(b, -1, s, pack).transpose(0, 1, 3, 2)
+    return heads.reshape(b, -1, s)[:, :h]
+
+
+def _stat_tiles(rows, like, pack):
+    """``[B, H, S]`` statistics in the tiles of ``like`` (heads past H:
+    zeros). Only the ring path's lse cotangent takes this way in."""
+    b, h, s = rows.shape
+    n_slabs = like.shape[0] // b
+    rows = jnp.pad(rows, ((0, 0), (0, n_slabs * pack - h), (0, 0)))
+    heads = rows.astype(like.dtype).reshape(b * n_slabs, pack, s)
+    return jnp.pad(
+        heads.transpose(0, 2, 1), ((0, 0), (0, 0), (0, STAT_LANES - pack))
     )
 
 
@@ -820,12 +886,16 @@ def _pallas_backward(q, k, v, out, lse, g, causal, scale,
                      interpret: Optional[bool] = None,
                      g_lse=None, window: int = 0, offsets=None,
                      head_pack: int = 1):
-    """FA2-style pallas backward: returns (dq, dk, dv).
+    """FA2-style pallas backward: returns (dq, dk, dv, delta).
 
     All [B,S,H,D] layouts like the forward; GQA dk/dv are group-summed
-    back to the kv head count. ``g_lse`` [B,H,S] (ring attention's lse
-    cotangent) folds into the per-row delta — ∂lse/∂s_j = p_j, so it
-    enters ds as an additive term and the kernels need no change.
+    back to the kv head count. ``lse`` is the forward kernel's own tile
+    array (``_flash_fwd``), read through the BlockSpec it was written
+    with; ``delta`` = Σ_d dO·out leaves the dq kernel in the same tiles
+    and the dk/dv kernel reads it there (returned for the tests), so no
+    XLA operation passes over the row statistics. ``g_lse`` [B,H,S]
+    (ring attention's lse cotangent) folds into delta in the dq kernel
+    — ∂lse/∂s_j = p_j, so it enters ds as an additive term.
 
     ``head_pack`` > 1 runs the head-packed kernels on 128-lane slabs of
     the ``[B, S, H·D]`` view (MHA with ``head_pack · D == 128`` only).
@@ -840,14 +910,6 @@ def _pallas_backward(q, k, v, out, lse, g, causal, scale,
     assert sq % block_q == 0 and sk % block_k == 0
     nq, nk = sq // block_q, sk // block_k
 
-    # per-row softmax residual of the backward, [B, H, S]
-    delta = jnp.sum(
-        g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
-    ).transpose(0, 2, 1)
-    if g_lse is not None:
-        # total ds = p·(dp − delta + g_lse): subtract here once
-        delta = delta - g_lse.astype(jnp.float32)
-
     common = dict(
         causal=causal,
         scale=scale,
@@ -861,18 +923,27 @@ def _pallas_backward(q, k, v, out, lse, g, causal, scale,
         causal and prefix is None and offsets is None,
         block_q, block_k, nq, window,
     )
+    g = g.astype(q.dtype)
+    glse = () if g_lse is None else (_stat_tiles(g_lse, lse, pack),)
+    stat_struct = _out_struct(lse.shape, lse.dtype, q)
+
+    def dq_kernel(kernel):
+        """(SMEM arrays, their specs, kernel) of a dq pass, whose slots
+        6-8 (lse cotangent, prefix, offsets) are all optional."""
+        extra, extra_specs, kernel = _optional_smem(
+            kernel, prefix, offsets, b, at=7
+        )
+        if g_lse is None:
+            kernel = _insert_none_args(kernel, [6])
+        return extra, extra_specs, kernel
 
     if pack > 1:
-        # the kernels read q, k, v, dO and write dq, dk, dv as column
-        # slabs of the projections' own [B, S, H·D] arrays: the index
-        # map does what a transpose to [B, H, S, D] did. MHA only, so
-        # no GQA index sharing or group-sum.
+        # the kernels read q, k, v, dO, out and write dq, dk, dv as
+        # column slabs of the projections' own [B, S, H·D] arrays: the
+        # index map does what a transpose to [B, H, S, D] did. MHA only,
+        # so no GQA index sharing or group-sum.
         n_slabs = _slab_count(h, hkv, d, pack)
-        operands = (
-            *(_slab_view(x) for x in (q, k, v, g.astype(q.dtype))),
-            _slab_rows(lse, b, h, n_slabs, pack, sq),
-            _slab_rows(delta, b, h, n_slabs, pack, sq),
-        )
+        qs, ks, vs, dos, outs = (_slab_view(x) for x in (q, k, v, g, out))
         common_p = dict(common, heads=h, pack=pack)
 
         def spec(rows, block):  # block index (batch, slab, step i, step j)
@@ -882,31 +953,31 @@ def _pallas_backward(q, k, v, out, lse, g, causal, scale,
 
         def row8_spec(block):
             return pl.BlockSpec(
-                (1, pack, block_q, 8),
-                lambda b_, s_, i, j: (b_ * n_slabs + s_, 0, block(i, j), 0),
+                (1, block_q, 8),
+                lambda b_, s_, i, j: (b_ * n_slabs + s_, block(i, j), 0),
             )
 
         def slab_struct(s_len, dtype):
             return _out_struct((b, s_len, h * d), dtype, q)
 
         first = lambda i, j: i  # noqa: E731 — this grid step's own block
-        extra, extra_specs, kernel = _optional_smem(
-            functools.partial(_bwd_dq_kernel_packed, **common_p),
-            prefix, offsets, b, at=6,
+        extra, extra_specs, kernel = dq_kernel(
+            functools.partial(_bwd_dq_kernel_packed, **common_p)
         )
-        dq = pl.pallas_call(
+        dq, delta = pl.pallas_call(
             kernel,
             grid=(b, n_slabs, nq, nk),
             in_specs=[spec(block_q, first), spec(block_k, k_block),
                       spec(block_k, k_block), spec(block_q, first),
-                      row8_spec(first), row8_spec(first), *extra_specs],
-            out_specs=spec(block_q, first),
-            out_shape=slab_struct(sq, q.dtype),
+                      spec(block_q, first),
+                      *[row8_spec(first)] * (1 + len(glse)), *extra_specs],
+            out_specs=[spec(block_q, first), row8_spec(first)],
+            out_shape=[slab_struct(sq, q.dtype), stat_struct],
             scratch_shapes=[pltpu.VMEM((block_q, LANES), jnp.float32)],
             compiler_params=_grid_params(interpret, 4),
             interpret=interpret,
             name="flash_bwd_dq_packed",
-        )(*operands, *extra)
+        )(qs, ks, vs, dos, outs, lse, *glse, *extra)
 
         extra, extra_specs, kernel = _optional_smem(
             functools.partial(_bwd_dkv_kernel_packed, **common_p),
@@ -928,25 +999,21 @@ def _pallas_backward(q, k, v, out, lse, g, causal, scale,
             compiler_params=_grid_params(interpret, 4),
             interpret=interpret,
             name="flash_bwd_dkv_packed",
-        )(*operands, *extra)
-        return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+        )(qs, ks, vs, dos, lse, delta, *extra)
+        return (
+            dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
+            delta,
+        )
 
-    qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    dot = g.transpose(0, 2, 1, 3).reshape(b * h, sq, d).astype(q.dtype)
+    qt, dot, outt = (
+        x.transpose(0, 2, 1, 3).reshape(b * h, sq, d) for x in (q, g, out)
+    )
     # K/V stay at hkv heads; the BlockSpec index_map shares them across
     # the head group (no jnp.repeat HBM copies). dk/dv are still written
     # per q-head and group-summed after — a transient the accumulate-in-
     # VMEM alternative would trade for an 'arbitrary' grid dim.
     kt = k.transpose(0, 2, 1, 3).reshape(b * hkv, sk, d)
     vt = v.transpose(0, 2, 1, 3).reshape(b * hkv, sk, d)
-    # the residuals broadcast to the 8-lane tile the kernels read
-    # column 0 of
-    delta8 = jnp.broadcast_to(
-        delta.reshape(b * h, sq)[..., None], (b * h, sq, 8)
-    )
-    lse8 = jnp.broadcast_to(
-        lse.reshape(b * h, sq)[..., None], (b * h, sq, 8)
-    )
     # grid-dim-0 entries per batch: the prefix SMEM row index is
     # program_id(0) // n_head
     common["n_head"] = h
@@ -960,22 +1027,21 @@ def _pallas_backward(q, k, v, out, lse, g, causal, scale,
         return (g_ // groups, j, 0)
 
     k_spec = pl.BlockSpec((1, block_k, d), k_idx)
-    extra, extra_specs, kernel = _optional_smem(
-        functools.partial(_bwd_dq_kernel, **common), prefix, offsets, b,
-        at=6,
+    extra, extra_specs, kernel = dq_kernel(
+        functools.partial(_bwd_dq_kernel, **common)
     )
-    dq = pl.pallas_call(
+    dq, delta = pl.pallas_call(
         kernel,
         grid=(b * h, nq, nk),
-        in_specs=[q_spec, k_spec, k_spec, q_spec, row8_spec, row8_spec,
-                  *extra_specs],
-        out_specs=q_spec,
-        out_shape=_out_struct((b * h, sq, d), q.dtype, q),
+        in_specs=[q_spec, k_spec, k_spec, q_spec, q_spec,
+                  *[row8_spec] * (1 + len(glse)), *extra_specs],
+        out_specs=[q_spec, row8_spec],
+        out_shape=[_out_struct((b * h, sq, d), q.dtype, q), stat_struct],
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=_grid_params(interpret, 3),
         interpret=interpret,
         name="flash_bwd_dq",
-    )(qt, kt, vt, dot, lse8, delta8, *extra)
+    )(qt, kt, vt, dot, outt, lse, *glse, *extra)
 
     # dkv grid swaps the roles: k-blocks outer, q-blocks inner
     qkv_spec = pl.BlockSpec(
@@ -1009,7 +1075,7 @@ def _pallas_backward(q, k, v, out, lse, g, causal, scale,
         compiler_params=_grid_params(interpret, 3),
         interpret=interpret,
         name="flash_bwd_dkv",
-    )(qt, kt, vt, dot, lse8, delta8, *extra)
+    )(qt, kt, vt, dot, lse, delta, *extra)
 
     dq = dq.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
     dk = dk.reshape(b, hkv, groups, sk, d).sum(axis=2)
@@ -1018,6 +1084,7 @@ def _pallas_backward(q, k, v, out, lse, g, causal, scale,
         dq.astype(q.dtype),
         dk.transpose(0, 2, 1, 3).astype(k.dtype),
         dv.transpose(0, 2, 1, 3).astype(v.dtype),
+        delta,
     )
 
 
@@ -1035,7 +1102,10 @@ def _flash_fwd(
     offsets: Optional[jax.Array] = None,  # [2] int32 global (q_off, k_off)
     head_pack: int = 1,  # heads per 128-lane slab (MHA, pack · D == 128)
 ):
-    """(out [B, S, H, D], lse [B, H, S] f32)."""
+    """(out [B, S, H, D], lse f32 as the kernel wrote it: 8-lane tiles
+    ``[B·slabs, S, 8]``, head p of a slab in lane p (unpacked: ``[B·H, S,
+    8]``) — what the backward kernels read; ``_stat_rows`` gives the
+    ``[B, H, S]`` view)."""
     interpret = INTERPRET if interpret is None else interpret
     b, sq, h, d = q.shape
     _, sk, hkv, _ = k.shape
@@ -1087,13 +1157,13 @@ def _flash_fwd(
         out_specs = [
             q_spec,
             pl.BlockSpec(
-                (1, pack, block_q, 8),
-                lambda b_, s_, i, j: (b_ * n_slabs + s_, 0, i, 0),
+                (1, block_q, 8),
+                lambda b_, s_, i, j: (b_ * n_slabs + s_, i, 0),
             ),
         ]
         out_shape = [
             _out_struct((b, sq, h * d), q.dtype, q),
-            _out_struct((b * n_slabs, pack, sq, 8), jnp.float32, q),
+            _out_struct((b * n_slabs, sq, 8), jnp.float32, q),
         ]
         scratch_shapes = [
             pltpu.VMEM((pack, block_q, 128), jnp.float32),
@@ -1151,11 +1221,9 @@ def _flash_fwd(
     )(*inputs, *extra)
     if pack > 1:
         out = out.reshape(b, sq, h, d)
-        lse = lse[..., 0].reshape(b, n_slabs * pack, sq)[:, :h]
     else:
         out = out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
-        lse = lse[:, :, 0].reshape(b, h, sq)
-    return out, lse  # lse [B, H, S]
+    return out, lse
 
 
 def _bwd_chunk(sk: int, block_k: int) -> int:
@@ -1317,10 +1385,11 @@ def flash_attention_with_lse(q, k, v, prefix, offsets, causal, scale,
     ``offsets`` [2] int32 (q_off, k_off) shifts the mask rule to global
     positions — ring attention passes the blocks' traced ring offsets so
     window-boundary and prefix-reach blocks run this kernel too."""
-    return _flash_fwd(
+    out, lse = _flash_fwd(
         q, k, v, causal, scale, block_q, block_k, prefix=prefix,
         window=window, offsets=offsets, head_pack=head_pack,
     )
+    return out, _stat_rows(lse, q.shape[0], q.shape[2], head_pack)
 
 
 def _fwd_rule_lse(q, k, v, prefix, offsets, causal, scale, block_q,
@@ -1333,7 +1402,10 @@ def _fwd_rule_lse(q, k, v, prefix, offsets, causal, scale, block_q,
     # checkpoint) pin the residuals instead of re-running the kernel
     out = checkpoint_name(out, "flash_out")
     lse = checkpoint_name(lse, "flash_lse")
-    return (out, lse), (q, k, v, prefix, offsets, out, lse)
+    # the [B, H, S] view is this caller's alone: the residual stays in
+    # the kernels' tiles
+    rows = _stat_rows(lse, q.shape[0], q.shape[2], head_pack)
+    return (out, rows), (q, k, v, prefix, offsets, out, lse)
 
 
 def _bwd_rule_lse(causal, scale, block_q, block_k, window, head_pack,
@@ -1342,28 +1414,34 @@ def _bwd_rule_lse(causal, scale, block_q, block_k, window, head_pack,
     None lse cotangent): FA2 pallas kernels on TPU/interpret with tiles
     capped per head width (BWD_BLOCK / BWD_BLOCK_WIDE — ~4 [bq,bk] f32
     transients per grid step at the applied cap); jnp chunked recompute
-    off-TPU or when the sequence doesn't tile to a lane-aligned block."""
+    off-TPU or when the sequence doesn't tile to a lane-aligned block.
+    The lse residual is the forward kernel's tile array: the kernels
+    take it as it is, the fallback its [B, H, S] view."""
     q, k, v, prefix, offsets, out, lse = residuals
     g_out, g_lse = cot
     # wider heads keep the MXU busier per tile, so bigger tiles win
     cap_q, cap_k = _bwd_caps(q.shape[-1])
     bq = _fit_block(q.shape[1], min(block_q, cap_q))
     bk = _fit_block(k.shape[1], min(block_k, cap_k))
-    if (
+    in_kernel = (
         USE_PALLAS_BWD
         and pltpu is not None
         and (device.on_tpu() or INTERPRET)
         and bq is not None
         and bk is not None
-    ):
-        dq, dk, dv = _pallas_backward(
+    )
+    set_counter("attn.delta_in_kernel", int(in_kernel))
+    if in_kernel:
+        dq, dk, dv, _ = _pallas_backward(
             q, k, v, out, lse, g_out, causal, scale, bq, bk,
             prefix=prefix, g_lse=g_lse, window=window, offsets=offsets,
             head_pack=head_pack,
         )
     else:
         dq, dk, dv = _chunked_backward(
-            q, k, v, out, lse, g_out, causal, scale,
+            q, k, v, out,
+            _stat_rows(lse, q.shape[0], q.shape[2], head_pack), g_out,
+            causal, scale,
             chunk=_bwd_chunk(k.shape[1], block_k),
             g_lse=g_lse,
             prefix=prefix,
@@ -1407,8 +1485,8 @@ def flash_attention(
     ``window`` (causal only) limits each query to the last ``window``
     positions — Mistral-style sliding-window attention.
     ``head_pack`` (module docstring, "narrow-head packing"): 0 runs
-    heads with D < 128 dividing 128, in an MHA layout, on 128-lane slabs
-    of ``128 // D`` heads; 1 keeps them on the unpacked kernels. A slab
+    heads with 16 <= D < 128 dividing 128, in an MHA layout, on 128-lane
+    slabs of ``128 // D`` heads; 1 keeps them on the unpacked kernels. A slab
     is the lane width, so the pack is never another number. GQA always
     runs unpacked — packing would replicate kv DMA per group and the
     kernels keep the simple grid//groups indexing.
@@ -1440,9 +1518,13 @@ def flash_attention(
             prefix_len=prefix_len, window=window,
         )
     # a slab is 128 lanes of the projection's array, so the pack is
-    # never a choice: 128 // D heads, or the unpacked kernels
+    # never a choice: 128 // D heads — at most STAT_LANES, each has its
+    # lane of a statistics tile — or the unpacked kernels
     pack = 1
-    if head_pack != 1 and d < LANES and LANES % d == 0 and h == hkv:
+    if (
+        head_pack != 1 and h == hkv
+        and LANES // STAT_LANES <= d < LANES and LANES % d == 0
+    ):
         pack = LANES // d
     set_counter("attn.heads_per_slab", pack)
     return _flash_attention(

@@ -20,7 +20,7 @@ def _qkv(key, b=2, s=256, h=4, hkv=None, d=64, dtype=jnp.float32):
 
 @pytest.mark.parametrize("causal", [True, False])
 def test_flash_kernel_matches_reference(causal):
-    from dlrover_tpu.ops.pallas_attention import _flash_fwd
+    from dlrover_tpu.ops.pallas_attention import _flash_fwd, _stat_rows
 
     q, k, v = _qkv(jax.random.key(0))
     scale = q.shape[-1] ** -0.5
@@ -28,6 +28,9 @@ def test_flash_kernel_matches_reference(causal):
         q, k, v, causal, scale, block_q=128, block_k=128, interpret=True
     )
     ref = mha_reference(q, k, v, causal=causal, softmax_scale=scale)
+    # the kernel's own tiles, a head's value in lane 0; [B, H, S] by view
+    assert lse.shape == (q.shape[0] * q.shape[2], q.shape[1], 8)
+    lse = _stat_rows(lse, q.shape[0], q.shape[2], 1)
     assert lse.shape == (q.shape[0], q.shape[2], q.shape[1])
     assert np.isfinite(np.asarray(lse)).all()
     np.testing.assert_allclose(
@@ -61,7 +64,7 @@ def test_pallas_backward_matches_reference(causal, hkv):
         q, k, v, causal, scale, block_q=128, block_k=128, interpret=True
     )
     g = jax.random.normal(jax.random.key(3), out.shape)
-    dq, dk, dv = pa._pallas_backward(
+    dq, dk, dv, _ = pa._pallas_backward(
         q, k, v, out, lse, g, causal, scale, 128, 128, interpret=True
     )
     ref = lambda q, k, v: jnp.vdot(  # noqa: E731
@@ -92,11 +95,11 @@ def test_pallas_backward_unequal_seq_lens():
         q, k, v, True, scale, block_q=128, block_k=128, interpret=True
     )
     g = jax.random.normal(ks[3], out.shape)
-    dq, dk, dv = pa._pallas_backward(
+    dq, dk, dv, _ = pa._pallas_backward(
         q, k, v, out, lse, g, True, scale, 128, 128, interpret=True
     )
     rq, rk, rv = pa._chunked_backward(
-        q, k, v, out, lse, g, True, scale, chunk=128
+        q, k, v, out, pa._stat_rows(lse, b, h, 1), g, True, scale, chunk=128
     )
     for a, r in zip((dq, dk, dv), (rq, rk, rv)):
         np.testing.assert_allclose(
@@ -377,6 +380,7 @@ def test_flash_backward_matches_reference(causal):
     from dlrover_tpu.ops.pallas_attention import (
         _chunked_backward,
         _flash_fwd,
+        _stat_rows,
     )
 
     q, k, v = _qkv(jax.random.key(2), b=2, s=256, h=4, d=64)
@@ -384,6 +388,7 @@ def test_flash_backward_matches_reference(causal):
     out, lse = _flash_fwd(
         q, k, v, causal, scale, block_q=128, block_k=128, interpret=True
     )
+    lse = _stat_rows(lse, 2, 4, 1)  # the fallback keeps its [B, H, S] input
     g = jax.random.normal(jax.random.key(3), out.shape, out.dtype)
 
     dq, dk, dv = _chunked_backward(
@@ -404,6 +409,7 @@ def test_flash_backward_gqa():
     from dlrover_tpu.ops.pallas_attention import (
         _chunked_backward,
         _flash_fwd,
+        _stat_rows,
     )
 
     q, k, v = _qkv(jax.random.key(4), b=2, s=128, h=8, hkv=2, d=32)
@@ -411,6 +417,7 @@ def test_flash_backward_gqa():
     out, lse = _flash_fwd(
         q, k, v, True, scale, block_q=128, block_k=128, interpret=True
     )
+    lse = _stat_rows(lse, 2, 8, 1)
     g = jax.random.normal(jax.random.key(5), out.shape, out.dtype)
     dq, dk, dv = _chunked_backward(q, k, v, out, lse, g, True, scale, chunk=64)
 
@@ -430,6 +437,7 @@ def test_chunked_backward_with_lse_cotangent():
     from dlrover_tpu.ops.pallas_attention import (
         _chunked_backward,
         _flash_fwd,
+        _stat_rows,
     )
 
     q, k, v = _qkv(jax.random.key(7), b=2, s=128, h=4, d=32)
@@ -437,6 +445,7 @@ def test_chunked_backward_with_lse_cotangent():
     out, lse = _flash_fwd(
         q, k, v, True, scale, block_q=128, block_k=128, interpret=True
     )
+    lse = _stat_rows(lse, 2, 4, 1)
     g_out = jax.random.normal(jax.random.key(8), out.shape, out.dtype)
     g_lse = jax.random.normal(jax.random.key(9), lse.shape, lse.dtype)
 
@@ -663,7 +672,9 @@ def test_factored_adamw_trains_tiny_model():
 # arrays: 128 // D heads side by side. Interpret mode pads what a block
 # reads beyond an array's bounds with NaN, so a case whose last slab is
 # not full (25 x 64, 5 x 32) also shows that nothing out there reaches a
-# result.
+# result. Both kernel families keep the row statistics in their own
+# tiles and make delta in the dq kernel, so the unpacked ones (head size
+# 128 with GQA, 256) are cases of the same tests.
 
 SLAB_CASES = {
     # GPT-2 XL's head count: 12½ slabs, the half slab in the kernel
@@ -677,25 +688,28 @@ SLAB_CASES = {
     "5x64-window": dict(h=5, d=64, window=48),
     "1x64-causal": dict(h=1, d=64),  # the array narrower than a slab
     "5x64-two-blocks": dict(h=5, d=64, s=256),  # the carried statistics
+    "16x128-gqa": dict(h=16, hkv=4, d=128),  # unpacked: Mistral, OLMoE
+    "2x256-two-blocks": dict(h=2, d=256, s=256),  # ... and GLM's width
 }
 
 
 @pytest.mark.parametrize("case", sorted(SLAB_CASES))
 def test_flash_slab_kernels_match_reference(monkeypatch, case):
-    """Forward and all three gradients of the packed path against
-    ``mha_reference``, through the public entry (head_pack=0: auto)."""
+    """Forward and all three gradients against ``mha_reference``, through
+    the public entry (head_pack=0: auto), with delta from the dq kernel."""
     from dlrover_tpu.observability import tracing
     from dlrover_tpu.ops import pallas_attention as pa
 
     spec = dict(SLAB_CASES[case])
     h, d, s_len = spec.pop("h"), spec.pop("d"), spec.pop("s", 128)
+    hkv = spec.pop("hkv", None)
     causal = spec.pop("causal", True)
     prefix = spec.pop("prefix", None)
     kw = dict(spec)
     if prefix is not None:
         kw["prefix_len"] = jnp.array(prefix, jnp.int32)
     monkeypatch.setattr(pa, "INTERPRET", True)
-    q, k, v = _qkv(jax.random.key(20), s=s_len, h=h, d=d)
+    q, k, v = _qkv(jax.random.key(20), s=s_len, h=h, hkv=hkv, d=d)
     scale = d ** -0.5
     g = jax.random.normal(jax.random.key(25), q.shape)
     f = lambda q, k, v: jnp.vdot(  # noqa: E731
@@ -707,7 +721,10 @@ def test_flash_slab_kernels_match_reference(monkeypatch, case):
         g,
     )
     (lo, go) = jax.value_and_grad(f, argnums=(0, 1, 2))(q, k, v)
-    assert tracing.counters()["attn.heads_per_slab"] == 128 // d
+    assert tracing.counters()["attn.heads_per_slab"] == (
+        1 if hkv else max(128 // d, 1)
+    )
+    assert tracing.counters()["attn.delta_in_kernel"] == 1
     (lr, gr) = jax.value_and_grad(fr, argnums=(0, 1, 2))(q, k, v)
     np.testing.assert_allclose(float(lo), float(lr), rtol=2e-3)
     for a, r in zip(go, gr):
@@ -747,23 +764,82 @@ def test_flash_half_slab_equals_a_zero_head(monkeypatch):
         assert not np.asarray(e[:, :, 25:]).any()
 
 
-@pytest.mark.parametrize("h", [5, 4])
-def test_flash_packed_lse_contract(h):
-    """``flash_attention_with_lse`` keeps its [B, H, S] lse (ring and
-    Ulysses attention merge on it) on the slab path, equal to the
-    unpacked kernel's to rounding, and its cotangent reaches q, k, v."""
+@pytest.mark.parametrize(
+    "h,hkv,d,dtype",
+    [
+        (25, None, 64, jnp.bfloat16),  # the half slab, stored precision
+        (5, None, 32, jnp.float32),  # four heads a slab, one in the last
+        (4, 2, 128, jnp.float32),  # unpacked, GQA
+        (2, None, 256, jnp.bfloat16),
+    ],
+)
+def test_flash_delta_from_the_dq_kernel(h, hkv, d, dtype):
+    """The dq kernel's second output is delta = Σ_d dO·out (f32 products
+    of the stored values, f32 row sum per head) in lse's tile format,
+    ``[B·slabs, S, 8]`` with head p of a slab in lane p; the head past H
+    of a half slab reads zero though the block holds NaN beyond column
+    H·D (the interpreter's padding): those lanes are in no head's sum."""
     from dlrover_tpu.ops import pallas_attention as pa
 
+    b, s_len = 2, 256
+    q, k, v = _qkv(jax.random.key(32), b=b, s=s_len, h=h, hkv=hkv, d=d,
+                   dtype=dtype)
+    pack = 128 // d if d < 128 else 1
+    out, lse = pa._flash_fwd(
+        q, k, v, True, d ** -0.5, 128, 128, interpret=True, head_pack=pack
+    )
+    g = jax.random.normal(jax.random.key(33), out.shape, dtype)
+    *_, delta = pa._pallas_backward(
+        q, k, v, out, lse, g, True, d ** -0.5, 128, 128, interpret=True,
+        head_pack=pack,
+    )
+    n_slabs = -(-h // pack)
+    assert delta.shape == lse.shape == (b * n_slabs, s_len, 8)
+    assert delta.dtype == jnp.float32
+    want = np.asarray(
+        jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    ).transpose(0, 2, 1)
+    got = np.asarray(pa._stat_rows(delta, b, h, pack))
+    assert np.abs(got - want).max() <= 1e-6 * np.abs(want).max()
+    if h % pack:
+        heads = np.asarray(pa._stat_rows(delta, b, n_slabs * pack, pack))
+        assert heads.shape[1] > h and not heads[:, h:].any()
+    # and the way back in, which only the ring's lse cotangent takes
+    back = pa._stat_tiles(jnp.asarray(got), delta, pack)
+    assert back.shape == delta.shape
+    np.testing.assert_array_equal(
+        np.asarray(pa._stat_rows(back, b, h, pack)), got
+    )
+
+
+@pytest.mark.parametrize("h", [5, 4])
+def test_flash_packed_lse_contract(monkeypatch, h):
+    """``flash_attention_with_lse`` keeps its [B, H, S] lse (ring and
+    Ulysses attention merge on it) on both kernel families, the packed
+    equal to the unpacked to rounding, and a nonzero lse cotangent
+    reaches q, k, v through the dq kernel's delta as autodiff of a plain
+    (out, lse) has it."""
+    from dlrover_tpu.ops import pallas_attention as pa
+
+    monkeypatch.setattr(pa, "INTERPRET", True)
     q, k, v = _qkv(jax.random.key(28), s=256, h=h, d=64)
     scale = 64 ** -0.5
 
     def run(pack):
-        return pa._flash_fwd(
-            q, k, v, True, scale, 128, 128, interpret=True, head_pack=pack
+        return lambda q, k, v: pa.flash_attention_with_lse(
+            q, k, v, None, None, True, scale, 128, 128, 0, pack
         )
 
-    (out_p, lse_p), (out_u, lse_u) = run(2), run(1)
+    def plain(q, k, v):
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+        mask = jnp.tril(jnp.ones((q.shape[1], k.shape[1]), bool))
+        s = jnp.where(mask[None, None], s, -1e30)
+        lse = jax.nn.logsumexp(s, axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", jnp.exp(s - lse[..., None]), v), lse
+
+    (out_p, lse_p), (out_u, lse_u) = run(2)(q, k, v), run(1)(q, k, v)
     assert lse_p.shape == (2, h, 256) and lse_p.dtype == jnp.float32
+    assert lse_u.shape == (2, h, 256)
     np.testing.assert_allclose(
         np.asarray(lse_p), np.asarray(lse_u), rtol=1e-5, atol=1e-5
     )
@@ -772,16 +848,21 @@ def test_flash_packed_lse_contract(h):
     )
     g = jax.random.normal(jax.random.key(29), out_p.shape)
     g_lse = jax.random.normal(jax.random.key(30), lse_p.shape)
-    grads = [
-        pa._pallas_backward(
-            q, k, v, out_u, lse_u, g, True, scale, 128, 128,
-            interpret=True, g_lse=g_lse, head_pack=pack,
-        )
-        for pack in (2, 1)
-    ]
-    for a, u in zip(*grads):
+
+    def grads(fn):
+        def loss(q, k, v):
+            out, lse = fn(q, k, v)
+            return jnp.vdot(out, g) + jnp.vdot(lse, g_lse)
+
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    packed, unpacked, want = grads(run(2)), grads(run(1)), grads(plain)
+    for a, u, w in zip(packed, unpacked, want):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(u), rtol=2e-4, atol=2e-4
+        )
+        np.testing.assert_allclose(
+            np.asarray(u), np.asarray(w), rtol=2e-3, atol=2e-3
         )
 
 

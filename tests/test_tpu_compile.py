@@ -216,6 +216,7 @@ STEP_CASES = {
         kernels={"flash_fwd_packed", "flash_bwd_dq_packed",
                  "flash_bwd_dkv_packed", "norm_fwd", "norm_bwd"},
         scopes={"embed", "attn", "mlp", "head_loss", "optimizer"},
+        stat_tiles="f32[104,1024,8]",  # [B·slabs, S, 8]
     ),
     # Mistral widths: head size 128, GQA 32/8, the window live
     "mistral-like": dict(
@@ -227,6 +228,7 @@ STEP_CASES = {
         kernels={"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
                  "norm_fwd", "norm_bwd"},
         scopes={"embed", "attn", "mlp", "head_loss", "optimizer"},
+        stat_tiles="f32[32,2048,8]",  # [B·H, S, 8]
     ),
     # OLMoE's published widths, one layer of 16: 64 experts of width
     # 1024 top-8 through ``lax.ragged_dot`` (the compiler's own grouped
@@ -241,6 +243,7 @@ STEP_CASES = {
                  "ragged-dot-metadata"},
         scopes={"embed", "attn", "mlp", "head_loss", "optimizer",
                 "moe.route", "moe.sort", "moe.experts", "moe.combine"},
+        stat_tiles="f32[32,4096,8]",
     ),
     # GLM-4.7-Flash's published widths, 1 dense + 1 routed layer + the
     # prediction module, 8 of 64 experts held: latent attention through
@@ -259,6 +262,7 @@ STEP_CASES = {
         scopes={"embed", "attn", "attn.latent", "mlp", "head_loss", "mtp",
                 "optimizer", "moe.route", "moe.sort", "moe.experts",
                 "moe.combine", "moe.shared"},
+        stat_tiles="f32[40,8192,8]",
     ),
     # the dp=4 ZeRO-1 recipe: f32 parameters, tied head
     "zero1-dp4": dict(
@@ -271,6 +275,7 @@ STEP_CASES = {
                  "flash_bwd_dkv_packed", "norm_fwd", "norm_bwd"},
         scopes={"embed", "attn", "mlp", "head_loss", "zero.pack",
                 "zero.exchange", "zero.update", "zero.gather"},
+        stat_tiles="f32[104,1024,8]",  # 8 of the 32 sequences a chip
     ),
     # the same under ZeRO-2, two microbatches: an exchange (and the tied
     # head's buckets) inside the accumulation scan. Layout guard only.
@@ -378,6 +383,29 @@ def test_step_names_its_kernels_and_phases(topo, case):
     assert counters["attn.heads_per_slab"] == (2 if packed else 1)
     if packed:
         assert "[8,26,1024,64]" not in text and "[104,2,1024,64]" not in text
+    # the row statistics (lse, delta) stay in the kernels' own tiles from
+    # the kernel that makes them to those that read them: no slice,
+    # broadcast or copy of that shape, and delta comes from the dq
+    # kernel, not from a reduce over 64-lane heads (before PR 35 four
+    # operations a layer, 33 ms of GPT-2 XL's step)
+    assert counters["attn.delta_in_kernel"] == 1
+    made = [
+        line for line in text.splitlines()
+        if re.match(
+            r"\s*(?:ROOT )?%[\w.\-]+ = " + re.escape(spec["stat_tiles"]),
+            line,
+        )
+    ]
+    by_kernel = [
+        re.search(r" get-tuple-element\(%(flash_\w+?)[.\d]*\), index=1", ln)
+        for ln in made
+    ]
+    assert all(by_kernel), [ln[:160] for ln in made]
+    assert {m.group(1) for m in by_kernel} == {
+        k for k in spec["kernels"] if k.startswith(("flash_fwd", "flash_bwd_dq"))
+    }
+    assert "f32[104,2,1024,8]" not in text  # the tiles before PR 35
+    assert not re.search(r"= f32\[8,1024,25\]\S* reduce\(", text)
     if spec["model"] == "olmoe-1b-7b":
         # the routed layer's counters, and its grouped matmuls under
         # their scope (by the kernel's name: it has no name stack)
