@@ -22,6 +22,20 @@ Convention (fixed here so that no later change can move it):
   against 256). ``mean_span`` is the mean number of visible keys per
   query under the causal mask and the sliding window: for s queries,
   window w (0 = none), query i (0-based) sees min(i + 1, w or s) keys.
+- A layer whose queries attend to a SELECTION of at most k of their
+  visible keys (a learned sparse attention: an indexer ranks the keys,
+  the attention runs over the k best) counts the selection, not the
+  span it was taken from: query i sees min(i + 1, k) keys, the window
+  capping it further: ``mean_span(seq, window, topk=k)``. The keys a
+  query never multiplies are work the model does not require, however
+  the program lays its kernel out.
+- A scorer that only RANKS keys (such an indexer: a score product and
+  no value product) counts its projections among the multiplied
+  parameters and heads x (score channels + 0) / 2 x the mean span of
+  the keys it SCORES, which is every visible one (``mean_span(seq,
+  window)``, no ``topk``): it has to look at a key to pass it over. A
+  loss that aligns the scorer with probabilities the layer computes
+  anyway counts nothing more.
 - Recomputation (remat) does not count: it is work the recipe chose,
   not work the model requires.
 - Layers are counted KIND BY KIND, not ``n_layer`` times one: a leading
@@ -81,10 +95,13 @@ import math
 TERMS = ("multiplied_params", "attention_pair_channels")
 
 
-def mean_span(seq: int, window: int = 0) -> float:
-    """Mean number of keys a query sees in a causal sequence of ``seq``
-    tokens under a sliding window of ``window`` keys (0 = no window)."""
+def mean_span(seq: int, window: int = 0, topk: int = 0) -> float:
+    """Mean number of keys a query attends to in a causal sequence of
+    ``seq`` tokens under a sliding window of ``window`` keys (0 = no
+    window) and a selection of at most ``topk`` of them (0 = none)."""
     w = min(window, seq) if window else seq
+    if topk:
+        w = min(w, topk)
     # queries 0..w-1 see 1..w keys; the remaining seq-w see w each
     return (w * (w + 1) / 2 + (seq - w) * w) / seq
 

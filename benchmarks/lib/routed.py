@@ -185,6 +185,87 @@ def routing_stats(router_logits, choices):
     }
 
 
+def logit_errors(logits, ref_logits):
+    """(max |difference| over max |reference|, rms of the difference
+    over rms of the reference): what LOGIT_TOL and LOGIT_RMS_TOL hold."""
+    import jax.numpy as jnp
+
+    diff = logits - ref_logits
+    return (
+        jnp.max(jnp.abs(diff)) / jnp.max(jnp.abs(ref_logits)),
+        jnp.sqrt(jnp.sum(diff * diff) / jnp.sum(ref_logits * ref_logits)),
+    )
+
+
+def routing_summary(stats):
+    """``routing_stats`` reduced to what is judged and recorded."""
+    import jax.numpy as jnp
+
+    regret = stats["regret"]
+    return {
+        "regret_max": jnp.max(regret),
+        "regret_max_by_layer": jnp.max(regret, axis=(1, 2)),
+        # the median of the places that moved; 0 where none did
+        "regret_median_moved": jnp.nan_to_num(
+            jnp.nanmedian(jnp.where(regret > 0, regret, jnp.nan))
+        ),
+        "tokens_moved": jnp.mean(jnp.any(regret > 0, axis=0)),
+        "gap_median": jnp.median(stats["gap"]),
+        "moved_by_layer": stats["moved"],
+    }
+
+
+def routing_record(got):
+    """``routing_summary``, read back, for the ``BENCH reference`` line."""
+    return {
+        "regret_max": float(got["regret_max"]),
+        "regret_max_by_layer": got["regret_max_by_layer"].tolist(),
+        "regret_median_moved": float(got["regret_median_moved"]),
+        "tokens_moved": float(got["tokens_moved"]),
+        "gap_median": float(got["gap_median"]),
+        "moved_by_layer": got["moved_by_layer"].tolist(),
+        "regret_tol": REGRET_TOL,
+    }
+
+
+def forced_checks(got, program, tolerances):
+    """The three checks of ``dense`` against a teacher-forced reference
+    (``got``: its ``ref_loss``, ``logit_err``, ``logit_rms``), and the
+    loss's relative error."""
+    logit_tol, logit_rms_tol, loss_tol = tolerances
+    ce = program.get("ce_loss", program["loss"])
+    loss_err = abs(ce - float(got["ref_loss"])) / abs(float(got["ref_loss"]))
+    return [
+        ("logits_vs_reference", float(got["logit_err"]) <= logit_tol,
+         float(got["logit_err"]), logit_tol),
+        ("logits_rms_vs_reference", float(got["logit_rms"]) <= logit_rms_tol,
+         float(got["logit_rms"]), logit_rms_tol),
+        ("loss_vs_reference", loss_err <= loss_tol, loss_err, loss_tol),
+    ], loss_err
+
+
+def objective_checks(reference, terms, program, loss_tol):
+    """One check for each term of the objective the teacher-forced
+    reference carries: the program's step metric of that name against
+    it, relative, at ``ROUTER_LOSS_TOL``, or at ``loss_tol`` where the
+    module lists the name in ``CROSS_ENTROPY_TERMS``. A term the program
+    does not report fails."""
+    results = []
+    cross_entropies = getattr(reference, "CROSS_ENTROPY_TERMS", ())
+    for name, want in terms.items():
+        tol = loss_tol if name in cross_entropies else ROUTER_LOSS_TOL
+        if name not in program:
+            results.append((
+                name + "_vs_reference", False,
+                "not among the program's step metrics", tol,
+            ))
+            continue
+        want = float(want)
+        err = abs(program[name] - want) / (abs(want) or 1.0)
+        results.append((name + "_vs_reference", err <= tol, err, tol))
+    return results
+
+
 def compare(reference, params, batch, sizes, q_block, logits, choices,
             program, tolerances):
     """The teacher-forced comparison on one share of the batch.
@@ -200,9 +281,7 @@ def compare(reference, params, batch, sizes, q_block, logits, choices,
     Returns (results, record): ``results`` as ``(name, ok, value,
     limit)`` for the checks, ``record`` for the ``BENCH reference`` line."""
     import jax
-    import jax.numpy as jnp
 
-    logit_tol, logit_rms_tol, loss_tol = tolerances
     faults = choice_faults(choices, sizes["n_experts"])
     results = [("choices_valid", faults == 0, faults, 0)]
     if faults:
@@ -215,63 +294,30 @@ def compare(reference, params, batch, sizes, q_block, logits, choices,
             ref_loss, ref_logits, routed = reference.loss_and_logits_routed(
                 params, batch, sizes, q_block, choices
             )
-        diff = logits - ref_logits
+        logit_err, logit_rms = logit_errors(logits, ref_logits)
         stats = routing_stats(routed.pop("router_logits"), choices)
-        regret = stats["regret"]
         return {
-            "ref_loss": ref_loss,
-            "logit_err": jnp.max(jnp.abs(diff)) / jnp.max(jnp.abs(ref_logits)),
-            "logit_rms": jnp.sqrt(
-                jnp.sum(diff * diff) / jnp.sum(ref_logits * ref_logits)
-            ),
-            "regret_max": jnp.max(regret),
-            "regret_max_by_layer": jnp.max(regret, axis=(1, 2)),
-            # the median of the places that moved; 0 where none did
-            "regret_median_moved": jnp.nan_to_num(
-                jnp.nanmedian(jnp.where(regret > 0, regret, jnp.nan))
-            ),
-            "tokens_moved": jnp.mean(jnp.any(regret > 0, axis=0)),
-            "gap_median": jnp.median(stats["gap"]),
-            "moved_by_layer": stats["moved"],
+            "ref_loss": ref_loss, "logit_err": logit_err,
+            "logit_rms": logit_rms, **routing_summary(stats),
             "objective_terms": routed,
         }
 
     got = jax.tree.map(np.asarray, against_forced(params, batch, logits, choices))
-    ce = program.get("ce_loss", program["loss"])
-    loss_err = abs(ce - float(got["ref_loss"])) / abs(float(got["ref_loss"]))
     regret_max = float(got["regret_max"])
+    dense, loss_err = forced_checks(got, program, tolerances)
     results += [
         ("routing_regret", regret_max <= REGRET_TOL, regret_max, REGRET_TOL),
-        ("logits_vs_reference", float(got["logit_err"]) <= logit_tol,
-         float(got["logit_err"]), logit_tol),
-        ("logits_rms_vs_reference", float(got["logit_rms"]) <= logit_rms_tol,
-         float(got["logit_rms"]), logit_rms_tol),
-        ("loss_vs_reference", loss_err <= loss_tol, loss_err, loss_tol),
+        *dense,
+        *objective_checks(
+            reference, got["objective_terms"], program, tolerances[2]
+        ),
     ]
-    cross_entropies = getattr(reference, "CROSS_ENTROPY_TERMS", ())
-    for name, want in got["objective_terms"].items():
-        tol = loss_tol if name in cross_entropies else ROUTER_LOSS_TOL
-        if name not in program:
-            results.append((
-                name + "_vs_reference", False,
-                "not among the program's step metrics", tol,
-            ))
-            continue
-        want = float(want)
-        err = abs(program[name] - want) / (abs(want) or 1.0)
-        results.append((name + "_vs_reference", err <= tol, err, tol))
     record = {
         "forced_ref_loss": float(got["ref_loss"]),
         "forced_loss_err": loss_err,
         "forced_logit_err": float(got["logit_err"]),
         "forced_logit_rms": float(got["logit_rms"]),
-        "regret_max": regret_max,
-        "regret_max_by_layer": got["regret_max_by_layer"].tolist(),
-        "regret_median_moved": float(got["regret_median_moved"]),
-        "tokens_moved": float(got["tokens_moved"]),
-        "gap_median": float(got["gap_median"]),
-        "moved_by_layer": got["moved_by_layer"].tolist(),
-        "regret_tol": REGRET_TOL,
+        **routing_record(got),
         "reference_terms": {
             k: float(v) for k, v in got["objective_terms"].items()
         },

@@ -35,7 +35,9 @@ from benchmarks.lib.watch import CompileWatch, hbm, synthetic_batch
 # to the reference's. ``routed`` (``benchmarks/lib/routed.py``) holds
 # them to a reference sent to the experts the program chose, holds those
 # choices to a regret limit, and the free-running logits are recorded
-# only; the tolerances below are the same for both. Kernel path: the
+# only; ``selected`` (``benchmarks/lib/selected.py``) does the same with
+# the keys each query attended to, and with the experts too where the
+# model routes; the tolerances below are the same for all. Kernel path: the
 # program's flash attention, fused norms and fused cross-entropy, bf16
 # activations (and bf16 weights in the one-chip cells). Reference: the
 # configuration's plain float32 forward under matmul precision
@@ -321,8 +323,11 @@ def _check_outputs(ctx, cfg, mesh, state, batch0, first):
 
     config, traffic, sizes = ctx["config"], ctx["traffic"], ctx["config"]["sizes"]
     kind = config.get("check", {}).get("kind", "dense")
-    if kind not in ("dense", "routed"):
+    if kind not in ("dense", "routed", "selected"):
         raise ValueError(f"no comparison of kind {kind!r}")
+    forcing = None  # a teacher-forced kind: ``benchmarks/lib/<kind>.py``
+    if kind != "dense":
+        forcing = importlib.import_module("benchmarks.lib." + kind)
     reference = importlib.import_module(
         "benchmarks.references." + config["reference"]
     )
@@ -362,13 +367,11 @@ def _check_outputs(ctx, cfg, mesh, state, batch0, first):
     t0 = time.perf_counter()
     batches = [share(i) for i in range(shares)]
     kernel_losses = [float(kernel_loss(params, b)) for b in batches]
-    if kind == "routed":
-        from benchmarks.lib import routed
-
-        logits, choices = routed.program_logits_and_choices(
+    if forcing:
+        logits, choices = forcing.program_logits_and_choices(
             params, batches[0]["tokens"], cfg
         )
-        program = routed.program_losses(params, batches[0], cfg)
+        program = forcing.program_losses(params, batches[0], cfg)
         ce_loss = program.get("ce_loss", program["loss"])
     else:
         logits = kernel_logits(params, batches[0])
@@ -382,17 +385,17 @@ def _check_outputs(ctx, cfg, mesh, state, batch0, first):
         logit_err=logit_err, logit_rms=logit_rms, loss_err=loss_err,
         rows=rows, shares=shares,
     )
-    if kind == "routed":
+    if forcing:
         # free-running logits are recorded; what is judged of that
         # reference is the loss, which never saw the program's choices
-        results, forced = routed.compare(
+        results, forced = forcing.compare(
             reference, params, batches[0], sizes,
             traffic["check"]["q_block"], logits, choices, program,
             (LOGIT_TOL, LOGIT_RMS_TOL, LOSS_TOL),
         )
         results.append((
-            "loss_vs_free_reference", loss_err <= routed.FREE_LOSS_TOL,
-            loss_err, routed.FREE_LOSS_TOL,
+            "loss_vs_free_reference", loss_err <= forcing.FREE_LOSS_TOL,
+            loss_err, forcing.FREE_LOSS_TOL,
         ))
         compared = ("loss", "ce_loss", *forced.get("reference_terms", ()))
         record.update(

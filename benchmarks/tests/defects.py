@@ -170,3 +170,155 @@ INJECT = {
     "lb_off_1pct": lb_off_1pct, "term_dropped": term_dropped,
     "ce_term_off": ce_term_off,
 }
+
+
+# ---- defects of a selecting attention --------------------------------------
+# What the ``selected`` comparison (``lib/selected.py``) has to catch,
+# each injected by patching the stand-in (``tests/sparse_standin.py``:
+# the program has no indexer to patch). ``test_selected.py`` runs them
+# at a tiny size on the CPU; the chip rehearsal (PERF.md section 4, PR 36)
+# ran the same patches at Keye-VL-2.0's language widths.
+
+def _some_rows(qpos, k, last):
+    """One query in a hundred, among those that have a choice to make."""
+    return (qpos % EVERY == 7) & (qpos >= k) & (qpos < last)
+
+
+def _lowest_chosen(index, chosen, n):
+    """bool: the ``n`` chosen keys of lowest index score, per query."""
+    import jax.numpy as jnp
+
+    order = jnp.argsort(jnp.where(chosen, index, jnp.inf), axis=-1)
+    return jnp.argsort(order, axis=-1) < n
+
+
+def recent_for_topk(patch):
+    """The last k keys instead of the learned ones: a sliding window
+    under the selection's name."""
+    import jax.numpy as jnp
+
+    from benchmarks.tests import sparse_standin
+
+    def select(index, k, qpos):
+        kpos = jnp.arange(index.shape[-1])[None, :]
+        recent = (kpos <= qpos) & (kpos > qpos - k)
+        return jnp.broadcast_to(recent[None], index.shape)
+
+    patch(sparse_standin, "_select", select)
+
+
+def index_8bit(patch):
+    """The indexer's query and key rounded to 8 bits (e4m3) before the
+    score product, as DeepSeek-V3.2 runs its indexer. The comparison
+    PASSES it, on the chip (``selection_regret`` 0.23..0.34 against 0.5,
+    ``selection_moved`` 1.6..1.8% against 3%, where the sound bf16
+    stand-in reads 0.020..0.027 and 0.18% at 1 layer but 0.158 and 1.06%
+    at 8: an 8-bit indexer costs what a few more layers of bf16 rounding
+    cost) as at the tiny size. The two selection checks are no check of
+    precision; a later ``perf_opt`` that moves the indexer to 8 bits is
+    judged by them as sound, and by ``indexer_loss_vs_reference`` only
+    just (2.0e-3..2.5e-3 against 2e-3 on the chip)."""
+    import jax.numpy as jnp
+
+    from benchmarks.tests import sparse_standin
+
+    def rounded(qi, ki):
+        return tuple(
+            a.astype(jnp.float8_e4m3fn).astype(a.dtype) for a in (qi, ki)
+        )
+
+    patch(sparse_standin, "_index_inputs", rounded)
+
+
+def relu_dropped(patch):
+    """The index score without its ReLU."""
+    from benchmarks.tests import sparse_standin
+
+    patch(sparse_standin, "_index_act", lambda dots: dots)
+
+
+def head_weights_dropped(patch):
+    """Every index head weighted alike (w = 1)."""
+    import jax.numpy as jnp
+
+    from benchmarks.tests import sparse_standin
+
+    patch(sparse_standin, "_head_weights", jnp.ones_like)
+
+
+def selection_short(patch):
+    """64 keys too few (a quarter of the selection where that is
+    smaller) on 1% of the rows."""
+    from benchmarks.tests import sparse_standin
+
+    select = sparse_standin._select
+
+    def short(index, k, qpos):
+        chosen = select(index, k, qpos)
+        drop = _lowest_chosen(index, chosen, max(1, min(64, k // 4)))
+        rows = _some_rows(qpos, k, index.shape[-1])
+        return chosen & ~(drop & rows[None])
+
+    patch(sparse_standin, "_select", short)
+
+
+def future_key(patch):
+    """The key after the query's own in place of the lowest chosen one,
+    on 1% of the rows."""
+    import jax.numpy as jnp
+
+    from benchmarks.tests import sparse_standin
+
+    select = sparse_standin._select
+
+    def ahead(index, k, qpos):
+        chosen = select(index, k, qpos)
+        rows = _some_rows(qpos, k, index.shape[-1] - 1)[None]
+        nxt = jnp.arange(index.shape[-1])[None, :] == qpos + 1
+        return (chosen & ~(_lowest_chosen(index, chosen, 1) & rows)) | (
+            nxt[None] & rows
+        )
+
+    patch(sparse_standin, "_select", ahead)
+
+
+def selection_ignored(patch):
+    """A valid selection handed over while the attention runs over
+    every visible key: the sparse attention not applied at all."""
+    import jax.numpy as jnp
+
+    from benchmarks.tests import sparse_standin
+
+    patch(
+        sparse_standin, "_attended",
+        lambda chosen, visible: jnp.broadcast_to(visible, chosen.shape),
+    )
+
+
+def indexer_loss_off(patch):
+    """The indexer's alignment loss 1% too large, in the program only."""
+    from benchmarks.tests import sparse_standin
+
+    patch(sparse_standin, "_reported_indexer_loss", lambda v: 1.01 * v)
+
+
+# defect -> the checks of which at least one has to read not ok; none:
+# a defect the comparison passes, written down as passing
+SELECTED_CAUGHT_BY = {
+    "recent_for_topk": ("selection_regret",),
+    "index_8bit": (),
+    "relu_dropped": ("selection_regret",),
+    "head_weights_dropped": ("selection_regret",),
+    "selection_short": ("selection_valid",),
+    "future_key": ("selection_valid",),
+    "selection_ignored": ("logits_rms_vs_reference",),
+    "indexer_loss_off": ("indexer_loss_vs_reference",),
+}
+SELECTED_INJECT = {
+    "recent_for_topk": recent_for_topk, "index_8bit": index_8bit,
+    "relu_dropped": relu_dropped,
+    "head_weights_dropped": head_weights_dropped,
+    "selection_short": selection_short, "future_key": future_key,
+    "selection_ignored": selection_ignored,
+    "indexer_loss_off": indexer_loss_off,
+}

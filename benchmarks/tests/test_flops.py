@@ -64,6 +64,47 @@ def test_mean_span():
     assert flops.mean_span(1024, 4096) == 512.5
 
 
+def test_mean_span_of_a_selection():
+    # clause (i): query i attends to min(i + 1, k) keys. k = 2 over 4
+    # queries: 1, 2, 2, 2 keys, as a window of 2 would give
+    assert flops.mean_span(4, 0, 2) == 7 / 4
+    # Keye-VL-2.0's language tower at s 8192, top-2048: 2048 queries see
+    # 1..2048 keys, 6144 more see 2048 each = 14,681,088 pairs
+    assert 2048 * 2049 // 2 + 6144 * 2048 == 14_681_088
+    assert flops.mean_span(8192, 0, 2048) == 14_681_088 / 8192 == 1792.125
+    # the narrower of window and selection caps the span
+    assert flops.mean_span(8192, 4096, 2048) == 1792.125
+    assert flops.mean_span(8192, 1024, 2048) == flops.mean_span(8192, 1024)
+    # no selection, or one wider than the sequence, is the causal span:
+    # every existing caller reads what it read
+    for seq, window in ((1024, 0), (8192, 4096), (4096, 0), (4, 2)):
+        assert flops.mean_span(seq, window, 0) == flops.mean_span(seq, window)
+        assert flops.mean_span(seq, window, seq) == flops.mean_span(seq, window)
+    assert flops.mean_span(8192, topk=2048) == 1792.125
+
+
+def test_a_selecting_layer_and_its_indexer_by_hand():
+    """Clauses (i) and (ii) at Keye-VL-2.0's language widths (32 heads
+    of 128 score and 128 value channels; an indexer of 16 heads of 64
+    score channels and no value product, which scores every visible
+    key), s 8192, top-2048."""
+    attention = 32 * (128 + 128) / 2 * flops.mean_span(8192, 0, 2048)
+    assert attention == 7_340_544
+    indexer = 16 * (64 + 0) / 2 * flops.mean_span(8192)
+    assert indexer == 2_097_408
+    assert round(12 * attention / 1e6, 1) == 88.1  # MFLOP a token
+    assert round(12 * indexer / 1e6, 1) == 25.2
+    # what the whole causal span would have credited the layer with
+    assert round(12 * 32 * 128 * flops.mean_span(8192) / 1e6, 1) == 201.4
+    # the indexer's projections (q 2048 x 16 x 64, k 2048 x 64, head
+    # weights 2048 x 16) are multiplied parameters like any other
+    terms = {
+        "multiplied_params": 2048 * (16 * 64 + 64 + 16),
+        "attention_pair_channels": attention + indexer,
+    }
+    assert flops.flops_of(terms) == 6.0 * 2_260_992 + 12.0 * 9_437_952
+
+
 # per layer, by hand:
 # GPT-2 XL: q, k, v, o 4 x 1600^2 = 10.24 M; MLP 2 x 1600 x 6400 = 20.48 M
 # Mistral: q, o 2 x 4096^2 = 33.554 M; k, v 2 x 4096 x 1024 = 8.389 M;
@@ -148,6 +189,9 @@ PARENT = {
     "gpt2-xl-zero1-dp4": (1024, 5142758400.0),
     "olmoe-1b-7b-1chip": (4096, 1979486208.0),
 }
+# the fifth, since PR 34, is counted by its reference module's
+# ``required_terms`` alone: what ``resolve`` gives on the parent of PR 36
+RESOLVED = dict(PARENT, **{"glm-4.7-flash-ep8-1chip": (8192, 5497466880.0)})
 
 
 @pytest.mark.parametrize("how", ["old_call", "resolved"])
@@ -160,6 +204,13 @@ def test_committed_counts_did_not_move(name, how):
         assert flops.required_flops_per_token(_sizes(name), seq) == parent
     else:
         assert flops.resolve(_config(name), seq) == parent
+
+
+def test_every_committed_configuration_resolves_to_its_count():
+    """PR 36 gave ``mean_span`` a third argument: all five committed
+    configurations read their counts to the last bit."""
+    for name, (seq, count) in sorted(RESOLVED.items()):
+        assert flops.resolve(_config(name), seq) == count, name
 
 
 # ---- an architecture whose layers are not all alike ----------------------
