@@ -39,8 +39,9 @@ class ModelConfig:
     # Mistral-style sliding-window attention (0 = unlimited): each query
     # attends to the last attn_window positions. Causal only; mutually
     # exclusive with prefix_lm. The flash kernel skips (and never DMAs)
-    # blocks outside the window, so attention cost is O(S·window).
-    attn_window: int = 0
+    # blocks outside the window, so attention cost is O(S·window). None
+    # reads as 0 everywhere (a published ``sliding_window: null``).
+    attn_window: Optional[int] = 0
     # flash-kernel tile sizes (128-multiples; tunable by strategy search).
     # 1024 measured +12% step throughput over 512 on v5e at s=1024
     # (less grid overhead); _fit_block caps them to the actual sequence.
@@ -56,6 +57,15 @@ class ModelConfig:
     # (n_head·head_dim / kv_heads·head_dim wide, one scale each), before
     # the head split and rope — OLMoE's q_norm/k_norm
     qk_norm: bool = False
+    # RMSNorm of q and k PER HEAD (one scale of head_dim each, shared by
+    # the heads), after the head split and before rope — Qwen3's and
+    # Keye's q_norm/k_norm. Not ``qk_norm``, whose statistic spans the
+    # whole projection
+    qk_head_norm: bool = False
+    # channels of one head where they are not d_model // n_head (0 =
+    # that): wq and wo are n_head · head_dim wide, which need not be
+    # d_model (Keye: 32 x 128 over 2048)
+    d_head: int = 0
     tie_embeddings: bool = True
     # numerics
     dtype: str = "bfloat16"          # activation/compute dtype
@@ -137,6 +147,23 @@ class ModelConfig:
     # times mtp_loss_coef. Training path only
     n_mtp_module: int = 0
     mtp_loss_coef: float = 0.3
+    # a learned selection of keys (DeepSeek-Sparse-Attention; 0 = every
+    # visible key): in every layer an indexer of ``index_n_heads`` query
+    # heads x ``index_head_dim`` channels against ONE key head scores
+    # each visible key, I_ts = sum_j w_tj relu(qI_tj . kI_s), and the
+    # attention runs over the ``index_topk`` best (all of them where a
+    # query sees no more). The indexer reads the layer's input detached
+    # and is trained by its own term alone: ``indexer_loss_coef`` x the
+    # KL from the attention's head-mean probabilities on the selection
+    # (detached) to softmax of I there, mean over queries, summed over
+    # layers. Training path only
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    indexer_loss_coef: float = 1.0
+    # queries the indexer scores at a time (``sa_config``'s chunk): no
+    # float [B, S, S] of index scores is ever whole
+    index_chunk: int = 512
     # pipeline microbatches when the mesh has pp > 1 (0 → one per stage)
     pp_microbatches: int = 0
     # interleaved (circular) pipeline: v layer chunks per stage cut the
@@ -247,6 +274,26 @@ class ModelConfig:
                     "latent attention is rope on its own channels, MHA, "
                     "no qk_norm"
                 )
+        if self.index_topk:
+            if not (self.index_n_heads > 0 and self.index_head_dim > 0
+                    and self.index_head_dim % 2 == 0):
+                raise ValueError(
+                    "a selection of keys needs an indexer: index_n_heads "
+                    "and an even index_head_dim"
+                )
+            if (
+                not self.causal or self.prefix_lm or self.attn_window
+                or self.latent_attention or self.pos != "rope"
+            ):
+                raise ValueError(
+                    "the selection is built for causal rope attention "
+                    "with plain q/k/v projections and no window"
+                )
+        if self.qk_head_norm and (self.qk_norm or self.latent_attention):
+            raise ValueError(
+                "qk_head_norm norms each head of plain q and k "
+                "projections; qk_norm norms them whole: one or the other"
+            )
         if self.n_mtp_module not in (0, 1):
             raise ValueError(
                 "one multi-token-prediction module is built; "
@@ -301,7 +348,22 @@ class ModelConfig:
     def head_dim(self) -> int:
         if self.latent_attention:
             return self.qk_nope_head_dim + self.qk_rope_head_dim
-        return self.d_model // self.n_head
+        return self.d_head or self.d_model // self.n_head
+
+    @property
+    def selects_keys(self) -> bool:
+        return self.index_topk > 0
+
+    @property
+    def index_params(self) -> int:
+        """One layer's indexer matrices: the query heads, the one key
+        head and the head weights (0 without a selection)."""
+        if not self.selects_keys:
+            return 0
+        return self.d_model * (
+            self.index_n_heads * self.index_head_dim
+            + self.index_head_dim + self.index_n_heads
+        )
 
     @property
     def rope_dim(self) -> int:
@@ -336,6 +398,8 @@ class ModelConfig:
             return "a trunk whose layers differ"
         if self.n_mtp_module:
             return "a prediction module"
+        if self.selects_keys:
+            return "a learned selection of keys has no cache path"
         return ""
 
     @property
@@ -361,11 +425,20 @@ class ModelConfig:
                 + self.q_lora_rank + self.kv_lora_rank
             )
         else:
-            attn = d * d + 2 * d * self.kv_heads * self.head_dim + d * d
+            attn = (
+                2 * d * self.n_head * self.head_dim
+                + 2 * d * self.kv_heads * self.head_dim
+            )
+        attn += self.index_params
         gated = 3 if self.act == "swiglu" else 2
         mlp = gated * d * f
         embed = v * d * (1 if self.tie_embeddings else 2)
         pos = self.max_seq * d if self.pos == "learned" else 0
+        if self.n_experts_held and not self.n_dense_layer:
+            # this device's share of a routed model of one kind
+            mlp = d * self.n_experts + (
+                self.n_experts_held * gated * d * self.expert_width
+            )
         if not self.n_dense_layer:
             return L * (attn + mlp + 2 * d) + embed + pos + d
         routed = attn + 2 * d + d * self.n_experts + (
@@ -393,8 +466,10 @@ class ModelConfig:
         A device that holds h of E experts counts k · h / E of them, a
         shared expert whole, a leading dense layer at ``d_ff``, latent
         attention's five projections, and a prediction module's
-        projection, block and the head once more. Recomputation does
-        not count."""
+        projection, block and the head once more. A layer that selects
+        ``index_topk`` keys counts min(i + 1, k) keys a query, and its
+        indexer's projections and heads x channels / 2 over every
+        visible key. Recomputation does not count."""
         d = self.d_model
         d_attn = self.n_head * self.head_dim
         if self.latent_attention:
@@ -407,6 +482,8 @@ class ModelConfig:
             )
         else:
             attn = 2 * d * d_attn + 2 * d * self.kv_heads * self.head_dim
+        # a score-only indexer: its projections among the multiplied
+        attn += self.index_params
         gated = 3 if self.act == "swiglu" else 2
         head = d * self.vocab_size
         routed = 0
@@ -427,13 +504,21 @@ class ModelConfig:
             + self.n_mtp_module * (2 * d * d + routed + head)
             + head
         )
-        if self.causal:
-            w = min(self.attn_window or seq_len, seq_len)
-            span = (w * (w + 1) / 2 + (seq_len - w) * w) / seq_len
-        else:
-            span = seq_len
+        def mean_span(w):
+            w = min(w or seq_len, seq_len)
+            return (w * (w + 1) / 2 + (seq_len - w) * w) / seq_len
+
+        span = mean_span(self.attn_window) if self.causal else seq_len
         attn_layers = self.n_layer + self.n_mtp_module
-        return 6.0 * multiplied + 12.0 * attn_layers * d_attn * span
+        pairs = d_attn * span
+        if self.selects_keys:
+            # the attention counts the keys it selects, min(i + 1, k) a
+            # query; the indexer half a pair-channel (a score product
+            # and no value product) over every key it scores
+            pairs = d_attn * mean_span(self.index_topk) + (
+                self.index_n_heads * self.index_head_dim / 2 * span
+            )
+        return 6.0 * multiplied + 12.0 * attn_layers * pairs
 
 
 def mup_base_config(cfg: "ModelConfig") -> "ModelConfig":
@@ -652,6 +737,38 @@ CONFIGS = {
         moe_score="sigmoid",
         routed_scaling_factor=1.8,
         n_mtp_module=1,
+    ),
+    # a learned selection of keys in every layer, a head wider than
+    # d_model / n_head, 128 narrow experts: Keye-VL-2.0-30B-A3B's
+    # language tower (huggingface.co/Kwai-Keye/Keye-VL-2.0-30B-A3B
+    # config.json, ``text_config`` and ``sa_config``; the vision tower is
+    # not built) — 48 layers of GQA 32 / 4 heads of 128 over d 2048 with
+    # per-head RMSNorm on q and k, rope theta 1e7, a DeepSeek-Sparse-
+    # Attention indexer (16 heads x 64 channels, one key head, top-2048),
+    # 128 SwiGLU experts of width 768, softmax top-8 renormalised, no
+    # shared expert; router loss 0.001 and indexer_loss_coef 1.0 are
+    # assumed. Training path only
+    "keye-vl-2.0": replace(
+        # intermediate_size 6144 is published and unused: every layer is
+        # routed (mlp_only_layers empty)
+        _llama(
+            "keye-vl-2.0", 48, 32, 2048, 6144, max_seq=262144, n_kv_head=4
+        ),
+        vocab_size=151936,
+        attn_window=None,  # sliding_window: null, as published
+        d_head=128,
+        qk_head_norm=True,
+        rope_theta=1e7,
+        index_n_heads=16,
+        index_head_dim=64,
+        index_topk=2048,
+        indexer_loss_coef=1.0,
+        n_experts=128,
+        expert_top_k=8,
+        d_expert=768,
+        moe_impl="ragged",
+        moe_renorm_topk=True,
+        moe_aux_coef=0.001,
     ),
 }
 

@@ -109,6 +109,18 @@ def _init_layers(keys, cfg: ModelConfig, lead, routed: bool) -> Params:
     if cfg.qk_norm:
         attn["q_norm"] = {"scale": ones(nh * hd)}
         attn["k_norm"] = {"scale": ones(nkv * hd)}
+    if cfg.qk_head_norm:
+        attn["q_norm"] = {"scale": ones(hd)}
+        attn["k_norm"] = {"scale": ones(hd)}
+    if cfg.selects_keys:
+        nj, nc = cfg.index_n_heads, cfg.index_head_dim
+        ik = jax.random.split(keys[14], 3)
+        layers["indexer"] = {
+            "wq": stack(ik[0], (d, nj * nc), d),
+            "wk": stack(ik[1], (d, nc), d),      # one key head
+            "w": stack(ik[2], (d, nj), d),       # a weight per query head
+            "k_norm": {"scale": ones(nc)},
+        }
     if cfg.norm == "layernorm":
         layers["ln1"]["bias"] = jnp.zeros(lead + (d,), pdt)
         layers["ln2"]["bias"] = jnp.zeros(lead + (d,), pdt)
@@ -203,9 +215,16 @@ def _layer_axes(cfg: ModelConfig, lead, routed: bool) -> Params:
             "w_up": lead + ("embed", "mlp"),
             "w_down": lead + ("mlp", "embed"),
         }
-    if cfg.qk_norm:
+    if cfg.qk_norm or cfg.qk_head_norm:
         attn["q_norm"] = {"scale": lead + ("norm",)}
         attn["k_norm"] = {"scale": lead + ("norm",)}
+    if cfg.selects_keys:
+        ax["indexer"] = {
+            "wq": lead + ("embed", None),
+            "wk": lead + ("embed", None),
+            "w": lead + ("embed", None),
+            "k_norm": {"scale": lead + ("norm",)},
+        }
     if cfg.norm == "layernorm":
         ax["ln1"]["bias"] = lead + ("norm",)
         ax["ln2"]["bias"] = lead + ("norm",)
@@ -442,7 +461,8 @@ def _project_qkv(
     before the head split, the remat tags and rope, whichever GEMM made
     them. The statistic spans the heads axis, which ``tp`` shards:
     ``forward`` refuses such a mesh rather than hand the norm kernel
-    one shard of the heads."""
+    one shard of the heads. ``cfg.qk_head_norm``: an RMSNorm over each
+    head's channels instead, after the split."""
     b, s, _ = x.shape
     nh, nkv, hd = cfg.n_head, cfg.kv_heads, cfg.head_dim
     attn = layer["attn"]
@@ -460,6 +480,11 @@ def _project_qkv(
     q = q.reshape(b, s, nh, hd)
     k = k.reshape(b, s, nkv, hd)
     v = v.reshape(b, s, nkv, hd)
+    if cfg.qk_head_norm:
+        # RMSNorm per head, one scale of head_dim for all heads; in jnp,
+        # so that it fuses with rope's pass over the same values
+        q = _norm(q, attn["q_norm"]["scale"], None, "rmsnorm")
+        k = _norm(k, attn["k_norm"]["scale"], None, "rmsnorm")
     # names for the selective remat policies (save_qkv / save_dots):
     # identity outside jax.checkpoint, so the cache paths are
     # unaffected. Tagged BEFORE rope: backward re-runs only the cheap
@@ -541,6 +566,18 @@ def _latent_qkv(x, attn, cfg: ModelConfig, positions, rope=None):
     return q, k, v
 
 
+def _constrain_qkv(q, k, v, mesh):
+    """q, k, v [B, S, heads, D] pinned to the mesh's batch, sequence and
+    head axes (as they are without a mesh)."""
+    if mesh is None:
+        return q, k, v
+    return (
+        shd.constrain(q, mesh, "batch", "seq", "heads", None),
+        shd.constrain(k, mesh, "batch", "seq", "kv", None),
+        shd.constrain(v, mesh, "batch", "seq", "kv", None),
+    )
+
+
 def _attention_block(
     x, layer, cfg: ModelConfig, mesh, positions, attn_fn, fp8=None,
     rope=None,
@@ -551,15 +588,270 @@ def _attention_block(
         q, k, v = _latent_qkv(x, layer["attn"], cfg, positions, rope=rope)
     else:
         q, k, v = _project_qkv(x, layer, cfg, positions, fp8=fp8, rope=rope)
-    if mesh is not None:
-        q = shd.constrain(q, mesh, "batch", "seq", "heads", None)
-        k = shd.constrain(k, mesh, "batch", "seq", "kv", None)
-        v = shd.constrain(v, mesh, "batch", "seq", "kv", None)
+    q, k, v = _constrain_qkv(q, k, v, mesh)
     out = attn_fn(q, k, v)
     out = out.reshape(b, s, nh * hd)
     if fp8 is not None:
         return _fp8_gemm(out, layer["attn"]["wo"].astype(x.dtype), fp8, "wo")
     return out @ layer["attn"]["wo"].astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
+# A learned selection of keys (DeepSeek-Sparse-Attention)
+# ---------------------------------------------------------------------------
+
+
+def _index_inputs(h, idx, cfg: ModelConfig, positions):
+    """The indexer's operands from the layer's normed input ``h``
+    [B, S, D]: qI [B, S, J, C] and kI [B, S, C] (one key head, RMSNorm
+    before rope; rope over all C channels) in the compute dtype, and the
+    head weights w [B, S, J] float32, scaled by (J·C)^-1/2."""
+    b, s, _ = h.shape
+    nj, nc = cfg.index_n_heads, cfg.index_head_dim
+    dt = h.dtype
+    rope = _rope_tables(positions, nc, cfg.rope_theta)
+    qi = _rope((h @ idx["wq"].astype(dt)).reshape(b, s, nj, nc), rope)
+    ki = _norm(
+        h @ idx["wk"].astype(dt), idx["k_norm"]["scale"], None, "rmsnorm"
+    )
+    ki = _rope(ki[:, :, None, :], rope)[:, :, 0]
+    w = jnp.matmul(
+        h, idx["w"].astype(dt), preferred_element_type=jnp.float32
+    ) * (nj * nc) ** -0.5
+    return qi, ki, w
+
+
+def _f32_dot(spec, a, b):
+    """einsum on the operands as they are, summed in float32 (XLA:CPU
+    runs no bf16 x bf16 = f32 dot behind a ``name`` barrier: see
+    ``mha_reference``)."""
+    if device.on_cpu():
+        return jnp.einsum(spec, a.astype(jnp.float32), b.astype(jnp.float32))
+    return jnp.einsum(spec, a, b, preferred_element_type=jnp.float32)
+
+
+def _index_scores(qi, ki, w):
+    """I_ts = sum_j w_tj relu(qI_tj . kI_s): qi [B, Q, J, C], ki
+    [B, Sk, C], w [B, Q, J] -> float32 [B, Q, Sk]. The products run on
+    the operands' dtype and sum in float32; the ReLU and the weighting
+    are float32 and elementwise (no second pass over the MXU)."""
+    dots = jax.nn.relu(_f32_dot("bqjc,bsc->bjqs", qi, ki))
+    return jnp.sum(dots * jnp.moveaxis(w, 1, 2)[..., None], axis=1)
+
+
+def _select_keys(index, qpos, topk: int):
+    """bool [B, Q, Sk]: for the query at position ``qpos[i]`` the
+    min(qpos + 1, topk) visible keys (s <= qpos) of largest ``index``
+    [B, Q, Sk] float32, ties to the lower s. EXACT, by bisection and
+    without a sort: the float32 scores map monotonically to uint32
+    keys, the k-th largest key is built bit by bit from counts of
+    ``keys >= candidate`` (32 fused compare-and-count passes), and the
+    ties at it are cut at the position that fills the room, found the
+    same way over the bits of s. ``lax.top_k`` and a sort give the same
+    set and cost a sort; ``approx_max_k`` gives another set."""
+    b, nq, sk = index.shape
+    kpos = jnp.arange(sk, dtype=jnp.int32)
+    visible = kpos[None, :] <= qpos[:, None]
+    index = jnp.where(index == 0, 0.0, index)  # -0.0 ranks as +0.0
+    bits = jax.lax.bitcast_convert_type(index, jnp.uint32)
+    keys = jnp.where(
+        bits >> 31 == 1, ~bits, bits | jnp.uint32(0x80000000)
+    )
+    # 0 lies under every float's key, -inf's too
+    keys = jnp.where(visible[None], keys, jnp.uint32(0))
+    want = jnp.minimum(qpos + 1, topk).astype(jnp.int32)[None, :]
+
+    def value_bit(i, kth):
+        cand = kth | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        count = jnp.sum(keys >= cand[..., None], axis=-1, dtype=jnp.int32)
+        return jnp.where(count >= want, cand, kth)
+
+    kth = jax.lax.fori_loop(
+        0, 32, value_bit, jnp.zeros((b, nq), jnp.uint32)
+    )
+    above, ties = keys > kth[..., None], keys == kth[..., None]
+    room = want - jnp.sum(above, axis=-1, dtype=jnp.int32)  # >= 1
+    n_bits = max(1, (sk - 1).bit_length())
+
+    def place_bit(i, last):
+        # the largest position with fewer than ``room`` ties before it
+        cand = last | (jnp.int32(1) << (n_bits - 1 - i))
+        before = jnp.sum(
+            ties & (kpos < cand[..., None]), axis=-1, dtype=jnp.int32
+        )
+        return jnp.where(before < room, cand, last)
+
+    last = jax.lax.fori_loop(
+        0, n_bits, place_bit, jnp.zeros((b, nq), jnp.int32)
+    )
+    return above | (ties & (kpos <= last[..., None]))
+
+
+def _index_chunks(cfg: ModelConfig, s: int):
+    """(start, end) of the query chunks the indexer works by; chunk
+    [start, end) sees keys [0, end) and no more."""
+    c = min(cfg.index_chunk, s)
+    if s % c:
+        raise ValueError(
+            f"sequence {s} is not a multiple of index_chunk {c}"
+        )
+    return [(start, start + c) for start in range(0, s, c)]
+
+
+def _select(qi, ki, w, cfg: ModelConfig):
+    """The selection of one layer, int8 [B, S, S]: 1 where query t
+    attends to key s. Scored and cut a chunk of queries at a time
+    against the keys the chunk can see; a chunk whose queries all see
+    no more than ``index_topk`` keys takes every visible one unscored."""
+    b, s = w.shape[:2]
+    rows = []
+    for start, end in _index_chunks(cfg, s):
+        qpos = jnp.arange(start, end, dtype=jnp.int32)
+        if end <= cfg.index_topk:
+            chosen = jnp.broadcast_to(
+                jnp.arange(end)[None, :] <= qpos[:, None],
+                (b, end - start, end),
+            )
+        else:
+            with jax.named_scope("attn.index"):
+                index = _index_scores(
+                    qi[:, start:end], ki[:, :end], w[:, start:end]
+                )
+            with jax.named_scope("attn.select"):
+                chosen = _select_keys(index, qpos, cfg.index_topk)
+        rows.append(
+            jnp.pad(chosen.astype(jnp.int8), ((0, 0), (0, 0), (0, s - end)))
+        )
+    with jax.named_scope("attn.select"):
+        return jnp.concatenate(rows, axis=1)
+
+
+def _chunk_kl(qi, ki, w, q, k, lse, sel, scale):
+    """Sum over a chunk's queries of KL(p_t || softmax_{S_t} I_t): p_t
+    the head-mean of the attention's probabilities exp(q.k * scale -
+    lse) on the selection ``sel`` [B, Q, Sk] (from the attention's own
+    lse [B, H, Q]; one kv group's [B, G, Q, Sk] scores at a time), I
+    the index scores; 0 log 0 = 0."""
+    hkv = k.shape[2]
+    groups = q.shape[2] // hkv
+    chosen = sel != 0
+    p = jnp.zeros(chosen.shape, jnp.float32)
+    for g in range(hkv):
+        scores = _f32_dot(
+            "bqrd,bkd->brqk",
+            q[:, :, g * groups:(g + 1) * groups], k[:, :, g],
+        ) * scale
+        p = p + jnp.sum(
+            jnp.exp(scores - lse[:, g * groups:(g + 1) * groups, :, None]),
+            axis=1,
+        )
+    p = jnp.where(chosen, p / q.shape[2], 0.0)
+    log_i = jax.nn.log_softmax(
+        jnp.where(chosen, _index_scores(qi, ki, w), -jnp.inf), axis=-1
+    )
+    live = p > 0
+    return jnp.sum(jnp.where(
+        live,
+        p * (jnp.log(jnp.where(live, p, 1.0)) - jnp.where(live, log_i, 0.0)),
+        0.0,
+    ))
+
+
+def _alignment_chunks(operands, cfg: ModelConfig, scale):
+    """(this chunk's operands, [start, end)) for ``_chunk_kl``."""
+    qi, ki, w, q, k, lse, mask = operands
+    for start, end in _index_chunks(cfg, w.shape[1]):
+        yield (
+            qi[:, start:end], ki[:, :end], w[:, start:end],
+            q[:, start:end], k[:, :end], lse[:, :, start:end],
+            mask[:, start:end, :end], scale,
+        ), (start, end)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _alignment_kl(qi, ki, w, q, k, lse, mask, cfg, scale):
+    """Sum over every query of KL(p_t || softmax_{S_t} I_t), chunk by
+    chunk. The attention's side (``q``, ``k``, ``lse``) is the target
+    and carries no gradient; the derivative for ``qi``, ``ki`` and ``w``
+    is taken chunk by chunk in the rule below, in the pass that makes
+    the value, so that a chunk's [B, G, Q, Sk] scores never outlive
+    it."""
+    return sum(
+        _chunk_kl(*args)
+        for args, _ in _alignment_chunks((qi, ki, w, q, k, lse, mask), cfg, scale)
+    )
+
+
+def _alignment_kl_fwd(qi, ki, w, q, k, lse, mask, cfg, scale):
+    total = jnp.zeros([], jnp.float32)
+    d_qi, d_w = [], []
+    d_ki = jnp.zeros(ki.shape, jnp.float32)
+    operands = (qi, ki, w, q, k, lse, mask)
+    for args, (_start, end) in _alignment_chunks(operands, cfg, scale):
+        value, grads = jax.value_and_grad(_chunk_kl, argnums=(0, 1, 2))(*args)
+        total = total + value
+        d_qi.append(grads[0])
+        d_ki = d_ki.at[:, :end].add(grads[1].astype(jnp.float32))
+        d_w.append(grads[2])
+    grads = (
+        jnp.concatenate(d_qi, axis=1), d_ki.astype(ki.dtype),
+        jnp.concatenate(d_w, axis=1),
+    )
+    return total, (grads, q, k, lse, mask)
+
+
+def _alignment_kl_bwd(cfg, scale, residuals, g):
+    (d_qi, d_ki, d_w), q, k, lse, mask = residuals
+    return (
+        (g * d_qi).astype(d_qi.dtype), (g * d_ki).astype(d_ki.dtype),
+        (g * d_w).astype(d_w.dtype),
+        jnp.zeros_like(q), jnp.zeros_like(k), jnp.zeros_like(lse),
+        np.zeros(mask.shape, dtype=jax.dtypes.float0),
+    )
+
+
+_alignment_kl.defvjp(_alignment_kl_fwd, _alignment_kl_bwd)
+
+
+def _selecting_attention_block(
+    x, layer, cfg: ModelConfig, mesh, positions, attn_fn, rope=None,
+    return_selected: bool = False,
+):
+    """``_attention_block`` for a model that selects its keys
+    (``cfg.index_topk``). Returns (block output [B, S, D], aux) with
+    ``aux["indexer_loss"]`` the layer's mean KL before its coefficient
+    and, where ``return_selected``, ``aux["attn_selected"]`` bool
+    [B, S, S].
+
+    The indexer reads ``x`` DETACHED and the alignment term's target
+    (the attention's probabilities) is detached too, so the indexer's
+    matrices get their gradient from ``indexer_loss`` alone and nothing
+    else gets any from it. The selection is made once a step: it is a
+    named residual (``attn_selected``, int8) that the recomputed forward
+    of ``remat: full`` loads instead of scoring and cutting again."""
+    b, s, _ = x.shape
+    nh, hd = cfg.n_head, cfg.head_dim
+    q, k, v = _constrain_qkv(
+        *_project_qkv(x, layer, cfg, positions, rope=rope), mesh
+    )
+    with jax.named_scope("attn.index"):
+        qi, ki, w = _index_inputs(
+            jax.lax.stop_gradient(x), layer["indexer"], cfg, positions
+        )
+    mask = jax.ad_checkpoint.checkpoint_name(
+        _select(*jax.lax.stop_gradient((qi, ki, w)), cfg), "attn_selected"
+    )
+    out, lse = attn_fn(q, k, v, selected=mask)
+    with jax.named_scope("attn.index_loss"):
+        kl = _alignment_kl(
+            qi, ki, w,
+            *jax.lax.stop_gradient((q, k, lse)), mask, cfg, hd ** -0.5,
+        ) / (b * s)
+    aux = {"indexer_loss": kl}
+    if return_selected:
+        aux["attn_selected"] = mask != 0
+    out = out.reshape(b, s, nh * hd)
+    return out @ layer["attn"]["wo"].astype(x.dtype), aux
 
 
 def _tag_residual(x, name, cfg: ModelConfig):
@@ -623,13 +915,21 @@ def _layer_body(
     tag_attn_out: bool = False,
     fp8=None,
     rope=None,
+    return_selected: bool = False,
 ):
     ln1, ln2 = layer["ln1"], layer["ln2"]
+    attn_aux = {}
     with jax.named_scope("attn"):
         h = _norm_block(x, ln1, cfg)
-        attn = _attention_block(
-            h, layer, cfg, mesh, positions, attn_fn, fp8=fp8, rope=rope
-        )
+        if cfg.selects_keys:
+            attn, attn_aux = _selecting_attention_block(
+                h, layer, cfg, mesh, positions, attn_fn, rope=rope,
+                return_selected=return_selected,
+            )
+        else:
+            attn = _attention_block(
+                h, layer, cfg, mesh, positions, attn_fn, fp8=fp8, rope=rope
+            )
         if tag_attn_out:
             # non-flash attention tags no flash_out/flash_lse, so
             # save_attn would otherwise pin nothing and recompute O(S²)
@@ -666,7 +966,7 @@ def _layer_body(
         x = x + attn + mlp_out if cfg.parallel_residual else x + mlp_out
         if mesh is not None:
             x = shd.constrain(x, mesh, "batch", "seq", None)
-    return x, aux
+    return x, {**aux, **attn_aux}
 
 
 def _offload_names_policy(*names):
@@ -681,7 +981,8 @@ def _offload_names_policy(*names):
     )
 
 
-def _remat_body(cfg: ModelConfig, mesh, attn_fn, tag_attn_out, fp8_layers):
+def _remat_body(cfg: ModelConfig, mesh, attn_fn, tag_attn_out, fp8_layers,
+                return_selected: bool = False):
     """``_layer_body`` bound to the model and wrapped in the configured
     rematerialisation policy: what every layer of the trunk, of either
     kind, and the prediction module's block run through."""
@@ -691,12 +992,19 @@ def _remat_body(cfg: ModelConfig, mesh, attn_fn, tag_attn_out, fp8_layers):
         mesh=mesh,
         attn_fn=attn_fn,
         tag_attn_out=tag_attn_out,
+        return_selected=return_selected,
         # the "current" sentinel must be BAKED into the partial, not
         # passed at call time: jax.checkpoint (below) treats call-time
         # args as traceable values and a str is not a valid JAX type
         **({"fp8": "current"} if fp8_layers == "current" else {}),
     )
-    if cfg.remat == "full":
+    if cfg.remat == "full" and cfg.selects_keys:
+        # everything recomputed but the selection: int8 [B, S, S] a
+        # layer, against scoring and cutting every query's keys again
+        body = jax.checkpoint(
+            body, policy=cp.save_only_these_names("attn_selected")
+        )
+    elif cfg.remat == "full":
         body = jax.checkpoint(body)
     elif cfg.remat == "dots_saveable":
         body = jax.checkpoint(body, policy=cp.dots_saveable)
@@ -798,6 +1106,7 @@ def run_trunk(
     tag_attn_out: bool = False,
     fp8_layers=None,
     dense_layers: Optional[Params] = None,
+    return_selected: bool = False,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Run the stacked transformer layers: remat policy, pp pipelining,
     MoE aux-loss accumulation. Shared by the decoder and the ViT trunk
@@ -813,9 +1122,14 @@ def run_trunk(
     (the only sound fp8 mode under pp; see the pp guard below). Dense
     layers only (MoE experts stay bf16).
 
+    ``return_selected`` (a model that selects its keys): every layer's
+    selection rides out in the aux, stacked bool [L, B, S, S].
+
     Returns (hidden states [B,S,D] — pre-final-norm, aux losses).
     """
-    body = _remat_body(cfg, mesh, attn_fn, tag_attn_out, fp8_layers)
+    body = _remat_body(
+        cfg, mesh, attn_fn, tag_attn_out, fp8_layers, return_selected
+    )
 
     zero_aux = {
         "moe_lb_loss": jnp.zeros([], jnp.float32),
@@ -897,11 +1211,13 @@ def run_trunk(
             body, x, layers, positions, rng, rope, first, n_layers,
             fp8_layers=fp8_layers,
         )
-        # the expert ids are per layer, not a sum: stacked [L, B, S, k]
-        choices = auxs.pop("moe_choices", None)
-        aux = jax.tree.map(lambda a: a.sum(), auxs)
-        if choices is not None:
-            aux["moe_choices"] = choices
+        # the expert ids and the selected keys are per layer, not a sum:
+        # stacked [L, B, S, k] and [L, B, S, S]
+        per_layer = {
+            name: auxs.pop(name)
+            for name in ("moe_choices", "attn_selected") if name in auxs
+        }
+        aux = {**jax.tree.map(lambda a: a.sum(), auxs), **per_layer}
     return x, aux
 
 
@@ -1006,14 +1322,19 @@ def forward(
     features_only: bool = False,
     prefix_len: Optional[jax.Array] = None,
     fp8_states=None,
+    return_selected: Optional[bool] = None,
 ):
     """tokens:[B,S] int32 → logits:[B,S,vocab] float32.
 
     ``return_aux=True`` additionally returns per-model MoE router losses
     summed over layers ({moe_lb_loss, moe_z_loss}) and, for a routed
     model, ``moe_choices``: the expert ids every token was sent to,
-    int32 [n_layer, B, S, k] (not under pp); ``rng`` enables
-    switch-gating jitter during training. ``features_only=True`` returns
+    int32 [n_layer, B, S, k] (not under pp); for a model that selects
+    its keys ``indexer_loss`` (the layers' mean KL summed, before its
+    coefficient) and ``attn_selected``, bool [n_layer, B, S, S], true
+    where query t attended to key s (``return_selected=False`` leaves
+    the masks out: ``loss_fn`` does, a train step stacks none);
+    ``rng`` enables switch-gating jitter during training. ``features_only=True`` returns
     the final-norm hidden states [B,S,D] instead of logits (value/reward
     heads attach here). ``prefix_len`` [B] int32 (prefix-LM configs):
     keys before prefix_len[b] are bidirectionally visible — GLM-style
@@ -1073,7 +1394,26 @@ def forward(
             "jnp.zeros([batch], int32) for fully-causal behavior"
         )
 
-    def attn_fn(q, k, v):
+    def attn_fn(q, k, v, selected=None):
+        if selected is not None:
+            # (out, lse [B, H, S] detached) over each query's selection
+            if attn_impl == "reference":
+                out, lse = mha_reference(
+                    q, k, v, causal=True, selected=selected,
+                    return_lse=True,
+                )
+                return out, jax.lax.stop_gradient(lse)
+            if attn_impl != "flash":
+                raise ValueError(
+                    f"attn_impl {attn_impl!r} takes no selection of keys "
+                    "(flash and reference do)"
+                )
+            from dlrover_tpu.ops.pallas_attention import flash_attention
+
+            return flash_attention(
+                q, k, v, causal=True, block_q=cfg.attn_block_q,
+                block_k=cfg.attn_block_k, selected=selected,
+            )
         if attn_impl == "ring":
             from dlrover_tpu.parallel.sequence import ring_attention
 
@@ -1140,6 +1480,9 @@ def forward(
         tag_attn_out=(attn_impl != "flash"),
         fp8_layers=fp8_states,
         dense_layers=params.get("dense_layers"),
+        return_selected=cfg.selects_keys and (
+            return_aux if return_selected is None else return_selected
+        ),
     )
     if cfg.n_mtp_module and return_aux:
         # the module reads the trunk's output BEFORE the final norm
@@ -1271,6 +1614,7 @@ def loss_fn(
             features_only=True,
             prefix_len=batch.get("prefix_len"),
             fp8_states=fp8_states,
+            return_selected=False,
         )
     else:
         logits, moe_aux = forward(
@@ -1283,6 +1627,7 @@ def loss_fn(
             return_aux=True,
             prefix_len=batch.get("prefix_len"),
             fp8_states=fp8_states,
+            return_selected=False,
         )
     with jax.named_scope("head_loss"):
         return _loss_from_head(
@@ -1339,6 +1684,12 @@ def _loss_from_head(
         loss = loss + lb + rz
         metrics["moe_lb_loss"] = lb
         metrics["moe_z_loss"] = rz
+    if cfg.selects_keys:
+        # the indexer's own term: it alone trains the indexer, and
+        # trains nothing else
+        il = cfg.indexer_loss_coef * moe_aux["indexer_loss"]
+        loss = loss + il
+        metrics["indexer_loss"] = il
     # run_trunk (and the prediction module) summed these over the
     # routed blocks; reported as the mean over them
     blocks = cfg.n_routed_layer + cfg.n_mtp_module

@@ -59,14 +59,15 @@ OPS_LINE = "XLA Ops"
 PHASES = ("forward", "recompute", "backward", "optimizer", "exchange", "other")
 # the step's named scopes (models/decoder.py, train/train_step.py,
 # parallel/sharding.py; inside ``attn`` latent attention's
-# ``attn.latent``, inside ``mlp`` parallel/moe.py's
+# ``attn.latent`` and a selecting attention's ``attn.index|select|
+# index_loss``, inside ``mlp`` parallel/moe.py's
 # ``moe.route|sort|experts|combine|shared``; ``mtp`` is the prediction
 # module, whose block keeps these names beneath it, so the innermost
 # scope of its operations is the block's and ``mtp`` is what is left:
 # projection, norms, head and loss); jax renders a scope inside the
 # transforms around it, ``transpose(jvp(embed))``, so delimiters are / ( )
 _SCOPE = re.compile(
-    r"[/(](embed|attn\.[a-z]+|attn|mlp|head_loss|mtp|optimizer"
+    r"[/(](embed|attn\.[a-z_]+|attn|mlp|head_loss|mtp|optimizer"
     r"|zero\.[a-z]+|moe\.[a-z]+)(?=[/)]|$)"
 )
 # A kernel the compiler itself puts in place of a primitive keeps no

@@ -42,14 +42,20 @@ def mha_reference(
     softmax_scale: Optional[float] = None,
     prefix_len: Optional[jax.Array] = None,
     window: int = 0,
-) -> jax.Array:
+    selected: Optional[jax.Array] = None,
+    return_lse: bool = False,
+):
     """Plain attention. q:[B,S,H,D], k/v:[B,S,Hkv,D] → [B,S,H,D].
 
     ``prefix_len`` [B] int32 (causal only): GLM-style prefix-LM — keys at
     positions < prefix_len[b] are visible to every query (bidirectional
     prefix), the rest follow the causal mask. ``window`` (causal only):
     Mistral-style sliding window — each query sees the last ``window``
-    positions only.
+    positions only. ``selected`` [B, Sq, Sk] (bool, or an integer array
+    that is nonzero at the chosen keys): each query attends to the keys
+    it names that the mask above also lets it see, the same for every
+    head; the softmax runs over those alone. ``return_lse`` adds the
+    log-sum-exp of each row's scores, float32 [B, H, Sq].
     """
     b, sq, h, d = q.shape
     _, sk, hkv, _ = k.shape
@@ -101,8 +107,13 @@ def mha_reference(
     if segment_ids is not None:
         seg_mask = segment_ids[:, :, None] == segment_ids[:, None, :]
         logits = jnp.where(seg_mask[:, None, :sq, :sk], logits, -1e30)
+    if selected is not None:
+        logits = jnp.where((selected != 0)[:, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    out = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    if return_lse:
+        return out, jax.nn.logsumexp(logits, axis=-1)
+    return out
 
 
 @functools.partial(

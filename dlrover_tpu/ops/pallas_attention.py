@@ -42,6 +42,28 @@ Both paths support GLM-style prefix-LM masking (per-batch prefix scalar in
 SMEM) and GQA (K/V shared across head groups via BlockSpec index maps, no
 materialized repeats).
 
+The selection operand (``flash_attention(..., selected=mask)``; a
+learned sparse attention, ``models/decoder.py``'s indexer): ``mask`` is
+``[B, Sq, Sk]`` int8, nonzero where query t chose key s, ONE mask for
+all heads of a sequence (the indexer ranks keys per query, not per
+head). The unpacked kernels take it as a further operand, one
+``[1, block_q, block_k]`` tile a grid step through the index map
+``(g // H, i, k block)``, and a pair counts where the causal rule admits
+it AND the tile names it; their traced names end in ``_sel``
+(``flash_fwd_sel``, ``flash_bwd_dq_sel``, ``flash_bwd_dkv_sel``). What
+is skipped is what the causal run gate skips: blocks above the diagonal
+are neither fetched nor computed. A block below it runs DENSE under the
+mask, whether the tile names one key or all, so a call executes the
+causal half of the pairs and is credited the selected ones (at 8192
+tokens and top-2048: 4,096.5 against 1,792.125 a query). The softmax
+statistics — the running maximum, the sum and the ``lse`` written for
+the backward — range over the selected keys alone: a row whose first
+blocks name none of its keys carries only masked scores there, which
+the first real score's rescaling wipes to exactly zero. ``lse`` is
+returned ``[B, H, S]`` and detached (the indexer's alignment term reads
+it). The tile is read again for each of the H heads (int8: 64 MiB a
+sequence of 8192, so 2 GB a call at 32 heads, under the MXU time).
+
 Narrow-head packing (``head_pack``): heads narrower than the 128-lane
 quantum (gpt2's head_dim=64) share a **slab**: 128 columns —
 ``128 // head_dim`` heads side by side — of the projection's own
@@ -207,11 +229,14 @@ def _allowed_mask(q_start, k_start, block_q, block_k, causal, has_prefix,
 
 def _masked_scores(q, k, scale, q_start, k_start, block_q, block_k,
                    causal, has_prefix, pref, window=0,
-                   allowed=_MASK_UNSET):
+                   allowed=_MASK_UNSET, sel_ref=None):
     """q @ kᵀ with the causal / prefix-LM / sliding-window mask.
     ``allowed`` short-circuits the mask computation with a precomputed
     ``_allowed_mask`` result (head-packed kernels build it once and
-    apply it to every packed head)."""
+    apply it to every packed head). ``sel_ref`` (the ``_sel`` kernels):
+    this block of the selection, [1, block_q, block_k] int8, nonzero at
+    the keys a query chose; a pair counts where the mask admits it AND
+    the selection names it."""
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -220,6 +245,13 @@ def _masked_scores(q, k, scale, q_start, k_start, block_q, block_k,
         allowed = _allowed_mask(
             q_start, k_start, block_q, block_k, causal, has_prefix,
             pref, window=window,
+        )
+    if sel_ref is not None:
+        # widened before the compare: the mask of a 32-bit select comes
+        # from 32-bit lanes
+        chosen = sel_ref[0].astype(jnp.int32) != 0
+        allowed = (
+            chosen if allowed is None else jnp.logical_and(allowed, chosen)
         )
     if allowed is not None:
         s = jnp.where(allowed, s, NEG_INF)
@@ -267,6 +299,7 @@ def _fwd_kernel(
     v_ref,  # [block_k, d]
     prefix_ref,  # [B, 1] int32, whole array in SMEM (None w/o prefix)
     offs_ref,  # [1, 2] int32 (q_off, k_off) in SMEM (None w/o offsets)
+    sel_ref,  # [1, block_q, block_k] int8 selection (None w/o one)
     o_ref,  # [block_q, d]
     lse_ref,  # [block_q, 8] f32 (8 lanes to satisfy TPU tiling; col 0 used)
     m_scratch,  # [block_q, 128] f32
@@ -307,6 +340,7 @@ def _fwd_kernel(
         s = _masked_scores(
             q_ref[0], k_ref[0], scale, q_start, k_start,
             block_q, block_k, causal, has_prefix, pref, window=window,
+            sel_ref=sel_ref,
         )
         v, m_prev, l_prev = v_ref[0], m_scratch[:, :1], l_scratch[:, :1]
         acc_prev = acc_scratch[:]
@@ -493,6 +527,7 @@ def _bwd_dq_kernel(
     lse_ref,  # [1, block_q, 8] f32, as the forward wrote it
     glse_ref,  # the lse cotangent in the same tiles (None: ring only)
     prefix_ref, offs_ref,
+    sel_ref,  # [1, block_q, block_k] int8 selection (None w/o one)
     dq_ref,
     delta_ref,  # [1, block_q, 8] f32: written here, read by the dkv pass
     acc_scratch,  # [block_q, d] f32
@@ -538,6 +573,7 @@ def _bwd_dq_kernel(
         s = _masked_scores(
             q_ref[0], k, scale, q_start, k_start,
             block_q, block_k, causal, has_prefix, pref, window=window,
+            sel_ref=sel_ref,
         )
         _, ds = _p_and_ds(
             s, do_ref[0], v_ref[0],
@@ -556,6 +592,7 @@ def _bwd_dq_kernel(
 def _bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, prefix_ref,
     offs_ref,
+    sel_ref,  # [1, block_q, block_k] int8 selection (None w/o one)
     dk_ref, dv_ref,
     dk_scratch,  # [block_k, d] f32
     dv_scratch,  # [block_k, d] f32
@@ -594,6 +631,7 @@ def _bwd_dkv_kernel(
         s = _masked_scores(
             q, k_ref[0], scale, q_start, k_start,
             block_q, block_k, causal, has_prefix, pref, window=window,
+            sel_ref=sel_ref,
         )
         p, ds = _p_and_ds(
             s, do, v_ref[0],
@@ -777,12 +815,15 @@ def _bwd_dkv_kernel_packed(
         dv_ref[0] = dv_scratch[:].astype(dv_ref.dtype)
 
 
-def _optional_smem(kernel, prefix, offsets, batch, at):
-    """The optional scalar operands every kernel takes in SMEM: the
-    per-batch prefix-LM lengths and the (q, k) global offsets. Returns
-    (arrays, specs, kernel) with None spliced into the kernel's
-    ``prefix_ref`` / ``offs_ref`` slots (positions ``at``, ``at + 1``)
-    for whichever is absent."""
+def _optional_smem(kernel, prefix, offsets, batch, at, selected=None,
+                   sel_spec=None):
+    """The optional operands every kernel takes: in SMEM the per-batch
+    prefix-LM lengths and the (q, k) global offsets, and (the unpacked
+    kernels) the selection ``selected`` [B, Sq, Sk] int8 through
+    ``sel_spec``. Returns (arrays, specs, kernel) with None spliced into
+    the kernel's ``prefix_ref`` / ``offs_ref`` / ``sel_ref`` slots
+    (positions ``at``, ``at + 1``, ``at + 2``; ``sel_spec`` None: the
+    kernel has no third slot) for whichever is absent."""
     arrays, none_idxs = [], []
     if prefix is not None:
         # the whole [B,1] scalar table lives in SMEM; the kernel indexes
@@ -796,6 +837,11 @@ def _optional_smem(kernel, prefix, offsets, batch, at):
     else:
         none_idxs.append(at + 1)
     specs = [pl.BlockSpec(memory_space=pltpu.SMEM)] * len(arrays)
+    if selected is not None:
+        arrays.append(selected)
+        specs.append(sel_spec)
+    elif sel_spec is not None:
+        none_idxs.append(at + 2)
     if none_idxs:
         kernel = _insert_none_args(kernel, none_idxs)
     return arrays, specs, kernel
@@ -885,7 +931,7 @@ def _pallas_backward(q, k, v, out, lse, g, causal, scale,
                      block_q, block_k, prefix=None,
                      interpret: Optional[bool] = None,
                      g_lse=None, window: int = 0, offsets=None,
-                     head_pack: int = 1):
+                     head_pack: int = 1, selected=None):
     """FA2-style pallas backward: returns (dq, dk, dv, delta).
 
     All [B,S,H,D] layouts like the forward; GQA dk/dv are group-summed
@@ -899,6 +945,8 @@ def _pallas_backward(q, k, v, out, lse, g, causal, scale,
 
     ``head_pack`` > 1 runs the head-packed kernels on 128-lane slabs of
     the ``[B, S, H·D]`` view (MHA with ``head_pack · D == 128`` only).
+    ``selected`` [B, Sq, Sk] int8 (``_flash_fwd``; unpacked only): the
+    ``_sel`` kernels, which recompute p over the selected keys.
     """
     interpret = INTERPRET if interpret is None else interpret
     b, sq, h, d = q.shape
@@ -908,6 +956,7 @@ def _pallas_backward(q, k, v, out, lse, g, causal, scale,
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
     assert sq % block_q == 0 and sk % block_k == 0
+    assert selected is None or pack == 1
     nq, nk = sq // block_q, sk // block_k
 
     common = dict(
@@ -927,11 +976,13 @@ def _pallas_backward(q, k, v, out, lse, g, causal, scale,
     glse = () if g_lse is None else (_stat_tiles(g_lse, lse, pack),)
     stat_struct = _out_struct(lse.shape, lse.dtype, q)
 
-    def dq_kernel(kernel):
-        """(SMEM arrays, their specs, kernel) of a dq pass, whose slots
-        6-8 (lse cotangent, prefix, offsets) are all optional."""
+    def dq_kernel(kernel, sel_spec=None):
+        """(optional arrays, their specs, kernel) of a dq pass, whose
+        slots 6-8 (lse cotangent, prefix, offsets) are all optional, as
+        is the unpacked kernel's selection after them."""
         extra, extra_specs, kernel = _optional_smem(
-            kernel, prefix, offsets, b, at=7
+            kernel, prefix, offsets, b, at=7, selected=selected,
+            sel_spec=sel_spec,
         )
         if g_lse is None:
             kernel = _insert_none_args(kernel, [6])
@@ -1027,8 +1078,13 @@ def _pallas_backward(q, k, v, out, lse, g, causal, scale,
         return (g_ // groups, j, 0)
 
     k_spec = pl.BlockSpec((1, block_k, d), k_idx)
+    sel = "" if selected is None else "_sel"
     extra, extra_specs, kernel = dq_kernel(
-        functools.partial(_bwd_dq_kernel, **common)
+        functools.partial(_bwd_dq_kernel, **common),
+        pl.BlockSpec(
+            (1, block_q, block_k),
+            lambda g_, i, j: (g_ // h, i, k_block(i, j)),
+        ),
     )
     dq, delta = pl.pallas_call(
         kernel,
@@ -1040,7 +1096,7 @@ def _pallas_backward(q, k, v, out, lse, g, causal, scale,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=_grid_params(interpret, 3),
         interpret=interpret,
-        name="flash_bwd_dq",
+        name="flash_bwd_dq" + sel,
     )(qt, kt, vt, dot, outt, lse, *glse, *extra)
 
     # dkv grid swaps the roles: k-blocks outer, q-blocks inner
@@ -1056,7 +1112,11 @@ def _pallas_backward(q, k, v, out, lse, g, causal, scale,
     kv_spec = pl.BlockSpec((1, block_k, d), lambda g_, j, i: (g_, j, 0))
     extra, extra_specs, kernel = _optional_smem(
         functools.partial(_bwd_dkv_kernel, **common), prefix, offsets, b,
-        at=6,
+        at=6, selected=selected,
+        sel_spec=pl.BlockSpec(
+            (1, block_q, block_k),
+            lambda g_, j, i: (g_ // h, q_block(j, i), j),
+        ),
     )
     dk, dv = pl.pallas_call(
         kernel,
@@ -1074,7 +1134,7 @@ def _pallas_backward(q, k, v, out, lse, g, causal, scale,
         ],
         compiler_params=_grid_params(interpret, 3),
         interpret=interpret,
-        name="flash_bwd_dkv",
+        name="flash_bwd_dkv" + sel,
     )(qt, kt, vt, dot, lse, delta, *extra)
 
     dq = dq.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
@@ -1101,6 +1161,7 @@ def _flash_fwd(
     window: int = 0,  # sliding window (causal only; 0 = unlimited)
     offsets: Optional[jax.Array] = None,  # [2] int32 global (q_off, k_off)
     head_pack: int = 1,  # heads per 128-lane slab (MHA, pack · D == 128)
+    selected: Optional[jax.Array] = None,  # [B, Sq, Sk] int8 (unpacked)
 ):
     """(out [B, S, H, D], lse f32 as the kernel wrote it: 8-lane tiles
     ``[B·slabs, S, 8]``, head p of a slab in lane p (unpacked: ``[B·H, S,
@@ -1117,7 +1178,9 @@ def _flash_fwd(
     assert sq % block_q == 0 and sk % block_k == 0, (
         "sequence must be padded to the block size"
     )
+    assert selected is None or pack == 1, "the selection runs unpacked"
     nq, nk = sq // block_q, sk // block_k
+    sel_spec = None
     common = dict(
         causal=causal,
         scale=scale,
@@ -1204,9 +1267,16 @@ def _flash_fwd(
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ]
+        # one [block_q, block_k] tile of the selection a grid step, the
+        # same for the h heads of a sequence
+        sel_spec = pl.BlockSpec(
+            (1, block_q, block_k),
+            lambda g, i, j: (g // h, i, k_block(i, j)),
+        )
 
     extra, extra_specs, kernel = _optional_smem(
-        kernel, prefix, offsets, b, at=3
+        kernel, prefix, offsets, b, at=3, selected=selected,
+        sel_spec=sel_spec,
     )
     out, lse = pl.pallas_call(
         kernel,
@@ -1217,7 +1287,10 @@ def _flash_fwd(
         scratch_shapes=scratch_shapes,
         compiler_params=_grid_params(interpret, len(grid)),
         interpret=interpret,
-        name="flash_fwd_packed" if pack > 1 else "flash_fwd",
+        name=(
+            "flash_fwd_packed" if pack > 1
+            else "flash_fwd" if selected is None else "flash_fwd_sel"
+        ),
     )(*inputs, *extra)
     if pack > 1:
         out = out.reshape(b, sq, h, d)
@@ -1464,6 +1537,47 @@ def _bwd_rule_lse(causal, scale, block_q, block_k, window, head_pack,
 flash_attention_with_lse.defvjp(_fwd_rule_lse, _bwd_rule_lse)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _flash_attention_selected(q, k, v, selected, scale, block_q, block_k):
+    """Causal attention over each query's SELECTION of keys
+    (``selected`` [B, Sq, Sk] int8, nonzero at the chosen keys, one mask
+    for all heads of a sequence) through the unpacked ``_sel`` kernels:
+    (out, lse [B, H, S]). The softmax statistics range over the selected
+    keys alone. ``lse`` leaves DETACHED — it is there for the indexer's
+    alignment term, whose target carries no gradient — so the backward
+    takes no cotangent for it."""
+    out, lse = _flash_fwd(
+        q, k, v, True, scale, block_q, block_k, selected=selected
+    )
+    return out, _stat_rows(lse, q.shape[0], q.shape[2], 1)
+
+
+def _fwd_rule_selected(q, k, v, selected, scale, block_q, block_k):
+    out, lse = _flash_fwd(
+        q, k, v, True, scale, block_q, block_k, selected=selected
+    )
+    out = checkpoint_name(out, "flash_out")
+    lse = checkpoint_name(lse, "flash_lse")
+    rows = _stat_rows(lse, q.shape[0], q.shape[2], 1)
+    return (out, rows), (q, k, v, selected, out, lse)
+
+
+def _bwd_rule_selected(scale, block_q, block_k, residuals, cot):
+    q, k, v, selected, out, lse = residuals
+    g_out, _ = cot  # lse is detached
+    cap_q, cap_k = _bwd_caps(q.shape[-1])
+    dq, dk, dv, _ = _pallas_backward(
+        q, k, v, out, lse, g_out, True, scale,
+        _fit_block(q.shape[1], min(block_q, cap_q)),
+        _fit_block(k.shape[1], min(block_k, cap_k)),
+        selected=selected,
+    )
+    return dq, dk, dv, np.zeros(selected.shape, dtype=jax.dtypes.float0)
+
+
+_flash_attention_selected.defvjp(_fwd_rule_selected, _bwd_rule_selected)
+
+
 def flash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -1476,7 +1590,8 @@ def flash_attention(
     prefix_len: Optional[jax.Array] = None,  # [B] int32: prefix-LM
     window: int = 0,  # sliding window (causal only; 0 = unlimited)
     head_pack: int = 0,  # 0 = auto (128 // D heads a slab), 1 = unpacked
-) -> jax.Array:
+    selected: Optional[jax.Array] = None,  # [B, Sq, Sk] bool or int8
+):
     """Flash attention; falls back to the jnp path off-TPU.
 
     q: [B, S, H, D]; k/v: [B, S, Hkv, D] (GQA via fewer kv heads).
@@ -1490,6 +1605,10 @@ def flash_attention(
     is the lane width, so the pack is never another number. GQA always
     runs unpacked — packing would replicate kv DMA per group and the
     kernels keep the simple grid//groups indexing.
+    ``selected`` (module docstring, "the selection operand"; causal, no
+    prefix, no window): each query attends to the keys it names, the
+    same for every head, and the result is ``(out, lse [B, H, S])`` with
+    ``lse`` float32 and detached.
     """
     scale = softmax_scale if softmax_scale is not None else q.shape[-1] ** -0.5
     sq, sk = q.shape[1], k.shape[1]
@@ -1507,6 +1626,12 @@ def flash_attention(
             raise ValueError("window and prefix_len are mutually exclusive")
     if head_pack < 0:
         raise ValueError(f"head_pack must be >= 0, got {head_pack}")
+    if selected is not None:
+        if not causal or prefix_len is not None or window:
+            raise ValueError(
+                "a selection of keys runs under the plain causal mask"
+            )
+        return _selected_attention(q, k, v, selected, scale, bq, bk)
     if pltpu is None or not (device.on_tpu() or INTERPRET) or bq is None or bk is None:
         # off-TPU (incl. GPU — this is a Mosaic-TPU kernel), or seq not
         # tileable to a lane-aligned block: plain jnp, never a trace-time
@@ -1529,6 +1654,27 @@ def flash_attention(
     set_counter("attn.heads_per_slab", pack)
     return _flash_attention(
         q, k, v, prefix_len, None, causal, scale, bq, bk, window, pack
+    )
+
+
+def _selected_attention(q, k, v, selected, scale, bq, bk):
+    """``flash_attention``'s path under a selection: the ``_sel`` kernels
+    where the kernels run at all, else the jnp reference with the same
+    mask. (out, lse [B, H, S] float32, detached)."""
+    if pltpu is None or not (device.on_tpu() or INTERPRET) or (
+        bq is None or bk is None or not USE_PALLAS_BWD
+    ):
+        from dlrover_tpu.ops.attention import mha_reference
+
+        out, lse = mha_reference(
+            q, k, v, causal=True, softmax_scale=scale, selected=selected,
+            return_lse=True,
+        )
+        return out, jax.lax.stop_gradient(lse)
+    set_counter("attn.heads_per_slab", 1)
+    set_counter("attn.delta_in_kernel", 1)
+    return _flash_attention_selected(
+        q, k, v, selected.astype(jnp.int8), scale, bq, bk
     )
 
 

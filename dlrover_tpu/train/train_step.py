@@ -1196,6 +1196,9 @@ class TrainStepBuilder:
             set_counter("attn.latent_rank", cfg.kv_lora_rank)
         if cfg.n_mtp_module:
             set_counter("mtp.depth", cfg.n_mtp_module)
+        if cfg.selects_keys:
+            set_counter("attn.index_heads", cfg.index_n_heads)
+            set_counter("attn.index_topk", cfg.index_topk)
         if self.update_sharding:
             return self._sharded_step_fn(state, batch)
         batch = jax.tree.map(

@@ -1,0 +1,450 @@
+"""Keye-VL-2.0's language tower (``keye-vl-2.0``: a sparse-attention
+indexer and a top-k selection of keys in every layer, GQA heads wider
+than d_model / n_head with a per-head QK norm, softmax top-k experts of
+which a part is held) against the benchmark's plain reference, at a tiny
+size on the CPU with seeded weights: the comparison the chip's cell is
+judged by (``benchmarks/lib/selected.py``), the gradient term by term,
+the selection's exactness, the flash kernels that take a selection
+(interpreted), the shares of an expert-parallel layer adding up, the
+FLOPs by hand, and the paths that refuse the model."""
+
+import dataclasses
+import json
+import pathlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.lib import flops, selected
+from benchmarks.references import keye_vl2_plain as plain
+from dlrover_tpu.models import decoder, generate, get_config
+from dlrover_tpu.ops import pallas_attention
+from dlrover_tpu.ops.attention import mha_reference
+from dlrover_tpu.parallel import moe
+
+CONFIG = (
+    pathlib.Path(__file__).parent.parent
+    / "benchmarks" / "configs" / "keye-vl-2.0-ep8-1chip.json"
+)
+# index_topk 40 over chunks of 32: the first chunk takes every visible
+# key unscored, the second has rows on both sides of t + 1 = k
+TINY = dict(
+    n_layer=2, d_model=128, n_head=4, n_kv_head=2, d_head=64,
+    vocab_size=512, max_seq=128, n_experts=16, expert_top_k=4, d_expert=64,
+    n_experts_held=4, expert_offset=4, index_n_heads=4, index_head_dim=16,
+    index_topk=40, index_chunk=32, remat="full", dtype="float32",
+)
+# float32 on both sides: far inside the chip's limits, so that a defect
+# shows by orders of magnitude
+TOLERANCES = (1e-3, 1e-3, 1e-4)
+Q_BLOCK = 32
+
+
+def _cfg(**over):
+    return get_config("keye-vl-2.0", **{**TINY, **over})
+
+
+def _sizes(cfg):
+    """The configuration file's ``sizes`` keys, read off ``cfg``."""
+    keys = json.loads(CONFIG.read_text())["sizes"]
+    return dict({k: getattr(cfg, k) for k in keys if k != "norm_eps"},
+                norm_eps=1e-6)
+
+
+def _batch(cfg, seq=128, rows=2):
+    tokens = jax.random.randint(
+        jax.random.key(1), (rows, seq), 0, cfg.vocab_size
+    )
+    return {"tokens": tokens, "targets": jnp.roll(tokens, -1, axis=1)}
+
+
+@pytest.fixture(scope="module")
+def model():
+    """Seeded weights with norm scales away from one, so that a scale
+    left out shows."""
+    cfg = _cfg()
+    params = decoder.init(jax.random.key(0), cfg)
+    keys = iter(jax.random.split(jax.random.key(9), 64))
+
+    def jiggle(path, w):
+        if path[-1].key == "scale":
+            return w * (1.0 + 0.2 * jax.random.normal(next(keys), w.shape))
+        return w
+
+    return cfg, jax.tree_util.tree_map_with_path(jiggle, params)
+
+
+def _judged(cfg, params, batch):
+    with jax.default_matmul_precision("highest"):
+        logits, choices = selected.program_logits_and_choices(
+            params, batch["tokens"], cfg
+        )
+        program = selected.program_losses(params, batch, cfg)
+        results, record = selected.compare(
+            plain, params, batch, _sizes(cfg), Q_BLOCK, logits, choices,
+            program, TOLERANCES,
+        )
+    return logits, choices, program, results, record
+
+
+def test_program_agrees_with_the_plain_reference(model):
+    """Every check of ``selected`` in float32: the selection exact and
+    the reference's own, the experts the reference's own, the forced
+    logits, loss, ``indexer_loss`` and ``moe_lb_loss`` equal; and
+    free-running, where float32 moves no key, the same logits."""
+    cfg, params = model
+    batch = _batch(cfg)
+    logits, choices, program, results, record = _judged(cfg, params, batch)
+    names = [name for name, *_ in results]
+    assert names == [
+        "selection_valid", "choices_valid", "selection_regret",
+        "selection_moved", "routing_regret", "logits_vs_reference",
+        "logits_rms_vs_reference", "loss_vs_reference",
+        "indexer_loss_vs_reference", "moe_lb_loss_vs_reference",
+    ]
+    assert all(ok for _n, ok, _v, _l in results), results
+    assert choices["attn_selected"].shape == (2, 2, 128, 128)
+    assert choices["attn_selected"].dtype == np.bool_
+    assert record["select_regret_max"] < 1e-4
+    assert program["indexer_loss"] > 0.01 and program["moe_lb_loss"] > 0
+    with jax.default_matmul_precision("highest"):
+        free_loss, free_logits = jax.jit(
+            lambda p, b: plain.loss_and_logits(p, b, _sizes(cfg), Q_BLOCK)
+        )(params, batch)
+    np.testing.assert_allclose(logits, free_logits, atol=2e-4)
+    assert abs(program["loss"] - float(free_loss)) < 1e-5
+
+
+def test_gradients_term_by_term(model):
+    """Loss and every parameter's gradient against ``jax.grad`` of the
+    reference's objective under the program's selection and choices:
+    the indexer's three matrices and its norm from ``indexer_loss``
+    alone (the reference's cross-entropy and balance term give them
+    exactly zero under forcing), everything else from the cross-entropy
+    and the balance term alone, untouched by ``indexer_loss``."""
+    cfg, params = model
+    batch = _batch(cfg)
+    sizes = _sizes(cfg)
+
+    def program(params):
+        loss, metrics = decoder.loss_fn(params, batch, cfg)
+        return loss, metrics
+
+    with jax.default_matmul_precision("highest"):
+        (loss, metrics), grads = jax.jit(
+            jax.value_and_grad(program, has_aux=True)
+        )(params)
+        _, choices = selected.program_logits_and_choices(
+            params, batch["tokens"], cfg
+        )
+
+        def reference(params, indexer_term):
+            ce, _, forced = plain.loss_and_logits_selected(
+                params, batch, sizes, Q_BLOCK, choices
+            )
+            if indexer_term:
+                return forced["indexer_loss"]
+            return ce + forced["moe_lb_loss"]
+
+        want_trunk = jax.jit(jax.grad(lambda p: reference(p, False)))(params)
+        want_index = jax.jit(jax.grad(lambda p: reference(p, True)))(params)
+        ref_total = reference(params, False) + reference(params, True)
+        only_index = jax.jit(jax.grad(
+            lambda p: decoder.loss_fn(p, batch, cfg)[1]["indexer_loss"]
+        ))(params)
+    assert float(loss) == pytest.approx(float(ref_total), rel=1e-5)
+    assert float(loss) == pytest.approx(float(
+        metrics["loss"] + metrics["indexer_loss"] + metrics["moe_lb_loss"]
+    ), rel=1e-6)
+
+    def split(tree):
+        index = tree["layers"]["indexer"]
+        rest = dict(tree, layers={
+            k: v for k, v in tree["layers"].items() if k != "indexer"
+        })
+        return index, rest
+
+    got_index, got_rest = split(grads)
+    ref_index, _ = split(want_index)
+    ce_on_index, ref_rest = split(want_trunk)
+    idx_only_index, idx_only_rest = split(only_index)
+
+    def close(got, want, what):
+        flat_g = jax.tree_util.tree_leaves_with_path(got)
+        flat_w = jax.tree.leaves(want)
+        assert len(flat_g) == len(flat_w)
+        for (path, g), w in zip(flat_g, flat_w):
+            scale = float(jnp.max(jnp.abs(w))) or 1.0
+            err = float(jnp.max(jnp.abs(g - w))) / scale
+            assert err < 2e-4, (what, jax.tree_util.keystr(path), err)
+
+    close(got_index, ref_index, "indexer from indexer_loss")
+    close(got_rest, ref_rest, "trunk from cross-entropy and balance")
+    assert all(
+        float(jnp.max(jnp.abs(w))) > 0 for w in jax.tree.leaves(ref_index)
+    )
+    # the cross-entropy reaches no indexer parameter (reference under
+    # forcing: exactly), and indexer_loss nothing but them (program)
+    assert all(
+        float(jnp.max(jnp.abs(w))) == 0 for w in jax.tree.leaves(ce_on_index)
+    )
+    assert all(
+        float(jnp.max(jnp.abs(w))) == 0 for w in jax.tree.leaves(idx_only_rest)
+    )
+    close(idx_only_index, ref_index, "indexer_loss alone")
+
+
+def _sorted_selection(index, qpos, k):
+    """The selection by a stable sort: min(t + 1, k) visible keys of
+    largest score, ties to the lower s."""
+    s = index.shape[-1]
+    visible = np.arange(s)[None, :] <= np.asarray(qpos)[:, None]
+    out = np.zeros(index.shape, bool)
+    for b in range(index.shape[0]):
+        for i, t in enumerate(np.asarray(qpos)):
+            score = np.where(visible[i], index[b, i], -np.inf)
+            order = np.argsort(-score, kind="stable")
+            out[b, i, order[: min(t + 1, k)]] = True
+    return out & visible[None]
+
+
+@pytest.mark.parametrize("kind", ["normal", "tied", "zeros", "extremes"])
+def test_selection_is_exact(kind):
+    """``_select_keys`` (bisection over the scores' bits) names exactly
+    the set a stable sort names: on scores with many exact ties, with
+    both zeros, and with infinities and denormals among them."""
+    rng = np.random.default_rng(3)
+    index = rng.normal(size=(2, 24, 96)).astype(np.float32)
+    if kind == "tied":
+        index = np.round(index * 2) / 2
+    elif kind == "zeros":
+        index = np.where(rng.random(index.shape) < 0.7, 0.0, index)
+        index = np.where(rng.random(index.shape) < 0.3, -0.0, index)
+    elif kind == "extremes":
+        index[:, :, ::7] = np.inf
+        index[:, :, 3::11] = -np.inf
+        index[:, :, 5::13] = 1e-42
+        index[:, :, 6::13] = -1e-42
+    index = index.astype(np.float32)
+    qpos = jnp.arange(60, 84, dtype=jnp.int32)
+    for k in (1, 7, 64, 96, 200):
+        got = np.asarray(decoder._select_keys(jnp.asarray(index), qpos, k))
+        want = _sorted_selection(index, qpos, k)
+        assert (got == want).all(), (kind, k, np.argwhere(got != want)[:5])
+        assert (got.sum(-1) == np.minimum(np.asarray(qpos) + 1, k)).all()
+
+
+def _qkv_and_selection(key, b=2, s=256, h=4, hkv=2, d=128, k=40):
+    keys = jax.random.split(key, 5)
+    q = jax.random.normal(keys[0], (b, s, h, d))
+    kk = jax.random.normal(keys[1], (b, s, hkv, d))
+    v = jax.random.normal(keys[2], (b, s, hkv, d))
+    scores = jax.random.normal(keys[3], (b, s, s))
+    sel = decoder._select_keys(scores, jnp.arange(s, dtype=jnp.int32), k)
+    return q, kk, v, sel, jax.random.normal(keys[4], q.shape)
+
+
+@pytest.mark.parametrize("what", ["out", "lse", "dq", "dk", "dv"])
+def test_flash_kernels_take_a_selection(monkeypatch, what):
+    """The unpacked kernels with a selection operand, interpreted: GQA 4
+    / 2 heads of 128 over a random valid selection of 40 keys a query,
+    two q blocks by two k blocks, against plain attention under the
+    same mask: output, lse, dq, dk, dv."""
+    monkeypatch.setattr(pallas_attention, "INTERPRET", True)
+    q, k, v, sel, g = _qkv_and_selection(jax.random.key(2))
+
+    def kernel(q, k, v):
+        return pallas_attention.flash_attention(
+            q, k, v, causal=True, block_q=128, block_k=128, selected=sel
+        )
+
+    def plain_attention(q, k, v):
+        return mha_reference(
+            q, k, v, causal=True, selected=sel, return_lse=True
+        )
+
+    if what in ("out", "lse"):
+        i = ("out", "lse").index(what)
+        np.testing.assert_allclose(
+            kernel(q, k, v)[i], plain_attention(q, k, v)[i], atol=2e-5
+        )
+        return
+    arg = ("dq", "dk", "dv").index(what)
+    got, want = (
+        jax.grad(lambda *a: (f(*a)[0] * g).sum(), argnums=arg)(q, k, v)
+        for f in (kernel, plain_attention)
+    )
+    np.testing.assert_allclose(got, want, atol=5e-5)
+
+
+def test_a_selection_of_every_key_is_the_unmasked_kernel(monkeypatch):
+    """With every visible key selected the ``_sel`` kernels give what the
+    kernels without the operand give, bit for bit, forward and
+    backward; and lse comes back detached."""
+    monkeypatch.setattr(pallas_attention, "INTERPRET", True)
+    q, k, v, _, g = _qkv_and_selection(jax.random.key(5))
+    everything = jnp.ones((2, 256, 256), jnp.int8)
+
+    def with_operand(q, k, v):
+        out, lse = pallas_attention.flash_attention(
+            q, k, v, causal=True, block_q=128, block_k=128,
+            selected=everything,
+        )
+        return (out * g).sum() + lse.sum()  # lse: no gradient
+
+    def without(q, k, v):
+        return (pallas_attention.flash_attention(
+            q, k, v, causal=True, block_q=128, block_k=128
+        ) * g).sum()
+
+    got = jax.grad(with_operand, argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(without, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        assert (np.asarray(a) == np.asarray(b)).all()
+
+
+def test_shares_of_the_expert_parallel_layer_add_up():
+    """Eight chips hold experts 0-1 ... 14-15 of one routed layer (16
+    experts as 8 x 2, softmax top-4 renormalised over all four chosen).
+    Their parts add up to what the uncut reference gives for the whole
+    layer, and every (token, choice) row goes to exactly one share."""
+    shares, held = 8, 2
+    whole = _cfg(n_experts=shares * held, n_experts_held=0, expert_offset=0)
+    full = moe.init_moe_params(jax.random.key(3), whole, lead=())
+    g = jax.random.normal(jax.random.key(4), (2, 32, whole.d_model))
+    sizes = dict(_sizes(whole), n_experts_held=shares * held, expert_offset=0)
+    with jax.default_matmul_precision("highest"):
+        want, _, _ = plain._routed(g.reshape(64, -1), full, sizes, None)
+        total, rows = 0.0, 0.0
+        for rank in range(shares):
+            cfg = dataclasses.replace(
+                whole, n_experts_held=held, expert_offset=rank * held
+            )
+            here = slice(rank * held, (rank + 1) * held)
+            part = dict(
+                full, **{k: full[k][here]
+                         for k in ("w_up", "w_gate_proj", "w_down")}
+            )
+            out, aux = moe._moe_block_ragged(g, part, cfg)
+            total = total + out
+            rows += float(aux["moe_held_rows"])
+    np.testing.assert_allclose(
+        np.asarray(total).reshape(64, -1), np.asarray(want),
+        rtol=2e-5, atol=2e-5,
+    )
+    assert rows == 2 * 32 * whole.expert_top_k
+
+
+def test_required_terms_by_hand():
+    """ISSUE 37's arithmetic for the cell: a layer multiplies 26,116,096
+    parameters a token (attention 18,874,368, indexer 2,260,992, router
+    262,144, 8 x 16 / 128 experts of 4,718,592) and 9,437,952
+    pair-channels at 8192 tokens (32 x 128 x 1,792.125 selected + 16 x
+    32 x 4,096.5 scored); the head 38,895,616; 12 layers 3.4728 GFLOP a
+    token, 8 layers 2.3930."""
+    config = json.loads(CONFIG.read_text())
+    sizes = config["sizes"]
+    terms = plain.required_terms(sizes, 8192)
+    assert terms["multiplied_params"] == 12 * 26_116_096 + 38_895_616
+    assert terms["multiplied_params"] == 352_288_768
+    assert terms["attention_pair_channels"] == 12 * 9_437_952
+    assert flops.mean_span(8192, 0, 2048) == 1792.125
+    assert flops.resolve(config, 8192) == pytest.approx(
+        6 * 352_288_768 + 12 * 113_255_424
+    )
+    assert flops.resolve(config, 8192) / 1e9 == pytest.approx(3.4728, abs=5e-5)
+    eight = plain.required_terms(dict(sizes, n_layer=8), 8192)
+    assert flops.flops_of(eight) / 1e9 == pytest.approx(2.3930, abs=5e-5)
+
+
+def test_configuration_file_is_what_the_program_runs():
+    """The file resolves through the runner's ``_program_config``: every
+    key of ``sizes`` equals the program's field, the published widths
+    among them, and the preset is the published model."""
+    from benchmarks.runners.train import _program_config
+
+    config = json.loads(CONFIG.read_text())
+    cfg = _program_config(config)
+    assert (cfg.d_model, cfg.n_head, cfg.head_dim, cfg.kv_heads) == (
+        2048, 32, 128, 4
+    )
+    assert (cfg.n_experts, cfg.expert_width, cfg.routed_top_k) == (
+        128, 768, 8
+    )
+    assert (cfg.index_n_heads, cfg.index_head_dim, cfg.index_topk) == (
+        16, 64, 2048
+    )
+    assert cfg.rope_theta == 1e7 and cfg.qk_head_norm and not cfg.qk_norm
+    assert (cfg.n_layer, cfg.experts_here, cfg.vocab_size) == (12, 16, 18992)
+    assert cfg.num_params() == pytest.approx(1.2406e9, rel=1e-4)
+    full = get_config("keye-vl-2.0")
+    assert (full.n_layer, full.vocab_size, full.experts_here) == (
+        48, 151936, 128
+    )
+    published = {
+        "hidden_size": full.d_model, "num_attention_heads": full.n_head,
+        "num_key_value_heads": full.kv_heads, "head_dim": full.head_dim,
+        "moe_intermediate_size": full.expert_width,
+        "num_experts_per_tok": full.routed_top_k,
+        "num_local_experts": full.n_experts, "rope_theta": full.rope_theta,
+    }
+    for key, value in published.items():
+        assert config[key] == value, key
+    sa = config["sa_config"]
+    assert (
+        sa["indexer_num_heads"], sa["indexer_head_dim"], sa["topk"],
+        sa["q_chunk_size"], sa["indexer_num_kv_heads"],
+    ) == (16, 64, 2048, full.index_chunk, 1)
+
+
+def test_masks_leave_only_where_asked(model):
+    """``forward(..., return_aux=True)`` hands the masks over; the call
+    ``loss_fn`` makes (and so the train step) stacks none."""
+    cfg, params = model
+    tokens = _batch(cfg)["tokens"]
+    aux = jax.eval_shape(
+        lambda p: decoder.forward(p, tokens, cfg, return_aux=True)[1], params
+    )
+    assert aux["attn_selected"].shape == (cfg.n_layer, 2, 128, 128)
+    aux = jax.eval_shape(
+        lambda p: decoder.forward(
+            p, tokens, cfg, return_aux=True, return_selected=False
+        )[1], params
+    )
+    assert "attn_selected" not in aux and aux["indexer_loss"].shape == ()
+    jaxpr = jax.make_jaxpr(
+        lambda p: decoder.loss_fn(p, _batch(cfg), cfg)
+    )(params)
+    assert all(
+        getattr(v.aval, "shape", ())[-2:] != (128, 128)
+        for v in jaxpr.jaxpr.outvars
+    )
+
+
+@pytest.mark.parametrize(
+    "path", ["init_kv_cache", "prefill", "generate", "pipeline"]
+)
+def test_cache_paths_refuse_the_model_by_name(model, path):
+    cfg, params = model
+    tokens = _batch(cfg)["tokens"]
+    with pytest.raises(
+        ValueError, match="keye-vl-2.0: a learned selection of keys has no "
+        "cache path"
+    ):
+        if path == "init_kv_cache":
+            decoder.init_kv_cache(cfg, 1, 64)
+        elif path == "prefill":
+            decoder.prefill(params, tokens[:, :16], cfg, 64)
+        elif path == "generate":
+            generate.sample(
+                params, cfg, tokens[:, :8], 2, jax.random.key(0)
+            )
+        else:
+            from dlrover_tpu.parallel import MeshConfig, build_mesh
+
+            mesh = build_mesh(
+                MeshConfig(pp=2, dp=-1), devices=jax.devices()[:2]
+            )
+            decoder.forward(params, tokens, cfg, mesh=mesh)

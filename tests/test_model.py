@@ -48,6 +48,7 @@ BENCHMARK_CONFIGS = {
     "mistral-7b-l6": 8192,
     "olmoe-1b-7b-1chip": 4096,
     "glm-4.7-flash-ep8-1chip": 8192,
+    "keye-vl-2.0-ep8-1chip": 8192,
 }
 
 
@@ -59,7 +60,8 @@ def test_flops_per_token_is_the_benchmarks_count(name):
     ``routed_top_k`` experts and the router for a routed layer; and for
     an architecture whose layers differ, the count its reference module
     states (``flops.resolve``): latent attention's projections, the dense
-    prefix, the held and shared experts, the prediction module."""
+    prefix, the held and shared experts, the prediction module, a
+    selection of keys and its score-only indexer."""
     from benchmarks.lib.flops import resolve
 
     configs = pathlib.Path(__file__).parent.parent / "benchmarks" / "configs"
@@ -74,6 +76,20 @@ def test_flops_per_token_is_the_benchmarks_count(name):
         assert cfg.flops_per_token(seq) == pytest.approx(
             resolve(config, seq), rel=1e-9
         )
+    if cfg.selects_keys:
+        # the selection is causal by construction; what it saves against
+        # every visible key is the attention's pairs past index_topk
+        dense = dataclasses.replace(cfg, index_topk=0)
+        spared = 8192 / 2 + 0.5 - (2048 * 2049 / 2 + 6144 * 2048) / 8192
+        assert dense.flops_per_token(8192) - cfg.flops_per_token(
+            8192
+        ) == pytest.approx(
+            12.0 * cfg.n_layer * (
+                cfg.n_head * cfg.head_dim * spared
+                - cfg.index_n_heads * cfg.index_head_dim / 2 * 4096.5
+            ) - 6.0 * cfg.n_layer * cfg.index_params
+        )
+        return
     # bidirectional attention sees every key, a causal one half on average
     both_ways = dataclasses.replace(cfg, causal=False, attn_window=0)
     causal = dataclasses.replace(cfg, attn_window=0)

@@ -79,6 +79,29 @@ def _flash(heads, head_dim, grad):
     return build
 
 
+def _flash_selected(grad):
+    """Keye-VL-2.0's attention: GQA 32 / 4 heads of 128 over one
+    sequence of 8192 with the int8 selection operand, at the model's
+    blocks of 1024 (the backward's tile is 1024 x 1024 too)."""
+    def build(S):
+        q = S((1, 8192, 32, 128), BF16)
+        k = S((1, 8192, 4, 128), BF16)
+        sel = S((1, 8192, 8192), jnp.int8)
+
+        def fwd(q, k, v, sel):
+            return pallas_attention.flash_attention(
+                q, k, v, causal=True, block_q=1024, block_k=1024,
+                selected=sel,
+            )
+
+        if not grad:
+            return fwd, (q, k, k, sel)
+        loss = lambda *a: fwd(*a)[0].astype(F32).sum()  # noqa: E731
+        return jax.grad(loss, argnums=(0, 1, 2)), (q, k, k, sel)
+
+    return build
+
+
 def _norm(d, grad, residual):
     def build(S):
         x, scale = S((8, 1024, d), BF16), S((d,), F32)
@@ -133,6 +156,9 @@ CASES = {
     # latent attention expanded (GLM-4.7-Flash): 20 heads of 256
     "flash-fwd-20x256": (_flash(20, 256, grad=False), 1),
     "flash-bwd-20x256": (_flash(20, 256, grad=True), 3),
+    # a selection of keys (Keye-VL-2.0): the ``_sel`` kernels
+    "flash-fwd-sel-32x4x128": (_flash_selected(grad=False), 1),
+    "flash-bwd-sel-32x4x128": (_flash_selected(grad=True), 3),
     # its two rank norms
     "norm-bwd-d768": (_norm(768, grad=True, residual=False), 1),
     "norm-bwd-d512": (_norm(512, grad=True, residual=False), 1),
@@ -159,7 +185,11 @@ def test_kernel_compiles_for_v5e(chip, case):
 
     fn, args = build(struct)
     compiled = jax.jit(fn).lower(*args).compile()
-    assert compiled.as_text().count("tpu_custom_call") == n_kernels
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == n_kernels
+    if "-sel-" in case:
+        names = ("flash_fwd_sel", "flash_bwd_dq_sel", "flash_bwd_dkv_sel")
+        assert sum(name in text for name in names) == n_kernels
 
 
 @pytest.mark.parametrize(
@@ -263,6 +293,26 @@ STEP_CASES = {
                 "optimizer", "moe.route", "moe.sort", "moe.experts",
                 "moe.combine", "moe.shared"},
         stat_tiles="f32[40,8192,8]",
+    ),
+    # Keye-VL-2.0's language tower as the benchmark's cell runs it (12
+    # layers in one scan, 16 of 128 experts held): the indexer, the
+    # selection and the alignment term under scopes of their own, and
+    # the unpacked flash kernels at head size 128 with the selection
+    # operand, under names of their own
+    "keye-cell": dict(
+        model="keye-vl-2.0",
+        overrides=dict(n_layer=12, n_experts_held=16, expert_offset=0,
+                       vocab_size=18992, max_seq=8192, remat="full",
+                       param_dtype="bfloat16"),
+        optimizer=dict(state_dtype="bfloat16"), comm=None, chips=1,
+        batch=(1, 8192),
+        kernels={"flash_fwd_sel", "flash_bwd_dq_sel", "flash_bwd_dkv_sel",
+                 "norm_fwd", "norm_bwd", "ragged-dot-none",
+                 "ragged-dot-metadata"},
+        scopes={"embed", "attn", "attn.index", "attn.select",
+                "attn.index_loss", "mlp", "head_loss", "optimizer",
+                "moe.route", "moe.sort", "moe.experts", "moe.combine"},
+        stat_tiles="f32[32,8192,8]",
     ),
     # the dp=4 ZeRO-1 recipe: f32 parameters, tied head
     "zero1-dp4": dict(
@@ -396,6 +446,10 @@ def test_step_names_its_kernels_and_phases(topo, case):
             line,
         )
     ]
+    if spec["model"] == "keye-vl-2.0":
+        # the alignment term reads lse: one fusion a layer pass takes the
+        # tiles as its parameter and slices the [B, H, S] view out
+        made = [ln for ln in made if " parameter(" not in ln]
     by_kernel = [
         re.search(r" get-tuple-element\(%(flash_\w+?)[.\d]*\), index=1", ln)
         for ln in made
@@ -417,6 +471,19 @@ def test_step_names_its_kernels_and_phases(topo, case):
             and runtime_timer.scope_of(op_name) == "moe.experts"
         ]
         assert len(grouped) == 12  # a layer: 3 forward, 3 recomputed, 6 back
+    if spec["model"] == "keye-vl-2.0":
+        assert counters["moe.experts"] == 128 and counters["moe.top_k"] == 8
+        assert counters["moe.experts_held"] == 16
+        assert counters["attn.index_heads"] == 16
+        assert counters["attn.index_topk"] == 2048
+        # GQA 32 / 4 heads of 128 over d 2048, one scanned layer body:
+        # the forward twice (full remat), dq and dk/dv once
+        flash = [ln for ln in kernel_lines if "%flash_" in ln]
+        assert len(flash) == 4 and all(
+            "bf16[32,8192,128]" in ln and "s8[1,8192,8192]" in ln
+            for ln in flash
+        )
+        _no_whole_score_array(text)
     if spec["model"] == "glm-4.7-flash":
         assert counters["moe.experts"] == 64 and counters["moe.top_k"] == 4
         assert counters["moe.experts_held"] == 8
@@ -440,6 +507,23 @@ def test_step_names_its_kernels_and_phases(topo, case):
             assert phase == "backward", (name, op_names[name])
         else:
             assert phase in ("forward", "recompute"), (name, op_names[name])
+
+
+def _no_whole_score_array(text):
+    """A selecting model's step at 8192 tokens: no float [.., S, S] is
+    ever whole — not the attention's [B, H, S, S], not an index head's
+    [B, S, S], not the head-summed index scores — the selection is int8
+    [B, S, S] a layer (a residual of the forward: the stacked [L, B, S,
+    S] is the scan's), and no mask is among the step's results."""
+    import re
+
+    assert not re.search(r"(?:f32|bf16|f16)\[[\d,]*8192,8192\]", text)
+    assert not re.search(r"pred\[[\d,]*8192,8192\]", text)
+    assert "s8[1,8192,8192]" in text
+    entry = next(
+        ln for ln in text.splitlines() if ln.startswith("ENTRY ")
+    )
+    assert "8192,8192" not in entry.split("->")[-1], entry[-400:]
 
 
 @pytest.mark.parametrize("case", ["zero1-dp4", "zero2-dp4"])
@@ -551,3 +635,35 @@ def test_glm_cell_fits_the_chip_at_its_depth(topo):
     assert stats.argument_size_in_bytes == pytest.approx(
         6 * 1_133_834_752, rel=1e-3  # bf16 parameters and two moments
     )
+
+
+def test_keye_cell_compiles_at_its_depth(topo):
+    """The benchmark's Keye-VL-2.0 configuration as it is run (12
+    layers, 16 of 128 experts held, 1 x 8192 tokens; STEP_CASES'
+    ``keye-cell`` IS the file's program): the step compiles for a
+    described v5e; bf16 parameters and two moments are 7.44 GB of
+    arguments and the compiler counts 17.6 GB in all, where the chip
+    itself reads 14.10 GB (my chip run, PR 37: on GLM's cell the same
+    count read 2.2 GB high, here 3.5). No float [.., 8192, 8192] array
+    in the step, the twelve selections int8, none among its results."""
+    import json
+    import pathlib
+
+    path = pathlib.Path(__file__).parent.parent / "benchmarks" / "configs"
+    config = json.loads((path / "keye-vl-2.0-ep8-1chip.json").read_text())
+    spec = STEP_CASES["keye-cell"]
+    assert (spec["model"], spec["overrides"]) == (
+        config["program"]["model"], config["program"]["overrides"]
+    )
+    _, text, _ = _compiled_step(topo, "keye-cell")
+    stats = _STEP_MEMORY["keye-cell"]
+    need = (
+        stats.argument_size_in_bytes + stats.output_size_in_bytes
+        - stats.alias_size_in_bytes + stats.temp_size_in_bytes
+    )
+    assert 15e9 < need < 18.5e9, need
+    assert stats.argument_size_in_bytes == pytest.approx(
+        6 * 1_240_585_984, rel=1e-3  # bf16 parameters and two moments
+    )
+    _no_whole_score_array(text)
+    assert "s8[12,1,8192,8192]" in text  # the saved selections, stacked
