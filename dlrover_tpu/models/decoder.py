@@ -775,7 +775,17 @@ def _alignment_kl(qi, ki, w, q, k, lse, mask, cfg, scale):
     and carries no gradient; the derivative for ``qi``, ``ki`` and ``w``
     is taken chunk by chunk in the rule below, in the pass that makes
     the value, so that a chunk's [B, G, Q, Sk] scores never outlive
-    it."""
+    it.
+
+    The rule tags that derivative as the named residual
+    ``attn_align_grad`` (qi's, ki's and w's shapes and dtypes). Under
+    ``remat: full`` a selecting model's policy keeps the name
+    (``_remat_body``), so the forward runs the rule once, value and
+    derivative, and the recomputed forward scores no pair for this term
+    again; under ``remat: none`` nothing is recomputed and the tag is
+    inert; every other tier's policy keeps its own names and not this
+    one, so the rule runs again in the recomputation, as the selection
+    does (``alignment_passes``)."""
     return sum(
         _chunk_kl(*args)
         for args, _ in _alignment_chunks((qi, ki, w, q, k, lse, mask), cfg, scale)
@@ -793,10 +803,10 @@ def _alignment_kl_fwd(qi, ki, w, q, k, lse, mask, cfg, scale):
         d_qi.append(grads[0])
         d_ki = d_ki.at[:, :end].add(grads[1].astype(jnp.float32))
         d_w.append(grads[2])
-    grads = (
+    grads = jax.ad_checkpoint.checkpoint_name((
         jnp.concatenate(d_qi, axis=1), d_ki.astype(ki.dtype),
         jnp.concatenate(d_w, axis=1),
-    )
+    ), "attn_align_grad")
     return total, (grads, q, k, lse, mask)
 
 
@@ -826,9 +836,14 @@ def _selecting_attention_block(
     The indexer reads ``x`` DETACHED and the alignment term's target
     (the attention's probabilities) is detached too, so the indexer's
     matrices get their gradient from ``indexer_loss`` alone and nothing
-    else gets any from it. The selection is made once a step: it is a
-    named residual (``attn_selected``, int8) that the recomputed forward
-    of ``remat: full`` loads instead of scoring and cutting again."""
+    else gets any from it. The selection and the alignment term are
+    made once a step: the selection (``attn_selected``, int8 [B, S, S])
+    and the term's derivative for the indexer (``attn_align_grad``:
+    ``qi``'s, ``ki``'s and ``w``'s shapes, 17.5 MB a layer at 1 x 8192)
+    are named residuals that ``remat: full`` keeps, so its recomputed
+    forward loads the selection for the flash kernels and runs neither
+    ``_select`` nor ``_alignment_kl``, and of ``_index_inputs`` only
+    what the projections' own backward reads."""
     b, s, _ = x.shape
     nh, hd = cfg.n_head, cfg.head_dim
     q, k, v = _constrain_qkv(
@@ -999,10 +1014,14 @@ def _remat_body(cfg: ModelConfig, mesh, attn_fn, tag_attn_out, fp8_layers,
         **({"fp8": "current"} if fp8_layers == "current" else {}),
     )
     if cfg.remat == "full" and cfg.selects_keys:
-        # everything recomputed but the selection: int8 [B, S, S] a
-        # layer, against scoring and cutting every query's keys again
+        # everything recomputed but the selection (int8 [B, S, S] a
+        # layer, against scoring and cutting every query's keys again)
+        # and the alignment term's derivative (qi's, ki's and w's
+        # shapes, against scoring every attention pair again)
         body = jax.checkpoint(
-            body, policy=cp.save_only_these_names("attn_selected")
+            body, policy=cp.save_only_these_names(
+                "attn_selected", "attn_align_grad"
+            )
         )
     elif cfg.remat == "full":
         body = jax.checkpoint(body)
@@ -1082,6 +1101,14 @@ def _remat_body(cfg: ModelConfig, mesh, attn_fn, tag_attn_out, fp8_layers,
         )
 
     return body
+
+
+def alignment_passes(cfg: ModelConfig) -> int:
+    """How often a training step runs ``_alignment_kl``'s chunked pass
+    a layer: once where its derivative is a kept residual (``full``) or
+    nothing is recomputed (``none``), twice under the other tiers,
+    whose names are not its."""
+    return 1 if cfg.remat in ("none", "full") else 2
 
 
 def _train_only_guard(cfg: ModelConfig, fn: str):

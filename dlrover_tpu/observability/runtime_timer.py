@@ -286,8 +286,11 @@ def scope_of(op_name: str) -> str:
 def phase_of(event_name: str, op_name: str) -> str:
     """Which part of the step an operation belongs to. jax's own
     wrappers in the name stack tell forward (``jvp(``) from backward
-    (``transpose(``) from recomputation (``rematted_computation``); the
-    step's scopes tell the optimizer and ZeRO's exchange."""
+    (``transpose(``) from recomputation (``rematted_computation``), the
+    OUTERMOST of them: a derivative that a forward rule takes itself
+    (``jvp()/.../transpose(jvp(...))``, the alignment term's) runs in
+    the forward. The step's scopes tell the optimizer and ZeRO's
+    exchange."""
     if is_collective(event_name):
         return "exchange"
     scope = scope_of(op_name)
@@ -297,11 +300,10 @@ def phase_of(event_name: str, op_name: str) -> str:
         return "exchange"  # pack, exchange, gather: ZeRO's bookkeeping
     if "rematted_computation" in op_name:
         return "recompute"
-    if "transpose(" in op_name:
-        return "backward"
-    if "jvp(" in op_name:
-        return "forward"
-    return "other"
+    outermost = re.search(r"transpose\(|jvp\(", op_name)
+    if outermost is None:
+        return "other"
+    return "backward" if outermost.group() == "transpose(" else "forward"
 
 
 # ---- reducing ----------------------------------------------------------------

@@ -1199,6 +1199,7 @@ class TrainStepBuilder:
         if cfg.selects_keys:
             set_counter("attn.index_heads", cfg.index_n_heads)
             set_counter("attn.index_topk", cfg.index_topk)
+            set_counter("attn.align_passes", decoder.alignment_passes(cfg))
         if self.update_sharding:
             return self._sharded_step_fn(state, batch)
         batch = jax.tree.map(

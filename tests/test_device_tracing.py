@@ -157,6 +157,16 @@ ENTRY %main.1 (a: f32[8]) -> f32[8] {
     assert rt.scope_of("jit(s)/zero.exchange/zero.pack/dus") == "zero.pack"
     assert rt.phase_of("%dus.7 = f32[4] dynamic-update-slice(%a)",
                        "jit(s)/zero.pack/dynamic_update_slice") == "exchange"
+    # the outermost wrapper decides: a derivative taken inside a
+    # forward rule runs in the forward
+    inner = "attn/attn.index_loss/transpose(jvp(bqjc,bsc->bjqs))/dot_general"
+    for outer, phase in (
+        ("jit(s)/jvp()/while/body/closed_call/", "forward"),
+        ("jit(s)/transpose(jvp())/while/body/closed_call/", "backward"),
+        ("jit(s)/transpose(jvp())/while/body/closed_call/checkpoint/"
+         "rematted_computation/", "recompute"),
+    ):
+        assert rt.phase_of("%fusion.9 = f32[8] fusion(%a)", outer + inner) == phase
 
 
 _RS = "channel_id=1, replica_groups={{0,1,2,3}}, use_global_device_ids=true"

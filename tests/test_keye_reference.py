@@ -196,6 +196,65 @@ def test_gradients_term_by_term(model):
     close(idx_only_index, ref_index, "indexer_loss alone")
 
 
+@pytest.mark.parametrize("remat", ["full", "none"])
+def test_alignment_term_is_made_once_a_step(remat):
+    """The gradient program of the toy model (3 layers, 2 x 128 tokens
+    in 4 chunks of 32, 2 kv groups): the alignment term's rule tags its
+    derivative ``attn_align_grad`` (for qi, ki and w) once, the forward
+    scan hands the three on stacked over the layers, and the
+    attention-side score product of ``_chunk_kl`` (``bqrd,bkd->brqk``:
+    [B, keys, 32, G] before its transpose) stands once a chunk and kv
+    group in the program, 8 times: under ``remat: full`` the recomputed
+    forward makes none again (16 before the derivative was a kept
+    residual), under ``remat: none`` nothing is recomputed."""
+    import re
+
+    cfg = _cfg(remat=remat, n_layer=3)
+    params = decoder.init(jax.random.key(0), cfg)
+    batch = _batch(cfg)
+    text = str(jax.make_jaxpr(
+        jax.grad(lambda p: decoder.loss_fn(p, batch, cfg)[0])
+    )(params))
+    assert text.count("name=attn_align_grad") == 3
+    for stacked in ("f32[3,2,128,4,16]", "f32[3,2,128,16]", "f32[3,2,128,4]"):
+        assert stacked in text
+    products = re.findall(r":f32\[2,(\d+),32,2\] = dot_general\[", text)
+    assert sorted(map(int, products)) == [32, 32, 64, 64, 96, 96, 128, 128]
+    assert decoder.alignment_passes(cfg) == 1
+    assert decoder.alignment_passes(_cfg(remat="save_attn")) == 2
+
+
+def test_remat_full_gives_what_remat_none_gives(model):
+    """Loss, ``indexer_loss`` and every parameter's gradient of the toy
+    model under ``remat: full`` (the selection and the alignment term's
+    derivative kept, everything else recomputed) against ``remat:
+    none``. The two losses are the same forward: bit-equal. The
+    gradients read bit-equal too on this CPU (before the derivative was
+    kept they differed by 1.9e-9); the limit is 1e-6 of a leaf's largest
+    entry, float32 rounding of a forward recomputed in another fusion,
+    so that another build of XLA does not fail it."""
+    cfg, params = model
+    batch = _batch(cfg)
+    got = {}
+    for remat in ("full", "none"):
+        cfg_r = dataclasses.replace(cfg, remat=remat)
+        got[remat] = jax.jit(jax.value_and_grad(
+            lambda p, c=cfg_r: decoder.loss_fn(p, batch, c), has_aux=True
+        ))(params)
+    (loss_f, metrics_f), grads_f = got["full"]
+    (loss_n, metrics_n), grads_n = got["none"]
+    assert float(loss_f) == float(loss_n)
+    assert float(metrics_f["indexer_loss"]) == float(metrics_n["indexer_loss"])
+    assert float(metrics_f["indexer_loss"]) > 0
+    flat_f = jax.tree_util.tree_leaves_with_path(grads_f)
+    flat_n = jax.tree.leaves(grads_n)
+    assert len(flat_f) == len(flat_n)
+    for (path, g), w in zip(flat_f, flat_n):
+        scale = float(jnp.max(jnp.abs(w))) or 1.0
+        err = float(jnp.max(jnp.abs(g - w))) / scale
+        assert err < 1e-6, (jax.tree_util.keystr(path), err)
+
+
 def _sorted_selection(index, qpos, k):
     """The selection by a stable sort: min(t + 1, k) visible keys of
     largest score, ties to the lower s."""
