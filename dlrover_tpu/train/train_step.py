@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dlrover_tpu.common import device
+from dlrover_tpu.common import compile_cache, device
 from dlrover_tpu.models import decoder
 from dlrover_tpu.models.config import ModelConfig
 from dlrover_tpu.observability import sentinels as snt
@@ -444,7 +444,19 @@ def init_train_state(
     and a state restored into the template must all spell alike, or a
     worker restarted after a crash recompiles the step it compiled
     before.
+
+    Whatever this compiles is counted as the state's initialisation
+    (``compile.init_state.s``, ``common/compile_cache.py``).
     """
+    with compile_cache.watch_compiles().within(compile_cache.INIT_STATE):
+        return _init_train_state(
+            rng, cfg, mesh, optimizer, rules, offload_opt_state, comm
+        )
+
+
+def _init_train_state(
+    rng, cfg, mesh, optimizer, rules, offload_opt_state, comm
+) -> TrainState:
     param_shardings = shd.shardings_for_tree(
         mesh, decoder.logical_axes(cfg), rules
     )
@@ -632,6 +644,10 @@ class TrainStepBuilder:
         comm: Optional[shd.CommConfig] = None,
         health_sentinels: bool = False,
     ):
+        # executables are made from here on: the compile recorder is on,
+        # and what came before is ``setup.before_build_s``
+        self._compiles = compile_cache.watch_compiles()
+        self._compiles.first_build()
         self.cfg = cfg
         self.mesh = mesh
         self.optimizer = optimizer
@@ -1181,24 +1197,9 @@ class TrainStepBuilder:
 
     def step_fn(self, state: TrainState, batch) -> Tuple[TrainState, Dict]:
         cfg = self.cfg
-        if cfg.n_experts > 0 and "tokens" in batch:
-            # what the routed layers of one step handle. Trace time,
-            # values: a retrace sets the same numbers again
-            set_counter("moe.experts", cfg.n_experts)
-            set_counter("moe.top_k", cfg.routed_top_k)
-            set_counter(
-                "moe.rows_per_step",
-                batch["tokens"].size * cfg.routed_top_k
-                * (cfg.n_routed_layer + cfg.n_mtp_module),
-            )
-            set_counter("moe.experts_held", cfg.experts_here)
-        if cfg.latent_attention:
-            set_counter("attn.latent_rank", cfg.kv_lora_rank)
-        if cfg.n_mtp_module:
-            set_counter("mtp.depth", cfg.n_mtp_module)
         if cfg.selects_keys:
-            set_counter("attn.index_heads", cfg.index_n_heads)
-            set_counter("attn.index_topk", cfg.index_topk)
+            # which path the step took. Trace time, a value: a retrace
+            # sets the same number again
             set_counter("attn.align_passes", decoder.alignment_passes(cfg))
         if self.update_sharding:
             return self._sharded_step_fn(state, batch)
@@ -1269,6 +1270,7 @@ class TrainStepBuilder:
 
     def build(self) -> Callable:
         """Return the jitted step with donated state."""
+        self._compiles.step_program(self.step_fn.__name__)
         return jax.jit(self.step_fn, donate_argnums=(0,))
 
     # ---- fused multi-step block -----------------------------------------
@@ -1307,6 +1309,7 @@ class TrainStepBuilder:
                 "fused train blocks do not compose with "
                 "offload_opt_state; use block_k=1"
             )
+        self._compiles.step_program(self.block_fn.__name__)
         return jax.jit(self.block_fn, donate_argnums=(0,))
 
 

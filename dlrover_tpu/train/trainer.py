@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from dlrover_tpu.common import compile_cache
 from dlrover_tpu.common.constants import GraftEnv
 from dlrover_tpu.common.log import get_logger
 from dlrover_tpu.models.config import ModelConfig
@@ -196,6 +197,11 @@ class Trainer:
         )
         self._step_fn = None
         self._block_fn = None
+        # the step programs the compile recorder had seen when this
+        # loop last looked, and the newest one's account
+        self._compiles = compile_cache.watch_compiles()
+        self._step_programs_seen = 0
+        self._step_compile = None
         self._eval_fn = eval_step_fn
         self._batch_sharding = batch_sharding(self.mesh, rules)
         if jax.process_count() == 1:
@@ -377,6 +383,7 @@ class Trainer:
                 logger.warning("model-info report failed", exc_info=True)
         control = self.control
         self.callbacks.fire("on_train_begin", self, control)
+        self._step_programs_seen = self._compiles.step_programs
         if args.block_k > 1:
             if self._block_fn is None:
                 self._block_fn = self._builder.build_block()
@@ -407,6 +414,30 @@ class Trainer:
 
     # ---- telemetry producers --------------------------------------------
 
+    def _note_step_compile(self, tracer):
+        """Inside a ``train.step`` span. Where the step was traced,
+        lowered and compiled (or fetched) in it — the first step of a
+        worker, a new block size — that interval gets its name on the
+        timeline: ``train.compile``, a child of the open span, laid out
+        from the trace's start for the three phases' seconds. Any other
+        step: one attribute read."""
+        seen = self._compiles.step_programs
+        if seen == self._step_programs_seen:
+            return
+        self._step_programs_seen = seen
+        made = self._compiles.last_step
+        cost = self._step_compile = {
+            k: made[k]
+            for k in ("trace_s", "lower_s", "backend_s", "cache_hit")
+        }
+        tracer.complete_span(
+            "train.compile",
+            # jax stamps the start on time.time()
+            time.monotonic() - (time.time() - made["start"]),
+            dur_s=cost["trace_s"] + cost["lower_s"] + cost["backend_s"],
+            **cost,
+        )
+
     def _emit_step_telemetry(
         self, step: int, loss: float, step_time_s: float,
         batch=None, n_steps: int = 1,
@@ -421,7 +452,12 @@ class Trainer:
             if hub.enabled:
                 hub.publish(
                     telemetry.ElasticEvent(
-                        kind="first_step_back", detail=f"step={step}"
+                        kind="first_step_back",
+                        # with what the step's compile-or-fetch cost
+                        detail=" ".join(
+                            f"{k}={v}" for k, v in
+                            {"step": step, **(self._step_compile or {})}.items()
+                        ),
                     )
                 )
         hub = telemetry.get_hub()
@@ -497,6 +533,7 @@ class Trainer:
                     )
                 else:
                     self.state, metrics = self._step_fn(self.state, batch)
+                self._note_step_compile(tracer)
             with tracer.span("train.readback", step=step):
                 self.timer.stop(outputs=metrics["loss"])
                 # ONE device→host transfer per step, sentinels or not —
@@ -760,6 +797,7 @@ class Trainer:
                     self.state, metrics = self._block_fn(self.state, block)
             else:
                 self.state, metrics = self._block_fn(self.state, block)
+            self._note_step_compile(tracer)
             step_span.end()
             hooks_span = tracer.span("train.hooks", step=step + k)
             if pending is not None:

@@ -461,10 +461,8 @@ def test_step_names_its_kernels_and_phases(topo, case):
     assert "f32[104,2,1024,8]" not in text  # the tiles before PR 35
     assert not re.search(r"= f32\[8,1024,25\]\S* reduce\(", text)
     if spec["model"] == "olmoe-1b-7b":
-        # the routed layer's counters, and its grouped matmuls under
-        # their scope (by the kernel's name: it has no name stack)
-        assert counters["moe.experts"] == 64 and counters["moe.top_k"] == 8
-        assert counters["moe.rows_per_step"] == 2 * 4096 * 8 * 1
+        # the routed layer's grouped matmuls under their scope (by the
+        # kernel's name: it has no name stack)
         grouped = [
             name for name, op_name in op_names.items()
             if name.startswith("ragged-dot-none")
@@ -472,10 +470,6 @@ def test_step_names_its_kernels_and_phases(topo, case):
         ]
         assert len(grouped) == 12  # a layer: 3 forward, 3 recomputed, 6 back
     if spec["model"] == "keye-vl-2.0":
-        assert counters["moe.experts"] == 128 and counters["moe.top_k"] == 8
-        assert counters["moe.experts_held"] == 16
-        assert counters["attn.index_heads"] == 16
-        assert counters["attn.index_topk"] == 2048
         assert counters["attn.align_passes"] == 1
         # GQA 32 / 4 heads of 128 over d 2048, one scanned layer body:
         # the forward twice (full remat), dq and dk/dv once
@@ -486,12 +480,6 @@ def test_step_names_its_kernels_and_phases(topo, case):
         )
         _no_whole_score_array(text)
     if spec["model"] == "glm-4.7-flash":
-        assert counters["moe.experts"] == 64 and counters["moe.top_k"] == 4
-        assert counters["moe.experts_held"] == 8
-        assert counters["attn.latent_rank"] == 512
-        assert counters["mtp.depth"] == 1
-        # one routed layer and the module's block
-        assert counters["moe.rows_per_step"] == 2 * 8192 * 4 * 2
         # the flash kernels run at head size 256, all three layers
         flash = [ln for ln in kernel_lines if "%flash_" in ln]
         assert len(flash) == 3 * 4 and all(
