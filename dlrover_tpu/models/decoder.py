@@ -726,16 +726,14 @@ def _select(qi, ki, w, cfg: ModelConfig):
         return jnp.concatenate(rows, axis=1)
 
 
-def _chunk_kl(qi, ki, w, q, k, lse, sel, scale):
-    """Sum over a chunk's queries of KL(p_t || softmax_{S_t} I_t): p_t
-    the head-mean of the attention's probabilities exp(q.k * scale -
-    lse) on the selection ``sel`` [B, Q, Sk] (from the attention's own
-    lse [B, H, Q]; one kv group's [B, G, Q, Sk] scores at a time), I
-    the index scores; 0 log 0 = 0."""
+def _chunk_target(q, k, lse, sel, scale):
+    """p_t of a chunk's queries, float32 [B, Q, Sk]: the head-mean of
+    the attention's probabilities exp(q.k * scale - lse) on the
+    selection ``sel`` [B, Q, Sk], zero off it (from the attention's own
+    lse [B, H, Q]; one kv group's [B, G, Q, Sk] scores at a time)."""
     hkv = k.shape[2]
     groups = q.shape[2] // hkv
-    chosen = sel != 0
-    p = jnp.zeros(chosen.shape, jnp.float32)
+    p = jnp.zeros(sel.shape, jnp.float32)
     for g in range(hkv):
         scores = _f32_dot(
             "bqrd,bkd->brqk",
@@ -745,9 +743,16 @@ def _chunk_kl(qi, ki, w, q, k, lse, sel, scale):
             jnp.exp(scores - lse[:, g * groups:(g + 1) * groups, :, None]),
             axis=1,
         )
-    p = jnp.where(chosen, p / q.shape[2], 0.0)
+    return jnp.where(sel != 0, p / q.shape[2], 0.0)
+
+
+def _chunk_kl(qi, ki, w, q, k, lse, sel, scale):
+    """Sum over a chunk's queries of KL(p_t || softmax_{S_t} I_t): p_t
+    the attention's side (``_chunk_target``), I the index scores on the
+    selection ``sel``; 0 log 0 = 0."""
+    p = _chunk_target(q, k, lse, sel, scale)
     log_i = jax.nn.log_softmax(
-        jnp.where(chosen, _index_scores(qi, ki, w), -jnp.inf), axis=-1
+        jnp.where(sel != 0, _index_scores(qi, ki, w), -jnp.inf), axis=-1
     )
     live = p > 0
     return jnp.sum(jnp.where(
@@ -768,14 +773,18 @@ def _alignment_chunks(operands, cfg: ModelConfig, scale):
         ), (start, end)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def _alignment_kl(qi, ki, w, q, k, lse, mask, cfg, scale):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def _alignment_kl(qi, ki, w, q, k, lse, mask, cfg, scale, tiles=None):
     """Sum over every query of KL(p_t || softmax_{S_t} I_t), chunk by
     chunk. The attention's side (``q``, ``k``, ``lse``) is the target
     and carries no gradient; the derivative for ``qi``, ``ki`` and ``w``
-    is taken chunk by chunk in the rule below, in the pass that makes
-    the value, so that a chunk's [B, G, Q, Sk] scores never outlive
-    it.
+    is taken in the rule below, in the pass that makes the value, so
+    that a chunk's [B, G, Q, Sk] scores never outlive it.
+
+    One algorithm on two back ends: where the attention runs the Pallas
+    kernels and the shapes fit (``tiles``: ``alignment_tiles``) the rule
+    is the kernel ``ops/pallas_align.py``; on the CPU, under
+    ``attn_impl == "reference"`` and at odd shapes it is the jnp below.
 
     The rule tags that derivative as the named residual
     ``attn_align_grad`` (qi's, ki's and w's shapes and dtypes). Under
@@ -792,25 +801,38 @@ def _alignment_kl(qi, ki, w, q, k, lse, mask, cfg, scale):
     )
 
 
-def _alignment_kl_fwd(qi, ki, w, q, k, lse, mask, cfg, scale):
-    total = jnp.zeros([], jnp.float32)
-    d_qi, d_w = [], []
-    d_ki = jnp.zeros(ki.shape, jnp.float32)
+def _alignment_kl_fwd(qi, ki, w, q, k, lse, mask, cfg, scale, tiles=None):
     operands = (qi, ki, w, q, k, lse, mask)
-    for args, (_start, end) in _alignment_chunks(operands, cfg, scale):
-        value, grads = jax.value_and_grad(_chunk_kl, argnums=(0, 1, 2))(*args)
-        total = total + value
-        d_qi.append(grads[0])
-        d_ki = d_ki.at[:, :end].add(grads[1].astype(jnp.float32))
-        d_w.append(grads[2])
-    grads = jax.ad_checkpoint.checkpoint_name((
-        jnp.concatenate(d_qi, axis=1), d_ki.astype(ki.dtype),
-        jnp.concatenate(d_w, axis=1),
-    ), "attn_align_grad")
+    if tiles is not None:
+        from dlrover_tpu.ops import pallas_align
+
+        # value and derivative in one kernel a layer: the attention's
+        # side and the indexer's tile by tile, nothing of either whole
+        kl, *grads = pallas_align.alignment_kl_and_grads(
+            qi, ki, w, mask, q, k, lse, scale, *tiles
+        )
+        total, grads = jnp.sum(kl), tuple(grads)
+    else:
+        total = jnp.zeros([], jnp.float32)
+        d_qi, d_w = [], []
+        d_ki = jnp.zeros(ki.shape, jnp.float32)
+        for args, (_start, end) in _alignment_chunks(operands, cfg, scale):
+            value, grads = jax.value_and_grad(
+                _chunk_kl, argnums=(0, 1, 2)
+            )(*args)
+            total = total + value
+            d_qi.append(grads[0])
+            d_ki = d_ki.at[:, :end].add(grads[1].astype(jnp.float32))
+            d_w.append(grads[2])
+        grads = (
+            jnp.concatenate(d_qi, axis=1), d_ki.astype(ki.dtype),
+            jnp.concatenate(d_w, axis=1),
+        )
+    grads = jax.ad_checkpoint.checkpoint_name(grads, "attn_align_grad")
     return total, (grads, q, k, lse, mask)
 
 
-def _alignment_kl_bwd(cfg, scale, residuals, g):
+def _alignment_kl_bwd(cfg, scale, tiles, residuals, g):
     (d_qi, d_ki, d_w), q, k, lse, mask = residuals
     return (
         (g * d_qi).astype(d_qi.dtype), (g * d_ki).astype(d_ki.dtype),
@@ -856,11 +878,12 @@ def _selecting_attention_block(
     mask = jax.ad_checkpoint.checkpoint_name(
         _select(*jax.lax.stop_gradient((qi, ki, w)), cfg), "attn_selected"
     )
-    out, lse = attn_fn(q, k, v, selected=mask)
+    out, lse, in_kernel = attn_fn(q, k, v, selected=mask)
     with jax.named_scope("attn.index_loss"):
         kl = _alignment_kl(
             qi, ki, w,
             *jax.lax.stop_gradient((q, k, lse)), mask, cfg, hd ** -0.5,
+            alignment_tiles(cfg, s) if in_kernel else None,
         ) / (b * s)
     aux = {"indexer_loss": kl}
     if return_selected:
@@ -1101,6 +1124,46 @@ def _remat_body(cfg: ModelConfig, mesh, attn_fn, tag_attn_out, fp8_layers,
         )
 
     return body
+
+
+def _resolve_attn_impl(attn_impl: str, mesh) -> str:
+    """What ``attn_impl == "auto"`` stands for on this mesh and host."""
+    if attn_impl != "auto":
+        return attn_impl
+    if mesh is not None and mesh.shape.get("sp", 1) > 1:
+        # a sequence-parallel mesh MUST use the shard_map sp paths:
+        # letting GSPMD partition a plain attention over seq-sharded
+        # q/k/v ends in "involuntary full rematerialization" (a
+        # replicate-then-repartition of the score matmul operands)
+        return "ulysses"
+    # flash (pallas) on real accelerators; the kernel's interpret path
+    # is far slower than plain jnp on CPU
+    return "reference" if device.on_cpu() else "flash"
+
+
+def alignment_tiles(cfg: ModelConfig, s: int):
+    """The alignment kernel's (chunk, query rows, key rows) for a
+    sequence of ``s`` where a model whose attention runs the Pallas
+    kernels takes the term through ``ops/pallas_align.py``, None where
+    it takes the jnp rule: off the TPU, or shapes the tiles do not
+    fit."""
+    from dlrover_tpu.ops import pallas_align
+
+    chunk = min(cfg.index_chunk, s)
+    tiles = pallas_align.tiles(
+        s, chunk, cfg.index_n_heads, cfg.index_head_dim
+    )
+    return None if tiles is None else (chunk, *tiles)
+
+
+def alignment_in_kernel(cfg: ModelConfig, s: int, attn_impl: str = "auto",
+                        mesh=None) -> bool:
+    """Whether a step at sequence length ``s`` takes the alignment term
+    through the kernel: what ``_selecting_attention_block`` decides."""
+    return (
+        _resolve_attn_impl(attn_impl, mesh) == "flash"
+        and alignment_tiles(cfg, s) is not None
+    )
 
 
 def alignment_passes(cfg: ModelConfig) -> int:
@@ -1384,19 +1447,7 @@ def forward(
         if mesh is not None:
             x = shd.constrain(x, mesh, "batch", "seq", None)
 
-    if attn_impl == "auto":
-        if mesh is not None and mesh.shape.get("sp", 1) > 1:
-            # a sequence-parallel mesh MUST use the shard_map sp paths:
-            # letting GSPMD partition a plain attention over seq-sharded
-            # q/k/v ends in "involuntary full rematerialization" (a
-            # replicate-then-repartition of the score matmul operands)
-            attn_impl = "ulysses"
-        else:
-            # flash (pallas) on real accelerators; the kernel's
-            # interpret path is far slower than plain jnp on CPU
-            attn_impl = (
-                "reference" if device.on_cpu() else "flash"
-            )
+    attn_impl = _resolve_attn_impl(attn_impl, mesh)
 
     if cfg.qk_norm and mesh is not None and mesh.shape.get("tp", 1) > 1:
         raise ValueError(
@@ -1423,13 +1474,14 @@ def forward(
 
     def attn_fn(q, k, v, selected=None):
         if selected is not None:
-            # (out, lse [B, H, S] detached) over each query's selection
+            # (out, lse [B, H, S] detached, whether the Pallas kernels
+            # are this model's attention) over each query's selection
             if attn_impl == "reference":
                 out, lse = mha_reference(
                     q, k, v, causal=True, selected=selected,
                     return_lse=True,
                 )
-                return out, jax.lax.stop_gradient(lse)
+                return out, jax.lax.stop_gradient(lse), False
             if attn_impl != "flash":
                 raise ValueError(
                     f"attn_impl {attn_impl!r} takes no selection of keys "
@@ -1437,10 +1489,10 @@ def forward(
                 )
             from dlrover_tpu.ops.pallas_attention import flash_attention
 
-            return flash_attention(
+            return *flash_attention(
                 q, k, v, causal=True, block_q=cfg.attn_block_q,
                 block_k=cfg.attn_block_k, selected=selected,
-            )
+            ), True
         if attn_impl == "ring":
             from dlrover_tpu.parallel.sequence import ring_attention
 

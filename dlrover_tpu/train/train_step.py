@@ -1201,6 +1201,11 @@ class TrainStepBuilder:
             # which path the step took. Trace time, a value: a retrace
             # sets the same number again
             set_counter("attn.align_passes", decoder.alignment_passes(cfg))
+            set_counter("attn.align_in_kernel", int(
+                decoder.alignment_in_kernel(
+                    cfg, batch["tokens"].shape[-1], self.attn_impl, self.mesh
+                )
+            ))
         if self.update_sharding:
             return self._sharded_step_fn(state, batch)
         batch = jax.tree.map(

@@ -196,50 +196,112 @@ def test_gradients_term_by_term(model):
     close(idx_only_index, ref_index, "indexer_loss alone")
 
 
+# widths at which the alignment kernel's tiles fit (index heads of 64,
+# chunks of 128): 256 tokens in two chunks, the first under index_topk
+KERNEL_FIT = dict(
+    index_head_dim=64, index_chunk=128, index_topk=160, max_seq=256
+)
+
+
+def _engage(monkeypatch, path):
+    """(configuration overrides, sequence, loss_fn's keywords) for the
+    alignment term's two back ends: the jnp rule as the CPU runs it, or
+    the Pallas kernels interpreted under ``attn_impl="flash"``."""
+    if path == "jnp":
+        return {}, 128, {}
+    monkeypatch.setattr(pallas_attention, "INTERPRET", True)
+    return KERNEL_FIT, 256, {"attn_impl": "flash"}
+
+
+@pytest.mark.parametrize("path", ["jnp", "kernel"])
 @pytest.mark.parametrize("remat", ["full", "none"])
-def test_alignment_term_is_made_once_a_step(remat):
-    """The gradient program of the toy model (3 layers, 2 x 128 tokens
-    in 4 chunks of 32, 2 kv groups): the alignment term's rule tags its
-    derivative ``attn_align_grad`` (for qi, ki and w) once, the forward
-    scan hands the three on stacked over the layers, and the
-    attention-side score product of ``_chunk_kl`` (``bqrd,bkd->brqk``:
-    [B, keys, 32, G] before its transpose) stands once a chunk and kv
-    group in the program, 8 times: under ``remat: full`` the recomputed
-    forward makes none again (16 before the derivative was a kept
-    residual), under ``remat: none`` nothing is recomputed."""
+def test_alignment_term_is_made_once_a_step(monkeypatch, remat, path):
+    """The gradient program of the toy model (3 layers, 2 sequences in
+    chunks, 2 kv groups): the alignment term's rule tags its derivative
+    ``attn_align_grad`` (for qi, ki and w) once, and the forward scan
+    hands the three on stacked over the layers. On the jnp rule (128
+    tokens in 4 chunks of 32) the attention-side score product of
+    ``_chunk_target`` (``bqrd,bkd->brqk``: [B, keys, 32, G] before its
+    transpose) stands once a chunk and kv group in the program, 8
+    times: under ``remat: full`` the recomputed forward makes none
+    again (16 before the derivative was a kept residual), under
+    ``remat: none`` nothing is recomputed. With the kernels engaged
+    (256 tokens in 2 chunks of 128) the kernel ``align_kl`` stands once
+    in the program, beside the flash forward, and no score product of
+    the rule's at all."""
     import re
 
-    cfg = _cfg(remat=remat, n_layer=3)
+    over, seq, kw = _engage(monkeypatch, path)
+    cfg = _cfg(remat=remat, n_layer=3, **over)
     params = decoder.init(jax.random.key(0), cfg)
-    batch = _batch(cfg)
+    batch = _batch(cfg, seq=seq)
     text = str(jax.make_jaxpr(
-        jax.grad(lambda p: decoder.loss_fn(p, batch, cfg)[0])
+        jax.grad(lambda p: decoder.loss_fn(p, batch, cfg, **kw)[0])
     )(params))
     assert text.count("name=attn_align_grad") == 3
-    for stacked in ("f32[3,2,128,4,16]", "f32[3,2,128,16]", "f32[3,2,128,4]"):
+    nc = cfg.index_head_dim
+    for stacked in (
+        f"f32[3,2,{seq},4,{nc}]", f"f32[3,2,{seq},{nc}]", f"f32[3,2,{seq},4]"
+    ):
         assert stacked in text
     products = re.findall(r":f32\[2,(\d+),32,2\] = dot_general\[", text)
-    assert sorted(map(int, products)) == [32, 32, 64, 64, 96, 96, 128, 128]
+    kernels = re.findall(r"name=(align_kl|flash_fwd_sel)\b", text)
+    if path == "jnp":
+        assert sorted(map(int, products)) == [32, 32, 64, 64, 96, 96, 128, 128]
+        assert not kernels
+    else:
+        assert not products
+        # the flash forward once more where the layer is recomputed
+        assert sorted(kernels) == ["align_kl"] + ["flash_fwd_sel"] * (
+            2 if remat == "full" else 1
+        )
+    assert decoder.alignment_in_kernel(cfg, seq, **kw) == (path == "kernel")
     assert decoder.alignment_passes(cfg) == 1
     assert decoder.alignment_passes(_cfg(remat="save_attn")) == 2
 
 
-def test_remat_full_gives_what_remat_none_gives(model):
+@pytest.mark.parametrize("case", ["cpu", "reference", "odd_shapes", "fits"])
+def test_which_alignment_path_runs(monkeypatch, case):
+    """The kernel where the attention runs the Pallas kernels and the
+    shapes fit its tiles, the jnp rule everywhere else: on the CPU
+    (nothing interpreted), under ``attn_impl == "reference"``, and at
+    widths the tiles do not fit (index heads of 16, chunks of 32)."""
+    if case != "cpu":
+        monkeypatch.setattr(pallas_attention, "INTERPRET", True)
+    cfg = _cfg(**({} if case == "odd_shapes" else KERNEL_FIT))
+    impl = "reference" if case == "reference" else "flash"
+    assert decoder.alignment_in_kernel(cfg, 256, impl) == (case == "fits")
+    assert decoder.alignment_in_kernel(cfg, 256) is False  # auto: the CPU
+    assert (decoder.alignment_tiles(cfg, 256) is None) == (
+        case in ("cpu", "odd_shapes")
+    )
+    # a sequence the chunks do not divide takes the rule too
+    assert decoder.alignment_tiles(_cfg(**KERNEL_FIT), 192) is None
+
+
+@pytest.mark.parametrize("path", ["jnp", "kernel"])
+def test_remat_full_gives_what_remat_none_gives(monkeypatch, model, path):
     """Loss, ``indexer_loss`` and every parameter's gradient of the toy
     model under ``remat: full`` (the selection and the alignment term's
     derivative kept, everything else recomputed) against ``remat:
-    none``. The two losses are the same forward: bit-equal. The
-    gradients read bit-equal too on this CPU (before the derivative was
-    kept they differed by 1.9e-9); the limit is 1e-6 of a leaf's largest
-    entry, float32 rounding of a forward recomputed in another fusion,
-    so that another build of XLA does not fail it."""
+    none``, on the jnp rule and with the kernels engaged (interpreted).
+    The two losses are the same forward: bit-equal. The gradients read
+    bit-equal too on this CPU (before the derivative was kept they
+    differed by 1.9e-9); the limit is 1e-6 of a leaf's largest entry,
+    float32 rounding of a forward recomputed in another fusion, so that
+    another build of XLA does not fail it."""
     cfg, params = model
-    batch = _batch(cfg)
+    over, seq, kw = _engage(monkeypatch, path)
+    if over:
+        cfg = _cfg(**over)
+        params = decoder.init(jax.random.key(0), cfg)
+    batch = _batch(cfg, seq=seq)
     got = {}
     for remat in ("full", "none"):
         cfg_r = dataclasses.replace(cfg, remat=remat)
         got[remat] = jax.jit(jax.value_and_grad(
-            lambda p, c=cfg_r: decoder.loss_fn(p, batch, c), has_aux=True
+            lambda p, c=cfg_r: decoder.loss_fn(p, batch, c, **kw),
+            has_aux=True,
         ))(params)
     (loss_f, metrics_f), grads_f = got["full"]
     (loss_n, metrics_n), grads_n = got["none"]
@@ -249,10 +311,123 @@ def test_remat_full_gives_what_remat_none_gives(model):
     flat_f = jax.tree_util.tree_leaves_with_path(grads_f)
     flat_n = jax.tree.leaves(grads_n)
     assert len(flat_f) == len(flat_n)
-    for (path, g), w in zip(flat_f, flat_n):
+    for (path_, g), w in zip(flat_f, flat_n):
         scale = float(jnp.max(jnp.abs(w))) or 1.0
         err = float(jnp.max(jnp.abs(g - w))) / scale
-        assert err < 1e-6, (jax.tree_util.keystr(path), err)
+        assert err < 1e-6, (jax.tree_util.keystr(path_), err)
+
+
+def test_train_step_says_which_alignment_path():
+    """The train step sets ``attn.align_in_kernel`` beside
+    ``attn.align_passes`` while it is traced: 0 on the CPU, where the
+    jnp rule runs (``tests/test_tpu_compile.py`` reads 1 from the step
+    compiled for the chip)."""
+    from dlrover_tpu.observability import tracing
+    from dlrover_tpu.parallel import MeshConfig, build_mesh
+    from dlrover_tpu.train import (
+        TrainStepBuilder, batch_sharding, make_optimizer,
+    )
+    from dlrover_tpu.train.train_step import abstract_train_state
+
+    cfg = _cfg(**KERNEL_FIT)
+    mesh = build_mesh(MeshConfig(dp=-1), devices=jax.devices()[:1])
+    opt = make_optimizer(learning_rate=1e-4, warmup_steps=1, decay_steps=10)
+    builder = TrainStepBuilder(cfg, mesh, opt)
+    state = abstract_train_state(cfg, mesh, opt, comm=builder.comm_resolved)
+    batch = {
+        k: jax.ShapeDtypeStruct(
+            (2, 256), jnp.int32, sharding=batch_sharding(mesh)
+        )
+        for k in ("tokens", "targets")
+    }
+    tracing._counters.clear()
+    builder.build().lower(state, batch)
+    counters = tracing.counters()
+    assert counters["attn.align_in_kernel"] == 0
+    assert counters["attn.align_passes"] == 1
+
+
+ALIGN_CASES = {
+    # four chunks of 32 under index_topk 40: the first takes every
+    # visible key, the others cut; one sequence, two kv groups
+    "chunks": dict(rows=1),
+    "batch2": dict(rows=2),
+    # attention so peaked that p underflows to exact zeros on keys the
+    # indexer selected: 0 log 0 = 0, and dI = softmax(I) there
+    "p_zeros": dict(rows=1, sharpen=60.0),
+    # every third query's first index head has no positive product: the
+    # ReLU's mask is all zero there, d_qi and d_w exactly zero
+    "relu_dead": dict(rows=1, dead_head=True),
+    # tiles smaller than a chunk: two query blocks a chunk, two key
+    # blocks a chunk
+    "small_tiles": dict(rows=2, block=16),
+}
+_ALIGNED = {}
+
+
+def _aligned(case, monkeypatch):
+    """(jnp rule's, kernel's) (value, d_qi, d_ki, d_w) of one of
+    ALIGN_CASES at the file's widths (4 index heads of 16, 4 / 2
+    attention heads of 64, 128 tokens), the kernel interpreted."""
+    if case in _ALIGNED:
+        return _ALIGNED[case]
+    spec = ALIGN_CASES[case]
+    cfg = _cfg()
+    b, s, block = spec["rows"], 128, spec.get("block", 32)
+    keys = jax.random.split(jax.random.key(11), 7)
+    nj, nc, hd = cfg.index_n_heads, cfg.index_head_dim, cfg.head_dim
+    qi = jax.random.normal(keys[0], (b, s, nj, nc))
+    ki = jax.random.normal(keys[1], (b, s, nc))
+    w = jax.random.normal(keys[2], (b, s, nj)) * (nj * nc) ** -0.5
+    q = jax.random.normal(keys[3], (b, s, cfg.n_head, hd))
+    k = jax.random.normal(keys[4], (b, s, cfg.kv_heads, hd))
+    v = jax.random.normal(keys[5], (b, s, cfg.kv_heads, hd))
+    if spec.get("dead_head"):
+        ki = jnp.abs(ki)
+        qi = qi.at[:, ::3, 0].set(-jnp.abs(qi[:, ::3, 0]))
+    q = q * spec.get("sharpen", 1.0)
+    mask = decoder._select(qi, ki, w, cfg)
+    _, lse = mha_reference(
+        q, k, v, causal=True, selected=mask, return_lse=True
+    )
+    scale = hd ** -0.5
+    operands = (qi, ki, w, q, k, lse, mask)
+    if spec.get("sharpen"):
+        p = decoder._chunk_target(q, k, lse, mask, scale)
+        assert int(jnp.sum((mask != 0) & (p == 0))) > 100
+    monkeypatch.setattr(pallas_attention, "INTERPRET", True)
+    with jax.default_matmul_precision("highest"):
+        want, (want_grads, *_) = decoder._alignment_kl_fwd(
+            *operands, cfg, scale, None
+        )
+        got, (got_grads, *_) = decoder._alignment_kl_fwd(
+            *operands, cfg, scale, (cfg.index_chunk, block, block)
+        )
+    if spec.get("dead_head"):
+        assert float(jnp.max(jnp.abs(want_grads[0][:, ::3, 0]))) == 0
+        assert float(jnp.max(jnp.abs(got_grads[0][:, ::3, 0]))) == 0
+        assert float(jnp.max(jnp.abs(got_grads[2][:, ::3, 0]))) == 0
+    _ALIGNED[case] = (want, *want_grads), (got, *got_grads)
+    return _ALIGNED[case]
+
+
+@pytest.mark.parametrize("what", ["value", "d_qi", "d_ki", "d_w"])
+@pytest.mark.parametrize("case", sorted(ALIGN_CASES))
+def test_alignment_kernel_against_the_jnp_rule(monkeypatch, case, what):
+    """The alignment kernel (``ops/pallas_align.py``, interpreted)
+    against the jnp rule ``_alignment_kl_fwd`` it stands in for: the
+    value to 1e-5 of it, each derivative to 2e-4 of its largest entry
+    (``test_gradients_term_by_term``'s limits; float32 reads 1e-6)."""
+    want, got = _aligned(case, monkeypatch)
+    i = ("value", "d_qi", "d_ki", "d_w").index(what)
+    assert got[i].shape == want[i].shape and got[i].dtype == want[i].dtype
+    if what == "value":
+        assert float(want[0]) > 1.0
+        assert float(got[0]) == pytest.approx(float(want[0]), rel=1e-5)
+        return
+    scale = float(jnp.max(jnp.abs(want[i])))
+    assert scale > 0
+    assert float(jnp.max(jnp.abs(got[i] - want[i]))) / scale < 2e-4
 
 
 def _sorted_selection(index, qpos, k):
