@@ -150,10 +150,11 @@ class CompileRecorder:
     def first_build(self) -> None:
         """Called where the step builder is constructed: the first call
         closes ``setup.before_build_s`` (interpreter, imports, the
-        runtime's start, the mesh)."""
+        runtime's start, the mesh); every call SETS that one value, so a
+        counter table emptied since holds it again."""
         if self.before_build_s is None:
             self.before_build_s = _process_age_s()
-            self._set_counter("setup.before_build_s", self.before_build_s)
+        self._set_counter("setup.before_build_s", self.before_build_s)
 
     # ---- jax's listeners -----------------------------------------------
 

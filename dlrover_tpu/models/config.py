@@ -21,8 +21,10 @@ class ModelConfig:
     max_seq: int = 256
     # architecture family
     norm: str = "rmsnorm"            # rmsnorm | layernorm
-    act: str = "swiglu"              # swiglu | gelu
-    pos: str = "rope"                # rope | learned
+    # swiglu | gelu | relu2 (two matrices around relu(.)², no gate: the
+    # experts and the shared expert of a ``layer_pattern`` model)
+    act: str = "swiglu"
+    pos: str = "rope"                # rope | learned | none
     # False = bidirectional attention (BERT-family encoders; the TP/SP
     # machinery is identical — same weights, different mask)
     causal: bool = True
@@ -164,6 +166,42 @@ class ModelConfig:
     # queries the indexer scores at a time (``sa_config``'s chunk): no
     # float [B, S, S] of index scores is ever whole
     index_chunk: int = 512
+    # a trunk whose layers are ONE part each, x <- x + part(norm(x))
+    # (NemotronH's ``hybrid_override_pattern``; "" = every layer an
+    # attention and an MLP): one letter a layer, ``M`` a Mamba-2 mixer,
+    # ``*`` an attention, ``E`` the routed experts. Parameters are
+    # stacked kind by kind and visited in this order. ``mtp_pattern``
+    # is the prediction module's layers, likewise. Training path only
+    layer_pattern: str = ""
+    mtp_pattern: str = ""
+    # the Mamba-2 mixer (``M``): ``mamba_num_heads`` heads of
+    # ``mamba_head_dim`` channels (d_inner their product), a state of
+    # ``ssm_state_size`` a channel, B and C shared by the heads of each
+    # of ``n_groups`` groups, a causal depthwise conv of ``conv_kernel``
+    # taps over [x | B | C], the scan in chunks of ``ssm_chunk`` tokens
+    # (ops/ssd.py), ``ssm_head_block`` heads at a time (0 = all at
+    # once; a multiple or a divisor of a group's heads), a gated RMSNorm over each group at ``ssm_norm_eps``. The
+    # time steps are drawn log-uniform in [time_step_min, time_step_max]
+    # and floored, A uniform in [1, 16], D = 1
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    ssm_state_size: int = 0
+    n_groups: int = 1
+    conv_kernel: int = 4
+    ssm_chunk: int = 128
+    ssm_head_block: int = 0
+    ssm_norm_eps: float = 1e-5
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    # a latent around the routed experts (LatentMoE; 0 = none): the
+    # router and the shared expert read the d_model-wide input, the
+    # experts a projection of it to this width, and one projection back
+    # follows the combine. Ragged lowering only
+    moe_latent_size: int = 0
+    # width of the shared expert where it is not n_shared_experts ·
+    # d_expert (0 = that)
+    d_shared_expert: int = 0
     # pipeline microbatches when the mesh has pp > 1 (0 → one per stage)
     pp_microbatches: int = 0
     # interleaved (circular) pipeline: v layer chunks per stage cut the
@@ -222,6 +260,20 @@ class ModelConfig:
             raise ValueError(
                 f"moe_score must be 'softmax' or 'sigmoid', got "
                 f"{self.moe_score!r}"
+            )
+        if self.act not in ("swiglu", "gelu", "relu2"):
+            raise ValueError(f"unknown act {self.act!r}")
+        if self.pos not in ("rope", "learned", "none"):
+            raise ValueError(f"unknown pos {self.pos!r}")
+        if self.layer_pattern:
+            self._check_pattern()
+        elif (
+            self.mtp_pattern or self.act == "relu2" or self.moe_latent_size
+            or self.pos == "none"
+        ):
+            raise ValueError(
+                "mtp_pattern, act='relu2', moe_latent_size and pos='none' "
+                "belong to a layer_pattern model"
             )
         if self.n_experts_held:
             if self.moe_impl != "ragged":
@@ -336,9 +388,84 @@ class ModelConfig:
                     "attn_window and prefix_lm are mutually exclusive"
                 )
 
+    def _check_pattern(self):
+        """A ``layer_pattern`` model: what its letters need."""
+        for name in ("layer_pattern", "mtp_pattern"):
+            odd = set(getattr(self, name)) - set("M*E")
+            if odd:
+                raise ValueError(
+                    f"{name} is made of M (Mamba-2), * (attention) and E "
+                    f"(routed experts); got {sorted(odd)}"
+                )
+        if len(self.layer_pattern) != self.n_layer:
+            raise ValueError(
+                f"layer_pattern names {len(self.layer_pattern)} layers, "
+                f"n_layer is {self.n_layer}"
+            )
+        if bool(self.mtp_pattern) != bool(self.n_mtp_module):
+            raise ValueError(
+                "a layer_pattern model's prediction module runs "
+                "mtp_pattern: both or neither"
+            )
+        letters = self.layer_pattern + self.mtp_pattern
+        if "M" in letters:
+            sizes = (
+                self.mamba_num_heads, self.mamba_head_dim,
+                self.ssm_state_size, self.n_groups, self.conv_kernel,
+                self.ssm_chunk,
+            )
+            if not all(n > 0 for n in sizes) or (
+                self.mamba_num_heads % self.n_groups
+            ):
+                raise ValueError(
+                    "a Mamba-2 layer needs mamba_num_heads (a multiple "
+                    "of n_groups), mamba_head_dim, ssm_state_size, "
+                    "conv_kernel and ssm_chunk"
+                )
+        if "E" in letters and not (
+            self.n_experts and self.moe_impl == "ragged"
+        ):
+            raise ValueError(
+                "an E layer is the ragged (dropless) routed block: "
+                "n_experts > 0 and moe_impl='ragged'"
+            )
+        if (
+            self.n_dense_layer or self.latent_attention or self.selects_keys
+            or self.parallel_residual or self.prefix_lm or self.fp8
+            or self.norm != "rmsnorm" or self.pos == "learned"
+            or self.qk_norm or self.qk_head_norm
+        ):
+            raise ValueError(
+                "a layer_pattern model is RMSNorm layers of one part "
+                "each: no dense prefix, latent attention, key selection, "
+                "parallel residual, prefix-LM, fp8, position table or "
+                "q/k norm"
+            )
+
     @property
     def kv_heads(self) -> int:
         return self.n_kv_head or self.n_head
+
+    @property
+    def d_inner(self) -> int:
+        """Channels of a Mamba-2 mixer."""
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels the mixer's conv runs over: [x | B | C]."""
+        return self.d_inner + 2 * self.n_groups * self.ssm_state_size
+
+    @property
+    def shared_expert_width(self) -> int:
+        return self.d_shared_expert or (
+            self.n_shared_experts * self.expert_width
+        )
+
+    @property
+    def expert_in(self) -> int:
+        """Width of the rows the routed experts read and write."""
+        return self.moe_latent_size or self.d_model
 
     @property
     def latent_attention(self) -> bool:
@@ -385,6 +512,8 @@ class ModelConfig:
     def n_routed_layer(self) -> int:
         """Routed layers of the trunk (the prediction module's block is
         not among them)."""
+        if self.layer_pattern:
+            return self.layer_pattern.count("E")
         return self.n_layer - self.n_dense_layer if self.n_experts else 0
 
     @property
@@ -392,6 +521,10 @@ class ModelConfig:
         """Why the cache, paged, pipeline and generate paths cannot run
         this model ("" where they can): they are written for one stack
         of plain-attention layers."""
+        if "M" in self.layer_pattern + self.mtp_pattern:
+            return "state-space layers: no recurrent state beside the cache"
+        if self.layer_pattern:
+            return "a trunk whose layers differ"
         if self.latent_attention:
             return "latent attention (no latent cache is built)"
         if self.n_dense_layer:
@@ -407,6 +540,43 @@ class ModelConfig:
         """Experts a token is sent to: switch gating is top-1."""
         return 1 if self.moe_gating == "switch" else self.expert_top_k
 
+    def _part_counts(self):
+        """letter -> (parameters held here, parameters a token is
+        multiplied by) of one layer of a ``layer_pattern`` model. The
+        scan's state update and read-out (2 · heads · head_dim · state
+        multiply-adds a token) are entered among the multiplied, as the
+        benchmark's reference counts them; the conv's taps are not."""
+        d = self.d_model
+        inner, heads = self.d_inner, self.mamba_num_heads
+        attn = (
+            2 * d * self.n_head * self.head_dim
+            + 2 * d * self.kv_heads * self.head_dim
+        )
+        w_in = d * (inner + self.conv_dim + heads)
+        mamba = w_in + inner * d
+        mats = 2 if self.act == "relu2" else 3  # matrices of an expert
+        outside = (
+            d * self.n_experts + 2 * d * self.moe_latent_size
+            + mats * d * self.shared_expert_width
+            * bool(self.n_shared_experts)
+        )
+        expert = mats * self.expert_in * self.expert_width
+        return {
+            "M": (
+                mamba + self.conv_dim * (self.conv_kernel + 1)
+                + 3 * heads + inner + d,
+                mamba + 2 * inner * self.ssm_state_size,
+            ),
+            "*": (attn + d, attn),
+            "E": (
+                outside + self.experts_here * expert + d,
+                outside + (
+                    self.routed_top_k * self.experts_here
+                    / max(self.n_experts, 1)
+                ) * expert,
+            ),
+        }
+
     def num_params(self) -> int:
         """Approximate parameter count. A routed layer of a model of one
         kind is counted as one MLP of ``d_ff`` (the dense part); a model
@@ -414,6 +584,15 @@ class ModelConfig:
         ``d_ff``, each routed block's held and shared experts and
         router, and the prediction module."""
         d, f, v, L = self.d_model, self.d_ff, self.vocab_size, self.n_layer
+        if self.layer_pattern:
+            held = {k: n for k, (n, _) in self._part_counts().items()}
+            mtp = self.n_mtp_module * (
+                2 * d * d + 3 * d + sum(held[c] for c in self.mtp_pattern)
+            )
+            return (
+                sum(held[c] for c in self.layer_pattern) + mtp
+                + v * d * (1 if self.tie_embeddings else 2) + d
+            )
         if self.latent_attention:
             attn = (
                 d * self.q_lora_rank
@@ -469,9 +648,23 @@ class ModelConfig:
         projection, block and the head once more. A layer that selects
         ``index_topk`` keys counts min(i + 1, k) keys a query, and its
         indexer's projections and heads x channels / 2 over every
-        visible key. Recomputation does not count."""
+        visible key. Recomputation does not count. A ``layer_pattern``
+        model counts each layer's one part (``_part_counts``) and the
+        pairs of its attention layers alone."""
         d = self.d_model
         d_attn = self.n_head * self.head_dim
+        if self.layer_pattern:
+            met = {k: n for k, (_, n) in self._part_counts().items()}
+            head = d * self.vocab_size
+            multiplied = (
+                sum(met[c] for c in self.layer_pattern) + head
+                + self.n_mtp_module * (
+                    2 * d * d + head + sum(met[c] for c in self.mtp_pattern)
+                )
+            )
+            attn_layers = (self.layer_pattern + self.mtp_pattern).count("*")
+            span = (seq_len + 1) / 2 if self.causal else seq_len
+            return 6.0 * multiplied + 12.0 * attn_layers * d_attn * span
         if self.latent_attention:
             attn = (
                 d * self.q_lora_rank + self.q_lora_rank * d_attn
@@ -769,6 +962,55 @@ CONFIGS = {
         moe_impl="ragged",
         moe_renorm_topk=True,
         moe_aux_coef=0.001,
+    ),
+    # state-space layers, one attention layer in eleven and experts in
+    # a latent: NVIDIA-Nemotron-3-Super-120B-A12B (``nemotron_h``,
+    # huggingface.co/nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16
+    # config.json) — 88 layers of ONE part each by
+    # ``hybrid_override_pattern``: 40 Mamba-2 mixers (128 heads x 64,
+    # 8 groups, state 128, conv 4, chunks of 128), 8 attentions (GQA
+    # 32 / 2 heads of 128, no rope), 40 LatentMoE blocks (sigmoid top-22
+    # of 512 relu² experts of width 2688 in a 1024-wide latent,
+    # renormalised x 5, beside a shared relu² expert of 5376 on the full
+    # width); one prediction module of an attention and a routed layer.
+    # Training path only
+    "nemotron-3-super": ModelConfig(
+        name="nemotron-3-super",
+        vocab_size=131072,
+        n_layer=88,
+        layer_pattern=(
+            "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+            "EMEMEMEMEM*EMEMEMEMEM*EMEMEMEM*EMEMEMEME"
+        ),
+        mtp_pattern="*E",
+        n_head=32,
+        n_kv_head=2,
+        d_head=128,
+        d_model=4096,
+        d_ff=2688,  # intermediate_size: published, and no layer uses it
+        max_seq=262144,
+        act="relu2",
+        pos="none",
+        attn_window=None,  # sliding_window: null
+        tie_embeddings=False,
+        mamba_num_heads=128,
+        mamba_head_dim=64,
+        ssm_state_size=128,
+        n_groups=8,
+        conv_kernel=4,
+        ssm_chunk=128,
+        ssm_head_block=16,
+        n_experts=512,
+        expert_top_k=22,
+        d_expert=2688,
+        moe_latent_size=1024,
+        n_shared_experts=1,
+        d_shared_expert=5376,
+        moe_impl="ragged",
+        moe_score="sigmoid",
+        moe_renorm_topk=True,
+        routed_scaling_factor=5.0,
+        n_mtp_module=1,
     ),
 }
 

@@ -46,13 +46,10 @@ def _dense_init(key, shape, in_axis_size, dtype):
     return (jax.random.normal(key, shape) * scale).astype(dtype)
 
 
-def _init_layers(keys, cfg: ModelConfig, lead, routed: bool) -> Params:
-    """One kind of layer, its tensors stacked on the leading axes
-    ``lead`` (``()`` = one layer): attention, the two norms and either a
-    dense MLP of ``d_ff`` or the routed block — never both."""
+def _stackers(cfg: ModelConfig, lead):
+    """(stack(key, shape, fan_in), ones(*shape)): one kind of layer's
+    tensors on the leading axes ``lead``, in the parameter dtype."""
     pdt = jnp.dtype(cfg.param_dtype)
-    d, f = cfg.d_model, cfg.d_ff
-    hd, nh, nkv = cfg.head_dim, cfg.n_head, cfg.kv_heads
     lead = tuple(lead)
 
     def stack(key, shape, fan_in):
@@ -63,6 +60,14 @@ def _init_layers(keys, cfg: ModelConfig, lead, routed: bool) -> Params:
     def ones(*shape):
         return jnp.ones(lead + shape, pdt)
 
+    return stack, ones
+
+
+def _init_attention(keys, cfg: ModelConfig, stack, ones) -> Params:
+    """The attention's matrices (latent or plain q/k/v/o), drawn from
+    ``keys`` through ``stack(key, shape, fan_in)``."""
+    d = cfg.d_model
+    hd, nh, nkv = cfg.head_dim, cfg.n_head, cfg.kv_heads
     if cfg.latent_attention:
         rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
         nope, rd, vd = (
@@ -86,6 +91,17 @@ def _init_layers(keys, cfg: ModelConfig, lead, routed: bool) -> Params:
             "wv": stack(keys[3], (d, nkv * hd), d),
             "wo": stack(keys[4], (nh * hd, d), nh * hd),
         }
+    return attn
+
+
+def _init_layers(keys, cfg: ModelConfig, lead, routed: bool) -> Params:
+    """One kind of layer, its tensors stacked on the leading axes
+    ``lead`` (``()`` = one layer): attention, the two norms and either a
+    dense MLP of ``d_ff`` or the routed block — never both."""
+    d, f = cfg.d_model, cfg.d_ff
+    hd, nh, nkv = cfg.head_dim, cfg.n_head, cfg.kv_heads
+    stack, ones = _stackers(cfg, lead)
+    attn = _init_attention(keys, cfg, stack, ones)
     layers: Params = {
         "attn": attn,
         "ln1": {"scale": ones(d)},
@@ -122,16 +138,85 @@ def _init_layers(keys, cfg: ModelConfig, lead, routed: bool) -> Params:
             "k_norm": {"scale": ones(nc)},
         }
     if cfg.norm == "layernorm":
-        layers["ln1"]["bias"] = jnp.zeros(lead + (d,), pdt)
-        layers["ln2"]["bias"] = jnp.zeros(lead + (d,), pdt)
+        layers["ln1"]["bias"] = jnp.zeros_like(layers["ln1"]["scale"])
+        layers["ln2"]["bias"] = jnp.zeros_like(layers["ln2"]["scale"])
     return layers
+
+
+# a ``layer_pattern`` letter -> the name its stack of layers goes by
+PART_NAMES = {"M": "mamba", "*": "attention", "E": "experts"}
+
+
+def _init_mamba(key, cfg: ModelConfig, lead) -> Params:
+    """A Mamba-2 mixer's parameters as published: ``A`` uniform in
+    [1, 16] (kept as its log), the time step log-uniform in
+    [time_step_min, time_step_max], floored, through the inverse
+    softplus into ``dt_bias``, ``D`` = 1. Normal draws there would give
+    decays no model has."""
+    d, inner, heads = cfg.d_model, cfg.d_inner, cfg.mamba_num_heads
+    taps, conv = cfg.conv_kernel, cfg.conv_dim
+    stack, ones = _stackers(cfg, lead)
+    pdt = jnp.dtype(cfg.param_dtype)
+    lead = tuple(lead)
+    k = jax.random.split(key, 6)
+    lo, hi = np.log(cfg.time_step_min), np.log(cfg.time_step_max)
+    step = jnp.maximum(
+        jnp.exp(jax.random.uniform(k[2], lead + (heads,)) * (hi - lo) + lo),
+        cfg.time_step_floor,
+    )
+    bound = 1.0 / np.sqrt(taps)  # a depthwise tap sees ``taps`` inputs
+
+    def uniform(key, shape):
+        return jax.random.uniform(
+            key, lead + shape, minval=-bound, maxval=bound
+        ).astype(pdt)
+
+    return {
+        # [z | x | B | C | dt]
+        "w_in": stack(k[0], (d, inner + conv + heads), d),
+        "conv_w": uniform(k[3], (taps, conv)),
+        "conv_b": uniform(k[4], (conv,)),
+        "a_log": jnp.log(
+            jax.random.uniform(k[1], lead + (heads,), minval=1.0, maxval=16.0)
+        ).astype(pdt),
+        # softplus(dt_bias) = step
+        "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(pdt),
+        "d_skip": ones(heads),
+        "norm": {"scale": ones(inner)},
+        "w_out": stack(k[5], (inner, d), inner),
+    }
+
+
+def _init_pattern(key, cfg: ModelConfig, pattern: str) -> Params:
+    """The layers ``pattern`` names, each kind stacked by itself under
+    its ``PART_NAMES`` name: one norm and one part a layer."""
+    out: Params = {}
+    for i, letter in enumerate(sorted(set(pattern))):
+        lead = (pattern.count(letter),)
+        kk = jax.random.fold_in(key, i)
+        stack, ones = _stackers(cfg, lead)
+        layer: Params = {"ln": {"scale": ones(cfg.d_model)}}
+        if letter == "M":
+            layer["ssm"] = _init_mamba(kk, cfg, lead)
+        elif letter == "*":
+            layer["attn"] = _init_attention(
+                jax.random.split(kk, 16), cfg, stack, ones
+            )
+        else:
+            from dlrover_tpu.parallel.moe import init_moe_params
+
+            layer["moe"] = init_moe_params(kk, cfg, lead)
+        out[PART_NAMES[letter]] = layer
+    return out
 
 
 def init(rng: jax.Array, cfg: ModelConfig) -> Params:
     """Initialise parameters; per-layer tensors stacked on axis 0, each
     kind of layer by itself: ``layers`` (every layer of a model of one
     kind; the routed layers of a model with a dense prefix) and
-    ``dense_layers`` (that prefix). A routed layer has no dense ``mlp``."""
+    ``dense_layers`` (that prefix). A routed layer has no dense ``mlp``.
+    A ``layer_pattern`` model's ``layers`` (and its module's ``block``)
+    hold one stack a kind of part (``_init_pattern``)."""
     pdt = jnp.dtype(cfg.param_dtype)
     d, v = cfg.d_model, cfg.vocab_size
     keys = jax.random.split(rng, 16)
@@ -140,8 +225,12 @@ def init(rng: jax.Array, cfg: ModelConfig) -> Params:
         "embed": {
             "tokens": (jax.random.normal(keys[0], (v, d)) * 0.02).astype(pdt)
         },
-        "layers": _init_layers(
-            keys, cfg, (cfg.n_layer - n_dense,), routed=cfg.n_experts > 0
+        "layers": (
+            _init_pattern(keys[15], cfg, cfg.layer_pattern)
+            if cfg.layer_pattern
+            else _init_layers(
+                keys, cfg, (cfg.n_layer - n_dense,), routed=cfg.n_experts > 0
+            )
         ),
         "final_norm": {"scale": jnp.ones((d,), pdt)},
     }
@@ -169,15 +258,18 @@ def init(rng: jax.Array, cfg: ModelConfig) -> Params:
             "hnorm": dict(norm),
             # [norm(emb(t_{i+1})) ‖ norm(h_i)] -> d
             "eh_proj": _dense_init(mk[16], (2 * d, d), 2 * d, pdt),
-            "block": _init_layers(mk, cfg, (), routed=cfg.n_experts > 0),
+            "block": (
+                _init_pattern(mk[15], cfg, cfg.mtp_pattern)
+                if cfg.layer_pattern
+                else _init_layers(mk, cfg, (), routed=cfg.n_experts > 0)
+            ),
             "norm": dict(norm),
         }
     return params
 
 
-def _layer_axes(cfg: ModelConfig, lead, routed: bool) -> Params:
-    """Logical axes of ``_init_layers``' tree."""
-    lead = tuple(lead)
+def _attention_axes(cfg: ModelConfig, lead) -> Params:
+    """Logical axes of ``_init_attention``'s matrices."""
     if cfg.latent_attention:
         attn = {
             "wq_a": lead + ("embed", None),
@@ -195,6 +287,40 @@ def _layer_axes(cfg: ModelConfig, lead, routed: bool) -> Params:
             "wv": lead + ("embed", "kv"),
             "wo": lead + ("heads", "embed"),
         }
+    return attn
+
+
+def _pattern_axes(cfg: ModelConfig, pattern: str, lead) -> Params:
+    """Logical axes of ``_init_pattern``'s tree."""
+    lead = tuple(lead)
+    out: Params = {}
+    for letter in set(pattern):
+        layer: Params = {"ln": {"scale": lead + ("norm",)}}
+        if letter == "M":
+            layer["ssm"] = {
+                "w_in": lead + ("embed", "mlp"),
+                "conv_w": lead + (None, "mlp"),
+                "conv_b": lead + ("mlp",),
+                "a_log": lead + (None,),
+                "dt_bias": lead + (None,),
+                "d_skip": lead + (None,),
+                "norm": {"scale": lead + ("norm",)},
+                "w_out": lead + ("mlp", "embed"),
+            }
+        elif letter == "*":
+            layer["attn"] = _attention_axes(cfg, lead)
+        else:
+            from dlrover_tpu.parallel.moe import moe_logical_axes
+
+            layer["moe"] = moe_logical_axes(cfg, lead)
+        out[PART_NAMES[letter]] = layer
+    return out
+
+
+def _layer_axes(cfg: ModelConfig, lead, routed: bool) -> Params:
+    """Logical axes of ``_init_layers``' tree."""
+    lead = tuple(lead)
+    attn = _attention_axes(cfg, lead)
     ax: Params = {
         "attn": attn,
         "ln1": {"scale": lead + ("norm",)},
@@ -236,7 +362,11 @@ def logical_axes(cfg: ModelConfig) -> Params:
     routed = cfg.n_experts > 0
     ax: Params = {
         "embed": {"tokens": ("vocab", "embed")},
-        "layers": _layer_axes(cfg, ("layers",), routed),
+        "layers": (
+            _pattern_axes(cfg, cfg.layer_pattern, ("layers",))
+            if cfg.layer_pattern
+            else _layer_axes(cfg, ("layers",), routed)
+        ),
         "final_norm": {"scale": ("norm",)},
     }
     if cfg.n_dense_layer:
@@ -255,7 +385,11 @@ def logical_axes(cfg: ModelConfig) -> Params:
             "enorm": dict(norm),
             "hnorm": dict(norm),
             "eh_proj": (None, "embed"),
-            "block": _layer_axes(cfg, (), routed),
+            "block": (
+                _pattern_axes(cfg, cfg.mtp_pattern, ("layers",))
+                if cfg.layer_pattern
+                else _layer_axes(cfg, (), routed)
+            ),
             "norm": dict(norm),
         }
     return ax
@@ -1007,6 +1141,124 @@ def _layer_body(
     return x, {**aux, **attn_aux}
 
 
+def _mamba_block(h, ssm, cfg: ModelConfig, mesh):
+    """A Mamba-2 mixer on the layer's normed input ``h`` [B, S, D]
+    (scope ``ssm``; inside it ``ssm.conv`` and ``ssm.scan``):
+
+        [z | xBC | dt] = h W_in;  xBC = silu(conv(xBC)) = [x | B | C]
+        Δ = softplus(dt + dt_bias);  A = -exp(A_log)        (float32)
+        y = scan(x, Δ, A, B, C) + D x                       (ops/ssd.py)
+        out = group_norm(y ⊙ silu(z)) W_out
+    """
+    from dlrover_tpu.ops import ssd
+
+    b, s, _ = h.shape
+    dt_ = h.dtype
+    inner, heads, hd = cfg.d_inner, cfg.mamba_num_heads, cfg.mamba_head_dim
+    g, n = cfg.n_groups, cfg.ssm_state_size
+    f32 = jnp.float32
+    proj = h @ ssm["w_in"].astype(dt_)
+    if mesh is not None:
+        proj = shd.constrain(proj, mesh, "batch", "seq", "mlp")
+    z = proj[..., :inner]
+    xbc = jax.nn.silu(ssd.causal_conv(
+        proj[..., inner:inner + cfg.conv_dim], ssm["conv_w"], ssm["conv_b"]
+    ))
+    step = jax.nn.softplus(
+        proj[..., inner + cfg.conv_dim:].astype(f32)
+        + ssm["dt_bias"].astype(f32)
+    )
+    x = xbc[..., :inner].reshape(b, s, heads, hd)
+    y = ssd.ssd_scan(
+        x, step, -jnp.exp(ssm["a_log"].astype(f32)),
+        xbc[..., inner:inner + g * n].reshape(b, s, g, n),
+        xbc[..., inner + g * n:].reshape(b, s, g, n),
+        cfg.ssm_chunk, cfg.ssm_head_block,
+    )
+    y = y + (ssm["d_skip"].astype(f32)[:, None] * x.astype(f32)).astype(dt_)
+    y = ssd.gated_group_norm(
+        y.reshape(b, s, inner), z, ssm["norm"]["scale"], g, cfg.ssm_norm_eps
+    )
+    return y @ ssm["w_out"].astype(dt_)
+
+
+def _part_body(
+    x, layer, positions, *, letter, cfg: ModelConfig, mesh, attn_fn,
+    rng=None, tag_attn_out: bool = False, rope=None,
+):
+    """One layer of a ``layer_pattern`` model, ``x + part(norm(x))``:
+    a Mamba-2 mixer (``M``), an attention (``*``) or the routed experts
+    (``E``). Returns (x, the routed block's aux or {})."""
+    aux = {}
+    scope = {"M": "ssm", "*": "attn", "E": "mlp"}[letter]
+    with jax.named_scope(scope):
+        h = _norm_block(x, layer["ln"], cfg)
+        if letter == "M":
+            out = _mamba_block(h, layer["ssm"], cfg, mesh)
+        elif letter == "*":
+            out = _attention_block(
+                h, layer, cfg, mesh, positions, attn_fn, rope=rope
+            )
+            if tag_attn_out:
+                out = _tag_residual(out, "attn_out", cfg)
+        else:
+            from dlrover_tpu.parallel.moe import moe_block
+
+            out, aux = moe_block(
+                h, layer["moe"], cfg, mesh, rng=rng, return_aux=True
+            )
+        x = x + out
+        if mesh is not None:
+            x = shd.constrain(x, mesh, "batch", "seq", None)
+    return x, aux
+
+
+def _run_pattern(
+    x, layers, pattern: str, positions, cfg: ModelConfig, mesh, attn_fn,
+    rng, tag_attn_out, first: int = 0,
+):
+    """The layers ``pattern`` names, in its order, each taken as the
+    next of its kind's stack in ``layers`` (``_init_pattern``) and run
+    through ``_part_body`` under the configured remat. The layers are
+    unrolled: neighbours differ in kind. Returns (x, aux): the routed
+    layers' scalars summed, their ``moe_choices`` stacked [E layers, B,
+    S, k] in trunk order ({} where no layer routes). ``first``: the
+    index of the pattern's first layer, folded into ``rng``."""
+    bodies = {
+        letter: _remat(
+            functools.partial(
+                _part_body, letter=letter, cfg=cfg, mesh=mesh,
+                attn_fn=attn_fn, tag_attn_out=tag_attn_out,
+            ),
+            cfg,
+        )
+        for letter in set(pattern)
+    }
+    rope = (
+        _rope_tables(positions, cfg.rope_dim, cfg.rope_theta)
+        if cfg.pos == "rope" and "*" in pattern
+        else None
+    )
+    seen = dict.fromkeys(bodies, 0)
+    auxs = []
+    for i, letter in enumerate(pattern):
+        layer = jax.tree.map(
+            lambda t: t[seen[letter]], layers[PART_NAMES[letter]]
+        )
+        seen[letter] += 1
+        r = jax.random.fold_in(rng, first + i) if rng is not None else None
+        x, aux = bodies[letter](x, layer, positions, rng=r, rope=rope)
+        if aux:
+            auxs.append(aux)
+    if not auxs:
+        return x, {}
+    stacked = jax.tree.map(lambda *ls: jnp.stack(ls), *auxs)
+    choices = stacked.pop("moe_choices")
+    return x, {
+        **jax.tree.map(lambda a: a.sum(0), stacked), "moe_choices": choices
+    }
+
+
 def _offload_names_policy(*names):
     """Checkpoint policy saving ``names`` to pinned host memory;
     everything unnamed is recomputed in backward, exactly like
@@ -1036,6 +1288,12 @@ def _remat_body(cfg: ModelConfig, mesh, attn_fn, tag_attn_out, fp8_layers,
         # args as traceable values and a str is not a valid JAX type
         **({"fp8": "current"} if fp8_layers == "current" else {}),
     )
+    return _remat(body, cfg)
+
+
+def _remat(body, cfg: ModelConfig):
+    """``body`` (a layer: ``_layer_body`` or ``_part_body``, bound to
+    its model) under the configured rematerialisation policy."""
     if cfg.remat == "full" and cfg.selects_keys:
         # everything recomputed but the selection (int8 [B, S, S] a
         # layer, against scoring and cutting every query's keys again)
@@ -1215,8 +1473,20 @@ def run_trunk(
     ``return_selected`` (a model that selects its keys): every layer's
     selection rides out in the aux, stacked bool [L, B, S, S].
 
+    A ``layer_pattern`` model's ``layers`` are its stacks kind by kind,
+    visited in the pattern's order (``_run_pattern``).
+
     Returns (hidden states [B,S,D] — pre-final-norm, aux losses).
     """
+    if cfg.layer_pattern:
+        if mesh is not None and mesh.shape.get("pp", 1) > 1:
+            _train_only_guard(cfg, "the pipeline")
+        x, aux = _run_pattern(
+            x, layers, cfg.layer_pattern, positions, cfg, mesh, attn_fn,
+            rng, tag_attn_out,
+        )
+        zero = jnp.zeros([], jnp.float32)
+        return x, {"moe_lb_loss": zero, "moe_z_loss": zero, **aux}
     body = _remat_body(
         cfg, mesh, attn_fn, tag_attn_out, fp8_layers, return_selected
     )
@@ -1599,7 +1869,8 @@ def _mtp_module(
     of ``glm4_moe_lite``'s ``num_nextn_predict_layers`` weights):
 
         h'_i = W_eh [norm(emb(t_{i+1})) ‖ norm(h_i)]
-        one block of the trunk's routed kind
+        one block of the trunk's routed kind, or the layers
+        ``mtp_pattern`` names where the trunk's are one part each
 
     with ``h`` the trunk's output before the final norm, the SHARED
     token table, and no position table added (the block has rope). The
@@ -1618,21 +1889,32 @@ def _mtp_module(
         ) @ m["eh_proj"].astype(dt)
         if mesh is not None:
             z = shd.constrain(z, mesh, "batch", "seq", None)
-        body = _remat_body(cfg, mesh, attn_fn, tag_attn_out, None)
-        rope = (
-            _rope_tables(positions, cfg.rope_dim, cfg.rope_theta)
-            if cfg.pos == "rope"
-            else None
-        )
-        r = jax.random.fold_in(rng, cfg.n_layer) if rng is not None else None
-        z, block_aux = body(z, m["block"], positions, rng=r, rope=rope)
+        if cfg.layer_pattern:
+            z, block_aux = _run_pattern(
+                z, m["block"], cfg.mtp_pattern, positions, cfg, mesh,
+                attn_fn, rng, tag_attn_out, first=cfg.n_layer,
+            )
+        else:
+            body = _remat_body(cfg, mesh, attn_fn, tag_attn_out, None)
+            rope = (
+                _rope_tables(positions, cfg.rope_dim, cfg.rope_theta)
+                if cfg.pos == "rope"
+                else None
+            )
+            r = (
+                jax.random.fold_in(rng, cfg.n_layer)
+                if rng is not None else None
+            )
+            z, block_aux = body(z, m["block"], positions, rng=r, rope=rope)
+            if "moe_choices" in block_aux:
+                block_aux["moe_choices"] = block_aux["moe_choices"][None]
     aux = dict(aux)
     choices = block_aux.pop("moe_choices", None)
     for name, value in block_aux.items():
         aux[name] = aux[name] + value
     if choices is not None:
         aux["moe_choices"] = jnp.concatenate(
-            [aux["moe_choices"], choices[None]], axis=0
+            [aux["moe_choices"], choices], axis=0
         )
     aux["mtp_features"] = z
     return aux

@@ -1,0 +1,185 @@
+"""Mamba-2's selective state-space scan in its chunked
+(state-space-duality) form, out of matmuls that XLA schedules.
+
+For every head h (of a group g(h) that shares B and C), with the time
+step Δ_t > 0 and A_h < 0:
+
+    a_t = exp(A_h Δ_t)
+    S_t = a_t S_{t-1} + Δ_t x_t B_tᵀ        (head_dim × state)
+    y_t = S_t C_t
+
+Cut into chunks of Q tokens (Dao & Gu 2024, section 6), with
+``cum_t`` the running sum of A_h Δ within a chunk:
+
+- within a chunk ``Y = (L ∘ C Bᵀ)(Δ ⊙ x)``, ``L_ts = exp(cum_t −
+  cum_s)`` for s ≤ t and 0 above: one [Q, Q] score block a group, one
+  decay block a head;
+- each chunk leaves ONE state ``Σ_s exp(cum_Q − cum_s) Δ_s x_s B_sᵀ``;
+  the state a chunk starts from is the earlier chunks' states, each
+  decayed by the chunks between (a [chunks, chunks] matrix of decays
+  times the stacked states: the recurrence over chunks, unrolled into
+  one small matmul in float32);
+- that state is read out by ``exp(cum_t) C_t``.
+
+Δ, A, the cumulative log-decays, L and the chunk decays are float32
+whatever the compute dtype: decays multiply thousands of times, and a
+bf16 ``cum`` is off by whole percents at the end of a chunk. The
+products run on operands of the compute dtype and sum in float32.
+
+``head_block`` heads go through at a time (``lax.map``, each block
+under its own ``jax.checkpoint``), so that L and the masked scores,
+[chunks, heads, Q, Q] float32 (512 MiB each for 128 heads at 8,192
+tokens), are never whole, forward or backward: a block of 16 heads
+(one B/C group of Nemotron-3's) holds 64 MiB of each.
+
+A length that is no multiple of the chunk is PADDED at its end with
+tokens of Δ = 0 and x = 0, which leave every state as it was and whose
+outputs are cut off again: exact, since the scan is causal.
+"""
+
+import jax
+import jax.numpy as jnp
+
+F32 = jnp.float32
+
+
+def _block_scan(x, dt, a, b_mat, c_mat, chunk):
+    """One block of heads, whole chunks. x [B, S, G, R, P] (G groups of
+    R heads of P channels), dt [B, S, G, R] float32, a [G, R] float32
+    (negative), b_mat and c_mat [B, S, G, N]. Returns y [B, S, G, R, P]
+    in x's dtype."""
+    bsz, s, g, r, p = x.shape
+    n = b_mat.shape[-1]
+    nc = s // chunk
+    dtype = x.dtype
+    x = x.reshape(bsz, nc, chunk, g, r, p)
+    dt = dt.reshape(bsz, nc, chunk, g, r)
+    b_mat = b_mat.reshape(bsz, nc, chunk, g, n)
+    c_mat = c_mat.reshape(bsz, nc, chunk, g, n)
+
+    # log-decays: the running sum within each chunk, float32
+    cum = jnp.cumsum(dt * a, axis=2)                       # [B,C,Q,G,R]
+    last = cum[:, :, -1]                                   # [B,C,G,R]
+    xdt = (x.astype(F32) * dt[..., None]).astype(dtype)
+
+    # within a chunk
+    scores = jnp.einsum(
+        "bcqgn,bcsgn->bcgqs", c_mat, b_mat, preferred_element_type=F32
+    )
+    cum_t = jnp.moveaxis(cum, 2, -1)                       # [B,C,G,R,Q]
+    seg = cum_t[..., :, None] - cum_t[..., None, :]        # [B,C,G,R,Q,Q]
+    causal = jnp.tril(jnp.ones((chunk, chunk), bool))
+    decay = jnp.exp(jnp.where(causal, seg, -jnp.inf))
+    mixed = (decay * scores[:, :, :, None]).astype(dtype)
+    y = jnp.einsum(
+        "bcgrqs,bcsgrp->bcqgrp", mixed, xdt, preferred_element_type=F32
+    )
+
+    # the state each chunk leaves, and the one each starts from
+    to_end = jnp.exp(last[:, :, None] - cum)               # [B,C,Q,G,R]
+    left = jnp.einsum(
+        "bcsgn,bcsgrp->bcgrpn", b_mat,
+        (xdt.astype(F32) * to_end[..., None]).astype(dtype),
+        preferred_element_type=F32,
+    )                                                      # [B,C,G,R,P,N]
+    # between[z, c] = the decay from the end of chunk c to the start of
+    # chunk z > c: exp of the sum of the chunks' totals between them
+    total = jnp.cumsum(last, axis=1)                       # [B,C,G,R]
+    before = total - last                                  # up to z's start
+    span = before[:, :, None] - total[:, None, :]          # [B,Z,C,G,R]
+    earlier = jnp.tril(jnp.ones((nc, nc), bool), -1)[None, :, :, None, None]
+    between = jnp.exp(jnp.where(earlier, span, -jnp.inf))
+    start = jnp.einsum(
+        "bzcgr,bcgrpn->bzgrpn", between, left,
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    y = y + jnp.exp(cum)[..., None] * jnp.einsum(
+        "bcqgn,bcgrpn->bcqgrp", c_mat, start.astype(dtype),
+        preferred_element_type=F32,
+    )
+    return y.astype(dtype).reshape(bsz, s, g, r, p)
+
+
+def ssd_scan(x, dt, a, b_mat, c_mat, chunk: int, head_block: int = 0):
+    """The scan over a sequence. x [B, S, H, P]; dt [B, S, H] float32,
+    positive (after the softplus); a [H] float32, negative; b_mat and
+    c_mat [B, S, G, N] with H a multiple of G. Returns y [B, S, H, P] in
+    x's dtype (the skip ``D x`` is the caller's). ``head_block`` heads
+    at a time (0 = all), a multiple or a divisor of H / G."""
+    bsz, s, h, p = x.shape
+    g = b_mat.shape[2]
+    per_group = h // g
+    pad = -s % chunk
+    if pad:
+        # Δ = 0: a decay of 1 and no input; see the module's docstring
+        widths = ((0, 0), (0, pad))
+        x = jnp.pad(x, widths + ((0, 0), (0, 0)))
+        dt = jnp.pad(dt, widths + ((0, 0),))
+        b_mat = jnp.pad(b_mat, widths + ((0, 0), (0, 0)))
+        c_mat = jnp.pad(c_mat, widths + ((0, 0), (0, 0)))
+    sp = s + pad
+    block = head_block or h
+    if h % block or (block % per_group and per_group % block):
+        raise ValueError(
+            f"a block of {block} heads neither divides nor is made of "
+            f"the groups of {per_group} of {h} heads"
+        )
+    if block < per_group:
+        # several blocks share a group: each gets its group's B and C
+        b_mat = jnp.repeat(b_mat, per_group // block, axis=2)
+        c_mat = jnp.repeat(c_mat, per_group // block, axis=2)
+        per_group = block
+    n_block = h // block
+    gb = block // per_group                  # groups in a block
+    with jax.named_scope("ssm.scan"):
+        if n_block == 1:
+            y = _block_scan(
+                x.reshape(bsz, sp, gb, per_group, p),
+                dt.reshape(bsz, sp, gb, per_group),
+                a.reshape(gb, per_group), b_mat, c_mat, chunk,
+            )
+            y = y.reshape(bsz, sp, h, p)
+        else:
+            def lead(t, shape):
+                # [B, S, blocks, ...] -> blocks first
+                return jnp.moveaxis(t.reshape(shape), 2, 0)
+
+            blocks = (
+                lead(x, (bsz, sp, n_block, gb, per_group, p)),
+                lead(dt, (bsz, sp, n_block, gb, per_group)),
+                a.reshape(n_block, gb, per_group),
+                lead(b_mat, (bsz, sp, n_block, gb, -1)),
+                lead(c_mat, (bsz, sp, n_block, gb, -1)),
+            )
+            one = jax.checkpoint(
+                lambda args: _block_scan(*args, chunk)
+            )
+            y = jax.lax.map(one, blocks)     # [blocks, B, S, gb, R, P]
+            y = jnp.moveaxis(y, 0, 2).reshape(bsz, sp, h, p)
+    return y[:, :s] if pad else y
+
+
+def causal_conv(x, weight, bias):
+    """Depthwise causal conv along the sequence: ``y_t = Σ_j w_j ⊙
+    x_{t-K+1+j} + b`` with x before the first token 0. x [B, S, C],
+    weight [K, C], bias [C]; K shifted copies, in float32."""
+    k = weight.shape[0]
+    s = x.shape[1]
+    with jax.named_scope("ssm.conv"):
+        padded = jnp.pad(x.astype(F32), ((0, 0), (k - 1, 0), (0, 0)))
+        w = weight.astype(F32)
+        out = bias.astype(F32)
+        for j in range(k):
+            out = out + padded[:, j:j + s] * w[j]
+        return out.astype(x.dtype)
+
+
+def gated_group_norm(y, z, scale, groups: int, eps: float):
+    """``RMSNorm over each of `groups` groups of (y ⊙ silu(z)) ⊙ scale``:
+    the gate first, then the norm (Mamba-2's ``norm_before_gate=False``).
+    y and z [B, S, C]; float32 inside."""
+    shape = y.shape
+    v = y.astype(F32) * jax.nn.silu(z.astype(F32))
+    v = v.reshape(shape[:-1] + (groups, shape[-1] // groups))
+    v = v * jax.lax.rsqrt(jnp.mean(v * v, -1, keepdims=True) + eps)
+    return (v.reshape(shape) * scale.astype(F32)).astype(y.dtype)

@@ -21,48 +21,72 @@ def init_moe_params(rng, cfg, lead=None) -> Dict:
     """Stacked per-layer MoE params: layers on axis 0 (``lead``, default
     the trunk's routed layers; ``()`` for one block), experts on the next.
     The router is ``n_experts`` wide whatever number of experts is held
-    here (``cfg.experts_here``); a shared expert is one SwiGLU of width
-    ``n_shared_experts · expert_width`` under ``"shared"``."""
+    here (``cfg.experts_here``); a shared expert is one MLP of width
+    ``cfg.shared_expert_width`` under ``"shared"``. ``act: relu2`` makes
+    every expert two matrices (no ``w_gate_proj`` / ``shared.w_gate``);
+    ``moe_latent_size`` makes the routed experts that wide at both ends
+    and adds the two projections under ``"latent"``."""
     d, f, e = cfg.d_model, cfg.expert_width, cfg.experts_here
+    di = cfg.expert_in
+    gated = cfg.act != "relu2"
     lead = (cfg.n_routed_layer,) if lead is None else tuple(lead)
     pdt = jnp.dtype(cfg.param_dtype)
     k = jax.random.split(rng, 4)
     s_in = 1.0 / jnp.sqrt(d)
+    s_exp = 1.0 / jnp.sqrt(di)
 
     def draw(key, shape, scale):
         return (jax.random.normal(key, lead + shape) * scale).astype(pdt)
 
     params = {
         "w_gate": draw(k[0], (d, cfg.n_experts), s_in),
-        "w_up": draw(k[1], (e, d, f), s_in),
-        "w_gate_proj": draw(k[2], (e, d, f), s_in),
-        "w_down": draw(k[3], (e, f, d), 1.0 / jnp.sqrt(f)),
+        "w_up": draw(k[1], (e, di, f), s_exp),
+        "w_down": draw(k[3], (e, f, di), 1.0 / jnp.sqrt(f)),
     }
+    if gated:
+        params["w_gate_proj"] = draw(k[2], (e, di, f), s_exp)
+    if cfg.moe_latent_size:
+        kl = jax.random.split(jax.random.fold_in(rng, 2), 2)
+        params["latent"] = {
+            "w_down": draw(kl[0], (d, di), s_in),
+            "w_up": draw(kl[1], (di, d), s_exp),
+        }
     if cfg.n_shared_experts:
-        fs = cfg.n_shared_experts * f
+        fs = cfg.shared_expert_width
         ks = jax.random.split(jax.random.fold_in(rng, 1), 3)
         params["shared"] = {
-            "w_gate": draw(ks[0], (d, fs), s_in),
             "w_up": draw(ks[1], (d, fs), s_in),
             "w_down": draw(ks[2], (fs, d), 1.0 / jnp.sqrt(fs)),
         }
+        if gated:
+            params["shared"]["w_gate"] = draw(ks[0], (d, fs), s_in)
     return params
 
 
 def moe_logical_axes(cfg, lead=("layers",)) -> Dict:
     lead = tuple(lead)
+    gated = cfg.act != "relu2"
+    # experts in a latent read rows that are not the embed axis
+    rows = None if cfg.moe_latent_size else "embed"
     ax = {
         "w_gate": lead + ("embed", None),
-        "w_up": lead + ("expert", "embed", "mlp"),
-        "w_gate_proj": lead + ("expert", "embed", "mlp"),
-        "w_down": lead + ("expert", "mlp", "embed"),
+        "w_up": lead + ("expert", rows, "mlp"),
+        "w_down": lead + ("expert", "mlp", rows),
     }
+    if gated:
+        ax["w_gate_proj"] = lead + ("expert", rows, "mlp")
+    if cfg.moe_latent_size:
+        ax["latent"] = {
+            "w_down": lead + ("embed", None),
+            "w_up": lead + (None, "embed"),
+        }
     if cfg.n_shared_experts:
         ax["shared"] = {
-            "w_gate": lead + ("embed", "mlp"),
             "w_up": lead + ("embed", "mlp"),
             "w_down": lead + ("mlp", "embed"),
         }
+        if gated:
+            ax["shared"]["w_gate"] = lead + ("embed", "mlp")
     return ax
 
 
@@ -334,11 +358,14 @@ def moe_block(
 
 
 def _shared_expert(x, shared, mesh):
-    """The SwiGLU every token meets beside its routed experts."""
+    """The MLP every token meets beside its routed experts: a SwiGLU,
+    or relu(.)² between two matrices where it has no gate."""
     with jax.named_scope("moe.shared"):
-        h = jax.nn.silu(x @ shared["w_gate"].astype(x.dtype)) * (
-            x @ shared["w_up"].astype(x.dtype)
-        )
+        h = x @ shared["w_up"].astype(x.dtype)
+        if "w_gate" in shared:
+            h = jax.nn.silu(x @ shared["w_gate"].astype(x.dtype)) * h
+        else:
+            h = jnp.square(jax.nn.relu(h))
         if mesh is not None:
             h = shd.constrain(h, mesh, "batch", "seq", "mlp")
         return h @ shared["w_down"].astype(x.dtype)
@@ -493,6 +520,14 @@ def _dispatch_bwd(k, res, g):
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
+def _held_row_bound(t, k, e, some_elsewhere):
+    """Rows of the expert-sorted arrays that can hold a pair whose
+    expert is here, where that is fewer than all ``t · k``: a token's k
+    choices are distinct, so at most ``min(k, e)`` of them name one of
+    the ``e`` experts held. None where every row can (GLM, Keye: k ≤ e)."""
+    return t * e if some_elsewhere and e < k else None
+
+
 def _sort_by_expert(xt, gate_idx, e, some_elsewhere=False):
     """Stable-sort prologue shared by both ragged lowerings: (token,
     choice) pairs ordered by expert. STABILITY is load-bearing — the
@@ -504,8 +539,15 @@ def _sort_by_expert(xt, gate_idx, e, some_elsewhere=False):
     behind the last group, where ``ragged_dot`` computes nothing, and
     bring no gradient back.
 
-    Returns (flat_idx [t·k] (``e`` = not held here), order [t·k], inv
-    [t·k] with ``inv[order] = arange``, sorted_in [t·k, D], counts [e])."""
+    Where a token chooses more experts than are here (k > e), the held
+    pairs fill at most the first ``t · e`` sorted rows
+    (``_held_row_bound``): ``order`` and ``sorted_in`` are CUT to them,
+    exactly and without a drop, and ``inv`` is clamped into them (the
+    pairs it then misplaces are all elsewhere, which ``held`` masks).
+
+    Returns (flat_idx [t·k] (``e`` = not held here), order [n], inv
+    [t·k] with ``inv[order] = arange`` on the held pairs, sorted_in
+    [n, D], counts [e]); n = t·k or the bound."""
     t, k = gate_idx.shape
     with jax.named_scope("moe.sort"):
         flat_idx = gate_idx.reshape(t * k)
@@ -515,6 +557,10 @@ def _sort_by_expert(xt, gate_idx, e, some_elsewhere=False):
             flat_idx = jnp.where(held, flat_idx, e)
         order = jnp.argsort(flat_idx)
         inv = jnp.argsort(order)
+        bound = _held_row_bound(t, k, e, some_elsewhere)
+        if bound is not None:
+            order = order[:bound]
+            inv = jnp.minimum(inv, bound - 1)
         sorted_in = _dispatch(k, xt, order // k, inv, held)
         counts = jnp.sum(
             flat_idx[:, None] == jnp.arange(e, dtype=flat_idx.dtype),
@@ -526,13 +572,17 @@ def _sort_by_expert(xt, gate_idx, e, some_elsewhere=False):
 def _ragged_experts(rows, w_up, w_gate_proj, w_down, group_sizes):
     """The SwiGLU experts over expert-sorted ``rows`` [N, D] as three
     ragged matmuls (``lax.ragged_dot``: rhs [E, ·, ·], group_sizes = the
-    rows each expert actually got — the MXU only sees routed tokens)."""
+    rows each expert actually got — the MXU only sees routed tokens).
+    ``w_gate_proj`` None: experts without a gate, relu(.)² between two."""
     with jax.named_scope("moe.experts"):
         up = jax.lax.ragged_dot(rows, w_up, group_sizes)
-        gate_p = jax.lax.ragged_dot(rows, w_gate_proj, group_sizes)
-        return jax.lax.ragged_dot(
-            jax.nn.silu(gate_p) * up, w_down, group_sizes
-        )
+        if w_gate_proj is None:
+            h = jnp.square(jax.nn.relu(up))
+        else:
+            h = jax.nn.silu(
+                jax.lax.ragged_dot(rows, w_gate_proj, group_sizes)
+            ) * up
+        return jax.lax.ragged_dot(h, w_down, group_sizes)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
@@ -597,13 +647,14 @@ def _ragged_ffn(xl, moe_local, gate_idx, weights, dtype):
     if some_elsewhere:
         held = flat_idx < e
         weights = jnp.where(held.reshape(weights.shape), weights, 0)
+    w_gate_proj = moe_local.get("w_gate_proj")
     out_sorted = _ragged_experts(
         sorted_in,
         moe_local["w_up"].astype(dtype),
-        moe_local["w_gate_proj"].astype(dtype),
+        None if w_gate_proj is None else w_gate_proj.astype(dtype),
         moe_local["w_down"].astype(dtype),
         group_sizes,
-    )  # [T·k, D]
+    )  # [T·k, D], or the rows that can hold a held pair
     out = _combine_weighted(out_sorted, weights, order, inv, dtype, held)
     return out, group_sizes
 
@@ -646,13 +697,24 @@ def _ragged_tokens(xl, moe_local, cfg, rng, pmean_axes=None):
     local_idx = gate_idx
     if cfg.n_experts_held:
         local_idx = gate_idx - cfg.expert_offset
+    rows = xl.reshape(bl * sl, d)
+    latent = moe_local.get("latent")
+    if latent is not None:
+        # the experts' rows are the latent's width from dispatch to
+        # combine; the router above and the shared expert read ``xl``
+        with jax.named_scope("moe.latent"):
+            rows = rows @ latent["w_down"].astype(xl.dtype)
     out, group_sizes = _ragged_ffn(
-        xl.reshape(bl * sl, d),
+        rows,
         moe_local,
         local_idx.reshape(bl * sl, -1),
         weights.reshape(bl * sl, -1),
         xl.dtype,
     )
+    if latent is not None:
+        # once, after the combine: on the sum of the experts held here
+        with jax.named_scope("moe.latent"):
+            out = out @ latent["w_up"].astype(xl.dtype)
     if not cfg.n_experts_held:
         return out, _ragged_aux(
             gate_logits, probs, group_sizes, pmean_axes
@@ -688,6 +750,12 @@ def _moe_block_ragged(x, moe, cfg, mesh=None, rng=None):
         aux["moe_choices"] = gate_idx
         return out.reshape(b, s, d), aux
 
+    if cfg.moe_latent_size or cfg.act == "relu2":
+        raise ValueError(
+            "experts in a latent or without a gate run on one device's "
+            "tokens and experts; the sharded ragged lowerings hand their "
+            "bodies three SwiGLU matrices"
+        )
     if mesh.shape.get("ep", 1) > 1:
         if cfg.n_experts_held:
             raise ValueError(

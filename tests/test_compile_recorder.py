@@ -194,6 +194,19 @@ def test_classes_sum_to_totals_and_to_the_counters(three_programs):
     assert table["setup.before_build_s"] > 0
 
 
+def test_before_build_survives_an_emptied_table(monkeypatch):
+    """``setup.before_build_s`` closes once a process, and every builder
+    sets that value again: a table another test emptied in between
+    (``tracing._counters.clear()``) does not lose it."""
+    rec = compile_cache.watch_compiles()
+    rec.first_build()
+    closed = rec.before_build_s
+    monkeypatch.setattr(tracing, "_counters", {})
+    rec.first_build()
+    assert rec.before_build_s == closed
+    assert tracing.counters() == {"setup.before_build_s": closed}
+
+
 def test_a_trace_is_counted_once():
     """jax reports the jitted functions a traced function calls inside
     its own interval and before it: the recorder counts the outer

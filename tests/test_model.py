@@ -49,6 +49,7 @@ BENCHMARK_CONFIGS = {
     "olmoe-1b-7b-1chip": 4096,
     "glm-4.7-flash-ep8-1chip": 8192,
     "keye-vl-2.0-ep8-1chip": 8192,
+    "nemotron-3-super-ep64-1chip": 8192,
 }
 
 
@@ -61,7 +62,8 @@ def test_flops_per_token_is_the_benchmarks_count(name):
     an architecture whose layers differ, the count its reference module
     states (``flops.resolve``): latent attention's projections, the dense
     prefix, the held and shared experts, the prediction module, a
-    selection of keys and its score-only indexer."""
+    selection of keys and its score-only indexer, layers of one part
+    each with a state-space scan's recurrence among the multiplied."""
     from benchmarks.lib.flops import resolve
 
     configs = pathlib.Path(__file__).parent.parent / "benchmarks" / "configs"
@@ -91,12 +93,15 @@ def test_flops_per_token_is_the_benchmarks_count(name):
         )
         return
     # bidirectional attention sees every key, a causal one half on average
+    attention_layers = cfg.n_layer + cfg.n_mtp_module
+    if cfg.layer_pattern:  # one part a layer: the attention layers alone
+        attention_layers = (cfg.layer_pattern + cfg.mtp_pattern).count("*")
     both_ways = dataclasses.replace(cfg, causal=False, attn_window=0)
     causal = dataclasses.replace(cfg, attn_window=0)
     assert both_ways.flops_per_token(1024) - causal.flops_per_token(
         1024
     ) == pytest.approx(
-        12.0 * (cfg.n_layer + cfg.n_mtp_module) * cfg.n_head * cfg.head_dim
+        12.0 * attention_layers * cfg.n_head * cfg.head_dim
         * (1024 - 512.5)
     )
 

@@ -665,6 +665,70 @@ def test_glm_cell_fits_the_chip_at_its_depth(topo):
     )
 
 
+def test_nemotron_cell_fits_the_chip_with_its_rows_cut(topo):
+    """The benchmark's Nemotron-3-Super configuration as it is run (one
+    period MEMEMEMEM*E + the module, 8 of 512 experts held, 1 x 8192
+    tokens): the step the chip's compiler lays out needs under the 16.9
+    GB the runtime gives and over 12 GB (14.54 GB by this count, PR 41;
+    bf16 parameters and two moments are 8.27 GB of arguments). Every
+    part shows under its scope, the attention goes through the unpacked
+    flash kernels (GQA 32 / 2 at head size 128), and the held experts'
+    rows are cut to 8,192 x 8: no array of 180,224 rows is as wide as
+    an expert."""
+    import json
+    import pathlib
+    import re
+
+    from dlrover_tpu.observability import runtime_timer
+
+    path = pathlib.Path(__file__).parent.parent / "benchmarks" / "configs"
+    config = json.loads(
+        (path / "nemotron-3-super-ep64-1chip.json").read_text()
+    )
+    STEP_CASES["nemotron-cell"] = dict(
+        model=config["program"]["model"],
+        overrides=config["program"]["overrides"],
+        optimizer=dict(state_dtype="bfloat16"), comm=None, chips=1,
+        batch=(1, 8192),
+    )
+    try:
+        _, text, _ = _compiled_step(topo, "nemotron-cell")
+    finally:
+        del STEP_CASES["nemotron-cell"]
+    stats = _STEP_MEMORY["nemotron-cell"]
+    need = (
+        stats.argument_size_in_bytes + stats.output_size_in_bytes
+        - stats.alias_size_in_bytes + stats.temp_size_in_bytes
+    )
+    assert 12e9 < need < 16.9e9, need
+    assert stats.argument_size_in_bytes == pytest.approx(
+        6 * 1_378_721_664, rel=1e-3  # bf16 parameters and two moments
+    )
+    op_names = runtime_timer.op_names_from_hlo(text)
+    parts = {
+        part for name in op_names.values()
+        for part in re.split(r"[/()]", name)
+    }
+    scopes = {"ssm", "ssm.conv", "ssm.scan", "attn", "mlp", "moe.route",
+              "moe.sort", "moe.latent", "moe.experts", "moe.combine",
+              "moe.shared", "mtp", "head_loss", "optimizer"}
+    assert scopes <= parts, scopes - parts
+    kernels = {
+        line.split("=")[0].strip().removeprefix("ROOT ").lstrip("%")
+        .split(".")[0]
+        for line in text.splitlines() if "tpu_custom_call" in line
+    }
+    assert kernels == {
+        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "norm_fwd",
+        "norm_bwd", "ragged-dot-none", "ragged-dot-metadata",
+    }
+    assert "[65536,2688]" in text and "[65536,1024]" in text
+    assert "[180224,2688]" not in text
+    # no score or decay block of all 128 heads at once
+    assert "[1,64,8,16,128,128]" not in text
+    assert "[1,64,1,16,128,128]" in text
+
+
 def test_keye_cell_compiles_at_its_depth(topo):
     """The benchmark's Keye-VL-2.0 configuration as it is run (12
     layers, 16 of 128 experts held, 1 x 8192 tokens; STEP_CASES'
