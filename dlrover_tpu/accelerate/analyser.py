@@ -6,10 +6,11 @@ exact about sharded memory: bytes = Σ params·dtype / (fsdp·tp shards) etc.,
 so infeasible strategies are rejected before any compilation.
 """
 
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from typing import Dict
 
 from dlrover_tpu.common import device
+from dlrover_tpu.models import decoder
 from dlrover_tpu.models.config import ModelConfig
 from dlrover_tpu.accelerate.strategy import AccelerationPlan
 
@@ -109,6 +110,14 @@ def analyse(
     if plan.remat == "full":
         # only layer-boundary activations are kept
         act_b = tokens * cfg.d_model * act_dtype_b * cfg.n_layer
+        if sizes["sp"] == 1 and decoder.keeps_attention_output(
+            replace(cfg, remat="full"), seq
+        ):
+            # and, at long spans on the flash kernels, every attention
+            # layer's output with its row statistics (one f32 a head)
+            act_b += tokens * cfg.n_attention_layers * cfg.n_head * (
+                cfg.head_dim * act_dtype_b + 4
+            )
     else:
         # rough: ~12 activation tensors per layer survive to the backward
         act_b = tokens * cfg.d_model * act_dtype_b * cfg.n_layer * 12

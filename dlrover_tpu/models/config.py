@@ -75,7 +75,12 @@ class ModelConfig:
     # rematerialisation policy:
     # none | full | dots_saveable | save_attn | save_qkv |
     # save_qkv_gate | save_dots | offload_attn | save_qkv_offload
-    # (save_qkv/save_qkv_gate/save_dots = save_attn plus the qkv /
+    # (full = the layer recomputed but what is quadratic to remake and
+    # linear to hold: a selecting model's selection and alignment
+    # derivative, and — where the attention runs the flash kernels over
+    # a mean span of 2,048 keys or more, decoder.keeps_attention_output
+    # — the kernel's output and row statistics, 2·D + 4 bytes a (query,
+    # head); save_qkv/save_qkv_gate/save_dots = save_attn plus the qkv /
     # qkv+gate / qkv+gate+up matmul outputs — graded memory/recompute
     # tradeoffs between full and dots_saveable; offload_attn =
     # save_attn with residuals in pinned host memory — reference:
@@ -629,6 +634,23 @@ class ModelConfig:
             + self.n_routed_layer * routed + mtp + embed + pos + d
         )
 
+    @property
+    def n_attention_layers(self) -> int:
+        """Layers with an attention, the prediction module's included."""
+        if self.layer_pattern:
+            return (self.layer_pattern + self.mtp_pattern).count("*")
+        return self.n_layer + self.n_mtp_module
+
+    def executed_span(self, seq_len: int) -> float:
+        """The mean number of keys the attention kernels EXECUTE a
+        query at ``seq_len``: the causal span under ``attn_window``,
+        every key without the mask. A model that selects its keys runs
+        the whole causal span too (the ``_sel`` kernels run every
+        causal block), whatever ``index_topk`` credits it."""
+        if not self.causal:
+            return float(seq_len)
+        return mean_span(seq_len, self.attn_window)
+
     def flops_per_token(self, seq_len: int) -> float:
         """FLOPs a training step requires per token, forward and
         backward, counted as the benchmark counts them
@@ -662,9 +684,11 @@ class ModelConfig:
                     2 * d * d + head + sum(met[c] for c in self.mtp_pattern)
                 )
             )
-            attn_layers = (self.layer_pattern + self.mtp_pattern).count("*")
-            span = (seq_len + 1) / 2 if self.causal else seq_len
-            return 6.0 * multiplied + 12.0 * attn_layers * d_attn * span
+            span = self.executed_span(seq_len)
+            return (
+                6.0 * multiplied
+                + 12.0 * self.n_attention_layers * d_attn * span
+            )
         if self.latent_attention:
             attn = (
                 d * self.q_lora_rank + self.q_lora_rank * d_attn
@@ -697,21 +721,23 @@ class ModelConfig:
             + self.n_mtp_module * (2 * d * d + routed + head)
             + head
         )
-        def mean_span(w):
-            w = min(w or seq_len, seq_len)
-            return (w * (w + 1) / 2 + (seq_len - w) * w) / seq_len
-
-        span = mean_span(self.attn_window) if self.causal else seq_len
-        attn_layers = self.n_layer + self.n_mtp_module
+        span = self.executed_span(seq_len)
         pairs = d_attn * span
         if self.selects_keys:
             # the attention counts the keys it selects, min(i + 1, k) a
             # query; the indexer half a pair-channel (a score product
             # and no value product) over every key it scores
-            pairs = d_attn * mean_span(self.index_topk) + (
+            pairs = d_attn * mean_span(seq_len, self.index_topk) + (
                 self.index_n_heads * self.index_head_dim / 2 * span
             )
-        return 6.0 * multiplied + 12.0 * attn_layers * pairs
+        return 6.0 * multiplied + 12.0 * self.n_attention_layers * pairs
+
+
+def mean_span(seq_len: int, window: int = 0) -> float:
+    """Keys a query sees under the causal mask, averaged over a sequence
+    of ``seq_len``: query i sees min(i + 1, ``window`` or seq_len)."""
+    w = min(window or seq_len, seq_len)
+    return (w * (w + 1) / 2 + (seq_len - w) * w) / seq_len
 
 
 def mup_base_config(cfg: "ModelConfig") -> "ModelConfig":

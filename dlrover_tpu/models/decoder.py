@@ -1215,7 +1215,7 @@ def _part_body(
 
 def _run_pattern(
     x, layers, pattern: str, positions, cfg: ModelConfig, mesh, attn_fn,
-    rng, tag_attn_out, first: int = 0,
+    rng, tag_attn_out, first: int = 0, keep_attn: bool = False,
 ):
     """The layers ``pattern`` names, in its order, each taken as the
     next of its kind's stack in ``layers`` (``_init_pattern``) and run
@@ -1230,7 +1230,7 @@ def _run_pattern(
                 _part_body, letter=letter, cfg=cfg, mesh=mesh,
                 attn_fn=attn_fn, tag_attn_out=tag_attn_out,
             ),
-            cfg,
+            cfg, keep_attn,
         )
         for letter in set(pattern)
     }
@@ -1272,7 +1272,7 @@ def _offload_names_policy(*names):
 
 
 def _remat_body(cfg: ModelConfig, mesh, attn_fn, tag_attn_out, fp8_layers,
-                return_selected: bool = False):
+                return_selected: bool = False, keep_attn: bool = False):
     """``_layer_body`` bound to the model and wrapped in the configured
     rematerialisation policy: what every layer of the trunk, of either
     kind, and the prediction module's block run through."""
@@ -1288,100 +1288,110 @@ def _remat_body(cfg: ModelConfig, mesh, attn_fn, tag_attn_out, fp8_layers,
         # args as traceable values and a str is not a valid JAX type
         **({"fp8": "current"} if fp8_layers == "current" else {}),
     )
-    return _remat(body, cfg)
+    return _remat(body, cfg, keep_attn)
 
 
-def _remat(body, cfg: ModelConfig):
+# the attention's results: on the flash path the kernel's custom_vjp
+# residuals (flash_out, and flash_lse: the row statistics as numbers,
+# [B, H, S] float32); on the reference path the tagged block output
+# (attn_out) — never both
+_ATTN_NAMES = ("attn_out", "flash_out", "flash_lse")
+_QKV_NAMES = ("q_proj", "k_proj", "v_proj")
+# remat tier -> (the named residuals its policy keeps; everything
+# unnamed is recomputed in the backward, whether they live in pinned
+# host memory)
+_REMAT_TIERS = {
+    # backward recomputes only the cheap MLP/norm/projection math
+    "save_attn": (_ATTN_NAMES, False),
+    # save_attn PLUS the post-rope q/k/v projections: backward skips
+    # the attention kernel re-run AND the qkv matmuls (~30% of the
+    # full-remat recompute flops) for ~130 MB/layer at b8·s1024 — the
+    # policy the fused-CE memory savings (ops/fused_ce.py) buy
+    "save_qkv": (_ATTN_NAMES + _QKV_NAMES, False),
+    # save_qkv plus ONE of the two swiglu projections: ~half the extra
+    # footprint of save_dots for half its recompute savings — the
+    # largest policy that still fits 1.4B training on a 16 GiB chip
+    "save_qkv_gate": (_ATTN_NAMES + _QKV_NAMES + ("mlp_gate",), False),
+    # save_qkv plus the swiglu gate/up projections: backward recomputes
+    # only norms/elementwise + the o/down matmuls — ~70% of the
+    # recompute flops gone for ~300 MB/layer
+    "save_dots": (
+        _ATTN_NAMES + _QKV_NAMES + ("mlp_gate", "mlp_up"), False,
+    ),
+    # like save_attn, but the pinned residuals live in pinned host
+    # memory instead of HBM (reference: atorch's selective offloading
+    # checkpoint, auto/opt_lib/selective_offloading_checkpoint.py) —
+    # activation memory ~frees the O(L·B·S·D) attention outputs at the
+    # cost of host DMA traffic in backward
+    "offload_attn": (_ATTN_NAMES, True),
+    # save_qkv's residual set, offloaded like offload_attn: for models
+    # whose pinned save_qkv residuals don't fit HBM (the gpt2-1.5b tied
+    # 50k-vocab embedding leaves no headroom on a 16 GiB chip) but full
+    # remat's ~30% recompute is too slow. Backward pays host DMA instead
+    # of matmul+kernel re-runs; the DMA overlaps the MLP recompute it
+    # replaced.
+    "save_qkv_offload": (_ATTN_NAMES + _QKV_NAMES, True),
+}
+
+
+def _kept_names(cfg: ModelConfig, keep_attn: bool):
+    """(the named residuals ``cfg.remat``'s policy keeps, offloaded?).
+    ``full`` recomputes the layer but what is quadratic to remake and
+    linear to hold: a selecting model's selection (int8 [B, S, S] a
+    layer, against scoring and cutting every query's keys again) and its
+    alignment term's derivative (qi's, ki's and w's shapes, against
+    scoring every attention pair again), and, where ``keep_attn``
+    (``keeps_attention_output``), the flash kernel's output and row
+    statistics."""
+    if cfg.remat != "full":
+        return _REMAT_TIERS.get(cfg.remat, ((), False))
+    names = ()
+    if cfg.selects_keys:
+        names += ("attn_selected", "attn_align_grad")
+    if keep_attn:
+        names += ("flash_out", "flash_lse")
+    return names, False
+
+
+def _remat(body, cfg: ModelConfig, keep_attn: bool = False):
     """``body`` (a layer: ``_layer_body`` or ``_part_body``, bound to
     its model) under the configured rematerialisation policy."""
-    if cfg.remat == "full" and cfg.selects_keys:
-        # everything recomputed but the selection (int8 [B, S, S] a
-        # layer, against scoring and cutting every query's keys again)
-        # and the alignment term's derivative (qi's, ki's and w's
-        # shapes, against scoring every attention pair again)
-        body = jax.checkpoint(
-            body, policy=cp.save_only_these_names(
-                "attn_selected", "attn_align_grad"
-            )
-        )
-    elif cfg.remat == "full":
-        body = jax.checkpoint(body)
-    elif cfg.remat == "dots_saveable":
-        body = jax.checkpoint(body, policy=cp.dots_saveable)
-    elif cfg.remat == "save_attn":
-        # pin the attention results so backward recomputes only the cheap
-        # MLP/norm/projection math: on the flash path the kernel's
-        # custom_vjp residuals (flash_out/flash_lse); on the reference
-        # path the tagged block output (attn_out) — never both
-        body = jax.checkpoint(
-            body,
-            policy=cp.save_only_these_names(
-                "attn_out", "flash_out", "flash_lse"
-            ),
-        )
-    elif cfg.remat == "save_qkv":
-        # save_attn PLUS the post-rope q/k/v projections: backward skips
-        # the attention kernel re-run AND the qkv matmuls (~30% of the
-        # full-remat recompute flops) for ~130 MB/layer at b8·s1024 —
-        # the policy the fused-CE memory savings (ops/fused_ce.py) buy
-        body = jax.checkpoint(
-            body,
-            policy=cp.save_only_these_names(
-                "attn_out", "flash_out", "flash_lse",
-                "q_proj", "k_proj", "v_proj",
-            ),
-        )
-    elif cfg.remat == "save_qkv_gate":
-        # save_qkv plus ONE of the two swiglu projections: ~half the
-        # extra footprint of save_dots for half its recompute savings —
-        # the largest policy that still fits 1.4B training on a 16 GiB
-        # chip
-        body = jax.checkpoint(
-            body,
-            policy=cp.save_only_these_names(
-                "attn_out", "flash_out", "flash_lse",
-                "q_proj", "k_proj", "v_proj", "mlp_gate",
-            ),
-        )
-    elif cfg.remat == "save_dots":
-        # save_qkv plus the swiglu gate/up projections: backward
-        # recomputes only norms/elementwise + the o/down matmuls —
-        # ~70% of the recompute flops gone for ~300 MB/layer
-        body = jax.checkpoint(
-            body,
-            policy=cp.save_only_these_names(
-                "attn_out", "flash_out", "flash_lse",
-                "q_proj", "k_proj", "v_proj", "mlp_gate", "mlp_up",
-            ),
-        )
-    elif cfg.remat == "offload_attn":
-        # like save_attn, but the pinned residuals live in pinned host
-        # memory instead of HBM (reference: atorch's selective offloading
-        # checkpoint, auto/opt_lib/selective_offloading_checkpoint.py) —
-        # activation memory ~frees the O(L·B·S·D) attention outputs at
-        # the cost of host DMA traffic in backward
-        body = jax.checkpoint(
-            body,
-            policy=_offload_names_policy(
-                "attn_out", "flash_out", "flash_lse"
-            ),
-        )
-    elif cfg.remat == "save_qkv_offload":
-        # save_qkv's residual set, offloaded like offload_attn: for
-        # models whose pinned save_qkv residuals don't fit HBM (the
-        # gpt2-1.5b tied 50k-vocab embedding leaves no headroom on a
-        # 16 GiB chip) but full remat's ~30% recompute is too slow.
-        # Backward pays host DMA instead of matmul+kernel re-runs; the
-        # DMA overlaps the MLP recompute it replaced.
-        body = jax.checkpoint(
-            body,
-            policy=_offload_names_policy(
-                "attn_out", "flash_out", "flash_lse",
-                "q_proj", "k_proj", "v_proj",
-            ),
-        )
-
+    names, offload = _kept_names(cfg, keep_attn)
+    if names:
+        policy = _offload_names_policy if offload else cp.save_only_these_names
+        return jax.checkpoint(body, policy=policy(*names))
+    if cfg.remat == "full":
+        return jax.checkpoint(body)
+    if cfg.remat == "dots_saveable":
+        return jax.checkpoint(body, policy=cp.dots_saveable)
     return body
+
+
+# the executed keys a query from which ``full`` keeps the flash kernel's
+# output: remaking costs 4 · span · D operations a (query, head), keeping
+# 2 · D + 4 bytes, so the time bought per byte grows with the span. The
+# chip says, a gigabyte of residuals: 28 ms at span 512.5 (GPT-2 XL, 15.3
+# of 16.9 GB in use: no room), 29 at 2,048.5 (OLMoE), 46-72 at 3,072.25
+# and 4,096.5 (PERF.md section 6, PR 42); no cell lies between 512.5 and
+# 2,048.5, so where the line is between them is not measured.
+KEEP_ATTN_SPAN = 2048
+
+
+def keeps_attention_output(cfg: ModelConfig, s: int, attn_impl: str = "auto",
+                           mesh=None) -> bool:
+    """Whether ``remat: full`` keeps the attention kernel's output
+    (``flash_out``, and ``flash_lse`` as numbers) at sequence length
+    ``s`` and does not run the kernel again in the recomputed forward:
+    the attention runs the Pallas kernels and a query's mean executed
+    span (``ModelConfig.executed_span``) is at least ``KEEP_ATTN_SPAN``
+    keys. Decided from the shape and not from the memory the chip has
+    left: what a step needs is known only once it is compiled."""
+    return (
+        cfg.remat == "full"
+        and _resolve_attn_impl(attn_impl, mesh) == "flash"
+        and s % 128 == 0  # the kernels' tiling (``_fit_block``)
+        and cfg.executed_span(s) >= KEEP_ATTN_SPAN
+    )
 
 
 def _resolve_attn_impl(attn_impl: str, mesh) -> str:
@@ -1455,6 +1465,7 @@ def run_trunk(
     fp8_layers=None,
     dense_layers: Optional[Params] = None,
     return_selected: bool = False,
+    keep_attn: bool = False,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Run the stacked transformer layers: remat policy, pp pipelining,
     MoE aux-loss accumulation. Shared by the decoder and the ViT trunk
@@ -1473,6 +1484,9 @@ def run_trunk(
     ``return_selected`` (a model that selects its keys): every layer's
     selection rides out in the aux, stacked bool [L, B, S, S].
 
+    ``keep_attn``: ``remat: full`` keeps the flash kernel's output
+    (``keeps_attention_output``; ``attn_fn`` was told, ``lse_rows``).
+
     A ``layer_pattern`` model's ``layers`` are its stacks kind by kind,
     visited in the pattern's order (``_run_pattern``).
 
@@ -1483,12 +1497,13 @@ def run_trunk(
             _train_only_guard(cfg, "the pipeline")
         x, aux = _run_pattern(
             x, layers, cfg.layer_pattern, positions, cfg, mesh, attn_fn,
-            rng, tag_attn_out,
+            rng, tag_attn_out, keep_attn=keep_attn,
         )
         zero = jnp.zeros([], jnp.float32)
         return x, {"moe_lb_loss": zero, "moe_z_loss": zero, **aux}
     body = _remat_body(
-        cfg, mesh, attn_fn, tag_attn_out, fp8_layers, return_selected
+        cfg, mesh, attn_fn, tag_attn_out, fp8_layers, return_selected,
+        keep_attn,
     )
 
     zero_aux = {
@@ -1742,6 +1757,10 @@ def forward(
             "jnp.zeros([batch], int32) for fully-causal behavior"
         )
 
+    keep_attn = keeps_attention_output(cfg, s, attn_impl, mesh)
+    # whether the layers' remat policy lists the kernel's statistics
+    lse_rows = "flash_lse" in _kept_names(cfg, keep_attn)[0]
+
     def attn_fn(q, k, v, selected=None):
         if selected is not None:
             # (out, lse [B, H, S] detached, whether the Pallas kernels
@@ -1762,6 +1781,7 @@ def forward(
             return *flash_attention(
                 q, k, v, causal=True, block_q=cfg.attn_block_q,
                 block_k=cfg.attn_block_k, selected=selected,
+                lse_rows=lse_rows,
             ), True
         if attn_impl == "ring":
             from dlrover_tpu.parallel.sequence import ring_attention
@@ -1795,6 +1815,7 @@ def forward(
                     block_q=cfg.attn_block_q,
                     block_k=cfg.attn_block_k,
                     head_pack=cfg.attn_head_pack,
+                    lse_rows=lse_rows,
                 ),
                 prefix_len=prefix_len,
                 window=cfg.attn_window,
@@ -1816,6 +1837,7 @@ def forward(
             prefix_len=prefix_len,
             window=cfg.attn_window,
             head_pack=cfg.attn_head_pack,
+            lse_rows=lse_rows,
         )
 
     x, aux = run_trunk(
@@ -1832,12 +1854,13 @@ def forward(
         return_selected=cfg.selects_keys and (
             return_aux if return_selected is None else return_selected
         ),
+        keep_attn=keep_attn,
     )
     if cfg.n_mtp_module and return_aux:
         # the module reads the trunk's output BEFORE the final norm
         aux = _mtp_module(
             params, x, tokens, positions, cfg, mesh, attn_fn, rng,
-            attn_impl != "flash", aux,
+            attn_impl != "flash", aux, keep_attn,
         )
 
     with jax.named_scope("head_loss"):
@@ -1863,7 +1886,7 @@ def next_tokens(tokens: jax.Array) -> jax.Array:
 
 def _mtp_module(
     params, h, tokens, positions, cfg: ModelConfig, mesh, attn_fn, rng,
-    tag_attn_out, aux,
+    tag_attn_out, aux, keep_attn: bool = False,
 ):
     """The multi-token-prediction module (DeepSeek-V3 §2.2; the layout
     of ``glm4_moe_lite``'s ``num_nextn_predict_layers`` weights):
@@ -1893,9 +1916,12 @@ def _mtp_module(
             z, block_aux = _run_pattern(
                 z, m["block"], cfg.mtp_pattern, positions, cfg, mesh,
                 attn_fn, rng, tag_attn_out, first=cfg.n_layer,
+                keep_attn=keep_attn,
             )
         else:
-            body = _remat_body(cfg, mesh, attn_fn, tag_attn_out, None)
+            body = _remat_body(
+                cfg, mesh, attn_fn, tag_attn_out, None, keep_attn=keep_attn
+            )
             rope = (
                 _rope_tables(positions, cfg.rope_dim, cfg.rope_theta)
                 if cfg.pos == "rope"

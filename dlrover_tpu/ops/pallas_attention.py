@@ -35,8 +35,15 @@ the dk/dv kernel reads. Only a caller that asked for lse
 from XLA (``_stat_tiles``) — into the dq kernel, which subtracts it from
 delta, so the SAME kernels serve the ring. The price of the format: a
 tile array is 128 lanes wide in memory (54 MB a layer at GPT-2 XL for
-0.85 MB of numbers), so a remat policy that saves ``flash_lse`` saves
-that.
+0.85 MB of numbers; 134 MB for 1 MB at 32 heads x 8192 tokens). So what
+a remat policy KEEPS is not the tiles: a caller whose policy lists
+``flash_out`` and ``flash_lse`` says so (``lse_rows``; the decoder's
+``save_attn`` tiers, and ``full`` at long spans,
+``decoder.keeps_attention_output``), and ``flash_lse`` then names the
+``[B, H, S]`` numbers; the backward rule pads them into tiles again for
+its kernels (one read and one write of the tile array a layer, against
+running the forward kernel a second time). Where no policy keeps them
+the residual is the tile array and the step is as above.
 
 Both paths support GLM-style prefix-LM masking (per-batch prefix scalar in
 SMEM) and GQA (K/V shared across head groups via BlockSpec index maps, no
@@ -915,13 +922,16 @@ def _stat_rows(tiles, b, h, pack):
     return heads.reshape(b, -1, s)[:, :h]
 
 
-def _stat_tiles(rows, like, pack):
-    """``[B, H, S]`` statistics in the tiles of ``like`` (heads past H:
-    zeros). Only the ring path's lse cotangent takes this way in."""
+def _stat_tiles(rows, pack):
+    """``[B, H, S]`` statistics in the kernels' float32 tiles,
+    ``[B·slabs, S, 8]`` (heads past H: zeros): ``_stat_rows``' inverse.
+    The way in for the ring path's lse cotangent and for an ``lse`` kept
+    as numbers (``lse_rows``)."""
     b, h, s = rows.shape
-    n_slabs = like.shape[0] // b
+    pack = max(int(pack), 1)
+    n_slabs = -(-h // pack)
     rows = jnp.pad(rows, ((0, 0), (0, n_slabs * pack - h), (0, 0)))
-    heads = rows.astype(like.dtype).reshape(b * n_slabs, pack, s)
+    heads = rows.astype(jnp.float32).reshape(b * n_slabs, pack, s)
     return jnp.pad(
         heads.transpose(0, 2, 1), ((0, 0), (0, 0), (0, STAT_LANES - pack))
     )
@@ -973,7 +983,7 @@ def _pallas_backward(q, k, v, out, lse, g, causal, scale,
         block_q, block_k, nq, window,
     )
     g = g.astype(q.dtype)
-    glse = () if g_lse is None else (_stat_tiles(g_lse, lse, pack),)
+    glse = () if g_lse is None else (_stat_tiles(g_lse, pack),)
     stat_struct = _out_struct(lse.shape, lse.dtype, q)
 
     def dq_kernel(kernel, sel_spec=None):
@@ -1411,11 +1421,39 @@ def _chunked_backward(q, k, v, out, lse, g, causal, scale, chunk,
     )
 
 
+def _name_residuals(out, lse, q, head_pack, lse_rows):
+    """The forward kernel's two results under the names a remat policy
+    lists to keep them and not run the kernel again (the decoder's
+    ``save_attn`` tiers, and ``full`` at long spans): (out, lse as the
+    backward rule's residual). ``flash_lse`` names the statistics as
+    NUMBERS, ``[B, H, S]`` float32, where the caller says a policy
+    keeps them (``lse_rows``): 1/128 of the tile array, which the
+    backward rule builds again (``_residual_tiles``). Where nothing
+    keeps them the residual is the tile array as the kernel wrote it
+    and XLA makes no pass over the statistics."""
+    if lse_rows:
+        lse = _stat_rows(lse, q.shape[0], q.shape[2], head_pack)
+    return checkpoint_name(out, "flash_out"), checkpoint_name(lse, "flash_lse")
+
+
+def _residual_rows(lse, q, head_pack, lse_rows):
+    """``[B, H, S]`` from ``_name_residuals``' lse: for a caller that
+    asked for lse, and the jnp fallback."""
+    if lse_rows:
+        return lse
+    return _stat_rows(lse, q.shape[0], q.shape[2], head_pack)
+
+
+def _residual_tiles(lse, head_pack, lse_rows):
+    """The tile array the backward kernels read, from the same."""
+    return _stat_tiles(lse, head_pack) if lse_rows else lse
+
+
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10)
+    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11)
 )
 def _flash_attention(q, k, v, prefix, offsets, causal, scale, block_q,
-                     block_k, window=0, head_pack=1):
+                     block_k, window=0, head_pack=1, lse_rows=False):
     out, _ = _flash_fwd(
         q, k, v, causal, scale, block_q, block_k, prefix=prefix,
         window=window, offsets=offsets, head_pack=head_pack,
@@ -1424,40 +1462,39 @@ def _flash_attention(q, k, v, prefix, offsets, causal, scale, block_q,
 
 
 def _fwd_rule(q, k, v, prefix, offsets, causal, scale, block_q, block_k,
-              window=0, head_pack=1):
+              window=0, head_pack=1, lse_rows=False):
     out, lse = _flash_fwd(
         q, k, v, causal, scale, block_q, block_k, prefix=prefix,
         window=window, offsets=offsets, head_pack=head_pack,
     )
-    # named so remat policies can pin the kernel residuals in memory and
-    # skip re-running the forward kernel in backward (decoder save_attn)
-    out = checkpoint_name(out, "flash_out")
-    lse = checkpoint_name(lse, "flash_lse")
+    out, lse = _name_residuals(out, lse, q, head_pack, lse_rows)
     return out, (q, k, v, prefix, offsets, out, lse)
 
 
-def _bwd_rule(causal, scale, block_q, block_k, window, head_pack,
+def _bwd_rule(causal, scale, block_q, block_k, window, head_pack, lse_rows,
               residuals, g):
     # same dispatch as the lse-carrying variant, with no lse cotangent
     return _bwd_rule_lse(
-        causal, scale, block_q, block_k, window, head_pack, residuals,
-        (g, None),
+        causal, scale, block_q, block_k, window, head_pack, lse_rows,
+        residuals, (g, None),
     )
 
 
 _flash_attention.defvjp(_fwd_rule, _bwd_rule)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
 def flash_attention_with_lse(q, k, v, prefix, offsets, causal, scale,
-                             block_q, block_k, window=0, head_pack=1):
+                             block_q, block_k, window=0, head_pack=1,
+                             lse_rows=False):
     """Flash attention returning (out, lse) with BOTH differentiable —
     the primitive ring attention composes (the lse feeds the cross-block
     softmax merge, so its gradient is load-bearing). ``prefix`` [B] int32
     adds the prefix-LM bidirectional-prefix mask (causal only).
     ``offsets`` [2] int32 (q_off, k_off) shifts the mask rule to global
     positions — ring attention passes the blocks' traced ring offsets so
-    window-boundary and prefix-reach blocks run this kernel too."""
+    window-boundary and prefix-reach blocks run this kernel too.
+    ``lse_rows``: ``_name_residuals``."""
     out, lse = _flash_fwd(
         q, k, v, causal, scale, block_q, block_k, prefix=prefix,
         window=window, offsets=offsets, head_pack=head_pack,
@@ -1466,30 +1503,27 @@ def flash_attention_with_lse(q, k, v, prefix, offsets, causal, scale,
 
 
 def _fwd_rule_lse(q, k, v, prefix, offsets, causal, scale, block_q,
-                  block_k, window=0, head_pack=1):
+                  block_k, window=0, head_pack=1, lse_rows=False):
     out, lse = _flash_fwd(
         q, k, v, causal, scale, block_q, block_k, prefix=prefix,
         window=window, offsets=offsets, head_pack=head_pack,
     )
-    # same tags as _fwd_rule: lets remat policies (and the ring's scan
-    # checkpoint) pin the residuals instead of re-running the kernel
-    out = checkpoint_name(out, "flash_out")
-    lse = checkpoint_name(lse, "flash_lse")
-    # the [B, H, S] view is this caller's alone: the residual stays in
-    # the kernels' tiles
-    rows = _stat_rows(lse, q.shape[0], q.shape[2], head_pack)
+    out, lse = _name_residuals(out, lse, q, head_pack, lse_rows)
+    # where no policy keeps them the [B, H, S] view is this caller's
+    # alone: the residual stays in the kernels' tiles
+    rows = _residual_rows(lse, q, head_pack, lse_rows)
     return (out, rows), (q, k, v, prefix, offsets, out, lse)
 
 
 def _bwd_rule_lse(causal, scale, block_q, block_k, window, head_pack,
-                  residuals, cot):
+                  lse_rows, residuals, cot):
     """The ONE backward dispatch (plain _bwd_rule delegates here with a
     None lse cotangent): FA2 pallas kernels on TPU/interpret with tiles
     capped per head width (BWD_BLOCK / BWD_BLOCK_WIDE — ~4 [bq,bk] f32
     transients per grid step at the applied cap); jnp chunked recompute
     off-TPU or when the sequence doesn't tile to a lane-aligned block.
-    The lse residual is the forward kernel's tile array: the kernels
-    take it as it is, the fallback its [B, H, S] view."""
+    The lse residual is ``_name_residuals``': the kernels take the tile
+    array, the fallback the [B, H, S] numbers."""
     q, k, v, prefix, offsets, out, lse = residuals
     g_out, g_lse = cot
     # wider heads keep the MXU busier per tile, so bigger tiles win
@@ -1506,14 +1540,15 @@ def _bwd_rule_lse(causal, scale, block_q, block_k, window, head_pack,
     set_counter("attn.delta_in_kernel", int(in_kernel))
     if in_kernel:
         dq, dk, dv, _ = _pallas_backward(
-            q, k, v, out, lse, g_out, causal, scale, bq, bk,
+            q, k, v, out, _residual_tiles(lse, head_pack, lse_rows), g_out,
+            causal, scale, bq, bk,
             prefix=prefix, g_lse=g_lse, window=window, offsets=offsets,
             head_pack=head_pack,
         )
     else:
         dq, dk, dv = _chunked_backward(
             q, k, v, out,
-            _stat_rows(lse, q.shape[0], q.shape[2], head_pack), g_out,
+            _residual_rows(lse, q, head_pack, lse_rows), g_out,
             causal, scale,
             chunk=_bwd_chunk(k.shape[1], block_k),
             g_lse=g_lse,
@@ -1537,8 +1572,9 @@ def _bwd_rule_lse(causal, scale, block_q, block_k, window, head_pack,
 flash_attention_with_lse.defvjp(_fwd_rule_lse, _bwd_rule_lse)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _flash_attention_selected(q, k, v, selected, scale, block_q, block_k):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash_attention_selected(q, k, v, selected, scale, block_q, block_k,
+                              lse_rows=False):
     """Causal attention over each query's SELECTION of keys
     (``selected`` [B, Sq, Sk] int8, nonzero at the chosen keys, one mask
     for all heads of a sequence) through the unpacked ``_sel`` kernels:
@@ -1552,22 +1588,22 @@ def _flash_attention_selected(q, k, v, selected, scale, block_q, block_k):
     return out, _stat_rows(lse, q.shape[0], q.shape[2], 1)
 
 
-def _fwd_rule_selected(q, k, v, selected, scale, block_q, block_k):
+def _fwd_rule_selected(q, k, v, selected, scale, block_q, block_k,
+                       lse_rows=False):
     out, lse = _flash_fwd(
         q, k, v, True, scale, block_q, block_k, selected=selected
     )
-    out = checkpoint_name(out, "flash_out")
-    lse = checkpoint_name(lse, "flash_lse")
-    rows = _stat_rows(lse, q.shape[0], q.shape[2], 1)
+    out, lse = _name_residuals(out, lse, q, 1, lse_rows)
+    rows = _residual_rows(lse, q, 1, lse_rows)
     return (out, rows), (q, k, v, selected, out, lse)
 
 
-def _bwd_rule_selected(scale, block_q, block_k, residuals, cot):
+def _bwd_rule_selected(scale, block_q, block_k, lse_rows, residuals, cot):
     q, k, v, selected, out, lse = residuals
     g_out, _ = cot  # lse is detached
     cap_q, cap_k = _bwd_caps(q.shape[-1])
     dq, dk, dv, _ = _pallas_backward(
-        q, k, v, out, lse, g_out, True, scale,
+        q, k, v, out, _residual_tiles(lse, 1, lse_rows), g_out, True, scale,
         _fit_block(q.shape[1], min(block_q, cap_q)),
         _fit_block(k.shape[1], min(block_k, cap_k)),
         selected=selected,
@@ -1591,6 +1627,7 @@ def flash_attention(
     window: int = 0,  # sliding window (causal only; 0 = unlimited)
     head_pack: int = 0,  # 0 = auto (128 // D heads a slab), 1 = unpacked
     selected: Optional[jax.Array] = None,  # [B, Sq, Sk] bool or int8
+    lse_rows: bool = False,  # a remat policy of the caller keeps flash_lse
 ):
     """Flash attention; falls back to the jnp path off-TPU.
 
@@ -1609,6 +1646,10 @@ def flash_attention(
     prefix, no window): each query attends to the keys it names, the
     same for every head, and the result is ``(out, lse [B, H, S])`` with
     ``lse`` float32 and detached.
+    ``lse_rows``: the caller runs this under a remat policy that keeps
+    ``flash_out`` and ``flash_lse``, so the residual of that name is the
+    statistics as numbers and not the kernels' tile array
+    (``_name_residuals``); the values and gradients are the same.
     """
     scale = softmax_scale if softmax_scale is not None else q.shape[-1] ** -0.5
     sq, sk = q.shape[1], k.shape[1]
@@ -1631,7 +1672,9 @@ def flash_attention(
             raise ValueError(
                 "a selection of keys runs under the plain causal mask"
             )
-        return _selected_attention(q, k, v, selected, scale, bq, bk)
+        return _selected_attention(
+            q, k, v, selected, scale, bq, bk, lse_rows
+        )
     if pltpu is None or not (device.on_tpu() or INTERPRET) or bq is None or bk is None:
         # off-TPU (incl. GPU — this is a Mosaic-TPU kernel), or seq not
         # tileable to a lane-aligned block: plain jnp, never a trace-time
@@ -1653,11 +1696,12 @@ def flash_attention(
         pack = LANES // d
     set_counter("attn.heads_per_slab", pack)
     return _flash_attention(
-        q, k, v, prefix_len, None, causal, scale, bq, bk, window, pack
+        q, k, v, prefix_len, None, causal, scale, bq, bk, window, pack,
+        lse_rows,
     )
 
 
-def _selected_attention(q, k, v, selected, scale, bq, bk):
+def _selected_attention(q, k, v, selected, scale, bq, bk, lse_rows):
     """``flash_attention``'s path under a selection: the ``_sel`` kernels
     where the kernels run at all, else the jnp reference with the same
     mask. (out, lse [B, H, S] float32, detached)."""
@@ -1674,7 +1718,7 @@ def _selected_attention(q, k, v, selected, scale, bq, bk):
     set_counter("attn.heads_per_slab", 1)
     set_counter("attn.delta_in_kernel", 1)
     return _flash_attention_selected(
-        q, k, v, selected.astype(jnp.int8), scale, bq, bk
+        q, k, v, selected.astype(jnp.int8), scale, bq, bk, lse_rows
     )
 
 

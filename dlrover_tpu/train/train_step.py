@@ -1197,13 +1197,17 @@ class TrainStepBuilder:
 
     def step_fn(self, state: TrainState, batch) -> Tuple[TrainState, Dict]:
         cfg = self.cfg
+        # which path the step took. Trace time, a value: a retrace sets
+        # the same number again
+        seq = batch["tokens"].shape[-1]
+        set_counter("attn.output_kept", int(
+            decoder.keeps_attention_output(cfg, seq, self.attn_impl, self.mesh)
+        ))
         if cfg.selects_keys:
-            # which path the step took. Trace time, a value: a retrace
-            # sets the same number again
             set_counter("attn.align_passes", decoder.alignment_passes(cfg))
             set_counter("attn.align_in_kernel", int(
                 decoder.alignment_in_kernel(
-                    cfg, batch["tokens"].shape[-1], self.attn_impl, self.mesh
+                    cfg, seq, self.attn_impl, self.mesh
                 )
             ))
         if self.update_sharding:

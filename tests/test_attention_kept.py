@@ -1,0 +1,241 @@
+"""``remat: full`` at long spans keeps the flash kernel's output
+(``flash_out``, and ``flash_lse`` as numbers) and does not run the
+kernel again in the recomputed forward (``decoder.keeps_attention_output``):
+the kept value is the value that would have been remade, so losses and
+gradients are the remade step's; where the line falls over the
+benchmark's cells; and what the residual of that name holds."""
+
+import functools
+import json
+import pathlib
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax._src.ad_checkpoint import saved_residuals
+from jax.ad_checkpoint import checkpoint_policies as cp
+
+from dlrover_tpu.models import decoder, get_config
+from dlrover_tpu.ops import pallas_attention
+
+ROOT = pathlib.Path(__file__).parent.parent
+SMALL = dict(n_layer=2, vocab_size=512, max_seq=256, remat="full",
+             dtype="float32")
+# model, overrides, the kernel a layer's forward runs
+LAYERS = {
+    # MHA, two heads of 128: the unpacked kernels
+    "causal": ("gpt2-1.5b", dict(d_model=256, n_head=2, d_ff=512),
+               "flash_fwd"),
+    # GQA 2 / 1 under a window that no block boundary meets
+    "windowed": ("mistral-7b", dict(d_model=256, n_head=2, n_kv_head=1,
+                                    d_ff=512, attn_window=96), "flash_fwd"),
+    # three heads of 64: two 128-lane slabs, the second half full
+    "packed": ("gpt2-1.5b", dict(d_model=192, n_head=3, d_ff=384),
+               "flash_fwd_packed"),
+    # a selection of keys, its alignment term through its kernel
+    "selected": ("keye-vl-2.0", dict(
+        d_model=128, n_head=4, n_kv_head=2, d_head=64, n_experts=16,
+        expert_top_k=4, d_expert=64, n_experts_held=4, expert_offset=4,
+        index_n_heads=4, index_head_dim=64, index_topk=160, index_chunk=128,
+    ), "flash_fwd_sel"),
+}
+
+
+def _forward_calls(fn, *args, kernel):
+    """How often the program of ``fn`` holds the forward ``kernel``."""
+    return len(re.findall(
+        rf"name={kernel}(?!\w)", str(jax.make_jaxpr(fn)(*args))
+    ))
+
+
+@pytest.mark.parametrize("case", sorted(LAYERS))
+def test_kept_output_gives_the_remade_gradients(monkeypatch, case):
+    """Two layers through ``loss_fn`` with the kernels interpreted: with
+    the line under the span the backward scan's body holds no forward
+    kernel (the forward scan's one is the program's only one), over it
+    one; loss and every gradient leaf are equal to the bit."""
+    monkeypatch.setattr(pallas_attention, "INTERPRET", True)
+    model, over, kernel = LAYERS[case]
+    cfg = get_config(model, **{**SMALL, **over})
+    params = decoder.init(jax.random.key(0), cfg)
+    tokens = jax.random.randint(jax.random.key(1), (2, 256), 0, 512)
+    batch = {"tokens": tokens, "targets": jnp.roll(tokens, -1, axis=1)}
+
+    got = {}
+    for kept, line in ((True, 1), (False, 10 ** 9)):
+        monkeypatch.setattr(decoder, "KEEP_ATTN_SPAN", line)
+        assert decoder.keeps_attention_output(cfg, 256, "flash") is kept
+
+        def step(p):  # a function of its own a side: traces are cached
+            return jax.value_and_grad(
+                lambda p: decoder.loss_fn(p, batch, cfg, attn_impl="flash")[0]
+            )(p)
+
+        assert _forward_calls(step, params, kernel=kernel) == (
+            1 if kept else 2
+        )
+        got[kept] = jax.jit(step)(params)
+    (loss_k, grads_k), (loss_r, grads_r) = got[True], got[False]
+    assert float(loss_k) == float(loss_r)
+    flat_k = jax.tree_util.tree_leaves_with_path(grads_k)
+    for (path, g), w in zip(flat_k, jax.tree.leaves(grads_r)):
+        assert float(jnp.max(jnp.abs(w))) > 0
+        np.testing.assert_array_equal(
+            np.asarray(g), np.asarray(w), err_msg=jax.tree_util.keystr(path)
+        )
+
+
+def test_kept_lse_variant_gives_the_remade_gradients(monkeypatch):
+    """``flash_attention_with_lse`` (the ring's primitive) under the
+    same two policies, with a cotangent for lse as the ring's merge has:
+    the residual named ``flash_lse`` is the [B, H, S] array the caller
+    got, and the backward rebuilds the kernels' tiles from it."""
+    monkeypatch.setattr(pallas_attention, "INTERPRET", True)
+    b, s, h, d = 2, 256, 3, 64
+    keys = jax.random.split(jax.random.key(3), 4)
+    x = jax.random.normal(keys[0], (b, s, 96))
+    w_qkv = jax.random.normal(keys[1], (96, 3 * h * d)) * 0.1
+    g_out = jax.random.normal(keys[2], (b, s, h, d))
+    g_lse = jax.random.normal(keys[3], (b, h, s))
+
+    def layer(lse_rows, x, w):
+        q, k, v = jnp.split((x @ w).reshape(b, s, 3 * h, d), 3, axis=2)
+        out, lse = pallas_attention.flash_attention_with_lse(
+            q, k, v, None, None, True, d ** -0.5, 128, 128, 0, 2, lse_rows
+        )
+        return jnp.vdot(out, g_out) + jnp.vdot(lse, g_lse)
+
+    kept = jax.checkpoint(
+        functools.partial(layer, True),
+        policy=cp.save_only_these_names("flash_out", "flash_lse"),
+    )
+    remade = jax.checkpoint(functools.partial(layer, False))
+    grad_k, grad_r = (
+        jax.grad(fn, argnums=(0, 1)) for fn in (kept, remade)
+    )
+    assert _forward_calls(grad_k, x, w_qkv, kernel="flash_fwd_packed") == 1
+    assert _forward_calls(grad_r, x, w_qkv, kernel="flash_fwd_packed") == 2
+    for a, r in zip(grad_k(x, w_qkv), grad_r(x, w_qkv)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(r))
+
+
+def test_kept_statistics_are_numbers(monkeypatch):
+    """What a policy that lists ``flash_lse`` saves: with ``lse_rows``
+    the [B, H, S] numbers, without it the kernels' tile array, 8 lanes
+    a row of which memory holds 128."""
+    monkeypatch.setattr(pallas_attention, "INTERPRET", True)
+    q = jax.random.normal(jax.random.key(5), (2, 256, 4, 128))
+
+    def saved(lse_rows):
+        fn = jax.checkpoint(
+            lambda q, k, v: pallas_attention.flash_attention(
+                q, k, v, block_q=128, block_k=128, lse_rows=lse_rows
+            ).sum(),
+            policy=cp.save_only_these_names("flash_out", "flash_lse"),
+        )
+        residuals = saved_residuals(fn, q, q, q)
+        return {
+            tuple(aval.shape) for aval, why in residuals
+            if "flash_lse" in why
+        }
+
+    assert saved(True) == {(2, 4, 256)}
+    assert saved(False) == {(8, 256, 8)}
+
+
+def _cells():
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    files = {c["name"]: c["file"] for c in bench["configs"]}
+    for cell in bench["workloads"]:
+        program = json.loads((ROOT / files[cell["config"]]).read_text())[
+            "program"
+        ]
+        traffic = json.loads(
+            (ROOT / "benchmarks" / "traffic" / f"{cell['traffic']}.json")
+            .read_text()
+        )
+        yield cell["name"], program, traffic["seq"]
+
+
+# the mean executed span of every cell, and whether ``full`` keeps the
+# attention's output there
+CELL_SPANS = {
+    "gpt2xl-train-b8s1024": (512.5, False),
+    "gpt2xl-zero1-dp4-b32s1024": (512.5, False),
+    "olmoe-1chip-train-b2s4096": (2048.5, True),
+    "mistral7b-l6-train-b1s8192": (3072.25, True),  # the window live
+    "glm47flash-ep8-train-b2s8192": (4096.5, True),
+    # the causal span, not ``index_topk``: the kernels run every block
+    "keyevl2-ep8-train-b1s8192": (4096.5, True),
+    "nemotron3super-ep64-train-b1s8192": (4096.5, True),
+}
+
+
+def test_the_line_falls_between_the_cells():
+    """The predicate over the benchmark's cells as they are run: true
+    for the five whose span is 2,048.5 keys or more, false for the two
+    at 1,024 tokens, and false everywhere on the reference attention
+    (the CPU's ``auto``), under another tier and at a sequence the
+    kernels do not tile."""
+    import dataclasses
+
+    seen = {}
+    for name, program, seq in _cells():
+        cfg = get_config(program["model"], **program["overrides"])
+        assert cfg.remat == "full", name
+        seen[name] = (
+            cfg.executed_span(seq),
+            decoder.keeps_attention_output(cfg, seq, "flash"),
+        )
+        assert not decoder.keeps_attention_output(cfg, seq, "reference")
+        assert not decoder.keeps_attention_output(cfg, seq)  # auto: CPU
+        assert not decoder.keeps_attention_output(cfg, seq + 64, "flash")
+        other = dataclasses.replace(cfg, remat="save_attn")
+        assert not decoder.keeps_attention_output(other, seq, "flash")
+    assert seen == CELL_SPANS
+    assert sum(kept for _, kept in seen.values()) == 5
+
+
+@pytest.mark.parametrize("remat,keep,lse_named", [
+    ("none", False, False), ("full", False, False), ("full", True, True),
+    ("dots_saveable", False, False), ("save_attn", False, True),
+    ("save_qkv", False, True), ("save_qkv_gate", False, True),
+    ("save_dots", False, True), ("offload_attn", False, True),
+    ("save_qkv_offload", False, True),
+])
+def test_which_tiers_name_the_statistics(remat, keep, lse_named):
+    """Every tier that lists ``flash_lse`` tells the kernels' rule to
+    name the numbers; where nothing lists it the residual stays the
+    tile array and the compiled step is what it was."""
+    cfg = get_config("gpt2-1.5b", n_layer=1, remat=remat)
+    names, offload = decoder._kept_names(cfg, keep)
+    assert ("flash_lse" in names) == lse_named
+    assert ("flash_out" in names) == lse_named
+    assert offload == ("offload" in remat)
+    keye = get_config("keye-vl-2.0", n_layer=1, remat=remat)
+    own = {"attn_selected", "attn_align_grad"}
+    assert own <= set(decoder._kept_names(keye, keep)[0]) or remat != "full"
+
+
+def test_analyser_counts_the_kept_attention_output(monkeypatch):
+    """Under ``full`` the activation bytes add the attention's kept
+    output and row statistics where ``keeps_attention_output`` holds
+    (the flash kernels, a span of 2,048 keys or more): Mistral's widths
+    at 8,192 tokens on the chip, not on the CPU's reference attention
+    and not at 1,024 tokens."""
+    from dlrover_tpu.accelerate.analyser import analyse
+    from dlrover_tpu.accelerate.strategy import apply_strategy
+    from dlrover_tpu.common import device
+
+    cfg = get_config("mistral-7b", n_layer=6, max_seq=8192)
+    plan = apply_strategy([("mixed_parallel", {"dp": 1})])
+    plan.remat, plan.compute_dtype = "full", "bfloat16"
+    on_cpu = analyse(cfg, plan, 1, 1, 8192, hbm_bytes=16e9)
+    monkeypatch.setattr(device, "on_cpu", lambda: False)
+    on_chip = analyse(cfg, plan, 1, 1, 8192, hbm_bytes=16e9)
+    kept = 8192 * 6 * 32 * (128 * 2 + 4)  # flash_out + flash_lse, 6 layers
+    assert on_chip.act_bytes_per_chip - on_cpu.act_bytes_per_chip == kept
+    short = analyse(cfg, plan, 1, 8, 1024, hbm_bytes=16e9)
+    assert short.act_bytes_per_chip == on_cpu.act_bytes_per_chip

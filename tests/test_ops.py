@@ -804,8 +804,8 @@ def test_flash_delta_from_the_dq_kernel(h, hkv, d, dtype):
     if h % pack:
         heads = np.asarray(pa._stat_rows(delta, b, n_slabs * pack, pack))
         assert heads.shape[1] > h and not heads[:, h:].any()
-    # and the way back in, which only the ring's lse cotangent takes
-    back = pa._stat_tiles(jnp.asarray(got), delta, pack)
+    # and the way back in: the ring's lse cotangent, an lse kept as rows
+    back = pa._stat_tiles(jnp.asarray(got), pack)
     assert back.shape == delta.shape
     np.testing.assert_array_equal(
         np.asarray(pa._stat_rows(back, b, h, pack)), got
