@@ -110,12 +110,14 @@ def analyse(
     if plan.remat == "full":
         # only layer-boundary activations are kept
         act_b = tokens * cfg.d_model * act_dtype_b * cfg.n_layer
-        if sizes["sp"] == 1 and decoder.keeps_attention_output(
-            replace(cfg, remat="full"), seq
-        ):
-            # and, at long spans on the flash kernels, every attention
-            # layer's output with its row statistics (one f32 a head)
-            act_b += tokens * cfg.n_attention_layers * cfg.n_head * (
+        if sizes["sp"] == 1:
+            # and, at long spans on the flash kernels, the output of
+            # every attention layer that keeps it, with its row
+            # statistics (one f32 a head)
+            kept = decoder.kept_attention_layers(
+                replace(cfg, remat="full"), seq
+            )
+            act_b += tokens * kept * cfg.n_head * (
                 cfg.head_dim * act_dtype_b + 4
             )
     else:

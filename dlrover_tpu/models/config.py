@@ -68,6 +68,24 @@ class ModelConfig:
     # that): wq and wo are n_head · head_dim wide, which need not be
     # d_model (Keye: 32 x 128 over 2048)
     d_head: int = 0
+    # an attention KIND per layer (a published ``layer_types``; "" =
+    # every layer the model's one kind): one letter a layer of the
+    # trunk, the dense prefix first. ``S`` a sliding-window layer, rope
+    # on q and k, the last ``attn_window`` keys; ``F`` a full causal
+    # layer with NO positional term at all. Both kinds have the same
+    # parameters, so a stack stays one stack and is scanned a period at
+    # a time. Training path only
+    layer_types: str = ""
+    # a sigmoid gate on the attention's output, per channel, from the
+    # layer's normed input: o <- o * sigmoid(h W_g) before W_o
+    attn_gate: bool = False
+    # a norm on each part's OUTPUT before the residual add, beside the
+    # one on its input: x <- x + norm(part(norm(x))), four norms a layer
+    post_norm: bool = False
+    # token embeddings times sqrt(d_model) (afmoe's ``mup_enabled``)
+    scale_embedding: bool = False
+    # the norms' epsilon (None = 1e-6 RMSNorm, 1e-5 LayerNorm)
+    norm_eps: Optional[float] = None
     tie_embeddings: bool = True
     # numerics
     dtype: str = "bfloat16"          # activation/compute dtype
@@ -351,6 +369,35 @@ class ModelConfig:
                 "qk_head_norm norms each head of plain q and k "
                 "projections; qk_norm norms them whole: one or the other"
             )
+        if self.layer_types:
+            odd = set(self.layer_types) - set("SF")
+            if odd or len(self.layer_types) != self.n_layer:
+                raise ValueError(
+                    "layer_types names each of the n_layer layers S "
+                    "(sliding window, rope) or F (full, no positions); "
+                    f"got {self.layer_types!r} for {self.n_layer} layers"
+                )
+            if (
+                self.pos != "rope" or not self.causal or self.prefix_lm
+                or self.latent_attention or self.selects_keys
+                or self.layer_pattern or self.n_mtp_module or self.fp8
+                or ("S" in self.layer_types and not self.attn_window)
+            ):
+                raise ValueError(
+                    "layer_types is for causal plain-attention layers of "
+                    "a rope model with attn_window set: no prefix-LM, "
+                    "latent attention, key selection, layer_pattern, "
+                    "prediction module or fp8"
+                )
+        if (self.attn_gate or self.post_norm) and (
+            self.latent_attention or self.selects_keys or self.layer_pattern
+            or self.parallel_residual or self.fp8
+        ):
+            raise ValueError(
+                "attn_gate and post_norm are built into the plain "
+                "attention layer: no latent attention, key selection, "
+                "layer_pattern, parallel residual or fp8"
+            )
         if self.n_mtp_module not in (0, 1):
             raise ValueError(
                 "one multi-token-prediction module is built; "
@@ -486,6 +533,26 @@ class ModelConfig:
     def selects_keys(self) -> bool:
         return self.index_topk > 0
 
+    def kind_window(self, kind: str = "") -> int:
+        """Keys a query of a layer of ``kind`` may see (0 = every
+        earlier one; None reads as 0): ``attn_window`` as it is, but
+        none on an ``F`` layer."""
+        return 0 if kind == "F" else self.attn_window
+
+    def kind_rope(self, kind: str = "") -> bool:
+        """Whether a layer of ``kind`` turns q and k by rope."""
+        return self.pos == "rope" and kind != "F"
+
+    @property
+    def attn_params(self) -> int:
+        """One plain-attention layer's matrices: q, k, v, o and the
+        output gate's where the model has one."""
+        d_attn = self.n_head * self.head_dim
+        return (
+            (2 + self.attn_gate) * self.d_model * d_attn
+            + 2 * self.d_model * self.kv_heads * self.head_dim
+        )
+
     @property
     def index_params(self) -> int:
         """One layer's indexer matrices: the query heads, the one key
@@ -532,8 +599,10 @@ class ModelConfig:
             return "a trunk whose layers differ"
         if self.latent_attention:
             return "latent attention (no latent cache is built)"
-        if self.n_dense_layer:
+        if self.n_dense_layer or self.layer_types:
             return "a trunk whose layers differ"
+        if self.attn_gate or self.post_norm or self.scale_embedding:
+            return "a gated, twice-normed layer the cache paths do not build"
         if self.n_mtp_module:
             return "a prediction module"
         if self.selects_keys:
@@ -553,10 +622,7 @@ class ModelConfig:
         benchmark's reference counts them; the conv's taps are not."""
         d = self.d_model
         inner, heads = self.d_inner, self.mamba_num_heads
-        attn = (
-            2 * d * self.n_head * self.head_dim
-            + 2 * d * self.kv_heads * self.head_dim
-        )
+        attn = self.attn_params
         w_in = d * (inner + self.conv_dim + heads)
         mamba = w_in + inner * d
         mats = 2 if self.act == "relu2" else 3  # matrices of an expert
@@ -609,11 +675,9 @@ class ModelConfig:
                 + self.q_lora_rank + self.kv_lora_rank
             )
         else:
-            attn = (
-                2 * d * self.n_head * self.head_dim
-                + 2 * d * self.kv_heads * self.head_dim
-            )
-        attn += self.index_params
+            attn = self.attn_params
+        # the indexer; and the two norms on the parts' outputs
+        attn += self.index_params + 2 * d * self.post_norm
         gated = 3 if self.act == "swiglu" else 2
         mlp = gated * d * f
         embed = v * d * (1 if self.tie_embeddings else 2)
@@ -641,15 +705,16 @@ class ModelConfig:
             return (self.layer_pattern + self.mtp_pattern).count("*")
         return self.n_layer + self.n_mtp_module
 
-    def executed_span(self, seq_len: int) -> float:
+    def executed_span(self, seq_len: int, kind: str = "") -> float:
         """The mean number of keys the attention kernels EXECUTE a
-        query at ``seq_len``: the causal span under ``attn_window``,
-        every key without the mask. A model that selects its keys runs
-        the whole causal span too (the ``_sel`` kernels run every
-        causal block), whatever ``index_topk`` credits it."""
+        query at ``seq_len`` in a layer of ``kind`` (``layer_types``'
+        letter; "" = the model's one kind): the causal span under that
+        kind's window, every key without the mask. A model that selects
+        its keys runs the whole causal span too (the ``_sel`` kernels
+        run every causal block), whatever ``index_topk`` credits it."""
         if not self.causal:
             return float(seq_len)
-        return mean_span(seq_len, self.attn_window)
+        return mean_span(seq_len, self.kind_window(kind))
 
     def flops_per_token(self, seq_len: int) -> float:
         """FLOPs a training step requires per token, forward and
@@ -698,7 +763,7 @@ class ModelConfig:
                 + self.n_head * self.v_head_dim * d
             )
         else:
-            attn = 2 * d * d_attn + 2 * d * self.kv_heads * self.head_dim
+            attn = self.attn_params
         # a score-only indexer: its projections among the multiplied
         attn += self.index_params
         gated = 3 if self.act == "swiglu" else 2
@@ -729,6 +794,11 @@ class ModelConfig:
             # and no value product) over every key it scores
             pairs = d_attn * mean_span(seq_len, self.index_topk) + (
                 self.index_n_heads * self.index_head_dim / 2 * span
+            )
+        if self.layer_types:
+            # each layer the span of its own kind
+            return 6.0 * multiplied + 12.0 * d_attn * sum(
+                self.executed_span(seq_len, kind) for kind in self.layer_types
             )
         return 6.0 * multiplied + 12.0 * self.n_attention_layers * pairs
 
@@ -1037,6 +1107,42 @@ CONFIGS = {
         moe_renorm_topk=True,
         routed_scaling_factor=5.0,
         n_mtp_module=1,
+    ),
+    # an attention kind per layer, a gate on the attention's output,
+    # four norms a layer: Trinity-Mini (``afmoe``, 26B-A3B;
+    # huggingface.co/arcee-ai/Trinity-Mini config.json) — 32 layers of
+    # GQA 32 / 4 heads of 128 over d 2048 with per-head RMSNorm on q
+    # and k, ``layer_types`` three sliding-window layers (2,048 keys,
+    # rope theta 1e4) then one full layer without positions, eight
+    # times; two dense SwiGLU layers of 6144, then 30 of 128 experts of
+    # width 1024 (sigmoid top-8 renormalised x 2.826) beside a shared
+    # one; embeddings x sqrt(d) (``mup_enabled``), rms_norm_eps 1e-5,
+    # load balancing 0.001. The gate, the per-head norm, the full
+    # layers' missing rope and the two output norms are the ``afmoe``
+    # model code's (no key in config.json). Training path only
+    "trinity-mini": replace(
+        _llama(
+            "trinity-mini", 32, 32, 2048, 6144, max_seq=131072, n_kv_head=4
+        ),
+        vocab_size=200192,
+        d_head=128,
+        qk_head_norm=True,
+        attn_window=2048,
+        layer_types="SSSF" * 8,
+        attn_gate=True,
+        post_norm=True,
+        scale_embedding=True,
+        norm_eps=1e-5,
+        n_dense_layer=2,
+        n_experts=128,
+        expert_top_k=8,
+        d_expert=1024,
+        n_shared_experts=1,
+        moe_impl="ragged",
+        moe_score="sigmoid",
+        moe_renorm_topk=True,
+        routed_scaling_factor=2.826,
+        moe_aux_coef=0.001,
     ),
 }
 

@@ -18,6 +18,7 @@ One functional model covers the GPT-2 and LLaMA families (configs in
   (reference: checkpoint_optimization.py:15).
 """
 
+import contextlib
 import functools
 from typing import Any, Dict, Optional, Tuple
 
@@ -91,6 +92,11 @@ def _init_attention(keys, cfg: ModelConfig, stack, ones) -> Params:
             "wv": stack(keys[3], (d, nkv * hd), d),
             "wo": stack(keys[4], (nh * hd, d), nh * hd),
         }
+        if cfg.attn_gate:
+            # the output gate, a channel of every head's output each
+            attn["wg"] = stack(
+                jax.random.fold_in(keys[1], 1), (d, nh * hd), d
+            )
     return attn
 
 
@@ -128,6 +134,10 @@ def _init_layers(keys, cfg: ModelConfig, lead, routed: bool) -> Params:
     if cfg.qk_head_norm:
         attn["q_norm"] = {"scale": ones(hd)}
         attn["k_norm"] = {"scale": ones(hd)}
+    if cfg.post_norm:
+        # the norms on the two parts' outputs
+        layers["ln1_post"] = {"scale": ones(d)}
+        layers["ln2_post"] = {"scale": ones(d)}
     if cfg.selects_keys:
         nj, nc = cfg.index_n_heads, cfg.index_head_dim
         ik = jax.random.split(keys[14], 3)
@@ -287,6 +297,8 @@ def _attention_axes(cfg: ModelConfig, lead) -> Params:
             "wv": lead + ("embed", "kv"),
             "wo": lead + ("heads", "embed"),
         }
+        if cfg.attn_gate:
+            attn["wg"] = lead + ("embed", "heads")
     return attn
 
 
@@ -344,6 +356,9 @@ def _layer_axes(cfg: ModelConfig, lead, routed: bool) -> Params:
     if cfg.qk_norm or cfg.qk_head_norm:
         attn["q_norm"] = {"scale": lead + ("norm",)}
         attn["k_norm"] = {"scale": lead + ("norm",)}
+    if cfg.post_norm:
+        ax["ln1_post"] = {"scale": lead + ("norm",)}
+        ax["ln2_post"] = {"scale": lead + ("norm",)}
     if cfg.selects_keys:
         ax["indexer"] = {
             "wq": lead + ("embed", None),
@@ -480,10 +495,14 @@ def _embed_tokens(params: Params, tokens, mesh, dt):
     return jnp.take(table, tokens, axis=0).astype(dt)
 
 
-def _norm(x, scale, bias, kind: str):
+def _norm(x, scale, bias, kind: str, eps=None):
+    """``eps``: ``cfg.norm_eps``; None = the program's own (1e-6
+    RMSNorm, 1e-5 LayerNorm: ``pallas_norm.RMS_EPS`` / ``LN_EPS``)."""
     x32 = x.astype(jnp.float32)
     if kind == "rmsnorm":
-        rms = jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + 1e-6)
+        rms = jax.lax.rsqrt(
+            jnp.mean(x32 * x32, -1, keepdims=True) + (eps or 1e-6)
+        )
         out = x32 * rms * scale.astype(jnp.float32)
     else:
         # single pass over the f32 upcast: E[x] and E[x²] share one
@@ -492,7 +511,7 @@ def _norm(x, scale, bias, kind: str):
         mean = jnp.mean(x32, -1, keepdims=True)
         ex2 = jnp.mean(x32 * x32, -1, keepdims=True)
         var = jnp.maximum(ex2 - mean * mean, 0.0)
-        out = (x32 - mean) * jax.lax.rsqrt(var + 1e-5)
+        out = (x32 - mean) * jax.lax.rsqrt(var + (eps or 1e-5))
         out = out * scale.astype(jnp.float32)
         if bias is not None:
             out = out + bias.astype(jnp.float32)
@@ -516,12 +535,13 @@ def _norm_block(x, ln, cfg: ModelConfig, residual=None):
     summed stream comes out of the same HBM visit."""
     if _fused_norm_enabled(cfg):
         return pallas_norm.norm(
-            x, ln["scale"], ln.get("bias"), cfg.norm, residual=residual
+            x, ln["scale"], ln.get("bias"), cfg.norm, residual=residual,
+            eps=cfg.norm_eps,
         )
     if residual is not None:
         h = x + residual
-        return _norm(h, ln["scale"], ln.get("bias"), cfg.norm), h
-    return _norm(x, ln["scale"], ln.get("bias"), cfg.norm)
+        return _norm(h, ln["scale"], ln.get("bias"), cfg.norm, cfg.norm_eps), h
+    return _norm(x, ln["scale"], ln.get("bias"), cfg.norm, cfg.norm_eps)
 
 
 def _rope_tables(positions: jax.Array, head_dim: int, theta: float):
@@ -588,7 +608,9 @@ def _project_qkv(
 
     ``rope``: precomputed (cos, sin) tables from ``_rope_tables`` —
     the trunk/prefill/decode loops build them once and pass them to
-    every layer; None recomputes here (external callers, pp bodies).
+    every layer; None recomputes here (external callers, pp bodies);
+    False: this layer has no rope (an ``F`` layer of
+    ``cfg.layer_types``).
 
     ``cfg.qk_norm``: q and k are normed over their WHOLE projection
     (all heads at once; ``attn.q_norm`` / ``attn.k_norm``, scale only)
@@ -617,8 +639,8 @@ def _project_qkv(
     if cfg.qk_head_norm:
         # RMSNorm per head, one scale of head_dim for all heads; in jnp,
         # so that it fuses with rope's pass over the same values
-        q = _norm(q, attn["q_norm"]["scale"], None, "rmsnorm")
-        k = _norm(k, attn["k_norm"]["scale"], None, "rmsnorm")
+        q = _norm(q, attn["q_norm"]["scale"], None, "rmsnorm", cfg.norm_eps)
+        k = _norm(k, attn["k_norm"]["scale"], None, "rmsnorm", cfg.norm_eps)
     # names for the selective remat policies (save_qkv / save_dots):
     # identity outside jax.checkpoint, so the cache paths are
     # unaffected. Tagged BEFORE rope: backward re-runs only the cheap
@@ -628,7 +650,7 @@ def _project_qkv(
     q = _tag_residual(q, "q_proj", cfg)
     k = _tag_residual(k, "k_proj", cfg)
     v = _tag_residual(v, "v_proj", cfg)
-    if cfg.pos == "rope":
+    if cfg.pos == "rope" and rope is not False:
         if rope is None:
             rope = _rope_tables(positions, hd, cfg.rope_theta)
         q = _rope(q, rope)
@@ -643,10 +665,10 @@ def _cache_layer_tail(x, attn_out, layer, cfg: ModelConfig):
     (mirrors _layer_body minus mesh constraints, aux and rng)."""
     ln2 = layer["ln2"]
     if cfg.parallel_residual:
-        h2 = _norm(x, ln2["scale"], ln2.get("bias"), cfg.norm)
+        h2 = _norm(x, ln2["scale"], ln2.get("bias"), cfg.norm, cfg.norm_eps)
     else:
         x = x + attn_out
-        h2 = _norm(x, ln2["scale"], ln2.get("bias"), cfg.norm)
+        h2 = _norm(x, ln2["scale"], ln2.get("bias"), cfg.norm, cfg.norm_eps)
     if cfg.n_experts > 0:
         from dlrover_tpu.parallel.moe import moe_block
 
@@ -712,6 +734,17 @@ def _constrain_qkv(q, k, v, mesh):
     )
 
 
+def _gate_output(out, x, w_gate):
+    """``out * sigmoid(x W_g)``, a gate a channel of the attention's
+    output [B, S, H·D] from the layer's normed input ``x``: the sigmoid
+    and the multiply in float32, one rounding to the compute dtype."""
+    with jax.named_scope("attn.gate"):
+        gate = x @ w_gate.astype(x.dtype)
+        return (
+            out * jax.nn.sigmoid(gate.astype(jnp.float32))
+        ).astype(x.dtype)
+
+
 def _attention_block(
     x, layer, cfg: ModelConfig, mesh, positions, attn_fn, fp8=None,
     rope=None,
@@ -725,6 +758,8 @@ def _attention_block(
     q, k, v = _constrain_qkv(q, k, v, mesh)
     out = attn_fn(q, k, v)
     out = out.reshape(b, s, nh * hd)
+    if cfg.attn_gate:
+        out = _gate_output(out, x, layer["attn"]["wg"])
     if fp8 is not None:
         return _fp8_gemm(out, layer["attn"]["wo"].astype(x.dtype), fp8, "wo")
     return out @ layer["attn"]["wo"].astype(x.dtype)
@@ -1076,6 +1111,16 @@ def _mlp_block(x, layer, cfg: ModelConfig, mesh, fp8=None):
     return h @ mlp["w_down"].astype(x.dtype)
 
 
+# a kind's whole attention part, kernels included, under ``attn``
+_KIND_SCOPES = {"S": "attn.window", "F": "attn.full"}
+
+
+def _kind_scope(kind: str):
+    if not kind:
+        return contextlib.nullcontext()
+    return jax.named_scope(_KIND_SCOPES[kind])
+
+
 def _layer_body(
     x,
     layer,
@@ -1088,10 +1133,17 @@ def _layer_body(
     fp8=None,
     rope=None,
     return_selected: bool = False,
+    kind: str = "",
 ):
+    """``kind``: the layer's letter of ``cfg.layer_types`` ("" = the
+    model's one kind); ``attn_fn`` is then called with it."""
     ln1, ln2 = layer["ln1"], layer["ln2"]
     attn_aux = {}
-    with jax.named_scope("attn"):
+    if kind:
+        attn_fn = functools.partial(attn_fn, kind=kind)
+        if not cfg.kind_rope(kind):
+            rope = False  # a full layer: no positional term
+    with jax.named_scope("attn"), _kind_scope(kind):
         h = _norm_block(x, ln1, cfg)
         if cfg.selects_keys:
             attn, attn_aux = _selecting_attention_block(
@@ -1107,6 +1159,8 @@ def _layer_body(
             # save_attn would otherwise pin nothing and recompute O(S²)
             # attention
             attn = _tag_residual(attn, "attn_out", cfg)
+        if cfg.post_norm:
+            attn = _norm_block(attn, layer["ln1_post"], cfg)
     aux = {
         "moe_lb_loss": jnp.zeros([], jnp.float32),
         "moe_z_loss": jnp.zeros([], jnp.float32),
@@ -1135,6 +1189,8 @@ def _layer_body(
             )
         else:
             mlp_out = _mlp_block(h2, layer, cfg, mesh, fp8=fp8)
+        if cfg.post_norm:
+            mlp_out = _norm_block(mlp_out, layer["ln2_post"], cfg)
         x = x + attn + mlp_out if cfg.parallel_residual else x + mlp_out
         if mesh is not None:
             x = shd.constrain(x, mesh, "batch", "seq", None)
@@ -1272,10 +1328,12 @@ def _offload_names_policy(*names):
 
 
 def _remat_body(cfg: ModelConfig, mesh, attn_fn, tag_attn_out, fp8_layers,
-                return_selected: bool = False, keep_attn: bool = False):
-    """``_layer_body`` bound to the model and wrapped in the configured
-    rematerialisation policy: what every layer of the trunk, of either
-    kind, and the prediction module's block run through."""
+                return_selected: bool = False, keep_attn: bool = False,
+                kind: str = ""):
+    """``_layer_body`` bound to the model (and to one ``kind`` of
+    ``cfg.layer_types``) and wrapped in the configured rematerialisation
+    policy: what every layer of the trunk, dense or routed, and the
+    prediction module's block run through."""
     body = functools.partial(
         _layer_body,
         cfg=cfg,
@@ -1283,6 +1341,7 @@ def _remat_body(cfg: ModelConfig, mesh, attn_fn, tag_attn_out, fp8_layers,
         attn_fn=attn_fn,
         tag_attn_out=tag_attn_out,
         return_selected=return_selected,
+        kind=kind,
         # the "current" sentinel must be BAKED into the partial, not
         # passed at call time: jax.checkpoint (below) treats call-time
         # args as traceable values and a str is not a valid JAX type
@@ -1378,10 +1437,12 @@ KEEP_ATTN_SPAN = 2048
 
 
 def keeps_attention_output(cfg: ModelConfig, s: int, attn_impl: str = "auto",
-                           mesh=None) -> bool:
+                           mesh=None, kind: str = "") -> bool:
     """Whether ``remat: full`` keeps the attention kernel's output
     (``flash_out``, and ``flash_lse`` as numbers) at sequence length
-    ``s`` and does not run the kernel again in the recomputed forward:
+    ``s`` in a layer of ``kind`` (``cfg.layer_types``' letter; one step
+    may keep a full layer's and remake a window layer's) and does not
+    run the kernel again in the recomputed forward:
     the attention runs the Pallas kernels and a query's mean executed
     span (``ModelConfig.executed_span``) is at least ``KEEP_ATTN_SPAN``
     keys. Decided from the shape and not from the memory the chip has
@@ -1390,7 +1451,29 @@ def keeps_attention_output(cfg: ModelConfig, s: int, attn_impl: str = "auto",
         cfg.remat == "full"
         and _resolve_attn_impl(attn_impl, mesh) == "flash"
         and s % 128 == 0  # the kernels' tiling (``_fit_block``)
-        and cfg.executed_span(s) >= KEEP_ATTN_SPAN
+        and cfg.executed_span(s, kind) >= KEEP_ATTN_SPAN
+    )
+
+
+def attention_kinds(cfg: ModelConfig):
+    """The kinds of attention layer a model has: the letters of
+    ``cfg.layer_types``, or the one kind ""."""
+    return tuple(sorted(set(cfg.layer_types))) or ("",)
+
+
+def kept_attention_layers(cfg: ModelConfig, s: int, attn_impl: str = "auto",
+                          mesh=None) -> int:
+    """How many layers' attention output a step at sequence length
+    ``s`` keeps (``keeps_attention_output``, kind by kind; the
+    prediction module's layer among them)."""
+    if not cfg.layer_types:
+        return cfg.n_attention_layers * keeps_attention_output(
+            cfg, s, attn_impl, mesh
+        )
+    return sum(
+        cfg.layer_types.count(kind)
+        for kind in attention_kinds(cfg)
+        if keeps_attention_output(cfg, s, attn_impl, mesh, kind)
     )
 
 
@@ -1465,7 +1548,7 @@ def run_trunk(
     fp8_layers=None,
     dense_layers: Optional[Params] = None,
     return_selected: bool = False,
-    keep_attn: bool = False,
+    keep_attn: Tuple[str, ...] = (),
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Run the stacked transformer layers: remat policy, pp pipelining,
     MoE aux-loss accumulation. Shared by the decoder and the ViT trunk
@@ -1484,8 +1567,13 @@ def run_trunk(
     ``return_selected`` (a model that selects its keys): every layer's
     selection rides out in the aux, stacked bool [L, B, S, S].
 
-    ``keep_attn``: ``remat: full`` keeps the flash kernel's output
-    (``keeps_attention_output``; ``attn_fn`` was told, ``lse_rows``).
+    ``keep_attn``: the KINDS of attention layer (``attention_kinds``:
+    "" in a model of one kind, else ``layer_types``' letters) whose
+    flash output ``remat: full`` keeps (``keeps_attention_output``;
+    ``attn_fn`` was told, ``lse_rows``). A ``layer_types`` model's
+    ``attn_fn`` takes ``kind=``, each kind runs its own remat-wrapped
+    body, and a stack is scanned a period of kinds at a time
+    (``_run_periods``).
 
     A ``layer_pattern`` model's ``layers`` are its stacks kind by kind,
     visited in the pattern's order (``_run_pattern``).
@@ -1497,14 +1585,20 @@ def run_trunk(
             _train_only_guard(cfg, "the pipeline")
         x, aux = _run_pattern(
             x, layers, cfg.layer_pattern, positions, cfg, mesh, attn_fn,
-            rng, tag_attn_out, keep_attn=keep_attn,
+            rng, tag_attn_out, keep_attn="" in keep_attn,
         )
         zero = jnp.zeros([], jnp.float32)
         return x, {"moe_lb_loss": zero, "moe_z_loss": zero, **aux}
-    body = _remat_body(
-        cfg, mesh, attn_fn, tag_attn_out, fp8_layers, return_selected,
-        keep_attn,
-    )
+    # one remat-wrapped body a kind of attention layer; a model of one
+    # kind has the one, ``_run_periods`` picks by kind
+    bodies = {
+        kind: _remat_body(
+            cfg, mesh, attn_fn, tag_attn_out, fp8_layers, return_selected,
+            kind in keep_attn, kind,
+        )
+        for kind in attention_kinds(cfg)
+    }
+    body = bodies.get("")
 
     zero_aux = {
         "moe_lb_loss": jnp.zeros([], jnp.float32),
@@ -1579,13 +1673,25 @@ def run_trunk(
         if dense_layers is not None:
             # the prefix's aux is zeros: a dense layer routes nothing
             first = jax.tree.leaves(dense_layers)[0].shape[0]
-            x, _ = _run_stack(
-                body, x, dense_layers, positions, rng, rope, 0, first
+        if cfg.layer_types:
+            kinds = cfg.layer_types
+            if first:
+                x, _ = _run_periods(
+                    bodies, x, dense_layers, kinds[:first], positions, rng,
+                    rope, 0,
+                )
+            x, auxs = _run_periods(
+                bodies, x, layers, kinds[first:], positions, rng, rope, first
             )
-        x, auxs = _run_stack(
-            body, x, layers, positions, rng, rope, first, n_layers,
-            fp8_layers=fp8_layers,
-        )
+        else:
+            if first:
+                x, _ = _run_stack(
+                    body, x, dense_layers, positions, rng, rope, 0, first
+                )
+            x, auxs = _run_stack(
+                body, x, layers, positions, rng, rope, first, n_layers,
+                fp8_layers=fp8_layers,
+            )
         # the expert ids and the selected keys are per layer, not a sum:
         # stacked [L, B, S, k] and [L, B, S, S]
         per_layer = {
@@ -1646,6 +1752,44 @@ def _run_stack(body, x, layers, positions, rng, rope, first, n_layers,
         return out, aux
 
     return jax.lax.scan(scan_fn, x, (layers, index))
+
+
+def _period(kinds: str) -> int:
+    """Length of the shortest prefix that ``kinds`` repeats whole."""
+    n = len(kinds)
+    return next(
+        p for p in range(1, n + 1)
+        if n % p == 0 and kinds[:p] * (n // p) == kinds
+    )
+
+
+def _run_periods(bodies, x, layers, kinds: str, positions, rng, rope, first):
+    """One stack of layers that differ in attention KIND and in nothing
+    else (``cfg.layer_types``; ``kinds`` this stack's letters,
+    ``bodies`` kind -> its remat-wrapped body): the parameters stay one
+    stack, viewed as [periods, period, ...], and the scan runs a whole
+    period of ``kinds`` a step, its layers unrolled. Returns what
+    ``_run_stack`` does."""
+    n, p = len(kinds), _period(kinds)
+    index = jnp.arange(first, first + n).reshape(n // p, p)
+    grouped = jax.tree.map(
+        lambda t: t.reshape((n // p, p) + t.shape[1:]), layers
+    )
+
+    def scan_fn(carry, inp):
+        group, idx = inp
+        auxs = []
+        for j, kind in enumerate(kinds[:p]):
+            layer = jax.tree.map(lambda t: t[j], group)
+            r = jax.random.fold_in(rng, idx[j]) if rng is not None else None
+            carry, aux = bodies[kind](
+                carry, layer, positions, rng=r, rope=rope
+            )
+            auxs.append(aux)
+        return carry, jax.tree.map(lambda *ls: jnp.stack(ls), *auxs)
+
+    x, auxs = jax.lax.scan(scan_fn, x, (grouped, index))
+    return x, jax.tree.map(lambda a: a.reshape((n,) + a.shape[2:]), auxs)
 
 
 def init_fp8_states(cfg: ModelConfig):
@@ -1729,6 +1873,9 @@ def forward(
             x = x + jnp.take(
                 params["pos_embed"]["table"], positions, axis=0
             ).astype(dt)
+        if cfg.scale_embedding:
+            # in float32: sqrt(d) has no exact bf16
+            x = (x.astype(jnp.float32) * cfg.d_model ** 0.5).astype(dt)
         if mesh is not None:
             x = shd.constrain(x, mesh, "batch", "seq", None)
 
@@ -1757,11 +1904,21 @@ def forward(
             "jnp.zeros([batch], int32) for fully-causal behavior"
         )
 
-    keep_attn = keeps_attention_output(cfg, s, attn_impl, mesh)
-    # whether the layers' remat policy lists the kernel's statistics
-    lse_rows = "flash_lse" in _kept_names(cfg, keep_attn)[0]
+    # the kinds of layer whose remat policy keeps the kernel's output,
+    # and those whose policy lists its statistics
+    kinds = attention_kinds(cfg)
+    keep_attn = tuple(
+        kind for kind in kinds
+        if keeps_attention_output(cfg, s, attn_impl, mesh, kind)
+    )
+    lse_kinds = {
+        kind for kind in kinds
+        if "flash_lse" in _kept_names(cfg, kind in keep_attn)[0]
+    }
 
-    def attn_fn(q, k, v, selected=None):
+    def attn_fn(q, k, v, selected=None, kind=""):
+        lse_rows = kind in lse_kinds
+        window = cfg.kind_window(kind)
         if selected is not None:
             # (out, lse [B, H, S] detached, whether the Pallas kernels
             # are this model's attention) over each query's selection
@@ -1795,7 +1952,7 @@ def forward(
                 block_q=cfg.attn_block_q,
                 block_k=cfg.attn_block_k,
                 prefix_len=prefix_len,
-                window=cfg.attn_window,
+                window=window,
             )
         if attn_impl == "ulysses":
             from dlrover_tpu.ops.pallas_attention import flash_attention
@@ -1818,12 +1975,12 @@ def forward(
                     lse_rows=lse_rows,
                 ),
                 prefix_len=prefix_len,
-                window=cfg.attn_window,
+                window=window,
             )
         if attn_impl == "reference":
             return mha_reference(
                 q, k, v, causal=cfg.causal, prefix_len=prefix_len,
-                window=cfg.attn_window,
+                window=window,
             )
         from dlrover_tpu.ops.pallas_attention import flash_attention
 
@@ -1835,7 +1992,7 @@ def forward(
             block_q=cfg.attn_block_q,
             block_k=cfg.attn_block_k,
             prefix_len=prefix_len,
-            window=cfg.attn_window,
+            window=window,
             head_pack=cfg.attn_head_pack,
             lse_rows=lse_rows,
         )
@@ -1860,7 +2017,7 @@ def forward(
         # the module reads the trunk's output BEFORE the final norm
         aux = _mtp_module(
             params, x, tokens, positions, cfg, mesh, attn_fn, rng,
-            attn_impl != "flash", aux, keep_attn,
+            attn_impl != "flash", aux, "" in keep_attn,
         )
 
     with jax.named_scope("head_loss"):
@@ -2229,7 +2386,7 @@ def prefill(
     def layer_fn(carry, layer):
         x = carry
         ln1 = layer["ln1"]
-        h = _norm(x, ln1["scale"], ln1.get("bias"), cfg.norm)
+        h = _norm(x, ln1["scale"], ln1.get("bias"), cfg.norm, cfg.norm_eps)
         q, k, v = _project_qkv(
             h, layer, cfg, positions, mup_full_scale=True, rope=rope
         )
@@ -2250,7 +2407,7 @@ def prefill(
 
     x, (new_k, new_v) = jax.lax.scan(layer_fn, x, params["layers"])
     fn = params["final_norm"]
-    x = _norm(x, fn["scale"], fn.get("bias"), cfg.norm)
+    x = _norm(x, fn["scale"], fn.get("bias"), cfg.norm, cfg.norm_eps)
     if cfg.tie_embeddings:
         w_out = params["embed"]["tokens"].T
     else:
@@ -2331,7 +2488,7 @@ def decode_step(
         x = carry
         layer, ck, cv = inp
         ln1 = layer["ln1"]
-        h = _norm(x, ln1["scale"], ln1.get("bias"), cfg.norm)
+        h = _norm(x, ln1["scale"], ln1.get("bias"), cfg.norm, cfg.norm_eps)
         q, k, v = _project_qkv(
             h, layer, cfg, positions, mup_full_scale=True, rope=rope
         )
@@ -2357,7 +2514,7 @@ def decode_step(
         layer_fn, x, (params["layers"], cache["k"], cache["v"])
     )
     fn = params["final_norm"]
-    x = _norm(x, fn["scale"], fn.get("bias"), cfg.norm)
+    x = _norm(x, fn["scale"], fn.get("bias"), cfg.norm, cfg.norm_eps)
     if cfg.tie_embeddings:
         w_out = params["embed"]["tokens"].T
     else:
@@ -2503,7 +2660,7 @@ def prefill_chunk(
         x = carry
         layer, ck, cv = inp
         ln1 = layer["ln1"]
-        h = _norm(x, ln1["scale"], ln1.get("bias"), cfg.norm)
+        h = _norm(x, ln1["scale"], ln1.get("bias"), cfg.norm, cfg.norm_eps)
         q, k, v = _project_qkv(
             h, layer, cfg, positions, mup_full_scale=True, rope=rope
         )
@@ -2523,7 +2680,7 @@ def prefill_chunk(
         layer_fn, x, (params["layers"], cache["k"], cache["v"])
     )
     fn = params["final_norm"]
-    x = _norm(x, fn["scale"], fn.get("bias"), cfg.norm)
+    x = _norm(x, fn["scale"], fn.get("bias"), cfg.norm, cfg.norm_eps)
     if cfg.tie_embeddings:
         w_out = params["embed"]["tokens"].T
     else:
@@ -2615,7 +2772,7 @@ def decode_step_paged(
         x = carry
         layer, pools_l = inp
         ln1 = layer["ln1"]
-        h = _norm(x, ln1["scale"], ln1.get("bias"), cfg.norm)
+        h = _norm(x, ln1["scale"], ln1.get("bias"), cfg.norm, cfg.norm_eps)
         q, k, v = _project_qkv(
             h, layer, cfg, positions, mup_full_scale=True, rope=rope
         )
@@ -2634,7 +2791,7 @@ def decode_step_paged(
 
     x, new_pools = jax.lax.scan(layer_fn, x, (params["layers"], pools))
     fn = params["final_norm"]
-    x = _norm(x, fn["scale"], fn.get("bias"), cfg.norm)
+    x = _norm(x, fn["scale"], fn.get("bias"), cfg.norm, cfg.norm_eps)
     if cfg.tie_embeddings:
         w_out = params["embed"]["tokens"].T
     else:
@@ -2691,7 +2848,7 @@ def prefill_chunk_paged(
         x = carry
         layer, pools_l = inp
         ln1 = layer["ln1"]
-        h = _norm(x, ln1["scale"], ln1.get("bias"), cfg.norm)
+        h = _norm(x, ln1["scale"], ln1.get("bias"), cfg.norm, cfg.norm_eps)
         q, k, v = _project_qkv(
             h, layer, cfg, positions, mup_full_scale=True, rope=rope
         )
@@ -2709,7 +2866,7 @@ def prefill_chunk_paged(
 
     x, new_pools = jax.lax.scan(layer_fn, x, (params["layers"], pools))
     fn = params["final_norm"]
-    x = _norm(x, fn["scale"], fn.get("bias"), cfg.norm)
+    x = _norm(x, fn["scale"], fn.get("bias"), cfg.norm, cfg.norm_eps)
     if cfg.tie_embeddings:
         w_out = params["embed"]["tokens"].T
     else:
@@ -2791,7 +2948,7 @@ def verify_chunk(
         x = carry
         layer, ck, cv = inp
         ln1 = layer["ln1"]
-        h = _norm(x, ln1["scale"], ln1.get("bias"), cfg.norm)
+        h = _norm(x, ln1["scale"], ln1.get("bias"), cfg.norm, cfg.norm_eps)
         q, k, v = _project_qkv(
             h, layer, cfg, positions, mup_full_scale=True, rope=rope
         )
@@ -2819,7 +2976,7 @@ def verify_chunk(
         layer_fn, x, (params["layers"], cache["k"], cache["v"])
     )
     fn = params["final_norm"]
-    x = _norm(x, fn["scale"], fn.get("bias"), cfg.norm)
+    x = _norm(x, fn["scale"], fn.get("bias"), cfg.norm, cfg.norm_eps)
     if cfg.tie_embeddings:
         w_out = params["embed"]["tokens"].T
     else:
@@ -2899,7 +3056,7 @@ def verify_chunk_paged(
         x = carry
         layer, pools_l = inp
         ln1 = layer["ln1"]
-        h = _norm(x, ln1["scale"], ln1.get("bias"), cfg.norm)
+        h = _norm(x, ln1["scale"], ln1.get("bias"), cfg.norm, cfg.norm_eps)
         q, k, v = _project_qkv(
             h, layer, cfg, positions, mup_full_scale=True, rope=rope
         )
@@ -2918,7 +3075,7 @@ def verify_chunk_paged(
         layer_fn, x, (params["layers"], pools)
     )
     fn = params["final_norm"]
-    x = _norm(x, fn["scale"], fn.get("bias"), cfg.norm)
+    x = _norm(x, fn["scale"], fn.get("bias"), cfg.norm, cfg.norm_eps)
     if cfg.tie_embeddings:
         w_out = params["embed"]["tokens"].T
     else:

@@ -1200,9 +1200,14 @@ class TrainStepBuilder:
         # which path the step took. Trace time, a value: a retrace sets
         # the same number again
         seq = batch["tokens"].shape[-1]
-        set_counter("attn.output_kept", int(
-            decoder.keeps_attention_output(cfg, seq, self.attn_impl, self.mesh)
+        # how many layers' attention output the step keeps, and how many
+        # layers of each kind it has
+        set_counter("attn.output_kept", decoder.kept_attention_layers(
+            cfg, seq, self.attn_impl, self.mesh
         ))
+        if cfg.layer_types:
+            set_counter("attn.window_layers", cfg.layer_types.count("S"))
+            set_counter("attn.full_layers", cfg.layer_types.count("F"))
         if cfg.selects_keys:
             set_counter("attn.align_passes", decoder.alignment_passes(cfg))
             set_counter("attn.align_in_kernel", int(

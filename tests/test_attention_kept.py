@@ -170,6 +170,10 @@ CELL_SPANS = {
     # the causal span, not ``index_topk``: the kernels run every block
     "keyevl2-ep8-train-b1s8192": (4096.5, True),
     "nemotron3super-ep64-train-b1s8192": (4096.5, True),
+    # an attention kind per layer: the line falls INSIDE the step
+    "trinitymini-ep8-train-b1s16384": {
+        "F": (8192.5, True), "S": (1920.0625, False),
+    },
 }
 
 
@@ -185,17 +189,25 @@ def test_the_line_falls_between_the_cells():
     for name, program, seq in _cells():
         cfg = get_config(program["model"], **program["overrides"])
         assert cfg.remat == "full", name
-        seen[name] = (
-            cfg.executed_span(seq),
-            decoder.keeps_attention_output(cfg, seq, "flash"),
-        )
-        assert not decoder.keeps_attention_output(cfg, seq, "reference")
-        assert not decoder.keeps_attention_output(cfg, seq)  # auto: CPU
-        assert not decoder.keeps_attention_output(cfg, seq + 64, "flash")
+        keeps = decoder.keeps_attention_output
+        kinds = decoder.attention_kinds(cfg)
+        by_kind = {
+            kind: (
+                cfg.executed_span(seq, kind),
+                keeps(cfg, seq, "flash", kind=kind),
+            )
+            for kind in kinds
+        }
+        seen[name] = by_kind[""] if kinds == ("",) else by_kind
         other = dataclasses.replace(cfg, remat="save_attn")
-        assert not decoder.keeps_attention_output(other, seq, "flash")
+        for kind in kinds:
+            assert not keeps(cfg, seq, "reference", kind=kind)
+            assert not keeps(cfg, seq, kind=kind)  # auto: CPU
+            assert not keeps(cfg, seq + 64, "flash", kind=kind)
+            assert not keeps(other, seq, "flash", kind=kind)
     assert seen == CELL_SPANS
-    assert sum(kept for _, kept in seen.values()) == 5
+    one_kind = [v for v in seen.values() if isinstance(v, tuple)]
+    assert sum(kept for _, kept in one_kind) == 5
 
 
 @pytest.mark.parametrize("remat,keep,lse_named", [

@@ -50,6 +50,7 @@ BENCHMARK_CONFIGS = {
     "glm-4.7-flash-ep8-1chip": 8192,
     "keye-vl-2.0-ep8-1chip": 8192,
     "nemotron-3-super-ep64-1chip": 8192,
+    "trinity-mini-ep8-1chip": 16384,
 }
 
 
@@ -63,7 +64,8 @@ def test_flops_per_token_is_the_benchmarks_count(name):
     states (``flops.resolve``): latent attention's projections, the dense
     prefix, the held and shared experts, the prediction module, a
     selection of keys and its score-only indexer, layers of one part
-    each with a state-space scan's recurrence among the multiplied."""
+    each with a state-space scan's recurrence among the multiplied, an
+    attention kind per layer with each kind's own span."""
     from benchmarks.lib.flops import resolve
 
     configs = pathlib.Path(__file__).parent.parent / "benchmarks" / "configs"
@@ -96,8 +98,11 @@ def test_flops_per_token_is_the_benchmarks_count(name):
     attention_layers = cfg.n_layer + cfg.n_mtp_module
     if cfg.layer_pattern:  # one part a layer: the attention layers alone
         attention_layers = (cfg.layer_pattern + cfg.mtp_pattern).count("*")
-    both_ways = dataclasses.replace(cfg, causal=False, attn_window=0)
-    causal = dataclasses.replace(cfg, attn_window=0)
+    # (``layer_types`` names causal kinds: one kind a model for this)
+    both_ways = dataclasses.replace(
+        cfg, causal=False, attn_window=0, layer_types=""
+    )
+    causal = dataclasses.replace(cfg, attn_window=0, layer_types="")
     assert both_ways.flops_per_token(1024) - causal.flops_per_token(
         1024
     ) == pytest.approx(
@@ -508,9 +513,9 @@ def test_layernorm_model_forward_matches_two_pass_family():
 
     orig = decoder._norm
 
-    def two_pass_norm(x, scale, bias, kind):
+    def two_pass_norm(x, scale, bias, kind, eps=None):
         if kind != "layernorm":
-            return orig(x, scale, bias, kind)
+            return orig(x, scale, bias, kind, eps)
         x32 = x.astype(jnp.float32)
         mean = jnp.mean(x32, -1, keepdims=True)
         var = jnp.var(x32, -1, keepdims=True)
