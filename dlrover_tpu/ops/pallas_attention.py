@@ -8,8 +8,28 @@ vendored library binding).
 Forward: classic FlashAttention-2 online-softmax over k/v blocks. Grid is
 (batch*kv_head_groups, q_blocks, k_blocks) with the k dimension marked
 "arbitrary" so the output block is revisited and carried in VMEM scratch
-(m/l running stats + f32 accumulator). Causal blocks above the diagonal are
-skipped entirely.
+(m/l running stats + f32 accumulator).
+
+Block skipping: a (q, k) block pair above the causal diagonal, or wholly
+older than a sliding window reaches, runs nothing (``_block_runs``) and
+fetches nothing (its index map re-addresses a live neighbour, which is
+not refetched). On the square grid such a pair is still a grid step.
+Under a LIVE window (causal, a static window shorter than the sequence,
+no prefix, no traced offsets) the inner grid axis is therefore the
+**band** (``_inner_grid``): as many steps as the widest query block's
+window touches key blocks — 3 at a window of 2,048 keys and tiles of
+1,024 on a sequence of 16 blocks, 5 at tiles of 512 of 32 —, step s of
+query block i standing for key block ``first(i) + s``
+(``_band_k_block``); the dk/dv grid walks the query blocks of a key
+block the same way (``_band_q_block``). The kernels take their
+``k_start`` / ``q_start`` from the block a step stands for, initialise at
+the axis' first step and finish at its last; a block's steps beyond its
+own run (the sequence's first blocks have shorter ones) stand for
+blocks past the diagonal or the sequence's end and run nothing, so no
+accumulator sees a block twice. Without a live window the extent is
+every block and the first block 0: the square, one path whose extent
+follows the window. Ring attention (traced offsets), a prefix and the
+``_sel`` kernels keep the square.
 
 Backward: FlashAttention-2-style pallas kernels via custom_vjp — a dq pass
 (k-blocks innermost, dq carried in VMEM scratch) and a dk/dv pass (q-blocks
@@ -17,8 +37,12 @@ innermost), both recomputing p from the saved lse; tiles capped by head
 width (BWD_BLOCK=512 for head_dim 64, BWD_BLOCK_WIDE=1024 for head_dim
 128, BWD_BLOCK_256 = 1024 × 512 for head_dim 256, where 1024 × 1024
 does not fit VMEM — all measured on v5e; the backward holds ~4 [bq,bk]
-f32 transients at whichever cap applies). A jnp-level chunked recompute
-remains as the off-TPU / untileable-shape fallback.
+f32 transients at whichever cap applies) and, on the banded grid, by the
+window (``_bwd_tiles``: at most a quarter of it — a tile of b rows
+executes about W + b keys a query for W useful; the sweep that set the
+fraction, and why the forward keeps the caller's tile, is in the comment
+above ``WINDOW_TILES``). A jnp-level chunked recompute remains as the
+off-TPU / untileable-shape fallback.
 
 The per-row statistics keep one format from the kernel that makes them
 to the kernels that read them — f32 tiles ``[B·slabs, S, 8]``, head p of
@@ -114,7 +138,7 @@ except ImportError:  # pragma: no cover
     pltpu = None
 
 from dlrover_tpu.common import device
-from dlrover_tpu.observability.tracing import set_counter
+from dlrover_tpu.observability.tracing import counters, set_counter
 
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
@@ -147,12 +171,82 @@ BWD_BLOCK_WIDE = 1024  # measured best for head_dim 128 (v5e)
 BWD_BLOCK_256 = (1024, 512)
 
 
+# Under a live window (``_inner_grid``: the banded grid) a tile of b rows
+# executes about W + b keys a query for W useful on a band of W / b + 1
+# steps, so a smaller tile saves pairs until the cost of a step takes it
+# back. Swept on a v5e with the band (PR 48; ms a call, the layout
+# copies around the kernels included; q rows x k rows):
+#   1 x 16,384, GQA 32 / 4 x 128, W 2,048 (Trinity-Mini's window layers;
+#   the square grid at 1024 x 1024: 8.27 / 11.85 / 12.07)
+#     tile         flash_fwd  flash_bwd_dq  flash_bwd_dkv
+#     1024 x 1024       7.51          9.72           9.81
+#     1024 x 512       13.39          9.41          10.08
+#     512 x 1024        8.40         10.03           9.72
+#     512 x 512        12.08          8.92           9.10
+#     256 x 512        13.56         10.14          12.15
+#     k rows 256   21.4-24.7     11.4-13.3      13.5-16.4
+#   1 x 8,192, GQA 32 / 8 x 128, W 4,096 (Mistral's; square 4.89 / 6.69 /
+#   6.99): 1024 x 1024 4.79 / 6.35 / 6.84, 512 x 512 8.41 / 6.26 / 6.85,
+#   1024 x 512 8.72 / 6.16 / 7.21, 512 x 1024 5.43 / 6.63 / 6.77
+# The forward has two matmuls a step to the backward's five and pays a
+# step's fixed cost (the carried statistics, the accumulator's rescale)
+# twice as often at half the k rows: it keeps the caller's tile. The
+# backward kernels gain 7-8% at a quarter of the window and lose at an
+# eighth, so their tile is at most window / WINDOW_TILES.
+WINDOW_TILES = 4
+
+
+def _gate_is_static(causal, prefix, offsets) -> bool:
+    """Whether the run gate's dead blocks are known while tracing, so
+    that index maps may clamp them away and a live window's grid may be
+    its band: not with a prefix, which can make above-diagonal blocks
+    live, nor with traced global offsets (ring attention), where the
+    diagonal's grid position is unknown."""
+    return bool(causal) and prefix is None and offsets is None
+
+
+def _live_window(window: int, sk: int) -> bool:
+    """A window that hides keys of a sequence of ``sk`` from some query."""
+    return bool(window) and 0 < window < sk
+
+
 def _bwd_caps(head_dim: int):
     """Largest backward tile (q rows, k rows) for a head width."""
     if head_dim >= 256:
         return BWD_BLOCK_256
     cap = BWD_BLOCK_WIDE if head_dim >= 128 else BWD_BLOCK
     return cap, cap
+
+
+def _bwd_tiles(sq, sk, head_dim, block_q, block_k, window=0):
+    """The backward kernels' tile (q rows, k rows; None where none
+    fits): the forward's, cut to the head width's cap and, where
+    ``window`` is live on the banded grid, to a quarter of it (the sweep
+    above) — the largest 128-multiple that divides the sequence."""
+    cap_q, cap_k = _bwd_caps(head_dim)
+    if _live_window(window, sk):
+        cap = max(128, window // WINDOW_TILES)
+        cap_q, cap_k = min(cap_q, cap), min(cap_k, cap)
+    return (
+        _fit_block(sq, min(block_q, cap_q)),
+        _fit_block(sk, min(block_k, cap_k)),
+    )
+
+
+def _lower(a, b):
+    """min(a, b): a Python int of Python ints (the band's extent is
+    counted at trace time, where a traced minimum has no value) and a
+    traced minimum of grid indices."""
+    if isinstance(a, int) and isinstance(b, int):
+        return min(a, b)
+    return jnp.minimum(a, b)
+
+
+def _upper(a, b):
+    """max(a, b), as ``_lower``."""
+    if isinstance(a, int) and isinstance(b, int):
+        return max(a, b)
+    return jnp.maximum(a, b)
 
 
 def _last_visible_k_block(i, block_q, block_k):
@@ -166,29 +260,56 @@ def _last_visible_k_block(i, block_q, block_k):
 def _first_window_k_block(i, block_q, block_k, window):
     """Lowest k-block index a sliding window admits for q block i:
     its oldest row sees back to q_start − window + 1."""
-    return jnp.maximum(0, (i * block_q - window + 1) // block_k)
+    return _upper(0, (i * block_q - window + 1) // block_k)
 
 
 def _first_visible_q_block(j, n_q_blocks, block_q, block_k):
     """Lowest q-block index the causal run gate admits for k block j,
     clamped into range (causal with sk > sq can otherwise exceed it)."""
-    return jnp.minimum((j * block_k) // block_q, n_q_blocks - 1)
+    return _lower((j * block_k) // block_q, n_q_blocks - 1)
 
 
 def _last_window_q_block(j, n_q_blocks, block_q, block_k, window):
     """Highest q-block index a sliding window admits for k block j: its
     newest key is visible up to k_end + window − 1."""
-    return jnp.minimum(
+    return _lower(
         ((j + 1) * block_k - 1 + window - 1) // block_q, n_q_blocks - 1
     )
 
 
+def _band_k_block(i, step, block_q, block_k, window, band):
+    """The inner grid axis of the forward and dq kernels: (the k block
+    that step ``step`` of q block i stands for, whether the sequence has
+    such a block — None on the square grid, where the step IS the
+    block). ``band``: 0 on the square grid, else the sequence's number
+    of k blocks; the band then starts at the first block the window
+    admits, and what lies past the diagonal the run gate drops like any
+    dead block."""
+    if not band:
+        return step, None
+    j = _first_window_k_block(i, block_q, block_k, window) + step
+    return j, j < band
+
+
+def _band_q_block(j, step, block_q, block_k, band):
+    """The same for the dk/dv kernels (``band``: the sequence's number
+    of q blocks): the band starts at the first q block the diagonal
+    admits for k block j, and the run gate drops what the window does
+    not reach."""
+    if not band:
+        return step, None
+    i = (j * block_k) // block_q + step
+    return i, i < band
+
+
 def _block_runs(causal, has_prefix, pref, q_start, k_start, block_q,
-                block_k=None, window=0):
+                block_k=None, window=0, in_band=None):
     """Run-gate shared by all kernels: a (q,k) block pair participates
     unless it lies entirely above the causal diagonal or (with a
     sliding window) entirely below it — and with a prefix-LM prefix,
-    k blocks inside the prefix always participate."""
+    k blocks inside the prefix always participate. ``in_band`` (the
+    banded grid: ``_band_k_block``): false for a step that stands for
+    no block of the sequence, which runs nothing."""
     run = (not causal) or (k_start <= q_start + block_q - 1)
     if causal and window:
         # the OLDEST q row (q_start) sees back to q_start − window + 1;
@@ -198,6 +319,8 @@ def _block_runs(causal, has_prefix, pref, q_start, k_start, block_q,
         )
     if causal and has_prefix:
         run = jnp.logical_or(run, k_start < pref)
+    if in_band is not None:
+        run = jnp.logical_and(run, in_band)
     return run
 
 
@@ -321,16 +444,18 @@ def _fwd_kernel(
     has_offsets: bool = False,
     n_head: int = 1,
     window: int = 0,
+    band: int = 0,  # _band_k_block / _band_q_block
 ):
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    step = pl.program_id(2)
+    n_steps = pl.num_programs(2)
+    ki, in_band = _band_k_block(qi, step, block_q, block_k, window, band)
     # grid dim 0 is batch·heads; the scalar prefix is per-batch
     pref = (
         prefix_ref[pl.program_id(0) // n_head, 0] if has_prefix else None
     )
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         m_scratch[:] = jnp.full_like(m_scratch, NEG_INF)
         l_scratch[:] = jnp.zeros_like(l_scratch)
@@ -342,7 +467,7 @@ def _fwd_kernel(
     k_start = ki * block_k + (offs_ref[0, 1] if has_offsets else 0)
 
     @pl.when(_block_runs(causal, has_prefix, pref, q_start, k_start,
-                         block_q, block_k, window))
+                         block_q, block_k, window, in_band))
     def _body():
         s = _masked_scores(
             q_ref[0], k_ref[0], scale, q_start, k_start,
@@ -356,7 +481,7 @@ def _fwd_kernel(
         m_scratch[:] = jnp.broadcast_to(m_new, m_scratch.shape)
         l_scratch[:] = jnp.broadcast_to(l_new, l_scratch.shape)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == n_steps - 1)
     def _finish():
         l = l_scratch[:, :1]
         l = jnp.where(l == 0.0, 1.0, l)
@@ -447,6 +572,7 @@ def _fwd_kernel_packed(
     heads: int = 2,  # real heads H (the last slab may hold fewer)
     window: int = 0,
     pack: int = 2,
+    band: int = 0,
 ):
     """Head-packed forward on grid (batch, slab, q block, k block): the
     ``pack`` heads of a slab share one program and one mask. Head p's
@@ -458,12 +584,13 @@ def _fwd_kernel_packed(
     the unpacked kernel."""
     slab = pl.program_id(1)
     qi = pl.program_id(2)
-    ki = pl.program_id(3)
-    nk = pl.num_programs(3)
+    step = pl.program_id(3)
+    n_steps = pl.num_programs(3)
+    ki, in_band = _band_k_block(qi, step, block_q, block_k, window, band)
     d = LANES // pack
     pref = prefix_ref[pl.program_id(0), 0] if has_prefix else None
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         m_scratch[:] = jnp.full_like(m_scratch, NEG_INF)
         l_scratch[:] = jnp.zeros_like(l_scratch)
@@ -473,7 +600,7 @@ def _fwd_kernel_packed(
     k_start = ki * block_k + (offs_ref[0, 1] if has_offsets else 0)
 
     @pl.when(_block_runs(causal, has_prefix, pref, q_start, k_start,
-                         block_q, block_k, window))
+                         block_q, block_k, window, in_band))
     def _body():
         allowed = _allowed_mask(
             q_start, k_start, block_q, block_k, causal, has_prefix,
@@ -500,7 +627,7 @@ def _fwd_kernel_packed(
             pv = pv_p if pv is None else pv + pv_p
         acc_scratch[:] = acc_scratch[:] * _spread_heads(alphas, d) + pv
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == n_steps - 1)
     def _finish():
         ls, lses = [], []
         for p in range(pack):
@@ -547,6 +674,7 @@ def _bwd_dq_kernel(
     has_offsets: bool = False,
     n_head: int = 1,
     window: int = 0,
+    band: int = 0,  # _band_k_block / _band_q_block
 ):
     """dq = Σ_k ds @ K with ds = p·(dp − delta)·scale, p recomputed from
     the saved lse — FlashAttention-2 backward, k-blocks innermost so dq
@@ -555,13 +683,14 @@ def _bwd_dq_kernel(
     the dO block the pass holds anyway, and leaves as a second output in
     lse's tile format."""
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    step = pl.program_id(2)
+    n_steps = pl.num_programs(2)
+    ki, in_band = _band_k_block(qi, step, block_q, block_k, window, band)
     pref = (
         prefix_ref[pl.program_id(0) // n_head, 0] if has_prefix else None
     )
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         acc_scratch[:] = jnp.zeros_like(acc_scratch)
         (delta,) = _delta_cols(do_ref[0], out_ref[0])
@@ -574,7 +703,7 @@ def _bwd_dq_kernel(
     k_start = ki * block_k + (offs_ref[0, 1] if has_offsets else 0)
 
     @pl.when(_block_runs(causal, has_prefix, pref, q_start, k_start,
-                         block_q, block_k, window))
+                         block_q, block_k, window, in_band))
     def _body():
         k = k_ref[0]
         s = _masked_scores(
@@ -591,7 +720,7 @@ def _bwd_dq_kernel(
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == n_steps - 1)
     def _finish():
         dq_ref[0] = acc_scratch[:].astype(dq_ref.dtype)
 
@@ -612,17 +741,19 @@ def _bwd_dkv_kernel(
     has_offsets: bool = False,
     n_head: int = 1,
     window: int = 0,
+    band: int = 0,  # _band_k_block / _band_q_block
 ):
     """dk/dv accumulated per k-block with q-blocks innermost:
     dv = Σ_q pᵀ @ dO, dk = Σ_q dsᵀ @ Q."""
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
-    nq = pl.num_programs(2)
+    step = pl.program_id(2)
+    n_steps = pl.num_programs(2)
+    qi, in_band = _band_q_block(ki, step, block_q, block_k, band)
     pref = (
         prefix_ref[pl.program_id(0) // n_head, 0] if has_prefix else None
     )
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scratch[:] = jnp.zeros_like(dk_scratch)
         dv_scratch[:] = jnp.zeros_like(dv_scratch)
@@ -631,7 +762,7 @@ def _bwd_dkv_kernel(
     k_start = ki * block_k + (offs_ref[0, 1] if has_offsets else 0)
 
     @pl.when(_block_runs(causal, has_prefix, pref, q_start, k_start,
-                         block_q, block_k, window))
+                         block_q, block_k, window, in_band))
     def _body():
         q = q_ref[0]
         do = do_ref[0]
@@ -653,7 +784,7 @@ def _bwd_dkv_kernel(
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step == n_steps - 1)
     def _finish():
         dk_ref[0] = dk_scratch[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scratch[:].astype(dv_ref.dtype)
@@ -677,6 +808,7 @@ def _bwd_dq_kernel_packed(
     heads: int = 2,
     window: int = 0,
     pack: int = 2,
+    band: int = 0,
 ):
     """Head-packed dq pass on grid (batch, slab, q block, k block): the
     recomputed-p backward per head under ONE shared mask, heads kept
@@ -687,12 +819,13 @@ def _bwd_dq_kernel_packed(
     _bwd_dq_kernel); lanes past H·D are in no head's sum."""
     slab = pl.program_id(1)
     qi = pl.program_id(2)
-    ki = pl.program_id(3)
-    nk = pl.num_programs(3)
+    step = pl.program_id(3)
+    n_steps = pl.num_programs(3)
+    ki, in_band = _band_k_block(qi, step, block_q, block_k, window, band)
     d = LANES // pack
     pref = prefix_ref[pl.program_id(0), 0] if has_prefix else None
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         acc_scratch[:] = jnp.zeros_like(acc_scratch)
         head_bits, _ = _slab_lanes(slab, heads, d, pack)
@@ -708,7 +841,7 @@ def _bwd_dq_kernel_packed(
     k_start = ki * block_k + (offs_ref[0, 1] if has_offsets else 0)
 
     @pl.when(_block_runs(causal, has_prefix, pref, q_start, k_start,
-                         block_q, block_k, window))
+                         block_q, block_k, window, in_band))
     def _body():
         allowed = _allowed_mask(
             q_start, k_start, block_q, block_k, causal, has_prefix,
@@ -737,7 +870,7 @@ def _bwd_dq_kernel_packed(
             dq = dq_p if dq is None else dq + dq_p
         acc_scratch[:] = acc_scratch[:] + dq
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == n_steps - 1)
     def _finish():
         dq_ref[0] = acc_scratch[:].astype(dq_ref.dtype)
 
@@ -759,6 +892,7 @@ def _bwd_dkv_kernel_packed(
     heads: int = 2,
     window: int = 0,
     pack: int = 2,
+    band: int = 0,
 ):
     """Head-packed dk/dv pass on grid (batch, slab, k block, q block),
     q-blocks innermost: here q and dO are the operands zeroed outside
@@ -766,12 +900,13 @@ def _bwd_dkv_kernel_packed(
     p's lanes of the dv and dk tiles."""
     slab = pl.program_id(1)
     ki = pl.program_id(2)
-    qi = pl.program_id(3)
-    nq = pl.num_programs(3)
+    step = pl.program_id(3)
+    n_steps = pl.num_programs(3)
+    qi, in_band = _band_q_block(ki, step, block_q, block_k, band)
     d = LANES // pack
     pref = prefix_ref[pl.program_id(0), 0] if has_prefix else None
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scratch[:] = jnp.zeros_like(dk_scratch)
         dv_scratch[:] = jnp.zeros_like(dv_scratch)
@@ -780,7 +915,7 @@ def _bwd_dkv_kernel_packed(
     k_start = ki * block_k + (offs_ref[0, 1] if has_offsets else 0)
 
     @pl.when(_block_runs(causal, has_prefix, pref, q_start, k_start,
-                         block_q, block_k, window))
+                         block_q, block_k, window, in_band))
     def _body():
         allowed = _allowed_mask(
             q_start, k_start, block_q, block_k, causal, has_prefix,
@@ -816,7 +951,7 @@ def _bwd_dkv_kernel_packed(
         dv_scratch[:] = dv_scratch[:] + dv
         dk_scratch[:] = dk_scratch[:] + dk
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step == n_steps - 1)
     def _finish():
         dk_ref[0] = dk_scratch[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scratch[:].astype(dv_ref.dtype)
@@ -854,39 +989,72 @@ def _optional_smem(kernel, prefix, offsets, batch, at, selected=None,
     return arrays, specs, kernel
 
 
-def _clamped_block_maps(causal_clamp, block_q, block_k, n_q_blocks, window):
-    """(k block of grid step (i, j), q block of grid step (j, i)) with
-    the run gate's dead blocks clamped onto a live neighbour: a
-    compute-skipped block still costs its DMA under a naive index map,
-    while re-addressing the SAME block is not refetched. Off
-    (identity) with a prefix, which can make above-diagonal blocks
-    live, and with traced global offsets, where the diagonal's grid
-    position is unknown at trace time."""
+def _inner_grid(causal_clamp, block_q, block_k, n_q_blocks, n_k_blocks,
+                window):
+    """The kernels' inner grid axis: ``(band, (steps, k_block), (steps,
+    q_block))`` — whether the axis is the band, then its extent and
+    index map for the forward and dq grids (``k_block(i, step)``: the k
+    block that step of q block i fetches) and for the dk/dv grid
+    (``q_block(j, step)``).
 
-    def k_block(i, j):
-        if causal_clamp:
-            j = jnp.minimum(j, _last_visible_k_block(i, block_q, block_k))
-            if window:
-                j = jnp.maximum(
-                    j, _first_window_k_block(i, block_q, block_k, window)
-                )
-        return j
+    The square: a step is a block, ``nk`` and ``nq`` of them, and under
+    ``causal_clamp`` the run gate's dead blocks are clamped onto a live
+    neighbour — a compute-skipped block still costs its DMA under a
+    naive index map, while re-addressing the SAME block is not
+    refetched (``causal_clamp``: ``_gate_is_static``; else identity).
 
-    def q_block(j, i):
-        if causal_clamp:
-            i = jnp.maximum(
-                i, _first_visible_q_block(j, n_q_blocks, block_q, block_k)
+    The band (the clamp and a live window): the blocks a window admits for a
+    q block are a run that starts at ``_first_window_k_block`` and ends
+    at the diagonal, so the axis walks that run and no more — as many
+    steps as the widest q block's run (3 at a window of 2,048 keys and
+    tiles of 1,024, 5 at tiles of 512), step s of q block i standing
+    for block first(i) + s (``_band_k_block``). A q block with a
+    shorter run (the sequence's first ones) re-addresses its last block
+    for the steps left over, and the run gate, which sees the block the
+    step stands for and not the one fetched, keeps the accumulators from
+    seeing a block twice. The dk/dv grid likewise from
+    ``_first_visible_q_block`` to ``_last_window_q_block``."""
+    band = causal_clamp and _live_window(window, n_k_blocks * block_k)
+    k_steps, q_steps = n_k_blocks, n_q_blocks
+    if band:
+        k_steps = max(
+            _lower(_last_visible_k_block(i, block_q, block_k), k_steps - 1)
+            - _first_window_k_block(i, block_q, block_k, window) + 1
+            for i in range(n_q_blocks)
+        )
+        q_steps = max(
+            _last_window_q_block(j, n_q_blocks, block_q, block_k, window)
+            - _first_visible_q_block(j, n_q_blocks, block_q, block_k) + 1
+            for j in range(n_k_blocks)
+        )
+
+    def k_block(i, step):
+        if not causal_clamp:
+            return step
+        last = _last_visible_k_block(i, block_q, block_k)
+        if band:
+            step, _ = _band_k_block(
+                i, step, block_q, block_k, window, n_k_blocks
             )
-            if window:
-                i = jnp.minimum(
-                    i,
-                    _last_window_q_block(
-                        j, n_q_blocks, block_q, block_k, window
-                    ),
-                )
-        return i
+            last = _lower(last, n_k_blocks - 1)
+        return _lower(step, last)
 
-    return k_block, q_block
+    def q_block(j, step):
+        if not causal_clamp:
+            return step
+        if band:
+            step, _ = _band_q_block(j, step, block_q, block_k, n_q_blocks)
+            return _lower(
+                step,
+                _last_window_q_block(
+                    j, n_q_blocks, block_q, block_k, window
+                ),
+            )
+        return _upper(
+            step, _first_visible_q_block(j, n_q_blocks, block_q, block_k)
+        )
+
+    return band, (k_steps, k_block), (q_steps, q_block)
 
 
 def _slab_view(x):
@@ -978,10 +1146,13 @@ def _pallas_backward(q, k, v, out, lse, g, causal, scale,
         has_offsets=offsets is not None,
         window=window,
     )
-    k_block, q_block = _clamped_block_maps(
-        causal and prefix is None and offsets is None,
-        block_q, block_k, nq, window,
+    band, (k_steps, k_block), (q_steps, q_block) = _inner_grid(
+        _gate_is_static(causal, prefix, offsets),
+        block_q, block_k, nq, nk, window,
     )
+    # the kernels on the banded grid count their blocks from the band's
+    # first one and need the sequence's block count to stop at its end
+    dq_band, dkv_band = nk * band, nq * band
     g = g.astype(q.dtype)
     glse = () if g_lse is None else (_stat_tiles(g_lse, pack),)
     stat_struct = _out_struct(lse.shape, lse.dtype, q)
@@ -1023,11 +1194,11 @@ def _pallas_backward(q, k, v, out, lse, g, causal, scale,
 
         first = lambda i, j: i  # noqa: E731 — this grid step's own block
         extra, extra_specs, kernel = dq_kernel(
-            functools.partial(_bwd_dq_kernel_packed, **common_p)
+            functools.partial(_bwd_dq_kernel_packed, **common_p, band=dq_band)
         )
         dq, delta = pl.pallas_call(
             kernel,
-            grid=(b, n_slabs, nq, nk),
+            grid=(b, n_slabs, nq, k_steps),
             in_specs=[spec(block_q, first), spec(block_k, k_block),
                       spec(block_k, k_block), spec(block_q, first),
                       spec(block_q, first),
@@ -1041,12 +1212,14 @@ def _pallas_backward(q, k, v, out, lse, g, causal, scale,
         )(qs, ks, vs, dos, outs, lse, *glse, *extra)
 
         extra, extra_specs, kernel = _optional_smem(
-            functools.partial(_bwd_dkv_kernel_packed, **common_p),
+            functools.partial(
+                _bwd_dkv_kernel_packed, **common_p, band=dkv_band
+            ),
             prefix, offsets, b, at=6,
         )
         dk, dv = pl.pallas_call(
             kernel,
-            grid=(b, n_slabs, nk, nq),
+            grid=(b, n_slabs, nk, q_steps),
             in_specs=[spec(block_q, q_block), spec(block_k, first),
                       spec(block_k, first), spec(block_q, q_block),
                       row8_spec(q_block), row8_spec(q_block),
@@ -1090,7 +1263,7 @@ def _pallas_backward(q, k, v, out, lse, g, causal, scale,
     k_spec = pl.BlockSpec((1, block_k, d), k_idx)
     sel = "" if selected is None else "_sel"
     extra, extra_specs, kernel = dq_kernel(
-        functools.partial(_bwd_dq_kernel, **common),
+        functools.partial(_bwd_dq_kernel, **common, band=dq_band),
         pl.BlockSpec(
             (1, block_q, block_k),
             lambda g_, i, j: (g_ // h, i, k_block(i, j)),
@@ -1098,7 +1271,7 @@ def _pallas_backward(q, k, v, out, lse, g, causal, scale,
     )
     dq, delta = pl.pallas_call(
         kernel,
-        grid=(b * h, nq, nk),
+        grid=(b * h, nq, k_steps),
         in_specs=[q_spec, k_spec, k_spec, q_spec, q_spec,
                   *[row8_spec] * (1 + len(glse)), *extra_specs],
         out_specs=[q_spec, row8_spec],
@@ -1121,7 +1294,8 @@ def _pallas_backward(q, k, v, out, lse, g, causal, scale,
     )
     kv_spec = pl.BlockSpec((1, block_k, d), lambda g_, j, i: (g_, j, 0))
     extra, extra_specs, kernel = _optional_smem(
-        functools.partial(_bwd_dkv_kernel, **common), prefix, offsets, b,
+        functools.partial(_bwd_dkv_kernel, **common, band=dkv_band),
+        prefix, offsets, b,
         at=6, selected=selected,
         sel_spec=pl.BlockSpec(
             (1, block_q, block_k),
@@ -1130,7 +1304,7 @@ def _pallas_backward(q, k, v, out, lse, g, causal, scale,
     )
     dk, dv = pl.pallas_call(
         kernel,
-        grid=(b * h, nk, nq),
+        grid=(b * h, nk, q_steps),
         in_specs=[qkv_spec, kv_in_spec, kv_in_spec, qkv_spec, row8_spec2,
                   row8_spec2, *extra_specs],
         out_specs=[kv_spec, kv_spec],
@@ -1200,10 +1374,11 @@ def _flash_fwd(
         has_offsets=offsets is not None,
         window=window,
     )
-    k_block, _ = _clamped_block_maps(
-        causal and prefix is None and offsets is None,
-        block_q, block_k, nq, window,
+    band, (k_steps, k_block), _ = _inner_grid(
+        _gate_is_static(causal, prefix, offsets),
+        block_q, block_k, nq, nk, window,
     )
+    common["band"] = nk * band
 
     if pack > 1:
         # [B, S, H, D] is a free view of the projection's [B, S, H·D]:
@@ -1218,7 +1393,7 @@ def _flash_fwd(
             _fwd_kernel_packed, **common, heads=h, pack=pack
         )
         inputs = tuple(_slab_view(x) for x in (q, k, v))
-        grid = (b, n_slabs, nq, nk)
+        grid = (b, n_slabs, nq, k_steps)
         q_spec = pl.BlockSpec(
             (1, block_q, LANES), lambda b_, s_, i, j: (b_, i, s_)
         )
@@ -1254,7 +1429,7 @@ def _flash_fwd(
             k.transpose(0, 2, 1, 3).reshape(b * hkv, sk, d),
             v.transpose(0, 2, 1, 3).reshape(b * hkv, sk, d),
         )
-        grid = (b * h, nq, nk)
+        grid = (b * h, nq, k_steps)
         kv_spec = pl.BlockSpec(
             (1, block_k, d),
             lambda g, i, j: (g // groups, k_block(i, j), 0),
@@ -1526,10 +1701,12 @@ def _bwd_rule_lse(causal, scale, block_q, block_k, window, head_pack,
     array, the fallback the [B, H, S] numbers."""
     q, k, v, prefix, offsets, out, lse = residuals
     g_out, g_lse = cot
-    # wider heads keep the MXU busier per tile, so bigger tiles win
-    cap_q, cap_k = _bwd_caps(q.shape[-1])
-    bq = _fit_block(q.shape[1], min(block_q, cap_q))
-    bk = _fit_block(k.shape[1], min(block_k, cap_k))
+    # wider heads keep the MXU busier per tile, so bigger tiles win; a
+    # window's tile only where the grid is its band
+    bq, bk = _bwd_tiles(
+        q.shape[1], k.shape[1], q.shape[-1], block_q, block_k,
+        window if _gate_is_static(causal, prefix, offsets) else 0,
+    )
     in_kernel = (
         USE_PALLAS_BWD
         and pltpu is not None
@@ -1601,11 +1778,9 @@ def _fwd_rule_selected(q, k, v, selected, scale, block_q, block_k,
 def _bwd_rule_selected(scale, block_q, block_k, lse_rows, residuals, cot):
     q, k, v, selected, out, lse = residuals
     g_out, _ = cot  # lse is detached
-    cap_q, cap_k = _bwd_caps(q.shape[-1])
     dq, dk, dv, _ = _pallas_backward(
         q, k, v, out, _residual_tiles(lse, 1, lse_rows), g_out, True, scale,
-        _fit_block(q.shape[1], min(block_q, cap_q)),
-        _fit_block(k.shape[1], min(block_k, cap_k)),
+        *_bwd_tiles(q.shape[1], k.shape[1], q.shape[-1], block_q, block_k),
         selected=selected,
     )
     return dq, dk, dv, np.zeros(selected.shape, dtype=jax.dtypes.float0)
@@ -1695,6 +1870,17 @@ def flash_attention(
     ):
         pack = LANES // d
     set_counter("attn.heads_per_slab", pack)
+    band, (k_steps, _), _ = _inner_grid(
+        _gate_is_static(causal, prefix_len, None),
+        bq, bk, sq // bq, sk // bk, window,
+    )
+    if band or not counters().get("attn.window_tile"):
+        # (a step of two kinds of layer keeps its window layers' reading)
+        set_counter("attn.band_blocks", k_steps)
+        set_counter(
+            "attn.window_tile",
+            _bwd_tiles(sq, sk, d, bq, bk, window)[1] if band else 0,
+        )
     return _flash_attention(
         q, k, v, prefix_len, None, causal, scale, bq, bk, window, pack,
         lse_rows,

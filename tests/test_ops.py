@@ -676,6 +676,22 @@ def test_factored_adamw_trains_tiny_model():
 # tiles and make delta in the dq kernel, so the unpacked ones (head size
 # 128 with GQA, 256) are cases of the same tests.
 
+def _watch_inner_grid(monkeypatch):
+    """[(arguments, result)] of every ``_inner_grid`` call from here on:
+    the grid each flash kernel traced after this is built with."""
+    from dlrover_tpu.ops import pallas_attention as pa
+
+    calls, inner_grid = [], pa._inner_grid
+
+    def watched(*args):
+        result = inner_grid(*args)
+        calls.append((args, result))
+        return result
+
+    monkeypatch.setattr(pa, "_inner_grid", watched)
+    return calls
+
+
 SLAB_CASES = {
     # GPT-2 XL's head count: 12½ slabs, the half slab in the kernel
     "25x64-causal": dict(h=25, d=64),
@@ -686,6 +702,22 @@ SLAB_CASES = {
     "4x32-dense": dict(h=4, d=32, causal=False),
     "25x64-prefix": dict(h=25, d=64, prefix=(17, 100)),
     "5x64-window": dict(h=5, d=64, window=48),
+    # the banded grid (a window's kernels walk the key blocks it admits):
+    # windows under a tile, of a tile, off the tiles' grid and of several
+    # tiles, over four tiles of keys at the two tiles given outright
+    # (the public entry's rule would pick 128 at each of these windows)
+    **{
+        f"{name}-window{window}-tile{tile}": dict(
+            window=window, tile=tile, s=4 * tile, b=1, **heads
+        )
+        for name, heads in (
+            ("2x128", dict(h=2, d=128)),
+            ("4x128-gqa", dict(h=4, hkv=1, d=128)),
+            ("3x64", dict(h=3, d=64)),  # packed, the last slab half full
+        )
+        for window in (48, 128, 200, 384)
+        for tile in (128, 256)
+    },
     "1x64-causal": dict(h=1, d=64),  # the array narrower than a slab
     "5x64-two-blocks": dict(h=5, d=64, s=256),  # the carried statistics
     "16x128-gqa": dict(h=16, hkv=4, d=128),  # unpacked: Mistral, OLMoE
@@ -696,7 +728,8 @@ SLAB_CASES = {
 @pytest.mark.parametrize("case", sorted(SLAB_CASES))
 def test_flash_slab_kernels_match_reference(monkeypatch, case):
     """Forward and all three gradients against ``mha_reference``, through
-    the public entry (head_pack=0: auto), with delta from the dq kernel."""
+    the public entry (head_pack=0: auto), with delta from the dq kernel;
+    a case that names its tile goes to the kernels' own entries."""
     from dlrover_tpu.observability import tracing
     from dlrover_tpu.ops import pallas_attention as pa
 
@@ -705,26 +738,42 @@ def test_flash_slab_kernels_match_reference(monkeypatch, case):
     hkv = spec.pop("hkv", None)
     causal = spec.pop("causal", True)
     prefix = spec.pop("prefix", None)
+    tile, batch = spec.pop("tile", None), spec.pop("b", 2)
     kw = dict(spec)
     if prefix is not None:
         kw["prefix_len"] = jnp.array(prefix, jnp.int32)
     monkeypatch.setattr(pa, "INTERPRET", True)
-    q, k, v = _qkv(jax.random.key(20), s=s_len, h=h, hkv=hkv, d=d)
+    q, k, v = _qkv(jax.random.key(20), b=batch, s=s_len, h=h, hkv=hkv, d=d)
     scale = d ** -0.5
+    pack = 1 if hkv else max(128 // d, 1)
     g = jax.random.normal(jax.random.key(25), q.shape)
-    f = lambda q, k, v: jnp.vdot(  # noqa: E731
-        pa.flash_attention(q, k, v, causal=causal, block_q=128,
-                           block_k=128, **kw), g
-    )
     fr = lambda q, k, v: jnp.vdot(  # noqa: E731
         mha_reference(q, k, v, causal=causal, softmax_scale=scale, **kw),
         g,
     )
-    (lo, go) = jax.value_and_grad(f, argnums=(0, 1, 2))(q, k, v)
-    assert tracing.counters()["attn.heads_per_slab"] == (
-        1 if hkv else max(128 // d, 1)
-    )
-    assert tracing.counters()["attn.delta_in_kernel"] == 1
+    if tile is None:
+        f = lambda q, k, v: jnp.vdot(  # noqa: E731
+            pa.flash_attention(q, k, v, causal=causal, block_q=128,
+                               block_k=128, **kw), g
+        )
+        (lo, go) = jax.value_and_grad(f, argnums=(0, 1, 2))(q, k, v)
+        assert tracing.counters()["attn.heads_per_slab"] == pack
+        assert tracing.counters()["attn.delta_in_kernel"] == 1
+    else:
+        # the kernels at the tile given outright, past the rules that
+        # choose one: four tiles of keys, of which a block's window
+        # reaches ceil(W / tile) + 1, and no grid's inner axis is longer
+        grids = _watch_inner_grid(monkeypatch)
+        tiled = dict(window=kw["window"], head_pack=pack)
+        out, lse = pa._flash_fwd(q, k, v, True, scale, tile, tile, **tiled)
+        lo = jnp.vdot(out, g)
+        go = pa._pallas_backward(
+            q, k, v, out, lse, g, True, scale, tile, tile, **tiled
+        )[:3]
+        steps = min(-(-kw["window"] // tile) + 1, 4)
+        assert [(band, ks, qs) for _, (band, (ks, _), (qs, _)) in grids] == [
+            (True, steps, steps)
+        ] * 2
     (lr, gr) = jax.value_and_grad(fr, argnums=(0, 1, 2))(q, k, v)
     np.testing.assert_allclose(float(lo), float(lr), rtol=2e-3)
     for a, r in zip(go, gr):
@@ -902,3 +951,123 @@ def test_flash_attention_gqa_demotes_head_pack(monkeypatch):
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), rtol=2e-3, atol=2e-3
     )
+
+
+# -- the banded grid (pallas_attention._inner_grid) -------------------------
+
+BAND_GRIDS = {
+    # (seq, window, block_q, block_k): (k steps, q steps) where a cell
+    # runs it, else None
+    "trinity-512": ((16384, 2048, 512, 512), (5, 5)),
+    "trinity-1024": ((16384, 2048, 1024, 1024), (3, 3)),
+    "mistral-1024": ((8192, 4096, 1024, 1024), (5, 5)),
+    "window-of-tiles": ((1024, 256, 128, 128), (3, 3)),
+    "window-off-the-tiles": ((1024, 200, 128, 128), (3, 3)),
+    "window-under-a-tile": ((1024, 48, 128, 128), (2, 2)),
+    "window-of-one-key": ((512, 1, 128, 128), (1, 1)),
+    # not live: the square, whose every step is its own block
+    "window-of-the-sequence": ((1024, 1024, 256, 256), (4, 4)),
+    "window-past-the-sequence": ((1024, 4096, 256, 256), (4, 4)),
+    "one-tile": ((256, 100, 256, 256), (1, 1)),
+    "wide-q-blocks": ((1024, 384, 256, 128), None),
+    "wide-k-blocks": ((1024, 384, 128, 256), None),
+    "q-blocks-of-four": ((2048, 520, 512, 128), None),
+    "k-blocks-of-four": ((2048, 640, 128, 512), None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(BAND_GRIDS))
+def test_band_walks_exactly_the_blocks_the_gate_admits(case):
+    """The banded grid's index arithmetic, in plain integers: for every
+    outer block, the steps the run gate lets through stand for exactly
+    the blocks ``_block_runs`` admits on the square, each once and in
+    order, and fetch the block they stand for; every other step fetches
+    a block of the sequence (a neighbour, not refetched) and runs
+    nothing. Forward / dq map and dk/dv map."""
+    from dlrover_tpu.ops import pallas_attention as pa
+
+    (seq, window, bq, bk), extents = BAND_GRIDS[case]
+    nq, nk = seq // bq, seq // bk
+    band, (k_steps, k_block), (q_steps, q_block) = pa._inner_grid(
+        True, bq, bk, nq, nk, window
+    )
+    assert band == (window < seq)
+    assert 1 <= k_steps <= nk and 1 <= q_steps <= nq
+    if extents:
+        assert (k_steps, q_steps) == extents
+
+    def runs(i, j, in_band=None):
+        return bool(pa._block_runs(
+            True, False, None, i * bq, j * bk, bq, bk, window, in_band
+        ))
+
+    for i in range(nq):
+        walked = []
+        for step in range(k_steps):
+            j, in_band = pa._band_k_block(
+                i, step, bq, bk, window, nk * band
+            )
+            fetched = int(k_block(i, step))
+            assert 0 <= fetched < nk
+            if runs(i, j, in_band):
+                assert fetched == j
+                walked.append(j)
+        assert walked == [j for j in range(nk) if runs(i, j)], i
+    for j in range(nk):
+        walked = []
+        for step in range(q_steps):
+            i, in_band = pa._band_q_block(j, step, bq, bk, nq * band)
+            fetched = int(q_block(j, step))
+            assert 0 <= fetched < nq
+            if runs(i, j, in_band):
+                assert fetched == i
+                walked.append(i)
+        assert walked == [i for i in range(nq) if runs(i, j)], j
+
+
+@pytest.mark.parametrize(
+    "seq,window,block,band_blocks,tile",
+    [
+        (16384, 2048, 1024, 3, 512),  # Trinity's window layers
+        (8192, 4096, 1024, 5, 1024),  # Mistral: the tile it had
+        (8192, 4096, 512, 9, 512),  # the caller's block is a cap
+        (1024, 48, 512, 2, 128),  # no tile under 128
+        (2048, 2048, 1024, 2, 0),  # a window of the sequence: not live
+        (2048, 0, 1024, 2, 0),  # no window: the square
+    ],
+)
+def test_window_tile_and_band_counters(monkeypatch, seq, window, block,
+                                       band_blocks, tile):
+    """``attn.band_blocks`` is the inner extent of the forward grid —
+    under a live window the key blocks it admits for a query block at
+    the caller's tile, else every key block — and ``attn.window_tile``
+    the backward kernels' k tile under a live window — the largest
+    128-multiple dividing the sequence, at most the caller's block, the
+    head width's cap and a quarter of the window — else 0. Set while
+    tracing; the backward's grids follow the tile."""
+    from dlrover_tpu.observability import tracing
+    from dlrover_tpu.ops import pallas_attention as pa
+
+    monkeypatch.setattr(pa, "INTERPRET", True)
+    q = jax.ShapeDtypeStruct((1, seq, 4, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, seq, 1, 128), jnp.bfloat16)
+    grids = _watch_inner_grid(monkeypatch)
+    tracing._counters.clear()
+    jax.eval_shape(
+        jax.grad(
+            lambda q, k, v: pa.flash_attention(
+                q, k, v, causal=True, block_q=block, block_k=block,
+                window=window,
+            ).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2),
+        ),
+        q, kv, kv,
+    )
+    got = tracing.counters()
+    assert got["attn.band_blocks"] == band_blocks
+    assert got["attn.window_tile"] == tile
+    # the last grid traced is the backward's: at its tile, and banded
+    # exactly where the window is live
+    (_, block_q, block_k, *_), (band, _, _) = grids[-1]
+    assert block_q == block_k == (tile or block)
+    assert band == bool(tile)

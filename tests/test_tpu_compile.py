@@ -81,6 +81,25 @@ def _flash(heads, head_dim, grad):
     return build
 
 
+def _flash_window(seq, kv_heads, window):
+    """A window layer's three kernels on the banded grid at a cell's own
+    shape: GQA 32 / ``kv_heads`` heads of 128 over one sequence, at the
+    model's blocks of 1024 (the tile comes from the window)."""
+    def build(S):
+        q = S((1, seq, 32, 128), BF16)
+        k = S((1, seq, kv_heads, 128), BF16)
+
+        def loss(q, k, v):
+            return pallas_attention.flash_attention(
+                q, k, v, causal=True, block_q=1024, block_k=1024,
+                window=window,
+            ).astype(F32).sum()
+
+        return jax.grad(loss, argnums=(0, 1, 2)), (q, k, k)
+
+    return build
+
+
 def _flash_selected(grad):
     """Keye-VL-2.0's attention: GQA 32 / 4 heads of 128 over one
     sequence of 8192 with the int8 selection operand, at the model's
@@ -179,6 +198,12 @@ CASES = {
     "flash-bwd-25x64-packed": (_flash(25, 64, grad=True), 3),
     "flash-fwd-16x128": (_flash(16, 128, grad=False), 1),
     "flash-bwd-16x128": (_flash(16, 128, grad=True), 3),
+    # a live window, the banded grid: Trinity-Mini's window layers (five
+    # tiles of 512) and Mistral's (five of 1024)
+    "flash-bwd-32x4x128-window2048-of-16384": (
+        _flash_window(16384, 4, 2048), 3),
+    "flash-bwd-32x8x128-window4096-of-8192": (
+        _flash_window(8192, 8, 4096), 3),
     # latent attention expanded (GLM-4.7-Flash): 20 heads of 256
     "flash-fwd-20x256": (_flash(20, 256, grad=False), 1),
     "flash-bwd-20x256": (_flash(20, 256, grad=True), 3),
@@ -258,6 +283,52 @@ def test_flash_layout_around_the_kernels(chip, case, kernels):
         assert shape not in text
 
 
+def _pallas_grids(jaxpr, found):
+    """{kernel name: grid} of every ``pallas_call`` in a jaxpr."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found[eqn.params["name"]] = tuple(eqn.params["grid_mapping"].grid)
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)  # a ClosedJaxpr's
+                if hasattr(sub, "eqns"):
+                    _pallas_grids(sub, found)
+    return found
+
+
+@pytest.mark.parametrize(
+    "case,grids",
+    [
+        # no window: the square, the grid before PR 48
+        ("flash-bwd-16x128", {"flash_fwd": (128, 2, 2),
+                              "flash_bwd_dq": (128, 2, 2),
+                              "flash_bwd_dkv": (128, 2, 2)}),
+        ("flash-bwd-25x64-packed", {"flash_fwd_packed": (8, 13, 2, 2),
+                                    "flash_bwd_dq_packed": (8, 13, 2, 2),
+                                    "flash_bwd_dkv_packed": (8, 13, 2, 2)}),
+        ("flash-bwd-sel-32x4x128", {"flash_fwd_sel": (32, 8, 8),
+                                    "flash_bwd_dq_sel": (32, 8, 8),
+                                    "flash_bwd_dkv_sel": (32, 8, 8)}),
+        # a live window: the forward a band of 3 blocks of 1024 and the
+        # backward one of 5 of 512 (the square had 16 x 16 of 1024);
+        # Mistral's a band of 5 of 1024 (8 x 8)
+        ("flash-bwd-32x4x128-window2048-of-16384",
+         {"flash_fwd": (32, 16, 3), "flash_bwd_dq": (32, 32, 5),
+          "flash_bwd_dkv": (32, 32, 5)}),
+        ("flash-bwd-32x8x128-window4096-of-8192",
+         {"flash_fwd": (32, 8, 5), "flash_bwd_dq": (32, 8, 5),
+          "flash_bwd_dkv": (32, 8, 5)}),
+    ],
+)
+def test_flash_grids(case, grids):
+    """The grid each flash kernel is lowered with: without a live window
+    the square ``(…, q blocks, k blocks)`` it always had, under one the
+    band of key blocks the window admits, the backward's at the tile
+    taken from the window."""
+    fn, args = CASES[case][0](jax.ShapeDtypeStruct)
+    assert _pallas_grids(jax.make_jaxpr(fn)(*args).jaxpr, {}) == grids
+
+
 # ---- the whole train step: kernel names and phase scopes ------------------
 # What a device trace shows for an operation is its HLO instruction's
 # name, and what the program's reducer (observability/runtime_timer.py)
@@ -291,6 +362,9 @@ STEP_CASES = {
         scopes={"embed", "attn", "mlp", "head_loss", "optimizer"},
         stat_tiles="f32[32,2048,8]",  # [B·H, S, 8]
         stat_rows="1,32,2048",  # span 768.25 under the window
+        # the forward's band is both blocks of 1024; the backward's tile
+        # is a quarter of the window
+        band=(2, 256),
     ),
     # OLMoE's published widths, one layer of 16: 64 experts of width
     # 1024 top-8 through ``lax.ragged_dot`` (the compiler's own grouped
@@ -467,6 +541,17 @@ def test_step_names_its_kernels_and_phases(topo, case):
     # keep the projections' layout in the whole step too
     packed = "flash_fwd_packed" in spec["kernels"]
     assert counters["attn.heads_per_slab"] == (2 if packed else 1)
+    # the forward grid's inner axis: the band of key blocks under a live
+    # window, else every key block; the backward's tile under a window
+    if "flash_fwd_sel" not in spec["kernels"]:  # (no window with one)
+        seq = spec["batch"][1]
+        band_blocks, window_tile = spec.get("band", (None, 0))
+        assert counters["attn.window_tile"] == window_tile
+        assert counters["attn.band_blocks"] == (
+            band_blocks or seq // pallas_attention._fit_block(
+                seq, builder.cfg.attn_block_k
+            )
+        )
     if packed:
         assert "[8,26,1024,64]" not in text and "[104,2,1024,64]" not in text
     # the row statistics (lse, delta) stay in the kernels' own tiles from
@@ -916,6 +1001,11 @@ def test_trinity_cell_keeps_the_full_layers_output_only(topo):
     assert counters["attn.window_layers"] == 4
     assert counters["attn.full_layers"] == 1
     assert counters["attn.output_kept"] == 1
+    # the window layers' kernels walk the band (PR 48): the forward
+    # three key blocks of 1024 a query block, on a grid of three where
+    # it was sixteen; the backward five of 512
+    assert counters["attn.band_blocks"] == 3
+    assert counters["attn.window_tile"] == 512
     # by scope: a window layer's forward kernel twice (forward and
     # recomputed), a full layer's once; the dense prefix's window layer
     # outside the scan, the period's three inside it
