@@ -1225,11 +1225,15 @@ def _mamba_block(h, ssm, cfg: ModelConfig, mesh):
         + ssm["dt_bias"].astype(f32)
     )
     x = xbc[..., :inner].reshape(b, s, heads, hd)
+    # the mesh goes along only where it rules the scan's kernels out
+    # (several devices: ROADMAP S6): the benchmark's planted defects
+    # stand in for ``ssd_scan`` by its parameters up to ``head_block``
+    several = {"mesh": mesh} if mesh is not None and mesh.size > 1 else {}
     y = ssd.ssd_scan(
         x, step, -jnp.exp(ssm["a_log"].astype(f32)),
         xbc[..., inner:inner + g * n].reshape(b, s, g, n),
         xbc[..., inner + g * n:].reshape(b, s, g, n),
-        cfg.ssm_chunk, cfg.ssm_head_block,
+        cfg.ssm_chunk, cfg.ssm_head_block, **several,
     )
     y = y + (ssm["d_skip"].astype(f32)[:, None] * x.astype(f32)).astype(dt_)
     y = ssd.gated_group_norm(
@@ -1515,6 +1519,18 @@ def alignment_in_kernel(cfg: ModelConfig, s: int, attn_impl: str = "auto",
         _resolve_attn_impl(attn_impl, mesh) == "flash"
         and alignment_tiles(cfg, s) is not None
     )
+
+
+def scan_in_kernel(cfg: ModelConfig, s: int, mesh=None) -> bool:
+    """Whether a step at sequence length ``s`` takes its Mamba-2 layers'
+    scan through the Pallas kernels (``ops/pallas_ssd.py``) and not the
+    XLA body: what ``ssd.ssd_scan`` decides from the same numbers."""
+    from dlrover_tpu.ops import ssd
+
+    return ssd.kernel_chunk(
+        s, cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.n_groups,
+        cfg.ssm_state_size, cfg.ssm_chunk, mesh,
+    ) is not None
 
 
 def alignment_passes(cfg: ModelConfig) -> int:

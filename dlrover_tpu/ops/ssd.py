@@ -1,5 +1,6 @@
 """Mamba-2's selective state-space scan in its chunked
-(state-space-duality) form, out of matmuls that XLA schedules.
+(state-space-duality) form: as Pallas kernels where they fit, else out
+of matmuls that XLA schedules.
 
 For every head h (of a group g(h) that shares B and C), with the time
 step Δ_t > 0 and A_h < 0:
@@ -26,11 +27,38 @@ whatever the compute dtype: decays multiply thousands of times, and a
 bf16 ``cum`` is off by whole percents at the end of a chunk. The
 products run on operands of the compute dtype and sum in float32.
 
-``head_block`` heads go through at a time (``lax.map``, each block
-under its own ``jax.checkpoint``), so that L and the masked scores,
-[chunks, heads, Q, Q] float32 (512 MiB each for 128 heads at 8,192
-tokens), are never whole, forward or backward: a block of 16 heads
-(one B/C group of Nemotron-3's) holds 64 MiB of each.
+One ``ssd_scan``, two bodies, chosen from what the call sees
+(``kernel_chunk``): on a TPU (or interpreted), on one device, at shapes
+the tiles fit — a group's heads filling 128-lane slabs, a state on the
+128 grid — the Pallas kernels of ``ops/pallas_ssd.py``; everywhere else
+the XLA body below, ``_block_scan``.
+
+The kernels keep in VMEM what the XLA body writes to memory and reads
+back — the decay and masked score blocks, Δ ⊙ x, the chunk states — and
+carry the state (and, going back, its cotangent) in scratch from chunk
+to chunk. The backward remakes a chunk's decay blocks from ``cum`` and
+takes the chunks' starting states from a pass of its own; nothing but
+the operands is a residual, so the scan runs a forward, a remade
+forward, a state pass and a backward a step under ``remat: full`` where
+the XLA body runs five forwards' worth (forward, the layer's remat, each
+head block's own checkpoint, the backward's two). Their chunk is their
+own tile (256 at Nemotron-3's widths); ``chunk`` and ``head_block`` are
+the XLA body's. On a v5e at 1 x 8,192 tokens, 128 heads of 64 in 8
+groups, state 128, bf16 (ms a call, the whole ``ssd_scan`` with its
+layout copies, forward / forward and backward; my chip runs, PR 50):
+the XLA body 5.44 / 12.22; the kernels 3.20 / 5.83, of which the
+kernels themselves 1.24 / 0.63 / 2.55 (forward / states / backward) and
+XLA's cumulative sum, layout copies and the test's own loss the rest. ``pallas_ssd``'s docstring has the
+sweeps, and what a kernel costs to trace and lower before it runs —
+the budget that chose its form.
+
+In the XLA body ``head_block`` heads go through at a time (``lax.map``,
+each block under its own ``jax.checkpoint``), so that L and the masked
+scores, [chunks, heads, Q, Q] float32 (512 MiB each for 128 heads at
+8,192 tokens), are never whole, forward or backward: a block of 16 heads
+(one B/C group of Nemotron-3's) holds 64 MiB of each. The kernels never
+hold a block outside VMEM, so ``head_block`` matters to the XLA body
+only.
 
 A length that is no multiple of the chunk is PADDED at its end with
 tokens of Δ = 0 and x = 0, which leave every state as it was and whose
@@ -39,6 +67,8 @@ outputs are cut off again: exact, since the scan is causal.
 
 import jax
 import jax.numpy as jnp
+
+from dlrover_tpu.ops import pallas_ssd
 
 F32 = jnp.float32
 
@@ -100,14 +130,45 @@ def _block_scan(x, dt, a, b_mat, c_mat, chunk):
     return y.astype(dtype).reshape(bsz, s, g, r, p)
 
 
-def ssd_scan(x, dt, a, b_mat, c_mat, chunk: int, head_block: int = 0):
+def _kernel_scan(x, dt, a, b_mat, c_mat, q):
+    """The Pallas body (``ops/pallas_ssd.py``) on whole chunks of ``q``
+    tokens: XLA makes the running log-decay (float32, within a chunk),
+    the kernels the rest, on the operands as they lie — no array with
+    heads of 64 channels as its last dimensions is made, which the
+    compiler would pad to 128 lanes and copy. The derivative reaches A
+    through ``cum``, Δ through it and by its own term."""
+    bsz, sp, h, p = x.shape
+    cum = jnp.cumsum((dt * a).reshape(bsz, sp // q, q, h), axis=2)
+    y = pallas_ssd.scan(
+        x.reshape(bsz, sp, h * p), dt, cum.reshape(bsz, sp, h),
+        b_mat.reshape(bsz, sp, -1), c_mat.reshape(bsz, sp, -1), q, p,
+        b_mat.shape[-1],
+    )
+    return y.reshape(bsz, sp, h, p)
+
+
+def kernel_chunk(s: int, heads: int, channels: int, groups: int, state: int,
+                 chunk: int, mesh=None):
+    """The chunk of the Pallas body for a sequence of ``s`` tokens
+    (padded to whole chunks of ``chunk``), or None where ``ssd_scan``
+    takes the XLA body: ``pallas_ssd.tile``'s conditions."""
+    return pallas_ssd.tile(
+        s + -s % chunk, heads // groups, channels, state, mesh
+    )
+
+
+def ssd_scan(x, dt, a, b_mat, c_mat, chunk: int, head_block: int = 0,
+             mesh=None):
     """The scan over a sequence. x [B, S, H, P]; dt [B, S, H] float32,
     positive (after the softplus); a [H] float32, negative; b_mat and
     c_mat [B, S, G, N] with H a multiple of G. Returns y [B, S, H, P] in
-    x's dtype (the skip ``D x`` is the caller's). ``head_block`` heads
-    at a time (0 = all), a multiple or a divisor of H / G."""
+    x's dtype (the skip ``D x`` is the caller's). One function, two
+    bodies, chosen from what it sees (``kernel_chunk``): the Pallas
+    kernels at their own chunk, or the XLA body at ``chunk`` with
+    ``head_block`` heads at a time (0 = all), a multiple or a divisor of
+    H / G."""
     bsz, s, h, p = x.shape
-    g = b_mat.shape[2]
+    g, n = b_mat.shape[2:]
     per_group = h // g
     pad = -s % chunk
     if pad:
@@ -118,6 +179,11 @@ def ssd_scan(x, dt, a, b_mat, c_mat, chunk: int, head_block: int = 0):
         b_mat = jnp.pad(b_mat, widths + ((0, 0), (0, 0)))
         c_mat = jnp.pad(c_mat, widths + ((0, 0), (0, 0)))
     sp = s + pad
+    q = kernel_chunk(s, h, p, g, n, chunk, mesh)
+    if q is not None:
+        with jax.named_scope("ssm.scan"):
+            y = _kernel_scan(x, dt, a, b_mat, c_mat, q)
+        return y[:, :s] if pad else y
     block = head_block or h
     if h % block or (block % per_group and per_group % block):
         raise ValueError(

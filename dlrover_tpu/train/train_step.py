@@ -1215,6 +1215,10 @@ class TrainStepBuilder:
                     cfg, seq, self.attn_impl, self.mesh
                 )
             ))
+        if "M" in cfg.layer_pattern + cfg.mtp_pattern:
+            set_counter("ssm.scan_in_kernel", int(
+                decoder.scan_in_kernel(cfg, seq, self.mesh)
+            ))
         if self.update_sharding:
             return self._sharded_step_fn(state, batch)
         batch = jax.tree.map(

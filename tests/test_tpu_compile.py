@@ -26,7 +26,8 @@ from jax.sharding import SingleDeviceSharding
 from dlrover_tpu.common import device
 from dlrover_tpu.models.config import get_config
 from dlrover_tpu.ops import (
-    pallas_align, pallas_attention, pallas_norm, pallas_paged,
+    pallas_align, pallas_attention, pallas_norm, pallas_paged, pallas_ssd,
+    ssd,
 )
 from dlrover_tpu.serving import kv_cache as kvc
 
@@ -147,6 +148,30 @@ def _align():
     return build
 
 
+def _ssd(grad):
+    """A Mamba-2 layer's scan at Nemotron-3-Super's widths: one sequence
+    of 8,192, 128 heads of 64 in 8 groups over a state of 128, at the
+    chunk the program picks (``ssd.kernel_chunk``)."""
+    def build(S):
+        s, heads, channels, groups, state = 8192, 128, 64, 8, 128
+        args = (
+            S((1, s, heads, channels), BF16), S((1, s, heads), F32),
+            S((heads,), F32), S((1, s, groups, state), BF16),
+            S((1, s, groups, state), BF16),
+        )
+        assert ssd.kernel_chunk(s, heads, channels, groups, state, 128) == 256
+
+        def fwd(*a):
+            return ssd.ssd_scan(*a, 128, 16)
+
+        if not grad:
+            return fwd, args
+        loss = lambda *a: fwd(*a).astype(F32).sum()  # noqa: E731
+        return jax.grad(loss, argnums=(0, 1, 2, 3, 4)), args
+
+    return build
+
+
 def _norm(d, grad, residual):
     def build(S):
         x, scale = S((8, 1024, d), BF16), S((d,), F32)
@@ -212,6 +237,9 @@ CASES = {
     "flash-bwd-sel-32x4x128": (_flash_selected(grad=True), 3),
     # and its alignment term (``ops/pallas_align.py``)
     "align-kl-16x64-32x4x128": (_align(), 1),
+    # a Mamba-2 layer's scan (Nemotron-3-Super): ``ops/pallas_ssd.py``
+    "ssd-fwd-128x64-8x128": (_ssd(grad=False), 1),
+    "ssd-bwd-128x64-8x128": (_ssd(grad=True), 2),
     # its two rank norms
     "norm-bwd-d768": (_norm(768, grad=True, residual=False), 1),
     "norm-bwd-d512": (_norm(512, grad=True, residual=False), 1),
@@ -245,6 +273,11 @@ def test_kernel_compiles_for_v5e(chip, case):
         assert sum(name in text for name in names) == n_kernels
     if case.startswith("align-"):
         assert "%align_kl" in text
+    if case.startswith("ssd-"):
+        # the gradient alone needs no y: the forward kernel is dead code
+        # there, and the backward rule's two kernels are what is left
+        names = ("ssd_states", "ssd_bwd") if "bwd" in case else ("ssd_fwd",)
+        assert all(f"%{name}" in text for name in names)
 
 
 @pytest.mark.parametrize(
@@ -451,6 +484,7 @@ STEP_CASES = {
 
 _STEP_TEXT = {}
 _STEP_MEMORY = {}  # case -> the compiled step's memory_analysis()
+_STEP_LOWERED = {}  # case -> the step's text before XLA, where kept
 
 
 def _compiled_step(topo, case):
@@ -492,7 +526,10 @@ def _compiled_step(topo, case):
         for k in ("tokens", "targets")
     }
     tracing._counters.clear()
-    compiled = builder.build().lower(state, batch).compile()
+    lowered = builder.build().lower(state, batch)
+    if spec.get("keep_lowered"):
+        _STEP_LOWERED[case] = lowered.as_text()
+    compiled = lowered.compile()
     _STEP_MEMORY[case] = compiled.memory_analysis()
     _STEP_TEXT[case] = builder, compiled.as_text(), dict(tracing.counters())
     return _STEP_TEXT[case]
@@ -812,7 +849,7 @@ def test_glm_cell_fits_the_chip_at_its_depth(topo):
     )
 
 
-def test_nemotron_cell_fits_the_chip_with_its_rows_cut(topo):
+def test_nemotron_cell_fits_the_chip_with_its_rows_cut(topo, monkeypatch):
     """The benchmark's Nemotron-3-Super configuration as it is run (one
     period MEMEMEMEM*E + the module, 8 of 512 experts held, 1 x 8192
     tokens): the step the chip's compiler lays out needs under the 16.9
@@ -824,7 +861,16 @@ def test_nemotron_cell_fits_the_chip_with_its_rows_cut(topo):
     part shows under its scope, the attention goes through the unpacked
     flash kernels (GQA 32 / 2 at head size 128), and the held experts'
     rows are cut to 8,192 x 8: no array of 180,224 rows is as wide as
-    an expert."""
+    an expert.
+
+    Since PR 50 the five Mamba-2 layers' scan runs its kernels
+    (``ops/pallas_ssd.py``). What they cost BEFORE the step runs is
+    held here without a clock (PR 49's form ran as fast and was refused
+    for 4.5 s of set-up): while the step is traced the forward kernel's
+    body is traced twice (the forward — the primal's and the forward
+    rule's are one trace — and the state pass) and the backward's once,
+    not once a layer and not once a rule; and in the step LOWERED,
+    before XLA, the twenty ``ssd_*`` calls hold three bodies."""
     import json
     import pathlib
     import re
@@ -839,12 +885,41 @@ def test_nemotron_cell_fits_the_chip_with_its_rows_cut(topo):
         model=config["program"]["model"],
         overrides=config["program"]["overrides"],
         optimizer=dict(state_dtype="bfloat16"), comm=None, chips=1,
-        batch=(1, 8192),
+        batch=(1, 8192), keep_lowered=True,
     )
+    # the kernels' traces are kept by shape for the process: forget the
+    # ``ssd-*`` cases' above, and count the bodies traced from here on
+    jax.clear_caches()
+    traced = {"ssd_fwd": 0, "ssd_bwd": 0}
+
+    def counting(name, kernel):
+        def body(*refs, **statics):
+            traced[name] += 1
+            return kernel(*refs, **statics)
+
+        return body
+
+    monkeypatch.setattr(pallas_ssd, "_fwd_kernel", counting(
+        "ssd_fwd", pallas_ssd._fwd_kernel
+    ))
+    monkeypatch.setattr(pallas_ssd, "_bwd_kernel", counting(
+        "ssd_bwd", pallas_ssd._bwd_kernel
+    ))
     try:
         _, text, counters = _compiled_step(topo, "nemotron-cell")
     finally:
         del STEP_CASES["nemotron-cell"]
+    assert traced == {"ssd_fwd": 2, "ssd_bwd": 1}, traced
+    bodies = {}
+    for line in _STEP_LOWERED.pop("nemotron-cell").splitlines():
+        name = re.search(r'kernel_name = "(ssd_\w+)"', line)
+        if name:
+            bodies.setdefault(name.group(1), []).append(
+                re.search(r'body\W+(\w+)', line).group(1)
+            )
+    assert {k: (len(v), len(set(v))) for k, v in bodies.items()} == {
+        "ssd_fwd": (10, 1), "ssd_states": (5, 1), "ssd_bwd": (5, 1),
+    }
     stats = _STEP_MEMORY["nemotron-cell"]
     need = (
         stats.argument_size_in_bytes + stats.output_size_in_bytes
@@ -871,7 +946,32 @@ def test_nemotron_cell_fits_the_chip_with_its_rows_cut(topo):
     assert kernels == {
         "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "norm_fwd",
         "norm_bwd", "ragged-dot-none", "ragged-dot-metadata",
+        "ssd_fwd", "ssd_states", "ssd_bwd",
     }
+    # the five Mamba-2 layers' scan through its kernels, every call
+    # under the scope the benchmark's ``ssm.*`` readers sum: the forward
+    # once in the forward and once remade, and in the backward the pass
+    # that makes the chunks' starting states and the backward kernel —
+    # where the XLA body ran three forwards a backward
+    assert counters["ssm.scan_in_kernel"] == 1
+    assert _kernel_calls(text, "ssd_fwd") == 10
+    assert _kernel_calls(text, "ssd_states") == 5
+    assert _kernel_calls(text, "ssd_bwd") == 5
+    scan_calls = [
+        (name, op_name) for name, op_name in op_names.items()
+        if name.startswith("ssd_")
+    ]
+    assert len(scan_calls) == 20 and all(
+        "ssm.scan" in re.split(r"[/()]", op_name)
+        for _, op_name in scan_calls
+    )
+    assert sorted(
+        runtime_timer.phase_of(f"%{name} = x", op_name)
+        for name, op_name in scan_calls
+    ) == ["backward"] * 10 + ["forward"] * 5 + ["recompute"] * 5
+    # the starting states live only around the backward kernel, not at
+    # the step's peak: the count of memory is the parent's (14.54 GB)
+    assert need < 14.65e9, need
     # the trunk's attention layer and the module's keep their kernel's
     # output (PR 42): one forward call each where there were two
     assert counters["attn.output_kept"] == 2
@@ -879,9 +979,53 @@ def test_nemotron_cell_fits_the_chip_with_its_rows_cut(topo):
     assert _kernel_calls(text, "flash_bwd_dq") == 2
     assert "[65536,2688]" in text and "[65536,1024]" in text
     assert "[180224,2688]" not in text
-    # no score or decay block of all 128 heads at once
-    assert "[1,64,8,16,128,128]" not in text
-    assert "[1,64,1,16,128,128]" in text
+    # no score or decay block in memory, of all 128 heads at once or of
+    # a block of 16 (the XLA body's), at either chunk
+    assert not re.search(r"\[1,(?:64|32),\d+,16,(?:128,128|256,256)\]", text)
+
+
+def _equations(jaxpr):
+    """Equations of a jaxpr with those of the jaxprs its equations hold."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _equations(sub)
+    return n
+
+
+# Equations of a kernel's body traced at Nemotron-3's widths (TURN 4;
+# the state pass a group whole), with a tenth of room. PR 50's form
+# reads 332 / 288 / 717, each traced once a process, and costs the
+# chip's host +0.91 s of the step's trace and lowering over the parent's
+# 3.90 s (my chip runs, PR 50: that host traces a thousand kernel
+# equations in about a third of a second); PR 49's bodies were 651 / 393
+# / 1,696, the forward's traced twice, behind a nested ``jit`` that cost
+# 1.6 s by itself: +4.3 s in the driver's runs, and the PR refused
+SCAN_BODY_BUDGET = {"ssd_fwd": 365, "ssd_states": 317, "ssd_bwd": 789}
+
+
+def test_scan_kernels_stay_inside_their_build_budget():
+    """A kernel's body is traced and lowered to Mosaic in every process
+    before anything runs, warm or cold, and the seconds go by the
+    equations (``ops/pallas_ssd.py``'s docstring): a body that grows
+    past its budget fails here, not in the benchmark's ``setup_s``."""
+    build, _ = CASES["ssd-bwd-128x64-8x128"]
+    fn, args = build(jax.ShapeDtypeStruct)
+    found = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found[eqn.params["name"]] = _equations(eqn.params["jaxpr"])
+            else:
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    assert set(found) == set(SCAN_BODY_BUDGET)
+    for name, budget in SCAN_BODY_BUDGET.items():
+        assert 0.5 * budget < found[name] <= budget, (name, found[name])
 
 
 def test_keye_cell_compiles_at_its_depth(topo):
