@@ -297,8 +297,7 @@ def search_strategy(
     if not feasible:
         # nothing fits: force max sharding + full remat + bf16 params
         # + host-offloaded moments (the one offload strategy method;
-        # activation offload is the remat='offload_attn' policy, not
-        # taken here — full remat is the lower device-memory bound)
+        # full remat is the lower device-memory bound)
         strat = [
             ("half", {}),
             ("mixed_parallel", {"dp": 1, "fsdp": n_devices, "tp": 1, "sp": 1}),

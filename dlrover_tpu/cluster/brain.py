@@ -423,35 +423,20 @@ def _algo_hot_ps(svc: BrainService, stats: Dict) -> ResourcePlan:
 # Auto-tuner: cold-start planning + live refinement (ROADMAP item 2).
 #
 # This module must stay importable on a bare host (no jax): the memory
-# model is a small local replica of analyser.py's formulas, calibrated
-# against the flagship shape (llama-1.4b b1×s8192 → save_qkv on a 16 GB
-# chip), instead of an import of jax-heavy modules; the bandwidth and
-# bucket model below is the repository's only one.
+# model is a small local replica of analyser.py's formulas, instead of
+# an import of jax-heavy modules; the bandwidth and bucket model below
+# is the repository's only one.
 # ---------------------------------------------------------------------------
 
-# cheapest-first remat ladder: each step trades more recompute for a
-# smaller residual set (models/config.py remat docstring); the OOM
-# ladder in BrainTuner descends it left→right.
-REMAT_LADDER = (
-    "none",
-    "save_dots",
-    "save_qkv_gate",
-    "save_qkv",
-    "save_attn",
-    "full",
-)
+# cheapest-first remat ladder (models/config.py's two policies): the
+# planner takes the first that fits, the OOM ladder in BrainTuner
+# descends it left→right.
+REMAT_LADDER = ("none", "full")
 # activation bytes ≈ tokens × d_model × 2 (bf16) × n_layer × scale:
 # the per-layer residual multiple each policy keeps live. "none" keeps
 # the full ×12 working set (analyser.py's non-remat multiple); "full"
 # keeps one boundary tensor per layer.
-_ACT_SCALE = {
-    "none": 12.0,
-    "save_dots": 8.0,
-    "save_qkv_gate": 5.0,
-    "save_qkv": 3.0,
-    "save_attn": 2.0,
-    "full": 1.0,
-}
+_ACT_SCALE = {"none": 12.0, "full": 1.0}
 # analyser.py's tables, replicated so the planner stays jax-free
 _OPT_SLOTS = {"adamw": 2, "adam": 2, "agd": 3, "sgd": 1, "lion": 1}
 _DTYPE_BYTES = {"float32": 4, "bfloat16": 2, "float16": 2, "int8": 1}

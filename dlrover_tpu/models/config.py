@@ -49,11 +49,6 @@ class ModelConfig:
     # (less grid overhead); _fit_block caps them to the actual sequence.
     attn_block_q: int = 1024
     attn_block_k: int = 1024
-    # narrow-head packing in the flash kernels (0 = auto: when head_dim
-    # < 128 and the layout is MHA, 128 // head_dim heads share a 128-lane
-    # slab of the projections' [B, S, H·D] arrays — gpt2-family d=64: two
-    # heads a program, no relayout around the kernels; 1 disables)
-    attn_head_pack: int = 0
     rope_theta: float = 10000.0
     # RMS/LayerNorm (cfg.norm) over the WHOLE q and k projections
     # (n_head·head_dim / kv_heads·head_dim wide, one scale each), before
@@ -90,29 +85,15 @@ class ModelConfig:
     # numerics
     dtype: str = "bfloat16"          # activation/compute dtype
     param_dtype: str = "float32"
-    # rematerialisation policy:
-    # none | full | dots_saveable | save_attn | save_qkv |
-    # save_qkv_gate | save_dots | offload_attn | save_qkv_offload
-    # (full = the layer recomputed but what is quadratic to remake and
-    # linear to hold: a selecting model's selection and alignment
-    # derivative, and — where the attention runs the flash kernels over
-    # a mean span of 2,048 keys or more, decoder.keeps_attention_output
-    # — the kernel's output and row statistics, 2·D + 4 bytes a (query,
-    # head); save_qkv/save_qkv_gate/save_dots = save_attn plus the qkv /
-    # qkv+gate / qkv+gate+up matmul outputs — graded memory/recompute
-    # tradeoffs between full and dots_saveable; offload_attn =
-    # save_attn with residuals in pinned host memory — reference:
-    # atorch selective_offloading_checkpoint.py; save_qkv_offload =
-    # save_qkv's residual set offloaded the same way, for models whose
-    # pinned save_qkv residuals OOM the chip but full remat's ~30%
-    # backward recompute is too slow — e.g. gpt2-1.5b's tied 50k-vocab
-    # embedding)
+    # rematerialisation policy: none | full. none keeps every
+    # intermediate of the forward; full recomputes the layer in the
+    # backward but what is quadratic to remake and linear to hold: a
+    # selecting model's selection and alignment derivative, and — where
+    # the attention runs the flash kernels over a mean span of 2,048
+    # keys or more, decoder.keeps_attention_output — the kernel's output
+    # and row statistics, 2·D + 4 bytes a (query, head). What is kept is
+    # read from the shape; there is no tier to name
     remat: str = "none"
-    # dtype the NAMED remat residuals are stored in (None = compute
-    # dtype). "bfloat16" halves pinned/offloaded residual bytes; the
-    # values re-enter backward matmuls that run in bf16 anyway, so the
-    # precision loss is confined to the storage round-trip.
-    remat_dtype: Optional[str] = None
     # MoE (0 = dense)
     n_experts: int = 0
     expert_top_k: int = 2
@@ -403,24 +384,15 @@ class ModelConfig:
                 "one multi-token-prediction module is built; "
                 f"n_mtp_module={self.n_mtp_module}"
             )
-        if self.remat not in (
-            "none", "full", "dots_saveable", "save_attn", "save_qkv",
-            "save_qkv_gate", "save_dots", "offload_attn",
-            "save_qkv_offload",
-        ):
+        if self.remat not in ("none", "full"):
             # a typo'd policy would silently train with NO remat and
             # OOM configs that only fit WITH one — fail at build time
-            raise ValueError(f"unknown remat policy {self.remat!r}")
-        if self.remat_dtype is not None and self.remat_dtype not in (
-            "bfloat16", "float32",
-        ):
             raise ValueError(
-                f"remat_dtype must be None, 'bfloat16' or 'float32', "
-                f"got {self.remat_dtype!r}"
-            )
-        if self.attn_head_pack < 0:
-            raise ValueError(
-                f"attn_head_pack must be >= 0, got {self.attn_head_pack}"
+                f"unknown remat policy {self.remat!r}: remat is 'none' "
+                "or 'full' (the graded and offloaded tiers — "
+                "dots_saveable, save_attn, save_qkv, save_qkv_gate, "
+                "save_dots, offload_attn, save_qkv_offload — went in "
+                "PR 51; 'full' keeps by shape what they kept by name)"
             )
         for name in ("attn_block_q", "attn_block_k"):
             b = getattr(self, name)

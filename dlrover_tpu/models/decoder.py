@@ -30,6 +30,7 @@ from jax.sharding import PartitionSpec as P
 
 from dlrover_tpu.common import device
 from dlrover_tpu.models.config import ModelConfig
+from dlrover_tpu.observability.tracing import set_counter
 from dlrover_tpu.ops import pallas_norm, pallas_paged, quant
 from dlrover_tpu.ops.attention import _repeat_kv, mha_reference
 from dlrover_tpu.parallel import sharding as shd
@@ -641,15 +642,6 @@ def _project_qkv(
         # so that it fuses with rope's pass over the same values
         q = _norm(q, attn["q_norm"]["scale"], None, "rmsnorm", cfg.norm_eps)
         k = _norm(k, attn["k_norm"]["scale"], None, "rmsnorm", cfg.norm_eps)
-    # names for the selective remat policies (save_qkv / save_dots):
-    # identity outside jax.checkpoint, so the cache paths are
-    # unaffected. Tagged BEFORE rope: backward re-runs only the cheap
-    # trig mix, never the projections — and the tag stays off the
-    # attention input, whose `name` barrier XLA:CPU's thunk runtime
-    # answers with an unsupported BF16xBF16=F32 DotThunk.
-    q = _tag_residual(q, "q_proj", cfg)
-    k = _tag_residual(k, "k_proj", cfg)
-    v = _tag_residual(v, "v_proj", cfg)
     if cfg.pos == "rope" and rope is not False:
         if rope is None:
             rope = _rope_tables(positions, hd, cfg.rope_theta)
@@ -707,9 +699,6 @@ def _latent_qkv(x, attn, cfg: ModelConfig, positions, rope=None):
         w_kv = attn["wkv_b"].astype(dt).reshape(rkv, nh, nope + vd)
         k_nope = jnp.einsum("bsr,rhc->bshc", c_kv, w_kv[..., :nope])
         v = jnp.einsum("bsr,rhc->bshc", c_kv, w_kv[..., nope:])
-        q = _tag_residual(q, "q_proj", cfg)
-        k_nope = _tag_residual(k_nope, "k_proj", cfg)
-        v = _tag_residual(v, "v_proj", cfg)
         if rope is None:
             rope = _rope_tables(positions, rd, cfg.rope_theta)
         q = jnp.concatenate(
@@ -961,9 +950,7 @@ def _alignment_kl(qi, ki, w, q, k, lse, mask, cfg, scale, tiles=None):
     (``_remat_body``), so the forward runs the rule once, value and
     derivative, and the recomputed forward scores no pair for this term
     again; under ``remat: none`` nothing is recomputed and the tag is
-    inert; every other tier's policy keeps its own names and not this
-    one, so the rule runs again in the recomputation, as the selection
-    does (``alignment_passes``)."""
+    inert. Either way the rule runs once a layer a step."""
     return sum(
         _chunk_kl(*args)
         for args, _ in _alignment_chunks((qi, ki, w, q, k, lse, mask), cfg, scale)
@@ -1048,34 +1035,19 @@ def _selecting_attention_block(
         _select(*jax.lax.stop_gradient((qi, ki, w)), cfg), "attn_selected"
     )
     out, lse, in_kernel = attn_fn(q, k, v, selected=mask)
+    tiles = alignment_tiles(cfg, s) if in_kernel else None
+    set_counter("attn.align_in_kernel", int(tiles is not None))
     with jax.named_scope("attn.index_loss"):
         kl = _alignment_kl(
             qi, ki, w,
             *jax.lax.stop_gradient((q, k, lse)), mask, cfg, hd ** -0.5,
-            alignment_tiles(cfg, s) if in_kernel else None,
+            tiles,
         ) / (b * s)
     aux = {"indexer_loss": kl}
     if return_selected:
         aux["attn_selected"] = mask != 0
     out = out.reshape(b, s, nh * hd)
     return out @ layer["attn"]["wo"].astype(x.dtype), aux
-
-
-def _tag_residual(x, name, cfg: ModelConfig):
-    """``checkpoint_name`` with the optional ``cfg.remat_dtype`` cast.
-
-    When set, the tagged (= saved/offloaded) tensor is the narrow cast
-    and BOTH passes compute from the round-tripped value, so forward
-    and backward see identical numerics; identity outside
-    ``jax.checkpoint``, where nothing is saved and the cast would only
-    lose precision."""
-    rd = cfg.remat_dtype
-    if rd is None or cfg.remat in ("none", "full") or x.dtype == rd:
-        return jax.ad_checkpoint.checkpoint_name(x, name)
-    wide = x.dtype
-    return jax.ad_checkpoint.checkpoint_name(
-        x.astype(rd), name
-    ).astype(wide)
 
 
 def _mlp_block(x, layer, cfg: ModelConfig, mesh, fp8=None):
@@ -1100,12 +1072,9 @@ def _mlp_block(x, layer, cfg: ModelConfig, mesh, fp8=None):
     if cfg.act == "swiglu":
         gate = x @ mlp["w_gate"].astype(x.dtype)
         up = x @ mlp["w_up"].astype(x.dtype)
-        gate = _tag_residual(gate, "mlp_gate", cfg)
-        up = _tag_residual(up, "mlp_up", cfg)
         h = jax.nn.silu(gate) * up
     else:
         h = jax.nn.gelu(x @ mlp["w_up"].astype(x.dtype))
-        h = _tag_residual(h, "mlp_up", cfg)
     if mesh is not None:
         h = shd.constrain(h, mesh, "batch", "seq", "mlp")
     return h @ mlp["w_down"].astype(x.dtype)
@@ -1129,7 +1098,6 @@ def _layer_body(
     mesh,
     attn_fn,
     rng=None,
-    tag_attn_out: bool = False,
     fp8=None,
     rope=None,
     return_selected: bool = False,
@@ -1154,11 +1122,6 @@ def _layer_body(
             attn = _attention_block(
                 h, layer, cfg, mesh, positions, attn_fn, fp8=fp8, rope=rope
             )
-        if tag_attn_out:
-            # non-flash attention tags no flash_out/flash_lse, so
-            # save_attn would otherwise pin nothing and recompute O(S²)
-            # attention
-            attn = _tag_residual(attn, "attn_out", cfg)
         if cfg.post_norm:
             attn = _norm_block(attn, layer["ln1_post"], cfg)
     aux = {
@@ -1244,7 +1207,7 @@ def _mamba_block(h, ssm, cfg: ModelConfig, mesh):
 
 def _part_body(
     x, layer, positions, *, letter, cfg: ModelConfig, mesh, attn_fn,
-    rng=None, tag_attn_out: bool = False, rope=None,
+    rng=None, rope=None,
 ):
     """One layer of a ``layer_pattern`` model, ``x + part(norm(x))``:
     a Mamba-2 mixer (``M``), an attention (``*``) or the routed experts
@@ -1259,8 +1222,6 @@ def _part_body(
             out = _attention_block(
                 h, layer, cfg, mesh, positions, attn_fn, rope=rope
             )
-            if tag_attn_out:
-                out = _tag_residual(out, "attn_out", cfg)
         else:
             from dlrover_tpu.parallel.moe import moe_block
 
@@ -1275,7 +1236,7 @@ def _part_body(
 
 def _run_pattern(
     x, layers, pattern: str, positions, cfg: ModelConfig, mesh, attn_fn,
-    rng, tag_attn_out, first: int = 0, keep_attn: bool = False,
+    rng, first: int = 0, keep_attn: bool = False,
 ):
     """The layers ``pattern`` names, in its order, each taken as the
     next of its kind's stack in ``layers`` (``_init_pattern``) and run
@@ -1288,7 +1249,7 @@ def _run_pattern(
         letter: _remat(
             functools.partial(
                 _part_body, letter=letter, cfg=cfg, mesh=mesh,
-                attn_fn=attn_fn, tag_attn_out=tag_attn_out,
+                attn_fn=attn_fn,
             ),
             cfg, keep_attn,
         )
@@ -1319,19 +1280,7 @@ def _run_pattern(
     }
 
 
-def _offload_names_policy(*names):
-    """Checkpoint policy saving ``names`` to pinned host memory;
-    everything unnamed is recomputed in backward, exactly like
-    ``save_only_these_names(*names)`` — only the residency differs."""
-    return cp.save_and_offload_only_these_names(
-        names_which_can_be_saved=[],
-        names_which_can_be_offloaded=list(names),
-        offload_src="device",
-        offload_dst="pinned_host",
-    )
-
-
-def _remat_body(cfg: ModelConfig, mesh, attn_fn, tag_attn_out, fp8_layers,
+def _remat_body(cfg: ModelConfig, mesh, attn_fn, fp8_layers,
                 return_selected: bool = False, keep_attn: bool = False,
                 kind: str = ""):
     """``_layer_body`` bound to the model (and to one ``kind`` of
@@ -1343,7 +1292,6 @@ def _remat_body(cfg: ModelConfig, mesh, attn_fn, tag_attn_out, fp8_layers,
         cfg=cfg,
         mesh=mesh,
         attn_fn=attn_fn,
-        tag_attn_out=tag_attn_out,
         return_selected=return_selected,
         kind=kind,
         # the "current" sentinel must be BAKED into the partial, not
@@ -1354,80 +1302,32 @@ def _remat_body(cfg: ModelConfig, mesh, attn_fn, tag_attn_out, fp8_layers,
     return _remat(body, cfg, keep_attn)
 
 
-# the attention's results: on the flash path the kernel's custom_vjp
-# residuals (flash_out, and flash_lse: the row statistics as numbers,
-# [B, H, S] float32); on the reference path the tagged block output
-# (attn_out) — never both
-_ATTN_NAMES = ("attn_out", "flash_out", "flash_lse")
-_QKV_NAMES = ("q_proj", "k_proj", "v_proj")
-# remat tier -> (the named residuals its policy keeps; everything
-# unnamed is recomputed in the backward, whether they live in pinned
-# host memory)
-_REMAT_TIERS = {
-    # backward recomputes only the cheap MLP/norm/projection math
-    "save_attn": (_ATTN_NAMES, False),
-    # save_attn PLUS the post-rope q/k/v projections: backward skips
-    # the attention kernel re-run AND the qkv matmuls (~30% of the
-    # full-remat recompute flops) for ~130 MB/layer at b8·s1024 — the
-    # policy the fused-CE memory savings (ops/fused_ce.py) buy
-    "save_qkv": (_ATTN_NAMES + _QKV_NAMES, False),
-    # save_qkv plus ONE of the two swiglu projections: ~half the extra
-    # footprint of save_dots for half its recompute savings — the
-    # largest policy that still fits 1.4B training on a 16 GiB chip
-    "save_qkv_gate": (_ATTN_NAMES + _QKV_NAMES + ("mlp_gate",), False),
-    # save_qkv plus the swiglu gate/up projections: backward recomputes
-    # only norms/elementwise + the o/down matmuls — ~70% of the
-    # recompute flops gone for ~300 MB/layer
-    "save_dots": (
-        _ATTN_NAMES + _QKV_NAMES + ("mlp_gate", "mlp_up"), False,
-    ),
-    # like save_attn, but the pinned residuals live in pinned host
-    # memory instead of HBM (reference: atorch's selective offloading
-    # checkpoint, auto/opt_lib/selective_offloading_checkpoint.py) —
-    # activation memory ~frees the O(L·B·S·D) attention outputs at the
-    # cost of host DMA traffic in backward
-    "offload_attn": (_ATTN_NAMES, True),
-    # save_qkv's residual set, offloaded like offload_attn: for models
-    # whose pinned save_qkv residuals don't fit HBM (the gpt2-1.5b tied
-    # 50k-vocab embedding leaves no headroom on a 16 GiB chip) but full
-    # remat's ~30% recompute is too slow. Backward pays host DMA instead
-    # of matmul+kernel re-runs; the DMA overlaps the MLP recompute it
-    # replaced.
-    "save_qkv_offload": (_ATTN_NAMES + _QKV_NAMES, True),
-}
-
-
 def _kept_names(cfg: ModelConfig, keep_attn: bool):
-    """(the named residuals ``cfg.remat``'s policy keeps, offloaded?).
-    ``full`` recomputes the layer but what is quadratic to remake and
-    linear to hold: a selecting model's selection (int8 [B, S, S] a
-    layer, against scoring and cutting every query's keys again) and its
-    alignment term's derivative (qi's, ki's and w's shapes, against
-    scoring every attention pair again), and, where ``keep_attn``
+    """The named residuals ``remat: full`` keeps. It recomputes the
+    layer but what is quadratic to remake and linear to hold: a
+    selecting model's selection (int8 [B, S, S] a layer, against scoring
+    and cutting every query's keys again) and its alignment term's
+    derivative (qi's, ki's and w's shapes, against scoring every
+    attention pair again), and, where ``keep_attn``
     (``keeps_attention_output``), the flash kernel's output and row
-    statistics."""
-    if cfg.remat != "full":
-        return _REMAT_TIERS.get(cfg.remat, ((), False))
+    statistics (``flash_lse`` as numbers, [B, H, S] float32)."""
     names = ()
     if cfg.selects_keys:
         names += ("attn_selected", "attn_align_grad")
     if keep_attn:
         names += ("flash_out", "flash_lse")
-    return names, False
+    return names
 
 
 def _remat(body, cfg: ModelConfig, keep_attn: bool = False):
     """``body`` (a layer: ``_layer_body`` or ``_part_body``, bound to
     its model) under the configured rematerialisation policy."""
-    names, offload = _kept_names(cfg, keep_attn)
+    if cfg.remat != "full":
+        return body
+    names = _kept_names(cfg, keep_attn)
     if names:
-        policy = _offload_names_policy if offload else cp.save_only_these_names
-        return jax.checkpoint(body, policy=policy(*names))
-    if cfg.remat == "full":
-        return jax.checkpoint(body)
-    if cfg.remat == "dots_saveable":
-        return jax.checkpoint(body, policy=cp.dots_saveable)
-    return body
+        return jax.checkpoint(body, policy=cp.save_only_these_names(*names))
+    return jax.checkpoint(body)
 
 
 # the executed keys a query from which ``full`` keeps the flash kernel's
@@ -1511,36 +1411,6 @@ def alignment_tiles(cfg: ModelConfig, s: int):
     return None if tiles is None else (chunk, *tiles)
 
 
-def alignment_in_kernel(cfg: ModelConfig, s: int, attn_impl: str = "auto",
-                        mesh=None) -> bool:
-    """Whether a step at sequence length ``s`` takes the alignment term
-    through the kernel: what ``_selecting_attention_block`` decides."""
-    return (
-        _resolve_attn_impl(attn_impl, mesh) == "flash"
-        and alignment_tiles(cfg, s) is not None
-    )
-
-
-def scan_in_kernel(cfg: ModelConfig, s: int, mesh=None) -> bool:
-    """Whether a step at sequence length ``s`` takes its Mamba-2 layers'
-    scan through the Pallas kernels (``ops/pallas_ssd.py``) and not the
-    XLA body: what ``ssd.ssd_scan`` decides from the same numbers."""
-    from dlrover_tpu.ops import ssd
-
-    return ssd.kernel_chunk(
-        s, cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.n_groups,
-        cfg.ssm_state_size, cfg.ssm_chunk, mesh,
-    ) is not None
-
-
-def alignment_passes(cfg: ModelConfig) -> int:
-    """How often a training step runs ``_alignment_kl``'s chunked pass
-    a layer: once where its derivative is a kept residual (``full``) or
-    nothing is recomputed (``none``), twice under the other tiers,
-    whose names are not its."""
-    return 1 if cfg.remat in ("none", "full") else 2
-
-
 def _train_only_guard(cfg: ModelConfig, fn: str):
     """The cache, paged, pipeline and generate paths scan ONE stack of
     plain-attention layers; a model they would run wrongly is refused
@@ -1560,7 +1430,6 @@ def run_trunk(
     mesh=None,
     attn_fn=None,
     rng: Optional[jax.Array] = None,
-    tag_attn_out: bool = False,
     fp8_layers=None,
     dense_layers: Optional[Params] = None,
     return_selected: bool = False,
@@ -1601,7 +1470,7 @@ def run_trunk(
             _train_only_guard(cfg, "the pipeline")
         x, aux = _run_pattern(
             x, layers, cfg.layer_pattern, positions, cfg, mesh, attn_fn,
-            rng, tag_attn_out, keep_attn="" in keep_attn,
+            rng, keep_attn="" in keep_attn,
         )
         zero = jnp.zeros([], jnp.float32)
         return x, {"moe_lb_loss": zero, "moe_z_loss": zero, **aux}
@@ -1609,7 +1478,7 @@ def run_trunk(
     # kind has the one, ``_run_periods`` picks by kind
     bodies = {
         kind: _remat_body(
-            cfg, mesh, attn_fn, tag_attn_out, fp8_layers, return_selected,
+            cfg, mesh, attn_fn, fp8_layers, return_selected,
             kind in keep_attn, kind,
         )
         for kind in attention_kinds(cfg)
@@ -1921,19 +1790,24 @@ def forward(
         )
 
     # the kinds of layer whose remat policy keeps the kernel's output,
-    # and those whose policy lists its statistics
-    kinds = attention_kinds(cfg)
+    # and with it its statistics as numbers (``lse_rows``)
     keep_attn = tuple(
-        kind for kind in kinds
+        kind for kind in attention_kinds(cfg)
         if keeps_attention_output(cfg, s, attn_impl, mesh, kind)
     )
-    lse_kinds = {
-        kind for kind in kinds
-        if "flash_lse" in _kept_names(cfg, kind in keep_attn)[0]
-    }
+    # which path the program took. Trace time, values: a retrace, or the
+    # forward-only program of the same configuration, sets the same
+    # numbers. How many layers' attention output is kept (the prediction
+    # module's among them), and how many layers of each kind there are
+    set_counter(
+        "attn.output_kept", kept_attention_layers(cfg, s, attn_impl, mesh)
+    )
+    if cfg.layer_types:
+        set_counter("attn.window_layers", cfg.layer_types.count("S"))
+        set_counter("attn.full_layers", cfg.layer_types.count("F"))
 
     def attn_fn(q, k, v, selected=None, kind=""):
-        lse_rows = kind in lse_kinds
+        lse_rows = kind in keep_attn
         window = cfg.kind_window(kind)
         if selected is not None:
             # (out, lse [B, H, S] detached, whether the Pallas kernels
@@ -1987,7 +1861,6 @@ def forward(
                     causal=cfg.causal,
                     block_q=cfg.attn_block_q,
                     block_k=cfg.attn_block_k,
-                    head_pack=cfg.attn_head_pack,
                     lse_rows=lse_rows,
                 ),
                 prefix_len=prefix_len,
@@ -2009,7 +1882,6 @@ def forward(
             block_k=cfg.attn_block_k,
             prefix_len=prefix_len,
             window=window,
-            head_pack=cfg.attn_head_pack,
             lse_rows=lse_rows,
         )
 
@@ -2021,7 +1893,6 @@ def forward(
         mesh=mesh,
         attn_fn=attn_fn,
         rng=rng,
-        tag_attn_out=(attn_impl != "flash"),
         fp8_layers=fp8_states,
         dense_layers=params.get("dense_layers"),
         return_selected=cfg.selects_keys and (
@@ -2032,8 +1903,8 @@ def forward(
     if cfg.n_mtp_module and return_aux:
         # the module reads the trunk's output BEFORE the final norm
         aux = _mtp_module(
-            params, x, tokens, positions, cfg, mesh, attn_fn, rng,
-            attn_impl != "flash", aux, "" in keep_attn,
+            params, x, tokens, positions, cfg, mesh, attn_fn, rng, aux,
+            "" in keep_attn,
         )
 
     with jax.named_scope("head_loss"):
@@ -2059,7 +1930,7 @@ def next_tokens(tokens: jax.Array) -> jax.Array:
 
 def _mtp_module(
     params, h, tokens, positions, cfg: ModelConfig, mesh, attn_fn, rng,
-    tag_attn_out, aux, keep_attn: bool = False,
+    aux, keep_attn: bool = False,
 ):
     """The multi-token-prediction module (DeepSeek-V3 §2.2; the layout
     of ``glm4_moe_lite``'s ``num_nextn_predict_layers`` weights):
@@ -2088,13 +1959,10 @@ def _mtp_module(
         if cfg.layer_pattern:
             z, block_aux = _run_pattern(
                 z, m["block"], cfg.mtp_pattern, positions, cfg, mesh,
-                attn_fn, rng, tag_attn_out, first=cfg.n_layer,
-                keep_attn=keep_attn,
+                attn_fn, rng, first=cfg.n_layer, keep_attn=keep_attn,
             )
         else:
-            body = _remat_body(
-                cfg, mesh, attn_fn, tag_attn_out, None, keep_attn=keep_attn
-            )
+            body = _remat_body(cfg, mesh, attn_fn, None, keep_attn=keep_attn)
             rope = (
                 _rope_tables(positions, cfg.rope_dim, cfg.rope_theta)
                 if cfg.pos == "rope"

@@ -218,7 +218,6 @@ def forward_vit(
         t,
         mesh=mesh,
         attn_fn=attn_fn,
-        tag_attn_out=(attn_impl != "flash"),
     )
     fn = params["final_norm"]
     x = decoder._norm(x, fn["scale"], fn.get("bias"), t.norm)

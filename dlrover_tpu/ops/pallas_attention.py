@@ -62,12 +62,12 @@ tile array is 128 lanes wide in memory (54 MB a layer at GPT-2 XL for
 0.85 MB of numbers; 134 MB for 1 MB at 32 heads x 8192 tokens). So what
 a remat policy KEEPS is not the tiles: a caller whose policy lists
 ``flash_out`` and ``flash_lse`` says so (``lse_rows``; the decoder's
-``save_attn`` tiers, and ``full`` at long spans,
-``decoder.keeps_attention_output``), and ``flash_lse`` then names the
-``[B, H, S]`` numbers; the backward rule pads them into tiles again for
-its kernels (one read and one write of the tile array a layer, against
-running the forward kernel a second time). Where no policy keeps them
-the residual is the tile array and the step is as above.
+``full`` at long spans, ``decoder.keeps_attention_output``), and
+``flash_lse`` then names the ``[B, H, S]`` numbers; the backward rule
+pads them into tiles again for its kernels (one read and one write of
+the tile array a layer, against running the forward kernel a second
+time). Where no policy keeps them the residual is the tile array and the
+step is as above.
 
 Both paths support GLM-style prefix-LM masking (per-batch prefix scalar in
 SMEM) and GQA (K/V shared across head groups via BlockSpec index maps, no
@@ -157,9 +157,8 @@ INTERPRET = os.environ.get(
     "DLROVER_TPU_PALLAS_INTERPRET", ""
 ).lower() in ("1", "true", "yes")
 
-# pallas FA2 backward kernels (vs the jnp chunked recompute); tiles
-# capped separately from the forward (see _bwd_rule)
-USE_PALLAS_BWD = True
+# the backward kernels' tiles, capped separately from the forward's
+# (see _bwd_rule)
 BWD_BLOCK = 512        # measured best for head_dim 64 (v5e)
 BWD_BLOCK_WIDE = 1024  # measured best for head_dim 128 (v5e)
 # head_dim >= 256 (latent attention expanded): (q rows, k rows). 1024 x
@@ -1599,7 +1598,7 @@ def _chunked_backward(q, k, v, out, lse, g, causal, scale, chunk,
 def _name_residuals(out, lse, q, head_pack, lse_rows):
     """The forward kernel's two results under the names a remat policy
     lists to keep them and not run the kernel again (the decoder's
-    ``save_attn`` tiers, and ``full`` at long spans): (out, lse as the
+    ``full`` at long spans): (out, lse as the
     backward rule's residual). ``flash_lse`` names the statistics as
     NUMBERS, ``[B, H, S]`` float32, where the caller says a policy
     keeps them (``lse_rows``): 1/128 of the tile array, which the
@@ -1708,8 +1707,7 @@ def _bwd_rule_lse(causal, scale, block_q, block_k, window, head_pack,
         window if _gate_is_static(causal, prefix, offsets) else 0,
     )
     in_kernel = (
-        USE_PALLAS_BWD
-        and pltpu is not None
+        pltpu is not None
         and (device.on_tpu() or INTERPRET)
         and bq is not None
         and bk is not None
@@ -1892,7 +1890,7 @@ def _selected_attention(q, k, v, selected, scale, bq, bk, lse_rows):
     where the kernels run at all, else the jnp reference with the same
     mask. (out, lse [B, H, S] float32, detached)."""
     if pltpu is None or not (device.on_tpu() or INTERPRET) or (
-        bq is None or bk is None or not USE_PALLAS_BWD
+        bq is None or bk is None
     ):
         from dlrover_tpu.ops.attention import mha_reference
 

@@ -517,8 +517,7 @@ def mixed_adamw(
     update) keeps bf16, while the variance — already a smooth, positive
     statistic that Adafactor famously rank-1-factorizes with no loss
     curve change — drops to int8 blocks. At 1.4B params this frees
-    ~2 GiB of HBM versus bf16 nu, which is exactly what buys the
-    ``save_qkv_gate`` remat tier on a 16 GiB chip.
+    ~2 GiB of HBM versus bf16 nu on a 16 GiB chip.
 
     Unlike ``lowbit_adamw``'s chunk-streamed scan (bounded f32 working
     set, built for when BOTH moments are int8/int4 at >=1.5B), this is a

@@ -68,6 +68,7 @@ outputs are cut off again: exact, since the scan is causal.
 import jax
 import jax.numpy as jnp
 
+from dlrover_tpu.observability.tracing import set_counter
 from dlrover_tpu.ops import pallas_ssd
 
 F32 = jnp.float32
@@ -180,6 +181,8 @@ def ssd_scan(x, dt, a, b_mat, c_mat, chunk: int, head_block: int = 0,
         c_mat = jnp.pad(c_mat, widths + ((0, 0), (0, 0)))
     sp = s + pad
     q = kernel_chunk(s, h, p, g, n, chunk, mesh)
+    # which body the program took. Trace time, a value
+    set_counter("ssm.scan_in_kernel", int(q is not None))
     if q is not None:
         with jax.named_scope("ssm.scan"):
             y = _kernel_scan(x, dt, a, b_mat, c_mat, q)

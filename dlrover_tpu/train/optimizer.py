@@ -238,8 +238,8 @@ def factored_adamw(
 
     Why it exists here: on a 16 GiB v5e training 1.4B params, dense nu
     costs 2.7 GiB of HBM and ~5.4 GiB of optimizer bandwidth per step.
-    Factoring frees both — the HBM buys the ``save_qkv_gate`` remat
-    tier (models/decoder.py), the bandwidth shortens the optimizer
+    Factoring frees both — the HBM goes to activations (a larger
+    batch, or ``remat: none``), the bandwidth shortens the optimizer
     phase outright. Reference capability analog: atorch low-bit states
     (low_bit/functional.py) compress nu 4x; factoring compresses it
     ~1000x with a weaker (but battle-tested) estimator.
@@ -861,8 +861,7 @@ def make_optimizer(
     if name == "adamw" and state_dtype in ("mixed8", "mixed4"):
         # bf16 momentum + int8/int4 blockwise variance: frees ~75% of
         # nu's HBM with Adafactor-grade variance fidelity; cheaper per
-        # step than bf16 nu (less optimizer bandwidth). The
-        # save_qkv_gate remat tier exists because of this headroom.
+        # step than bf16 nu (less optimizer bandwidth).
         from dlrover_tpu.ops.quant import mixed_adamw
 
         chain.append(

@@ -1196,29 +1196,6 @@ class TrainStepBuilder:
         return new_state, metrics
 
     def step_fn(self, state: TrainState, batch) -> Tuple[TrainState, Dict]:
-        cfg = self.cfg
-        # which path the step took. Trace time, a value: a retrace sets
-        # the same number again
-        seq = batch["tokens"].shape[-1]
-        # how many layers' attention output the step keeps, and how many
-        # layers of each kind it has
-        set_counter("attn.output_kept", decoder.kept_attention_layers(
-            cfg, seq, self.attn_impl, self.mesh
-        ))
-        if cfg.layer_types:
-            set_counter("attn.window_layers", cfg.layer_types.count("S"))
-            set_counter("attn.full_layers", cfg.layer_types.count("F"))
-        if cfg.selects_keys:
-            set_counter("attn.align_passes", decoder.alignment_passes(cfg))
-            set_counter("attn.align_in_kernel", int(
-                decoder.alignment_in_kernel(
-                    cfg, seq, self.attn_impl, self.mesh
-                )
-            ))
-        if "M" in cfg.layer_pattern + cfg.mtp_pattern:
-            set_counter("ssm.scan_in_kernel", int(
-                decoder.scan_in_kernel(cfg, seq, self.mesh)
-            ))
         if self.update_sharding:
             return self._sharded_step_fn(state, batch)
         batch = jax.tree.map(

@@ -177,58 +177,150 @@ CELL_SPANS = {
 }
 
 
-def test_the_line_falls_between_the_cells():
-    """The predicate over the benchmark's cells as they are run: true
-    for the five whose span is 2,048.5 keys or more, false for the two
-    at 1,024 tokens, and false everywhere on the reference attention
-    (the CPU's ``auto``), under another tier and at a sequence the
-    kernels do not tile."""
+def test_cell_spans_cover_the_benchmark():
+    """``CELL_SPANS`` names the benchmark's workloads, all of them and
+    no other."""
+    assert sorted(name for name, _, _ in _cells()) == sorted(CELL_SPANS)
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_SPANS))
+def test_the_line_falls_between_the_cells(cell):
+    """The predicate over a benchmark cell as it is run: true for the
+    five whose span is 2,048.5 keys or more and for Trinity's full
+    layers, false for the two at 1,024 tokens and Trinity's windows, and
+    false everywhere on the reference attention (the CPU's ``auto``),
+    under ``none`` and at a sequence the kernels do not tile."""
     import dataclasses
 
-    seen = {}
-    for name, program, seq in _cells():
-        cfg = get_config(program["model"], **program["overrides"])
-        assert cfg.remat == "full", name
-        keeps = decoder.keeps_attention_output
-        kinds = decoder.attention_kinds(cfg)
-        by_kind = {
-            kind: (
-                cfg.executed_span(seq, kind),
-                keeps(cfg, seq, "flash", kind=kind),
-            )
-            for kind in kinds
-        }
-        seen[name] = by_kind[""] if kinds == ("",) else by_kind
-        other = dataclasses.replace(cfg, remat="save_attn")
-        for kind in kinds:
-            assert not keeps(cfg, seq, "reference", kind=kind)
-            assert not keeps(cfg, seq, kind=kind)  # auto: CPU
-            assert not keeps(cfg, seq + 64, "flash", kind=kind)
-            assert not keeps(other, seq, "flash", kind=kind)
-    assert seen == CELL_SPANS
-    one_kind = [v for v in seen.values() if isinstance(v, tuple)]
-    assert sum(kept for _, kept in one_kind) == 5
+    (program, seq), = (
+        (program, seq) for name, program, seq in _cells() if name == cell
+    )
+    cfg = get_config(program["model"], **program["overrides"])
+    assert cfg.remat == "full"
+    keeps = decoder.keeps_attention_output
+    kinds = decoder.attention_kinds(cfg)
+    by_kind = {
+        kind: (
+            cfg.executed_span(seq, kind),
+            keeps(cfg, seq, "flash", kind=kind),
+        )
+        for kind in kinds
+    }
+    assert (by_kind[""] if kinds == ("",) else by_kind) == CELL_SPANS[cell]
+    other = dataclasses.replace(cfg, remat="none")
+    for kind in kinds:
+        assert not keeps(cfg, seq, "reference", kind=kind)
+        assert not keeps(cfg, seq, kind=kind)  # auto: CPU
+        assert not keeps(cfg, seq + 64, "flash", kind=kind)
+        assert not keeps(other, seq, "flash", kind=kind)
 
 
 @pytest.mark.parametrize("remat,keep,lse_named", [
     ("none", False, False), ("full", False, False), ("full", True, True),
-    ("dots_saveable", False, False), ("save_attn", False, True),
-    ("save_qkv", False, True), ("save_qkv_gate", False, True),
-    ("save_dots", False, True), ("offload_attn", False, True),
-    ("save_qkv_offload", False, True),
 ])
 def test_which_tiers_name_the_statistics(remat, keep, lse_named):
-    """Every tier that lists ``flash_lse`` tells the kernels' rule to
-    name the numbers; where nothing lists it the residual stays the
-    tile array and the compiled step is what it was."""
+    """``full`` lists ``flash_lse`` exactly where it keeps the kernel's
+    output, and the kernels' rule is then told to name the numbers
+    (``forward``: ``lse_rows=kind in keep_attn``); where nothing lists
+    it the residual stays the tile array and the compiled step is what
+    it was. A selecting model's own two names ride beside them."""
     cfg = get_config("gpt2-1.5b", n_layer=1, remat=remat)
-    names, offload = decoder._kept_names(cfg, keep)
+    names = decoder._kept_names(cfg, keep)
     assert ("flash_lse" in names) == lse_named
     assert ("flash_out" in names) == lse_named
-    assert offload == ("offload" in remat)
     keye = get_config("keye-vl-2.0", n_layer=1, remat=remat)
-    own = {"attn_selected", "attn_align_grad"}
-    assert own <= set(decoder._kept_names(keye, keep)[0]) or remat != "full"
+    assert {"attn_selected", "attn_align_grad"} <= set(
+        decoder._kept_names(keye, keep)
+    )
+
+
+_REMOVED_TIERS = (
+    "dots_saveable", "save_attn", "save_qkv", "save_qkv_gate", "save_dots",
+    "offload_attn", "save_qkv_offload",
+)
+
+
+@pytest.mark.parametrize("tier", _REMOVED_TIERS)
+def test_removed_remat_tiers_are_refused(tier):
+    """The graded and offloaded tiers went in PR 51: no alias and no
+    fall-back to ``full``; the error names the two policies there are."""
+    with pytest.raises(ValueError, match=tier) as refused:
+        get_config("tiny", remat=tier)
+    assert "'none'" in str(refused.value) and "'full'" in str(refused.value)
+
+
+def test_the_step_tags_the_four_kept_names_and_no_other(monkeypatch):
+    """Every ``checkpoint_name`` in the gradient program of a selecting
+    (Keye-shaped) and a windowed (Mistral-shaped) toy model is one that
+    ``full`` may keep: the kernels' two, and a selecting model's two."""
+    monkeypatch.setattr(pallas_attention, "INTERPRET", True)
+    monkeypatch.setattr(decoder, "KEEP_ATTN_SPAN", 1)
+    tokens = jax.ShapeDtypeStruct((2, 256), jnp.int32)
+    tagged = {}
+    for case in ("selected", "windowed"):
+        model, over, _ = LAYERS[case]
+        cfg = get_config(model, **{**SMALL, **over})
+        params = jax.eval_shape(lambda: decoder.init(jax.random.key(0), cfg))
+        text = str(jax.make_jaxpr(jax.grad(
+            lambda p, t: decoder.loss_fn(
+                p, {"tokens": t, "targets": t}, cfg, attn_impl="flash"
+            )[0]
+        ))(params, tokens))
+        tagged[case] = set(re.findall(r"\bname\[name=(\w+)\]", text))
+    kernels = {"flash_out", "flash_lse"}
+    assert tagged == {
+        "selected": kernels | {"attn_selected", "attn_align_grad"},
+        "windowed": kernels,
+    }
+
+
+def _forward_counters(cfg, seq=256, **kw):
+    """The counters ``decoder.forward`` sets while it is traced alone:
+    no train step around it."""
+    from dlrover_tpu.observability import tracing
+
+    params = jax.eval_shape(lambda: decoder.init(jax.random.key(0), cfg))
+    tracing._counters.clear()
+    jax.eval_shape(
+        lambda p, t: decoder.forward(p, t, cfg, **kw),
+        params, jax.ShapeDtypeStruct((2, seq), jnp.int32),
+    )
+    return tracing.counters()
+
+
+def test_a_path_is_reported_by_the_code_that_takes_it(monkeypatch):
+    """``attn.align_in_kernel`` and ``ssm.scan_in_kernel`` are set by
+    the block that chooses, so ``decoder.forward`` traced alone carries
+    them for a model with the mechanism, and a model with neither
+    carries neither; the layers kept are counted by ``forward`` for
+    every model."""
+    monkeypatch.setattr(pallas_attention, "INTERPRET", True)
+    model, over, _ = LAYERS["windowed"]
+    plain = _forward_counters(
+        get_config(model, **{**SMALL, **over}), attn_impl="flash"
+    )
+    assert "attn.align_in_kernel" not in plain
+    assert "ssm.scan_in_kernel" not in plain
+    assert plain["attn.output_kept"] == 0  # a span of 96 keys
+
+    model, over, _ = LAYERS["selected"]
+    selecting = _forward_counters(
+        get_config(model, **{**SMALL, **over}), attn_impl="flash"
+    )
+    assert selecting["attn.align_in_kernel"] == 1
+    assert "ssm.scan_in_kernel" not in selecting
+
+    mixer = get_config(
+        "nemotron-3-super", n_layer=2, layer_pattern="ME", d_model=64,
+        n_head=4, n_kv_head=2, d_head=16, vocab_size=256, max_seq=256,
+        mamba_num_heads=4, mamba_head_dim=64, ssm_state_size=128,
+        n_groups=2, ssm_chunk=128, n_experts=16, expert_top_k=6,
+        d_expert=48, moe_latent_size=32, d_shared_expert=96,
+        n_experts_held=4, expert_offset=0, remat="full", dtype="float32",
+    )
+    scanning = _forward_counters(mixer)
+    assert scanning["ssm.scan_in_kernel"] == 1
+    assert "attn.align_in_kernel" not in scanning
 
 
 def test_analyser_counts_the_kept_attention_output(monkeypatch):

@@ -20,6 +20,7 @@ import pytest
 from benchmarks.lib import flops, selected
 from benchmarks.references import keye_vl2_plain as plain
 from dlrover_tpu.models import decoder, generate, get_config
+from dlrover_tpu.observability import tracing
 from dlrover_tpu.ops import pallas_attention
 from dlrover_tpu.ops.attention import mha_reference
 from dlrover_tpu.parallel import moe
@@ -235,6 +236,7 @@ def test_alignment_term_is_made_once_a_step(monkeypatch, remat, path):
     cfg = _cfg(remat=remat, n_layer=3, **over)
     params = decoder.init(jax.random.key(0), cfg)
     batch = _batch(cfg, seq=seq)
+    tracing._counters.clear()
     text = str(jax.make_jaxpr(
         jax.grad(lambda p: decoder.loss_fn(p, batch, cfg, **kw)[0])
     )(params))
@@ -255,9 +257,20 @@ def test_alignment_term_is_made_once_a_step(monkeypatch, remat, path):
         assert sorted(kernels) == ["align_kl"] + ["flash_fwd_sel"] * (
             2 if remat == "full" else 1
         )
-    assert decoder.alignment_in_kernel(cfg, seq, **kw) == (path == "kernel")
-    assert decoder.alignment_passes(cfg) == 1
-    assert decoder.alignment_passes(_cfg(remat="save_attn")) == 2
+    # said by ``_selecting_attention_block`` as it chose
+    assert tracing.counters()["attn.align_in_kernel"] == (path == "kernel")
+
+
+def _traced_counters(cfg, seq, **kw):
+    """The counters ``decoder.forward`` sets while it is traced, alone:
+    no train step around it."""
+    params = jax.eval_shape(lambda: decoder.init(jax.random.key(0), cfg))
+    tokens = jax.ShapeDtypeStruct((2, seq), jnp.int32)
+    tracing._counters.clear()
+    jax.eval_shape(
+        lambda p, t: decoder.forward(p, t, cfg, **kw), params, tokens
+    )
+    return tracing.counters()
 
 
 @pytest.mark.parametrize("case", ["cpu", "reference", "odd_shapes", "fits"])
@@ -270,8 +283,11 @@ def test_which_alignment_path_runs(monkeypatch, case):
         monkeypatch.setattr(pallas_attention, "INTERPRET", True)
     cfg = _cfg(**({} if case == "odd_shapes" else KERNEL_FIT))
     impl = "reference" if case == "reference" else "flash"
-    assert decoder.alignment_in_kernel(cfg, 256, impl) == (case == "fits")
-    assert decoder.alignment_in_kernel(cfg, 256) is False  # auto: the CPU
+    assert _traced_counters(cfg, 256, attn_impl=impl)[
+        "attn.align_in_kernel"
+    ] == (case == "fits")
+    # auto: the CPU
+    assert _traced_counters(cfg, 256)["attn.align_in_kernel"] == 0
     assert (decoder.alignment_tiles(cfg, 256) is None) == (
         case in ("cpu", "odd_shapes")
     )
@@ -318,11 +334,10 @@ def test_remat_full_gives_what_remat_none_gives(monkeypatch, model, path):
 
 
 def test_train_step_says_which_alignment_path():
-    """The train step sets ``attn.align_in_kernel`` beside
-    ``attn.align_passes`` while it is traced: 0 on the CPU, where the
-    jnp rule runs (``tests/test_tpu_compile.py`` reads 1 from the step
-    compiled for the chip)."""
-    from dlrover_tpu.observability import tracing
+    """A traced train step carries ``attn.align_in_kernel``, set where
+    the block chose: 0 on the CPU, where the jnp rule runs
+    (``tests/test_tpu_compile.py`` reads 1 from the step compiled for
+    the chip)."""
     from dlrover_tpu.parallel import MeshConfig, build_mesh
     from dlrover_tpu.train import (
         TrainStepBuilder, batch_sharding, make_optimizer,
@@ -342,9 +357,7 @@ def test_train_step_says_which_alignment_path():
     }
     tracing._counters.clear()
     builder.build().lower(state, batch)
-    counters = tracing.counters()
-    assert counters["attn.align_in_kernel"] == 0
-    assert counters["attn.align_passes"] == 1
+    assert tracing.counters()["attn.align_in_kernel"] == 0
 
 
 ALIGN_CASES = {

@@ -393,8 +393,6 @@ _SLOW_LEDGER = [
     "test_moe.py::test_alltoall_matches_dense_dispatch",
     "test_moe.py::test_ragged_sharded_matches_local",
     "test_model.py::test_streamed_offload_serializes_leaf_transfers",
-    "test_model.py::test_offload_attn_remat_matches_no_remat",
-    "test_model.py::test_remat_dtype_cast_close_to_full_precision",
     "test_generate_cache.py::test_external_cache_rollout_bitwise_identical",
     "test_mup.py::test_zip_infshapes_on_decoder_params",
     "test_fused_block.py::test_mid_block_stop_flag_stops_at_boundary",
@@ -408,7 +406,6 @@ _SLOW_LEDGER = [
     # 35s + 23s + 22s, each a coarse double-compile or full-Trainer
     # composition with a faster tier-1 sibling) moved to the slow tier.
     "test_model.py::test_logical_axes_match_params",
-    "test_model.py::test_save_qkv_offload_matches_save_qkv",
     "test_model.py::test_remat_matches_no_remat",
     "test_observability.py::test_runtime_timer_in_trainer",
     "test_model.py::test_moe_forward",

@@ -916,8 +916,8 @@ def test_flash_packed_lse_contract(monkeypatch, h):
 
 
 def test_flash_head_pack_one_runs_unpacked(monkeypatch):
-    """``head_pack=1`` (``attn_head_pack`` 1) keeps narrow heads on the
-    unpacked kernels; the counter says which path a trace took."""
+    """``head_pack=1`` keeps narrow heads on the unpacked kernels; the
+    counter says which path a trace took."""
     from dlrover_tpu.observability import tracing
     from dlrover_tpu.ops import pallas_attention as pa
 

@@ -14,6 +14,7 @@ import pytest
 
 from benchmarks.references import nemotron_h_plain as plain
 from dlrover_tpu.models import decoder, get_config
+from dlrover_tpu.observability import tracing
 from dlrover_tpu.ops import pallas_attention, pallas_ssd, ssd
 
 F32 = jnp.float32
@@ -168,7 +169,6 @@ def test_mixer_through_the_kernels_is_the_token_by_token_reference(
     two kernel chunks, the second padded."""
     monkeypatch.setattr(pallas_attention, "INTERPRET", True)
     cfg = get_config("nemotron-3-super", **MIXER)
-    assert decoder.scan_in_kernel(cfg, 200)
     params = decoder.init(jax.random.key(1), cfg)
     mixer = jax.tree.map(lambda t: t[0], params["layers"]["mamba"]["ssm"])
     u = jax.random.normal(jax.random.key(2), (2, 200, cfg.d_model))
@@ -178,7 +178,9 @@ def test_mixer_through_the_kernels_is_the_token_by_token_reference(
             "n_groups", "conv_kernel", "ssm_norm_eps",
         )
     }
+    tracing._counters.clear()
     got = decoder._mamba_block(u, mixer, cfg, None)
+    assert tracing.counters()["ssm.scan_in_kernel"] == 1
     with jax.default_matmul_precision("highest"):
         want = plain._mamba(u, mixer, sizes)
     np.testing.assert_allclose(
@@ -230,7 +232,6 @@ def test_shapes_the_kernels_do_not_tile_take_the_xla_body(monkeypatch, case):
 
 
 def _step_counters(cfg, seq, devices=1):
-    from dlrover_tpu.observability import tracing
     from dlrover_tpu.train import (
         TrainStepBuilder, batch_sharding, make_optimizer,
     )
@@ -264,15 +265,14 @@ def _step_counters(cfg, seq, devices=1):
 def test_train_step_says_which_body_the_scan_took(
     monkeypatch, widths, interpreted, devices, engaged
 ):
-    """``ssm.scan_in_kernel``, set while the step is traced beside the
-    attention's counters: 0 at tier-1's small widths and off the chip,
-    1 where the kernels tile (a step of this model on a mesh of several
+    """``ssm.scan_in_kernel``, set by ``ssd_scan`` while the step is
+    traced: 0 at tier-1's small widths and off the chip, 1 where the
+    kernels tile (a step of this model on a mesh of several
     devices is refused by its routed blocks: the scan's own answer there
     is ``test_shapes_the_kernels_do_not_tile_take_the_xla_body``'s)."""
     monkeypatch.setattr(pallas_attention, "INTERPRET", interpreted)
     cfg = get_config("nemotron-3-super", **{**MIXER, **widths})
     assert _step_counters(cfg, 256, devices)["ssm.scan_in_kernel"] == engaged
-    assert not decoder.scan_in_kernel(cfg, 256, _mesh(2))
 
 
 def test_a_model_without_a_mamba_layer_sets_no_counter():

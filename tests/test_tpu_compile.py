@@ -659,7 +659,6 @@ def test_step_names_its_kernels_and_phases(topo, case):
         ]
         assert len(grouped) == 12  # a layer: 3 forward, 3 recomputed, 6 back
     if spec["model"] == "keye-vl-2.0":
-        assert counters["attn.align_passes"] == 1
         # the alignment term through its kernel: one custom call in the
         # step, in the forward's scanned body and under the term's
         # scope; the recomputed body holds none (its derivative is a

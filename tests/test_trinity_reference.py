@@ -328,8 +328,9 @@ def test_two_periods_scanned_are_the_layers_one_by_one():
 
 
 def test_step_counts_its_layers_by_kind(monkeypatch):
-    """The train step sets ``attn.window_layers``, ``attn.full_layers``
-    and ``attn.output_kept`` while it is traced: on the CPU (no flash
+    """A traced train step carries ``attn.window_layers``,
+    ``attn.full_layers`` and ``attn.output_kept``, set by
+    ``decoder.forward`` as it decides: on the CPU (no flash
     kernel) nothing is kept; where the attention runs the kernels the
     FULL layers' output is kept at a span of 2,048 keys or more and a
     window layer's, whose span the window bounds, is not."""
