@@ -5,6 +5,7 @@ Sizes mirror the models the reference benchmarks with
 BASELINE.md #3-#11), plus small configs for tests and CI.
 """
 
+import re
 from dataclasses import dataclass, field, replace
 from typing import Optional, Tuple
 
@@ -170,10 +171,14 @@ class ModelConfig:
     # queries the indexer scores at a time (``sa_config``'s chunk): no
     # float [B, S, S] of index scores is ever whole
     index_chunk: int = 512
-    # a trunk whose layers are ONE part each, x <- x + part(norm(x))
+    # a trunk made of PARTS, x <- x + part(norm(x)) each
     # (NemotronH's ``hybrid_override_pattern``; "" = every layer an
-    # attention and an MLP): one letter a layer, ``M`` a Mamba-2 mixer,
-    # ``*`` an attention, ``E`` the routed experts. Parameters are
+    # attention and an MLP): one letter a part, ``M`` a Mamba-2 mixer,
+    # ``m`` a Mamba-1 mixer, ``*`` an attention, ``E`` the routed
+    # experts, ``-`` a dense MLP of ``d_ff``. A ``-`` that follows
+    # another part is the second part of that part's LAYER (a mixer +
+    # MLP layer, pre-norm twice: ``m-``, ``*-``); every other letter is
+    # a layer by itself, and ``n_layer`` counts layers. Parameters are
     # stacked kind by kind and visited in this order. ``mtp_pattern``
     # is the prediction module's layers, likewise. Training path only
     layer_pattern: str = ""
@@ -198,6 +203,15 @@ class ModelConfig:
     time_step_min: float = 0.001
     time_step_max: float = 0.1
     time_step_floor: float = 1e-4
+    # the Mamba-1 mixer (``m``): ``mamba_expand`` x d_model channels
+    # with a state of ``ssm_state_size`` EACH and a decay a channel and
+    # state (ops/selective_scan.py), the time step through a low rank
+    # of ``mamba_dt_rank``, RMSNorms on that rank's input and on B and
+    # C, a conv of ``conv_kernel`` taps over the channels alone. The
+    # time step's bias is drawn as the Mamba-2 mixer's, A = 1..state in
+    # every channel, D = 1
+    mamba_expand: int = 0
+    mamba_dt_rank: int = 0
     # a latent around the routed experts (LatentMoE; 0 = none): the
     # router and the shared expert read the d_model-wide input, the
     # experts a projection of it to this width, and one projection back
@@ -415,16 +429,17 @@ class ModelConfig:
     def _check_pattern(self):
         """A ``layer_pattern`` model: what its letters need."""
         for name in ("layer_pattern", "mtp_pattern"):
-            odd = set(getattr(self, name)) - set("M*E")
+            odd = set(getattr(self, name)) - set("Mm*E-")
             if odd:
                 raise ValueError(
-                    f"{name} is made of M (Mamba-2), * (attention) and E "
-                    f"(routed experts); got {sorted(odd)}"
+                    f"{name} is made of M (Mamba-2), m (Mamba-1), * "
+                    f"(attention), E (routed experts) and - (dense MLP); "
+                    f"got {sorted(odd)}"
                 )
-        if len(self.layer_pattern) != self.n_layer:
+        if pattern_layers(self.layer_pattern) != self.n_layer:
             raise ValueError(
-                f"layer_pattern names {len(self.layer_pattern)} layers, "
-                f"n_layer is {self.n_layer}"
+                f"layer_pattern names {pattern_layers(self.layer_pattern)} "
+                f"layers, n_layer is {self.n_layer}"
             )
         if bool(self.mtp_pattern) != bool(self.n_mtp_module):
             raise ValueError(
@@ -446,6 +461,20 @@ class ModelConfig:
                     "of n_groups), mamba_head_dim, ssm_state_size, "
                     "conv_kernel and ssm_chunk"
                 )
+        if "m" in letters and not all(
+            n > 0 for n in (
+                self.mamba_expand, self.mamba_dt_rank, self.ssm_state_size,
+                self.conv_kernel,
+            )
+        ):
+            raise ValueError(
+                "a Mamba-1 part needs mamba_expand, mamba_dt_rank, "
+                "ssm_state_size and conv_kernel"
+            )
+        if "-" in letters and self.act not in ("swiglu", "gelu"):
+            raise ValueError(
+                "a - part is the dense MLP of d_ff: act 'swiglu' or 'gelu'"
+            )
         if "E" in letters and not (
             self.n_experts and self.moe_impl == "ragged"
         ):
@@ -460,7 +489,7 @@ class ModelConfig:
             or self.qk_norm or self.qk_head_norm
         ):
             raise ValueError(
-                "a layer_pattern model is RMSNorm layers of one part "
+                "a layer_pattern model is RMSNorm parts, x + part(norm(x)) "
                 "each: no dense prefix, latent attention, key selection, "
                 "parallel residual, prefix-LM, fp8, position table or "
                 "q/k norm"
@@ -474,6 +503,11 @@ class ModelConfig:
     def d_inner(self) -> int:
         """Channels of a Mamba-2 mixer."""
         return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def d_inner1(self) -> int:
+        """Channels of a Mamba-1 mixer."""
+        return self.mamba_expand * self.d_model
 
     @property
     def conv_dim(self) -> int:
@@ -565,7 +599,7 @@ class ModelConfig:
         """Why the cache, paged, pipeline and generate paths cannot run
         this model ("" where they can): they are written for one stack
         of plain-attention layers."""
-        if "M" in self.layer_pattern + self.mtp_pattern:
+        if set("Mm") & set(self.layer_pattern + self.mtp_pattern):
             return "state-space layers: no recurrent state beside the cache"
         if self.layer_pattern:
             return "a trunk whose layers differ"
@@ -604,7 +638,18 @@ class ModelConfig:
             * bool(self.n_shared_experts)
         )
         expert = mats * self.expert_in * self.expert_width
+        inner1, rank = self.d_inner1, self.mamba_dt_rank
+        low = rank + 2 * self.ssm_state_size       # [Δ's rank | B | C]
+        mamba1 = 2 * d * inner1 + inner1 * low + rank * inner1 + inner1 * d
+        mlp = (3 if self.act == "swiglu" else 2) * d * self.d_ff
         return {
+            "m": (
+                mamba1 + inner1 * (self.conv_kernel + 1)   # conv, its bias
+                + inner1 * (self.ssm_state_size + 2)       # A, D, Δ's bias
+                + low + d,
+                mamba1 + 2 * inner1 * self.ssm_state_size,
+            ),
+            "-": (mlp + d, mlp),
             "M": (
                 mamba + self.conv_dim * (self.conv_kernel + 1)
                 + 3 * heads + inner + d,
@@ -773,6 +818,18 @@ class ModelConfig:
                 self.executed_span(seq_len, kind) for kind in self.layer_types
             )
         return 6.0 * multiplied + 12.0 * self.n_attention_layers * pairs
+
+
+def pattern_parts(pattern: str):
+    """A ``layer_pattern`` cut into its layers, each a string of parts:
+    a ``-`` that follows another part is that part's layer's MLP, every
+    other letter a layer by itself."""
+    return re.findall(r"[^-]-?|-", pattern)
+
+
+def pattern_layers(pattern: str) -> int:
+    """Layers a ``layer_pattern`` names."""
+    return len(pattern_parts(pattern))
 
 
 def mean_span(seq_len: int, window: int = 0) -> float:
@@ -1079,6 +1136,35 @@ CONFIGS = {
         moe_renorm_topk=True,
         routed_scaling_factor=5.0,
         n_mtp_module=1,
+    ),
+    # Mamba-1 mixers and one attention in fourteen, every layer a mixer
+    # and a dense MLP: AI21-Jamba2-3B (``jamba``;
+    # huggingface.co/ai21labs/AI21-Jamba2-3B config.json) — 28 layers
+    # over d 2560, each x + mixer(norm(x)) then x + MLP(norm(x)), so two
+    # parts a layer; the mixer is an attention (20 query heads of 128 on
+    # ONE key/value head, no rope) where i mod 14 = 7 and a Mamba-1
+    # mixer elsewhere (5120 channels of 16 states, Δ through a rank of
+    # 160, conv 4); one SwiGLU of 8192 a layer (num_experts 1); vocabulary
+    # 65,536, head tied. Training path only
+    "jamba2-3b": ModelConfig(
+        name="jamba2-3b",
+        vocab_size=65536,
+        n_layer=28,
+        layer_pattern=("m-" * 7 + "*-" + "m-" * 6) * 2,
+        n_head=20,
+        n_kv_head=1,
+        d_head=128,
+        d_model=2560,
+        d_ff=8192,
+        max_seq=262144,
+        act="swiglu",
+        pos="none",
+        attn_window=None,  # sliding_window: null
+        tie_embeddings=True,
+        mamba_expand=2,
+        mamba_dt_rank=160,
+        ssm_state_size=16,
+        conv_kernel=4,
     ),
     # an attention kind per layer, a gate on the attention's output,
     # four norms a layer: Trinity-Mini (``afmoe``, 26B-A3B;

@@ -29,7 +29,7 @@ from jax.ad_checkpoint import checkpoint_policies as cp
 from jax.sharding import PartitionSpec as P
 
 from dlrover_tpu.common import device
-from dlrover_tpu.models.config import ModelConfig
+from dlrover_tpu.models.config import ModelConfig, pattern_parts
 from dlrover_tpu.observability.tracing import set_counter
 from dlrover_tpu.ops import pallas_norm, pallas_paged, quant
 from dlrover_tpu.ops.attention import _repeat_kv, mha_reference
@@ -105,7 +105,7 @@ def _init_layers(keys, cfg: ModelConfig, lead, routed: bool) -> Params:
     """One kind of layer, its tensors stacked on the leading axes
     ``lead`` (``()`` = one layer): attention, the two norms and either a
     dense MLP of ``d_ff`` or the routed block — never both."""
-    d, f = cfg.d_model, cfg.d_ff
+    d = cfg.d_model
     hd, nh, nkv = cfg.head_dim, cfg.n_head, cfg.kv_heads
     stack, ones = _stackers(cfg, lead)
     attn = _init_attention(keys, cfg, stack, ones)
@@ -118,17 +118,8 @@ def _init_layers(keys, cfg: ModelConfig, lead, routed: bool) -> Params:
         from dlrover_tpu.parallel.moe import init_moe_params
 
         layers["moe"] = init_moe_params(keys[10], cfg, lead)
-    elif cfg.act == "swiglu":
-        layers["mlp"] = {
-            "w_gate": stack(keys[5], (d, f), d),
-            "w_up": stack(keys[6], (d, f), d),
-            "w_down": stack(keys[7], (f, d), f),
-        }
     else:
-        layers["mlp"] = {
-            "w_up": stack(keys[6], (d, f), d),
-            "w_down": stack(keys[7], (f, d), f),
-        }
+        layers["mlp"] = _init_mlp(keys, cfg, stack)
     if cfg.qk_norm:
         attn["q_norm"] = {"scale": ones(nh * hd)}
         attn["k_norm"] = {"scale": ones(nkv * hd)}
@@ -154,8 +145,79 @@ def _init_layers(keys, cfg: ModelConfig, lead, routed: bool) -> Params:
     return layers
 
 
-# a ``layer_pattern`` letter -> the name its stack of layers goes by
-PART_NAMES = {"M": "mamba", "*": "attention", "E": "experts"}
+# a ``layer_pattern`` letter -> the name its stack of parts goes by:
+# M a Mamba-2 mixer, m a Mamba-1 mixer, * an attention, E the routed
+# experts, - a dense MLP
+PART_NAMES = {
+    "M": "mamba", "m": "mamba1", "*": "attention", "E": "experts",
+    "-": "mlp",
+}
+
+
+def _pattern_runs(pattern: str):
+    """``pattern`` cut into runs, in order: [(unit, repeats)], ``unit``
+    a string of whole layers (``config.pattern_parts``). A run of
+    repeats > 1 goes through ``lax.scan``: at each layer, the SHORTEST
+    unit of layers that repeats at least once more at once, all its
+    repeats; a layer that starts no such unit runs unrolled (repeats 1).
+    A unit with a routed part (``E``) never qualifies: its choices ride
+    out layer by layer and its jitter folds the layer's index in."""
+    layers = pattern_parts(pattern)
+    runs, i = [], 0
+    while i < len(layers):
+        unit, reps = layers[i:i + 1], 1
+        for p in range(1, (len(layers) - i) // 2 + 1):
+            cand = layers[i:i + p]
+            if "E" in "".join(cand):
+                break
+            n = 1
+            while layers[i + n * p:i + (n + 1) * p] == cand:
+                n += 1
+            if n > 1:
+                unit, reps = cand, n
+                break
+        runs.append(("".join(unit), reps))
+        i += len(unit) * reps
+    return runs
+
+
+def _scanned_parts(pattern: str) -> int:
+    """Parts of ``pattern`` that run inside a scan."""
+    return sum(len(u) * n for u, n in _pattern_runs(pattern) if n > 1)
+
+
+def _pattern_stacks(pattern: str):
+    """The stacks ``pattern``'s parameters are kept in, [(name, letter,
+    parts)]: each kind by itself under its ``PART_NAMES`` name, and a
+    kind whose parts lie in several scanned runs, or in a run and
+    outside it, a stack a stretch (``mlp``, ``mlp.1``, ``mlp.2``), so
+    that a run scans WHOLE stacks. A slice of a stack handed to
+    ``lax.scan`` is a copy of it, and the run's gradient a second buffer
+    beside the stack's: 1.5 GB over the chip at Jamba2-3B's widths. A
+    pattern with no run (``MEMEMEMEM*E``) keeps one stack a kind."""
+    stretches = {}  # letter -> [[parts, scanned], ...] in trunk order
+    for unit, reps in _pattern_runs(pattern):
+        for letter in dict.fromkeys(unit):
+            mine = stretches.setdefault(letter, [])
+            n = unit.count(letter) * reps
+            if reps == 1 and mine and not mine[-1][1]:
+                mine[-1][0] += n  # parts outside any run, side by side
+            else:
+                mine.append([n, reps > 1])
+    return [
+        (PART_NAMES[letter] + (f".{i}" if i else ""), letter, n)
+        for letter in sorted(stretches)
+        for i, (n, _) in enumerate(stretches[letter])
+    ]
+
+
+def _part_places(pattern: str):
+    """letter -> [(stack name, index in it)], a part of the kind each,
+    in trunk order."""
+    places = {}
+    for name, letter, n in _pattern_stacks(pattern):
+        places.setdefault(letter, []).extend((name, i) for i in range(n))
+    return places
 
 
 def _init_mamba(key, cfg: ModelConfig, lead) -> Params:
@@ -170,11 +232,6 @@ def _init_mamba(key, cfg: ModelConfig, lead) -> Params:
     pdt = jnp.dtype(cfg.param_dtype)
     lead = tuple(lead)
     k = jax.random.split(key, 6)
-    lo, hi = np.log(cfg.time_step_min), np.log(cfg.time_step_max)
-    step = jnp.maximum(
-        jnp.exp(jax.random.uniform(k[2], lead + (heads,)) * (hi - lo) + lo),
-        cfg.time_step_floor,
-    )
     bound = 1.0 / np.sqrt(taps)  # a depthwise tap sees ``taps`` inputs
 
     def uniform(key, shape):
@@ -190,34 +247,98 @@ def _init_mamba(key, cfg: ModelConfig, lead) -> Params:
         "a_log": jnp.log(
             jax.random.uniform(k[1], lead + (heads,), minval=1.0, maxval=16.0)
         ).astype(pdt),
-        # softplus(dt_bias) = step
-        "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(pdt),
+        "dt_bias": _time_step_bias(k[2], cfg, lead + (heads,)).astype(pdt),
         "d_skip": ones(heads),
         "norm": {"scale": ones(inner)},
         "w_out": stack(k[5], (inner, d), inner),
     }
 
 
+def _time_step_bias(key, cfg: ModelConfig, shape):
+    """float32: the time step log-uniform in [time_step_min,
+    time_step_max], floored, through the inverse softplus (softplus of
+    the bias IS the step): Mamba's own initialisation."""
+    lo, hi = np.log(cfg.time_step_min), np.log(cfg.time_step_max)
+    step = jnp.maximum(
+        jnp.exp(jax.random.uniform(key, shape) * (hi - lo) + lo),
+        cfg.time_step_floor,
+    )
+    return step + jnp.log(-jnp.expm1(-step))
+
+
+def _init_mamba1(key, cfg: ModelConfig, lead) -> Params:
+    """A Mamba-1 mixer's parameters as published: ``A`` = 1..N in every
+    channel (kept as its log), Δ's bias as ``_time_step_bias``, Δ's
+    projection uniform in ±rank^-1/2, ``D`` = 1. Normal draws there
+    would give decays no model has."""
+    d, inner, n = cfg.d_model, cfg.d_inner1, cfg.ssm_state_size
+    rank, taps = cfg.mamba_dt_rank, cfg.conv_kernel
+    stack, ones = _stackers(cfg, lead)
+    pdt = jnp.dtype(cfg.param_dtype)
+    lead = tuple(lead)
+    k = jax.random.split(key, 7)
+
+    def uniform(key, shape, bound):
+        return jax.random.uniform(
+            key, lead + shape, minval=-bound, maxval=bound
+        ).astype(pdt)
+
+    tap = 1.0 / np.sqrt(taps)  # a depthwise tap sees ``taps`` inputs
+    return {
+        "w_in": stack(k[0], (d, 2 * inner), d),           # [u | z]
+        "conv_w": uniform(k[1], (taps, inner), tap),
+        "conv_b": uniform(k[2], (inner,), tap),
+        "w_x": stack(k[3], (inner, rank + 2 * n), inner),  # [r | B | C]
+        "dt_norm": {"scale": ones(rank)},
+        "b_norm": {"scale": ones(n)},
+        "c_norm": {"scale": ones(n)},
+        "w_dt": uniform(k[4], (rank, inner), rank ** -0.5),
+        "dt_bias": _time_step_bias(k[5], cfg, lead + (inner,)).astype(pdt),
+        "a_log": jnp.broadcast_to(
+            jnp.log(jnp.arange(1, n + 1, dtype=jnp.float32)),
+            lead + (inner, n),
+        ).astype(pdt),
+        "d_skip": ones(inner),
+        "w_out": stack(k[6], (inner, d), inner),
+    }
+
+
+def _init_mlp(keys, cfg: ModelConfig, stack) -> Params:
+    """The dense MLP's matrices: SwiGLU's three, or two."""
+    d, f = cfg.d_model, cfg.d_ff
+    mlp = {
+        "w_up": stack(keys[6], (d, f), d),
+        "w_down": stack(keys[7], (f, d), f),
+    }
+    if cfg.act == "swiglu":
+        mlp["w_gate"] = stack(keys[5], (d, f), d)
+    return mlp
+
+
 def _init_pattern(key, cfg: ModelConfig, pattern: str) -> Params:
-    """The layers ``pattern`` names, each kind stacked by itself under
-    its ``PART_NAMES`` name: one norm and one part a layer."""
+    """The parts ``pattern`` names, stacked kind by kind
+    (``_pattern_stacks``): one norm and one part each."""
     out: Params = {}
-    for i, letter in enumerate(sorted(set(pattern))):
-        lead = (pattern.count(letter),)
+    for i, (name, letter, n) in enumerate(_pattern_stacks(pattern)):
+        lead = (n,)
         kk = jax.random.fold_in(key, i)
         stack, ones = _stackers(cfg, lead)
         layer: Params = {"ln": {"scale": ones(cfg.d_model)}}
         if letter == "M":
             layer["ssm"] = _init_mamba(kk, cfg, lead)
+        elif letter == "m":
+            layer["ssm1"] = _init_mamba1(kk, cfg, lead)
         elif letter == "*":
             layer["attn"] = _init_attention(
                 jax.random.split(kk, 16), cfg, stack, ones
             )
+        elif letter == "-":
+            layer["mlp"] = _init_mlp(jax.random.split(kk, 16), cfg, stack)
         else:
             from dlrover_tpu.parallel.moe import init_moe_params
 
             layer["moe"] = init_moe_params(kk, cfg, lead)
-        out[PART_NAMES[letter]] = layer
+        out[name] = layer
     return out
 
 
@@ -303,11 +424,22 @@ def _attention_axes(cfg: ModelConfig, lead) -> Params:
     return attn
 
 
+def _mlp_axes(cfg: ModelConfig, lead) -> Params:
+    """Logical axes of ``_init_mlp``'s matrices."""
+    ax = {
+        "w_up": lead + ("embed", "mlp"),
+        "w_down": lead + ("mlp", "embed"),
+    }
+    if cfg.act == "swiglu":
+        ax["w_gate"] = lead + ("embed", "mlp")
+    return ax
+
+
 def _pattern_axes(cfg: ModelConfig, pattern: str, lead) -> Params:
     """Logical axes of ``_init_pattern``'s tree."""
     lead = tuple(lead)
     out: Params = {}
-    for letter in set(pattern):
+    for name, letter, _ in _pattern_stacks(pattern):
         layer: Params = {"ln": {"scale": lead + ("norm",)}}
         if letter == "M":
             layer["ssm"] = {
@@ -320,13 +452,30 @@ def _pattern_axes(cfg: ModelConfig, pattern: str, lead) -> Params:
                 "norm": {"scale": lead + ("norm",)},
                 "w_out": lead + ("mlp", "embed"),
             }
+        elif letter == "m":
+            layer["ssm1"] = {
+                "w_in": lead + ("embed", "mlp"),
+                "conv_w": lead + (None, "mlp"),
+                "conv_b": lead + ("mlp",),
+                "w_x": lead + ("mlp", None),
+                "dt_norm": {"scale": lead + ("norm",)},
+                "b_norm": {"scale": lead + ("norm",)},
+                "c_norm": {"scale": lead + ("norm",)},
+                "w_dt": lead + (None, "mlp"),
+                "dt_bias": lead + ("mlp",),
+                "a_log": lead + ("mlp", None),
+                "d_skip": lead + ("mlp",),
+                "w_out": lead + ("mlp", "embed"),
+            }
         elif letter == "*":
             layer["attn"] = _attention_axes(cfg, lead)
+        elif letter == "-":
+            layer["mlp"] = _mlp_axes(cfg, lead)
         else:
             from dlrover_tpu.parallel.moe import moe_logical_axes
 
             layer["moe"] = moe_logical_axes(cfg, lead)
-        out[PART_NAMES[letter]] = layer
+        out[name] = layer
     return out
 
 
@@ -343,17 +492,8 @@ def _layer_axes(cfg: ModelConfig, lead, routed: bool) -> Params:
         from dlrover_tpu.parallel.moe import moe_logical_axes
 
         ax["moe"] = moe_logical_axes(cfg, lead)
-    elif cfg.act == "swiglu":
-        ax["mlp"] = {
-            "w_gate": lead + ("embed", "mlp"),
-            "w_up": lead + ("embed", "mlp"),
-            "w_down": lead + ("mlp", "embed"),
-        }
     else:
-        ax["mlp"] = {
-            "w_up": lead + ("embed", "mlp"),
-            "w_down": lead + ("mlp", "embed"),
-        }
+        ax["mlp"] = _mlp_axes(cfg, lead)
     if cfg.qk_norm or cfg.qk_head_norm:
         attn["q_norm"] = {"scale": lead + ("norm",)}
         attn["k_norm"] = {"scale": lead + ("norm",)}
@@ -1050,7 +1190,10 @@ def _selecting_attention_block(
     return out @ layer["attn"]["wo"].astype(x.dtype), aux
 
 
-def _mlp_block(x, layer, cfg: ModelConfig, mesh, fp8=None):
+def _mlp_block(x, layer, cfg: ModelConfig, mesh, fp8=None, interior=None):
+    """``interior``: the dtype of what lies between the matmuls and of
+    the output (None = ``x``'s): a ``layer_pattern`` model's ``-`` part
+    keeps float32 there, for ``_mamba1_block``'s reason."""
     mlp = layer["mlp"]
     if fp8 is not None:
         # fp8 GEMMs (cfg.fp8): delayed scaling against per-projection
@@ -1069,15 +1212,16 @@ def _mlp_block(x, layer, cfg: ModelConfig, mesh, fp8=None):
         if mesh is not None:
             h = shd.constrain(h, mesh, "batch", "seq", "mlp")
         return _fp8_gemm(h, mlp["w_down"].astype(x.dtype), fp8, "down")
+    matmul = functools.partial(jnp.matmul, preferred_element_type=interior)
     if cfg.act == "swiglu":
-        gate = x @ mlp["w_gate"].astype(x.dtype)
-        up = x @ mlp["w_up"].astype(x.dtype)
+        gate = matmul(x, mlp["w_gate"].astype(x.dtype))
+        up = matmul(x, mlp["w_up"].astype(x.dtype))
         h = jax.nn.silu(gate) * up
     else:
-        h = jax.nn.gelu(x @ mlp["w_up"].astype(x.dtype))
+        h = jax.nn.gelu(matmul(x, mlp["w_up"].astype(x.dtype)))
     if mesh is not None:
         h = shd.constrain(h, mesh, "batch", "seq", "mlp")
-    return h @ mlp["w_down"].astype(x.dtype)
+    return matmul(h.astype(x.dtype), mlp["w_down"].astype(x.dtype))
 
 
 # a kind's whole attention part, kernels included, under ``attn``
@@ -1205,23 +1349,89 @@ def _mamba_block(h, ssm, cfg: ModelConfig, mesh):
     return y @ ssm["w_out"].astype(dt_)
 
 
+def _mamba1_block(h, ssm, cfg: ModelConfig, mesh):
+    """A Mamba-1 mixer on the layer's normed input ``h`` [B, S, D]
+    (scope ``ssm1``; inside it ``ssm1.conv``, ``ssm1.dbc`` and
+    ``ssm1.scan``):
+
+        [u | z] = h W_in;  u = silu(conv(u) + b)
+        [r | B | C] = u W_x, each RMS-normed;  Δ = softplus(r W_dt + b_dt)
+        A = -exp(A_log)                                     (float32)
+        y = scan(u, Δ, A, B, C) + D u          (ops/selective_scan.py)
+        out = (y ⊙ silu(z)) W_out
+
+    The matmuls multiply operands of the compute dtype and everything
+    between them is float32, the output too (``_run_pattern`` rounds
+    the stream, once a scanned unit): thirteen of these and fourteen
+    MLPs in a row amplify each other's rounding (a relative change of
+    the stream grows 2-5 x on its way down, PERF.md section 4), and
+    with bf16 between the matmuls the logits stand 2.9e-2 from the
+    float32 reference where a dense cell may stand 2.5e-2."""
+    from dlrover_tpu.ops import ssd
+    from dlrover_tpu.ops.selective_scan import selective_scan
+
+    dt_ = jnp.dtype(cfg.dtype)
+    inner, rank, n = cfg.d_inner1, cfg.mamba_dt_rank, cfg.ssm_state_size
+    f32 = jnp.float32
+
+    def matmul(x, w):
+        return jnp.matmul(
+            x.astype(dt_), w.astype(dt_), preferred_element_type=f32
+        )
+
+    proj = matmul(h, ssm["w_in"])
+    if mesh is not None:
+        proj = shd.constrain(proj, mesh, "batch", "seq", "mlp")
+    z = proj[..., inner:]
+    with jax.named_scope("ssm1.conv"):
+        u = jax.nn.silu(
+            ssd.causal_conv(proj[..., :inner], ssm["conv_w"], ssm["conv_b"])
+        )
+    with jax.named_scope("ssm1.dbc"):
+        r, b_mat, c_mat = (
+            _norm(t, ssm[name]["scale"], None, "rmsnorm", cfg.norm_eps)
+            for t, name in zip(
+                jnp.split(matmul(u, ssm["w_x"]), [rank, rank + n], axis=-1),
+                ("dt_norm", "b_norm", "c_norm"),
+            )
+        )
+        step = jax.nn.softplus(
+            matmul(r, ssm["w_dt"]) + ssm["dt_bias"].astype(f32)
+        )
+    y = selective_scan(
+        u, step, -jnp.exp(ssm["a_log"].astype(f32)), b_mat, c_mat
+    )
+    y = (y + ssm["d_skip"].astype(f32) * u) * jax.nn.silu(z)
+    return matmul(y, ssm["w_out"])
+
+
+# the scope a part's operations are traced under
+_PART_SCOPES = {"M": "ssm", "m": "ssm1", "*": "attn", "E": "mlp", "-": "mlp"}
+
+
 def _part_body(
     x, layer, positions, *, letter, cfg: ModelConfig, mesh, attn_fn,
     rng=None, rope=None,
 ):
-    """One layer of a ``layer_pattern`` model, ``x + part(norm(x))``:
-    a Mamba-2 mixer (``M``), an attention (``*``) or the routed experts
-    (``E``). Returns (x, the routed block's aux or {})."""
+    """One part of a ``layer_pattern`` model, ``x + part(norm(x))``:
+    a Mamba-2 mixer (``M``), a Mamba-1 mixer (``m``), an attention
+    (``*``), the routed experts (``E``) or a dense MLP (``-``). Returns
+    (x, the routed block's aux or {})."""
     aux = {}
-    scope = {"M": "ssm", "*": "attn", "E": "mlp"}[letter]
-    with jax.named_scope(scope):
-        h = _norm_block(x, layer["ln"], cfg)
+    with jax.named_scope(_PART_SCOPES[letter]):
+        # (``x`` is float32 behind a part whose output is, until
+        # ``_run_pattern`` rounds it)
+        h = _norm_block(x, layer["ln"], cfg).astype(cfg.dtype)
         if letter == "M":
             out = _mamba_block(h, layer["ssm"], cfg, mesh)
+        elif letter == "m":
+            out = _mamba1_block(h, layer["ssm1"], cfg, mesh)
         elif letter == "*":
             out = _attention_block(
                 h, layer, cfg, mesh, positions, attn_fn, rope=rope
             )
+        elif letter == "-":
+            out = _mlp_block(h, layer, cfg, mesh, interior=jnp.float32)
         else:
             from dlrover_tpu.parallel.moe import moe_block
 
@@ -1238,39 +1448,74 @@ def _run_pattern(
     x, layers, pattern: str, positions, cfg: ModelConfig, mesh, attn_fn,
     rng, first: int = 0, keep_attn: bool = False,
 ):
-    """The layers ``pattern`` names, in its order, each taken as the
-    next of its kind's stack in ``layers`` (``_init_pattern``) and run
-    through ``_part_body`` under the configured remat. The layers are
-    unrolled: neighbours differ in kind. Returns (x, aux): the routed
-    layers' scalars summed, their ``moe_choices`` stacked [E layers, B,
-    S, k] in trunk order ({} where no layer routes). ``first``: the
-    index of the pattern's first layer, folded into ``rng``."""
-    bodies = {
-        letter: _remat(
-            functools.partial(
-                _part_body, letter=letter, cfg=cfg, mesh=mesh,
-                attn_fn=attn_fn,
-            ),
-            cfg, keep_attn,
+    """The parts ``pattern`` names, in its order, each taken as the
+    next of its kind in ``layers`` (``_init_pattern``) and run through
+    ``_part_body``. A run of one repeated unit (``_pattern_runs``) is a
+    ``lax.scan`` over the stacks it owns (``_pattern_stacks``), the
+    unit's parts unrolled inside on ONE value of the stream (float32
+    behind a part whose output is, rounded at the unit's end) and the
+    UNIT under the configured remat (one kept input a repeat); the rest
+    is unrolled, each part under the remat and the stream rounded
+    behind it: neighbours differ in kind. Returns (x, aux): the
+    routed layers' scalars summed, their ``moe_choices`` stacked [E
+    layers, B, S, k] in trunk order ({} where no layer routes).
+    ``first``: the index of the pattern's first part, folded into
+    ``rng``."""
+    parts = {
+        letter: functools.partial(
+            _part_body, letter=letter, cfg=cfg, mesh=mesh, attn_fn=attn_fn
         )
         for letter in set(pattern)
+    }
+    bodies = {
+        letter: _remat(part, cfg, keep_attn) for letter, part in parts.items()
     }
     rope = (
         _rope_tables(positions, cfg.rope_dim, cfg.rope_theta)
         if cfg.pos == "rope" and "*" in pattern
         else None
     )
+    places = _part_places(pattern)
     seen = dict.fromkeys(bodies, 0)
     auxs = []
-    for i, letter in enumerate(pattern):
-        layer = jax.tree.map(
-            lambda t: t[seen[letter]], layers[PART_NAMES[letter]]
-        )
-        seen[letter] += 1
-        r = jax.random.fold_in(rng, first + i) if rng is not None else None
-        x, aux = bodies[letter](x, layer, positions, rng=r, rope=rope)
-        if aux:
-            auxs.append(aux)
+    i = 0
+    for unit, reps in _pattern_runs(pattern):
+        if reps > 1:
+            # the run's stacks whole: [repeats, the unit's parts of the
+            # kind, ...] a kind
+            stacks = {}
+            for letter in set(unit):
+                n = unit.count(letter)
+                name, _ = places[letter][seen[letter]]
+                stacks[letter] = jax.tree.map(
+                    lambda t: t.reshape((reps, n) + t.shape[1:]),
+                    layers[name],
+                )
+                seen[letter] += n * reps
+
+            def repeat(x, stacks):
+                at = dict.fromkeys(stacks, 0)
+                for letter in unit:
+                    layer = jax.tree.map(
+                        lambda t: t[at[letter]], stacks[letter]
+                    )
+                    at[letter] += 1
+                    x, _ = parts[letter](x, layer, positions, rope=rope)
+                return x.astype(cfg.dtype), None
+
+            x = _scan_run(_remat(repeat, cfg, keep_attn), x, stacks)
+            i += len(unit) * reps
+            continue
+        for letter in unit:
+            name, at = places[letter][seen[letter]]
+            layer = jax.tree.map(lambda t: t[at], layers[name])
+            seen[letter] += 1
+            r = jax.random.fold_in(rng, first + i) if rng is not None else None
+            x, aux = bodies[letter](x, layer, positions, rng=r, rope=rope)
+            x = x.astype(cfg.dtype)
+            i += 1
+            if aux:
+                auxs.append(aux)
     if not auxs:
         return x, {}
     stacked = jax.tree.map(lambda *ls: jnp.stack(ls), *auxs)
@@ -1278,6 +1523,12 @@ def _run_pattern(
     return x, {
         **jax.tree.map(lambda a: a.sum(0), stacked), "moe_choices": choices
     }
+
+
+def _scan_run(repeat, x, stacks):
+    """A run of repeats of one unit: ``repeat(x, a repeat's slices of
+    the stacks) -> (x, None)`` over the stacks' leading axis."""
+    return jax.lax.scan(repeat, x, stacks)[0]
 
 
 def _remat_body(cfg: ModelConfig, mesh, attn_fn, fp8_layers,
@@ -1468,6 +1719,12 @@ def run_trunk(
     if cfg.layer_pattern:
         if mesh is not None and mesh.shape.get("pp", 1) > 1:
             _train_only_guard(cfg, "the pipeline")
+        # which parts the trunk runs, and how. Trace time, values
+        set_counter(
+            "pattern.scanned_parts", _scanned_parts(cfg.layer_pattern)
+        )
+        if "m" in cfg.layer_pattern:
+            set_counter("ssm1.layers", cfg.layer_pattern.count("m"))
         x, aux = _run_pattern(
             x, layers, cfg.layer_pattern, positions, cfg, mesh, attn_fn,
             rng, keep_attn="" in keep_attn,

@@ -51,6 +51,7 @@ BENCHMARK_CONFIGS = {
     "keye-vl-2.0-ep8-1chip": 8192,
     "nemotron-3-super-ep64-1chip": 8192,
     "trinity-mini-ep8-1chip": 16384,
+    "jamba2-3b-l14": 8192,
 }
 
 
@@ -65,7 +66,8 @@ def test_flops_per_token_is_the_benchmarks_count(name):
     prefix, the held and shared experts, the prediction module, a
     selection of keys and its score-only indexer, layers of one part
     each with a state-space scan's recurrence among the multiplied, an
-    attention kind per layer with each kind's own span."""
+    attention kind per layer with each kind's own span, mixer + MLP
+    layers of two parts each with a selective scan's."""
     from benchmarks.lib.flops import resolve
 
     configs = pathlib.Path(__file__).parent.parent / "benchmarks" / "configs"

@@ -567,13 +567,16 @@ def test_pipeline_refuses_the_model():
     "over,why",
     [
         (dict(layer_pattern="MEM*"), "names 4 layers"),
-        (dict(layer_pattern="MEM-E"), "made of M"),
+        (dict(layer_pattern="MEMxE"), "made of M"),
+        (dict(layer_pattern="ME-*E"), "names 4 layers"),
+        (dict(layer_pattern="MEM*E-"), "dense MLP of d_ff"),
         (dict(mtp_pattern=""), "both or neither"),
         (dict(moe_impl="dense"), "ragged"),
         (dict(mamba_num_heads=0), "Mamba-2 layer needs"),
         (dict(pos="learned"), "position table"),
     ],
-    ids=["count", "letter", "module", "lowering", "mixer", "positions"],
+    ids=["count", "letter", "mlp-in-a-layer", "mlp-act", "module", "lowering",
+         "mixer", "positions"],
 )
 def test_config_refuses_a_pattern_it_cannot_run(over, why):
     with pytest.raises(ValueError, match=why):

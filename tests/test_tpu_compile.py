@@ -983,6 +983,83 @@ def test_nemotron_cell_fits_the_chip_with_its_rows_cut(topo, monkeypatch):
     assert not re.search(r"\[1,(?:64|32),\d+,16,(?:128,128|256,256)\]", text)
 
 
+def test_jamba_cell_compiles_with_its_runs_scanned(topo):
+    """The benchmark's Jamba2-3B configuration as it is run (one period
+    of 14 mixer + MLP layers, 1 x 8192 tokens) compiles for a described
+    v5e: the two runs of ``m-`` as scans over their own stacks with the
+    unit the remat unit, the attention layer's two parts unrolled. The
+    count of memory made here reads 19.04 GB where the chip reads 16.0
+    (D18: it reads high, and the compiler's own check, which passes, is
+    what says the step fits), so it is held to its own reading. The
+    kernels are the unpacked flash kernels at 20 / 1 heads of 128 and
+    the fused norms, nothing else; no array holds a state a token
+    (``[B, S, 5120, 16]`` in either order) before XLA or after, and the
+    selective scan's backward holds one chunk's states."""
+    import json
+    import pathlib
+    import re
+
+    from dlrover_tpu.observability import runtime_timer
+
+    path = pathlib.Path(__file__).parent.parent / "benchmarks" / "configs"
+    config = json.loads((path / "jamba2-3b-l14.json").read_text())
+    STEP_CASES["jamba-cell"] = dict(
+        model=config["program"]["model"],
+        overrides=config["program"]["overrides"],
+        optimizer=dict(state_dtype="bfloat16"), comm=None, chips=1,
+        batch=(1, 8192), keep_lowered=True,
+    )
+    try:
+        _, text, counters = _compiled_step(topo, "jamba-cell")
+    finally:
+        del STEP_CASES["jamba-cell"]
+    lowered = _STEP_LOWERED.pop("jamba-cell")
+    assert counters["ssm1.layers"] == 13
+    assert counters["ssm1.scan_chunk"] == 128
+    assert counters["pattern.scanned_parts"] == 26
+    assert counters["attn.output_kept"] == 1  # a span of 4,096.5 keys
+    stats = _STEP_MEMORY["jamba-cell"]
+    need = (
+        stats.argument_size_in_bytes + stats.output_size_in_bytes
+        - stats.alias_size_in_bytes + stats.temp_size_in_bytes
+    )
+    assert 18.0e9 < need < 19.6e9, need
+    assert stats.argument_size_in_bytes == pytest.approx(
+        6 * 1_598_556_096, rel=1e-3  # bf16 parameters and two moments
+    )
+    kernels = {
+        line.split("=")[0].strip().removeprefix("ROOT ").lstrip("%")
+        .split(".")[0]
+        for line in text.splitlines() if "tpu_custom_call" in line
+    }
+    assert kernels == {
+        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "norm_fwd", "norm_bwd",
+    }
+    flash = [
+        ln for ln in text.splitlines()
+        if "tpu_custom_call" in ln and "%flash_" in ln
+    ]
+    # multi-query: 20 heads of 128 on one; the output kept, so one
+    # forward call
+    assert len(flash) == 3 and all(
+        "bf16[20,8192,128]" in ln and "bf16[1,8192,128]" in ln for ln in flash
+    )
+    op_names = runtime_timer.op_names_from_hlo(text)
+    parts = {
+        part for name in op_names.values()
+        for part in re.split(r"[/()]", name)
+    }
+    scopes = {"ssm1", "ssm1.conv", "ssm1.dbc", "ssm1.scan", "attn", "mlp",
+              "head_loss", "optimizer"}
+    assert scopes <= parts, scopes - parts
+    # a state a token, in either order, with any leading axes
+    whole = r"8192[x,](?:1[x,])?(?:5120[x,]16|16[x,]5120)\b"
+    assert not re.search(whole, lowered) and not re.search(whole, text)
+    assert not re.search(r"\b64[x,]128[x,]1[x,]16[x,]5120\b", lowered)
+    # one chunk's states in the backward, one state a chunk kept
+    assert "128x1x16x5120xf32" in lowered and "64x1x16x5120xf32" in lowered
+
+
 def _equations(jaxpr):
     """Equations of a jaxpr with those of the jaxprs its equations hold."""
     n = 0
