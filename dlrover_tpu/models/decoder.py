@@ -1399,7 +1399,7 @@ def _mamba1_block(h, ssm, cfg: ModelConfig, mesh):
             matmul(r, ssm["w_dt"]) + ssm["dt_bias"].astype(f32)
         )
     y = selective_scan(
-        u, step, -jnp.exp(ssm["a_log"].astype(f32)), b_mat, c_mat
+        u, step, -jnp.exp(ssm["a_log"].astype(f32)), b_mat, c_mat, mesh=mesh
     )
     y = (y + ssm["d_skip"].astype(f32) * u) * jax.nn.silu(z)
     return matmul(y, ssm["w_out"])

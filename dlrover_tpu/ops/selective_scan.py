@@ -39,8 +39,17 @@ so a step under ``remat: full`` runs the recurrence four times (forward,
 the layer's remade forward, the backward's remade chunk, the walk back),
 not the three forwards a backward that differentiating the loop would.
 
-This is the XLA body. A Pallas kernel that keeps the tile in VMEM over a
-chunk is the next step (ROADMAP R10).
+One function, two bodies of this one algorithm, chosen by what the code
+sees (``pallas_selective_scan.tile``): on a TPU (or interpreted), on one
+device, with channels in whole blocks of 1,024, states in eights and a
+chunk of whole eights, the Pallas kernels ``sscan_fwd`` / ``sscan_bwd``
+(``ops/pallas_selective_scan.py``: the state in VMEM from a chunk's
+first token to its last, the backward's chunk of states remade into
+VMEM) — what the Jamba cell's thirteen layers run since PR 54; anywhere
+else — the CPU, tier-1's small widths, a mesh of several devices — the
+XLA body below, through this module's own ``jnp``. Both keep the same
+residuals and the same float32 mathematics; ``ssm1.scan_in_kernel``
+says which one a program took.
 """
 
 import functools
@@ -49,14 +58,17 @@ import jax
 import jax.numpy as jnp
 
 from dlrover_tpu.observability.tracing import set_counter
+from dlrover_tpu.ops import pallas_selective_scan
 
 F32 = jnp.float32
 
-# Tokens a chunk, and tokens unrolled into one iteration of the inner
-# loop (XLA fuses their updates). By a sweep on a v5e at the Jamba2-3B
-# cell's size, one call at [1, 8192, 5120] x 16 states, float32 operands
-# (my chip runs, PR 53; ms forward / forward and backward, the smallest of
-# five; both sweeps read the same to 0.1 ms):
+# Tokens a chunk (both bodies), and tokens unrolled into one iteration of
+# the XLA body's inner loop (XLA fuses their updates). The XLA body's
+# sweep, which is the fallback's since PR 54 (the kernels' own is in
+# ``ops/pallas_selective_scan.py``), on a v5e at the Jamba2-3B cell's
+# size, one call at [1, 8192, 5120] x 16 states, float32 operands (my chip
+# runs, PR 53; ms forward / forward and backward, the smallest of five;
+# both sweeps read the same to 0.1 ms):
 #   chunk  64: unroll 1  7.19 / 20.47   unroll 4  6.20 / 20.16
 #   chunk 128: unroll 1  7.00 / 19.71   unroll 4  5.94 / 19.50
 #   chunk 256: unroll 1  6.85 / 33.08   unroll 2  6.80 / 37.87
@@ -188,22 +200,30 @@ def _scan_bwd(chunk, residuals, dy):
 _scan.defvjp(_scan_fwd, _scan_bwd)
 
 
-def selective_scan(u, delta, a, b, c, chunk: int = SCAN_CHUNK):
+def selective_scan(u, delta, a, b, c, chunk: int = SCAN_CHUNK, mesh=None):
     """The scan over a sequence. u [B, S, C]; delta [B, S, C] float32,
     positive (after the softplus); a [C, N] float32, negative; b and c
     [B, S, N]. Returns y [B, S, C] in u's dtype (the skip ``D u`` and
     the gate are the caller's). ``chunk``: tokens between two carried
-    states (at most the sequence)."""
+    states (at most the sequence). One function, two bodies, chosen from
+    what it sees (``pallas_selective_scan.tile``; ``mesh`` is the mesh
+    the operands live on, if any): the Pallas kernels, or the XLA body
+    above."""
     s = u.shape[1]
     chunk = min(chunk, s)
-    # the chunk the scan runs. Trace time, a value
-    set_counter("ssm1.scan_chunk", chunk)
     pad = -s % chunk
+    in_kernel = pallas_selective_scan.tile(
+        s + pad, u.shape[2], a.shape[1], chunk, mesh
+    )
+    # the chunk the scan runs and which body took it. Trace time, values
+    set_counter("ssm1.scan_chunk", chunk)
+    set_counter("ssm1.scan_in_kernel", int(in_kernel))
+    scan = pallas_selective_scan.sscan if in_kernel else _scan
     if pad:
         # Δ = 0: a decay of 1 and no input; see the module's docstring
         u, delta, b, c = (
             jnp.pad(t, ((0, 0), (0, pad), (0, 0))) for t in (u, delta, b, c)
         )
     with jax.named_scope("ssm1.scan"):
-        y = _scan(u, delta.astype(F32), a.astype(F32), b, c, chunk)
+        y = scan(u, delta.astype(F32), a.astype(F32), b, c, chunk)
     return y[:, :s] if pad else y
