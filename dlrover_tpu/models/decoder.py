@@ -1324,18 +1324,20 @@ def _mamba_block(h, ssm, cfg: ModelConfig, mesh):
     if mesh is not None:
         proj = shd.constrain(proj, mesh, "batch", "seq", "mlp")
     z = proj[..., :inner]
+    # the mesh goes along only where it rules the conv's and the scan's
+    # kernels out (several devices: ROADMAP S6): the benchmark's planted
+    # defects stand in for ``causal_conv`` by its three parameters and
+    # for ``ssd_scan`` by its own up to ``head_block``. The conv takes
+    # its columns of the projection where they lie (``ssd.Columns``)
+    several = {"mesh": mesh} if mesh is not None and mesh.size > 1 else {}
     xbc = jax.nn.silu(ssd.causal_conv(
-        proj[..., inner:inner + cfg.conv_dim], ssm["conv_w"], ssm["conv_b"]
+        ssd.Columns(proj, inner), ssm["conv_w"], ssm["conv_b"], **several
     ))
     step = jax.nn.softplus(
         proj[..., inner + cfg.conv_dim:].astype(f32)
         + ssm["dt_bias"].astype(f32)
     )
     x = xbc[..., :inner].reshape(b, s, heads, hd)
-    # the mesh goes along only where it rules the scan's kernels out
-    # (several devices: ROADMAP S6): the benchmark's planted defects
-    # stand in for ``ssd_scan`` by its parameters up to ``head_block``
-    several = {"mesh": mesh} if mesh is not None and mesh.size > 1 else {}
     y = ssd.ssd_scan(
         x, step, -jnp.exp(ssm["a_log"].astype(f32)),
         xbc[..., inner:inner + g * n].reshape(b, s, g, n),
@@ -1383,10 +1385,12 @@ def _mamba1_block(h, ssm, cfg: ModelConfig, mesh):
     if mesh is not None:
         proj = shd.constrain(proj, mesh, "batch", "seq", "mlp")
     z = proj[..., inner:]
+    # (the mesh only where it rules the kernels out: ``_mamba_block``)
+    several = {"mesh": mesh} if mesh is not None and mesh.size > 1 else {}
     with jax.named_scope("ssm1.conv"):
-        u = jax.nn.silu(
-            ssd.causal_conv(proj[..., :inner], ssm["conv_w"], ssm["conv_b"])
-        )
+        u = jax.nn.silu(ssd.causal_conv(
+            ssd.Columns(proj, 0), ssm["conv_w"], ssm["conv_b"], **several
+        ))
     with jax.named_scope("ssm1.dbc"):
         r, b_mat, c_mat = (
             _norm(t, ssm[name]["scale"], None, "rmsnorm", cfg.norm_eps)

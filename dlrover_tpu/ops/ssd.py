@@ -65,11 +65,13 @@ tokens of Δ = 0 and x = 0, which leave every state as it was and whose
 outputs are cut off again: exact, since the scan is causal.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 
 from dlrover_tpu.observability.tracing import set_counter
-from dlrover_tpu.ops import pallas_ssd
+from dlrover_tpu.ops import pallas_conv, pallas_ssd
 
 F32 = jnp.float32
 
@@ -228,19 +230,57 @@ def ssd_scan(x, dt, a, b_mat, c_mat, chunk: int, head_block: int = 0,
     return y[:, :s] if pad else y
 
 
-def causal_conv(x, weight, bias):
-    """Depthwise causal conv along the sequence: ``y_t = Σ_j w_j ⊙
-    x_{t-K+1+j} + b`` with x before the first token 0. x [B, S, C],
-    weight [K, C], bias [C]; K shifted copies, in float32."""
+def _conv(x, weight, bias):
+    """``causal_conv``'s XLA body: K shifted copies of x padded at its
+    start, in float32."""
     k = weight.shape[0]
     s = x.shape[1]
+    padded = jnp.pad(x.astype(F32), ((0, 0), (k - 1, 0), (0, 0)))
+    w = weight.astype(F32)
+    out = bias.astype(F32)
+    for j in range(k):
+        out = out + padded[:, j:j + s] * w[j]
+    return out.astype(x.dtype)
+
+
+@dataclasses.dataclass(frozen=True)
+class Columns:
+    """The channels of ``of`` [B, S, wider] from column ``start`` on, as
+    many as the taps have: what a mixer hands ``causal_conv`` for x in
+    place of the slice ``of[..., start:start + C]``, which on the chip is
+    a copy of the array each way before a kernel can take it (168 MB a
+    pass at both cells' sizes). The kernels read the in-projection where
+    it lies; the XLA body takes the slice."""
+    of: jax.Array
+    start: int
+
+
+def causal_conv(x, weight, bias, mesh=None):
+    """Depthwise causal conv along the sequence: ``y_t = Σ_j w_j ⊙
+    x_{t-K+1+j} + b`` with x before the first token 0. x [B, S, C] or
+    ``Columns`` of a wider array, weight [K, C], bias [C]; the taps, the
+    bias and every product and sum float32, y [B, S, C] in x's dtype.
+    One function, two bodies, chosen from what it sees
+    (``pallas_conv.tile``; ``mesh`` is the mesh the operands live on, if
+    any): on a TPU (or interpreted), on one device, with the channels
+    and their first column on the 128-lane grid, a length of whole token
+    blocks and at most 9 taps, the Pallas kernels ``conv_fwd`` /
+    ``conv_bwd`` (``ops/pallas_conv.py``: x read where it lies, once a
+    pass) — what the Nemotron and Jamba cells' mixers run; anywhere else
+    — the CPU, tier-1's small widths, a mesh of several devices — the
+    XLA body above. ``ssm.conv_in_kernel`` says which one a program
+    took."""
+    taps, channels = weight.shape
+    wide, start = (x.of, x.start) if isinstance(x, Columns) else (x, 0)
+    block = pallas_conv.tile(wide.shape[1], channels, taps, start, mesh)
+    # which body the program took. Trace time, a value
+    set_counter("ssm.conv_in_kernel", int(block is not None))
     with jax.named_scope("ssm.conv"):
-        padded = jnp.pad(x.astype(F32), ((0, 0), (k - 1, 0), (0, 0)))
-        w = weight.astype(F32)
-        out = bias.astype(F32)
-        for j in range(k):
-            out = out + padded[:, j:j + s] * w[j]
-        return out.astype(x.dtype)
+        if block is not None:
+            return pallas_conv.conv(wide, weight, bias, block, start)
+        if wide is not x:
+            x = wide[..., start:start + channels]
+        return _conv(x, weight, bias)
 
 
 def gated_group_norm(y, z, scale, groups: int, eps: float):
