@@ -16,9 +16,11 @@ query i attends to min(i + 1, ``attn_window``) keys,
 ``lib/flops.mean_span(seq, attn_window)`` a query (1,920.06 at 16,384
 tokens and a window of 2,048); a full layer's to i + 1,
 ``mean_span(seq)`` (8,192.5). The kernels also multiply the masked part
-of every block they touch — the upper half of a diagonal block, and of a
-window layer's 3 key blocks of 1,024 a query block about 1,100 keys a
-query the window leaves out — and none of that is counted, so the share
+of every block they touch — the upper half of a diagonal block, and of
+the band of key blocks a window layer's query block walks (since PR 48:
+3 blocks of 1,024 forward, 2,880 keys a query executed; blocks of 512
+backward, 2,400) the 960 and 480 keys a query the window leaves out —
+and none of that is counted, so the share
 cannot read high: no pair outside a window is counted. One call runs
 the whole batch and every head (``call_flops``). The calls are counted
 from the trace, kind by kind: under full rematerialisation a window

@@ -29,13 +29,22 @@ Convention (fixed here so that no later change can move it):
   capping it further: ``mean_span(seq, window, topk=k)``. The keys a
   query never multiplies are work the model does not require, however
   the program lays its kernel out.
+- A selection made by BLOCKS of b keys (a block-sparse attention: k
+  blocks a query, its own among them, which the causal mask cuts)
+  counts the keys of the chosen blocks that the query may see: query
+  i, in block i // b, sees min(i // b + 1, k) - 1 whole blocks and
+  i mod b + 1 keys of its own: ``mean_span(seq, topk=k, block=b)``.
 - A scorer that only RANKS keys (such an indexer: a score product and
   no value product) counts its projections among the multiplied
   parameters and heads x (score channels + 0) / 2 x the mean span of
   the keys it SCORES, which is every visible one (``mean_span(seq,
   window)``, no ``topk``): it has to look at a key to pass it over. A
   loss that aligns the scorer with probabilities the layer computes
-  anyway counts nothing more.
+  anyway counts nothing more. A scorer over POOLED keys (one mean of
+  ``window`` keys every ``stride``, scored once its window has ended)
+  counts heads x score channels / 2 x the mean number of pooled keys a
+  query scores, ``mean_span(seq) / stride`` to within a window; the
+  pooling itself, a sum, multiplies nothing.
 - Recomputation (remat) does not count: it is work the recipe chose,
   not work the model requires.
 - Layers are counted KIND BY KIND, not ``n_layer`` times one: a leading
@@ -95,10 +104,24 @@ import math
 TERMS = ("multiplied_params", "attention_pair_channels")
 
 
-def mean_span(seq: int, window: int = 0, topk: int = 0) -> float:
+def mean_span(seq: int, window: int = 0, topk: int = 0,
+              block: int = 1) -> float:
     """Mean number of keys a query attends to in a causal sequence of
     ``seq`` tokens under a sliding window of ``window`` keys (0 = no
-    window) and a selection of at most ``topk`` of them (0 = none)."""
+    window) and a selection of at most ``topk`` of them (0 = none), or,
+    with ``block`` > 1, of at most ``topk`` BLOCKS of ``block`` keys,
+    the query's own among them."""
+    if block > 1:
+        if window or seq % block:
+            raise ValueError(
+                "a selection by blocks takes whole blocks and no window"
+            )
+        units = seq // block
+        k = min(topk, units) if topk else units
+        # block u's queries: min(u + 1, k) - 1 whole blocks each, and
+        # 1..block keys of their own
+        whole = k * (k - 1) // 2 + (units - k) * (k - 1)
+        return (whole * block * block + units * block * (block + 1) / 2) / seq
     w = min(window, seq) if window else seq
     if topk:
         w = min(w, topk)

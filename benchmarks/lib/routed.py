@@ -197,11 +197,12 @@ def program_losses(params, batch, cfg):
     return {k: float(v) for k, v in losses(params, batch).items()}
 
 
-def program_logits_and_choices(params, tokens, cfg):
+def program_logits_and_choices(params, tokens, cfg, sizes=None):
     """The program's forward on ``tokens``: (logits, expert ids int32
     [n_layer, B, S, k]). A program that does not hand its choices over
     cannot be judged by this comparison, and is refused before anything
-    compiles."""
+    compiles. ``sizes``, the configuration's, is what the runner passes
+    to every teacher-forced kind; this one reads nothing from it."""
     import jax
 
     from dlrover_tpu.models import decoder

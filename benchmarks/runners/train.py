@@ -93,6 +93,10 @@ def _program_config(config):
     for key, want in config["sizes"].items():
         if key == "norm_eps":
             continue  # fixed in the program's code, not a field
+        if key in ("select_block", "select_groups") and not hasattr(cfg, key):
+            # ``selected``'s contract: held against the shape of what the
+            # program hands over (``lib/selected.py``), field or no field
+            continue
         got = getattr(cfg, key)
         if got != want:
             raise ValueError(
@@ -368,7 +372,7 @@ def _check_outputs(ctx, cfg, mesh, state, batch0, first):
     kernel_losses = [float(kernel_loss(params, b)) for b in batches]
     if forcing:
         logits, choices = forcing.program_logits_and_choices(
-            params, batches[0]["tokens"], cfg
+            params, batches[0]["tokens"], cfg, sizes
         )
         program = forcing.program_losses(params, batches[0], cfg)
         ce_loss = program.get("ce_loss", program["loss"])

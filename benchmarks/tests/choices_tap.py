@@ -19,16 +19,16 @@ from benchmarks.lib.device import Refused
 _program_logits_and_choices = routed.program_logits_and_choices
 
 
-def logits_and_choices(params, tokens, cfg):
+def logits_and_choices(params, tokens, cfg, sizes=None):
     """``routed.program_logits_and_choices``; the tap where the program
     refuses."""
     try:
-        return _program_logits_and_choices(params, tokens, cfg)
+        return _program_logits_and_choices(params, tokens, cfg, sizes)
     except Refused:
         return tapped_logits_and_choices(params, tokens, cfg)
 
 
-def tapped_logits_and_choices(params, tokens, cfg):
+def tapped_logits_and_choices(params, tokens, cfg, sizes=None):
     import jax
     import jax.numpy as jnp
 

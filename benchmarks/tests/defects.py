@@ -324,3 +324,203 @@ SELECTED_INJECT = {
     "selection_ignored": selection_ignored,
     "indexer_loss_off": indexer_loss_off,
 }
+
+
+# ---- defects of an attention that selects by BLOCKS ------------------------
+# What the ``selected`` comparison has to catch of a selection made in
+# units of ``select_block`` keys, ``select_groups`` selections a layer,
+# with units the model's rule forces (``lib/selected.py``), each
+# injected by patching the block stand-in (``tests/block_standin.py``:
+# the program selects keys, one selection a layer). ``test_selected.py``
+# runs them at a tiny size on the CPU; the chip rehearsal (PERF.md
+# section 4, PR 56) ran the same patches at MiniCPM-SALA's sparse layer.
+
+def recent_blocks(patch):
+    """Beside the forced units the most recent free blocks, not the
+    best: a wider local window under the selection's name."""
+    import jax.numpy as jnp
+
+    from benchmarks.tests import block_standin
+
+    select = block_standin._select_units
+
+    def recent(index, forced, k, qpos):
+        unit = jnp.arange(index.shape[-1], dtype=index.dtype)
+        nearest = jnp.where(jnp.isfinite(index), unit, -jnp.inf)
+        return select(nearest, forced, k, qpos)
+
+    patch(block_standin, "_select_units", recent)
+
+
+def one_head_scores(patch):
+    """A KV head's blocks scored by its first query head alone, not by
+    the sum over its query heads."""
+    from benchmarks.tests import block_standin
+
+    patch(block_standin, "_head_sum", lambda p: p[:, :, 0])
+
+
+def block_mean(patch):
+    """A block scored by the mean of its pooled keys, not their max."""
+    import jax.numpy as jnp
+
+    from benchmarks.tests import block_standin
+
+    patch(block_standin, "_block_reduce", lambda p: jnp.mean(p, axis=-1))
+
+
+def pool_no_overlap(patch):
+    """Pooled keys every ``pool_window`` keys (stride 32 for 16): every
+    other pooled key is not there, and the windows do not overlap."""
+    import jax.numpy as jnp
+
+    from benchmarks.tests import block_standin
+
+    def every_other(ended, sizes):
+        step = sizes["pool_window"] // sizes["pool_stride"]
+        return ended & (jnp.arange(ended.shape[-1]) % step == 0)[None, :]
+
+    patch(block_standin, "_live_pooled", every_other)
+
+
+def initial_dropped(patch):
+    """The initial block left to its score like any other."""
+    from benchmarks.tests import block_standin
+
+    forced = block_standin._forced_units
+
+    def no_initial(qpos, n_units, sizes):
+        return forced(qpos, n_units, dict(sizes, select_init_blocks=0))
+
+    patch(block_standin, "_forced_units", no_initial)
+
+
+def local_dropped(patch):
+    """The local window's blocks left to their scores, but for the
+    query's own."""
+    from benchmarks.tests import block_standin
+
+    forced = block_standin._forced_units
+
+    def no_local(qpos, n_units, sizes):
+        return forced(
+            qpos, n_units, dict(sizes, select_local=sizes["select_block"])
+        )
+
+    patch(block_standin, "_forced_units", no_local)
+
+
+def _lowest_free(index, chosen, forced):
+    """bool: each query's chosen free unit of lowest score."""
+    import jax.numpy as jnp
+
+    free = jnp.where(chosen & ~forced, index, jnp.inf)
+    lowest = jnp.argmin(free, axis=-1)[..., None]
+    return (jnp.arange(index.shape[-1]) == lowest) & jnp.isfinite(free)
+
+
+def _some_queries(qpos):
+    """One query in a hundred, [Q, 1]."""
+    return (qpos % EVERY == 7)[:, None]
+
+
+def short_row(patch):
+    """One block too few on 1% of the rows that chose any."""
+    from benchmarks.tests import block_standin
+
+    select = block_standin._select_units
+
+    def short(index, forced, k, qpos):
+        chosen = select(index, forced, k, qpos)
+        drop = _lowest_free(index, chosen, forced)
+        return chosen & ~(drop & _some_queries(qpos))
+
+    patch(block_standin, "_select_units", short)
+
+
+def future_block(patch):
+    """The block after the query's own in place of its lowest chosen
+    one, on 1% of the rows that chose any."""
+    import jax.numpy as jnp
+
+    from benchmarks.tests import block_standin
+
+    select = block_standin._select_units
+
+    def ahead(index, forced, k, qpos):
+        chosen = select(index, forced, k, qpos)
+        drop = _lowest_free(index, chosen, forced)
+        rows = _some_queries(qpos) & jnp.any(drop, -1, keepdims=True)
+        # a query sees units 0 .. own: as many as its finite scores
+        seen = jnp.sum(jnp.isfinite(index), -1, keepdims=True)
+        nxt = jnp.arange(index.shape[-1]) == seen
+        return (chosen & ~(drop & rows)) | (nxt & rows)
+
+    patch(block_standin, "_select_units", ahead)
+
+
+def group0_for_both(patch):
+    """Every KV head's queries attend under KV head 0's selection, and
+    that is what is handed over for each."""
+    import jax.numpy as jnp
+
+    from benchmarks.tests import block_standin
+
+    patch(
+        block_standin, "_group_selection",
+        lambda chosen: jnp.broadcast_to(chosen[:, :1], chosen.shape),
+    )
+
+
+def blocks_ignored(patch):
+    """A valid selection handed over while the attention runs over
+    every visible key: the sparse attention not applied at all."""
+    import jax.numpy as jnp
+
+    from benchmarks.tests import block_standin
+
+    patch(
+        block_standin, "_attended",
+        lambda keys, visible: jnp.broadcast_to(visible, keys.shape),
+    )
+
+
+def pool_8bit(patch):
+    """The query and the pooled keys rounded to 8 bits (e4m3) before
+    their product. Its verdict is RECORDED, pass or fail (PERF.md
+    section 4, PR 56): it decides what a later ``perf_opt`` may do."""
+    import jax.numpy as jnp
+
+    from benchmarks.tests import block_standin
+
+    def rounded(q, pooled):
+        return tuple(
+            a.astype(jnp.float8_e4m3fn).astype(a.dtype) for a in (q, pooled)
+        )
+
+    patch(block_standin, "_pool_inputs", rounded)
+
+
+# defect -> the checks of which at least one has to read not ok; None:
+# a defect whose verdict is recorded, not required
+BLOCK_CAUGHT_BY = {
+    "recent_blocks": ("selection_regret",),
+    "one_head_scores": ("selection_regret", "selection_moved"),
+    "block_mean": ("selection_regret", "selection_moved"),
+    "pool_no_overlap": ("selection_regret", "selection_moved"),
+    "initial_dropped": ("selection_forced",),
+    "local_dropped": ("selection_forced",),
+    "short_row": ("selection_valid",),
+    "future_block": ("selection_valid",),
+    "group0_for_both": ("selection_regret",),
+    "blocks_ignored": ("logits_rms_vs_reference", "logits_vs_reference"),
+    "pool_8bit": None,
+}
+BLOCK_INJECT = {
+    "recent_blocks": recent_blocks, "one_head_scores": one_head_scores,
+    "block_mean": block_mean, "pool_no_overlap": pool_no_overlap,
+    "initial_dropped": initial_dropped, "local_dropped": local_dropped,
+    "short_row": short_row, "future_block": future_block,
+    "group0_for_both": group0_for_both, "blocks_ignored": blocks_ignored,
+    "pool_8bit": pool_8bit,
+}

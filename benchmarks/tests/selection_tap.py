@@ -96,7 +96,7 @@ def install(patch):
     patch(decoder, "_attention_block", block)
 
 
-def logits_and_choices(params, tokens, cfg):
+def logits_and_choices(params, tokens, cfg, sizes=None):
     """In ``selected.program_logits_and_choices``'s place, on a program
     ``install`` was applied to."""
     global _emit

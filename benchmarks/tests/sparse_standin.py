@@ -230,9 +230,17 @@ def forward(params, tokens, sizes, q_block=512):
 
 
 def judge(reference, params, batch, sizes, q_block, tolerances):
+    """``judge_forward`` with this stand-in in the program's place."""
+    return judge_forward(
+        forward, reference, params, batch, sizes, q_block, tolerances
+    )
+
+
+def judge_forward(forward, reference, params, batch, sizes, q_block,
+                  tolerances):
     """What the runner does for a ``selected`` configuration
-    (``runners/train.py::_check_outputs``), with this stand-in in the
-    program's place: (checks as ``(name, ok, value, limit)``, the
+    (``runners/train.py::_check_outputs``), with a stand-in's ``forward``
+    in the program's place: (checks as ``(name, ok, value, limit)``, the
     ``BENCH reference`` record with the free-running errors in it)."""
     from benchmarks.lib import routed
 
@@ -253,10 +261,9 @@ def judge(reference, params, batch, sizes, q_block, tolerances):
         return (ref_loss, *routed.logit_errors(logits, ref_logits))
 
     logits, aux, ce = program(params, batch)
-    losses = {
-        "loss": float(ce), "ce_loss": float(ce),
-        "indexer_loss": float(aux["indexer_loss"]),
-    }
+    losses = {"loss": float(ce), "ce_loss": float(ce)}
+    if "indexer_loss" in aux:  # a stand-in with an indexer to align
+        losses["indexer_loss"] = float(aux["indexer_loss"])
     ref_loss, logit_err, logit_rms = (
         float(x) for x in against_free(params, batch, logits)
     )

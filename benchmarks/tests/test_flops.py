@@ -83,6 +83,31 @@ def test_mean_span_of_a_selection():
     assert flops.mean_span(8192, topk=2048) == 1792.125
 
 
+def test_mean_span_of_a_selection_by_blocks():
+    """``block`` > 1: ``topk`` counts blocks, the query's own among them,
+    and a query sees the keys of its own up to itself."""
+    def brute(seq, k, b):
+        return sum(
+            (min(i // b + 1, k or seq) - 1) * b + i % b + 1
+            for i in range(seq)
+        ) / seq
+
+    for seq, k, b in ((64, 2, 8), (64, 0, 8), (64, 100, 16), (256, 6, 16),
+                      (16384, 64, 64)):
+        assert flops.mean_span(seq, 0, k, b) == brute(seq, k, b)
+    # MiniCPM-SALA's sparse layer at 16,384: row t >= 4,096 holds
+    # 63 x 64 + t mod 64 + 1 keys
+    assert flops.mean_span(16384, topk=64, block=64) == 3560.5
+    # blocks of one key are keys: the old count, bit for bit
+    for seq, k in ((8192, 2048), (4, 2), (128, 0)):
+        assert flops.mean_span(seq, 0, k, 1) == flops.mean_span(seq, 0, k)
+    # every block chosen is the causal span
+    assert flops.mean_span(256, block=16) == flops.mean_span(256)
+    for bad in ((100, 0, 2, 16), (256, 64, 2, 16)):
+        with pytest.raises(ValueError):
+            flops.mean_span(*bad)
+
+
 def test_a_selecting_layer_and_its_indexer_by_hand():
     """Clauses (i) and (ii) at Keye-VL-2.0's language widths (32 heads
     of 128 score and 128 value channels; an indexer of 16 heads of 64

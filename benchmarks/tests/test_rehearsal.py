@@ -328,7 +328,7 @@ def test_routed_comparison_catches(monkeypatch, capsys, defect):
 def _corrupt(how):
     from benchmarks.tests import choices_tap
 
-    def logits_and_choices(params, tokens, cfg):
+    def logits_and_choices(params, tokens, cfg, sizes=None):
         logits, ids = choices_tap.logits_and_choices(params, tokens, cfg)
         if how == "duplicate":
             ids = ids.at[1, 0, 5, 1].set(ids[1, 0, 5, 0])

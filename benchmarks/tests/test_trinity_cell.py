@@ -178,7 +178,9 @@ def test_manifest_lists_the_cell_and_its_four_metrics():
         "swa.window_share", "swa.full_share", "swa.gate_share",
         "swa.flash_roofline",
     ]
-    assert manifest["per_layer"][-4:] == ours
+    # found by name: later cells append their metrics behind these
+    names = [m["name"] for m in manifest["per_layer"]]
+    assert all(names.count(m["name"]) == 1 for m in ours)
     for m in ours:
         assert m["workloads"] == [CELL] and m["unit"] == "%"
         assert m["moves"] == "train_tokens_per_s"
