@@ -174,7 +174,10 @@ class ModelConfig:
     # a trunk made of PARTS, x <- x + part(norm(x)) each
     # (NemotronH's ``hybrid_override_pattern``; "" = every layer an
     # attention and an MLP): one letter a part, ``M`` a Mamba-2 mixer,
-    # ``m`` a Mamba-1 mixer, ``*`` an attention, ``E`` the routed
+    # ``m`` a Mamba-1 mixer, ``*`` an attention, ``S`` a block-sparse
+    # attention (``sparse_block``; no rope), ``L`` a lightning linear
+    # attention (``n_head`` heads of their own k and v, rope, a fixed
+    # decay a head, a norm over the whole read-out and a gate on it), ``E`` the routed
     # experts, ``-`` a dense MLP of ``d_ff``. A ``-`` that follows
     # another part is the second part of that part's LAYER (a mixer +
     # MLP layer, pre-norm twice: ``m-``, ``*-``); every other letter is
@@ -212,6 +215,32 @@ class ModelConfig:
     # every channel, D = 1
     mamba_expand: int = 0
     mamba_dt_rank: int = 0
+    # a selection of BLOCKS of keys with no parameters of its own
+    # (InfLLM-v2, MiniCPM4's ``sparse_config``; the ``S`` part of a
+    # ``layer_pattern``; 0 = none): keys mean-pooled over windows of
+    # ``pool_window`` every ``pool_stride`` are scored by every query
+    # head (softmax over the pooled keys whose window has ended), the
+    # probabilities summed over the query heads of a KV head, a block of
+    # ``sparse_block`` keys scored by the max over the pooled keys that
+    # overlap it; the first ``select_init_blocks`` blocks and those of
+    # the last ``select_local`` keys are taken whatever their score and
+    # the best of the rest fill up to ``index_topk`` blocks a query and
+    # KV head, ties to the lower block. Not differentiated. A sequence
+    # of at most ``select_dense_len`` tokens runs plain causal
+    # attention. ``index_chunk`` queries are scored at a time
+    sparse_block: int = 0
+    pool_window: int = 0
+    pool_stride: int = 0
+    select_init_blocks: int = 0
+    select_local: int = 0
+    select_dense_len: int = 0
+    # a ``layer_pattern`` model's three multipliers (MiniCPM's
+    # ``scale_emb``; ``scale_depth`` / sqrt(the PUBLISHED depth) on
+    # every part's output; ``dim_model_base`` / d_model on the last
+    # hidden state before the head)
+    scale_emb: float = 1.0
+    residual_scale: float = 1.0
+    logit_scale: float = 1.0
     # a latent around the routed experts (LatentMoE; 0 = none): the
     # router and the shared expert read the d_model-wide input, the
     # experts a projection of it to this width, and one projection back
@@ -344,7 +373,18 @@ class ModelConfig:
                     "latent attention is rope on its own channels, MHA, "
                     "no qk_norm"
                 )
-        if self.index_topk:
+        if self.sparse_block and "S" not in self.layer_pattern:
+            raise ValueError(
+                "sparse_block is the S part of a layer_pattern model"
+            )
+        if (self.scale_emb, self.residual_scale, self.logit_scale) != (
+            1.0, 1.0, 1.0
+        ) and not self.layer_pattern:
+            raise ValueError(
+                "scale_emb, residual_scale and logit_scale are a "
+                "layer_pattern model's multipliers"
+            )
+        if self.selects_keys:
             if not (self.index_n_heads > 0 and self.index_head_dim > 0
                     and self.index_head_dim % 2 == 0):
                 raise ValueError(
@@ -385,13 +425,15 @@ class ModelConfig:
                     "prediction module or fp8"
                 )
         if (self.attn_gate or self.post_norm) and (
-            self.latent_attention or self.selects_keys or self.layer_pattern
+            self.latent_attention or self.selects_keys
+            or (self.layer_pattern and self.post_norm)
             or self.parallel_residual or self.fp8
         ):
             raise ValueError(
                 "attn_gate and post_norm are built into the plain "
                 "attention layer: no latent attention, key selection, "
-                "layer_pattern, parallel residual or fp8"
+                "parallel residual or fp8, and no post_norm in a "
+                "layer_pattern model"
             )
         if self.n_mtp_module not in (0, 1):
             raise ValueError(
@@ -429,12 +471,13 @@ class ModelConfig:
     def _check_pattern(self):
         """A ``layer_pattern`` model: what its letters need."""
         for name in ("layer_pattern", "mtp_pattern"):
-            odd = set(getattr(self, name)) - set("Mm*E-")
+            odd = set(getattr(self, name)) - set("Mm*SLE-")
             if odd:
                 raise ValueError(
                     f"{name} is made of M (Mamba-2), m (Mamba-1), * "
-                    f"(attention), E (routed experts) and - (dense MLP); "
-                    f"got {sorted(odd)}"
+                    f"(attention), S (block-sparse attention), L "
+                    f"(lightning attention), E (routed experts) and - "
+                    f"(dense MLP); got {sorted(odd)}"
                 )
         if pattern_layers(self.layer_pattern) != self.n_layer:
             raise ValueError(
@@ -471,6 +514,46 @@ class ModelConfig:
                 "a Mamba-1 part needs mamba_expand, mamba_dt_rank, "
                 "ssm_state_size and conv_kernel"
             )
+        if set("SL") & set(self.mtp_pattern):
+            raise ValueError(
+                "S and L parts are the trunk's: a prediction module's "
+                "selection is handed over by no one"
+            )
+        if "S" in letters:
+            sizes = (
+                self.sparse_block, self.pool_window, self.pool_stride,
+                self.index_topk, self.select_local,
+            )
+            if not all(n > 0 for n in sizes) or self.select_init_blocks < 0:
+                raise ValueError(
+                    "an S part needs sparse_block, pool_window, "
+                    "pool_stride, index_topk and select_local"
+                )
+            if (
+                self.sparse_block % self.pool_stride
+                or self.pool_window % self.pool_stride
+                or self.select_local % self.sparse_block
+                or self.index_topk
+                < self.select_init_blocks
+                + self.select_local // self.sparse_block
+            ):
+                raise ValueError(
+                    "pool_stride divides pool_window and sparse_block, "
+                    "sparse_block divides select_local, and the forced "
+                    "blocks (select_init_blocks and the local window's) "
+                    "fit in index_topk"
+                )
+            if not self.causal or self.attn_window:
+                raise ValueError(
+                    "a selection of blocks runs under the plain causal mask"
+                )
+        elif self.index_topk or self.sparse_block:
+            raise ValueError(
+                "index_topk and sparse_block in a layer_pattern model are "
+                "its S parts'"
+            )
+        if "L" in letters and self.head_dim % 2:
+            raise ValueError("an L part turns q and k by rope: an even head")
         if "-" in letters and self.act not in ("swiglu", "gelu"):
             raise ValueError(
                 "a - part is the dense MLP of d_ff: act 'swiglu' or 'gelu'"
@@ -486,13 +569,13 @@ class ModelConfig:
             self.n_dense_layer or self.latent_attention or self.selects_keys
             or self.parallel_residual or self.prefix_lm or self.fp8
             or self.norm != "rmsnorm" or self.pos == "learned"
-            or self.qk_norm or self.qk_head_norm
+            or self.qk_norm
         ):
             raise ValueError(
                 "a layer_pattern model is RMSNorm parts, x + part(norm(x)) "
-                "each: no dense prefix, latent attention, key selection, "
-                "parallel residual, prefix-LM, fp8, position table or "
-                "q/k norm"
+                "each: no dense prefix, latent attention, learned key "
+                "selection, parallel residual, prefix-LM, fp8, position "
+                "table or whole-projection q/k norm"
             )
 
     @property
@@ -537,7 +620,43 @@ class ModelConfig:
 
     @property
     def selects_keys(self) -> bool:
-        return self.index_topk > 0
+        """A LEARNED selection of keys (the indexer); a selection of
+        blocks by the keys themselves is ``selects_blocks``."""
+        return self.index_topk > 0 and not self.sparse_block
+
+    @property
+    def selects_blocks(self) -> bool:
+        return self.sparse_block > 0
+
+    @property
+    def select_block(self) -> int:
+        """Keys a unit of the selection, and ``select_groups``, the
+        selections a selecting layer makes (one a KV head), under the
+        names the benchmark's ``selected`` comparison states them by
+        (``benchmarks/lib/selected.py``). ONLY a model that selects
+        blocks has them: the comparison reads their absence as "keys,
+        one selection a layer", so elsewhere they are no attribute."""
+        if not self.sparse_block:
+            raise AttributeError("select_block: this model selects no blocks")
+        return self.sparse_block
+
+    @property
+    def select_groups(self) -> int:
+        if not self.sparse_block:
+            raise AttributeError("select_groups: this model selects no blocks")
+        return self.kv_heads
+
+    def selects_at(self, seq_len: int) -> bool:
+        """Whether an ``S`` part's queries choose at ``seq_len``: a
+        sequence of at most ``select_dense_len`` runs plain causal
+        attention."""
+        return self.selects_blocks and seq_len > self.select_dense_len
+
+    @property
+    def lightning_params(self) -> int:
+        """One ``L`` part's matrices: q, k, v, the output gate and o,
+        ``n_head`` heads of ``head_dim`` each."""
+        return 5 * self.d_model * self.n_head * self.head_dim
 
     def kind_window(self, kind: str = "") -> int:
         """Keys a query of a layer of ``kind`` may see (0 = every
@@ -601,6 +720,12 @@ class ModelConfig:
         of plain-attention layers."""
         if set("Mm") & set(self.layer_pattern + self.mtp_pattern):
             return "state-space layers: no recurrent state beside the cache"
+        if "L" in self.layer_pattern:
+            return (
+                "lightning (L) layers: no recurrent state beside the cache"
+            )
+        if "S" in self.layer_pattern:
+            return "block-sparse (S) layers: a selection has no cache path"
         if self.layer_pattern:
             return "a trunk whose layers differ"
         if self.latent_attention:
@@ -642,6 +767,8 @@ class ModelConfig:
         low = rank + 2 * self.ssm_state_size       # [Δ's rank | B | C]
         mamba1 = 2 * d * inner1 + inner1 * low + rank * inner1 + inner1 * d
         mlp = (3 if self.act == "swiglu" else 2) * d * self.d_ff
+        d_attn = self.n_head * self.head_dim
+        qk_scales = 2 * self.head_dim * self.qk_head_norm
         return {
             "m": (
                 mamba1 + inner1 * (self.conv_kernel + 1)   # conv, its bias
@@ -655,7 +782,13 @@ class ModelConfig:
                 + 3 * heads + inner + d,
                 mamba + 2 * inner * self.ssm_state_size,
             ),
-            "*": (attn + d, attn),
+            "*": (attn + d + qk_scales, attn),
+            "S": (attn + d + qk_scales, attn),
+            # the recurrence's update and read-out: 2 x inner x state
+            "L": (
+                self.lightning_params + d_attn + d + qk_scales,
+                self.lightning_params + 2 * d_attn * self.head_dim,
+            ),
             "E": (
                 outside + self.experts_here * expert + d,
                 outside + (
@@ -719,7 +852,8 @@ class ModelConfig:
     def n_attention_layers(self) -> int:
         """Layers with an attention, the prediction module's included."""
         if self.layer_pattern:
-            return (self.layer_pattern + self.mtp_pattern).count("*")
+            letters = self.layer_pattern + self.mtp_pattern
+            return letters.count("*") + letters.count("S")
         return self.n_layer + self.n_mtp_module
 
     def executed_span(self, seq_len: int, kind: str = "") -> float:
@@ -767,9 +901,17 @@ class ModelConfig:
                 )
             )
             span = self.executed_span(seq_len)
-            return (
-                6.0 * multiplied
-                + 12.0 * self.n_attention_layers * d_attn * span
+            pairs = d_attn * span
+            if self.selects_at(seq_len):
+                # an S part counts the keys of the blocks a query chose
+                # and, at half a pair-channel, the pooled keys it scored
+                pairs = d_attn * selected_span(
+                    seq_len, self.index_topk, self.sparse_block
+                ) + d_attn / 2 * span / self.pool_stride
+            stars = (self.layer_pattern + self.mtp_pattern).count("*")
+            return 6.0 * multiplied + 12.0 * (
+                stars * d_attn * span
+                + self.layer_pattern.count("S") * pairs
             )
         if self.latent_attention:
             attn = (
@@ -837,6 +979,26 @@ def mean_span(seq_len: int, window: int = 0) -> float:
     of ``seq_len``: query i sees min(i + 1, ``window`` or seq_len)."""
     w = min(window or seq_len, seq_len)
     return (w * (w + 1) / 2 + (seq_len - w) * w) / seq_len
+
+
+def selected_span(seq_len: int, topk: int, block: int) -> float:
+    """Keys a query attends to where it selects at most ``topk`` BLOCKS
+    of ``block`` keys, its own among them, averaged over a sequence of
+    whole blocks: min(i // block + 1, topk) - 1 whole blocks and
+    i mod block + 1 keys of its own."""
+    units = seq_len // block
+    k = min(topk, units)
+    whole = k * (k - 1) // 2 + (units - k) * (k - 1)
+    return (
+        whole * block * block + units * block * (block + 1) / 2
+    ) / seq_len
+
+
+def lightning_log_decay(n_head: int):
+    """float [n_head]: log λ_h = -2^(-8 h / n_head), h = 1 .. n_head —
+    Lightning Attention's fixed slope a head, a constant and no
+    parameter, the same in every ``L`` part."""
+    return [-(2.0 ** (-8.0 * h / n_head)) for h in range(1, n_head + 1)]
 
 
 def mup_base_config(cfg: "ModelConfig") -> "ModelConfig":
@@ -1165,6 +1327,53 @@ CONFIGS = {
         mamba_dt_rank=160,
         ssm_state_size=16,
         conv_kernel=4,
+    ),
+    # block-sparse attention in one layer of four and lightning linear
+    # attention in the rest, every layer a mixer and a dense MLP:
+    # MiniCPM-SALA (``minicpm_sala``, 9B;
+    # huggingface.co/openbmb/MiniCPM-SALA config.json) — 32 layers over
+    # d 4096 in ``mixer_types``' order, each x + s mixer(norm(x)) then
+    # x + s MLP(norm(x)) with s = scale_depth 1.4 / sqrt(32); 8
+    # ``minicpm4`` mixers (``S``: GQA 32 / 2 heads of 128, per-head
+    # RMSNorm on q and k, no rope, a sigmoid output gate, InfLLM-v2's
+    # selection of 64 blocks of 64 keys a KV head from keys pooled 32
+    # every 16 — MiniCPM4's ``sparse_config``, assumed) among 24
+    # ``lightning-attn`` mixers (``L``: 32 heads of 128 with their own
+    # k and v, per-head RMSNorm and rope on q and k, a fixed decay a
+    # head, an output norm and gate); SwiGLU 16,384; embeddings x 12,
+    # the last hidden state x 256 / 4096; vocabulary 73,448 untied.
+    # Training path only
+    "minicpm-sala": ModelConfig(
+        name="minicpm-sala",
+        vocab_size=73448,
+        n_layer=32,
+        layer_pattern="".join(
+            c + "-" for c in "SLLLLLLLLSLLLLLLSSLLLLSLLLLLLSSS"
+        ),
+        n_head=32,
+        n_kv_head=2,
+        d_head=128,
+        d_model=4096,
+        d_ff=16384,
+        max_seq=524288,
+        act="swiglu",
+        pos="rope",  # the L parts' (lightning_use_rope); the S parts none
+        rope_theta=10000.0,
+        attn_window=None,
+        tie_embeddings=False,
+        qk_head_norm=True,
+        attn_gate=True,
+        norm_eps=1e-6,
+        index_topk=64,
+        sparse_block=64,
+        pool_window=32,
+        pool_stride=16,
+        select_init_blocks=1,
+        select_local=2048,
+        select_dense_len=8192,
+        scale_emb=12.0,
+        residual_scale=1.4 / 32 ** 0.5,
+        logit_scale=256 / 4096,
     ),
     # an attention kind per layer, a gate on the attention's output,
     # four norms a layer: Trinity-Mini (``afmoe``, 26B-A3B;

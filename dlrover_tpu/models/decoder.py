@@ -29,7 +29,9 @@ from jax.ad_checkpoint import checkpoint_policies as cp
 from jax.sharding import PartitionSpec as P
 
 from dlrover_tpu.common import device
-from dlrover_tpu.models.config import ModelConfig, pattern_parts
+from dlrover_tpu.models.config import (
+    ModelConfig, lightning_log_decay, pattern_parts,
+)
 from dlrover_tpu.observability.tracing import set_counter
 from dlrover_tpu.ops import pallas_norm, pallas_paged, quant
 from dlrover_tpu.ops.attention import _repeat_kv, mha_reference
@@ -146,11 +148,12 @@ def _init_layers(keys, cfg: ModelConfig, lead, routed: bool) -> Params:
 
 
 # a ``layer_pattern`` letter -> the name its stack of parts goes by:
-# M a Mamba-2 mixer, m a Mamba-1 mixer, * an attention, E the routed
-# experts, - a dense MLP
+# M a Mamba-2 mixer, m a Mamba-1 mixer, * an attention, S a block-sparse
+# attention, L a lightning linear attention, E the routed experts, - a
+# dense MLP
 PART_NAMES = {
-    "M": "mamba", "m": "mamba1", "*": "attention", "E": "experts",
-    "-": "mlp",
+    "M": "mamba", "m": "mamba1", "*": "attention", "S": "sparse",
+    "L": "lightning", "E": "experts", "-": "mlp",
 }
 
 
@@ -161,14 +164,16 @@ def _pattern_runs(pattern: str):
     unit of layers that repeats at least once more at once, all its
     repeats; a layer that starts no such unit runs unrolled (repeats 1).
     A unit with a routed part (``E``) never qualifies: its choices ride
-    out layer by layer and its jitter folds the layer's index in."""
+    out layer by layer and its jitter folds the layer's index in; nor
+    one with a block-sparse attention (``S``), whose selection rides out
+    likewise."""
     layers = pattern_parts(pattern)
     runs, i = [], 0
     while i < len(layers):
         unit, reps = layers[i:i + 1], 1
         for p in range(1, (len(layers) - i) // 2 + 1):
             cand = layers[i:i + p]
-            if "E" in "".join(cand):
+            if set("ES") & set("".join(cand)):
                 break
             n = 1
             while layers[i + n * p:i + (n + 1) * p] == cand:
@@ -303,6 +308,27 @@ def _init_mamba1(key, cfg: ModelConfig, lead) -> Params:
     }
 
 
+def _init_lightning(key, cfg: ModelConfig, lead) -> Params:
+    """A lightning linear attention's parameters: q, k, v, the output
+    gate and o at ``n_head`` heads of ``head_dim`` each, a scale of
+    ``head_dim`` for each of the two per-head norms (q and k) and one a
+    channel for the norm over the whole read-out. The decay is a
+    constant (``config.lightning_log_decay``)."""
+    d, wide, hd = cfg.d_model, cfg.n_head * cfg.head_dim, cfg.head_dim
+    stack, ones = _stackers(cfg, lead)
+    k = jax.random.split(key, 5)
+    return {
+        "wq": stack(k[0], (d, wide), d),
+        "wk": stack(k[1], (d, wide), d),
+        "wv": stack(k[2], (d, wide), d),
+        "wg": stack(k[3], (d, wide), d),
+        "wo": stack(k[4], (wide, d), wide),
+        "q_norm": {"scale": ones(hd)},
+        "k_norm": {"scale": ones(hd)},
+        "o_norm": {"scale": ones(wide)},
+    }
+
+
 def _init_mlp(keys, cfg: ModelConfig, stack) -> Params:
     """The dense MLP's matrices: SwiGLU's three, or two."""
     d, f = cfg.d_model, cfg.d_ff
@@ -328,10 +354,15 @@ def _init_pattern(key, cfg: ModelConfig, pattern: str) -> Params:
             layer["ssm"] = _init_mamba(kk, cfg, lead)
         elif letter == "m":
             layer["ssm1"] = _init_mamba1(kk, cfg, lead)
-        elif letter == "*":
+        elif letter in "*S":
             layer["attn"] = _init_attention(
                 jax.random.split(kk, 16), cfg, stack, ones
             )
+            if cfg.qk_head_norm:
+                layer["attn"]["q_norm"] = {"scale": ones(cfg.head_dim)}
+                layer["attn"]["k_norm"] = {"scale": ones(cfg.head_dim)}
+        elif letter == "L":
+            layer["lin"] = _init_lightning(kk, cfg, lead)
         elif letter == "-":
             layer["mlp"] = _init_mlp(jax.random.split(kk, 16), cfg, stack)
         else:
@@ -467,8 +498,23 @@ def _pattern_axes(cfg: ModelConfig, pattern: str, lead) -> Params:
                 "d_skip": lead + ("mlp",),
                 "w_out": lead + ("mlp", "embed"),
             }
-        elif letter == "*":
+        elif letter in "*S":
             layer["attn"] = _attention_axes(cfg, lead)
+            if cfg.qk_head_norm:
+                layer["attn"]["q_norm"] = {"scale": lead + ("norm",)}
+                layer["attn"]["k_norm"] = {"scale": lead + ("norm",)}
+        elif letter == "L":
+            layer["lin"] = {
+                **{
+                    w: lead + ("embed", "heads")
+                    for w in ("wq", "wk", "wv", "wg")
+                },
+                "wo": lead + ("heads", "embed"),
+                **{
+                    n: {"scale": lead + ("norm",)}
+                    for n in ("q_norm", "k_norm", "o_norm")
+                },
+            }
         elif letter == "-":
             layer["mlp"] = _mlp_axes(cfg, lead)
         else:
@@ -947,42 +993,56 @@ def _select_keys(index, qpos, topk: int):
     ties at it are cut at the position that fills the room, found the
     same way over the bits of s. ``lax.top_k`` and a sort give the same
     set and cost a sort; ``approx_max_k`` gives another set."""
-    b, nq, sk = index.shape
-    kpos = jnp.arange(sk, dtype=jnp.int32)
+    kpos = jnp.arange(index.shape[-1], dtype=jnp.int32)
     visible = kpos[None, :] <= qpos[:, None]
+    keys = _sortable_keys(index, visible)
+    want = jnp.minimum(qpos + 1, topk).astype(jnp.int32)[None, :]
+    return _top_of(keys, want, kpos)
+
+
+def _sortable_keys(index, live):
+    """uint32 like ``index`` [..., Q, U] float32: keys that order as the
+    scores do, 0 — under every float's key, -inf's too — at the places
+    that are not ``live`` (bool [Q, U], or with ``index``'s leading
+    axes)."""
     index = jnp.where(index == 0, 0.0, index)  # -0.0 ranks as +0.0
     bits = jax.lax.bitcast_convert_type(index, jnp.uint32)
     keys = jnp.where(
         bits >> 31 == 1, ~bits, bits | jnp.uint32(0x80000000)
     )
-    # 0 lies under every float's key, -inf's too
-    keys = jnp.where(visible[None], keys, jnp.uint32(0))
-    want = jnp.minimum(qpos + 1, topk).astype(jnp.int32)[None, :]
+    live = jnp.expand_dims(live, tuple(range(index.ndim - live.ndim)))
+    return jnp.where(live, keys, jnp.uint32(0))
+
+
+def _top_of(keys, want, place):
+    """bool like ``keys`` [..., Q, U] (``_sortable_keys``): in each row
+    the ``want`` [..., Q] (broadcast; at most the row's live places)
+    places of largest key, ties to the lower place (``place``: arange
+    U); none where ``want`` is 0. ``_select_keys``' bisection."""
+    lead, n = keys.shape[:-1], keys.shape[-1]
 
     def value_bit(i, kth):
         cand = kth | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
         count = jnp.sum(keys >= cand[..., None], axis=-1, dtype=jnp.int32)
         return jnp.where(count >= want, cand, kth)
 
-    kth = jax.lax.fori_loop(
-        0, 32, value_bit, jnp.zeros((b, nq), jnp.uint32)
-    )
+    kth = jax.lax.fori_loop(0, 32, value_bit, jnp.zeros(lead, jnp.uint32))
     above, ties = keys > kth[..., None], keys == kth[..., None]
     room = want - jnp.sum(above, axis=-1, dtype=jnp.int32)  # >= 1
-    n_bits = max(1, (sk - 1).bit_length())
+    n_bits = max(1, (n - 1).bit_length())
 
     def place_bit(i, last):
         # the largest position with fewer than ``room`` ties before it
         cand = last | (jnp.int32(1) << (n_bits - 1 - i))
         before = jnp.sum(
-            ties & (kpos < cand[..., None]), axis=-1, dtype=jnp.int32
+            ties & (place < cand[..., None]), axis=-1, dtype=jnp.int32
         )
         return jnp.where(before < room, cand, last)
 
     last = jax.lax.fori_loop(
-        0, n_bits, place_bit, jnp.zeros((b, nq), jnp.int32)
+        0, n_bits, place_bit, jnp.zeros(lead, jnp.int32)
     )
-    return above | (ties & (kpos <= last[..., None]))
+    return above | (ties & (place <= last[..., None]))
 
 
 def _index_chunks(cfg: ModelConfig, s: int):
@@ -1188,6 +1248,243 @@ def _selecting_attention_block(
         aux["attn_selected"] = mask != 0
     out = out.reshape(b, s, nh * hd)
     return out @ layer["attn"]["wo"].astype(x.dtype), aux
+
+
+# ---------------------------------------------------------------------------
+# A selection of blocks of keys (InfLLM-v2) and lightning linear attention
+# ---------------------------------------------------------------------------
+
+
+def _pooled_keys(k, window: int, stride: int):
+    """k [B, S, KV, D] -> [B, P, KV, D] in k's dtype: pooled key j the
+    float32 mean of keys [stride j, stride j + window), P whole
+    windows; sums of ``stride`` keys first, then ``window // stride`` of
+    those side by side."""
+    b, s, kv, d = k.shape
+    count = (s - window) // stride + 1
+    parts = k.astype(jnp.float32).reshape(b, s // stride, stride, kv, d)
+    parts = jnp.sum(parts, axis=2)
+    total = sum(parts[:, o:o + count] for o in range(window // stride))
+    return (total / window).astype(k.dtype)
+
+
+def _head_sum(p):
+    """[B, G, heads of a KV head, Q, P] -> [B, G, Q, P]."""
+    return jnp.sum(p, axis=2)
+
+
+def _block_reduce(p):
+    """[..., U, the pooled keys that overlap a block] -> [..., U]."""
+    return jnp.max(p, axis=-1)
+
+
+def _forced_blocks(qpos, n_units: int, cfg: ModelConfig):
+    """bool [Q, U]: the blocks the query at ``qpos`` takes whatever
+    their score, among those it sees: the initial ones and its local
+    window's."""
+    own = (qpos // cfg.sparse_block)[:, None]
+    unit = jnp.arange(n_units, dtype=jnp.int32)[None, :]
+    near = own - unit < cfg.select_local // cfg.sparse_block
+    return (near | (unit < cfg.select_init_blocks)) & (unit <= own)
+
+
+def _block_scores(q, pooled, qpos, cfg: ModelConfig, n_units: int):
+    """q [B, Q, H, D] at positions ``qpos`` [Q], pooled [B, P, KV, D]
+    -> float32 [B, KV, Q, U]: a block's score for each KV head, the max
+    over the pooled keys that overlap it of the KV head's query heads'
+    summed probabilities; 0 where no pooled key has ended. The product
+    on the operands' dtype summed in float32, everything behind it
+    float32."""
+    b, nq, h, d = q.shape
+    n_pooled, kv = pooled.shape[1:3]
+    window, stride, block = cfg.pool_window, cfg.pool_stride, cfg.sparse_block
+    ended = (
+        stride * jnp.arange(n_pooled) + window - 1
+    )[None, :] <= qpos[:, None]
+    dots = _f32_dot(
+        "bqgrd,bpgd->bgrqp", q.reshape(b, nq, kv, h // kv, d), pooled
+    ) * d ** -0.5
+    p = jax.nn.softmax(jnp.where(ended, dots, -1e30), axis=-1)
+    p = _head_sum(jnp.where(ended, p, 0.0))  # none ended: zeros
+    # pooled keys r u - extra .. r u + r - 1 overlap block u
+    r, extra = block // stride, window // stride - 1
+    p = jnp.pad(p, [(0, 0)] * 3 + [(extra, r * n_units - n_pooled)])
+    over = jnp.stack(
+        [p[..., o:o + r * n_units:r] for o in range(r + extra)], axis=-1
+    )
+    return _block_reduce(over)
+
+
+def _select_blocks(q, k, cfg: ModelConfig):
+    """The selection of one ``S`` part, int8 [B, KV, S, U] (U = S /
+    ``sparse_block``): 1 where query t of a KV head's query heads
+    attends to block u. ``index_chunk`` queries at a time against every
+    pooled key (no float [H, S, P] is ever whole). No parameter of its
+    own and nothing differentiated: the caller detaches q and k."""
+    b, s, _, _ = q.shape
+    block, topk = cfg.sparse_block, cfg.index_topk
+    if s % block or s % cfg.pool_stride:
+        raise ValueError(
+            f"sequence {s} is no whole number of blocks of {block} keys"
+        )
+    n_units = s // block
+    pooled = _pooled_keys(k, cfg.pool_window, cfg.pool_stride)
+    chunk = min(cfg.index_chunk, s)
+    if s % chunk:
+        raise ValueError(f"sequence {s} is not a multiple of {chunk}")
+    place = jnp.arange(n_units, dtype=jnp.int32)
+    unit = place[None, :]
+
+    def rows(start):
+        qpos = start + jnp.arange(chunk, dtype=jnp.int32)
+        scores = _block_scores(
+            jax.lax.dynamic_slice_in_dim(q, start, chunk, 1), pooled, qpos,
+            cfg, n_units,
+        )
+        seen = unit <= (qpos // block)[:, None]
+        forced = _forced_blocks(qpos, n_units, cfg) & seen
+        free = seen & ~forced
+        want = jnp.minimum(
+            jnp.sum(free, -1), jnp.maximum(topk - jnp.sum(forced, -1), 0)
+        ).astype(jnp.int32)
+        best = _top_of(_sortable_keys(scores, free), want, place)
+        return (forced | best).astype(jnp.int8)
+
+    chosen = jax.lax.map(rows, jnp.arange(0, s, chunk, dtype=jnp.int32))
+    # [chunks, B, KV, Q, U] -> [B, KV, S, U]
+    return jnp.moveaxis(chosen, 0, 2).reshape(b, -1, s, n_units)
+
+
+def _block_sparse_attention(
+    x, layer, cfg: ModelConfig, mesh, positions, attn_fn,
+    return_selected: bool = False,
+):
+    """An ``S`` part on the layer's normed input ``x`` [B, S, D]: GQA
+    with a per-head RMSNorm on q and k and NO rope; past
+    ``select_dense_len`` tokens each KV head's query heads attend to the
+    keys up to themselves inside the blocks ``_select_blocks`` chose
+    (scope ``attn.block_select``; the named residual ``attn_selected``,
+    which ``remat: full`` keeps: the recomputed forward runs neither the
+    scorer nor the top-k), else to every earlier key; a sigmoid gate,
+    then ``W_o`` into float32. Returns (output, aux):
+    ``aux["sparse_attn_out_ms"]`` the mean square of the attention's
+    output before gate and ``W_o`` (float32; the part's one health
+    number), ``aux["attn_selected"]`` bool [KV, B, S, U] where
+    ``return_selected`` and the queries choose."""
+    b, s, _ = x.shape
+    f32 = jnp.float32
+    q, k, v = _constrain_qkv(
+        *_project_qkv(x, layer, cfg, positions, rope=False), mesh
+    )
+    aux = {}
+    if cfg.selects_at(s):
+        with jax.named_scope("attn.block_select"):
+            units = jax.ad_checkpoint.checkpoint_name(
+                _select_blocks(*jax.lax.stop_gradient((q, k)), cfg),
+                "attn_selected",
+            )
+        # units to keys, int8 [B, KV, S, S]; the causal mask inside a
+        # query's own block is the kernels'
+        keys = jnp.repeat(units, cfg.sparse_block, axis=-1)
+        out = attn_fn(q, k, v, selected=keys)[0]
+        if return_selected:
+            aux["attn_selected"] = jnp.moveaxis(units != 0, 1, 0)
+    else:
+        out = attn_fn(q, k, v)
+    out = out.reshape(b, s, -1)
+    aux["sparse_attn_out_ms"] = jax.lax.stop_gradient(
+        jnp.mean(jnp.square(out.astype(f32)))
+    )
+    attn = layer["attn"]
+    if cfg.attn_gate:
+        out = _gate_output(out, x, attn["wg"])
+    return jnp.matmul(
+        out, attn["wo"].astype(x.dtype), preferred_element_type=f32
+    ), aux
+
+
+def _lightning_decay(cfg: ModelConfig):
+    """float32 [n_head]: log λ_h, a constant."""
+    return jnp.asarray(lightning_log_decay(cfg.n_head), jnp.float32)
+
+
+def _lightning_block(h, lin, cfg: ModelConfig, mesh, rope):
+    """A lightning linear attention on the layer's normed input ``h``
+    [B, S, D] (scope ``lin``; inside it ``ssm.scan`` from ``ssd_scan``):
+
+        q, k, v = h W_q, h W_k, h W_v        (n_head heads of head_dim)
+        q, k = rope(rms_head(q)), rope(rms_head(k))
+        S_t = λ_h S_{t-1} + k_tᵀ v_t;  o_t = q_t S_t / sqrt(head_dim)
+        out = (rms(o) ⊙ sigmoid(h W_g)) W_o
+
+    The recurrence is ``ops/ssd.py``'s chunked scan at one head a group
+    with x = v, Δ ≡ 1, A = log λ, B = k, C = q / sqrt(head_dim): the
+    state and the cumulative log-decays float32. q and k are normed,
+    turned (``rope`` None: no positions) and scaled in float32 and
+    rounded once; the output norm, the gate and the product with it
+    float32, ``W_o`` into float32. The output norm's statistic is over
+    the WHOLE read-out, every head's channels together (Lightning
+    Attention's own form): over one head's alone, a token's output would
+    be a unit vector however small its read-out, and at the first tokens,
+    whose read-out is (q_0 . k_0) v_0 and little more, rounding near
+    q_0 . k_0 = 0 decides its sign (PERF.md section 6, PR 57: one seed
+    in thirteen failed ``logits_vs_reference`` at position 0).
+
+    Returns (output, aux): ``aux["lightning_fast_out_ms"]`` the mean
+    square of the read-out ``o`` of the quarter of the heads that forget
+    fastest (the first ``n_head // 4``, at least one), before the norm
+    (float32). Normed whole, those heads hold about a hundredth of the
+    read-out's energy, so the logits do not see what garbles them alone
+    (a running log-decay kept in eight bits: its sum passes 200 inside a
+    chunk there); this number does."""
+    from dlrover_tpu.ops import ssd
+
+    b, s, _ = h.shape
+    nh, hd = cfg.n_head, cfg.head_dim
+    dt_, f32 = h.dtype, jnp.float32
+
+    def heads(w, norm=None, scale=1.0):
+        t = (h @ w.astype(dt_)).reshape(b, s, nh, hd)
+        if norm is None:
+            return t
+        t = _head_norm(t.astype(f32), norm["scale"], cfg)
+        if rope is not None:
+            t = _rope(t, rope)
+        return (t * scale).astype(dt_)
+
+    q = heads(lin["wq"], lin["q_norm"], hd ** -0.5)
+    k = heads(lin["wk"], lin["k_norm"])
+    v = heads(lin["wv"])
+    # (the mesh only where it rules the kernels out: ``_mamba_block``)
+    several = {"mesh": mesh} if mesh is not None and mesh.size > 1 else {}
+    o = ssd.ssd_scan(
+        v, jnp.ones((b, s, nh), f32), _lightning_decay(cfg), k, q,
+        cfg.ssm_chunk, 0, **several,
+    )
+    o = o.astype(f32)
+    aux = {"lightning_fast_out_ms": jax.lax.stop_gradient(
+        jnp.mean(jnp.square(o[:, :, :max(1, nh // 4)]))
+    )}
+    o = _head_norm(o.reshape(b, s, nh * hd), lin["o_norm"]["scale"], cfg)
+    o = _lightning_gate(o, h, lin["wg"])
+    return jnp.matmul(
+        o.astype(dt_), lin["wo"].astype(dt_), preferred_element_type=f32
+    ), aux
+
+
+def _head_norm(t, scale, cfg: ModelConfig):
+    """RMSNorm over the last axis: each head's channels of a lightning
+    part's q and k [B, S, H, D] (one learned scale of D for all heads),
+    the whole of its read-out [B, S, H·D]."""
+    return _norm(t, scale, None, "rmsnorm", cfg.norm_eps)
+
+
+def _lightning_gate(o, h, w_gate):
+    """``o ⊙ sigmoid(h W_g)``, float32 [B, S, H·D]."""
+    gate = jnp.matmul(
+        h, w_gate.astype(h.dtype), preferred_element_type=jnp.float32
+    )
+    return o * jax.nn.sigmoid(gate)
 
 
 def _mlp_block(x, layer, cfg: ModelConfig, mesh, fp8=None, interior=None):
@@ -1410,17 +1707,22 @@ def _mamba1_block(h, ssm, cfg: ModelConfig, mesh):
 
 
 # the scope a part's operations are traced under
-_PART_SCOPES = {"M": "ssm", "m": "ssm1", "*": "attn", "E": "mlp", "-": "mlp"}
+_PART_SCOPES = {
+    "M": "ssm", "m": "ssm1", "*": "attn", "S": "attn", "L": "lin",
+    "E": "mlp", "-": "mlp",
+}
 
 
 def _part_body(
     x, layer, positions, *, letter, cfg: ModelConfig, mesh, attn_fn,
-    rng=None, rope=None,
+    rng=None, rope=None, return_selected: bool = False,
 ):
-    """One part of a ``layer_pattern`` model, ``x + part(norm(x))``:
-    a Mamba-2 mixer (``M``), a Mamba-1 mixer (``m``), an attention
-    (``*``), the routed experts (``E``) or a dense MLP (``-``). Returns
-    (x, the routed block's aux or {})."""
+    """One part of a ``layer_pattern`` model, ``x + s part(norm(x))``
+    (s = ``cfg.residual_scale``): a Mamba-2 mixer (``M``), a Mamba-1
+    mixer (``m``), an attention (``*``), a block-sparse attention
+    (``S``), a lightning linear attention (``L``), the routed experts
+    (``E``) or a dense MLP (``-``). Returns (x, the routed block's, the
+    block-sparse attention's or the lightning part's aux, or {})."""
     aux = {}
     with jax.named_scope(_PART_SCOPES[letter]):
         # (``x`` is float32 behind a part whose output is, until
@@ -1434,6 +1736,12 @@ def _part_body(
             out = _attention_block(
                 h, layer, cfg, mesh, positions, attn_fn, rope=rope
             )
+        elif letter == "S":
+            out, aux = _block_sparse_attention(
+                h, layer, cfg, mesh, positions, attn_fn, return_selected
+            )
+        elif letter == "L":
+            out, aux = _lightning_block(h, layer["lin"], cfg, mesh, rope)
         elif letter == "-":
             out = _mlp_block(h, layer, cfg, mesh, interior=jnp.float32)
         else:
@@ -1442,15 +1750,24 @@ def _part_body(
             out, aux = moe_block(
                 h, layer["moe"], cfg, mesh, rng=rng, return_aux=True
             )
-        x = x + out
+        x = x + _residual_scaled(out, cfg)
         if mesh is not None:
             x = shd.constrain(x, mesh, "batch", "seq", None)
     return x, aux
 
 
+def _residual_scaled(out, cfg: ModelConfig):
+    """A part's output times ``cfg.residual_scale``, in float32 where
+    there is one."""
+    if cfg.residual_scale == 1.0:
+        return out
+    return out.astype(jnp.float32) * cfg.residual_scale
+
+
 def _run_pattern(
     x, layers, pattern: str, positions, cfg: ModelConfig, mesh, attn_fn,
     rng, first: int = 0, keep_attn: bool = False,
+    return_selected: bool = False,
 ):
     """The parts ``pattern`` names, in its order, each taken as the
     next of its kind in ``layers`` (``_init_pattern``) and run through
@@ -1462,12 +1779,18 @@ def _run_pattern(
     is unrolled, each part under the remat and the stream rounded
     behind it: neighbours differ in kind. Returns (x, aux): the
     routed layers' scalars summed, their ``moe_choices`` stacked [E
-    layers, B, S, k] in trunk order ({} where no layer routes).
+    layers, B, S, k] in trunk order ({} where no layer routes); the
+    block-sparse attentions' ``sparse_attn_out_ms`` as their mean and,
+    where ``return_selected``, their selections as ``attn_selected``
+    bool [S parts x KV, B, S, U], layer-major and group-minor; the
+    lightning parts' ``lightning_fast_out_ms`` as their mean, those of a
+    scanned run among them (the one thing a run hands out beside x).
     ``first``: the index of the pattern's first part, folded into
     ``rng``."""
     parts = {
         letter: functools.partial(
-            _part_body, letter=letter, cfg=cfg, mesh=mesh, attn_fn=attn_fn
+            _part_body, letter=letter, cfg=cfg, mesh=mesh, attn_fn=attn_fn,
+            return_selected=return_selected,
         )
         for letter in set(pattern)
     }
@@ -1476,19 +1799,22 @@ def _run_pattern(
     }
     rope = (
         _rope_tables(positions, cfg.rope_dim, cfg.rope_theta)
-        if cfg.pos == "rope" and "*" in pattern
+        if cfg.pos == "rope" and set("*L") & set(pattern)
         else None
     )
     places = _part_places(pattern)
     seen = dict.fromkeys(bodies, 0)
-    auxs = []
+    auxs, sparse, lightning = [], [], []
     i = 0
     for unit, reps in _pattern_runs(pattern):
         if reps > 1:
             # the run's stacks whole: [repeats, the unit's parts of the
             # kind, ...] a kind
             stacks = {}
-            for letter in set(unit):
+            # (sorted: a set's order changes with the process's hash
+            # seed, and with it the order of the step's text — a warm
+            # start would miss the compile cache every other time)
+            for letter in sorted(set(unit)):
                 n = unit.count(letter)
                 name, _ = places[letter][seen[letter]]
                 stacks[letter] = jax.tree.map(
@@ -1499,15 +1825,20 @@ def _run_pattern(
 
             def repeat(x, stacks):
                 at = dict.fromkeys(stacks, 0)
+                read = []
                 for letter in unit:
                     layer = jax.tree.map(
                         lambda t: t[at[letter]], stacks[letter]
                     )
                     at[letter] += 1
-                    x, _ = parts[letter](x, layer, positions, rope=rope)
-                return x.astype(cfg.dtype), None
+                    x, aux = parts[letter](x, layer, positions, rope=rope)
+                    if letter == "L":
+                        read.append(aux["lightning_fast_out_ms"])
+                return x.astype(cfg.dtype), jnp.stack(read) if read else None
 
-            x = _scan_run(_remat(repeat, cfg, keep_attn), x, stacks)
+            x, read = _scan_run(_remat(repeat, cfg, keep_attn), x, stacks)
+            if read is not None:
+                lightning.append(read.reshape(-1))
             i += len(unit) * reps
             continue
         for letter in unit:
@@ -1518,21 +1849,36 @@ def _run_pattern(
             x, aux = bodies[letter](x, layer, positions, rng=r, rope=rope)
             x = x.astype(cfg.dtype)
             i += 1
-            if aux:
-                auxs.append(aux)
+            if letter == "L":
+                lightning.append(aux["lightning_fast_out_ms"][None])
+            elif aux:
+                (sparse if letter == "S" else auxs).append(aux)
+    out = {}
+    if lightning:
+        out["lightning_fast_out_ms"] = jnp.mean(jnp.concatenate(lightning))
+    if sparse:
+        out["sparse_attn_out_ms"] = jnp.mean(
+            jnp.stack([a["sparse_attn_out_ms"] for a in sparse])
+        )
+        if "attn_selected" in sparse[0]:
+            out["attn_selected"] = jnp.concatenate(
+                [a["attn_selected"] for a in sparse]
+            )
     if not auxs:
-        return x, {}
+        return x, out
     stacked = jax.tree.map(lambda *ls: jnp.stack(ls), *auxs)
     choices = stacked.pop("moe_choices")
     return x, {
-        **jax.tree.map(lambda a: a.sum(0), stacked), "moe_choices": choices
+        **jax.tree.map(lambda a: a.sum(0), stacked), "moe_choices": choices,
+        **out,
     }
 
 
 def _scan_run(repeat, x, stacks):
     """A run of repeats of one unit: ``repeat(x, a repeat's slices of
-    the stacks) -> (x, None)`` over the stacks' leading axis."""
-    return jax.lax.scan(repeat, x, stacks)[0]
+    the stacks) -> (x, what the repeat hands out, or None)`` over the
+    stacks' leading axis. Returns (x, those stacked, or None)."""
+    return jax.lax.scan(repeat, x, stacks)
 
 
 def _remat_body(cfg: ModelConfig, mesh, attn_fn, fp8_layers,
@@ -1569,6 +1915,8 @@ def _kept_names(cfg: ModelConfig, keep_attn: bool):
     names = ()
     if cfg.selects_keys:
         names += ("attn_selected", "attn_align_grad")
+    if cfg.selects_blocks:
+        names += ("attn_selected",)
     if keep_attn:
         names += ("flash_out", "flash_lse")
     return names
@@ -1729,9 +2077,15 @@ def run_trunk(
         )
         if "m" in cfg.layer_pattern:
             set_counter("ssm1.layers", cfg.layer_pattern.count("m"))
+        if "L" in cfg.layer_pattern:
+            set_counter("lin.layers", cfg.layer_pattern.count("L"))
+        if "S" in cfg.layer_pattern:
+            set_counter("attn.sparse_layers", cfg.layer_pattern.count("S"))
+            set_counter("attn.select_block", cfg.sparse_block)
+            set_counter("attn.select_groups", cfg.kv_heads)
         x, aux = _run_pattern(
             x, layers, cfg.layer_pattern, positions, cfg, mesh, attn_fn,
-            rng, keep_attn="" in keep_attn,
+            rng, keep_attn="" in keep_attn, return_selected=return_selected,
         )
         zero = jnp.zeros([], jnp.float32)
         return x, {"moe_lb_loss": zero, "moe_z_loss": zero, **aux}
@@ -1998,7 +2352,14 @@ def forward(
     its keys ``indexer_loss`` (the layers' mean KL summed, before its
     coefficient) and ``attn_selected``, bool [n_layer, B, S, S], true
     where query t attended to key s (``return_selected=False`` leaves
-    the masks out: ``loss_fn`` does, a train step stacks none);
+    the masks out: ``loss_fn`` does, a train step stacks none); for a
+    model that selects BLOCKS past ``select_dense_len`` tokens
+    ``attn_selected`` is bool [S parts x KV heads, B, S, S /
+    sparse_block], a row a KV head's selection, layer-major and
+    group-minor, true at the blocks query t attended into, and
+    ``sparse_attn_out_ms`` (always) the S parts' mean square output,
+    and for one with lightning parts ``lightning_fast_out_ms``, the mean
+    square read-out of their fastest quarter of heads;
     ``rng`` enables switch-gating jitter during training. ``features_only=True`` returns
     the final-norm hidden states [B,S,D] instead of logits (value/reward
     heads attach here). ``prefix_len`` [B] int32 (prefix-LM configs):
@@ -2022,6 +2383,8 @@ def forward(
         if cfg.scale_embedding:
             # in float32: sqrt(d) has no exact bf16
             x = (x.astype(jnp.float32) * cfg.d_model ** 0.5).astype(dt)
+        if cfg.scale_emb != 1.0:
+            x = (x.astype(jnp.float32) * cfg.scale_emb).astype(dt)
         if mesh is not None:
             x = shd.constrain(x, mesh, "batch", "seq", None)
 
@@ -2156,7 +2519,7 @@ def forward(
         rng=rng,
         fp8_layers=fp8_states,
         dense_layers=params.get("dense_layers"),
-        return_selected=cfg.selects_keys and (
+        return_selected=(cfg.selects_keys or cfg.selects_blocks) and (
             return_aux if return_selected is None else return_selected
         ),
         keep_attn=keep_attn,
@@ -2264,9 +2627,9 @@ def head_weight_scale(params: Params, cfg: ModelConfig):
         w = shd.tied_head_table(params["embed"]["tokens"]).T
     else:
         w = params["lm_head"]["w"]
-    scale = 1.0
+    scale = cfg.logit_scale
     if cfg.mup_base_width and cfg.tie_embeddings:
-        scale = cfg.mup_base_width / cfg.d_model
+        scale *= cfg.mup_base_width / cfg.d_model
     return w, scale
 
 
@@ -2379,6 +2742,14 @@ def _loss_from_head(
         il = cfg.indexer_loss_coef * moe_aux["indexer_loss"]
         loss = loss + il
         metrics["indexer_loss"] = il
+    if "sparse_attn_out_ms" in moe_aux:
+        # no term of the objective: the block-sparse attentions' mean
+        # square output, what a selection the attention ignores moves
+        metrics["sparse_attn_out_ms"] = moe_aux["sparse_attn_out_ms"]
+    if "lightning_fast_out_ms" in moe_aux:
+        # nor this: the lightning parts' fast heads' mean square
+        # read-out, what a running log-decay of too few bits moves
+        metrics["lightning_fast_out_ms"] = moe_aux["lightning_fast_out_ms"]
     # run_trunk (and the prediction module) summed these over the
     # routed blocks; reported as the mean over them
     blocks = cfg.n_routed_layer + cfg.n_mtp_module

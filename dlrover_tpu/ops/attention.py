@@ -54,7 +54,8 @@ def mha_reference(
     positions only. ``selected`` [B, Sq, Sk] (bool, or an integer array
     that is nonzero at the chosen keys): each query attends to the keys
     it names that the mask above also lets it see, the same for every
-    head; the softmax runs over those alone. ``return_lse`` adds the
+    head (or ``[B, G, Sq, Sk]``: a selection for each of G groups of
+    H / G consecutive heads); the softmax runs over those alone. ``return_lse`` adds the
     log-sum-exp of each row's scores, float32 [B, H, Sq].
     """
     b, sq, h, d = q.shape
@@ -108,7 +109,12 @@ def mha_reference(
         seg_mask = segment_ids[:, :, None] == segment_ids[:, None, :]
         logits = jnp.where(seg_mask[:, None, :sq, :sk], logits, -1e30)
     if selected is not None:
-        logits = jnp.where((selected != 0)[:, None], logits, -1e30)
+        chosen = selected != 0
+        if chosen.ndim == 3:
+            chosen = chosen[:, None]
+        else:  # [B, G, Sq, Sk]: a selection a group of heads
+            chosen = jnp.repeat(chosen, h // chosen.shape[1], axis=1)
+        logits = jnp.where(chosen, logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     out = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
     if return_lse:

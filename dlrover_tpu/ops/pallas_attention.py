@@ -77,7 +77,10 @@ The selection operand (``flash_attention(..., selected=mask)``; a
 learned sparse attention, ``models/decoder.py``'s indexer): ``mask`` is
 ``[B, Sq, Sk]`` int8, nonzero where query t chose key s, ONE mask for
 all heads of a sequence (the indexer ranks keys per query, not per
-head). The unpacked kernels take it as a further operand, one
+head) — or ``[B, G, Sq, Sk]``, a mask for each of G groups of H / G
+consecutive heads (a block-sparse attention that selects per KV head:
+``_sel_rows``; the same kernels, the tile's row picked by the head's
+group). The unpacked kernels take it as a further operand, one
 ``[1, block_q, block_k]`` tile a grid step through the index map
 ``(g // H, i, k block)``, and a pair counts where the causal rule admits
 it AND the tile names it; their traced names end in ``_sel``
@@ -956,6 +959,22 @@ def _bwd_dkv_kernel_packed(
         dv_ref[0] = dv_scratch[:].astype(dv_ref.dtype)
 
 
+def _sel_rows(selected, h):
+    """(the selection as ``[rows, Sq, Sk]``, row(g) of grid program g =
+    batch x H + head): ``[B, Sq, Sk]``, one mask for the H heads of a
+    sequence, as it is, row ``g // H``; ``[B, G, Sq, Sk]``, a mask for
+    each of G groups of H / G consecutive heads (a KV head's query
+    heads), batch-major."""
+    if selected is None or selected.ndim == 3:
+        return selected, lambda g: g // h
+    b, groups, sq, sk = selected.shape
+    per = h // groups
+    return (
+        selected.reshape(b * groups, sq, sk),
+        lambda g: (g // h) * groups + (g % h) // per,
+    )
+
+
 def _optional_smem(kernel, prefix, offsets, batch, at, selected=None,
                    sel_spec=None):
     """The optional operands every kernel takes: in SMEM the per-batch
@@ -1153,6 +1172,7 @@ def _pallas_backward(q, k, v, out, lse, g, causal, scale,
     # first one and need the sequence's block count to stop at its end
     dq_band, dkv_band = nk * band, nq * band
     g = g.astype(q.dtype)
+    selected, sel_row = _sel_rows(selected, h)
     glse = () if g_lse is None else (_stat_tiles(g_lse, pack),)
     stat_struct = _out_struct(lse.shape, lse.dtype, q)
 
@@ -1265,7 +1285,7 @@ def _pallas_backward(q, k, v, out, lse, g, causal, scale,
         functools.partial(_bwd_dq_kernel, **common, band=dq_band),
         pl.BlockSpec(
             (1, block_q, block_k),
-            lambda g_, i, j: (g_ // h, i, k_block(i, j)),
+            lambda g_, i, j: (sel_row(g_), i, k_block(i, j)),
         ),
     )
     dq, delta = pl.pallas_call(
@@ -1298,7 +1318,7 @@ def _pallas_backward(q, k, v, out, lse, g, causal, scale,
         at=6, selected=selected,
         sel_spec=pl.BlockSpec(
             (1, block_q, block_k),
-            lambda g_, j, i: (g_ // h, q_block(j, i), j),
+            lambda g_, j, i: (sel_row(g_), q_block(j, i), j),
         ),
     )
     dk, dv = pl.pallas_call(
@@ -1344,7 +1364,7 @@ def _flash_fwd(
     window: int = 0,  # sliding window (causal only; 0 = unlimited)
     offsets: Optional[jax.Array] = None,  # [2] int32 global (q_off, k_off)
     head_pack: int = 1,  # heads per 128-lane slab (MHA, pack · D == 128)
-    selected: Optional[jax.Array] = None,  # [B, Sq, Sk] int8 (unpacked)
+    selected: Optional[jax.Array] = None,  # [B, (G,) Sq, Sk] int8 (unpacked)
 ):
     """(out [B, S, H, D], lse f32 as the kernel wrote it: 8-lane tiles
     ``[B·slabs, S, 8]``, head p of a slab in lane p (unpacked: ``[B·H, S,
@@ -1364,6 +1384,7 @@ def _flash_fwd(
     assert selected is None or pack == 1, "the selection runs unpacked"
     nq, nk = sq // block_q, sk // block_k
     sel_spec = None
+    selected, sel_row = _sel_rows(selected, h)
     common = dict(
         causal=causal,
         scale=scale,
@@ -1452,10 +1473,10 @@ def _flash_fwd(
             pltpu.VMEM((block_q, d), jnp.float32),
         ]
         # one [block_q, block_k] tile of the selection a grid step, the
-        # same for the h heads of a sequence
+        # same for the h heads of a sequence (or of a group of them)
         sel_spec = pl.BlockSpec(
             (1, block_q, block_k),
-            lambda g, i, j: (g // h, i, k_block(i, j)),
+            lambda g, i, j: (sel_row(g), i, k_block(i, j)),
         )
 
     extra, extra_specs, kernel = _optional_smem(
@@ -1799,7 +1820,7 @@ def flash_attention(
     prefix_len: Optional[jax.Array] = None,  # [B] int32: prefix-LM
     window: int = 0,  # sliding window (causal only; 0 = unlimited)
     head_pack: int = 0,  # 0 = auto (128 // D heads a slab), 1 = unpacked
-    selected: Optional[jax.Array] = None,  # [B, Sq, Sk] bool or int8
+    selected: Optional[jax.Array] = None,  # [B, (G,) Sq, Sk] bool or int8
     lse_rows: bool = False,  # a remat policy of the caller keeps flash_lse
 ):
     """Flash attention; falls back to the jnp path off-TPU.
@@ -1817,7 +1838,8 @@ def flash_attention(
     kernels keep the simple grid//groups indexing.
     ``selected`` (module docstring, "the selection operand"; causal, no
     prefix, no window): each query attends to the keys it names, the
-    same for every head, and the result is ``(out, lse [B, H, S])`` with
+    same for every head (``[B, G, Sq, Sk]``: for every head of a group),
+    and the result is ``(out, lse [B, H, S])`` with
     ``lse`` float32 and detached.
     ``lse_rows``: the caller runs this under a remat policy that keeps
     ``flash_out`` and ``flash_lse``, so the residual of that name is the

@@ -171,6 +171,8 @@ CELL_SPANS = {
     "keyevl2-ep8-train-b1s8192": (4096.5, True),
     "nemotron3super-ep64-train-b1s8192": (4096.5, True),
     "jamba2-3b-l14-train-b1s8192": (4096.5, True),  # its one attention
+    # the causal span, not the 64 chosen blocks': every block runs
+    "minicpm-sala-l4-train-b1s16384": (8192.5, True),
     # an attention kind per layer: the line falls INSIDE the step
     "trinitymini-ep8-train-b1s16384": {
         "F": (8192.5, True), "S": (1920.0625, False),

@@ -257,7 +257,7 @@ def test_scanned_runs_are_bit_equal_to_unrolled(model, monkeypatch):
     def unrolled_run(repeat, x, stacks):
         for r in range(jax.tree.leaves(stacks)[0].shape[0]):
             x, _ = repeat(x, jax.tree.map(lambda t: t[r], stacks))
-        return x
+        return x, None
 
     monkeypatch.setattr(decoder, "_scan_run", unrolled_run)
     unrolled = step()

@@ -52,6 +52,7 @@ BENCHMARK_CONFIGS = {
     "nemotron-3-super-ep64-1chip": 8192,
     "trinity-mini-ep8-1chip": 16384,
     "jamba2-3b-l14": 8192,
+    "minicpm-sala-l4": 16384,
 }
 
 
@@ -67,7 +68,9 @@ def test_flops_per_token_is_the_benchmarks_count(name):
     selection of keys and its score-only indexer, layers of one part
     each with a state-space scan's recurrence among the multiplied, an
     attention kind per layer with each kind's own span, mixer + MLP
-    layers of two parts each with a selective scan's."""
+    layers of two parts each with a selective scan's, a selection of
+    blocks with its pooled scorer past the length the model runs dense
+    up to, and a linear attention's recurrence."""
     from benchmarks.lib.flops import resolve
 
     configs = pathlib.Path(__file__).parent.parent / "benchmarks" / "configs"
@@ -94,6 +97,17 @@ def test_flops_per_token_is_the_benchmarks_count(name):
                 cfg.n_head * cfg.head_dim * spared
                 - cfg.index_n_heads * cfg.index_head_dim / 2 * 4096.5
             ) - 6.0 * cfg.n_layer * cfg.index_params
+        )
+        return
+    if cfg.selects_blocks:
+        # up to ``select_dense_len`` a sparse layer counts every visible
+        # key; past it the keys of the blocks a query chose (3,560.5 at
+        # 16,384) and half a pair-channel a pooled key it scored
+        assert cfg.flops_per_token(16384) - cfg.flops_per_token(
+            8192
+        ) == pytest.approx(
+            12.0 * cfg.layer_pattern.count("S") * cfg.n_head * cfg.head_dim
+            * (3560.5 + 8192.5 / 16 / 2 - 4096.5)
         )
         return
     # bidirectional attention sees every key, a causal one half on average
