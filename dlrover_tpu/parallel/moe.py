@@ -8,12 +8,14 @@ emit the all-to-alls on ICI — no hand-written collectives, and the expert
 FFN is a single batched matmul on the MXU (the grouped-GEMM equivalent).
 """
 
+import dataclasses
 import functools
 from typing import Dict
 
 import jax
 import jax.numpy as jnp
 
+from dlrover_tpu.ops import pallas_rows
 from dlrover_tpu.parallel import sharding as shd
 
 
@@ -492,27 +494,110 @@ def _rows(x, idx):
     return x.at[idx].get(mode="promise_in_bounds")
 
 
+# Where the device holds a part of the experts (docs/performance.md, "the
+# held rows"): the pairs whose expert is here are the PREFIX of the
+# expert order — ``_sort_by_expert`` sends the others to the tail —,
+# ``Held.rows`` of them, a number the device knows and the trace does
+# not. The arrays keep their static rows; the sums over a token's held
+# rows (``pallas_rows.rows_sum``) and the combine's derivative
+# (``_combine_bwd_held``) walk the prefix alone, by that count: no static
+# bound, nothing dropped. ``held is None`` — every expert here — is the
+# other structure, with nothing to skip, and keeps its own program.
+
+
+@functools.partial(
+    jax.tree_util.register_dataclass,
+    data_fields=["mask", "rows"], meta_fields=["one_device"],
+)
+@dataclasses.dataclass(frozen=True)
+class Held:
+    """The pairs whose expert is here: ``mask`` [t·k] bool in token
+    order, ``rows`` their count (int32 scalar, = ``group_sizes.sum()``);
+    ``one_device`` (static): the rows are one device's own, no mesh
+    around the call, so the row kernel may run."""
+
+    mask: jax.Array
+    rows: jax.Array
+    one_device: bool = False
+
+    def tiles(self, t, rows, dtype):
+        """``pallas_rows.tile`` for sums of ``rows`` [n, d] into ``t``
+        tokens, None where the XLA body runs."""
+        if not self.one_device:
+            return None
+        return pallas_rows.tile(t, *rows.shape, dtype)
+
+
+# rows a turn of the loops over the held prefix (chip sweep in
+# ``_combine_bwd_held``)
+HELD_CHUNK = 1024
+
+
+def _prefix_turns(held_rows, chunk):
+    return (held_rows + chunk - 1) // chunk
+
+
+def _chunk_start(c, chunk, n):
+    """Where turn ``c`` of a loop over chunks of n rows begins: the last
+    chunk of an n that ``chunk`` does not divide overlaps the one
+    before (what a turn writes depends on the row alone)."""
+    return jnp.minimum(c * chunk, n - chunk)
+
+
+def _held_weights(weights, order, held_rows):
+    """``weights`` [t, k] in expert order, [n] float32, over the held
+    prefix (whole chunks of it) and 0 behind: a gather of scalars costs
+    the chip 8 ns each, so only the prefix's are moved."""
+    n = order.shape[0]
+    chunk = min(4 * HELD_CHUNK, n)
+    flat = weights.reshape(-1).astype(jnp.float32)
+
+    def turn(c, out):
+        at = _chunk_start(c, chunk, n)
+        pairs = jax.lax.dynamic_slice_in_dim(order, at, chunk)
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, _rows(flat, pairs), at, 0
+        )
+
+    return jax.lax.fori_loop(
+        0, _prefix_turns(held_rows, chunk), turn, jnp.zeros((n,), flat.dtype)
+    )
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _dispatch(k, xt, token_of, inv, held=None):
-    """Token rows [t, d] → expert order [t·k, d] (``token_of`` =
-    ``order // k``). ``held`` [t·k] bool, token order: the pairs whose
-    expert is here (None = all); the others' rows bring nothing back."""
+    """Token rows [t, d] → expert order [n, d] (``token_of`` =
+    ``order // k``). ``held`` (``Held``, None = all): the pairs whose
+    expert is here; the others' rows bring nothing back. The forward
+    gathers every row either way: the compiler's gather out of the
+    32 MB of tokens runs at the memory's write rate (0.45 ms for 65,536
+    rows, my chip runs, PR 59), which a loop over the held rows with its
+    zero fill does not beat (0.73 ms at 8,194 held)."""
     return _rows(xt, token_of)
 
 
 def _dispatch_fwd(k, xt, token_of, inv, held=None):
-    return _dispatch(k, xt, token_of, inv, held), (inv, held)
+    return _dispatch(k, xt, token_of, inv, held), (token_of, inv, held)
 
 
 def _dispatch_bwd(k, res, g):
     # the k rows of a token, back in token order, summed in float32
-    inv, held = res
+    token_of, inv, held = res
+    t = inv.shape[0] // k
     with jax.named_scope("moe.sort"):
+        tiles = None if held is None else held.tiles(t, g, g.dtype)
+        if tiles is not None:
+            # the held rows alone, from the prefix they fill: what lies
+            # in rows no expert wrote is never read
+            d_xt = pallas_rows.rows_sum(
+                g, token_of, None, held.rows, t, g.dtype, tiles
+            )
+            return d_xt, None, None, None
         d_xt = _rows(g, inv).reshape(-1, k, g.shape[-1])
         if held is not None:
             # a select, not a product: what lies in rows no expert
             # wrote is unspecified
-            d_xt = jnp.where(held.reshape(-1, k, 1), d_xt, 0)
+            d_xt = jnp.where(held.mask.reshape(-1, k, 1), d_xt, 0)
         d_xt = d_xt.sum(axis=1, dtype=jnp.float32).astype(g.dtype)
     return d_xt, None, None, None
 
@@ -528,7 +613,7 @@ def _held_row_bound(t, k, e, some_elsewhere):
     return t * e if some_elsewhere and e < k else None
 
 
-def _sort_by_expert(xt, gate_idx, e, some_elsewhere=False):
+def _sort_by_expert(xt, gate_idx, e, some_elsewhere=False, one_device=False):
     """Stable-sort prologue shared by both ragged lowerings: (token,
     choice) pairs ordered by expert. STABILITY is load-bearing — the
     a2a pack/unpack indexing assumes per-expert token order survives.
@@ -544,6 +629,9 @@ def _sort_by_expert(xt, gate_idx, e, some_elsewhere=False):
     (``_held_row_bound``): ``order`` and ``sorted_in`` are CUT to them,
     exactly and without a drop, and ``inv`` is clamped into them (the
     pairs it then misplaces are all elsewhere, which ``held`` masks).
+
+    ``one_device``: the rows are one device's own (no mesh around the
+    call), so the row kernel may run (``Held``).
 
     Returns (flat_idx [t·k] (``e`` = not held here), order [n], inv
     [t·k] with ``inv[order] = arange`` on the held pairs, sorted_in
@@ -561,6 +649,8 @@ def _sort_by_expert(xt, gate_idx, e, some_elsewhere=False):
         if bound is not None:
             order = order[:bound]
             inv = jnp.minimum(inv, bound - 1)
+        if held is not None:
+            held = Held(held, jnp.sum(held, dtype=jnp.int32), one_device)
         sorted_in = _dispatch(k, xt, order // k, inv, held)
         counts = jnp.sum(
             flat_idx[:, None] == jnp.arange(e, dtype=flat_idx.dtype),
@@ -587,18 +677,27 @@ def _ragged_experts(rows, w_up, w_gate_proj, w_down, group_sizes):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def _combine_weighted(out_per_choice, weights, order, inv, dtype, held=None):
-    """Per-(token, choice) expert outputs [t·k, D] in expert order back
-    to token order, weighted by ``weights`` [t, k] — the combine tail
-    both ragged lowerings share. The k rows of a token are gathered
-    (``inv``) and contracted with its weights in float32. ``held`` [t·k]
-    bool (None = all): a pair whose expert is not here comes with weight
-    0 (the caller's select, which also drops its weight's cotangent),
-    and its row, which no expert wrote, is not read into the sum."""
+    """Per-(token, choice) expert outputs [n, D] in expert order back to
+    token order, weighted by ``weights`` [t, k] — the combine tail both
+    ragged lowerings share. The k rows of a token are gathered (``inv``)
+    and contracted with its weights in float32. ``held`` (``Held``, None
+    = all): a pair whose expert is not here comes with weight 0 (the
+    caller's select, which also drops its weight's cotangent), and its
+    row, which no expert wrote, is not read into the sum; where the row
+    kernel runs (``Held.tiles``) only the held rows are read at all,
+    from the prefix of the expert order they fill."""
     t, k = weights.shape
     with jax.named_scope("moe.combine"):
+        tiles = None if held is None else held.tiles(t, out_per_choice, dtype)
+        if tiles is not None:
+            return pallas_rows.rows_sum(
+                out_per_choice, order // k,
+                _held_weights(weights, order, held.rows), held.rows, t,
+                dtype, tiles,
+            )
         picked = _rows(out_per_choice, inv).reshape(t, k, -1)
         if held is not None:
-            picked = jnp.where(held.reshape(t, k, 1), picked, 0)
+            picked = jnp.where(held.mask.reshape(t, k, 1), picked, 0)
         return jnp.einsum(
             "tkd,tk->td", picked, weights,
             preferred_element_type=jnp.float32,
@@ -610,11 +709,57 @@ def _combine_fwd(out_per_choice, weights, order, inv, dtype, held=None):
     return out, (out_per_choice, weights, order, inv, held)
 
 
+def _combine_bwd_held(g, out_per_choice, weights, order, held_rows):
+    """``_combine_bwd`` over the held prefix of the expert order alone:
+    (d_out [n, D], zero behind the prefix's last chunk; d_weights [t, k],
+    zero at every pair that is not held). A loop of ``held_rows /
+    HELD_CHUNK`` turns (rounded up) around the compiler's own gather: a
+    turn takes a chunk of pairs, their tokens' rows of ``g`` and their
+    weights, writes the chunk of ``d_out`` and scatters the chunk's row
+    dots to their pairs — t·k scalars gathered by ``inv`` cost more
+    than the rows."""
+    n, d = out_per_choice.shape
+    t, k = weights.shape
+    chunk = min(HELD_CHUNK, n)
+    flat = weights.reshape(-1).astype(jnp.float32)
+
+    def turn(c, carry):
+        d_out, d_w = carry
+        at = _chunk_start(c, chunk, n)
+        pairs = jax.lax.dynamic_slice_in_dim(order, at, chunk)
+        g_rows = _rows(g, pairs // k).astype(jnp.float32)
+        rows = (g_rows * _rows(flat, pairs)[:, None]).astype(d_out.dtype)
+        out_rows = jax.lax.dynamic_slice_in_dim(out_per_choice, at, chunk)
+        dots = (out_rows.astype(jnp.float32) * g_rows).sum(-1)
+        # a select: what the experts left behind the prefix is garbage
+        dots = jnp.where(at + jnp.arange(chunk) < held_rows, dots, 0)
+        return (
+            jax.lax.dynamic_update_slice_in_dim(d_out, rows, at, 0),
+            d_w.at[pairs].set(
+                dots, mode="promise_in_bounds", unique_indices=True
+            ),
+        )
+
+    d_out, d_w = jax.lax.fori_loop(
+        0, _prefix_turns(held_rows, chunk), turn,
+        (
+            jnp.zeros((n, d), out_per_choice.dtype),
+            jnp.zeros((t * k,), jnp.float32),
+        ),
+    )
+    return d_out, d_w.reshape(t, k)
+
+
 def _combine_bwd(dtype, res, g):
     # in expert order, so that d_out feeds the grouped matmul's transpose
     # as it is; only the t·k scalars of d_weights are permuted
     out_per_choice, weights, order, inv, held = res
     with jax.named_scope("moe.combine"):
+        if held is not None:
+            d_out, d_weights = _combine_bwd_held(
+                g, out_per_choice, weights, order, held.rows
+            )
+            return d_out, d_weights.astype(weights.dtype), None, None, None
         g_sorted = _rows(g, order // weights.shape[1]).astype(jnp.float32)
         w_sorted = _rows(weights.reshape(-1), order)
         d_out = (g_sorted * w_sorted[:, None]).astype(out_per_choice.dtype)
@@ -626,7 +771,7 @@ def _combine_bwd(dtype, res, g):
 _combine_weighted.defvjp(_combine_fwd, _combine_bwd)
 
 
-def _ragged_ffn(xl, moe_local, gate_idx, weights, dtype):
+def _ragged_ffn(xl, moe_local, gate_idx, weights, dtype, one_device=False):
     """Grouped-GEMM expert FFN over one rank's token slice.
 
     xl: [T, D] tokens, gate_idx/weights: [T, k] routing. Sorts the (token,
@@ -635,18 +780,20 @@ def _ragged_ffn(xl, moe_local, gate_idx, weights, dtype):
     outputs (``_combine_weighted``). No capacity, no drops. Where fewer
     experts are here than the router is wide, ``gate_idx`` is local to
     them and a choice of an expert elsewhere adds nothing
-    (``_sort_by_expert``).
+    (``_sort_by_expert``); the combine and both derivatives then go by
+    the rows the experts here received (``Held``). ``one_device``: no
+    mesh around the call.
     Returns (out [T, D], group_sizes [E here] int32).
     """
     e = moe_local["w_up"].shape[0]
     some_elsewhere = e < moe_local["w_gate"].shape[-1]
     flat_idx, order, inv, sorted_in, group_sizes = _sort_by_expert(
-        xl, gate_idx, e, some_elsewhere
+        xl, gate_idx, e, some_elsewhere, one_device
     )
     held = None
     if some_elsewhere:
-        held = flat_idx < e
-        weights = jnp.where(held.reshape(weights.shape), weights, 0)
+        held = Held(flat_idx < e, group_sizes.sum(), one_device)
+        weights = jnp.where(held.mask.reshape(weights.shape), weights, 0)
     w_gate_proj = moe_local.get("w_gate_proj")
     out_sorted = _ragged_experts(
         sorted_in,
@@ -710,6 +857,7 @@ def _ragged_tokens(xl, moe_local, cfg, rng, pmean_axes=None):
         local_idx.reshape(bl * sl, -1),
         weights.reshape(bl * sl, -1),
         xl.dtype,
+        one_device=pmean_axes is None,
     )
     if latent is not None:
         # once, after the combine: on the sum of the experts held here

@@ -556,13 +556,20 @@ def test_loss_fn_reports_mean_max_load():
 # ---- token order and expert order: gathers against the scatter-add oracle ----
 
 
-def _scatter_add_oracle(xt, out_sorted, weights, gate_idx, dtype):
+def _scatter_add_oracle(xt, out_sorted, weights, gate_idx, dtype, held=None):
     """Dispatch and combine as they were before the inverse permutation:
     a gather whose derivative jax writes as a scatter-add of rows, and a
     weighted scatter-add of the expert outputs. Kept here as the plain
-    formulation the program's is held to. → (sorted_in, out)."""
+    formulation the program's is held to. ``held``: experts 0 .. held - 1
+    are here; the others' pairs sort behind them with weight 0, and the
+    sorted rows are as many as ``out_sorted`` has. → (sorted_in, out)."""
     t, k = gate_idx.shape
-    order = jnp.argsort(gate_idx.reshape(t * k))
+    flat = gate_idx.reshape(t * k)
+    if held is not None:
+        here = flat < held
+        flat = jnp.where(here, flat, held)
+        weights = jnp.where(here.reshape(t, k), weights, 0)
+    order = jnp.argsort(flat)[: out_sorted.shape[0]]
     token_of = order // k
     sorted_in = jnp.take(xt, token_of, axis=0)
     w_sorted = jnp.take(weights.reshape(-1), order)[:, None]
@@ -571,11 +578,24 @@ def _scatter_add_oracle(xt, out_sorted, weights, gate_idx, dtype):
     return sorted_in, out.astype(dtype)
 
 
-def _program_dispatch_combine(xt, out_sorted, weights, gate_idx, dtype, e):
+def _program_dispatch_combine(
+    xt, out_sorted, weights, gate_idx, dtype, e, held=None
+):
     from dlrover_tpu.parallel import moe as moe_mod
 
-    _, order, inv, sorted_in, _ = moe_mod._sort_by_expert(xt, gate_idx, e)
-    out = moe_mod._combine_weighted(out_sorted, weights, order, inv, dtype)
+    if held is None:
+        _, order, inv, sorted_in, _ = moe_mod._sort_by_expert(xt, gate_idx, e)
+        out = moe_mod._combine_weighted(out_sorted, weights, order, inv, dtype)
+        return sorted_in, out
+    # as ``_ragged_ffn`` does where a part of the experts is here
+    flat_idx, order, inv, sorted_in, counts = moe_mod._sort_by_expert(
+        xt, gate_idx, held, True
+    )
+    here = moe_mod.Held(flat_idx < held, counts.sum())
+    weights = jnp.where(here.mask.reshape(weights.shape), weights, 0)
+    out = moe_mod._combine_weighted(
+        out_sorted, weights, order, inv, dtype, here
+    )
     return sorted_in, out
 
 
@@ -585,16 +605,22 @@ def _bf16_ulp(ref):
     return 2.0 ** (np.floor(np.log2(mag)) - 7)
 
 
+@pytest.mark.parametrize("held", [None, 6], ids=["all", "held6"])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("routing", ["balanced", "collapsed"])
 @pytest.mark.parametrize("k", [1, 2, 8])
-def test_dispatch_and_combine_match_the_scatter_add_oracle(k, routing, dtype):
+def test_dispatch_and_combine_match_the_scatter_add_oracle(
+    k, routing, dtype, held
+):
     """``_sort_by_expert`` and ``_combine_weighted`` move rows by gather
     in both directions of the derivative (``inv``) and sum a token's k
     rows densely. Outputs and the gradients with respect to tokens,
     expert outputs and router weights are the scatter-add formulation's:
     to 1e-6 in float32; in bfloat16 within two ulps of the float32
-    oracle and no further from it than the scatter-add is."""
+    oracle and no further from it than the scatter-add is. ``held6``:
+    experts 0-5 of the 16 are here (at k = 8 the sorted rows are cut to
+    six a token); the derivatives then go by the held rows' count, and
+    what comes back in rows no held expert received is dropped."""
     from dlrover_tpu.parallel import moe as moe_mod
 
     t, e, d = 48, 16, 32
@@ -606,20 +632,37 @@ def test_dispatch_and_combine_match_the_scatter_add_oracle(k, routing, dtype):
     gate_idx = gate_idx.astype(jnp.int32)
     keys = jax.random.split(jax.random.key(k), 5)
     xt = jax.random.normal(keys[0], (t, d)).astype(dt)
-    out_sorted = jax.random.normal(keys[1], (t * k, d)).astype(dt)
+    n = t * k if held is None else t * min(k, held)
+    out_sorted = jax.random.normal(keys[1], (n, d)).astype(dt)
     weights = jax.nn.softmax(jax.random.normal(keys[2], (t, k)), -1)
-    cot_sorted = jax.random.normal(keys[3], (t * k, d)).astype(dt)
+    cot_sorted = jax.random.normal(keys[3], (n, d)).astype(dt)
     cot_out = jax.random.normal(keys[4], (t, d)).astype(dt)
 
-    flat_idx, order, inv, _, counts = moe_mod._sort_by_expert(
-        xt, gate_idx, e
-    )
-    np.testing.assert_array_equal(inv[order], np.arange(t * k))
-    np.testing.assert_array_equal(counts, np.bincount(flat_idx, minlength=e))
-    assert counts.dtype == jnp.int32
-    # stable: an expert's rows keep their token order
-    same_expert = np.diff(flat_idx[order]) == 0
-    assert (np.diff(order)[same_expert] > 0).all()
+    if held is None:
+        flat_idx, order, inv, _, counts = moe_mod._sort_by_expert(
+            xt, gate_idx, e
+        )
+        np.testing.assert_array_equal(inv[order], np.arange(t * k))
+        np.testing.assert_array_equal(
+            counts, np.bincount(flat_idx, minlength=e)
+        )
+        assert counts.dtype == jnp.int32
+        # stable: an expert's rows keep their token order
+        same_expert = np.diff(flat_idx[order]) == 0
+        assert (np.diff(order)[same_expert] > 0).all()
+    else:
+        flat_idx, order, inv, _, counts = moe_mod._sort_by_expert(
+            xt, gate_idx, held, True
+        )
+        held_rows = int((np.asarray(gate_idx) < held).sum())
+        assert order.shape == (n,) and int(counts.sum()) == held_rows
+        np.testing.assert_array_equal(
+            inv[order][:held_rows], np.arange(held_rows)
+        )
+        assert (np.asarray(flat_idx)[order[:held_rows]] < held).all()
+        # what the experts' transposes leave behind their groups is not
+        # the oracle's to carry: nothing comes back from those rows
+        cot_sorted = cot_sorted.at[held_rows:].set(0)
 
     def run(fn, cast):
         def both(xt, out_sorted, weights):
@@ -639,11 +682,11 @@ def test_dispatch_and_combine_match_the_scatter_add_oracle(k, routing, dtype):
         return [np.asarray(a, np.float32) for a in (*outs, *grads)]
 
     new = run(
-        lambda *a: _program_dispatch_combine(*a, gate_idx, dt, e), dt
+        lambda *a: _program_dispatch_combine(*a, gate_idx, dt, e, held), dt
     )
-    old = run(lambda *a: _scatter_add_oracle(*a, gate_idx, dt), dt)
+    old = run(lambda *a: _scatter_add_oracle(*a, gate_idx, dt, held), dt)
     ref = run(
-        lambda *a: _scatter_add_oracle(*a, gate_idx, jnp.float32),
+        lambda *a: _scatter_add_oracle(*a, gate_idx, jnp.float32, held),
         jnp.float32,
     )
     names = ("sorted_in", "out", "d_tokens", "d_expert_out", "d_weights")

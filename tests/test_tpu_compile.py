@@ -27,8 +27,9 @@ from dlrover_tpu.common import device
 from dlrover_tpu.models.config import get_config
 from dlrover_tpu.ops import (
     pallas_align, pallas_attention, pallas_conv, pallas_norm, pallas_paged,
-    pallas_selective_scan, pallas_ssd, selective_scan, ssd,
+    pallas_rows, pallas_selective_scan, pallas_ssd, selective_scan, ssd,
 )
+from dlrover_tpu.parallel import moe
 from dlrover_tpu.serving import kv_cache as kvc
 
 BF16 = jnp.bfloat16
@@ -266,6 +267,42 @@ def _conv(channels, dtype, grad):
     return build
 
 
+def _held_rows(t, k, d, tiles, bound=None, back=False):
+    """A routed block's sum over the rows its held experts received
+    (``ops/pallas_rows.py``) as the cells run it: the combine (weighted)
+    of Keye-VL-2.0's and GLM-4.7-Flash's 65,536 rows and Trinity-Mini's
+    131,072 at 2,048 columns, and the dispatch's derivative
+    (unweighted, ``back``) of Nemotron-3-Super's 180,224 pairs cut to
+    65,536 rows at its latent's 1,024."""
+    def build(S):
+        n = bound or t * k
+        assert pallas_rows.tile(t, n, d, BF16) == tiles
+        mask, rows = S((t * k,), jnp.bool_), S((), jnp.int32)
+        order, inv = S((n,), jnp.int32), S((t * k,), jnp.int32)
+        if back:
+            def fn(xt, cot, order, inv, mask, rows):
+                held = moe.Held(mask, rows, True)
+                return jax.grad(
+                    lambda x: (
+                        moe._dispatch(k, x, order // k, inv, held)
+                        .astype(F32) * cot
+                    ).sum()
+                )(xt)
+
+            return fn, (S((t, d), BF16), S((n, d), F32), order, inv, mask,
+                        rows)
+
+        def fn(out_rows, weights, order, inv, mask, rows):
+            return moe._combine_weighted(
+                out_rows, weights, order, inv, BF16,
+                moe.Held(mask, rows, True),
+            )
+
+        return fn, (S((n, d), BF16), S((t, k), F32), order, inv, mask, rows)
+
+    return build
+
+
 def _norm(d, grad, residual):
     def build(S):
         x, scale = S((8, 1024, d), BF16), S((d,), F32)
@@ -353,6 +390,11 @@ CASES = {
     "conv-bwd-10240-bf16": (_conv(10240, BF16, grad=True), 2),
     "conv-fwd-5120-f32": (_conv(5120, F32, grad=False), 1),
     "conv-bwd-5120-f32": (_conv(5120, F32, grad=True), 2),
+    # the routed blocks' sums over the held rows (``ops/pallas_rows.py``)
+    "rows-sum-8192x8-2048": (_held_rows(8192, 8, 2048, (2048, 1024)), 1),
+    "rows-sum-16384x8-2048": (_held_rows(16384, 8, 2048, (2048, 512)), 1),
+    "rows-sum-back-8192x22-1024": (
+        _held_rows(8192, 22, 1024, (2048, 1024), bound=65536, back=True), 1),
     # its two rank norms
     "norm-bwd-d768": (_norm(768, grad=True, residual=False), 1),
     "norm-bwd-d512": (_norm(512, grad=True, residual=False), 1),
@@ -394,6 +436,8 @@ def test_kernel_compiles_for_v5e(chip, case):
     if case.startswith("sscan-"):
         names = ("sscan_fwd", "sscan_bwd") if "bwd" in case else ("sscan_fwd",)
         assert all(f"%{name}" in text for name in names)
+    if case.startswith("rows-sum-"):
+        assert "%rows_sum" in text
     if case.startswith("conv-"):
         names = ("conv_fwd", "conv_bwd") if "bwd" in case else ("conv_fwd",)
         assert all(f"%{name}" in text for name in names)
@@ -551,7 +595,7 @@ STEP_CASES = {
         batch=(2, 8192),
         kernels={"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
                  "norm_fwd", "norm_bwd", "ragged-dot-none",
-                 "ragged-dot-metadata"},
+                 "ragged-dot-metadata", "rows_sum"},
         scopes={"embed", "attn", "attn.latent", "mlp", "head_loss", "mtp",
                 "optimizer", "moe.route", "moe.sort", "moe.experts",
                 "moe.combine", "moe.shared"},
@@ -572,7 +616,7 @@ STEP_CASES = {
         batch=(1, 8192),
         kernels={"flash_fwd_sel", "flash_bwd_dq_sel", "flash_bwd_dkv_sel",
                  "align_kl", "norm_fwd", "norm_bwd", "ragged-dot-none",
-                 "ragged-dot-metadata"},
+                 "ragged-dot-metadata", "rows_sum"},
         scopes={"embed", "attn", "attn.index", "attn.select",
                 "attn.index_loss", "mlp", "head_loss", "optimizer",
                 "moe.route", "moe.sort", "moe.experts", "moe.combine"},
@@ -772,6 +816,25 @@ def test_step_names_its_kernels_and_phases(topo, case):
     }
     assert "f32[104,2,1024,8]" not in text  # the tiles before PR 35
     assert not re.search(r"= f32\[8,1024,25\]\S* reduce\(", text)
+    # the held rows' paths (PR 59) are a held model's alone: with every
+    # expert here — or no routed block — a step holds no ``rows_sum``
+    # call and no loop under the routed blocks' two row scopes
+    row_loops = [
+        name for name, op_name in op_names.items()
+        if name.startswith("while")
+        and runtime_timer.scope_of(op_name) in ("moe.sort", "moe.combine")
+    ]
+    held_rows = [ln for ln in kernel_lines if "%rows_sum" in ln]
+    if "rows_sum" in spec["kernels"]:
+        assert row_loops and held_rows
+        assert all(
+            "/moe.sort/" in ln or "/moe.combine/" in ln for ln in held_rows
+        )
+    else:
+        assert not row_loops and not held_rows
+        assert not any(
+            "/moe.sort/" in ln or "/moe.combine/" in ln for ln in kernel_lines
+        )
     if spec["model"] == "olmoe-1b-7b":
         # the routed layer's grouped matmuls under their scope (by the
         # kernel's name: it has no name stack)
@@ -813,6 +876,11 @@ def test_step_names_its_kernels_and_phases(topo, case):
         )
         if kernel.startswith("ragged-dot"):
             continue  # the compiler's kernels run in every phase
+        if kernel == "rows_sum":
+            # the combine's sum going forward, the dispatch's coming back
+            want = "moe.sort" if phase == "backward" else "moe.combine"
+            assert runtime_timer.scope_of(op_names[name]) == want
+            continue
         if kernel.startswith("flash_bwd") or kernel == "norm_bwd":
             assert phase == "backward", (name, op_names[name])
         else:
@@ -1100,6 +1168,7 @@ def test_nemotron_cell_fits_the_chip_with_its_rows_cut(topo, monkeypatch):
         "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "norm_fwd",
         "norm_bwd", "ragged-dot-none", "ragged-dot-metadata",
         "ssd_fwd", "ssd_states", "ssd_bwd", "conv_fwd", "conv_bwd",
+        "rows_sum",
     }
     # the five layers' conv through its kernels (PR 55), every call
     # under ``ssm.conv``: forward, remade, backward; x read as it lies,
@@ -1415,6 +1484,11 @@ SCAN_BODY_BUDGET = {
     # rotate, a select and a concatenate)
     "conv-bwd-10240-bf16": {"conv_fwd": 53, "conv_bwd": 87},
     "conv-bwd-5120-f32": {"conv_fwd": 51, "conv_bwd": 84},
+    # the held rows' sum (PR 59: 115 weighted, the combine's, and 99
+    # unweighted, the dispatch's derivative's; 8 rows of the loop
+    # unrolled; each traced once a process)
+    "rows-sum-8192x8-2048": {"rows_sum": 127},
+    "rows-sum-back-8192x22-1024": {"rows_sum": 109},
 }
 
 
@@ -1495,6 +1569,10 @@ def test_keye_cell_compiles_at_its_depth(topo):
     )
     _no_whole_score_array(text)
     assert _kernel_calls(text, "flash_fwd_sel") == 1
+    # a routed block's held rows (PR 59): the combine's sum in the
+    # scanned forward body, the dispatch's derivative in the backward
+    # one; the remade forward needs no combine
+    assert _kernel_calls(text, "rows_sum") == 2
     assert "bf16[12,1,8192,32,128]" in text and "f32[12,1,32,8192]" in text
     assert "f32[12,32,8192,8]" not in text
     assert "s8[12,1,8192,8192]" in text  # the saved selections, stacked
