@@ -125,6 +125,11 @@ class CompileRecorder:
         self.step_programs = 0
         self.last_step = None
         self.before_build_s = None
+        # for the step clock (``observability/profiler.py``): the time
+        # (``perf_counter``) and name of the newest events of any kind,
+        # and when the newest backend interval closed
+        self.events = deque(maxlen=256)
+        self.last_backend_end = None
         # what the recorder itself costs: listener calls that found one
         # of its events, and the seconds they took
         self.calls = 0
@@ -171,18 +176,23 @@ class CompileRecorder:
         return state
 
     def _on_event(self, event, **_kw):
+        self.events.append((time.perf_counter(), event))
         if event == _CACHE_HIT:
             self._thread().hit = True
 
     def _on_duration(self, event, seconds, **_kw):
+        self.events.append((time.perf_counter(), event))
         if event == _CACHE_FETCH:
             self._thread().fetch_s = seconds
 
     def _on_span(self, event, start, end, fun_name="", **_kw):
+        t0 = time.perf_counter()
+        self.events.append((t0, event))
         phase = _PHASES.get(event)
         if phase is None:
             return
-        t0 = time.perf_counter()
+        if phase == "backend_s":
+            self.last_backend_end = t0
         state = self._thread()
         cls = state.label or (
             STEP if fun_name in self._step_names else OTHER

@@ -30,7 +30,11 @@ from dlrover_tpu.observability.numeric import (
     check_finite,
     sanitize_grads,
 )
-from dlrover_tpu.observability.profiler import StepTimer
+from dlrover_tpu.observability.profiler import (
+    StepClock,
+    reset_step_clock,
+    step_clock,
+)
 from dlrover_tpu.observability.telemetry import (
     CheckpointRecord,
     ElasticEvent,
@@ -66,7 +70,9 @@ from dlrover_tpu.observability.tracing import (
 )
 
 __all__ = [
-    "StepTimer",
+    "StepClock",
+    "step_clock",
+    "reset_step_clock",
     "LossSpikeDetector",
     "NumericChecker",
     "GradSanitizer",

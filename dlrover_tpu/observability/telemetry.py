@@ -1,6 +1,6 @@
 """Unified telemetry bus: typed records, pluggable sinks, one stream.
 
-The observability layer grew as disconnected point tools (StepTimer,
+The observability layer grew as disconnected point tools (a step timer,
 the runtime sampler, loss-spike/numeric checks, GoodputTracker) with nothing consuming them at runtime.  This module
 is the substrate that joins them: producers publish small, typed,
 JSON-serializable records into a :class:`TelemetryHub`; consumers

@@ -34,6 +34,13 @@ Beside the spans sits a process-wide **counter table**
 (``set_counter`` / ``counters``), always on, for values taken at
 boundaries that happen at most once a trace of the step — so it costs
 the step loop nothing. A counter is added with the metric that reads it.
+The step clock (``profiler.py``) sets none: its metrics read its ticks
+over a window, which a whole-process counter cannot serve. Its one
+span, ``host.stall``, reaches an enabled tracer twice over: held open
+by the clock's beat while a step is overdue (so it is in a profiler
+session's trace) and, when the tick comes, back-dated over the whole
+period with the stall's record as its arguments (``complete_span``);
+the open one is then dropped (``cancel``).
 
 Producers stream one JSON event per line into
 ``$DLROVER_TPU_TRACE_DIR/trace-{role}-{pid}.jsonl`` (append-only, one

@@ -39,6 +39,7 @@ from typing import Dict, List, Optional
 from dlrover_tpu.common.log import get_logger
 from dlrover_tpu.observability import telemetry
 from dlrover_tpu.observability.loss_spike import LossSpikeDetector
+from dlrover_tpu.observability.profiler import overdue_stall
 
 logger = get_logger(__name__)
 
@@ -177,15 +178,24 @@ class Watchdog:
             and step_time_s
             > self.cfg.step_time_factor * planned_step_time_s
         ):
+            detail = (
+                f"planned={planned_step_time_s:.6f}s "
+                f"factor={self.cfg.step_time_factor:g}"
+            )
+            # the step clock (observability/profiler.py): where this
+            # step is a stall already, what the host was doing in it —
+            # the capture below samples the NEXT step, which for a stall
+            # that has passed is a healthy one
+            stall = overdue_stall()
+            if stall is not None:
+                detail += (
+                    f" stall={stall['cause']} excess={stall['excess_s']:.3f}s"
+                    f" site={stall['site']!r}"
+                )
             out.append(
                 self._anomaly(
-                    "step_time_regression",
-                    step,
-                    value=step_time_s,
-                    detail=(
-                        f"planned={planned_step_time_s:.6f}s "
-                        f"factor={self.cfg.step_time_factor:g}"
-                    ),
+                    "step_time_regression", step, value=step_time_s,
+                    detail=detail,
                 )
             )
         return out

@@ -5,14 +5,35 @@ batch; each host loads only its data-parallel slice (its shard from the
 master's TaskManager) and contributes it as the addressable part of the
 global array. Reference analog: the per-worker DataLoader + DistributedSampler
 split — here the split is the batch axis sharding itself.
+
+The placement of a batch is also where every loop passes once a step on
+the host, the program's own or not: ``form_global_batch`` and
+``prefetch_to_device``'s ``put`` tick the process's step clock
+(``observability/profiler.py``), once a batch.
 """
 
 import collections
+import time
 from typing import Any, Dict, Iterable, Iterator, Optional, Tuple
 
 import jax
 import numpy as np
 from jax.sharding import NamedSharding
+
+from dlrover_tpu.observability.profiler import step_clock
+
+
+def _ticked(place, batch):
+    """``place(batch)`` as one tick of the step clock; a batch the last
+    tick placed (it came out of ``form_global_batch`` and now passes
+    ``prefetch_to_device``) is not ticked again."""
+    clock = step_clock()
+    if clock.placed_before(batch):
+        return place(batch)
+    entered = time.perf_counter()
+    placed = place(batch)
+    clock.tick(entered, time.perf_counter(), placed)
+    return placed
 
 
 def form_global_batch(
@@ -25,7 +46,9 @@ def form_global_batch(
     local rows and JAX assembles the global array without any data exchange.
     """
     if jax.process_count() == 1:
-        return jax.device_put(local_batch, sharding)
+        return _ticked(
+            lambda batch: jax.device_put(batch, sharding), local_batch
+        )
 
     def put(x):
         x = np.asarray(x)
@@ -34,7 +57,7 @@ def form_global_batch(
             sharding, x, global_shape
         )
 
-    return jax.tree.map(put, local_batch)
+    return _ticked(lambda batch: jax.tree.map(put, batch), local_batch)
 
 
 def prefetch_to_device(
@@ -55,7 +78,7 @@ def prefetch_to_device(
     """
     def put(batch):
         # device_put(x, None) == device_put(x): one helper, both paths
-        return jax.device_put(batch, sharding)
+        return _ticked(lambda b: jax.device_put(b, sharding), batch)
 
     if size <= 0:
         for batch in it:

@@ -25,7 +25,7 @@ from dlrover_tpu.common.log import get_logger
 from dlrover_tpu.models.config import ModelConfig
 from dlrover_tpu.observability import telemetry
 from dlrover_tpu.observability.loss_spike import LossSpikeDetector
-from dlrover_tpu.observability.profiler import StepTimer
+from dlrover_tpu.observability.profiler import step_clock
 from dlrover_tpu.observability.tracing import get_tracer
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.train.callbacks import (
@@ -233,9 +233,9 @@ class Trainer:
                 self._batch_sharding,
             )
         self.state: Any = None
-        self.timer = StepTimer(
-            flops_per_step=0.0, peak_flops=0.0
-        )
+        # the process's step clock: the placement above ticks it, this
+        # loop's measured step seconds live on it too
+        self.timer = step_clock()
         self.spike_detector = (
             LossSpikeDetector(
                 save_dir=os.path.join(args.output_dir, "loss_spikes")

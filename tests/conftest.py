@@ -40,3 +40,13 @@ def _cleanup_shm():
             os.unlink(path)
         except OSError:
             pass
+
+
+@pytest.fixture(autouse=True)
+def _fresh_step_clock():
+    """The step clock is the process's: a test starts without the one
+    the test before it ticked (its beat thread, its learnt period)."""
+    yield
+    from dlrover_tpu.observability.profiler import reset_step_clock
+
+    reset_step_clock()

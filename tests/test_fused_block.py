@@ -20,7 +20,7 @@ import optax
 
 from dlrover_tpu.models.config import get_config
 from dlrover_tpu.observability.loss_spike import LossSpikeDetector
-from dlrover_tpu.observability.profiler import StepTimer
+from dlrover_tpu.observability.profiler import StepClock
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.train.callbacks import Callback
 from dlrover_tpu.train.optimizer import make_optimizer
@@ -280,11 +280,10 @@ def test_loss_spike_update_block_fires_at_exact_step(tmp_path):
 
 
 def test_step_timer_attributes_block_time_per_step():
-    t = StepTimer(window=16)
+    t = StepClock(beat=False)
     t.record(0.8, n_steps=8)
     assert t.steps == 8
-    assert t.mean_s == pytest.approx(0.1)
-    assert t.steps_per_s == pytest.approx(10.0)
+    assert t.last_s == pytest.approx(0.1)
     t.record(0.1)  # unfused records still work alongside
     assert t.steps == 9
-    assert t.mean_s == pytest.approx(0.1)
+    assert t.last_s == pytest.approx(0.1)

@@ -1,4 +1,5 @@
-"""Observability tier: step timer, loss-spike, numerics."""
+"""Observability tier: loss-spike, numerics (the step clock:
+``tests/test_step_clock.py``)."""
 
 import jax
 import jax.numpy as jnp
@@ -8,26 +9,9 @@ import pytest
 from dlrover_tpu.observability import (
     LossSpikeDetector,
     NumericChecker,
-    StepTimer,
     check_finite,
     sanitize_grads,
 )
-
-
-def test_step_timer():
-    timer = StepTimer(flops_per_step=1e9, peak_flops=1e12)
-    f = jax.jit(lambda x: (x @ x).sum())
-    x = jnp.ones((128, 128))
-    for _ in range(3):
-        timer.start()
-        timer.stop(f(x))
-    assert timer.mean_s > 0
-    assert timer.steps_per_s > 0
-    assert 0 < timer.mfu  # 1e9 flops at some measured rate
-    assert timer.percentile(99) >= timer.percentile(0)
-    # a fused block of K steps is attributed per step
-    timer.record(4 * timer.last_s, n_steps=4)
-    assert timer.steps == 7
 
 
 def test_loss_spike_detector(tmp_path):
