@@ -90,10 +90,12 @@ class ModelConfig:
     # intermediate of the forward; full recomputes the layer in the
     # backward but what is quadratic to remake and linear to hold: a
     # selecting model's selection and alignment derivative, and — where
-    # the attention runs the flash kernels over a mean span of 2,048
-    # keys or more, decoder.keeps_attention_output — the kernel's output
-    # and row statistics, 2·D + 4 bytes a (query, head). What is kept is
-    # read from the shape; there is no tier to name
+    # the attention runs the flash kernels and their forward executes
+    # 2,048 keys a query or more (the kernels' own count of whole tiles,
+    # pallas_attention.forward_keys; decoder.keeps_attention_output) —
+    # the kernel's output and row statistics, 2·D + 4 bytes a (query,
+    # head). What is kept is read from the shape; there is no tier to
+    # name
     remat: str = "none"
     # MoE (0 = dense)
     n_experts: int = 0
@@ -857,12 +859,15 @@ class ModelConfig:
         return self.n_layer + self.n_mtp_module
 
     def executed_span(self, seq_len: int, kind: str = "") -> float:
-        """The mean number of keys the attention kernels EXECUTE a
-        query at ``seq_len`` in a layer of ``kind`` (``layer_types``'
-        letter; "" = the model's one kind): the causal span under that
-        kind's window, every key without the mask. A model that selects
-        its keys runs the whole causal span too (the ``_sel`` kernels
-        run every causal block), whatever ``index_topk`` credits it."""
+        """The mean number of keys a query ATTENDS to at ``seq_len`` in
+        a layer of ``kind`` (``layer_types``' letter; "" = the model's
+        one kind): the causal span under that kind's window, every key
+        without the mask — the pairs ``flops_per_token`` requires. A
+        model that selects its keys is given the whole causal span here
+        (``flops_per_token`` puts ``index_topk`` in its place). What
+        the kernels execute — whole tiles, more than this — is the
+        kernels' to say (``pallas_attention.forward_keys``), and is what
+        ``remat: full`` decides by."""
         if not self.causal:
             return float(seq_len)
         return mean_span(seq_len, self.kind_window(kind))

@@ -1933,13 +1933,20 @@ def _remat(body, cfg: ModelConfig, keep_attn: bool = False):
     return jax.checkpoint(body)
 
 
-# the executed keys a query from which ``full`` keeps the flash kernel's
-# output: remaking costs 4 · span · D operations a (query, head), keeping
-# 2 · D + 4 bytes, so the time bought per byte grows with the span. The
-# chip says, a gigabyte of residuals: 28 ms at span 512.5 (GPT-2 XL, 15.3
-# of 16.9 GB in use: no room), 29 at 2,048.5 (OLMoE), 46-72 at 3,072.25
-# and 4,096.5 (PERF.md section 6, PR 42); no cell lies between 512.5 and
-# 2,048.5, so where the line is between them is not measured.
+# the keys a query's forward kernel EXECUTES (``pallas_attention.
+# forward_keys``: whole key tiles, the diagonal's and a window's edge
+# blocks entire — not the span the mask lets through) from which ``full``
+# keeps the flash kernel's output: remaking costs 4 · keys · D operations
+# a (query, head), keeping 2 · D + 4 bytes, so the time bought per byte
+# grows with the keys executed. The chip says, a gigabyte of residuals:
+# 28 ms at 1,024 executed keys (GPT-2 XL, 15.3 of 16.9 GB in use: no
+# room), 29 at 2,560 (OLMoE), 46-72 at 3,840 and 4,608 (PERF.md section
+# 6, PR 42) and 37 at 2,880 (Trinity-Mini's window layers at 16,384
+# tokens, a band of three tiles of 1,024 for 1,920 keys attended to:
+# four calls of 6.13 ms gone, 3.9 ms of passes over the kept output and
+# its statistics come, 0.545 GB; PERF.md section 6, PR 61); no cell
+# lies between 1,024 and 2,560, so where the line is between them is
+# not measured.
 KEEP_ATTN_SPAN = 2048
 
 
@@ -1947,18 +1954,25 @@ def keeps_attention_output(cfg: ModelConfig, s: int, attn_impl: str = "auto",
                            mesh=None, kind: str = "") -> bool:
     """Whether ``remat: full`` keeps the attention kernel's output
     (``flash_out``, and ``flash_lse`` as numbers) at sequence length
-    ``s`` in a layer of ``kind`` (``cfg.layer_types``' letter; one step
-    may keep a full layer's and remake a window layer's) and does not
-    run the kernel again in the recomputed forward:
-    the attention runs the Pallas kernels and a query's mean executed
-    span (``ModelConfig.executed_span``) is at least ``KEEP_ATTN_SPAN``
-    keys. Decided from the shape and not from the memory the chip has
-    left: what a step needs is known only once it is compiled."""
+    ``s`` in a layer of ``kind`` (``cfg.layer_types``' letter; the
+    decision is per kind inside one step) and does not run the kernel
+    again in the recomputed forward: the attention runs the Pallas
+    kernels, and the keys a query's forward kernel executes at the
+    model's tile and that kind's window (``forward_keys``, the kernels'
+    own count; a selecting model's ``_sel`` kernels run every causal
+    block) are at least ``KEEP_ATTN_SPAN``. Decided from the shape and
+    not from the memory the chip has left: what a step needs is known
+    only once it is compiled."""
+    from dlrover_tpu.ops.pallas_attention import forward_keys
+
     return (
         cfg.remat == "full"
         and _resolve_attn_impl(attn_impl, mesh) == "flash"
         and s % 128 == 0  # the kernels' tiling (``_fit_block``)
-        and cfg.executed_span(s, kind) >= KEEP_ATTN_SPAN
+        and forward_keys(
+            s, s, cfg.attn_block_q, cfg.attn_block_k, cfg.causal,
+            cfg.kind_window(kind),
+        ) >= KEEP_ATTN_SPAN
     )
 
 

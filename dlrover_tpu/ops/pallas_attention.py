@@ -1007,6 +1007,39 @@ def _optional_smem(kernel, prefix, offsets, batch, at, selected=None,
     return arrays, specs, kernel
 
 
+def _k_run_blocks(i, block_q, block_k, n_k_blocks, window):
+    """How many k blocks the causal run gate admits for q block i: the
+    run from the first block a live window admits (block 0 with none) to
+    the diagonal or the sequence's end. The band's extent is the longest
+    of these, ``forward_keys`` their mean."""
+    first = 0
+    if _live_window(window, n_k_blocks * block_k):
+        first = _first_window_k_block(i, block_q, block_k, window)
+    last = _lower(_last_visible_k_block(i, block_q, block_k), n_k_blocks - 1)
+    return last - first + 1
+
+
+def forward_keys(sq, sk, block_q, block_k, causal=True, window=0) -> float:
+    """The mean number of keys a query's FORWARD kernel multiplies —
+    what ``flash_fwd*`` executes, not what the mask lets through: at the
+    tile ``flash_attention`` fits to the caller's ``block_q`` /
+    ``block_k``, every k block of each q block's run (``_k_run_blocks``:
+    what the run gate admits, and the banded grid walks) times the key
+    tile, averaged over the q blocks; every key without the causal mask.
+    A selection of keys runs every causal block (``window`` 0); a
+    prefix adds the blocks it makes live above the diagonal, known only
+    at run time and not counted. 0.0 where no tile fits the sequence:
+    the kernels do not run."""
+    bq, bk = _fit_block(sq, block_q), _fit_block(sk, block_k)
+    if bq is None or bk is None:
+        return 0.0
+    if not causal:
+        return float(sk)
+    n_q, n_k = sq // bq, sk // bk
+    blocks = sum(_k_run_blocks(i, bq, bk, n_k, window) for i in range(n_q))
+    return blocks * bk / n_q
+
+
 def _inner_grid(causal_clamp, block_q, block_k, n_q_blocks, n_k_blocks,
                 window):
     """The kernels' inner grid axis: ``(band, (steps, k_block), (steps,
@@ -1036,8 +1069,7 @@ def _inner_grid(causal_clamp, block_q, block_k, n_q_blocks, n_k_blocks,
     k_steps, q_steps = n_k_blocks, n_q_blocks
     if band:
         k_steps = max(
-            _lower(_last_visible_k_block(i, block_q, block_k), k_steps - 1)
-            - _first_window_k_block(i, block_q, block_k, window) + 1
+            _k_run_blocks(i, block_q, block_k, n_k_blocks, window)
             for i in range(n_q_blocks)
         )
         q_steps = max(

@@ -18,6 +18,7 @@ from jax._src.ad_checkpoint import saved_residuals
 from jax.ad_checkpoint import checkpoint_policies as cp
 
 from dlrover_tpu.models import decoder, get_config
+from dlrover_tpu.models.config import mean_span
 from dlrover_tpu.ops import pallas_attention
 
 ROOT = pathlib.Path(__file__).parent.parent
@@ -159,23 +160,28 @@ def _cells():
         yield cell["name"], program, traffic["seq"]
 
 
-# the mean executed span of every cell, and whether ``full`` keeps the
-# attention's output there
+# every cell: the span a query attends to (``ModelConfig.executed_span``:
+# what ``flops_per_token`` counts), the keys its forward kernel executes
+# at tiles of 1,024 (``pallas_attention.forward_keys``: what the rule
+# reads since PR 61), and whether ``full`` keeps the attention's output
 CELL_SPANS = {
-    "gpt2xl-train-b8s1024": (512.5, False),
-    "gpt2xl-zero1-dp4-b32s1024": (512.5, False),
-    "olmoe-1chip-train-b2s4096": (2048.5, True),
-    "mistral7b-l6-train-b1s8192": (3072.25, True),  # the window live
-    "glm47flash-ep8-train-b2s8192": (4096.5, True),
+    "gpt2xl-train-b8s1024": (512.5, 1024, False),
+    "gpt2xl-zero1-dp4-b32s1024": (512.5, 1024, False),
+    "olmoe-1chip-train-b2s4096": (2048.5, 2560, True),
+    # the window live: a band of 5 of 8 key blocks
+    "mistral7b-l6-train-b1s8192": (3072.25, 3840, True),
+    "glm47flash-ep8-train-b2s8192": (4096.5, 4608, True),
     # the causal span, not ``index_topk``: the kernels run every block
-    "keyevl2-ep8-train-b1s8192": (4096.5, True),
-    "nemotron3super-ep64-train-b1s8192": (4096.5, True),
-    "jamba2-3b-l14-train-b1s8192": (4096.5, True),  # its one attention
+    "keyevl2-ep8-train-b1s8192": (4096.5, 4608, True),
+    "nemotron3super-ep64-train-b1s8192": (4096.5, 4608, True),
+    "jamba2-3b-l14-train-b1s8192": (4096.5, 4608, True),  # its one attention
     # the causal span, not the 64 chosen blocks': every block runs
-    "minicpm-sala-l4-train-b1s16384": (8192.5, True),
-    # an attention kind per layer: the line falls INSIDE the step
+    "minicpm-sala-l4-train-b1s16384": (8192.5, 8704, True),
+    # an attention kind per layer, each decided by itself: a window
+    # layer attends to 1,920 keys — under the line, remade until PR 61 —
+    # and its forward's band of three tiles executes 2,880: kept
     "trinitymini-ep8-train-b1s16384": {
-        "F": (8192.5, True), "S": (1920.0625, False),
+        "F": (8192.5, 8704, True), "S": (1920.0625, 2880, True),
     },
 }
 
@@ -186,13 +192,23 @@ def test_cell_spans_cover_the_benchmark():
     assert sorted(name for name, _, _ in _cells()) == sorted(CELL_SPANS)
 
 
+def _cell_keys(cfg, seq, kind):
+    return pallas_attention.forward_keys(
+        seq, seq, cfg.attn_block_q, cfg.attn_block_k, cfg.causal,
+        cfg.kind_window(kind),
+    )
+
+
 @pytest.mark.parametrize("cell", sorted(CELL_SPANS))
 def test_the_line_falls_between_the_cells(cell):
-    """The predicate over a benchmark cell as it is run: true for the
-    five whose span is 2,048.5 keys or more and for Trinity's full
-    layers, false for the two at 1,024 tokens and Trinity's windows, and
-    false everywhere on the reference attention (the CPU's ``auto``),
-    under ``none`` and at a sequence the kernels do not tile."""
+    """The predicate over a benchmark cell as it is run, by the keys
+    its forward kernel executes: true for the eight whose forward runs
+    2,560 keys a query or more — Trinity's window layers among them,
+    2,880 executed for 1,920 attended to —, false for the two at 1,024
+    tokens, and false everywhere on the reference attention (the CPU's
+    ``auto``), under ``none`` and at a sequence the kernels do not
+    tile. The attended span keeps its values: ``flops_per_token``
+    counts them."""
     import dataclasses
 
     (program, seq), = (
@@ -205,6 +221,7 @@ def test_the_line_falls_between_the_cells(cell):
     by_kind = {
         kind: (
             cfg.executed_span(seq, kind),
+            _cell_keys(cfg, seq, kind),
             keeps(cfg, seq, "flash", kind=kind),
         )
         for kind in kinds
@@ -216,6 +233,120 @@ def test_the_line_falls_between_the_cells(cell):
         assert not keeps(cfg, seq, kind=kind)  # auto: CPU
         assert not keeps(cfg, seq + 64, "flash", kind=kind)
         assert not keeps(other, seq, "flash", kind=kind)
+
+
+# sq, sk, q tile, k tile, causal, window: the ten cells' shapes (tiles
+# of 1,024) and odd ones
+EXECUTED_SHAPES = {
+    "gpt2xl-s1024": (1024, 1024, 1024, 1024, True, 0),
+    "olmoe-s4096": (4096, 4096, 1024, 1024, True, 0),
+    "mistral-s8192-w4096": (8192, 8192, 1024, 1024, True, 4096),
+    "glm-keye-nemotron-jamba-s8192": (8192, 8192, 1024, 1024, True, 0),
+    "sala-trinity-full-s16384": (16384, 16384, 1024, 1024, True, 0),
+    "trinity-window-s16384-w2048": (16384, 16384, 1024, 1024, True, 2048),
+    # the backward's tile on Trinity's window layers, as a forward's
+    "tiles-512-w2048": (8192, 8192, 512, 512, True, 2048),
+    "tiles-512-causal": (4096, 4096, 512, 512, True, 0),
+    "tiles-unequal": (4096, 4096, 512, 1024, True, 1536),
+    "tiles-unequal-wide-q": (4096, 4096, 1024, 256, True, 700),
+    # a window no block boundary meets
+    "window-off-boundary": (4096, 4096, 512, 512, True, 1000),
+    "window-of-one-key": (2048, 2048, 256, 256, True, 1),
+    "window-96-s256": (256, 256, 1024, 1024, True, 96),
+    # a window that hides nothing is no window: the square grid
+    "window-is-the-sequence": (2048, 2048, 512, 512, True, 2048),
+    "window-past-the-sequence": (2048, 2048, 512, 512, True, 5000),
+    # more keys than queries (a cache before the queries' block), fewer
+    "sk-over-sq": (1024, 4096, 512, 512, True, 0),
+    "sk-over-sq-window": (1024, 4096, 256, 512, True, 768),
+    "sq-over-sk": (4096, 1024, 512, 512, True, 0),
+    # a tile the sequence does not take whole is refitted, as the
+    # kernels' caller does
+    "tile-refitted": (1536, 1536, 1024, 1024, True, 0),
+    "no-mask": (2048, 2048, 512, 512, False, 0),
+}
+
+
+def _gate_admits(sq, sk, block_q, block_k, causal, window):
+    """Brute force: the (query block, step) pairs of the forward grid
+    that the kernels' run gate admits x the key tile, a query."""
+    pa = pallas_attention
+    bq, bk = pa._fit_block(sq, block_q), pa._fit_block(sk, block_k)
+    nq, nk = sq // bq, sk // bk
+    band, (k_steps, _), _ = pa._inner_grid(
+        pa._gate_is_static(causal, None, None), bq, bk, nq, nk, window
+    )
+    admitted = 0
+    for i in range(nq):
+        for step in range(k_steps):
+            j, in_band = pa._band_k_block(i, step, bq, bk, window, nk * band)
+            admitted += bool(pa._block_runs(
+                causal, False, None, i * bq, j * bk, bq, bk, window, in_band
+            ))
+    return admitted * bk / nq
+
+
+@pytest.mark.parametrize("shape", sorted(EXECUTED_SHAPES))
+def test_forward_keys_are_what_the_run_gate_admits(shape):
+    """``forward_keys`` — what ``full`` decides by — against the grid
+    the forward kernel is launched on, step by step through the gate
+    its body asks (``_block_runs``): the same count, so the rule
+    cannot drift from what runs. Never fewer than the keys the mask
+    lets through."""
+    sq, sk, block_q, block_k, causal, window = EXECUTED_SHAPES[shape]
+    got = pallas_attention.forward_keys(
+        sq, sk, block_q, block_k, causal, window
+    )
+    assert got == _gate_admits(sq, sk, block_q, block_k, causal, window)
+    if sq == sk:
+        assert got >= (mean_span(sq, window) if causal else sq)
+
+
+def test_forward_keys_of_a_sequence_no_tile_fits():
+    """No 128-multiple divides it: the kernels do not run (the jnp
+    path), nothing is executed by them and nothing is kept."""
+    assert pallas_attention._fit_block(1000, 1024) is None
+    assert pallas_attention.forward_keys(1000, 1000, 1024, 1024) == 0.0
+
+
+# model, overrides, sequence, kind -> keys executed, kept: the
+# decision where the two counts disagree, beyond the benchmark's cells
+DECISIONS = {
+    # causal at 3,072: 1,536.5 attended to, three tiles' 2,048 executed
+    "causal-s3072": ("gpt2-1.5b", dict(max_seq=3072), 3072, "", 2048, True),
+    "causal-s2048": ("gpt2-1.5b", dict(max_seq=2048), 2048, "", 1536, False),
+    # the same sequence at tiles of 512 executes fewer keys: remade
+    "causal-s3072-tiles-512": (
+        "gpt2-1.5b", dict(max_seq=3072, attn_block_q=512, attn_block_k=512),
+        3072, "", 1792, False,
+    ),
+    # Trinity's window at tiles of 512 is a band of five: 2,400
+    "window-2048-tiles-512": (
+        "trinity-mini", dict(attn_block_q=512, attn_block_k=512),
+        16384, "S", 2400, True,
+    ),
+    "window-1024": (
+        "trinity-mini", dict(attn_window=1024), 16384, "S", 1984, False,
+    ),
+    "window-1024-full-layer": (
+        "trinity-mini", dict(attn_window=1024), 16384, "F", 8704, True,
+    ),
+    # no mask: every key
+    "bidirectional-s2048": (
+        "bert-base", dict(max_seq=2048), 2048, "", 2048, True,
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DECISIONS))
+def test_the_decision_follows_the_executed_keys(case):
+    """``keeps_attention_output`` at shapes where the attended span and
+    the executed keys lie on different sides of ``KEEP_ATTN_SPAN``, or
+    where the tile moves the count: the kernels' count decides."""
+    model, over, seq, kind, executed, kept = DECISIONS[case]
+    cfg = get_config(model, remat="full", **over)
+    assert _cell_keys(cfg, seq, kind) == executed
+    assert decoder.keeps_attention_output(cfg, seq, "flash", kind=kind) is kept
 
 
 @pytest.mark.parametrize("remat,keep,lse_named", [
@@ -329,20 +460,32 @@ def test_a_path_is_reported_by_the_code_that_takes_it(monkeypatch):
 def test_analyser_counts_the_kept_attention_output(monkeypatch):
     """Under ``full`` the activation bytes add the attention's kept
     output and row statistics where ``keeps_attention_output`` holds
-    (the flash kernels, a span of 2,048 keys or more): Mistral's widths
-    at 8,192 tokens on the chip, not on the CPU's reference attention
-    and not at 1,024 tokens."""
+    (the flash kernels, a forward that executes 2,048 keys a query or
+    more): Mistral's widths at 8,192 tokens on the chip, not on the
+    CPU's reference attention and not at 1,024 tokens. The estimate
+    asks ``kept_attention_layers`` and so follows the rule by itself:
+    Trinity-Mini's five layers at 16,384 tokens, window layers and all
+    (PR 61; one before it)."""
     from dlrover_tpu.accelerate.analyser import analyse
     from dlrover_tpu.accelerate.strategy import apply_strategy
     from dlrover_tpu.common import device
 
     cfg = get_config("mistral-7b", n_layer=6, max_seq=8192)
+    kinds = get_config(
+        "trinity-mini", n_layer=5, layer_types="SSSSF", max_seq=16384
+    )
     plan = apply_strategy([("mixed_parallel", {"dp": 1})])
     plan.remat, plan.compute_dtype = "full", "bfloat16"
     on_cpu = analyse(cfg, plan, 1, 1, 8192, hbm_bytes=16e9)
+    kinds_on_cpu = analyse(kinds, plan, 1, 1, 16384, hbm_bytes=16e9)
     monkeypatch.setattr(device, "on_cpu", lambda: False)
     on_chip = analyse(cfg, plan, 1, 1, 8192, hbm_bytes=16e9)
     kept = 8192 * 6 * 32 * (128 * 2 + 4)  # flash_out + flash_lse, 6 layers
     assert on_chip.act_bytes_per_chip - on_cpu.act_bytes_per_chip == kept
     short = analyse(cfg, plan, 1, 8, 1024, hbm_bytes=16e9)
     assert short.act_bytes_per_chip == on_cpu.act_bytes_per_chip
+    kinds_on_chip = analyse(kinds, plan, 1, 1, 16384, hbm_bytes=16e9)
+    assert (
+        kinds_on_chip.act_bytes_per_chip - kinds_on_cpu.act_bytes_per_chip
+        == 16384 * 5 * 32 * (128 * 2 + 4)
+    )

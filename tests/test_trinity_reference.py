@@ -331,9 +331,10 @@ def test_step_counts_its_layers_by_kind(monkeypatch):
     """A traced train step carries ``attn.window_layers``,
     ``attn.full_layers`` and ``attn.output_kept``, set by
     ``decoder.forward`` as it decides: on the CPU (no flash
-    kernel) nothing is kept; where the attention runs the kernels the
-    FULL layers' output is kept at a span of 2,048 keys or more and a
-    window layer's, whose span the window bounds, is not."""
+    kernel) nothing is kept; where the attention runs the kernels each
+    KIND's output is kept where its forward kernel executes 2,048 keys
+    a query or more — the published window's band does, a narrower
+    one's does not beside full layers that do."""
     cfg = _cfg(n_layer=9, layer_types="S" + "SSSF" * 2, dtype="bfloat16")
     mesh = build_mesh(MeshConfig(dp=-1), devices=jax.devices()[:1])
     opt = make_optimizer(learning_rate=1e-4, warmup_steps=2, decay_steps=10)
@@ -354,9 +355,19 @@ def test_step_counts_its_layers_by_kind(monkeypatch):
         n_layer=9, layer_types="S" + "SSSF" * 2, attn_window=2048,
         max_seq=16384,
     )
-    assert not decoder.keeps_attention_output(wide, 16384, "flash", kind="S")
+    # — a band of three key tiles of 1,024, 2,880 keys a query executed
+    # for 1,920 attended to (PR 61: the rule reads what the kernel runs)
+    assert decoder.keeps_attention_output(wide, 16384, "flash", kind="S")
     assert decoder.keeps_attention_output(wide, 16384, "flash", kind="F")
-    assert decoder.kept_attention_layers(wide, 16384, "flash") == 2
+    assert decoder.kept_attention_layers(wide, 16384, "flash") == 9
+    # the line can still fall INSIDE a step: a window of 1,024 keys is a
+    # band of two tiles, 1,984 keys executed — remade — beside 8,704
+    narrow = dataclasses.replace(wide, attn_window=1024)
+    assert not decoder.keeps_attention_output(
+        narrow, 16384, "flash", kind="S"
+    )
+    assert decoder.keeps_attention_output(narrow, 16384, "flash", kind="F")
+    assert decoder.kept_attention_layers(narrow, 16384, "flash") == 2
     assert decoder.kept_attention_layers(wide, 2048, "flash") == 0
     assert decoder.kept_attention_layers(wide, 16384, "reference") == 0
     # a model of one kind counts all its layers or none
