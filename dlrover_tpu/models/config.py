@@ -179,11 +179,15 @@ class ModelConfig:
     # ``m`` a Mamba-1 mixer, ``*`` an attention, ``S`` a block-sparse
     # attention (``sparse_block``; no rope), ``L`` a lightning linear
     # attention (``n_head`` heads of their own k and v, rope, a fixed
-    # decay a head, a norm over the whole read-out and a gate on it), ``E`` the routed
+    # decay a head, a norm over the whole read-out and a gate on it),
+    # ``G`` a gated-delta-rule mixer (``gdn_*``), ``E`` the routed
     # experts, ``-`` a dense MLP of ``d_ff``. A ``-`` that follows
     # another part is the second part of that part's LAYER (a mixer +
-    # MLP layer, pre-norm twice: ``m-``, ``*-``); every other letter is
-    # a layer by itself, and ``n_layer`` counts layers. Parameters are
+    # MLP layer, pre-norm twice: ``m-``, ``*-``), and so is an ``e``,
+    # the ROUTED experts as a layer's second part (a mixer + routed
+    # layer: ``Ge``, ``*e``; one pattern routes by ``E`` or by ``e``,
+    # not both); every other letter is a layer by itself, and
+    # ``n_layer`` counts layers. Parameters are
     # stacked kind by kind and visited in this order. ``mtp_pattern``
     # is the prediction module's layers, likewise. Training path only
     layer_pattern: str = ""
@@ -217,6 +221,34 @@ class ModelConfig:
     # every channel, D = 1
     mamba_expand: int = 0
     mamba_dt_rank: int = 0
+    # the gated-delta-rule mixer (``G``; Gated DeltaNet,
+    # ops/gated_delta.py): ``gdn_key_heads`` heads of ``gdn_key_dim``
+    # channels for q and k, each shared by ``gdn_value_heads`` /
+    # ``gdn_key_heads`` NEIGHBOURING value heads (repeat-interleave) of
+    # ``gdn_value_dim`` channels; one write strength and one decay a
+    # value head and token; a causal depthwise conv of ``conv_kernel``
+    # taps over [q | k | v], no bias; the rule in chunks the op chooses
+    # (``ops/gated_delta.py``: no size of the model); an RMSNorm over
+    # each value head's read-out with ONE scale of ``gdn_value_dim``,
+    # THEN the gate silu(z). The decay's A is
+    # drawn uniform in [1, 16] and the time step's bias as the Mamba
+    # mixers' (``time_step_*``)
+    gdn_key_heads: int = 0
+    gdn_value_heads: int = 0
+    gdn_key_dim: int = 0
+    gdn_value_dim: int = 0
+    # the share of a head's channels that rope turns, the FIRST ones
+    # (rotate-half inside them; the rest pass untouched): a published
+    # ``partial_rotary_factor``
+    partial_rotary_factor: float = 1.0
+    # every RMSNorm of the trunk (a part's input norm, the final norm,
+    # the per-head q and k norms) multiplies by 1 + w and w starts at 0
+    # (Qwen3-Next's and Gemma's form): w is what is stored, so weight
+    # decay pulls the multiplier to 1. A layer_pattern model's field
+    norm_zero_centered: bool = False
+    # the shared expert's own gate: sigmoid(x w) of ONE d_model x 1
+    # matrix times its output
+    shared_expert_gate: bool = False
     # a selection of BLOCKS of keys with no parameters of its own
     # (InfLLM-v2, MiniCPM4's ``sparse_config``; the ``S`` part of a
     # ``layer_pattern``; 0 = none): keys mean-pooled over windows of
@@ -375,6 +407,23 @@ class ModelConfig:
                     "latent attention is rope on its own channels, MHA, "
                     "no qk_norm"
                 )
+        if self.partial_rotary_factor != 1.0 and (
+            not 0.0 < self.partial_rotary_factor < 1.0
+            or (self.head_dim * self.partial_rotary_factor) % 2
+        ):
+            raise ValueError(
+                "partial_rotary_factor leaves rope an even number of a "
+                f"head's channels, in (0, 1]; got {self.partial_rotary_factor}"
+            )
+        if (
+            self.partial_rotary_factor != 1.0 or self.norm_zero_centered
+        ) and not self.layer_pattern:
+            raise ValueError(
+                "partial_rotary_factor and norm_zero_centered are a "
+                "layer_pattern model's"
+            )
+        if self.shared_expert_gate and not self.n_shared_experts:
+            raise ValueError("shared_expert_gate gates a shared expert")
         if self.sparse_block and "S" not in self.layer_pattern:
             raise ValueError(
                 "sparse_block is the S part of a layer_pattern model"
@@ -473,13 +522,14 @@ class ModelConfig:
     def _check_pattern(self):
         """A ``layer_pattern`` model: what its letters need."""
         for name in ("layer_pattern", "mtp_pattern"):
-            odd = set(getattr(self, name)) - set("Mm*SLE-")
+            odd = set(getattr(self, name)) - set("Mm*SLGEe-")
             if odd:
                 raise ValueError(
                     f"{name} is made of M (Mamba-2), m (Mamba-1), * "
                     f"(attention), S (block-sparse attention), L "
-                    f"(lightning attention), E (routed experts) and - "
-                    f"(dense MLP); got {sorted(odd)}"
+                    f"(lightning attention), G (gated delta rule), E "
+                    f"(routed experts), e (routed experts, a layer's "
+                    f"second part) and - (dense MLP); got {sorted(odd)}"
                 )
         if pattern_layers(self.layer_pattern) != self.n_layer:
             raise ValueError(
@@ -516,11 +566,24 @@ class ModelConfig:
                 "a Mamba-1 part needs mamba_expand, mamba_dt_rank, "
                 "ssm_state_size and conv_kernel"
             )
-        if set("SL") & set(self.mtp_pattern):
+        if set("SLG") & set(self.mtp_pattern):
             raise ValueError(
-                "S and L parts are the trunk's: a prediction module's "
-                "selection is handed over by no one"
+                "S, L and G parts are the trunk's: a prediction module's "
+                "selection or read-out is handed over by no one"
             )
+        if "G" in letters:
+            sizes = (
+                self.gdn_key_heads, self.gdn_value_heads, self.gdn_key_dim,
+                self.gdn_value_dim, self.conv_kernel,
+            )
+            if not all(n > 0 for n in sizes) or (
+                self.gdn_value_heads % self.gdn_key_heads
+            ):
+                raise ValueError(
+                    "a G part needs gdn_key_heads, gdn_value_heads (a "
+                    "multiple of them), gdn_key_dim, gdn_value_dim, "
+                    "and conv_kernel"
+                )
         if "S" in letters:
             sizes = (
                 self.sparse_block, self.pool_window, self.pool_stride,
@@ -560,12 +623,17 @@ class ModelConfig:
             raise ValueError(
                 "a - part is the dense MLP of d_ff: act 'swiglu' or 'gelu'"
             )
-        if "E" in letters and not (
+        if set("Ee") & set(letters) and not (
             self.n_experts and self.moe_impl == "ragged"
         ):
             raise ValueError(
                 "an E layer is the ragged (dropless) routed block: "
                 "n_experts > 0 and moe_impl='ragged'"
+            )
+        if "E" in letters and "e" in letters:
+            raise ValueError(
+                "a pattern routes by E (a layer by itself) or by e (a "
+                "layer's second part): the two share one stack"
             )
         if (
             self.n_dense_layer or self.latent_attention or self.selects_keys
@@ -660,6 +728,23 @@ class ModelConfig:
         ``n_head`` heads of ``head_dim`` each."""
         return 5 * self.d_model * self.n_head * self.head_dim
 
+    @property
+    def gdn_conv_dim(self) -> int:
+        """Channels a ``G`` part's conv runs over: [q | k | v]."""
+        return (
+            2 * self.gdn_key_heads * self.gdn_key_dim
+            + self.gdn_value_heads * self.gdn_value_dim
+        )
+
+    @property
+    def gdn_params(self) -> int:
+        """One ``G`` part's matrices: [q | k | v | z], [b | a] and the
+        output's."""
+        inner = self.gdn_value_heads * self.gdn_value_dim
+        return self.d_model * (
+            self.gdn_conv_dim + inner + 2 * self.gdn_value_heads + inner
+        )
+
     def kind_window(self, kind: str = "") -> int:
         """Keys a query of a layer of ``kind`` may see (0 = every
         earlier one; None reads as 0): ``attn_window`` as it is, but
@@ -696,7 +781,7 @@ class ModelConfig:
         """Channels of a head that rope turns."""
         if self.latent_attention:
             return self.qk_rope_head_dim
-        return self.head_dim
+        return int(self.head_dim * self.partial_rotary_factor)
 
     @property
     def expert_width(self) -> int:
@@ -712,7 +797,7 @@ class ModelConfig:
         """Routed layers of the trunk (the prediction module's block is
         not among them)."""
         if self.layer_pattern:
-            return self.layer_pattern.count("E")
+            return sum(self.layer_pattern.count(c) for c in "Ee")
         return self.n_layer - self.n_dense_layer if self.n_experts else 0
 
     @property
@@ -725,6 +810,11 @@ class ModelConfig:
         if "L" in self.layer_pattern:
             return (
                 "lightning (L) layers: no recurrent state beside the cache"
+            )
+        if "G" in self.layer_pattern:
+            return (
+                "gated-delta-rule (G) layers: no recurrent state beside "
+                "the cache"
             )
         if "S" in self.layer_pattern:
             return "block-sparse (S) layers: a selection has no cache path"
@@ -763,6 +853,7 @@ class ModelConfig:
             d * self.n_experts + 2 * d * self.moe_latent_size
             + mats * d * self.shared_expert_width
             * bool(self.n_shared_experts)
+            + d * self.shared_expert_gate
         )
         expert = mats * self.expert_in * self.expert_width
         inner1, rank = self.d_inner1, self.mamba_dt_rank
@@ -771,6 +862,13 @@ class ModelConfig:
         mlp = (3 if self.act == "swiglu" else 2) * d * self.d_ff
         d_attn = self.n_head * self.head_dim
         qk_scales = 2 * self.head_dim * self.qk_head_norm
+        routed = (
+            outside + self.experts_here * expert + d,
+            outside + (
+                self.routed_top_k * self.experts_here
+                / max(self.n_experts, 1)
+            ) * expert,
+        )
         return {
             "m": (
                 mamba1 + inner1 * (self.conv_kernel + 1)   # conv, its bias
@@ -791,13 +889,19 @@ class ModelConfig:
                 self.lightning_params + d_attn + d + qk_scales,
                 self.lightning_params + 2 * d_attn * self.head_dim,
             ),
-            "E": (
-                outside + self.experts_here * expert + d,
-                outside + (
-                    self.routed_top_k * self.experts_here
-                    / max(self.n_experts, 1)
-                ) * expert,
+            # the rule's own work a token and value head: the decay of
+            # the state (half a multiply-add a cell), Sᵀk, the rank-one
+            # write and the read-out Sᵀq: 3.5 x key x value channels
+            "G": (
+                self.gdn_params + self.gdn_conv_dim * self.conv_kernel
+                + 2 * self.gdn_value_heads + self.gdn_value_dim + d,
+                self.gdn_params + int(
+                    3.5 * self.gdn_value_heads * self.gdn_key_dim
+                    * self.gdn_value_dim
+                ),
             ),
+            "E": routed,
+            "e": routed,
         }
 
     def num_params(self) -> int:
@@ -969,9 +1073,10 @@ class ModelConfig:
 
 def pattern_parts(pattern: str):
     """A ``layer_pattern`` cut into its layers, each a string of parts:
-    a ``-`` that follows another part is that part's layer's MLP, every
+    a ``-`` (the dense MLP) or an ``e`` (the routed experts) that
+    follows another part is that part's layer's second part, every
     other letter a layer by itself."""
-    return re.findall(r"[^-]-?|-", pattern)
+    return re.findall(r"[^-e][-e]?|[-e]", pattern)
 
 
 def pattern_layers(pattern: str) -> int:
@@ -1379,6 +1484,59 @@ CONFIGS = {
         scale_emb=12.0,
         residual_scale=1.4 / 32 ** 0.5,
         logit_scale=256 / 4096,
+    ),
+    # a gated delta rule in three layers of four and a gated attention
+    # in the fourth, every layer's second part routed:
+    # Qwen3-Next-80B-A3B-Instruct (``qwen3_next``; huggingface.co/Qwen/
+    # Qwen3-Next-80B-A3B-Instruct config.json) — 48 layers over d 2048,
+    # each x + mixer(norm(x)) then x + routed(norm(x)), every norm but
+    # the mixer's output norm zero-centred (1 + w); layer i a
+    # gated-delta-rule mixer (``G``: 16 key heads of 128 shared two to
+    # one by 32 value heads of 128, conv 4 over [q | k | v], a decay and
+    # a write strength a value head, RMSNorm a head THEN silu(z)) where
+    # (i + 1) mod 4 is not 0, else a gated attention (``*``: GQA 16 / 2
+    # heads of 256, per-head zero-centred RMSNorm on q and k, rope theta
+    # 1e7 on the first 64 channels of a head, a sigmoid gate a channel);
+    # 512 SwiGLU experts of width 512, softmax top-10 renormalised,
+    # beside a shared expert of 512 under its own sigmoid gate; no
+    # router loss (config.json carries no coefficient); vocabulary
+    # 151,936 untied. The module for multi-token prediction is not
+    # built (config.json has no key for it). Training path only
+    "qwen3-next": ModelConfig(
+        name="qwen3-next",
+        vocab_size=151936,
+        n_layer=48,
+        layer_pattern="GeGeGe*e" * 12,
+        n_head=16,
+        n_kv_head=2,
+        d_head=256,
+        d_model=2048,
+        d_ff=5120,  # intermediate_size: published, and no layer uses it
+        max_seq=262144,
+        act="swiglu",
+        pos="rope",
+        rope_theta=1e7,
+        partial_rotary_factor=0.25,
+        attn_window=None,  # use_sliding_window: false
+        tie_embeddings=False,
+        qk_head_norm=True,
+        attn_gate=True,
+        norm_eps=1e-6,
+        norm_zero_centered=True,
+        gdn_key_heads=16,
+        gdn_value_heads=32,
+        gdn_key_dim=128,
+        gdn_value_dim=128,
+        conv_kernel=4,
+        n_experts=512,
+        expert_top_k=10,
+        d_expert=512,
+        n_shared_experts=1,
+        d_shared_expert=512,
+        shared_expert_gate=True,
+        moe_impl="ragged",
+        moe_score="softmax",
+        moe_renorm_topk=True,
     ),
     # an attention kind per layer, a gate on the attention's output,
     # four norms a layer: Trinity-Mini (``afmoe``, 26B-A3B;

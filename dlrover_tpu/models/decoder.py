@@ -67,6 +67,14 @@ def _stackers(cfg: ModelConfig, lead):
     return stack, ones
 
 
+def _norm_scale(cfg: ModelConfig, *shape):
+    """A trunk norm's learned vector as it starts: ones, or the zeros of
+    a zero-centred norm (``cfg.norm_zero_centered``: the multiplier is
+    1 + w and w is what is stored)."""
+    pdt = jnp.dtype(cfg.param_dtype)
+    return (jnp.zeros if cfg.norm_zero_centered else jnp.ones)(shape, pdt)
+
+
 def _init_attention(keys, cfg: ModelConfig, stack, ones) -> Params:
     """The attention's matrices (latent or plain q/k/v/o), drawn from
     ``keys`` through ``stack(key, shape, fan_in)``."""
@@ -149,11 +157,13 @@ def _init_layers(keys, cfg: ModelConfig, lead, routed: bool) -> Params:
 
 # a ``layer_pattern`` letter -> the name its stack of parts goes by:
 # M a Mamba-2 mixer, m a Mamba-1 mixer, * an attention, S a block-sparse
-# attention, L a lightning linear attention, E the routed experts, - a
-# dense MLP
+# attention, L a lightning linear attention, G a gated-delta-rule mixer,
+# E the routed experts (e: as a layer's second part; a pattern has one
+# of the two), - a dense MLP
 PART_NAMES = {
     "M": "mamba", "m": "mamba1", "*": "attention", "S": "sparse",
-    "L": "lightning", "E": "experts", "-": "mlp",
+    "L": "lightning", "G": "gdn", "E": "experts", "e": "experts",
+    "-": "mlp",
 }
 
 
@@ -163,8 +173,8 @@ def _pattern_runs(pattern: str):
     repeats > 1 goes through ``lax.scan``: at each layer, the SHORTEST
     unit of layers that repeats at least once more at once, all its
     repeats; a layer that starts no such unit runs unrolled (repeats 1).
-    A unit with a routed part (``E``) never qualifies: its choices ride
-    out layer by layer and its jitter folds the layer's index in; nor
+    A unit with a routed part (``E``, ``e``) never qualifies: its choices
+    ride out layer by layer and its jitter folds the layer's index in; nor
     one with a block-sparse attention (``S``), whose selection rides out
     likewise."""
     layers = pattern_parts(pattern)
@@ -173,7 +183,7 @@ def _pattern_runs(pattern: str):
         unit, reps = layers[i:i + 1], 1
         for p in range(1, (len(layers) - i) // 2 + 1):
             cand = layers[i:i + p]
-            if set("ES") & set("".join(cand)):
+            if set("EeS") & set("".join(cand)):
                 break
             n = 1
             while layers[i + n * p:i + (n + 1) * p] == cand:
@@ -329,6 +339,38 @@ def _init_lightning(key, cfg: ModelConfig, lead) -> Params:
     }
 
 
+def _init_gdn(key, cfg: ModelConfig, lead) -> Params:
+    """A gated-delta-rule mixer's parameters: ``w_qkvz`` [q | k | v | z]
+    and ``w_ba`` [b | a] in blocks (the published checkpoint interleaves
+    them a key head: a permutation of columns, the same function), the
+    conv's taps over [q | k | v] (no bias), ``A`` uniform in [1, 16]
+    (kept as its log), the time step's bias as ``_time_step_bias``, ONE
+    output-norm scale of a value head's channels (ones: this norm is
+    not zero-centred), and the output matrix."""
+    d, taps = cfg.d_model, cfg.conv_kernel
+    heads = cfg.gdn_value_heads
+    inner = heads * cfg.gdn_value_dim
+    stack, ones = _stackers(cfg, lead)
+    pdt = jnp.dtype(cfg.param_dtype)
+    lead = tuple(lead)
+    k = jax.random.split(key, 6)
+    bound = 1.0 / np.sqrt(taps)  # a depthwise tap sees ``taps`` inputs
+    return {
+        "w_qkvz": stack(k[0], (d, cfg.gdn_conv_dim + inner), d),
+        "w_ba": stack(k[1], (d, 2 * heads), d),
+        "conv_w": jax.random.uniform(
+            k[2], lead + (taps, cfg.gdn_conv_dim), minval=-bound,
+            maxval=bound,
+        ).astype(pdt),
+        "a_log": jnp.log(
+            jax.random.uniform(k[3], lead + (heads,), minval=1.0, maxval=16.0)
+        ).astype(pdt),
+        "dt_bias": _time_step_bias(k[4], cfg, lead + (heads,)).astype(pdt),
+        "norm": {"scale": ones(cfg.gdn_value_dim)},
+        "w_out": stack(k[5], (inner, d), inner),
+    }
+
+
 def _init_mlp(keys, cfg: ModelConfig, stack) -> Params:
     """The dense MLP's matrices: SwiGLU's three, or two."""
     d, f = cfg.d_model, cfg.d_ff
@@ -349,7 +391,7 @@ def _init_pattern(key, cfg: ModelConfig, pattern: str) -> Params:
         lead = (n,)
         kk = jax.random.fold_in(key, i)
         stack, ones = _stackers(cfg, lead)
-        layer: Params = {"ln": {"scale": ones(cfg.d_model)}}
+        layer: Params = {"ln": {"scale": _norm_scale(cfg, n, cfg.d_model)}}
         if letter == "M":
             layer["ssm"] = _init_mamba(kk, cfg, lead)
         elif letter == "m":
@@ -359,10 +401,14 @@ def _init_pattern(key, cfg: ModelConfig, pattern: str) -> Params:
                 jax.random.split(kk, 16), cfg, stack, ones
             )
             if cfg.qk_head_norm:
-                layer["attn"]["q_norm"] = {"scale": ones(cfg.head_dim)}
-                layer["attn"]["k_norm"] = {"scale": ones(cfg.head_dim)}
+                for which in ("q_norm", "k_norm"):
+                    layer["attn"][which] = {
+                        "scale": _norm_scale(cfg, n, cfg.head_dim)
+                    }
         elif letter == "L":
             layer["lin"] = _init_lightning(kk, cfg, lead)
+        elif letter == "G":
+            layer["gdn"] = _init_gdn(kk, cfg, lead)
         elif letter == "-":
             layer["mlp"] = _init_mlp(jax.random.split(kk, 16), cfg, stack)
         else:
@@ -395,7 +441,7 @@ def init(rng: jax.Array, cfg: ModelConfig) -> Params:
                 keys, cfg, (cfg.n_layer - n_dense,), routed=cfg.n_experts > 0
             )
         ),
-        "final_norm": {"scale": jnp.ones((d,), pdt)},
+        "final_norm": {"scale": _norm_scale(cfg, d)},
     }
     if n_dense:
         params["dense_layers"] = _init_layers(
@@ -514,6 +560,16 @@ def _pattern_axes(cfg: ModelConfig, pattern: str, lead) -> Params:
                     n: {"scale": lead + ("norm",)}
                     for n in ("q_norm", "k_norm", "o_norm")
                 },
+            }
+        elif letter == "G":
+            layer["gdn"] = {
+                "w_qkvz": lead + ("embed", "mlp"),
+                "w_ba": lead + ("embed", None),
+                "conv_w": lead + (None, "mlp"),
+                "a_log": lead + (None,),
+                "dt_bias": lead + (None,),
+                "norm": {"scale": lead + ("norm",)},
+                "w_out": lead + ("mlp", "embed"),
             }
         elif letter == "-":
             layer["mlp"] = _mlp_axes(cfg, lead)
@@ -720,15 +776,24 @@ def _norm_block(x, ln, cfg: ModelConfig, residual=None):
     prior program. With ``residual``, returns
     ``(norm(x + residual), x + residual)`` — on the kernel path the
     summed stream comes out of the same HBM visit."""
+    scale = _multiplier(ln["scale"], cfg)
     if _fused_norm_enabled(cfg):
         return pallas_norm.norm(
-            x, ln["scale"], ln.get("bias"), cfg.norm, residual=residual,
+            x, scale, ln.get("bias"), cfg.norm, residual=residual,
             eps=cfg.norm_eps,
         )
     if residual is not None:
         h = x + residual
-        return _norm(h, ln["scale"], ln.get("bias"), cfg.norm, cfg.norm_eps), h
-    return _norm(x, ln["scale"], ln.get("bias"), cfg.norm, cfg.norm_eps)
+        return _norm(h, scale, ln.get("bias"), cfg.norm, cfg.norm_eps), h
+    return _norm(x, scale, ln.get("bias"), cfg.norm, cfg.norm_eps)
+
+
+def _multiplier(scale, cfg: ModelConfig):
+    """What a trunk norm multiplies by: its learned vector, or 1 + it
+    in float32 where the model's norms are zero-centred."""
+    if not cfg.norm_zero_centered:
+        return scale
+    return 1.0 + scale.astype(jnp.float32)
 
 
 def _rope_tables(positions: jax.Array, head_dim: int, theta: float):
@@ -746,13 +811,21 @@ def _rope_tables(positions: jax.Array, head_dim: int, theta: float):
 
 def _rope(x: jax.Array, rope) -> jax.Array:
     """Apply rotary embedding. x:[B,S,H,D], rope: (cos, sin) tables
-    from ``_rope_tables``. Rotate-half via strided reshape — the f32
+    from ``_rope_tables``, of D channels or of a head's first few
+    (``cfg.rope_dim``). Rotate-half via strided reshape — the f32
     view [..., 2, D/2] pairs lane i with i+D/2 exactly like the old
     split+concatenate, without materializing two half-width
     temporaries, and is bitwise-identical to it (pinned in
     tests/test_model.py)."""
     d = x.shape[-1]
     cos, sin = rope
+    turned = 2 * cos.shape[-1]
+    if turned < d:
+        # a partial rotary factor: the first channels of a head are
+        # turned (rotate-half inside them), the rest pass as they are
+        return jnp.concatenate(
+            [_rope(x[..., :turned], rope), x[..., turned:]], axis=-1
+        )
     xr = x.astype(jnp.float32).reshape(x.shape[:-1] + (2, d // 2))
     x1, x2 = xr[..., 0, :], xr[..., 1, :]
     out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-2)
@@ -826,11 +899,16 @@ def _project_qkv(
     if cfg.qk_head_norm:
         # RMSNorm per head, one scale of head_dim for all heads; in jnp,
         # so that it fuses with rope's pass over the same values
-        q = _norm(q, attn["q_norm"]["scale"], None, "rmsnorm", cfg.norm_eps)
-        k = _norm(k, attn["k_norm"]["scale"], None, "rmsnorm", cfg.norm_eps)
+        q, k = (
+            _norm(
+                t, _multiplier(attn[name]["scale"], cfg), None, "rmsnorm",
+                cfg.norm_eps,
+            )
+            for t, name in ((q, "q_norm"), (k, "k_norm"))
+        )
     if cfg.pos == "rope" and rope is not False:
         if rope is None:
-            rope = _rope_tables(positions, hd, cfg.rope_theta)
+            rope = _rope_tables(positions, cfg.rope_dim, cfg.rope_theta)
         q = _rope(q, rope)
         k = _rope(k, rope)
     if cfg.mup_base_width:
@@ -1706,11 +1784,98 @@ def _mamba1_block(h, ssm, cfg: ModelConfig, mesh):
     return matmul(y, ssm["w_out"])
 
 
+def _l2_heads(t, scale=1.0, eps=1e-6):
+    """Each head's channels of t [B, S, H, D] over their L2 norm, times
+    ``scale``: float32 inside, one rounding."""
+    t32 = t.astype(jnp.float32)
+    inv = jax.lax.rsqrt(jnp.sum(t32 * t32, -1, keepdims=True) + eps)
+    return (t32 * (inv * scale)).astype(t.dtype)
+
+
+def _gdn_block(h, gdn, cfg: ModelConfig, mesh):
+    """A gated-delta-rule mixer on the layer's normed input ``h``
+    [B, S, D] (scope ``gdn``; inside it ``gdn.conv`` around ``ssm.conv``,
+    ``gdn.rule`` and ``gdn.gate``):
+
+        [q | k | v | z] = h W_qkvz;  [b | a] = h W_ba       (float32)
+        [q | k | v] = silu(conv([q | k | v]))               (no bias)
+        β = sigmoid(b);  g = -exp(A_log) softplus(a + dt_bias)
+        q, k = q / |q|, k / |k| a head;  q = q / sqrt(key channels)
+        o = gated_delta_rule(q, k, v, g, β)      (ops/gated_delta.py)
+        out = (rms_head(o) w ⊙ silu(z)) W_out
+
+    Key head j serves value heads R j .. R j + R - 1. The norm comes
+    BEFORE the gate (``ssd.gated_group_norm(norm_before_gate=True)``),
+    with one learned scale of a head's channels.
+
+    The three matrices multiply operands of the compute dtype and
+    EVERYTHING between them is float32, the output too (``_run_pattern``
+    rounds the stream), and the rule multiplies float32 operands in
+    three bf16 passes (``gated_delta._products``) — ``_mamba1_block``'s
+    reason, more so: a mixer between norms (its input's, q's and k's L2
+    norms, the read-out's) maps a relative change of its input into one
+    1.6 times as large in its output, every part behind it does so
+    again, and with bf16 between the matmuls each mixer adds 0.8% of its
+    own (its projection's rounding 0.6, q, k and v into the rule 0.25,
+    the rule's twelve roundings 0.35) where this form adds 0.17: at
+    eight layers the stream then stands 4.6e-2 from the float32
+    reference by rms, each mixer adding the same 0.7e-2, where the cell
+    may stand 2.5e-2 (my chip runs, PR 63: PERF.md section 6). Returns
+    (output, aux):
+    ``aux["gdn_readout_ms"]`` the mean square of the read-out ``o``
+    before the norm (float32): a uniform scale of ``o`` — q's
+    1 / sqrt(channels), a missing L2 norm on q — is invisible behind
+    the per-head norm, and this number is what sees it."""
+    from dlrover_tpu.ops import ssd
+    from dlrover_tpu.ops.gated_delta import gated_delta_rule
+
+    b, s, _ = h.shape
+    dt_, f32 = jnp.dtype(cfg.dtype), jnp.float32
+    hk, hv = cfg.gdn_key_heads, cfg.gdn_value_heads
+    dk, dv = cfg.gdn_key_dim, cfg.gdn_value_dim
+    keys, wide, inner = hk * dk, cfg.gdn_conv_dim, hv * dv
+
+    def matmul(x, w):
+        return jnp.matmul(
+            x.astype(dt_), w.astype(dt_), preferred_element_type=f32
+        )
+
+    proj = matmul(h, gdn["w_qkvz"])
+    if mesh is not None:
+        proj = shd.constrain(proj, mesh, "batch", "seq", "mlp")
+    ba = matmul(h, gdn["w_ba"])
+    # (the mesh only where it rules the kernels out: ``_mamba_block``)
+    several = {"mesh": mesh} if mesh is not None and mesh.size > 1 else {}
+    with jax.named_scope("gdn.conv"):
+        qkv = jax.nn.silu(ssd.causal_conv(
+            ssd.Columns(proj, 0), gdn["conv_w"], jnp.zeros((wide,), f32),
+            **several,
+        ))
+    with jax.named_scope("gdn.rule"):
+        beta = jax.nn.sigmoid(ba[..., :hv])
+        g = -jnp.exp(gdn["a_log"].astype(f32)) * jax.nn.softplus(
+            ba[..., hv:] + gdn["dt_bias"].astype(f32)
+        )
+        q = _l2_heads(qkv[..., :keys].reshape(b, s, hk, dk), dk ** -0.5)
+        k = _l2_heads(qkv[..., keys:2 * keys].reshape(b, s, hk, dk))
+        v = qkv[..., 2 * keys:].reshape(b, s, hv, dv)
+        o = gated_delta_rule(q, k, v, g, beta)
+    aux = {"gdn_readout_ms": jax.lax.stop_gradient(jnp.mean(jnp.square(o)))}
+    with jax.named_scope("gdn.gate"):
+        y = ssd.gated_group_norm(
+            o.reshape(b, s, inner), proj[..., wide:], gdn["norm"]["scale"],
+            hv, cfg.norm_eps or 1e-6, norm_before_gate=True,
+        )
+    return matmul(y, gdn["w_out"]), aux
+
+
 # the scope a part's operations are traced under
 _PART_SCOPES = {
     "M": "ssm", "m": "ssm1", "*": "attn", "S": "attn", "L": "lin",
-    "E": "mlp", "-": "mlp",
+    "G": "gdn", "E": "mlp", "e": "mlp", "-": "mlp",
 }
+# the one number a mixer part hands out beside x, by its letter
+_PART_READS = {"L": "lightning_fast_out_ms", "G": "gdn_readout_ms"}
 
 
 def _part_body(
@@ -1720,9 +1885,11 @@ def _part_body(
     """One part of a ``layer_pattern`` model, ``x + s part(norm(x))``
     (s = ``cfg.residual_scale``): a Mamba-2 mixer (``M``), a Mamba-1
     mixer (``m``), an attention (``*``), a block-sparse attention
-    (``S``), a lightning linear attention (``L``), the routed experts
-    (``E``) or a dense MLP (``-``). Returns (x, the routed block's, the
-    block-sparse attention's or the lightning part's aux, or {})."""
+    (``S``), a lightning linear attention (``L``), a gated-delta-rule
+    mixer (``G``), the routed experts (``E``, ``e``) or a dense MLP
+    (``-``). Returns (x, the routed block's, the block-sparse
+    attention's, the lightning part's or the delta-rule mixer's aux, or
+    {})."""
     aux = {}
     with jax.named_scope(_PART_SCOPES[letter]):
         # (``x`` is float32 behind a part whose output is, until
@@ -1742,6 +1909,8 @@ def _part_body(
             )
         elif letter == "L":
             out, aux = _lightning_block(h, layer["lin"], cfg, mesh, rope)
+        elif letter == "G":
+            out, aux = _gdn_block(h, layer["gdn"], cfg, mesh)
         elif letter == "-":
             out = _mlp_block(h, layer, cfg, mesh, interior=jnp.float32)
         else:
@@ -1783,8 +1952,9 @@ def _run_pattern(
     block-sparse attentions' ``sparse_attn_out_ms`` as their mean and,
     where ``return_selected``, their selections as ``attn_selected``
     bool [S parts x KV, B, S, U], layer-major and group-minor; the
-    lightning parts' ``lightning_fast_out_ms`` as their mean, those of a
-    scanned run among them (the one thing a run hands out beside x).
+    lightning parts' ``lightning_fast_out_ms`` and the delta-rule
+    mixers' ``gdn_readout_ms`` as their means (``_PART_READS``), those
+    of a scanned run among them (the one thing a run hands out beside x).
     ``first``: the index of the pattern's first part, folded into
     ``rng``."""
     parts = {
@@ -1804,7 +1974,8 @@ def _run_pattern(
     )
     places = _part_places(pattern)
     seen = dict.fromkeys(bodies, 0)
-    auxs, sparse, lightning = [], [], []
+    auxs, sparse = [], []
+    reads = {name: [] for name in _PART_READS.values()}
     i = 0
     for unit, reps in _pattern_runs(pattern):
         if reps > 1:
@@ -1825,20 +1996,23 @@ def _run_pattern(
 
             def repeat(x, stacks):
                 at = dict.fromkeys(stacks, 0)
-                read = []
+                read = {}
                 for letter in unit:
                     layer = jax.tree.map(
                         lambda t: t[at[letter]], stacks[letter]
                     )
                     at[letter] += 1
                     x, aux = parts[letter](x, layer, positions, rope=rope)
-                    if letter == "L":
-                        read.append(aux["lightning_fast_out_ms"])
-                return x.astype(cfg.dtype), jnp.stack(read) if read else None
+                    if letter in _PART_READS:
+                        name = _PART_READS[letter]
+                        read.setdefault(name, []).append(aux[name])
+                return x.astype(cfg.dtype), {
+                    name: jnp.stack(r) for name, r in read.items()
+                }
 
             x, read = _scan_run(_remat(repeat, cfg, keep_attn), x, stacks)
-            if read is not None:
-                lightning.append(read.reshape(-1))
+            for name, r in (read or {}).items():
+                reads[name].append(r.reshape(-1))
             i += len(unit) * reps
             continue
         for letter in unit:
@@ -1849,13 +2023,14 @@ def _run_pattern(
             x, aux = bodies[letter](x, layer, positions, rng=r, rope=rope)
             x = x.astype(cfg.dtype)
             i += 1
-            if letter == "L":
-                lightning.append(aux["lightning_fast_out_ms"][None])
+            if letter in _PART_READS:
+                name = _PART_READS[letter]
+                reads[name].append(aux[name][None])
             elif aux:
                 (sparse if letter == "S" else auxs).append(aux)
-    out = {}
-    if lightning:
-        out["lightning_fast_out_ms"] = jnp.mean(jnp.concatenate(lightning))
+    out = {
+        name: jnp.mean(jnp.concatenate(r)) for name, r in reads.items() if r
+    }
     if sparse:
         out["sparse_attn_out_ms"] = jnp.mean(
             jnp.stack([a["sparse_attn_out_ms"] for a in sparse])
@@ -1876,8 +2051,9 @@ def _run_pattern(
 
 def _scan_run(repeat, x, stacks):
     """A run of repeats of one unit: ``repeat(x, a repeat's slices of
-    the stacks) -> (x, what the repeat hands out, or None)`` over the
-    stacks' leading axis. Returns (x, those stacked, or None)."""
+    the stacks) -> (x, what the repeat's mixers hand out, by name)``
+    over the stacks' leading axis. Returns (x, those stacked, or
+    None)."""
     return jax.lax.scan(repeat, x, stacks)
 
 
@@ -2093,6 +2269,8 @@ def run_trunk(
             set_counter("ssm1.layers", cfg.layer_pattern.count("m"))
         if "L" in cfg.layer_pattern:
             set_counter("lin.layers", cfg.layer_pattern.count("L"))
+        if "G" in cfg.layer_pattern:
+            set_counter("gdn.layers", cfg.layer_pattern.count("G"))
         if "S" in cfg.layer_pattern:
             set_counter("attn.sparse_layers", cfg.layer_pattern.count("S"))
             set_counter("attn.select_block", cfg.sparse_block)
@@ -2764,6 +2942,10 @@ def _loss_from_head(
         # nor this: the lightning parts' fast heads' mean square
         # read-out, what a running log-decay of too few bits moves
         metrics["lightning_fast_out_ms"] = moe_aux["lightning_fast_out_ms"]
+    if "gdn_readout_ms" in moe_aux:
+        # nor this: the delta-rule mixers' mean square read-out before
+        # the per-head norm, what a uniform scale of it moves
+        metrics["gdn_readout_ms"] = moe_aux["gdn_readout_ms"]
     # run_trunk (and the prediction module) summed these over the
     # routed blocks; reported as the mean over them
     blocks = cfg.n_routed_layer + cfg.n_mtp_module

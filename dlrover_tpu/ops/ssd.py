@@ -283,12 +283,26 @@ def causal_conv(x, weight, bias, mesh=None):
         return _conv(x, weight, bias)
 
 
-def gated_group_norm(y, z, scale, groups: int, eps: float):
+def gated_group_norm(y, z, scale, groups: int, eps: float,
+                     norm_before_gate: bool = False):
     """``RMSNorm over each of `groups` groups of (y ⊙ silu(z)) ⊙ scale``:
-    the gate first, then the norm (Mamba-2's ``norm_before_gate=False``).
-    y and z [B, S, C]; float32 inside."""
+    the gate first, then the norm (Mamba-2's ``norm_before_gate=False``);
+    or, ``norm_before_gate``, ``RMSNorm(y) ⊙ scale ⊙ silu(z)`` (the
+    gated-delta-rule mixer's order). y and z [B, S, C]; ``scale`` [C],
+    or one group's [C / groups] that every group shares; float32
+    inside."""
     shape = y.shape
-    v = y.astype(F32) * jax.nn.silu(z.astype(F32))
+    gate = jax.nn.silu(z.astype(F32))
+    v = y.astype(F32)
+    if not norm_before_gate:
+        v = v * gate
     v = v.reshape(shape[:-1] + (groups, shape[-1] // groups))
     v = v * jax.lax.rsqrt(jnp.mean(v * v, -1, keepdims=True) + eps)
-    return (v.reshape(shape) * scale.astype(F32)).astype(y.dtype)
+    if scale.shape[-1] != shape[-1]:
+        v = v * scale.astype(F32)  # one group's, shared
+        v = v.reshape(shape)
+    else:
+        v = v.reshape(shape) * scale.astype(F32)
+    if norm_before_gate:
+        v = v * gate
+    return v.astype(y.dtype)

@@ -62,6 +62,10 @@ def init_moe_params(rng, cfg, lead=None) -> Dict:
         }
         if gated:
             params["shared"]["w_gate"] = draw(ks[0], (d, fs), s_in)
+        if cfg.shared_expert_gate:
+            params["shared"]["w_own_gate"] = draw(
+                jax.random.fold_in(rng, 3), (d, 1), s_in
+            )
     return params
 
 
@@ -89,6 +93,8 @@ def moe_logical_axes(cfg, lead=("layers",)) -> Dict:
         }
         if gated:
             ax["shared"]["w_gate"] = lead + ("embed", "mlp")
+        if cfg.shared_expert_gate:
+            ax["shared"]["w_own_gate"] = lead + ("embed", None)
     return ax
 
 
@@ -361,7 +367,10 @@ def moe_block(
 
 def _shared_expert(x, shared, mesh):
     """The MLP every token meets beside its routed experts: a SwiGLU,
-    or relu(.)² between two matrices where it has no gate."""
+    or relu(.)² between two matrices where it has no gate; times
+    ``sigmoid(x w)`` of its OWN gate ``w_own_gate`` [d, 1] where the
+    model has one (``cfg.shared_expert_gate``), the sigmoid and the
+    product float32."""
     with jax.named_scope("moe.shared"):
         h = x @ shared["w_up"].astype(x.dtype)
         if "w_gate" in shared:
@@ -370,7 +379,14 @@ def _shared_expert(x, shared, mesh):
             h = jnp.square(jax.nn.relu(h))
         if mesh is not None:
             h = shd.constrain(h, mesh, "batch", "seq", "mlp")
-        return h @ shared["w_down"].astype(x.dtype)
+        out = h @ shared["w_down"].astype(x.dtype)
+        if "w_own_gate" in shared:
+            own = jnp.matmul(
+                x, shared["w_own_gate"].astype(x.dtype),
+                preferred_element_type=jnp.float32,
+            )
+            out = (out * jax.nn.sigmoid(own)).astype(x.dtype)
+        return out
 
 
 def _moe_block_dense(x, moe, cfg, mesh, rng, fp8):

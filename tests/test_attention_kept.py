@@ -183,6 +183,7 @@ CELL_SPANS = {
     "trinitymini-ep8-train-b1s16384": {
         "F": (8192.5, 8704, True), "S": (1920.0625, 2880, True),
     },
+    "qwen3next-ep16-train-b1s16384": (8192.5, 8704, True),  # its one *
 }
 
 
@@ -202,7 +203,7 @@ def _cell_keys(cfg, seq, kind):
 @pytest.mark.parametrize("cell", sorted(CELL_SPANS))
 def test_the_line_falls_between_the_cells(cell):
     """The predicate over a benchmark cell as it is run, by the keys
-    its forward kernel executes: true for the eight whose forward runs
+    its forward kernel executes: true for the nine whose forward runs
     2,560 keys a query or more — Trinity's window layers among them,
     2,880 executed for 1,920 attended to —, false for the two at 1,024
     tokens, and false everywhere on the reference attention (the CPU's

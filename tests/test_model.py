@@ -53,6 +53,7 @@ BENCHMARK_CONFIGS = {
     "trinity-mini-ep8-1chip": 16384,
     "jamba2-3b-l14": 8192,
     "minicpm-sala-l4": 16384,
+    "qwen3-next-80b-a3b-ep16-1chip": 16384,
 }
 
 
@@ -70,7 +71,8 @@ def test_flops_per_token_is_the_benchmarks_count(name):
     attention kind per layer with each kind's own span, mixer + MLP
     layers of two parts each with a selective scan's, a selection of
     blocks with its pooled scorer past the length the model runs dense
-    up to, and a linear attention's recurrence."""
+    up to, a linear attention's recurrence, and mixer + ROUTED layers
+    with a gated delta rule's."""
     from benchmarks.lib.flops import resolve
 
     configs = pathlib.Path(__file__).parent.parent / "benchmarks" / "configs"

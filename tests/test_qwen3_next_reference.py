@@ -1,0 +1,466 @@
+"""Qwen3-Next's architecture (``qwen3-next``: layers of a mixer and a
+ROUTED part by a pattern — a gated-delta-rule mixer through the chunked
+rule in three of four, a gated attention with partial rope in the
+fourth, zero-centred norms, softmax top-k experts beside a gated shared
+one, a part of them held) against the benchmark's plain reference, at a
+tiny size on the CPU with seeded weights whose norm offsets are not
+zero: the comparison the chip's cell is judged by
+(``benchmarks/lib/routed.py``), the read-out's mean square, the shares
+of an expert-parallel layer adding up to the uncut layer, the defects
+the comparison has to catch, the gradient, the parameter count and the
+FLOPs, and the paths that refuse the model."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.lib import flops as flopslib
+from benchmarks.lib import routed
+from benchmarks.references import qwen3_next_plain as plain
+from benchmarks.tests import qwen3next_defects as defects
+from dlrover_tpu.models import decoder, generate, get_config
+from dlrover_tpu.models.config import pattern_layers, pattern_parts
+from dlrover_tpu.parallel import moe
+
+# two periods; 2 key heads shared by 4 value heads of 8 (a sequence of
+# 72 is a chunk of 64 and a padded one); heads of 16 with rope on their
+# first 4 channels; top-2 of 8 experts with 4 held
+TINY = dict(
+    n_layer=8, layer_pattern="GeGeGe*e" * 2, d_model=64, n_head=4,
+    n_kv_head=2, d_head=16, vocab_size=256, max_seq=128, gdn_key_heads=2,
+    gdn_value_heads=4, gdn_key_dim=8, gdn_value_dim=8,
+    n_experts=8, expert_top_k=2, d_expert=32, d_shared_expert=32,
+    n_experts_held=4, expert_offset=0, remat="full", dtype="float32",
+)
+SIZE_KEYS = (
+    "n_layer", "layer_pattern", "d_model", "n_head", "n_kv_head", "d_head",
+    "vocab_size", "partial_rotary_factor", "rope_theta", "gdn_key_heads",
+    "gdn_value_heads", "gdn_key_dim", "gdn_value_dim", "conv_kernel",
+    "n_experts", "n_experts_held", "expert_offset", "expert_top_k",
+    "d_expert", "d_shared_expert", "moe_renorm_topk",
+)
+# float32 on both sides: far inside the chip's limits, so that a defect
+# shows by orders of magnitude
+TOLERANCES = (1e-3, 1e-3, 1e-4)
+SEQ = 72
+
+
+def _cfg(**over):
+    return get_config("qwen3-next", **{**TINY, **over})
+
+
+def _sizes(cfg):
+    return dict({k: getattr(cfg, k) for k in SIZE_KEYS}, norm_eps=1e-6)
+
+
+def _batch(seq=SEQ, rows=2, vocab=256):
+    """Every token twice in a row (a a b b c c ...): the next token is
+    the present one half of the time."""
+    half = np.random.default_rng(7).integers(0, vocab, (rows, seq // 2 + 1))
+    data = jnp.asarray(np.repeat(half, 2, axis=1)[:, : seq + 1], jnp.int32)
+    return {"tokens": data[:, :-1], "targets": data[:, 1:]}
+
+
+def _offsets(tree, key):
+    """Every norm offset (a ``scale`` that starts at 0) and the mixer's
+    output-norm scale moved off its initial value: at 0 and 1 a program
+    that reads ``w`` for ``1 + w``, or norms after the gate, could not
+    be told from a sound one by these alone."""
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(tree)
+    out = []
+    for i, (path, leaf) in enumerate(leaves):
+        if "scale" in jax.tree_util.keystr(path):
+            leaf = leaf + 0.3 * jax.random.normal(
+                jax.random.fold_in(key, i), leaf.shape
+            )
+        out.append(leaf)
+    return jax.tree_util.tree_unflatten(treedef, out)
+
+
+@pytest.fixture(scope="module")
+def model():
+    """Seeded weights, but for a head that reads the token table
+    (``tests/test_glm_reference.py`` says why) and norms that are off
+    their initial values. A_log, dt_bias and the conv's taps are drawn,
+    not constants, by ``decoder.init`` itself."""
+    cfg = _cfg()
+    params = _offsets(
+        decoder.init(jax.random.key(0), cfg), jax.random.key(1)
+    )
+    d = cfg.d_model
+    params["lm_head"]["w"] = params["embed"]["tokens"].T / (0.02 * d ** 0.5)
+    return cfg, params
+
+
+def _compare(cfg, params, batch, sizes=None):
+    """The cell's comparison, teacher-forced and free-running."""
+    sizes = sizes or _sizes(cfg)
+    logits, choices = routed.program_logits_and_choices(
+        params, batch["tokens"], cfg
+    )
+    program = routed.program_losses(params, batch, cfg)
+    results, record = routed.compare(
+        plain, params, batch, sizes, 8, logits, choices, program, TOLERANCES
+    )
+    with jax.default_matmul_precision("highest"):
+        free_loss, _ = plain.loss_and_logits(params, batch, sizes, 8)
+    err = abs(program["loss"] - float(free_loss)) / float(free_loss)
+    results.append(
+        ("loss_vs_free_reference", err <= routed.FREE_LOSS_TOL, err,
+         routed.FREE_LOSS_TOL)
+    )
+    return {name: (ok, value) for name, ok, value, _ in results}, record
+
+
+def test_program_matches_the_plain_reference(model):
+    cfg, params = model
+    checks, record = _compare(cfg, params, _batch())
+    assert list(checks) == [
+        "choices_valid", "routing_regret", "logits_vs_reference",
+        "logits_rms_vs_reference", "loss_vs_reference",
+        "gdn_readout_ms_vs_reference", "loss_vs_free_reference",
+    ]
+    assert all(ok for ok, _ in checks.values()), checks
+    assert checks["routing_regret"][1] == 0.0
+    assert checks["logits_vs_reference"][1] < 1e-4
+    assert checks["gdn_readout_ms_vs_reference"][1] < 1e-5
+    # one row of choices per routed block: every layer has one
+    assert len(record["moved_by_layer"]) == cfg.n_routed_layer == 8
+
+
+def test_forward_hands_over_every_choice_and_the_readout(model):
+    cfg, params = model
+    batch = _batch()
+    _, aux = jax.jit(
+        lambda p, t: decoder.forward(p, t, cfg, return_aux=True)
+    )(params, batch["tokens"])
+    ids = np.asarray(aux["moe_choices"])
+    assert ids.dtype == np.int32
+    assert ids.shape == (8, 2, SEQ, cfg.expert_top_k)
+    assert ids.max() >= cfg.n_experts_held and ids.max() < cfg.n_experts
+    metrics = jax.jit(lambda p, b: decoder.loss_fn(p, b, cfg)[1])(
+        params, batch
+    )
+    assert float(metrics["moe_held_rows"]) == pytest.approx(
+        (ids < cfg.n_experts_held).sum() / 8
+    )
+    assert set(metrics) >= {"loss", "gdn_readout_ms", "moe_held_rows"}
+    with jax.default_matmul_precision("highest"):
+        _, _, readout = jax.jit(
+            lambda p, t: plain.forward(p, t, _sizes(cfg), 8)
+        )(params, batch["tokens"])
+    assert float(metrics["gdn_readout_ms"]) == pytest.approx(
+        float(readout), rel=1e-5
+    )
+
+
+def test_a_layer_is_a_mixer_and_a_routed_part():
+    """``e`` is a layer's second part as ``-`` is; ``E`` stays a layer
+    by itself; one pattern has one of the two."""
+    assert pattern_parts("GeGe*e") == ["Ge", "Ge", "*e"]
+    assert pattern_layers("GeGeGe*e" * 12) == 48
+    assert pattern_parts("MEM*E") == ["M", "E", "M", "*", "E"]
+    assert pattern_parts("m-*-") == ["m-", "*-"]
+    cfg = get_config("qwen3-next")
+    assert cfg.n_layer == 48 and cfg.n_routed_layer == 48
+    assert cfg.layer_pattern.count("G") == 36
+    assert [i for i in range(48) if cfg.layer_pattern[2 * i] == "*"] == [
+        i for i in range(48) if (i + 1) % 4 == 0
+    ]
+    with pytest.raises(ValueError, match="E .* or by e"):
+        _cfg(layer_pattern="GeGeGe*EGeGeGeGe", n_layer=9)
+    # no unit with a routed part is scanned: its choices ride out
+    assert all(n == 1 for _, n in decoder._pattern_runs(cfg.layer_pattern))
+
+
+def test_rope_turns_a_quarter_of_a_head():
+    cfg = get_config("qwen3-next")
+    assert cfg.head_dim == 256 and cfg.rope_dim == 64
+    assert get_config("keye-vl-2.0").rope_dim == 128
+    assert get_config("glm-4.7-flash").rope_dim == 64
+    x = jax.random.normal(jax.random.key(0), (1, 6, 2, 16))
+    positions = jnp.arange(6)[None]
+    rope = decoder._rope_tables(positions, 4, 1e4)
+    got = decoder._rope(x, rope)
+    np.testing.assert_array_equal(
+        np.asarray(got[..., 4:]), np.asarray(x[..., 4:])
+    )
+    np.testing.assert_allclose(
+        np.asarray(got[..., :4]), np.asarray(decoder._rope(x[..., :4], rope)),
+        rtol=1e-6,
+    )
+    assert not np.allclose(np.asarray(got[:, 1:, :, :4]),
+                           np.asarray(x[:, 1:, :, :4]))
+    with pytest.raises(ValueError, match="partial_rotary_factor"):
+        _cfg(partial_rotary_factor=0.3)
+
+
+def test_norm_offsets_start_at_zero_and_the_mixers_norm_at_one():
+    cfg = _cfg()
+    params = decoder.init(jax.random.key(0), cfg)
+    layers = params["layers"]
+    for scale in (
+        params["final_norm"]["scale"], layers["gdn"]["ln"]["scale"],
+        layers["experts"]["ln"]["scale"], layers["attention"]["ln"]["scale"],
+        layers["attention"]["attn"]["q_norm"]["scale"],
+        layers["attention"]["attn"]["k_norm"]["scale"],
+    ):
+        np.testing.assert_array_equal(np.asarray(scale), 0.0)
+    norm = layers["gdn"]["gdn"]["norm"]["scale"]
+    assert norm.shape == (6, cfg.gdn_value_dim)
+    np.testing.assert_array_equal(np.asarray(norm), 1.0)
+    a = np.exp(np.asarray(layers["gdn"]["gdn"]["a_log"]))
+    assert 1.0 <= a.min() and a.max() <= 16.0 and a.std() > 0
+    # a model that is not zero-centred keeps its ones
+    other = decoder.init(
+        jax.random.key(0),
+        get_config("jamba2-3b", n_layer=2, layer_pattern="m-*-", d_model=64,
+                   n_head=4, d_head=16, d_ff=64, vocab_size=64,
+                   mamba_dt_rank=4),
+    )
+    np.testing.assert_array_equal(
+        np.asarray(other["final_norm"]["scale"]), 1.0
+    )
+
+
+# ---- defects the comparison has to catch ---------------------------------
+
+
+def _conv_looks_ahead(patch):
+    from dlrover_tpu.ops import ssd
+
+    conv = ssd.causal_conv
+    patch(
+        ssd, "causal_conv",
+        lambda x, w, b: jnp.roll(conv(x, w, b), -1, axis=1),
+    )
+
+
+def _held_only_weights(patch):
+    """Combine weights normalised over the chosen experts that are HERE."""
+
+    def weights(probs, k, renormalize):
+        vals, idx = jax.lax.top_k(probs, k)
+        here = idx < TINY["n_experts_held"]
+        total = jnp.sum(jnp.where(here, vals, 0.0), -1, keepdims=True)
+        return vals / jnp.maximum(total, 1e-9), idx
+
+    patch(moe, "_topk_weights", weights)
+
+
+LOGITS = ("logits_vs_reference", "logits_rms_vs_reference")
+DEFECTS = {
+    **{
+        name: (defects.PLANT[name], defects.CAUGHT_BY[name])
+        for name in defects.PLANT
+    },
+    "conv_looks_ahead": (_conv_looks_ahead, LOGITS),
+    "weights_over_held_experts_only": (_held_only_weights, LOGITS),
+    "sigmoid_for_softmax": (dict(moe_score="sigmoid"), LOGITS),
+    "weights_not_renormalised": (dict(moe_renorm_topk=False), LOGITS),
+    "attention_gate_left_out": (dict(attn_gate=False), LOGITS),
+}
+
+
+@pytest.mark.parametrize("defect", sorted(DEFECTS))
+def test_comparison_catches(monkeypatch, model, defect):
+    cfg, params = model
+    plant, caught_by = DEFECTS[defect]
+    program_cfg = cfg
+    if isinstance(plant, dict):
+        program_cfg = dataclasses.replace(cfg, **plant)
+    else:
+        plant(monkeypatch.setattr)
+    checks, _ = _compare(program_cfg, params, _batch(), sizes=_sizes(cfg))
+    failed = {name for name, (ok, _) in checks.items() if not ok}
+    assert failed & set(caught_by), (defect, checks)
+
+
+def test_a_scale_of_the_readout_shows_in_its_mean_square(monkeypatch, model):
+    """Why ``gdn_readout_ms`` is a term: q's 1 / sqrt(channels) left
+    out makes every read-out sqrt(Dk) times too large, which the norm a
+    head behind it takes out again but for its eps; the mean square
+    reads Dk times the reference's."""
+    cfg, params = model
+    defects.PLANT["query_scale_left_out"](monkeypatch.setattr)
+    checks, _ = _compare(cfg, params, _batch())
+    ok, value = checks["gdn_readout_ms_vs_reference"]
+    assert not ok
+    assert value == pytest.approx(cfg.gdn_key_dim - 1, rel=0.1)
+
+
+# ---- the shares add up ----------------------------------------------------
+
+
+def test_shares_of_the_expert_parallel_layer_add_up():
+    """Two chips hold experts 0-3 and 4-7 of one routed block. Their
+    routed parts, and the GATED shared expert ONCE, add up to what the
+    uncut reference gives for the whole block: nothing is lost or
+    counted twice at the seams, and a token's weights are over all it
+    chose."""
+    shares, held = 2, 4
+    whole = _cfg(n_experts_held=0)
+    full = moe.init_moe_params(jax.random.key(3), whole, lead=())
+    assert full["shared"]["w_own_gate"].shape == (whole.d_model, 1)
+    g = jax.random.normal(jax.random.key(4), (2, 32, whole.d_model))
+    sizes = dict(
+        _sizes(whole), n_experts_held=shares * held, expert_offset=0
+    )
+    with jax.default_matmul_precision("highest"):
+        want, _ = plain._routed(g.reshape(64, -1), full, sizes, None)
+        total = moe._shared_expert(g, full["shared"], None)
+        rows = 0.0
+        for rank in range(shares):
+            cfg = dataclasses.replace(
+                whole, n_experts_held=held, expert_offset=rank * held
+            )
+            here = slice(rank * held, (rank + 1) * held)
+            part = dict(full, **{
+                k: full[k][here] for k in ("w_up", "w_down", "w_gate_proj")
+            })
+            out, aux = moe._moe_block_ragged(g, part, cfg)
+            total = total + out
+            rows += float(aux["moe_held_rows"])
+    np.testing.assert_allclose(
+        np.asarray(total).reshape(64, -1), np.asarray(want),
+        rtol=2e-5, atol=2e-5,
+    )
+    # every (token, choice) row went to exactly one share
+    assert rows == 2 * 32 * whole.expert_top_k
+
+
+# ---- the gradient -----------------------------------------------------------
+
+
+def test_gradient_of_every_kind_of_parameter_is_the_references(model):
+    """d(loss)/d(params) through the mixers' chunked rule and its
+    hand-written inverse derivative, the gated attention, the held
+    experts' cut dispatch and combine and the shared expert's gate,
+    against ``jax.grad`` of the plain reference sent to the same
+    experts."""
+    cfg, params = model
+    batch = _batch()
+    sizes = _sizes(cfg)
+    _, choices = routed.program_logits_and_choices(
+        params, batch["tokens"], cfg
+    )
+
+    def objective(p):
+        return plain.loss_and_logits_routed(p, batch, sizes, 8, choices)[0]
+
+    got = jax.jit(jax.grad(lambda p: decoder.loss_fn(p, batch, cfg)[0]))(
+        params
+    )
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(jax.grad(objective))(params)
+    flat_got = jax.tree_util.tree_leaves_with_path(got)
+    flat_want = jax.tree.leaves(want)
+    assert len(flat_got) == len(flat_want)
+    for (path, a), b in zip(flat_got, flat_want):
+        scale = float(jnp.max(jnp.abs(b))) or 1.0
+        np.testing.assert_allclose(
+            # (float32 on both sides; sixteen parts amplify its rounding)
+            np.asarray(a) / scale, np.asarray(b) / scale, atol=2e-3,
+            err_msg=jax.tree_util.keystr(path),
+        )
+        assert float(jnp.max(jnp.abs(b))) > 0, jax.tree_util.keystr(path)
+
+
+# ---- the parameters and the FLOPs -------------------------------------------
+
+
+def _cell_cfg():
+    return get_config(
+        "qwen3-next", n_layer=4, layer_pattern="GeGeGe*e",
+        n_experts_held=32, vocab_size=18992, max_seq=16384,
+    )
+
+
+def test_num_params_is_the_tables_count():
+    """ISSUE 63's table, part by part: the mixer, the attention, the
+    routed block as held, the embedding and the head's slice."""
+    cfg = _cell_cfg()
+    held, _ = zip(*(cfg._part_counts()[c] for c in "G*e"))
+    d = cfg.d_model
+    assert held[0] - d == 33_718_464
+    assert held[1] - d == 27_263_488
+    assert held[2] - d == 104_859_648
+    # one period; ISSUE 63's two are 1,173,540,992
+    assert cfg.num_params() == 625_667_136
+    two = dataclasses.replace(
+        cfg, n_layer=8, layer_pattern=cfg.layer_pattern * 2
+    )
+    assert two.num_params() == 1_173_540_992
+    params = jax.eval_shape(lambda: decoder.init(jax.random.key(0), cfg))
+    assert sum(
+        int(np.prod(t.shape)) for t in jax.tree.leaves(params)
+    ) == cfg.num_params()
+    tiny = _cfg()
+    counted = jax.eval_shape(lambda: decoder.init(jax.random.key(0), tiny))
+    assert sum(
+        int(np.prod(t.shape)) for t in jax.tree.leaves(counted)
+    ) == tiny.num_params()
+
+
+@pytest.mark.parametrize("size", ["tiny", "cell"])
+def test_flops_per_token_is_the_references_required_terms(size):
+    cfg, seq = (_cfg(), SEQ) if size == "tiny" else (_cell_cfg(), 16384)
+    sizes = _sizes(cfg)
+    terms = plain.required_terms(sizes, seq)
+    assert cfg.flops_per_token(seq) == pytest.approx(
+        flopslib.flops_of(terms), rel=1e-12
+    )
+    if size == "cell":
+        rule = 3 * plain.gdn_multiply_adds(sizes)
+        assert rule == 3 * 1_835_008
+        assert terms["multiplied_params"] - rule == 191_864_832
+        assert flopslib.flops_of(terms) == 1_586_896_896
+
+
+# ---- the published sizes, and the paths that refuse the model --------------
+
+
+def test_the_published_pattern_traces_whole():
+    """48 layers, 512 experts, the full vocabulary: shapes only."""
+    cfg = get_config("qwen3-next", remat="full")
+    params = jax.eval_shape(lambda: decoder.init(jax.random.key(0), cfg))
+    layers = params["layers"]
+    assert layers["gdn"]["gdn"]["w_qkvz"].shape == (36, 2048, 12288)
+    assert layers["gdn"]["gdn"]["w_ba"].shape == (36, 2048, 64)
+    assert layers["gdn"]["gdn"]["conv_w"].shape == (36, 4, 8192)
+    assert layers["attention"]["attn"]["wq"].shape == (12, 2048, 4096)
+    assert layers["attention"]["attn"]["wg"].shape == (12, 2048, 4096)
+    assert layers["attention"]["attn"]["wk"].shape == (12, 2048, 512)
+    assert layers["experts"]["moe"]["w_up"].shape == (48, 512, 2048, 512)
+    assert layers["experts"]["moe"]["shared"]["w_own_gate"].shape == (
+        48, 2048, 1
+    )
+    batch = {
+        k: jax.ShapeDtypeStruct((1, 128), jnp.int32)
+        for k in ("tokens", "targets")
+    }
+    loss, metrics = jax.eval_shape(
+        lambda p, b: decoder.loss_fn(p, b, cfg), params, batch
+    )
+    assert loss.shape == () and "gdn_readout_ms" in metrics
+    aux = jax.eval_shape(
+        lambda p, t: decoder.forward(p, t, cfg, return_aux=True)[1],
+        params, batch["tokens"],
+    )
+    assert aux["moe_choices"].shape == (48, 1, 128, 10)
+
+
+def test_cache_paths_refuse_the_model_by_name(model):
+    cfg, params = model
+    assert "gated-delta-rule" in cfg.train_only
+    tokens = _batch()["tokens"]
+    with pytest.raises(ValueError, match="gated-delta-rule"):
+        decoder.prefill(params, tokens, cfg, max_len=128)
+    with pytest.raises(ValueError, match="gated-delta-rule"):
+        generate.sample(
+            params, cfg, tokens[:, :4], max_new_tokens=2,
+            rng=jax.random.key(0),
+        )
