@@ -1859,7 +1859,7 @@ def _gdn_block(h, gdn, cfg: ModelConfig, mesh):
         q = _l2_heads(qkv[..., :keys].reshape(b, s, hk, dk), dk ** -0.5)
         k = _l2_heads(qkv[..., keys:2 * keys].reshape(b, s, hk, dk))
         v = qkv[..., 2 * keys:].reshape(b, s, hv, dv)
-        o = gated_delta_rule(q, k, v, g, beta)
+        o = gated_delta_rule(q, k, v, g, beta, **several)
     aux = {"gdn_readout_ms": jax.lax.stop_gradient(jnp.mean(jnp.square(o)))}
     with jax.named_scope("gdn.gate"):
         y = ssd.gated_group_norm(
@@ -2270,7 +2270,16 @@ def run_trunk(
         if "L" in cfg.layer_pattern:
             set_counter("lin.layers", cfg.layer_pattern.count("L"))
         if "G" in cfg.layer_pattern:
-            set_counter("gdn.layers", cfg.layer_pattern.count("G"))
+            from dlrover_tpu.ops import gated_delta
+
+            linear = cfg.layer_pattern.count("G")
+            set_counter("gdn.layers", linear)
+            # those whose rule runs the Pallas kernels: all or none
+            set_counter("gdn.kernel_layers", linear * int(
+                gated_delta.in_kernels(
+                    cfg.gdn_key_dim, cfg.gdn_value_dim, mesh=mesh
+                )
+            ))
         if "S" in cfg.layer_pattern:
             set_counter("attn.sparse_layers", cfg.layer_pattern.count("S"))
             set_counter("attn.select_block", cfg.sparse_block)

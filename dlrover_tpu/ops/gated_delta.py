@@ -48,30 +48,50 @@ two, ``T21 = −T22 A21 T11``, all of it float32 multiply-adds with the
 batch of chunks on the lanes. Its derivative is by hand and needs
 that inverse's transpose: ``dA = −strict_lower(Tᵀ dT Tᵀ)``.
 
-This XLA body (``jnp``, one ``lax.scan`` over the chunks, differentiated
-by JAX) is the op's only body today: what the chip runs, what the CPU
-tests hold to the recurrence, and the oracle of the Pallas kernels to
-come (ROADMAP S13). Whichever body runs, the caller runs it under the
-scope ``gdn.rule``.
+Two bodies, one algorithm, chosen from what the call sees
+(``in_kernels``: no argument, knob or model name). The XLA body
+(``jnp``, one ``lax.scan`` over the chunks, differentiated by JAX) runs
+on the CPU, at widths off the 128 lanes and on a mesh of several
+devices: what the CPU tests hold to the recurrence, the fallback, and
+the oracle of the other. The Pallas kernels
+(``ops/pallas_gated_delta.py``, PR 64, ROADMAP S16(a)) run on a TPU, on
+one device, with key and value channels on the 128-lane grid and chunks
+of 64: XLA makes ``A`` and ``T`` of whole chunks (``_chunk_inverse``,
+parallel over the chunks) and the kernels walk the chunks with the state
+in VMEM, making W, U, the decay block and ``Q Kᵀ`` in each visit, behind
+one ``jax.custom_vjp`` (``_kernel_rule``). Whichever body runs, the
+caller runs it under the scope ``gdn.rule``.
 
-What is kept and what is remade. The sequence goes through in
-STRETCHES of 2,048 tokens (32 chunks of 64), one after another, each
-under its own ``jax.checkpoint``: what a stretch keeps for its backward
-is its operands and the state it starts from (2 MB a stretch), and its
-own forward is remade when its backward comes. Whole, the chunks'
-operands and the scan's residuals — A, T, the decays, ``Q Kᵀ`` (float32
-[chunks, heads, C, C], each 268 MB at 16,384 tokens and 32 heads, the
-64 of C padded to 128 lanes), W, U, V' and the state every chunk starts
-from (S/C × heads × 128 × 128 float32: 537 MB) — are 3.6 GB forward
-and backward of ONE layer (the compiler's count for a described v5e),
-and the cell's step then needs 15.98 GB of the chip's 15.75; a stretch
-at a time they are an eighth of that. Under ``remat: full`` nothing of
-the rule is kept across layers either: the boundary states of six
-layers (3.2 GB) do not fit beside 9.4 GB of train state, and keeping
-``o`` alone would save nothing, since the backward needs the states and
-they come from a forward pass whichever way. So a step runs the rule's
-forward three times (the layer's forward, the layer's remade forward,
-each stretch's remade forward) and its backward once.
+What is kept and what is remade. ON THE XLA BODY the sequence goes
+through in STRETCHES of 2,048 tokens (32 chunks of 64), one after
+another, each under its own ``jax.checkpoint``: what a stretch keeps
+for its backward is its operands and the state it starts from (2 MB a
+stretch), and its own forward is remade when its backward comes. Whole,
+the chunks' operands and the scan's residuals — A, T, the decays,
+``Q Kᵀ`` (float32 [chunks, heads, C, C], each 268 MB at 16,384 tokens
+and 32 heads, the 64 of C padded to 128 lanes), W, U, V' and the state
+every chunk starts from (S/C × heads × 128 × 128 float32: 537 MB) — are
+3.6 GB forward and backward of ONE layer (the compiler's count for a
+described v5e), and the cell's step then needs 15.98 GB of the chip's
+15.75; a stretch at a time they are an eighth of that. Under ``remat:
+full`` nothing of the rule is kept across layers either: the boundary
+states of six layers (3.2 GB) do not fit beside 9.4 GB of train state,
+and keeping ``o`` alone would save nothing, since the backward needs
+the states and they come from a forward pass whichever way. So a step
+on the XLA body runs the rule's forward THREE times (the layer's
+forward, the layer's remade forward, each stretch's remade forward) and
+its backward once. ON THE KERNELS there is no stretch and no
+checkpoint: the residuals are the five operands (q, k, v, g, β), the
+backward rule makes A and T again (XLA, a pass over whole chunks: the
+compiler shares it with the layer's remade forward, the same work on
+the same operands), takes every chunk's starting state from a pass of
+its own (``gdn_states``: 537 MB a layer, alive inside that layer's
+backward alone) and walks back remaking each chunk's operands in the
+visit; T's cotangent comes out of the walk and goes through
+``unit_lower_inverse``'s hand derivative. A step runs the rule's
+forward TWICE (the layer's and ``remat: full``'s remade one) and its
+backward once, with 0.95 GB of temporaries forward and 2.3 GB going
+back (the compiler's count for a described v5e).
 
 A length that is no multiple of the chunk is PADDED at its end with
 tokens of g = 0, β = 0 and k = 0, which leave every state as it was and
@@ -82,6 +102,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from dlrover_tpu.ops import pallas_gated_delta
 
 F32 = jnp.float32
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -140,27 +162,45 @@ def _product(a, b):
     )
 
 
+def _two_by_two(t11, t21, t22):
+    """[[T11, 0], [T21, T22]] of blocks [.., m, m, ..] whose rows are
+    axis 1 and columns axis 2."""
+    return jnp.concatenate(
+        [
+            jnp.concatenate([t11, jnp.zeros_like(t11)], axis=2),
+            jnp.concatenate([t21, t22], axis=2),
+        ],
+        axis=1,
+    )
+
+
 def _inverse_of(a):
     """(I + a)^{-1}, a [N, C, C] strictly lower, float32, C a power of
-    two (or under 16). Worked with the batch N on the LANES throughout,
-    products and all, as multiply-adds: blocks of 16 or 32 rows as the
-    trailing dimensions of an array are padded to the 128 lanes of a
-    tile (a [8192, 4, 16, 4, 16] float32 view of 134 MB of chunks took
-    1 GB of the chip), and these products are a thousandth of the
-    rule's work."""
+    two (or under 16). Blocks of 16 by substitution and their merges
+    worked with the batch N on the LANES, products and all, as
+    multiply-adds: blocks of 16 or 32 rows as the trailing dimensions of
+    an array are padded to the 128 lanes of a tile (a [8192, 4, 16, 4,
+    16] float32 view of 134 MB of chunks took 1 GB of the chip). The
+    LAST merge of a chunk of 64 or more, two halves of 32 rows or more,
+    is two batched matmuls at ``HIGHEST`` with the batch first: as
+    multiply-adds its 2 x 32 steps each read and wrote the halves whole,
+    a column of them a strided copy — 9 of the 13 ms a pass and layer
+    that XLA spent on a chunk's operands at Qwen3-Next's widths (my chip
+    runs, PR 64) — and T is wanted batch first anyway."""
     n, c, _ = a.shape
     size = min(c, _BASE)
-    a = jnp.moveaxis(a, 0, -1)                       # [C, C, N]
+    on_mxu = c >= 4 * _BASE  # the last merge: see above
+    lanes_last = jnp.moveaxis(a, 0, -1)              # [C, C, N]
 
     def blocks(of):
         # [P, of, P, of, N]: block row, row, block column, column
-        return a.reshape(c // of, of, c // of, of, n)
+        return lanes_last.reshape(c // of, of, c // of, of, n)
 
     diag = blocks(size)
     inv = jnp.stack([
         _substitute(diag[i, :, i]) for i in range(c // size)
     ])                                               # [P, m, m, N]
-    while size < c:
+    while size < (c // 2 if on_mxu else c):
         # [[T11, 0], [T21, T22]] of each pair of neighbours,
         # T21 = −T22 A21 T11
         pair = blocks(2 * size)
@@ -169,15 +209,16 @@ def _inverse_of(a):
         ])
         t11, t22 = inv[0::2], inv[1::2]
         t21 = -_product(t22, _product(a21, t11))
-        inv = jnp.concatenate(
-            [
-                jnp.concatenate([t11, jnp.zeros_like(t11)], axis=2),
-                jnp.concatenate([t21, t22], axis=2),
-            ],
-            axis=1,
-        )
+        inv = _two_by_two(t11, t21, t22)
         size *= 2
-    return jnp.moveaxis(inv[0], -1, 0)
+    if not on_mxu:
+        return jnp.moveaxis(inv[0], -1, 0)
+    t11, t22 = jnp.moveaxis(inv[0], -1, 0), jnp.moveaxis(inv[1], -1, 0)
+    t21 = -jnp.matmul(
+        t22, jnp.matmul(a[:, size:, :size], t11, precision=_HIGHEST),
+        precision=_HIGHEST,
+    )
+    return _two_by_two(t11, t21, t22)
 
 
 @jax.custom_vjp
@@ -217,17 +258,15 @@ def _products(dtype):
     )
 
 
-def _chunk_operands(q, k, v, g, beta, chunk):
-    """What a chunk brings to the scan, from whole chunks. q, k
-    [B, N, C, Hk, Dk], v [B, N, C, Hk, R, Dv], g and beta
-    [B, N, C, Hk, R] float32. Returns (w [B, N, C, Hk, R, Dk], u
-    [B, N, C, Hk, R, Dv], attn [B, N, Hk, R, C, C]) in v's dtype and
-    gamma [B, N, C, Hk, R] float32."""
-    dtype = v.dtype
-    dot = _products(dtype)
+def _chunk_inverse(k, g, beta, chunk):
+    """What a chunk's keys and gates alone decide, from whole chunks: k
+    [B, N, C, Hk, Dk], g and beta [B, N, C, Hk, R] float32. Returns (the
+    triangular inverse T [B, N, Hk, R, C, C] float32, gamma
+    [B, N, C, Hk, R], then a token last, gamma and beta [B, N, Hk, R, C],
+    and the decay block on and under the diagonal [B, N, Hk, R, C, C])."""
+    dot = _products(k.dtype)
     gamma = jnp.cumsum(g, axis=2)
     kk = dot("bnikd,bnjkd->bnkij", k, k)
-    qk = dot("bnikd,bnjkd->bnkij", q, k)
     gamma_t = jnp.moveaxis(gamma, 2, -1)             # [B, N, Hk, R, C]
     beta_t = jnp.moveaxis(beta, 2, -1)
     lower = jnp.tril(jnp.ones((chunk, chunk), bool))
@@ -239,7 +278,19 @@ def _chunk_operands(q, k, v, g, beta, chunk):
     a = jnp.where(
         strict, beta_t[..., :, None] * kk[:, :, :, None] * decay, 0.0
     )
-    t = unit_lower_inverse(a)
+    return unit_lower_inverse(a), gamma, gamma_t, beta_t, decay
+
+
+def _chunk_operands(q, k, v, g, beta, chunk):
+    """What a chunk brings to the scan, from whole chunks. q, k
+    [B, N, C, Hk, Dk], v [B, N, C, Hk, R, Dv], g and beta
+    [B, N, C, Hk, R] float32. Returns (w [B, N, C, Hk, R, Dk], u
+    [B, N, C, Hk, R, Dv], attn [B, N, Hk, R, C, C]) in v's dtype and
+    gamma [B, N, C, Hk, R] float32."""
+    dtype = v.dtype
+    dot = _products(dtype)
+    t, gamma, gamma_t, beta_t, decay = _chunk_inverse(k, g, beta, chunk)
+    qk = dot("bnikd,bnjkd->bnkij", q, k)
     # a value head's own scales go on T's COLUMNS: K stays a key head's
     w = dot(
         "bnkrij,bnjkd->bnikrd",
@@ -320,16 +371,91 @@ def _chunked(q, k, v, g, beta, chunk, stretch):
     return jnp.moveaxis(o, 0, 1).reshape(b, s, hk, r, dv)
 
 
+def _kernel_operands(k, g, beta):
+    """What XLA makes of whole chunks for the kernels
+    (``ops/pallas_gated_delta.py``), parallel over the chunks: k
+    [B, S, Hk, Dk], g and beta [B, S, Hk, R] float32. Returns (T
+    [B, N, Hk, R, C, C], gamma a column a token [B, N, Hk, C, R], gamma
+    then beta a row a token [B, N, Hk, 2 R, C]) float32."""
+    chunk = pallas_gated_delta.CHUNK
+    b, s = k.shape[:2]
+
+    def cut(t):
+        return t.reshape((b, s // chunk, chunk) + t.shape[2:])
+
+    t, gamma, gamma_t, beta_t, _ = _chunk_inverse(
+        cut(k), cut(g), cut(beta), chunk
+    )
+    return (
+        t, jnp.moveaxis(gamma, 2, 3),
+        jnp.concatenate([gamma_t, beta_t], axis=3),
+    )
+
+
+def _flat(q, k, v):
+    """q, k [B, S, Hk * Dk] and v [B, S, Hv * Dv], as the kernels take
+    them: a head a run of columns."""
+    b, s = k.shape[:2]
+    return q.reshape(b, s, -1), k.reshape(b, s, -1), v.reshape(b, s, -1)
+
+
+@jax.custom_vjp
+def _kernel_rule(q, k, v, g, beta):
+    """The rule over whole chunks of 64 by the Pallas kernels: q, k
+    [B, S, Hk, Dk] and v [B, S, Hk, R, Dv] of one dtype, g and beta
+    [B, S, Hk, R] float32. Returns o [B, S, Hk, R, Dv]. What it keeps
+    for its backward is its five operands."""
+    return pallas_gated_delta.forward(
+        *_flat(q, k, v), *_kernel_operands(k, g, beta), k.shape[-1],
+        v.shape[-1],
+    ).reshape(v.shape)
+
+
+def _kernel_rule_fwd(q, k, v, g, beta):
+    return _kernel_rule(q, k, v, g, beta), (q, k, v, g, beta)
+
+
+def _kernel_rule_bwd(operands, do):
+    q, k, v, g, beta = operands
+    made, pull = jax.vjp(_kernel_operands, k, g, beta)
+    dq, dk_walk, dv, dt, dcol, drow = pallas_gated_delta.backward(
+        *_flat(q, k, v), *made, do.reshape(do.shape[:2] + (-1,)),
+        k.shape[-1], v.shape[-1],
+    )
+    # T's, gamma's and beta's cotangents through what XLA made of them
+    dk_made, dg, dbeta = pull((dt, dcol, drow))
+    return (
+        dq.reshape(q.shape), dk_walk.reshape(k.shape) + dk_made,
+        dv.reshape(v.shape), dg, dbeta,
+    )
+
+
+_kernel_rule.defvjp(_kernel_rule_fwd, _kernel_rule_bwd)
+
+
+def in_kernels(dk: int, dv: int, chunk: int = 64, mesh=None) -> bool:
+    """Whether ``gated_delta_rule`` runs the Pallas kernels at these
+    widths (``pallas_gated_delta.tile``): what the counter
+    ``gdn.kernel_layers`` counts by."""
+    return pallas_gated_delta.tile(dk, dv, chunk, mesh)
+
+
 def gated_delta_rule(q, k, v, g, beta, chunk: int = 64,
-                     stretch: int = 2048):
+                     stretch: int = 2048, mesh=None):
     """The rule over a sequence. q, k [B, S, Hk, Dk] (the caller's to
     have normed and scaled), v [B, S, Hv, Dv] with Hv a multiple of Hk
     (key head j serves value heads R j .. R j + R − 1), g [B, S, Hv]
     float32, the log-decay (<= 0), beta [B, S, Hv] float32 in (0, 1).
     Returns o [B, S, Hv, Dv] in v's dtype. ``chunk`` is a power of two
     (or under 16) and ``stretch`` a multiple of it; neither is a size of
-    the model."""
-    b, s, hk, _ = k.shape
+    the model. One function, two bodies, chosen from what it sees
+    (``in_kernels``; ``mesh`` is the mesh the operands live on, if any):
+    on a TPU (or interpreted), on one device, with key and value
+    channels on the 128-lane grid and chunks of 64, the Pallas kernels
+    ``gdn_fwd`` / ``gdn_states`` / ``gdn_bwd``, which take no stretch;
+    anywhere else — the CPU, other widths, a mesh of several devices —
+    the XLA body, stretches and all."""
+    b, s, hk, dk = k.shape
     hv, dv = v.shape[2:]
     if hv % hk:
         raise ValueError(f"{hv} value heads are not shared by {hk} key heads")
@@ -343,8 +469,10 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = 64,
     dtype = v.dtype  # the products' operands: see ``_products``
     q, k = q.astype(dtype), k.astype(dtype)
     g, beta = g.astype(F32), beta.astype(F32)
-    # whole chunks, and whole stretches where there are several
-    stretch = min(stretch, s + -s % chunk)
+    kernels = in_kernels(dk, dv, chunk, mesh)
+    # whole chunks, and on the XLA body whole stretches where there are
+    # several
+    stretch = chunk if kernels else min(stretch, s + -s % chunk)
     pad = -s % stretch
     if pad:
         # g = 0, β = 0, k = 0: see the module's docstring
@@ -352,9 +480,13 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = 64,
             jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
             for t in (q, k, v, g, beta)
         )
-    o = _chunked(
+    operands = (
         q, k, v.reshape(b, s + pad, hk, r, dv),
-        g.reshape(b, s + pad, hk, r), beta.reshape(b, s + pad, hk, r), chunk,
-        stretch,
-    ).reshape(b, s + pad, hv, dv)
+        g.reshape(b, s + pad, hk, r), beta.reshape(b, s + pad, hk, r),
+    )
+    if kernels:
+        o = _kernel_rule(*operands)
+    else:
+        o = _chunked(*operands, chunk, stretch)
+    o = o.reshape(b, s + pad, hv, dv)
     return o[:, :s] if pad else o

@@ -2,7 +2,11 @@
 definition, the per-token recurrence: values and gradients in all of q,
 k, v, g and β, at several chunk sizes, at a length that is no multiple
 of the chunk, with fast-forgetting heads; the triangular inverse and
-its hand-written derivative against ``jnp.linalg``."""
+its hand-written derivative against ``jnp.linalg``. And the op's second
+body, the Pallas kernels (``ops/pallas_gated_delta.py``), interpreted:
+held to the XLA body AND to the recurrence, values and the five
+gradients, on float32 and on bf16 operands; the counter that says which
+body a model's linear layers took."""
 
 import jax
 import jax.numpy as jnp
@@ -10,6 +14,7 @@ import numpy as np
 import pytest
 
 from dlrover_tpu.ops import gated_delta as gd
+from dlrover_tpu.ops import pallas_attention
 
 # jitted: eager, the inverse's row-by-row substitution is a dispatch an
 # operation
@@ -155,3 +160,150 @@ def test_shapes_that_fit_nothing_are_refused_by_name():
         gd.gated_delta_rule(q, k, v, g, beta, chunk=16, stretch=40)
     with pytest.raises(ValueError, match="not shared by"):
         rule(q, k, v[:, :, :3], g[..., :3], beta[..., :3])
+
+
+# --- the Pallas kernels, interpreted -----------------------------------
+
+F32, BF16 = jnp.float32, jnp.bfloat16
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    """The kernels where a TPU would run them, by the Pallas interpreter
+    (a trace made before the switch is no trace of the kernels: every
+    test under it jits its own functions)."""
+    monkeypatch.setattr(pallas_attention, "INTERPRET", True)
+
+
+def _identical_keys(s):
+    """``test_identical_keys_stay_finite_and_exact``'s chunk at the
+    kernels' widths: one key, β near 1, no decay."""
+    k = jnp.broadcast_to(jnp.eye(128)[0], (1, s, 1, 128))
+    v = jax.random.normal(jax.random.key(5), (1, s, 1, 128))
+    return k, k, v, jnp.zeros((1, s, 1)), jnp.full((1, s, 1), 0.999)
+
+
+def _wide(s, **kw):
+    return _inputs(s, b=1, dk=128, dv=128, **kw)
+
+
+# R = 2 value heads a key head, whose fourth head's γ passes −100 inside
+# a chunk of 64; three chunks, so that the state and its cotangent cross
+# visits twice; a length that is no multiple of the chunk
+KERNEL_CASES = {
+    "three-chunks": lambda: _wide(192),
+    "padded": lambda: _wide(150, hk=1),
+    "one-chunk-two-batches": lambda: _inputs(64, hk=1, dk=128, dv=128),
+    "identical-keys": lambda: _identical_keys(128),
+}
+
+
+def _value_and_grads(fn, args, w):
+    o = jax.jit(fn)(*args)
+    grads = jax.jit(jax.grad(
+        lambda *a: (fn(*a).astype(F32) * w).sum(), range(5)
+    ))(*args)
+    return o.astype(F32), [g.astype(F32) for g in grads]
+
+
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_kernels_are_the_xla_body_and_the_recurrence(
+    interpreted, case, dtype
+):
+    """Values and all five gradients. Float32 operands at the tolerance
+    the XLA body is held to the recurrence (on the CPU that body's
+    products are float32 whole; the kernels' are three passes of bf16
+    pieces, as on the chip); bf16 operands at bf16's."""
+    q, k, v, g, beta = KERNEL_CASES[case]()
+    args = (q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta)
+    w = jax.random.normal(jax.random.key(9), v.shape)
+    assert gd.in_kernels(128, 128)
+    got, got_grads = _value_and_grads(
+        lambda *a: gd.gated_delta_rule(*a), args, w
+    )
+    # the XLA body through its own entry, as the fallback runs it
+    xla, xla_grads = _value_and_grads(
+        lambda *a: gd.gated_delta_rule(
+            *a, mesh=jax.make_mesh((2,), ("dp",))
+        ), args, w,
+    )
+    want, want_grads = _value_and_grads(gd.recurrence, args, w)
+    tol = 2e-5 if dtype == F32 else 2e-2
+    if case == "identical-keys":
+        # a chunk of ones under the diagonal amplifies a product's
+        # rounding (the XLA body is held at 1e-4 there), and g's
+        # cotangent is what is left of terms a thousand times its size:
+        # it is held on β's scale
+        tol = max(tol, 5e-4)
+    assert got.shape == v.shape and np.isfinite(np.asarray(got)).all()
+    if case == "identical-keys" and dtype == BF16:
+        # rounded to bf16 a dozen times, that chunk is no number to hold
+        # anything to (the XLA body stands 0.1 from the recurrence)
+        assert all(np.isfinite(np.asarray(g)).all() for g in got_grads)
+        return
+    scale = float(jnp.max(jnp.abs(want)))
+    for other in (xla, want):
+        np.testing.assert_allclose(
+            np.asarray(got) / scale, np.asarray(other) / scale, atol=tol
+        )
+    for name, a, b, c in zip("qkvgβ", got_grads, xla_grads, want_grads):
+        scale = float(jnp.max(jnp.abs(c)))
+        assert scale > 0, name
+        if case == "identical-keys" and name == "g":
+            scale = float(jnp.max(jnp.abs(want_grads[4])))
+        for other in (b, c):
+            np.testing.assert_allclose(
+                np.asarray(a) / scale, np.asarray(other) / scale, atol=tol,
+                err_msg=name,
+            )
+
+
+@pytest.mark.parametrize(
+    "why", ["other-widths", "several-devices", "another-chunk"]
+)
+def test_fallback_is_the_xla_body_bit_for_bit(interpreted, why):
+    """Where the kernels do not fit — channels off the 128-lane grid, a
+    mesh of several devices, a chunk that is not theirs — the rule is
+    the XLA body, stretches and all: no kernel in the program, and the
+    result of ``_chunked`` to the bit."""
+    kw = {}
+    if why == "other-widths":
+        args = _inputs(128)
+    else:
+        args = _wide(128, hk=1)
+        kw = (
+            {"mesh": jax.make_mesh((2,), ("dp",))}
+            if why == "several-devices" else {"chunk": 32}
+        )
+    dk, dv = args[0].shape[-1], args[2].shape[-1]
+    assert not gd.in_kernels(dk, dv, **kw)
+    fn = lambda *a: gd.gated_delta_rule(*a, stretch=64, **kw)  # noqa: E731
+    assert "pallas_call" not in str(jax.make_jaxpr(fn)(*args))
+    q, k, v, g, beta = args
+    b, s, hk = k.shape[:3]
+    body = jax.jit(lambda: gd._chunked(
+        q, k, v.reshape(b, s, hk, -1, dv), g.reshape(b, s, hk, -1),
+        beta.reshape(b, s, hk, -1), kw.get("chunk", 64), 64,
+    ).reshape(v.shape))()
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(fn)(*args)), np.asarray(body)
+    )
+
+
+def test_kernel_path_has_no_scan_over_chunks(interpreted):
+    """The 256-step dependence is the kernels' grid: the traced program
+    of the kernel path holds three kernels by name and no ``scan`` or
+    ``while`` of its own; the XLA body's holds both of its scans."""
+    args = _wide(256, hk=1)
+    loss = lambda *a: gd.gated_delta_rule(*a, stretch=128).sum()  # noqa: E731
+    text = str(jax.make_jaxpr(jax.grad(loss, range(5)))(*args))
+    for name in ("gdn_fwd", "gdn_states", "gdn_bwd"):
+        assert f"name={name}" in text, name
+    assert "scan[" not in text and "while[" not in text
+    mesh = jax.make_mesh((2,), ("dp",))
+    xla = str(jax.make_jaxpr(jax.grad(
+        lambda *a: gd.gated_delta_rule(*a, stretch=128, mesh=mesh).sum(),
+        range(5),
+    ))(*args))
+    assert "scan[" in xla and "pallas_call" not in xla

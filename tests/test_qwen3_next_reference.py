@@ -464,3 +464,36 @@ def test_cache_paths_refuse_the_model_by_name(model):
             params, cfg, tokens[:, :4], max_new_tokens=2,
             rng=jax.random.key(0),
         )
+
+
+@pytest.mark.parametrize(
+    "widths,interpret,kernel_layers",
+    [(128, True, 3), (128, False, 0), (8, True, 0)],
+    ids=["kernels", "off-the-chip", "other-widths"],
+)
+def test_counters_say_which_body_the_linear_layers_took(
+    monkeypatch, widths, interpret, kernel_layers
+):
+    """``gdn.layers`` counts the pattern's linear layers and
+    ``gdn.kernel_layers`` those whose rule runs the Pallas kernels: at
+    the cell's pattern all 3 with key and value channels of 128 where a
+    TPU (here the interpreter) would run them, none off the chip or at
+    widths off the 128 lanes — where the trace holds no kernel of the
+    rule's."""
+    from dlrover_tpu.observability import tracing
+    from dlrover_tpu.ops import pallas_attention
+
+    monkeypatch.setattr(pallas_attention, "INTERPRET", interpret)
+    cfg = _cfg(
+        n_layer=4, layer_pattern="GeGeGe*e", gdn_key_heads=1,
+        gdn_value_heads=2, gdn_key_dim=widths, gdn_value_dim=widths,
+    )
+    params = jax.eval_shape(lambda: decoder.init(jax.random.key(0), cfg))
+    tracing._counters.clear()
+    text = str(jax.make_jaxpr(
+        lambda p, t: decoder.forward(p, t, cfg)
+    )(params, _batch()["tokens"]))
+    counters = tracing.counters()
+    assert counters["gdn.layers"] == 3
+    assert counters["gdn.kernel_layers"] == kernel_layers
+    assert ("name=gdn_fwd" in text) == bool(kernel_layers)

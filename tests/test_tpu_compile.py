@@ -26,7 +26,7 @@ from jax.sharding import SingleDeviceSharding
 from dlrover_tpu.common import device
 from dlrover_tpu.models.config import get_config
 from dlrover_tpu.ops import (
-    pallas_align, pallas_attention, pallas_conv, pallas_norm, pallas_paged,
+    gated_delta, pallas_align, pallas_attention, pallas_conv, pallas_norm, pallas_paged,
     pallas_rows, pallas_selective_scan, pallas_ssd, selective_scan, ssd,
 )
 from dlrover_tpu.parallel import moe
@@ -244,6 +244,31 @@ def _sscan(grad):
     return build
 
 
+def _gdn(grad):
+    """A gated-delta-rule layer at Qwen3-Next's widths (16 key heads
+    shared by 32 value heads of 128, one sequence of 16,384, float32 as
+    the mixer hands them over), under the caller's scope: the kernels
+    take their names behind it."""
+    def build(S):
+        s = 16384
+        args = (
+            S((1, s, 16, 128), F32), S((1, s, 16, 128), F32),
+            S((1, s, 32, 128), F32), S((1, s, 32), F32), S((1, s, 32), F32),
+        )
+        assert gated_delta.in_kernels(128, 128)
+
+        def rule(*a):
+            with jax.named_scope("gdn.rule"):
+                return gated_delta.gated_delta_rule(*a)
+
+        if not grad:
+            return rule, args
+        loss = lambda *a: rule(*a).sum()  # noqa: E731
+        return jax.grad(loss, argnums=(0, 1, 2, 3, 4)), args
+
+    return build
+
+
 def _conv(channels, dtype, grad):
     """A mixer's causal conv of 4 taps over one sequence of 8,192 as the
     two cells run it: Nemotron-3-Super's 10,240 channels in bf16 with
@@ -427,6 +452,10 @@ CASES = {
     # still needs the forward kernel, for the chunks' starting states
     "sscan-fwd-5120x16": (_sscan(grad=False), 1),
     "sscan-bwd-5120x16": (_sscan(grad=True), 2),
+    # a gated-delta-rule layer's walk over the chunks (Qwen3-Next): the
+    # gradient alone needs the state pass and the walk back
+    "gdn-fwd-16x2x128": (_gdn(grad=False), 1),
+    "gdn-bwd-16x2x128": (_gdn(grad=True), 2),
     # both mixers' causal conv (``ops/pallas_conv.py``)
     "conv-fwd-10240-bf16": (_conv(10240, BF16, grad=False), 1),
     "conv-bwd-10240-bf16": (_conv(10240, BF16, grad=True), 2),
@@ -480,6 +509,16 @@ def test_kernel_compiles_for_v5e(chip, case):
     if case.startswith("sscan-"):
         names = ("sscan_fwd", "sscan_bwd") if "bwd" in case else ("sscan_fwd",)
         assert all(f"%{name}" in text for name in names)
+    if case.startswith("gdn-"):
+        names = ("gdn_states", "gdn_bwd") if "bwd" in case else ("gdn_fwd",)
+        assert all(f"%{name}" in text for name in names)
+        # the 256 chunks' dependence is the kernels' grid: no loop of
+        # the compiler's around a chunk step, and what XLA makes of
+        # whole chunks beside them (T; going back the states, 537 MB,
+        # and T's cotangent) fits beside the cell's 9.4 GB of state
+        assert "while(" not in text and " while " not in text
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < (2.6e9 if "bwd" in case else 1.2e9)
     if case.startswith("rows-sum-"):
         assert "%rows_sum" in text
     if case.startswith("conv-"):
@@ -493,20 +532,18 @@ def test_kernel_compiles_for_v5e(chip, case):
             assert "f32[1,8192,10240]" not in text.split("ENTRY")[1]
 
 
-def test_gated_delta_rule_compiles_a_stretch_at_a_time(chip):
+def test_gated_delta_rule_compiles_a_stretch_at_a_time(topo, chip):
     """The gated delta rule's XLA body at Qwen3-Next's widths (16 key
     heads shared by 32 value heads of 128, one sequence of 16,384,
     chunks of 64, float32 operands as the mixer hands them over),
     forward and backward, for a described v5e: matmuls and no kernel
-    yet, and with each stretch of 2,048 tokens under its own checkpoint
+    (the op's fallback since PR 64), and with each stretch of 2,048 tokens under its own checkpoint
     the compiler counts 1.26 GB of temporaries (on bf16 operands 0.94
     GB where the sequence whole took 3.6, and two periods' step then
     needed 15.98 GiB of 15.75). No array has blocks of 16 rows as its
     trailing dimensions, which a tile pads to 128 lanes: the inverse
     works with the batch on the lanes."""
     import re
-
-    from dlrover_tpu.ops import gated_delta
 
     def struct(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
@@ -517,8 +554,12 @@ def test_gated_delta_rule_compiles_a_stretch_at_a_time(chip):
         struct((1, s, 32, 128), F32), struct((1, s, 32), F32),
         struct((1, s, 32), F32),
     )
+    # the fallback's own entry: a mesh of several devices rules the
+    # kernels out
+    several = jax.sharding.Mesh(topo.devices[:2], ("dp",))
+    assert not gated_delta.in_kernels(128, 128, mesh=several)
     loss = lambda *a: gated_delta.gated_delta_rule(  # noqa: E731
-        *a, chunk=64
+        *a, chunk=64, mesh=several
     ).astype(F32).sum()
     compiled = jax.jit(jax.grad(loss, argnums=range(5))).lower(*args).compile()
     text = compiled.as_text()
