@@ -140,11 +140,15 @@ class ModelConfig:
     # (``first_k_dense_replace``); the other n_layer - n_dense_layer are
     # routed. params holds each kind stacked by itself
     n_dense_layer: int = 0
-    # latent attention (MLA; 0 ranks = plain q/k/v projections): q
-    # through rank q_lora_rank to n_head × (qk_nope + qk_rope) channels,
-    # k and v through rank kv_lora_rank (+ qk_rope rope channels all
-    # heads share) to n_head × (qk_nope ‖ v_head_dim). Training runs the
-    # expanded form: MHA at head size qk_nope + qk_rope = v_head_dim
+    # latent attention (MLA; kv_lora_rank 0 = plain q/k/v projections):
+    # q through rank q_lora_rank (0 = ONE matrix at full rank, a
+    # published ``q_lora_rank: null``) to n_head × (qk_nope + qk_rope)
+    # channels, k and v through rank kv_lora_rank (+ qk_rope channels all
+    # heads share: rope's where ``pos`` is rope, and as they are where a
+    # layer_pattern model has ``pos: none``, a published ``mla_use_nope``)
+    # to n_head × (qk_nope ‖ v_head_dim). Training runs the expanded
+    # form: MHA with scores over qk_nope + qk_rope channels and values
+    # of v_head_dim, which may be narrower (192 against 128)
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -180,14 +184,18 @@ class ModelConfig:
     # attention (``sparse_block``; no rope), ``L`` a lightning linear
     # attention (``n_head`` heads of their own k and v, rope, a fixed
     # decay a head, a norm over the whole read-out and a gate on it),
-    # ``G`` a gated-delta-rule mixer (``gdn_*``), ``E`` the routed
-    # experts, ``-`` a dense MLP of ``d_ff``. A ``-`` that follows
+    # ``G`` a gated-delta-rule mixer (``gdn_*``), ``K`` a delta-rule
+    # mixer whose decay is a vector over the key channels (KDA;
+    # ``kda_*``), ``E`` the routed experts, ``-`` a dense MLP of
+    # ``d_ff``. The ``*`` of a model with ``kv_lora_rank`` is latent
+    # attention. A ``-`` that follows
     # another part is the second part of that part's LAYER (a mixer +
     # MLP layer, pre-norm twice: ``m-``, ``*-``), and so is an ``e``,
     # the ROUTED experts as a layer's second part (a mixer + routed
     # layer: ``Ge``, ``*e``; one pattern routes by ``E`` or by ``e``,
-    # not both); every other letter is a layer by itself, and
-    # ``n_layer`` counts layers. Parameters are
+    # not both; a leading dense-MLP layer beside routed ones is spelled
+    # ``K-Ke``, not ``n_dense_layer``); every other letter is a layer by
+    # itself, and ``n_layer`` counts layers. Parameters are
     # stacked kind by kind and visited in this order. ``mtp_pattern``
     # is the prediction module's layers, likewise. Training path only
     layer_pattern: str = ""
@@ -237,6 +245,20 @@ class ModelConfig:
     gdn_value_heads: int = 0
     gdn_key_dim: int = 0
     gdn_value_dim: int = 0
+    # the delta-rule mixer with a decay a key channel (``K``; Kimi Delta
+    # Attention, arXiv:2510.26692; ops/gated_delta.py with g a vector):
+    # ``kda_heads`` heads of ``kda_head_dim`` key and as many value
+    # channels, q, k and v each their own, no sharing; a causal depthwise
+    # conv of ``conv_kernel`` taps over [q | k | v], no bias; the decay
+    # g = -exp(A_log_h) softplus((x W_fa) W_fb + dt_bias), one a head
+    # and KEY CHANNEL, through a low rank of ``kda_gate_rank``; one write
+    # strength a head; an RMSNorm over each head's read-out with ONE
+    # scale of ``kda_head_dim``, THEN a SIGMOID gate through the same
+    # low rank, (x W_ga) W_gb. A and the time step's bias are drawn as
+    # the ``G`` part's
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_gate_rank: int = 0
     # the share of a head's channels that rope turns, the FIRST ones
     # (rotate-half inside them; the rest pass untouched): a published
     # ``partial_rotary_factor``
@@ -381,31 +403,35 @@ class ModelConfig:
             )
         if self.latent_attention:
             ranks = (
-                self.q_lora_rank, self.kv_lora_rank,
-                self.qk_nope_head_dim, self.qk_rope_head_dim,
+                self.kv_lora_rank, self.qk_nope_head_dim,
+                self.qk_rope_head_dim,
             )
-            if not all(r > 0 for r in ranks) or self.qk_rope_head_dim % 2:
-                raise ValueError(
-                    "latent attention needs q_lora_rank, kv_lora_rank, "
-                    "qk_nope_head_dim and an even qk_rope_head_dim"
-                )
-            if self.v_head_dim != (
-                self.qk_nope_head_dim + self.qk_rope_head_dim
+            if (
+                not all(r > 0 for r in ranks) or self.qk_rope_head_dim % 2
+                or self.q_lora_rank < 0
             ):
                 raise ValueError(
-                    "latent attention runs expanded, as MHA at one head "
-                    "size: v_head_dim must equal qk_nope_head_dim + "
-                    f"qk_rope_head_dim, got {self.v_head_dim} against "
-                    f"{self.qk_nope_head_dim} + {self.qk_rope_head_dim}"
+                    "latent attention needs kv_lora_rank, qk_nope_head_dim, "
+                    "an even qk_rope_head_dim and q_lora_rank >= 0 (0: q "
+                    "at full rank)"
+                )
+            if not 0 < self.v_head_dim <= self.head_dim:
+                raise ValueError(
+                    "latent attention runs expanded, as MHA whose values "
+                    "are no wider than its scores: v_head_dim must lie in "
+                    f"(0, qk_nope_head_dim + qk_rope_head_dim], got "
+                    f"{self.v_head_dim} against {self.qk_nope_head_dim} + "
+                    f"{self.qk_rope_head_dim}"
                 )
             if (
-                self.pos != "rope"
+                self.pos == "learned"
                 or self.qk_norm
                 or self.kv_heads != self.n_head
             ):
                 raise ValueError(
-                    "latent attention is rope on its own channels, MHA, "
-                    "no qk_norm"
+                    "latent attention is rope on its own channels (or, in "
+                    "a layer_pattern model with pos 'none', no rotation "
+                    "at all), MHA, no qk_norm"
                 )
         if self.partial_rotary_factor != 1.0 and (
             not 0.0 < self.partial_rotary_factor < 1.0
@@ -522,14 +548,16 @@ class ModelConfig:
     def _check_pattern(self):
         """A ``layer_pattern`` model: what its letters need."""
         for name in ("layer_pattern", "mtp_pattern"):
-            odd = set(getattr(self, name)) - set("Mm*SLGEe-")
+            odd = set(getattr(self, name)) - set("Mm*SLGKEe-")
             if odd:
                 raise ValueError(
                     f"{name} is made of M (Mamba-2), m (Mamba-1), * "
-                    f"(attention), S (block-sparse attention), L "
-                    f"(lightning attention), G (gated delta rule), E "
-                    f"(routed experts), e (routed experts, a layer's "
-                    f"second part) and - (dense MLP); got {sorted(odd)}"
+                    f"(attention; latent attention where kv_lora_rank is "
+                    f"set), S (block-sparse attention), L (lightning "
+                    f"attention), G (gated delta rule), K (delta rule "
+                    f"with a decay a key channel, KDA), E (routed "
+                    f"experts), e (routed experts, a layer's second "
+                    f"part) and - (dense MLP); got {sorted(odd)}"
                 )
         if pattern_layers(self.layer_pattern) != self.n_layer:
             raise ValueError(
@@ -566,10 +594,10 @@ class ModelConfig:
                 "a Mamba-1 part needs mamba_expand, mamba_dt_rank, "
                 "ssm_state_size and conv_kernel"
             )
-        if set("SLG") & set(self.mtp_pattern):
+        if set("SLGK") & set(self.mtp_pattern):
             raise ValueError(
-                "S, L and G parts are the trunk's: a prediction module's "
-                "selection or read-out is handed over by no one"
+                "S, L, G and K parts are the trunk's: a prediction "
+                "module's selection or read-out is handed over by no one"
             )
         if "G" in letters:
             sizes = (
@@ -584,6 +612,16 @@ class ModelConfig:
                     "multiple of them), gdn_key_dim, gdn_value_dim, "
                     "and conv_kernel"
                 )
+        if "K" in letters and not all(
+            n > 0 for n in (
+                self.kda_heads, self.kda_head_dim, self.kda_gate_rank,
+                self.conv_kernel,
+            )
+        ):
+            raise ValueError(
+                "a K part needs kda_heads, kda_head_dim, kda_gate_rank "
+                "and conv_kernel"
+            )
         if "S" in letters:
             sizes = (
                 self.sparse_block, self.pool_window, self.pool_stride,
@@ -635,17 +673,26 @@ class ModelConfig:
                 "a pattern routes by E (a layer by itself) or by e (a "
                 "layer's second part): the two share one stack"
             )
+        if self.latent_attention and (
+            "S" in letters or self.attn_gate or self.mtp_pattern
+        ):
+            raise ValueError(
+                "latent attention as a part is the * of a layer_pattern "
+                "model's trunk: no S part, output gate or prediction module"
+            )
         if (
-            self.n_dense_layer or self.latent_attention or self.selects_keys
+            self.n_dense_layer or self.selects_keys
             or self.parallel_residual or self.prefix_lm or self.fp8
             or self.norm != "rmsnorm" or self.pos == "learned"
             or self.qk_norm
         ):
             raise ValueError(
                 "a layer_pattern model is RMSNorm parts, x + part(norm(x)) "
-                "each: no dense prefix, latent attention, learned key "
-                "selection, parallel residual, prefix-LM, fp8, position "
-                "table or whole-projection q/k norm"
+                "each: no dense prefix (a leading mixer + dense-MLP layer "
+                "is spelled K-, *-, m-), learned key selection, parallel "
+                "residual, prefix-LM, fp8, position table or "
+                "whole-projection q/k norm; latent attention may be a "
+                "part (*)"
             )
 
     @property
@@ -745,6 +792,41 @@ class ModelConfig:
             self.gdn_conv_dim + inner + 2 * self.gdn_value_heads + inner
         )
 
+    @property
+    def kda_params(self) -> int:
+        """One ``K`` part's matrices: [q | k | v], the low-rank pairs of
+        the decay and of the output gate, the write strength's and the
+        output's."""
+        inner = self.kda_heads * self.kda_head_dim
+        return (
+            self.d_model * (3 * inner + 2 * self.kda_gate_rank
+                            + self.kda_heads)
+            + 2 * self.kda_gate_rank * inner + inner * self.d_model
+        )
+
+    @property
+    def value_dim(self) -> int:
+        """Channels of a head's values: ``v_head_dim`` under latent
+        attention, else the head's."""
+        return self.v_head_dim if self.latent_attention else self.head_dim
+
+    @property
+    def latent_params(self) -> int:
+        """One latent attention's matrices: q (through its rank, or one
+        matrix where ``q_lora_rank`` is 0), the joint down-projection
+        [c ‖ k_r], the up-projection [k_nope ‖ v] and o."""
+        d, d_q = self.d_model, self.n_head * self.head_dim
+        q = (
+            d * self.q_lora_rank + self.q_lora_rank * d_q
+            if self.q_lora_rank else d * d_q
+        )
+        return (
+            q + d * (self.kv_lora_rank + self.qk_rope_head_dim)
+            + self.kv_lora_rank * self.n_head
+            * (self.qk_nope_head_dim + self.v_head_dim)
+            + self.n_head * self.v_head_dim * d
+        )
+
     def kind_window(self, kind: str = "") -> int:
         """Keys a query of a layer of ``kind`` may see (0 = every
         earlier one; None reads as 0): ``attn_window`` as it is, but
@@ -816,6 +898,11 @@ class ModelConfig:
                 "gated-delta-rule (G) layers: no recurrent state beside "
                 "the cache"
             )
+        if "K" in self.layer_pattern:
+            return (
+                "delta-rule layers with a decay a key channel (K): no "
+                "recurrent state beside the cache"
+            )
         if "S" in self.layer_pattern:
             return "block-sparse (S) layers: a selection has no cache path"
         if self.layer_pattern:
@@ -845,7 +932,11 @@ class ModelConfig:
         benchmark's reference counts them; the conv's taps are not."""
         d = self.d_model
         inner, heads = self.d_inner, self.mamba_num_heads
-        attn = self.attn_params
+        attn, attn_scales = self.attn_params, 0
+        if self.latent_attention:
+            # the ``*`` of this model; the latents' norm scales beside it
+            attn = self.latent_params
+            attn_scales = self.q_lora_rank + self.kv_lora_rank
         w_in = d * (inner + self.conv_dim + heads)
         mamba = w_in + inner * d
         mats = 2 if self.act == "relu2" else 3  # matrices of an expert
@@ -882,7 +973,7 @@ class ModelConfig:
                 + 3 * heads + inner + d,
                 mamba + 2 * inner * self.ssm_state_size,
             ),
-            "*": (attn + d + qk_scales, attn),
+            "*": (attn + d + qk_scales + attn_scales, attn),
             "S": (attn + d + qk_scales, attn),
             # the recurrence's update and read-out: 2 x inner x state
             "L": (
@@ -898,6 +989,18 @@ class ModelConfig:
                 self.gdn_params + int(
                     3.5 * self.gdn_value_heads * self.gdn_key_dim
                     * self.gdn_value_dim
+                ),
+            ),
+            # as G's: conv taps, A, the time step's bias a channel, the
+            # output norm's scale and the part's norm beside the
+            # matrices; the rule's own 3.5 x key x value channels a head
+            "K": (
+                self.kda_params
+                + 3 * self.kda_heads * self.kda_head_dim * self.conv_kernel
+                + self.kda_heads + self.kda_heads * self.kda_head_dim
+                + self.kda_head_dim + d,
+                self.kda_params + int(
+                    3.5 * self.kda_heads * self.kda_head_dim ** 2
                 ),
             ),
             "E": routed,
@@ -921,15 +1024,7 @@ class ModelConfig:
                 + v * d * (1 if self.tie_embeddings else 2) + d
             )
         if self.latent_attention:
-            attn = (
-                d * self.q_lora_rank
-                + self.q_lora_rank * self.n_head * self.head_dim
-                + d * (self.kv_lora_rank + self.qk_rope_head_dim)
-                + self.kv_lora_rank * self.n_head
-                * (self.qk_nope_head_dim + self.v_head_dim)
-                + self.n_head * self.v_head_dim * d
-                + self.q_lora_rank + self.kv_lora_rank
-            )
+            attn = self.latent_params + self.q_lora_rank + self.kv_lora_rank
         else:
             attn = self.attn_params
         # the indexer; and the two norms on the parts' outputs
@@ -999,7 +1094,10 @@ class ModelConfig:
         model counts each layer's one part (``_part_counts``) and the
         pairs of its attention layers alone."""
         d = self.d_model
-        d_attn = self.n_head * self.head_dim
+        # a pair's two products: one over the score channels, one over
+        # the value channels (the same number but under latent attention
+        # with narrower values)
+        d_attn = self.n_head * (self.head_dim + self.value_dim) / 2
         if self.layer_pattern:
             met = {k: n for k, (_, n) in self._part_counts().items()}
             head = d * self.vocab_size
@@ -1022,16 +1120,9 @@ class ModelConfig:
                 stars * d_attn * span
                 + self.layer_pattern.count("S") * pairs
             )
-        if self.latent_attention:
-            attn = (
-                d * self.q_lora_rank + self.q_lora_rank * d_attn
-                + d * (self.kv_lora_rank + self.qk_rope_head_dim)
-                + self.kv_lora_rank * self.n_head
-                * (self.qk_nope_head_dim + self.v_head_dim)
-                + self.n_head * self.v_head_dim * d
-            )
-        else:
-            attn = self.attn_params
+        attn = (
+            self.latent_params if self.latent_attention else self.attn_params
+        )
         # a score-only indexer: its projections among the multiplied
         attn += self.index_params
         gated = 3 if self.act == "swiglu" else 2
@@ -1537,6 +1628,62 @@ CONFIGS = {
         moe_impl="ragged",
         moe_score="softmax",
         moe_renorm_topk=True,
+    ),
+    # a delta rule whose decay is a vector over the key channels in
+    # three layers of four and latent attention without positions in
+    # the fourth: Kimi-Linear-48B-A3B-Instruct (``kimi_linear``;
+    # huggingface.co/moonshotai/Kimi-Linear-48B-A3B-Instruct config.json;
+    # arXiv:2510.26692) — 27 layers over d 2304, each x + mixer(norm(x))
+    # then x + mlp(norm(x)), RMSNorm eps 1e-5; published layer i (from 1)
+    # a KDA mixer (``K``: 32 heads of 128 key and 128 value channels,
+    # conv 4 over [q | k | v], a decay a head and KEY CHANNEL and a
+    # sigmoid output gate, both through a rank of 128 — the model
+    # code's, no key in config.json —, RMSNorm a head THEN the gate)
+    # where i is in ``kda_layers``, else latent attention (``*``: 32
+    # heads, q at full rank (``q_lora_rank: null``), k and v through a
+    # latent of 512, scores over 128 + 64 channels and values of 128, the
+    # 64 shared channels NOT rotated, ``mla_use_nope``); the first
+    # layer's second part a dense SwiGLU of 9216, every other layer's 256
+    # SwiGLU experts of width 1024, sigmoid top-8 of ONE group
+    # renormalised x 2.446, beside a shared expert of 1024; no router
+    # loss (config.json carries no coefficient), the selection bias held
+    # at zero; no prediction module; vocabulary 163,840 untied. Training
+    # path only
+    "kimi-linear": ModelConfig(
+        name="kimi-linear",
+        vocab_size=163840,
+        n_layer=27,
+        layer_pattern="".join(
+            ("*" if i % 4 == 0 or i == 27 else "K") + ("-" if i == 1 else "e")
+            for i in range(1, 28)
+        ),
+        n_head=32,
+        n_kv_head=32,
+        d_model=2304,
+        d_ff=9216,
+        max_seq=1048576,
+        act="swiglu",
+        pos="none",  # mla_use_nope; rope_theta is published and unused
+        attn_window=None,
+        tie_embeddings=False,
+        norm_eps=1e-5,
+        q_lora_rank=0,  # q_lora_rank: null
+        kv_lora_rank=512,
+        qk_nope_head_dim=128,
+        qk_rope_head_dim=64,
+        v_head_dim=128,
+        kda_heads=32,
+        kda_head_dim=128,
+        kda_gate_rank=128,
+        conv_kernel=4,
+        n_experts=256,
+        expert_top_k=8,
+        d_expert=1024,
+        n_shared_experts=1,
+        moe_impl="ragged",
+        moe_score="sigmoid",
+        moe_renorm_topk=True,
+        routed_scaling_factor=2.446,
     ),
     # an attention kind per layer, a gate on the attention's output,
     # four norms a layer: Trinity-Mini (``afmoe``, 26B-A3B;

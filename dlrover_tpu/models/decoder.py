@@ -85,10 +85,14 @@ def _init_attention(keys, cfg: ModelConfig, stack, ones) -> Params:
         nope, rd, vd = (
             cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
         )
-        attn = {
+        # q through its rank, or one matrix where there is none
+        q_mats = {
             "wq_a": stack(keys[1], (d, rq), d),
             "q_a_norm": {"scale": ones(rq)},
             "wq_b": stack(keys[11], (rq, nh * (nope + rd)), rq),
+        } if rq else {"wq": stack(keys[1], (d, nh * (nope + rd)), d)}
+        attn = {
+            **q_mats,
             # [c_kv ‖ k_r]: the latent and the rope channels all heads share
             "wkv_a": stack(keys[2], (d, rkv + rd), d),
             "kv_a_norm": {"scale": ones(rkv)},
@@ -158,12 +162,13 @@ def _init_layers(keys, cfg: ModelConfig, lead, routed: bool) -> Params:
 # a ``layer_pattern`` letter -> the name its stack of parts goes by:
 # M a Mamba-2 mixer, m a Mamba-1 mixer, * an attention, S a block-sparse
 # attention, L a lightning linear attention, G a gated-delta-rule mixer,
-# E the routed experts (e: as a layer's second part; a pattern has one
-# of the two), - a dense MLP
+# K a delta-rule mixer with a decay a key channel (KDA), E the routed
+# experts (e: as a layer's second part; a pattern has one of the two),
+# - a dense MLP
 PART_NAMES = {
     "M": "mamba", "m": "mamba1", "*": "attention", "S": "sparse",
-    "L": "lightning", "G": "gdn", "E": "experts", "e": "experts",
-    "-": "mlp",
+    "L": "lightning", "G": "gdn", "K": "kda", "E": "experts",
+    "e": "experts", "-": "mlp",
 }
 
 
@@ -371,6 +376,41 @@ def _init_gdn(key, cfg: ModelConfig, lead) -> Params:
     }
 
 
+def _init_kda(key, cfg: ModelConfig, lead) -> Params:
+    """A KDA mixer's parameters: ``w_qkv`` [q | k | v] and ``w_gates``
+    [f_a | g_a | b] (the decay's and the output gate's low-rank inputs
+    and the write strength's, side by side: the published checkpoint
+    holds a matrix each, a concatenation of columns is the same
+    function), the conv's taps over [q | k | v] (no bias), the two
+    low-rank outputs ``w_fb`` and ``w_gb``, ``A`` uniform in [1, 16] a
+    head (kept as its log), the time step's bias a head and KEY CHANNEL
+    as ``_time_step_bias``, ONE output-norm scale of a head's channels,
+    and the output matrix."""
+    d, taps, rank = cfg.d_model, cfg.conv_kernel, cfg.kda_gate_rank
+    heads = cfg.kda_heads
+    inner = heads * cfg.kda_head_dim
+    stack, ones = _stackers(cfg, lead)
+    pdt = jnp.dtype(cfg.param_dtype)
+    lead = tuple(lead)
+    k = jax.random.split(key, 8)
+    bound = 1.0 / np.sqrt(taps)  # a depthwise tap sees ``taps`` inputs
+    return {
+        "w_qkv": stack(k[0], (d, 3 * inner), d),
+        "w_gates": stack(k[1], (d, 2 * rank + heads), d),
+        "conv_w": jax.random.uniform(
+            k[2], lead + (taps, 3 * inner), minval=-bound, maxval=bound,
+        ).astype(pdt),
+        "w_fb": stack(k[3], (rank, inner), rank),
+        "w_gb": stack(k[4], (rank, inner), rank),
+        "a_log": jnp.log(
+            jax.random.uniform(k[5], lead + (heads,), minval=1.0, maxval=16.0)
+        ).astype(pdt),
+        "dt_bias": _time_step_bias(k[6], cfg, lead + (inner,)).astype(pdt),
+        "norm": {"scale": ones(cfg.kda_head_dim)},
+        "w_out": stack(k[7], (inner, d), inner),
+    }
+
+
 def _init_mlp(keys, cfg: ModelConfig, stack) -> Params:
     """The dense MLP's matrices: SwiGLU's three, or two."""
     d, f = cfg.d_model, cfg.d_ff
@@ -409,6 +449,8 @@ def _init_pattern(key, cfg: ModelConfig, pattern: str) -> Params:
             layer["lin"] = _init_lightning(kk, cfg, lead)
         elif letter == "G":
             layer["gdn"] = _init_gdn(kk, cfg, lead)
+        elif letter == "K":
+            layer["kda"] = _init_kda(kk, cfg, lead)
         elif letter == "-":
             layer["mlp"] = _init_mlp(jax.random.split(kk, 16), cfg, stack)
         else:
@@ -480,10 +522,13 @@ def init(rng: jax.Array, cfg: ModelConfig) -> Params:
 def _attention_axes(cfg: ModelConfig, lead) -> Params:
     """Logical axes of ``_init_attention``'s matrices."""
     if cfg.latent_attention:
-        attn = {
+        q_mats = {
             "wq_a": lead + ("embed", None),
             "q_a_norm": {"scale": lead + ("norm",)},
             "wq_b": lead + (None, "heads"),
+        } if cfg.q_lora_rank else {"wq": lead + ("embed", "heads")}
+        attn = {
+            **q_mats,
             "wkv_a": lead + ("embed", None),
             "kv_a_norm": {"scale": lead + ("norm",)},
             "wkv_b": lead + (None, "heads"),
@@ -568,6 +613,18 @@ def _pattern_axes(cfg: ModelConfig, pattern: str, lead) -> Params:
                 "conv_w": lead + (None, "mlp"),
                 "a_log": lead + (None,),
                 "dt_bias": lead + (None,),
+                "norm": {"scale": lead + ("norm",)},
+                "w_out": lead + ("mlp", "embed"),
+            }
+        elif letter == "K":
+            layer["kda"] = {
+                "w_qkv": lead + ("embed", "mlp"),
+                "w_gates": lead + ("embed", None),
+                "conv_w": lead + (None, "mlp"),
+                "w_fb": lead + (None, "mlp"),
+                "w_gb": lead + (None, "mlp"),
+                "a_log": lead + (None,),
+                "dt_bias": lead + ("mlp",),
                 "norm": {"scale": lead + ("norm",)},
                 "w_out": lead + ("mlp", "embed"),
             }
@@ -935,14 +992,17 @@ def _cache_layer_tail(x, attn_out, layer, cfg: ModelConfig):
 
 
 def _latent_qkv(x, attn, cfg: ModelConfig, positions, rope=None):
-    """Latent attention's q, k, v in the EXPANDED form, [B, S, H, D] each
-    with D = qk_nope + qk_rope = v_head_dim, so that what follows is MHA
-    through the flash kernels:
+    """Latent attention's q, k, v in the EXPANDED form: q and k
+    [B, S, H, D] with D = qk_nope + qk_rope, v [B, S, H, v_head_dim]
+    (as wide as D, or narrower), so that what follows is MHA through
+    the flash kernels:
 
         c_q = norm(x W_dq);  q_h = c_q W_uq  -> [q_nope,h ‖ q_rope,h]
+                (``q_lora_rank`` 0: q_h = x W_q, one matrix, no norm)
         [c_kv ‖ k_r] = x W_dkv;  c_kv = norm(c_kv)
         [k_nope,h ‖ v_h] = c_kv W_ukv
         q_h = [q_nope,h ‖ rope(q_rope,h)];  k_h = [k_nope,h ‖ rope(k_r)]
+                (``pos`` none: neither is turned, ``mla_use_nope``)
 
     ``k_r`` is one set of rope channels that every head shares. The
     up-projection's weight is cut into its k and v columns (4.6 M
@@ -955,22 +1015,29 @@ def _latent_qkv(x, attn, cfg: ModelConfig, positions, rope=None):
     nope, rd, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     dt = x.dtype
     with jax.named_scope("attn.latent"):
-        c_q = _norm_block(x @ attn["wq_a"].astype(dt), attn["q_a_norm"], cfg)
-        q = (c_q @ attn["wq_b"].astype(dt)).reshape(b, s, nh, nope + rd)
+        if cfg.q_lora_rank:
+            c_q = _norm_block(
+                x @ attn["wq_a"].astype(dt), attn["q_a_norm"], cfg
+            )
+            q = c_q @ attn["wq_b"].astype(dt)
+        else:
+            q = x @ attn["wq"].astype(dt)
+        q = q.reshape(b, s, nh, nope + rd)
         kv = x @ attn["wkv_a"].astype(dt)
         c_kv = _norm_block(kv[..., :rkv], attn["kv_a_norm"], cfg)
         k_r = kv[..., rkv:].reshape(b, s, 1, rd)
         w_kv = attn["wkv_b"].astype(dt).reshape(rkv, nh, nope + vd)
         k_nope = jnp.einsum("bsr,rhc->bshc", c_kv, w_kv[..., :nope])
         v = jnp.einsum("bsr,rhc->bshc", c_kv, w_kv[..., nope:])
-        if rope is None:
-            rope = _rope_tables(positions, rd, cfg.rope_theta)
-        q = jnp.concatenate(
-            [q[..., :nope], _rope(q[..., nope:], rope)], axis=-1
-        )
+        if cfg.pos == "rope":
+            if rope is None:
+                rope = _rope_tables(positions, rd, cfg.rope_theta)
+            q = jnp.concatenate(
+                [q[..., :nope], _rope(q[..., nope:], rope)], axis=-1
+            )
+            k_r = _rope(k_r, rope)
         k = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(_rope(k_r, rope), (b, s, nh, rd))],
-            axis=-1,
+            [k_nope, jnp.broadcast_to(k_r, (b, s, nh, rd))], axis=-1
         )
     return q, k, v
 
@@ -1003,14 +1070,22 @@ def _attention_block(
     rope=None,
 ):
     b, s, d = x.shape
-    nh, hd = cfg.n_head, cfg.head_dim
+    nh, hd, vd = cfg.n_head, cfg.head_dim, cfg.value_dim
     if cfg.latent_attention:
         q, k, v = _latent_qkv(x, layer["attn"], cfg, positions, rope=rope)
     else:
         q, k, v = _project_qkv(x, layer, cfg, positions, fp8=fp8, rope=rope)
+    if vd < hd:
+        # values narrower than the scores (latent attention at 192 / 128):
+        # the kernels have one width for q, k and v, so v is padded with
+        # zeros to the scores' and the output cut again. Exact: a zero
+        # channel of v is a zero channel of p v, and of dv
+        v = jnp.pad(v, ((0, 0),) * 3 + ((0, hd - vd),))
     q, k, v = _constrain_qkv(q, k, v, mesh)
     out = attn_fn(q, k, v)
-    out = out.reshape(b, s, nh * hd)
+    if vd < hd:
+        out = out[..., :vd]
+    out = out.reshape(b, s, nh * vd)
     if cfg.attn_gate:
         out = _gate_output(out, x, layer["attn"]["wg"])
     if fp8 is not None:
@@ -1869,13 +1944,87 @@ def _gdn_block(h, gdn, cfg: ModelConfig, mesh):
     return matmul(y, gdn["w_out"]), aux
 
 
+def _kda_block(h, kda, cfg: ModelConfig, mesh):
+    """A delta-rule mixer whose decay is a vector over the key channels
+    (Kimi Delta Attention) on the layer's normed input ``h`` [B, S, D]
+    (scope ``kda``; inside it ``kda.conv`` around ``ssm.conv``,
+    ``kda.rule`` and ``kda.gate``):
+
+        [q | k | v] = h W_qkv;  [f | z | b] = h W_gates     (float32)
+        [q | k | v] = silu(conv([q | k | v]))               (no bias)
+        β = sigmoid(b);  g = -exp(A_log) softplus(f W_fb + dt_bias)
+                (one decay a head AND key channel, through a low rank)
+        q, k = q / |q|, k / |k| a head;  q = q / sqrt(key channels)
+        o = gated_delta_rule(q, k, v, g, β)      (ops/gated_delta.py)
+        out = (rms_head(o) w ⊙ sigmoid(z W_gb)) W_out
+
+    H heads of D key and D value channels, each head its own q, k and v.
+    ``_gdn_block`` with three differences: the decay is [B, S, H, D] and
+    so the rule's other body; both gates come through a low rank; the
+    output gate is a sigmoid. The interior is ``_gdn_block``'s for
+    ``_gdn_block``'s reason: the matrices multiply operands of the
+    compute dtype, EVERYTHING between them is float32 (the output too),
+    and the rule multiplies float32 operands in three bf16 passes.
+    Returns (output, aux): ``aux["kda_readout_ms"]`` the mean square of
+    the read-out ``o`` before the norm, as ``gdn_readout_ms``."""
+    from dlrover_tpu.ops import ssd
+    from dlrover_tpu.ops.gated_delta import gated_delta_rule
+
+    b, s, _ = h.shape
+    dt_, f32 = jnp.dtype(cfg.dtype), jnp.float32
+    heads, dh, rank = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_gate_rank
+    inner = heads * dh
+
+    def matmul(x, w):
+        return jnp.matmul(
+            x.astype(dt_), w.astype(dt_), preferred_element_type=f32
+        )
+
+    proj = matmul(h, kda["w_qkv"])
+    if mesh is not None:
+        proj = shd.constrain(proj, mesh, "batch", "seq", "mlp")
+    gates = matmul(h, kda["w_gates"])
+    decay_in = matmul(gates[..., :rank], kda["w_fb"])
+    gate_in = matmul(gates[..., rank:2 * rank], kda["w_gb"])
+    # (the mesh only where it rules the kernels out: ``_mamba_block``)
+    several = {"mesh": mesh} if mesh is not None and mesh.size > 1 else {}
+    with jax.named_scope("kda.conv"):
+        qkv = jax.nn.silu(ssd.causal_conv(
+            ssd.Columns(proj, 0), kda["conv_w"],
+            jnp.zeros((3 * inner,), f32), **several,
+        ))
+    with jax.named_scope("kda.rule"):
+        beta = jax.nn.sigmoid(gates[..., 2 * rank:])
+        g = -jnp.exp(kda["a_log"].astype(f32))[:, None] * jax.nn.softplus(
+            decay_in + kda["dt_bias"].astype(f32)
+        ).reshape(b, s, heads, dh)
+        q, k, v = (
+            qkv[..., i * inner:(i + 1) * inner].reshape(b, s, heads, dh)
+            for i in range(3)
+        )
+        o = gated_delta_rule(
+            _l2_heads(q, dh ** -0.5), _l2_heads(k), v, g, beta, **several
+        )
+    aux = {"kda_readout_ms": jax.lax.stop_gradient(jnp.mean(jnp.square(o)))}
+    with jax.named_scope("kda.gate"):
+        y = ssd.gated_group_norm(
+            o.reshape(b, s, inner), gate_in, kda["norm"]["scale"], heads,
+            cfg.norm_eps or 1e-6, norm_before_gate=True,
+            gate=jax.nn.sigmoid,
+        )
+    return matmul(y, kda["w_out"]), aux
+
+
 # the scope a part's operations are traced under
 _PART_SCOPES = {
     "M": "ssm", "m": "ssm1", "*": "attn", "S": "attn", "L": "lin",
-    "G": "gdn", "E": "mlp", "e": "mlp", "-": "mlp",
+    "G": "gdn", "K": "kda", "E": "mlp", "e": "mlp", "-": "mlp",
 }
 # the one number a mixer part hands out beside x, by its letter
-_PART_READS = {"L": "lightning_fast_out_ms", "G": "gdn_readout_ms"}
+_PART_READS = {
+    "L": "lightning_fast_out_ms", "G": "gdn_readout_ms",
+    "K": "kda_readout_ms",
+}
 
 
 def _part_body(
@@ -1886,10 +2035,10 @@ def _part_body(
     (s = ``cfg.residual_scale``): a Mamba-2 mixer (``M``), a Mamba-1
     mixer (``m``), an attention (``*``), a block-sparse attention
     (``S``), a lightning linear attention (``L``), a gated-delta-rule
-    mixer (``G``), the routed experts (``E``, ``e``) or a dense MLP
-    (``-``). Returns (x, the routed block's, the block-sparse
-    attention's, the lightning part's or the delta-rule mixer's aux, or
-    {})."""
+    mixer (``G``), a delta-rule mixer with a decay a key channel
+    (``K``), the routed experts (``E``, ``e``) or a dense MLP (``-``).
+    Returns (x, the routed block's, the block-sparse attention's, the
+    lightning part's or the delta-rule mixer's aux, or {})."""
     aux = {}
     with jax.named_scope(_PART_SCOPES[letter]):
         # (``x`` is float32 behind a part whose output is, until
@@ -1911,6 +2060,8 @@ def _part_body(
             out, aux = _lightning_block(h, layer["lin"], cfg, mesh, rope)
         elif letter == "G":
             out, aux = _gdn_block(h, layer["gdn"], cfg, mesh)
+        elif letter == "K":
+            out, aux = _kda_block(h, layer["kda"], cfg, mesh)
         elif letter == "-":
             out = _mlp_block(h, layer, cfg, mesh, interior=jnp.float32)
         else:
@@ -1953,7 +2104,8 @@ def _run_pattern(
     where ``return_selected``, their selections as ``attn_selected``
     bool [S parts x KV, B, S, U], layer-major and group-minor; the
     lightning parts' ``lightning_fast_out_ms`` and the delta-rule
-    mixers' ``gdn_readout_ms`` as their means (``_PART_READS``), those
+    mixers' ``gdn_readout_ms`` / ``kda_readout_ms`` as their means
+    (``_PART_READS``), those
     of a scanned run among them (the one thing a run hands out beside x).
     ``first``: the index of the pattern's first part, folded into
     ``rng``."""
@@ -2278,6 +2430,18 @@ def run_trunk(
             set_counter("gdn.kernel_layers", linear * int(
                 gated_delta.in_kernels(
                     cfg.gdn_key_dim, cfg.gdn_value_dim, mesh=mesh
+                )
+            ))
+        if "K" in cfg.layer_pattern:
+            from dlrover_tpu.ops import gated_delta
+
+            linear = cfg.layer_pattern.count("K")
+            set_counter("kda.layers", linear)
+            # (a decay a key channel has the XLA body alone: 0 today)
+            set_counter("kda.kernel_layers", linear * int(
+                gated_delta.in_kernels(
+                    cfg.kda_head_dim, cfg.kda_head_dim, mesh=mesh,
+                    per_channel=True,
                 )
             ))
         if "S" in cfg.layer_pattern:
@@ -2955,6 +3119,8 @@ def _loss_from_head(
         # nor this: the delta-rule mixers' mean square read-out before
         # the per-head norm, what a uniform scale of it moves
         metrics["gdn_readout_ms"] = moe_aux["gdn_readout_ms"]
+    if "kda_readout_ms" in moe_aux:
+        metrics["kda_readout_ms"] = moe_aux["kda_readout_ms"]
     # run_trunk (and the prediction module) summed these over the
     # routed blocks; reported as the mean over them
     blocks = cfg.n_routed_layer + cfg.n_mtp_module

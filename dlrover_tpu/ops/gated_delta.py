@@ -23,7 +23,8 @@ inside a chunk (the WY / UT form):
     S_next = e^{γ_C} S_prev + Kᵀ (e^{γ_C − γ} ⊙ V')
 
 The per-token recurrence is the definition; this form agrees with it
-(``tests/test_gated_delta.py``, values and gradients).
+(``tests/test_gated_delta.py``, values and gradients; with a decay a
+key channel — the last section below — ``tests/test_kda_rule.py``).
 
 What is float32 whatever the compute dtype: g, β, the running sums γ,
 every difference ``γ_i − γ_j`` (formed BEFORE the exponential: γ itself
@@ -96,6 +97,40 @@ back (the compiler's count for a described v5e).
 A length that is no multiple of the chunk is PADDED at its end with
 tokens of g = 0, β = 0 and k = 0, which leave every state as it was and
 whose outputs are cut off again: exact, since the rule is causal.
+
+A DECAY A KEY CHANNEL (Kimi Delta Attention, arXiv:2510.26692; PR 65):
+``g`` [B, S, Hv, Dk], ``S' = Diag(α_t) S_{t-1}``, row d of the state by
+its own ``α_td``. The same function takes it and the shape of ``g`` is
+what chooses. With γ the running sum of g inside a chunk, a vector a
+token:
+
+    A = strict_lower(β_i Σ_d k_id k_jd e^{γ_id − γ_jd})   T = (I + A)^{-1}
+    W = T (β ⊙ K ⊙ e^{γ})      U = T (β ⊙ V)      V' = U − W S_prev
+    O  = (Q ⊙ e^{γ}) S_prev + lower(Σ_d q_id k_jd e^{γ_id − γ_jd}) V'
+    S_next = Diag(e^{γ_C}) S_prev + (K ⊙ e^{γ_C − γ})ᵀ V'
+
+which with g equal over a head's channels is the form above, line for
+line. The decay now sits INSIDE the sums over channels, so ``A`` and the
+``Q Kᵀ`` block are no product of a key matrix and a decay block, and the
+difference must still come before the exponential: ``_channel_pairs``
+makes them in sub-blocks of 16 tokens, the diagonal blocks from explicit
+[16, 16, Dk] differences and the rest as matmuls of ``K ⊙ e^{γ − r}`` by
+``K ⊙ e^{r − γ}`` with r the running sum at the LATER block's first
+token, so that every exponent formed is <= 0 and no [C, C, Dk] array of
+a whole chunk exists. A head is a VALUE head here (shared keys are
+repeated: the decayed keys are a value head's own). The XLA body alone
+(``_channel_stretch``; kernels are ROADMAP S16(e)), in stretches of
+``CHANNEL_STRETCH`` = 1,024 tokens, each under its own checkpoint. What
+a stretch holds at 32 heads of 128 channels: 16 chunks' operands (q, k,
+v, g, W, U, ``Q ⊙ e^{γ}``, ``K ⊙ e^{γ_C − γ}``: 17 MB each, float32),
+the earlier keys once a block row (38 MB), 16 chunk states (34 MB), and,
+where XLA keeps them for the backward, the diagonal sub-blocks' decayed
+keys [16, 32, 4, 16, 16, 128] (8 KB a token and head, 268 MB): forward
+and backward of ONE layer are 0.53 GB of temporaries by the compiler's
+count for a described v5e, 1.03 GB at stretches of 2,048 and 0.29 at 512
+(the Kimi-Linear cell's step: 15.12 GB of the chip's 16.91 by that
+count). As on the scalar XLA body, a step under ``remat: full`` runs the
+rule's forward three times and its backward once.
 """
 
 import functools
@@ -113,14 +148,17 @@ _BASE = 16
 
 def recurrence(q, k, v, g, beta):
     """The definition, token by token (``lax.scan`` over t), float32:
-    q, k [B, S, Hk, Dk], v [B, S, Hv, Dv], g and beta [B, S, Hv].
-    Returns o [B, S, Hv, Dv] float32. For tests and small shapes."""
+    q, k [B, S, Hk, Dk], v [B, S, Hv, Dv], beta [B, S, Hv], g
+    [B, S, Hv] (one decay a head) or [B, S, Hv, Dk] (one a key channel:
+    ``S' = Diag(α_t) S_{t-1}``). Returns o [B, S, Hv, Dv] float32. For
+    tests and small shapes."""
     rep = v.shape[2] // k.shape[2]
     q, k = (jnp.repeat(t.astype(F32), rep, axis=2) for t in (q, k))
+    cells = (None,) * (5 - g.ndim)  # a state's axes a decay is spread over
 
     def token(state, inp):
         q_t, k_t, v_t, g_t, b_t = inp
-        state = jnp.exp(g_t)[..., None, None] * state
+        state = jnp.exp(g_t)[(...,) + cells] * state
         seen = jnp.einsum("bhde,bhd->bhe", state, k_t, precision=_HIGHEST)
         state = state + (b_t[..., None] * k_t)[..., None] * (
             (v_t - seen)[:, :, None, :]
@@ -347,16 +385,16 @@ def _stretch(state, q, k, v, g, beta, chunk):
     return state, jnp.moveaxis(o, 0, 1).reshape(b, s, hk, r, dv)
 
 
-def _chunked(q, k, v, g, beta, chunk, stretch):
+def _chunked(q, k, v, g, beta, chunk, stretch, body=_stretch):
     """Whole stretches of ``stretch`` tokens, each of whole chunks, one
     after another (``lax.scan``), each under its own
-    ``jax.checkpoint``: see the module's docstring. Shapes as
-    ``_stretch``'s."""
-    b, s, hk, dk = k.shape
-    r, dv = v.shape[3:]
-    start = jnp.zeros((b, hk, r, dk, dv), F32)
+    ``jax.checkpoint``: see the module's docstring. ``body`` is
+    ``_stretch`` or ``_channel_stretch``, shapes as its own: the state
+    starts as zeros [B, v's head axes, Dk, Dv]."""
+    b, s = k.shape[:2]
+    start = jnp.zeros((b,) + v.shape[2:-1] + (k.shape[-1], v.shape[-1]), F32)
     if s == stretch:
-        return _stretch(start, q, k, v, g, beta, chunk)[1]
+        return body(start, q, k, v, g, beta, chunk)[1]
 
     def lead(t):
         # [B, S, ...] -> [stretches, B, stretch, ...]
@@ -365,10 +403,139 @@ def _chunked(q, k, v, g, beta, chunk, stretch):
         )
 
     one = jax.checkpoint(
-        lambda state, operands: _stretch(state, *operands, chunk)
+        lambda state, operands: body(state, *operands, chunk)
     )
     _, o = jax.lax.scan(one, start, tuple(map(lead, (q, k, v, g, beta))))
-    return jnp.moveaxis(o, 0, 1).reshape(b, s, hk, r, dv)
+    return jnp.moveaxis(o, 0, 1).reshape(v.shape)
+
+
+def _channel_pairs(q, k, gamma):
+    """A chunk's decayed products under a decay a key CHANNEL: q, k
+    [..., C, Dk] of one dtype, gamma [..., C, Dk] float32, the running
+    sums of g inside the chunk (<= 0 and falling). Returns (kk, qk)
+    [..., C, C] float32, ``Σ_d k_id k_jd e^{γ_id − γ_jd}`` and
+    ``Σ_d q_id k_jd e^{γ_id − γ_jd}``, on and under the diagonal and 0
+    above it. The decay sits INSIDE the sum over channels, so neither is
+    a product of a key matrix and a decay block, and ``K ⊙ e^{−γ}``
+    overflows float32 (γ passes −100 inside a chunk of a fast channel).
+    In SUB-BLOCKS of ``_BASE`` tokens:
+
+    - a diagonal block from explicit differences [16, 16, Dk], the
+      exponential of each (<= 0 under the diagonal) and float32
+      multiply-adds over the channels: 8 KB a token and head, where the
+      differences of a whole chunk of 64 would be 32;
+    - the blocks LEFT of block I as ONE matmul a block row, of
+      ``K_I ⊙ e^{γ_i − r_I}`` (and ``Q_I ⊙`` the same) by
+      ``K_j ⊙ e^{r_I − γ_j}`` over the earlier tokens j, with the
+      reference r_I the running sum at block I's FIRST token: γ falls,
+      so ``γ_i <= r_I <= γ_j`` and both exponents are <= 0. The right
+      operand is the chunk's earlier keys once a block row."""
+    dtype = k.dtype
+    dot = _products(dtype)
+    lead, (c, dk) = k.shape[:-2], k.shape[-2:]
+    sub = min(c, _BASE)
+    p = c // sub
+
+    def blocks(t):
+        return t.astype(F32).reshape(lead + (p, sub, dk))
+
+    qb, kb, gb = blocks(q), blocks(k), blocks(gamma)
+    on = jnp.tril(jnp.ones((sub, sub), bool))[..., None]
+    # the difference first, then the exponential: [.., P, a, b, Dk]
+    seen = kb[..., None, :, :] * jnp.exp(jnp.where(
+        on, gb[..., :, None, :] - gb[..., None, :, :], -jnp.inf
+    ))
+    diag = [
+        jnp.sum(t[..., :, None, :] * seen, axis=-1) for t in (kb, qb)
+    ]
+    # block (I, I) of a [C, C] array
+    eye = jnp.eye(p, dtype=F32)[:, None, :, None]
+    kk, qk = (
+        (t[..., :, :, None, :] * eye).reshape(lead + (c, c)) for t in diag
+    )
+    if p == 1:
+        return kk, qk
+    ref = gb[..., 1:, :1, :]                         # [.., P-1, 1, Dk]
+    into = jnp.exp(gb[..., 1:, :, :] - ref)          # e^{γ_i − r_I} <= 1
+    cols = c - sub  # the last block stands left of none
+    earlier = (
+        jnp.arange(cols) < sub * jnp.arange(1, p)[:, None]
+    )[..., None]                                     # [P-1, cols, 1]
+    k_then = kb.reshape(lead + (1, c, dk))[..., :cols, :]
+    out_of = k_then * jnp.exp(jnp.where(
+        earlier, ref - gamma[..., None, :cols, :], -jnp.inf
+    ))                                               # [.., P-1, cols, Dk]
+    left = jnp.concatenate(
+        [kb[..., 1:, :, :] * into, qb[..., 1:, :, :] * into], axis=-2
+    )
+    off = dot(
+        "...pad,...pjd->...paj", left.astype(dtype), out_of.astype(dtype)
+    )                                                # [.., P-1, 2 sub, cols]
+    none = ((0, 0),) * len(lead)
+
+    def placed(t):
+        # block rows 1.., columns before the last block, of [C, C]
+        return jnp.pad(t, none + ((1, 0), (0, 0), (0, sub))).reshape(
+            lead + (c, c)
+        )
+
+    return kk + placed(off[..., :sub, :]), qk + placed(off[..., sub:, :])
+
+
+def _channel_stretch(state, q, k, v, g, beta, chunk):
+    """``_stretch`` under a decay a key channel, a head a VALUE head
+    (the caller repeats shared keys): ``state`` [B, H, Dk, Dv] float32,
+    q, k [B, S, H, Dk], v [B, S, H, Dv], g [B, S, H, Dk] and beta
+    [B, S, H] float32. Returns (the state it leaves, o [B, S, H, Dv] in
+    v's dtype). The decays a chunk's operands carry are all <= 1:
+    ``K ⊙ e^{γ}`` into W, ``Q ⊙ e^{γ}`` on the state it starts from,
+    ``K ⊙ e^{γ_C − γ}`` and ``e^{γ_C}`` into the state it leaves."""
+    b, s, h, dk = k.shape
+    dv = v.shape[-1]
+    n = s // chunk
+    dtype = v.dtype
+    dot = _products(dtype)
+
+    def cut(t):
+        # [B, S, H, ...] -> [B, N, H, C, ...]
+        return jnp.moveaxis(
+            t.reshape((b, n, chunk) + t.shape[2:]), 2, 3
+        )
+
+    q, k, v, g, beta = (cut(t) for t in (q, k, v, g, beta))
+    gamma = jnp.cumsum(g, axis=3)
+    kk, qk = _channel_pairs(q, k, gamma)
+    strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
+    beta = beta[..., None]
+    t = unit_lower_inverse(jnp.where(strict, beta * kk, 0.0)).astype(dtype)
+    k32, grown = k.astype(F32), jnp.exp(gamma)
+    w = dot("bnhij,bnhjd->bnhid", t, (beta * grown * k32).astype(dtype))
+    u = dot("bnhij,bnhje->bnhie", t, (beta * v.astype(F32)).astype(dtype))
+    last = gamma[..., -1:, :]
+    operands = (
+        (q.astype(F32) * grown).astype(dtype),
+        (k32 * jnp.exp(last - gamma)).astype(dtype),
+        w.astype(dtype), u.astype(dtype), qk.astype(dtype),
+        jnp.exp(last[..., 0, :]),
+    )
+
+    def one(state, inp):
+        q_c, k_c, w_c, u_c, attn_c, kept = inp
+        s_op = state.astype(dtype)
+        fresh = u_c.astype(F32) - dot("bhid,bhde->bhie", w_c, s_op)
+        o = dot("bhid,bhde->bhie", q_c, s_op) + dot(
+            "bhij,bhje->bhie", attn_c, fresh.astype(dtype)
+        )
+        state = kept[..., None] * state + dot(
+            "bhjd,bhje->bhde", k_c, fresh.astype(dtype)
+        )
+        return state, o.astype(dtype)
+
+    state, o = jax.lax.scan(
+        one, state, jax.tree.map(lambda t: jnp.moveaxis(t, 1, 0), operands)
+    )
+    # [N, B, H, C, Dv] -> [B, S, H, Dv]
+    return state, jnp.transpose(o, (1, 0, 3, 2, 4)).reshape(b, s, h, dv)
 
 
 def _kernel_operands(k, g, beta):
@@ -433,34 +600,53 @@ def _kernel_rule_bwd(operands, do):
 _kernel_rule.defvjp(_kernel_rule_fwd, _kernel_rule_bwd)
 
 
-def in_kernels(dk: int, dv: int, chunk: int = 64, mesh=None) -> bool:
+def in_kernels(dk: int, dv: int, chunk: int = 64, mesh=None,
+               per_channel: bool = False) -> bool:
     """Whether ``gated_delta_rule`` runs the Pallas kernels at these
-    widths (``pallas_gated_delta.tile``): what the counter
-    ``gdn.kernel_layers`` counts by."""
-    return pallas_gated_delta.tile(dk, dv, chunk, mesh)
+    widths (``pallas_gated_delta.tile``): what the counters
+    ``gdn.kernel_layers`` and ``kda.kernel_layers`` count by. A decay a
+    key channel (``per_channel``) has the XLA body alone."""
+    return not per_channel and pallas_gated_delta.tile(dk, dv, chunk, mesh)
+
+
+# tokens a stretch of the rule with a decay a key channel holds: see
+# the module's docstring
+CHANNEL_STRETCH = 1024
 
 
 def gated_delta_rule(q, k, v, g, beta, chunk: int = 64,
-                     stretch: int = 2048, mesh=None):
+                     stretch: int = 0, mesh=None):
     """The rule over a sequence. q, k [B, S, Hk, Dk] (the caller's to
     have normed and scaled), v [B, S, Hv, Dv] with Hv a multiple of Hk
-    (key head j serves value heads R j .. R j + R − 1), g [B, S, Hv]
-    float32, the log-decay (<= 0), beta [B, S, Hv] float32 in (0, 1).
-    Returns o [B, S, Hv, Dv] in v's dtype. ``chunk`` is a power of two
-    (or under 16) and ``stretch`` a multiple of it; neither is a size of
-    the model. One function, two bodies, chosen from what it sees
-    (``in_kernels``; ``mesh`` is the mesh the operands live on, if any):
-    on a TPU (or interpreted), on one device, with key and value
-    channels on the 128-lane grid and chunks of 64, the Pallas kernels
-    ``gdn_fwd`` / ``gdn_states`` / ``gdn_bwd``, which take no stretch;
-    anywhere else — the CPU, other widths, a mesh of several devices —
-    the XLA body, stretches and all."""
+    (key head j serves value heads R j .. R j + R − 1), beta [B, S, Hv]
+    float32 in (0, 1), and g float32, the log-decay (<= 0): [B, S, Hv],
+    one a value head, or [B, S, Hv, Dk], one a KEY CHANNEL of each value
+    head (``S' = Diag(e^{g_t}) S_{t-1}``; KDA). The shape of g is what
+    chooses, and nothing else does. Returns o [B, S, Hv, Dv] in v's
+    dtype. ``chunk`` is a power of two (or under 16) and ``stretch`` a
+    multiple of it (0: 2,048 tokens, ``CHANNEL_STRETCH`` under a decay a
+    channel); neither is a size of the model. One decay a head: one
+    function, two bodies, chosen from what it sees (``in_kernels``;
+    ``mesh`` is the mesh the operands live on, if any): on a TPU (or
+    interpreted), on one device, with key and value channels on the
+    128-lane grid and chunks of 64, the Pallas kernels ``gdn_fwd`` /
+    ``gdn_states`` / ``gdn_bwd``, which take no stretch; anywhere else —
+    the CPU, other widths, a mesh of several devices — the XLA body,
+    stretches and all. One decay a channel: the XLA body in sub-blocks
+    (``_channel_pairs``), everywhere."""
     b, s, hk, dk = k.shape
     hv, dv = v.shape[2:]
     if hv % hk:
         raise ValueError(f"{hv} value heads are not shared by {hk} key heads")
     if chunk & (chunk - 1) and chunk > _BASE:
         raise ValueError(f"a chunk of {chunk} tokens is no power of two")
+    per_channel = g.ndim == 4
+    if g.shape != (b, s, hv) + (dk,) * per_channel:
+        raise ValueError(
+            f"g {g.shape} is one decay neither a value head [{b}, {s}, "
+            f"{hv}] nor a key channel of each [{b}, {s}, {hv}, {dk}]"
+        )
+    stretch = stretch or (CHANNEL_STRETCH if per_channel else 2048)
     if stretch % chunk:
         raise ValueError(
             f"a stretch of {stretch} tokens is not whole chunks of {chunk}"
@@ -469,7 +655,7 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = 64,
     dtype = v.dtype  # the products' operands: see ``_products``
     q, k = q.astype(dtype), k.astype(dtype)
     g, beta = g.astype(F32), beta.astype(F32)
-    kernels = in_kernels(dk, dv, chunk, mesh)
+    kernels = in_kernels(dk, dv, chunk, mesh, per_channel)
     # whole chunks, and on the XLA body whole stretches where there are
     # several
     stretch = chunk if kernels else min(stretch, s + -s % chunk)
@@ -480,13 +666,21 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = 64,
             jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
             for t in (q, k, v, g, beta)
         )
-    operands = (
-        q, k, v.reshape(b, s + pad, hk, r, dv),
-        g.reshape(b, s + pad, hk, r), beta.reshape(b, s + pad, hk, r),
-    )
-    if kernels:
-        o = _kernel_rule(*operands)
+    if per_channel:
+        # a head a value head: shared keys are repeated
+        if r > 1:
+            q, k = (jnp.repeat(t, r, axis=2) for t in (q, k))
+        o = _chunked(
+            q, k, v, g, beta, chunk, stretch, body=_channel_stretch
+        )
     else:
-        o = _chunked(*operands, chunk, stretch)
-    o = o.reshape(b, s + pad, hv, dv)
+        operands = (
+            q, k, v.reshape(b, s + pad, hk, r, dv),
+            g.reshape(b, s + pad, hk, r), beta.reshape(b, s + pad, hk, r),
+        )
+        if kernels:
+            o = _kernel_rule(*operands)
+        else:
+            o = _chunked(*operands, chunk, stretch)
+        o = o.reshape(b, s + pad, hv, dv)
     return o[:, :s] if pad else o

@@ -164,7 +164,7 @@ INTERPRET = os.environ.get(
 # (see _bwd_rule)
 BWD_BLOCK = 512        # measured best for head_dim 64 (v5e)
 BWD_BLOCK_WIDE = 1024  # measured best for head_dim 128 (v5e)
-# head_dim >= 256 (latent attention expanded): (q rows, k rows). 1024 x
+# head_dim > 128 (latent attention expanded): (q rows, k rows). 1024 x
 # 1024 needs 17.3 MB of the kernel's 16 MB of VMEM (Mosaic refuses it:
 # tests/test_tpu_compile.py). Of those that fit, swept on a v5e at 2 x
 # 8192 x 20 heads (PR 34; the two backward kernels, ms): 1024 x 512
@@ -213,8 +213,10 @@ def _live_window(window: int, sk: int) -> bool:
 
 
 def _bwd_caps(head_dim: int):
-    """Largest backward tile (q rows, k rows) for a head width."""
-    if head_dim >= 256:
+    """Largest backward tile (q rows, k rows) for a head width. A head
+    past one tile of 128 lanes lies in two, whatever it fills of the
+    second (192 score channels: 1024 x 1024 asks 17.5 MB of the 16)."""
+    if head_dim > LANES:
         return BWD_BLOCK_256
     cap = BWD_BLOCK_WIDE if head_dim >= 128 else BWD_BLOCK
     return cap, cap

@@ -284,15 +284,16 @@ def causal_conv(x, weight, bias, mesh=None):
 
 
 def gated_group_norm(y, z, scale, groups: int, eps: float,
-                     norm_before_gate: bool = False):
+                     norm_before_gate: bool = False, gate=jax.nn.silu):
     """``RMSNorm over each of `groups` groups of (y ⊙ silu(z)) ⊙ scale``:
     the gate first, then the norm (Mamba-2's ``norm_before_gate=False``);
     or, ``norm_before_gate``, ``RMSNorm(y) ⊙ scale ⊙ silu(z)`` (the
     gated-delta-rule mixer's order). y and z [B, S, C]; ``scale`` [C],
     or one group's [C / groups] that every group shares; float32
-    inside."""
+    inside. ``gate`` is the gate's function where it is not silu (the
+    KDA mixer's is a sigmoid)."""
     shape = y.shape
-    gate = jax.nn.silu(z.astype(F32))
+    gate = gate(z.astype(F32))
     v = y.astype(F32)
     if not norm_before_gate:
         v = v * gate

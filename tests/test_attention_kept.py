@@ -184,6 +184,8 @@ CELL_SPANS = {
         "F": (8192.5, 8704, True), "S": (1920.0625, 2880, True),
     },
     "qwen3next-ep16-train-b1s16384": (8192.5, 8704, True),  # its one *
+    # its one latent layer, 192 score channels and values padded to them
+    "kimilinear-ep16-train-b1s16384": (8192.5, 8704, True),
 }
 
 

@@ -54,6 +54,7 @@ BENCHMARK_CONFIGS = {
     "jamba2-3b-l14": 8192,
     "minicpm-sala-l4": 16384,
     "qwen3-next-80b-a3b-ep16-1chip": 16384,
+    "kimi-linear-48b-a3b-ep16-1chip": 16384,
 }
 
 
@@ -71,8 +72,10 @@ def test_flops_per_token_is_the_benchmarks_count(name):
     attention kind per layer with each kind's own span, mixer + MLP
     layers of two parts each with a selective scan's, a selection of
     blocks with its pooled scorer past the length the model runs dense
-    up to, a linear attention's recurrence, and mixer + ROUTED layers
-    with a gated delta rule's."""
+    up to, a linear attention's recurrence, mixer + ROUTED layers with
+    a gated delta rule's, and a delta rule by key channel's beside
+    latent attention whose pairs count the mean of 192 score and 128
+    value channels."""
     from benchmarks.lib.flops import resolve
 
     configs = pathlib.Path(__file__).parent.parent / "benchmarks" / "configs"
@@ -124,8 +127,11 @@ def test_flops_per_token_is_the_benchmarks_count(name):
     assert both_ways.flops_per_token(1024) - causal.flops_per_token(
         1024
     ) == pytest.approx(
-        12.0 * attention_layers * cfg.n_head * cfg.head_dim
-        * (1024 - 512.5)
+        # (a pair's two products: one over the score channels, one
+        # over the value channels, narrower under Kimi-Linear's latent
+        # attention alone)
+        12.0 * attention_layers * cfg.n_head
+        * (cfg.head_dim + cfg.value_dim) / 2 * (1024 - 512.5)
     )
 
 

@@ -427,6 +427,10 @@ CASES = {
     # latent attention expanded (GLM-4.7-Flash): 20 heads of 256
     "flash-fwd-20x256": (_flash(20, 256, grad=False), 1),
     "flash-bwd-20x256": (_flash(20, 256, grad=True), 3),
+    # latent attention at 192 score channels, values padded to them
+    # (Kimi-Linear): a head and a half of lanes, the tiles of 256
+    "flash-fwd-32x192": (_flash(32, 192, grad=False), 1),
+    "flash-bwd-32x192": (_flash(32, 192, grad=True), 3),
     # a selection of keys (Keye-VL-2.0): the ``_sel`` kernels
     # GQA at head size 256 (Qwen3-Next)
     "flash-fwd-16x2x256-of-16384": (_flash_gqa_256(grad=False), 1),
@@ -566,6 +570,36 @@ def test_gated_delta_rule_compiles_a_stretch_at_a_time(topo, chip):
     assert "tpu_custom_call" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1.6e9
     assert not re.search(r"f32\[[\d,]*,16,\d+,16\]", text)
+
+
+def test_vector_delta_rule_compiles_a_stretch_at_a_time(chip):
+    """The delta rule with a decay a key channel at Kimi-Linear's widths
+    (32 heads of 128 key and 128 value channels, one sequence of 16,384,
+    chunks of 64 in sub-blocks of 16, float32 operands as the mixer
+    hands them over), forward and backward, for a described v5e: matmuls
+    and no kernel, and with each stretch of 1,024 tokens under its own
+    checkpoint the compiler counts 0.53 GB of temporaries (1.03 at
+    stretches of 2,048). No array holds a whole chunk's [64, 64, 128]
+    differences: the largest with two token axes and the channels is a
+    sub-block's [16, 16, 128]."""
+    import re
+
+    def struct(shape):
+        return jax.ShapeDtypeStruct(shape, F32, sharding=chip)
+
+    s = 16384
+    wide = struct((1, s, 32, 128))
+    args = (wide, wide, wide, wide, struct((1, s, 32)))
+    assert not gated_delta.in_kernels(128, 128, per_channel=True)
+    loss = lambda *a: gated_delta.gated_delta_rule(  # noqa: E731
+        *a, chunk=64
+    ).astype(F32).sum()
+    compiled = jax.jit(jax.grad(loss, argnums=range(5))).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.7e9
+    assert not re.search(r"f32\[[\d,]*64,64,128\]", text)
+    assert re.search(r"f32\[[\d,]*16,16,128\]", text)
 
 
 @pytest.mark.parametrize(
@@ -1158,6 +1192,57 @@ def test_glm_cell_fits_the_chip_at_its_depth(topo):
     assert stats.argument_size_in_bytes == pytest.approx(
         6 * 1_133_834_752, rel=1e-3  # bf16 parameters and two moments
     )
+
+
+def test_kimi_cell_fits_the_chip(topo):
+    """The benchmark's Kimi-Linear configuration as it is run (published
+    layers 1-5 — a KDA mixer and the dense MLP, then KDA, KDA, latent
+    attention, KDA with sixteen held experts each — one sequence of
+    16,384 tokens) compiles for a described v5e under the chip's 15.75
+    GiB (16.91 GB): the vector rule a stretch of 1,024 tokens at a time,
+    the latent layer's three flash kernels at 192 channels with the
+    backward's tile of 1024 x 512 (1024 x 1024 asks 17.5 MB of VMEM's
+    16 at a head and a half of lanes), its output kept, the convs as
+    kernels, and no kernel of the rule's: a decay a key channel has the
+    XLA body alone. No array holds a whole chunk's [64, 64, 128]
+    differences."""
+    import json
+    import pathlib
+    import re
+
+    path = pathlib.Path(__file__).parent.parent / "benchmarks" / "configs"
+    config = json.loads(
+        (path / "kimi-linear-48b-a3b-ep16-1chip.json").read_text()
+    )
+    STEP_CASES["kimi-cell"] = dict(
+        model=config["program"]["model"],
+        overrides=config["program"]["overrides"],
+        optimizer=dict(state_dtype="bfloat16"), comm=None, chips=1,
+        batch=(1, 16384),
+    )
+    try:
+        _, text, counters = _compiled_step(topo, "kimi-cell")
+    finally:
+        del STEP_CASES["kimi-cell"]
+    stats = _STEP_MEMORY["kimi-cell"]
+    need = (
+        stats.argument_size_in_bytes + stats.output_size_in_bytes
+        - stats.alias_size_in_bytes + stats.temp_size_in_bytes
+    )
+    assert 12e9 < need < 15.75 * 2 ** 30, need
+    assert stats.argument_size_in_bytes == pytest.approx(
+        6 * 828_925_824, rel=1e-3  # bf16 parameters and two moments
+    )
+    assert counters["kda.layers"] == 4
+    assert counters["kda.kernel_layers"] == 0
+    assert counters["attn.output_kept"] == 1
+    assert counters["ssm.conv_in_kernel"] == 1
+    assert _kernel_calls(text, "flash_fwd") == 1
+    assert _kernel_calls(text, "flash_bwd_dq") == 1
+    assert _kernel_calls(text, "flash_bwd_dkv") == 1
+    assert "bf16[32,16384,192]" in text
+    assert "gdn_fwd" not in text and "gdn_bwd" not in text
+    assert not re.search(r"f32\[[\d,]*64,64,128\]", text)
 
 
 def _count_traced_bodies(monkeypatch, module, kernels):
