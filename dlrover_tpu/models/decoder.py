@@ -1960,7 +1960,9 @@ def _kda_block(h, kda, cfg: ModelConfig, mesh):
 
     H heads of D key and D value channels, each head its own q, k and v.
     ``_gdn_block`` with three differences: the decay is [B, S, H, D] and
-    so the rule's other body; both gates come through a low rank; the
+    so the vector rule (``ops/pallas_kda.py``'s kernels where
+    ``gated_delta.in_kernels`` says so, else its XLA body); both gates
+    come through a low rank; the
     output gate is a sigmoid. The interior is ``_gdn_block``'s for
     ``_gdn_block``'s reason: the matrices multiply operands of the
     compute dtype, EVERYTHING between them is float32 (the output too),
@@ -2437,7 +2439,7 @@ def run_trunk(
 
             linear = cfg.layer_pattern.count("K")
             set_counter("kda.layers", linear)
-            # (a decay a key channel has the XLA body alone: 0 today)
+            # those whose rule runs ``ops/pallas_kda.py``: all or none
             set_counter("kda.kernel_layers", linear * int(
                 gated_delta.in_kernels(
                     cfg.kda_head_dim, cfg.kda_head_dim, mesh=mesh,

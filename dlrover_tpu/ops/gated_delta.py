@@ -118,8 +118,11 @@ makes them in sub-blocks of 16 tokens, the diagonal blocks from explicit
 ``K ⊙ e^{r − γ}`` with r the running sum at the LATER block's first
 token, so that every exponent formed is <= 0 and no [C, C, Dk] array of
 a whole chunk exists. A head is a VALUE head here (shared keys are
-repeated: the decayed keys are a value head's own). The XLA body alone
-(``_channel_stretch``; kernels are ROADMAP S16(e)), in stretches of
+repeated: the decayed keys are a value head's own).
+
+Two bodies here too, one algorithm, chosen as above (``in_kernels``: the
+same conditions). THE XLA BODY (``_channel_stretch``) runs on the CPU,
+off the 128 lanes and on several devices, in stretches of
 ``CHANNEL_STRETCH`` = 1,024 tokens, each under its own checkpoint. What
 a stretch holds at 32 heads of 128 channels: 16 chunks' operands (q, k,
 v, g, W, U, ``Q ⊙ e^{γ}``, ``K ⊙ e^{γ_C − γ}``: 17 MB each, float32),
@@ -127,10 +130,40 @@ the earlier keys once a block row (38 MB), 16 chunk states (34 MB), and,
 where XLA keeps them for the backward, the diagonal sub-blocks' decayed
 keys [16, 32, 4, 16, 16, 128] (8 KB a token and head, 268 MB): forward
 and backward of ONE layer are 0.53 GB of temporaries by the compiler's
-count for a described v5e, 1.03 GB at stretches of 2,048 and 0.29 at 512
-(the Kimi-Linear cell's step: 15.12 GB of the chip's 16.91 by that
-count). As on the scalar XLA body, a step under ``remat: full`` runs the
+count for a described v5e, 1.03 GB at stretches of 2,048 and 0.29 at
+512. As on the scalar XLA body, a step under ``remat: full`` runs the
 rule's forward three times and its backward once.
+
+THE KERNELS (``ops/pallas_kda.py``, PR 66, ROADMAP S16(e); on a TPU, one
+device, heads of 128, chunks of 64) share no arithmetic with the scalar
+rule's: the decay inside the sums over channels is another algorithm in
+the chunk, and equal channels are NOT routed to the scalar kernels by a
+test of values. A pass is: ``kda_pairs`` (chunks in parallel; a visit
+makes γ from g — a triangle of ones times g in three bf16 pieces —, the
+four diagonal sub-blocks from explicit differences with the sums over
+the 128 channels on the vector unit's lanes, the three block rows left
+of them as one product each, and writes ``A = strict_lower(β_i kk)`` and
+the decayed ``Q Kᵀ``, M) → XLA's ``unit_lower_inverse`` over all
+chunk-heads at once (the substitution wants the batch of chunks on the
+lanes; in a visit it is 16 dependent sublane steps) → the walk
+(``kda_fwd``: one head a visit, the state [Dv, Dk] float32 in VMEM from
+the first chunk to the last, γ, ``K ⊙ e^γ``, ``Q ⊙ e^γ``,
+``K ⊙ e^{γ_C − γ}``, W, U and V' made in the visit and never written).
+One ``jax.custom_vjp`` (``_channel_kernel_rule``) whose residuals are
+the five operands (q, k, v, g: 268 MB each at 16,384 tokens and 32
+heads, float32; β 2 MB); no stretch and no checkpoint. The backward rule
+makes A, M and T again (the compiler shares them with the layer's remade
+forward), takes every chunk's starting state from ``kda_states`` (537 MB
+a layer, alive inside that layer's backward alone, behind a barrier),
+walks back (``kda_bwd``: the chunk's operands remade in the visit; dv,
+its parts of dq, dk, dγ and dβ, and dT and dM, 268 MB each as tiles pad
+them), takes dT through the inverse's hand derivative and the pairs'
+cotangents back through ``kda_pairs_bwd``, which adds the walk's parts
+and writes dq, dk, dg and dβ whole. A step under ``remat: full`` runs
+the rule's forward TWICE and its backward once, with 1.62 GB of
+temporaries forward and 2.97 GB going back by the compiler's count for a
+described v5e (the Kimi-Linear cell's step: 15.85 GB by that count, of
+the chip's 16.91; 15.12 on the XLA body).
 """
 
 import functools
@@ -138,7 +171,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from dlrover_tpu.ops import pallas_gated_delta
+from dlrover_tpu.ops import pallas_gated_delta, pallas_kda
 
 F32 = jnp.float32
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -600,13 +633,73 @@ def _kernel_rule_bwd(operands, do):
 _kernel_rule.defvjp(_kernel_rule_fwd, _kernel_rule_bwd)
 
 
+def _channel_operands(q, k, v, g, beta):
+    """The vector rule's operands as its kernels take them: q, k, v and
+    g a head a run of columns ([B, S, H * D]), beta a row a chunk
+    [B, N, H, 1, C]."""
+    b, s, h = beta.shape
+    chunk = pallas_gated_delta.CHUNK
+    rows = jnp.moveaxis(beta.reshape(b, s // chunk, chunk, h), 2, 3)
+    return _flat(q, k, v) + (g.reshape(b, s, -1), rows[..., None, :])
+
+
+@jax.custom_vjp
+def _channel_kernel_rule(q, k, v, g, beta):
+    """The rule with a decay a key channel over whole chunks of 64 by
+    the Pallas kernels (``ops/pallas_kda.py``): q, k [B, S, H, Dk] and v
+    [B, S, H, Dv] of one dtype, g [B, S, H, Dk] and beta [B, S, H]
+    float32. Returns o [B, S, H, Dv]. XLA makes the triangular inverse
+    between the pairs and the walk and nothing else. What it keeps for
+    its backward is its five operands."""
+    dk, dv = k.shape[-1], v.shape[-1]
+    qf, kf, vf, gf, rows = _channel_operands(q, k, v, g, beta)
+    a, scores = pallas_kda.pairs(qf, kf, gf, rows, dk)
+    return pallas_kda.forward(
+        qf, kf, vf, gf, rows, unit_lower_inverse(a), scores, dk, dv
+    ).reshape(v.shape)
+
+
+def _channel_kernel_rule_fwd(q, k, v, g, beta):
+    return _channel_kernel_rule(q, k, v, g, beta), (q, k, v, g, beta)
+
+
+def _channel_kernel_rule_bwd(operands, do):
+    q, k, v, g, beta = operands
+    dk, dv = k.shape[-1], v.shape[-1]
+    qf, kf, vf, gf, rows = _channel_operands(*operands)
+    a, scores = pallas_kda.pairs(qf, kf, gf, rows, dk)
+    t, pull = jax.vjp(unit_lower_inverse, a)
+    dq, dk_walk, dval, dgamma, dbeta, dt, dscores = pallas_kda.backward(
+        qf, kf, vf, gf, rows, t, scores,
+        do.reshape(do.shape[:2] + (-1,)), dk, dv,
+    )
+    # T's cotangent through the inverse, then the pairs' back
+    dq, dkey, dg, dbeta = pallas_kda.pairs_backward(
+        qf, kf, gf, rows, *pull(dt), dscores, dq, dk_walk, dgamma, dbeta,
+        dk,
+    )
+    return (
+        dq.reshape(q.shape), dkey.reshape(k.shape), dval.reshape(v.shape),
+        dg.reshape(g.shape),
+        jnp.moveaxis(dbeta[..., 0, :], 2, 3).reshape(beta.shape),
+    )
+
+
+_channel_kernel_rule.defvjp(
+    _channel_kernel_rule_fwd, _channel_kernel_rule_bwd
+)
+
+
 def in_kernels(dk: int, dv: int, chunk: int = 64, mesh=None,
                per_channel: bool = False) -> bool:
     """Whether ``gated_delta_rule`` runs the Pallas kernels at these
     widths (``pallas_gated_delta.tile``): what the counters
-    ``gdn.kernel_layers`` and ``kda.kernel_layers`` count by. A decay a
-    key channel (``per_channel``) has the XLA body alone."""
-    return not per_channel and pallas_gated_delta.tile(dk, dv, chunk, mesh)
+    ``gdn.kernel_layers`` and ``kda.kernel_layers`` count by. The two
+    rules' kernels (``per_channel``: ``ops/pallas_kda.py``'s) take the
+    same calls: a TPU or interpreted, one device, channels on the
+    128-lane grid, chunks of 64."""
+    del per_channel
+    return pallas_gated_delta.tile(dk, dv, chunk, mesh)
 
 
 # tokens a stretch of the rule with a decay a key channel holds: see
@@ -632,8 +725,10 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = 64,
     128-lane grid and chunks of 64, the Pallas kernels ``gdn_fwd`` /
     ``gdn_states`` / ``gdn_bwd``, which take no stretch; anywhere else —
     the CPU, other widths, a mesh of several devices — the XLA body,
-    stretches and all. One decay a channel: the XLA body in sub-blocks
-    (``_channel_pairs``), everywhere."""
+    stretches and all. One decay a channel: the same choice between
+    ``ops/pallas_kda.py``'s kernels (``kda_pairs``, ``kda_fwd`` /
+    ``kda_states`` / ``kda_bwd``, ``kda_pairs_bwd``) and the XLA body in
+    sub-blocks (``_channel_pairs``, ``_channel_stretch``)."""
     b, s, hk, dk = k.shape
     hv, dv = v.shape[2:]
     if hv % hk:
@@ -670,9 +765,12 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = 64,
         # a head a value head: shared keys are repeated
         if r > 1:
             q, k = (jnp.repeat(t, r, axis=2) for t in (q, k))
-        o = _chunked(
-            q, k, v, g, beta, chunk, stretch, body=_channel_stretch
-        )
+        if kernels:
+            o = _channel_kernel_rule(q, k, v, g, beta)
+        else:
+            o = _chunked(
+                q, k, v, g, beta, chunk, stretch, body=_channel_stretch
+            )
     else:
         operands = (
             q, k, v.reshape(b, s + pad, hk, r, dv),

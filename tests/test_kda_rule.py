@@ -6,7 +6,9 @@ that is no multiple of the chunk, with channels whose running sum
 passes -100 inside a chunk; a ``g`` equal over a head's channels against
 the rule with one decay a head; that no exponent above 0 is taken and
 no [C, C, Dk] array of a whole chunk formed; and that the shape of ``g``
-alone chooses."""
+alone chooses. Then the rule's other body, the Pallas kernels
+(``ops/pallas_kda.py``), interpreted, against the XLA body and the
+recurrence."""
 
 import jax
 import jax.numpy as jnp
@@ -14,6 +16,7 @@ import numpy as np
 import pytest
 
 from dlrover_tpu.ops import gated_delta as gd
+from dlrover_tpu.ops import pallas_attention
 
 rule = jax.jit(gd.gated_delta_rule, static_argnames=("chunk", "stretch"))
 recurrence = jax.jit(gd.recurrence)
@@ -216,4 +219,157 @@ def test_the_shape_of_g_alone_chooses_and_a_wrong_one_is_refused():
     assert text != vector
     with pytest.raises(ValueError, match="key channel"):
         gd.gated_delta_rule(q, k, v, g[..., :4], beta)
+    # (the plain CPU: no kernel takes the call)
     assert not gd.in_kernels(128, 128, per_channel=True)
+
+
+# --- the Pallas kernels, interpreted -----------------------------------
+
+F32, BF16 = jnp.float32, jnp.bfloat16
+KERNELS = ("kda_pairs", "kda_fwd", "kda_states", "kda_bwd", "kda_pairs_bwd")
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    """The kernels where a TPU would run them, by the Pallas interpreter
+    (a trace made before the switch is no trace of the kernels: every
+    test under it jits its own functions)."""
+    monkeypatch.setattr(pallas_attention, "INTERPRET", True)
+
+
+def _wide(s, **kw):
+    """Two heads of 128 key and 128 value channels, the smallest the
+    kernels take; the second head's fastest channels forget e^-4.5 a
+    token."""
+    return _inputs(s, **{"b": 1, "dk": 128, "dv": 128, **kw})
+
+
+# two chunks, so that the state and its cotangent cross a visit; three
+# with a length that is no multiple of the chunk; two sequences
+KERNEL_CASES = {
+    "two-chunks": lambda: _wide(128),
+    "padded-to-three": lambda: _wide(150),
+    "two-batches-one-head": lambda: _wide(128, b=2, hk=1),
+}
+
+
+def _value_and_grads(fn, args, w):
+    def loss(*a):
+        o = fn(*a).astype(F32)
+        return (o * w).sum(), o
+
+    (_, o), grads = jax.jit(
+        jax.value_and_grad(loss, range(5), has_aux=True)
+    )(*args)
+    return o, [g.astype(F32) for g in grads]
+
+
+def _held(got, others, tol, names="o"):
+    for name, a, *rest in zip(names, got, *others):
+        scale = float(jnp.max(jnp.abs(rest[-1])))
+        assert scale > 0, name
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        for other in rest:
+            np.testing.assert_allclose(
+                np.asarray(a) / scale, np.asarray(other) / scale, atol=tol,
+                err_msg=name,
+            )
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_kernels_are_the_xla_body_and_the_recurrence(interpreted, case):
+    """Values and all five gradients on float32 operands, at the
+    tolerance the XLA body is held to the recurrence (on the CPU that
+    body's products are float32 whole; the kernels' are three passes of
+    bf16 pieces, as on the chip). The fast channels' running sum passes
+    -100 inside every chunk: finite, values and gradients."""
+    args = KERNEL_CASES[case]()
+    b, s, h, dk = args[3].shape
+    pad = -s % 64
+    gamma = jnp.cumsum(
+        jnp.pad(args[3], ((0, 0), (0, pad), (0, 0), (0, 0))).reshape(
+            b, -1, 64, h, dk
+        ), axis=2,
+    )
+    assert float(gamma.min()) < -100.0
+    w = jax.random.normal(jax.random.key(9), args[2].shape)
+    assert gd.in_kernels(128, 128, per_channel=True)
+    got, got_grads = _value_and_grads(
+        lambda *a: gd.gated_delta_rule(*a), args, w
+    )
+    # the XLA body through its own entry, as the fallback runs it
+    xla, xla_grads = _value_and_grads(
+        lambda *a: gd.gated_delta_rule(
+            *a, mesh=jax.make_mesh((2,), ("dp",))
+        ), args, w,
+    )
+    want, want_grads = _value_and_grads(gd.recurrence, args, w)
+    assert got.shape == args[2].shape
+    _held([got], [[xla], [want]], 2e-5)
+    _held(got_grads, [xla_grads, want_grads], 2e-5, "qkvgβ")
+
+
+def test_kernels_on_bf16_operands_are_the_xla_bodys(interpreted):
+    """One pass on bf16 operands (g and β stay float32), held to the
+    XLA body on the same operands at bf16's tolerance."""
+    q, k, v, g, beta = _wide(128, fast=1.0)
+    args = (q.astype(BF16), k.astype(BF16), v.astype(BF16), g, beta)
+    w = jax.random.normal(jax.random.key(9), v.shape)
+    got, got_grads = _value_and_grads(
+        lambda *a: gd.gated_delta_rule(*a), args, w
+    )
+    xla, xla_grads = _value_and_grads(
+        lambda *a: gd.gated_delta_rule(
+            *a, mesh=jax.make_mesh((2,), ("dp",))
+        ), args, w,
+    )
+    _held([got], [[xla]], 2e-2)
+    _held(got_grads, [xla_grads], 2e-2, "qkvgβ")
+
+
+def test_equal_channels_in_the_kernels_are_the_scalar_rules(interpreted):
+    """``g`` equal over a head's channels goes through the VECTOR rule's
+    kernels (the shape chooses, never the values) and agrees with the
+    scalar rule's, values and the gradient in v."""
+    q, k, v, g, beta = _wide(128, fast=1.0)
+    one = g[..., 0]
+    spread = jnp.broadcast_to(one[..., None], g.shape)
+    text = str(jax.make_jaxpr(gd.gated_delta_rule)(q, k, v, spread, beta))
+    assert "name=kda_fwd" in text and "name=gdn_fwd" not in text
+    got = jax.jit(gd.gated_delta_rule)(q, k, v, spread, beta)
+    want = jax.jit(gd.gated_delta_rule)(q, k, v, one, beta)
+    scale = float(jnp.max(jnp.abs(want)))
+    np.testing.assert_allclose(
+        np.asarray(got) / scale, np.asarray(want) / scale, atol=2e-5
+    )
+
+
+@pytest.mark.parametrize(
+    "why", ["interpreted", "off-the-lanes", "several-devices",
+            "another-chunk"],
+)
+def test_what_the_call_sees_chooses_the_vector_rules_body(interpreted, why):
+    """``in_kernels(..., per_channel=True)`` is ``pallas_gated_delta.
+    tile``'s answer: true interpreted at heads of 128 on one device,
+    false off the 128 lanes, on a mesh of several devices and at a chunk
+    that is not the kernels' — and the traced program holds the five
+    kernels by name and no stretch under a checkpoint, or the XLA body's
+    stretches and no kernel."""
+    dk, kw = 128, {}
+    if why == "off-the-lanes":
+        dk = 64
+    elif why == "several-devices":
+        kw = {"mesh": jax.make_mesh((2,), ("dp",))}
+    elif why == "another-chunk":
+        kw = {"chunk": 32}
+    args = _inputs(128, b=1, hk=1, dk=dk, dv=dk)
+    taken = why == "interpreted"
+    assert gd.in_kernels(dk, dk, per_channel=True, **kw) == taken
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda *a: gd.gated_delta_rule(*a, stretch=64, **kw).sum(),
+        range(5),
+    ))(*args))
+    for name in KERNELS:
+        assert (f"name={name}" in text) == taken, name
+    assert ("remat2[" in text) != taken
+    assert ("pallas_call" in text) == taken

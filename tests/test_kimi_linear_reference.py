@@ -596,23 +596,38 @@ def test_cache_paths_refuse_the_model_by_name(model):
         )
 
 
-def test_counters_and_scopes_are_what_the_readers_read(model):
+@pytest.mark.parametrize(
+    "widths,interpret,kernel_layers",
+    [(128, True, 4), (128, False, 0), (8, True, 0)],
+    ids=["kernels", "off-the-chip", "other-widths"],
+)
+def test_counters_and_scopes_are_what_the_readers_read(
+    monkeypatch, widths, interpret, kernel_layers
+):
     """``kda.layers`` counts the pattern's KDA layers and
-    ``kda.kernel_layers`` those whose rule runs Pallas kernels — none: a
-    decay a key channel has the XLA body alone; the scopes ``kda.conv``,
-    ``kda.rule`` and ``kda.gate`` stand under the part's scope ``kda``."""
+    ``kda.kernel_layers`` those whose rule runs the Pallas kernels
+    (``ops/pallas_kda.py``): all 4 with heads of 128 where a TPU (here
+    the interpreter) would run them, none off the chip or at widths off
+    the 128 lanes — where the lowered text holds no kernel of the
+    rule's; the scopes ``kda.conv``, ``kda.rule`` and ``kda.gate`` stand
+    under the part's scope ``kda`` whichever body runs."""
     from dlrover_tpu.observability import tracing
+    from dlrover_tpu.ops import pallas_attention
 
-    cfg, params = model
+    monkeypatch.setattr(pallas_attention, "INTERPRET", interpret)
+    cfg = _cfg(kda_heads=2, kda_head_dim=widths)
+    params = jax.eval_shape(lambda: decoder.init(jax.random.key(0), cfg))
     tracing._counters.clear()
     lowered = jax.jit(
         lambda p, t: decoder.forward(p, t, cfg)
     ).lower(params, _batch()["tokens"])
     counters = tracing.counters()
     assert counters["kda.layers"] == 4
-    assert counters["kda.kernel_layers"] == 0
+    assert counters["kda.kernel_layers"] == kernel_layers
     assert "gdn.layers" not in counters
     text = lowered.as_text(debug_info=True)
+    assert ("kda_fwd" in text) == bool(kernel_layers)
+    assert "gdn_fwd" not in text
     for scope in ("kda/kda.conv/ssm.conv", "kda/kda.rule", "kda/kda.gate",
                   "attn/attn.latent"):
         assert scope in text, scope

@@ -572,34 +572,84 @@ def test_gated_delta_rule_compiles_a_stretch_at_a_time(topo, chip):
     assert not re.search(r"f32\[[\d,]*,16,\d+,16\]", text)
 
 
-def test_vector_delta_rule_compiles_a_stretch_at_a_time(chip):
-    """The delta rule with a decay a key channel at Kimi-Linear's widths
-    (32 heads of 128 key and 128 value channels, one sequence of 16,384,
-    chunks of 64 in sub-blocks of 16, float32 operands as the mixer
-    hands them over), forward and backward, for a described v5e: matmuls
-    and no kernel, and with each stretch of 1,024 tokens under its own
-    checkpoint the compiler counts 0.53 GB of temporaries (1.03 at
-    stretches of 2,048). No array holds a whole chunk's [64, 64, 128]
-    differences: the largest with two token axes and the channels is a
-    sub-block's [16, 16, 128]."""
-    import re
-
+def _vector_rule_args(chip):
+    """Kimi-Linear's rule: 32 heads of 128 key and 128 value channels,
+    one sequence of 16,384, float32 operands as the mixer hands them."""
     def struct(shape):
         return jax.ShapeDtypeStruct(shape, F32, sharding=chip)
 
-    s = 16384
-    wide = struct((1, s, 32, 128))
-    args = (wide, wide, wide, wide, struct((1, s, 32)))
-    assert not gated_delta.in_kernels(128, 128, per_channel=True)
+    wide = struct((1, 16384, 32, 128))
+    return wide, wide, wide, wide, struct((1, 16384, 32))
+
+
+def test_vector_delta_rule_compiles_a_stretch_at_a_time(topo, chip):
+    """The delta rule with a decay a key channel at Kimi-Linear's widths
+    (32 heads of 128 key and 128 value channels, one sequence of 16,384,
+    chunks of 64 in sub-blocks of 16, float32 operands as the mixer
+    hands them over), its XLA body (the op's fallback since PR 66),
+    forward and backward, for a described v5e: matmuls and no kernel,
+    and with each stretch of 1,024 tokens under its own checkpoint the
+    compiler counts 0.53 GB of temporaries (1.03 at stretches of 2,048).
+    No array holds a whole chunk's [64, 64, 128] differences: the
+    largest with two token axes and the channels is a sub-block's
+    [16, 16, 128]."""
+    import re
+
+    # the fallback's own entry: a mesh of several devices rules the
+    # kernels out
+    several = jax.sharding.Mesh(topo.devices[:2], ("dp",))
+    assert not gated_delta.in_kernels(
+        128, 128, mesh=several, per_channel=True
+    )
     loss = lambda *a: gated_delta.gated_delta_rule(  # noqa: E731
-        *a, chunk=64
+        *a, chunk=64, mesh=several
     ).astype(F32).sum()
-    compiled = jax.jit(jax.grad(loss, argnums=range(5))).lower(*args).compile()
+    compiled = jax.jit(jax.grad(loss, argnums=range(5))).lower(
+        *_vector_rule_args(chip)
+    ).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 0.7e9
     assert not re.search(r"f32\[[\d,]*64,64,128\]", text)
     assert re.search(r"f32\[[\d,]*16,16,128\]", text)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "bwd"])
+def test_vector_delta_rule_kernels_compile_for_v5e(chip, grad):
+    """The same rule on its kernels (``ops/pallas_kda.py``: what a v5e
+    runs at these widths on one device): the pairs and the walk going
+    forward; going back the pairs again, the state pass, the walk back
+    and the pairs' pull-back — no loop of the compiler's around a chunk
+    step and no stretch. What XLA holds around them, by the compiler's
+    count: 1.62 GB of temporaries forward (A, M and T, float32
+    [256, 32, 64, 64] each, the 64 padded to 128 lanes: 268 MB, and what
+    the substitution holds between A and T) and 2.97 GB going back
+    (those, the states every chunk starts from, 537 MB, the walk's parts
+    of dq, dk and dγ, 268 MB each, and dT, dM and dA), which the cell's
+    step fits beside its train state
+    (``test_kimi_cell_fits_the_chip``). Neither a sub-block's
+    [16, 16, 128] nor a chunk's [64, 64, 128] differences exist as an
+    array."""
+    import re
+
+    assert gated_delta.in_kernels(128, 128, per_channel=True)
+    fwd = lambda *a: gated_delta.gated_delta_rule(*a)  # noqa: E731
+    fn = jax.grad(
+        lambda *a: fwd(*a).astype(F32).sum(), argnums=range(5)
+    ) if grad else fwd
+    compiled = jax.jit(fn).lower(*_vector_rule_args(chip)).compile()
+    text = compiled.as_text()
+    names = (
+        ("kda_pairs", "kda_states", "kda_bwd", "kda_pairs_bwd") if grad
+        else ("kda_pairs", "kda_fwd")
+    )
+    assert text.count("tpu_custom_call") == len(names)
+    for name in names:
+        assert re.search(rf"%\w*{name}[_.\d]* = ", text), name
+    assert "while(" not in text and " while " not in text
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < (3.3e9 if grad else 1.8e9)
+    assert not re.search(r"f32\[[\d,]*(16,16|64,64),128\]", text)
 
 
 @pytest.mark.parametrize(
@@ -1199,12 +1249,16 @@ def test_kimi_cell_fits_the_chip(topo):
     layers 1-5 — a KDA mixer and the dense MLP, then KDA, KDA, latent
     attention, KDA with sixteen held experts each — one sequence of
     16,384 tokens) compiles for a described v5e under the chip's 15.75
-    GiB (16.91 GB): the vector rule a stretch of 1,024 tokens at a time,
+    GiB (16.91 GB; 14.76 GiB by this count since PR 66, 14.08 before):
+    the vector rule as its kernels in every KDA layer (``ops/
+    pallas_kda.py``: under ``remat: full`` the pairs and the forward
+    walk twice a layer — the layer's and the remade one, whose pairs the
+    backward rule shares —, the state pass, the walk back and the pairs'
+    pull-back once; no stretch and none of the scalar rule's kernels),
     the latent layer's three flash kernels at 192 channels with the
     backward's tile of 1024 x 512 (1024 x 1024 asks 17.5 MB of VMEM's
     16 at a head and a half of lanes), its output kept, the convs as
-    kernels, and no kernel of the rule's: a decay a key channel has the
-    XLA body alone. No array holds a whole chunk's [64, 64, 128]
+    kernels. No array holds a whole chunk's [64, 64, 128]
     differences."""
     import json
     import pathlib
@@ -1234,7 +1288,12 @@ def test_kimi_cell_fits_the_chip(topo):
         6 * 828_925_824, rel=1e-3  # bf16 parameters and two moments
     )
     assert counters["kda.layers"] == 4
-    assert counters["kda.kernel_layers"] == 0
+    assert counters["kda.kernel_layers"] == 4
+    for kernel, calls in (
+        ("kda_pairs", 8), ("kda_fwd", 8), ("kda_states", 4),
+        ("kda_bwd", 4), ("kda_pairs_bwd", 4),
+    ):
+        assert _kernel_calls(text, kernel) == calls, kernel
     assert counters["attn.output_kept"] == 1
     assert counters["ssm.conv_in_kernel"] == 1
     assert _kernel_calls(text, "flash_fwd") == 1
@@ -1242,6 +1301,7 @@ def test_kimi_cell_fits_the_chip(topo):
     assert _kernel_calls(text, "flash_bwd_dkv") == 1
     assert "bf16[32,16384,192]" in text
     assert "gdn_fwd" not in text and "gdn_bwd" not in text
+    assert "gdn_states" not in text
     assert not re.search(r"f32\[[\d,]*64,64,128\]", text)
 
 
