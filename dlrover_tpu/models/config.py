@@ -7,7 +7,7 @@ BASELINE.md #3-#11), plus small configs for tests and CI.
 
 import re
 from dataclasses import dataclass, field, replace
-from typing import Optional, Tuple
+from typing import Callable, Optional, Tuple
 
 
 @dataclass(frozen=True)
@@ -179,25 +179,16 @@ class ModelConfig:
     index_chunk: int = 512
     # a trunk made of PARTS, x <- x + part(norm(x)) each
     # (NemotronH's ``hybrid_override_pattern``; "" = every layer an
-    # attention and an MLP): one letter a part, ``M`` a Mamba-2 mixer,
-    # ``m`` a Mamba-1 mixer, ``*`` an attention, ``S`` a block-sparse
-    # attention (``sparse_block``; no rope), ``L`` a lightning linear
-    # attention (``n_head`` heads of their own k and v, rope, a fixed
-    # decay a head, a norm over the whole read-out and a gate on it),
-    # ``G`` a gated-delta-rule mixer (``gdn_*``), ``K`` a delta-rule
-    # mixer whose decay is a vector over the key channels (KDA;
-    # ``kda_*``), ``E`` the routed experts, ``-`` a dense MLP of
-    # ``d_ff``. The ``*`` of a model with ``kv_lora_rank`` is latent
-    # attention. A ``-`` that follows
-    # another part is the second part of that part's LAYER (a mixer +
-    # MLP layer, pre-norm twice: ``m-``, ``*-``), and so is an ``e``,
-    # the ROUTED experts as a layer's second part (a mixer + routed
-    # layer: ``Ge``, ``*e``; one pattern routes by ``E`` or by ``e``,
-    # not both; a leading dense-MLP layer beside routed ones is spelled
-    # ``K-Ke``, not ``n_dense_layer``); every other letter is a layer by
-    # itself, and ``n_layer`` counts layers. Parameters are
-    # stacked kind by kind and visited in this order. ``mtp_pattern``
-    # is the prediction module's layers, likewise. Training path only
+    # attention and an MLP): one letter a part, the kinds and what each
+    # asks in ``PART_RULES`` below (``S`` runs without rope). A ``-``
+    # that follows another part is the second part of that part's LAYER
+    # (a mixer + MLP layer, pre-norm twice: ``m-``, ``*-``), and so is an
+    # ``e``, the ROUTED experts as a layer's second part (``Ge``, ``*e``;
+    # a leading dense-MLP layer beside routed ones is spelled ``K-Ke``,
+    # not ``n_dense_layer``); every other letter is a layer by itself,
+    # and ``n_layer`` counts layers. Parameters are stacked kind by kind
+    # and visited in this order. ``mtp_pattern`` is the prediction
+    # module's layers, likewise. Training path only
     layer_pattern: str = ""
     mtp_pattern: str = ""
     # the Mamba-2 mixer (``M``): ``mamba_num_heads`` heads of
@@ -546,18 +537,15 @@ class ModelConfig:
                 )
 
     def _check_pattern(self):
-        """A ``layer_pattern`` model: what its letters need."""
+        """What each of a ``layer_pattern`` model's kinds needs
+        (``PART_RULES``), then what ties two kinds or the whole model."""
         for name in ("layer_pattern", "mtp_pattern"):
-            odd = set(getattr(self, name)) - set("Mm*SLGKEe-")
+            odd = set(getattr(self, name)) - set(PART_RULES)
             if odd:
+                kinds = [f"{c} ({r.what})" for c, r in PART_RULES.items()]
                 raise ValueError(
-                    f"{name} is made of M (Mamba-2), m (Mamba-1), * "
-                    f"(attention; latent attention where kv_lora_rank is "
-                    f"set), S (block-sparse attention), L (lightning "
-                    f"attention), G (gated delta rule), K (delta rule "
-                    f"with a decay a key channel, KDA), E (routed "
-                    f"experts), e (routed experts, a layer's second "
-                    f"part) and - (dense MLP); got {sorted(odd)}"
+                    f"{name} is made of {', '.join(kinds[:-1])} and "
+                    f"{kinds[-1]}; got {sorted(odd)}"
                 )
         if pattern_layers(self.layer_pattern) != self.n_layer:
             raise ValueError(
@@ -570,103 +558,24 @@ class ModelConfig:
                 "mtp_pattern: both or neither"
             )
         letters = self.layer_pattern + self.mtp_pattern
-        if "M" in letters:
-            sizes = (
-                self.mamba_num_heads, self.mamba_head_dim,
-                self.ssm_state_size, self.n_groups, self.conv_kernel,
-                self.ssm_chunk,
-            )
-            if not all(n > 0 for n in sizes) or (
-                self.mamba_num_heads % self.n_groups
-            ):
+        for letter, rule in PART_RULES.items():
+            if rule.trunk_only and letter in self.mtp_pattern:
                 raise ValueError(
-                    "a Mamba-2 layer needs mamba_num_heads (a multiple "
-                    "of n_groups), mamba_head_dim, ssm_state_size, "
-                    "conv_kernel and ssm_chunk"
+                    f"{letter} parts are the trunk's: a prediction module's "
+                    "selection or read-out is handed over by no one"
                 )
-        if "m" in letters and not all(
-            n > 0 for n in (
-                self.mamba_expand, self.mamba_dt_rank, self.ssm_state_size,
-                self.conv_kernel,
-            )
-        ):
-            raise ValueError(
-                "a Mamba-1 part needs mamba_expand, mamba_dt_rank, "
-                "ssm_state_size and conv_kernel"
-            )
-        if set("SLGK") & set(self.mtp_pattern):
-            raise ValueError(
-                "S, L, G and K parts are the trunk's: a prediction "
-                "module's selection or read-out is handed over by no one"
-            )
-        if "G" in letters:
-            sizes = (
-                self.gdn_key_heads, self.gdn_value_heads, self.gdn_key_dim,
-                self.gdn_value_dim, self.conv_kernel,
-            )
-            if not all(n > 0 for n in sizes) or (
-                self.gdn_value_heads % self.gdn_key_heads
-            ):
-                raise ValueError(
-                    "a G part needs gdn_key_heads, gdn_value_heads (a "
-                    "multiple of them), gdn_key_dim, gdn_value_dim, "
-                    "and conv_kernel"
-                )
-        if "K" in letters and not all(
-            n > 0 for n in (
-                self.kda_heads, self.kda_head_dim, self.kda_gate_rank,
-                self.conv_kernel,
-            )
-        ):
-            raise ValueError(
-                "a K part needs kda_heads, kda_head_dim, kda_gate_rank "
-                "and conv_kernel"
-            )
-        if "S" in letters:
-            sizes = (
-                self.sparse_block, self.pool_window, self.pool_stride,
-                self.index_topk, self.select_local,
-            )
-            if not all(n > 0 for n in sizes) or self.select_init_blocks < 0:
-                raise ValueError(
-                    "an S part needs sparse_block, pool_window, "
-                    "pool_stride, index_topk and select_local"
-                )
-            if (
-                self.sparse_block % self.pool_stride
-                or self.pool_window % self.pool_stride
-                or self.select_local % self.sparse_block
-                or self.index_topk
-                < self.select_init_blocks
-                + self.select_local // self.sparse_block
-            ):
-                raise ValueError(
-                    "pool_stride divides pool_window and sparse_block, "
-                    "sparse_block divides select_local, and the forced "
-                    "blocks (select_init_blocks and the local window's) "
-                    "fit in index_topk"
-                )
-            if not self.causal or self.attn_window:
-                raise ValueError(
-                    "a selection of blocks runs under the plain causal mask"
-                )
-        elif self.index_topk or self.sparse_block:
+            if letter not in letters:
+                continue
+            sized = all(getattr(self, name) > 0 for name in rule.needs)
+            if not sized or (rule.unless and rule.unless(self)):
+                raise ValueError(rule.refusal)
+            for wrong, refusal in rule.also:
+                if wrong(self):
+                    raise ValueError(refusal)
+        if "S" not in letters and (self.index_topk or self.sparse_block):
             raise ValueError(
                 "index_topk and sparse_block in a layer_pattern model are "
                 "its S parts'"
-            )
-        if "L" in letters and self.head_dim % 2:
-            raise ValueError("an L part turns q and k by rope: an even head")
-        if "-" in letters and self.act not in ("swiglu", "gelu"):
-            raise ValueError(
-                "a - part is the dense MLP of d_ff: act 'swiglu' or 'gelu'"
-            )
-        if set("Ee") & set(letters) and not (
-            self.n_experts and self.moe_impl == "ragged"
-        ):
-            raise ValueError(
-                "an E layer is the ragged (dropless) routed block: "
-                "n_experts > 0 and moe_impl='ragged'"
             )
         if "E" in letters and "e" in letters:
             raise ValueError(
@@ -887,24 +796,10 @@ class ModelConfig:
         """Why the cache, paged, pipeline and generate paths cannot run
         this model ("" where they can): they are written for one stack
         of plain-attention layers."""
-        if set("Mm") & set(self.layer_pattern + self.mtp_pattern):
-            return "state-space layers: no recurrent state beside the cache"
-        if "L" in self.layer_pattern:
-            return (
-                "lightning (L) layers: no recurrent state beside the cache"
-            )
-        if "G" in self.layer_pattern:
-            return (
-                "gated-delta-rule (G) layers: no recurrent state beside "
-                "the cache"
-            )
-        if "K" in self.layer_pattern:
-            return (
-                "delta-rule layers with a decay a key channel (K): no "
-                "recurrent state beside the cache"
-            )
-        if "S" in self.layer_pattern:
-            return "block-sparse (S) layers: a selection has no cache path"
+        letters = self.layer_pattern + self.mtp_pattern
+        for letter, rule in PART_RULES.items():
+            if rule.train_only and letter in letters:
+                return rule.train_only
         if self.layer_pattern:
             return "a trunk whose layers differ"
         if self.latent_attention:
@@ -1160,6 +1055,107 @@ class ModelConfig:
                 self.executed_span(seq_len, kind) for kind in self.layer_types
             )
         return 6.0 * multiplied + 12.0 * self.n_attention_layers * pairs
+
+
+@dataclass(frozen=True)
+class PartRule:
+    """What ``ModelConfig`` asks of one kind of ``layer_pattern`` part
+    (what the trunk does with it: ``models/decoder.py::PARTS``; what it
+    counts: ``ModelConfig._part_counts``)."""
+
+    what: str  # the kind in a few words, as the refusal of a letter lists it
+    # fields that must be positive and what must NOT hold beside them
+    # (asked only where they are), else ``refusal``
+    needs: Tuple[str, ...] = ()
+    unless: Optional[Callable] = None
+    refusal: str = ""
+    also: tuple = ()  # ((what must not hold, its refusal), ...) behind those
+    trunk_only: bool = False  # not a part of a prediction module
+    # why the cache paths refuse a model with such a part ("": as a trunk
+    # whose layers differ); the first kind of the table a model has speaks
+    train_only: str = ""
+
+
+_STATE_SPACE = "state-space layers: no recurrent state beside the cache"
+_ROUTED = dict(
+    unless=lambda c: not (c.n_experts and c.moe_impl == "ragged"),
+    refusal="an E layer is the ragged (dropless) routed block: "
+    "n_experts > 0 and moe_impl='ragged'",
+)
+# a ``layer_pattern`` letter -> its rule, in the order the checks and
+# ``train_only`` go by
+PART_RULES = {
+    "M": PartRule(
+        "Mamba-2", train_only=_STATE_SPACE,
+        needs=("mamba_num_heads", "mamba_head_dim", "ssm_state_size",
+               "n_groups", "conv_kernel", "ssm_chunk"),
+        unless=lambda c: c.mamba_num_heads % c.n_groups,
+        refusal="a Mamba-2 layer needs mamba_num_heads (a multiple of "
+        "n_groups), mamba_head_dim, ssm_state_size, conv_kernel and "
+        "ssm_chunk",
+    ),
+    "m": PartRule(
+        "Mamba-1", train_only=_STATE_SPACE,
+        needs=("mamba_expand", "mamba_dt_rank", "ssm_state_size",
+               "conv_kernel"),
+        refusal="a Mamba-1 part needs mamba_expand, mamba_dt_rank, "
+        "ssm_state_size and conv_kernel",
+    ),
+    "*": PartRule("attention; latent attention where kv_lora_rank is set"),
+    "L": PartRule(
+        "lightning attention", trunk_only=True,
+        unless=lambda c: c.head_dim % 2,
+        refusal="an L part turns q and k by rope: an even head",
+        train_only="lightning (L) layers: no recurrent state beside the "
+        "cache",
+    ),
+    "G": PartRule(
+        "gated delta rule", trunk_only=True,
+        needs=("gdn_key_heads", "gdn_value_heads", "gdn_key_dim",
+               "gdn_value_dim", "conv_kernel"),
+        unless=lambda c: c.gdn_value_heads % c.gdn_key_heads,
+        refusal="a G part needs gdn_key_heads, gdn_value_heads (a multiple "
+        "of them), gdn_key_dim, gdn_value_dim, and conv_kernel",
+        train_only="gated-delta-rule (G) layers: no recurrent state beside "
+        "the cache",
+    ),
+    "K": PartRule(
+        "delta rule with a decay a key channel, KDA", trunk_only=True,
+        needs=("kda_heads", "kda_head_dim", "kda_gate_rank", "conv_kernel"),
+        refusal="a K part needs kda_heads, kda_head_dim, kda_gate_rank and "
+        "conv_kernel",
+        train_only="delta-rule layers with a decay a key channel (K): no "
+        "recurrent state beside the cache",
+    ),
+    "S": PartRule(
+        "block-sparse attention", trunk_only=True,
+        needs=("sparse_block", "pool_window", "pool_stride", "index_topk",
+               "select_local"),
+        unless=lambda c: c.select_init_blocks < 0,
+        refusal="an S part needs sparse_block, pool_window, pool_stride, "
+        "index_topk and select_local",
+        also=(
+            (lambda c: (
+                c.sparse_block % c.pool_stride
+                or c.pool_window % c.pool_stride
+                or c.select_local % c.sparse_block
+                or c.index_topk
+                < c.select_init_blocks + c.select_local // c.sparse_block
+            ), "pool_stride divides pool_window and sparse_block, "
+             "sparse_block divides select_local, and the forced blocks "
+             "(select_init_blocks and the local window's) fit in index_topk"),
+            (lambda c: not c.causal or c.attn_window,
+             "a selection of blocks runs under the plain causal mask"),
+        ),
+        train_only="block-sparse (S) layers: a selection has no cache path",
+    ),
+    "E": PartRule("routed experts", **_ROUTED),
+    "e": PartRule("routed experts, a layer's second part", **_ROUTED),
+    "-": PartRule(
+        "dense MLP", unless=lambda c: c.act not in ("swiglu", "gelu"),
+        refusal="a - part is the dense MLP of d_ff: act 'swiglu' or 'gelu'",
+    ),
+}
 
 
 def pattern_parts(pattern: str):

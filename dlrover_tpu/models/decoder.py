@@ -19,8 +19,10 @@ One functional model covers the GPT-2 and LLaMA families (configs in
 """
 
 import contextlib
+import dataclasses
 import functools
-from typing import Any, Dict, Optional, Tuple
+import types
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -33,9 +35,9 @@ from dlrover_tpu.models.config import (
     ModelConfig, lightning_log_decay, pattern_parts,
 )
 from dlrover_tpu.observability.tracing import set_counter
-from dlrover_tpu.ops import pallas_norm, pallas_paged, quant
+from dlrover_tpu.ops import gated_delta, pallas_norm, pallas_paged, quant
 from dlrover_tpu.ops.attention import _repeat_kv, mha_reference
-from dlrover_tpu.parallel import sharding as shd
+from dlrover_tpu.parallel import moe, sharding as shd
 
 Params = Dict[str, Any]
 
@@ -129,9 +131,7 @@ def _init_layers(keys, cfg: ModelConfig, lead, routed: bool) -> Params:
         "ln2": {"scale": ones(d)},
     }
     if routed:
-        from dlrover_tpu.parallel.moe import init_moe_params
-
-        layers["moe"] = init_moe_params(keys[10], cfg, lead)
+        layers["moe"] = moe.init_moe_params(keys[10], cfg, lead)
     else:
         layers["mlp"] = _init_mlp(keys, cfg, stack)
     if cfg.qk_norm:
@@ -159,36 +159,22 @@ def _init_layers(keys, cfg: ModelConfig, lead, routed: bool) -> Params:
     return layers
 
 
-# a ``layer_pattern`` letter -> the name its stack of parts goes by:
-# M a Mamba-2 mixer, m a Mamba-1 mixer, * an attention, S a block-sparse
-# attention, L a lightning linear attention, G a gated-delta-rule mixer,
-# K a delta-rule mixer with a decay a key channel (KDA), E the routed
-# experts (e: as a layer's second part; a pattern has one of the two),
-# - a dense MLP
-PART_NAMES = {
-    "M": "mamba", "m": "mamba1", "*": "attention", "S": "sparse",
-    "L": "lightning", "G": "gdn", "K": "kda", "E": "experts",
-    "e": "experts", "-": "mlp",
-}
-
-
 def _pattern_runs(pattern: str):
     """``pattern`` cut into runs, in order: [(unit, repeats)], ``unit``
     a string of whole layers (``config.pattern_parts``). A run of
     repeats > 1 goes through ``lax.scan``: at each layer, the SHORTEST
     unit of layers that repeats at least once more at once, all its
     repeats; a layer that starts no such unit runs unrolled (repeats 1).
-    A unit with a routed part (``E``, ``e``) never qualifies: its choices
-    ride out layer by layer and its jitter folds the layer's index in; nor
-    one with a block-sparse attention (``S``), whose selection rides out
-    likewise."""
+    A unit with a part that ``rides_out`` (``PARTS``: a routed part,
+    whose jitter folds the layer's index in besides, or a block-sparse
+    attention) never qualifies."""
     layers = pattern_parts(pattern)
     runs, i = [], 0
     while i < len(layers):
         unit, reps = layers[i:i + 1], 1
         for p in range(1, (len(layers) - i) // 2 + 1):
             cand = layers[i:i + p]
-            if set("EeS") & set("".join(cand)):
+            if any(PARTS[c].rides_out for c in "".join(cand)):
                 break
             n = 1
             while layers[i + n * p:i + (n + 1) * p] == cand:
@@ -208,7 +194,7 @@ def _scanned_parts(pattern: str) -> int:
 
 def _pattern_stacks(pattern: str):
     """The stacks ``pattern``'s parameters are kept in, [(name, letter,
-    parts)]: each kind by itself under its ``PART_NAMES`` name, and a
+    parts)]: each kind by itself under its name (``PARTS``), and a
     kind whose parts lie in several scanned runs, or in a run and
     outside it, a stack a stretch (``mlp``, ``mlp.1``, ``mlp.2``), so
     that a run scans WHOLE stacks. A slice of a stack handed to
@@ -225,7 +211,7 @@ def _pattern_stacks(pattern: str):
             else:
                 mine.append([n, reps > 1])
     return [
-        (PART_NAMES[letter] + (f".{i}" if i else ""), letter, n)
+        (PARTS[letter].stack + (f".{i}" if i else ""), letter, n)
         for letter in sorted(stretches)
         for i, (n, _) in enumerate(stretches[letter])
     ]
@@ -423,42 +409,26 @@ def _init_mlp(keys, cfg: ModelConfig, stack) -> Params:
     return mlp
 
 
+def _init_attention_part(key, cfg: ModelConfig, lead) -> Params:
+    """An attention part's matrices and its q and k norms a head."""
+    keys = jax.random.split(key, 16)
+    attn = _init_attention(keys, cfg, *_stackers(cfg, lead))
+    if cfg.qk_head_norm:
+        for which in ("q_norm", "k_norm"):
+            attn[which] = {"scale": _norm_scale(cfg, *lead, cfg.head_dim)}
+    return attn
+
+
 def _init_pattern(key, cfg: ModelConfig, pattern: str) -> Params:
     """The parts ``pattern`` names, stacked kind by kind
-    (``_pattern_stacks``): one norm and one part each."""
-    out: Params = {}
-    for i, (name, letter, n) in enumerate(_pattern_stacks(pattern)):
-        lead = (n,)
-        kk = jax.random.fold_in(key, i)
-        stack, ones = _stackers(cfg, lead)
-        layer: Params = {"ln": {"scale": _norm_scale(cfg, n, cfg.d_model)}}
-        if letter == "M":
-            layer["ssm"] = _init_mamba(kk, cfg, lead)
-        elif letter == "m":
-            layer["ssm1"] = _init_mamba1(kk, cfg, lead)
-        elif letter in "*S":
-            layer["attn"] = _init_attention(
-                jax.random.split(kk, 16), cfg, stack, ones
-            )
-            if cfg.qk_head_norm:
-                for which in ("q_norm", "k_norm"):
-                    layer["attn"][which] = {
-                        "scale": _norm_scale(cfg, n, cfg.head_dim)
-                    }
-        elif letter == "L":
-            layer["lin"] = _init_lightning(kk, cfg, lead)
-        elif letter == "G":
-            layer["gdn"] = _init_gdn(kk, cfg, lead)
-        elif letter == "K":
-            layer["kda"] = _init_kda(kk, cfg, lead)
-        elif letter == "-":
-            layer["mlp"] = _init_mlp(jax.random.split(kk, 16), cfg, stack)
-        else:
-            from dlrover_tpu.parallel.moe import init_moe_params
-
-            layer["moe"] = init_moe_params(kk, cfg, lead)
-        out[name] = layer
-    return out
+    (``_pattern_stacks``): one norm and one part (``PARTS``) each."""
+    return {
+        name: {
+            "ln": {"scale": _norm_scale(cfg, n, cfg.d_model)},
+            PARTS[c].key: PARTS[c].init(jax.random.fold_in(key, i), cfg, (n,)),
+        }
+        for i, (name, c, n) in enumerate(_pattern_stacks(pattern))
+    }
 
 
 def init(rng: jax.Array, cfg: ModelConfig) -> Params:
@@ -520,7 +490,7 @@ def init(rng: jax.Array, cfg: ModelConfig) -> Params:
 
 
 def _attention_axes(cfg: ModelConfig, lead) -> Params:
-    """Logical axes of ``_init_attention``'s matrices."""
+    """Logical axes of an attention's matrices and its q and k norms."""
     if cfg.latent_attention:
         q_mats = {
             "wq_a": lead + ("embed", None),
@@ -543,6 +513,9 @@ def _attention_axes(cfg: ModelConfig, lead) -> Params:
         }
         if cfg.attn_gate:
             attn["wg"] = lead + ("embed", "heads")
+    if cfg.qk_norm or cfg.qk_head_norm:
+        attn["q_norm"] = {"scale": lead + ("norm",)}
+        attn["k_norm"] = {"scale": lead + ("norm",)}
     return attn
 
 
@@ -557,85 +530,194 @@ def _mlp_axes(cfg: ModelConfig, lead) -> Params:
     return ax
 
 
+def _mamba_axes(cfg: ModelConfig, lead) -> Params:
+    """Logical axes of ``_init_mamba``'s tree."""
+    return {
+        "w_in": lead + ("embed", "mlp"),
+        "conv_w": lead + (None, "mlp"),
+        "conv_b": lead + ("mlp",),
+        **{k: lead + (None,) for k in ("a_log", "dt_bias", "d_skip")},
+        "norm": {"scale": lead + ("norm",)},
+        "w_out": lead + ("mlp", "embed"),
+    }
+
+
+def _mamba1_axes(cfg: ModelConfig, lead) -> Params:
+    """Logical axes of ``_init_mamba1``'s tree."""
+    return {
+        "w_in": lead + ("embed", "mlp"),
+        "conv_w": lead + (None, "mlp"),
+        "conv_b": lead + ("mlp",),
+        "w_x": lead + ("mlp", None),
+        "dt_norm": {"scale": lead + ("norm",)},
+        "b_norm": {"scale": lead + ("norm",)},
+        "c_norm": {"scale": lead + ("norm",)},
+        "w_dt": lead + (None, "mlp"),
+        "dt_bias": lead + ("mlp",),
+        "a_log": lead + ("mlp", None),
+        "d_skip": lead + ("mlp",),
+        "w_out": lead + ("mlp", "embed"),
+    }
+
+
+def _lightning_axes(cfg: ModelConfig, lead) -> Params:
+    """Logical axes of ``_init_lightning``'s tree."""
+    return {
+        **{w: lead + ("embed", "heads") for w in ("wq", "wk", "wv", "wg")},
+        "wo": lead + ("heads", "embed"),
+        **{
+            n: {"scale": lead + ("norm",)}
+            for n in ("q_norm", "k_norm", "o_norm")
+        },
+    }
+
+
+def _gdn_axes(cfg: ModelConfig, lead) -> Params:
+    """Logical axes of ``_init_gdn``'s tree."""
+    return {
+        "w_qkvz": lead + ("embed", "mlp"),
+        "w_ba": lead + ("embed", None),
+        "conv_w": lead + (None, "mlp"),
+        "a_log": lead + (None,),
+        "dt_bias": lead + (None,),
+        "norm": {"scale": lead + ("norm",)},
+        "w_out": lead + ("mlp", "embed"),
+    }
+
+
+def _kda_axes(cfg: ModelConfig, lead) -> Params:
+    """Logical axes of ``_init_kda``'s tree."""
+    return {
+        "w_qkv": lead + ("embed", "mlp"),
+        "w_gates": lead + ("embed", None),
+        "conv_w": lead + (None, "mlp"),
+        "w_fb": lead + (None, "mlp"),
+        "w_gb": lead + (None, "mlp"),
+        "a_log": lead + (None,),
+        "dt_bias": lead + ("mlp",),
+        "norm": {"scale": lead + ("norm",)},
+        "w_out": lead + ("mlp", "embed"),
+    }
+
+
+@dataclasses.dataclass(frozen=True)
+class PartKind:
+    """One kind of ``layer_pattern`` part: all the trunk knows of a
+    letter (what ``ModelConfig`` asks of it: ``config.PART_RULES``; what
+    it counts: ``ModelConfig._part_counts``)."""
+
+    stack: str  # the name its stack of parts goes by in ``layers``
+    key: str  # its subtree of a layer, beside the norm's ``ln``
+    init: Callable  # (key, cfg, lead) -> the subtree, stacked on ``lead``
+    axes: Callable  # (cfg, lead) -> the subtree's logical axes
+    # (h, the layer, c: what ``_part_body`` was given) -> (out, aux). It
+    # NAMES its block: one patched on the module (a planted defect) runs
+    run: Callable
+    scope: Optional[str] = None  # what it is traced under, where not ``key``
+    read: Optional[str] = None  # the number in its aux the trunk reports
+    # (cfg, mesh, n: the trunk's parts of the kind) -> {counter: value}
+    counters: Optional[Callable] = None
+    rides_out: bool = False  # its aux rides out part by part: never scanned
+    wants_rope: bool = False  # turns q and k by the trunk's rope tables
+    selects: bool = False  # its aux is a selection (``attn_selected``)
+
+
+def _rule_layers(name: str, n: int, mesh, *dims, **how):
+    """A delta-rule kind's layers and, all or none, its kernels' layers."""
+    kernels = gated_delta.in_kernels(*dims, mesh=mesh, **how)
+    return {f"{name}.layers": n, f"{name}.kernel_layers": n * int(kernels)}
+
+
+_ROUTED = PartKind(
+    stack="experts", key="moe", scope="mlp",
+    init=moe.init_moe_params, axes=moe.moe_logical_axes,
+    run=lambda h, p, c: moe.moe_block(
+        h, p["moe"], c.cfg, c.mesh, rng=c.rng, return_aux=True
+    ),
+    rides_out=True,
+)
+# a ``layer_pattern`` letter -> its kind, in the order the counters are
+# set and the reads handed out; the routed experts are a layer by
+# themselves or (e) its second part
+PARTS = {
+    "M": PartKind(
+        stack="mamba", key="ssm", init=_init_mamba, axes=_mamba_axes,
+        run=lambda h, p, c: (_mamba_block(h, p["ssm"], c.cfg, c.mesh), {}),
+    ),
+    "m": PartKind(
+        stack="mamba1", key="ssm1", init=_init_mamba1, axes=_mamba1_axes,
+        run=lambda h, p, c: (_mamba1_block(h, p["ssm1"], c.cfg, c.mesh), {}),
+        counters=lambda cfg, mesh, n: {"ssm1.layers": n},
+    ),
+    "*": PartKind(
+        stack="attention", key="attn",
+        init=_init_attention_part, axes=_attention_axes,
+        run=lambda h, p, c: (_attention_block(
+            h, p, c.cfg, c.mesh, c.positions, c.attn_fn, rope=c.rope
+        ), {}),
+        wants_rope=True,
+    ),
+    "L": PartKind(
+        stack="lightning", key="lin",
+        init=_init_lightning, axes=_lightning_axes,
+        run=lambda h, p, c: _lightning_block(h, p["lin"], c.cfg, c.mesh, c.rope),
+        read="lightning_fast_out_ms", wants_rope=True,
+        counters=lambda cfg, mesh, n: {"lin.layers": n},
+    ),
+    "G": PartKind(
+        stack="gdn", key="gdn", init=_init_gdn, axes=_gdn_axes,
+        run=lambda h, p, c: _gdn_block(h, p["gdn"], c.cfg, c.mesh),
+        read="gdn_readout_ms",
+        counters=lambda cfg, mesh, n: _rule_layers(
+            "gdn", n, mesh, cfg.gdn_key_dim, cfg.gdn_value_dim
+        ),
+    ),
+    "K": PartKind(
+        stack="kda", key="kda", init=_init_kda, axes=_kda_axes,
+        run=lambda h, p, c: _kda_block(h, p["kda"], c.cfg, c.mesh),
+        read="kda_readout_ms",
+        counters=lambda cfg, mesh, n: _rule_layers(
+            "kda", n, mesh, cfg.kda_head_dim, cfg.kda_head_dim,
+            per_channel=True,
+        ),
+    ),
+    "S": PartKind(
+        stack="sparse", key="attn",
+        init=_init_attention_part, axes=_attention_axes,
+        run=lambda h, p, c: _block_sparse_attention(
+            h, p, c.cfg, c.mesh, c.positions, c.attn_fn, c.return_selected
+        ),
+        rides_out=True, selects=True,
+        counters=lambda cfg, mesh, n: {
+            "attn.sparse_layers": n, "attn.select_block": cfg.sparse_block,
+            "attn.select_groups": cfg.kv_heads,
+        },
+    ),
+    "E": _ROUTED,
+    "e": _ROUTED,
+    "-": PartKind(
+        stack="mlp", key="mlp",
+        init=lambda key, cfg, lead: _init_mlp(
+            jax.random.split(key, 16), cfg, _stackers(cfg, lead)[0]
+        ),
+        axes=_mlp_axes,
+        run=lambda h, p, c: (
+            _mlp_block(h, p, c.cfg, c.mesh, interior=jnp.float32), {}
+        ),
+    ),
+}
+
+
 def _pattern_axes(cfg: ModelConfig, pattern: str, lead) -> Params:
     """Logical axes of ``_init_pattern``'s tree."""
     lead = tuple(lead)
-    out: Params = {}
-    for name, letter, _ in _pattern_stacks(pattern):
-        layer: Params = {"ln": {"scale": lead + ("norm",)}}
-        if letter == "M":
-            layer["ssm"] = {
-                "w_in": lead + ("embed", "mlp"),
-                "conv_w": lead + (None, "mlp"),
-                "conv_b": lead + ("mlp",),
-                "a_log": lead + (None,),
-                "dt_bias": lead + (None,),
-                "d_skip": lead + (None,),
-                "norm": {"scale": lead + ("norm",)},
-                "w_out": lead + ("mlp", "embed"),
-            }
-        elif letter == "m":
-            layer["ssm1"] = {
-                "w_in": lead + ("embed", "mlp"),
-                "conv_w": lead + (None, "mlp"),
-                "conv_b": lead + ("mlp",),
-                "w_x": lead + ("mlp", None),
-                "dt_norm": {"scale": lead + ("norm",)},
-                "b_norm": {"scale": lead + ("norm",)},
-                "c_norm": {"scale": lead + ("norm",)},
-                "w_dt": lead + (None, "mlp"),
-                "dt_bias": lead + ("mlp",),
-                "a_log": lead + ("mlp", None),
-                "d_skip": lead + ("mlp",),
-                "w_out": lead + ("mlp", "embed"),
-            }
-        elif letter in "*S":
-            layer["attn"] = _attention_axes(cfg, lead)
-            if cfg.qk_head_norm:
-                layer["attn"]["q_norm"] = {"scale": lead + ("norm",)}
-                layer["attn"]["k_norm"] = {"scale": lead + ("norm",)}
-        elif letter == "L":
-            layer["lin"] = {
-                **{
-                    w: lead + ("embed", "heads")
-                    for w in ("wq", "wk", "wv", "wg")
-                },
-                "wo": lead + ("heads", "embed"),
-                **{
-                    n: {"scale": lead + ("norm",)}
-                    for n in ("q_norm", "k_norm", "o_norm")
-                },
-            }
-        elif letter == "G":
-            layer["gdn"] = {
-                "w_qkvz": lead + ("embed", "mlp"),
-                "w_ba": lead + ("embed", None),
-                "conv_w": lead + (None, "mlp"),
-                "a_log": lead + (None,),
-                "dt_bias": lead + (None,),
-                "norm": {"scale": lead + ("norm",)},
-                "w_out": lead + ("mlp", "embed"),
-            }
-        elif letter == "K":
-            layer["kda"] = {
-                "w_qkv": lead + ("embed", "mlp"),
-                "w_gates": lead + ("embed", None),
-                "conv_w": lead + (None, "mlp"),
-                "w_fb": lead + (None, "mlp"),
-                "w_gb": lead + (None, "mlp"),
-                "a_log": lead + (None,),
-                "dt_bias": lead + ("mlp",),
-                "norm": {"scale": lead + ("norm",)},
-                "w_out": lead + ("mlp", "embed"),
-            }
-        elif letter == "-":
-            layer["mlp"] = _mlp_axes(cfg, lead)
-        else:
-            from dlrover_tpu.parallel.moe import moe_logical_axes
-
-            layer["moe"] = moe_logical_axes(cfg, lead)
-        out[name] = layer
-    return out
+    return {
+        name: {
+            "ln": {"scale": lead + ("norm",)},
+            PARTS[c].key: PARTS[c].axes(cfg, lead),
+        }
+        for name, c, _ in _pattern_stacks(pattern)
+    }
 
 
 def _layer_axes(cfg: ModelConfig, lead, routed: bool) -> Params:
@@ -648,14 +730,9 @@ def _layer_axes(cfg: ModelConfig, lead, routed: bool) -> Params:
         "ln2": {"scale": lead + ("norm",)},
     }
     if routed:
-        from dlrover_tpu.parallel.moe import moe_logical_axes
-
-        ax["moe"] = moe_logical_axes(cfg, lead)
+        ax["moe"] = moe.moe_logical_axes(cfg, lead)
     else:
         ax["mlp"] = _mlp_axes(cfg, lead)
-    if cfg.qk_norm or cfg.qk_head_norm:
-        attn["q_norm"] = {"scale": lead + ("norm",)}
-        attn["k_norm"] = {"scale": lead + ("norm",)}
     if cfg.post_norm:
         ax["ln1_post"] = {"scale": lead + ("norm",)}
         ax["ln2_post"] = {"scale": lead + ("norm",)}
@@ -983,9 +1060,7 @@ def _cache_layer_tail(x, attn_out, layer, cfg: ModelConfig):
         x = x + attn_out
         h2 = _norm(x, ln2["scale"], ln2.get("bias"), cfg.norm, cfg.norm_eps)
     if cfg.n_experts > 0:
-        from dlrover_tpu.parallel.moe import moe_block
-
-        mlp_out = moe_block(h2, layer["moe"], cfg, None)
+        mlp_out = moe.moe_block(h2, layer["moe"], cfg, None)
     else:
         mlp_out = _mlp_block(h2, layer, cfg, None)
     return x + attn_out + mlp_out if cfg.parallel_residual else x + mlp_out
@@ -1902,7 +1977,6 @@ def _gdn_block(h, gdn, cfg: ModelConfig, mesh):
     1 / sqrt(channels), a missing L2 norm on q — is invisible behind
     the per-head norm, and this number is what sees it."""
     from dlrover_tpu.ops import ssd
-    from dlrover_tpu.ops.gated_delta import gated_delta_rule
 
     b, s, _ = h.shape
     dt_, f32 = jnp.dtype(cfg.dtype), jnp.float32
@@ -1934,7 +2008,7 @@ def _gdn_block(h, gdn, cfg: ModelConfig, mesh):
         q = _l2_heads(qkv[..., :keys].reshape(b, s, hk, dk), dk ** -0.5)
         k = _l2_heads(qkv[..., keys:2 * keys].reshape(b, s, hk, dk))
         v = qkv[..., 2 * keys:].reshape(b, s, hv, dv)
-        o = gated_delta_rule(q, k, v, g, beta, **several)
+        o = gated_delta.gated_delta_rule(q, k, v, g, beta, **several)
     aux = {"gdn_readout_ms": jax.lax.stop_gradient(jnp.mean(jnp.square(o)))}
     with jax.named_scope("gdn.gate"):
         y = ssd.gated_group_norm(
@@ -1970,7 +2044,6 @@ def _kda_block(h, kda, cfg: ModelConfig, mesh):
     Returns (output, aux): ``aux["kda_readout_ms"]`` the mean square of
     the read-out ``o`` before the norm, as ``gdn_readout_ms``."""
     from dlrover_tpu.ops import ssd
-    from dlrover_tpu.ops.gated_delta import gated_delta_rule
 
     b, s, _ = h.shape
     dt_, f32 = jnp.dtype(cfg.dtype), jnp.float32
@@ -2004,7 +2077,7 @@ def _kda_block(h, kda, cfg: ModelConfig, mesh):
             qkv[..., i * inner:(i + 1) * inner].reshape(b, s, heads, dh)
             for i in range(3)
         )
-        o = gated_delta_rule(
+        o = gated_delta.gated_delta_rule(
             _l2_heads(q, dh ** -0.5), _l2_heads(k), v, g, beta, **several
         )
     aux = {"kda_readout_ms": jax.lax.stop_gradient(jnp.mean(jnp.square(o)))}
@@ -2017,61 +2090,22 @@ def _kda_block(h, kda, cfg: ModelConfig, mesh):
     return matmul(y, kda["w_out"]), aux
 
 
-# the scope a part's operations are traced under
-_PART_SCOPES = {
-    "M": "ssm", "m": "ssm1", "*": "attn", "S": "attn", "L": "lin",
-    "G": "gdn", "K": "kda", "E": "mlp", "e": "mlp", "-": "mlp",
-}
-# the one number a mixer part hands out beside x, by its letter
-_PART_READS = {
-    "L": "lightning_fast_out_ms", "G": "gdn_readout_ms",
-    "K": "kda_readout_ms",
-}
-
-
 def _part_body(
     x, layer, positions, *, letter, cfg: ModelConfig, mesh, attn_fn,
     rng=None, rope=None, return_selected: bool = False,
 ):
     """One part of a ``layer_pattern`` model, ``x + s part(norm(x))``
-    (s = ``cfg.residual_scale``): a Mamba-2 mixer (``M``), a Mamba-1
-    mixer (``m``), an attention (``*``), a block-sparse attention
-    (``S``), a lightning linear attention (``L``), a gated-delta-rule
-    mixer (``G``), a delta-rule mixer with a decay a key channel
-    (``K``), the routed experts (``E``, ``e``) or a dense MLP (``-``).
-    Returns (x, the routed block's, the block-sparse attention's, the
-    lightning part's or the delta-rule mixer's aux, or {})."""
-    aux = {}
-    with jax.named_scope(_PART_SCOPES[letter]):
+    (s = ``cfg.residual_scale``), ``part`` the block of the letter's
+    kind (``PARTS``). Returns (x, the block's aux, or {})."""
+    kind = PARTS[letter]
+    with jax.named_scope(kind.scope or kind.key):
         # (``x`` is float32 behind a part whose output is, until
         # ``_run_pattern`` rounds it)
         h = _norm_block(x, layer["ln"], cfg).astype(cfg.dtype)
-        if letter == "M":
-            out = _mamba_block(h, layer["ssm"], cfg, mesh)
-        elif letter == "m":
-            out = _mamba1_block(h, layer["ssm1"], cfg, mesh)
-        elif letter == "*":
-            out = _attention_block(
-                h, layer, cfg, mesh, positions, attn_fn, rope=rope
-            )
-        elif letter == "S":
-            out, aux = _block_sparse_attention(
-                h, layer, cfg, mesh, positions, attn_fn, return_selected
-            )
-        elif letter == "L":
-            out, aux = _lightning_block(h, layer["lin"], cfg, mesh, rope)
-        elif letter == "G":
-            out, aux = _gdn_block(h, layer["gdn"], cfg, mesh)
-        elif letter == "K":
-            out, aux = _kda_block(h, layer["kda"], cfg, mesh)
-        elif letter == "-":
-            out = _mlp_block(h, layer, cfg, mesh, interior=jnp.float32)
-        else:
-            from dlrover_tpu.parallel.moe import moe_block
-
-            out, aux = moe_block(
-                h, layer["moe"], cfg, mesh, rng=rng, return_aux=True
-            )
+        out, aux = kind.run(h, layer, types.SimpleNamespace(
+            cfg=cfg, mesh=mesh, positions=positions, attn_fn=attn_fn,
+            rng=rng, rope=rope, return_selected=return_selected,
+        ))
         x = x + _residual_scaled(out, cfg)
         if mesh is not None:
             x = shd.constrain(x, mesh, "batch", "seq", None)
@@ -2104,11 +2138,9 @@ def _run_pattern(
     layers, B, S, k] in trunk order ({} where no layer routes); the
     block-sparse attentions' ``sparse_attn_out_ms`` as their mean and,
     where ``return_selected``, their selections as ``attn_selected``
-    bool [S parts x KV, B, S, U], layer-major and group-minor; the
-    lightning parts' ``lightning_fast_out_ms`` and the delta-rule
-    mixers' ``gdn_readout_ms`` / ``kda_readout_ms`` as their means
-    (``_PART_READS``), those
-    of a scanned run among them (the one thing a run hands out beside x).
+    bool [S parts x KV, B, S, U], layer-major and group-minor; every
+    kind's ``read`` (``PARTS``) as the mean over its parts, those of a
+    scanned run among them (the one thing a run hands out beside x).
     ``first``: the index of the pattern's first part, folded into
     ``rng``."""
     parts = {
@@ -2123,13 +2155,13 @@ def _run_pattern(
     }
     rope = (
         _rope_tables(positions, cfg.rope_dim, cfg.rope_theta)
-        if cfg.pos == "rope" and set("*L") & set(pattern)
+        if cfg.pos == "rope" and any(PARTS[c].wants_rope for c in pattern)
         else None
     )
     places = _part_places(pattern)
     seen = dict.fromkeys(bodies, 0)
     auxs, sparse = [], []
-    reads = {name: [] for name in _PART_READS.values()}
+    reads = {kind.read: [] for kind in PARTS.values() if kind.read}
     i = 0
     for unit, reps in _pattern_runs(pattern):
         if reps > 1:
@@ -2157,8 +2189,8 @@ def _run_pattern(
                     )
                     at[letter] += 1
                     x, aux = parts[letter](x, layer, positions, rope=rope)
-                    if letter in _PART_READS:
-                        name = _PART_READS[letter]
+                    name = PARTS[letter].read
+                    if name:
                         read.setdefault(name, []).append(aux[name])
                 return x.astype(cfg.dtype), {
                     name: jnp.stack(r) for name, r in read.items()
@@ -2177,11 +2209,11 @@ def _run_pattern(
             x, aux = bodies[letter](x, layer, positions, rng=r, rope=rope)
             x = x.astype(cfg.dtype)
             i += 1
-            if letter in _PART_READS:
-                name = _PART_READS[letter]
-                reads[name].append(aux[name][None])
+            kind = PARTS[letter]
+            if kind.read:
+                reads[kind.read].append(aux[kind.read][None])
             elif aux:
-                (sparse if letter == "S" else auxs).append(aux)
+                (sparse if kind.selects else auxs).append(aux)
     out = {
         name: jnp.mean(jnp.concatenate(r)) for name, r in reads.items() if r
     }
@@ -2419,37 +2451,11 @@ def run_trunk(
         set_counter(
             "pattern.scanned_parts", _scanned_parts(cfg.layer_pattern)
         )
-        if "m" in cfg.layer_pattern:
-            set_counter("ssm1.layers", cfg.layer_pattern.count("m"))
-        if "L" in cfg.layer_pattern:
-            set_counter("lin.layers", cfg.layer_pattern.count("L"))
-        if "G" in cfg.layer_pattern:
-            from dlrover_tpu.ops import gated_delta
-
-            linear = cfg.layer_pattern.count("G")
-            set_counter("gdn.layers", linear)
-            # those whose rule runs the Pallas kernels: all or none
-            set_counter("gdn.kernel_layers", linear * int(
-                gated_delta.in_kernels(
-                    cfg.gdn_key_dim, cfg.gdn_value_dim, mesh=mesh
-                )
-            ))
-        if "K" in cfg.layer_pattern:
-            from dlrover_tpu.ops import gated_delta
-
-            linear = cfg.layer_pattern.count("K")
-            set_counter("kda.layers", linear)
-            # those whose rule runs ``ops/pallas_kda.py``: all or none
-            set_counter("kda.kernel_layers", linear * int(
-                gated_delta.in_kernels(
-                    cfg.kda_head_dim, cfg.kda_head_dim, mesh=mesh,
-                    per_channel=True,
-                )
-            ))
-        if "S" in cfg.layer_pattern:
-            set_counter("attn.sparse_layers", cfg.layer_pattern.count("S"))
-            set_counter("attn.select_block", cfg.sparse_block)
-            set_counter("attn.select_groups", cfg.kv_heads)
+        for letter, kind in PARTS.items():
+            n = cfg.layer_pattern.count(letter)
+            if n and kind.counters:
+                for name, value in kind.counters(cfg, mesh, n).items():
+                    set_counter(name, value)
         x, aux = _run_pattern(
             x, layers, cfg.layer_pattern, positions, cfg, mesh, attn_fn,
             rng, keep_attn="" in keep_attn, return_selected=return_selected,

@@ -197,7 +197,7 @@ def test_two_parts_are_one_published_layer(model):
         for letter in unit:
             stack = params["layers"][
                 "mlp.1" if unit == "*-" and letter == "-"
-                else decoder.PART_NAMES[letter]
+                else decoder.PARTS[letter].stack
             ]
             got, _ = decoder._part_body(
                 got, jax.tree.map(lambda t: t[0], stack), positions,

@@ -415,7 +415,7 @@ def test_pattern_trunk_is_its_layers_one_by_one(model):
     for letter in cfg.layer_pattern:
         layer = jax.tree.map(
             lambda t: t[seen[letter]],
-            params["layers"][decoder.PART_NAMES[letter]],
+            params["layers"][decoder.PARTS[letter].stack],
         )
         seen[letter] += 1
         want, layer_aux = decoder._part_body(
