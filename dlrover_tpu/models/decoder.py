@@ -623,9 +623,15 @@ class PartKind:
 
 
 def _rule_layers(name: str, n: int, mesh, *dims, **how):
-    """A delta-rule kind's layers and, all or none, its kernels' layers."""
+    """A delta-rule kind's layers and, all or none, its kernels' layers:
+    the rule's, and those whose ``_l2_heads`` norms q and k by the
+    kernel."""
     kernels = gated_delta.in_kernels(*dims, mesh=mesh, **how)
-    return {f"{name}.layers": n, f"{name}.kernel_layers": n * int(kernels)}
+    return {
+        f"{name}.layers": n, f"{name}.kernel_layers": n * int(kernels),
+        # (``dims`` start with the key channels of a head)
+        f"{name}.norm_kernel_layers": n * int(_l2_in_kernel(dims[0])),
+    }
 
 
 _ROUTED = PartKind(
@@ -1934,9 +1940,30 @@ def _mamba1_block(h, ssm, cfg: ModelConfig, mesh):
     return matmul(y, ssm["w_out"])
 
 
+def _l2_in_kernel(d: int) -> bool:
+    """Whether ``_l2_heads`` norms heads of ``d`` channels by
+    ``pallas_norm.l2_heads``: a TPU (or interpreted) and a head whole
+    lanes. What ``gdn.norm_kernel_layers`` / ``kda.norm_kernel_layers``
+    count by."""
+    return pallas_norm.kernels_available() and d % 128 == 0
+
+
 def _l2_heads(t, scale=1.0, eps=1e-6):
     """Each head's channels of t [B, S, H, D] over their L2 norm, times
-    ``scale``: float32 inside, one rounding."""
+    ``scale``: float32 inside, one rounding. One formula, two bodies,
+    by the device and D (``_l2_in_kernel``). On the chip at heads of
+    whole lanes the 4-D form is a VIEW on both sides and the arithmetic
+    is done on ``[B, S, H * D]``, a head a run of columns
+    (``pallas_norm.l2_heads``, one Pallas pass forward and one back):
+    the layout the delta rules' kernels take (``gated_delta._flat``), so
+    the reshapes here cancel against the caller's and theirs and no
+    ``[S, H, D]``-tiled copy of q or k is made. Elsewhere the ``jnp``
+    body, in the 4-D form."""
+    if _l2_in_kernel(t.shape[-1]):
+        b, s, h, d = t.shape
+        return pallas_norm.l2_heads(
+            t.reshape(b, s, h * d), d, scale, eps
+        ).reshape(t.shape)
     t32 = t.astype(jnp.float32)
     inv = jax.lax.rsqrt(jnp.sum(t32 * t32, -1, keepdims=True) + eps)
     return (t32 * (inv * scale)).astype(t.dtype)
@@ -1975,7 +2002,15 @@ def _gdn_block(h, gdn, cfg: ModelConfig, mesh):
     ``aux["gdn_readout_ms"]`` the mean square of the read-out ``o``
     before the norm (float32): a uniform scale of ``o`` — q's
     1 / sqrt(channels), a missing L2 norm on q — is invisible behind
-    the per-head norm, and this number is what sees it."""
+    the per-head norm, and this number is what sees it.
+
+    Which form is computed in: q, k and v leave the conv a head a run
+    of columns (``[B, S, H * D]``), the form the rule's kernels take
+    (``gated_delta._flat``). ``_l2_heads`` and ``gated_delta_rule``
+    keep their 4-D doors (``[B, S, H, D]``), and on the chip that form
+    is a VIEW: the norms are computed flat (``_l2_heads``), g and β are
+    ``[B, S, Hv]`` either way, and no array of a whole sequence is
+    copied between the two tilings."""
     from dlrover_tpu.ops import ssd
 
     b, s, _ = h.shape
@@ -2042,7 +2077,14 @@ def _kda_block(h, kda, cfg: ModelConfig, mesh):
     compute dtype, EVERYTHING between them is float32 (the output too),
     and the rule multiplies float32 operands in three bf16 passes.
     Returns (output, aux): ``aux["kda_readout_ms"]`` the mean square of
-    the read-out ``o`` before the norm, as ``gdn_readout_ms``."""
+    the read-out ``o`` before the norm, as ``gdn_readout_ms``.
+
+    Which form is computed in, as in ``_gdn_block``: flat (``[B, S,
+    H * D]``, a head a run of columns) from the conv to the rule's
+    kernels — the L2 norms by ``_l2_heads``' flat body, g as ``A_log``
+    repeated over a head's columns times the softplus of ``[B, S,
+    H * D]`` — and ``[B, S, H, D]`` a view of it at ``_l2_heads``' and
+    ``gated_delta_rule``'s doors."""
     from dlrover_tpu.ops import ssd
 
     b, s, _ = h.shape
@@ -2070,8 +2112,11 @@ def _kda_block(h, kda, cfg: ModelConfig, mesh):
         ))
     with jax.named_scope("kda.rule"):
         beta = jax.nn.sigmoid(gates[..., 2 * rank:])
-        g = -jnp.exp(kda["a_log"].astype(f32))[:, None] * jax.nn.softplus(
-            decay_in + kda["dt_bias"].astype(f32)
+        # made FLAT (a head's decay repeated over its channels' columns)
+        # and viewed by head at the rule's door: see ``_l2_heads``
+        g = (
+            -jnp.repeat(jnp.exp(kda["a_log"].astype(f32)), dh)
+            * jax.nn.softplus(decay_in + kda["dt_bias"].astype(f32))
         ).reshape(b, s, heads, dh)
         q, k, v = (
             qkv[..., i * inner:(i + 1) * inner].reshape(b, s, heads, dh)

@@ -594,7 +594,12 @@ def _kernel_operands(k, g, beta):
 
 def _flat(q, k, v):
     """q, k [B, S, Hk * Dk] and v [B, S, Hv * Dv], as the kernels take
-    them: a head a run of columns."""
+    them: a head a run of columns. A VIEW where the caller computed in
+    this form and reshaped to heads at the rule's door (the models'
+    mixers do: ``decoder._l2_heads``, ``_kda_block``'s g); arithmetic
+    done on ``[B, S, H, D]`` pins that tiling (8 of H by 128 of D
+    against 8 of S by 128 of H * D) and makes this reshape, and each
+    cotangent's on the way back, a copy of the whole array."""
     b, s = k.shape[:2]
     return q.reshape(b, s, -1), k.reshape(b, s, -1), v.reshape(b, s, -1)
 

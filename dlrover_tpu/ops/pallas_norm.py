@@ -48,6 +48,7 @@ except ImportError:  # pragma: no cover
 
 from dlrover_tpu.common import device
 from dlrover_tpu.ops.pallas_attention import _out_struct
+from dlrover_tpu.ops.pallas_ssd import _traced_once
 
 # test hook: run every kernel in pallas interpret mode (CPU-executable).
 # Seeded from the environment so a whole pytest run can flip it without
@@ -384,3 +385,157 @@ def norm(
     if residual is not None:
         return unrows(out[0]), unrows(out[1])
     return unrows(out)
+
+
+# ---------------------------------------------------------------------------
+# The L2 norm of every head of a row, on the flat layout
+# ---------------------------------------------------------------------------
+
+# columns of a block at most, and elements a turn of the kernel's loop
+# works on (32 float32 registers): the body is unrolled over a block's
+# heads, and what a body costs before it runs goes by its text
+# (``ops/pallas_ssd.py``'s docstring), so a block is few heads wide and
+# a turn as many rows as fill the registers' half
+_L2_LANES = 1024
+_L2_TURN = 32 * 1024
+
+
+def _fit_heads(n: int, w: int, d: int, dtype):
+    """(rows, columns, rows a turn) of ``l2_heads``' block over x
+    [n, w], heads of ``d`` columns: whole heads, the widest run of them
+    that divides w inside ``_L2_LANES``; as many rows as the VMEM budget
+    holds, in whole turns, no more than n's; a turn whole sublane tiles
+    of the dtype (8 rows of float32, 16 of bf16), ``_L2_TURN`` elements
+    where the block has them. The grid takes the rows' last block short.
+    On a v5e at [16384, 4096] float32 (my chip run, PR 69; ms forward |
+    back): this tile, 512 x 1024 x 32, 0.84 | 1.20 (637 | 671 GB/s of
+    819); 128 rows of all 32 heads, 8 rows a turn, the same (0.85 |
+    1.21) at four times the text: the Kimi-Linear step's trace and
+    lowering +0.85 s over the parent's on this sandbox's CPU where this
+    tile's is -0.4, its warm build on the chip's host +3.8 and +7.5 s
+    where this tile's is +2.1 and +2.2; 512 x 1024 at 8 rows a turn 0.94 |
+    1.64; 512 columns 1.7 | 3.1 whatever the rows; XLA's fusions on the
+    same flat array 4.4 | 8.3."""
+    sub = max(8, 32 // jnp.dtype(dtype).itemsize)
+    heads = w // d
+    cols = d * max(
+        k for k in range(1, heads + 1)
+        if heads % k == 0 and d * k <= max(d, _L2_LANES)
+    )
+    fit = (n + sub - 1) // sub * sub
+    held = max(sub, _ROW_BLOCK_BYTES // (4 * cols) // sub * sub)
+    turn = min(max(sub, _L2_TURN // cols // sub * sub), held, fit)
+    rows = min(held // turn * turn, (fit + turn - 1) // turn * turn)
+    return rows, cols, turn
+
+
+def _l2_turns(kernel, rows, turn, *refs):
+    """``kernel(row window)`` over a block's rows a turn at a time, one
+    rolled loop: a turn's columns stay in registers between the sum
+    over a head's lanes and the product with what comes of it."""
+    def one(i, carry):
+        kernel(pl.ds(pl.multiple_of(i * turn, turn), turn), *refs)
+        return carry
+
+    jax.lax.fori_loop(0, rows // turn, one, 0)
+
+
+def _l2_fwd_rows(at, x_ref, y_ref, *, d, scale, eps):
+    for h in range(x_ref.shape[1] // d):
+        cols = slice(h * d, (h + 1) * d)
+        x = x_ref[at, cols].astype(jnp.float32)
+        inv = jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + eps)
+        y_ref[at, cols] = (x * (inv * scale)).astype(y_ref.dtype)
+
+
+def _l2_bwd_rows(at, x_ref, dy_ref, dx_ref, *, d, scale, eps):
+    for h in range(x_ref.shape[1] // d):
+        cols = slice(h * d, (h + 1) * d)
+        x = x_ref[at, cols].astype(jnp.float32)
+        dy = dy_ref[at, cols].astype(jnp.float32)
+        inv = jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + eps)
+        unit = x * inv
+        along = jnp.sum(dy * unit, -1, keepdims=True)
+        dx_ref[at, cols] = (
+            (inv * scale) * (dy - unit * along)
+        ).astype(dx_ref.dtype)
+
+
+def _l2_pass(rows_kernel, name, arrays, *, d, scale, eps, tile, interpret):
+    """One pass of ``rows_kernel`` over ``arrays`` [n, w] (all alike),
+    a block of ``tile`` a grid step: returns one array like them."""
+    like = arrays[0]
+    n, w = like.shape
+    rows, cols, turn = tile
+    spec = pl.BlockSpec((rows, cols), lambda i, j: (i, j))
+    return pl.pallas_call(
+        functools.partial(
+            _l2_turns,
+            functools.partial(rows_kernel, d=d, scale=scale, eps=eps),
+            rows, turn,
+        ),
+        grid=(pl.cdiv(n, rows), w // cols),
+        in_specs=[spec] * len(arrays),
+        out_specs=spec,
+        out_shape=_out_struct((n, w), like.dtype, like),
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+        ),
+        interpret=interpret,
+        name=name,
+    )(*arrays)
+
+
+_L2_STATIC = ("d", "scale", "eps", "tile", "interpret")
+_l2_fwd = _traced_once(
+    lambda x, **how: _l2_pass(_l2_fwd_rows, "l2_heads_fwd", (x,), **how),
+    _L2_STATIC,
+)
+_l2_bwd = _traced_once(
+    lambda x, dy, **how: _l2_pass(
+        _l2_bwd_rows, "l2_heads_bwd", (x, dy), **how
+    ),
+    _L2_STATIC,
+)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5))
+def _l2_call(x, *how):
+    return _l2_fwd(x, **dict(zip(_L2_STATIC, how)))
+
+
+def _l2_call_fwd(x, *how):
+    return _l2_call(x, *how), x
+
+
+def _l2_call_bwd(*args):
+    *how, x, dy = args
+    return (_l2_bwd(x, dy, **dict(zip(_L2_STATIC, how))),)
+
+
+_l2_call.defvjp(_l2_call_fwd, _l2_call_bwd)
+
+
+def l2_heads(x, d: int, scale: float = 1.0, eps: float = 1e-6,
+             interpret: bool = None):
+    """Every head of x [..., H * d] — a head a run of ``d`` columns, d a
+    multiple of the 128 lanes — over its L2 norm, times ``scale``:
+
+        y = x * rsqrt(sum(x², a head's columns) + eps) * scale
+
+    float32 inside, the output in x's dtype: ``decoder._l2_heads``'
+    formula, on the layout the delta rules' kernels take (``[B, S,
+    H * D]``) where that one norms ``[B, S, H, D]``. Two kernels,
+    ``l2_heads_fwd`` (one read, one write) and ``l2_heads_bwd`` (x and
+    dy read, the inverse norm made again, ``dx = inv scale (dy − x̂
+    ⟨dy, x̂⟩)`` with x̂ = x inv written), behind one ``custom_vjp`` that
+    keeps x alone. The caller asks ``kernels_available()`` first."""
+    interpret = INTERPRET if interpret is None else interpret
+    w = x.shape[-1]
+    if d % 128 or w % d:
+        raise ValueError(f"{w} columns are no heads of {d} on the lanes")
+    flat = x.reshape(-1, w)
+    tile = _fit_heads(flat.shape[0], w, d, x.dtype)
+    return _l2_call(
+        flat, d, float(scale), float(eps), tile, bool(interpret)
+    ).reshape(x.shape)

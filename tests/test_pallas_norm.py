@@ -11,6 +11,10 @@ Tolerances: f32 cases compare at a few ulp (the kernel reduces by
 sum/d where the reference uses mean — same value, different op order);
 bf16 cases at 1-2 bf16 ulp. The fused-residual summed stream is pinned
 BITWISE: it is an input-dtype add in both implementations.
+
+``l2_heads`` (PR 69: every head's L2 norm on the flat layout, what
+``decoder._l2_heads`` runs on the chip) is held to that function's jnp
+body the same way, as cases of one test.
 """
 
 import jax
@@ -137,6 +141,134 @@ def test_unknown_kind_raises():
     x = jnp.ones((2, 2, 8))
     with pytest.raises(ValueError, match="unknown norm kind"):
         pallas_norm.norm(x, jnp.ones((8,)), None, "batchnorm")
+
+
+# --- every head's L2 norm on the flat layout (``l2_heads``) -------------
+
+# what differs from the base case (float32, 2 x 24 tokens of 2 heads of
+# 128, scale 1, the default tile): a token count the tile does not
+# divide is met by making the block small, since a block of 2 MB holds
+# more rows than an interpreted test should
+L2_CASES = {
+    "scale-1": {},
+    "scale-rsqrt-d": {"scale": 128 ** -0.5},
+    "rows-no-multiple-of-the-tile": {"tokens": 44, "block_bytes": 16384},
+    "a-row-of-zeros": {"zero_row": True},
+    "bf16": {"dt": jnp.bfloat16, "scale": 128 ** -0.5},
+    "bf16-rows-no-multiple-of-the-tile": {
+        "dt": jnp.bfloat16, "tokens": 44, "block_bytes": 16384,
+    },
+    "eps-1e-3": {"eps": 1e-3, "zero_row": True},
+}
+
+
+def _l2_body(t, scale=1.0, eps=1e-6):
+    """``decoder._l2_heads``' jnp body, as it stood before the kernel."""
+    t32 = t.astype(jnp.float32)
+    inv = jax.lax.rsqrt(jnp.sum(t32 * t32, -1, keepdims=True) + eps)
+    return (t32 * (inv * scale)).astype(t.dtype)
+
+
+@pytest.mark.parametrize("case", sorted(L2_CASES))
+def test_l2_heads_is_the_jnp_body(monkeypatch, case):
+    """``l2_heads`` interpreted, on [B, S, H * D], against the jnp body
+    on [B, S, H, D]: values and gradients. A row of zeros is the ``eps``
+    case (the inverse norm is eps ** -0.5 and the derivative the
+    cotangent times it)."""
+    how = dict(
+        dt=jnp.float32, tokens=24, scale=1.0, eps=1e-6, zero_row=False,
+        block_bytes=None,
+    )
+    how.update(L2_CASES[case])
+    if how["block_bytes"]:
+        monkeypatch.setattr(
+            pallas_norm, "_ROW_BLOCK_BYTES", how["block_bytes"]
+        )
+    b, s, h, d = 2, how["tokens"], 2, 128
+    rows, cols, _ = pallas_norm._fit_heads(b * s, h * d, d, how["dt"])
+    assert cols == h * d
+    assert bool((b * s) % rows) == bool(how["block_bytes"])
+    kx, kc = jax.random.split(jax.random.key(7))
+    x = (3.0 * jax.random.normal(kx, (b, s, h * d))).astype(how["dt"])
+    if how["zero_row"]:
+        x = x.at[1, 5].set(0.0)
+    ct = jax.random.normal(kc, (b, s, h * d), jnp.float32)
+
+    def kernel(x):
+        return pallas_norm.l2_heads(
+            x, d, how["scale"], how["eps"], interpret=True
+        )
+
+    def body(x):
+        return _l2_body(
+            x.reshape(b, s, h, d), how["scale"], how["eps"]
+        ).reshape(x.shape)
+
+    def pulled(fn):
+        return jax.grad(lambda x: jnp.sum(fn(x).astype(jnp.float32) * ct))(x)
+
+    got, want = kernel(x), body(x)
+    assert got.dtype == want.dtype == how["dt"]
+    dgot, dwant = pulled(kernel), pulled(body)
+    assert dgot.dtype == how["dt"]
+    # float32: a few ulp (the sums' order); bf16: one rounding of it
+    tol = 1e-6 if how["dt"] == jnp.float32 else 1e-2
+    for a, w in ((got, want), (dgot, dwant)):
+        a, w = np.asarray(a, np.float32), np.asarray(w, np.float32)
+        assert np.isfinite(a).all()
+        np.testing.assert_allclose(a, w, rtol=tol, atol=tol * np.abs(w).max())
+
+
+def test_l2_heads_off_the_lanes_is_the_jnp_body_bit_for_bit():
+    """``decoder._l2_heads`` on the CPU, and at heads off the 128 lanes
+    wherever it runs, is the jnp body to the bit; heads that are no
+    whole lanes are refused by the kernel's entry."""
+    from dlrover_tpu.models import decoder
+
+    assert not decoder._l2_in_kernel(8) and not decoder._l2_in_kernel(128)
+    t = jax.random.normal(jax.random.key(2), (2, 12, 3, 8), jnp.float32)
+    for scale in (1.0, 8 ** -0.5):
+        np.testing.assert_array_equal(
+            np.asarray(decoder._l2_heads(t, scale)),
+            np.asarray(_l2_body(t, scale)),
+        )
+        got = jax.grad(lambda t: jnp.sum(decoder._l2_heads(t, scale) ** 3))(t)
+        want = jax.grad(lambda t: jnp.sum(_l2_body(t, scale) ** 3))(t)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    with pytest.raises(ValueError, match="no heads of 8 on the lanes"):
+        pallas_norm.l2_heads(t.reshape(2, 12, 24), 8, interpret=True)
+
+
+def test_l2_heads_in_the_decoder_is_a_view_around_the_kernel(monkeypatch):
+    """Interpreted at heads of 128 ``decoder._l2_heads`` keeps its 4-D
+    door and runs the kernel on the flat form: the jaxpr holds the two
+    Pallas calls and no norm of its own."""
+    from dlrover_tpu.models import decoder
+
+    monkeypatch.setattr(pallas_norm, "INTERPRET", True)
+    assert decoder._l2_in_kernel(128) and not decoder._l2_in_kernel(64)
+    t = jax.random.normal(jax.random.key(4), (1, 16, 2, 128), jnp.float32)
+    np.testing.assert_allclose(
+        np.asarray(decoder._l2_heads(t, 0.5)), np.asarray(_l2_body(t, 0.5)),
+        rtol=1e-6, atol=1e-6,
+    )
+    jaxpr = jax.make_jaxpr(
+        jax.value_and_grad(lambda t: jnp.sum(decoder._l2_heads(t, 0.5) * t))
+    )(t)
+    names, primitives = [], set()
+
+    def walk(jaxpr):
+        for e in jaxpr.eqns:
+            primitives.add(e.primitive.name)
+            if e.primitive.name == "pallas_call":
+                names.append(e.params["name"])
+            else:
+                for sub in jax.core.jaxprs_in_params(e.params):
+                    walk(sub)
+
+    walk(jaxpr.jaxpr)
+    assert names == ["l2_heads_fwd", "l2_heads_bwd"]
+    assert "rsqrt" not in primitives
 
 
 @pytest.mark.slow

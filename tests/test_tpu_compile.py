@@ -387,6 +387,24 @@ def _norm(d, grad, residual):
     return build
 
 
+def _l2(heads):
+    """Both kernels of ``pallas_norm.l2_heads`` at a delta-rule mixer's
+    q (or k) in its cell: one sequence of 16,384, ``heads`` heads of 128
+    a run of columns each, float32."""
+    def build(S):
+        x = S((1, 16384, heads * 128), F32)
+
+        def fn(x, dy):
+            y, pull = jax.vjp(
+                lambda x: pallas_norm.l2_heads(x, 128, 128 ** -0.5), x
+            )
+            return y, pull(dy)[0]
+
+        return fn, (x, x)
+
+    return build
+
+
 def _paged(variant, c, mode):
     def build(S):
         cfg = get_config("gpt2-1.5b")
@@ -481,6 +499,10 @@ CASES = {
     "norm-fwd-d2048": (_norm(2048, grad=False, residual=False), 1),
     "norm-bwd-d2048": (_norm(2048, grad=True, residual=False), 1),
     "norm-residual-bwd-d2048": (_norm(2048, grad=True, residual=True), 2),
+    # q's and k's L2 norm a head on the flat layout, forward and back
+    # (Kimi-Linear's 32 heads, Qwen3-Next's 16 key heads)
+    "l2-heads-32x128": (_l2(32), 2),
+    "l2-heads-16x128": (_l2(16), 2),
     **{
         f"paged-{variant}{c}-{mode}": (_paged(variant, c, mode), 1)
         for mode in ("bf16", "int8")
@@ -523,6 +545,13 @@ def test_kernel_compiles_for_v5e(chip, case):
         assert "while(" not in text and " while " not in text
         temp = compiled.memory_analysis().temp_size_in_bytes
         assert temp < (2.6e9 if "bwd" in case else 1.2e9)
+    if case.startswith("l2-heads-"):
+        import re
+
+        for name in ("l2_heads_fwd", "l2_heads_bwd"):
+            assert re.search(rf"%\w*{name}[_.\d]* = ", text), name
+        # x as it lies: a head a run of columns, nothing made [S, H, D]
+        assert not re.search(r"f32\[[\d,]*16384,\d+,128\]", text)
     if case.startswith("rows-sum-"):
         assert "%rows_sum" in text
     if case.startswith("conv-"):
@@ -1249,7 +1278,8 @@ def test_kimi_cell_fits_the_chip(topo):
     layers 1-5 — a KDA mixer and the dense MLP, then KDA, KDA, latent
     attention, KDA with sixteen held experts each — one sequence of
     16,384 tokens) compiles for a described v5e under the chip's 15.75
-    GiB (16.91 GB; 14.76 GiB by this count since PR 66, 14.08 before):
+    GiB (16.91 GB; 13.98 GiB by this count since PR 69, 14.76 since PR
+    66, 14.08 before):
     the vector rule as its kernels in every KDA layer (``ops/
     pallas_kda.py``: under ``remat: full`` the pairs and the forward
     walk twice a layer — the layer's and the remade one, whose pairs the
@@ -1289,11 +1319,29 @@ def test_kimi_cell_fits_the_chip(topo):
     )
     assert counters["kda.layers"] == 4
     assert counters["kda.kernel_layers"] == 4
+    assert counters["kda.norm_kernel_layers"] == 4
+    # q's and k's L2 norms (PR 69): a kernel each in the layer's forward
+    # and again in the one ``full`` remakes (2 x 4 x 2), their
+    # derivatives once (2 x 4)
     for kernel, calls in (
         ("kda_pairs", 8), ("kda_fwd", 8), ("kda_states", 4),
         ("kda_bwd", 4), ("kda_pairs_bwd", 4),
+        ("l2_heads_fwd", 16), ("l2_heads_bwd", 8),
     ):
         assert _kernel_calls(text, kernel) == calls, kernel
+    # between the conv and the rule's kernels [B, S, H, D] is a view:
+    # nothing under the scope makes, copies or relays an array of a
+    # whole sequence's heads in that form or out of it (the parent held
+    # 24 broadcasts of the norms to f32[16384,32,128] and 24 relayouts
+    # of them to f32[1,16384,4096], 268 MB each)
+    under_rule = [ln for ln in text.splitlines() if "kda.rule" in ln]
+    assert under_rule
+    assert not [
+        ln for ln in under_rule if re.search(
+            r"= f32\[(?:1,)?16384,(?:4096|32,128)\]\S* "
+            r"(?:copy|reshape|transpose)\(", ln
+        ) or re.search(r"= f32\[(?:1,)?16384,32,128\]\S* broadcast\(", ln)
+    ]
     assert counters["attn.output_kept"] == 1
     assert counters["ssm.conv_in_kernel"] == 1
     assert _kernel_calls(text, "flash_fwd") == 1
@@ -1755,6 +1803,13 @@ SCAN_BODY_BUDGET = {
     # unrolled; each traced once a process)
     "rows-sum-8192x8-2048": {"rows_sum": 127},
     "rows-sum-back-8192x22-1024": {"rows_sum": 109},
+    # the L2 norm a head, forward and back (PR 69: 9 and 16 equations a
+    # head of the block, 76 / 132 at the 8 heads a block holds whatever
+    # the width — 292 / 516 with all of Kimi-Linear's 32 in it, which
+    # cost its cell 2-5 s of warm ``setup_s`` for no speed; each traced
+    # twice a process, once for q's scale and once for k's)
+    "l2-heads-32x128": {"l2_heads_fwd": 83, "l2_heads_bwd": 145},
+    "l2-heads-16x128": {"l2_heads_fwd": 83, "l2_heads_bwd": 145},
 }
 
 
