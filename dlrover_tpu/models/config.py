@@ -5,6 +5,7 @@ Sizes mirror the models the reference benchmarks with
 BASELINE.md #3-#11), plus small configs for tests and CI.
 """
 
+import math
 import re
 from dataclasses import dataclass, field, replace
 from typing import Callable, Optional, Tuple
@@ -51,6 +52,20 @@ class ModelConfig:
     attn_block_q: int = 1024
     attn_block_k: int = 1024
     rope_theta: float = 10000.0
+    # a SCALED rope (YaRN, the ``transformers`` reading of a published
+    # ``rope_type: yarn``; 0 = none): a pair whose wavelength fits
+    # ``rope_beta_fast`` times and more into ``rope_original_max``
+    # positions keeps its frequency, one that fits ``rope_beta_slow``
+    # times or fewer turns ``rope_factor`` times slower, those between
+    # are blended linearly by pair index, and cos and sin are multiplied
+    # by ``rope_attn_factor`` (0 = 0.1 ln(rope_factor) + 1), so scores
+    # carry its square. Which layers it turns ``ATTN_KINDS`` says: every
+    # layer of a model without ``layer_types``. Training path only
+    rope_factor: float = 0.0
+    rope_original_max: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_attn_factor: float = 0.0
     # RMS/LayerNorm (cfg.norm) over the WHOLE q and k projections
     # (n_head·head_dim / kv_heads·head_dim wide, one scale each), before
     # the head split and rope — OLMoE's q_norm/k_norm
@@ -66,11 +81,10 @@ class ModelConfig:
     d_head: int = 0
     # an attention KIND per layer (a published ``layer_types``; "" =
     # every layer the model's one kind): one letter a layer of the
-    # trunk, the dense prefix first. ``S`` a sliding-window layer, rope
-    # on q and k, the last ``attn_window`` keys; ``F`` a full causal
-    # layer with NO positional term at all. Both kinds have the same
-    # parameters, so a stack stays one stack and is scanned a period at
-    # a time. Training path only
+    # trunk, the dense prefix first, each a row of ``ATTN_KINDS`` (its
+    # window, its rope). All kinds have the same parameters, so a stack
+    # stays one stack and is scanned a period at a time. Training path
+    # only
     layer_types: str = ""
     # a sigmoid gate on the attention's output, per channel, from the
     # layer's normed input: o <- o * sigmoid(h W_g) before W_o
@@ -83,6 +97,12 @@ class ModelConfig:
     # the norms' epsilon (None = 1e-6 RMSNorm, 1e-5 LayerNorm)
     norm_eps: Optional[float] = None
     tie_embeddings: bool = True
+    # standard deviation the token embeddings are drawn with
+    # (``decoder.init``). Every matrix is drawn at 1 / sqrt(fan-in), so
+    # a block's output is near 1 a channel; at 0.02 a fresh residual
+    # stream is what the blocks added and not the token's, and a fresh
+    # router sends whole stretches of a sequence to the same experts
+    embed_init_std: float = 0.02
     # numerics
     dtype: str = "bfloat16"          # activation/compute dtype
     param_dtype: str = "float32"
@@ -340,6 +360,11 @@ class ModelConfig:
     fused_norm: Optional[bool] = None
 
     def __post_init__(self):
+        if not self.embed_init_std > 0:
+            raise ValueError(
+                f"embed_init_std is a standard deviation, got "
+                f"{self.embed_init_std!r}"
+            )
         if self.moe_impl not in ("dense", "ragged"):
             raise ValueError(
                 f"moe_impl must be 'dense' or 'ragged', got "
@@ -472,25 +497,45 @@ class ModelConfig:
                 "qk_head_norm norms each head of plain q and k "
                 "projections; qk_norm norms them whole: one or the other"
             )
+        if self.rope_factor and (
+            self.rope_factor <= 1.0 or self.rope_original_max <= 0
+            or not 0 < self.rope_beta_slow < self.rope_beta_fast
+            or self.rope_attn_factor < 0 or self.pos != "rope"
+            or self.latent_attention or self.selects_keys
+            or self.layer_pattern or self.n_mtp_module
+        ):
+            raise ValueError(
+                "a scaled rope needs rope_factor > 1, rope_original_max > 0 "
+                "and 0 < rope_beta_slow < rope_beta_fast, on the plain "
+                "attention of a rope model: latent attention, a key "
+                "selection, layer_pattern's parts and a prediction module "
+                "build tables of their own"
+            )
         if self.layer_types:
-            odd = set(self.layer_types) - set("SF")
+            odd = set(self.layer_types) - set(ATTN_KINDS)
             if odd or len(self.layer_types) != self.n_layer:
                 raise ValueError(
-                    "layer_types names each of the n_layer layers S "
-                    "(sliding window, rope) or F (full, no positions); "
-                    f"got {self.layer_types!r} for {self.n_layer} layers"
+                    "layer_types names each of the n_layer layers "
+                    f"{_kinds_said()}; got {self.layer_types!r} for "
+                    f"{self.n_layer} layers"
                 )
+            rules = [ATTN_KINDS[kind] for kind in set(self.layer_types)]
             if (
                 self.pos != "rope" or not self.causal or self.prefix_lm
                 or self.latent_attention or self.selects_keys
                 or self.layer_pattern or self.n_mtp_module or self.fp8
-                or ("S" in self.layer_types and not self.attn_window)
+                or (any(r.window for r in rules) and not self.attn_window)
+                or (
+                    any(r.rope == "scaled" for r in rules)
+                    and not self.rope_factor
+                )
             ):
                 raise ValueError(
                     "layer_types is for causal plain-attention layers of "
-                    "a rope model with attn_window set: no prefix-LM, "
-                    "latent attention, key selection, layer_pattern, "
-                    "prediction module or fp8"
+                    "a rope model, with attn_window set where a kind has "
+                    "a window and rope_factor where one is turned by the "
+                    "scaled table: no prefix-LM, latent attention, key "
+                    "selection, layer_pattern, prediction module or fp8"
                 )
         if (self.attn_gate or self.post_norm) and (
             self.latent_attention or self.selects_keys
@@ -738,13 +783,43 @@ class ModelConfig:
 
     def kind_window(self, kind: str = "") -> int:
         """Keys a query of a layer of ``kind`` may see (0 = every
-        earlier one; None reads as 0): ``attn_window`` as it is, but
-        none on an ``F`` layer."""
-        return 0 if kind == "F" else self.attn_window
+        earlier one; None reads as 0): ``attn_window`` where the kind
+        has a window (``ATTN_KINDS``; "" = the model's one kind: as it
+        is)."""
+        if kind and not ATTN_KINDS[kind].window:
+            return 0
+        return self.attn_window
 
-    def kind_rope(self, kind: str = "") -> bool:
-        """Whether a layer of ``kind`` turns q and k by rope."""
-        return self.pos == "rope" and kind != "F"
+    def kind_rope(self, kind: str = "") -> str:
+        """The rope that turns q and k in a layer of ``kind``: "" none,
+        "plain" or "scaled" (``rope_factor`` and its fields); the
+        model's one kind is under the scaled table where it has one."""
+        if self.pos != "rope":
+            return ""
+        if kind:
+            return ATTN_KINDS[kind].rope
+        return "scaled" if self.rope_factor else "plain"
+
+    @property
+    def rope_kinds(self) -> Tuple[str, ...]:
+        """The rope tables a forward builds: one a rope ("plain",
+        "scaled") that some layer of the trunk is turned by."""
+        return tuple(sorted(
+            {self.kind_rope(kind) for kind in self.layer_types or ("",)}
+            - {""}
+        ))
+
+    @property
+    def rope_scaling(self):
+        """(factor, original length, beta_fast, beta_slow, amplitude) of
+        the scaled table; None without one."""
+        if not self.rope_factor:
+            return None
+        return (
+            self.rope_factor, self.rope_original_max, self.rope_beta_fast,
+            self.rope_beta_slow,
+            self.rope_attn_factor or 0.1 * math.log(self.rope_factor) + 1.0,
+        )
 
     @property
     def attn_params(self) -> int:
@@ -806,6 +881,10 @@ class ModelConfig:
             return "latent attention (no latent cache is built)"
         if self.n_dense_layer or self.layer_types:
             return "a trunk whose layers differ"
+        if self.rope_factor:
+            return (
+                "a scaled rope (prefill and decode build the plain table)"
+            )
         if self.attn_gate or self.post_norm or self.scale_embedding:
             return "a gated, twice-normed layer the cache paths do not build"
         if self.n_mtp_module:
@@ -903,11 +982,12 @@ class ModelConfig:
         }
 
     def num_params(self) -> int:
-        """Approximate parameter count. A routed layer of a model of one
-        kind is counted as one MLP of ``d_ff`` (the dense part); a model
-        with a dense prefix counts what this device holds: the prefix at
-        ``d_ff``, each routed block's held and shared experts and
-        router, and the prediction module."""
+        """Parameter count. A capacity-routed (``moe_impl: dense``)
+        layer of a model of one kind is counted as one MLP of ``d_ff``
+        (the dense part); a dropless routed model counts what this
+        device holds: a dense prefix at ``d_ff``, each routed block's
+        held and shared experts and router, and the prediction
+        module."""
         d, f, v, L = self.d_model, self.d_ff, self.vocab_size, self.n_layer
         if self.layer_pattern:
             held = {k: n for k, (n, _) in self._part_counts().items()}
@@ -924,14 +1004,17 @@ class ModelConfig:
             attn = self.attn_params
         # the indexer; and the two norms on the parts' outputs
         attn += self.index_params + 2 * d * self.post_norm
+        attn += 2 * self.head_dim * self.qk_head_norm  # the per-head scales
         gated = 3 if self.act == "swiglu" else 2
         mlp = gated * d * f
         embed = v * d * (1 if self.tie_embeddings else 2)
         pos = self.max_seq * d if self.pos == "learned" else 0
-        if self.n_experts_held and not self.n_dense_layer:
+        if self.n_experts and self.moe_impl == "ragged" and (
+            not self.n_dense_layer
+        ):
             # this device's share of a routed model of one kind
             mlp = d * self.n_experts + (
-                self.n_experts_held * gated * d * self.expert_width
+                self.experts_here * gated * d * self.expert_width
             )
         if not self.n_dense_layer:
             return L * (attn + mlp + 2 * d) + embed + pos + d
@@ -1055,6 +1138,33 @@ class ModelConfig:
                 self.executed_span(seq_len, kind) for kind in self.layer_types
             )
         return 6.0 * multiplied + 12.0 * self.n_attention_layers * pairs
+
+
+@dataclass(frozen=True)
+class AttnKind:
+    """One kind of plain-attention layer a ``layer_types`` letter names:
+    what ``kind_window``, ``kind_rope``, the validation, the counts and
+    ``models/decoder.py`` (its scope, its table) read. A kind added
+    later is one row."""
+
+    what: str  # the kind in a few words, as errors and refusals list it
+    window: bool  # the last ``attn_window`` keys (else every earlier one)
+    rope: str  # "" no positional term | "plain" | "scaled" (rope_factor)
+    scope: str  # the tracing scope of its whole attention part
+
+
+ATTN_KINDS = {
+    "S": AttnKind("sliding window, plain rope", True, "plain", "attn.window"),
+    "F": AttnKind("full, no positions", False, "", "attn.full"),
+    "Y": AttnKind("full, scaled rope", False, "scaled", "attn.full"),
+}
+
+
+def _kinds_said() -> str:
+    """The kinds as a refusal lists them: "S (sliding ...), F (...) or
+    Y (...)"."""
+    said = [f"{k} ({rule.what})" for k, rule in ATTN_KINDS.items()]
+    return ", ".join(said[:-1]) + " or " + said[-1]
 
 
 @dataclass(frozen=True)
@@ -1715,6 +1825,38 @@ CONFIGS = {
         moe_score="sigmoid",
         moe_renorm_topk=True,
         routed_scaling_factor=2.826,
+        moe_aux_coef=0.001,
+    ),
+    # a rope per layer kind: Mellum2-12B-A2.5B-Instruct (``mellum``;
+    # huggingface.co/JetBrains/Mellum2-12B-A2.5B-Instruct config.json) —
+    # 28 layers of GQA 32 / 4 heads of 128 over d 2304, ``layer_types``
+    # three sliding-window layers (1,024 keys, plain rope at theta 5e5)
+    # then one full layer under YaRN x 16 over an original 8,192
+    # (beta 32 / 1, amplitude 1.2772588722239782), seven times; every
+    # layer 64 experts of width 896, softmax top-8 renormalised, no
+    # shared expert, no dense layer; rms_norm_eps 1e-6, untied head. The
+    # per-head norm of q and k and the router's balance term (0.001,
+    # OLMoE's form) have no key in config.json: the Qwen3-MoE family's,
+    # whose key set this is. Training path only
+    "mellum2": replace(
+        _llama("mellum2", 28, 32, 2304, 7168, max_seq=131072, n_kv_head=4),
+        vocab_size=98304,
+        d_head=128,
+        qk_head_norm=True,
+        attn_window=1024,
+        layer_types="SSSY" * 7,
+        rope_theta=5e5,
+        rope_factor=16.0,
+        rope_original_max=8192,
+        rope_beta_fast=32.0,
+        rope_beta_slow=1.0,
+        rope_attn_factor=1.2772588722239782,
+        norm_eps=1e-6,
+        n_experts=64,
+        expert_top_k=8,
+        d_expert=896,
+        moe_impl="ragged",
+        moe_renorm_topk=True,
         moe_aux_coef=0.001,
     ),
 }

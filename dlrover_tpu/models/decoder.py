@@ -21,6 +21,7 @@ One functional model covers the GPT-2 and LLaMA families (configs in
 import contextlib
 import dataclasses
 import functools
+import math
 import types
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -32,7 +33,7 @@ from jax.sharding import PartitionSpec as P
 
 from dlrover_tpu.common import device
 from dlrover_tpu.models.config import (
-    ModelConfig, lightning_log_decay, pattern_parts,
+    ATTN_KINDS, ModelConfig, lightning_log_decay, pattern_parts,
 )
 from dlrover_tpu.observability.tracing import set_counter
 from dlrover_tpu.ops import gated_delta, pallas_norm, pallas_paged, quant
@@ -444,7 +445,9 @@ def init(rng: jax.Array, cfg: ModelConfig) -> Params:
     n_dense = cfg.n_dense_layer
     params: Params = {
         "embed": {
-            "tokens": (jax.random.normal(keys[0], (v, d)) * 0.02).astype(pdt)
+            "tokens": (
+                jax.random.normal(keys[0], (v, d)) * cfg.embed_init_std
+            ).astype(pdt)
         },
         "layers": (
             _init_pattern(keys[15], cfg, cfg.layer_pattern)
@@ -936,17 +939,57 @@ def _multiplier(scale, cfg: ModelConfig):
     return 1.0 + scale.astype(jnp.float32)
 
 
-def _rope_tables(positions: jax.Array, head_dim: int, theta: float):
-    """cos/sin rope tables [B,S,1,D/2] f32 from positions [B,S] —
-    computed ONCE per forward (run_trunk / prefill / decode_step) and
-    threaded to every layer; rebuilding them per layer costs a
-    transcendental sweep per call that XLA does not hoist out of the
-    scan body."""
+def _rope_frequencies(head_dim: int, theta: float, scaling=None):
+    """A head's rope frequencies [D/2] f32, pair i at theta^(-2i/D);
+    under ``scaling`` (``cfg.rope_scaling``: factor, original length,
+    beta_fast, beta_slow, amplitude) YaRN's blend, the ``transformers``
+    reading with ``truncate``: the pair whose wavelength fits beta times
+    into the original length is c(beta) = D ln(L / (2 pi beta)) /
+    (2 ln theta); pairs up to low = floor(c(beta_fast)) keep their
+    frequency, pairs from high = ceil(c(beta_slow)) turn ``factor``
+    times slower, those between are blended linearly by index."""
     freqs = theta ** (
         -jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim
     )
-    angles = positions[..., None].astype(jnp.float32) * freqs  # [B,S,D/2]
-    return jnp.cos(angles)[:, :, None, :], jnp.sin(angles)[:, :, None, :]
+    if scaling is None:
+        return freqs
+    factor, original, beta_fast, beta_slow, _ = scaling
+
+    def pair(beta):
+        return head_dim * math.log(original / (2 * math.pi * beta)) / (
+            2 * math.log(theta)
+        )
+
+    low = max(math.floor(pair(beta_fast)), 0)
+    high = min(math.ceil(pair(beta_slow)), head_dim - 1)
+    ramp = jnp.clip(
+        (jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+        / max(high - low, 0.001),
+        0.0, 1.0,
+    )
+    return freqs * (1.0 - ramp) + freqs / factor * ramp
+
+
+def _rope_tables(positions: jax.Array, head_dim: int, theta: float,
+                 scaling=None):
+    """cos/sin rope tables [B,S,1,D/2] f32 from positions [B,S] —
+    computed ONCE per forward (run_trunk / prefill / decode_step), one
+    a rope kind the model's layers use, and threaded to the layers;
+    rebuilding them per layer costs a transcendental sweep per call
+    that XLA does not hoist out of the scan body. ``scaling``: the
+    scaled table (``_rope_frequencies``), cos and sin times its
+    amplitude, so a score of q and k both turned carries its square."""
+    with jax.named_scope("attn.rope"):
+        freqs = _rope_frequencies(head_dim, theta, scaling)
+        angles = positions[..., None].astype(jnp.float32) * freqs  # [B,S,D/2]
+
+        def table(wave):
+            t = wave(angles)
+            if scaling is not None:
+                t = t * scaling[4]
+            return t[:, :, None, :]
+
+        return table(jnp.cos), table(jnp.sin)
 
 
 def _rope(x: jax.Array, rope) -> jax.Array:
@@ -1006,11 +1049,11 @@ def _project_qkv(
     (keys "wq"/"wk"/"wv"; cfg.fp8 training only — the cache paths pass
     None and stay bf16).
 
-    ``rope``: precomputed (cos, sin) tables from ``_rope_tables`` —
-    the trunk/prefill/decode loops build them once and pass them to
-    every layer; None recomputes here (external callers, pp bodies);
-    False: this layer has no rope (an ``F`` layer of
-    ``cfg.layer_types``).
+    ``rope``: this layer's precomputed (cos, sin) tables from
+    ``_rope_tables`` — the trunk/prefill/decode loops build them once,
+    one a rope kind, and hand each layer its own; None builds the table
+    of the model's one kind here (external callers, pp bodies); False:
+    this layer has no rope (a kind of ``cfg.layer_types`` without one).
 
     ``cfg.qk_norm``: q and k are normed over their WHOLE projection
     (all heads at once; ``attn.q_norm`` / ``attn.k_norm``, scale only)
@@ -1048,9 +1091,12 @@ def _project_qkv(
         )
     if cfg.pos == "rope" and rope is not False:
         if rope is None:
-            rope = _rope_tables(positions, cfg.rope_dim, cfg.rope_theta)
-        q = _rope(q, rope)
-        k = _rope(k, rope)
+            rope = _rope_tables(
+                positions, cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling
+            )
+        with jax.named_scope("attn.rope"):
+            q = _rope(q, rope)
+            k = _rope(k, rope)
     if cfg.mup_base_width:
         q = q * (hd ** (-1.0 if mup_full_scale else -0.5))
     return q, k, v
@@ -1755,14 +1801,11 @@ def _mlp_block(x, layer, cfg: ModelConfig, mesh, fp8=None, interior=None):
     return matmul(h.astype(x.dtype), mlp["w_down"].astype(x.dtype))
 
 
-# a kind's whole attention part, kernels included, under ``attn``
-_KIND_SCOPES = {"S": "attn.window", "F": "attn.full"}
-
-
 def _kind_scope(kind: str):
+    """A kind's whole attention part, kernels included, under ``attn``."""
     if not kind:
         return contextlib.nullcontext()
-    return jax.named_scope(_KIND_SCOPES[kind])
+    return jax.named_scope(ATTN_KINDS[kind].scope)
 
 
 def _layer_body(
@@ -1779,13 +1822,16 @@ def _layer_body(
     kind: str = "",
 ):
     """``kind``: the layer's letter of ``cfg.layer_types`` ("" = the
-    model's one kind); ``attn_fn`` is then called with it."""
+    model's one kind); ``attn_fn`` is then called with it. ``rope``:
+    that kind's tables (``_project_qkv``)."""
     ln1, ln2 = layer["ln1"], layer["ln2"]
     attn_aux = {}
     if kind:
         attn_fn = functools.partial(attn_fn, kind=kind)
         if not cfg.kind_rope(kind):
-            rope = False  # a full layer: no positional term
+            # no positional term (said here: a False handed through the
+            # remat wrapper would arrive as an array)
+            rope = False
     with jax.named_scope("attn"), _kind_scope(kind):
         h = _norm_block(x, ln1, cfg)
         if cfg.selects_keys:
@@ -2579,29 +2625,40 @@ def run_trunk(
             layers = jax.tree.map(lambda t: jnp.take(t, perm, 0), layers)
 
         # rope tables hoisted out of the layer scan: one [B,S,1,D/2]
-        # cos/sin build per forward instead of one per layer. Passed as
-        # a call-time kwarg (tracers through jax.checkpoint, like rng)
-        # so the remat-wrapped body needn't close over them.
-        rope = (
-            _rope_tables(positions, cfg.rope_dim, cfg.rope_theta)
-            if cfg.pos == "rope"
-            else None
-        )
+        # cos/sin build per forward instead of one per layer, and one a
+        # rope kind that some layer is turned by (``cfg.rope_kinds``: a
+        # model of one kind builds one). Passed as a call-time kwarg
+        # (tracers through jax.checkpoint, like rng) so the
+        # remat-wrapped body needn't close over them.
+        tables = {
+            name: _rope_tables(
+                positions, cfg.rope_dim, cfg.rope_theta,
+                cfg.rope_scaling if name == "scaled" else None,
+            )
+            for name in cfg.rope_kinds
+        }
+        set_counter("attn.rope_tables", len(tables))
         first = 0
         if dense_layers is not None:
             # the prefix's aux is zeros: a dense layer routes nothing
             first = jax.tree.leaves(dense_layers)[0].shape[0]
         if cfg.layer_types:
             kinds = cfg.layer_types
+            # each kind of layer its own (None: a kind without positions)
+            ropes = {
+                kind: tables.get(cfg.kind_rope(kind)) for kind in bodies
+            }
             if first:
                 x, _ = _run_periods(
                     bodies, x, dense_layers, kinds[:first], positions, rng,
-                    rope, 0,
+                    ropes, 0,
                 )
             x, auxs = _run_periods(
-                bodies, x, layers, kinds[first:], positions, rng, rope, first
+                bodies, x, layers, kinds[first:], positions, rng, ropes,
+                first,
             )
         else:
+            rope = tables.get(cfg.kind_rope())
             if first:
                 x, _ = _run_stack(
                     body, x, dense_layers, positions, rng, rope, 0, first
@@ -2681,10 +2738,11 @@ def _period(kinds: str) -> int:
     )
 
 
-def _run_periods(bodies, x, layers, kinds: str, positions, rng, rope, first):
+def _run_periods(bodies, x, layers, kinds: str, positions, rng, ropes, first):
     """One stack of layers that differ in attention KIND and in nothing
     else (``cfg.layer_types``; ``kinds`` this stack's letters,
-    ``bodies`` kind -> its remat-wrapped body): the parameters stay one
+    ``bodies`` kind -> its remat-wrapped body, ``ropes`` kind -> its
+    rope tables, None where it has none): the parameters stay one
     stack, viewed as [periods, period, ...], and the scan runs a whole
     period of ``kinds`` a step, its layers unrolled. Returns what
     ``_run_stack`` does."""
@@ -2701,7 +2759,7 @@ def _run_periods(bodies, x, layers, kinds: str, positions, rng, rope, first):
             layer = jax.tree.map(lambda t: t[j], group)
             r = jax.random.fold_in(rng, idx[j]) if rng is not None else None
             carry, aux = bodies[kind](
-                carry, layer, positions, rng=r, rope=rope
+                carry, layer, positions, rng=r, rope=ropes[kind]
             )
             auxs.append(aux)
         return carry, jax.tree.map(lambda *ls: jnp.stack(ls), *auxs)
@@ -2845,8 +2903,12 @@ def forward(
         "attn.output_kept", kept_attention_layers(cfg, s, attn_impl, mesh)
     )
     if cfg.layer_types:
-        set_counter("attn.window_layers", cfg.layer_types.count("S"))
-        set_counter("attn.full_layers", cfg.layer_types.count("F"))
+        rules = [ATTN_KINDS[kind] for kind in cfg.layer_types]
+        set_counter("attn.window_layers", sum(r.window for r in rules))
+        set_counter("attn.full_layers", sum(not r.window for r in rules))
+        set_counter(
+            "attn.scaled_rope_layers", sum(r.rope == "scaled" for r in rules)
+        )
 
     def attn_fn(q, k, v, selected=None, kind=""):
         lse_rows = kind in keep_attn

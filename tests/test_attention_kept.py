@@ -186,6 +186,12 @@ CELL_SPANS = {
     "qwen3next-ep16-train-b1s16384": (8192.5, 8704, True),  # its one *
     # its one latent layer, 192 score channels and values padded to them
     "kimilinear-ep16-train-b1s16384": (8192.5, 8704, True),
+    # a rope per layer kind: the full layer kept; a window of 1,024 keys
+    # is a band of two tiles, 2,016 keys executed — under the line by
+    # 32, remade
+    "mellum2-ep4-train-b1s32768": {
+        "Y": (16384.5, 16896, True), "S": (1008.015625, 2016, False),
+    },
 }
 
 

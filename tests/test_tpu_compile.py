@@ -1988,3 +1988,67 @@ def test_trinity_cell_keeps_both_kinds_output(topo):
     assert calls("flash_bwd_dq", "attn.full") == 1
     assert calls("flash_bwd_dkv", "attn.full") == 1
     assert "/attn.gate/" in text
+
+
+def test_mellum_cell_builds_a_table_a_rope_kind(topo):
+    """The benchmark's Mellum2 configuration as it is run (one period
+    SSSY, 16 of 64 experts held, 1 x 32,768 tokens): the step compiles
+    for a described v5e and fits the chip's 15.75 GiB; one step holds
+    BOTH flash variants — the window layers' banded calls at a window of
+    1,024 and the full layer's over the whole causal span —; the forward
+    builds two rope tables, the plain one and YaRN's, outside the layer
+    scan, and every turning of q and k sits under the scope
+    ``attn.rope`` inside its kind's. ``remat: full`` keeps the full
+    layer's flash output (16,896 keys a query executed) and remakes the
+    window layers' (a band of two tiles of 1,024, 2,016 keys, under
+    ``KEEP_ATTN_SPAN``)."""
+    import json
+    import pathlib
+    import re
+
+    path = pathlib.Path(__file__).parent.parent / "benchmarks" / "configs"
+    config = json.loads(
+        (path / "mellum2-12b-a2.5b-ep4-1chip.json").read_text()
+    )
+    STEP_CASES["mellum-cell"] = dict(
+        model=config["program"]["model"],
+        overrides=config["program"]["overrides"],
+        optimizer=dict(state_dtype="bfloat16"), comm=None, chips=1,
+        batch=(1, 32768),
+    )
+    try:
+        builder, text, counters = _compiled_step(topo, "mellum-cell")
+    finally:
+        del STEP_CASES["mellum-cell"]
+    cfg = builder.cfg
+    assert cfg.rope_kinds == ("plain", "scaled")
+    stats = _STEP_MEMORY["mellum-cell"]
+    need = (
+        stats.argument_size_in_bytes + stats.output_size_in_bytes
+        - stats.alias_size_in_bytes + stats.temp_size_in_bytes
+    )
+    # 13.78 GB = 12.83 GiB (PR 70)
+    assert 12e9 < need < 15.75 * 2 ** 30, need
+    assert counters["attn.rope_tables"] == 2
+    assert counters["attn.scaled_rope_layers"] == 1
+    assert counters["attn.window_layers"] == 3
+    assert counters["attn.full_layers"] == 1
+    assert counters["attn.output_kept"] == 1
+    lines = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+
+    def calls(kernel, scope):
+        return sum(
+            bool(re.match(rf"\s*(?:ROOT )?%{kernel}[.\d]* = ", ln))
+            and f"/{scope}/" in ln
+            for ln in lines
+        )
+
+    # the window layers' forward runs again in the recomputed forward
+    assert calls("flash_fwd", "attn.window") == 2 * 3
+    assert calls("flash_bwd_dq", "attn.window") == 3
+    assert calls("flash_bwd_dkv", "attn.window") == 3
+    assert calls("flash_fwd", "attn.full") == 1
+    assert calls("flash_bwd_dq", "attn.full") == 1
+    assert calls("flash_bwd_dkv", "attn.full") == 1
+    assert "/attn.window/attn.rope/" in text
+    assert "/attn.full/attn.rope/" in text
