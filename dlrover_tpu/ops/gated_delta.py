@@ -57,11 +57,29 @@ devices: what the CPU tests hold to the recurrence, the fallback, and
 the oracle of the other. The Pallas kernels
 (``ops/pallas_gated_delta.py``, PR 64, ROADMAP S16(a)) run on a TPU, on
 one device, with key and value channels on the 128-lane grid and chunks
-of 64: XLA makes ``A`` and ``T`` of whole chunks (``_chunk_inverse``,
-parallel over the chunks) and the kernels walk the chunks with the state
-in VMEM, making W, U, the decay block and ``Q Kᵀ`` in each visit, behind
+of 64: XLA makes ``K Kᵀ`` and ``A`` of whole chunks
+(``_chunk_inverse``, parallel over the chunks), the kernel
+``tri_inverse`` inverts them (``pallas_gated_delta.inverse``, PR 71: the
+same substitution and merges, a grid step 128 rows of the batch turned
+to the lanes in VMEM) and the kernels walk the chunks with the state in
+VMEM, making W, U, the decay block and ``Q Kᵀ`` in each visit, behind
 one ``jax.custom_vjp`` (``_kernel_rule``). Whichever body runs, the
 caller runs it under the scope ``gdn.rule``.
+
+WHAT CROSSES HBM BETWEEN XLA AND THE KERNELS IS AS WIDE AS THE LANES
+(PR 71, ROADMAP S16(d)). A float32 array that ends in [64, 64] is 268 MB
+where its numbers are 134 (8,192 chunk-heads a layer at 16,384 tokens
+and 32 value heads): an (8, 128) tile pads the 64 columns to 128 lanes,
+and every pass over it moves the padding. A key head's R value heads
+are one visit's, so ``A``, ``T`` and T's cotangent lie R matrices SIDE
+BY SIDE on the last axis, ``[B, N, Hk, C, R C]`` (value head r is
+columns ``[C r, C r + C)``; [.., 64, 128] at R = 2, nothing padded),
+made in that order from the start (``_chunk_inverse(side_by_side=
+True)``), inverted in that form and read by a lane slice in the visit;
+the hand derivative takes them so too (``_inverse_pullback``: products over
+all the lanes against T's blocks on a diagonal). R = 1 is the same code
+at one matrix a row. The XLA body keeps ``[.., R, C, C]``: one
+algorithm, the layout derived from the shapes.
 
 What is kept and what is remade. ON THE XLA BODY the sequence goes
 through in STRETCHES of 2,048 tokens (32 chunks of 64), one after
@@ -83,16 +101,18 @@ on the XLA body runs the rule's forward THREE times (the layer's
 forward, the layer's remade forward, each stretch's remade forward) and
 its backward once. ON THE KERNELS there is no stretch and no
 checkpoint: the residuals are the five operands (q, k, v, g, β), the
-backward rule makes A and T again (XLA, a pass over whole chunks: the
-compiler shares it with the layer's remade forward, the same work on
-the same operands), takes every chunk's starting state from a pass of
-its own (``gdn_states``: 537 MB a layer, alive inside that layer's
-backward alone) and walks back remaking each chunk's operands in the
-visit; T's cotangent comes out of the walk and goes through
-``unit_lower_inverse``'s hand derivative. A step runs the rule's
-forward TWICE (the layer's and ``remat: full``'s remade one) and its
-backward once, with 0.95 GB of temporaries forward and 2.3 GB going
-back (the compiler's count for a described v5e).
+backward rule makes A and T again (``K Kᵀ`` and A XLA's, T the inverse
+kernel's: the compiler shares them with the layer's remade forward, the
+same work on the same operands), takes every chunk's starting state
+from a pass of its own (``gdn_states``: 537 MB a layer, alive inside
+that layer's backward alone) and walks back remaking each chunk's
+operands in the visit; T's cotangent comes out of the walk and goes
+through the inverse's hand derivative and A's pull-back to k, g and β
+(XLA's). A step runs the rule's forward TWICE (the layer's and ``remat:
+full``'s remade one) and its backward once, with 0.82 GB of temporaries
+forward and 2.03 GB going back (the compiler's count for a described
+v5e; 0.95 and 2.3 before PR 71, when A, T and dT were [.., R, 64, 64]
+and the substitution XLA's).
 
 A length that is no multiple of the chunk is PADDED at its end with
 tokens of g = 0, β = 0 and k = 0, which leave every state as it was and
@@ -143,9 +163,10 @@ makes γ from g — a triangle of ones times g in three bf16 pieces —, the
 four diagonal sub-blocks from explicit differences with the sums over
 the 128 channels on the vector unit's lanes, the three block rows left
 of them as one product each, and writes ``A = strict_lower(β_i kk)`` and
-the decayed ``Q Kᵀ``, M) → XLA's ``unit_lower_inverse`` over all
-chunk-heads at once (the substitution wants the batch of chunks on the
-lanes; in a visit it is 16 dependent sublane steps) → the walk
+the decayed ``Q Kᵀ``, M) → the inverse of all chunk-heads at once
+(the kernel ``tri_inverse`` since PR 71, XLA's ``unit_lower_inverse``
+before: the substitution wants the batch of chunks on the lanes; in a
+visit it is 16 dependent sublane steps) → the walk
 (``kda_fwd``: one head a visit, the state [Dv, Dk] float32 in VMEM from
 the first chunk to the last, γ, ``K ⊙ e^γ``, ``Q ⊙ e^γ``,
 ``K ⊙ e^{γ_C − γ}``, W, U and V' made in the visit and never written).
@@ -156,14 +177,18 @@ makes A, M and T again (the compiler shares them with the layer's remade
 forward), takes every chunk's starting state from ``kda_states`` (537 MB
 a layer, alive inside that layer's backward alone, behind a barrier),
 walks back (``kda_bwd``: the chunk's operands remade in the visit; dv,
-its parts of dq, dk, dγ and dβ, and dT and dM, 268 MB each as tiles pad
-them), takes dT through the inverse's hand derivative and the pairs'
-cotangents back through ``kda_pairs_bwd``, which adds the walk's parts
-and writes dq, dk, dg and dβ whole. A step under ``remat: full`` runs
-the rule's forward TWICE and its backward once, with 1.62 GB of
-temporaries forward and 2.97 GB going back by the compiler's count for a
-described v5e (the Kimi-Linear cell's step: 15.85 GB by that count, of
-the chip's 16.91; 15.12 on the XLA body).
+its parts of dq, dk, dγ and dβ, and dT and dM), takes dT through the
+inverse's hand derivative (XLA's) and the pairs' cotangents back through
+``kda_pairs_bwd``, which adds the walk's parts and writes dq, dk, dg and
+dβ whole. A, M, T, dT, dM and dA are still ``[B, N, H, 64, 64]`` between
+these kernels, 268 MB each as tiles pad them: two chunks of a head side
+by side were built and measured slower (PR 71: a visit's chunks are a
+rolled loop, so a chunk's half of a row is chosen and kept by selects;
+``kda_pairs`` +0.8 ms a call, ``kda_pairs_bwd`` +1.5, ``kda_bwd`` +0.3:
+ROADMAP S16(d)). A step under ``remat: full`` runs the rule's forward
+TWICE and its backward once, with 1.61 GB of temporaries forward and
+2.96 GB going back by the compiler's count for a described v5e (the
+Kimi-Linear cell's step: 14.12 GB by that count, of the chip's 16.91).
 """
 
 import functools
@@ -292,30 +317,61 @@ def _inverse_of(a):
     return _two_by_two(t11, t21, t22)
 
 
-@jax.custom_vjp
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _inverse(a, in_kernel):
+    """``(I + a)^{-1}`` of every strictly lower [C, C] matrix of a
+    [..., C, P C] float32, P of them side by side on the last axis
+    (matrix p is columns ``[C p, C p + C)``). ``in_kernel``: by
+    ``pallas_gated_delta.inverse``, which takes any P (what the rules'
+    kernel paths call, where ``in_kernels`` holds); else by
+    ``_inverse_of``, one matrix a row. One hand-written derivative
+    serves both."""
+    if in_kernel:
+        return pallas_gated_delta.inverse(a)
+    return _inverse_of(a.reshape((-1,) + a.shape[-2:])).reshape(a.shape)
+
+
+def _inverse_fwd(a, in_kernel):
+    t = _inverse(a, in_kernel)
+    return t, t
+
+
+def _inverse_pullback(t, dt):
+    """A's cotangent from T's: d(I + a)^{-1} = −T da T, so the cotangent
+    takes T's transpose, ``dA = −strict_lower(Tᵀ dT Tᵀ)`` matrix by
+    matrix. With P matrices side by side ([..., C, P C]): dT Tᵀ of all P
+    in one product over the lanes, against T's blocks on a diagonal
+    (rows (p, c), matrix p's lanes, zeros elsewhere); then Tᵀ · of every
+    pair of matrices, of which a matrix's own columns are kept. No array
+    is narrower than T."""
+    c, wide = t.shape[-2:]
+    p = wide // c
+    own = jnp.arange(wide) // c == jnp.arange(p)[:, None, None]
+    on_diagonal = jnp.where(own, t[..., None, :, :], 0.0).reshape(
+        t.shape[:-2] + (wide, wide)
+    )
+    dt_tt = jnp.einsum(
+        "...il,...cl->...ic", dt, on_diagonal, precision=_HIGHEST
+    )
+    every = jnp.einsum(
+        "...ia,...ic->...ac", t, dt_tt, precision=_HIGHEST
+    ).reshape(t.shape[:-2] + (p, c, wide))
+    strict = jnp.tile(jnp.tril(jnp.ones((c, c), bool), -1), (1, p))
+    return jnp.sum(jnp.where(own & strict, -every, 0.0), axis=-3)
+
+
+def _inverse_bwd(_, t, dt):
+    return (_inverse_pullback(t, dt),)
+
+
+_inverse.defvjp(_inverse_fwd, _inverse_bwd)
+
+
 def unit_lower_inverse(a):
     """``(I + a)^{-1}`` for a [..., C, C] float32 that is STRICTLY lower
     triangular (what lies on or above the diagonal is the caller's to
     have zeroed), C a power of two or under 16."""
-    return _inverse_of(a.reshape((-1,) + a.shape[-2:])).reshape(a.shape)
-
-
-def _inverse_fwd(a):
-    t = unit_lower_inverse(a)
-    return t, t
-
-
-def _inverse_bwd(t, dt):
-    # d(I + a)^{-1} = −T da T: the cotangent takes T's transpose
-    tt = jnp.swapaxes(t, -1, -2)
-    da = -jnp.matmul(
-        jnp.matmul(tt, dt, precision=_HIGHEST), tt, precision=_HIGHEST
-    )
-    c = t.shape[-1]
-    return (jnp.where(jnp.tril(jnp.ones((c, c), bool), -1), da, 0.0),)
-
-
-unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
+    return _inverse(a, False)
 
 
 def _products(dtype):
@@ -329,17 +385,72 @@ def _products(dtype):
     )
 
 
+def _heads_side_by_side(kk, rows):
+    """``A = strict_lower(β_i (k_i · k_j) e^{γ_i − γ_j})`` of a key
+    head's R value heads SIDE BY SIDE on the lanes: kk [B, N, Hk, C, C]
+    and rows [B, N, Hk, 2 R, C] (γ a row a token of each head, then β)
+    float32 give [B, N, Hk, C, R C], value head r as columns
+    ``[C r, C r + C)``, made in that order from the start (an array
+    that ends in [C, C] is padded to the 128 lanes of a tile, twice its
+    bytes at C = 64; one the R heads share a row of is not). What the
+    kernel ``tri_inverse`` makes for itself going forward
+    (``pallas_gated_delta.gated_inverse``); here for its pull-back to
+    kk, γ and β."""
+    chunk = kk.shape[-1]
+    n_heads = rows.shape[3] // 2
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
+    heads = []
+    for r in range(n_heads):
+        gamma, beta = rows[..., r, :], rows[..., n_heads + r, :]
+        # the difference first, then the exponential
+        decay = jnp.exp(jnp.where(
+            lower, gamma[..., :, None] - gamma[..., None, :], -jnp.inf
+        ))
+        heads.append(jnp.where(strict, beta[..., :, None] * kk * decay, 0.0))
+    return jnp.concatenate(heads, axis=-1)
+
+
+@jax.custom_vjp
+def _gated_inverse(kk, rows):
+    """``(I + A)^{-1}`` of ``_heads_side_by_side``'s A, in its form, by
+    the kernel that makes A as it goes; the derivative by hand through
+    the inverse (``_inverse_pullback``), then A's pull-back by JAX."""
+    return pallas_gated_delta.gated_inverse(kk, rows)
+
+
+def _gated_inverse_fwd(kk, rows):
+    t = _gated_inverse(kk, rows)
+    return t, (kk, rows, t)
+
+
+def _gated_inverse_bwd(kept, dt):
+    kk, rows, t = kept
+    _, pull = jax.vjp(_heads_side_by_side, kk, rows)
+    return pull(_inverse_pullback(t, dt))
+
+
+_gated_inverse.defvjp(_gated_inverse_fwd, _gated_inverse_bwd)
+
+
+def _chunk_gates(k, g, beta):
+    """What a chunk's keys and gates are multiplied and summed to before
+    anything is inverted: k [B, N, C, Hk, Dk], g and beta
+    [B, N, C, Hk, R] float32 give (``K Kᵀ`` [B, N, Hk, C, C], gamma
+    [B, N, C, Hk, R], then a token last, gamma and beta
+    [B, N, Hk, R, C])."""
+    gamma = jnp.cumsum(g, axis=2)
+    kk = _products(k.dtype)("bnikd,bnjkd->bnkij", k, k)
+    return kk, gamma, jnp.moveaxis(gamma, 2, -1), jnp.moveaxis(beta, 2, -1)
+
+
 def _chunk_inverse(k, g, beta, chunk):
     """What a chunk's keys and gates alone decide, from whole chunks: k
     [B, N, C, Hk, Dk], g and beta [B, N, C, Hk, R] float32. Returns (the
     triangular inverse T [B, N, Hk, R, C, C] float32, gamma
     [B, N, C, Hk, R], then a token last, gamma and beta [B, N, Hk, R, C],
     and the decay block on and under the diagonal [B, N, Hk, R, C, C])."""
-    dot = _products(k.dtype)
-    gamma = jnp.cumsum(g, axis=2)
-    kk = dot("bnikd,bnjkd->bnkij", k, k)
-    gamma_t = jnp.moveaxis(gamma, 2, -1)             # [B, N, Hk, R, C]
-    beta_t = jnp.moveaxis(beta, 2, -1)
+    kk, gamma, gamma_t, beta_t = _chunk_gates(k, g, beta)
     lower = jnp.tril(jnp.ones((chunk, chunk), bool))
     # the difference first, then the exponential
     decay = jnp.exp(jnp.where(
@@ -572,24 +683,22 @@ def _channel_stretch(state, q, k, v, g, beta, chunk):
 
 
 def _kernel_operands(k, g, beta):
-    """What XLA makes of whole chunks for the kernels
+    """What is made of whole chunks for the kernels' walk
     (``ops/pallas_gated_delta.py``), parallel over the chunks: k
-    [B, S, Hk, Dk], g and beta [B, S, Hk, R] float32. Returns (T
-    [B, N, Hk, R, C, C], gamma a column a token [B, N, Hk, C, R], gamma
-    then beta a row a token [B, N, Hk, 2 R, C]) float32."""
+    [B, S, Hk, Dk], g and beta [B, S, Hk, R] float32. Returns (T a key
+    head's R value heads side by side [B, N, Hk, C, R C], gamma a column
+    a token [B, N, Hk, C, R], gamma then beta a row a token
+    [B, N, Hk, 2 R, C]) float32: ``K Kᵀ`` and the running sums XLA's, T
+    the kernel ``tri_inverse``'s."""
     chunk = pallas_gated_delta.CHUNK
     b, s = k.shape[:2]
 
     def cut(t):
         return t.reshape((b, s // chunk, chunk) + t.shape[2:])
 
-    t, gamma, gamma_t, beta_t, _ = _chunk_inverse(
-        cut(k), cut(g), cut(beta), chunk
-    )
-    return (
-        t, jnp.moveaxis(gamma, 2, 3),
-        jnp.concatenate([gamma_t, beta_t], axis=3),
-    )
+    kk, gamma, gamma_t, beta_t = _chunk_gates(cut(k), cut(g), cut(beta))
+    rows = jnp.concatenate([gamma_t, beta_t], axis=3)
+    return _gated_inverse(kk, rows), jnp.moveaxis(gamma, 2, 3), rows
 
 
 def _flat(q, k, v):
@@ -660,7 +769,7 @@ def _channel_kernel_rule(q, k, v, g, beta):
     qf, kf, vf, gf, rows = _channel_operands(q, k, v, g, beta)
     a, scores = pallas_kda.pairs(qf, kf, gf, rows, dk)
     return pallas_kda.forward(
-        qf, kf, vf, gf, rows, unit_lower_inverse(a), scores, dk, dv
+        qf, kf, vf, gf, rows, _inverse(a, True), scores, dk, dv
     ).reshape(v.shape)
 
 
@@ -673,7 +782,7 @@ def _channel_kernel_rule_bwd(operands, do):
     dk, dv = k.shape[-1], v.shape[-1]
     qf, kf, vf, gf, rows = _channel_operands(*operands)
     a, scores = pallas_kda.pairs(qf, kf, gf, rows, dk)
-    t, pull = jax.vjp(unit_lower_inverse, a)
+    t, pull = jax.vjp(lambda a: _inverse(a, True), a)
     dq, dk_walk, dval, dgamma, dbeta, dt, dscores = pallas_kda.backward(
         qf, kf, vf, gf, rows, t, scores,
         do.reshape(do.shape[:2] + (-1,)), dk, dv,

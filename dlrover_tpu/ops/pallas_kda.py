@@ -38,11 +38,20 @@ three bf16 pieces — read 13.5 (my chip runs, PR 66: the stacked tiles
 cross VMEM and are split into pieces on the same vector slots the lane
 sums use).
 
-Between the pairs and the walk XLA makes ``T = (I + A)^{-1}`` of whole
-chunks and nothing else (``gated_delta.unit_lower_inverse``;
+Between the pairs and the walk ``T = (I + A)^{-1}`` of whole chunks is
+made and nothing else: since PR 71 by the kernel ``tri_inverse``
+(``pallas_gated_delta.inverse``, at one matrix a row of its batch;
 ``pallas_gated_delta``'s docstring says why the substitution is not a
-visit's work); β goes on T's COLUMNS in the visit: ``W = (T ⊙ β) (K ⊙
-e^γ)``, ``U = (T ⊙ β) V``.
+visit's work), XLA keeping the hand derivative that takes dT to dA; β
+goes on T's COLUMNS in the visit: ``W = (T ⊙ β) (K ⊙ e^γ)``,
+``U = (T ⊙ β) V``. A, M, T, dT, dM and dA stay ``[B, N, H, C, C]``
+between these kernels: two neighbouring chunks of a head side by side
+on the 128 lanes (``[B, N / 2, H, C, 2 C]``, nothing padded) were built
+and measured (PR 71, one layer's rule on a v5e): a visit's chunks are a
+rolled loop, so a chunk's half of a row is read through a select and
+written by reading the row, selecting and storing it whole, and
+``kda_pairs`` read 6.20 ms a call for 5.43, ``kda_pairs_bwd`` 10.86 for
+9.33, ``kda_bwd`` 8.22 for 7.93 — more than the halved arrays gave back.
 
 The walk (``kda_fwd``, ``kda_states``, ``kda_bwd``), the chunk axis
 sequential, the head's state in VMEM scratch from the first chunk to the

@@ -131,25 +131,76 @@ def test_identical_keys_stay_finite_and_exact():
     )
 
 
-@pytest.mark.parametrize("c", [8, 16, 32, 128])
-def test_unit_lower_inverse_and_its_derivative(c):
-    a = jnp.tril(jax.random.normal(jax.random.key(c), (3, 2, c, c)), -1)
+# (C, matrices side by side, rows of the batch): the XLA body's inverse,
+# one matrix a row, at a chunk under the base block, of a block exactly,
+# of one merge, of two and of three; then the kernel's
+# (``pallas_gated_delta.inverse``, interpreted; 0 rows: the XLA body's),
+# chunks of 64 with R = 1, 2 and 3 matrices side by side on the lanes,
+# the batch padded to a grid step's 128 rows and over two grid steps
+INVERSE_CASES = [
+    (8, 1, 0), (16, 1, 0), (32, 1, 0), (128, 1, 0), (64, 1, 0),
+    (64, 1, 6), (64, 2, 6), (64, 3, 5), (64, 2, 130),
+]
+
+
+def _side_by_side(x):
+    """[N, P, C, C] -> [N, C, P C]: matrix p as columns [C p, C p + C)."""
+    n, p, c, _ = x.shape
+    return jnp.moveaxis(x, 1, 2).reshape(n, c, p * c)
+
+
+@pytest.mark.parametrize(
+    "c,side,rows", INVERSE_CASES,
+    ids=[f"{c}x{p}" + f"-kernel-{n}" * bool(n) for c, p, n in INVERSE_CASES],
+)
+def test_unit_lower_inverse_and_its_derivative(c, side, rows, monkeypatch):
+    """Against ``jnp.linalg``. The kernel's inverse — P matrices side by
+    side on the last axis ([N, C, P C]: how the rules' kernels take T
+    and hand back its cotangent) — also against ``unit_lower_inverse``
+    of the same matrices one a row, inverse and derivative."""
+    n = rows or 6
+    a = jnp.tril(jax.random.normal(jax.random.key(c), (n, side, c, c)), -1)
     a = a * (0.8 / c ** 0.5)
-    want = jnp.linalg.inv(jnp.eye(c) + a)
-    np.testing.assert_allclose(
-        np.asarray(inverse(a)), np.asarray(want),
-        rtol=1e-4, atol=1e-4,
-    )
     w = jax.random.normal(jax.random.key(1), a.shape)
-    got = jax.jit(jax.grad(lambda a: (inverse(a) * w).sum()))(a)
+    want = jnp.linalg.inv(jnp.eye(c) + a)
     by_jax = jax.grad(
         lambda a: (jnp.linalg.inv(jnp.eye(c) + jnp.tril(a, -1)) * w).sum()
     )(a)
     scale = float(jnp.max(jnp.abs(by_jax)))
+    got = inverse(a)
+    got_grad = jax.jit(jax.grad(lambda a: (inverse(a) * w).sum()))(a)
+    if rows:
+        monkeypatch.setattr(pallas_attention, "INTERPRET", True)
+        in_kernel = jax.jit(lambda a: gd._inverse(a, True))
+        assert "name=tri_inverse" in str(
+            jax.make_jaxpr(in_kernel)(_side_by_side(a))
+        )
+        each, each_grad = _side_by_side(got), _side_by_side(got_grad)
+        want, by_jax = _side_by_side(want), _side_by_side(by_jax)
+        got = in_kernel(_side_by_side(a))
+        got_grad = jax.jit(jax.grad(
+            lambda a: (in_kernel(a) * _side_by_side(w)).sum()
+        ))(_side_by_side(a))
+        assert got.shape == (n, c, side * c)
+        # the same substitution, the same merges: float32's last bits
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(each), rtol=2e-6, atol=2e-6
+        )
+        np.testing.assert_allclose(
+            np.asarray(got_grad) / scale, np.asarray(each_grad) / scale,
+            atol=2e-6,
+        )
     np.testing.assert_allclose(
-        np.asarray(got) / scale, np.asarray(by_jax) / scale, atol=1e-4
+        np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-4
     )
-    assert not np.asarray(jnp.triu(got)).any()
+    np.testing.assert_allclose(
+        np.asarray(got_grad) / scale, np.asarray(by_jax) / scale, atol=1e-4
+    )
+    # nothing on or above a matrix's diagonal
+    upper = jnp.tile(jnp.triu(jnp.ones((c, c), bool)), (1, side))
+    if not rows:
+        upper = upper[:, :c]
+    assert not np.asarray(jnp.where(upper, got_grad, 0.0)).any()
 
 
 def test_shapes_that_fit_nothing_are_refused_by_name():
@@ -187,14 +238,19 @@ def _wide(s, **kw):
     return _inputs(s, b=1, dk=128, dv=128, **kw)
 
 
-# R = 2 value heads a key head, whose fourth head's γ passes −100 inside
-# a chunk of 64; three chunks, so that the state and its cotangent cross
-# visits twice; a length that is no multiple of the chunk
+# R = 2 value heads a key head (T [.., 64, 128]: the two side by side on
+# the lanes), whose fourth head's γ passes −100 inside a chunk of 64;
+# three chunks, so that the state and its cotangent cross visits twice; a
+# length that is no multiple of the chunk
 KERNEL_CASES = {
     "three-chunks": lambda: _wide(192),
     "padded": lambda: _wide(150, hk=1),
     "one-chunk-two-batches": lambda: _inputs(64, hk=1, dk=128, dv=128),
     "identical-keys": lambda: _identical_keys(128),
+    # one value head a key head (T a matrix a row, the form before the
+    # heads lay side by side) and four (two tiles of lanes)
+    "one-value-head": lambda: _wide(192, hk=2, r=1),
+    "four-value-heads": lambda: _wide(128, hk=1, r=4),
 }
 
 
@@ -291,16 +347,28 @@ def test_fallback_is_the_xla_body_bit_for_bit(interpreted, why):
     )
 
 
+def _outside_kernels(jaxpr):
+    """The primitives of a traced program, those inside scans,
+    checkpoints and custom derivatives among them, a kernel's body
+    not."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from _outside_kernels(sub)
+
+
 def test_kernel_path_has_no_scan_over_chunks(interpreted):
     """The 256-step dependence is the kernels' grid: the traced program
-    of the kernel path holds three kernels by name and no ``scan`` or
-    ``while`` of its own; the XLA body's holds both of its scans."""
+    of the kernel path holds the walk's three kernels and the inverse's
+    by name and, outside their bodies, no ``scan`` or ``while``; the XLA
+    body's holds both of its scans."""
     args = _wide(256, hk=1)
     loss = lambda *a: gd.gated_delta_rule(*a, stretch=128).sum()  # noqa: E731
-    text = str(jax.make_jaxpr(jax.grad(loss, range(5)))(*args))
-    for name in ("gdn_fwd", "gdn_states", "gdn_bwd"):
-        assert f"name={name}" in text, name
-    assert "scan[" not in text and "while[" not in text
+    traced = jax.make_jaxpr(jax.grad(loss, range(5)))(*args)
+    for name in ("gdn_fwd", "gdn_states", "gdn_bwd", "tri_inverse"):
+        assert f"name={name}" in str(traced), name
+    assert not {"scan", "while"} & set(_outside_kernels(traced.jaxpr))
     mesh = jax.make_mesh((2,), ("dp",))
     xla = str(jax.make_jaxpr(jax.grad(
         lambda *a: gd.gated_delta_rule(*a, stretch=128, mesh=mesh).sum(),

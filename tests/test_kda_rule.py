@@ -244,12 +244,17 @@ def _wide(s, **kw):
     return _inputs(s, **{"b": 1, "dk": 128, "dv": 128, **kw})
 
 
-# two chunks, so that the state and its cotangent cross a visit; three
-# with a length that is no multiple of the chunk; two sequences
+# two chunks, so that the state and its cotangent cross a visit (an even
+# count: A, M, T and their cotangents go two chunks to a row of 128
+# lanes); three with a length that is no multiple of the chunk (an odd
+# count: a chunk a row); two sequences; four chunks a visit, two rows;
+# six, three visits of one row
 KERNEL_CASES = {
     "two-chunks": lambda: _wide(128),
     "padded-to-three": lambda: _wide(150),
     "two-batches-one-head": lambda: _wide(128, b=2, hk=1),
+    "four-chunks-a-visit": lambda: _wide(256, hk=1),
+    "six-chunks": lambda: _wide(384, hk=1),
 }
 
 

@@ -474,10 +474,11 @@ CASES = {
     # still needs the forward kernel, for the chunks' starting states
     "sscan-fwd-5120x16": (_sscan(grad=False), 1),
     "sscan-bwd-5120x16": (_sscan(grad=True), 2),
-    # a gated-delta-rule layer's walk over the chunks (Qwen3-Next): the
-    # gradient alone needs the state pass and the walk back
-    "gdn-fwd-16x2x128": (_gdn(grad=False), 1),
-    "gdn-bwd-16x2x128": (_gdn(grad=True), 2),
+    # a gated-delta-rule layer's walk over the chunks (Qwen3-Next)
+    # behind the triangular inverse of whole chunks: the gradient alone
+    # needs the inverse, the state pass and the walk back
+    "gdn-fwd-16x2x128": (_gdn(grad=False), 2),
+    "gdn-bwd-16x2x128": (_gdn(grad=True), 3),
     # both mixers' causal conv (``ops/pallas_conv.py``)
     "conv-fwd-10240-bf16": (_conv(10240, BF16, grad=False), 1),
     "conv-bwd-10240-bf16": (_conv(10240, BF16, grad=True), 2),
@@ -536,15 +537,33 @@ def test_kernel_compiles_for_v5e(chip, case):
         names = ("sscan_fwd", "sscan_bwd") if "bwd" in case else ("sscan_fwd",)
         assert all(f"%{name}" in text for name in names)
     if case.startswith("gdn-"):
+        import math
+        import re
+
         names = ("gdn_states", "gdn_bwd") if "bwd" in case else ("gdn_fwd",)
         assert all(f"%{name}" in text for name in names)
+        assert _kernel_calls(text, "tri_inverse") == 1
         # the 256 chunks' dependence is the kernels' grid: no loop of
         # the compiler's around a chunk step, and what XLA makes of
-        # whole chunks beside them (T; going back the states, 537 MB,
-        # and T's cotangent) fits beside the cell's 9.4 GB of state
+        # whole chunks beside them (K K^T and T; going back the states,
+        # 537 MB, T's cotangent and A's) fits beside the cell's 9.4 GB
+        # of state: 0.81 and 2.03 GB by the compiler's count (0.95 and
+        # 2.3 before PR 71)
         assert "while(" not in text and " while " not in text
         temp = compiled.memory_analysis().temp_size_in_bytes
-        assert temp < (2.6e9 if "bwd" in case else 1.2e9)
+        assert temp < (2.23e9 if "bwd" in case else 0.89e9)
+        # T and the cotangents of T and A cross HBM a key head's two
+        # value heads side by side, f32[.., 64, 128], and A going
+        # forward not at all: no array of the 8,192 chunk-heads ends in
+        # [64, 64] or [32, 32], which a tile pads to its 128 lanes
+        # (K K^T and its cotangent, a matrix a KEY head, are what is
+        # left)
+        padded = re.findall(r"f32\[([\d,]*),(?:64,64|32,32)\]", text)
+        assert padded
+        assert all(
+            math.prod(map(int, dims.split(","))) <= 256 * 16
+            for dims in padded
+        ), sorted(set(padded))
     if case.startswith("l2-heads-"):
         import re
 
@@ -646,17 +665,19 @@ def test_vector_delta_rule_compiles_a_stretch_at_a_time(topo, chip):
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "bwd"])
 def test_vector_delta_rule_kernels_compile_for_v5e(chip, grad):
     """The same rule on its kernels (``ops/pallas_kda.py``: what a v5e
-    runs at these widths on one device): the pairs and the walk going
-    forward; going back the pairs again, the state pass, the walk back
-    and the pairs' pull-back — no loop of the compiler's around a chunk
-    step and no stretch. What XLA holds around them, by the compiler's
+    runs at these widths on one device): the pairs, the triangular
+    inverse and the walk going forward; going back the pairs and the
+    inverse again, the state pass, the walk back and the pairs'
+    pull-back — no loop of the compiler's around a chunk step and no
+    stretch. What XLA holds around them, by the compiler's
     count: 1.62 GB of temporaries forward (A, M and T, float32
     [256, 32, 64, 64] each, the 64 padded to 128 lanes: 268 MB, and what
     the substitution holds between A and T) and 2.97 GB going back
     (those, the states every chunk starts from, 537 MB, the walk's parts
     of dq, dk and dγ, 268 MB each, and dT, dM and dA), which the cell's
     step fits beside its train state
-    (``test_kimi_cell_fits_the_chip``). Neither a sub-block's
+    (``test_kimi_cell_fits_the_chip``): 1.61 and 2.96 GB since T is the
+    kernel ``tri_inverse``'s (PR 71). Neither a sub-block's
     [16, 16, 128] nor a chunk's [64, 64, 128] differences exist as an
     array."""
     import re
@@ -671,14 +692,17 @@ def test_vector_delta_rule_kernels_compile_for_v5e(chip, grad):
     names = (
         ("kda_pairs", "kda_states", "kda_bwd", "kda_pairs_bwd") if grad
         else ("kda_pairs", "kda_fwd")
-    )
+    ) + ("tri_inverse",)
     assert text.count("tpu_custom_call") == len(names)
     for name in names:
         assert re.search(rf"%\w*{name}[_.\d]* = ", text), name
     assert "while(" not in text and " while " not in text
     temp = compiled.memory_analysis().temp_size_in_bytes
-    assert temp < (3.3e9 if grad else 1.8e9)
+    assert temp < (3.26e9 if grad else 1.77e9)
     assert not re.search(r"f32\[[\d,]*(16,16|64,64),128\]", text)
+    # the inverse is a kernel: nothing of the substitution's — blocks of
+    # 16 or 32 rows, the batch on the lanes — is XLA's
+    assert not re.search(r"f32\[[\d,]*(16,16|32,32),8192\]", text)
 
 
 @pytest.mark.parametrize(
@@ -1217,11 +1241,14 @@ def test_backward_tile_of_1024_is_refused_at_head_size_256(chip, monkeypatch):
 
 
 def _kernel_calls(text, kernel):
-    """Custom calls of ``kernel`` in a compiled step's text."""
+    """Custom calls of ``kernel`` in a compiled step's text (one traced
+    under a derivative's rule is ``jvp_<kernel>_``)."""
     import re
 
     return sum(
-        bool(re.match(rf"\s*(?:ROOT )?%{kernel}[.\d]* = .*tpu_custom_call", ln))
+        bool(re.match(
+            rf"\s*(?:ROOT )?%(?:jvp_)?{kernel}[_.\d]* = .*tpu_custom_call", ln
+        ))
         for ln in text.splitlines()
     )
 
@@ -1324,8 +1351,8 @@ def test_kimi_cell_fits_the_chip(topo):
     # and again in the one ``full`` remakes (2 x 4 x 2), their
     # derivatives once (2 x 4)
     for kernel, calls in (
-        ("kda_pairs", 8), ("kda_fwd", 8), ("kda_states", 4),
-        ("kda_bwd", 4), ("kda_pairs_bwd", 4),
+        ("kda_pairs", 8), ("tri_inverse", 8), ("kda_fwd", 8),
+        ("kda_states", 4), ("kda_bwd", 4), ("kda_pairs_bwd", 4),
         ("l2_heads_fwd", 16), ("l2_heads_bwd", 8),
     ):
         assert _kernel_calls(text, kernel) == calls, kernel
@@ -1810,6 +1837,17 @@ SCAN_BODY_BUDGET = {
     # twice a process, once for q's scale and once for k's)
     "l2-heads-32x128": {"l2_heads_fwd": 83, "l2_heads_bwd": 145},
     "l2-heads-16x128": {"l2_heads_fwd": 83, "l2_heads_bwd": 145},
+    # the gated delta rule's walk (212 / 158 / 492) and, since PR 71, the
+    # triangular inverse before it (926 where it makes A of two value
+    # heads itself, 893 of a given A: the fifteen steps of a diagonal
+    # block and the 16 or 32 terms of a product's eight rows are
+    # unrolled — rolled they were 412 equations and 2.3 times the
+    # kernel's time —, the blocks, the pairs merged, the rows of eight
+    # and the transposes are rolled loops; traced once a process)
+    "gdn-bwd-16x2x128": {
+        "tri_inverse": 1018, "gdn_fwd": 233, "gdn_states": 174,
+        "gdn_bwd": 541,
+    },
 }
 
 
