@@ -15,6 +15,7 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
+from dlrover_tpu.observability.tracing import set_counter
 from dlrover_tpu.ops import pallas_rows
 from dlrover_tpu.parallel import sharding as shd
 
@@ -515,10 +516,12 @@ def _rows(x, idx):
 # expert order — ``_sort_by_expert`` sends the others to the tail —,
 # ``Held.rows`` of them, a number the device knows and the trace does
 # not. The arrays keep their static rows; the sums over a token's held
-# rows (``pallas_rows.rows_sum``) and the combine's derivative
-# (``_combine_bwd_held``) walk the prefix alone, by that count: no static
-# bound, nothing dropped. ``held is None`` — every expert here — is the
-# other structure, with nothing to skip, and keeps its own program.
+# rows (``pallas_rows.rows_sum``), the combine's derivative
+# (``_combine_bwd_held``) and the experts' interior between the grouped
+# matmuls (``_interior_held``) walk the prefix alone, by that count: no
+# static bound, nothing dropped. ``held is None`` — every expert here —
+# is the other structure, with nothing to skip, and keeps its own
+# program.
 
 
 @functools.partial(
@@ -542,6 +545,13 @@ class Held:
         if not self.one_device:
             return None
         return pallas_rows.tile(t, *rows.shape, dtype)
+
+    def interior_tiles(self, up, gated):
+        """``pallas_rows.act_tile`` for the experts' interior over
+        ``up`` [n, d_expert], None where the XLA body runs."""
+        if not self.one_device:
+            return None
+        return pallas_rows.act_tile(*up.shape, up.dtype, gated)
 
 
 # rows a turn of the loops over the held prefix (chip sweep in
@@ -675,19 +685,56 @@ def _sort_by_expert(xt, gate_idx, e, some_elsewhere=False, one_device=False):
     return flat_idx, order, inv, sorted_in, counts
 
 
-def _ragged_experts(rows, w_up, w_gate_proj, w_down, group_sizes):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _interior_held(up, gate, held_rows, tiles):
+    """The experts' activation between the grouped matmuls over the held
+    prefix of the expert order alone (``pallas_rows.experts_act``, and
+    ``experts_act_bwd`` coming back): ``ragged_dot`` wrote ``up`` and
+    ``gate`` inside its groups, the first ``held_rows`` rows, and reads
+    ``h`` and the cotangents there and nowhere else, so the rows behind
+    them are neither read nor written."""
+    return pallas_rows.experts_act(up, gate, held_rows, tiles)
+
+
+def _interior_held_fwd(up, gate, held_rows, tiles):
+    return _interior_held(up, gate, held_rows, tiles), (up, gate, held_rows)
+
+
+def _interior_held_bwd(tiles, res, d_h):
+    up, gate, held_rows = res
+    with jax.named_scope("moe.experts"):
+        d_up, d_gate = pallas_rows.experts_act_bwd(
+            up, gate, d_h, held_rows, tiles
+        )
+    return d_up, d_gate, None
+
+
+_interior_held.defvjp(_interior_held_fwd, _interior_held_bwd)
+
+
+def _ragged_experts(rows, w_up, w_gate_proj, w_down, group_sizes, held=None):
     """The SwiGLU experts over expert-sorted ``rows`` [N, D] as three
     ragged matmuls (``lax.ragged_dot``: rhs [E, ·, ·], group_sizes = the
     rows each expert actually got — the MXU only sees routed tokens).
-    ``w_gate_proj`` None: experts without a gate, relu(.)² between two."""
+    ``w_gate_proj`` None: experts without a gate, relu(.)² between two.
+    ``held`` (``Held``, None = every expert here): the activation
+    between the matmuls goes by the held prefix where the kernel runs
+    (``Held.interior_tiles``; counter ``moe.experts_by_prefix``)."""
     with jax.named_scope("moe.experts"):
         up = jax.lax.ragged_dot(rows, w_up, group_sizes)
-        if w_gate_proj is None:
+        gate = None
+        if w_gate_proj is not None:
+            gate = jax.lax.ragged_dot(rows, w_gate_proj, group_sizes)
+        tiles = None
+        if held is not None:
+            tiles = held.interior_tiles(up, gate is not None)
+        set_counter("moe.experts_by_prefix", int(tiles is not None))
+        if tiles is not None:
+            h = _interior_held(up, gate, held.rows, tiles)
+        elif gate is None:
             h = jnp.square(jax.nn.relu(up))
         else:
-            h = jax.nn.silu(
-                jax.lax.ragged_dot(rows, w_gate_proj, group_sizes)
-            ) * up
+            h = jax.nn.silu(gate) * up
         return jax.lax.ragged_dot(h, w_down, group_sizes)
 
 
@@ -817,6 +864,7 @@ def _ragged_ffn(xl, moe_local, gate_idx, weights, dtype, one_device=False):
         None if w_gate_proj is None else w_gate_proj.astype(dtype),
         moe_local["w_down"].astype(dtype),
         group_sizes,
+        held,
     )  # [T·k, D], or the rows that can hold a held pair
     out = _combine_weighted(out_sorted, weights, order, inv, dtype, held)
     return out, group_sizes

@@ -7,12 +7,15 @@ as a loop over the held prefix against the parent's whole-array body,
 what lies behind the prefix never read, the count the paths get, and a
 two-block model's gradient against the parent bodies'."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from dlrover_tpu.models import get_config
+from dlrover_tpu.observability import tracing
 from dlrover_tpu.ops import pallas_attention, pallas_rows
 from dlrover_tpu.parallel import moe
 
@@ -167,8 +170,64 @@ def test_shapes_off_the_tiles_take_the_xla_body(monkeypatch):
     # 16,384 tokens' sums do not fit VMEM at 1,024 columns: narrower
     assert pallas_rows.tile(8192, 65536, 2048, jnp.bfloat16) == (2048, 1024)
     assert pallas_rows.tile(16384, 131072, 2048, jnp.bfloat16) == (2048, 512)
+    # the experts' interior: (rows, columns, rows a turn)
+    bf16 = jnp.bfloat16
+    assert pallas_rows.act_tile(512, 1024, bf16, True) == (512, 1024, 16)
+    assert pallas_rows.act_tile(512, 256, F32, False) == (512, 256, 64)
+    assert pallas_rows.act_tile(90, 1024, bf16, True) is None  # rows
+    assert pallas_rows.act_tile(512, 96, bf16, True) is None  # lanes
+    # the derivative's five arrays of 2,048 float32 rows, each in two
+    # buffers, fit VMEM at half a row of 2,048 columns
+    assert pallas_rows.act_tile(65536, 2048, F32, True) == (2048, 1024, 16)
     monkeypatch.setattr(pallas_attention, "INTERPRET", False)
     assert pallas_rows.tile(64, 512, 1024, jnp.bfloat16) is None  # the CPU
+    assert pallas_rows.act_tile(512, 1024, bf16, True) is None
+
+
+def _interior_xla(up, gate):
+    """The XLA body between the grouped matmuls (``_ragged_experts``)."""
+    if gate is None:
+        return jnp.square(jax.nn.relu(up))
+    return jax.nn.silu(gate) * up
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("act", ["swiglu", "relu2"])
+@pytest.mark.parametrize(
+    "held_rows", [0, 16, 128, 256, 1024, 200],
+    ids=["none", "1/64", "1/8", "1/4", "all", "inside-a-tile"],
+)
+def test_interior_kernels_are_the_xla_bodys_over_the_prefix(
+    interpreted, held_rows, act, dtype
+):
+    """``experts_act`` and ``experts_act_bwd`` interpreted, eight tiles
+    of 128 rows by two passes of 128 columns, against the XLA body and
+    its derivative in float32 rounded once: equal on the held prefix —
+    200 rows end inside the second tile and inside a turn of 16 —, and
+    NaN in every input behind the prefix changes nothing there."""
+    dt = jnp.dtype(dtype)
+    n, d, tiles = 1024, 256, (128, 128, 16)
+    keys = jax.random.split(jax.random.key(held_rows), 3)
+    up, gate, d_h = (jax.random.normal(k, (n, d)).astype(dt) for k in keys)
+    if act == "relu2":
+        gate = None
+    ref, pull = jax.vjp(
+        _interior_xla, *jax.tree.map(lambda a: a.astype(F32), (up, gate))
+    )
+    ref_up, ref_gate = pull(d_h.astype(F32))
+    behind = (jnp.arange(n) >= held_rows)[:, None]
+    up, gate, d_h = jax.tree.map(
+        lambda a: jnp.where(behind, jnp.nan, a), (up, gate, d_h)
+    )
+    count = jnp.int32(held_rows)
+    got = pallas_rows.experts_act(up, gate, count, tiles)
+    got_up, got_gate = pallas_rows.experts_act_bwd(up, gate, d_h, count, tiles)
+    assert (got_gate is None) == (gate is None)
+    for a, b in ((got, ref), (got_up, ref_up), (got_gate, ref_gate)):
+        if a is None:
+            continue
+        assert a.dtype == dt and a.shape == (n, d)
+        _close(a[:held_rows], b[:held_rows], dt)
 
 
 def _parent_combine_bwd(g, out_rows, weights, order, inv):
@@ -223,7 +282,7 @@ def test_combine_derivative_over_the_prefix_is_the_whole_bodys(
 TINY = dict(
     n_layer=2, d_model=128, n_head=2, n_kv_head=2, d_ff=128, vocab_size=256,
     max_seq=64, q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=24,
-    qk_rope_head_dim=8, v_head_dim=32, d_expert=64, n_experts=8,
+    qk_rope_head_dim=8, v_head_dim=32, d_expert=128, n_experts=8,
     expert_top_k=2, n_experts_held=4, expert_offset=2, remat="full",
     dtype="float32",
 )
@@ -246,9 +305,10 @@ def _two_blocks(cfg, seed=0):
 
 
 def _with_parent_bodies(monkeypatch):
-    """The parent's program for a held model: the XLA sums and the
-    combine's derivative over every row."""
+    """The parent's program for a held model: the XLA sums, the XLA
+    interior and the combine's derivative over every row."""
     monkeypatch.setattr(pallas_rows, "tile", lambda *a, **k: None)
+    monkeypatch.setattr(pallas_rows, "act_tile", lambda *a, **k: None)
 
     def whole(g, out_rows, weights, order, held_rows):
         k = weights.shape[1]
@@ -264,10 +324,10 @@ def _with_parent_bodies(monkeypatch):
 @pytest.mark.parametrize("kernel", [True, False], ids=["kernel", "xla"])
 def test_two_held_blocks_gradient_is_the_parent_bodies(monkeypatch, kernel):
     """Loss and every gradient of two routed blocks in a row, each
-    holding experts 2-5 of 8, through the held-rows paths (the kernel
-    interpreted, or the XLA sums with the prefix loop) against the
-    parent's bodies; and the count the paths get is the rows the held
-    experts received."""
+    holding experts 2-5 of 8, through the held-rows paths (the kernels
+    interpreted — the sums and the experts' interior —, or the XLA
+    bodies with the prefix loop) against the parent's bodies; and the
+    count the paths get is the rows the held experts received."""
     cfg = get_config("glm-4.7-flash", **TINY)
     blocks, x, loss = _two_blocks(cfg)
     monkeypatch.setattr(pallas_attention, "INTERPRET", kernel)
@@ -279,12 +339,21 @@ def test_two_held_blocks_gradient_is_the_parent_bodies(monkeypatch, kernel):
         return real(rows, token_of, weights, held_rows, *a)
 
     monkeypatch.setattr(pallas_rows, "rows_sum", watched)
+    for name in ("experts_act", "experts_act_bwd"):
+        def watched_act(*args, real=getattr(pallas_rows, name)):
+            seen.append(args[-2])
+            return real(*args)
+
+        monkeypatch.setattr(pallas_rows, name, watched_act)
     (got, held), got_grads = jax.value_and_grad(
         loss, argnums=(0, 1), has_aux=True
     )(blocks, x)
-    # each block's combine and its dispatch's derivative: the count they
-    # get is the step metric's, the held experts' group sizes summed
-    assert len(seen) == (4 if kernel else 0)
+    assert tracing.counters()["moe.experts_by_prefix"] == int(kernel)
+    # each block's combine and its dispatch's derivative, its interior
+    # going forward, remade under ``remat`` or not, and coming back: the
+    # count they get is the step metric's, the held experts' group sizes
+    # summed
+    assert len(seen) == (8 if kernel else 0)
     for count in seen:
         assert count.dtype == jnp.int32
         assert int(count) in [int(h) for h in held]
@@ -307,8 +376,10 @@ def test_garbage_behind_the_groups_reaches_no_loss_or_gradient(
 ):
     """``ragged_dot`` leaves finite garbage in the rows behind its last
     group and its transposes leave it in their cotangents; here the
-    experts leave NaN there, forward and backward. The blocks' loss and
-    gradients stay finite and equal to the clean run's."""
+    experts leave NaN there, forward and backward, and so does their
+    interior where it goes by the prefix (the rows its kernels do not
+    write). The blocks' loss and gradients stay finite and equal to the
+    clean run's."""
     cfg = get_config("glm-4.7-flash", **TINY)
     blocks, x, loss = _two_blocks(cfg, seed=1)
     monkeypatch.setattr(pallas_attention, "INTERPRET", kernel)
@@ -334,17 +405,115 @@ def test_garbage_behind_the_groups_reaches_no_loss_or_gradient(
         lambda live, g: (jnp.where(live[:, None], g, jnp.nan), None),
     )
 
-    def poisoned(rows, w_up, w_gate_proj, w_down, group_sizes):
+    def poisoned(rows, w_up, w_gate_proj, w_down, group_sizes, held):
         live = jnp.arange(rows.shape[0]) < group_sizes.sum()
         out = real(
-            poison_back(rows, live), w_up, w_gate_proj, w_down, group_sizes
+            poison_back(rows, live), w_up, w_gate_proj, w_down, group_sizes,
+            held,
         )
         return poison_out(out, live)
 
     monkeypatch.setattr(moe, "_ragged_experts", poisoned)
+    for name in ("experts_act", "experts_act_bwd"):
+        def unwritten(*args, real=getattr(pallas_rows, name)):
+            behind = (jnp.arange(args[0].shape[0]) >= args[-2])[:, None]
+            return jax.tree.map(
+                lambda a: jnp.where(behind, jnp.nan, a), real(*args)
+            )
+
+        monkeypatch.setattr(pallas_rows, name, unwritten)
     (dirty, _), dirty_grads = grad(blocks, x)
     assert np.isfinite(dirty)
     np.testing.assert_allclose(dirty, clean, rtol=1e-6)
     for a, b in zip(jax.tree.leaves(dirty_grads), jax.tree.leaves(clean_grads)):
         assert np.isfinite(a).all()
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
+
+
+OLMOE_TINY = dict(
+    n_layer=2, d_model=128, n_head=2, n_kv_head=2, d_ff=128, d_expert=128,
+    n_experts=8, expert_top_k=2, vocab_size=256, max_seq=64, remat="full",
+    dtype="float32",
+)
+
+
+@pytest.mark.parametrize(
+    "case,by_prefix",
+    [("held", 1), ("every-expert-here", 0), ("held-under-a-mesh", 0)],
+)
+def test_counter_says_which_interior_a_block_traces(
+    interpreted, case, by_prefix
+):
+    """``moe.experts_by_prefix`` is set while a routed block is traced: 1
+    where the experts' interior goes by the held prefix (a part of the
+    experts on one device), 0 where the XLA body runs — every expert
+    here (OLMoE's structure), or a mesh of several devices around the
+    block (a Mosaic call is not partitioned)."""
+    from dlrover_tpu.parallel import MeshConfig, build_mesh
+
+    cfg, mesh = get_config("glm-4.7-flash", **TINY), None
+    if case == "every-expert-here":
+        cfg = get_config("olmoe-1b-7b", **OLMOE_TINY)
+    if case == "held-under-a-mesh":
+        mesh = build_mesh(MeshConfig(dp=2), devices=jax.devices()[:2])
+    block = jax.eval_shape(
+        lambda: moe.init_moe_params(jax.random.key(0), cfg, lead=())
+    )
+    x = jax.ShapeDtypeStruct((2, 32, cfg.d_model), F32)
+    tracing._counters.pop("moe.experts_by_prefix", None)
+    text = str(jax.make_jaxpr(
+        jax.grad(lambda x, b: moe.moe_block(x, b, cfg, mesh=mesh).sum())
+    )(x, block))
+    assert tracing.counters()["moe.experts_by_prefix"] == by_prefix
+    for kernel in ("experts_act", "experts_act_bwd"):
+        assert len(re.findall(rf"name={kernel}(?!\w)", text)) == by_prefix
+
+
+def _parent_ragged_experts(
+    rows, w_up, w_gate_proj, w_down, group_sizes, held=None
+):
+    """``_ragged_experts`` as PR 71 had it."""
+    with jax.named_scope("moe.experts"):
+        up = jax.lax.ragged_dot(rows, w_up, group_sizes)
+        if w_gate_proj is None:
+            h = jnp.square(jax.nn.relu(up))
+        else:
+            h = jax.nn.silu(
+                jax.lax.ragged_dot(rows, w_gate_proj, group_sizes)
+            ) * up
+        return jax.lax.ragged_dot(h, w_down, group_sizes)
+
+
+@pytest.mark.parametrize(
+    "model,overrides",
+    [("olmoe-1b-7b", OLMOE_TINY),
+     ("gpt2-124m", dict(n_layer=2, max_seq=64, dtype="float32"))],
+    ids=["olmoe", "gpt2"],
+)
+def test_models_that_hold_every_expert_or_none_trace_the_parents_text(
+    interpreted, monkeypatch, model, overrides
+):
+    """Nothing to skip, nothing changed: a model whose device holds
+    every expert (``held is None``) and a dense one trace, loss and
+    gradient, to the text they trace to with the parent's body between
+    the grouped matmuls — even where a kernel could run."""
+    from dlrover_tpu.models import decoder
+
+    cfg = get_config(model, **overrides)
+    params = jax.eval_shape(lambda: decoder.init(jax.random.key(0), cfg))
+    batch = {
+        k: jax.ShapeDtypeStruct((2, 32), jnp.int32)
+        for k in ("tokens", "targets")
+    }
+    trace = lambda: str(jax.make_jaxpr(jax.grad(
+        lambda p: decoder.loss_fn(p, batch_of(p), cfg)[0]
+    ))(params))
+
+    def batch_of(_):
+        return {k: jnp.zeros(v.shape, v.dtype) for k, v in batch.items()}
+
+    ours = trace()
+    monkeypatch.setattr(moe, "_ragged_experts", _parent_ragged_experts)
+    assert ours == trace()
+    assert ("ragged_dot" in ours) == bool(cfg.n_experts)
+    assert "experts_act" not in ours

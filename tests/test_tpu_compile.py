@@ -367,6 +367,27 @@ def _held_rows(t, k, d, tiles, bound=None, back=False):
     return build
 
 
+def _interior(n, d, gated, tiles):
+    """The experts' activation between the grouped matmuls and its
+    derivative over the held prefix (``moe._interior_held``:
+    ``pallas_rows.experts_act`` / ``experts_act_bwd``) at a held cell's
+    rows and expert width; ``gated`` False: Nemotron-3-Super's
+    relu(.)² experts."""
+    def build(S):
+        assert pallas_rows.act_tile(n, d, BF16, gated) == tiles
+
+        def fn(up, gate, cot, rows):
+            h, pull = jax.vjp(
+                lambda u, g: moe._interior_held(u, g, rows, tiles), up, gate
+            )
+            return h, pull(cot)
+
+        a = S((n, d), BF16)
+        return fn, (a, a if gated else None, a, S((), jnp.int32))
+
+    return build
+
+
 def _norm(d, grad, residual):
     def build(S):
         x, scale = S((8, 1024, d), BF16), S((d,), F32)
@@ -491,6 +512,21 @@ CASES = {
     "rows-sum-16384x8-2048": (_held_rows(16384, 8, 2048, (2048, 512)), 1),
     "rows-sum-back-8192x22-1024": (
         _held_rows(8192, 22, 1024, (2048, 1024), bound=65536, back=True), 1),
+    # the experts' interior over the held prefix, forward and back, at
+    # the seven held cells' rows x expert width: Trinity-Mini and
+    # Kimi-Linear, Mellum2, Keye-VL-2.0, GLM-4.7-Flash, Nemotron-3-Super
+    # (its pairs cut to 65,536 rows, no gate), Qwen3-Next
+    "experts-act-131072x1024": (
+        _interior(131072, 1024, True, (2048, 1024, 16)), 2),
+    "experts-act-262144x896": (
+        _interior(262144, 896, True, (2048, 896, 16)), 2),
+    "experts-act-65536x768": (_interior(65536, 768, True, (2048, 768, 16)), 2),
+    "experts-act-65536x1536": (
+        _interior(65536, 1536, True, (2048, 1536, 16)), 2),
+    "experts-act-relu2-65536x2688": (
+        _interior(65536, 2688, False, (2048, 2688, 16)), 2),
+    "experts-act-163840x512": (
+        _interior(163840, 512, True, (2048, 512, 32)), 2),
     # its two rank norms
     "norm-bwd-d768": (_norm(768, grad=True, residual=False), 1),
     "norm-bwd-d512": (_norm(512, grad=True, residual=False), 1),
@@ -573,6 +609,9 @@ def test_kernel_compiles_for_v5e(chip, case):
         assert not re.search(r"f32\[[\d,]*16384,\d+,128\]", text)
     if case.startswith("rows-sum-"):
         assert "%rows_sum" in text
+    if case.startswith("experts-act-"):
+        for name in ("experts_act", "experts_act_bwd"):
+            assert _kernel_calls(text, name) == 1
     if case.startswith("conv-"):
         names = ("conv_fwd", "conv_bwd") if "bwd" in case else ("conv_fwd",)
         assert all(f"%{name}" in text for name in names)
@@ -853,7 +892,8 @@ STEP_CASES = {
         batch=(2, 8192),
         kernels={"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
                  "norm_fwd", "norm_bwd", "ragged-dot-none",
-                 "ragged-dot-metadata", "rows_sum"},
+                 "ragged-dot-metadata", "rows_sum", "experts_act",
+                 "experts_act_bwd"},
         scopes={"embed", "attn", "attn.latent", "mlp", "head_loss", "mtp",
                 "optimizer", "moe.route", "moe.sort", "moe.experts",
                 "moe.combine", "moe.shared"},
@@ -874,7 +914,8 @@ STEP_CASES = {
         batch=(1, 8192),
         kernels={"flash_fwd_sel", "flash_bwd_dq_sel", "flash_bwd_dkv_sel",
                  "align_kl", "norm_fwd", "norm_bwd", "ragged-dot-none",
-                 "ragged-dot-metadata", "rows_sum"},
+                 "ragged-dot-metadata", "rows_sum", "experts_act",
+                 "experts_act_bwd"},
         scopes={"embed", "attn", "attn.index", "attn.select",
                 "attn.index_loss", "mlp", "head_loss", "optimizer",
                 "moe.route", "moe.sort", "moe.experts", "moe.combine"},
@@ -1093,6 +1134,12 @@ def test_step_names_its_kernels_and_phases(topo, case):
         assert not any(
             "/moe.sort/" in ln or "/moe.combine/" in ln for ln in kernel_lines
         )
+    if builder.cfg.n_experts:
+        # which interior the routed blocks traced: by the held prefix
+        # where a part of the experts is here, the XLA body otherwise
+        assert counters["moe.experts_by_prefix"] == int(
+            "experts_act" in spec["kernels"]
+        )
     if spec["model"] == "olmoe-1b-7b":
         # the routed layer's grouped matmuls under their scope (by the
         # kernel's name: it has no name stack)
@@ -1138,6 +1185,12 @@ def test_step_names_its_kernels_and_phases(topo, case):
             # the combine's sum going forward, the dispatch's coming back
             want = "moe.sort" if phase == "backward" else "moe.combine"
             assert runtime_timer.scope_of(op_names[name]) == want
+            continue
+        if kernel in ("experts_act", "experts_act_bwd"):
+            # the experts' interior over the held prefix (PR 72), under
+            # the scope the XLA body's passes had
+            assert runtime_timer.scope_of(op_names[name]) == "moe.experts"
+            assert (phase == "backward") == (kernel == "experts_act_bwd")
             continue
         if kernel.startswith("flash_bwd") or kernel == "norm_bwd":
             assert phase == "backward", (name, op_names[name])
@@ -1509,8 +1562,13 @@ def test_nemotron_cell_fits_the_chip_with_its_rows_cut(topo, monkeypatch):
         "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "norm_fwd",
         "norm_bwd", "ragged-dot-none", "ragged-dot-metadata",
         "ssd_fwd", "ssd_states", "ssd_bwd", "conv_fwd", "conv_bwd",
-        "rows_sum",
+        "rows_sum", "experts_act", "experts_act_bwd",
     }
+    # the routed blocks' relu(.)² by the held prefix (PR 72): a block's
+    # interior going forward, remade, and its derivative
+    assert counters["moe.experts_by_prefix"] == 1
+    routed = _kernel_calls(text, "experts_act_bwd")
+    assert routed and _kernel_calls(text, "experts_act") == 2 * routed
     # the five layers' conv through its kernels (PR 55), every call
     # under ``ssm.conv``: forward, remade, backward; x read as it lies,
     # no padded float32 copy of it
@@ -1830,6 +1888,13 @@ SCAN_BODY_BUDGET = {
     # unrolled; each traced once a process)
     "rows-sum-8192x8-2048": {"rows_sum": 127},
     "rows-sum-back-8192x22-1024": {"rows_sum": 109},
+    # the experts' interior over the held prefix and its derivative (PR
+    # 72: 36 / 45 with a gate, 33 / 36 without; a turn of rows is one
+    # rolled loop whatever the tile; each traced once a process)
+    "experts-act-131072x1024": {"experts_act": 40, "experts_act_bwd": 50},
+    "experts-act-relu2-65536x2688": {
+        "experts_act": 37, "experts_act_bwd": 40,
+    },
     # the L2 norm a head, forward and back (PR 69: 9 and 16 equations a
     # head of the block, 76 / 132 at the 8 heads a block holds whatever
     # the width — 292 / 516 with all of Kimi-Linear's 32 in it, which
@@ -1916,7 +1981,7 @@ def test_keye_cell_compiles_at_its_depth(topo):
     assert (spec["model"], spec["overrides"]) == (
         config["program"]["model"], config["program"]["overrides"]
     )
-    _, text, _ = _compiled_step(topo, "keye-cell")
+    _, text, counters = _compiled_step(topo, "keye-cell")
     stats = _STEP_MEMORY["keye-cell"]
     need = (
         stats.argument_size_in_bytes + stats.output_size_in_bytes
@@ -1932,6 +1997,11 @@ def test_keye_cell_compiles_at_its_depth(topo):
     # scanned forward body, the dispatch's derivative in the backward
     # one; the remade forward needs no combine
     assert _kernel_calls(text, "rows_sum") == 2
+    # the experts' interior by the held prefix (PR 72): in the scanned
+    # forward body, remade in the backward one, and its derivative there
+    assert counters["moe.experts_by_prefix"] == 1
+    assert _kernel_calls(text, "experts_act") == 2
+    assert _kernel_calls(text, "experts_act_bwd") == 1
     assert "bf16[12,1,8192,32,128]" in text and "f32[12,1,32,8192]" in text
     assert "f32[12,32,8192,8]" not in text
     assert "s8[12,1,8192,8192]" in text  # the saved selections, stacked
