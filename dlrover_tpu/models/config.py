@@ -208,7 +208,17 @@ class ModelConfig:
     # not ``n_dense_layer``); every other letter is a layer by itself,
     # and ``n_layer`` counts layers. Parameters are stacked kind by kind
     # and visited in this order. ``mtp_pattern`` is the prediction
-    # module's layers, likewise. Training path only
+    # module's layers, likewise. A ``C`` is a GATED SHORT CONVOLUTION
+    # (LFM2's conv mixer), a mixer like ``m`` or ``K`` with no state but
+    # the last ``conv_kernel`` - 1 tokens: [B | C | x] = u W_in (d -> 3d),
+    # y = (C * conv(B * x)) W_out, the conv depthwise, causal, of
+    # ``conv_kernel`` taps, without bias or activation, over d_model
+    # channels; it has no field of its own beside ``conv_kernel``. A
+    # leading dense layer whose mixer is a conv is spelled like any
+    # other, by its two parts: ``C-C-*eCeCeCe`` is two conv + dense-MLP
+    # layers, then an attention + routed layer and three conv + routed
+    # ones (``n_dense_layer`` cannot say which mixer a dense layer has;
+    # the pattern can). Training path only
     layer_pattern: str = ""
     mtp_pattern: str = ""
     # the Mamba-2 mixer (``M``): ``mamba_num_heads`` heads of
@@ -942,6 +952,8 @@ class ModelConfig:
                 mamba1 + 2 * inner1 * self.ssm_state_size,
             ),
             "-": (mlp + d, mlp),
+            # [B | C | x] and the output's matrices, the taps, the norm
+            "C": (4 * d * d + self.conv_kernel * d + d, 4 * d * d),
             "M": (
                 mamba + self.conv_dim * (self.conv_kernel + 1)
                 + 3 * heads + inner + d,
@@ -1210,6 +1222,14 @@ PART_RULES = {
                "conv_kernel"),
         refusal="a Mamba-1 part needs mamba_expand, mamba_dt_rank, "
         "ssm_state_size and conv_kernel",
+    ),
+    "C": PartRule(
+        "gated short convolution", needs=("conv_kernel",),
+        unless=lambda c: c.conv_kernel < 2,
+        refusal="a C part needs conv_kernel >= 2: a conv of one tap is a "
+        "gate and no conv",
+        train_only="gated-short-convolution (C) layers: a conv state of "
+        "conv_kernel - 1 rows has no place beside the cache",
     ),
     "*": PartRule("attention; latent attention where kv_lora_rank is set"),
     "L": PartRule(
@@ -1858,6 +1878,50 @@ CONFIGS = {
         moe_impl="ragged",
         moe_renorm_topk=True,
         moe_aux_coef=0.001,
+    ),
+    # a gated short convolution in three layers of four and a
+    # grouped-query attention in the fourth: LFM2-8B-A1B (``lfm2_moe``,
+    # 8.3B-A1.5B; huggingface.co/LiquidAI/LFM2-8B-A1B config.json) — 24
+    # layers over d 2048, each x + mixer(norm(x)) then x + ffn(norm(x)),
+    # RMSNorm eps 1e-5; ``layer_types`` a conv mixer (``C``: d -> 3d,
+    # [B | C | x], a depthwise causal conv of ``conv_L_cache`` 3 taps
+    # over B * x, no bias, no activation, gated by C, d -> d) but for
+    # layers 2, 6, 10, 14, 18 and 21, an attention (``*``: GQA 32 / 8
+    # heads of 64, per-head RMSNorm on q and k, rope theta 1e6 over all
+    # 64 channels); the first two layers' second part a dense SwiGLU of
+    # 7168 (``num_dense_layers`` 2: ``C-C-``), every other layer's 32
+    # SwiGLU experts of width 1792, sigmoid top-4 renormalised x 1, no
+    # shared expert, the selection bias (``use_expert_bias``) held at
+    # zero; vocabulary 65,536, head tied (the family's default).
+    # Training path only
+    "lfm2-8b-a1b": ModelConfig(
+        name="lfm2-8b-a1b",
+        vocab_size=65536,
+        n_layer=24,
+        layer_pattern="".join(
+            ("*" if i in (2, 6, 10, 14, 18, 21) else "C")
+            + ("-" if i < 2 else "e")
+            for i in range(24)
+        ),
+        n_head=32,
+        n_kv_head=8,
+        d_model=2048,
+        d_ff=7168,
+        max_seq=128000,
+        act="swiglu",
+        pos="rope",
+        rope_theta=1e6,
+        attn_window=None,
+        tie_embeddings=True,
+        qk_head_norm=True,
+        norm_eps=1e-5,
+        conv_kernel=3,
+        n_experts=32,
+        expert_top_k=4,
+        d_expert=1792,
+        moe_impl="ragged",
+        moe_score="sigmoid",
+        moe_renorm_topk=True,
     ),
 }
 

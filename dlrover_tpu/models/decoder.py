@@ -36,7 +36,7 @@ from dlrover_tpu.models.config import (
     ATTN_KINDS, ModelConfig, lightning_log_decay, pattern_parts,
 )
 from dlrover_tpu.observability.tracing import set_counter
-from dlrover_tpu.ops import gated_delta, pallas_norm, pallas_paged, quant
+from dlrover_tpu.ops import gated_delta, pallas_norm, pallas_paged, quant, ssd
 from dlrover_tpu.ops.attention import _repeat_kv, mha_reference
 from dlrover_tpu.parallel import moe, sharding as shd
 
@@ -398,6 +398,23 @@ def _init_kda(key, cfg: ModelConfig, lead) -> Params:
     }
 
 
+def _init_conv(key, cfg: ModelConfig, lead) -> Params:
+    """A gated short convolution's parameters: ``w_in`` [B | C | x] (the
+    published ``in_proj``, d -> 3d), the conv's taps over d_model
+    channels (no bias), and the output matrix."""
+    d, taps = cfg.d_model, cfg.conv_kernel
+    stack, _ = _stackers(cfg, lead)
+    k = jax.random.split(key, 3)
+    bound = 1.0 / np.sqrt(taps)  # a depthwise tap sees ``taps`` inputs
+    return {
+        "w_in": stack(k[0], (d, 3 * d), d),
+        "conv_w": jax.random.uniform(
+            k[1], tuple(lead) + (taps, d), minval=-bound, maxval=bound,
+        ).astype(jnp.dtype(cfg.param_dtype)),
+        "w_out": stack(k[2], (d, d), d),
+    }
+
+
 def _init_mlp(keys, cfg: ModelConfig, stack) -> Params:
     """The dense MLP's matrices: SwiGLU's three, or two."""
     d, f = cfg.d_model, cfg.d_ff
@@ -603,6 +620,15 @@ def _kda_axes(cfg: ModelConfig, lead) -> Params:
     }
 
 
+def _conv_axes(cfg: ModelConfig, lead) -> Params:
+    """Logical axes of ``_init_conv``'s tree."""
+    return {
+        "w_in": lead + ("embed", "mlp"),
+        "conv_w": lead + (None, "mlp"),
+        "w_out": lead + ("mlp", "embed"),
+    }
+
+
 @dataclasses.dataclass(frozen=True)
 class PartKind:
     """One kind of ``layer_pattern`` part: all the trunk knows of a
@@ -618,7 +644,8 @@ class PartKind:
     run: Callable
     scope: Optional[str] = None  # what it is traced under, where not ``key``
     read: Optional[str] = None  # the number in its aux the trunk reports
-    # (cfg, mesh, n: the trunk's parts of the kind) -> {counter: value}
+    # (cfg, mesh, n: the trunk's parts of the kind, s: the tokens of a
+    # sequence) -> {counter: value}
     counters: Optional[Callable] = None
     rides_out: bool = False  # its aux rides out part by part: never scanned
     wants_rope: bool = False  # turns q and k by the trunk's rope tables
@@ -656,7 +683,20 @@ PARTS = {
     "m": PartKind(
         stack="mamba1", key="ssm1", init=_init_mamba1, axes=_mamba1_axes,
         run=lambda h, p, c: (_mamba1_block(h, p["ssm1"], c.cfg, c.mesh), {}),
-        counters=lambda cfg, mesh, n: {"ssm1.layers": n},
+        counters=lambda cfg, mesh, n, s: {"ssm1.layers": n},
+    ),
+    "C": PartKind(
+        stack="conv", key="conv", init=_init_conv, axes=_conv_axes,
+        run=lambda h, p, c: (
+            _gated_conv_block(h, p["conv"], c.cfg, c.mesh), {}
+        ),
+        # a trunk whose conv fell back to the XLA body says so
+        counters=lambda cfg, mesh, n, s: {
+            "conv.layers": n,
+            "conv.kernel_layers": n * int(ssd.gated_conv_in_kernel(
+                s, cfg.conv_kernel, cfg.d_model, cfg.dtype, mesh
+            ) is not None),
+        },
     ),
     "*": PartKind(
         stack="attention", key="attn",
@@ -671,13 +711,13 @@ PARTS = {
         init=_init_lightning, axes=_lightning_axes,
         run=lambda h, p, c: _lightning_block(h, p["lin"], c.cfg, c.mesh, c.rope),
         read="lightning_fast_out_ms", wants_rope=True,
-        counters=lambda cfg, mesh, n: {"lin.layers": n},
+        counters=lambda cfg, mesh, n, s: {"lin.layers": n},
     ),
     "G": PartKind(
         stack="gdn", key="gdn", init=_init_gdn, axes=_gdn_axes,
         run=lambda h, p, c: _gdn_block(h, p["gdn"], c.cfg, c.mesh),
         read="gdn_readout_ms",
-        counters=lambda cfg, mesh, n: _rule_layers(
+        counters=lambda cfg, mesh, n, s: _rule_layers(
             "gdn", n, mesh, cfg.gdn_key_dim, cfg.gdn_value_dim
         ),
     ),
@@ -685,7 +725,7 @@ PARTS = {
         stack="kda", key="kda", init=_init_kda, axes=_kda_axes,
         run=lambda h, p, c: _kda_block(h, p["kda"], c.cfg, c.mesh),
         read="kda_readout_ms",
-        counters=lambda cfg, mesh, n: _rule_layers(
+        counters=lambda cfg, mesh, n, s: _rule_layers(
             "kda", n, mesh, cfg.kda_head_dim, cfg.kda_head_dim,
             per_channel=True,
         ),
@@ -697,7 +737,7 @@ PARTS = {
             h, p, c.cfg, c.mesh, c.positions, c.attn_fn, c.return_selected
         ),
         rides_out=True, selects=True,
-        counters=lambda cfg, mesh, n: {
+        counters=lambda cfg, mesh, n, s: {
             "attn.sparse_layers": n, "attn.select_block": cfg.sparse_block,
             "attn.select_groups": cfg.kv_heads,
         },
@@ -1717,8 +1757,6 @@ def _lightning_block(h, lin, cfg: ModelConfig, mesh, rope):
     read-out's energy, so the logits do not see what garbles them alone
     (a running log-decay kept in eight bits: its sum passes 200 inside a
     chunk there); this number does."""
-    from dlrover_tpu.ops import ssd
-
     b, s, _ = h.shape
     nh, hd = cfg.n_head, cfg.head_dim
     dt_, f32 = h.dtype, jnp.float32
@@ -1890,8 +1928,6 @@ def _mamba_block(h, ssm, cfg: ModelConfig, mesh):
         y = scan(x, Δ, A, B, C) + D x                       (ops/ssd.py)
         out = group_norm(y ⊙ silu(z)) W_out
     """
-    from dlrover_tpu.ops import ssd
-
     b, s, _ = h.shape
     dt_ = h.dtype
     inner, heads, hd = cfg.d_inner, cfg.mamba_num_heads, cfg.mamba_head_dim
@@ -1946,7 +1982,6 @@ def _mamba1_block(h, ssm, cfg: ModelConfig, mesh):
     the stream grows 2-5 x on its way down, PERF.md section 4), and
     with bf16 between the matmuls the logits stand 2.9e-2 from the
     float32 reference where a dense cell may stand 2.5e-2."""
-    from dlrover_tpu.ops import ssd
     from dlrover_tpu.ops.selective_scan import selective_scan
 
     dt_ = jnp.dtype(cfg.dtype)
@@ -1984,6 +2019,35 @@ def _mamba1_block(h, ssm, cfg: ModelConfig, mesh):
     )
     y = (y + ssm["d_skip"].astype(f32) * u) * jax.nn.silu(z)
     return matmul(y, ssm["w_out"])
+
+
+def _gated_conv_block(h, conv, cfg: ModelConfig, mesh):
+    """A gated short convolution (LFM2's conv mixer) on the layer's
+    normed input ``h`` [B, S, D] (scope ``conv``; inside it
+    ``conv.in_proj``, ``conv.gate`` and ``conv.out_proj``):
+
+        [B | C | x] = h W_in                                (D -> 3 D)
+        z = B ⊙ x;  c_t = Σ_j w_j ⊙ z_{t-K+1+j}     (ops/ssd.py::gated_conv)
+        out = (C ⊙ c) W_out
+
+    a depthwise causal conv of ``conv_kernel`` taps a channel, z before
+    a sequence's first token 0, no bias and NO activation. The
+    in-projection is written once, in the compute dtype, and read once
+    where it lies by the gated conv's one pass (float32 inside, one
+    rounding of ``C ⊙ c``); the output is float32 as the ``-`` part's
+    is (``_run_pattern`` rounds the stream)."""
+    dt_ = h.dtype
+    with jax.named_scope("conv.in_proj"):
+        proj = h @ conv["w_in"].astype(dt_)
+        if mesh is not None:
+            proj = shd.constrain(proj, mesh, "batch", "seq", "mlp")
+    # (the mesh only where it rules the kernels out: ``_mamba_block``)
+    several = {"mesh": mesh} if mesh is not None and mesh.size > 1 else {}
+    y = ssd.gated_conv(proj, conv["conv_w"], **several)
+    with jax.named_scope("conv.out_proj"):
+        return jnp.matmul(
+            y, conv["w_out"].astype(dt_), preferred_element_type=jnp.float32
+        )
 
 
 def _l2_in_kernel(d: int) -> bool:
@@ -2057,8 +2121,6 @@ def _gdn_block(h, gdn, cfg: ModelConfig, mesh):
     is a VIEW: the norms are computed flat (``_l2_heads``), g and β are
     ``[B, S, Hv]`` either way, and no array of a whole sequence is
     copied between the two tilings."""
-    from dlrover_tpu.ops import ssd
-
     b, s, _ = h.shape
     dt_, f32 = jnp.dtype(cfg.dtype), jnp.float32
     hk, hv = cfg.gdn_key_heads, cfg.gdn_value_heads
@@ -2131,8 +2193,6 @@ def _kda_block(h, kda, cfg: ModelConfig, mesh):
     repeated over a head's columns times the softplus of ``[B, S,
     H * D]`` — and ``[B, S, H, D]`` a view of it at ``_l2_heads``' and
     ``gated_delta_rule``'s doors."""
-    from dlrover_tpu.ops import ssd
-
     b, s, _ = h.shape
     dt_, f32 = jnp.dtype(cfg.dtype), jnp.float32
     heads, dh, rank = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_gate_rank
@@ -2545,7 +2605,9 @@ def run_trunk(
         for letter, kind in PARTS.items():
             n = cfg.layer_pattern.count(letter)
             if n and kind.counters:
-                for name, value in kind.counters(cfg, mesh, n).items():
+                for name, value in kind.counters(
+                    cfg, mesh, n, x.shape[1]
+                ).items():
                     set_counter(name, value)
         x, aux = _run_pattern(
             x, layers, cfg.layer_pattern, positions, cfg, mesh, attn_fn,

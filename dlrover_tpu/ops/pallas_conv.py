@@ -83,6 +83,56 @@ and what stood at the kernels' doors.
 What a kernel costs before it runs (``ops/pallas_ssd.py``'s docstring):
 bodies traced once a process and laid in as plain equations — 48 and 79
 of them; ``tests/test_tpu_compile.py`` holds them.
+
+The GATED conv (``gated_conv``; ``ops/ssd.py::gated_conv``; PR 73), a
+mixer by itself (LFM2's short conv): with the in-projection
+``[B | C | x]`` of 3 C columns as it lies,
+
+    z = B ⊙ x;   c_t = Σ_j w_j ⊙ z_{t-K+1+j};   y = C ⊙ c
+
+no bias, no activation. Composed from the kernels above it is three
+passes — z written out, ``conv``, the product with C — 8 array-passes
+of [B, S, C] forward where the operation needs 4 (three windows read, y
+written) and going back many more than its 7 (dy and the windows read,
+the three cotangents written). ``gated_conv_fwd`` / ``gated_conv_bwd``
+make the one pass each way. The grid is ``(batch, token block)``, the
+token axis sequential; a step takes the channels WHOLE — the three
+windows through three block specs on the one array — and works them
+``chunk`` columns at a time inside, because the backward writes ``[dB |
+dC | dx]`` as ONE block of 3 C columns of one output: no concatenate
+and no pad behind the kernel, which would move the 6 array-passes the
+fusion saves. Forward: z's last 8 rows carried in VMEM from block to
+block, as x's are above. Backward, blocks of ``GATED_TOKENS_BWD`` last
+to first: ``dc = dy ⊙ C`` moved the other way from its carried first 8
+rows gives ``dz`` and the taps' sums exactly as ``conv_bwd``; ``dC = dy
+⊙ c`` needs c, which is REMADE from z and z's 8 rows before the block —
+those come from a second pair of block specs on the same array (16
+rows of B and of x, a bf16 tile; zeros before the first token), since
+the walk goes the other way; ``dB = dz ⊙ x``, ``dx = dz ⊙ B``.
+Residuals: the caller's two arrays. Float32 inside, one rounding out.
+
+The sweep, on a v5e (my chip runs, PR 73; ms a call at LFM2's
+``bf16[8, 4096, 6144]``, 3 taps, forward / backward alone, the smallest
+of five runs of twenty calls): the XLA body (``ssd._gated_conv``: the
+pad, the whole cast, the shifted copies) 2.34 / 8.25; the compiler's
+one-fusion form (shifted slices of z, no pad of the whole) 2.35 / 8.41;
+the composition of ``B ⊙ x``, the kernels above and ``⊙ C`` 1.65 /
+5.12; these kernels by (forward tokens, backward tokens, chunk): **(512,
+256, 1024) 0.801 / 1.481**, (512, 256, 512) 0.802 / 1.479, (512, 256,
+2048) 0.802 / 1.477, (256, 256, 1024) 0.801 / 1.475, (512, 512, 1024)
+0.799 / 1.467, (512, 512, 512) 0.801 / 1.467, (512, 128, 1024) 0.802 /
+1.534, (1024, 256, 1024) 0.806 / 1.478: flat within 1% but for the
+smallest backward block, 671 and 635 GB/s over the operation's 537 and
+940 MB, 82% and 78% of the memory's rate — bf16 here runs nearer the
+memory than the ungated kernels' 56% / 43-50% because each element
+loaded does four to seven times the arithmetic's worth of traffic. The
+kernels win by 2.9 x forward and 5.6 x backward and are kept; (512,
+256, 1024) is taken, the smaller backward block leaving VMEM for wider
+models. On the chip against the XLA body: y equal to the bit, the
+projection's cotangent within 1.8e-3 of its largest entry (the XLA
+body's transpose rounds in another order), the taps' 4.4e-5. In the
+LFM2 cell's traced step: 0.77 / 1.44 ms a call, ten + five calls
+(PERF.md section 6, PR 73).
 """
 
 import functools
@@ -215,11 +265,13 @@ def _bwd_kernel(
     edge[...] = dy[:HALO]
 
 
-def _params(interpret):
+def _params(interpret, parallel=2):
+    """The grid's leading ``parallel`` axes parallel, the token axis
+    behind them sequential."""
     if interpret:
         return None
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        dimension_semantics=("parallel",) * parallel + ("arbitrary",),
         vmem_limit_bytes=VMEM_LIMIT,
     )
 
@@ -332,3 +384,251 @@ def _conv_bwd(block, start, residuals, dy):
 
 
 conv.defvjp(_conv_fwd, _conv_bwd)
+
+
+# ---------------------------------------------------------------------------
+# The GATED conv: y = C ⊙ conv(B ⊙ x), the three read where they lie
+# ---------------------------------------------------------------------------
+
+# a grid step's tokens going forward and going back, and the widest of
+# the chunks of channels a step works at a time inside that divides the
+# width (the sweep: module docstring)
+GATED_TOKENS = 512
+GATED_TOKENS_BWD = 256
+GATED_CHUNKS = (1024, 512, 256, 128)
+# rows of the block before a token block that the backward is handed
+# beside it: a bf16 tile, of which the last ``HALO`` are read
+GATED_BEFORE = 16
+# bytes of VMEM the widest block may take, both buffers: past it the XLA
+# body runs
+GATED_VMEM = VMEM_LIMIT // 2
+
+
+def gated_tile(s: int, channels: int, taps: int, itemsize: int = 2,
+               mesh=None):
+    """The gated kernels' channel chunk for ``s`` tokens of ``channels``
+    channels a gate, or None where the XLA body runs: ``tile``'s reasons
+    (off the TPU and not interpreted, a mesh of several devices, channels
+    off the 128-lane grid, more taps than a carried tile holds), a
+    length that is not whole blocks of ``GATED_TOKENS`` (a multiple of
+    ``GATED_TOKENS_BWD``), and a width whose rows do not fit the
+    kernels' blocks: a step takes the channels whole, because the
+    backward writes its three cotangents as ONE block of 3 x
+    ``channels`` columns."""
+    if tile(GATED_TOKENS, channels, taps, 0, mesh) is None:
+        return None
+    if s % GATED_TOKENS:
+        return None
+    # a step's blocks, twice: the three windows in and y out going
+    # forward; dy and the three windows in, the three cotangents out
+    rows = max(4 * GATED_TOKENS, 7 * GATED_TOKENS_BWD)
+    if 2 * rows * channels * itemsize > GATED_VMEM:
+        return None
+    return next(c for c in GATED_CHUNKS if channels % c == 0)
+
+
+def _conv_of(z, edge, w_ref, at):
+    """Σ_j w_j ⊙ z_{t-K+1+j} of a chunk z [T, Cb] whose rows before the
+    block are ``edge`` [8, Cb]; ``at`` the chunk's columns of the taps."""
+    k = w_ref.shape[0]
+    taps = _shifted(z, edge, k)
+    out = taps[k - 1] * w_ref[pl.ds(0, 1), at]
+    for j in range(1, k):
+        out = out + taps[k - 1 - j] * w_ref[pl.ds(j, 1), at]
+    return out
+
+
+def _gated_fwd_kernel(
+    b_ref, c_ref, x_ref,  # [T, C]: the in-projection's three windows
+    w_ref,  # [K, C] float32
+    y_ref,  # [T, C]
+    edge,  # [8, C] float32: B ⊙ x of the last 8 tokens of the block before
+    *, chunk,
+):
+    t = x_ref.shape[0]
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        edge[...] = jnp.zeros_like(edge)
+
+    for lo in range(0, x_ref.shape[1], chunk):
+        at = pl.ds(lo, chunk)
+        z = b_ref[:, at].astype(F32) * x_ref[:, at].astype(F32)
+        c = _conv_of(z, edge[:, at], w_ref, at)
+        y_ref[:, at] = (c_ref[:, at].astype(F32) * c).astype(y_ref.dtype)
+        edge[:, at] = z[t - HALO:]
+
+
+def _gated_bwd_kernel(
+    dy_ref,  # [T, C]: the blocks last to first
+    b_ref, c_ref, x_ref,  # [T, C]: the three windows
+    b_before, x_before,  # [16, C]: B's and x's rows before the block
+    w_ref,  # [K, C] float32
+    d_ref,  # [T, 3 C]: [dB | dC | dx], one block
+    sums_ref,  # [K, 8, C] float32: dw's sums, resident over the tokens
+    edge,  # [8, C] float32: dy ⊙ C of the first 8 tokens of the block after
+    *, chunk,
+):
+    t, k = x_ref.shape[0], w_ref.shape[0]
+    ch = x_ref.shape[1]
+    step = pl.program_id(1)
+
+    @pl.when(step == 0)
+    def _():
+        edge[...] = jnp.zeros_like(edge)
+        sums_ref[...] = jnp.zeros_like(sums_ref)
+
+    # the first token block has nothing before it (its halo block is a
+    # clamped read of its own rows)
+    first = step == pl.num_programs(1) - 1
+    keep = jax.lax.select(first, jnp.zeros((), F32), jnp.ones((), F32))
+
+    def rows(p):
+        """Σ over the tokens of p [T, Cb], as the 8 rows of a tile."""
+        return jnp.sum(p.reshape(t // SUBLANES, SUBLANES, -1), axis=0)
+
+    for lo in range(0, ch, chunk):
+        at = pl.ds(lo, chunk)
+        dy = dy_ref[:, at].astype(F32)
+        b = b_ref[:, at].astype(F32)
+        x = x_ref[:, at].astype(F32)
+        z = b * x
+        before = (
+            b_before[:, at].astype(F32) * x_before[:, at].astype(F32)
+        )[GATED_BEFORE - HALO:]
+        # c, the conv's output, remade: dC = dy ⊙ c
+        c = _conv_of(z, before * keep, w_ref, at)
+        d_ref[:, pl.ds(ch + lo, chunk)] = (dy * c).astype(d_ref.dtype)
+        # dz from dc = dy ⊙ C moved the other way, as ``_bwd_kernel``'s dx
+        dc = dy * c_ref[:, at].astype(F32)
+        later = _shifted(dc, edge[:, at], k, back=True)
+        dz = None
+        for j in range(k):
+            g = later[k - 1 - j]
+            term = g * w_ref[pl.ds(j, 1), at]
+            dz = term if dz is None else dz + term
+            sums_ref[j, :, at] += rows(g * z)
+        d_ref[:, at] = (dz * x).astype(d_ref.dtype)
+        d_ref[:, pl.ds(2 * ch + lo, chunk)] = (dz * b).astype(d_ref.dtype)
+        edge[:, at] = dc[:HALO]
+
+
+def _gated_windows(tokens, ch, n, reverse):
+    """The block specs of [B | C | x], each [tokens, ch] of the
+    in-projection's 3 x ch columns, on the grid (batch, step): the token
+    block is the step, or the last minus it going back."""
+    return [
+        pl.BlockSpec(
+            (None, tokens, ch),
+            lambda b, i, w=w: (b, n - 1 - i if reverse else i, w),
+        )
+        for w in range(3)
+    ]
+
+
+_GATED_STATIC = ("chunk", "interpret")
+
+
+@functools.partial(_traced_once, static=_GATED_STATIC)
+def _gated_forward(proj, weight, *, chunk, interpret):
+    """y [B, S, C] in proj's dtype of proj [B, S, 3 C] = [B | C | x] and
+    weight [K, C] float32, S whole blocks of ``GATED_TOKENS``."""
+    bsz, s, _ = proj.shape
+    taps, ch = weight.shape
+    n = s // GATED_TOKENS
+    return pl.pallas_call(
+        functools.partial(_gated_fwd_kernel, chunk=chunk),
+        grid=(bsz, n),
+        in_specs=[
+            *_gated_windows(GATED_TOKENS, ch, n, False),
+            pl.BlockSpec((taps, ch), lambda b, i: (0, 0)),
+        ],
+        out_specs=pl.BlockSpec(
+            (None, GATED_TOKENS, ch), lambda b, i: (b, i, 0)
+        ),
+        out_shape=pallas_attention._out_struct(
+            (bsz, s, ch), proj.dtype, proj
+        ),
+        scratch_shapes=[pltpu.VMEM((HALO, ch), F32)],
+        compiler_params=_params(interpret, parallel=1),
+        interpret=interpret,
+        name="gated_conv_fwd",
+    )(proj, proj, proj, weight)
+
+
+@functools.partial(_traced_once, static=_GATED_STATIC)
+def _gated_backward(proj, weight, dy, *, chunk, interpret):
+    """(dproj [B, S, 3 C] = [dB | dC | dx] in proj's dtype, dw [K, C]
+    float32) from proj, the float32 taps and y's cotangent."""
+    bsz, s, _ = proj.shape
+    taps, ch = weight.shape
+    tokens = GATED_TOKENS_BWD
+    n = s // tokens
+    per = tokens // GATED_BEFORE
+    like = pallas_attention._out_struct
+
+    def before(w):
+        # the 16 rows before token block n - 1 - i; the first block's own
+        # first rows where there are none (the kernel reads zeros there)
+        return pl.BlockSpec(
+            (None, GATED_BEFORE, ch),
+            lambda b, i: (b, jnp.maximum((n - 1 - i) * per - 1, 0), w),
+        )
+
+    dproj, sums = pl.pallas_call(
+        functools.partial(_gated_bwd_kernel, chunk=chunk),
+        grid=(bsz, n),
+        in_specs=[
+            pl.BlockSpec((None, tokens, ch), lambda b, i: (b, n - 1 - i, 0)),
+            *_gated_windows(tokens, ch, n, True),
+            before(0), before(2),
+            pl.BlockSpec((taps, ch), lambda b, i: (0, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec(
+                (None, tokens, 3 * ch), lambda b, i: (b, n - 1 - i, 0)
+            ),
+            pl.BlockSpec(
+                (None, taps, SUBLANES, ch), lambda b, i: (b, 0, 0, 0)
+            ),
+        ],
+        out_shape=[
+            like(proj.shape, proj.dtype, proj),
+            like((bsz, taps, SUBLANES, ch), F32, proj),
+        ],
+        scratch_shapes=[pltpu.VMEM((HALO, ch), F32)],
+        compiler_params=_params(interpret, parallel=1),
+        interpret=interpret,
+        name="gated_conv_bwd",
+    )(dy, proj, proj, proj, proj, proj, weight)
+    return dproj, jnp.sum(sums, axis=(0, 2))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def gated_conv(proj, weight, chunk):
+    """``ssd.gated_conv`` on the kernels: y [B, S, C] in proj's dtype,
+    ``C ⊙ conv(B ⊙ x)`` of proj [B, S, 3 C] = [B | C | x] (a mixer's
+    in-projection, read where it lies) and weight [K, C], no bias, at
+    shapes ``gated_tile`` admits (``chunk`` its answer). Differentiable
+    in both; proj's cotangent is one array, [dB | dC | dx]."""
+    return _gated_conv_fwd(proj, weight, chunk)[0]
+
+
+def _gated_conv_fwd(proj, weight, chunk):
+    y = _gated_forward(
+        proj, weight.astype(F32), chunk=chunk,
+        interpret=pallas_attention.INTERPRET,
+    )
+    return y, (proj, weight)
+
+
+def _gated_conv_bwd(chunk, residuals, dy):
+    proj, weight = residuals
+    dproj, dw = _gated_backward(
+        proj, weight.astype(F32), dy, chunk=chunk,
+        interpret=pallas_attention.INTERPRET,
+    )
+    return dproj, dw.astype(weight.dtype)
+
+
+gated_conv.defvjp(_gated_conv_fwd, _gated_conv_bwd)

@@ -283,6 +283,52 @@ def causal_conv(x, weight, bias, mesh=None):
         return _conv(x, weight, bias)
 
 
+def _gated_conv(proj, weight):
+    """``gated_conv``'s XLA body: ``_conv`` between two multiplies, in
+    float32, one rounding."""
+    ch = weight.shape[1]
+    gate_in, gate_out, x = (
+        proj[..., i * ch:(i + 1) * ch].astype(F32) for i in range(3)
+    )
+    c = _conv(gate_in * x, weight, jnp.zeros((ch,), F32))
+    return (gate_out * c).astype(proj.dtype)
+
+
+def gated_conv(proj, weight, mesh=None):
+    """A GATED depthwise causal conv along the sequence (LFM2's short
+    conv): with proj [B, S, 3 C] = [B | C | x], a mixer's in-projection
+    as it lies, and weight [K, C],
+
+        z = B ⊙ x;  c_t = Σ_j w_j ⊙ z_{t-K+1+j};  y = C ⊙ c
+
+    z before the first token 0, each row of the batch by itself, no
+    bias and no activation; the gates, the taps and every product and
+    sum float32, y [B, S, C] in proj's dtype. One function, two bodies,
+    chosen from what it sees (``pallas_conv.gated_tile``, as
+    ``causal_conv``): the kernels ``gated_conv_fwd`` /
+    ``gated_conv_bwd`` (``ops/pallas_conv.py``: the three windows read
+    once where they lie, y written once; going back the three
+    cotangents written as one array), or the XLA body above. Which one
+    a model's trunk took, ``conv.kernel_layers`` says
+    (``gated_conv_in_kernel``)."""
+    chunk = gated_conv_in_kernel(
+        proj.shape[1], *weight.shape, proj.dtype, mesh
+    )
+    with jax.named_scope("conv.gate"):
+        if chunk is not None:
+            return pallas_conv.gated_conv(proj, weight, chunk)
+        return _gated_conv(proj, weight)
+
+
+def gated_conv_in_kernel(s: int, taps: int, channels: int, dtype, mesh=None):
+    """``pallas_conv.gated_tile``'s answer for ``gated_conv`` over ``s``
+    tokens of [B | C | x] in ``dtype``: the kernels' chunk, or None
+    where the XLA body runs."""
+    return pallas_conv.gated_tile(
+        s, channels, taps, jnp.dtype(dtype).itemsize, mesh
+    )
+
+
 def gated_group_norm(y, z, scale, groups: int, eps: float,
                      norm_before_gate: bool = False, gate=jax.nn.silu):
     """``RMSNorm over each of `groups` groups of (y ⊙ silu(z)) ⊙ scale``:

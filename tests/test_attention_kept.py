@@ -192,6 +192,8 @@ CELL_SPANS = {
     "mellum2-ep4-train-b1s32768": {
         "Y": (16384.5, 16896, True), "S": (1008.015625, 2016, False),
     },
+    # its one attention layer of six, heads of 64, as OLMoE's span
+    "lfm2-ep4-train-b8s4096": (2048.5, 2560, True),
 }
 
 

@@ -59,12 +59,14 @@ def _value_and_grads(conv, args, weight):
         y = conv(*a)
         return (y.astype(F32) * weight).sum(), y
 
-    (_, y), grads = jax.value_and_grad(loss, range(3), has_aux=True)(*args)
+    (_, y), grads = jax.value_and_grad(
+        loss, range(len(args)), has_aux=True
+    )(*args)
     return (y, *grads)
 
 
-def _close(got, want, tolerance):
-    for name, a, b in zip(NAMES, got, want):
+def _close(got, want, tolerance, names=NAMES):
+    for name, a, b in zip(names, got, want):
         assert a.shape == b.shape and a.dtype == b.dtype, name
         a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
         err = np.abs(a - b).max() / np.abs(b).max()
@@ -356,3 +358,131 @@ def test_mixers_call_the_conv_by_its_three_parameters(monkeypatch, model):
 def test_a_model_without_a_mixer_sets_no_counter():
     counters = _forward_counters("jamba2-3b", 64, layer_pattern="*-*-")
     assert "ssm.conv_in_kernel" not in counters
+
+
+# ---- the GATED conv (``ssd.gated_conv``; LFM2's ``C`` part) ----------------
+
+# token blocks (of the forward's 512; the backward walks blocks of 256),
+# channels a gate, batch, taps, dtype of [B | C | x], dtype of the taps. A
+# batch of 2: a sequence's first tokens see zeros, not the row before
+GATED_SHAPES = {
+    "gated-one-block": (1, 128, 1, 3, "float32", "float32"),
+    "gated-three-blocks-two-rows": (3, 256, 2, 3, "float32", "float32"),
+    # bf16 in, bf16 out, as the LFM2 cell's mixers hand it over
+    "gated-as-lfm2": (2, 256, 2, 3, "bfloat16", "bfloat16"),
+    "gated-float32-proj-bf16-taps": (2, 128, 2, 3, "float32", "bfloat16"),
+    # two chunks of 1,024 channels inside a step, then three of 128
+    "gated-two-chunks": (1, 2048, 2, 3, "float32", "float32"),
+    "gated-three-chunks-of-128": (1, 384, 1, 3, "float32", "float32"),
+    "gated-two-taps": (2, 128, 2, 2, "float32", "float32"),
+    "gated-nine-taps": (2, 128, 2, 9, "float32", "float32"),
+}
+GATED_NAMES = ("y", "dproj", "dw")
+
+
+def _gated_operands(blocks, channels, batch, taps, dtype, w_dtype, key=11):
+    k = jax.random.split(jax.random.key(key), 3)
+    seq = blocks * kernels.GATED_TOKENS
+    return (
+        jax.random.normal(k[0], (batch, seq, 3 * channels), dtype),
+        jax.random.normal(k[1], (taps, channels), w_dtype),
+    ), jax.random.normal(k[2], (batch, seq, channels))
+
+
+@pytest.mark.parametrize("shape", sorted(GATED_SHAPES))
+def test_gated_kernels_are_the_xla_body(monkeypatch, shape):
+    """y and all three cotangents — [dB | dC | dx] as ONE array, and the
+    taps' — of ``y = C * conv(B * x)``: one token block and several (the
+    rows of B * x carried across a block's edge going forward; going
+    back dy * C's carried the other way and B's and x's 16 rows before
+    the block read beside it), zeros before a sequence's first token in
+    every row of the batch, one chunk of channels and several, 2 to 9
+    taps, bf16 and float32 with float32 inside."""
+    *sizes, dtype, w_dtype = GATED_SHAPES[shape]
+    args, weight = _gated_operands(*sizes, dtype, w_dtype)
+    monkeypatch.setattr(pallas_attention, "INTERPRET", True)
+    assert ssd.gated_conv_in_kernel(
+        args[0].shape[1], *args[1].shape, dtype
+    ) is not None
+    text = str(jax.make_jaxpr(lambda *a: ssd.gated_conv(*a))(*args))
+    assert text.count("pallas_call") == 1
+    got = _value_and_grads(ssd.gated_conv, args, weight)
+    monkeypatch.setattr(pallas_attention, "INTERPRET", False)
+    text = str(jax.make_jaxpr(lambda *a: ssd.gated_conv(*a))(*args))
+    assert "pallas_call" not in text
+    want = _value_and_grads(ssd.gated_conv, args, weight)
+    _close(
+        want, _value_and_grads(ssd._gated_conv, args, weight), 0.0,
+        GATED_NAMES,
+    )
+    _close(got, want, max(TOLERANCE[dtype], TOLERANCE[w_dtype]), GATED_NAMES)
+
+
+def test_gated_xla_body_is_the_conv_between_two_multiplies():
+    """The XLA body against ``_conv`` on the product, gated: the same
+    float32 sums, to the bit."""
+    (proj, w), _ = _gated_operands(1, 128, 2, 3, "float32", "float32")
+    gate_in, gate_out, x = jnp.split(proj, 3, axis=-1)
+    want = gate_out * ssd._conv(gate_in * x, w, jnp.zeros((128,)))
+    np.testing.assert_array_equal(ssd._gated_conv(proj, w), want)
+    # a row of the batch by itself: the second row's first tokens read
+    # nothing of the first row's last
+    alone = ssd._gated_conv(proj[1:], w)
+    np.testing.assert_array_equal(ssd._gated_conv(proj, w)[1:], alone)
+
+
+def test_gated_kernels_keep_the_operands_only(monkeypatch):
+    """The residuals of the gated rule are the caller's two arrays, and
+    no window of the in-projection is sliced out around the kernels."""
+    monkeypatch.setattr(pallas_attention, "INTERPRET", True)
+    args, _ = _gated_operands(1, 128, 2, 3, "bfloat16", "bfloat16")
+    y, residuals = kernels._gated_conv_fwd(*args, 128)
+    assert y.dtype == jnp.bfloat16 and y.shape == (2, 512, 128)
+    assert all(kept is a for kept, a in zip(residuals, args))
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda *a: ssd.gated_conv(*a).astype(F32).sum(), range(2)
+    ))(*args))
+    assert text.count("pallas_call") == 2  # (the jaxpr keeps the dead y)
+    outside = text.split("pallas_call")[0]
+    assert "slice" not in outside and "f32[2,512,384]" not in outside
+    # and behind it: [dB | dC | dx] leaves the kernel as one array
+    assert "[2,512,128] = slice" not in text
+    assert "[2,512,384] = concatenate" not in text
+    assert "[2,512,384] = pad" not in text
+
+
+# tokens, channels a gate, taps, devices, interpreted
+GATED_XLA_BODY = {
+    "gated-tier-1-widths": (64, 64, 3, 1, True),
+    "gated-channels-off-the-lanes": (1024, 192, 3, 1, True),
+    "gated-a-length-off-the-block": (768, 128, 3, 1, True),
+    "gated-ten-taps": (1024, 128, 10, 1, True),
+    "gated-a-mesh-of-two": (1024, 128, 3, 2, True),
+    "gated-off-the-chip": (1024, 128, 3, 1, False),
+    # the backward's one block of 3 x 32,768 columns does not fit VMEM
+    "gated-too-wide-a-row": (1024, 32768, 3, 1, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GATED_XLA_BODY))
+def test_gated_shapes_the_kernels_do_not_tile_take_the_xla_body(
+    monkeypatch, case
+):
+    seq, channels, taps, devices, interpreted = GATED_XLA_BODY[case]
+    monkeypatch.setattr(pallas_attention, "INTERPRET", interpreted)
+    mesh = _mesh(devices)
+    assert kernels.gated_tile(seq, channels, taps, mesh=mesh) is None
+    if channels > 4096:
+        return  # the answer is what is held; no array of that width here
+    k = jax.random.split(jax.random.key(1), 2)
+    args = (
+        jax.random.normal(k[0], (1, seq, 3 * channels)),
+        jax.random.normal(k[1], (taps, channels)),
+    )
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda *a: ssd.gated_conv(*a, mesh=mesh).sum(), range(2)
+    ))(*args))
+    assert "pallas_call" not in text
+    np.testing.assert_array_equal(
+        ssd.gated_conv(*args, mesh=mesh), ssd._gated_conv(*args)
+    )

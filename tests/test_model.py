@@ -56,6 +56,7 @@ BENCHMARK_CONFIGS = {
     "qwen3-next-80b-a3b-ep16-1chip": 16384,
     "kimi-linear-48b-a3b-ep16-1chip": 16384,
     "mellum2-12b-a2.5b-ep4-1chip": 32768,
+    "lfm2-8b-a1b-ep4-1chip": 4096,
 }
 
 
@@ -76,7 +77,9 @@ def test_flops_per_token_is_the_benchmarks_count(name):
     up to, a linear attention's recurrence, mixer + ROUTED layers with
     a gated delta rule's, and a delta rule by key channel's beside
     latent attention whose pairs count the mean of 192 score and 128
-    value channels, and a rope per layer kind, which counts nothing."""
+    value channels, a rope per layer kind, which counts nothing, and a
+    gated short convolution's two matrices (its taps multiply nothing
+    worth the name)."""
     from benchmarks.lib.flops import resolve
 
     configs = pathlib.Path(__file__).parent.parent / "benchmarks" / "configs"

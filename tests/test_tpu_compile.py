@@ -313,6 +313,24 @@ def _conv_of_projection(grad):
     return build
 
 
+def _gated_conv(grad):
+    """LFM2's gated short conv: 3 taps over B ⊙ x gated by C, the three
+    read where they lie in the 6,144-wide in-projection [B | C | x],
+    eight sequences of 4,096, bf16."""
+    def build(S):
+        args = (S((8, 4096, 6144), BF16), S((3, 2048), BF16))
+        assert ssd.gated_conv_in_kernel(4096, 3, 2048, BF16) == 1024
+
+        if not grad:
+            return ssd.gated_conv, args
+        loss = lambda *a: jax.nn.silu(  # noqa: E731
+            ssd.gated_conv(*a).astype(F32)
+        ).sum()
+        return jax.grad(loss, argnums=(0, 1)), args
+
+    return build
+
+
 def _flash_gqa_256(grad):
     """Qwen3-Next's full layers: 16 query heads on 2 key-value heads of
     256 channels, one sequence of 16,384."""
@@ -507,6 +525,9 @@ CASES = {
     "conv-bwd-8192-of-12288-bf16": (_conv_of_projection(grad=True), 2),
     "conv-fwd-5120-f32": (_conv(5120, F32, grad=False), 1),
     "conv-bwd-5120-f32": (_conv(5120, F32, grad=True), 2),
+    # the gated short conv (LFM2's ``C`` part)
+    "gated-conv-fwd-3x2048-bf16": (_gated_conv(grad=False), 1),
+    "gated-conv-bwd-3x2048-bf16": (_gated_conv(grad=True), 2),
     # the routed blocks' sums over the held rows (``ops/pallas_rows.py``)
     "rows-sum-8192x8-2048": (_held_rows(8192, 8, 2048, (2048, 1024)), 1),
     "rows-sum-16384x8-2048": (_held_rows(16384, 8, 2048, (2048, 512)), 1),
@@ -612,6 +633,16 @@ def test_kernel_compiles_for_v5e(chip, case):
     if case.startswith("experts-act-"):
         for name in ("experts_act", "experts_act_bwd"):
             assert _kernel_calls(text, name) == 1
+    if case.startswith("gated-conv-"):
+        names = ("gated_conv_fwd", "gated_conv_bwd")
+        assert all(f"%{n}" in text for n in names[:2 if "bwd" in case else 1])
+        # the in-projection as it lies: no window of it copied out, no
+        # float32 copy, and going back ONE array of the three cotangents
+        entry = text.split("ENTRY")[1]
+        assert "f32[8,4096,2048]" not in entry
+        assert "f32[8,4096,6144]" not in entry
+        assert " slice(" not in entry and " concatenate(" not in entry
+        assert " pad(" not in entry
     if case.startswith("conv-"):
         names = ("conv_fwd", "conv_bwd") if "bwd" in case else ("conv_fwd",)
         assert all(f"%{name}" in text for name in names)
@@ -2160,3 +2191,63 @@ def test_mellum_cell_builds_a_table_a_rope_kind(topo):
     assert calls("flash_bwd_dkv", "attn.full") == 1
     assert "/attn.window/attn.rope/" in text
     assert "/attn.full/attn.rope/" in text
+
+
+def test_lfm2_cell_runs_the_gated_conv_in_kernels(topo):
+    """The benchmark's LFM2 configuration as it is run (the first six
+    published layers ``C-C-*eCeCeCe``, 8 of 32 experts held, 8 x 4,096
+    tokens): the step compiles for a described v5e and fits the chip's
+    15.75 GiB; the five conv mixers run the gated conv's kernels — a
+    forward and a recomputed forward a layer (the scanned ``C-`` pair
+    holds one body), one backward — under ``conv.gate`` inside ``conv``,
+    beside ``conv.in_proj`` and ``conv.out_proj``, with no window of the
+    in-projection copied out and no float32 copy of it; the one
+    attention layer runs the flash kernels at 32 / 8 heads of 64."""
+    import json
+    import pathlib
+    import re
+
+    path = pathlib.Path(__file__).parent.parent / "benchmarks" / "configs"
+    config = json.loads((path / "lfm2-8b-a1b-ep4-1chip.json").read_text())
+    STEP_CASES["lfm2-cell"] = dict(
+        model=config["program"]["model"],
+        overrides=config["program"]["overrides"],
+        optimizer=dict(state_dtype="bfloat16"), comm=None, chips=1,
+        batch=(8, 4096),
+    )
+    try:
+        builder, text, counters = _compiled_step(topo, "lfm2-cell")
+    finally:
+        del STEP_CASES["lfm2-cell"]
+    assert builder.cfg.num_params() == 568_647_808
+    stats = _STEP_MEMORY["lfm2-cell"]
+    need = (
+        stats.argument_size_in_bytes + stats.output_size_in_bytes
+        - stats.alias_size_in_bytes + stats.temp_size_in_bytes
+    )
+    # 9.84 GB = 9.16 GiB (PR 73)
+    assert 8e9 < need < 15.75 * 2 ** 30, need
+    assert counters["conv.layers"] == 5
+    assert counters["conv.kernel_layers"] == 5
+    assert counters["pattern.scanned_parts"] == 4
+    assert counters["moe.experts_by_prefix"] == 1
+    lines = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+
+    def calls(kernel, scope):
+        return sum(
+            bool(re.match(rf"\s*(?:ROOT )?%{kernel}[.\d]* = ", ln))
+            and f"/{scope}" in ln
+            for ln in lines
+        )
+
+    # the scanned pair's body once, the three unrolled layers each
+    assert calls("gated_conv_fwd", "conv.gate") == 2 * (1 + 3)
+    assert calls("gated_conv_bwd", "conv.gate") == 1 + 3
+    for scope in ("conv.in_proj", "conv.gate", "conv.out_proj"):
+        assert f"/conv/{scope}" in text, scope
+    assert "f32[8,4096,6144]" not in text
+    flash = {
+        m.group(1) for ln in lines
+        if (m := re.match(r"\s*(?:ROOT )?%(flash_\w+?)[.\d]* = ", ln))
+    }
+    assert len(flash) == 3, flash
