@@ -16,16 +16,23 @@ three round-trips.
 Numerics mirror ``models/decoder.py::_norm`` exactly: the (optional)
 residual add happens in the input dtype, statistics are f32
 (single-pass E[x], E[x^2] for layernorm), the output is cast back to
-the input dtype. Padded-lane handling: a non-128-multiple last dim is
-zero-padded at the jnp level — zero lanes contribute nothing to the
-sums (the divisor is the TRUE dim), and the padded output lanes are
-sliced off, so no in-kernel masking is needed.
+the input dtype. The kernels take the last dim AS IT IS (PR 74): a
+block's last dimension is the array's, which Mosaic accepts at any
+width, so a width off the 128 lanes (GPT-2 XL's 1,600) is neither
+padded before the call nor sliced after it — at 1,664 that was seven
+whole-activation passes a layer. The sums along the last axis reduce
+the logical ``[rows, d]`` value: Mosaic masks the last lane tile's
+tail (unspecified in VMEM, not zero) out of a reduction, and the
+divisor is the true dim. At a multiple of 128 nothing differs.
 
 Backward is a custom_vjp with row-local Pallas kernels that recompute
 the statistics from the saved summed stream (cheaper than storing
 per-row stats: in the fused-residual case the stream is a forward
 OUTPUT already, so the residuals cost nothing extra). The per-program
-scale/bias cotangent partials are summed at the jnp level.
+scale/bias cotangent partials are summed at the jnp level. The summed
+stream's own cotangent is added to ``dx`` inside the backward kernel at
+a width of whole lane tiles and by XLA at a width off them
+(``_norm_call_bwd`` says why).
 
 Off-TPU the public entry point falls back to the jnp reference; the
 ``INTERPRET`` hook (or the ``DLROVER_TPU_PALLAS_INTERPRET`` env var,
@@ -47,6 +54,7 @@ except ImportError:  # pragma: no cover
     pltpu = None
 
 from dlrover_tpu.common import device
+from dlrover_tpu.observability.tracing import counters, set_counter
 from dlrover_tpu.ops.pallas_attention import _out_struct
 from dlrover_tpu.ops.pallas_ssd import _traced_once
 
@@ -63,9 +71,10 @@ INTERPRET = os.environ.get(
 RMS_EPS = 1e-6
 LN_EPS = 1e-5
 
-# per-program f32 row-block VMEM budget: bounds [rows, dp] f32
-# transients to ~2 MB each (the kernel holds a handful alongside the
-# input-dtype block), far under the ~16 MB VMEM/core
+# per-program f32 row-block VMEM budget: bounds [rows, d] f32
+# transients (d rounded up to whole 128-lane tiles, what they occupy)
+# to ~2 MB each (the kernel holds a handful alongside the input-dtype
+# block), far under the ~16 MB VMEM/core
 _ROW_BLOCK_BYTES = 2 * 1024 * 1024
 
 
@@ -76,13 +85,14 @@ def kernels_available(interpret=None) -> bool:
     return pltpu is not None and (device.on_tpu() or interpret)
 
 
-def _fit_rows(n: int, dp: int, dtype) -> int:
+def _fit_rows(n: int, d: int, dtype) -> int:
     """Rows per grid program: largest power-of-two block that divides
     the row count, respects the dtype's min sublane tile, and keeps
-    [rows, dp] f32 under the VMEM budget. None = shape untileable
-    (fall back to the jnp reference)."""
+    [rows, d] f32 — at the lanes it occupies in VMEM, d rounded up to
+    128 — under the VMEM budget. None = shape untileable (fall back to
+    the jnp reference)."""
     min_rows = 16 if jnp.dtype(dtype) == jnp.bfloat16 else 8
-    budget = _ROW_BLOCK_BYTES // (4 * dp)
+    budget = _ROW_BLOCK_BYTES // (4 * ((d + 127) // 128 * 128))
     for bn in (512, 256, 128, 64, 32, 16, 8):
         if bn <= budget and bn >= min_rows and n % bn == 0:
             return bn
@@ -111,8 +121,6 @@ def _fwd_kernel(*refs, kind, eps, d, has_bias, has_res):
     x32 = x.astype(jnp.float32)
     s32 = scale_ref[...].astype(jnp.float32)
     if kind == "rmsnorm":
-        # padded lanes are zero: they add nothing to the sum, and the
-        # divisor is the true dim
         ms = jnp.sum(x32 * x32, axis=-1, keepdims=True) / d
         out = x32 * jax.lax.rsqrt(ms + eps) * s32
     else:
@@ -168,7 +176,7 @@ def _bwd_kernel(*refs, kind, eps, d, has_bias, has_res):
 
 
 # ---------------------------------------------------------------------------
-# custom_vjp (operates on [N, dp] padded 2-D views)
+# custom_vjp (operates on [N, d] 2-D views)
 # ---------------------------------------------------------------------------
 
 
@@ -179,12 +187,12 @@ def _compiler_params(interpret):
 
 
 def _call_fwd(kind, eps, dims, interpret, x, scale, bias, res):
-    d, dp, bn = dims
+    d, bn = dims
     n = x.shape[0]
     has_bias = bias is not None
     has_res = res is not None
-    row_spec = pl.BlockSpec((bn, dp), lambda i: (i, 0))
-    vec_spec = pl.BlockSpec((1, dp), lambda i: (0, 0))
+    row_spec = pl.BlockSpec((bn, d), lambda i: (i, 0))
+    vec_spec = pl.BlockSpec((1, d), lambda i: (0, 0))
     in_specs = [row_spec, vec_spec]
     inputs = [x, scale]
     if has_bias:
@@ -194,10 +202,10 @@ def _call_fwd(kind, eps, dims, interpret, x, scale, bias, res):
         in_specs.append(row_spec)
         inputs.append(res)
     out_specs = [row_spec]
-    out_shape = [_out_struct((n, dp), x.dtype, x)]
+    out_shape = [_out_struct((n, d), x.dtype, x)]
     if has_res:
         out_specs.append(row_spec)
-        out_shape.append(_out_struct((n, dp), x.dtype, x))
+        out_shape.append(_out_struct((n, d), x.dtype, x))
     outs = pl.pallas_call(
         functools.partial(
             _fwd_kernel, kind=kind, eps=eps, d=d,
@@ -231,7 +239,7 @@ def _norm_call_fwd(kind, eps, dims, interpret, x, scale, bias, res):
 
 
 def _norm_call_bwd(kind, eps, dims, interpret, saved, g):
-    d, dp, bn = dims
+    d, bn = dims
     h, scale, bias, has_res = saved
     has_bias = bias is not None
     if has_res:
@@ -240,21 +248,28 @@ def _norm_call_bwd(kind, eps, dims, interpret, saved, g):
         gout, gh = g, None
     n = h.shape[0]
     grid = n // bn
-    row_spec = pl.BlockSpec((bn, dp), lambda i: (i, 0))
-    vec_spec = pl.BlockSpec((1, dp), lambda i: (0, 0))
-    # per-program partials live in a [grid, 1, dp] array so the block's
-    # last two dims equal the array's: Mosaic refuses a (1, dp) block
-    # over a (grid, dp) array (sublane dim must be 8-divisible or full)
-    part_spec = pl.BlockSpec((1, 1, dp), lambda i: (i, 0, 0))
-    part_shape = _out_struct((grid, 1, dp), jnp.float32, h)
+    # the summed stream's own cotangent: at a width of whole lane tiles
+    # the kernel adds it. At a width off them XLA does (PR 74): an
+    # operand of a custom call is not prefetched into VMEM for its
+    # other readers, and the carried cotangent's are the MLP's two
+    # weight-gradient matmuls (GPT-2 XL on one chip: 9 ms a step);
+    # XLA sums the stream's cotangents in a pass of its own anyway
+    fold = has_res and d % 128 == 0
+    row_spec = pl.BlockSpec((bn, d), lambda i: (i, 0))
+    vec_spec = pl.BlockSpec((1, d), lambda i: (0, 0))
+    # per-program partials live in a [grid, 1, d] array so the block's
+    # last two dims equal the array's: Mosaic refuses a (1, d) block
+    # over a (grid, d) array (sublane dim must be 8-divisible or full)
+    part_spec = pl.BlockSpec((1, 1, d), lambda i: (i, 0, 0))
+    part_shape = _out_struct((grid, 1, d), jnp.float32, h)
     in_specs = [row_spec, row_spec, vec_spec]
     inputs = [gout, h, scale]
-    if has_res:
+    if fold:
         in_specs.append(row_spec)
         inputs.append(gh)
     out_specs = [row_spec, part_spec]
     out_shape = [
-        _out_struct((n, dp), h.dtype, h),
+        _out_struct((n, d), h.dtype, h),
         part_shape,
     ]
     if has_bias:
@@ -263,7 +278,7 @@ def _norm_call_bwd(kind, eps, dims, interpret, saved, g):
     outs = pl.pallas_call(
         functools.partial(
             _bwd_kernel, kind=kind, eps=eps, d=d,
-            has_bias=has_bias, has_res=has_res,
+            has_bias=has_bias, has_res=fold,
         ),
         grid=(grid,),
         in_specs=in_specs,
@@ -274,6 +289,8 @@ def _norm_call_bwd(kind, eps, dims, interpret, saved, g):
         name="norm_bwd",
     )(*inputs)
     dx = outs[0]
+    if has_res and not fold:
+        dx = dx + gh
     dscale = outs[1].sum(axis=0).astype(scale.dtype)
     dbias = (
         outs[2].sum(axis=0).astype(bias.dtype)
@@ -281,7 +298,7 @@ def _norm_call_bwd(kind, eps, dims, interpret, saved, g):
         else None
     )
     # d(x + res)/dx = d(x + res)/dres = identity: both get the stream
-    # cotangent (gh already folded into dx inside the kernel)
+    # cotangent (gh is in dx already)
     dres = dx if has_res else None
     return dx, dscale, dbias, dres
 
@@ -348,43 +365,26 @@ def norm(
     if not (pltpu is not None and (device.on_tpu() or interpret)):
         return _reference(x, scale, bias, kind, eps, residual)
     n = math.prod(x.shape[:-1])
-    dp = (d + 127) // 128 * 128
-    bn = _fit_rows(n, dp, x.dtype)
+    bn = _fit_rows(n, d, x.dtype)
     if bn is None:
         return _reference(x, scale, bias, kind, eps, residual)
-
-    lead = x.shape[:-1]
-
-    def rows(a):
-        a = a.reshape(n, d)
-        if dp != d:
-            a = jnp.pad(a, ((0, 0), (0, dp - d)))
-        return a
-
-    def vec(a):
-        a = a.reshape(1, d)
-        if dp != d:
-            a = jnp.pad(a, ((0, 0), (0, dp - d)))
-        return a
-
-    def unrows(a):
-        if dp != d:
-            a = a[:, :d]
-        return a.reshape(lead + (d,))
-
+    # trace time: the call sites that hand the kernels a width off the
+    # 128 lanes (their blocks' last tile is part empty)
+    set_counter(
+        "norm.unaligned_calls",
+        counters().get("norm.unaligned_calls", 0) + (d % 128 != 0),
+    )
     out = _norm_call(
         kind,
         eps,
-        (d, dp, bn),
+        (d, bn),
         interpret,
-        rows(x),
-        vec(scale),
-        vec(bias) if bias is not None else None,
-        rows(residual) if residual is not None else None,
+        x.reshape(n, d),
+        scale.reshape(1, d),
+        None if bias is None else bias.reshape(1, d),
+        None if residual is None else residual.reshape(n, d),
     )
-    if residual is not None:
-        return unrows(out[0]), unrows(out[1])
-    return unrows(out)
+    return jax.tree.map(lambda a: a.reshape(x.shape), out)
 
 
 # ---------------------------------------------------------------------------

@@ -33,23 +33,27 @@ def _ref(x, scale, bias, kind, residual=None):
 
 
 def _make(kind, dt, d, with_res, seed=0):
+    # GPT-2 XL's width at 1,024 rows (four row blocks of 256: twelve
+    # whole lane tiles and a thirteenth half empty), else 32 rows
+    lead = (1, 1024) if d == 1600 else (2, 16)
     ks = jax.random.split(jax.random.key(seed), 4)
-    x = jax.random.normal(ks[0], (2, 16, d), dt)
+    x = jax.random.normal(ks[0], lead + (d,), dt)
     s = (1.0 + 0.1 * jax.random.normal(ks[1], (d,))).astype(dt)
     b = (
         (0.1 * jax.random.normal(ks[2], (d,))).astype(dt)
         if kind == "layernorm"
         else None
     )
-    res = jax.random.normal(ks[3], (2, 16, d), dt) if with_res else None
+    res = jax.random.normal(ks[3], lead + (d,), dt) if with_res else None
     return x, s, b, res
 
 
 @pytest.mark.parametrize("kind", ["rmsnorm", "layernorm"])
 @pytest.mark.parametrize("dt", [jnp.float32, jnp.bfloat16])
-# 256 = clean lanes; 192/100 exercise the zero-pad-to-128 path (100 is
-# the odd last-dim case: pad 28 lanes, slice them back off)
-@pytest.mark.parametrize("d", [256, 192, 100])
+# 256 = clean lanes; 192/100/1600 are widths off the 128 lanes, which
+# the kernels take as they are (a block's last tile part empty; 100 is
+# the odd case: one tile, 28 lanes of it empty)
+@pytest.mark.parametrize("d", [256, 192, 100, 1600])
 @pytest.mark.parametrize("with_res", [False, True])
 def test_forward_parity(kind, dt, d, with_res):
     tol = 2e-5 if dt == jnp.float32 else 2e-2
@@ -76,7 +80,7 @@ def test_forward_parity(kind, dt, d, with_res):
 
 @pytest.mark.parametrize("kind", ["rmsnorm", "layernorm"])
 @pytest.mark.parametrize("dt", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("d", [256, 100])
+@pytest.mark.parametrize("d", [256, 100, 1600])
 @pytest.mark.parametrize("with_res", [False, True])
 def test_grad_parity(kind, dt, d, with_res):
     """Backward kernels vs jnp autodiff: dx, dscale, dbias, dres —
@@ -114,6 +118,150 @@ def test_grad_parity(kind, dt, d, with_res):
             rtol=tol, atol=tol,
             err_msg=f"grad argnum {a}",
         )
+
+
+def _walk(jaxpr, calls, outside):
+    """The ``pallas_call`` equations of a jaxpr and the names of every
+    primitive outside them, sub-jaxprs included."""
+    for e in jaxpr.eqns:
+        if e.primitive.name == "pallas_call":
+            calls.append(e)
+            continue
+        outside.add(e.primitive.name)
+        for sub in jax.core.jaxprs_in_params(e.params):
+            _walk(sub, calls, outside)
+
+
+def _blocks(call):
+    return [
+        tuple(getattr(dim, "block_size", dim) for dim in bm.block_shape)
+        for bm in call.params["grid_mapping"].block_mappings
+    ]
+
+
+@pytest.mark.parametrize("d", [1600, 2048])
+def test_kernels_take_the_width_as_it_is(d):
+    """Forward and ``jax.grad``: nothing but reshapes around the two
+    kernels — no ``pad`` before a call and no ``slice`` after it — and
+    every block's last dimension the array's own. At 2,048 that is the
+    form the kernels always had (rows of 256 by the VMEM budget, the
+    vectors ``(1, d)``, the partials ``(1, 1, d)``, the summed stream's
+    cotangent an operand of the backward kernel); at 1,600 the budget
+    goes by the 1,664 lanes a block occupies and gives the same rows,
+    and the stream's cotangent is added outside the kernel."""
+    x = res = jax.ShapeDtypeStruct((1, 1024, d), jnp.bfloat16)
+    s = b = jax.ShapeDtypeStruct((d,), jnp.bfloat16)
+
+    def fwd(x, s, b, res):
+        return pallas_norm.norm(
+            x, s, b, "layernorm", residual=res, interpret=True
+        )
+
+    def loss(*a):
+        out, h = fwd(*a)
+        return (out.astype(jnp.float32) * 1.3).sum() + (
+            h.astype(jnp.float32) * 0.7
+        ).sum()
+
+    for fn, names in (
+        (fwd, ["norm_fwd"]),
+        (jax.grad(loss, argnums=(0, 1, 2, 3)), ["norm_fwd", "norm_bwd"]),
+    ):
+        calls, outside = [], set()
+        _walk(jax.make_jaxpr(fn)(x, s, b, res).jaxpr, calls, outside)
+        assert [c.params["name"] for c in calls] == names
+        assert not outside & {"pad", "slice", "dynamic_slice", "gather"}
+        for call in calls:
+            assert call.params["grid_mapping"].grid == (4,)
+            assert all(a.aval.shape[-1] == d for a in call.invars)
+            assert all(a.aval.shape[-1] == d for a in call.outvars)
+        row, vec, part = (256, d), (1, d), (1, 1, d)
+        # x, scale, bias, residual -> out, summed stream
+        assert _blocks(calls[0]) == [row, vec, vec, row, row, row]
+        if len(calls) == 2:
+            # g, h, scale, h's cotangent -> dx, dscale's and dbias's
+            # parts; at a width off the lanes h's cotangent is no
+            # operand: XLA adds it to dx
+            stream = [row] if d % 128 == 0 else []
+            assert _blocks(calls[1]) == (
+                [row, row, vec] + stream + [row, part, part]
+            )
+
+
+@pytest.mark.parametrize("with_res", [False, True])
+def test_lanes_behind_the_width_do_not_reach_the_sums(with_res):
+    """The operand as a window of a [n, 1664] array of ones — what lies
+    behind column 1,600 is not zero — against the plain operand, values
+    and gradients to the bit: a reduction that read a block's last tile
+    whole would fail here and not on the chip."""
+    x, s, b, res = _make("layernorm", jnp.bfloat16, 1600, with_res, seed=5)
+
+    def windowed(a):
+        wide = jnp.ones(a.shape[:-1] + (1664,), a.dtype)
+        return wide.at[..., :1600].set(a)[..., :1600]
+
+    def run(x, s, b, res):
+        def loss(x, s, b, res):
+            o = pallas_norm.norm(
+                x, s, b, "layernorm", residual=res, interpret=True
+            )
+            o = o if with_res else (o,)
+            return sum((t.astype(jnp.float32) * 1.3).sum() for t in o), o
+
+        argnums = (0, 1, 2, 3) if with_res else (0, 1, 2)
+        return jax.jit(jax.grad(loss, argnums=argnums, has_aux=True))(
+            x, s, b, res
+        )
+
+    plain = run(x, s, b, res)
+    wide = run(
+        windowed(x), windowed(s), windowed(b),
+        windowed(res) if with_res else None,
+    )
+    for u, v in zip(jax.tree.leaves(plain), jax.tree.leaves(wide)):
+        np.testing.assert_array_equal(np.asarray(u), np.asarray(v))
+    # and the divisor is the true width: a row of ones but for one
+    # column has the statistics of 1,600 columns, not of 1,664
+    row = jnp.ones((16, 1600), jnp.float32).at[:, 0].set(41.0)
+    out = pallas_norm.norm(
+        row, jnp.ones((1600,)), jnp.zeros((1600,)), "layernorm",
+        interpret=True,
+    )
+    mean, var = 1.0 + 40.0 / 1600, 40.0**2 / 1600 - (40.0 / 1600) ** 2
+    np.testing.assert_allclose(
+        np.asarray(out[:, 1]), (1.0 - mean) / np.sqrt(var + 1e-5), rtol=1e-5
+    )
+
+
+def test_unaligned_calls_are_counted_where_they_are_traced():
+    """``norm.unaligned_calls``: the call sites traced at a width off
+    the 128 lanes — one a ``norm`` call whatever is derived from it,
+    none at a whole number of lane tiles, none on the jnp fallback."""
+    from dlrover_tpu.observability import tracing
+
+    def count():
+        return tracing.counters().get("norm.unaligned_calls")
+
+    def call(d, interpret=True, grad=False):
+        x = jnp.ones((2, 16, d), jnp.bfloat16)
+
+        def fn(x):
+            return pallas_norm.norm(
+                x, jnp.ones((d,)), None, "rmsnorm", interpret=interpret
+            ).astype(jnp.float32).sum()
+
+        jax.make_jaxpr(jax.grad(fn) if grad else fn)(x)
+
+    call(2048)
+    before = count()
+    assert before is not None
+    call(2048, grad=True)
+    call(1600, interpret=False)  # off the chip: the jnp body
+    assert count() == before
+    call(1600)
+    assert count() == before + 1
+    call(100, grad=True)
+    assert count() == before + 2
 
 
 def test_untileable_rows_fall_back():
@@ -255,19 +403,11 @@ def test_l2_heads_in_the_decoder_is_a_view_around_the_kernel(monkeypatch):
     jaxpr = jax.make_jaxpr(
         jax.value_and_grad(lambda t: jnp.sum(decoder._l2_heads(t, 0.5) * t))
     )(t)
-    names, primitives = [], set()
-
-    def walk(jaxpr):
-        for e in jaxpr.eqns:
-            primitives.add(e.primitive.name)
-            if e.primitive.name == "pallas_call":
-                names.append(e.params["name"])
-            else:
-                for sub in jax.core.jaxprs_in_params(e.params):
-                    walk(sub)
-
-    walk(jaxpr.jaxpr)
-    assert names == ["l2_heads_fwd", "l2_heads_bwd"]
+    calls, primitives = [], set()
+    _walk(jaxpr.jaxpr, calls, primitives)
+    assert [c.params["name"] for c in calls] == [
+        "l2_heads_fwd", "l2_heads_bwd"
+    ]
     assert "rsqrt" not in primitives
 
 

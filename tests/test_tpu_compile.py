@@ -580,6 +580,11 @@ def test_kernel_compiles_for_v5e(chip, case):
     compiled = jax.jit(fn).lower(*args).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == n_kernels
+    if case.startswith("norm-") and case.endswith("-d1600"):
+        # the width as it is (PR 74): nothing rounded up to 13 whole
+        # lane tiles around the kernels, no pad made and none taken off
+        assert "1664" not in text
+        assert " pad(" not in text and " slice(" not in text
     if "-sel-" in case:
         names = ("flash_fwd_sel", "flash_bwd_dq_sel", "flash_bwd_dkv_sel")
         assert sum(name in text for name in names) == n_kernels
@@ -1075,6 +1080,12 @@ def test_step_names_its_kernels_and_phases(topo, case):
     # keep the projections' layout in the whole step too
     packed = "flash_fwd_packed" in spec["kernels"]
     assert counters["attn.heads_per_slab"] == (2 if packed else 1)
+    # the norm kernels' call sites at a width off the 128 lanes (PR 74:
+    # GPT-2 XL's 1,600 columns as they are, no [.., 1664] array made)
+    unaligned = builder.cfg.d_model % 128 != 0
+    assert (counters["norm.unaligned_calls"] > 0) == unaligned
+    if unaligned:
+        assert not re.search(r"[\[,]1664[\],]", text)
     # the forward grid's inner axis: the band of key blocks under a live
     # window, else every key block; the backward's tile under a window
     if "flash_fwd_sel" not in spec["kernels"]:  # (no window with one)
