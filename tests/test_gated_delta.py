@@ -255,11 +255,17 @@ KERNEL_CASES = {
 
 
 def _value_and_grads(fn, args, w):
-    o = jax.jit(fn)(*args)
-    grads = jax.jit(jax.grad(
-        lambda *a: (fn(*a).astype(F32) * w).sum(), range(5)
-    ))(*args)
-    return o.astype(F32), [g.astype(F32) for g in grads]
+    """One program: the value is the forward the gradients' rule runs
+    (``_kernel_rule_fwd`` IS the rule), not a second compile of it."""
+
+    def loss(*a):
+        o = fn(*a).astype(F32)
+        return (o * w).sum(), o
+
+    (_, o), grads = jax.jit(
+        jax.value_and_grad(loss, range(5), has_aux=True)
+    )(*args)
+    return o, [g.astype(F32) for g in grads]
 
 
 @pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])

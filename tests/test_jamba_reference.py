@@ -12,9 +12,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from reference_suite import Suite
 
 from benchmarks.references import jamba_plain as plain
-from dlrover_tpu.models import decoder, generate, get_config
+from dlrover_tpu.models import decoder, get_config
 from dlrover_tpu.models.config import pattern_layers, pattern_parts
 from dlrover_tpu.observability import tracing
 from dlrover_tpu.ops import selective_scan as sscan
@@ -34,28 +35,23 @@ SIZE_KEYS = (
 SEQ = 40
 
 
-def _cfg(**over):
-    return get_config("jamba2-3b", **{**TINY, **over})
+def _made(cfg, seed):
+    params = decoder.init(jax.random.key(seed), cfg)
+    # a head that reads the token table at a size where logits differ
+    params["embed"]["tokens"] = params["embed"]["tokens"] * 20.0
+    return params
 
 
-def _sizes(cfg):
-    return dict({k: getattr(cfg, k) for k in SIZE_KEYS}, norm_eps=1e-6)
-
-
-def _batch(seq=SEQ, rows=2, vocab=256):
-    data = jnp.asarray(
-        np.random.default_rng(7).integers(0, vocab, (rows, seq + 1)), jnp.int32
-    )
-    return {"tokens": data[:, :-1], "targets": data[:, 1:]}
+SUITE = Suite(
+    "jamba2-3b", plain, TINY, SIZE_KEYS, seq=SEQ, q_block=8, make=_made,
+    doubled=False,
+)
+_cfg, _sizes, _batch = SUITE.cfg, SUITE.sizes, SUITE.batch
 
 
 @pytest.fixture(scope="module")
 def model():
-    cfg = _cfg()
-    params = decoder.init(jax.random.key(0), cfg)
-    # a head that reads the token table at a size where logits differ
-    params["embed"]["tokens"] = params["embed"]["tokens"] * 20.0
-    return cfg, params
+    return SUITE.model()
 
 
 def _reference(params, batch, cfg):
@@ -79,19 +75,7 @@ def test_every_parameters_gradient_matches_the_reference(model):
     """The hand-written derivative of the scan inside the whole model,
     through remat and the scanned runs, against autodiff of the
     reference's token-by-token recurrence."""
-    cfg, params = model
-    batch = _batch()
-    got = jax.grad(lambda p: decoder.loss_fn(p, batch, cfg=cfg)[0])(params)
-    with jax.default_matmul_precision("highest"):
-        want = jax.grad(
-            lambda p: plain.loss_and_logits(p, batch, _sizes(cfg), 8)[0]
-        )(params)
-    flat_got = dict(jax.tree_util.tree_leaves_with_path(got))
-    for path, w in jax.tree_util.tree_leaves_with_path(want):
-        g = flat_got[path]
-        top = float(jnp.max(jnp.abs(w)))
-        assert top > 0, path
-        assert float(jnp.max(jnp.abs(g - w))) / top < 2e-4, path
+    SUITE.gradients_match(model, forced=False)
 
 
 # ---- the selective scan -----------------------------------------------------
@@ -393,11 +377,5 @@ def test_a_mixer_without_its_mlp_is_a_layer_of_its_own():
 
 
 def test_cache_paths_refuse_the_model(model):
-    cfg, params = model
-    with pytest.raises(ValueError, match="jamba2-3b: state-space layers"):
-        decoder.prefill(params, _batch()["tokens"], cfg, 64)
-    with pytest.raises(ValueError, match="jamba2-3b: state-space layers"):
-        generate.sample(
-            params, cfg, _batch()["tokens"][:, :4], max_new_tokens=2,
-            rng=jax.random.key(0),
-        )
+    for path in ("prefill", "sample"):
+        SUITE.refuses(model, path, "jamba2-3b: state-space layers")

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from reference_suite import Suite, seeded
 
 from benchmarks.lib import flops, selected
 from benchmarks.references import keye_vl2_plain as plain
@@ -23,7 +24,6 @@ from dlrover_tpu.models import decoder, generate, get_config
 from dlrover_tpu.observability import tracing
 from dlrover_tpu.ops import pallas_attention
 from dlrover_tpu.ops.attention import mha_reference
-from dlrover_tpu.parallel import moe
 
 CONFIG = (
     pathlib.Path(__file__).parent.parent
@@ -43,15 +43,17 @@ TOLERANCES = (1e-3, 1e-3, 1e-4)
 Q_BLOCK = 32
 
 
-def _cfg(**over):
-    return get_config("keye-vl-2.0", **{**TINY, **over})
-
-
-def _sizes(cfg):
-    """The configuration file's ``sizes`` keys, read off ``cfg``."""
-    keys = json.loads(CONFIG.read_text())["sizes"]
-    return dict({k: getattr(cfg, k) for k in keys if k != "norm_eps"},
-                norm_eps=1e-6)
+# the configuration file's ``sizes`` keys, read off a config; norm
+# scales away from one, so that a scale left out shows
+SUITE = Suite(
+    "keye-vl-2.0", plain, TINY,
+    [k for k in json.loads(CONFIG.read_text())["sizes"] if k != "norm_eps"],
+    seq=128, q_block=Q_BLOCK,
+    make=lambda cfg, seed: seeded(
+        cfg, seed, scales=jax.random.key(9), spread=0.2, head=False
+    ),
+)
+_cfg, _sizes = SUITE.cfg, SUITE.sizes
 
 
 def _batch(cfg, seq=128, rows=2):
@@ -63,18 +65,7 @@ def _batch(cfg, seq=128, rows=2):
 
 @pytest.fixture(scope="module")
 def model():
-    """Seeded weights with norm scales away from one, so that a scale
-    left out shows."""
-    cfg = _cfg()
-    params = decoder.init(jax.random.key(0), cfg)
-    keys = iter(jax.random.split(jax.random.key(9), 64))
-
-    def jiggle(path, w):
-        if path[-1].key == "scale":
-            return w * (1.0 + 0.2 * jax.random.normal(next(keys), w.shape))
-        return w
-
-    return cfg, jax.tree_util.tree_map_with_path(jiggle, params)
+    return SUITE.model()
 
 
 def _judged(cfg, params, batch):
@@ -554,34 +545,8 @@ def test_a_selection_of_every_key_is_the_unmasked_kernel(monkeypatch):
 
 def test_shares_of_the_expert_parallel_layer_add_up():
     """Eight chips hold experts 0-1 ... 14-15 of one routed layer (16
-    experts as 8 x 2, softmax top-4 renormalised over all four chosen).
-    Their parts add up to what the uncut reference gives for the whole
-    layer, and every (token, choice) row goes to exactly one share."""
-    shares, held = 8, 2
-    whole = _cfg(n_experts=shares * held, n_experts_held=0, expert_offset=0)
-    full = moe.init_moe_params(jax.random.key(3), whole, lead=())
-    g = jax.random.normal(jax.random.key(4), (2, 32, whole.d_model))
-    sizes = dict(_sizes(whole), n_experts_held=shares * held, expert_offset=0)
-    with jax.default_matmul_precision("highest"):
-        want, _, _ = plain._routed(g.reshape(64, -1), full, sizes, None)
-        total, rows = 0.0, 0.0
-        for rank in range(shares):
-            cfg = dataclasses.replace(
-                whole, n_experts_held=held, expert_offset=rank * held
-            )
-            here = slice(rank * held, (rank + 1) * held)
-            part = dict(
-                full, **{k: full[k][here]
-                         for k in ("w_up", "w_gate_proj", "w_down")}
-            )
-            out, aux = moe._moe_block_ragged(g, part, cfg)
-            total = total + out
-            rows += float(aux["moe_held_rows"])
-    np.testing.assert_allclose(
-        np.asarray(total).reshape(64, -1), np.asarray(want),
-        rtol=2e-5, atol=2e-5,
-    )
-    assert rows == 2 * 32 * whole.expert_top_k
+    experts as 8 x 2, softmax top-4 renormalised over all four chosen)."""
+    SUITE.shares_add_up(8, 2, n_experts=16, expert_offset=0)
 
 
 def test_required_terms_by_hand():

@@ -13,7 +13,6 @@ parameter count and the FLOPs against the configuration file's
 arithmetic, latent attention's new shapes against ``mha_reference``, and
 GLM's preset building what it built."""
 
-import dataclasses
 import hashlib
 import json
 import pathlib
@@ -22,15 +21,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from reference_suite import LOGITS, Suite, seeded
 
 from benchmarks.lib import flops as flopslib
-from benchmarks.lib import routed
 from benchmarks.references import kimi_linear_plain as plain
 from benchmarks.tests import kimilinear_defects as defects
-from dlrover_tpu.models import decoder, generate, get_config
+from dlrover_tpu.models import decoder, get_config
 from dlrover_tpu.models.config import pattern_layers, pattern_parts
 from dlrover_tpu.ops.attention import mha_reference
-from dlrover_tpu.parallel import moe
 
 # the cell's five layers; 4 heads of 8 key and 8 value channels in the
 # mixer (a sequence of 72 is a chunk of 64 and a padded one: sub-blocks
@@ -63,75 +61,28 @@ SIZE_KEYS = (
 # (``test_bf16_between_the_mixers_matmuls_fails``)
 TOLERANCES = (1e-3, 2e-4, 1e-4)
 SEQ = 72
-
-
-def _cfg(**over):
-    return get_config("kimi-linear", **{**TINY, **over})
-
-
-def _sizes(cfg):
-    return {k: getattr(cfg, k) for k in SIZE_KEYS}
-
-
-def _batch(seq=SEQ, rows=2, vocab=256):
-    """Every token twice in a row (a a b b c c ...): the next token is
-    the present one half of the time."""
-    half = np.random.default_rng(7).integers(0, vocab, (rows, seq // 2 + 1))
-    data = jnp.asarray(np.repeat(half, 2, axis=1)[:, : seq + 1], jnp.int32)
-    return {"tokens": data[:, :-1], "targets": data[:, 1:]}
-
-
-def _scaled(tree, key):
-    """Every norm scale moved off its initial 1: at 1 a program that
-    norms after the gate, or leaves a norm out, could not be told from
-    a sound one by these alone."""
-    leaves, treedef = jax.tree_util.tree_flatten_with_path(tree)
-    out = []
-    for i, (path, leaf) in enumerate(leaves):
-        if "scale" in jax.tree_util.keystr(path):
-            leaf = leaf + 0.3 * jax.random.normal(
-                jax.random.fold_in(key, i), leaf.shape
-            )
-        out.append(leaf)
-    return jax.tree_util.tree_unflatten(treedef, out)
+# norm scales off their initial 1: at 1 a program that norms after the
+# gate, or leaves a norm out, could not be told from a sound one. A_log,
+# dt_bias and the conv's taps are drawn, not constants, by
+# ``decoder.init`` itself
+SUITE = Suite(
+    "kimi-linear", plain, TINY, SIZE_KEYS, seq=SEQ, q_block=8,
+    tolerances=TOLERANCES, norm_eps=None,
+    make=lambda cfg, seed: seeded(
+        cfg, seed, scales=jax.random.key(seed + 1), by_index=True
+    ),
+)
+_cfg, _sizes, _batch = SUITE.cfg, SUITE.sizes, SUITE.batch
 
 
 @pytest.fixture(scope="module")
 def model():
-    """Seeded weights, but for a head that reads the token table
-    (``tests/test_glm_reference.py`` says why) and norms that are off
-    their initial values. A_log, dt_bias and the conv's taps are drawn,
-    not constants, by ``decoder.init`` itself."""
-    cfg = _cfg()
-    params = _scaled(decoder.init(jax.random.key(0), cfg), jax.random.key(1))
-    d = cfg.d_model
-    params["lm_head"]["w"] = params["embed"]["tokens"].T / (0.02 * d ** 0.5)
-    return cfg, params
-
-
-def _compare(cfg, params, batch, sizes=None):
-    """The cell's comparison, teacher-forced and free-running."""
-    sizes = sizes or _sizes(cfg)
-    logits, choices = routed.program_logits_and_choices(
-        params, batch["tokens"], cfg
-    )
-    program = routed.program_losses(params, batch, cfg)
-    results, record = routed.compare(
-        plain, params, batch, sizes, 8, logits, choices, program, TOLERANCES
-    )
-    with jax.default_matmul_precision("highest"):
-        free_loss, _ = plain.loss_and_logits(params, batch, sizes, 8)
-    err = abs(program["loss"] - float(free_loss)) / float(free_loss)
-    results.append(
-        ("loss_vs_free_reference", err <= routed.FREE_LOSS_TOL, err,
-         routed.FREE_LOSS_TOL)
-    )
-    return {name: (ok, value) for name, ok, value, _ in results}, record
+    return SUITE.model()
 
 
 def test_program_matches_the_plain_reference(model):
     cfg, params = model
-    checks, record = _compare(cfg, params, _batch())
+    checks, record = SUITE.compare(cfg, params)
     assert list(checks) == [
         "choices_valid", "routing_regret", "logits_vs_reference",
         "logits_rms_vs_reference", "loss_vs_reference",
@@ -154,26 +105,20 @@ def test_the_reference_does_not_import_the_program():
 
 def test_forward_hands_over_every_choice_and_the_readout(model):
     cfg, params = model
-    batch = _batch()
-    _, aux = jax.jit(
-        lambda p, t: decoder.forward(p, t, cfg, return_aux=True)
-    )(params, batch["tokens"])
-    ids = np.asarray(aux["moe_choices"])
+    ids = np.asarray(SUITE.forward(cfg, params)[1])
     assert ids.dtype == np.int32
     assert ids.shape == (4, 2, SEQ, cfg.expert_top_k)
     assert ids.max() >= cfg.n_experts_held and ids.max() < cfg.n_experts
-    metrics = jax.jit(lambda p, b: decoder.loss_fn(p, b, cfg)[1])(
-        params, batch
-    )
-    assert float(metrics["moe_held_rows"]) == pytest.approx(
+    metrics = SUITE.losses(cfg, params)
+    assert metrics["moe_held_rows"] == pytest.approx(
         (ids < cfg.n_experts_held).sum() / 4
     )
     assert set(metrics) >= {"loss", "kda_readout_ms", "moe_held_rows"}
     with jax.default_matmul_precision("highest"):
         _, scores, readout = jax.jit(
             lambda p, t: plain.forward(p, t, _sizes(cfg), 8)
-        )(params, batch["tokens"])
-    assert float(metrics["kda_readout_ms"]) == pytest.approx(
+        )(params, _batch()["tokens"])
+    assert metrics["kda_readout_ms"] == pytest.approx(
         float(readout), rel=1e-5
     )
     # the router's scores: the program's choices are the top-2 of the
@@ -206,7 +151,9 @@ def test_the_first_layer_is_a_mixer_and_a_dense_mlp():
 
 def test_norm_scales_and_the_decays_parameters_start_as_drawn():
     cfg = _cfg()
-    kda = decoder.init(jax.random.key(0), cfg)["layers"]["kda"]["kda"]
+    kda = jax.jit(decoder.init, static_argnums=1)(
+        jax.random.key(0), cfg
+    )["layers"]["kda"]["kda"]
     inner = cfg.kda_heads * cfg.kda_head_dim
     assert kda["w_qkv"].shape == (4, 64, 3 * inner)
     assert kda["w_gates"].shape == (4, 64, 2 * 8 + 4)
@@ -244,7 +191,7 @@ def _bf16_between_the_matmuls(patch):
     patch(ssd, "gated_group_norm", lambda *a, **kw: rounded(norm(*a, **kw)))
 
 
-def _conv_looks_ahead(patch):
+def _conv_looks_ahead(patch, cfg):
     from dlrover_tpu.ops import ssd
 
     conv = ssd.causal_conv
@@ -254,11 +201,11 @@ def _conv_looks_ahead(patch):
     )
 
 
-LOGITS = ("logits_vs_reference", "logits_rms_vs_reference")
 DEFECTS = {
     **{
-        name: (defects.PLANT[name], defects.CAUGHT_BY[name])
-        for name in defects.PLANT
+        name: (lambda patch, cfg, plant=plant: plant(patch),
+               defects.CAUGHT_BY[name])
+        for name, plant in defects.PLANT.items()
     },
     "conv_looks_ahead": (_conv_looks_ahead, LOGITS),
     "softmax_for_sigmoid": (dict(moe_score="softmax"), LOGITS),
@@ -268,16 +215,7 @@ DEFECTS = {
 
 @pytest.mark.parametrize("defect", sorted(DEFECTS))
 def test_comparison_catches(monkeypatch, model, defect):
-    cfg, params = model
-    plant, caught_by = DEFECTS[defect]
-    program_cfg = cfg
-    if isinstance(plant, dict):
-        program_cfg = dataclasses.replace(cfg, **plant)
-    else:
-        plant(monkeypatch.setattr)
-    checks, _ = _compare(program_cfg, params, _batch(), sizes=_sizes(cfg))
-    failed = {name for name, (ok, _) in checks.items() if not ok}
-    assert failed & set(caught_by), (defect, checks)
+    SUITE.catches(monkeypatch, model, *DEFECTS[defect])
 
 
 def test_bf16_between_the_mixers_matmuls_fails(monkeypatch, model):
@@ -285,7 +223,7 @@ def test_bf16_between_the_mixers_matmuls_fails(monkeypatch, model):
     program and one with bf16 between the mixer's matmuls."""
     cfg, params = model
     _bf16_between_the_matmuls(monkeypatch.setattr)
-    checks, _ = _compare(cfg, params, _batch())
+    checks, _ = SUITE.compare(cfg, params, planted=True, read=LOGITS)
     assert not checks["logits_rms_vs_reference"][0], checks
     assert checks["logits_rms_vs_reference"][1] > 5 * TOLERANCES[1]
 
@@ -297,7 +235,7 @@ def test_a_scale_of_the_readout_shows_in_its_mean_square(monkeypatch, model):
     times the reference's."""
     cfg, params = model
     defects.PLANT["query_scale_left_out"](monkeypatch.setattr)
-    checks, _ = _compare(cfg, params, _batch())
+    checks, _ = SUITE.compare(cfg, params, planted=True)
     ok, value = checks["kda_readout_ms_vs_reference"]
     assert not ok
     assert value == pytest.approx(cfg.kda_head_dim - 1, rel=0.1)
@@ -307,37 +245,10 @@ def test_a_scale_of_the_readout_shows_in_its_mean_square(monkeypatch, model):
 
 
 def test_sixteen_shares_of_the_expert_parallel_layer_add_up():
-    """Sixteen chips hold one expert each of one routed block's sixteen.
-    Their routed parts, and the shared expert ONCE, add up to what the
-    uncut reference gives for the whole block: nothing is lost or
-    counted twice at the seams, and a token's weights are over all it
-    chose, times the scaling factor."""
-    shares, held = 16, 1
-    whole = _cfg(n_experts=16, expert_top_k=4, n_experts_held=0)
-    full = moe.init_moe_params(jax.random.key(3), whole, lead=())
-    g = jax.random.normal(jax.random.key(4), (2, 32, whole.d_model))
-    sizes = dict(_sizes(whole), n_experts_held=shares * held, expert_offset=0)
-    with jax.default_matmul_precision("highest"):
-        want, _ = plain._routed(g.reshape(64, -1), full, sizes, None)
-        total = moe._shared_expert(g, full["shared"], None)
-        rows = 0.0
-        for rank in range(shares):
-            cfg = dataclasses.replace(
-                whole, n_experts_held=held, expert_offset=rank * held
-            )
-            here = slice(rank * held, (rank + 1) * held)
-            part = dict(full, **{
-                k: full[k][here] for k in ("w_up", "w_down", "w_gate_proj")
-            })
-            out, aux = moe._moe_block_ragged(g, part, cfg)
-            total = total + out
-            rows += float(aux["moe_held_rows"])
-    np.testing.assert_allclose(
-        np.asarray(total).reshape(64, -1), np.asarray(want),
-        rtol=2e-5, atol=2e-5,
-    )
-    # every (token, choice) row went to exactly one share
-    assert rows == 2 * 32 * whole.expert_top_k
+    """Sixteen chips hold one expert each of one routed block's
+    sixteen; a token's weights are over all it chose, times the scaling
+    factor."""
+    SUITE.shares_add_up(16, 1, n_experts=16, expert_top_k=4)
 
 
 # ---- the gradient -----------------------------------------------------------
@@ -348,37 +259,15 @@ def test_gradient_of_every_kind_of_parameter_is_the_references(model):
     hand-written inverse derivative, the latent attention with its
     padded values, the held experts' cut dispatch and combine, against
     ``jax.grad`` of the plain reference sent to the same experts:
-    element by element, and so the norms."""
-    cfg, params = model
-    batch = _batch()
-    sizes = _sizes(cfg)
-    _, choices = routed.program_logits_and_choices(
-        params, batch["tokens"], cfg
-    )
-
-    def objective(p):
-        return plain.loss_and_logits_routed(p, batch, sizes, 8, choices)[0]
-
-    got = jax.jit(jax.grad(lambda p: decoder.loss_fn(p, batch, cfg)[0]))(
-        params
-    )
-    with jax.default_matmul_precision("highest"):
-        want = jax.jit(jax.grad(objective))(params)
-    flat_got = jax.tree_util.tree_leaves_with_path(got)
-    flat_want = jax.tree.leaves(want)
-    assert len(flat_got) == len(flat_want)
-    for (path, a), b in zip(flat_got, flat_want):
-        name = jax.tree_util.keystr(path)
-        scale = float(jnp.max(jnp.abs(b))) or 1.0
-        assert float(jnp.max(jnp.abs(b))) > 0, name
-        np.testing.assert_allclose(
-            # (float32 on both sides; ten parts amplify its rounding)
-            np.asarray(a) / scale, np.asarray(b) / scale, atol=2e-3,
-            err_msg=name,
-        )
+    element by element (float32 on both sides; ten parts amplify its
+    rounding), and so the norms."""
+    got, want = SUITE.gradients_match(model, atol=2e-3)
+    for (path, a), b in zip(
+        jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)
+    ):
         assert float(jnp.linalg.norm(a)) == pytest.approx(
             float(jnp.linalg.norm(b)), rel=2e-3
-        ), name
+        ), jax.tree_util.keystr(path)
 
 
 # ---- the parameters and the FLOPs -------------------------------------------
@@ -443,13 +332,9 @@ def test_num_params_is_the_files_arithmetic():
 @pytest.mark.parametrize("size", ["tiny", "cell"])
 def test_flops_per_token_is_the_references_required_terms(size):
     cfg, seq = (_cfg(), SEQ) if size == "tiny" else (_cell_cfg(), 16384)
-    sizes = _sizes(cfg)
-    terms = plain.required_terms(sizes, seq)
-    assert cfg.flops_per_token(seq) == pytest.approx(
-        flopslib.flops_of(terms), rel=1e-12
-    )
+    terms = SUITE.flops_terms(cfg, seq)
     if size == "cell":
-        rule = 4 * plain.kda_multiply_adds(sizes)
+        rule = 4 * plain.kda_multiply_adds(_sizes(cfg))
         assert rule == 4 * 1_835_008
         assert terms["multiplied_params"] == 350_011_392
         # 32 heads x (192 + 128) / 2 channels x 8,192.5 keys
@@ -584,16 +469,9 @@ def test_the_published_pattern_traces_whole():
 
 
 def test_cache_paths_refuse_the_model_by_name(model):
-    cfg, params = model
-    assert "decay a key channel (K)" in cfg.train_only
-    tokens = _batch()["tokens"]
-    with pytest.raises(ValueError, match="key channel"):
-        decoder.prefill(params, tokens, cfg, max_len=128)
-    with pytest.raises(ValueError, match="key channel"):
-        generate.sample(
-            params, cfg, tokens[:, :4], max_new_tokens=2,
-            rng=jax.random.key(0),
-        )
+    assert "decay a key channel (K)" in model[0].train_only
+    for path in ("prefill", "sample"):
+        SUITE.refuses(model, path, "key channel")
 
 
 @pytest.mark.parametrize(

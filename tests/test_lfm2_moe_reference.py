@@ -17,15 +17,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from reference_suite import REFUSALS, Suite, seeded
 
-from benchmarks.lib import routed
 from benchmarks.references import lfm2_moe_plain as plain
 from benchmarks.runners.train import _program_config
 from benchmarks.tests import lfm2_defects
-from dlrover_tpu.models import decoder, generate, get_config
+from dlrover_tpu.models import decoder, get_config
 from dlrover_tpu.observability import tracing
 from dlrover_tpu.ops import pallas_attention, ssd
-from dlrover_tpu.parallel import moe
 
 # two conv + dense layers (a scanned run of two), an attention + routed
 # layer and a conv + routed one
@@ -58,65 +57,28 @@ CELL = (
 )
 
 
-def _cfg(**over):
-    return get_config("lfm2-8b-a1b", **{**TINY, **over})
-
-
-def _sizes(cfg):
-    return {k: getattr(cfg, k) for k in SIZE_KEYS}
-
-
-def _batch(seq=64, rows=2, vocab=256):
-    """Every token twice in a row (a a b b c c ...): the next token is
-    the present one half of the time, which a tied head predicts."""
-    half = np.random.default_rng(7).integers(0, vocab, (rows, seq // 2 + 1))
-    data = jnp.asarray(np.repeat(half, 2, axis=1)[:, : seq + 1], jnp.int32)
-    return {"tokens": data[:, :-1], "targets": data[:, 1:]}
-
-
-def _seeded(cfg, seed=0):
-    """Seeded weights, but every norm scale and per-head scale drawn
-    around 1: at 1 a scale left out could not show."""
-    params = decoder.init(jax.random.key(seed), cfg)
-    keys = iter(jax.random.split(jax.random.key(seed + 100), 64))
-
-    def scales(path, leaf):
-        if path[-1].key != "scale":
-            return leaf
-        return 1.0 + 0.3 * jax.random.normal(next(keys), leaf.shape)
-
-    return jax.tree_util.tree_map_with_path(scales, params)
+# every norm scale and per-head scale drawn around 1: at 1 a scale left
+# out could not show. The head is the token table's own (tied), which
+# predicts the doubled tokens
+SUITE = Suite(
+    "lfm2-8b-a1b", plain, TINY, SIZE_KEYS, seq=64, q_block=16,
+    tolerances=TOLERANCES, norm_eps=None,
+    make=lambda cfg, seed: seeded(
+        cfg, seed, scales=jax.random.key(seed + 100), head=False
+    ),
+)
+_cfg = SUITE.cfg
+_seeded = SUITE.weights
 
 
 @pytest.fixture(scope="module")
 def model():
-    cfg = _cfg()
-    return cfg, _seeded(cfg)
-
-
-def _compare(cfg, params, batch, sizes=None, tolerances=TOLERANCES):
-    """The cell's comparison, teacher-forced and free-running."""
-    sizes = sizes or _sizes(cfg)
-    logits, choices = routed.program_logits_and_choices(
-        params, batch["tokens"], cfg
-    )
-    program = routed.program_losses(params, batch, cfg)
-    results, record = routed.compare(
-        plain, params, batch, sizes, 16, logits, choices, program, tolerances
-    )
-    with jax.default_matmul_precision("highest"):
-        free_loss, _ = plain.loss_and_logits(params, batch, sizes, 16)
-    err = abs(program["loss"] - float(free_loss)) / float(free_loss)
-    results.append(
-        ("loss_vs_free_reference", err <= routed.FREE_LOSS_TOL, err,
-         routed.FREE_LOSS_TOL)
-    )
-    return {name: (ok, value) for name, ok, value, _ in results}, record
+    return SUITE.model()
 
 
 def test_program_matches_the_plain_reference(model):
     cfg, params = model
-    checks, record = _compare(cfg, params, _batch())
+    checks, record = SUITE.compare(cfg, params)
     assert list(checks) == CHECKS
     assert all(ok for ok, _ in checks.values()), checks
     assert checks["routing_regret"][1] == 0.0
@@ -136,10 +98,10 @@ def test_the_cells_six_layers_are_the_layers_one_by_one():
     assert [s[0] for s in decoder._pattern_stacks(cfg.layer_pattern)] == [
         "attention", "mlp", "conv", "conv.1", "experts",
     ]
-    params = _seeded(cfg, seed=5)
+    params = _seeded(cfg, 5)
     assert params["layers"]["conv"]["conv"]["w_in"].shape == (2, 64, 192)
     assert params["layers"]["conv.1"]["conv"]["conv_w"].shape == (3, 3, 64)
-    checks, record = _compare(cfg, params, _batch())
+    checks, record = SUITE.compare(cfg, params)
     assert all(ok for ok, _ in checks.values()), checks
     assert checks["logits_vs_reference"][1] < 1e-5
     assert len(record["moved_by_layer"]) == 4
@@ -152,12 +114,12 @@ def test_a_reference_side_in_bf16_does_not_pass(model):
     for it)."""
     cfg, params = model
     low = dataclasses.replace(cfg, dtype="bfloat16")
-    checks, _ = _compare(low, params, _batch())
+    checks, _ = SUITE.compare(low, params)
     assert not checks["logits_rms_vs_reference"][0], checks
     from benchmarks.runners import train
 
     chip = (train.LOGIT_TOL, train.LOGIT_RMS_TOL, train.LOSS_TOL)
-    checks, _ = _compare(low, params, _batch(), tolerances=chip)
+    checks, _ = SUITE.compare(low, params, tolerances=chip)
     assert checks["logits_rms_vs_reference"][0], checks
 
 
@@ -166,7 +128,7 @@ def test_a_reference_side_in_bf16_does_not_pass(model):
 
 DEFECTS = {
     # the eight the chip's cell is held to ...
-    **{name: lambda patch, plant=plant: plant(patch)
+    **{name: lambda patch, cfg, plant=plant: plant(patch)
        for name, plant in lfm2_defects.PLANT.items()},
     # ... and others a configuration can state
     "four_taps": dict(conv_kernel=4),
@@ -177,33 +139,18 @@ DEFECTS = {
 
 @pytest.mark.parametrize("defect", sorted(DEFECTS))
 def test_comparison_catches(monkeypatch, model, defect):
-    cfg, params = model
-    plant = DEFECTS[defect]
-    program_cfg, program_params = cfg, params
-    if isinstance(plant, dict):
-        program_cfg = dataclasses.replace(cfg, **plant)
-        if "conv_kernel" in plant:
-            # a fourth tap, the oldest, on every conv
-            def wider(path, leaf):
-                if path[-1].key != "conv_w":
-                    return leaf
-                return jnp.concatenate([leaf[:, :1], leaf], axis=1)
+    plant, program_params = DEFECTS[defect], None
+    if isinstance(plant, dict) and "conv_kernel" in plant:
+        # a fourth tap, the oldest, on every conv
 
-            program_params = jax.tree_util.tree_map_with_path(wider, params)
-    else:
-        plant(monkeypatch.setattr)
-    # the reference keeps the sound sizes and weights
-    logits, choices = routed.program_logits_and_choices(
-        program_params, _batch()["tokens"], program_cfg
-    )
-    program = routed.program_losses(program_params, _batch(), program_cfg)
-    results, _ = routed.compare(
-        plain, params, _batch(), _sizes(cfg), 16, logits, choices, program,
-        TOLERANCES,
-    )
-    failed = {name for name, ok, _, _ in results if not ok}
+        def wider(path, leaf):
+            if path[-1].key != "conv_w":
+                return leaf
+            return jnp.concatenate([leaf[:, :1], leaf], axis=1)
+
+        program_params = jax.tree_util.tree_map_with_path(wider, model[1])
     caught_by = lfm2_defects.CAUGHT_BY.get(defect, lfm2_defects.LOGITS)
-    assert failed & set(caught_by), (defect, results)
+    SUITE.catches(monkeypatch, model, plant, caught_by, program_params)
 
 
 # ---- the gated conv, both bodies, as the mixer calls it -------------------
@@ -265,48 +212,10 @@ def test_mixer_goes_through_the_one_door(monkeypatch, interpreted):
 
 def test_shares_of_the_expert_parallel_layer_add_up():
     """Four chips hold experts 0-1 ... 6-7 of one routed layer
-    (``expert_offset`` 0, E/4, 2E/4, 3E/4). Their parts add up to what
-    the uncut reference gives for the whole layer: nothing is lost or
-    counted twice at the seams, and a token's sigmoid weights are
-    renormalised over all it chose."""
-    shares, held = 4, 2
-    whole = _cfg(n_experts=shares * held, n_experts_held=0, expert_top_k=4)
-    full = moe.init_moe_params(jax.random.key(3), whole, lead=())
-    g = jax.random.normal(jax.random.key(4), (2, 32, whole.d_model))
-    sizes = dict(
-        _sizes(whole), n_experts_held=shares * held, expert_offset=0
-    )
-    with jax.default_matmul_precision("highest"):
-        want, _ = plain._routed(g.reshape(64, -1), full, sizes, None)
-        total, rows = 0.0, 0.0
-        for rank in range(shares):
-            cfg = dataclasses.replace(
-                whole, n_experts_held=held, expert_offset=rank * held
-            )
-            here = slice(rank * held, (rank + 1) * held)
-            part = dict(
-                full, **{k: full[k][here]
-                         for k in ("w_up", "w_gate_proj", "w_down")}
-            )
-            out, aux = moe._moe_block_ragged(g, part, cfg)
-            total = total + out
-            rows += float(aux["moe_held_rows"])
-            # the reference's share is the program's
-            mine, _ = plain._routed(
-                g.reshape(64, -1), part,
-                dict(sizes, n_experts_held=held, expert_offset=rank * held),
-                None,
-            )
-            np.testing.assert_allclose(
-                np.asarray(out).reshape(64, -1), np.asarray(mine),
-                rtol=2e-5, atol=2e-5,
-            )
-    np.testing.assert_allclose(
-        np.asarray(total).reshape(64, -1), np.asarray(want),
-        rtol=2e-5, atol=2e-5,
-    )
-    # every (token, choice) row went to exactly one share
-    assert rows == 2 * 32 * whole.expert_top_k
+    (``expert_offset`` 0, E/4, 2E/4, 3E/4): a token's sigmoid weights
+    are renormalised over all it chose, and the reference's share is
+    the program's, share by share."""
+    SUITE.shares_add_up(4, 2, each=True, n_experts=8, expert_top_k=4)
 
 
 # ---- the gradient ---------------------------------------------------------
@@ -318,31 +227,7 @@ def test_gradient_of_every_leaf_is_the_references(model):
     reference sent to the same experts: every leaf, the taps and the
     tied table among them, to 2e-4 of the leaf's largest entry (float32
     sums in another order)."""
-    cfg, params = model
-    batch = _batch()
-    sizes = _sizes(cfg)
-    _, choices = routed.program_logits_and_choices(
-        params, batch["tokens"], cfg
-    )
-
-    def objective(p):
-        return plain.loss_and_logits_routed(p, batch, sizes, 16, choices)[0]
-
-    got = jax.jit(
-        jax.grad(lambda p: decoder.loss_fn(p, batch, cfg)[0])
-    )(params)
-    with jax.default_matmul_precision("highest"):
-        want = jax.jit(jax.grad(objective))(params)
-    flat_got = jax.tree_util.tree_leaves_with_path(got)
-    flat_want = jax.tree.leaves(want)
-    assert len(flat_got) == len(flat_want)
-    for (path, a), b in zip(flat_got, flat_want):
-        scale = float(jnp.max(jnp.abs(b))) or 1.0
-        np.testing.assert_allclose(
-            np.asarray(a) / scale, np.asarray(b) / scale, atol=2e-4,
-            err_msg=jax.tree_util.keystr(path),
-        )
-        assert float(jnp.max(jnp.abs(b))) > 0, jax.tree_util.keystr(path)
+    SUITE.gradients_match(model)
 
 
 # ---- counts, counters and refusals ----------------------------------------
@@ -422,25 +307,11 @@ def test_config_refuses(over, why):
         _cfg(**over)
 
 
-REFUSALS = {
-    "init_kv_cache": lambda cfg, p, t: decoder.init_kv_cache(cfg, 2, 64),
-    "prefill": lambda cfg, p, t: decoder.prefill(p, t, cfg, 64),
-    "decode_step": lambda cfg, p, t: decoder.decode_step(
-        p, t[:, 0], {}, 0, cfg
-    ),
-    "decode_step_paged": lambda cfg, p, t: decoder.decode_step_paged(
-        p, t[:, 0], {}, None, jnp.zeros(2, jnp.int32), None, cfg
-    ),
-    "sample": lambda cfg, p, t: generate.sample(
-        p, cfg, t, 4, jax.random.key(0)
-    ),
-}
+CACHE_PATHS = sorted(set(REFUSALS) - {"prefill_chunk", "verify_chunk"})
 
 
-@pytest.mark.parametrize("path", sorted(REFUSALS))
+@pytest.mark.parametrize("path", CACHE_PATHS)
 def test_cache_and_generate_paths_refuse_the_model(model, path):
-    cfg, params = model
-    with pytest.raises(
-        ValueError, match=r"lfm2-8b-a1b: gated-short-convolution \(C\) layers"
-    ):
-        REFUSALS[path](cfg, params, _batch()["tokens"])
+    SUITE.refuses(
+        model, path, r"lfm2-8b-a1b: gated-short-convolution \(C\) layers"
+    )

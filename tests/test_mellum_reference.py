@@ -18,14 +18,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from reference_suite import REFUSALS, Suite, seeded
 
-from benchmarks.lib import routed
 from benchmarks.references import mellum_plain as plain
 from benchmarks.runners.train import _program_config
 from benchmarks.tests import mellum_defects
-from dlrover_tpu.models import decoder, generate, get_config
+from dlrover_tpu.models import decoder, get_config
 from dlrover_tpu.observability import tracing
-from dlrover_tpu.parallel import MeshConfig, build_mesh, moe
+from dlrover_tpu.parallel import MeshConfig, build_mesh
 from dlrover_tpu.train import TrainStepBuilder, make_optimizer
 from dlrover_tpu.train.train_step import abstract_train_state
 
@@ -49,9 +49,6 @@ SIZE_KEYS = (
     "n_experts_held", "expert_offset", "expert_top_k", "moe_renorm_topk",
     "moe_aux_coef",
 )
-# float32 on both sides: far inside the chip's limits, so that a defect
-# shows by orders of magnitude
-TOLERANCES = (1e-3, 1e-3, 1e-4)
 CHECKS = [
     "choices_valid", "routing_regret", "logits_vs_reference",
     "logits_rms_vs_reference", "loss_vs_reference",
@@ -63,70 +60,26 @@ CELL = (
 )
 
 
-def _cfg(**over):
-    return get_config("mellum2", **{**TINY, **over})
-
-
-def _sizes(cfg):
-    return {k: getattr(cfg, k) for k in SIZE_KEYS}
-
-
-def _batch(seq=64, rows=2, vocab=256):
-    """Every token twice in a row (a a b b c c ...): the next token is
-    the present one half of the time."""
-    half = np.random.default_rng(7).integers(0, vocab, (rows, seq // 2 + 1))
-    data = jnp.asarray(np.repeat(half, 2, axis=1)[:, : seq + 1], jnp.int32)
-    return {"tokens": data[:, :-1], "targets": data[:, 1:]}
-
-
-def _seeded(cfg, seed=0):
-    """Seeded weights, but every norm scale and per-head scale drawn
-    around 1 (at 1 a scale left out could not show) and a head that
-    reads the token table, so that predictions lean towards the token
-    just given."""
-    params = decoder.init(jax.random.key(seed), cfg)
-    keys = iter(jax.random.split(jax.random.key(seed + 100), 64))
-
-    def scales(path, leaf):
-        if path[-1].key != "scale":
-            return leaf
-        return 1.0 + 0.3 * jax.random.normal(next(keys), leaf.shape)
-
-    params = jax.tree_util.tree_map_with_path(scales, params)
-    d = cfg.d_model
-    params["lm_head"]["w"] = params["embed"]["tokens"].T / (0.02 * d ** 0.5)
-    return params
+# every norm scale and per-head scale drawn around 1 (at 1 a scale left
+# out could not show) and a head that reads the token table
+SUITE = Suite(
+    "mellum2", plain, TINY, SIZE_KEYS, seq=64, q_block=16, norm_eps=None,
+    make=lambda cfg, seed: seeded(
+        cfg, seed, scales=jax.random.key(seed + 100)
+    ),
+)
+_cfg, _sizes, _batch = SUITE.cfg, SUITE.sizes, SUITE.batch
+_seeded = SUITE.weights
 
 
 @pytest.fixture(scope="module")
 def model():
-    cfg = _cfg()
-    return cfg, _seeded(cfg)
-
-
-def _compare(cfg, params, batch, sizes=None):
-    """The cell's comparison, teacher-forced and free-running."""
-    sizes = sizes or _sizes(cfg)
-    logits, choices = routed.program_logits_and_choices(
-        params, batch["tokens"], cfg
-    )
-    program = routed.program_losses(params, batch, cfg)
-    results, record = routed.compare(
-        plain, params, batch, sizes, 16, logits, choices, program, TOLERANCES
-    )
-    with jax.default_matmul_precision("highest"):
-        free_loss, _ = plain.loss_and_logits(params, batch, sizes, 16)
-    err = abs(program["loss"] - float(free_loss)) / float(free_loss)
-    results.append(
-        ("loss_vs_free_reference", err <= routed.FREE_LOSS_TOL, err,
-         routed.FREE_LOSS_TOL)
-    )
-    return {name: (ok, value) for name, ok, value, _ in results}, record
+    return SUITE.model()
 
 
 def test_program_matches_the_plain_reference(model):
     cfg, params = model
-    checks, record = _compare(cfg, params, _batch())
+    checks, record = SUITE.compare(cfg, params)
     assert list(checks) == CHECKS
     assert all(ok for ok, _ in checks.values()), checks
     assert checks["routing_regret"][1] == 0.0
@@ -141,8 +94,7 @@ def test_two_periods_scanned_are_the_layers_one_by_one():
     table, gives what the reference's eight layers give one after
     another."""
     cfg = _cfg(n_layer=8, layer_types="SSSY" * 2)
-    params = _seeded(cfg, seed=5)
-    checks, record = _compare(cfg, params, _batch())
+    checks, record = SUITE.compare(cfg, _seeded(cfg, 5))
     assert all(ok for ok, _ in checks.values()), checks
     assert checks["logits_vs_reference"][1] < 1e-5
     assert len(record["moved_by_layer"]) == 8
@@ -164,18 +116,8 @@ DEFECTS = {
 
 @pytest.mark.parametrize("defect", sorted(DEFECTS))
 def test_comparison_catches(monkeypatch, model, defect):
-    cfg, params = model
-    plant = DEFECTS[defect]
-    program_cfg = cfg
-    if isinstance(plant, dict):
-        program_cfg = dataclasses.replace(cfg, **plant)
-    else:
-        plant(monkeypatch.setattr, cfg)
-    # the reference keeps the sound sizes; only the program is defective
-    checks, _ = _compare(program_cfg, params, _batch(), sizes=_sizes(cfg))
-    failed = {name for name, (ok, _) in checks.items() if not ok}
     caught_by = mellum_defects.CAUGHT_BY.get(defect, mellum_defects.LOGITS)
-    assert failed & set(caught_by), (defect, checks)
+    SUITE.catches(monkeypatch, model, DEFECTS[defect], caught_by)
 
 
 # ---- the scaled table -----------------------------------------------------
@@ -260,9 +202,9 @@ def test_a_scaled_rope_without_layer_types_turns_every_layer():
     table, which is what a stack of ``Y`` layers is to the reference."""
     cfg = _cfg(n_layer=2, layer_types="", attn_window=0)
     assert cfg.rope_kinds == ("scaled",) and cfg.kind_rope() == "scaled"
-    params = _seeded(cfg, seed=3)
+    params = _seeded(cfg, 3)
     sizes = dict(_sizes(cfg), layer_types="YY")
-    checks, _ = _compare(cfg, params, _batch(), sizes=sizes)
+    checks, _ = SUITE.compare(cfg, params, sizes=sizes)
     assert all(ok for ok, _ in checks.values()), checks
     assert checks["logits_vs_reference"][1] < 1e-5
     assert tracing.counters()["attn.rope_tables"] == 1
@@ -282,48 +224,9 @@ def test_a_scaled_rope_without_layer_types_turns_every_layer():
 
 def test_shares_of_the_expert_parallel_layer_add_up():
     """Four chips hold experts 0-1 ... 6-7 of one routed layer
-    (``expert_offset`` 0, E/4, 2E/4, 3E/4). Their parts add up to what
-    the uncut reference gives for the whole layer: nothing is lost or
-    counted twice at the seams, and a token's weights are over all it
-    chose."""
-    shares, held = 4, 2
-    whole = _cfg(n_experts=shares * held, n_experts_held=0, expert_top_k=4)
-    full = moe.init_moe_params(jax.random.key(3), whole, lead=())
-    g = jax.random.normal(jax.random.key(4), (2, 32, whole.d_model))
-    sizes = dict(
-        _sizes(whole), n_experts_held=shares * held, expert_offset=0
-    )
-    with jax.default_matmul_precision("highest"):
-        want, _, _ = plain._routed(g.reshape(64, -1), full, sizes, None)
-        total, rows = 0.0, 0.0
-        for rank in range(shares):
-            cfg = dataclasses.replace(
-                whole, n_experts_held=held, expert_offset=rank * held
-            )
-            here = slice(rank * held, (rank + 1) * held)
-            part = dict(
-                full, **{k: full[k][here]
-                         for k in ("w_up", "w_gate_proj", "w_down")}
-            )
-            out, aux = moe._moe_block_ragged(g, part, cfg)
-            total = total + out
-            rows += float(aux["moe_held_rows"])
-            # the reference's share is the program's
-            mine, _, _ = plain._routed(
-                g.reshape(64, -1), part,
-                dict(sizes, n_experts_held=held, expert_offset=rank * held),
-                None,
-            )
-            np.testing.assert_allclose(
-                np.asarray(out).reshape(64, -1), np.asarray(mine),
-                rtol=2e-5, atol=2e-5,
-            )
-    np.testing.assert_allclose(
-        np.asarray(total).reshape(64, -1), np.asarray(want),
-        rtol=2e-5, atol=2e-5,
-    )
-    # every (token, choice) row went to exactly one share
-    assert rows == 2 * 32 * whole.expert_top_k
+    (``expert_offset`` 0, E/4, 2E/4, 3E/4); the reference's share is
+    the program's, share by share."""
+    SUITE.shares_add_up(4, 2, each=True, n_experts=8, expert_top_k=4)
 
 
 # ---- the gradient ---------------------------------------------------------
@@ -334,32 +237,7 @@ def test_gradient_of_every_leaf_is_the_references(model):
     period at a time under ``remat: full``, each kind under its own
     table, against ``jax.grad`` of the plain reference sent to the same
     experts."""
-    cfg, params = model
-    batch = _batch()
-    sizes = _sizes(cfg)
-    _, choices = routed.program_logits_and_choices(
-        params, batch["tokens"], cfg
-    )
-
-    def objective(p):
-        ce, _, terms = plain.loss_and_logits_routed(
-            p, batch, sizes, 16, choices
-        )
-        return ce + terms["moe_lb_loss"]
-
-    got = jax.grad(lambda p: decoder.loss_fn(p, batch, cfg)[0])(params)
-    with jax.default_matmul_precision("highest"):
-        want = jax.grad(objective)(params)
-    flat_got = jax.tree_util.tree_leaves_with_path(got)
-    flat_want = jax.tree.leaves(want)
-    assert len(flat_got) == len(flat_want)
-    for (path, a), b in zip(flat_got, flat_want):
-        scale = float(jnp.max(jnp.abs(b))) or 1.0
-        np.testing.assert_allclose(
-            np.asarray(a) / scale, np.asarray(b) / scale, atol=2e-4,
-            err_msg=jax.tree_util.keystr(path),
-        )
-        assert float(jnp.max(jnp.abs(b))) > 0, jax.tree_util.keystr(path)
+    SUITE.gradients_match(model, terms=("moe_lb_loss",))
 
 
 # ---- counts, counters and refusals ----------------------------------------
@@ -495,27 +373,6 @@ def test_embeddings_are_drawn_at_the_configured_std(std):
         _cfg(embed_init_std=0.0)
 
 
-REFUSALS = {
-    "init_kv_cache": lambda cfg, p, t: decoder.init_kv_cache(cfg, 2, 64),
-    "prefill": lambda cfg, p, t: decoder.prefill(p, t, cfg, 64),
-    "decode_step": lambda cfg, p, t: decoder.decode_step(
-        p, t[:, 0], {}, 0, cfg
-    ),
-    "prefill_chunk": lambda cfg, p, t: decoder.prefill_chunk(
-        p, t, {}, 0, cfg
-    ),
-    "decode_step_paged": lambda cfg, p, t: decoder.decode_step_paged(
-        p, t[:, 0], {}, None, jnp.zeros(2, jnp.int32), None, cfg
-    ),
-    "verify_chunk": lambda cfg, p, t: decoder.verify_chunk(p, t, {}, 0, cfg),
-    "sample": lambda cfg, p, t: generate.sample(
-        p, cfg, t, 4, jax.random.key(0)
-    ),
-}
-
-
 @pytest.mark.parametrize("path", sorted(REFUSALS))
 def test_cache_and_generate_paths_refuse_the_model(model, path):
-    cfg, params = model
-    with pytest.raises(ValueError, match="mellum2: a trunk whose"):
-        REFUSALS[path](cfg, params, _batch()["tokens"])
+    SUITE.refuses(model, path, "mellum2: a trunk whose")

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from reference_suite import Suite
 
 from benchmarks.lib import selected as sel
 from benchmarks.references import minicpm_sala_plain as plain
@@ -44,21 +45,6 @@ SIZE_KEYS = (
 SEQ = 64
 
 
-def _cfg(**over):
-    return get_config("minicpm-sala", **{**TINY, **over})
-
-
-def _sizes(cfg):
-    return dict({k: getattr(cfg, k) for k in SIZE_KEYS}, norm_eps=1e-6)
-
-
-def _batch(seq=SEQ, rows=2, vocab=256):
-    data = jnp.asarray(
-        np.random.default_rng(7).integers(0, vocab, (rows, seq + 1)), jnp.int32
-    )
-    return {"tokens": data[:, :-1], "targets": data[:, 1:]}
-
-
 def _init(cfg, seed=0):
     """Seeded weights with norm scales off 1, so that each scale's place
     in the equations is compared."""
@@ -73,17 +59,29 @@ def _init(cfg, seed=0):
     return jax.tree_util.tree_map_with_path(off_one, params)
 
 
+SUITE = Suite(
+    "minicpm-sala", plain, TINY, SIZE_KEYS, seq=SEQ, q_block=16,
+    make=_init, doubled=False,
+)
+_cfg, _sizes, _batch = SUITE.cfg, SUITE.sizes, SUITE.batch
+
+
 @pytest.fixture(scope="module")
 def model():
-    cfg = _cfg()
-    return cfg, _init(cfg)
+    return SUITE.model()
 
 
 def _forward(params, tokens, cfg):
-    with jax.default_matmul_precision("highest"):
-        return jax.jit(
-            lambda p, t: decoder.forward(p, t, cfg, return_aux=True)
-        )(params, tokens)
+    """(logits, aux) of the program, once for a config, weights and
+    length."""
+
+    def forward():
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(
+                lambda p, t: decoder.forward(p, t, cfg, return_aux=True)
+            )(params, tokens)
+
+    return SUITE.once(("aux", tokens.shape), cfg, params, forward)
 
 
 def test_program_matches_the_plain_reference(model):
@@ -109,21 +107,8 @@ def test_program_matches_the_plain_reference(model):
 
 
 def test_every_parameters_gradient_matches_the_reference(model):
-    cfg, params = model
-    batch = _batch()
     with jax.default_matmul_precision("highest"):
-        got = jax.grad(
-            lambda p: decoder.loss_fn(p, batch, cfg=cfg)[0]
-        )(params)
-        want = jax.grad(
-            lambda p: plain.loss_and_logits(p, batch, _sizes(cfg), 16)[0]
-        )(params)
-    flat_got = dict(jax.tree_util.tree_leaves_with_path(got))
-    for path, w in jax.tree_util.tree_leaves_with_path(want):
-        g = flat_got[path]
-        top = float(jnp.max(jnp.abs(w)))
-        assert top > 0, path
-        assert float(jnp.max(jnp.abs(g - w))) / top < 2e-4, path
+        SUITE.gradients_match(model, forced=False)
 
 
 def test_teacher_forced_on_the_programs_units(model):

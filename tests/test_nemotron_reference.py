@@ -16,12 +16,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from reference_suite import LOGITS, REFUSALS, Suite, seeded
 
 from benchmarks.lib import flops as flopslib
-from benchmarks.lib import routed
 from benchmarks.references import nemotron_h_plain as plain
 from benchmarks.tests import nemotron_defects as shared_defects
-from dlrover_tpu.models import decoder, generate, get_config
+from dlrover_tpu.models import decoder, get_config
 from dlrover_tpu.ops import ssd
 from dlrover_tpu.parallel import moe
 
@@ -45,66 +45,24 @@ SIZE_KEYS = (
     "n_mtp_module", "mtp_loss_coef", "routed_scaling_factor",
     "moe_renorm_topk",
 )
-# float32 on both sides: far inside the chip's limits, so that a defect
-# shows by orders of magnitude
-TOLERANCES = (1e-3, 1e-3, 1e-4)
 SEQ = 40
-
-
-def _cfg(**over):
-    return get_config("nemotron-3-super", **{**TINY, **over})
-
-
-def _sizes(cfg):
-    return dict({k: getattr(cfg, k) for k in SIZE_KEYS}, norm_eps=1e-6)
-
-
-def _batch(seq=SEQ, rows=2, vocab=256):
-    """Every token twice in a row (a a b b c c ...): the next token is
-    the present one half of the time, the one after that never."""
-    half = np.random.default_rng(7).integers(0, vocab, (rows, seq // 2 + 1))
-    data = jnp.asarray(np.repeat(half, 2, axis=1)[:, : seq + 1], jnp.int32)
-    return {"tokens": data[:, :-1], "targets": data[:, 1:]}
+# a head that reads the token table and a module projection that passes
+# the next token's embedding through
+SUITE = Suite(
+    "nemotron-3-super", plain, TINY, SIZE_KEYS, seq=SEQ, q_block=8,
+    make=lambda cfg, seed: seeded(cfg, seed, module=True),
+)
+_cfg, _batch = SUITE.cfg, SUITE.batch
 
 
 @pytest.fixture(scope="module")
 def model():
-    """Seeded weights, but for a head that reads the token table and a
-    module projection that passes the next token's embedding through
-    (``tests/test_glm_reference.py`` says why)."""
-    cfg = _cfg()
-    params = decoder.init(jax.random.key(0), cfg)
-    d = cfg.d_model
-    params["lm_head"]["w"] = params["embed"]["tokens"].T / (0.02 * d ** 0.5)
-    params["mtp"]["eh_proj"] = jnp.concatenate(
-        [jnp.eye(d), 0.25 * params["mtp"]["eh_proj"][d:]]
-    )
-    return cfg, params
-
-
-def _compare(cfg, params, batch, sizes=None):
-    """The cell's comparison, teacher-forced and free-running."""
-    sizes = sizes or _sizes(cfg)
-    logits, choices = routed.program_logits_and_choices(
-        params, batch["tokens"], cfg
-    )
-    program = routed.program_losses(params, batch, cfg)
-    results, record = routed.compare(
-        plain, params, batch, sizes, 8, logits, choices, program, TOLERANCES
-    )
-    with jax.default_matmul_precision("highest"):
-        free_loss, _ = plain.loss_and_logits(params, batch, sizes, 8)
-    err = abs(program["loss"] - float(free_loss)) / float(free_loss)
-    results.append(
-        ("loss_vs_free_reference", err <= routed.FREE_LOSS_TOL, err,
-         routed.FREE_LOSS_TOL)
-    )
-    return {name: (ok, value) for name, ok, value, _ in results}, record
+    return SUITE.model()
 
 
 def test_program_matches_the_plain_reference(model):
     cfg, params = model
-    checks, record = _compare(cfg, params, _batch())
+    checks, record = SUITE.compare(cfg, params)
     assert list(checks) == [
         "choices_valid", "routing_regret", "logits_vs_reference",
         "logits_rms_vs_reference", "loss_vs_reference",
@@ -120,14 +78,12 @@ def test_program_matches_the_plain_reference(model):
 
 def test_forward_hands_over_every_choice_of_every_routed_layer(model):
     cfg, params = model
-    batch = _batch()
-    _, aux = decoder.forward(params, batch["tokens"], cfg, return_aux=True)
-    ids = np.asarray(aux["moe_choices"])
+    ids = np.asarray(SUITE.forward(cfg, params)[1])
     assert ids.dtype == np.int32
     assert ids.shape == (3, 2, SEQ, cfg.expert_top_k)
     assert ids.max() >= cfg.n_experts_held and ids.max() < cfg.n_experts
-    metrics = decoder.loss_fn(params, batch, cfg)[1]
-    assert float(metrics["moe_held_rows"]) == pytest.approx(
+    metrics = SUITE.losses(cfg, params)
+    assert metrics["moe_held_rows"] == pytest.approx(
         (ids < cfg.n_experts_held).sum() / 3
     )
     assert set(metrics) >= {"loss", "mtp_loss", "moe_held_rows"}
@@ -267,7 +223,6 @@ def _shared(name):
     return lambda patch, cfg: shared_defects.INJECT[name](patch)
 
 
-LOGITS = ("logits_vs_reference", "logits_rms_vs_reference")
 DEFECTS = {
     "decays_summed_in_bf16": (_shared("bf16_decays"), LOGITS),
     "gate_after_the_group_norm": (_shared("gate_after_the_norm"), LOGITS),
@@ -288,53 +243,16 @@ DEFECTS = {
 
 @pytest.mark.parametrize("defect", sorted(DEFECTS))
 def test_comparison_catches(monkeypatch, model, defect):
-    cfg, params = model
-    plant, caught_by = DEFECTS[defect]
-    program_cfg = cfg
-    if isinstance(plant, dict):
-        program_cfg = dataclasses.replace(cfg, **plant)
-    else:
-        plant(monkeypatch.setattr, cfg)
-    checks, _ = _compare(program_cfg, params, _batch(), sizes=_sizes(cfg))
-    failed = {name for name, (ok, _) in checks.items() if not ok}
-    assert failed & set(caught_by), (defect, checks)
+    SUITE.catches(monkeypatch, model, *DEFECTS[defect])
 
 
 # ---- the shares add up ----------------------------------------------------
 
 
 def test_shares_of_the_expert_parallel_layer_add_up():
-    """Four chips hold experts 0-3 ... 12-15 of one routed layer. Their
-    routed parts (each through W_up, which is linear), and the shared
-    expert ONCE, add up to what the uncut reference gives for the whole
-    layer: nothing is lost or counted twice at the seams, and a token's
-    weights are over all it chose."""
-    shares, held = 4, 4
-    whole = _cfg(n_experts_held=0)
-    full = moe.init_moe_params(jax.random.key(3), whole, lead=())
-    g = jax.random.normal(jax.random.key(4), (2, 32, whole.d_model))
-    sizes = dict(
-        _sizes(whole), n_experts_held=shares * held, expert_offset=0
-    )
-    with jax.default_matmul_precision("highest"):
-        want, _ = plain._routed(g.reshape(64, -1), full, sizes, None)
-        total = moe._shared_expert(g, full["shared"], None)
-        rows = 0.0
-        for rank in range(shares):
-            cfg = dataclasses.replace(
-                whole, n_experts_held=held, expert_offset=rank * held
-            )
-            here = slice(rank * held, (rank + 1) * held)
-            part = dict(full, **{k: full[k][here] for k in ("w_up", "w_down")})
-            out, aux = moe._moe_block_ragged(g, part, cfg)
-            total = total + out
-            rows += float(aux["moe_held_rows"])
-    np.testing.assert_allclose(
-        np.asarray(total).reshape(64, -1), np.asarray(want),
-        rtol=2e-5, atol=2e-5,
-    )
-    # every (token, choice) row went to exactly one share
-    assert rows == 2 * 32 * whole.expert_top_k
+    """Four chips hold experts 0-3 ... 12-15 of one routed layer, each
+    through W_up, which is linear."""
+    SUITE.shares_add_up(4, 4, cut=("w_up", "w_down"))
 
 
 # ---- the row bound ----------------------------------------------------------
@@ -466,32 +384,7 @@ def test_gradient_of_every_kind_of_parameter_is_the_references(model):
     under its own checkpoint, the attention, the held experts' cut
     dispatch and combine and the module, against ``jax.grad`` of the
     plain reference sent to the same experts."""
-    cfg, params = model
-    batch = _batch()
-    sizes = _sizes(cfg)
-    _, choices = routed.program_logits_and_choices(
-        params, batch["tokens"], cfg
-    )
-
-    def objective(p):
-        ce, _, terms = plain.loss_and_logits_routed(
-            p, batch, sizes, 8, choices
-        )
-        return ce + terms["mtp_loss"]
-
-    got = jax.grad(lambda p: decoder.loss_fn(p, batch, cfg)[0])(params)
-    with jax.default_matmul_precision("highest"):
-        want = jax.grad(objective)(params)
-    flat_got = jax.tree_util.tree_leaves_with_path(got)
-    flat_want = jax.tree.leaves(want)
-    assert len(flat_got) == len(flat_want)
-    for (path, a), b in zip(flat_got, flat_want):
-        scale = float(jnp.max(jnp.abs(b))) or 1.0
-        np.testing.assert_allclose(
-            np.asarray(a) / scale, np.asarray(b) / scale, atol=2e-4,
-            err_msg=jax.tree_util.keystr(path),
-        )
-        assert float(jnp.max(jnp.abs(b))) > 0, jax.tree_util.keystr(path)
+    SUITE.gradients_match(model, terms=("mtp_loss",))
 
 
 # ---- the FLOPs ---------------------------------------------------------------
@@ -506,10 +399,7 @@ def test_flops_per_token_is_the_references_required_terms(size):
             "nemotron-3-super", n_layer=11, layer_pattern="MEMEMEMEM*E",
             n_experts_held=8, vocab_size=16384, max_seq=8192,
         ), 8192
-    terms = plain.required_terms(_sizes(cfg), seq)
-    assert cfg.flops_per_token(seq) == pytest.approx(
-        flopslib.flops_of(terms), rel=1e-12
-    )
+    terms = SUITE.flops_terms(cfg, seq)
     if size == "cell":
         # the matrices, and 5 x 2.10 M of the recurrence entered as such
         scan = 5 * 2 * 128 * 64 * 128
@@ -522,32 +412,9 @@ def test_flops_per_token_is_the_references_required_terms(size):
 
 # ---- the paths that cannot run it say so ----------------------------------
 
-REFUSALS = {
-    "init_kv_cache": lambda cfg, p, t: decoder.init_kv_cache(cfg, 2, 64),
-    "prefill": lambda cfg, p, t: decoder.prefill(p, t, cfg, 64),
-    "decode_step": lambda cfg, p, t: decoder.decode_step(
-        p, t[:, 0], {}, 0, cfg
-    ),
-    "prefill_chunk": lambda cfg, p, t: decoder.prefill_chunk(
-        p, t, {}, 0, cfg
-    ),
-    "decode_step_paged": lambda cfg, p, t: decoder.decode_step_paged(
-        p, t[:, 0], {}, None, jnp.zeros(2, jnp.int32), None, cfg
-    ),
-    "verify_chunk": lambda cfg, p, t: decoder.verify_chunk(p, t, {}, 0, cfg),
-    "sample": lambda cfg, p, t: generate.sample(
-        p, cfg, t, 4, jax.random.key(0)
-    ),
-}
-
-
 @pytest.mark.parametrize("path", sorted(REFUSALS))
 def test_cache_and_generate_paths_refuse_the_model(model, path):
-    cfg, params = model
-    with pytest.raises(
-        ValueError, match="nemotron-3-super: state-space layers"
-    ):
-        REFUSALS[path](cfg, params, _batch()["tokens"])
+    SUITE.refuses(model, path, "nemotron-3-super: state-space layers")
 
 
 def test_pipeline_refuses_the_model():

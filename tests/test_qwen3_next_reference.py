@@ -16,12 +16,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from reference_suite import LOGITS, Suite, seeded
 
 from benchmarks.lib import flops as flopslib
-from benchmarks.lib import routed
 from benchmarks.references import qwen3_next_plain as plain
 from benchmarks.tests import qwen3next_defects as defects
-from dlrover_tpu.models import decoder, generate, get_config
+from dlrover_tpu.models import decoder, get_config
 from dlrover_tpu.models.config import pattern_layers, pattern_parts
 from dlrover_tpu.parallel import moe
 
@@ -42,82 +42,38 @@ SIZE_KEYS = (
     "n_experts", "n_experts_held", "expert_offset", "expert_top_k",
     "d_expert", "d_shared_expert", "moe_renorm_topk",
 )
-# float32 on both sides: far inside the chip's limits, so that a defect
-# shows by orders of magnitude
-TOLERANCES = (1e-3, 1e-3, 1e-4)
 SEQ = 72
-
-
-def _cfg(**over):
-    return get_config("qwen3-next", **{**TINY, **over})
-
-
-def _sizes(cfg):
-    return dict({k: getattr(cfg, k) for k in SIZE_KEYS}, norm_eps=1e-6)
-
-
-def _batch(seq=SEQ, rows=2, vocab=256):
-    """Every token twice in a row (a a b b c c ...): the next token is
-    the present one half of the time."""
-    half = np.random.default_rng(7).integers(0, vocab, (rows, seq // 2 + 1))
-    data = jnp.asarray(np.repeat(half, 2, axis=1)[:, : seq + 1], jnp.int32)
-    return {"tokens": data[:, :-1], "targets": data[:, 1:]}
-
-
-def _offsets(tree, key):
-    """Every norm offset (a ``scale`` that starts at 0) and the mixer's
-    output-norm scale moved off its initial value: at 0 and 1 a program
-    that reads ``w`` for ``1 + w``, or norms after the gate, could not
-    be told from a sound one by these alone."""
-    leaves, treedef = jax.tree_util.tree_flatten_with_path(tree)
-    out = []
-    for i, (path, leaf) in enumerate(leaves):
-        if "scale" in jax.tree_util.keystr(path):
-            leaf = leaf + 0.3 * jax.random.normal(
-                jax.random.fold_in(key, i), leaf.shape
-            )
-        out.append(leaf)
-    return jax.tree_util.tree_unflatten(treedef, out)
+# one period: every layer kind the model has. The defect and gradient
+# cases run on it; what two periods add (stacking, one row of choices a
+# layer of eight) is ``test_program_matches_the_plain_reference``'s
+PERIOD = dict(n_layer=4, layer_pattern="GeGeGe*e")
+# float32 on both sides (the suite's tolerances): far inside the chip's
+# limits, so that a defect shows by orders of magnitude. Norm offsets
+# and the mixer's output-norm scale are off their initial 0 and 1; A_log,
+# dt_bias and the conv's taps are drawn, not constants, by
+# ``decoder.init`` itself
+SUITE = Suite(
+    "qwen3-next", plain, TINY, SIZE_KEYS, seq=SEQ, q_block=8,
+    make=lambda cfg, seed: seeded(
+        cfg, seed, scales=jax.random.key(seed + 1), by_index=True
+    ),
+)
+_cfg, _sizes, _batch = SUITE.cfg, SUITE.sizes, SUITE.batch
 
 
 @pytest.fixture(scope="module")
 def model():
-    """Seeded weights, but for a head that reads the token table
-    (``tests/test_glm_reference.py`` says why) and norms that are off
-    their initial values. A_log, dt_bias and the conv's taps are drawn,
-    not constants, by ``decoder.init`` itself."""
-    cfg = _cfg()
-    params = _offsets(
-        decoder.init(jax.random.key(0), cfg), jax.random.key(1)
-    )
-    d = cfg.d_model
-    params["lm_head"]["w"] = params["embed"]["tokens"].T / (0.02 * d ** 0.5)
-    return cfg, params
+    return SUITE.model()
 
 
-def _compare(cfg, params, batch, sizes=None):
-    """The cell's comparison, teacher-forced and free-running."""
-    sizes = sizes or _sizes(cfg)
-    logits, choices = routed.program_logits_and_choices(
-        params, batch["tokens"], cfg
-    )
-    program = routed.program_losses(params, batch, cfg)
-    results, record = routed.compare(
-        plain, params, batch, sizes, 8, logits, choices, program, TOLERANCES
-    )
-    with jax.default_matmul_precision("highest"):
-        free_loss, _ = plain.loss_and_logits(params, batch, sizes, 8)
-    err = abs(program["loss"] - float(free_loss)) / float(free_loss)
-    results.append(
-        ("loss_vs_free_reference", err <= routed.FREE_LOSS_TOL, err,
-         routed.FREE_LOSS_TOL)
-    )
-    return {name: (ok, value) for name, ok, value, _ in results}, record
+@pytest.fixture(scope="module")
+def period():
+    return SUITE.model(**PERIOD)
 
 
 def test_program_matches_the_plain_reference(model):
     cfg, params = model
-    checks, record = _compare(cfg, params, _batch())
+    checks, record = SUITE.compare(cfg, params)
     assert list(checks) == [
         "choices_valid", "routing_regret", "logits_vs_reference",
         "logits_rms_vs_reference", "loss_vs_reference",
@@ -133,26 +89,20 @@ def test_program_matches_the_plain_reference(model):
 
 def test_forward_hands_over_every_choice_and_the_readout(model):
     cfg, params = model
-    batch = _batch()
-    _, aux = jax.jit(
-        lambda p, t: decoder.forward(p, t, cfg, return_aux=True)
-    )(params, batch["tokens"])
-    ids = np.asarray(aux["moe_choices"])
+    ids = np.asarray(SUITE.forward(cfg, params)[1])
     assert ids.dtype == np.int32
     assert ids.shape == (8, 2, SEQ, cfg.expert_top_k)
     assert ids.max() >= cfg.n_experts_held and ids.max() < cfg.n_experts
-    metrics = jax.jit(lambda p, b: decoder.loss_fn(p, b, cfg)[1])(
-        params, batch
-    )
-    assert float(metrics["moe_held_rows"]) == pytest.approx(
+    metrics = SUITE.losses(cfg, params)
+    assert metrics["moe_held_rows"] == pytest.approx(
         (ids < cfg.n_experts_held).sum() / 8
     )
     assert set(metrics) >= {"loss", "gdn_readout_ms", "moe_held_rows"}
     with jax.default_matmul_precision("highest"):
         _, _, readout = jax.jit(
             lambda p, t: plain.forward(p, t, _sizes(cfg), 8)
-        )(params, batch["tokens"])
-    assert float(metrics["gdn_readout_ms"]) == pytest.approx(
+        )(params, _batch()["tokens"])
+    assert metrics["gdn_readout_ms"] == pytest.approx(
         float(readout), rel=1e-5
     )
 
@@ -200,7 +150,8 @@ def test_rope_turns_a_quarter_of_a_head():
 
 def test_norm_offsets_start_at_zero_and_the_mixers_norm_at_one():
     cfg = _cfg()
-    params = decoder.init(jax.random.key(0), cfg)
+    init = jax.jit(decoder.init, static_argnums=1)
+    params = init(jax.random.key(0), cfg)
     layers = params["layers"]
     for scale in (
         params["final_norm"]["scale"], layers["gdn"]["ln"]["scale"],
@@ -215,7 +166,7 @@ def test_norm_offsets_start_at_zero_and_the_mixers_norm_at_one():
     a = np.exp(np.asarray(layers["gdn"]["gdn"]["a_log"]))
     assert 1.0 <= a.min() and a.max() <= 16.0 and a.std() > 0
     # a model that is not zero-centred keeps its ones
-    other = decoder.init(
+    other = init(
         jax.random.key(0),
         get_config("jamba2-3b", n_layer=2, layer_pattern="m-*-", d_model=64,
                    n_head=4, d_head=16, d_ff=64, vocab_size=64,
@@ -229,7 +180,7 @@ def test_norm_offsets_start_at_zero_and_the_mixers_norm_at_one():
 # ---- defects the comparison has to catch ---------------------------------
 
 
-def _conv_looks_ahead(patch):
+def _conv_looks_ahead(patch, cfg):
     from dlrover_tpu.ops import ssd
 
     conv = ssd.causal_conv
@@ -239,23 +190,23 @@ def _conv_looks_ahead(patch):
     )
 
 
-def _held_only_weights(patch):
+def _held_only_weights(patch, cfg):
     """Combine weights normalised over the chosen experts that are HERE."""
 
     def weights(probs, k, renormalize):
         vals, idx = jax.lax.top_k(probs, k)
-        here = idx < TINY["n_experts_held"]
+        here = idx < cfg.n_experts_held
         total = jnp.sum(jnp.where(here, vals, 0.0), -1, keepdims=True)
         return vals / jnp.maximum(total, 1e-9), idx
 
     patch(moe, "_topk_weights", weights)
 
 
-LOGITS = ("logits_vs_reference", "logits_rms_vs_reference")
 DEFECTS = {
     **{
-        name: (defects.PLANT[name], defects.CAUGHT_BY[name])
-        for name in defects.PLANT
+        name: (lambda patch, cfg, plant=plant: plant(patch),
+               defects.CAUGHT_BY[name])
+        for name, plant in defects.PLANT.items()
     },
     "conv_looks_ahead": (_conv_looks_ahead, LOGITS),
     "weights_over_held_experts_only": (_held_only_weights, LOGITS),
@@ -266,27 +217,18 @@ DEFECTS = {
 
 
 @pytest.mark.parametrize("defect", sorted(DEFECTS))
-def test_comparison_catches(monkeypatch, model, defect):
-    cfg, params = model
-    plant, caught_by = DEFECTS[defect]
-    program_cfg = cfg
-    if isinstance(plant, dict):
-        program_cfg = dataclasses.replace(cfg, **plant)
-    else:
-        plant(monkeypatch.setattr)
-    checks, _ = _compare(program_cfg, params, _batch(), sizes=_sizes(cfg))
-    failed = {name for name, (ok, _) in checks.items() if not ok}
-    assert failed & set(caught_by), (defect, checks)
+def test_comparison_catches(monkeypatch, period, defect):
+    SUITE.catches(monkeypatch, period, *DEFECTS[defect])
 
 
-def test_a_scale_of_the_readout_shows_in_its_mean_square(monkeypatch, model):
+def test_a_scale_of_the_readout_shows_in_its_mean_square(monkeypatch, period):
     """Why ``gdn_readout_ms`` is a term: q's 1 / sqrt(channels) left
     out makes every read-out sqrt(Dk) times too large, which the norm a
     head behind it takes out again but for its eps; the mean square
     reads Dk times the reference's."""
-    cfg, params = model
+    cfg, params = period
     defects.PLANT["query_scale_left_out"](monkeypatch.setattr)
-    checks, _ = _compare(cfg, params, _batch())
+    checks, _ = SUITE.compare(cfg, params, planted=True)
     ok, value = checks["gdn_readout_ms_vs_reference"]
     assert not ok
     assert value == pytest.approx(cfg.gdn_key_dim - 1, rel=0.1)
@@ -296,77 +238,23 @@ def test_a_scale_of_the_readout_shows_in_its_mean_square(monkeypatch, model):
 
 
 def test_shares_of_the_expert_parallel_layer_add_up():
-    """Two chips hold experts 0-3 and 4-7 of one routed block. Their
-    routed parts, and the GATED shared expert ONCE, add up to what the
-    uncut reference gives for the whole block: nothing is lost or
-    counted twice at the seams, and a token's weights are over all it
-    chose."""
-    shares, held = 2, 4
-    whole = _cfg(n_experts_held=0)
-    full = moe.init_moe_params(jax.random.key(3), whole, lead=())
+    """Two chips hold experts 0-3 and 4-7 of one routed block; the
+    GATED shared expert is added once."""
+    whole, full = SUITE.shares_add_up(2, 4)
     assert full["shared"]["w_own_gate"].shape == (whole.d_model, 1)
-    g = jax.random.normal(jax.random.key(4), (2, 32, whole.d_model))
-    sizes = dict(
-        _sizes(whole), n_experts_held=shares * held, expert_offset=0
-    )
-    with jax.default_matmul_precision("highest"):
-        want, _ = plain._routed(g.reshape(64, -1), full, sizes, None)
-        total = moe._shared_expert(g, full["shared"], None)
-        rows = 0.0
-        for rank in range(shares):
-            cfg = dataclasses.replace(
-                whole, n_experts_held=held, expert_offset=rank * held
-            )
-            here = slice(rank * held, (rank + 1) * held)
-            part = dict(full, **{
-                k: full[k][here] for k in ("w_up", "w_down", "w_gate_proj")
-            })
-            out, aux = moe._moe_block_ragged(g, part, cfg)
-            total = total + out
-            rows += float(aux["moe_held_rows"])
-    np.testing.assert_allclose(
-        np.asarray(total).reshape(64, -1), np.asarray(want),
-        rtol=2e-5, atol=2e-5,
-    )
-    # every (token, choice) row went to exactly one share
-    assert rows == 2 * 32 * whole.expert_top_k
 
 
 # ---- the gradient -----------------------------------------------------------
 
 
-def test_gradient_of_every_kind_of_parameter_is_the_references(model):
+def test_gradient_of_every_kind_of_parameter_is_the_references(period):
     """d(loss)/d(params) through the mixers' chunked rule and its
     hand-written inverse derivative, the gated attention, the held
     experts' cut dispatch and combine and the shared expert's gate,
     against ``jax.grad`` of the plain reference sent to the same
-    experts."""
-    cfg, params = model
-    batch = _batch()
-    sizes = _sizes(cfg)
-    _, choices = routed.program_logits_and_choices(
-        params, batch["tokens"], cfg
-    )
-
-    def objective(p):
-        return plain.loss_and_logits_routed(p, batch, sizes, 8, choices)[0]
-
-    got = jax.jit(jax.grad(lambda p: decoder.loss_fn(p, batch, cfg)[0]))(
-        params
-    )
-    with jax.default_matmul_precision("highest"):
-        want = jax.jit(jax.grad(objective))(params)
-    flat_got = jax.tree_util.tree_leaves_with_path(got)
-    flat_want = jax.tree.leaves(want)
-    assert len(flat_got) == len(flat_want)
-    for (path, a), b in zip(flat_got, flat_want):
-        scale = float(jnp.max(jnp.abs(b))) or 1.0
-        np.testing.assert_allclose(
-            # (float32 on both sides; sixteen parts amplify its rounding)
-            np.asarray(a) / scale, np.asarray(b) / scale, atol=2e-3,
-            err_msg=jax.tree_util.keystr(path),
-        )
-        assert float(jnp.max(jnp.abs(b))) > 0, jax.tree_util.keystr(path)
+    experts (float32 on both sides; eight parts amplify its
+    rounding)."""
+    SUITE.gradients_match(period, atol=2e-3)
 
 
 # ---- the parameters and the FLOPs -------------------------------------------
@@ -408,13 +296,9 @@ def test_num_params_is_the_tables_count():
 @pytest.mark.parametrize("size", ["tiny", "cell"])
 def test_flops_per_token_is_the_references_required_terms(size):
     cfg, seq = (_cfg(), SEQ) if size == "tiny" else (_cell_cfg(), 16384)
-    sizes = _sizes(cfg)
-    terms = plain.required_terms(sizes, seq)
-    assert cfg.flops_per_token(seq) == pytest.approx(
-        flopslib.flops_of(terms), rel=1e-12
-    )
+    terms = SUITE.flops_terms(cfg, seq)
     if size == "cell":
-        rule = 3 * plain.gdn_multiply_adds(sizes)
+        rule = 3 * plain.gdn_multiply_adds(_sizes(cfg))
         assert rule == 3 * 1_835_008
         assert terms["multiplied_params"] - rule == 191_864_832
         assert flopslib.flops_of(terms) == 1_586_896_896
@@ -454,16 +338,9 @@ def test_the_published_pattern_traces_whole():
 
 
 def test_cache_paths_refuse_the_model_by_name(model):
-    cfg, params = model
-    assert "gated-delta-rule" in cfg.train_only
-    tokens = _batch()["tokens"]
-    with pytest.raises(ValueError, match="gated-delta-rule"):
-        decoder.prefill(params, tokens, cfg, max_len=128)
-    with pytest.raises(ValueError, match="gated-delta-rule"):
-        generate.sample(
-            params, cfg, tokens[:, :4], max_new_tokens=2,
-            rng=jax.random.key(0),
-        )
+    assert "gated-delta-rule" in model[0].train_only
+    for path in ("prefill", "sample"):
+        SUITE.refuses(model, path, "gated-delta-rule")
 
 
 @pytest.mark.parametrize(

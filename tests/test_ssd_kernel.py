@@ -67,7 +67,11 @@ def _value_and_grads(args, weight, monkeypatch, kernels: bool):
         y = ssd.ssd_scan(*a, 128, 2)
         return (y.astype(F32) * weight).sum(), y
 
-    (_, y), grads = jax.value_and_grad(loss, range(5), has_aux=True)(*args)
+    # one program (eager, every operation of both bodies is a dispatch
+    # and a compile of its own); the switch is read as it is traced
+    (_, y), grads = jax.jit(
+        jax.value_and_grad(loss, range(5), has_aux=True)
+    )(*args)
     return (y, *grads)
 
 

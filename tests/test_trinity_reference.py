@@ -15,13 +15,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from reference_suite import LOGITS, REFUSALS, Suite, seeded
 
-from benchmarks.lib import routed
 from benchmarks.references import trinity_afmoe_plain as plain
 from benchmarks.tests import trinity_defects
-from dlrover_tpu.models import decoder, generate, get_config
+from dlrover_tpu.models import decoder, get_config
 from dlrover_tpu.observability import tracing
-from dlrover_tpu.parallel import MeshConfig, build_mesh, moe
+from dlrover_tpu.parallel import MeshConfig, build_mesh
 from dlrover_tpu.train import TrainStepBuilder, make_optimizer
 from dlrover_tpu.train.train_step import abstract_train_state
 
@@ -39,80 +39,31 @@ SIZE_KEYS = (
     "n_experts_held", "expert_offset", "expert_top_k", "n_shared_experts",
     "routed_scaling_factor", "moe_renorm_topk", "moe_aux_coef",
 )
-# float32 on both sides: far inside the chip's limits, so that a defect
-# shows by orders of magnitude
-TOLERANCES = (1e-3, 1e-3, 1e-4)
 CHECKS = [
     "choices_valid", "routing_regret", "logits_vs_reference",
     "logits_rms_vs_reference", "loss_vs_reference",
     "moe_lb_loss_vs_reference", "loss_vs_free_reference",
 ]
-
-
-def _cfg(**over):
-    return get_config("trinity-mini", **{**TINY, **over})
-
-
-def _sizes(cfg):
-    return {k: getattr(cfg, k) for k in SIZE_KEYS}
-
-
-def _batch(seq=32, rows=2, vocab=256):
-    """Every token twice in a row (a a b b c c ...): the next token is
-    the present one half of the time."""
-    half = np.random.default_rng(7).integers(0, vocab, (rows, seq // 2 + 1))
-    data = jnp.asarray(np.repeat(half, 2, axis=1)[:, : seq + 1], jnp.int32)
-    return {"tokens": data[:, :-1], "targets": data[:, 1:]}
-
-
-def _seeded(cfg, seed=0):
-    """Seeded weights, but every norm scale and per-head scale drawn
-    around 1 (at 1 a scale left out could not show) and a head that
-    reads the token table, so that predictions lean towards the token
-    just given."""
-    params = decoder.init(jax.random.key(seed), cfg)
-    keys = iter(jax.random.split(jax.random.key(seed + 100), 64))
-
-    def scales(path, leaf):
-        if path[-1].key != "scale":
-            return leaf
-        return 1.0 + 0.3 * jax.random.normal(next(keys), leaf.shape)
-
-    params = jax.tree_util.tree_map_with_path(scales, params)
-    d = cfg.d_model
-    params["lm_head"]["w"] = params["embed"]["tokens"].T / (0.02 * d ** 0.5)
-    return params
+# every norm scale and per-head scale drawn around 1 (at 1 a scale left
+# out could not show) and a head that reads the token table
+SUITE = Suite(
+    "trinity-mini", plain, TINY, SIZE_KEYS, seq=32, q_block=16, norm_eps=None,
+    make=lambda cfg, seed: seeded(
+        cfg, seed, scales=jax.random.key(seed + 100)
+    ),
+)
+_cfg, _sizes, _batch = SUITE.cfg, SUITE.sizes, SUITE.batch
+_seeded = SUITE.weights
 
 
 @pytest.fixture(scope="module")
 def model():
-    cfg = _cfg()
-    return cfg, _seeded(cfg)
-
-
-def _compare(cfg, params, batch, sizes=None):
-    """The cell's comparison, teacher-forced and free-running."""
-    sizes = sizes or _sizes(cfg)
-    logits, choices = routed.program_logits_and_choices(
-        params, batch["tokens"], cfg
-    )
-    program = routed.program_losses(params, batch, cfg)
-    results, record = routed.compare(
-        plain, params, batch, sizes, 16, logits, choices, program, TOLERANCES
-    )
-    with jax.default_matmul_precision("highest"):
-        free_loss, _ = plain.loss_and_logits(params, batch, sizes, 16)
-    err = abs(program["loss"] - float(free_loss)) / float(free_loss)
-    results.append(
-        ("loss_vs_free_reference", err <= routed.FREE_LOSS_TOL, err,
-         routed.FREE_LOSS_TOL)
-    )
-    return {name: (ok, value) for name, ok, value, _ in results}, record
+    return SUITE.model()
 
 
 def test_program_matches_the_plain_reference(model):
     cfg, params = model
-    checks, record = _compare(cfg, params, _batch())
+    checks, record = SUITE.compare(cfg, params)
     assert list(checks) == CHECKS
     assert all(ok for ok, _ in checks.values()), checks
     assert checks["routing_regret"][1] == 0.0
@@ -128,7 +79,6 @@ def test_program_matches_the_plain_reference(model):
 
 # defect -> what differs in the program; every one has to fail the
 # teacher-forced logits
-LOGITS = {"logits_vs_reference", "logits_rms_vs_reference"}
 DEFECTS = {
     # the five the chip's cell was held to (PERF.md section 6, PR 47) ...
     **{name: lambda patch, cfg, plant=plant: plant(patch)
@@ -142,17 +92,7 @@ DEFECTS = {
 
 @pytest.mark.parametrize("defect", sorted(DEFECTS))
 def test_comparison_catches(monkeypatch, model, defect):
-    cfg, params = model
-    plant = DEFECTS[defect]
-    program_cfg = cfg
-    if isinstance(plant, dict):
-        program_cfg = dataclasses.replace(cfg, **plant)
-    else:
-        plant(monkeypatch.setattr, cfg)
-    # the reference keeps the sound sizes; only the program is defective
-    checks, _ = _compare(program_cfg, params, _batch(), sizes=_sizes(cfg))
-    failed = {name for name, (ok, _) in checks.items() if not ok}
-    assert failed & LOGITS, (defect, checks)
+    SUITE.catches(monkeypatch, model, DEFECTS[defect], LOGITS)
 
 
 # ---- what each kind of layer sees -----------------------------------------
@@ -161,7 +101,7 @@ def test_comparison_catches(monkeypatch, model, defect):
 def _one_kind(kind):
     """Two routed layers of one kind behind no dense layer."""
     cfg = _cfg(n_layer=2, n_dense_layer=0, layer_types=kind * 2)
-    return cfg, _seeded(cfg, seed=2)
+    return cfg, _seeded(cfg, 2)
 
 
 @pytest.mark.parametrize("kind,sees", [("S", False), ("F", True)])
@@ -229,39 +169,8 @@ def test_a_full_layer_has_no_rope():
 
 
 def test_shares_of_the_expert_parallel_layer_add_up():
-    """Eight chips hold experts 0-1 ... 14-15 of one routed layer. Their
-    routed parts, and the shared expert ONCE, add up to what the uncut
-    reference gives for the whole layer: nothing is lost or counted
-    twice at the seams, and a token's weights are over all it chose."""
-    shares, held = 8, 2
-    whole = _cfg(n_experts=shares * held, n_experts_held=0, expert_top_k=4)
-    full = moe.init_moe_params(jax.random.key(3), whole, lead=())
-    g = jax.random.normal(jax.random.key(4), (2, 32, whole.d_model))
-    sizes = dict(
-        _sizes(whole), n_experts_held=shares * held, expert_offset=0
-    )
-    with jax.default_matmul_precision("highest"):
-        want, _, _ = plain._routed(g.reshape(64, -1), full, sizes, None)
-        total = moe._shared_expert(g, full["shared"], None)
-        rows = 0.0
-        for rank in range(shares):
-            cfg = dataclasses.replace(
-                whole, n_experts_held=held, expert_offset=rank * held
-            )
-            here = slice(rank * held, (rank + 1) * held)
-            part = dict(
-                full, **{k: full[k][here]
-                         for k in ("w_up", "w_gate_proj", "w_down")}
-            )
-            out, aux = moe._moe_block_ragged(g, part, cfg)
-            total = total + out
-            rows += float(aux["moe_held_rows"])
-    np.testing.assert_allclose(
-        np.asarray(total).reshape(64, -1), np.asarray(want),
-        rtol=2e-5, atol=2e-5,
-    )
-    # every (token, choice) row went to exactly one share
-    assert rows == 2 * 32 * whole.expert_top_k
+    """Eight chips hold experts 0-1 ... 14-15 of one routed layer."""
+    SUITE.shares_add_up(8, 2, n_experts=16, expert_top_k=4)
 
 
 # ---- the gradient ---------------------------------------------------------
@@ -272,32 +181,7 @@ def test_gradient_of_every_leaf_is_the_references(model):
     layer, the routed stack scanned a period at a time under ``remat:
     full``, the gate and the four norms, against ``jax.grad`` of the
     plain reference sent to the same experts."""
-    cfg, params = model
-    batch = _batch()
-    sizes = _sizes(cfg)
-    _, choices = routed.program_logits_and_choices(
-        params, batch["tokens"], cfg
-    )
-
-    def objective(p):
-        ce, _, terms = plain.loss_and_logits_routed(
-            p, batch, sizes, 16, choices
-        )
-        return ce + terms["moe_lb_loss"]
-
-    got = jax.grad(lambda p: decoder.loss_fn(p, batch, cfg)[0])(params)
-    with jax.default_matmul_precision("highest"):
-        want = jax.grad(objective)(params)
-    flat_got = jax.tree_util.tree_leaves_with_path(got)
-    flat_want = jax.tree.leaves(want)
-    assert len(flat_got) == len(flat_want)
-    for (path, a), b in zip(flat_got, flat_want):
-        scale = float(jnp.max(jnp.abs(b))) or 1.0
-        np.testing.assert_allclose(
-            np.asarray(a) / scale, np.asarray(b) / scale, atol=2e-4,
-            err_msg=jax.tree_util.keystr(path),
-        )
-        assert float(jnp.max(jnp.abs(b))) > 0, jax.tree_util.keystr(path)
+    SUITE.gradients_match(model, terms=("moe_lb_loss",))
 
 
 # ---- the period scan ------------------------------------------------------
@@ -317,8 +201,7 @@ def test_two_periods_scanned_are_the_layers_one_by_one():
     scan over two periods gives what the reference's nine layers give
     one after another, and the expert ids come out in trunk order."""
     cfg = _cfg(n_layer=9, layer_types="S" + "SSSF" * 2)
-    params = _seeded(cfg, seed=5)
-    checks, record = _compare(cfg, params, _batch())
+    checks, record = SUITE.compare(cfg, _seeded(cfg, 5))
     assert all(ok for ok, _ in checks.values()), checks
     assert checks["logits_vs_reference"][1] < 1e-5
     assert len(record["moved_by_layer"]) == 8
@@ -414,30 +297,9 @@ def test_config_refuses(over, why):
         _cfg(**over)
 
 
-REFUSALS = {
-    "init_kv_cache": lambda cfg, p, t: decoder.init_kv_cache(cfg, 2, 64),
-    "prefill": lambda cfg, p, t: decoder.prefill(p, t, cfg, 64),
-    "decode_step": lambda cfg, p, t: decoder.decode_step(
-        p, t[:, 0], {}, 0, cfg
-    ),
-    "prefill_chunk": lambda cfg, p, t: decoder.prefill_chunk(
-        p, t, {}, 0, cfg
-    ),
-    "decode_step_paged": lambda cfg, p, t: decoder.decode_step_paged(
-        p, t[:, 0], {}, None, jnp.zeros(2, jnp.int32), None, cfg
-    ),
-    "verify_chunk": lambda cfg, p, t: decoder.verify_chunk(p, t, {}, 0, cfg),
-    "sample": lambda cfg, p, t: generate.sample(
-        p, cfg, t, 4, jax.random.key(0)
-    ),
-}
-
-
 @pytest.mark.parametrize("path", sorted(REFUSALS))
 def test_cache_and_generate_paths_refuse_the_model(model, path):
-    cfg, params = model
-    with pytest.raises(ValueError, match="trinity-mini: a trunk whose"):
-        REFUSALS[path](cfg, params, _batch()["tokens"])
+    SUITE.refuses(model, path, "trinity-mini: a trunk whose")
 
 
 def test_a_gated_layer_of_one_kind_is_refused_by_the_cache_paths():
